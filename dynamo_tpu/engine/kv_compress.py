@@ -4,9 +4,9 @@ KV pages leave HBM in two places: the host-DRAM tier (engine/kv_manager
 multi-tier pool — reference KV block manager V2's host tier) and the
 disaggregation transfer plane (llm/disagg/transfer.py — the NIXL
 replacement). Both move whole pages ``[L, n, KV, ps, hd]`` over links
-that are orders of magnitude slower than HBM (PCIe/relay for D2H, DCN
-TCP for disagg). Quantizing per (token, head) row to int8 with an f32
-amax/127 scale halves the bytes on those links (hd bytes + 4 vs 2·hd
+that are orders of magnitude slower than HBM (PCIe for D2H, DCN TCP for
+disagg; how much slower is unmeasured on a directly attached chip).
+Quantizing per (token, head) row to int8 with an f32 amax/127 scale halves the bytes on those links (hd bytes + 4 vs 2·hd
 bf16) at a per-element error ≤ s/2 — the LMCache/CacheGen-style KV
 compression the GPU stacks apply at the same boundary.
 

@@ -188,13 +188,16 @@ class KvTransferServer:
     async def stop(self) -> None:
         if self._server:
             self._server.close()
-            await asyncio.wait_for(self._server.wait_closed(), _io_timeout())
         # drop established connections too — a stop() is a restart from the
         # sender's point of view, and senders probe liveness through the
-        # socket, not the (gone) listener
+        # socket, not the (gone) listener. BEFORE wait_closed(): since
+        # Python 3.12 that waits for every connection handler to finish,
+        # so awaiting it first waited out the very sockets closed here
         for w in list(self._conns):
             w.close()
         self._conns.clear()
+        if self._server:
+            await asyncio.wait_for(self._server.wait_closed(), _io_timeout())
         for st in list(self._ingests.values()):
             if st.task is not None:
                 st.task.cancel()

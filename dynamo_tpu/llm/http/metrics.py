@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import time
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 from ...runtime import slo
 
@@ -93,6 +93,7 @@ class Metrics:
         self.slo_registry = slo.SloRegistry.from_env()
         self.goodput = slo.GoodputTracker(self.slo_registry)
         self.slo = slo.SloEngine(self.slo_registry, source=self._slo_source)
+        self.sources: List[Callable[[], List[str]]] = []
 
     def guard(self, model: str, endpoint: str, request_type: str) -> "InflightGuard":
         return InflightGuard(self, model, endpoint, request_type)
@@ -153,6 +154,11 @@ class Metrics:
     def count_output_tokens(self, model: str, n: int) -> None:
         self.output_tokens_total[model] += n
 
+    def add_source(self, lines_fn: Callable[[], List[str]]) -> None:
+        """Extra exposition lines rendered with every scrape (a zero-arg
+        callable returning prom text lines)."""
+        self.sources.append(lines_fn)
+
     def render(self) -> str:
         lines: List[str] = []
 
@@ -211,6 +217,8 @@ class Metrics:
         lines.extend(guard.render_prom_lines())
         # dynaprof plane: this process's event-loop lag + stall captures
         lines.extend(profiling.render_prom_lines())
+        for source in self.sources:
+            lines.extend(source())
         return "\n".join(lines) + "\n"
 
 

@@ -252,10 +252,9 @@ def _use_pallas() -> bool:
     (DYN_DISABLE_PALLAS=1 forces the XLA gather path everywhere)."""
     if env_flag("DYN_DISABLE_PALLAS"):
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:
-        return False
+    # a backend that fails to initialise raises here: an unreachable chip
+    # must stop the server, not turn into the XLA gather path
+    return jax.default_backend() == "tpu"
 
 
 def _softcap_mask(scores: jax.Array, visible: jax.Array,

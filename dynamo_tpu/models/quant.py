@@ -138,8 +138,8 @@ def synthetic_int8_params(model, cfg,
     milliseconds — for throughput benchmarking only.
 
     ``host_init_quantized`` draws a full Gaussian tree on the host; at
-    8B on a single-core bench host that costs minutes of the chip
-    session's budget for values the throughput measurement never looks
+    8B on a single-core bench host that costs minutes of a chip run's
+    budget for values the throughput measurement never looks
     at. Here: ``jax.eval_shape`` gives the exact tree without computing
     it, quantized keys get UNINITIALIZED int8 (always finite) with
     fan-in scales, norms get ones and everything else zeros (finite

@@ -340,15 +340,18 @@ def _prefill_kernel(ps: int, scale: float, softcap: float | None,
     """Chunked-prefill flash attention over the paged pool.
 
     Per (b, kv) the query chunk stays VMEM-resident while pages stream
-    in (grid innermost axis); online softmax runs per query row. The
-    causal structure is positional: kv slot j of table entry p holds
-    logical position p*ps+j, visible to query t iff within
-    (q_position[t] - window, q_position[t]] — window is the per-row
-    effective sliding window (huge when the layer is global).
+    in (grid innermost axis); online softmax runs per query row. Rows
+    are the chunk's (token, group-head) pairs flattened to R = T*group
+    BEFORE the call, so every block is 2-D with the head dim in lanes —
+    the chip's compiler refuses a (T, group) tile with group < 8 and the
+    in-kernel reshapes that went with it. The causal structure is
+    positional: kv slot j of table entry p holds logical position
+    p*ps+j, visible to row r iff within (q_position[r] - window,
+    q_position[r]] — window is the per-row effective sliding window
+    (huge when the layer is global).
     """
     b = pl.program_id(0)
     p = pl.program_id(2)
-    T, group, hd = q_ref.shape
 
     @pl.when(p == 0)
     def _():
@@ -363,42 +366,40 @@ def _prefill_kernel(ps: int, scale: float, softcap: float | None,
     # pages wholly outside [lower, length): no compute, no fetch
     @pl.when(jnp.logical_and(p * ps < length, (p + 1) * ps > lower))
     def _():
-        q = q_ref[...].astype(jnp.float32).reshape(T * group, hd)
+        q = q_ref[...].astype(jnp.float32)             # [R, hd]
         k = k_ref[...].astype(jnp.float32)             # [ps, hd]
         v = v_ref[...].astype(jnp.float32)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [T*group, ps]
-        s = s.reshape(T, group, ps)
+            preferred_element_type=jnp.float32) * scale  # [R, ps]
         if softcap:  # Gemma-2 score softcap — BEFORE masking
             s = softcap * jnp.tanh(s / softcap)
-        kv_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        q_pos = qpos_ref[...].reshape(T, 1, 1)
+        kv_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        q_pos = qpos_ref[...]                          # [R, 1]
         valid = jnp.logical_and(kv_pos <= q_pos,       # causal + padding
                                 kv_pos > q_pos - win)  # sliding window
         s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[...].reshape(T, group, 1)
-        l_prev = l_ref[...].reshape(T, group, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        m_prev = m_ref[:, :1]                          # [R, 1]
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        # exp only where valid: an all-masked (t, page) pair (window
+        # exp only where valid: an all-masked (row, page) pair (window
         # already slid past the page) would otherwise add exp(0)=1 rows
         p_exp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_prev + jnp.sum(p_exp, axis=2, keepdims=True)
+        l_new = alpha * l_prev + jnp.sum(p_exp, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
-            p_exp.reshape(T * group, ps), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [T*group, hd]
-        acc_ref[...] = (acc_ref[...] * alpha.reshape(T * group, 1) + pv)
-        m_ref[...] = m_new.reshape(T, group)
-        l_ref[...] = l_new.reshape(T, group)
+            p_exp, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [R, hd]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(p == pl.num_programs(2) - 1)
     def _():
-        l = jnp.maximum(l_ref[...].reshape(T * group, 1), 1e-9)
-        o_ref[...] = (acc_ref[...] / l).reshape(T, group, hd).astype(
-            o_ref.dtype)
+        l = jnp.maximum(l_ref[:, :1], 1e-9)  # all-masked (padding) rows → 0
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
@@ -424,9 +425,15 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
     _, KV, ps, _ = k_pages.shape
     P = page_table.shape[1]
     group = H // KV
+    R = T * group  # kernel rows: (token, group-head) pairs, token-major
     if scale is None:
         scale = hd ** -0.5
-    q5 = q.reshape(B, T, KV, group, hd).transpose(0, 2, 1, 3, 4)
+    q4 = q.reshape(B, T, KV, group, hd).transpose(0, 2, 1, 3, 4).reshape(
+        B, KV, R, hd)
+    # per-row query position as a column (positions ride sublanes, like
+    # the score rows they mask)
+    qpos = jnp.repeat(q_positions.astype(jnp.int32), group,
+                      axis=1)[:, :, None]              # [B, R, 1]
     # pages to visit per row: those covering [lower, max position]
     lengths = jnp.max(q_positions, axis=1) + 1  # [B]; all-pad rows → 0
     if eff_win is None:
@@ -446,31 +453,31 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
         num_scalar_prefetch=4,
         grid=(B, KV, P),
         in_specs=[
-            pl.BlockSpec((None, None, T, group, hd),
-                         lambda b, kv, p, pt, ln, lo, win:
-                         (b, kv, 0, 0, 0)),
-            pl.BlockSpec((None, T),
-                         lambda b, kv, p, pt, ln, lo, win: (b, 0)),
+            pl.BlockSpec((None, None, R, hd),
+                         lambda b, kv, p, pt, ln, lo, win: (b, kv, 0, 0)),
+            pl.BlockSpec((None, R, 1),
+                         lambda b, kv, p, pt, ln, lo, win: (b, 0, 0)),
             pl.BlockSpec((None, None, ps, hd), page_index),
             pl.BlockSpec((None, None, ps, hd), page_index),
         ],
-        out_specs=pl.BlockSpec((None, None, T, group, hd),
+        out_specs=pl.BlockSpec((None, None, R, hd),
                                lambda b, kv, p, pt, ln, lo, win:
-                               (b, kv, 0, 0, 0)),
+                               (b, kv, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((T, group), jnp.float32),
-            pltpu.VMEM((T, group), jnp.float32),
-            pltpu.VMEM((T * group, hd), jnp.float32),
+            pltpu.VMEM((R, 128), jnp.float32),
+            pltpu.VMEM((R, 128), jnp.float32),
+            pltpu.VMEM((R, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, ps, scale, softcap),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, T, group, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       lower.astype(jnp.int32), eff_win.astype(jnp.int32),
-      q5, q_positions.astype(jnp.int32), k_pages, v_pages)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, T, H, hd)
+      q4, qpos, k_pages, v_pages)
+    return out.reshape(B, KV, T, group, hd).transpose(
+        0, 2, 1, 3, 4).reshape(B, T, H, hd)

@@ -186,9 +186,10 @@ def build_replica_engine(model_cfg, engine_cfg, spec: ReplicaSpec, *,
     from ..engine.jax_engine import JaxEngine
     from .mesh import MeshSpec
 
-    mesh = None
-    if devices_per_replica(spec.mesh_axes) > 1:
-        mesh = MeshSpec(**spec.mesh_axes).build(spec.devices)
+    # always a mesh over the replica's OWN devices: a one-chip replica
+    # gets a one-device mesh, which is how the engine learns which chip
+    # is its (mesh=None would put every replica on the default device)
+    mesh = MeshSpec(**spec.mesh_axes).build(spec.devices)
     engine = JaxEngine(model_cfg, engine_cfg, params=params, seed=seed,
                        mesh=mesh, quant=quant, worker_label=spec.name)
     if warmup:

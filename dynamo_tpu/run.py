@@ -328,7 +328,11 @@ def _load_python_engine(path: str, kind: str):
 # -------------------------------------------------------------- input modes
 
 
-async def run_http(args) -> None:
+async def run_http(args, built=None) -> None:
+    """Serve the OpenAI frontend until SIGINT/SIGTERM. ``built`` is an
+    already-built ``build_engine(args)`` result (chip_smoke.py builds the
+    engine itself so it can time the warm-up and inspect it afterwards);
+    None builds it here."""
     from .llm.engines import LocalChatChain, LocalCompletionChain
     from .llm.http.discovery import ModelWatcher
     from .llm.http.service import HttpService, ModelManager
@@ -344,11 +348,11 @@ async def run_http(args) -> None:
         watcher = ModelWatcher(drt, manager)
         await watcher.start()
     else:
-        engine, mdc, full = await asyncio.to_thread(build_engine, args)
+        engine, mdc, full = built or await asyncio.to_thread(build_engine,
+                                                            args)
         if full:
             manager.add_chat_model(mdc.name, engine)
         else:
-            pre = None
             chat = LocalChatChain(mdc, engine)
             comp = LocalCompletionChain(mdc, engine, chat.preprocessor)
             manager.add_chat_model(mdc.name, chat)
@@ -360,6 +364,15 @@ async def run_http(args) -> None:
             # signals; sheds nothing until DYN_SHED_* thresholds are set
             svc.set_admission(revive.AdmissionController(
                 lambda: revive.signals_from_stats(engine.stats())))
+        fence = getattr(engine, "fence", None)
+        if fence is not None:
+            # no aggregator scrapes an in-process engine, so its compile
+            # fence counter goes on this frontend's own /metrics under
+            # the aggregator's name for it
+            svc.metrics.add_source(lambda: [
+                "# TYPE dyn_engine_post_warmup_compiles_total counter",
+                "dyn_engine_post_warmup_compiles_total "
+                f"{fence.post_warmup_compiles}"])
         if hasattr(engine, "drain"):
             # POST /drain: stop admitting, finish in-flight bounded by
             # DYN_DRAIN_TIMEOUT_MS
@@ -621,7 +634,11 @@ async def _dispatch(args) -> int:
 
 def main(argv=None) -> int:
     logging.basicConfig(level=env_str("DYN_LOG"))
-    return asyncio.run(amain(parse_args(argv)))
+    args = parse_args(argv)
+    if args.output == "jax":
+        from .runtime.compile_cache import enable_compile_cache
+        log.info("compile cache at %s", enable_compile_cache())
+    return asyncio.run(amain(args))
 
 
 if __name__ == "__main__":

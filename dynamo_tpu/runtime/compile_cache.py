@@ -1,0 +1,45 @@
+"""Persistent XLA compile cache, placeable from outside.
+
+A cold warm-up compiles the whole bucket grid; a machine that keeps
+``JAX_COMPILATION_CACHE_DIR`` between runs gets it back for the price of
+reading files. The rule is one line: if that variable is set, JAX has
+already read it and this code sets no directory; otherwise the cache
+lives at ``<checkout>/.jax_cache`` — a fixed path derived from where
+this package sits (the path is part of the cache's key, so a directory
+named after a pid, a time or a temp file would never hit). The
+checkout's own path is kept OUT of the key, so a cache placed from
+outside is found again by another checkout of the same code.
+"""
+
+from __future__ import annotations
+
+import os
+import re
+
+from .config import env_str
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Switch the persistent compile cache on; returns the directory in
+    use. Call before the first jit of any process that serves or
+    benches (run.py, the SDK's TPU workers, bench.py, chip_smoke.py)."""
+    import jax
+
+    path = env_str("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    # source locations ride into the cache key inside the Pallas kernel's
+    # serialized module (the tpu_custom_call's opaque config): without
+    # this every kernel-bearing program misses from a checkout at another
+    # path (measured: 532 s of warm-up with the whole cache present)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(_CHECKOUT + os.sep))
+    # the warm grid is mostly programs that compile in under a second
+    # each; JAX's default would skip caching exactly those
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path

@@ -358,12 +358,8 @@ register_env("DYN_PROFILE_DIR", None, "run",
              "Capture a JAX/XLA profiler trace of the serving session "
              "into this directory.")
 
-register_env("DYN_BENCH_PROBE_TIMEOUT", "240", "bench",
-             "bench.py: seconds allowed for the server-readiness probe.")
 register_env("DYN_BENCH_REQ_TIMEOUT", "600", "bench",
              "bench.py: per-request timeout in seconds.")
-register_env("DYN_BENCH_WALL_BUDGET", "3000", "bench",
-             "bench.py: total wall-clock budget in seconds.")
 
 register_env("DYN_TEST_TPU", None, "tests",
              "Set to run the test suite against real TPU hardware instead "
@@ -385,6 +381,10 @@ register_env("KUBERNETES_SERVICE_HOST", None, "external",
              "operator's InClusterClient.")
 register_env("KUBERNETES_SERVICE_PORT", "443", "external",
              "In-cluster apiserver port.")
+register_env("JAX_COMPILATION_CACHE_DIR", None, "external",
+             "JAX's persistent compile cache directory. Set: JAX reads "
+             "it and the code sets no directory. Unset: "
+             "runtime.compile_cache uses <checkout>/.jax_cache.")
 register_env("JAX_PLATFORMS", None, "external",
              "JAX backend selector; the SDK/bench pin control-plane "
              "processes to cpu so only TPU workers touch the chip.")

@@ -156,6 +156,11 @@ async def cmd_serve_worker(args) -> int:
         raise SystemExit(f"service {args.service!r} not in graph of "
                          f"{args.target}")
     cfg = ServiceConfig.from_env()
+    if svc.resources.get("tpu"):
+        # before the worker's first jit (control-plane services never
+        # compile, and stay off jax)
+        from ..runtime.compile_cache import enable_compile_cache
+        enable_compile_cache()
     runtime = await asyncio.to_thread(Runtime)
     loop = asyncio.get_running_loop()
     for sig in (signal.SIGINT, signal.SIGTERM):

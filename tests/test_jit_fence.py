@@ -209,30 +209,34 @@ def test_fence_zero_compiles_mixed_workload(caplog):
                      temperature=0.9, seed=7),          # sampled fallback
                 _req(list(range(50, 55)), mt=4)]        # short row
         out = await asyncio.gather(*(one(r) for r in reqs))
+        served = eng.fence.post_warmup_compiles
+        # an intentionally unbucketed call trips warn mode — while the
+        # engine is live: stop() disarms the fence (a stopped engine's
+        # fence must not count the next engine's warm-up)
+        eng.fence._mode_override = "warn"
+        with caplog.at_level(logging.WARNING, "dynamo_tpu.engine.fence"):
+            jax.jit(lambda x: x - 3)(jnp.zeros((11,)))
         await eng.stop()
-        return out
+        return out, served
 
-    results = asyncio.run(main())
+    results, served = asyncio.run(main())
     assert all(len(r) >= 4 for r in results)
-    assert eng.fence.post_warmup_compiles == 0, (
+    assert served == 0, (
         "the zero-compile serving invariant broke: a jitted engine entry "
         "compiled mid-serving (run with jax_log_compiles to locate it)")
-    assert eng.stats()["post_warmup_compiles_total"] == 0
     # the engine's dispatch wrapper stamped real step-fn call forms, so
     # any trip above would have named the offending form
     assert eng.fence.last_dispatch_form().split("(")[0] in {
         "prefill_fn", "decode_fn", "decode_multi_fn", "verify_fn",
         "long_prefill_fn"}
-
-    # an intentionally unbucketed call trips warn mode
-    eng.fence._mode_override = "warn"
-    with caplog.at_level(logging.WARNING, "dynamo_tpu.engine.fence"):
-        jax.jit(lambda x: x - 3)(jnp.zeros((11,)))
     assert eng.fence.post_warmup_compiles >= 1
     assert eng.stats()["post_warmup_compiles_total"] >= 1
+    assert not eng.fence.armed, "stop() disarms the fence"
+    before = eng.fence.post_warmup_compiles
+    jax.jit(lambda x: x - 5)(jnp.zeros((13,)))
+    assert eng.fence.post_warmup_compiles == before
     assert any("XLA compile after warmup" in r.message
                for r in caplog.records)
-    eng.fence.disarm()
 
 
 def test_warmup_covers_host_tier_programs():

@@ -50,7 +50,7 @@ def test_host_pool_dtype_without_device_roundtrip():
                          seed=0, dtype=jnp.bfloat16)
     assert eng_bf16.host_k.dtype == np.dtype(jnp.bfloat16)
     # int8 tier default-on: host pools hold quantized pages regardless
-    # of the device dtype (halved relay bytes; identity pinned in
+    # of the device dtype (halved host-link bytes; identity pinned in
     # tests/test_kv_offload.py)
     eng_i8 = mk_engine(host_pages=8, num_pages=16)
     assert eng_i8.ecfg.host_tier_int8 is True

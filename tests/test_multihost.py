@@ -41,7 +41,7 @@ def test_two_process_spmd_forward():
     finally:
         for p in procs:
             if p.poll() is None:
-                p.terminate()  # SIGTERM only (relay discipline)
+                p.terminate()
     for rc, out, err in outs:
         assert rc == 0, f"worker failed rc={rc}\n{out}\n{err[-3000:]}"
         assert "MULTIHOST-OK" in out, out

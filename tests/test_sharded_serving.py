@@ -197,6 +197,61 @@ def test_sharded_engine_token_identity_and_fence(run_async):
     assert st["mesh_devices"] == 2
 
 
+def test_one_device_replicas_live_on_their_own_devices(run_async):
+    """DYN_DP_REPLICAS=N with one chip each: every replica's params, KV
+    pool and step outputs live on ITS device (spec.devices[0]), not the
+    process default — on one CPU nobody could tell, on virtual devices
+    the arrays say where they are. Tokens stay identical to the plain
+    engine and the compile fence holds on the non-default device (the
+    committed-carry warm-up variants cover one-device meshes too)."""
+    import jax
+    import numpy as np
+
+    from dynamo_tpu.engine.jax_engine import JaxEngine
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.parallel.serving import (build_replica_engine,
+                                             plan_replicas)
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs the forced multi-device CPU host")
+    cfg = ModelConfig.tiny()
+    rng = np.random.RandomState(11)
+    prompts = [rng.randint(1, 400, int(n)).tolist()
+               for n in rng.randint(8, 30, size=4)]
+    specs = plan_replicas({}, 2, jax.devices()[1:3])
+    engines = [build_replica_engine(cfg, _tiny_ecfg(), s, seed=3)
+               for s in specs]
+    for spec, eng in zip(specs, engines):
+        want = {spec.devices[0]}
+        assert eng.device == spec.devices[0]
+        assert eng.kv_k.devices() == want and eng.kv_v.devices() == want
+        assert all(leaf.devices() == want
+                   for leaf in jax.tree.leaves(eng.params))
+    assert engines[0].kv_k.devices() != engines[1].kv_k.devices()
+
+    async def serve(engine):
+        outs = await asyncio.gather(
+            *(_collect(engine, p, n=6) for p in prompts))
+        outs += await asyncio.gather(
+            *(_collect(engine, p, n=6) for p in prompts[:2]))
+        # the pool is rebound to each step's output: still on the device
+        where = engine.kv_k.devices()
+        await engine.stop()
+        return outs, where
+
+    control = JaxEngine(cfg, _tiny_ecfg(), seed=3)
+    control.warmup()
+    want_toks, _ = run_async(serve(control))
+    for spec, eng in zip(specs, engines):
+        # compiles are process-global: the later warm-ups above already
+        # counted on the earlier fences, so judge serving by its delta
+        before = eng.fence.post_warmup_compiles
+        got, where = run_async(serve(eng))
+        assert got == want_toks
+        assert where == {spec.devices[0]}
+        assert eng.fence.post_warmup_compiles == before
+
+
 def test_replica_identity_in_cost_block(run_async):
     """The PR 10 per-request cost block names the replica/submesh that
     served the request (the /v1/traces/{rid} surface)."""

@@ -1,0 +1,350 @@
+"""Compile the main path's kernels and step programs for a DESCRIBED
+v5e, at the widths the chip serves. Nothing runs: the TPU compiler is
+installed here and refuses what the attached chip would refuse (a block
+shape the tiling cannot hold, too much VMEM, a program past HBM), which
+interpret-mode tests cannot see — the flash prefill kernel passed every
+interpret test while no width of it compiled.
+
+The topology is described inside a module-scoped fixture (never at
+import, never autouse): only the xdist worker that is handed this file
+loads libtpu, and every worker collects the same tests. The persistent
+compile cache is off around these compiles — an entry written for a
+described device cannot be read back without one.
+"""
+
+import os
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import (NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from dynamo_tpu.models import llama
+from dynamo_tpu.ops import paged_attention as pa
+
+# the two GQA shapes the repo's presets serve (run.py --model 1b | 8b)
+WIDTHS = {"1b": dict(H=32, KV=8, hd=64), "8b": dict(H=32, KV=8, hd=128)}
+PS, NUM_PAGES, L = 64, 512, 16     # EngineConfig defaults, 1B depth
+B_DEC, P_DEC = 32, 64              # decode batch bucket x page bucket
+B_PRE, P_PRE = 8, 64               # max_prefill_batch x page bucket
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_kernel_path(monkeypatch):
+    """The model code picks the kernel by asking jax.default_backend(),
+    which sees the CPU here; steer it from the test (not a new option)."""
+    monkeypatch.delenv("DYN_DISABLE_PALLAS", raising=False)
+    monkeypatch.delenv("DYN_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(llama, "_use_pallas", lambda: True)
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _has_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------------------ decode kernel
+
+
+@pytest.mark.parametrize("softcap", [None, 50.0], ids=["nocap", "softcap"])
+@pytest.mark.parametrize("lower", [False, True], ids=["full", "window"])
+@pytest.mark.parametrize("stats", [False, True], ids=["out", "stats"])
+@pytest.mark.parametrize("entry", ["plain", "layered"])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_decode_kernel_compiles(one_chip, width, entry, stats, lower,
+                                softcap):
+    w = WIDTHS[width]
+    s = partial(_sds, one_chip)
+    q = s((B_DEC, w["H"], w["hd"]), jnp.bfloat16)
+    pool = (NUM_PAGES, w["KV"], PS, w["hd"])
+    table = s((B_DEC, P_DEC), jnp.int32)
+    lengths = s((B_DEC,), jnp.int32)
+    lo = lengths if lower else None
+    if entry == "plain":
+        k = s(pool, jnp.bfloat16)
+        lowered = pa.paged_attention_decode.lower(
+            q, k, k, table, lengths, return_stats=stats, softcap=softcap,
+            lower=lo)
+    else:
+        k = s((L,) + pool, jnp.bfloat16)
+        lowered = pa.paged_attention_decode_layered.lower(
+            q, k, k, s((), jnp.int32), table, lengths, return_stats=stats,
+            softcap=softcap, lower=lo)
+    assert _has_kernel(lowered.compile())
+
+
+# ----------------------------------------------------------- prefill kernel
+
+
+@pytest.mark.parametrize("softcap,window", [(None, False), (50.0, True)],
+                         ids=["plain", "softcap-window"])
+@pytest.mark.parametrize("T", [128, 512])
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_prefill_kernel_compiles(one_chip, width, T, softcap, window):
+    """The DYN_PREFILL_PALLAS kernel: refused at every width until its
+    blocks were made 2-D (rows = token x group-head, head dim in lanes)."""
+    w = WIDTHS[width]
+    s = partial(_sds, one_chip)
+    q = s((B_PRE, T, w["H"], w["hd"]), jnp.bfloat16)
+    k = s((NUM_PAGES, w["KV"], PS, w["hd"]), jnp.bfloat16)
+    lowered = pa.paged_attention_prefill.lower(
+        q, k, k, s((B_PRE, P_PRE), jnp.int32), s((B_PRE, T), jnp.int32),
+        softcap=softcap,
+        eff_win=s((B_PRE,), jnp.int32) if window else None)
+    assert _has_kernel(lowered.compile())
+
+
+def test_sharded_kernels_compile_on_four_chips(topo):
+    """The tensor-parallel wrappers (shard_map over the head axis) on the
+    described 2x2: what `chip_smoke.py --chips 4` runs under model=4."""
+    from dynamo_tpu.parallel.mesh import MeshSpec
+
+    w = WIDTHS["1b"]
+    mesh = MeshSpec(model=4).build(list(topo.devices))
+
+    def s(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    q = s((B_DEC, w["H"], w["hd"]), jnp.bfloat16, P(None, "model", None))
+    pools = s((L, NUM_PAGES, w["KV"], PS, w["hd"]), jnp.bfloat16,
+              P(None, None, "model", None, None))
+    table = s((B_DEC, P_DEC), jnp.int32, P())
+    lengths = s((B_DEC,), jnp.int32, P())
+    dec = jax.jit(partial(pa.paged_attention_decode_sharded, mesh=mesh)
+                  ).lower(q, pools, pools, s((), jnp.int32, P()), table,
+                          lengths).compile()
+    assert _has_kernel(dec)
+    T = 128
+    qp = s((B_PRE, T, w["H"], w["hd"]), jnp.bfloat16,
+           P(None, None, "model", None))
+    pool = s((NUM_PAGES, w["KV"], PS, w["hd"]), jnp.bfloat16,
+             P(None, "model", None, None))
+    pre = jax.jit(partial(pa.paged_attention_prefill_sharded, mesh=mesh)
+                  ).lower(qp, pool, pool, s((B_PRE, P_PRE), jnp.int32, P()),
+                          s((B_PRE, T), jnp.int32, P())).compile()
+    assert _has_kernel(pre)
+
+
+# ------------------------------------------- whole step programs, 1B preset
+
+
+def _preset_1b():
+    import types
+
+    from dynamo_tpu.run import build_model_config
+
+    return build_model_config(types.SimpleNamespace(model="1b",
+                                                    model_path=None))
+
+
+def _engine_shapes(cfg, sharding):
+    """params + KV pools exactly as JaxEngine.__init__ builds them, as
+    shapes: nothing can be put on a described device."""
+    params = jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    kv_k, kv_v = jax.eval_shape(
+        lambda: llama.init_kv_cache(cfg, llama.KVCacheSpec(NUM_PAGES, PS)))
+    return (_on(sharding, params), _on(sharding, kv_k),
+            _on(sharding, kv_v))
+
+
+@pytest.mark.parametrize("logprobs_topn", [0, 20], ids=["plain", "logprobs"])
+def test_decode_window_program_compiles(one_chip, tpu_kernel_path,
+                                        logprobs_topn):
+    """One warmed bucket of the fused decode window as JaxEngine builds
+    it (decode_steps=4, B=32, P=64): kernel inside, fits the chip."""
+    cfg = _preset_1b()
+    params, kv_k, kv_v = _engine_shapes(cfg, one_chip)
+    fn = llama.make_decode_window_fn(cfg, True, 64)
+    s = partial(_sds, one_chip)
+    B = B_DEC
+    i32 = s((B,), jnp.int32)
+    f32 = s((B,), jnp.float32)
+    compiled = fn.lower(
+        params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+        s((B, P_DEC), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+        s((B, 8), jnp.int32), None, k_steps=4,
+        logprobs_topn=logprobs_topn).compile()
+    assert _has_kernel(compiled)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 1024 ** 3)
+
+
+@pytest.mark.parametrize("flash", [False, True], ids=["xla", "flash"])
+def test_prefill_program_compiles(one_chip, tpu_kernel_path, monkeypatch,
+                                  flash):
+    """One warmed bucket of chunked prefill (PB=8, T=512, P=64), on the
+    default XLA gather path and with DYN_PREFILL_PALLAS."""
+    if flash:
+        monkeypatch.setenv("DYN_PREFILL_PALLAS", "1")
+    else:
+        monkeypatch.delenv("DYN_PREFILL_PALLAS", raising=False)
+    cfg = _preset_1b()
+    params, kv_k, kv_v = _engine_shapes(cfg, one_chip)
+    prefill, _ = llama.make_step_fns(cfg)
+    s = partial(_sds, one_chip)
+    PB, T = B_PRE, 512
+    compiled = prefill.lower(
+        params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k, kv_v,
+        s((PB, P_PRE), jnp.int32), s((PB, T), jnp.int32),
+        s((PB,), jnp.int32), s((PB, T // PS), jnp.int32)).compile()
+    assert _has_kernel(compiled) == flash
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 1024 ** 3)
+
+
+def test_decode_window_program_compiles_model4(topo, tpu_kernel_path):
+    """The model=4 engine's window (KV=8 divides): the kernel under
+    shard_map plus the collectives GSPMD inserts around it."""
+    from dynamo_tpu.parallel.mesh import (MeshSpec, kv_cache_pspec,
+                                          param_pspecs)
+
+    cfg = _preset_1b()
+    mesh = MeshSpec(model=4).build(list(topo.devices))
+    rep = NamedSharding(mesh, P())
+    params, kv_k, kv_v = _engine_shapes(cfg, rep)
+    specs = param_pspecs(cfg)
+    params = {k: jax.ShapeDtypeStruct(
+        v.shape, v.dtype, sharding=NamedSharding(
+            mesh, specs.get(k, P(*([None] * len(v.shape))))))
+        for k, v in params.items()}
+    kvs = NamedSharding(mesh, kv_cache_pspec(cfg))
+    kv_k, kv_v = _on(kvs, kv_k), _on(kvs, kv_v)
+    fn = llama.make_decode_window_fn(cfg, True, 64, mesh=mesh)
+    s = partial(_sds, rep)
+    B = B_DEC
+    i32 = s((B,), jnp.int32)
+    f32 = s((B,), jnp.float32)
+    compiled = fn.lower(
+        params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+        s((B, P_DEC), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+        s((B, 8), jnp.int32), None, k_steps=4, logprobs_topn=0).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text or "all-gather" in text
+
+
+# -------------------- MLA + routed experts at Moonlight-16B-A3B's widths
+
+
+# moonshotai/Moonlight-16B-A3B config.json (the model-configs catalog
+# row), every width as published; depth cut to the dense layer plus one
+# expert layer. ROADMAP B1's first cell starts from here.
+MOONLIGHT = {
+    "model_type": "deepseek_v3", "vocab_size": 163840, "hidden_size": 2048,
+    "intermediate_size": 11264, "moe_intermediate_size": 1408,
+    "num_hidden_layers": 2, "first_k_dense_replace": 1,
+    "num_attention_heads": 16, "num_key_value_heads": 16,
+    "kv_lora_rank": 512, "q_lora_rank": None, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128, "n_routed_experts": 64,
+    "n_shared_experts": 2, "num_experts_per_tok": 6, "n_group": 1,
+    "topk_group": 1, "norm_topk_prob": True, "scoring_func": "sigmoid",
+    "topk_method": "noaux_tc", "routed_scaling_factor": 2.446,
+    "rope_theta": 50000, "rms_norm_eps": 1e-05, "hidden_act": "silu",
+    "tie_word_embeddings": False, "max_position_embeddings": 8192,
+}
+
+
+@pytest.mark.parametrize("step", ["prefill", "decode"])
+def test_mla_moe_step_compiles_at_moonlight_widths(one_chip, step):
+    from dynamo_tpu.models import mla
+    from dynamo_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig.from_hf_config(dict(MOONLIGHT))
+    assert cfg.is_mla and cfg.num_experts == 64 and cfg.num_layers == 2
+    params = _on(one_chip, jax.eval_shape(
+        lambda: mla.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (_on(one_chip, x) for x in jax.eval_shape(
+        lambda: mla.init_kv_cache(cfg, llama.KVCacheSpec(NUM_PAGES, PS))))
+    prefill, decode = mla.make_step_fns(cfg)
+    s = partial(_sds, one_chip)
+    if step == "prefill":
+        PB, T = B_PRE, 512
+        lowered = prefill.lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P_PRE), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32))
+    else:
+        B = B_DEC
+        lowered = decode.lower(
+            params, s((B,), jnp.int32), s((B,), jnp.int32), kv_k, kv_v,
+            s((B, P_DEC), jnp.int32), s((B,), jnp.int32))
+    mem = lowered.compile().memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 1024 ** 3)
+
+
+def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
+    """The Pallas kernel's serialized module rides inside the
+    tpu_custom_call's opaque config, source locations included: without
+    the canonicalization that enable_compile_cache() sets, every
+    kernel-bearing program's cache key depends on where the checkout
+    lies (a run from an unpacked `git archive` recompiled everything)."""
+    import base64
+    import re
+
+    from dynamo_tpu.runtime import compile_cache
+
+    s = partial(_sds, one_chip)
+    w = WIDTHS["1b"]
+
+    def kernel_body(B) -> bytes:
+        # a batch size of its own per call: traces and kernel lowerings
+        # are cached per shape within a process
+        k = s((NUM_PAGES, w["KV"], PS, w["hd"]), jnp.bfloat16)
+        text = pa.paged_attention_decode.lower(
+            s((B, w["H"], w["hd"]), jnp.bfloat16), k, k,
+            s((B, P_DEC), jnp.int32), s((B,), jnp.int32)).compiler_ir() \
+            .operation.get_asm(enable_debug_info=False)
+        body = re.search(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+        return base64.b64decode(body.group(1))
+
+    checkout = compile_cache._CHECKOUT.encode()
+    knob = "jax_hlo_source_file_canonicalization_regex"
+    was = getattr(jax.config, knob)
+    try:
+        jax.config.update(knob, None)  # an earlier test may have set it
+        assert checkout in kernel_body(24), "the path rides in by default"
+        jax.config.update(knob, re.escape(compile_cache._CHECKOUT + os.sep))
+        body = kernel_body(40)
+        assert checkout not in body and b"paged_attention.py" in body
+    finally:
+        jax.config.update(knob, was)

@@ -10,7 +10,8 @@ into that quote::
 
 Accepts either a full BENCH-shaped record (``detail.bucket_cost``) or a
 bare ``{"bucket_cost": {...}}`` / ``{bucket: {...}}`` mapping, so it also
-diffs the ``bench_results/*.json`` files chip_session.sh leaves behind.
+diffs the ``bench_results/*.json`` reports of the A/B invocations listed
+in ROADMAP A6.
 """
 
 from __future__ import annotations
