@@ -32,6 +32,7 @@ import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
+import functools
 from functools import partial
 from typing import AsyncIterator, Dict, List, Optional, Tuple
 
@@ -65,6 +66,22 @@ def _cancel_reason(ctx: Context) -> str:
     ("cancelled"). Either way the sequence is terminated on the cancel
     path and its pages free immediately."""
     return FINISH_TIMEOUT if ctx.expired else FINISH_CANCELLED
+
+
+def _phased(name: str):
+    """Run the method inside the step-thread phase ``name``
+    (engine/profiler.py: the phase ledger + a ``dyn.<name>`` trace
+    annotation; phases entered further in take the clock over)."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(self, *args, **kwargs):
+            with self.profiler.phase(name):
+                return fn(self, *args, **kwargs)
+
+        return run
+
+    return deco
 
 
 def _stamp_dispatch(fence: CompileFence, name: str, fn):
@@ -315,6 +332,16 @@ class Sequence:
     # share (each dispatch distributes exactly 1.0 across its batch, so
     # fleet-wide shares sum to the dispatch count); peak page footprint
     queue_wait_s: float = 0.0
+    # the TTFT split, beside `arrival` (monotonic; None until reached):
+    # admission, the first prefill dispatch that carries a chunk of the
+    # prompt, the first token-bearing emission. A preempted sequence
+    # keeps the stamps of its first pass.
+    t_admit: Optional[float] = None
+    t_first_dispatch: Optional[float] = None
+    t_first_token: Optional[float] = None
+    # wire ctx of the span ambient in generate() (None = not sampled):
+    # parent of the engine.* spans recorded at finish
+    trace_ctx: Optional[dict] = None
     prefix_hit: int = 0
     dispatch_share: float = 0.0
     dispatches: int = 0
@@ -655,7 +682,21 @@ class JaxEngine:
         # conservation invariant: sum of per-request shares == this)
         self.batch_dispatches_total = 0
         self.queue_wait_seconds_total = 0.0
+        # the TTFT split, summed at each request's first emission:
+        # queue_wait + prefill_wait + first_token == engine_ttft
+        self.prefill_wait_seconds_total = 0.0
+        self.first_token_seconds_total = 0.0
+        self.engine_ttft_seconds_total = 0.0
+        self.first_tokens_total = 0
         self.prefill_tokens_total = 0
+        # fill counters, added where the slots are chosen: real prompt
+        # tokens / rows against the slots of the bucket that ran
+        self.prefill_slots_total = 0
+        self.prefill_dispatches_total = 0
+        self.decode_rows_total = 0
+        self.decode_slots_total = 0
+        self.decode_windows_total = 0
+        self.warmup_seconds = 0.0
         # iterations where a decode window dispatched WHILE prompts were
         # still prefilling — the observable for budgeted mixing
         self.mixed_dispatches = 0
@@ -976,8 +1017,9 @@ class JaxEngine:
         # arm the runtime compile fence: from here on, ANY XLA compile is
         # a serving stall — counted always, warn/raise per DYN_JIT_FENCE
         self.fence.arm()
+        self.warmup_seconds = time.monotonic() - t0
         log.info("warmup compiled %d programs in %.1fs", n,
-                 time.monotonic() - t0)
+                 self.warmup_seconds)
         return n
 
     def start(self) -> None:
@@ -1053,7 +1095,8 @@ class JaxEngine:
                 span.set_attribute("mesh_shape", self.mesh_shape)
         seq = Sequence(req=request, context=context, out=asyncio.Queue(),
                        tokens=list(request.token_ids),
-                       num_prompt=len(request.token_ids))
+                       num_prompt=len(request.token_ids),
+                       trace_ctx=tracing.get_tracer().current_trace_ctx())
         if seq.num_prompt == 0:
             yield EngineOutput(finish_reason="error", text="empty prompt")
             return
@@ -1106,6 +1149,22 @@ class JaxEngine:
             "num_requests_waiting": len(self.waiting),
             "queue_wait_seconds_total": round(self.queue_wait_seconds_total,
                                               4),
+            # where the step thread's time went (engine/profiler.py
+            # PHASES; sums to the wall time) and the TTFT split
+            "step_phase_seconds_total": self.profiler.phase_snapshot(),
+            "step_iterations_total": self.profiler.step_iterations,
+            "prefill_wait_seconds_total": self.prefill_wait_seconds_total,
+            "first_token_seconds_total": self.first_token_seconds_total,
+            "engine_ttft_seconds_total": self.engine_ttft_seconds_total,
+            "first_tokens_total": self.first_tokens_total,
+            # slot fill: real tokens / rows against the bucket that ran
+            "prefill_tokens_total": self.prefill_tokens_total,
+            "prefill_slots_total": self.prefill_slots_total,
+            "prefill_dispatches_total": self.prefill_dispatches_total,
+            "decode_rows_total": self.decode_rows_total,
+            "decode_slots_total": self.decode_slots_total,
+            "decode_windows_total": self.decode_windows_total,
+            "warmup_seconds": self.warmup_seconds,
             "gpu_cache_usage_perc": self.pm.usage(),
             # dynacache: the headline rate is WINDOWED (last
             # DYN_CACHE_WINDOW admissions) so the aggregator gauge tracks
@@ -1200,6 +1259,7 @@ class JaxEngine:
             if not (self.waiting or self.prefilling or self.running
                     or self._inflight or self._pending_prefill):
                 self._wake.clear()
+                self.profiler.slept = True   # the gap to the next step is idle
                 await self._wake.wait()
                 continue
             if guard.chaos() is not None:
@@ -1230,12 +1290,21 @@ class JaxEngine:
                 log.exception("pipeline flush on stop failed")
 
     def _step(self) -> None:
-        """One scheduler iteration (executor thread). Pipelined mode
-        enqueues the next decode window AND the next prefill chunk before
-        reading back the previous window/prefill, so the host round-trip
-        (the dominant cost on dispatch-latency-bound setups) overlaps
-        device compute. Unpipelined modes keep the reference-equivalent
-        prefill-priority ordering."""
+        """One scheduler iteration (executor thread), inside the phase
+        ledger: the gap since the last iteration is settled on entry, and
+        whatever of the iteration no phase names is ``other``."""
+        self.profiler.step_begin()
+        try:
+            self._step_phases()
+        finally:
+            self.profiler.step_end()
+
+    def _step_phases(self) -> None:
+        """Pipelined mode enqueues the next decode window AND the next
+        prefill chunk before reading back the previous window/prefill, so
+        the host round-trip (the dominant cost on dispatch-latency-bound
+        setups) overlaps device compute. Unpipelined modes keep the
+        reference-equivalent prefill-priority ordering."""
         self.profiler.tick()  # dynaprof: one compare at sample=0
         self._drain_kv_tier()
         if self.verify_fn is not None:
@@ -1408,7 +1477,8 @@ class JaxEngine:
                 # _unrestored_pages gate in _dispatch_prefill
                 seq.restore_t0 = time.monotonic()
             if seq.generated == 0:  # don't double-count resumed sequences
-                wait = time.monotonic() - seq.arrival
+                seq.t_admit = time.monotonic()
+                wait = seq.t_admit - seq.arrival
                 self.queue_wait_seconds_total += wait
                 seq.queue_wait_s = wait
                 self.latency.observe("queue_wait", wait)
@@ -1433,9 +1503,10 @@ class JaxEngine:
         keeps the common no-waiters iteration at one compare."""
         if not self.waiting:
             return
-        at0 = self.profiler.begin()
-        self._admit()
-        self.profiler.end(at0, "admit", ("host",))
+        with self.profiler.phase("admit"):
+            at0 = self.profiler.begin()
+            self._admit()
+            self.profiler.end(at0, "admit", ("host",))
 
     # ------------------------------------------------------- KV tier drain
 
@@ -1485,6 +1556,10 @@ class JaxEngine:
         reserve/extract/inject)."""
         if self.host_k is None:
             return
+        with self.profiler.phase("kv_tier"):
+            self._drain_kv_tier_ops(full)
+
+    def _drain_kv_tier_ops(self, full: bool) -> None:
         chunk = None if full else (self.ecfg.tier_restore_chunk or None)
         # land the previous drain's staged restore batch FIRST: its H2D
         # overlapped the intervening step, so this inject is cheap
@@ -1530,7 +1605,7 @@ class JaxEngine:
             self._offload_inflight = keep
             self._land_inflight_offloads(harvest)
         if res:
-            rt0 = time.perf_counter()
+            rt0 = time.monotonic()
             pages = [p for p, _ in res]
             slots = [s for _, s in res]
             # pad the host gather with slot 0 (content discarded)
@@ -1567,14 +1642,14 @@ class JaxEngine:
             # and a dyntrace span per drained batch (dispatch time only;
             # no sync added — the copies land with the next device step).
             # Both are no-ops when their ring/sampling is off.
-            rdt = time.perf_counter() - rt0
+            rdt = time.monotonic() - rt0
             self.step_timeline.add(
                 "cache.restore", pages=len(res),
                 queued=len(self._unrestored_pages),
                 staged=int(overlap),
                 dispatch_ms=round(rdt * 1000.0, 3))
             tracing.get_tracer().record_span(
-                "cache.restore", rdt, parent=None,
+                "cache.restore", rdt, start=rt0, parent=None,
                 attributes={"pages": len(res), "staged": overlap,
                             "queued": len(self._unrestored_pages)})
 
@@ -1596,6 +1671,7 @@ class JaxEngine:
 
     # ------------------------------------------------------------- prefill
 
+    @_phased("dispatch_prefill")
     def _dispatch_prefill(self, token_budget: Optional[int] = None
                           ) -> Optional[_PendingPrefill]:
         """Enqueue one chunked-prefill step over a BATCH of prefilling
@@ -1722,6 +1798,9 @@ class JaxEngine:
                           tokens=int(sum(chunks)), sync_ref=logits)
         self._account_dispatch(batch)
         self.steps += 1
+        self.prefill_slots_total += B * T
+        self.prefill_dispatches_total += 1
+        self._stamp_first_dispatch(batch)
         self.step_timeline.add(
             "prefill", batch=len(batch), tokens=int(sum(chunks)),
             occupancy=len(self.running) + len(self.prefilling),
@@ -1772,6 +1851,9 @@ class JaxEngine:
         self.profiler.end(pt0, "long_prefill", (T,),
                           tokens=extent - seq.computed, sync_ref=logits)
         self._account_dispatch([seq])
+        self.prefill_slots_total += T
+        self.prefill_dispatches_total += 1
+        self._stamp_first_dispatch([seq])
         pages = np.asarray(seq.pages, np.int64)
         pos = np.arange(T)
         # positions below seq.computed are prefix-cache hits living in
@@ -1794,18 +1876,28 @@ class JaxEngine:
         self._commit_full_pages(seq)
         if seq.generated == 0:
             toks_d, aux_d = self._sample_device([seq], logits)
-            aux = (tuple(np.asarray(a) for a in aux_d)
-                   if aux_d is not None else None)
-            self._append_token(seq, int(np.asarray(toks_d)[0]),
-                               lp=self._lp_entry(seq, aux, 0))
-            if seq.finished is None:
-                # proto: request.lifecycle prefill->decode
-                self.running.append(seq)
+            with self.profiler.phase("readback_prefill"):
+                aux = (tuple(np.asarray(a) for a in aux_d)
+                       if aux_d is not None else None)
+                tok = int(np.asarray(toks_d)[0])
+            with self.profiler.phase("process_prefill"):
+                self._append_token(seq, tok, lp=self._lp_entry(seq, aux, 0))
+                if seq.finished is None:
+                    # proto: request.lifecycle prefill->decode
+                    self.running.append(seq)
         else:
             # resumed after preemption: next token already sampled
             seq.last_token = seq.tokens[-1]
             # proto: request.lifecycle prefill->decode
             self.running.append(seq)
+
+    def _stamp_first_dispatch(self, batch: List[Sequence]) -> None:
+        """TTFT split: the first prefill dispatch that carries a chunk
+        of each prompt (one clock read per dispatch, not per row)."""
+        now = time.monotonic()
+        for seq in batch:
+            if seq.t_first_dispatch is None:
+                seq.t_first_dispatch = now
 
     def _long_bucket(self, extent: int) -> int:
         """Padded length for the ring prefill: pow2 multiples of
@@ -1823,22 +1915,28 @@ class JaxEngine:
         if pf.processed:
             return
         pf.processed = True
-        toks = np.asarray(pf.sampled) if pf.sampled is not None else None
-        aux = (tuple(np.asarray(a) for a in pf.aux)
-               if pf.aux is not None else None)
-        for i, seq in pf.finishing:
-            self._commit_full_pages(seq)
-            if seq.generated == 0:
-                self._append_token(seq, int(toks[i]),
-                                   lp=self._lp_entry(seq, aux, i))
-                if seq.finished is None:
+        if not pf.finishing:
+            return      # a mid-prompt chunk: nothing to read back
+        with self.profiler.phase("readback_prefill"):
+            # the step thread blocked on the device: the prefill program
+            # and whatever was queued ahead of it
+            toks = np.asarray(pf.sampled) if pf.sampled is not None else None
+            aux = (tuple(np.asarray(a) for a in pf.aux)
+                   if pf.aux is not None else None)
+        with self.profiler.phase("process_prefill"):
+            for i, seq in pf.finishing:
+                self._commit_full_pages(seq)
+                if seq.generated == 0:
+                    self._append_token(seq, int(toks[i]),
+                                       lp=self._lp_entry(seq, aux, i))
+                    if seq.finished is None:
+                        # proto: request.lifecycle prefill->decode
+                        self.running.append(seq)
+                else:
+                    # resumed after preemption: last token already sampled
+                    seq.last_token = seq.tokens[-1]
                     # proto: request.lifecycle prefill->decode
                     self.running.append(seq)
-            else:
-                # resumed after preemption: last token already sampled
-                seq.last_token = seq.tokens[-1]
-                # proto: request.lifecycle prefill->decode
-                self.running.append(seq)
 
     # -------------------------------------------------------------- decode
 
@@ -1886,6 +1984,7 @@ class JaxEngine:
         # poisoning the host tier with spliced pages
         self._drain_kv_tier()
 
+    @_phased("dispatch_window")
     def _decode_step_single(self, batch: Optional[List[Sequence]] = None
                             ) -> None:
         """K=1 decode: one forward + sample per dispatch, synchronous.
@@ -1925,14 +2024,17 @@ class JaxEngine:
         self.profiler.end(pt0, "decode", (B, P), tokens=len(batch),
                           sync_ref=toks_d)
         self._account_dispatch(batch)
-        sampled = np.asarray(toks_d)[:len(batch)]
-        aux = (tuple(np.asarray(a) for a in aux_d)
-               if aux_d is not None else None)
+        self._count_decode_slots(batch, B, 1)
+        with self.profiler.phase("readback_window"):
+            sampled = np.asarray(toks_d)[:len(batch)]
+            aux = (tuple(np.asarray(a) for a in aux_d)
+                   if aux_d is not None else None)
         self.steps += 1
         self.decode_tokens_total += len(batch)
-        for i, (seq, tok) in enumerate(zip(batch, sampled)):
-            self._append_token(seq, int(tok),
-                               lp=self._lp_entry(seq, aux, i))
+        with self.profiler.phase("process_window"):
+            for i, (seq, tok) in enumerate(zip(batch, sampled)):
+                self._append_token(seq, int(tok),
+                                   lp=self._lp_entry(seq, aux, i))
         self.step_timeline.add(
             "decode", batch=len(batch), tokens=len(batch),
             occupancy=len(self.running) + len(self.prefilling),
@@ -2014,6 +2116,7 @@ class JaxEngine:
         return propose_ngram_draft(seq.tokens, k, self.ecfg.spec_ngram_max,
                                    self.ecfg.spec_ngram_min)
 
+    @_phased("dispatch_window")
     def _decode_step_spec(self, batch: List[Sequence],
                           drafts: Dict[int, List[int]]) -> None:
         """One batched multi-token verify: each row's input is [pending
@@ -2068,28 +2171,31 @@ class JaxEngine:
                           tokens=int(draft_len.sum()) + len(batch),
                           sync_ref=out_d)
         self._account_dispatch(batch)
-        out = np.asarray(out_d)  # host sync — the spec arm is synchronous
-        acc = np.asarray(acc_d)
+        with self.profiler.phase("readback_window"):
+            out = np.asarray(out_d)  # host sync — the spec arm is synchronous
+            acc = np.asarray(acc_d)
         self.steps += 1
         self.spec_steps += 1
         step_accepted = step_drafted = 0
-        for i, seq in enumerate(batch):
-            accepted = int(acc[i])
-            self.spec_draft_tokens_total += int(draft_len[i])
-            self.spec_accepted_tokens_total += accepted
-            step_drafted += int(draft_len[i])
-            step_accepted += accepted
-            for j in range(accepted + 1):
-                if seq.finished is not None or seq.context.stopped:
-                    break  # tokens past an accepted stop are discarded
-                self._append_token(seq, int(out[i, j]))
-                self.decode_tokens_total += 1
+        with self.profiler.phase("process_window"):
+            for i, seq in enumerate(batch):
+                accepted = int(acc[i])
+                self.spec_draft_tokens_total += int(draft_len[i])
+                self.spec_accepted_tokens_total += accepted
+                step_drafted += int(draft_len[i])
+                step_accepted += accepted
+                for j in range(accepted + 1):
+                    if seq.finished is not None or seq.context.stopped:
+                        break  # tokens past an accepted stop are discarded
+                    self._append_token(seq, int(out[i, j]))
+                    self.decode_tokens_total += 1
         self.step_timeline.add(
             "spec_verify", batch=len(batch), drafted=step_drafted,
             accepted=step_accepted,
             occupancy=len(self.running) + len(self.prefilling),
             waiting=len(self.waiting))
 
+    @_phased("dispatch_window")
     def _dispatch_decode_window(self, batch: Optional[List[Sequence]] = None
                                 ) -> Optional[_PendingWindow]:
         """Enqueue the next fused K-step decode window WITHOUT reading
@@ -2217,6 +2323,7 @@ class JaxEngine:
         self.profiler.end(pt0, "decode_window", (B, P, K),
                           tokens=len(batch) * K, sync_ref=toks)
         self._account_dispatch(batch)
+        self._count_decode_slots(batch, B, K)
         self.steps += 1
         pend = _PendingWindow(batch=list(batch), toks=toks,
                               emitted=emitted, carry=carry, aux=aux,
@@ -2233,48 +2340,60 @@ class JaxEngine:
         if pend.processed:
             return
         pend.processed = True
-        toks = np.asarray(pend.toks)
-        aux = (tuple(np.asarray(a) for a in pend.aux)
-               if pend.aux is not None else None)
         coalesce = self.ecfg.coalesce_window_emissions
-        if coalesce:
-            # outputs of the same program as toks — ready the moment toks
-            # is, so these reads add no extra device sync. carry is never
-            # donated (warmup's merge-combo loop reuses one), so reading
-            # done here is safe even with the next window in flight.
-            counts = np.asarray(pend.emitted)
-            done = np.asarray(pend.carry[2])
+        with self.profiler.phase("readback_window"):
+            # the step thread blocked on the device until this window
+            # (and whatever was queued ahead of it) has run
+            toks = np.asarray(pend.toks)
+            aux = (tuple(np.asarray(a) for a in pend.aux)
+                   if pend.aux is not None else None)
+            if coalesce:
+                # outputs of the same program as toks — ready the moment
+                # toks is, so these reads add no extra device sync. carry
+                # is never donated (warmup's merge-combo loop reuses one),
+                # so reading done here is safe even with the next window
+                # in flight.
+                counts = np.asarray(pend.emitted)
+                done = np.asarray(pend.carry[2])
         if pend in self._inflight:
             self._inflight.remove(pend)
         if self._pending is pend:
             self._pending = None
         K = toks.shape[1]
         emitted = 0
-        # host-segment bracket: pure bookkeeping time (emission, stop
-        # mirror, page publish) — the readback wait above is already
-        # visible as decode_window device_us
-        ht0 = self.profiler.begin()
-        for i, seq in enumerate(pend.batch):
-            if seq.finished is not None:
-                continue
-            if coalesce and not seq.context.stopped \
-                    and self._device_stops_complete(seq):
-                emitted += self._append_row(
-                    seq, toks[i], int(counts[i]), bool(done[i]), aux, i)
-                continue
-            for j in range(K):
-                if seq.finished is not None or seq.context.stopped:
-                    break  # tokens past EOS/stop are discarded
-                self._append_token(seq, int(toks[i, j]),
-                                   lp=self._lp_entry(seq, aux, i, j))
-                self.decode_tokens_total += 1
-                emitted += 1
-        self.profiler.end(ht0, "process_window", (len(pend.batch), K),
-                          tokens=emitted)
+        with self.profiler.phase("process_window"):
+            # pure bookkeeping: emission, stop mirror, page publish (the
+            # sampled bracket keeps its cost-table row)
+            ht0 = self.profiler.begin()
+            for i, seq in enumerate(pend.batch):
+                if seq.finished is not None:
+                    continue
+                if coalesce and not seq.context.stopped \
+                        and self._device_stops_complete(seq):
+                    emitted += self._append_row(
+                        seq, toks[i], int(counts[i]), bool(done[i]), aux, i)
+                    continue
+                for j in range(K):
+                    if seq.finished is not None or seq.context.stopped:
+                        break  # tokens past EOS/stop are discarded
+                    self._append_token(seq, int(toks[i, j]),
+                                       lp=self._lp_entry(seq, aux, i, j))
+                    self.decode_tokens_total += 1
+                    emitted += 1
+            self.profiler.end(ht0, "process_window", (len(pend.batch), K),
+                              tokens=emitted)
         self.step_timeline.add(
             "decode_window", batch=len(pend.batch), tokens=emitted,
             occupancy=len(self.running) + len(self.prefilling),
             waiting=len(self.waiting))
+
+    def _count_decode_slots(self, batch: List[Sequence], B: int,
+                            K: int) -> None:
+        """Fill counters of one decode dispatch: live rows x K steps
+        against the B x K slots of the bucket that ``_pick`` chose."""
+        self.decode_rows_total += len(batch) * K
+        self.decode_slots_total += B * K
+        self.decode_windows_total += 1
 
     def _device_stops_complete(self, seq: Sequence) -> bool:
         """True when the row's full stop-id set fit the on-device stop
@@ -2554,6 +2673,13 @@ class JaxEngine:
         prompt_blocks = (seq.num_prompt + ps - 1) // ps
         return {
             "queue_wait_ms": round(seq.queue_wait_s * 1000.0, 3),
+            # the TTFT split (None where the request never got there):
+            # admission -> first prefill dispatch -> first emission, and
+            # first -> last emission
+            "prefill_wait_ms": _span_ms(seq.t_admit, seq.t_first_dispatch),
+            "first_token_ms": _span_ms(seq.t_first_dispatch,
+                                       seq.t_first_token),
+            "decode_ms": _span_ms(seq.t_first_token, seq.last_emit_t),
             "device_step_share": round(seq.dispatch_share, 6),
             "dispatches": seq.dispatches,
             "prompt_tokens": seq.num_prompt,
@@ -2586,12 +2712,29 @@ class JaxEngine:
         # dynaslo e2e: arrival → finish emission (cancel/error finishes
         # included — a timed-out request IS a latency observation)
         self.latency.observe("e2e", time.monotonic() - seq.arrival)
+        if seq.trace_ctx is not None:
+            self._record_request_spans(seq)
         cost = self._attribution(seq)
         profiling.record_attribution(seq.context.id, cost)
         self._emit(seq, EngineOutput(token_ids=[], finish_reason=seq.finished,
                                      prompt_tokens=seq.num_prompt,
                                      completion_tokens=seq.generated,
                                      cost=cost))
+
+    def _record_request_spans(self, seq: Sequence) -> None:
+        """The request's path through the engine as child spans of the
+        span that was ambient in generate(): /v1/traces/{request_id}
+        lists them under ``stages`` and the span-end listener feeds the
+        stage histograms."""
+        tracer = tracing.get_tracer()
+        marks = (("engine.queue", seq.arrival, seq.t_admit),
+                 ("engine.prefill_wait", seq.t_admit, seq.t_first_dispatch),
+                 ("engine.prefill", seq.t_first_dispatch, seq.t_first_token),
+                 ("engine.decode", seq.t_first_token, seq.last_emit_t))
+        for name, start, end in marks:
+            if start is not None and end is not None:
+                tracer.record_span(name, end - start, start=start,
+                                   parent=seq.trace_ctx)
 
     def _emit(self, seq: Sequence, out: EngineOutput) -> None:
         if out.token_ids:
@@ -2602,6 +2745,16 @@ class JaxEngine:
             now = time.monotonic()
             if seq.last_emit_t is None:
                 self.latency.observe("ttft", now - seq.arrival)
+                seq.t_first_token = now
+                if seq.t_first_dispatch is not None:
+                    # the TTFT split of a request prefilled here; with
+                    # queue_wait these add up to engine_ttft exactly
+                    self.prefill_wait_seconds_total += (
+                        seq.t_first_dispatch - seq.t_admit)
+                    self.first_token_seconds_total += (
+                        now - seq.t_first_dispatch)
+                    self.engine_ttft_seconds_total += now - seq.arrival
+                    self.first_tokens_total += 1
             else:
                 n = len(out.token_ids)
                 self.latency.observe("itl", (now - seq.last_emit_t) / n, n)
@@ -2928,6 +3081,13 @@ def _make_decode_multi(model, cfg: ModelConfig, max_top_k: int,
         return out_toks, emitted, carry, kv_k, kv_v
 
     return decode_multi
+
+
+def _span_ms(start: Optional[float], end: Optional[float]
+             ) -> Optional[float]:
+    if start is None or end is None:
+        return None
+    return round((end - start) * 1000.0, 3)
 
 
 def _wants_count_state(s) -> bool:

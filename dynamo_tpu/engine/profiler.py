@@ -1,4 +1,21 @@
-"""dynaprof engine layer: sampled device/host split + per-bucket cost.
+"""dynaprof engine layer: always-on step-thread phases, plus the sampled
+device/host split + per-bucket cost.
+
+Two halves. The HOST half is always on: every second of the engine's
+step thread belongs to exactly one named phase (``PHASES``), kept as a
+ledger — entering a phase closes the interval of the one around it, so
+nested brackets (a pipeline flush inside a window dispatch) stay
+disjoint and the phases sum to the thread's wall time by construction.
+Each bracket costs two ``perf_counter`` reads and one
+``jax.profiler.TraceAnnotation("dyn.<phase>")``, which is an atomic load
+while no profiler session is open and, while one is, puts the phase on
+the step thread's line of ``/host:CPU`` on the device trace's clock.
+``stats()["step_phase_seconds_total"]`` carries the ledger.
+
+The DEVICE half is the sampled sync below, off by default. Under
+pipelining its "device time" is a host-clock drain of everything queued
+before the sampled dispatch; device time proper is read from a profiler
+trace (benchmark/harness/trace.py).
 
 The serving loop's time goes three places: device compute, host dispatch
 (Python building arrays + enqueueing the jitted call), and event-loop /
@@ -33,16 +50,57 @@ import time
 from typing import Dict, Optional, Tuple
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from ..runtime import profiling
 from ..runtime.config import env_int
 
 
+# exhaustive and disjoint on the step thread (docs/profiling.md has the
+# table of what each covers); "other" is what of _step no bracket names
+PHASES = ("admit", "kv_tier", "dispatch_window", "dispatch_prefill",
+          "readback_window", "process_window", "readback_prefill",
+          "process_prefill", "between_steps", "idle", "other")
+# ledger slot of the time between two _step calls, settled into
+# between_steps or idle when the next _step (or a stats() read) arrives
+_GAP = "_gap"
+
+
+def _settle_gap(seconds: Dict[str, float], slept: bool) -> None:
+    seconds["idle" if slept else "between_steps"] += seconds[_GAP]
+    seconds[_GAP] = 0.0
+
+
+class _Phase:
+    """One phase's re-usable bracket (``with profiler.phase(name):``).
+    The ledger lives on the profiler; a bracket only parks, on the
+    profiler's stack, the phase to hand the clock back to and its open
+    trace annotation."""
+
+    __slots__ = ("prof", "name", "label")
+
+    def __init__(self, prof: "EngineProfiler", name: str):
+        self.prof = prof
+        self.name = name
+        self.label = "dyn." + name
+
+    def __enter__(self) -> None:
+        stack = self.prof._open
+        stack.append(self.prof._switch(self.name))
+        stack.append(TraceAnnotation(self.label))
+
+    def __exit__(self, *exc) -> None:
+        stack = self.prof._open
+        stack.pop().__exit__(*exc)
+        self.prof._switch(stack.pop())
+
+
 class EngineProfiler:
-    """Per-engine sampled dispatch timer + cost table. All mutation
-    happens on the engine's single-worker executor thread (the same
-    serialization the scheduler itself relies on); ``summary()`` reads
-    are snapshot-style dict builds."""
+    """Per-engine step-phase ledger + sampled dispatch timer + cost
+    table. All mutation happens on the engine's single-worker executor
+    thread (the same serialization the scheduler itself relies on);
+    ``summary()`` and ``phase_snapshot()`` reads are snapshot-style dict
+    builds."""
 
     def __init__(self, name: str, timeline=None,
                  sample: Optional[int] = None):
@@ -58,7 +116,67 @@ class EngineProfiler:
         self.dispatch_seconds_total = 0.0
         # "kind:B8xP64[xT512|xK4]" -> {samples, device_us, dispatch_us, tokens}
         self.buckets: Dict[str, dict] = {}
+        # the always-on phase ledger: seconds per phase, the phase the
+        # clock is running for, and when it started running
+        self.phase_seconds: Dict[str, float] = dict.fromkeys(
+            PHASES + (_GAP,), 0.0)
+        self.step_iterations = 0
+        self.slept = False      # _loop waited on _wake since the last step
+        self._phases = {n: _Phase(self, n) for n in PHASES}
+        self._open: list = []   # outer phase, annotation per open bracket
+        self._step_ann = None
+        self._cur = _GAP
+        self._t = time.perf_counter()
+        self._ver = 0           # odd while _switch is mid-update
         profiling.register_profile(name, self)
+
+    # -------------------------------------------------------------- phases
+
+    def phase(self, name: str) -> _Phase:
+        return self._phases[name]
+
+    def _switch(self, name: str) -> str:
+        """Close the running phase's interval and start ``name``'s;
+        returns the phase that was running."""
+        now = time.perf_counter()
+        prev = self._cur
+        self._ver += 1
+        self.phase_seconds[prev] += now - self._t
+        self._cur = name
+        self._t = now
+        self._ver += 1
+        return prev
+
+    def step_begin(self) -> None:
+        """Entry of one ``_step``: the time since the last one ended was
+        ``idle`` if the loop slept on its wake event in between, else
+        ``between_steps`` (executor hop, reap, loop-thread admission)."""
+        self._switch("other")
+        _settle_gap(self.phase_seconds, self.slept)
+        self.slept = False
+        self.step_iterations += 1
+        # a TraceAnnotation opens when it is made and closes in __exit__
+        self._step_ann = TraceAnnotation("dyn.step")
+
+    def step_end(self) -> None:
+        self._step_ann.__exit__(None, None, None)
+        self._switch(_GAP)
+
+    def phase_snapshot(self) -> Dict[str, float]:
+        """{phase: cumulative seconds} up to now, the running interval
+        included, so two snapshots differ by the wall time between them.
+        Read from any thread: retried while the step thread is inside
+        ``_switch``."""
+        for _ in range(16):
+            ver = self._ver
+            seconds = dict(self.phase_seconds)
+            cur, t = self._cur, self._t
+            if ver == self._ver and not ver & 1:
+                break
+        seconds[cur] += time.perf_counter() - t
+        _settle_gap(seconds, self.slept)
+        del seconds[_GAP]
+        return seconds
 
     # ------------------------------------------------------------ sampling
 

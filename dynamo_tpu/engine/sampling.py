@@ -109,10 +109,12 @@ def update_penalty_state(penalties, sampled: jax.Array, done: jax.Array):
         # apply_penalties (neutral rep/freq/pres) — nothing to fold in,
         # and a real scatter would index out of bounds
         return penalties
-    rows = jnp.arange(counts.shape[0])
-    live = jnp.logical_not(done).astype(counts.dtype)
-    counts = counts.at[rows, sampled].add(live)
-    presence = presence.at[rows, sampled].max(live.astype(presence.dtype))
+    with jax.named_scope("sample"):
+        rows = jnp.arange(counts.shape[0])
+        live = jnp.logical_not(done).astype(counts.dtype)
+        counts = counts.at[rows, sampled].add(live)
+        presence = presence.at[rows, sampled].max(
+            live.astype(presence.dtype))
     return (counts, presence) + rest
 
 
@@ -132,38 +134,39 @@ def sample_tokens(logits: jax.Array, temperature: jax.Array,
     consumed by :func:`apply_penalties`; None (the default and the only
     pre-compiled variant) keeps the penalty-free program.
     """
-    if penalties is not None:
-        logits = apply_penalties(logits, *penalties)
-    step = jnp.broadcast_to(step, temperature.shape)
-    B, V = logits.shape
+    with jax.named_scope("sample"):
+        if penalties is not None:
+            logits = apply_penalties(logits, *penalties)
+        step = jnp.broadcast_to(step, temperature.shape)
+        B, V = logits.shape
 
-    temp = jnp.where(temperature > 0, temperature, 1.0)[:, None]
-    scaled = logits / temp
+        temp = jnp.where(temperature > 0, temperature, 1.0)[:, None]
+        scaled = logits / temp
 
-    # top-k within a static bound: take max_top_k once, mask per-row k.
-    # Greedy rows reuse this pass too: argmax == top-1, and a separate
-    # jnp.argmax over the full vocab costs ~2.5x the top_k call on TPU
-    k_vals, k_idx = jax.lax.top_k(scaled, max_top_k)  # [B, K]
-    greedy = k_idx[:, 0]
-    ranks = jnp.arange(max_top_k)[None, :]
-    eff_k = jnp.where(top_k[:, None] > 0,
-                      jnp.minimum(top_k[:, None], max_top_k), max_top_k)
-    k_vals = jnp.where(ranks < eff_k, k_vals, -jnp.inf)
+        # top-k within a static bound: take max_top_k once, mask per-row k.
+        # Greedy rows reuse this pass too: argmax == top-1, and a separate
+        # jnp.argmax over the full vocab costs ~2.5x the top_k call on TPU
+        k_vals, k_idx = jax.lax.top_k(scaled, max_top_k)  # [B, K]
+        greedy = k_idx[:, 0]
+        ranks = jnp.arange(max_top_k)[None, :]
+        eff_k = jnp.where(top_k[:, None] > 0,
+                          jnp.minimum(top_k[:, None], max_top_k), max_top_k)
+        k_vals = jnp.where(ranks < eff_k, k_vals, -jnp.inf)
 
-    # top-p over the (sorted) top-k candidates
-    probs = jax.nn.softmax(k_vals, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_p[:, None]  # always keep the first candidate
-    k_vals = jnp.where(keep, k_vals, -jnp.inf)
+        # top-p over the (sorted) top-k candidates
+        probs = jax.nn.softmax(k_vals, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs) < top_p[:, None]  # always keep the first candidate
+        k_vals = jnp.where(keep, k_vals, -jnp.inf)
 
-    def row_sample(i):
-        key = jax.random.fold_in(
-            jax.random.fold_in(jax.random.PRNGKey(0), seeds[i]), step[i])
-        choice = jax.random.categorical(key, k_vals[i])
-        return k_idx[i, choice]
+        def row_sample(i):
+            key = jax.random.fold_in(
+                jax.random.fold_in(jax.random.PRNGKey(0), seeds[i]), step[i])
+            choice = jax.random.categorical(key, k_vals[i])
+            return k_idx[i, choice]
 
-    sampled = jax.vmap(row_sample)(jnp.arange(B))
-    return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+        sampled = jax.vmap(row_sample)(jnp.arange(B))
+        return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("max_top_k",))
@@ -221,6 +224,7 @@ def logprob_aux(logits: jax.Array, chosen: jax.Array, topn: int):
     the RAW model logits — OpenAI logprobs describe the model's
     distribution, so penalties/temperature are not reflected (vLLM's
     default differs; this is the documented contract here)."""
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    tv, ti = jax.lax.top_k(logp, topn)
-    return _gather_rows(logp, chosen), tv, ti
+    with jax.named_scope("sample"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        tv, ti = jax.lax.top_k(logp, topn)
+        return _gather_rows(logp, chosen), tv, ti

@@ -513,11 +513,12 @@ class HttpService:
         # dynaslo goodput inputs for this request (mean ITL over the gaps)
         ttft_s: Optional[float] = None
         itl_total, itl_n = 0.0, 0
+        first_text_written = False
 
         async def _write_chunk(chunk) -> bool:
             """Writes one stream item; returns False to stop the stream."""
             nonlocal errored, saw_first_token, last_token_t
-            nonlocal ttft_s, itl_total, itl_n
+            nonlocal ttft_s, itl_total, itl_n, first_text_written
             if chunk is None:
                 return True
             if isinstance(chunk, Annotated) and chunk.event and chunk.data is None:
@@ -547,6 +548,18 @@ class HttpService:
                 itl_n += 1
             last_token_t = now
             await resp.write(b"data: " + json.dumps(data).encode() + b"\n\n")
+            if not first_text_written and _carries_text(data):
+                # the frontend's share of TTFT, on the request's own
+                # trace: request received (the http.request span's start)
+                # -> first SSE chunk with generated text written (a chat
+                # stream opens with a role-only chunk before any token)
+                first_text_written = True
+                req_span = tracing.current_span()
+                if req_span is not None:
+                    tracing.get_tracer().record_span(
+                        "http.first_chunk",
+                        time.monotonic() - req_span.start,
+                        start=req_span.start)
             return True
 
         try:
@@ -846,6 +859,13 @@ def _chunk_dict(chunk) -> Optional[dict]:
     if hasattr(chunk, "model_dump"):
         return chunk.model_dump(exclude_none=True)
     return chunk
+
+
+def _carries_text(data: dict) -> bool:
+    """A data chunk with generated text in it (chat delta or completion
+    text), as opposed to the role-only chunk that opens a chat stream."""
+    return any((c.get("delta") or {}).get("content") or c.get("text")
+               for c in data.get("choices") or ())
 
 
 def _request_deadline(http_request: web.Request, req):

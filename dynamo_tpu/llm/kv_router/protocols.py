@@ -132,6 +132,16 @@ STATS_PROMETHEUS_SKIP = {
         "raw counter folded into spec_decode_acceptance_rate",
     "spec_decode_accepted_tokens_total":
         "raw counter folded into spec_decode_acceptance_rate",
+    # the engine's account of its own time (docs/observability.md): read
+    # as deltas from stats() by the benchmark's readers; no gauge yet
+    **{key: "stats()-only counter of the engine's own time accounting"
+       for key in (
+           "step_iterations_total", "prefill_wait_seconds_total",
+           "first_token_seconds_total", "engine_ttft_seconds_total",
+           "first_tokens_total", "prefill_tokens_total",
+           "prefill_slots_total", "prefill_dispatches_total",
+           "decode_rows_total", "decode_slots_total",
+           "decode_windows_total", "warmup_seconds")},
 }
 
 

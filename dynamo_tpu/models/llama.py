@@ -155,14 +155,15 @@ def project_logits(params: Params, cfg: ModelConfig,
     """LM head (tied to the embedding when absent) + the optional
     Gemma-2-style final-logit softcap — the single logit-path exit used
     by every forward variant."""
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    logits = (h @ head).astype(jnp.float32)
-    if cfg.final_logit_softcap:
-        c = cfg.final_logit_softcap
-        logits = c * jnp.tanh(logits / c)
-    return logits
+    with jax.named_scope("lm_head"):
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed"].T
+        logits = (h @ head).astype(jnp.float32)
+        if cfg.final_logit_softcap:
+            c = cfg.final_logit_softcap
+            logits = c * jnp.tanh(logits / c)
+        return logits
 
 
 def _act(cfg: ModelConfig):
@@ -398,7 +399,8 @@ def _paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
 
 
 def _mlp(h: jax.Array, w_gate, w_up, w_down, act=jax.nn.silu) -> jax.Array:
-    return (act(h @ w_gate) * (h @ w_up)) @ w_down
+    with jax.named_scope("mlp"):
+        return (act(h @ w_gate) * (h @ w_up)) @ w_down
 
 
 def _layer_keys(cfg: ModelConfig) -> list:
@@ -558,26 +560,31 @@ def _moe_mlp(h: jax.Array, w_router, w_gate, w_up, w_down,
     """
     B, T, D = h.shape
     E = w_gate.shape[0]
-    logits = (h @ w_router).astype(jnp.float32)  # [B, T, E]
-    weights, idx = lax.top_k(logits, top_k)  # [B, T, k]
-    weights = jax.nn.softmax(weights, axis=-1)
+    with jax.named_scope("moe.router"):
+        logits = (h @ w_router).astype(jnp.float32)  # [B, T, E]
+        weights, idx = lax.top_k(logits, top_k)  # [B, T, k]
+        weights = jax.nn.softmax(weights, axis=-1)
     if _moe_use_blocked(mesh, B * T, E, top_k, _MOE_BLOCK):
-        out = moe_experts_blocked(
-            h.reshape(B * T, D).astype(jnp.float32),
-            weights.reshape(B * T, top_k), idx.reshape(B * T, top_k),
-            w_gate, w_up, w_down, block=_MOE_BLOCK)
-        return out.reshape(B, T, D).astype(h.dtype)
-    full_gate = jnp.sum(
-        jax.nn.one_hot(idx, E, dtype=jnp.float32) * weights[..., None], axis=2)
+        with jax.named_scope("moe.experts"):
+            out = moe_experts_blocked(
+                h.reshape(B * T, D).astype(jnp.float32),
+                weights.reshape(B * T, top_k), idx.reshape(B * T, top_k),
+                w_gate, w_up, w_down, block=_MOE_BLOCK)
+            return out.reshape(B, T, D).astype(h.dtype)
+    with jax.named_scope("moe.router"):
+        full_gate = jnp.sum(
+            jax.nn.one_hot(idx, E, dtype=jnp.float32) * weights[..., None],
+            axis=2)
     # dense-over-experts: out = sum_e gate[...,e] * mlp_e(h)
-    ge = jnp.einsum("btd,edi->btei", h.astype(jnp.float32),
-                    w_gate.astype(jnp.float32))
-    up = jnp.einsum("btd,edi->btei", h.astype(jnp.float32),
-                    w_up.astype(jnp.float32))
-    act = jax.nn.silu(ge) * up
-    down = jnp.einsum("btei,eid->bted", act, w_down.astype(jnp.float32))
-    out = jnp.einsum("bted,bte->btd", down, full_gate)
-    return out.astype(h.dtype)
+    with jax.named_scope("moe.experts"):
+        ge = jnp.einsum("btd,edi->btei", h.astype(jnp.float32),
+                        w_gate.astype(jnp.float32))
+        up = jnp.einsum("btd,edi->btei", h.astype(jnp.float32),
+                        w_up.astype(jnp.float32))
+        act = jax.nn.silu(ge) * up
+        down = jnp.einsum("btei,eid->bted", act, w_down.astype(jnp.float32))
+        out = jnp.einsum("bted,bte->btd", down, full_gate)
+        return out.astype(h.dtype)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
@@ -608,34 +615,37 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     def layer(h, xs):
         lp, l_idx, k_layer, v_layer = xs
-        x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-        xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
-        if cfg.attn_bias:
-            xq, xk, xv = xq + lp["bq"], xk + lp["bk"], xv + lp["bv"]
-        q = xq.reshape(B, T, H, hd)
-        k = xk.reshape(B, T, KV, hd)
-        v = xv.reshape(B, T, KV, hd)
-        q, k = _qk_headnorm(q, k, lp, cfg)
-        q = apply_rope(q, safe_pos, inv_freq)
-        k = apply_rope(k, safe_pos, inv_freq)
-        if page_slots is not None:
-            k_layer = _scatter_pages_paged(k_layer, k, page_slots)
-            v_layer = _scatter_pages_paged(v_layer, v, page_slots)
-        else:
-            k_layer = _scatter_pages(k_layer, k, flat_slots)
-            v_layer = _scatter_pages(v_layer, v, flat_slots)
-        attn = _attention(q, k_layer, v_layer, page_table, positions, scale,
-                          allow_pallas=allow_pallas, mesh=mesh,
-                          softcap=cfg.attn_logit_softcap,
-                          window=cfg.sliding_window,
-                          is_sliding=_sliding_flag(cfg, l_idx))
-        h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"], lp,
-                          "ln_attn_post", cfg)
+        with jax.named_scope("attn"):
+            x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                         cfg.norm_unit_offset)
+            xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+            if cfg.attn_bias:
+                xq, xk, xv = xq + lp["bq"], xk + lp["bk"], xv + lp["bv"]
+            q = xq.reshape(B, T, H, hd)
+            k = xk.reshape(B, T, KV, hd)
+            v = xv.reshape(B, T, KV, hd)
+            q, k = _qk_headnorm(q, k, lp, cfg)
+            q = apply_rope(q, safe_pos, inv_freq)
+            k = apply_rope(k, safe_pos, inv_freq)
+            if page_slots is not None:
+                k_layer = _scatter_pages_paged(k_layer, k, page_slots)
+                v_layer = _scatter_pages_paged(v_layer, v, page_slots)
+            else:
+                k_layer = _scatter_pages(k_layer, k, flat_slots)
+                v_layer = _scatter_pages(v_layer, v, flat_slots)
+            attn = _attention(q, k_layer, v_layer, page_table, positions,
+                              scale, allow_pallas=allow_pallas, mesh=mesh,
+                              softcap=cfg.attn_logit_softcap,
+                              window=cfg.sliding_window,
+                              is_sliding=_sliding_flag(cfg, l_idx))
+            h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"], lp,
+                              "ln_attn_post", cfg)
         x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
         if cfg.num_experts > 0:
-            mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"], lp["w_up"],
-                               lp["w_down"], cfg.num_experts_per_tok,
-                               mesh=mesh)
+            with jax.named_scope("moe"):
+                mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
+                                   lp["w_up"], lp["w_down"],
+                                   cfg.num_experts_per_tok, mesh=mesh)
         else:
             mlp_out = _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], act)
         h = _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
@@ -652,7 +662,8 @@ def logits_at(params: Params, cfg: ModelConfig, hidden: jax.Array,
     """LM head at selected positions. hidden: [B, T, D];
     gather_idx: [B] position per row → logits [B, V] (float32)."""
     B = hidden.shape[0]
-    h_last = hidden[jnp.arange(B), gather_idx]  # [B, D]
+    with jax.named_scope("lm_head"):
+        h_last = hidden[jnp.arange(B), gather_idx]  # [B, D]
     return project_logits(params, cfg, h_last)
 
 
@@ -835,43 +846,47 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                 # slice copy for each unrolled step's pallas operand
                 # (≈6.4 GB/step of copy traffic at serving sizes)
                 lp, l_idx, wk_l, wv_l = xs
-                x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-                xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
-                if cfg.attn_bias:
-                    xq, xk, xv = (xq + lp["bq"], xk + lp["bk"],
-                                  xv + lp["bv"])
-                q, k = _qk_headnorm(xq.reshape(B, 1, H, hd),
-                                    xk.reshape(B, 1, KV, hd), lp, cfg)
-                q = apply_rope(q, safe_pos, inv_freq)
-                k = apply_rope(k, safe_pos, inv_freq)
-                v = xv.reshape(B, 1, KV, hd)
-                wk_l = wk_l.at[:, i].set(k[:, 0].astype(wdt))
-                wv_l = wv_l.at[:, i].set(v[:, 0].astype(wdt))
-                if use_pallas:
-                    attn = _pool_window_attention_pallas(
-                        q, kv_k, kv_v, l_idx, page_table, start, wk_l,
-                        wv_l, i, scale,
-                        interpret=pallas_interpret,
-                        mesh=mesh if sharded else None,
-                        softcap=cfg.attn_logit_softcap,
-                        window=cfg.sliding_window,
-                        is_sliding=_sliding_flag(cfg, l_idx),
-                        q_pos=safe_pos[:, 0])
-                else:
-                    attn = _pool_window_attention(
-                        q, kv_k[l_idx], kv_v[l_idx], page_table, start,
-                        wk_l, wv_l, i, scale,
-                        softcap=cfg.attn_logit_softcap,
-                        window=cfg.sliding_window,
-                        is_sliding=_sliding_flag(cfg, l_idx),
-                        q_pos=safe_pos[:, 0])
-                h = _residual_add(h, attn.reshape(B, 1, H * hd) @ lp["wo"],
-                                  lp, "ln_attn_post", cfg)
+                with jax.named_scope("attn"):
+                    x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                                 cfg.norm_unit_offset)
+                    xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+                    if cfg.attn_bias:
+                        xq, xk, xv = (xq + lp["bq"], xk + lp["bk"],
+                                      xv + lp["bv"])
+                    q, k = _qk_headnorm(xq.reshape(B, 1, H, hd),
+                                        xk.reshape(B, 1, KV, hd), lp, cfg)
+                    q = apply_rope(q, safe_pos, inv_freq)
+                    k = apply_rope(k, safe_pos, inv_freq)
+                    v = xv.reshape(B, 1, KV, hd)
+                    wk_l = wk_l.at[:, i].set(k[:, 0].astype(wdt))
+                    wv_l = wv_l.at[:, i].set(v[:, 0].astype(wdt))
+                    if use_pallas:
+                        attn = _pool_window_attention_pallas(
+                            q, kv_k, kv_v, l_idx, page_table, start, wk_l,
+                            wv_l, i, scale,
+                            interpret=pallas_interpret,
+                            mesh=mesh if sharded else None,
+                            softcap=cfg.attn_logit_softcap,
+                            window=cfg.sliding_window,
+                            is_sliding=_sliding_flag(cfg, l_idx),
+                            q_pos=safe_pos[:, 0])
+                    else:
+                        attn = _pool_window_attention(
+                            q, kv_k[l_idx], kv_v[l_idx], page_table, start,
+                            wk_l, wv_l, i, scale,
+                            softcap=cfg.attn_logit_softcap,
+                            window=cfg.sliding_window,
+                            is_sliding=_sliding_flag(cfg, l_idx),
+                            q_pos=safe_pos[:, 0])
+                    h = _residual_add(
+                        h, attn.reshape(B, 1, H * hd) @ lp["wo"], lp,
+                        "ln_attn_post", cfg)
                 x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
                 if cfg.num_experts > 0:
-                    mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
-                                       lp["w_up"], lp["w_down"],
-                                       cfg.num_experts_per_tok, mesh=mesh)
+                    with jax.named_scope("moe"):
+                        mlp_out = _moe_mlp(
+                            x, lp["w_router"], lp["w_gate"], lp["w_up"],
+                            lp["w_down"], cfg.num_experts_per_tok, mesh=mesh)
                 else:
                     mlp_out = _mlp(x, lp["w_gate"], lp["w_up"],
                                    lp["w_down"], act)
@@ -912,15 +927,18 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
         # commit the window into the pool: one scatter per layer; entry i
         # holds the K/V of position start+i, valid only if the row was
         # still active at step i (start+i < final pos)
-        wpos = start[:, None] + jnp.arange(k_steps)[None, :]  # [B, K]
-        page = page_table[jnp.arange(B)[:, None],
-                          jnp.clip(wpos // ps, 0, page_table.shape[1] - 1)]
-        valid = jnp.logical_and(start[:, None] >= 0, wpos < pos[:, None])
-        flat = jnp.where(valid, page * ps + wpos % ps, DROP_SLOT)
-        kv_k = jax.vmap(_scatter_pages)(kv_k, wk, jnp.broadcast_to(
-            flat, (cfg.num_layers,) + flat.shape))
-        kv_v = jax.vmap(_scatter_pages)(kv_v, wv, jnp.broadcast_to(
-            flat, (cfg.num_layers,) + flat.shape))
+        with jax.named_scope("kv_carry"):
+            wpos = start[:, None] + jnp.arange(k_steps)[None, :]  # [B, K]
+            page = page_table[jnp.arange(B)[:, None],
+                              jnp.clip(wpos // ps, 0,
+                                       page_table.shape[1] - 1)]
+            valid = jnp.logical_and(start[:, None] >= 0,
+                                    wpos < pos[:, None])
+            flat = jnp.where(valid, page * ps + wpos % ps, DROP_SLOT)
+            kv_k = jax.vmap(_scatter_pages)(kv_k, wk, jnp.broadcast_to(
+                flat, (cfg.num_layers,) + flat.shape))
+            kv_v = jax.vmap(_scatter_pages)(kv_v, wv, jnp.broadcast_to(
+                flat, (cfg.num_layers,) + flat.shape))
         out_toks = jnp.stack(toks, axis=1)
         carry = (tok, pos, done, steps, remaining)
         if logprobs_topn:

@@ -280,6 +280,9 @@ def paged_attention_decode_layered(q: jax.Array, k_pools: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        # the name a device trace shows the kernel under: what
+        # benchmark/harness/trace.py DECODE_KERNEL_OP matches
+        name="paged_attention_decode_layered",
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       lower.astype(jnp.int32),
@@ -476,6 +479,7 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention_prefill",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       lower.astype(jnp.int32), eff_win.astype(jnp.int32),
       q4, qpos, k_pages, v_pages)

@@ -38,6 +38,22 @@ def enable_compile_cache() -> str:
     # path (measured: 532 s of warm-up with the whole cache present)
     jax.config.update("jax_hlo_source_file_canonicalization_regex",
                       re.escape(_CHECKOUT + os.sep))
+    # the names a profiler trace shows (jax.named_scope paths, kernel
+    # names) are metadata, which the key leaves out by default: a program
+    # whose scopes changed is then handed the executable, and the names,
+    # of the program compiled before it, and a trace names its ops after
+    # code that is no longer there (seen in PR 24: parent and change
+    # share prefill_step's key). With the names in the key, the source
+    # lines that JAX attaches to every op (the op's own and ten calling
+    # frames) would be in it too, and a comment added to the engine
+    # would recompile the grid. So no frames are attached: a location is
+    # the op's name-scope path alone, a pure line shift anywhere misses
+    # nothing (JAX's defaults miss most of the grid on one, through the
+    # frames in the Pallas kernels' serialized modules; PERF.md, PR 24,
+    # has both measured), and what a profile loses is an op's `source`
+    # file:line, which its path locates as well.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 0)
     # the warm grid is mostly programs that compile in under a second
     # each; JAX's default would skip caching exactly those
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)

@@ -269,17 +269,25 @@ class Tracer:
         return span
 
     def record_span(self, name: str, seconds: float, *,
+                    start: Optional[float] = None,
                     parent: Any = _AMBIENT,
                     attributes: Optional[dict] = None) -> None:
-        """Synthesize an already-finished span of the given duration ending
-        now — how measured stage accumulators (TransferStats deltas) are
-        adopted as child spans without wrapping their interleaved code."""
+        """Synthesize an already-finished span of the given duration.
+        ``start`` is the span's own ``time.monotonic()`` start (the
+        engine's request phases, stamped as they happened and recorded
+        at finish); without it the span ends now — how measured stage
+        accumulators that have no start of their own (TransferStats
+        deltas) are adopted as child spans."""
         span = self.start_span(name, parent=parent, attributes=attributes)
         if not span.recording:
             return
-        span.start = time.monotonic() - seconds
-        span.wall_start = time.time() - seconds
-        span.end()
+        now = time.monotonic()
+        if start is None:
+            start = now - seconds
+        span.start = start
+        span.wall_start = time.time() - (now - start)
+        span.end_time = start + seconds
+        self._finish(span)
 
     def current_trace_ctx(self) -> Optional[dict]:
         """Wire form of the ambient span, or None when nothing is being

@@ -65,6 +65,51 @@ def test_unset_uses_the_checkout_path_from_any_process(tmp_path):
     assert os.listdir(want)
 
 
+KEY_PROBE = """
+import os, sys
+import jax, jax.numpy as jnp
+from dynamo_tpu.runtime.compile_cache import enable_compile_cache
+enable_compile_cache()
+scope, op_pad, caller_pad = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
+ns = {"jax": jax, "jnp": jnp, "scope": scope}
+exec(compile("\\n" * op_pad + "def step(x):\\n"
+             "    with jax.named_scope(scope):\\n"
+             "        return jnp.sin(x) * 2\\n", "/elsewhere/model.py",
+             "exec"), ns)
+exec(compile("\\n" * caller_pad + "def run(f, x):\\n    return f(x)\\n",
+             "/elsewhere/engine.py", "exec"), ns)
+step = jax.jit(ns["step"])
+ns["run"](step, jnp.ones((4,))).block_until_ready()
+# the compiled program still names its ops by scope
+print(step.lower(jnp.ones((4,))).compile().as_text())
+"""
+
+
+def test_the_key_holds_scope_names_and_no_source_line(tmp_path):
+    """A trace must name an op after the program that is running, so
+    another scope is another entry; the source lines that would come
+    with the names are left off, so an edit that only moves lines, of
+    the model or of what calls it, recompiles nothing."""
+    placed = tmp_path / "placed"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               JAX_COMPILATION_CACHE_DIR=str(placed))
+
+    def entries_after(scope, op_pad, caller_pad):
+        proc = subprocess.run(
+            [sys.executable, "-c", KEY_PROBE, scope, str(op_pad),
+             str(caller_pad)], env=env, cwd=str(tmp_path),
+            capture_output=True, text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-800:]
+        assert "moe/sin" in proc.stdout or "experts/sin" in proc.stdout
+        return {f for f in os.listdir(placed) if f.startswith("jit_step")}
+
+    first = entries_after("moe", 0, 0)
+    assert len(first) == 1
+    assert entries_after("moe", 0, 5) == first, "the caller's lines moved"
+    assert entries_after("moe", 3, 5) == first, "the op's line moved"
+    assert len(entries_after("experts", 3, 5)) == 2, "same HLO, other name"
+
+
 def test_registered_as_external_and_gitignored():
     from dynamo_tpu.runtime.config import ENV_REGISTRY
 

@@ -235,7 +235,7 @@ def test_async_detok_cancellation_isolated(run_async):
     tok = ByteTokenizer()
     text = "the quick brown fox jumps over the lazy dog " * 4
 
-    async def victim():
+    async def victim(started=None):
         ids = tok.encode(text, add_special_tokens=False)
         be = Backend(_ChunkEngine([ids[j:j + 2]
                                    for j in range(0, len(ids), 2)]), tok)
@@ -246,15 +246,20 @@ def test_async_detok_cancellation_isolated(run_async):
         got = ""
         async for out in be.generate(req, Context()):
             got += out.text or ""
+            if started is not None:
+                started.set()
             await asyncio.sleep(0)  # cancellation window
             if out.finish_reason:
                 break
         return got
 
     async def main():
-        t1 = asyncio.ensure_future(victim())
+        # cancel on t1's first chunk, not after a fixed sleep: on a fast
+        # machine all 88 chunks were through before 10 ms had passed
+        started = asyncio.Event()
+        t1 = asyncio.ensure_future(victim(started))
         t2 = asyncio.ensure_future(victim())
-        await asyncio.sleep(0.01)
+        await started.wait()
         t1.cancel()
         survivor = await t2
         with pytest.raises(asyncio.CancelledError):

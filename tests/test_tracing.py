@@ -457,3 +457,118 @@ def test_prefill_queue_carries_trace_ctx(run_async):
             await drt.shutdown()
 
     run_async(main())
+
+
+# ------------------------------------------- the engine's request phases
+
+REQUEST_STAGES = {"http.request", "preprocess", "engine.queue",
+                  "engine.prefill_wait", "engine.prefill", "engine.decode",
+                  "http.first_chunk"}
+
+
+def test_record_span_takes_its_own_start(fresh_tracer):
+    """With ``start`` the span lies where it happened, not "ends now"."""
+    import time
+
+    t = fresh_tracer
+    began = time.monotonic() - 5.0
+    with t.start_span("parent") as p:
+        t.record_span("phase", 0.5, start=began, parent=p)
+        t.record_span("ends-now", 0.5, parent=p)
+    phase = [s for s in t.snapshot() if s.name == "phase"][0]
+    assert phase.start == began and phase.end_time == began + 0.5
+    assert phase.duration_s == pytest.approx(0.5)
+    assert phase.wall_start == pytest.approx(time.time() - 5.0, abs=0.5)
+    late = [s for s in t.snapshot() if s.name == "ends-now"][0]
+    assert late.end_time == pytest.approx(time.monotonic(), abs=0.5)
+    assert phase.parent_id == late.parent_id == p.span_id
+
+
+async def _serve_one_stream(rid: str):
+    """One streamed chat request through HttpService -> LocalChatChain
+    -> a tiny JaxEngine; returns (/v1/traces/{rid} body, engine stats)."""
+    import aiohttp
+
+    from dynamo_tpu.llm.engines import LocalChatChain
+    from dynamo_tpu.llm.http.service import HttpService
+    from dynamo_tpu.llm.model_card import ModelDeploymentCard
+
+    engine = make_engine()
+    mdc = ModelDeploymentCard(name="tiny-jax", tokenizer_kind="byte",
+                              context_length=256)
+    service = HttpService()
+    service.manager.add_chat_model("tiny-jax", LocalChatChain(mdc, engine))
+    await service.start(host="127.0.0.1", port=0)
+    base = f"http://127.0.0.1:{service.port}"
+    try:
+        async with aiohttp.ClientSession() as http:
+            body = {"model": "tiny-jax", "stream": True, "max_tokens": 8,
+                    "messages": [{"role": "user", "content": "hello"}]}
+            async with http.post(f"{base}/v1/chat/completions", json=body,
+                                 headers={"X-Request-Id": rid}) as r:
+                assert r.status == 200
+                async for line in r.content:
+                    if line.decode().strip() == "data: [DONE]":
+                        break
+            # the stream can end a moment before the engine emits its
+            # finish (the page release waits for the window in flight),
+            # and the engine's spans are recorded with that finish
+            for _ in range(100):
+                async with http.get(f"{base}/v1/traces/{rid}") as r:
+                    # 404 until a span or the cost block exists
+                    trace_body = await r.json() if r.status == 200 else {}
+                if (trace_body.get("cost") or {}).get("finish_reason"):
+                    break
+                await asyncio.sleep(0.05)
+    finally:
+        await service.stop()
+        stats = engine.stats()
+        await engine.stop()
+    return trace_body, stats
+
+
+def test_engine_spans_join_the_http_request_trace(run_async):
+    """/v1/traces/{request_id} shows the whole path under one trace_id:
+    the frontend's spans, the engine's four request phases (recorded at
+    finish with their own start times) and http.first_chunk."""
+    body, stats = run_async(_serve_one_stream("phases-1"))
+    spans = {s["name"]: s for s in body["spans"]}
+    assert REQUEST_STAGES <= set(spans) and REQUEST_STAGES <= set(
+        body["stages"])
+    assert {s["trace_id"] for s in body["spans"]} == {body["trace_id"]}
+    root = spans["http.request"]
+    assert spans["http.first_chunk"]["parent_id"] == root["span_id"]
+    # the engine's spans hang from whatever was ambient in generate()
+    parents = {spans[n]["parent_id"] for n in REQUEST_STAGES
+               if n.startswith("engine.")}
+    assert len(parents) == 1 and parents <= {s["span_id"]
+                                             for s in body["spans"]}
+    # in order, touching end to start, inside the request's span
+    order = ["engine.queue", "engine.prefill_wait", "engine.prefill",
+             "engine.decode"]
+    for a, b in zip(order, order[1:]):
+        assert spans[a]["start_ms"] + spans[a]["duration_ms"] == \
+            pytest.approx(spans[b]["start_ms"], abs=1.0)
+    assert spans["engine.queue"]["start_ms"] >= root["start_ms"] - 1.0
+    first = spans["http.first_chunk"]
+    assert first["start_ms"] == pytest.approx(root["start_ms"], abs=1.0)
+    engine_ttft = sum(spans[n]["duration_ms"] for n in order[:3])
+    assert engine_ttft <= first["duration_ms"] + 1.0
+    assert body["cost"]["first_token_ms"] == pytest.approx(
+        spans["engine.prefill"]["duration_ms"], abs=0.01)
+    assert stats["first_tokens_total"] == 1
+
+
+def test_sample_zero_records_no_engine_span_and_counts_the_same(run_async):
+    tracer = tracing.configure(sample=0.0)
+    body, stats = run_async(_serve_one_stream("phases-0"))
+    assert body["spans"] == [] and tracer.spans_recorded == 0
+    assert stats["first_tokens_total"] == 1
+    assert stats["engine_ttft_seconds_total"] > 0
+    split = (stats["queue_wait_seconds_total"]
+             + stats["prefill_wait_seconds_total"]
+             + stats["first_token_seconds_total"])
+    assert split == pytest.approx(stats["engine_ttft_seconds_total"],
+                                  abs=1e-3)
+    for key in ("prefill_wait_ms", "first_token_ms", "decode_ms"):
+        assert body["cost"][key] > 0
