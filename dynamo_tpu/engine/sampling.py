@@ -118,6 +118,73 @@ def update_penalty_state(penalties, sampled: jax.Array, done: jax.Array):
     return (counts, presence) + rest
 
 
+_CHUNK = 128  # lanes of one TPU vector register: a chunk is one lane row
+
+
+def _top_k_two_key(x: jax.Array, ids: jax.Array, k: int
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """The k best of every (narrow) float32 row with their ``ids``, by
+    one two-key sort: (value, -id) ascending, read from the end, so
+    equal values come out by lower id and no tie is left to the sort.
+    The value key is the float's bits mapped to the int32 of the same
+    rank, which is ``lax.top_k``'s total order (-0.0 below 0.0) where
+    ``lax.sort`` on floats would call the two zeros equal.
+    ``lax.top_k`` itself would do on paper; on a v5e its single-row
+    lowering does not keep equal values in index order (PERF.md, PR 25).
+    """
+    def flip(bits):     # float32 bits <-> int32 of the same rank
+        return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+    key = flip(jax.lax.bitcast_convert_type(x, jnp.int32))
+    key, neg_ids = jax.lax.sort((key, -ids), dimension=1, num_keys=2)
+    vals = jax.lax.bitcast_convert_type(flip(key[:, :-k - 1:-1]),
+                                        jnp.float32)
+    return vals, -neg_ids[:, :-k - 1:-1]
+
+
+@partial(jax.jit, static_argnames=("k",))
+def exact_top_k(x: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """``jax.lax.top_k(x, k)`` for x [B, V], to the letter: values
+    descending, equal values by lower vocabulary index, float32
+    compares, nothing approximate -- without sorting the vocabulary.
+
+    1. the row is N = ceil(V / 128) chunks of 128 (tail padded with
+       -inf); take every chunk's maximum: one read of the logits;
+    2. the k best of the N maxima (lower chunk first among equals) name
+       k chunks. An element of the global top k sits in one of them:
+       were its chunk left out, k chunks would precede it (larger
+       maximum, or equal maximum and lower index), and each holds an
+       element that precedes it;
+    3. gather those chunks and take the k best of the k * 128
+       candidates, each under its vocabulary id, so ties break as they
+       did. The padding has the highest ids and the lowest value: an
+       id >= V is never returned.
+
+    The shape decides at trace time: with N <= 2k (tiny vocabularies)
+    this is the plain call. Jitted, so that an eager caller
+    (``logprob_aux`` after an eager prefill) dispatches one program and
+    not two dozen.
+    """
+    B, V = x.shape
+    N = -(-V // _CHUNK)
+    if N <= 2 * k:
+        return jax.lax.top_k(x, k)
+    dtype, x = x.dtype, x.astype(jnp.float32)
+    if N * _CHUNK != V:
+        x = jnp.pad(x, ((0, 0), (0, N * _CHUNK - V)),
+                    constant_values=-jnp.inf)
+    chunks = x.reshape(B, N, _CHUNK)
+    iota = partial(jax.lax.broadcasted_iota, jnp.int32)
+    _, picked = _top_k_two_key(jnp.max(chunks, axis=-1),
+                               iota((B, N), 1), k)              # [B, k]
+    cand = jnp.take_along_axis(chunks, picked[:, :, None], axis=1,
+                               mode="promise_in_bounds")
+    ids = picked[:, :, None] * _CHUNK + iota((B, k, _CHUNK), 2)
+    vals, ids = _top_k_two_key(cand.reshape(B, k * _CHUNK),
+                               ids.reshape(B, k * _CHUNK), k)
+    return vals.astype(dtype), ids
+
+
 @partial(jax.jit, static_argnames=("max_top_k",))
 def sample_tokens(logits: jax.Array, temperature: jax.Array,
                   top_k: jax.Array, top_p: jax.Array, seeds: jax.Array,
@@ -144,9 +211,14 @@ def sample_tokens(logits: jax.Array, temperature: jax.Array,
         scaled = logits / temp
 
         # top-k within a static bound: take max_top_k once, mask per-row k.
-        # Greedy rows reuse this pass too: argmax == top-1, and a separate
-        # jnp.argmax over the full vocab costs ~2.5x the top_k call on TPU
-        k_vals, k_idx = jax.lax.top_k(scaled, max_top_k)  # [B, K]
+        # Greedy rows reuse this pass: argmax == top-1. Device time on a
+        # v5e at [64, 151936] float32 (tools/sampler_op_timing.py; PERF.md,
+        # PR 25): this function 12.46 ms on lax.top_k, which inside this
+        # program compiles to a stable sort of the whole row (alone, as a
+        # TopK custom call, 1.91 ms), 0.55 ms on exact_top_k; a jnp.argmax
+        # alone 0.03 ms. A greedy-only branch could save the 0.5 ms, and
+        # lose them with the first sampled row in the batch
+        k_vals, k_idx = exact_top_k(scaled, max_top_k)  # [B, K]
         greedy = k_idx[:, 0]
         ranks = jnp.arange(max_top_k)[None, :]
         eff_k = jnp.where(top_k[:, None] > 0,
@@ -189,13 +261,13 @@ def verify_greedy_draft(logits: jax.Array, draft: jax.Array,
     position; entries past that are -1.
 
     The greedy target is computed exactly as :func:`sample_tokens`'
-    greedy arm (``lax.top_k`` first element over the temperature-1
-    logits), so speculation on/off is token-identical by construction,
-    tie-breaking included.
+    greedy arm (:func:`exact_top_k`'s first element over the
+    temperature-1 logits), so speculation on/off is token-identical by
+    construction, tie-breaking included.
     """
     B, K1, V = logits.shape
     K = K1 - 1
-    _, k_idx = jax.lax.top_k(logits.reshape(B * K1, V), max_top_k)
+    _, k_idx = exact_top_k(logits.reshape(B * K1, V), max_top_k)
     greedy = k_idx[:, 0].reshape(B, K1).astype(jnp.int32)
     match = jnp.logical_and(draft == greedy[:, :K],
                             jnp.arange(K)[None, :] < draft_len[:, None])
@@ -226,5 +298,5 @@ def logprob_aux(logits: jax.Array, chosen: jax.Array, topn: int):
     default differs; this is the documented contract here)."""
     with jax.named_scope("sample"):
         logp = jax.nn.log_softmax(logits, axis=-1)
-        tv, ti = jax.lax.top_k(logp, topn)
+        tv, ti = exact_top_k(logp, topn)
         return _gather_rows(logp, chosen), tv, ti
