@@ -34,6 +34,7 @@ import functools
 import glob
 import json
 import os
+import re
 import sys
 from collections import defaultdict
 from typing import Dict, Iterator, List, Optional, Tuple
@@ -211,20 +212,14 @@ def scope_seconds(loaded: dict) -> Optional[dict]:
                 per.items(), key=lambda kv: -kv[1])}}
 
 
-def scope_share(raw: dict, scope: str, reader_file: str) -> Optional[float]:
-    """100 x device time of the ops under ``scope`` / busy time, in the
-    traced slice of the run ``raw`` came from; ``reader_file`` is the
-    calling reader's ``__file__`` (``<root>/benchmark/metrics/x.py``:
-    the run's trace lies under that root). None where the run was not
-    traced, the program is one without the scopes (told by its
-    ``stats()``, which then lacks the phases of the same PR: op names
-    cannot tell, because a compile cache shared with a scoped program
-    hands its executables, names included, to an unscoped one with the
-    same HLO), the file is not that run's (its busy time differs from
-    the one ``trace.reduce`` read), or no op carries a scope at all."""
+def _run_trace(raw: dict, reader_file: str) -> Optional[Tuple[dict, dict]]:
+    """(``load``, ``scope_seconds``) of the trace of the run ``raw`` came
+    from; ``reader_file`` is the calling reader's ``__file__``
+    (``<root>/benchmark/metrics/x.py``: the run's trace lies under that
+    root). None where the run was not traced, no file is there, no
+    operation ran on a device, or the file is not that run's (its busy
+    time differs from the one ``trace.reduce`` read)."""
     if not raw.get("trace"):
-        return None
-    if counters.PHASES_KEY not in raw.get("stats1", {}):
         return None
     root = os.path.abspath(reader_file)
     for _ in range(3):
@@ -232,14 +227,54 @@ def scope_share(raw: dict, scope: str, reader_file: str) -> Optional[float]:
     path = find_xplane(root)
     if path is None:
         return None
-    got = scope_seconds(load(path))
+    loaded = load(path)
+    got = scope_seconds(loaded)
     if not got or got["busy_s"] <= 0:
         return None
     if abs(got["busy_s"] - raw["trace"]["busy_s"]) > 0.01 * got["busy_s"]:
         return None
+    return loaded, got
+
+
+def scope_share(raw: dict, scope: str, reader_file: str) -> Optional[float]:
+    """100 x device time of the ops under ``scope`` / busy time, in the
+    traced slice of the run ``raw`` came from (``_run_trace``). None
+    also where the program is one without the scopes (told by its
+    ``stats()``, which then lacks the phases of the same PR: op names
+    cannot tell, because a compile cache shared with a scoped program
+    hands its executables, names included, to an unscoped one with the
+    same HLO), or no op carries a scope at all."""
+    if counters.PHASES_KEY not in raw.get("stats1", {}):
+        return None
+    found = _run_trace(raw, reader_file)
+    if found is None:
+        return None
+    got = found[1]
     if set(got["scopes"]) <= {"unscoped"}:
         return None
     return 100.0 * got["scopes"].get(scope, 0.0) / got["busy_s"]
+
+
+def op_seconds(raw: dict, pattern: str, reader_file: str) -> Optional[float]:
+    """Device seconds of the ops whose kind (``trace._op``: the
+    instruction's name without its number, ``paged_attention_decode_
+    layered``, ``fusion``) matches the regular expression ``pattern``
+    from its start, averaged over the chips, in the traced slice of the
+    run ``raw`` came from (``_run_trace``): what ``raw["trace"]
+    ["kernel_s"]`` is for the one kernel ``trace.reduce`` knows. Ops
+    that only contain other ops are left out. 0.0 where none matches."""
+    found = _run_trace(raw, reader_file)
+    if found is None:
+        return None
+    planes = found[0]["ops"]
+    hit = re.compile(pattern)
+    total = 0.0
+    for ops in planes.values():
+        for name, _, d, _ in ops:
+            kind = trace._op(name)[0]
+            if hit.match(kind) and not trace.CONTAINER_OP.match(kind):
+                total += d
+    return total / len(planes)
 
 
 def exclusive_phases(phases: List[Event]) -> List[Event]:

@@ -3,8 +3,9 @@ process touches JAX and holds the engine, the OpenAI frontend runs
 in-process through ``run.run_http(args, built=...)`` and is stopped by
 its own SIGTERM path. Copied from chip_smoke.py (ran on the chip in
 PR 21): the device check, the server start and stop, the greedy
-request, the comparison rule. Not copied: its reference (see
-benchmark/reference.py for why).
+request, the comparison rule. Not copied: its reference. Each
+configuration names its own (``cells.load_reference``); the rule of
+agreement is one for all of them (``benchmark/reference.py judge``).
 """
 
 from __future__ import annotations
@@ -72,7 +73,8 @@ def build(cell: dict, seed: int, port: int):
         "--http-port", str(port), "--no-warmup"])
     cfg, ecfg, _none, quant, mesh = run._jax_engine_setup(args)
     ecfg = dataclasses.replace(ecfg, **cells.engine_overrides(cell))
-    params = weights.make_params(get_model_module(cfg), cfg, seed)
+    params = weights.make_params(get_model_module(cfg), cfg, seed,
+                                 cell["weight_scales"])
     engine = JaxEngine(cfg, ecfg, params=params, seed=args.seed, mesh=mesh,
                        quant=quant)
     mdc = run.build_mdc(args)
@@ -106,13 +108,11 @@ AGREE_PROMPT = 96
 AGREE_STEPS = 8
 
 
-def _reference_logprobs(engine, prompt, toks):
+def _reference_logprobs(engine, reference_logits, prompt, toks):
     """[1 + AGREE_STEPS, V] reference logprobs at the positions the
     engine sampled from, teacher-forced on the engine's own tokens."""
     import jax
     import numpy as np
-
-    from benchmark.reference import reference_logits
 
     seq = list(prompt) + toks[:-1]
     # called before warmup() arms the compile fence: the reference's
@@ -122,10 +122,11 @@ def _reference_logprobs(engine, prompt, toks):
         return np.asarray(jax.nn.log_softmax(logits[len(prompt) - 1:], -1))
 
 
-async def agree(engine, seed: int) -> dict:
+async def agree(engine, seed: int, reference_logits) -> dict:
     """reference.PROMPTS seeded 96-token prompts + 8 greedy steps each
-    against benchmark/reference.py, on the cell's own engine, before the
-    window; all positions judged together (``reference.judge``)."""
+    against the configuration's ``reference_logits``, on the cell's own
+    engine, before the window; all positions judged together
+    (``reference.judge``)."""
     import random
 
     import numpy as np
@@ -141,8 +142,8 @@ async def agree(engine, seed: int) -> dict:
         check(len(toks) == 1 + AGREE_STEPS and len(tops) == len(toks),
               f"engine returned {len(toks)} tokens / {len(tops)} "
               f"logprob rows")
-        refs.append(await asyncio.to_thread(_reference_logprobs, engine,
-                                            prompt, toks))
+        refs.append(await asyncio.to_thread(
+            _reference_logprobs, engine, reference_logits, prompt, toks))
         all_toks += toks
         all_tops += tops
     return judge(np.concatenate(refs), all_toks, all_tops)

@@ -11,8 +11,11 @@ slice under ``lax.map``, so at most one layer's float32 draw is alive.
 
 Rule per leaf, by its name: ``ln_*`` / ``*_norm`` are ones, ``b*``
 (biases) zeros, everything else normal with std 1/sqrt(fan_in) (fan_in =
-second-to-last axis), the rule ``init_params`` uses. Two leaves get a
-scale of their own, for reasons that are the benchmark's:
+second-to-last axis), the rule ``init_params`` uses. A configuration
+names the leaves that need another scale in its about.json
+(``weight_scales``: leaf name -> multiple of what the rule gives, or
+``"zeros"``; harness/cells.py). One leaf gets a scale of its own from
+the benchmark itself:
 
 ``lm_head``: the columns of the printable ASCII ids (32..126) keep the
 rule's scale, every other column gets 1/8 of it. The byte tokenizer
@@ -24,7 +27,10 @@ as a content chunk of one character per token and the clients need not
 ask for logprobs to be able to time chunks. The head's shape, type and
 work are unchanged.
 
-``w_router``: ROUTER_GAIN times the rule's scale, see the constant.
+Why ``w_router`` is 2.0 in the two top-k-softmax configurations (router
+logits of std ~2: where bf16 and float32 choose a different k-th expert
+the swapped one is light; the agreement check reads lowest there on the
+chip): benchmark/reference.py and PERF.md, Findings PR 23.
 """
 
 from __future__ import annotations
@@ -33,13 +39,6 @@ import math
 
 PRINTABLE = (32, 127)      # ids the byte tokenizer decodes to one ASCII char
 OTHER_IDS_SCALE = 0.125
-# The router's weights are drawn at ROUTER_GAIN times the rule's scale
-# (router logits of std ~2 over a unit-RMS input): a softmax over the
-# chosen experts as peaked as a trained router's, so that where bf16 and
-# float32 choose a different k-th expert the swapped one is light. 2 is
-# where the agreement check reads lowest on both configurations on the
-# chip (benchmark/reference.py; PERF.md, Findings PR 23).
-ROUTER_GAIN = 2.0
 
 
 def seed_key(seed: int):
@@ -52,9 +51,10 @@ def seed_key(seed: int):
                               seed >> 31)
 
 
-def build_tree(model, cfg, key):
+def build_tree(model, cfg, key, scales):
     """The parameter tree from a key; traceable (make_params jits it,
-    rehearse.py compiles it for a described chip)."""
+    rehearse.py compiles it for a described chip). ``scales`` is the
+    configuration's ``weight_scales``."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -62,6 +62,10 @@ def build_tree(model, cfg, key):
     shapes = jax.eval_shape(
         lambda: model.init_params(cfg, jax.random.PRNGKey(0)))
     names = sorted(shapes)
+    unknown = sorted(set(scales) - set(names))
+    if unknown:
+        raise ValueError(f"weight_scales names {unknown}: no such leaf "
+                         f"in the tree ({names})")
 
     def draw(key, shape, dtype, gain=1.0):
         scale = 1.0 / math.sqrt(shape[-2]) if len(shape) > 1 else 0.02
@@ -69,16 +73,17 @@ def build_tree(model, cfg, key):
                 * (scale * gain)).astype(dtype)
 
     def leaf(name, key, sds):
-        if name.startswith("ln_") or name.endswith("_norm"):
-            return jnp.ones(sds.shape, sds.dtype)
-        if name.startswith("b"):
+        gain = scales.get(name, 1.0)
+        if gain == "zeros" or name.startswith("b"):
             return jnp.zeros(sds.shape, sds.dtype)
+        if name.startswith("ln_") or name.endswith("_norm"):
+            return jnp.full(sds.shape, gain, sds.dtype)
         if name == "lm_head":
             ids = jnp.arange(sds.shape[-1])
-            gain = jnp.where((ids >= PRINTABLE[0]) & (ids < PRINTABLE[1]),
-                             1.0, OTHER_IDS_SCALE)
+            gain = gain * jnp.where(
+                (ids >= PRINTABLE[0]) & (ids < PRINTABLE[1]), 1.0,
+                OTHER_IDS_SCALE)
             return draw(key, sds.shape, sds.dtype, gain)
-        gain = ROUTER_GAIN if name == "w_router" else 1.0
         if len(sds.shape) >= 3:
             return lax.map(lambda k: draw(k, sds.shape[1:], sds.dtype, gain),
                            jax.random.split(key, sds.shape[0]))
@@ -88,7 +93,8 @@ def build_tree(model, cfg, key):
     return {n: leaf(n, k, shapes[n]) for n, k in zip(names, keys)}
 
 
-def make_params(model, cfg, seed: int):
+def make_params(model, cfg, seed: int, scales: dict):
     import jax
 
-    return jax.jit(lambda key: build_tree(model, cfg, key))(seed_key(seed))
+    return jax.jit(lambda key: build_tree(model, cfg, key, scales))(
+        seed_key(seed))

@@ -177,6 +177,9 @@ def _layer(cfg, params, h, l):
     return h + out
 
 
+layer = _layer      # the one-layer program rehearse.py compiles
+
+
 def reference_logits(params, cfg, tokens):
     """Logits [T, V] float32 for one sequence of token ids."""
     import jax

@@ -6,6 +6,10 @@ HBM, a kernel the tiling cannot hold).
 
     JAX_PLATFORMS=cpu python3 benchmark/rehearse.py --workload <cell>
 
+--root <dir> reads BENCHMARK.json and the cell's files from a copy (the
+code stays this checkout's): a configuration is sized there before it
+is in the repo's BENCHMARK.json.
+
 Prints, per program, arguments + temporaries as the compiler counts
 them, and the sum a serving process holds (parameters + KV pool + the
 largest program's temporaries) against the 15.75 GB the compiler allows.
@@ -24,7 +28,8 @@ import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 
 HBM_LIMIT = 15.75 * 2 ** 30     # what the v5e compiler allows a program
 
@@ -32,6 +37,8 @@ HBM_LIMIT = 15.75 * 2 ** 30     # what the v5e compiler allows a program
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
+    ap.add_argument("--root", default=ROOT,
+                    help="where BENCHMARK.json and benchmark/ are read from")
     ap.add_argument("--topn", default="0",
                     help="logprobs_topn variants of the window, e.g. 0,20")
     a = ap.parse_args(argv)
@@ -42,7 +49,8 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from benchmark.harness import cells, weights
-    from dynamo_tpu.engine.jax_engine import EngineConfig
+    from dynamo_tpu.engine.jax_engine import (EngineConfig,
+                                              _make_decode_multi)
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
     from dynamo_tpu.models.registry import get_model_module
@@ -60,7 +68,7 @@ def main(argv=None) -> int:
     def on(tree):
         return jax.tree.map(lambda x: s(x.shape, x.dtype), tree)
 
-    cell = cells.load_cell(a.workload)
+    cell = cells.load_cell(a.workload, a.root)
     cfg = ModelConfig.from_local_path(cell["model_path"])
     model = get_model_module(cfg)
     ecfg = dataclasses.replace(EngineConfig(),
@@ -89,7 +97,11 @@ def main(argv=None) -> int:
         print(json.dumps(row), flush=True)
 
     prefill, _ = model.make_step_fns(cfg)
-    window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
+    # the decode program as JaxEngine.__init__ chooses it
+    if hasattr(model, "make_decode_window_fn"):
+        window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
+    else:
+        window = _make_decode_multi(model, cfg, ecfg.max_top_k)
     ps = ecfg.page_size
     for P in grid["page_buckets"]:
         for T in grid["prefill_lens"]:
@@ -108,17 +120,17 @@ def main(argv=None) -> int:
                     kv_v, s((B, P), jnp.int32), f32, i32, f32,
                     s((B,), jnp.uint32), s((B, ecfg.max_eos_ids), jnp.int32),
                     None, k_steps=ecfg.decode_steps, logprobs_topn=topn))
-    # the benchmark's own programs: the weights and one reference layer
+    # the benchmark's own programs: the weights and, where the
+    # configuration's reference exposes it, one reference layer
     key = s((2,), jnp.uint32)
-    record("weights.make_params", jax.jit(
-        lambda k: weights.build_tree(model, cfg, k)).lower(key))
+    record("weights.make_params", jax.jit(lambda k: weights.build_tree(
+        model, cfg, k, cell["weight_scales"])).lower(key))
     from functools import partial
 
-    from benchmark import reference
-
-    T = 104
-    record("reference layer T=104", jax.jit(
-        partial(reference._layer, cfg)).lower(
+    layer = getattr(cells.load_reference(cell), "layer", None)
+    if layer is not None:
+        T = 104
+        record("reference layer T=104", jax.jit(partial(layer, cfg)).lower(
             params, s((T, cfg.hidden_size), jnp.float32), s((), jnp.int32)))
     worst = max(r["temporaries_gb"] for r in out["programs"]
                 if not r["program"].startswith("weights"))

@@ -6,10 +6,10 @@
 
 Builds the cell's engine on the chip (weights made on the device from
 --seed, the persistent compile cache on, DYN_JIT_FENCE=raise), warms the
-cell's own grid, checks agreement with benchmark/reference.py, starts
-the OpenAI frontend in-process, starts the load generator as a child
-process that never imports jax, measures for --seconds, and prints as
-its LAST line one JSON object: correct, attempted, failed, metrics,
+cell's own grid, checks agreement with the configuration's reference,
+starts the OpenAI frontend in-process, starts the load generator as a
+child process that never imports jax, measures for --seconds, and prints
+as its LAST line one JSON object: correct, attempted, failed, metrics,
 device (and breakdown with --trace 1). --trace 0 reports the cell's
 end-to-end metrics, --trace 1 its per-layer metrics (a profiler trace of
 a 5 s slice in the middle of the window is taken then). Earlier lines
@@ -105,7 +105,14 @@ async def _window(cell, engine, base, mdc_name, a, trace_dir=None,
                      "num_kv_heads": engine.cfg.num_kv_heads,
                      "head_dim": engine.cfg.head_dim_,
                      "page_size": engine.ecfg.page_size,
-                     "kv_itemsize": engine.kv_k.dtype.itemsize},
+                     "kv_itemsize": engine.kv_k.dtype.itemsize,
+                     # what the six keys above cannot say of another
+                     # family: the configuration as run, and the
+                     # engine's pools as they are
+                     "config": dict(cell["model_config"]),
+                     "kv_pools": [{"shape": list(p.shape),
+                                   "itemsize": p.dtype.itemsize}
+                                  for p in (engine.kv_k, engine.kv_v)]},
            "trace": None, "trace_slice": None}
     if rate is not None:
         raw["traffic"]["rate_rps"] = rate
@@ -162,6 +169,7 @@ def _client_notes(raw: dict) -> dict:
 
 
 async def amain(a, cell, dev, root) -> dict:
+    reference = cells.load_reference(cell)
     port = serve.free_port()
     args, built = await asyncio.to_thread(serve.build, cell, a.seed, port)
     engine, mdc, _ = built
@@ -170,7 +178,7 @@ async def amain(a, cell, dev, root) -> dict:
         # of the window does: its programs compile here, before warmup()
         # arms the compile fence, and the window's grid needs no
         # logprobs variant
-        res = await serve.agree(engine, a.seed)
+        res = await serve.agree(engine, a.seed, reference.reference_logits)
         note("agree", **res)
         t0 = time.monotonic()
         compiles = await asyncio.to_thread(engine.warmup)

@@ -4,6 +4,7 @@ without a chip, and against the files it names."""
 import json
 import os
 import re
+import shutil
 
 import pytest
 
@@ -76,6 +77,51 @@ def test_config_entry(cfg):
             assert run[key] == pub, key
     assert any(w["config"] == cfg["name"] for w in B["workloads"])
     assert len({c["file"] for c in B["configs"]}) == len(B["configs"])
+    # the configuration's own reference: a file under paths that has
+    # reference_logits; its weight scales: numbers or "zeros"
+    from benchmark.harness import cells
+
+    cell = next(w["name"] for w in B["workloads"]
+                if w["config"] == cfg["name"])
+    loaded = cells.load_cell(cell)
+    ref = os.path.relpath(loaded["reference_file"], ROOT)
+    assert ref == about["reference"]
+    assert any(ref.startswith(p + "/") for p in B["paths"])
+    assert callable(cells.load_reference(loaded).reference_logits)
+    assert loaded["weight_scales"] == about.get("weight_scales", {})
+
+
+@pytest.mark.parametrize("about, said", [
+    ({}, '"reference" key'),
+    ({"reference": None}, '"reference" key'),
+    ({"reference": "benchmark/configs/none/reference.py"}, "not a file"),
+    ({"reference": "benchmark/../bench.py"}, "not a file under benchmark/"),
+    ({"reference": "benchmark/reference.py",
+      "weight_scales": {"w_router": "big"}}, '"weight_scales"'),
+    (None, "about.json"),
+])
+def test_a_configuration_that_names_no_reference_is_refused(tmp_path, about,
+                                                            said):
+    """At load_cell, before anything is built, by a message that names
+    the key."""
+    from benchmark.harness import cells
+
+    shutil.copytree(BENCH, tmp_path / "benchmark",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    shutil.copy(os.path.join(ROOT, "bench.py"), tmp_path)
+    cfg = B["configs"][0]
+    path = tmp_path / os.path.dirname(cfg["file"]) / "about.json"
+    cell = next(w["name"] for w in B["workloads"]
+                if w["config"] == cfg["name"])
+    assert cells.load_cell(cell, str(tmp_path))["reference_file"] == str(
+        tmp_path / "benchmark" / "reference.py")
+    if about is None:
+        path.unlink()
+    else:
+        path.write_text(json.dumps(about))
+    with pytest.raises(SystemExit, match=said):
+        cells.load_cell(cell, str(tmp_path))
 
 
 @pytest.mark.parametrize("cell", B["workloads"], ids=lambda w: w["name"])
@@ -123,9 +169,10 @@ def test_metric_entry_and_reader(m):
             moved.get("workloads", CELLS))
         if m["name"].endswith("_roofline"):
             assert m["unit"] == "%"
-    assert os.path.isfile(os.path.join(BENCH, "metrics", m["name"] + ".py"))
     from benchmark.harness import cells
 
+    # a variant <quantity>.<variant> is read by the quantity's file
+    assert os.path.isfile(cells.reader_path(m["name"]))
     assert callable(cells.load_reader(m["name"]))
 
 
@@ -142,3 +189,21 @@ def test_layers_are_perf_md_layers():
         perf = f.read()
     for m in B["per_layer"]:
         assert f"| {m['layer']} |" in perf, m["layer"]
+
+
+def test_variant_is_read_by_its_quantity_unless_it_has_a_file(tmp_path):
+    """``<quantity>.<variant>`` (a quantity listed again for cells where
+    it moves another end-to-end metric) needs no file of its own."""
+    from benchmark.harness import cells
+
+    mdir = tmp_path / "benchmark" / "metrics"
+    mdir.mkdir(parents=True)
+    (mdir / "wait_ms.py").write_text("def read(raw):\n    return 1.0\n")
+    root = str(tmp_path)
+    assert cells.load_reader("wait_ms.some-cell", root)({}) == 1.0
+    (mdir / "wait_ms.some-cell.py").write_text(
+        "def read(raw):\n    return 2.0\n")
+    assert cells.load_reader("wait_ms.some-cell", root)({}) == 2.0
+    assert cells.load_reader("wait_ms", root)({}) == 1.0
+    with pytest.raises(FileNotFoundError):
+        cells.load_reader("nothing.some-cell", root)

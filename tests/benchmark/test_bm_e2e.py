@@ -1,9 +1,12 @@
 """The whole command end to end at tiny size on the CPU, from a temp
-copy of the benchmark to which one configuration, one traffic mix, one
-cell and one per-layer metric are ADDED as files and BENCHMARK.json
-entries — no file that exists is edited. The device check is stubbed
-(``require_platform=None``) only here; without the stub the same
-command exits non-zero and prints no result line."""
+copy of the benchmark to which two configurations (``tiny-moe`` of the
+family the harness was written around, ``tiny-mla`` of another: latent
+cache, a leading dense layer, routed + shared experts, sigmoid
+``noaux_tc`` routing, with its own reference file and weight scales),
+two traffic mixes, three cells and two per-layer metrics are ADDED as
+files and BENCHMARK.json entries — no file that exists is edited. The
+device check is stubbed (``require_platform=None``) only here; without
+the stub the same command exits non-zero and prints no result line."""
 
 import json
 import os
@@ -22,6 +25,47 @@ TINY_CONFIG = {
     "num_local_experts": 4, "num_experts_per_tok": 2,
     "rope_theta": 10000.0, "rms_norm_eps": 1e-05,
     "max_position_embeddings": 2048}
+# routed_scaling_factor: at Moonlight's 2.446 one swapped expert of 8 at
+# width 64 moves a position by 1.0-2.1 (FLIP_ATOL is 2.5) on most seeds,
+# whatever w_router's scale (1 to 8 tried): a matter of the tiny size,
+# and this test is of the plumbing. At 1.0 and _run's seed the 27
+# positions read 0.10 at most.
+TINY_MLA_CONFIG = {
+    "model_type": "deepseek_v3", "vocab_size": 512, "hidden_size": 64,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "num_attention_heads": 4, "num_key_value_heads": 4,
+    "kv_lora_rank": 32, "q_lora_rank": None, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "v_head_dim": 16, "n_routed_experts": 8,
+    "n_shared_experts": 2, "num_experts_per_tok": 2, "n_group": 1,
+    "topk_group": 1, "norm_topk_prob": True, "scoring_func": "sigmoid",
+    "topk_method": "noaux_tc", "routed_scaling_factor": 1.0,
+    "rope_theta": 50000, "rms_norm_eps": 1e-05,
+    "tie_word_embeddings": False, "max_position_embeddings": 2048}
+# what a configuration's about.json has to say to the harness
+ABOUT = {
+    "tiny-moe": {"reference": "benchmark/reference.py",
+                 "weight_scales": {"w_router": 2.0}},
+    "tiny-mla": {"reference": "benchmark/configs/tiny-mla/reference.py",
+                 "weight_scales": {"w_router": 2.0,
+                                   "router_bias": "zeros"}},
+}
+MLA_REFERENCE = '''"""Added by the test, for the plumbing only: it wraps the PROGRAM's own
+mla.reference_forward (float32 copies of the tiny tree), so it is not
+independent of the code under test. The plain MLA reference is the PR's
+that adds an MLA configuration to the benchmark."""
+
+
+def reference_logits(params, cfg, tokens):
+    import jax
+    import jax.numpy as jnp
+
+    from dynamo_tpu.models import mla
+
+    params = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+    return mla.reference_forward(
+        params, cfg, jnp.asarray(tokens, jnp.int32)[None])[0]
+'''
 TINY_ENGINE = {
     "page_size": 16, "num_pages": 64, "max_batch": 4, "batch_buckets": [4],
     "prefill_chunk": 128, "prefill_buckets": [128], "page_buckets": [8],
@@ -38,11 +82,42 @@ TRAFFIC = {
         "prompt_len": {"dist": "uniform", "min": 8, "max": 40},
         "output_len": {"dist": "uniform", "min": 6, "max": 10}},
 }
+CELLS = ["tiny-moe.tiny-open", "tiny-moe.tiny-closed",
+         "tiny-mla.tiny-closed"]
 NEW_METRIC = '''"""Added by the test: requests the clients sent."""
 
 
 def read(raw):
     return len(raw["rows"])
+'''
+POOL_METRIC = '''"""Added by the test: bytes of cache the tokens decoded in the window
+read at the least (a token attends to its context once in every layer),
+over the device seconds of the fusions in the traced slice. The bytes of
+a cached token are counted from the engine's pools as they are, whatever
+their layout, and checked against the configuration as run."""
+
+from benchmark.harness import host_trace
+
+
+def read(raw):
+    m = raw["model"]
+    per_token = 0
+    for pool in m["kv_pools"]:
+        layers, _pages, heads, page_size, width = pool["shape"]
+        if layers != m["num_layers"] or page_size != m["page_size"]:
+            raise ValueError(f"pool {pool} is not [L, pages, h, ps, d]")
+        per_token += layers * heads * width * pool["itemsize"]
+    c = m["config"]
+    if "kv_lora_rank" in c:
+        latent = c["kv_lora_rank"] + c["qk_rope_head_dim"]
+        if per_token != m["num_layers"] * latent * m["kv_itemsize"]:
+            raise ValueError(f"{per_token} bytes a token is no latent pool")
+    seconds = host_trace.op_seconds(raw, "fusion", __file__)
+    if not seconds:
+        return None
+    read_tokens = sum(r["prompt_len"] + j for r in raw["rows"]
+                      for j in range(1, sum(r["chunk_n"])))
+    return per_token * read_tokens / seconds / 1e9
 '''
 
 
@@ -55,31 +130,42 @@ def root(tmp_path_factory):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         b = json.load(f)
     bdir = os.path.join(root, "benchmark")
-    os.makedirs(os.path.join(bdir, "configs", "tiny-moe"))
-    _dump(os.path.join(bdir, "configs", "tiny-moe", "config.json"),
-          TINY_CONFIG)
-    b["configs"].append({
-        "name": "tiny-moe", "source": "test", "reduced": [], "why": "test",
-        "file": "benchmark/configs/tiny-moe/config.json"})
+    for name, config in (("tiny-moe", TINY_CONFIG),
+                         ("tiny-mla", TINY_MLA_CONFIG)):
+        os.makedirs(os.path.join(bdir, "configs", name))
+        _dump(os.path.join(bdir, "configs", name, "config.json"), config)
+        _dump(os.path.join(bdir, "configs", name, "about.json"), ABOUT[name])
+        b["configs"].append({
+            "name": name, "source": "test", "reduced": [], "why": "test",
+            "file": f"benchmark/configs/{name}/config.json"})
+    with open(os.path.join(bdir, "configs", "tiny-mla", "reference.py"),
+              "w") as f:
+        f.write(MLA_REFERENCE)
     for mix, params in TRAFFIC.items():
-        cell = f"tiny-moe.{mix}"
         _dump(os.path.join(bdir, "traffic", mix + ".json"), params)
+    for cell in CELLS:
+        config, mix = cell.split(".")
         _dump(os.path.join(bdir, "workloads", cell + ".json"), {
-            "config": "tiny-moe", "traffic": mix, "chips": 1,
+            "config": config, "traffic": mix, "chips": 1,
             "engine": TINY_ENGINE})
-        b["workloads"].append({"name": cell, "config": "tiny-moe",
+        b["workloads"].append({"name": cell, "config": config,
                                "traffic": mix, "chips": 1, "why": "test"})
-    with open(os.path.join(bdir, "metrics", "requests_sent.py"), "w") as f:
-        f.write(NEW_METRIC)
-    b["per_layer"].append({
-        "name": "requests_sent", "unit": "requests", "better": "higher",
-        "source": "host_clock", "layer": "HTTP frontend and client",
-        "moves": "tpot_p50_ms", "workloads": ["tiny-moe.tiny-open"]})
+    added = {"requests_sent": (NEW_METRIC, "tiny-moe.tiny-open"),
+             "cache_read_gb_s": (POOL_METRIC, "tiny-mla.tiny-closed")}
+    for name, (source, cell) in added.items():
+        with open(os.path.join(bdir, "metrics", name + ".py"), "w") as f:
+            f.write(source)
+        b["per_layer"].append({
+            "name": name, "unit": "x", "better": "higher",
+            "source": "host_clock", "layer": "HTTP frontend and client",
+            "moves": "tpot_p50_ms", "workloads": [cell]})
+    # each new cell reports what the cell of today with its loop reports
+    like = {"tiny-moe.tiny-open": "mixtral-8x7b.chat-steady",
+            "tiny-moe.tiny-closed": "qwen3-30b-a3b.decode-heavy",
+            "tiny-mla.tiny-closed": "qwen3-30b-a3b.decode-heavy"}
     for m in b["end_to_end"] + b["per_layer"]:
-        if "workloads" in m and m["name"] != "requests_sent":
-            m["workloads"].append(
-                "tiny-moe.tiny-closed" if m["name"] == "output_tok_s"
-                else "tiny-moe.tiny-open")
+        if "workloads" in m and m["name"] not in added:
+            m["workloads"] += [c for c in CELLS if like[c] in m["workloads"]]
     _dump(os.path.join(root, "BENCHMARK.json"), b)
     return root
 
@@ -125,8 +211,10 @@ def test_open_loop_cell_end_to_end(root):
     assert line["device"]["platform"] == "cpu"     # never a device number
 
 
-def test_closed_loop_cell_end_to_end(root):
-    proc = _run(root, "tiny-moe.tiny-closed", 0, seconds=3)
+@pytest.mark.parametrize("cell", ["tiny-moe.tiny-closed",
+                                  "tiny-mla.tiny-closed"])
+def test_closed_loop_cell_end_to_end(root, cell):
+    proc = _run(root, cell, 0, seconds=3)
     line = _last_line(proc)
     assert line["correct"] is True and line["attempted"] >= 3
     assert set(line["metrics"]) == {"tpot_p50_ms", "output_tok_s",
@@ -137,13 +225,17 @@ def test_closed_loop_cell_end_to_end(root):
     assert client["cut_by_window_end"] <= 3 and client["failed"] == 0
 
 
-def test_traced_run_without_a_device_plane_is_refused(root):
+@pytest.mark.parametrize("cell", ["tiny-moe.tiny-open",
+                                  "tiny-mla.tiny-closed"])
+def test_traced_run_without_a_device_plane_is_refused(root, cell):
     """On the CPU the profiler's trace has no /device:TPU plane: the
     per-layer line must not appear (a traced run in which no operation
-    ran on a device is no measurement)."""
-    proc = _run(root, "tiny-moe.tiny-open", 1, seconds=6)
+    ran on a device is no measurement). The refusal comes after every
+    per-layer reader of the cell has read the run's raw material, the
+    added ones among them: one that raised would end the run before it."""
+    proc = _run(root, cell, 1, seconds=6)
     assert proc.returncode != 0
-    assert "no operation on a device" in proc.stderr
+    assert "no operation on a device" in proc.stderr, proc.stderr[-3000:]
     assert not any(ln.startswith('{"correct"')
                    for ln in proc.stdout.splitlines())
     # the counters and client-side readers did their work before that

@@ -159,6 +159,66 @@ def test_scope_share_reads_the_runs_own_newest_trace(xplane, tmp_path):
     ) is None
 
 
+def _place(xplane, root):
+    """The hand-made trace as the newest traced run under ``root``."""
+    d = os.path.join(root, ".bench_trace", "hand", "plugins", "profile", "t1")
+    os.makedirs(d)
+    os.link(xplane, os.path.join(d, "host.xplane.pb"))
+
+
+def test_op_seconds_sums_the_ops_a_pattern_names(xplane, tmp_path):
+    """The second helper a reader of its own file can call: no scopes
+    and no stats() needed, the same guards on whose trace it is."""
+    _place(xplane, str(tmp_path))
+    reader = str(tmp_path / "benchmark" / "metrics" / "some_kernel_s.py")
+    raw = {"trace": {"busy_s": 1100e-6}}
+    us = 1e-6
+    assert host_trace.op_seconds(raw, "fusion", reader) == \
+        pytest.approx(500 * us)
+    assert host_trace.op_seconds(raw, "sort|copy", reader) == \
+        pytest.approx(300 * us)
+    assert host_trace.op_seconds(raw, "usion", reader) == 0.0   # from its start
+    # a container's time is its body's: everything but the while
+    assert host_trace.op_seconds(raw, ".", reader) == pytest.approx(800 * us)
+    assert host_trace.op_seconds(raw, "paged_attention", reader) == 0.0
+    # not this run's file, not traced, no file under the reader's root
+    assert host_trace.op_seconds({"trace": {"busy_s": 2.0}}, "fusion",
+                                 reader) is None
+    assert host_trace.op_seconds({"trace": None}, "fusion", reader) is None
+    assert host_trace.op_seconds(
+        raw, "fusion", str(tmp_path / "x" / "benchmark" / "metrics" / "m.py")
+    ) is None
+
+
+def test_an_added_reader_counts_a_latent_pool_from_the_raw_material(
+        xplane, root):  # noqa: F811
+    """test_bm_e2e's ``cache_read_gb_s``, a file added to the copy: the
+    bytes of a cached token from ``raw["model"]["kv_pools"]``, checked
+    against ``raw["model"]["config"]``, over ``op_seconds``."""
+    from test_bm_e2e import TINY_MLA_CONFIG
+
+    from benchmark.harness import cells
+
+    read = cells.load_reader("cache_read_gb_s", root)
+    model = {"num_layers": 3, "page_size": 16, "kv_itemsize": 2,
+             "config": dict(TINY_MLA_CONFIG),
+             "kv_pools": [{"shape": [3, 64, 1, 16, 32], "itemsize": 2},
+                          {"shape": [3, 64, 1, 16, 8], "itemsize": 2}]}
+    rows = [{"prompt_len": 10, "chunk_n": [1, 2]},      # 11 + 12
+            {"prompt_len": 20, "chunk_n": [1]}]         # prefill only
+    raw = {"trace": None, "model": model, "rows": rows}
+    assert read(raw) is None                            # not traced
+    _place(xplane, root)
+    raw["trace"] = {"busy_s": 1100e-6}
+    assert read(raw) == pytest.approx(240 * 23 / 500e-6 / 1e9)
+    gqa = dict(model, config={}, kv_pools=[
+        {"shape": [3, 64, 2, 16, 16], "itemsize": 2}] * 2)
+    assert read(dict(raw, model=gqa)) == pytest.approx(
+        384 * 23 / 500e-6 / 1e9)
+    with pytest.raises(ValueError, match="no latent pool"):
+        read(dict(raw, model=dict(model, kv_pools=gqa["kv_pools"])))
+
+
 def test_nested_phases_are_cut_into_disjoint_pieces():
     us = 1e-6
     phases = [("dyn.step", 100 * us, 1100 * us),
