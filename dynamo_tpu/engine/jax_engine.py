@@ -51,7 +51,8 @@ from ..runtime import blackbox, guard, profiling, slo, tracing
 from ..runtime.config import env_bool, env_flag, env_int, env_str
 from ..runtime.engine import Context
 from .jit_fence import CompileFence
-from .kv_manager import ChainHashCache, PageManager
+from .kv_manager import (RECURRENT_STATE_REFUSAL, ChainHashCache,
+                         PageManager)
 from .profiler import EngineProfiler, memory_snapshot
 from .sampling import (SamplingBatch, logprob_aux, sample_tokens,
                        update_penalty_state, verify_greedy_draft)
@@ -318,6 +319,9 @@ class Sequence:
     tokens: List[int]            # prompt + generated (host truth)
     num_prompt: int
     pages: List[int] = field(default_factory=list)
+    # row of the recurrent-state pool, for a model that keeps state
+    # beside its pages (claimed at admission, released with the pages)
+    state_slot: Optional[int] = None
     computed: int = 0            # positions already in the KV cache
     generated: int = 0
     finished: Optional[str] = None
@@ -479,6 +483,24 @@ class JaxEngine:
             spec = KVCacheSpec(self.ecfg.num_pages, self.ecfg.page_size)
             self.kv_k, self.kv_v = model.init_kv_cache(model_cfg, spec,
                                                        dtype)
+            # recurrent state beside the paged KV, for a model module that
+            # declares it (init_state): one pool the step thread donates
+            # through every program as it does the KV pools, max_batch
+            # slots + one drop slot that padding rows read and write.
+            # None for every other module: their programs take no operand
+            # for it and their call forms are unchanged.
+            self.state = None
+            self._state_free: List[int] = []
+            # slots held and slots there, summed at every decode dispatch
+            # (_count_decode_slots): the pool's fill over a window is a
+            # ratio of two deltas, and idle time around it counts nothing
+            self.state_slots_held_total = 0
+            self.state_slots_seen_total = 0
+            if hasattr(model, "init_state"):
+                _refuse_recurrent_state(self.ecfg, mesh)
+                self.state = model.init_state(
+                    model_cfg, self.ecfg.max_batch + 1, dtype)
+                self._state_free = list(range(self.ecfg.max_batch))[::-1]
         if mesh is not None:
             from ..parallel.mesh import shard_kv_cache, shard_params
             self.params = shard_params(self.params, model_cfg, mesh)
@@ -556,7 +578,9 @@ class JaxEngine:
         self.pm = PageManager(self.ecfg.num_pages,  # guarded-by: self._pm_lock
                               self.ecfg.page_size,
                               host_pages=self.ecfg.host_pages,
-                              evict_policy=self.ecfg.evict_policy)
+                              evict_policy=self.ecfg.evict_policy,
+                              # a hit hands over pages and no state
+                              prefix_reuse=self.state is None)
         # host-DRAM offload pools (same per-page layout as the HBM pool)
         self.host_k = self.host_v = None
         self.host_k_s = self.host_v_s = None
@@ -728,6 +752,25 @@ class JaxEngine:
             return contextlib.nullcontext()
         return jax.default_device(self.device)
 
+    def _state_args(self, slots) -> tuple:
+        """The two trailing operands of a step program of a model with
+        recurrent state (the pool and the rows' slots); none otherwise."""
+        return () if self.state is None else (self.state, slots)
+
+    def _take_state(self, out):
+        """A step program's results without the state pool it returned
+        last (kept as the engine's), for a model with recurrent state."""
+        if self.state is None:
+            return out
+        *out, self.state = out
+        return out
+
+    def _drop_slots(self, n: int) -> np.ndarray:
+        """Slot operand of n rows that all read and write the drop slot
+        (None where the model keeps no state)."""
+        return (None if self.state is None
+                else np.full(n, self.ecfg.max_batch, np.int32))
+
     @property
     def role(self) -> str:
         return self.latency.role
@@ -773,12 +816,15 @@ class JaxEngine:
                     pslots = (jnp.full((PB, T // ecfg.page_size),
                                        ecfg.num_pages, jnp.int32)
                               if T % ecfg.page_size == 0 else None)
-                    logits, self.kv_k, self.kv_v = self.prefill_fn(
-                        self.params, jnp.zeros((PB, T), jnp.int32),
-                        jnp.zeros((PB, T), jnp.int32) - 1,
-                        self.kv_k, self.kv_v, jnp.zeros((PB, P), jnp.int32),
-                        jnp.full((PB, T), DROP_SLOT, jnp.int32),
-                        jnp.zeros((PB,), jnp.int32), pslots)
+                    logits, self.kv_k, self.kv_v = self._take_state(
+                        self.prefill_fn(
+                            self.params, jnp.zeros((PB, T), jnp.int32),
+                            jnp.zeros((PB, T), jnp.int32) - 1,
+                            self.kv_k, self.kv_v,
+                            jnp.zeros((PB, P), jnp.int32),
+                            jnp.full((PB, T), DROP_SLOT, jnp.int32),
+                            jnp.zeros((PB,), jnp.int32), pslots,
+                            *self._state_args(self._drop_slots(PB))))
                     # penalties=None EXPLICITLY: the jit cache keys on the
                     # call's (args, kwargs) treedef, so an explicit-None
                     # kwarg and an omitted default are DIFFERENT entries —
@@ -831,7 +877,7 @@ class JaxEngine:
                             # kwargs from omitted defaults (compile-fence
                             # finding, same class as the penalties=None
                             # note above)
-                            out = self.decode_multi_fn(
+                            out = self._take_state(self.decode_multi_fn(
                                 self.params, jnp.zeros(B, jnp.int32),
                                 jnp.zeros(B, jnp.int32) - 1,
                                 jnp.zeros(B, bool), jnp.zeros(B, jnp.int32),
@@ -841,8 +887,9 @@ class JaxEngine:
                                 jnp.ones(B), jnp.zeros(B, jnp.uint32),
                                 jnp.full((B, ecfg.max_eos_ids), -1,
                                          jnp.int32),
-                                pv, k_steps=ecfg.decode_steps,
-                                logprobs_topn=topn)
+                                pv, *self._state_args(self._drop_slots(B)),
+                                k_steps=ecfg.decode_steps,
+                                logprobs_topn=topn))
                             if topn:
                                 (toks, _emitted, _aux, _carry, self.kv_k,
                                  self.kv_v) = out
@@ -866,15 +913,19 @@ class JaxEngine:
                                 # merge-combo loop below.
                                 if topn == 0:
                                     carries[B] = _carry
-                                out = self.decode_multi_fn(
-                                    self.params, *_carry, self.kv_k,
-                                    self.kv_v, tableB, jnp.zeros(B),
-                                    jnp.zeros(B, jnp.int32), jnp.ones(B),
-                                    jnp.zeros(B, jnp.uint32),
-                                    jnp.full((B, ecfg.max_eos_ids), -1,
-                                             jnp.int32),
-                                    pv, k_steps=ecfg.decode_steps,
-                                    logprobs_topn=topn)
+                                out = self._take_state(
+                                    self.decode_multi_fn(
+                                        self.params, *_carry, self.kv_k,
+                                        self.kv_v, tableB, jnp.zeros(B),
+                                        jnp.zeros(B, jnp.int32),
+                                        jnp.ones(B),
+                                        jnp.zeros(B, jnp.uint32),
+                                        jnp.full((B, ecfg.max_eos_ids), -1,
+                                                 jnp.int32),
+                                        pv, *self._state_args(
+                                            self._drop_slots(B)),
+                                        k_steps=ecfg.decode_steps,
+                                        logprobs_topn=topn))
                                 if topn:
                                     (toks, _emitted, _aux, _carry,
                                      self.kv_k, self.kv_v) = out
@@ -883,10 +934,13 @@ class JaxEngine:
                                      self.kv_v) = out
                                 n += 1
                 else:
-                    logits, self.kv_k, self.kv_v = self.decode_fn(
-                        self.params, jnp.zeros(B, jnp.int32),
-                        jnp.zeros(B, jnp.int32) - 1, self.kv_k, self.kv_v,
-                        tableB, jnp.full((B,), DROP_SLOT, jnp.int32))
+                    logits, self.kv_k, self.kv_v = self._take_state(
+                        self.decode_fn(
+                            self.params, jnp.zeros(B, jnp.int32),
+                            jnp.zeros(B, jnp.int32) - 1, self.kv_k,
+                            self.kv_v, tableB,
+                            jnp.full((B,), DROP_SLOT, jnp.int32),
+                            *self._state_args(self._drop_slots(B))))
                     toks = sample_tokens(
                         logits, jnp.zeros(B),
                         jnp.zeros(B, jnp.int32),
@@ -1146,6 +1200,7 @@ class JaxEngine:
             "request_total_slots": self.ecfg.max_batch,
             "kv_active_blocks": self.pm.active,
             "kv_total_blocks": self.ecfg.num_pages - 1,
+            **self._state_stats(),
             "num_requests_waiting": len(self.waiting),
             "queue_wait_seconds_total": round(self.queue_wait_seconds_total,
                                               4),
@@ -1200,6 +1255,18 @@ class JaxEngine:
                 (self.spec_accepted_tokens_total /
                  max(self.spec_steps, 1)),
         }
+
+    def _state_stats(self) -> dict:
+        """The recurrent-state pool's keys of stats(); none for a model
+        without one."""
+        if self.state is None:
+            return {}
+        total = self.ecfg.max_batch
+        return {"state_slots_total": total,
+                "state_slots_active": total - len(self._state_free),
+                "state_slots_held_total": self.state_slots_held_total,
+                "state_slots_seen_total": self.state_slots_seen_total,
+                "state_pool_bytes": int(sum(x.nbytes for x in self.state))}
 
     def _windowed_hit_rate(self) -> float:
         """Prefix-hit tokens / prompt tokens over the admission window
@@ -1455,6 +1522,10 @@ class JaxEngine:
                          f"context capacity {self.cap_tokens}"))
                 self._finish(seq, "error")
                 continue
+            if self.state is not None and not self._state_free:
+                # every state slot is held (a finished row keeps its slot
+                # until no window in flight lists it); wait for frees
+                break
             chain = self._chain(seq)
             with self._pm_lock:
                 alloc = self.pm.allocate_sequence(seq.tokens, chain=chain)
@@ -1471,6 +1542,10 @@ class JaxEngine:
             self.waiting.pop(0)
             pages, cached_tokens = alloc
             seq.pages = pages
+            if self.state is not None:
+                # whatever the slot holds is dropped by the prefill chunk
+                # that starts at position 0 (computed is 0 here: no hit)
+                seq.state_slot = self._state_free.pop()
             seq.computed = min(cached_tokens, seq.prefill_extent)
             if alloc.restores:
                 # restore_wait stops when the sequence clears the
@@ -1771,7 +1846,10 @@ class JaxEngine:
                      and all(s.computed % ps == 0 for s in batch))
         slots = np.full((B, T), DROP_SLOT, np.int32)
         pslots = np.full((B, max(T // ps, 1)), self.ecfg.num_pages, np.int32)
+        sslots = self._drop_slots(B)
         for i, (seq, chunk) in enumerate(zip(batch, chunks)):
+            if sslots is not None:
+                sslots[i] = seq.state_slot
             start = seq.computed
             tokens[i, :chunk] = seq.tokens[start:start + chunk]
             positions[i, :chunk] = np.arange(start, start + chunk)
@@ -1789,11 +1867,12 @@ class JaxEngine:
                 pslots[i, :npg] = pages[first:first + npg]
 
         pt0 = self.profiler.begin()
-        logits, self.kv_k, self.kv_v = self.prefill_fn(
+        logits, self.kv_k, self.kv_v = self._take_state(self.prefill_fn(
             self.params, jnp.asarray(tokens), jnp.asarray(positions),
             self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots),
             jnp.asarray(last_idx),
-            jnp.asarray(pslots) if use_paged else None)
+            jnp.asarray(pslots) if use_paged else None,
+            *self._state_args(sslots)))
         self.profiler.end(pt0, "prefill", (B, T, P),
                           tokens=int(sum(chunks)), sync_ref=logits)
         self._account_dispatch(batch)
@@ -2008,7 +2087,10 @@ class JaxEngine:
         positions = np.full(B, -1, np.int32)
         table = np.zeros((B, P), np.int32)
         slots = np.full(B, DROP_SLOT, np.int32)
+        sslots = self._drop_slots(B)
         for i, seq in enumerate(batch):
+            if sslots is not None:
+                sslots[i] = seq.state_slot
             pos = len(seq.tokens) - 1  # position of last_token
             tokens[i] = seq.last_token
             positions[i] = pos
@@ -2017,9 +2099,10 @@ class JaxEngine:
             slots[i] = (page * self.ecfg.page_size
                         + pos % self.ecfg.page_size)
         pt0 = self.profiler.begin()
-        logits, self.kv_k, self.kv_v = self.decode_fn(
+        logits, self.kv_k, self.kv_v = self._take_state(self.decode_fn(
             self.params, jnp.asarray(tokens), jnp.asarray(positions),
-            self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots))
+            self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots),
+            *self._state_args(sslots)))
         toks_d, aux_d = self._sample_device(batch, logits)
         self.profiler.end(pt0, "decode", (B, P), tokens=len(batch),
                           sync_ref=toks_d)
@@ -2258,11 +2341,16 @@ class JaxEngine:
         cached = self._samp_cache
         if key is not None and cached is not None and cached[0] == key:
             sb, (d_table, d_temp, d_topk, d_topp, d_seeds,
-                 d_eos) = cached[1], cached[2]
+                 d_eos, d_sslots) = cached[1], cached[2]
         else:
             table = np.zeros((B, P), np.int32)
             eos = np.full((B, E), -1, np.int32)
+            sslots = self._drop_slots(B)
             for i, seq in enumerate(batch):
+                if sslots is not None:
+                    # fixed from admission to release, and a release
+                    # changes the batch: safe under the key above
+                    sslots[i] = seq.state_slot
                 table[i, :len(seq.pages)] = seq.pages
                 ids: List[int] = []
                 if not seq.req.stop.ignore_eos:
@@ -2276,9 +2364,11 @@ class JaxEngine:
             d_topk = jnp.asarray(sb.top_k)
             d_topp = jnp.asarray(sb.top_p)
             d_seeds = jnp.asarray(sb.seeds)
+            d_sslots = None if sslots is None else jnp.asarray(sslots)
             if key is not None:
                 self._samp_cache = (key, sb, (d_table, d_temp, d_topk,
-                                              d_topp, d_seeds, d_eos))
+                                              d_topp, d_seeds, d_eos,
+                                              d_sslots))
         from_carry = np.zeros(B, bool)
         src = np.zeros(B, np.int32)
         ntok = np.zeros(B, np.int32)
@@ -2308,10 +2398,10 @@ class JaxEngine:
         topn = (self.ecfg.max_top_logprobs
                 if self._wants_logprobs(batch) else 0)
         pt0 = self.profiler.begin()
-        out = self.decode_multi_fn(
+        out = self._take_state(self.decode_multi_fn(
             self.params, tok, pos, done, steps, rem, self.kv_k, self.kv_v,
             d_table, d_temp, d_topk, d_topp, d_seeds, d_eos, pen,
-            k_steps=K, logprobs_topn=topn)
+            *self._state_args(d_sslots), k_steps=K, logprobs_topn=topn))
         if topn:
             toks, emitted, aux, carry, self.kv_k, self.kv_v = out
         else:
@@ -2394,6 +2484,10 @@ class JaxEngine:
         self.decode_rows_total += len(batch) * K
         self.decode_slots_total += B * K
         self.decode_windows_total += 1
+        if self.state is not None:
+            self.state_slots_held_total += (self.ecfg.max_batch
+                                            - len(self._state_free))
+            self.state_slots_seen_total += self.ecfg.max_batch
 
     def _device_stops_complete(self, seq: Sequence) -> bool:
         """True when the row's full stop-id set fit the on-device stop
@@ -2627,7 +2721,11 @@ class JaxEngine:
 
     def _chain(self, seq: Sequence) -> List[int]:
         """Full-block hashes of seq.tokens via the per-sequence
-        incremental cache (created on first use)."""
+        incremental cache (created on first use); none where nothing
+        is ever matched or published (a model with recurrent state), so
+        admission and the page-boundary publishes hash nothing."""
+        if not self.pm.prefix_reuse:
+            return []
         if seq.hash_cache is None:
             seq.hash_cache = ChainHashCache(self.ecfg.page_size)
         return seq.hash_cache.extend(seq.tokens)
@@ -2642,6 +2740,13 @@ class JaxEngine:
         if seq.pages:
             self.pm.release_sequence(seq.pages)
             seq.pages = []
+        if seq.state_slot is not None:
+            # reached only once no window in flight lists the row
+            # (_release_or_defer; preemption flushes first), so the next
+            # owner's programs are all dispatched after the last one that
+            # wrote this slot for the old row
+            self._state_free.append(seq.state_slot)
+            seq.state_slot = None
 
     def _finish(self, seq: Sequence, reason: str) -> None:
         if seq.finished is None:
@@ -3081,6 +3186,26 @@ def _make_decode_multi(model, cfg: ModelConfig, max_top_k: int,
         return out_toks, emitted, carry, kv_k, kv_v
 
     return decode_multi
+
+
+def _refuse_recurrent_state(ecfg: EngineConfig, mesh) -> None:
+    """What JaxEngine itself refuses a model with recurrent state, at
+    construction."""
+    if ecfg.host_pages > 0:
+        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
+            what="the host KV tier (host_pages > 0)",
+            why="a page restored from the host comes without the state "
+            "that goes with it"))
+    if ecfg.spec_decode:
+        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
+            what="spec_decode",
+            why="a rejected draft token has already advanced the state, "
+            "which cannot be rolled back"))
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
+            what="a mesh of more than one device",
+            why="no sharding rule places the state pool or the Mamba "
+            "leaves"))
 
 
 def _span_ms(start: Optional[float], end: Optional[float]

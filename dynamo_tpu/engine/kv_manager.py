@@ -44,6 +44,22 @@ HASH_SEED = 1337  # match the reference's block hasher (kv_router/indexer.rs)
 EVICT_POLICIES = ("lru", "cost")
 
 
+RECURRENT_STATE_REFUSAL = (
+    "{what} is not supported for a model with recurrent state "
+    "(models/jamba.py): {why}; nothing snapshots or moves the state pool "
+    "yet (ROADMAP B7)")
+
+
+def refuse_recurrent_state(engine, what: str) -> None:
+    """Raise where ``engine`` serves a model that keeps recurrent state
+    beside its KV pages: the paths that move KV pages between places
+    (host tier, disagg, KV transfer) would leave that state behind."""
+    if getattr(engine, "state", None) is not None:
+        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
+            what=what, why="it moves KV pages between places, and a "
+            "sequence's pages without its state are not the sequence"))
+
+
 def hash_block(parent: int, tokens: Sequence[int]) -> int:
     """Chained block hash: xxh3_64(parent_hash_le || token_le_bytes)."""
     h = xxhash.xxh3_64(seed=HASH_SEED)
@@ -149,7 +165,7 @@ class PageManager:
     """Host-side page pool bookkeeping with prefix reuse."""
 
     def __init__(self, num_pages: int, page_size: int, host_pages: int = 0,
-                 evict_policy: str = "lru"):
+                 evict_policy: str = "lru", prefix_reuse: bool = True):
         if evict_policy not in EVICT_POLICIES:
             raise ValueError(
                 f"evict_policy must be one of {EVICT_POLICIES}, "
@@ -157,6 +173,12 @@ class PageManager:
         self.num_pages = num_pages
         self.page_size = page_size
         self.evict_policy = evict_policy
+        # False for a model whose sequences carry recurrent state beside
+        # their pages (the engine sets it from the model module): a
+        # prefix hit would hand over pages and no state, so no page is
+        # ever published or matched and every hit counts as a miss.
+        # Until state snapshots exist; not a knob.
+        self.prefix_reuse = prefix_reuse
         # every pool structure below is event-loop-affine: all methods
         # are sync (each call is one atomic block under the loop), and
         # cross-thread callers serialize on the engine's _pm_lock. The
@@ -248,6 +270,8 @@ class PageManager:
         """Longest cached prefix: returns (page_ids, their hashes). Does NOT
         take references — call ``allocate`` to claim."""
         pages, hashes = [], []
+        if not self.prefix_reuse:
+            return pages, hashes
         for h in chain_hashes(token_ids, self.page_size):
             page = self.by_hash.get(h)
             if page is None:
@@ -279,6 +303,8 @@ class PageManager:
         # full-prompt hit: leave at least the final token to recompute so
         # prefill produces logits (cap reuse at len-1 tokens)
         max_reuse = max((len(token_ids) - 1) // self.page_size, 0)
+        if not self.prefix_reuse:
+            chain = []          # every hit counts as a miss
         if chain is None:
             chain = chain_hashes(token_ids, self.page_size)
         chain = chain[:max_reuse]
@@ -419,6 +445,8 @@ class PageManager:
         optionally supplies precomputed full-block hashes covering at
         least ``extent`` so the publish skips the O(extent) re-hash."""
         nblocks = extent // self.page_size
+        if not self.prefix_reuse:
+            return nblocks      # nothing is published: no later hit
         if chain is not None and len(chain) >= nblocks:
             hashes = chain[:nblocks]
         else:

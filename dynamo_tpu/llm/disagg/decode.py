@@ -14,6 +14,7 @@ import asyncio
 import logging
 from typing import AsyncIterator, Optional
 
+from ...engine.kv_manager import refuse_recurrent_state
 from ...runtime import guard, tracing
 from ...runtime.config import env_float, env_int
 from ...runtime.engine import Context
@@ -47,6 +48,7 @@ class DisaggDecodeEngine:
                  router: DisaggRouter, engine_id: int,
                  prefill_timeout: Optional[float] = None,
                  max_dispatches: Optional[int] = None):
+        refuse_recurrent_state(engine, "a disaggregated decode engine")
         self.engine = engine
         if hasattr(engine, "set_role"):
             # dynaslo: the wrapped engine serves the decode side of the

@@ -23,6 +23,7 @@ from typing import Dict, List, Optional, Set
 
 import numpy as np
 
+from ...engine.kv_manager import refuse_recurrent_state
 from ...runtime import guard, tracing
 from ...runtime.engine import Context
 from ..protocols.common import (PreprocessedRequest, SamplingOptions,
@@ -44,6 +45,7 @@ class PrefillWorker:
         from ...runtime.config import env_bool, env_int
 
         self.drt = drt
+        refuse_recurrent_state(engine, "a disaggregated prefill worker")
         self.engine = engine
         if hasattr(engine, "set_role"):
             # dynaslo: this engine serves prefill-only — its latency

@@ -40,6 +40,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
+from ...engine.kv_manager import refuse_recurrent_state
 from ...runtime import codec, guard, tracing, wire
 from ...runtime.codec import TwoPartMessage
 from ...runtime.config import env_float
@@ -166,6 +167,7 @@ class KvTransferServer:
     (per-chunk late-write guard)."""
 
     def __init__(self, engine):
+        refuse_recurrent_state(engine, "the KV transfer server")
         self.engine = engine
         self._server: Optional[asyncio.AbstractServer] = None
         self._waiters: Dict[str, asyncio.Future] = {}

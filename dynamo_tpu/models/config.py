@@ -73,11 +73,40 @@ class ModelConfig:
     sandwich_norms: bool = False
     attn_logit_softcap: Optional[float] = None
     sliding_window: Optional[int] = None
+    # Jamba (model_type "jamba", models/jamba.py): Mamba-1 mixers in
+    # every layer but those at attn_layer_offset + k * attn_layer_period,
+    # which attend (no positional embedding of any kind). mamba_d_state
+    # > 0 switches the model module and gives the engine a pool of
+    # per-sequence recurrent state beside the KV pages.
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: int = 0
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
     dtype: str = "bfloat16"
 
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def has_recurrent_state(self) -> bool:
+        return self.mamba_d_state > 0
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.hidden_size
+
+    @property
+    def attn_layer_ids(self) -> tuple:
+        """Layers whose mixer is attention, for a model whose layers are
+        of two kinds (every layer attends otherwise)."""
+        if not self.has_recurrent_state:
+            return tuple(range(self.num_layers))
+        return tuple(l for l in range(self.num_layers)
+                     if (l - self.attn_layer_offset)
+                     % self.attn_layer_period == 0)
 
     @property
     def head_dim_(self) -> int:
@@ -179,6 +208,26 @@ class ModelConfig:
                 c.num_experts = cfg.get("num_experts", 128)
                 c.num_experts_per_tok = cfg.get("num_experts_per_tok", 8)
                 c.intermediate_size = cfg["moe_intermediate_size"]
+        if mt == "jamba":
+            if (cfg.get("num_experts") or 1) > 1:
+                # every layer's MLP is dense here; the expert_layer_*
+                # keys would select routed layers
+                raise NotImplementedError(
+                    "jamba with num_experts > 1 is not supported (every "
+                    "layer's MLP is computed dense)")
+            if cfg.get("sliding_window"):
+                raise NotImplementedError(
+                    "jamba with sliding_window set is not supported (its "
+                    "attention layers attend to the whole context)")
+            c.model_type = "jamba"
+            c.mamba_d_state = cfg.get("mamba_d_state", 16)
+            c.mamba_d_conv = cfg.get("mamba_d_conv", 4)
+            c.mamba_expand = cfg.get("mamba_expand", 2)
+            c.mamba_dt_rank = (cfg.get("mamba_dt_rank")
+                               or -(-cfg["hidden_size"] // 16))
+            c.attn_layer_period = cfg.get("attn_layer_period", 8)
+            c.attn_layer_offset = cfg.get("attn_layer_offset", 4)
+            c.rms_norm_eps = cfg.get("rms_norm_eps", 1e-6)
         if mt in ("gemma", "gemma2"):
             # Gemma rides the Llama GQA stack with four semantic switches
             c.model_type = "gemma"

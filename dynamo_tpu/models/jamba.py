@@ -1,0 +1,528 @@
+"""Jamba: Mamba-1 mixers with attention every ``attn_layer_period`` layers
+(AI21 Jamba family, ``model_type: jamba``), on the engine's paged step-fn
+contract plus one thing no other module has: **per-sequence recurrent
+state that is not a KV page**.
+
+Layer l: ``h += Mixer_l(rms_norm(h))`` then ``h += MLP(rms_norm(h))``
+(SwiGLU, dense, every layer). ``Mixer_l`` attends where ``(l -
+attn_layer_offset) % attn_layer_period == 0`` (GQA, no bias, **no
+positional embedding of any kind**) and is a Mamba-1 mixer elsewhere:
+
+    [x, z] = split(W_in u)
+    x      = silu(causal depthwise conv1d(x; conv_w, b_conv))   kernel d_conv
+    [dt_r, B, C] = split(W_x x)            each through its own RMSNorm
+    dt     = softplus(W_dt dt_r + b_dt),   A = -exp(A_log)
+    s_t    = exp(dt_t * A) * s_{t-1} + (dt_t * x_t) outer B_t
+    y_t    = s_t . C_t + d_skip * x_t
+    out    = W_out(y_t * silu(z_t))
+
+What a sequence carries between programs is ``s`` (float32, kept as
+``[N, d_inner]``: d_inner on the TPU's 128 lanes, the published
+``[d_inner, N]`` would pad 16 to 128) and the last ``d_conv - 1`` conv
+inputs. The engine owns one pool of both (``init_state``: ``[S, M, N,
+d_inner]`` float32 and ``[S, M, (d_conv - 1) * d_inner]``, S slots, M
+Mamba layers; slot-major, so a row's whole state is one contiguous 8.5 MB
+and the gather and scatter by slot move whole slabs) and passes it
+through every program with the rows' slot indices, as it passes the KV
+pools with page tables. A program
+gathers its rows' state once, carries it, and scatters it back once; a
+row that does not advance (padding, frozen by a stop) writes back what
+it read. A prefill chunk that starts at position 0 starts from zeros,
+whatever the slot held.
+
+The Mamba layers are stacked on a leading axis and ``lax.scan``-ned in
+their runs between the attending layers, so a program holds one Mamba
+layer's trace per run, not 28 layers. KV pools hold the attending layers
+only (``[n_attn, pages, KV, ps, hd]``) and go through llama.py's paged
+attention: the page scatter, ``_attention``, and the read-only-pool
+window attention with the Pallas decode kernel on a TPU.
+
+The selective scan is plain XLA here, in two forms: a chunk of T tokens
+from a carried state (``_ssm_chunk``: time blocks run side by side from
+zero and are stitched by their entry states, so nothing of size ``[T,
+d_inner, N]`` is ever held) and one token from a stored state (the
+fused window). Scopes: ``ssm`` around the mixer with
+``ssm.proj``, ``ssm.conv``, ``ssm.scan`` inside; ``attn``, ``mlp``,
+``lm_head``, ``sample``, ``kv_carry`` as in llama.py.
+"""
+
+from __future__ import annotations
+
+import math
+from functools import partial
+from typing import List, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from .config import ModelConfig
+from .llama import (DROP_SLOT, KVCacheSpec, Params, _attention, _mlp,
+                    _pool_window_attention, _pool_window_attention_pallas,
+                    _scatter_pages, _scatter_pages_paged, _use_pallas,
+                    carry_active, carry_step_update, embed_tokens, logits_at,
+                    rms_norm)
+from ..runtime.config import env_flag
+
+State = Tuple[jax.Array, jax.Array]     # (ssm [S,M,N,di] f32, conv [S,M,(dc-1)*di])
+
+MAMBA_KEYS = ("w_in", "conv_w", "b_conv", "w_x", "dt_norm", "ssm_b_norm",
+              "ssm_c_norm", "w_dt", "b_dt", "A_log", "d_skip", "w_out")
+SCAN_BLOCK = 32     # tokens per time block of the prefill scan
+
+
+def segments(cfg: ModelConfig) -> List[tuple]:
+    """The layer pattern as runs: ("mamba", first mamba index, first
+    layer, count) and ("attn", attention index, layer), in layer order."""
+    out: List[tuple] = []
+    m = first = 0           # next Mamba index, first layer of the run
+    for a, l in enumerate((*cfg.attn_layer_ids, cfg.num_layers)):
+        if l > first:
+            out.append(("mamba", m, first, l - first))
+            m += l - first
+        if l < cfg.num_layers:
+            out.append(("attn", a, l))
+        first = l + 1
+    return out
+
+
+def num_mamba_layers(cfg: ModelConfig) -> int:
+    return cfg.num_layers - len(cfg.attn_layer_ids)
+
+
+# ------------------------------------------------------- params and pools
+
+
+def init_kv_cache(cfg: ModelConfig, spec: KVCacheSpec,
+                  dtype=None) -> Tuple[jax.Array, jax.Array]:
+    """K and V pools of the attending layers only."""
+    shape = (len(cfg.attn_layer_ids), spec.num_pages, cfg.num_kv_heads,
+             spec.page_size, cfg.head_dim_)
+    dtype = dtype or cfg.jax_dtype
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def init_state(cfg: ModelConfig, slots: int, dtype=None) -> State:
+    """The recurrent-state pool for ``slots`` sequences: what declares to
+    the engine that this module's sequences carry state beside pages."""
+    M, N, di = num_mamba_layers(cfg), cfg.mamba_d_state, cfg.mamba_d_inner
+    return (jnp.zeros((slots, M, N, di), jnp.float32),
+            jnp.zeros((slots, M, (cfg.mamba_d_conv - 1) * di),
+                      dtype or cfg.jax_dtype))
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
+    """Random-init params; each kind of layer stacked on its own axis 0
+    (MLP and pre-norms over all L layers, Mamba leaves over the M Mamba
+    layers, attention leaves over the attending ones)."""
+    dtype = dtype or cfg.jax_dtype
+    D, I, L, V = (cfg.hidden_size, cfg.intermediate_size, cfg.num_layers,
+                  cfg.vocab_size)
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    M, A = num_mamba_layers(cfg), len(cfg.attn_layer_ids)
+    di, N, R, dc = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
+                    cfg.mamba_d_conv)
+    ks = iter(jax.random.split(key, 16))
+
+    def w(*shape):
+        scale = 1.0 / math.sqrt(shape[-2])
+        return (jax.random.normal(next(ks), shape, jnp.float32)
+                * scale).astype(dtype)
+
+    # the published Mamba init: dt between 1e-3 and 1e-1 through the
+    # bias, A = -(1..N) per channel, skip of ones
+    dt = jnp.exp(jax.random.uniform(next(ks), (M, di), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    p: Params = {
+        "embed": w(V, D),
+        "ln_mixer": jnp.ones((L, D), dtype),
+        "ln_mlp": jnp.ones((L, D), dtype),
+        "ln_final": jnp.ones((D,), dtype),
+        "w_gate": w(L, D, I), "w_up": w(L, D, I), "w_down": w(L, I, D),
+        "wq": w(A, D, H * hd), "wk": w(A, D, KV * hd),
+        "wv": w(A, D, KV * hd), "wo": w(A, H * hd, D),
+        "w_in": w(M, D, 2 * di),
+        "conv_w": w(M, dc, di),
+        "b_conv": jnp.zeros((M, di), dtype),
+        "w_x": w(M, di, R + 2 * N),
+        "dt_norm": jnp.ones((M, R), dtype),
+        "ssm_b_norm": jnp.ones((M, N), dtype),
+        "ssm_c_norm": jnp.ones((M, N), dtype),
+        "w_dt": w(M, R, di),
+        "b_dt": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)),
+            (M, di, N)).astype(dtype),
+        "d_skip": jnp.ones((M, di), dtype),
+        "w_out": w(M, di, D),
+    }
+    if not cfg.tie_word_embeddings:
+        p["lm_head"] = w(D, V)
+    return p
+
+
+# ----------------------------------------------------------- the mixers
+
+
+def _ssm_step(s, dt_t, x_t, b_t, c_t, a_neg):
+    """One token of the recurrence for every row. s [B, N, di] float32;
+    dt_t, x_t [B, di]; b_t, c_t [B, N]; a_neg [N, di] = -exp(A_log).T.
+    A row whose dt_t is 0 keeps its state (exp(0) = 1, nothing added)."""
+    s = (jnp.exp(dt_t[:, None, :] * a_neg[None]) * s
+         + (dt_t * x_t)[:, None, :] * b_t[:, :, None])
+    return s, jnp.sum(s * c_t[:, :, None], axis=1)
+
+
+def _ssm_chunk(s0, dt, x, b, c, a_neg):
+    """T tokens of the recurrence from the carried state s0 [B, N, di],
+    in nb = T / tb time blocks. dt, x [B, T, di]; b, c [B, T, N] float32.
+    A token-by-token loop is T dependent steps of a few microseconds of
+    work each; here every block runs its tb tokens from a ZERO state side
+    by side (tb steps over [B, nb, N, di]), the blocks' carried states
+    follow from their totals (nb steps), and what a block's entry state
+    adds to each of its tokens is one fused pass:
+
+        s_t = P_t * s_entry + local_t,    P_t = exp(A * sum_{j<=t} dt_j)
+        y_t = sum_n C_t * local_t  +  sum_n C_t * P_t * s_entry
+
+    P_t <= 1 everywhere (nothing is divided), and nothing of size
+    [T, N, di] is ever held: the local states are read out to y inside
+    their loop. Returns (s after the last token, y [B, T, di])."""
+    B, T, di = dt.shape
+    N = b.shape[-1]
+    tb = math.gcd(T, SCAN_BLOCK)
+    nb = T // tb
+
+    def blocks(v):          # [B, T, w] -> [tb, B, nb, w], time-major
+        return jnp.moveaxis(v.reshape(B, nb, tb, v.shape[-1]), 2, 0)
+
+    def token(carry, xs):
+        local, cum = carry                      # [B, nb, N, di], [B, nb, di]
+        dt_j, x_j, b_j, c_j = xs
+        local = (jnp.exp(dt_j[:, :, None, :] * a_neg) * local
+                 + (dt_j * x_j)[:, :, None, :] * b_j[..., None])
+        cum = cum + dt_j
+        return (local, cum), (jnp.sum(local * c_j[..., None], axis=2), cum)
+
+    (local, cum_end), (y_local, cum) = lax.scan(
+        token, (jnp.zeros((B, nb, N, di), jnp.float32),
+                jnp.zeros((B, nb, di), jnp.float32)),
+        (blocks(dt), blocks(x), blocks(b), blocks(c)))
+
+    def block(s, xs):       # entry state of each block, then the next
+        total, local_k = xs
+        return total * s + local_k, s
+
+    s, entry = lax.scan(
+        block, s0,
+        (jnp.moveaxis(jnp.exp(cum_end[:, :, None, :] * a_neg), 1, 0),
+         jnp.moveaxis(local, 1, 0)))
+    entry = jnp.moveaxis(entry, 0, 1)           # [B, nb, N, di]
+    # [tb, B, nb, N, di] only inside the fusion that reduces it over N
+    carried = jnp.sum(
+        blocks(c)[..., None] * jnp.exp(cum[:, :, :, None, :] * a_neg)
+        * entry[None], axis=3)
+    y = jnp.moveaxis(y_local + carried, 0, 2).reshape(B, T, di)
+    return s, y
+
+
+def _mamba(cfg: ModelConfig, mp, u, valid, s, tail):
+    """The Mamba-1 mixer on a chunk. u [B, T, D] (normed); valid [B, T]
+    (a row's valid tokens lead); s [B, N, di] float32 and tail
+    [B, d_conv - 1, di]: the rows' state on entry. Returns (out [B, T, D],
+    s, tail) with the state after each row's last valid token."""
+    f32 = jnp.float32
+    B, T, _ = u.shape
+    di, N, R, dc = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
+                    cfg.mamba_d_conv)
+    eps = cfg.rms_norm_eps
+
+    def dot(a, w):
+        """Operands in the weights' type, the result in float32 (the
+        accumulator's own type): between the mixer's matmuls nothing is
+        rounded to bf16. 28 layers deep, rounding x, z, dt, B, C and y
+        at every layer is what the agreement with the float32 reference
+        loses first (PERF.md, Findings PR 27)."""
+        return jnp.dot(a.astype(w.dtype), w, preferred_element_type=f32)
+
+    with jax.named_scope("ssm"):
+        with jax.named_scope("ssm.proj"):
+            x, z = jnp.split(dot(u, mp["w_in"]), 2, axis=-1)    # [B, T, di]
+        with jax.named_scope("ssm.conv"):
+            xp = jnp.concatenate([tail.astype(f32), x], axis=1)
+            cw = mp["conv_w"].astype(f32)                       # [dc, di]
+            xc = jax.nn.silu(mp["b_conv"].astype(f32) + sum(
+                xp[:, k:k + T] * cw[k] for k in range(dc)))
+            # the next chunk's tail: the dc - 1 inputs that end at the
+            # row's last valid token (the old tail where it has none)
+            n_valid = jnp.sum(valid, axis=1)
+            tail = jax.vmap(lambda row, n: lax.dynamic_slice_in_dim(
+                row, n, dc - 1, 0))(xp, n_valid).astype(tail.dtype)
+        with jax.named_scope("ssm.proj"):
+            dt_r, b, c = jnp.split(dot(xc, mp["w_x"]), [R, R + N], axis=-1)
+            dt_r = rms_norm(dt_r, mp["dt_norm"].astype(f32), eps)
+            b = rms_norm(b, mp["ssm_b_norm"].astype(f32), eps)
+            c = rms_norm(c, mp["ssm_c_norm"].astype(f32), eps)
+            dt = jax.nn.softplus(dot(dt_r, mp["w_dt"])
+                                 + mp["b_dt"].astype(f32))
+            dt = jnp.where(valid[:, :, None], dt, 0.0)          # [B, T, di]
+        with jax.named_scope("ssm.scan"):
+            a_neg = -jnp.exp(mp["A_log"].astype(f32)).T         # [N, di]
+            if T == 1:      # one token from a stored state
+                s, y = _ssm_step(s, dt[:, 0], xc[:, 0], b[:, 0], c[:, 0],
+                                 a_neg)
+                y = y[:, None]
+            else:           # a chunk from a carried state, in time blocks
+                s, y = _ssm_chunk(s, dt, xc, b, c, a_neg)
+            y = y + mp["d_skip"].astype(f32) * xc
+        with jax.named_scope("ssm.proj"):
+            out = dot(y * jax.nn.silu(z), mp["w_out"])
+    return out, s, tail
+
+
+def _at(params: Params, keys, i):
+    """One layer's leaves of the stacks named, by a (traced) index."""
+    return {k: lax.dynamic_index_in_dim(params[k], i, 0, False)
+            for k in keys}
+
+
+def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
+           cache):
+    """All layers on h [B, T, D]. ssm [B, M, N, di] / conv [B, M,
+    (dc-1)*di] are the ROWS' state (gathered by the caller), updated in
+    place layer by layer. ``attend(a, x, cache) -> (out, cache)`` is the
+    attention mixer of attending layer a on the normed input: the caller
+    owns how K/V are cached (pages for a chunk, the window buffer inside
+    the fused window)."""
+    eps = cfg.rms_norm_eps
+    B = h.shape[0]
+    dc1, di = cfg.mamba_d_conv - 1, cfg.mamba_d_inner
+    wdt = params["embed"].dtype
+    # the residual stream is float32 and every block reads it through a
+    # norm that hands the matmuls the weights' type: 56 additions deep,
+    # a bf16 stream's rounding is the other half of what the agreement
+    # loses (a [B, T, D] float32 array: nothing beside the weights)
+    h = h.astype(jnp.float32)
+
+    def norm(h, w):
+        return rms_norm(h, w.astype(jnp.float32), eps).astype(wdt)
+
+    def mlp(h, l):
+        lp = _at(params, ("ln_mlp", "w_gate", "w_up", "w_down"), l)
+        return h + _mlp(norm(h, lp["ln_mlp"]), lp["w_gate"], lp["w_up"],
+                        lp["w_down"])
+
+    for seg in segments(cfg):
+        if seg[0] == "attn":
+            _, a, l = seg
+            with jax.named_scope("attn"):
+                x = norm(h, params["ln_mixer"][l])
+                out, cache = attend(a, x, cache)
+                h = h + out
+            h = mlp(h, l)
+            continue
+        _, m0, l0, count = seg
+
+        def layer(carry, i, m0=m0, l0=l0):
+            h, ssm, conv = carry
+            m = m0 + i
+            mp = _at(params, MAMBA_KEYS, m)
+            x = norm(h, lax.dynamic_index_in_dim(
+                params["ln_mixer"], l0 + i, 0, False))
+            out, s, tail = _mamba(
+                cfg, mp, x, valid,
+                lax.dynamic_index_in_dim(ssm, m, 1, False),
+                lax.dynamic_index_in_dim(conv, m, 1, False).reshape(
+                    B, dc1, di))
+            ssm = lax.dynamic_update_index_in_dim(ssm, s, m, 1)
+            conv = lax.dynamic_update_index_in_dim(
+                conv, tail.reshape(B, dc1 * di), m, 1)
+            return (mlp(h + out, l0 + i), ssm, conv), None
+
+        (h, ssm, conv), _ = lax.scan(layer, (h, ssm, conv),
+                                     jnp.arange(count, dtype=jnp.int32))
+    return norm(h, params["ln_final"]), ssm, conv, cache
+
+
+def _qkv(cfg: ModelConfig, params: Params, a: int, x):
+    B, T, _ = x.shape
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    return ((x @ params["wq"][a]).reshape(B, T, H, hd),
+            (x @ params["wk"][a]).reshape(B, T, KV, hd),
+            (x @ params["wv"][a]).reshape(B, T, KV, hd))
+
+
+def _rows(state: State, slots):
+    """The rows' state out of the pool: ([B, M, N, di], [B, M, ...])."""
+    return state[0][slots], state[1][slots]
+
+
+def _store(state: State, slots, ssm, conv) -> State:
+    return (state[0].at[slots].set(ssm),
+            state[1].at[slots].set(conv.astype(state[1].dtype)))
+
+
+def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
+            page_table, flat_slots, state: State, state_slots,
+            allow_pallas: bool = True, page_slots=None, mesh=None):
+    """A chunk [B, T] for every row from its stored state (zeros where
+    the chunk starts at position 0): prefill, and K=1 decode at T = 1.
+    Arguments as llama.forward, plus the state pool and the rows' slots.
+    Returns (hidden [B, T, D], kv_k, kv_v, state)."""
+    valid = positions >= 0
+    ssm, conv = _rows(state, state_slots)
+    fresh = positions[:, 0] == 0
+    ssm = jnp.where(fresh[:, None, None, None], 0.0, ssm)
+    conv = jnp.where(fresh[:, None, None], 0, conv)
+
+    def attend(a, x, cache):
+        kv_k, kv_v = cache
+        q, k, v = _qkv(cfg, params, a, x)
+        if page_slots is not None:
+            k_l = _scatter_pages_paged(kv_k[a], k, page_slots)
+            v_l = _scatter_pages_paged(kv_v[a], v, page_slots)
+        else:
+            k_l = _scatter_pages(kv_k[a], k, flat_slots)
+            v_l = _scatter_pages(kv_v[a], v, flat_slots)
+        out = _attention(q, k_l, v_l, page_table, positions, cfg.attn_scale,
+                         allow_pallas=allow_pallas, mesh=mesh)
+        return (out.reshape(*x.shape[:2], -1) @ params["wo"][a],
+                (kv_k.at[a].set(k_l), kv_v.at[a].set(v_l)))
+
+    h = embed_tokens(params, cfg, tokens)
+    h, ssm, conv, (kv_k, kv_v) = _stack(params, cfg, h, valid, ssm, conv,
+                                        attend, (kv_k, kv_v))
+    return h, kv_k, kv_v, _store(state, state_slots, ssm, conv)
+
+
+# ----------------------------------------------------- jitted entry points
+
+
+def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
+    """(prefill_step, decode_step) as llama.make_step_fns builds them, each
+    with two more operands, the state pool (donated like the KV pools)
+    and the rows' slots, and one more result, the pool."""
+
+    @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "state"))
+    def prefill_step(params, tokens, positions, kv_k, kv_v, page_table,
+                     flat_slots, last_idx, page_slots=None, state=None,
+                     state_slots=None):
+        h, kv_k, kv_v, state = forward(
+            params, cfg, tokens, positions, kv_k, kv_v, page_table,
+            flat_slots, state, state_slots, allow_pallas=allow_pallas,
+            page_slots=page_slots, mesh=mesh)
+        return logits_at(params, cfg, h, last_idx), kv_k, kv_v, state
+
+    @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "state"))
+    def decode_step(params, tokens, positions, kv_k, kv_v, page_table,
+                    flat_slots, state=None, state_slots=None):
+        h, kv_k, kv_v, state = forward(
+            params, cfg, tokens[:, None], positions[:, None], kv_k, kv_v,
+            page_table, flat_slots[:, None], state, state_slots,
+            allow_pallas=allow_pallas, mesh=mesh)
+        return (logits_at(params, cfg, h,
+                          jnp.zeros(tokens.shape[0], jnp.int32)),
+                kv_k, kv_v, state)
+
+    return prefill_step, decode_step
+
+
+def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
+                          max_top_k: int = 64, mesh=None,
+                          pallas_interpret: bool = False):
+    """The fused K-step window of llama.make_decode_window_fn (read-only
+    KV pool + window buffer + on-device carry) with the rows' recurrent
+    state carried beside it: gathered from the pool once, advanced by
+    every step a row is active in, scattered back once."""
+    from ..engine.sampling import (logprob_aux, sample_tokens,
+                                   update_penalty_state)
+
+    KV, hd = cfg.num_kv_heads, cfg.head_dim_
+    n_attn = len(cfg.attn_layer_ids)
+    pallas_interpret = pallas_interpret or (
+        env_flag("DYN_PALLAS_INTERPRET")
+        and not env_flag("DYN_DISABLE_PALLAS") and not _use_pallas())
+    use_pallas = allow_pallas and (_use_pallas() or pallas_interpret)
+
+    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
+             donate_argnames=("kv_k", "kv_v", "state"))
+    def decode_window(params, tokens, positions, done, steps, remaining,
+                      kv_k, kv_v, page_table, temperature, top_k, top_p,
+                      seeds, eos_table, penalties=None, state=None,
+                      state_slots=None, *, k_steps: int,
+                      logprobs_topn: int = 0):
+        B = tokens.shape[0]
+        ps = kv_k.shape[3]
+        start = positions
+        wk = jnp.zeros((n_attn, B, k_steps, KV, hd), kv_k.dtype)
+        wv = jnp.zeros_like(wk)
+        ssm, conv = _rows(state, state_slots)
+
+        def one_step(tok, pos, active, wk, wv, ssm, conv, i):
+            def attend(a, x, cache):
+                wk, wv = cache
+                q, k, v = _qkv(cfg, params, a, x)
+                wk_l = wk[a].at[:, i].set(k[:, 0].astype(wk.dtype))
+                wv_l = wv[a].at[:, i].set(v[:, 0].astype(wv.dtype))
+                if use_pallas:
+                    out = _pool_window_attention_pallas(
+                        q, kv_k, kv_v, jnp.int32(a), page_table, start,
+                        wk_l, wv_l, i, cfg.attn_scale,
+                        interpret=pallas_interpret)
+                else:
+                    out = _pool_window_attention(
+                        q, kv_k[a], kv_v[a], page_table, start, wk_l, wv_l,
+                        i, cfg.attn_scale)
+                return (out.reshape(B, 1, -1) @ params["wo"][a],
+                        (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
+
+            h = embed_tokens(params, cfg, tok)[:, None]
+            h, ssm, conv, (wk, wv) = _stack(
+                params, cfg, h, active[:, None], ssm, conv, attend, (wk, wv))
+            return (logits_at(params, cfg, h, jnp.zeros(B, jnp.int32)),
+                    wk, wv, ssm, conv)
+
+        tok, pos = tokens, positions
+        toks, lps, tvs, tis = [], [], [], []
+        emitted = jnp.zeros((B,), jnp.int32)
+        for i in range(k_steps):
+            # a frozen or padding row flows through the matmuls; its
+            # state does not move (dt masked to 0, conv tail kept) and
+            # its K/V never commit
+            active = carry_active(done, pos)
+            logits, wk, wv, ssm, conv = one_step(tok, pos, active, wk, wv,
+                                                 ssm, conv, i)
+            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
+                                steps, max_top_k=max_top_k,
+                                penalties=penalties)
+            if logprobs_topn:
+                lp, tv, ti = logprob_aux(logits, nxt, logprobs_topn)
+                lps.append(lp); tvs.append(tv); tis.append(ti)
+            penalties = update_penalty_state(penalties, nxt, done)
+            emitted = emitted + active.astype(jnp.int32)
+            tok, pos, done, steps, remaining = carry_step_update(
+                nxt, tok, pos, done, steps, remaining, eos_table)
+            toks.append(tok)
+
+        with jax.named_scope("kv_carry"):
+            wpos = start[:, None] + jnp.arange(k_steps)[None, :]
+            page = page_table[jnp.arange(B)[:, None],
+                              jnp.clip(wpos // ps, 0,
+                                       page_table.shape[1] - 1)]
+            valid = jnp.logical_and(start[:, None] >= 0,
+                                    wpos < pos[:, None])
+            flat = jnp.broadcast_to(
+                jnp.where(valid, page * ps + wpos % ps, DROP_SLOT),
+                (n_attn, B, k_steps))
+            kv_k = jax.vmap(_scatter_pages)(kv_k, wk, flat)
+            kv_v = jax.vmap(_scatter_pages)(kv_v, wv, flat)
+            state = _store(state, state_slots, ssm, conv)
+        out_toks = jnp.stack(toks, axis=1)
+        carry = (tok, pos, done, steps, remaining)
+        if logprobs_topn:
+            aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
+                   jnp.stack(tis, axis=1))
+            return out_toks, emitted, aux, carry, kv_k, kv_v, state
+        return out_toks, emitted, carry, kv_k, kv_v, state
+
+    return decode_window

@@ -1,0 +1,482 @@
+"""Jamba (Mamba-1 + attention, models/jamba.py): the step programs and
+the engine's recurrent-state pool against the plain reference
+(benchmark/configs/jamba2-3b/reference.py), on the CPU at a tiny size:
+float32, ONE whole period of 14 layers (attention at layer 7), d_state
+16, d_conv 4, seeded random weights.
+
+Tolerance. Both sides are float32 and compute the same sums in another
+order (the program in row blocks with a carried state, the reference as
+one sequence from zero), so logits of magnitude ~3 differ by a few 1e-6
+(measured 7e-6 at worst); ATOL = 1e-4 leaves room and is still 100x
+under what a dropped state, a wrong conv tail or a missed token moves
+(1e-2 and more, see the tests that provoke them)."""
+
+import asyncio
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
+from dynamo_tpu.llm.protocols.common import (OutputOptions,
+                                             PreprocessedRequest,
+                                             SamplingOptions, StopConditions)
+from dynamo_tpu.models import jamba
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.llama import DROP_SLOT, KVCacheSpec
+from dynamo_tpu.models.registry import get_model_module
+from dynamo_tpu.runtime.engine import Context
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ATOL = 1e-4
+PS = 8
+
+
+def _reference():
+    spec = importlib.util.spec_from_file_location(
+        "jamba_reference", os.path.join(
+            ROOT, "benchmark", "configs", "jamba2-3b", "reference.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+
+
+def tiny(**over) -> ModelConfig:
+    hf = dict(model_type="jamba", vocab_size=512, hidden_size=64,
+              intermediate_size=128, num_hidden_layers=14,
+              num_attention_heads=4, num_key_value_heads=1,
+              mamba_d_state=16, mamba_d_conv=4, mamba_expand=2,
+              mamba_dt_rank=8, attn_layer_period=14, attn_layer_offset=7,
+              num_experts=1, rms_norm_eps=1e-6, tie_word_embeddings=False)
+    hf.update(over)
+    cfg = ModelConfig.from_hf_config(hf)
+    cfg.dtype = "float32"
+    return cfg
+
+
+def ref_logits(params, cfg, tokens):
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(REF.reference_logits(params, cfg, tokens))
+
+
+class Pools:
+    """One sequence's pages and state slot in small pools, driven the way
+    the engine drives them."""
+
+    def __init__(self, cfg, pages=(3, 5, 7, 9, 11, 2), slot=2, slots=5):
+        self.cfg = cfg
+        self.kv_k, self.kv_v = jamba.init_kv_cache(cfg, KVCacheSpec(16, PS))
+        ssm, conv = jamba.init_state(cfg, slots)
+        # what a previous owner left in the slot must not matter
+        self.state = (ssm.at[slot].set(7.0), conv.at[slot].set(3.0))
+        self.pages, self.slot, self.drop = list(pages), slot, slots - 1
+        self.prefill, self.decode = jamba.make_step_fns(cfg)
+
+    def table(self, rows, width=8):
+        t = np.zeros((rows, width), np.int32)
+        t[0, :len(self.pages)] = self.pages
+        return jnp.asarray(t)
+
+    def run_prefill(self, params, tokens, start, bucket):
+        """One chunk of row 0 (row 1 is padding) in a [2, bucket]
+        program; logits at the chunk's last token."""
+        n = len(tokens)
+        tok = np.zeros((2, bucket), np.int32)
+        pos = np.full((2, bucket), -1, np.int32)
+        slots = np.full((2, bucket), DROP_SLOT, np.int32)
+        at = np.arange(start, start + n)
+        tok[0, :n], pos[0, :n] = tokens, at
+        slots[0, :n] = np.asarray(self.pages)[at // PS] * PS + at % PS
+        logits, self.kv_k, self.kv_v, self.state = self.prefill(
+            params, jnp.asarray(tok), jnp.asarray(pos), self.kv_k,
+            self.kv_v, self.table(2), jnp.asarray(slots),
+            jnp.asarray([n - 1, 0]), None, self.state,
+            jnp.asarray([self.slot, self.drop], jnp.int32))
+        return np.asarray(logits[0])
+
+
+def test_from_hf_config_on_the_catalog_config():
+    """(e) the published config: attention at layers 7 and 21 only, the
+    Mamba sizes as published, and what the module does not compute is
+    refused."""
+    with open(os.path.join(ROOT, "benchmark", "configs", "jamba2-3b",
+                           "about.json")) as f:
+        published = json.load(f)["published"]
+    cfg = ModelConfig.from_hf_config(published)
+    assert cfg.attn_layer_ids == (7, 21) and cfg.num_layers == 28
+    assert (cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_dt_rank,
+            cfg.mamba_d_inner) == (16, 4, 160, 5120)
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_) == (20, 1, 128)
+    assert cfg.tie_word_embeddings and cfg.has_recurrent_state
+    assert get_model_module(cfg) is jamba
+    assert jamba.segments(cfg) == [("mamba", 0, 0, 7), ("attn", 0, 7),
+                                   ("mamba", 7, 8, 13), ("attn", 1, 21),
+                                   ("mamba", 20, 22, 6)]
+    with pytest.raises(NotImplementedError, match="num_experts"):
+        ModelConfig.from_hf_config(dict(published, num_experts=2))
+    with pytest.raises(NotImplementedError, match="sliding_window"):
+        ModelConfig.from_hf_config(dict(published, sliding_window=4096))
+
+
+@pytest.mark.parametrize("tied", [False, True])
+def test_prefill_and_window_match_reference(tied):
+    """(a) and (f): prefill_step then decode_window through the pools
+    against the reference's full forward, on logits (the window's top-8
+    log-probabilities at each of its steps), with the head tied and not."""
+    cfg = tiny(tie_word_embeddings=tied)
+    params = jamba.init_params(cfg, jax.random.PRNGKey(0))
+    assert ("lm_head" in params) == (not tied)
+    pools = Pools(cfg)
+    prompt = np.random.default_rng(0).integers(1, 512, 21)
+    logits = pools.run_prefill(params, prompt, 0, 32)
+    want = ref_logits(params, cfg, prompt)
+    assert np.abs(logits - want[-1]).max() < ATOL
+    # padding rows read and wrote the drop slot, and left it as it was
+    assert float(jnp.abs(pools.state[0][pools.drop]).max()) == 0.0
+
+    window = jamba.make_decode_window_fn(cfg, True, 64)
+    B, K = 2, 4
+    first = int(np.argmax(logits))
+    toks, emitted, aux, _carry, *_ = window(
+        params, jnp.asarray([first, 0], jnp.int32),
+        jnp.asarray([len(prompt), -1], jnp.int32), jnp.zeros(B, bool),
+        jnp.zeros(B, jnp.int32), jnp.asarray([100, 1], jnp.int32),
+        pools.kv_k, pools.kv_v, pools.table(B), jnp.zeros(B),
+        jnp.zeros(B, jnp.int32), jnp.ones(B), jnp.zeros(B, jnp.uint32),
+        jnp.full((B, 8), -1, jnp.int32), None, pools.state,
+        jnp.asarray([pools.slot, pools.drop], jnp.int32),
+        k_steps=K, logprobs_topn=8)
+    assert list(np.asarray(emitted)) == [K, 0]
+    seq = list(prompt) + [first] + [int(t) for t in toks[0]]
+    want = np.asarray(jax.nn.log_softmax(
+        ref_logits(params, cfg, seq[:-1]), -1))
+    _lp, top_vals, top_ids = aux
+    for j in range(K):
+        at = len(prompt) + j
+        got = np.asarray(top_vals[0, j])
+        assert np.abs(got - want[at][np.asarray(top_ids[0, j])]).max() < ATOL
+        assert int(toks[0, j]) == int(np.argmax(want[at]))
+
+
+@pytest.mark.parametrize("cuts", [(13,), (8, 29)])
+def test_a_prompt_in_chunks_gives_the_same_logits_and_state(cuts):
+    """(b) a 37-token prompt prefilled whole, in 2 and in 3 chunks (one
+    cut off a page boundary is not possible in the engine; these are on
+    it and off it for the scan and the conv tail, which do not care):
+    the same last logits and the same stored state."""
+    cfg = tiny()
+    params = jamba.init_params(cfg, jax.random.PRNGKey(1))
+    prompt = np.random.default_rng(1).integers(1, 512, 37)
+    whole = Pools(cfg)
+    want = whole.run_prefill(params, prompt, 0, 64)
+    assert np.abs(want - ref_logits(params, cfg, prompt)[-1]).max() < ATOL
+
+    parts = Pools(cfg)
+    edges = (0, *cuts, len(prompt))
+    for a, b in zip(edges, edges[1:]):
+        got = parts.run_prefill(params, prompt[a:b], a, 32)
+    assert np.abs(got - want).max() < ATOL
+    for x, y in zip(parts.state, whole.state):
+        assert np.abs(np.asarray(x[parts.slot], np.float32)
+                      - np.asarray(y[whole.slot], np.float32)
+                      ).max() < ATOL
+    # the fault this guards against is visible at this tolerance: a
+    # second chunk that starts from zeros instead of the carried state
+    lost = Pools(cfg)
+    lost.run_prefill(params, prompt[:cuts[0]], 0, 32)
+    lost.state = jax.tree.map(jnp.zeros_like, lost.state)
+    for a, b in zip(edges[1:], edges[2:]):
+        bad = lost.run_prefill(params, prompt[a:b], a, 32)
+    assert np.abs(bad - want).max() > 100 * ATOL
+
+
+# ------------------------------------------------------ through JaxEngine
+
+
+def _engine(cfg=None, **over) -> JaxEngine:
+    base = dict(page_size=PS, num_pages=64, max_batch=4, prefill_chunk=16,
+                batch_buckets=(4,), prefill_buckets=(16,),
+                page_buckets=(16,), max_prefill_batch=2, decode_steps=4,
+                warmup_logprobs=False)
+    base.update(over)
+    return JaxEngine(cfg or tiny(), EngineConfig(**base), seed=0)
+
+
+def _req(prompt, n, logprobs=None):
+    return PreprocessedRequest(
+        token_ids=[int(t) for t in prompt], sampling=SamplingOptions(),
+        stop=StopConditions(max_tokens=n, ignore_eos=True),
+        output=OutputOptions(logprobs=logprobs))
+
+
+async def _gen(engine, prompt, n, logprobs=None):
+    toks, tops = [], []
+    async for out in engine.generate(_req(prompt, n, logprobs), Context()):
+        toks.extend(out.token_ids)
+        tops.extend(out.top_logprobs or [])
+        if out.finish_reason is not None:
+            break
+    return toks, tops
+
+
+def _prompts(seed, *lens):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 512, n).tolist() for n in lens]
+
+
+def test_generate_matches_reference_and_interleaving_changes_nothing(
+        run_async):
+    """(c) a 37-token prompt crosses three prefill chunks of 16 and then
+    four windows: the engine's top-5 log-probabilities agree with the
+    reference at every position; two sequences interleaved give what
+    each gives alone."""
+    eng = _engine()
+    p1, p2 = _prompts(2, 37, 11)
+
+    async def main():
+        a, tops = await _gen(eng, p1, 13, logprobs=5)
+        b, _ = await _gen(eng, p2, 9)
+        both = await asyncio.gather(_gen(eng, p1, 13), _gen(eng, p2, 9))
+        stats = eng.stats()
+        await eng.stop()
+        return a, tops, b, both, stats
+
+    a, tops, b, both, stats = run_async(main())
+    want = np.asarray(jax.nn.log_softmax(
+        ref_logits(eng.params, eng.cfg, p1 + a[:-1]), -1))
+    for j, top in enumerate(tops):
+        row = want[len(p1) - 1 + j]
+        assert max(abs(row[i] - v) for i, v in top.items()) < ATOL
+    assert both[0][0] == a and both[1][0] == b
+    assert stats["state_slots_active"] == 0
+    assert stats["state_slots_total"] == 4
+    assert stats["state_pool_bytes"] == sum(x.nbytes for x in eng.state)
+    assert 0 < stats["state_slots_held_total"] \
+        <= stats["state_slots_seen_total"]
+
+
+def test_the_same_prompt_twice_prefills_twice(run_async):
+    """(d) no prefix hit for a model with recurrent state: the second
+    request computes every prompt token again and answers alike; no page
+    is ever published."""
+    eng = _engine()
+    (p,) = _prompts(3, 40)
+
+    async def main():
+        a, _ = await _gen(eng, p, 6)
+        mid = eng.stats()
+        b, _ = await _gen(eng, p, 6)
+        end = eng.stats()
+        await eng.stop()
+        return a, b, mid, end
+
+    a, b, mid, end = run_async(main())
+    assert a == b
+    assert mid["prefill_tokens_total"] == 40
+    assert end["prefill_tokens_total"] == 80
+    assert end["prefix_hit_tokens_total"] == 0
+    assert end["kv_cached_blocks"] == 0 and not eng.pm.by_hash
+
+
+def test_a_slot_is_reused_while_the_previous_window_is_in_flight(run_async):
+    """(c) max_batch 2 = two state slots, five requests of different
+    lengths: each finish hands its slot to a waiting request while the
+    pipelined window that still lists the finished row is in flight.
+    Every answer equals the one the request gets alone."""
+    eng = _engine(max_batch=2, batch_buckets=(2,))
+    prompts = _prompts(4, 9, 21, 14, 30, 5)
+    lens = [5, 11, 7, 3, 9]
+    claimed = []
+    admit = eng._admit
+
+    def spy():
+        before = {id(s) for s in eng.prefilling}
+        admit()
+        claimed.extend((s.state_slot, bool(eng._inflight))
+                       for s in eng.prefilling if id(s) not in before)
+
+    eng._admit = spy
+
+    async def main():
+        alone = [(await _gen(eng, p, n))[0] for p, n in zip(prompts, lens)]
+        del claimed[:]
+        together = await asyncio.gather(*(
+            _gen(eng, p, n) for p, n in zip(prompts, lens)))
+        stats = eng.stats()
+        await eng.stop()
+        return alone, [t for t, _ in together], stats
+
+    alone, together, stats = run_async(main())
+    assert together == alone
+    assert len(claimed) == 5 and {s for s, _ in claimed} == {0, 1}
+    assert any(inflight for _, inflight in claimed[2:]), \
+        "no slot changed hands with a window in flight"
+    assert stats["state_slots_active"] == 0
+
+
+def test_a_row_that_stops_mid_window_keeps_the_state_of_its_last_token(
+        run_async):
+    """(c) max_tokens 3 = one token from prefill and two of a 4-step
+    window: the row freezes after step 2. Its slot then holds the state
+    after the last token it CONSUMED (prompt + 2 tokens; the third was
+    sampled and never fed back). Shown on logits: one more decode step
+    from the slot and the pages, on the third token, against the
+    reference's last row."""
+    eng = _engine()
+    (p,) = _prompts(5, 19)
+    held = []
+    release = eng._release
+
+    def spy(seq):
+        held.append((list(seq.pages), seq.state_slot))
+        release(seq)
+
+    eng._release = spy
+
+    async def main():
+        toks, _ = await _gen(eng, p, 3)
+        await eng.stop()
+        return toks
+
+    toks = run_async(main())
+    assert len(toks) == 3
+    (pages, slot), = [h for h in held if h[1] is not None]
+    pos = len(p) + 2                    # position of the unconsumed token
+    table = np.zeros((4, 16), np.int32)
+    table[0, :len(pages)] = pages
+    flat = np.full(4, DROP_SLOT, np.int32)
+    flat[0] = pages[pos // PS] * PS + pos % PS
+    positions = np.full(4, -1, np.int32)
+    positions[0] = pos
+    slots = np.full(4, eng.ecfg.max_batch, np.int32)
+    slots[0] = slot
+    logits, *_ = eng.decode_fn(
+        eng.params, jnp.asarray([toks[-1], 0, 0, 0], jnp.int32),
+        jnp.asarray(positions), eng.kv_k, eng.kv_v, jnp.asarray(table),
+        jnp.asarray(flat), eng.state, jnp.asarray(slots))
+    want = ref_logits(eng.params, eng.cfg, p + toks)[-1]
+    assert np.abs(np.asarray(logits[0]) - want).max() < ATOL
+
+
+def test_preempt_and_resume_equals_an_uninterrupted_run(run_async):
+    """(c) a pool too small for four rows preempts some; a preempted row
+    gives up its slot, prefills again from position 0 into whichever
+    slot it is given, and still answers as it does alone."""
+    eng = _engine(num_pages=16, watermark_pages=1, prefill_buckets=(16, 32),
+                  prefill_chunk=32)
+    prompts = _prompts(6, 16, 16, 16, 16)
+    preempted = []
+    grow = eng._grow_or_preempt
+
+    def spy(batch, lookahead):
+        before = {id(s): s for s in eng.running}
+        grow(batch, lookahead)
+        preempted.extend(s for s in eng.waiting if id(s) in before)
+        assert all(s.state_slot is None for s in eng.waiting)
+
+    eng._grow_or_preempt = spy
+
+    async def main():
+        alone = [(await _gen(eng, p, 16))[0] for p in prompts]
+        del preempted[:]
+        together = await asyncio.wait_for(asyncio.gather(*(
+            _gen(eng, p, 16) for p in prompts)), 300)
+        stats = eng.stats()
+        await eng.stop()
+        return alone, [t for t, _ in together], stats
+
+    alone, together, stats = run_async(main())
+    assert preempted, "the pool was meant to run out"
+    assert together == alone
+    assert stats["state_slots_active"] == 0
+    assert sorted(eng._state_free) == [0, 1, 2, 3]
+
+
+def test_warmup_covers_the_serving_forms(run_async):
+    """The state operand is part of every program's call form: warmup()
+    goes through the same helpers as serving, so nothing compiles after
+    it, with the fence set to raise."""
+    eng = _engine()
+    eng.warmup()
+    (p,) = _prompts(7, 37)
+
+    async def main():
+        toks, _ = await _gen(eng, p, 9)
+        stats = eng.stats()
+        await eng.stop()
+        return toks, stats
+
+    toks, stats = run_async(main())
+    assert len(toks) == 9 and stats["post_warmup_compiles_total"] == 0
+
+
+def test_models_without_state_take_no_state_operand():
+    """A llama engine holds no pool, reports no state keys and keeps its
+    call forms: its programs are the parent's."""
+    eng = JaxEngine(ModelConfig.tiny(), EngineConfig(
+        page_size=PS, num_pages=16, max_batch=2, batch_buckets=(2,),
+        prefill_buckets=(16,), page_buckets=(8,), prefill_chunk=16), seed=0)
+    assert eng.state is None and eng._state_args(None) == ()
+    assert eng.pm.prefix_reuse
+    assert not [k for k in eng.stats() if k.startswith("state_")]
+
+
+# ------------------------------------------------------------- refusals
+
+
+class _Stateful:
+    """Stands for an engine that serves a model with recurrent state."""
+    state = object()
+
+
+def _refused(what):
+    return pytest.raises(NotImplementedError,
+                         match=f"{what}.*recurrent state")
+
+
+def test_the_host_tier_refuses_recurrent_state():
+    with _refused("host KV tier"):
+        _engine(host_pages=8)
+
+
+def test_spec_decode_refuses_recurrent_state():
+    with _refused("spec_decode"):
+        _engine(spec_decode=True)
+
+
+def test_a_mesh_of_several_devices_refuses_recurrent_state():
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2),
+                ("data", "model"))
+    with _refused("mesh"):
+        JaxEngine(tiny(), EngineConfig(page_size=PS, num_pages=16),
+                  mesh=mesh)
+
+
+def test_disagg_prefill_refuses_recurrent_state():
+    from dynamo_tpu.llm.disagg.prefill_worker import PrefillWorker
+
+    with _refused("disaggregated prefill worker"):
+        PrefillWorker(None, _Stateful())
+
+
+def test_disagg_decode_refuses_recurrent_state():
+    from dynamo_tpu.llm.disagg.decode import DisaggDecodeEngine
+
+    with _refused("disaggregated decode engine"):
+        DisaggDecodeEngine(_Stateful(), None, None, None, "d0")
+
+
+def test_kv_transfer_refuses_recurrent_state():
+    from dynamo_tpu.llm.disagg.transfer import KvTransferServer
+
+    with _refused("KV transfer server"):
+        KvTransferServer(_Stateful())
