@@ -38,7 +38,7 @@ from ..ops.paged_attention import (effective_window,
                                    paged_attention_decode_sharded,
                                    paged_attention_prefill,
                                    paged_attention_prefill_sharded)
-from ..runtime.config import env_flag, env_int
+from ..runtime.config import env_flag
 from .config import ModelConfig
 
 Params = Dict[str, jax.Array]
@@ -448,129 +448,195 @@ def _sliding_flag(cfg: ModelConfig, l_idx):
     return (l_idx % 2) == 0
 
 
-def _dyn_expert(w, e):
-    """One expert's weight from the stacked [E, ...] tensor by traced
-    index — dequantizing after the slice when quantized, so the scan body
-    only reads the ACTIVE expert's int8 bytes from HBM."""
+def _dyn_expert(w, e, layer=None):
+    """One expert's weight by traced index, as float32: from a layer's
+    stacked [E, ...] tensor, or with ``layer`` from the whole
+    [L, E, ...] parameter in ONE dynamic_slice, so nothing the size of a
+    layer's expert stack is ever made. Dequantizes after the slice when
+    quantized: the loop body only reads the ACTIVE expert's int8 bytes."""
     from .quant import QuantInt8
 
+    def one(a):
+        if layer is None:
+            return lax.dynamic_index_in_dim(a, e, 0, False)
+        tail = a.shape[2:]
+        return lax.dynamic_slice(
+            a, (layer, e) + (0,) * len(tail), (1, 1) + tail).reshape(tail)
+
     if isinstance(w, QuantInt8):
-        return QuantInt8(lax.dynamic_index_in_dim(w.q, e, 0, False),
-                         lax.dynamic_index_in_dim(w.s, e, 0, False)
-                         ).dequant(jnp.float32)
-    return lax.dynamic_index_in_dim(w, e, 0, False).astype(jnp.float32)
+        return QuantInt8(one(w.q), one(w.s)).dequant(jnp.float32)
+    return one(w).astype(jnp.float32)
+
+
+def moe_block(n_tokens: int, top_k: int, w_shape) -> int:
+    """Rows of one block of the sorted dispatch, from the shapes at trace
+    time (``w_shape``: the [..., E, D, I] of an expert stack).
+
+    The power of two at or above the pairs an expert gets from a full
+    bucket (N*k/E), within 32..256: a sparse bucket then pads each live
+    expert's one block with few dead rows. Where reading an expert's
+    weights outweighs a 256-row block's own traffic the padding rows are
+    free and a second block for the same expert is not (it reads the
+    weights again), so the block is the ridge's 256 whatever the bucket.
+    Per block: 3 bf16 matrices, 6*D*I bytes, against ~16 float32 bytes a
+    row for each of D + I columns (x in, the two [block, I] products,
+    y out and into place). On the v5e (PERF.md, PR 28): Mixtral-8x7B
+    (4,096 x 14,336) runs 512 slots in 4.5 ms a layer at 256 against
+    4.1-6.0 at 128; Qwen3-30B-A3B (2,048 x 768) runs 2,048 slots in
+    5.1-7.0 ms at 128 against 6.0 at 256."""
+    E, D, I = w_shape[-3:]
+    if 6 * D * I >= 16 * 256 * (D + I):
+        return 256
+    mean = max(n_tokens * top_k // E, 1)
+    return min(max(1 << (mean - 1).bit_length(), 32), 256)
+
+
+def moe_block_plan(counts: jax.Array, block: int, n_max: int):
+    """Which blocks the sorted dispatch runs, from the live pairs per
+    expert ``counts`` [E]: expert e owns ``ceil(counts[e] / block)``
+    consecutive blocks over its run of the sorted pairs, and an expert
+    with no pair owns none.
+
+    Returns ``(n_blocks, block_e, row0, row_end)``: the number of blocks
+    that hold a pair (``sum_e ceil(counts[e] / block)``), and for block
+    j < n_blocks its expert, its first sorted pair and the end of its
+    expert's run (its rows are ``row0[j] + i < row_end[j]``). The three
+    vectors are ``n_max`` long; entries at or past ``n_blocks`` mean
+    nothing."""
+    E = counts.shape[0]
+    per_e = (counts + block - 1) // block
+    b_end = jnp.cumsum(per_e)
+    g_end = jnp.cumsum(counts)
+    j = jnp.arange(n_max, dtype=jnp.int32)
+    block_e = jnp.minimum(
+        jnp.sum(j[:, None] >= b_end[None, :], axis=1), E - 1)
+    row0 = ((g_end - counts)[block_e]
+            + (j - (b_end - per_e)[block_e]) * block)
+    return b_end[-1], block_e, row0, g_end[block_e]
 
 
 def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
-                        w_gate, w_up, w_down, block: int = 256,
-                        act=jax.nn.silu) -> jax.Array:
-    """Sparse top-k expert dispatch with static shapes and NO token drops.
+                        w_gate, w_up, w_down, block: int, live=None,
+                        layer=None, act=jax.nn.silu) -> jax.Array:
+    """Sparse top-k expert dispatch with static shapes, NO token drops,
+    and work in proportion to the LIVE (token, expert) pairs.
 
-    x: [N, D] (f32) flattened tokens; weights/idx: [N, k] routing output.
-    Sort the N*k (token, expert) pairs by expert, pad each expert's group
-    to a multiple of ``block``, and scan fixed-size blocks — each block
-    belongs to ONE expert, fetched by traced index (a dynamic-slice, so
-    HBM only streams the active experts' weights). Cost ≈ (k/E +
-    padding) of the dense-over-experts einsum; exact same math (the
-    per-expert MLP is linear in which rows are present — padded rows are
-    zero and are never scattered back).
+    x: [N, D] (f32) flattened tokens; weights/idx: [N, k] routing output;
+    live: optional [N] bool, False on padding rows (None = all live);
+    w_*: a layer's [E, ...] expert stacks, or with ``layer`` (traced
+    index) the whole [L, E, ...] parameters, read in place.
 
-    TPU-first shape rationale: argsort/cumsum/gather are bandwidth-bound
-    O(N·k·D); each scanned block is a [block, D]×[D, I] MXU matmul.
-    Reference analog: vLLM's fused_moe dispatch (the reference serves
-    Mixtral through vLLM); this is the XLA-native equivalent.
+    Sort the N*k pairs by expert, a dead row's pairs behind every
+    expert's run. Expert e's run is cut into ``ceil(count_e / block)``
+    blocks and only those run (``moe_block_plan``; a ``fori_loop`` whose
+    bound is known on the device after the sort — on the v5e 3-17% faster
+    than a scan of the worst case under a ``cond``): each gathers its rows
+    of ``x``, fetches ONE expert's weights by traced index (HBM only
+    streams the experts that hold a pair) and writes its [block, D]
+    result at its place in sorted order. A run's last block overhangs
+    the next run with zero rows, which that run's own blocks, coming
+    later, overwrite. Every pair's result is then read back from its
+    sorted place and summed into its token with its routing weight; a
+    dead row reads zeros. Exact same math as the dense-over-experts
+    einsum: the per-row MLP does not depend on which block a row sits in.
+
+    Nothing D wide is sized by a worst case: the one buffer is the
+    N*k + block pairs' own results, of which a sparse bucket writes only
+    its live blocks. Reference analog: vLLM's fused_moe dispatch.
     """
     N, D = x.shape
     k = idx.shape[-1]
-    E = w_gate.shape[0]
+    E = w_gate.shape[-3]
     NK = N * k
-    nb = (NK + block - 1) // block + E  # static worst-case block count
+    n_max = (NK + block - 1) // block + E
 
-    pair_e = idx.reshape(-1)                          # [NK]
-    pair_t = jnp.repeat(jnp.arange(N, dtype=jnp.int32), k)
-    pair_w = weights.reshape(-1).astype(jnp.float32)
-    order = jnp.argsort(pair_e, stable=True)
-    se, st, sw = pair_e[order], pair_t[order], pair_w[order]
+    with jax.named_scope("moe.dispatch"):
+        pair_e = idx.reshape(-1)                          # [NK]
+        if live is not None:
+            pair_e = jnp.where(jnp.repeat(live, k), pair_e, E)
+        order = jnp.argsort(pair_e, stable=True)          # sorted -> pair
+        place = jnp.zeros((NK,), jnp.int32).at[order].set(
+            jnp.arange(NK, dtype=jnp.int32), unique_indices=True)
+        # token of each sorted pair, padded so a block's rows are one slice
+        tok = jnp.pad(order // k, (0, block))
+        counts = jnp.sum(jax.nn.one_hot(pair_e, E, dtype=jnp.int32), axis=0)
+        n_blocks, block_e, row0, row_end = moe_block_plan(
+            counts, block, n_max)
 
-    counts = jnp.sum(jax.nn.one_hot(pair_e, E, dtype=jnp.int32), axis=0)
-    start = jnp.cumsum(counts) - counts               # exclusive, [E]
-    padded = ((counts + block - 1) // block) * block
-    pend = jnp.cumsum(padded)                         # padded group ends
-    pstart = pend - padded
-    pos = jnp.arange(NK, dtype=jnp.int32) - start[se]
-    dest = pstart[se] + pos                           # [NK], < nb*block
+    def run_block(j, ys):
+        r0 = row0[j]
+        rows = r0 + jnp.arange(block, dtype=jnp.int32)
+        t = lax.dynamic_slice(tok, (r0,), (block,))
+        xb = jnp.where((rows < row_end[j])[:, None], x[t], 0.0)
+        wg, wu, wd = (_dyn_expert(w, block_e[j], layer)
+                      for w in (w_gate, w_up, w_down))
+        yb = (act(xb @ wg) * (xb @ wu)) @ wd
+        return lax.dynamic_update_slice(ys, yb, (r0, 0))
 
-    buf = jnp.zeros((nb * block, D), jnp.float32).at[dest].set(x[st])
-    # block j covers rows [j*block, (j+1)*block) of exactly one padded
-    # group; slack blocks past the last group stay all-zero (clamped
-    # expert index — their output is discarded by the scatter-back)
-    bstart = jnp.arange(nb, dtype=jnp.int32) * block
-    block_e = jnp.minimum(
-        jnp.sum(bstart[:, None] >= pend[None, :], axis=1), E - 1)
-
-    def body(_, inp):
-        xb, be = inp
-        wg, wu, wd = (_dyn_expert(w, be) for w in (w_gate, w_up, w_down))
-        return None, (act(xb @ wg) * (xb @ wu)) @ wd
-
-    _, yb = lax.scan(body, None, (buf.reshape(nb, block, D), block_e))
-    contrib = yb.reshape(nb * block, D)[dest] * sw[:, None]
-    return jnp.zeros((N, D), jnp.float32).at[st].add(contrib)
+    with jax.named_scope("moe.experts"):
+        ys = jnp.zeros((NK + block, D), jnp.float32)
+        ys = lax.fori_loop(0, n_blocks, run_block, ys)
+    with jax.named_scope("moe.dispatch"):
+        return jnp.sum(ys[place].reshape(N, k, D)
+                       * weights.astype(jnp.float32)[..., None], axis=1)
 
 
-# scanned block height for the sorted dispatch (MXU-friendly; also the
-# per-expert padding quantum, so it enters the cost model below)
-_MOE_BLOCK = env_int("DYN_MOE_BLOCK")
+# rows through one expert up to which computing EVERY expert for every
+# row is free: the experts' weights are read from HBM either way, and N
+# rows against one read of a bf16 [D, I] matrix are N FLOP a byte, under
+# the v5e's ridge (197 TFLOP/s / 819 GB/s = 240 FLOP a byte)
+_MOE_DENSE_ROWS = 256
 
 
 def _moe_use_blocked(mesh, n_tokens: int, n_experts: int,
-                     top_k: int, block: int) -> bool:
-    """Blocked dispatch only where its cost model actually wins, and
-    only on UNSHARDED execution.
+                     top_k: int) -> bool:
+    """The sorted dispatch for dispatches past the chip's ridge, and only
+    on UNSHARDED execution.
 
-    Cost in row-MLPs: blocked pays worst-case ``N*k + E*block`` (every
-    pair once, plus up to one padded block per expert — slack blocks are
-    scanned too); dense-over-experts pays ``N*E``. Require blocked to be
-    at least 2x cheaper so the argsort/one-hot/scatter overhead can't
-    eat the margin — a flat token threshold would mis-fire near the
-    boundary (e.g. Mixtral E=8, k=2 at N=256: blocked is ~1.25x DENSE).
+    Up to ``_MOE_DENSE_ROWS`` rows the dense-over-experts einsum is bound
+    by the one read of the experts' weights that the sorted form pays as
+    well (every decode window, N = 1..128, and a PB 1 x T 128 prefill);
+    past it the dense form computes E/k times the row-MLPs that were
+    routed, and the sorted form only the blocks that hold a live pair.
 
     Under any >1-device mesh the tokens/experts are GSPMD-sharded and
-    the sort/scatter would turn into cross-device gathers — there the
+    the sort/gather would turn into cross-device gathers — there the
     dense einsum (whose E axis shards cleanly over the "expert" mesh
     axis) stays the right program."""
-    return (n_experts > 1
-            and n_tokens * top_k + n_experts * block
-            <= (n_tokens * n_experts) // 2
+    return (top_k < n_experts and n_tokens > _MOE_DENSE_ROWS
             and (mesh is None or mesh.size == 1))
 
 
 def _moe_mlp(h: jax.Array, w_router, w_gate, w_up, w_down,
-             top_k: int, mesh=None) -> jax.Array:
+             top_k: int, mesh=None, live=None, layer=None) -> jax.Array:
     """Mixtral-style MoE MLP: token-choice top-k routing.
 
     Two execution strategies, chosen at trace time (shapes are static
-    under jit):
-    - ``moe_experts_blocked`` sorted dispatch — ~top_k/E of the dense
-      FLOPs; default for big dispatches on an unsharded expert axis.
+    under jit) by ``_moe_use_blocked``:
+    - ``moe_experts_blocked`` sorted dispatch — work follows the live
+      (token, expert) pairs; ``live`` [B, T] bool marks the rows that
+      are not padding (None = all), and with ``layer`` the w_* are the
+      whole [L, E, ...] parameters, read in place (``forward``).
     - dense einsum over ALL experts weighted by the routing mask —
-      decode-sized dispatches (sort overhead dominates) and
-      expert-parallel meshes (GSPMD shards the E axis of the einsum;
-      the blocked scan's dynamic expert indexing would all-gather).
+      decode-sized dispatches (one read of the weights bounds both
+      forms) and expert-parallel meshes (GSPMD shards the E axis of the
+      einsum; the sorted form's dynamic expert indexing would
+      all-gather).
     """
     B, T, D = h.shape
-    E = w_gate.shape[0]
+    E = w_gate.shape[-3]
     with jax.named_scope("moe.router"):
         logits = (h @ w_router).astype(jnp.float32)  # [B, T, E]
         weights, idx = lax.top_k(logits, top_k)  # [B, T, k]
         weights = jax.nn.softmax(weights, axis=-1)
-    if _moe_use_blocked(mesh, B * T, E, top_k, _MOE_BLOCK):
-        with jax.named_scope("moe.experts"):
-            out = moe_experts_blocked(
-                h.reshape(B * T, D).astype(jnp.float32),
-                weights.reshape(B * T, top_k), idx.reshape(B * T, top_k),
-                w_gate, w_up, w_down, block=_MOE_BLOCK)
-            return out.reshape(B, T, D).astype(h.dtype)
+    if layer is not None or _moe_use_blocked(mesh, B * T, E, top_k):
+        out = moe_experts_blocked(
+            h.reshape(B * T, D).astype(jnp.float32),
+            weights.reshape(B * T, top_k), idx.reshape(B * T, top_k),
+            w_gate, w_up, w_down, moe_block(B * T, top_k, w_gate.shape),
+            live=None if live is None else live.reshape(B * T),
+            layer=layer)
+        return out.reshape(B, T, D).astype(h.dtype)
     with jax.named_scope("moe.router"):
         full_gate = jnp.sum(
             jax.nn.one_hot(idx, E, dtype=jnp.float32) * weights[..., None],
@@ -612,6 +678,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     safe_pos = jnp.maximum(positions, 0)
 
     layer_params = {k: params[k] for k in _layer_keys(cfg)}
+    # the sorted MoE dispatch reads w[layer, expert] from the stacked
+    # parameters: as scanned xs each layer's whole expert stack would be
+    # sliced out (copied) before the block loop may index it
+    moe_in_place = cfg.num_experts > 0 and _moe_use_blocked(
+        mesh, B * T, cfg.num_experts, cfg.num_experts_per_tok)
+    if moe_in_place:
+        experts = [layer_params.pop(k) for k in ("w_gate", "w_up", "w_down")]
+        live = positions >= 0   # padding rows make no (token, expert) pair
 
     def layer(h, xs):
         lp, l_idx, k_layer, v_layer = xs
@@ -643,9 +717,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
         if cfg.num_experts > 0:
             with jax.named_scope("moe"):
-                mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
-                                   lp["w_up"], lp["w_down"],
-                                   cfg.num_experts_per_tok, mesh=mesh)
+                if moe_in_place:
+                    mlp_out = _moe_mlp(x, lp["w_router"], *experts,
+                                       cfg.num_experts_per_tok, mesh=mesh,
+                                       live=live, layer=l_idx)
+                else:
+                    mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
+                                       lp["w_up"], lp["w_down"],
+                                       cfg.num_experts_per_tok, mesh=mesh)
         else:
             mlp_out = _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], act)
         h = _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)

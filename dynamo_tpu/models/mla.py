@@ -213,19 +213,20 @@ def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig,
     DeepSeek-V3 the dense-over-experts einsum is ~32x waste); decode-
     sized dispatches and expert-parallel meshes keep the dense einsum
     (see llama._moe_mlp for the strategy rationale)."""
-    from .llama import _MOE_BLOCK, _moe_use_blocked, moe_experts_blocked
+    from .llama import _moe_use_blocked, moe_block, moe_experts_blocked
 
     B, T, D = x.shape
     E = lp["w_gate_e"].shape[0]
     x32 = x.astype(jnp.float32)
     w, topi = _deepseek_gate(x32, lp["w_router"],
                              lp.get("router_bias"), cfg)
-    if _moe_use_blocked(mesh, B * T, E, cfg.num_experts_per_tok,
-                        _MOE_BLOCK):
+    k = cfg.num_experts_per_tok
+    if _moe_use_blocked(mesh, B * T, E, k):
         out = moe_experts_blocked(
-            x32.reshape(B * T, D), w.reshape(B * T, -1),
-            topi.reshape(B * T, -1), lp["w_gate_e"], lp["w_up_e"],
-            lp["w_down_e"], block=_MOE_BLOCK).reshape(B, T, D)
+            x32.reshape(B * T, D), w.reshape(B * T, k),
+            topi.reshape(B * T, k), lp["w_gate_e"], lp["w_up_e"],
+            lp["w_down_e"],
+            moe_block(B * T, k, lp["w_gate_e"].shape)).reshape(B, T, D)
     else:
         gate = _dense_gate(w, topi, E)
         ge = jnp.einsum("btd,edi->btei", x32,

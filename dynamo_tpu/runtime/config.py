@@ -342,8 +342,6 @@ register_env("DYN_MESH_SHAPE", None, "parallel",
 register_env("DYN_DISABLE_PALLAS", None, "models",
              "Any non-empty value forces the XLA gather attention path "
              "everywhere (Pallas kill switch).")
-register_env("DYN_MOE_BLOCK", "256", "models",
-             "Scanned block height for the sorted MoE dispatch.")
 register_env("DYN_PALLAS_INTERPRET", None, "models",
              "CPU test hook: any non-empty value runs Pallas kernels in "
              "interpret mode (never on a real TPU backend).")
