@@ -100,9 +100,7 @@ def metric_name(args) -> str:
                 f"overload ({_model_tag(args)} llama, {smoke})")
     if args.scenario == "hotpath":
         smoke = "cpu smoke" if getattr(args, "cpu", False) else "1 chip"
-        arm = ("legacy" if getattr(args, "hotpath_legacy", False)
-               else "overhauled")
-        return (f"ITL raw-chunk p99 ms, decode-heavy hot path ({arm} arm, "
+        return (f"ITL raw-chunk p99 ms, decode-heavy hot path ("
                 f"ISL~{args.isl}/OSL {args.osl}, {args.requests} reqs, "
                 f"conc {args.concurrency}, K={args.decode_steps}, "
                 f"{_model_tag(args)} llama, {smoke})")
@@ -186,8 +184,7 @@ def parse_args():
                          "engine vs --dp-replicas mesh-sharded replicas "
                          "behind the real HTTP frontend + KV router at "
                          "identical workload (tok/s, mesh_shape, "
-                         "per-replica device_time_fraction, compile "
-                         "counts); "
+                         "compile counts); "
                          "failover = dynarevive robustness bench: a "
                          "2-worker pool behind the KV router with one "
                          "worker killed mid-burst (goodput under churn + "
@@ -196,17 +193,8 @@ def parse_args():
                          "control (shed rate + admitted TTFT p99); "
                          "hotpath = dynaturbo decode hot-path record: "
                          "decode-heavy/small-batch/long-generation mix "
-                         "reporting itl_raw_chunk_p99_ms + the per-bucket "
-                         "cost table + loop-lag p99 + the compile fence "
-                         "in ONE record (forces --prof-sample 2 when "
-                         "unset); --hotpath-legacy runs the same workload "
-                         "with every hot-path optimization off for A/B")
-    ap.add_argument("--hotpath-legacy", action="store_true",
-                    help="hotpath scenario A/B arm: disable the dynaturbo "
-                         "optimizations (idle-prefill overlap, coalesced "
-                         "window emissions, sampler-param cache, in-step "
-                         "admission, async detok) and restore the legacy "
-                         "per-iteration event-loop yield")
+                         "reporting itl_raw_chunk_p99_ms + loop-lag p99 "
+                         "+ the compile fence in ONE record")
     ap.add_argument("--mesh", default=None,
                     help="sharded scenario: per-replica mesh as 'axis=N' "
                          "pairs (e.g. 'model=2'; default DYN_MESH_SHAPE "
@@ -282,12 +270,6 @@ def parse_args():
                          "chip is available")
     ap.add_argument("--spec-tokens", type=int, default=4,
                     help="max draft tokens verified per step (K)")
-    ap.add_argument("--prof-sample", type=int, default=0,
-                    help="dynaprof: profile every Nth engine step with a "
-                         "timed dispatch (device/host split + per-bucket "
-                         "cost table in the report). 0 = off: the hot "
-                         "path stays sync-free and the report's "
-                         "device_time_fraction/bucket_cost stay empty")
     ap.add_argument("--trace", action="store_true",
                     help="dyntrace: record a trace per benched request "
                          "(sampling forced to 1.0) and dump a per-request "
@@ -371,21 +353,11 @@ def engine_setup(args):
     if args.max_batch:
         ecfg.max_batch = args.max_batch
         ecfg.batch_buckets = (8, args.max_batch)
-    if getattr(args, "prof_sample", 0):
-        ecfg.prof_sample = args.prof_sample
     if getattr(args, "_spec_on", False):
         ecfg.spec_decode = True
         ecfg.spec_tokens = args.spec_tokens
     if args.prefill_token_budget is not None:
         ecfg.prefill_token_budget = args.prefill_token_budget
-    if getattr(args, "hotpath_legacy", False):
-        # dynaturbo A/B "before" arm: every hot-path toggle off (the env
-        # side — DYN_LOOP_YIELD / DYN_ASYNC_DETOK — is set in main()
-        # before the engine loop starts)
-        ecfg.overlap_idle_prefill = False
-        ecfg.coalesce_window_emissions = False
-        ecfg.cache_sampler_params = False
-        ecfg.admit_in_step = False
     if args.scenario == "multiturn":
         # size the HBM pool BELOW the conversation working set so turns
         # evict each other; the host tier is what keeps TTFT low
@@ -525,8 +497,6 @@ async def run_multiturn(args):
         "post_warmup_compiles": stats["post_warmup_compiles_total"],
         "loop_lag_p99_ms": round(
             stats["loop_lag_p99_seconds"] * 1000, 2),
-        "device_time_fraction": stats["device_time_fraction"],
-        "bucket_cost": stats["bucket_cost"],
     }
     print(json.dumps(report), file=sys.stderr)
     return report
@@ -857,9 +827,9 @@ async def run_shared(args):
         # dynaslo: goodput + per-role quantiles from the engine's merged
         # latency histograms (every wave's request rows judged)
         report["slo"] = _slo_block([st], all_rows)
-        # dynaheat flat cache keys: the per-toggle A/B driver and
-        # tools/cost_diff.py read these top-level (share-leg TTFT, the
-        # lifecycle counters, and the arm's toggle settings)
+        # dynaheat flat cache keys: the per-toggle A/B driver reads
+        # these top-level (share-leg TTFT, the lifecycle counters, and
+        # the arm's toggle settings)
         sorted_tt = sorted(share_ttfts)
         report["ttft_p50_ms"] = (round(
             sorted_tt[len(sorted_tt) // 2] * 1000, 1) if sorted_tt else None)
@@ -1026,8 +996,8 @@ async def run_sharded(args):
     unsharded engine and (b) --dp-replicas mesh-sharded engine replicas
     on partitioned submeshes — both behind the real aiohttp → HttpService
     → Processor → KvRouter → generate_tokens stack. Reports tok/s per
-    leg, the mesh shape, per-replica device_time_fraction and compile
-    counts (the compile fence must hold under sharding: 0 per replica)."""
+    leg, the mesh shape and compile counts (the compile fence must hold
+    under sharding: 0 per replica)."""
     import aiohttp
     import jax
     import numpy as np
@@ -1115,9 +1085,7 @@ async def run_sharded(args):
 
         return ([lambda: engine.decode_tokens_total],
                 lambda: engine.fence.post_warmup_compiles,
-                lambda: {"device_time_fraction":
-                         round(engine.profiler.device_time_fraction(), 4),
-                         "mesh_shape": "single"},
+                lambda: {"mesh_shape": "single"},
                 stop)
 
     async def start_sharded(drt):
@@ -1134,8 +1102,6 @@ async def run_sharded(args):
             return {
                 "mesh_shape": rs.mesh_shape,
                 "sharding": rs.describe(),
-                "per_replica_device_time_fraction":
-                    rs.device_time_fractions(),
                 "per_replica_compiles": rs.post_warmup_compiles(),
                 "per_replica_decode_tokens": {
                     r.name: r.engine.decode_tokens_total
@@ -1667,10 +1633,6 @@ async def run_bench(args):
     # compile-regression gate for hot-path work (ROADMAP item 3): any
     # nonzero value means a serve-time XLA compile stalled the run
     report["post_warmup_compiles"] = st["post_warmup_compiles_total"]
-    # dynaprof: sampled device/host split + per-bucket program costs
-    # (empty/0.0 unless --prof-sample > 0)
-    report["device_time_fraction"] = st["device_time_fraction"]
-    report["bucket_cost"] = st["bucket_cost"]
     # dynaslo: per-role latency quantiles from the engine's mergeable
     # histograms (no per-request rows here — measure() owns the client
     # view; goodput rides the shared/failover scenarios)
@@ -1690,13 +1652,10 @@ async def run_bench(args):
 
 
 async def run_hotpath(args):
-    """dynaturbo hot-path record: a decode-heavy, small-batch,
-    long-generation mix (ITL is decided by per-token host work, not
-    FLOPs, in this regime) with profiling forced on, so ONE record
-    carries the honest client metric (``itl_raw_chunk_p99_ms``), the
-    per-bucket dispatch/device cost table, loop-lag p99 and the compile
-    fence. Two invocations (±``--hotpath-legacy``) diff with
-    ``python -m tools.cost_diff``."""
+    """A decode-heavy, small-batch, long-generation mix (ITL is decided
+    by per-token host work, not FLOPs, in this regime): ONE record
+    carries the client metric (``itl_raw_chunk_p99_ms``), loop-lag p99
+    and the compile fence."""
     # decode-heavy defaults wherever the caller left the global ones:
     # short prompts, long generations, small concurrency
     if args.isl == 512:
@@ -1707,14 +1666,7 @@ async def run_hotpath(args):
         args.requests = 16
     if args.concurrency == 32:
         args.concurrency = 4
-    if not getattr(args, "prof_sample", 0):
-        # the record is useless as hot-path evidence without the cost
-        # table; sample every other iteration
-        args.prof_sample = 2
-    report = await run_bench(args)
-    report["hotpath_legacy"] = bool(getattr(args, "hotpath_legacy",
-                                            False))
-    return report
+    return await run_bench(args)
 
 
 async def run_disagg(args):
@@ -1741,8 +1693,6 @@ async def run_disagg(args):
                         trace=getattr(args, "trace", False))
     agg_st = engine.stats()
     agg["post_warmup_compiles"] = agg_st["post_warmup_compiles_total"]
-    agg["device_time_fraction"] = agg_st["device_time_fraction"]
-    agg["bucket_cost"] = agg_st["bucket_cost"]
     await engine.stop()
     base_ecfg = engine.ecfg
     del engine
@@ -1798,12 +1748,6 @@ async def run_disagg(args):
         dis["post_warmup_compiles"] = (
             decode_eng.fence.post_warmup_compiles
             + prefill_eng.fence.post_warmup_compiles)
-        # dynaprof per-leg: decode-engine device/host split + program
-        # cost table (the prefill engine's table rides under a suffix)
-        dis["device_time_fraction"] = round(
-            decode_eng.profiler.device_time_fraction(), 4)
-        dis["bucket_cost"] = decode_eng.profiler.cost_table()
-        dis["prefill_bucket_cost"] = prefill_eng.profiler.cost_table()
         dis["remote_prefills"] = (st["remote_prefills"]
                                   - before_st["remote_prefills"])
         dis["local_prefills"] = (st["local_prefills"]
@@ -2002,12 +1946,6 @@ def _run_sweep(args) -> dict:
 
 def main() -> int:
     args = parse_args()
-    if getattr(args, "hotpath_legacy", False):
-        # legacy arm env half: restore the per-iteration loop yield and
-        # inline detokenization (must land before the engine loop and
-        # the first Backend.generate read them)
-        os.environ["DYN_LOOP_YIELD"] = "1"
-        os.environ["DYN_ASYNC_DETOK"] = "0"
     if args.cpu:
         os.environ["JAX_PLATFORMS"] = "cpu"  # before jax is imported
         if args.scenario == "sharded":

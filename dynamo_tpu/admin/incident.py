@@ -7,7 +7,6 @@ block) into the human-readable 3 a.m. view:
 - header: trigger, detail, capture time, contributing workers
 - burn-rate timeline (SLO alert transitions found in the bundle)
 - per-stage trace rollup (span name -> count / total / max duration)
-- worst cost-table buckets vs their pre-incident baseline
 - cache hit-rate cliff (windowed vs lifetime hit rate per cache)
 - per-worker shadow rings, aligned by their timeline anchors
 
@@ -21,7 +20,7 @@ from __future__ import annotations
 
 import json
 import sys
-from typing import Any, Dict, List, Optional
+from typing import Dict, List, Optional
 
 
 def _fmt_ms(ms: Optional[float]) -> str:
@@ -91,50 +90,6 @@ def render_stage_rollup(bundle: dict) -> List[str]:
     for name, durs in rows[:20]:
         lines.append(f"  {name:<32} {len(durs):>6} "
                      f"{_fmt_ms(sum(durs)):>12} {_fmt_ms(max(durs)):>12}")
-    return lines
-
-
-def _cost_buckets(profiles: Any) -> Dict[str, dict]:
-    """Flatten {engine: {buckets: {key: {...}}}} into one keyed table."""
-    out: Dict[str, dict] = {}
-    for engine, prof in (profiles or {}).items():
-        for key, row in ((prof or {}).get("buckets") or {}).items():
-            out[f"{engine}/{key}"] = row if isinstance(row, dict) else {}
-    return out
-
-
-def render_cost_table(bundle: dict) -> List[str]:
-    lines = _section("worst cost-table buckets vs pre-incident baseline")
-    now = _cost_buckets((bundle.get("telemetry") or {}).get("profiles"))
-    base = _cost_buckets((bundle.get("baseline") or {}).get("profiles"))
-    if not now:
-        lines.append("(no cost table captured)")
-        return lines
-
-    def _us(row: dict) -> Optional[float]:
-        for k in ("dispatch_us_mean", "dispatch_us", "host_us_mean"):
-            if isinstance(row.get(k), (int, float)):
-                return float(row[k])
-        return None
-
-    rows = []
-    for key, row in now.items():
-        cur = _us(row)
-        if cur is None:
-            continue
-        ref = _us(base.get(key, {}))
-        delta = None if ref is None or ref == 0 else (cur - ref) / ref
-        rows.append((key, cur, ref, delta))
-    if not rows:
-        lines.append("(cost table has no dispatch timings)")
-        return lines
-    rows.sort(key=lambda r: -(r[3] if r[3] is not None else 0.0))
-    lines.append(f"  {'bucket':<44} {'now':>10} {'baseline':>10} "
-                 f"{'delta':>8}")
-    for key, cur, ref, delta in rows[:15]:
-        d = "-" if delta is None else f"{delta:+.0%}"
-        r = "-" if ref is None else f"{ref:.1f}us"
-        lines.append(f"  {key:<44} {cur:>9.1f}us {r:>10} {d:>8}")
     return lines
 
 
@@ -218,7 +173,6 @@ def render_postmortem(bundle: dict) -> str:
     lines += render_header(bundle)
     lines += render_burn_timeline(bundle)
     lines += render_stage_rollup(bundle)
-    lines += render_cost_table(bundle)
     lines += render_cache_cliff(bundle)
     lines += render_guard_state(bundle)
     lines += render_worker_rings(bundle)

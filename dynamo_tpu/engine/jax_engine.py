@@ -48,7 +48,7 @@ from ..models.config import ModelConfig
 from ..models.llama import DROP_SLOT, KVCacheSpec
 from ..models.registry import get_model_module
 from ..runtime import blackbox, guard, profiling, slo, tracing
-from ..runtime.config import env_bool, env_flag, env_int, env_str
+from ..runtime.config import env_bool, env_int, env_str
 from ..runtime.engine import Context
 from .jit_fence import CompileFence
 from .kv_manager import (RECURRENT_STATE_REFUSAL, ChainHashCache,
@@ -162,24 +162,15 @@ class EngineConfig:
     # K-fold. EOS/stop/budget masking runs ON DEVICE (rows freeze), so K
     # can grow without dead compute past a stop.
     decode_steps: int = 4
-    # pipelined dispatch: window N+1 (and the next prefill batch) are
-    # enqueued BEFORE window N's tokens are read back, so the host
-    # round-trip overlaps device compute. The device-side carry
-    # (tok/pos/done/steps/remaining) makes this exact, not speculative.
-    pipeline_decode: bool = True
-    # prefill-priority: iterations with prompts waiting to prefill skip
-    # the decode-window dispatch, so prompt batches drain at full cadence
-    # (measured: interleaving a K-step window between every prefill batch
-    # doubles TTFT and costs throughput by delaying batch build-up)
-    prefill_priority: bool = True
-    # token-budgeted chunked-prefill mixing (the vLLM-style middle ground
-    # between the two all-or-nothing policies above): when set, every
-    # iteration dispatches BOTH a decode window and a prefill batch, but
-    # the prefill batch is trimmed to at most this many prompt tokens, so
-    # a burst of long prompts cannot starve running decodes (ITL p99
-    # bounded by window + budget-prefill time instead of the full burst
-    # drain). None keeps pure prefill-priority. Overrides prefill_priority
-    # when set.
+    # the prefill policy of an iteration, one field. None is prefill
+    # priority: an iteration that ships a prefill batch ships no decode
+    # window, so prompt batches drain at full cadence (interleaving a
+    # K-step window between every prefill batch delays batch build-up).
+    # A number is token-budgeted mixing (the vLLM-style middle ground):
+    # every iteration ships BOTH a decode window and a prefill batch
+    # trimmed to at most this many prompt tokens, so a burst of long
+    # prompts cannot starve running decodes (ITL p99 bounded by window +
+    # budget-prefill time instead of the full burst drain).
     prefill_token_budget: Optional[int] = None
     # self-speculative decoding: a host-side prompt-lookup drafter
     # (engine/spec_decode.py) proposes up to spec_tokens candidates per
@@ -195,11 +186,6 @@ class EngineConfig:
     spec_tokens: int = 4      # K: max draft tokens verified per step
     spec_ngram_max: int = 4   # longest suffix n-gram the drafter matches
     spec_ngram_min: int = 1   # shortest n-gram worth matching
-    # dynaprof sampling cadence: profile every Nth scheduler iteration
-    # with a timed dispatch (device/host split + per-bucket cost table;
-    # engine/profiler.py). The sampled iteration pays one deliberate
-    # device sync. None reads DYN_PROF_SAMPLE; 0 disables (default).
-    prof_sample: Optional[int] = None
     # on-device stop table width (eos_token_ids + stop_token_ids rows,
     # padded with -1); requests with more ids fall back to the (lagging
     # but correct) host-side check
@@ -218,33 +204,6 @@ class EngineConfig:
     prefill_buckets: Tuple[int, ...] = (16, 64, 512)
     page_buckets: Tuple[int, ...] = (8, 64)
     watermark_pages: int = 4  # keep-free headroom before admitting
-    # ── decode hot-path toggles ──────────────────────────────────────
-    # each gates exactly ONE hot-path change so its cost-table delta can
-    # be measured in isolation (tools/cost_diff.py; docs/hot_path.md)
-    #
-    # prefill-priority iterations where the prefill sweep dispatched
-    # NOTHING (every candidate restore-gated / cancelled / cache-covered)
-    # still dispatch a decode window instead of idling the device for a
-    # whole iteration. TTFT semantics unchanged: iterations that actually
-    # dispatch a prefill batch still skip the window.
-    overlap_idle_prefill: bool = True
-    # read the window's on-device per-row emitted counts and emit each
-    # row's tokens as ONE chunk: one EngineOutput + one event-loop wakeup
-    # per row-window instead of per token, and one bulk page commit. Rows
-    # whose stop-id set exceeds max_eos_ids keep the per-token host path
-    # (the device stop table can't represent them).
-    coalesce_window_emissions: bool = True
-    # reuse the uploaded sampler-param/page-table device arrays across
-    # decode-window dispatches while the batch composition is unchanged,
-    # skipping the per-dispatch host→device re-upload. NOTE: freezes the
-    # per-dispatch reseed of UNSEEDED sampled rows for the cached span
-    # (seeded rows and greedy rows are bit-identical either way).
-    cache_sampler_params: bool = True
-    # run _admit inside _step right after the decode-window dispatch, so
-    # its host work (bucketing, page reservation, prefix-cache hashing)
-    # overlaps the window's device compute instead of serializing ahead
-    # of the dispatch on the event-loop thread
-    admit_in_step: bool = True
 
     def __post_init__(self) -> None:
         if self.prefill_chunk % self.page_size != 0:
@@ -612,9 +571,10 @@ class JaxEngine:
                 self.host_v = np.zeros(hv, hdtype)
         self.offload_pages_total = 0
         self.restore_pages_total = 0
-        # guards PageManager between the event-loop thread (_admit) and
-        # executor-thread disagg jobs (reserve/release/submit); engine steps
-        # are already serialized with those jobs by the single-worker executor
+        # guards PageManager between the event-loop thread's reads
+        # (cache_snapshot) and the step thread's admission and disagg jobs
+        # (reserve/release/submit), which the single-worker executor
+        # already serializes with the engine's steps
         self._pm_lock = threading.Lock()
         self.waiting: List[Sequence] = []
         self.prefilling: List[Sequence] = []
@@ -628,11 +588,8 @@ class JaxEngine:
         self._pending: Optional[_PendingWindow] = None
         self._pending_prefill: Optional[_PendingPrefill] = None
         self._deferred_free: List[Sequence] = []
-        # cache_sampler_params: (key, SamplingBatch, device arrays) of the
-        # last decode-window dispatch. The key holds the batch list itself
-        # (Sequence is identity-eq), so a stale hit after id() reuse is
-        # impossible — the cached refs keep those Sequences alive until
-        # the next composition change replaces the entry.
+        # (key, SamplingBatch, device arrays) of the last decode-window
+        # dispatch, reused while its key holds (_dispatch_decode_window)
         self._samp_cache: Optional[tuple] = None
         # tiered-KV overlap state: offload gathers dispatched but not yet
         # copied to the host pool (device arrays + target slots), and HBM
@@ -692,11 +649,8 @@ class JaxEngine:
             if _fn is not None:
                 setattr(self, _attr,
                         _stamp_dispatch(self.fence, _attr, _fn))
-        # dynaprof: sampled device/host dispatch timing + per-bucket cost
-        # (engine/profiler.py; sample=0 keeps the hot path sync-free)
-        self.profiler = EngineProfiler(f"jax-engine-{id(self):x}",
-                                       timeline=self.step_timeline,
-                                       sample=self.ecfg.prof_sample)
+        # the step thread's phase ledger (engine/profiler.py)
+        self.profiler = EngineProfiler(f"jax-engine-{id(self):x}")
         # per-page KV bytes (both pools) for attribution/occupancy
         # accounting — .nbytes is shape metadata, not a device sync
         self._page_bytes = int(
@@ -994,7 +948,7 @@ class JaxEngine:
         # carry-merge combos (tiny programs): window N+1's inputs stitch
         # the previous window's device carry with host rows for newly
         # admitted sequences — one compile per (B_prev, B_new) pair
-        if decode and ecfg.decode_steps > 1 and ecfg.pipeline_decode:
+        if decode and ecfg.decode_steps > 1:
             bset = grid["decode_batches"]
             for Bp in bset:
                 # under a mesh the in-flight window's carry is COMMITTED
@@ -1183,14 +1137,9 @@ class JaxEngine:
             # merges these across workers into fleet-wide quantiles
             "role": self.role,
             "latency_hist": self.latency.to_wire(),
-            # dynaprof: loop health + sampled device/host split +
-            # per-bucket program costs + page-pool occupancy
+            # dynaprof: loop health + page-pool occupancy
             "loop_lag_p50_seconds": lag["p50_s"],
             "loop_lag_p99_seconds": lag["p99_s"],
-            "device_time_fraction":
-                round(self.profiler.device_time_fraction(), 4),
-            "profiled_steps_total": self.profiler.profiled_steps,
-            "bucket_cost": self.profiler.cost_table(),
             "batch_dispatches_total": self.batch_dispatches_total,
             "kv_free_blocks": len(self.pm.free),
             "kv_cached_blocks": len(self.pm.reusable),
@@ -1316,12 +1265,8 @@ class JaxEngine:
         loop = asyncio.get_running_loop()
         # `await run_in_executor` suspends this coroutine at least once
         # per iteration (the step future is never done at await time), so
-        # the event loop already drains its ready queue every step. The
-        # historical unconditional `asyncio.sleep(0)` on top of that only
-        # bought a second scheduling round-trip per iteration — measured
-        # loop-lag p99 before/after in docs/hot_path.md. DYN_LOOP_YIELD=1
-        # restores it for A/B.
-        extra_yield = env_flag("DYN_LOOP_YIELD")
+        # the event loop drains its ready queue every step without a
+        # yield of its own here
         while not self._stopped:
             if not (self.waiting or self.prefilling or self.running
                     or self._inflight or self._pending_prefill):
@@ -1338,17 +1283,11 @@ class JaxEngine:
                 # free of the coroutine when no chaos is configured.
                 await guard.chaos_point("engine.stall")
             try:
-                if not self.ecfg.admit_in_step:
-                    # legacy placement: admission host work serializes
-                    # ahead of the step on the event-loop thread
-                    self._admit()
                 await loop.run_in_executor(self._exec, self._step)
                 self._reap()
             except Exception:  # noqa: BLE001 — engine loop must survive
                 log.exception("engine step failed")
                 await loop.run_in_executor(self._exec, self._abort_all)
-            if extra_yield:
-                await asyncio.sleep(0)
         # shutdown: drain in-flight windows so no client hangs on a queue
         if self._inflight or self._pending_prefill:
             try:
@@ -1367,83 +1306,62 @@ class JaxEngine:
             self.profiler.step_end()
 
     def _step_phases(self) -> None:
-        """Pipelined mode enqueues the next decode window AND the next
-        prefill chunk before reading back the previous window/prefill, so
-        the host round-trip (the dominant cost on dispatch-latency-bound
-        setups) overlaps device compute. Unpipelined modes keep the
-        reference-equivalent prefill-priority ordering."""
-        self.profiler.tick()  # dynaprof: one compare at sample=0
+        """One of three arms: speculative (``spec_decode``), single-step
+        (``decode_steps`` <= 1, the reference the window is tested
+        against) and the pipelined window arm that every deployment
+        runs. Each admits on this thread (``_admit``)."""
         self._drain_kv_tier()
         if self.verify_fn is not None:
-            if self.ecfg.admit_in_step:
-                self._admit_in_step()
+            self._admit()
             self._step_spec()
-            return
-        if self.ecfg.decode_steps <= 1:
-            # single-step decode: fully synchronous; budgeted mixing
-            # interleaves a decode step behind the trimmed prefill batch
-            if self.ecfg.admit_in_step:
-                self._admit_in_step()
-            budget = self.ecfg.prefill_token_budget
-            if self.prefilling:
-                pf = self._dispatch_prefill(budget)
-                if pf is not None:
-                    self._process_prefill(pf)
-            if self.running and (budget is not None
-                                 or not self.prefilling):
-                if budget is not None and self.prefilling:
-                    self.mixed_dispatches += 1
-                self._decode_step_single()
-            return
-        if not self.ecfg.pipeline_decode:
-            if self.ecfg.admit_in_step:
-                self._admit_in_step()
-            budget = self.ecfg.prefill_token_budget
-            if self.prefilling:
-                pf = self._dispatch_prefill(budget)
-                if pf is not None:
-                    self._process_prefill(pf)
-            if self.running and (budget is not None
-                                 or not self.prefilling):
-                if budget is not None and self.prefilling:
-                    self.mixed_dispatches += 1
-                pend = self._dispatch_decode_window()
-                if pend is not None:
-                    self._process_window(pend)
-            self._drain_deferred()
-            return
+        elif self.ecfg.decode_steps <= 1:
+            self._step_single()
+        else:
+            self._step_window()
+
+    def _step_single(self) -> None:
+        """Single-step decode: fully synchronous; budgeted mixing
+        interleaves a decode step behind the trimmed prefill batch."""
+        self._admit()
+        budget = self.ecfg.prefill_token_budget
+        if self.prefilling:
+            pf = self._dispatch_prefill(budget)
+            if pf is not None:
+                self._process_prefill(pf)
+        if self.running and (budget is not None or not self.prefilling):
+            if budget is not None and self.prefilling:
+                self.mixed_dispatches += 1
+            self._decode_step_single()
+
+    def _step_window(self) -> None:
+        """The pipelined window arm: enqueue the next decode window and
+        the next prefill chunk BEFORE reading back the previous ones, so
+        the host round-trip overlaps device compute (the on-device carry
+        makes this exact, not speculative). In order: dispatch, admit,
+        read back what the last iteration dispatched, free deferred
+        pages."""
         prev = self._pending
         prev_pf = self._pending_prefill
         budget = self.ecfg.prefill_token_budget
-        if (budget is None and self.ecfg.prefill_priority
-                and self.prefilling):
-            # prefill-priority: prompt batches drain at full cadence. But
-            # when the sweep dispatches NOTHING (every candidate
+        if budget is None and self.prefilling:
+            # prefill priority: an iteration that ships a prefill ships
+            # no window. When the sweep ships NOTHING (every candidate
             # restore-gated, cancelled, or cache-covered) the device
-            # would idle a whole iteration — fill the bubble with a
-            # decode window (overlap_idle_prefill). TTFT is untouched:
-            # iterations that actually ship a prefill still skip it.
-            self._pending_prefill = self._dispatch_prefill(budget)
-            if (self._pending_prefill is None
-                    and self.ecfg.overlap_idle_prefill):
-                self._pending = self._dispatch_decode_window()
-            else:
-                self._pending = None
+            # would idle a whole iteration: a decode window fills it.
+            self._pending_prefill = self._dispatch_prefill(None)
+            self._pending = (self._dispatch_decode_window()
+                             if self._pending_prefill is None else None)
         else:
-            # budgeted mixing (or prefill_priority off): decode windows
+            # budgeted mixing (or nothing to prefill): decode windows
             # keep their cadence even while prompts are prefilling
             self._pending = self._dispatch_decode_window()
             self._pending_prefill = self._dispatch_prefill(budget)
             if (self._pending is not None
                     and self._pending_prefill is not None):
                 self.mixed_dispatches += 1
-        if self.ecfg.admit_in_step:
-            # admission lands AFTER the dispatches: its host work
-            # (bucketing, page reservation, prefix hashing) overlaps the
-            # in-flight window's device compute instead of serializing
-            # ahead of the dispatch on the event-loop thread. Admitted
-            # sequences enter prefilling for the next iteration's sweep.
-            self._admit_in_step()
+        # AFTER the dispatches: admission's host work overlaps the device;
+        # admitted sequences enter prefilling for the next sweep
+        self._admit()
         if prev is not None:
             self._process_window(prev)
         if prev_pf is not None:
@@ -1502,6 +1420,14 @@ class JaxEngine:
     # ----------------------------------------------------------- admission
 
     def _admit(self) -> None:
+        """Admission, on the step thread only. The guard keeps the common
+        no-waiters iteration at one compare and out of the ``admit``
+        phase."""
+        if self.waiting:
+            self._admit_waiting()
+
+    @_phased("admit")
+    def _admit_waiting(self) -> None:
         while self.waiting and (len(self.running) + len(self.prefilling)
                                 < self.ecfg.max_batch):
             seq = self.waiting[0]
@@ -1570,18 +1496,6 @@ class JaxEngine:
                 self._hit_window.append((seq.computed, seq.num_prompt))
             # proto: request.lifecycle admitted->prefill
             self.prefilling.append(seq)
-
-    def _admit_in_step(self) -> None:
-        """Admission on the executor thread (admit_in_step), bracketed as
-        its own cost-table row so the host segment it moves off the
-        event-loop thread stays visible under --prof-sample. The guard
-        keeps the common no-waiters iteration at one compare."""
-        if not self.waiting:
-            return
-        with self.profiler.phase("admit"):
-            at0 = self.profiler.begin()
-            self._admit()
-            self.profiler.end(at0, "admit", ("host",))
 
     # ------------------------------------------------------- KV tier drain
 
@@ -1866,15 +1780,12 @@ class JaxEngine:
                 npg = (chunk + ps - 1) // ps
                 pslots[i, :npg] = pages[first:first + npg]
 
-        pt0 = self.profiler.begin()
         logits, self.kv_k, self.kv_v = self._take_state(self.prefill_fn(
             self.params, jnp.asarray(tokens), jnp.asarray(positions),
             self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots),
             jnp.asarray(last_idx),
             jnp.asarray(pslots) if use_paged else None,
             *self._state_args(sslots)))
-        self.profiler.end(pt0, "prefill", (B, T, P),
-                          tokens=int(sum(chunks)), sync_ref=logits)
         self._account_dispatch(batch)
         self.steps += 1
         self.prefill_slots_total += B * T
@@ -1924,11 +1835,8 @@ class JaxEngine:
         positions = np.full((1, T), -1, np.int32)
         tokens[0, :extent] = seq.tokens[:extent]
         positions[0, :extent] = np.arange(extent)
-        pt0 = self.profiler.begin()
         logits, k_all, v_all = self.long_prefill_fn(
             self.params, jnp.asarray(tokens), jnp.asarray(positions))
-        self.profiler.end(pt0, "long_prefill", (T,),
-                          tokens=extent - seq.computed, sync_ref=logits)
         self._account_dispatch([seq])
         self.prefill_slots_total += T
         self.prefill_dispatches_total += 1
@@ -2098,14 +2006,11 @@ class JaxEngine:
             page = seq.pages[pos // self.ecfg.page_size]
             slots[i] = (page * self.ecfg.page_size
                         + pos % self.ecfg.page_size)
-        pt0 = self.profiler.begin()
         logits, self.kv_k, self.kv_v = self._take_state(self.decode_fn(
             self.params, jnp.asarray(tokens), jnp.asarray(positions),
             self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots),
             *self._state_args(sslots)))
         toks_d, aux_d = self._sample_device(batch, logits)
-        self.profiler.end(pt0, "decode", (B, P), tokens=len(batch),
-                          sync_ref=toks_d)
         self._account_dispatch(batch)
         self._count_decode_slots(batch, B, 1)
         with self.profiler.phase("readback_window"):
@@ -2141,7 +2046,7 @@ class JaxEngine:
             pf = self._dispatch_prefill(budget)
             if pf is not None:
                 self._process_prefill(pf)
-        if self.prefilling and budget is None and self.ecfg.prefill_priority:
+        if self.prefilling and budget is None:
             return
         for seq in list(self.running):
             if seq.context.stopped:
@@ -2243,16 +2148,12 @@ class JaxEngine:
             slots[i, :n + 1] = pages[pr // ps] * ps + pr % ps
             draft_arr[i, :n] = d
             draft_len[i] = n
-        pt0 = self.profiler.begin()
         logits, self.kv_k, self.kv_v = self.verify_fn(
             self.params, jnp.asarray(tokens), jnp.asarray(positions),
             self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots))
         out_d, acc_d = verify_greedy_draft(
             logits, jnp.asarray(draft_arr), jnp.asarray(draft_len),
             max_top_k=self.ecfg.max_top_k)
-        self.profiler.end(pt0, "spec_verify", (B, P),
-                          tokens=int(draft_len.sum()) + len(batch),
-                          sync_ref=out_d)
         self._account_dispatch(batch)
         with self.profiler.phase("readback_window"):
             out = np.asarray(out_d)  # host sync — the spec arm is synchronous
@@ -2329,17 +2230,16 @@ class JaxEngine:
         B = self.ecfg.bucket_batch(len(batch))
         P = self.ecfg.bucket_pages(max(len(s.pages) for s in batch))
         E = self.ecfg.max_eos_ids
-        # cache_sampler_params: while the batch composition (rows, page
-        # counts, bucket shape) is unchanged, the page table, stop table
-        # and sampler params are bit-identical — reuse last dispatch's
-        # device arrays instead of rebuilding + re-uploading them. The key
-        # holds the Sequence objects themselves (identity compare), so no
-        # stale hit is possible. NOTE: a hit also freezes the build-time
-        # random seeds of UNSEEDED sampled rows for the cached span.
-        key = ((B, P, list(batch), [len(s.pages) for s in batch])
-               if self.ecfg.cache_sampler_params else None)
+        # while the batch composition (rows, page counts, bucket shape) is
+        # unchanged, the page table, stop table and sampler params are
+        # bit-identical — reuse last dispatch's device arrays instead of
+        # rebuilding + re-uploading them. The key holds the Sequence
+        # objects themselves (identity compare), so no stale hit is
+        # possible. NOTE: a hit also freezes the build-time random seeds
+        # of UNSEEDED sampled rows for the cached span.
+        key = (B, P, list(batch), [len(s.pages) for s in batch])
         cached = self._samp_cache
-        if key is not None and cached is not None and cached[0] == key:
+        if cached is not None and cached[0] == key:
             sb, (d_table, d_temp, d_topk, d_topp, d_seeds,
                  d_eos, d_sslots) = cached[1], cached[2]
         else:
@@ -2365,10 +2265,8 @@ class JaxEngine:
             d_topp = jnp.asarray(sb.top_p)
             d_seeds = jnp.asarray(sb.seeds)
             d_sslots = None if sslots is None else jnp.asarray(sslots)
-            if key is not None:
-                self._samp_cache = (key, sb, (d_table, d_temp, d_topk,
-                                              d_topp, d_seeds, d_eos,
-                                              d_sslots))
+            self._samp_cache = (key, sb, (d_table, d_temp, d_topk, d_topp,
+                                          d_seeds, d_eos, d_sslots))
         from_carry = np.zeros(B, bool)
         src = np.zeros(B, np.int32)
         ntok = np.zeros(B, np.int32)
@@ -2397,7 +2295,6 @@ class JaxEngine:
         pen = self._penalty_args(batch, sb, B)
         topn = (self.ecfg.max_top_logprobs
                 if self._wants_logprobs(batch) else 0)
-        pt0 = self.profiler.begin()
         out = self._take_state(self.decode_multi_fn(
             self.params, tok, pos, done, steps, rem, self.kv_k, self.kv_v,
             d_table, d_temp, d_topk, d_topp, d_seeds, d_eos, pen,
@@ -2407,11 +2304,6 @@ class JaxEngine:
         else:
             toks, emitted, carry, self.kv_k, self.kv_v = out
             aux = None
-        # sampled window timing serializes THIS window's pipeline (the
-        # drain waits out the in-flight overlap) — the documented
-        # sampling overhead; absent entirely at sample=0
-        self.profiler.end(pt0, "decode_window", (B, P, K),
-                          tokens=len(batch) * K, sync_ref=toks)
         self._account_dispatch(batch)
         self._count_decode_slots(batch, B, K)
         self.steps += 1
@@ -2430,21 +2322,19 @@ class JaxEngine:
         if pend.processed:
             return
         pend.processed = True
-        coalesce = self.ecfg.coalesce_window_emissions
         with self.profiler.phase("readback_window"):
             # the step thread blocked on the device until this window
             # (and whatever was queued ahead of it) has run
             toks = np.asarray(pend.toks)
             aux = (tuple(np.asarray(a) for a in pend.aux)
                    if pend.aux is not None else None)
-            if coalesce:
-                # outputs of the same program as toks — ready the moment
-                # toks is, so these reads add no extra device sync. carry
-                # is never donated (warmup's merge-combo loop reuses one),
-                # so reading done here is safe even with the next window
-                # in flight.
-                counts = np.asarray(pend.emitted)
-                done = np.asarray(pend.carry[2])
+            # outputs of the same program as toks — ready the moment
+            # toks is, so these reads add no extra device sync. carry is
+            # never donated (warmup's merge-combo loop reuses one), so
+            # reading done here is safe even with the next window in
+            # flight.
+            counts = np.asarray(pend.emitted)
+            done = np.asarray(pend.carry[2])
         if pend in self._inflight:
             self._inflight.remove(pend)
         if self._pending is pend:
@@ -2452,17 +2342,20 @@ class JaxEngine:
         K = toks.shape[1]
         emitted = 0
         with self.profiler.phase("process_window"):
-            # pure bookkeeping: emission, stop mirror, page publish (the
-            # sampled bracket keeps its cost-table row)
-            ht0 = self.profiler.begin()
+            # pure bookkeeping: emission, stop mirror, page publish
             for i, seq in enumerate(pend.batch):
                 if seq.finished is not None:
                     continue
-                if coalesce and not seq.context.stopped \
+                if not seq.context.stopped \
                         and self._device_stops_complete(seq):
+                    # one chunk per row-window, cut by the device's
+                    # emitted count and done flag
                     emitted += self._append_row(
                         seq, toks[i], int(counts[i]), bool(done[i]), aux, i)
                     continue
+                # token by token, for the rows the device cannot speak
+                # for: a stop list wider than max_eos_ids (the host's
+                # check wins, the device lags) and cancelled rows
                 for j in range(K):
                     if seq.finished is not None or seq.context.stopped:
                         break  # tokens past EOS/stop are discarded
@@ -2470,8 +2363,6 @@ class JaxEngine:
                                        lp=self._lp_entry(seq, aux, i, j))
                     self.decode_tokens_total += 1
                     emitted += 1
-            self.profiler.end(ht0, "process_window", (len(pend.batch), K),
-                              tokens=emitted)
         self.step_timeline.add(
             "decode_window", batch=len(pend.batch), tokens=emitted,
             occupancy=len(self.running) + len(self.prefilling),
@@ -2770,10 +2661,7 @@ class JaxEngine:
 
     def _attribution(self, seq: Sequence) -> dict:
         """Per-request cost block: where this request's share of the
-        engine's time and memory went. ``device_ms_est`` scales the
-        occupancy-weighted step share by the sampled mean device time
-        per dispatch (None until something has been sampled)."""
-        est = self.profiler.mean_device_ms_per_step()
+        engine's time and memory went."""
         ps = self.ecfg.page_size
         prompt_blocks = (seq.num_prompt + ps - 1) // ps
         return {
@@ -2801,8 +2689,6 @@ class JaxEngine:
             "decode_tokens": seq.generated,
             "kv_pages_peak": seq.max_pages,
             "kv_bytes_peak": seq.max_pages * self._page_bytes,
-            "device_ms_est": (round(seq.dispatch_share * est, 3)
-                              if est is not None else None),
             "finish_reason": seq.finished,
             # dynashard: which replica/submesh served this request —
             # /v1/traces/{rid} and the usage cost extension surface these

@@ -147,7 +147,7 @@ class CompileFence:
         _fences.add(self)
         self.armed = True
         # end of warmup = steady state begins: snapshot the pre-incident
-        # cost-table/cache baseline dynablack postmortems diff against
+        # phase-ledger/cache baseline dynablack postmortems are read against
         from ..runtime import blackbox
         rec = blackbox.get_recorder()
         if rec.enabled:

@@ -687,7 +687,6 @@ class FleetSim:
         from the final aggregator scrape (sorted per-worker rows keep the
         JSON byte-stable across runs)."""
         wm = [m for _, m in sorted(self.agg.worker_metrics.items())]
-        n = max(len(wm), 1)
         return {
             "workers_scraped": len(wm),
             "inflight_sequences": sum(m.request_active_slots for m in wm),
@@ -695,8 +694,6 @@ class FleetSim:
                                          for m in wm),
             "kv_free_blocks_min": min((m.kv_free_blocks for m in wm),
                                       default=0),
-            "device_time_fraction_avg": round(
-                sum(m.device_time_fraction for m in wm) / n, 6),
             "loop_lag_p99_seconds_max": max(
                 (m.loop_lag_p99_seconds for m in wm), default=0.0),
             "queue_wait_seconds_total": round(

@@ -364,13 +364,9 @@ class SimEngineModel:
             prompt_tokens_total=(self.prompt_blocks_total
                                  * self.block_size),
             cache_device_hit_blocks_total=self.realized_hit_blocks,
-            # dynaprof gauges, modeled from virtual state only (so seeded
-            # reports stay byte-identical): slot utilization stands in
-            # for the sampled device fraction; free pages from the block
-            # model
+            # modeled from virtual state only (so seeded reports stay
+            # byte-identical): free pages from the block model
             kv_free_blocks=p.kv_total_blocks - blocks,
-            device_time_fraction=round(
-                len(self.active) / max(p.slots, 1), 4),
         ).to_dict()
 
 

@@ -14,7 +14,6 @@ from concurrent.futures import ThreadPoolExecutor
 from typing import AsyncIterator, List, Optional
 
 from ..runtime import profiling
-from ..runtime.config import env_bool
 from ..runtime.engine import Context
 from .protocols.common import (FINISH_EOS, FINISH_LENGTH, FINISH_STOP,
                                EngineOutput, PreprocessedRequest)
@@ -151,8 +150,7 @@ class Backend:
             tail, _ = jail.feed(decode.flush())
             return released + tail + jail.flush()
 
-        offload = env_bool("DYN_ASYNC_DETOK")
-        loop = asyncio.get_running_loop() if offload else None
+        loop = asyncio.get_running_loop()
 
         agen = _aiter(self.engine.generate(request, context))
         async for raw in agen:
@@ -186,13 +184,11 @@ class Backend:
                     break
             if not decode_ids:
                 text = ""
-            elif offload:
+            else:
                 # awaited before the next engine chunk is pulled — the
                 # per-request decode order is preserved by construction
                 text = await loop.run_in_executor(
                     _detok_executor(), _decode_many, decode, decode_ids)
-            else:
-                text = _decode_many(decode, decode_ids)
             released, hit = jail.feed(text) if text else ("", False)
             if hit:
                 finished = finished or FINISH_STOP

@@ -245,7 +245,7 @@ class HttpService:
 
     async def _debug_profile(self, request: web.Request) -> web.Response:
         """One-stop profiling snapshot: loop lag + stall-watchdog stats,
-        every live engine's sampled cost table, and the attribution ring
+        every live engine's step-phase ledger, and the attribution ring
         depth."""
         prof = profiling.current_loop_profiler()
         return web.json_response({

@@ -99,16 +99,11 @@ class ForwardPassMetrics:
     host_offload_pages_total: int = 0
     host_restore_pages_total: int = 0
     long_prefills_total: int = 0
-    # dynaprof (engine/profiler.py + runtime/profiling.py): event-loop
-    # lag percentiles, sampled device/host split, per-bucket program
-    # cost table ("kind:BxP..." -> {samples, dispatch_us, device_us,
-    # tokens_per_s}), and the attribution conservation counter
+    # dynaprof (runtime/profiling.py): event-loop lag percentiles, and
+    # the attribution conservation counter
     loop_lag_p50_seconds: float = 0.0
     loop_lag_p99_seconds: float = 0.0
-    device_time_fraction: float = 0.0
-    profiled_steps_total: int = 0
     batch_dispatches_total: int = 0
-    bucket_cost: dict = field(default_factory=dict)
 
     def to_dict(self) -> dict:
         return dict(self.__dict__)

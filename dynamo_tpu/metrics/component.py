@@ -306,7 +306,7 @@ class MetricsAggregator:
              "decode-side wait for remote prefill (enqueue to KV commit)",
              lambda m: m.remote_prefill_wait_seconds_total),
             # dynaprof: engine internals that previously never left
-            # stats() + the sampled device/host split
+            # stats()
             ("dyn_engine_inflight_sequences",
              "sequences holding engine batch slots (prefilling+running)",
              lambda m: m.request_active_slots),
@@ -335,22 +335,13 @@ class MetricsAggregator:
             ("dyn_engine_long_prefills_total",
              "sequence-parallel ring prefills served",
              lambda m: m.long_prefills_total),
-            ("dyn_engine_device_time_fraction",
-             "sampled device-drain fraction of (device + host dispatch) "
-             "time (dynaprof; 0 until DYN_PROF_SAMPLE>0 samples a step)",
-             lambda m: m.device_time_fraction),
-            ("dyn_engine_profiled_steps_total",
-             "scheduler iterations sampled by the dynaprof timed "
-             "dispatch", lambda m: m.profiled_steps_total),
         ]
         for name, help_, get in per_worker:
             rows = [
                 f'{name}{{{wlabels(wid, m)}}} {get(m)}'
                 for wid, m in sorted(self.worker_metrics.items())]
             gauge(name, help_, rows)
-        # dynaprof labeled families: loop lag quantiles + per-bucket
-        # program cost (one row per compiled (kind, bucket) program —
-        # the ROADMAP item-3 regression surface)
+        # dynaprof labeled family: loop lag quantiles
         gauge("dyn_runtime_loop_lag_seconds",
               "per-worker event-loop sleep-drift percentiles (dynaprof)",
               [f'dyn_runtime_loop_lag_seconds{{{wlabels(wid, m)},'
@@ -358,15 +349,6 @@ class MetricsAggregator:
                for wid, m in sorted(self.worker_metrics.items())
                for q, val in (("p50", m.loop_lag_p50_seconds),
                               ("p99", m.loop_lag_p99_seconds))])
-        gauge("dyn_engine_bucket_cost_us",
-              "mean sampled device-drain microseconds per dispatch, per "
-              "compiled (kind, bucket) program (dynaprof cost table)",
-              [f'dyn_engine_bucket_cost_us{{{wlabels(wid, m)},'
-               f'bucket="{bucket}"}} '
-               f'{row.get("device_us", 0.0)}'
-               for wid, m in sorted(self.worker_metrics.items())
-               for bucket, row in sorted(
-                   (m.bucket_cost or {}).items())])
         usages = [m.gpu_cache_usage_perc
                   for m in self.worker_metrics.values()]
         if usages:

@@ -423,10 +423,6 @@ class ShardedReplicaSet:
         return {r.name: r.engine.fence.post_warmup_compiles
                 for r in self.replicas}
 
-    def device_time_fractions(self) -> Dict[str, float]:
-        return {r.name: round(r.engine.profiler.device_time_fraction(), 4)
-                for r in self.replicas}
-
     def describe(self) -> dict:
         """Report block: mesh shape, the live submesh assignment, and the
         per-replica instance ids (the KV router's worker ids)."""

@@ -1,7 +1,7 @@
 """dynablack: the incident flight recorder.
 
 Every telemetry plane in this tree is sampled, windowed, or ring-bounded
-(DYN_TRACE_SAMPLE, DYN_PROF_SAMPLE, the bounded stall table) — correct
+(DYN_TRACE_SAMPLE, DYN_STEP_TIMELINE, the bounded stall table) — correct
 for steady-state overhead, useless at 3 a.m. when the evidence of *why*
 a burn-rate alert fired or a breaker opened has already rotated out.
 The standard production answer (Dapper's always-on sampling plus
@@ -268,8 +268,8 @@ class FlightRecorder:
         self._listeners.append(fn)
 
     def refresh_baseline(self) -> None:
-        """Snapshot the profiler cost table + cache stats as the
-        pre-incident baseline the postmortem renderer diffs against.
+        """Snapshot the engines' phase ledgers + cache stats as the
+        pre-incident baseline a postmortem is read against.
         Called at construction, from CompileFence.arm() (end of
         warmup), and after every capture."""
         if not self.enabled or not self.include_process_state:

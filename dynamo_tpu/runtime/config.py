@@ -230,12 +230,6 @@ register_env("DYN_REDISPATCH_MAX", "2", "llm/disagg",
              "re-enqueues after a fast transfer-plane failure, e.g. a "
              "prefill worker dying mid-transfer). 1 disables hedging.")
 
-register_env("DYN_ASYNC_DETOK", "1", "llm",
-             "dynaturbo: run Backend detokenization on a dedicated "
-             "executor thread instead of the event-loop thread. Chunks "
-             "of one request stay ordered (at most one in-flight decode "
-             "per request); 0 restores inline decoding for A/B.")
-
 register_env("DYN_CACHE_TOPK", "20", "engine",
              "dynacache: hot prefix chains reported per engine in "
              "GET /debug/cache (top-K cached block hashes by reuse "
@@ -269,14 +263,6 @@ register_env("DYN_HOST_TIER_FP16", "0", "engine",
              "bit-exact restores matter more than tier capacity. "
              "Explicit EngineConfig.host_tier_int8=True/False wins.")
 
-register_env("DYN_LOOP_YIELD", None, "engine",
-             "dynaturbo A/B: restore the historical unconditional "
-             "asyncio.sleep(0) after each scheduler iteration. The "
-             "await run_in_executor(step) already suspends the loop "
-             "coroutine once per iteration, so the extra yield only "
-             "adds a second event-loop round-trip; set (any value) to "
-             "measure the difference with the loop-lag monitor.")
-
 register_env("DYN_JIT_FENCE", None, "engine",
              "Runtime compile fence: reaction to an XLA compile AFTER "
              "JaxEngine.warmup() (the zero-compile serving invariant). "
@@ -284,13 +270,6 @@ register_env("DYN_JIT_FENCE", None, "engine",
              "dyn_engine_post_warmup_compiles_total); 'warn' logs each "
              "compile; 'raise' fails the offending jit call with "
              "PostWarmupCompileError (the CI mode).")
-
-register_env("DYN_PROF_SAMPLE", "0", "engine",
-             "dynaprof: profile every Nth engine scheduler iteration "
-             "with a timed dispatch (host-dispatch vs device-drain "
-             "split, per-bucket cost table). The sampled iteration pays "
-             "one deliberate device sync; 0 (default) disables sampling "
-             "entirely — the hot path stays sync-free.")
 
 register_env("DYN_ROUTER_AUTOTUNE", "1", "llm",
              "dynaheat: self-tune KvScheduler.load_balance_weight from "

@@ -411,7 +411,7 @@ def attributions_snapshot(limit: int = 100) -> List[Tuple[str, dict]]:
 
 # --------------------------------------------------- engine profile registry
 # Engine-side profilers (engine/profiler.py) register here so the HTTP
-# /debug/profile endpoint can render every live engine's cost table —
+# /debug/profile endpoint can render every live engine's phase ledger —
 # same weakref pattern as tracing.register_timeline.
 
 _profiles: Dict[str, "weakref.ref"] = {}

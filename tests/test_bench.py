@@ -53,8 +53,6 @@ def record_of(fn, *a):
      "tok/s"),
     ({"scenario": "failover"}, "tok/s"),
     ({"scenario": "hotpath", "decode_steps": 16}, "ms"),
-    ({"scenario": "hotpath", "decode_steps": 16,
-      "hotpath_legacy": True}, "ms"),
 ])
 def test_emit_error_matches_metric_name(over, unit):
     """An error record must carry the SAME metric label (and a

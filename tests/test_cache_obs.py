@@ -325,7 +325,7 @@ def _shared_args(**over):
         dtype="bf16", users=3, turns=3, host_pages=0,
         disagg_threshold=256, seed=0, decode_steps=2,
         prefill_token_budget=None, host_tier_int8=False, max_batch=None,
-        spec=False, cpu=True, prof_sample=0, trace=False,
+        spec=False, cpu=True, trace=False,
         shared_prefix=False)
     base.update(over)
     return types.SimpleNamespace(**base)

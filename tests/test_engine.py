@@ -207,50 +207,55 @@ def test_engine_behind_full_llm_chain(run_async):
     run_async(main())
 
 
-def test_multi_step_decode_matches_single_step(run_async):
+@pytest.mark.parametrize("rng_seed,lens,k,n,concurrent", [
+    (7, (9, 21), 4, 11, False), (3, (7, 18, 33), 3, 9, True)],
+    ids=["sequential", "concurrent"])
+def test_multi_step_decode_matches_single_step(run_async, rng_seed, lens, k,
+                                               n, concurrent):
     """The fused K-step decode window must produce exactly the same
-    tokens as K single steps (greedy and seeded sampling)."""
-    import numpy as np
-
-    from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
-    from dynamo_tpu.llm.protocols.common import (PreprocessedRequest,
-                                                 SamplingOptions,
-                                                 StopConditions)
-    from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.runtime.engine import Context
+    tokens as K single steps: a greedy and a seeded row one after the
+    other (K 4), and three seeded rows sharing pipelined windows (K 3:
+    the device carry is exact, not speculative)."""
+    def sampling(i):
+        if concurrent:
+            return SamplingOptions(temperature=0.7, top_k=12, seed=100 + i)
+        return (SamplingOptions() if i == 0 else
+                SamplingOptions(temperature=0.8, top_k=20, seed=42))
 
     cfg = ModelConfig.tiny()
-    rng = np.random.RandomState(7)
-    prompts = [rng.randint(1, 500, n).tolist() for n in (9, 21)]
+    rng = np.random.RandomState(rng_seed)
+    prompts = [rng.randint(1, 500, m).tolist() for m in lens]
 
     async def gen_all(engine):
-        outs = []
-        for i, p in enumerate(prompts):
-            sampling = (SamplingOptions() if i == 0 else
-                        SamplingOptions(temperature=0.8, top_k=20, seed=42))
+        async def one(i, p):
             req = PreprocessedRequest(
-                token_ids=p, sampling=sampling,
-                stop=StopConditions(max_tokens=11, ignore_eos=True),
+                token_ids=p, sampling=sampling(i),
+                stop=StopConditions(max_tokens=n, ignore_eos=True),
                 eos_token_ids=[])
             toks = []
             async for out in engine.generate(req, Context()):
                 toks.extend(out.token_ids)
                 if out.finish_reason:
                     break
-            outs.append(toks)
+            return toks
+        if concurrent:
+            outs = list(await asyncio.gather(
+                *(one(i, p) for i, p in enumerate(prompts))))
+        else:
+            outs = [await one(i, p) for i, p in enumerate(prompts)]
         await engine.stop()
         return outs
 
     results = {}
-    for k in (1, 4):
+    for steps in (1, k):
         ecfg = EngineConfig(page_size=4, num_pages=64, max_batch=4,
                             prefill_chunk=32, prefill_buckets=(32,),
                             batch_buckets=(4,), page_buckets=(16,),
-                            decode_steps=k)
-        results[k] = run_async(gen_all(JaxEngine(cfg, ecfg, seed=0)))
+                            decode_steps=steps)
+        results[steps] = run_async(gen_all(JaxEngine(cfg, ecfg, seed=0)))
 
-    assert results[1] == results[4]
-    assert all(len(t) == 11 for t in results[4])
+    assert results[1] == results[k]
+    assert all(len(t) == n for t in results[k])
 
 
 def test_on_device_eos_stops_mid_window(run_async):
@@ -286,45 +291,6 @@ def test_on_device_eos_stops_mid_window(run_async):
     got, fin2 = run_async(gen(JaxEngine(cfg, ecfg, seed=0), [eos], 12))
     assert fin2 == "eos"
     assert got == cut
-
-
-def test_pipeline_toggle_token_identity(run_async):
-    """pipeline_decode=False (dispatch+readback each window) and the
-    pipelined default must produce identical tokens — the device carry is
-    exact, not speculative."""
-    cfg = ModelConfig.tiny()
-    rng = np.random.RandomState(3)
-    prompts = [rng.randint(1, 500, n).tolist() for n in (7, 18, 33)]
-
-    async def gen_all(engine):
-        async def one(p, i):
-            req = PreprocessedRequest(
-                token_ids=p,
-                sampling=SamplingOptions(temperature=0.7, top_k=12,
-                                         seed=100 + i),
-                stop=StopConditions(max_tokens=9, ignore_eos=True),
-                eos_token_ids=[])
-            toks = []
-            async for out in engine.generate(req, Context()):
-                toks.extend(out.token_ids)
-                if out.finish_reason:
-                    break
-            return toks
-        outs = await asyncio.gather(*(one(p, i)
-                                      for i, p in enumerate(prompts)))
-        await engine.stop()
-        return outs
-
-    results = {}
-    for pipe in (False, True):
-        ecfg = EngineConfig(page_size=4, num_pages=64, max_batch=4,
-                            prefill_chunk=32, prefill_buckets=(32,),
-                            batch_buckets=(4,), page_buckets=(16,),
-                            decode_steps=3, pipeline_decode=pipe)
-        results[pipe] = run_async(gen_all(JaxEngine(cfg, ecfg, seed=0)))
-
-    assert results[False] == results[True]
-    assert all(len(t) == 9 for t in results[True])
 
 
 def test_prefill_token_budget_mixing(run_async):

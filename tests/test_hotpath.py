@@ -1,16 +1,18 @@
-"""dynaturbo hot-path tests (ISSUE 16): token identity across every
-scheduler arm with the hot-path optimizations on vs off, zero post-warmup
+"""Decode hot-path tests: the window against the single-step reference
+for every pinned request kind, the pipelined arm's prefill policy, the
+sampler-parameter upload's reuse, one chunk per row-window and the
+per-token path beside it, admission on the step thread, zero post-warmup
 compiles under default AND exotic warmed_grid configs, async-detok
-ordering/cancellation, the cost_diff evidence tool, and the CPU hotpath
-bench smoke so the evidence pipeline itself can't silently rot."""
+ordering/cancellation, and the CPU hotpath bench smoke."""
 
 import asyncio
-import json
+import inspect
+import threading
 
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
+from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine, Sequence
 from dynamo_tpu.llm.backend import Backend
 from dynamo_tpu.llm.protocols.common import (EngineOutput,
                                              PreprocessedRequest,
@@ -19,9 +21,6 @@ from dynamo_tpu.llm.protocols.common import (EngineOutput,
 from dynamo_tpu.llm.tokenizer import ByteTokenizer
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.runtime import Context
-
-LEGACY = dict(overlap_idle_prefill=False, coalesce_window_emissions=False,
-              cache_sampler_params=False, admit_in_step=False)
 
 
 def _ecfg(**kw):
@@ -32,21 +31,27 @@ def _ecfg(**kw):
     return EngineConfig(**base)
 
 
-def _req(tokens, mt=10, eos=(), **sampling):
+def _req(tokens, mt=10, eos=(), stop_ids=(), **sampling):
     return PreprocessedRequest(
         token_ids=list(tokens), sampling=SamplingOptions(**sampling),
-        stop=StopConditions(max_tokens=mt, ignore_eos=not eos),
+        stop=StopConditions(max_tokens=mt, ignore_eos=not eos,
+                            stop_token_ids=list(stop_ids) or None),
         eos_token_ids=list(eos))
 
 
-async def _collect(engine, req):
-    toks, fin = [], None
-    async for out in engine.generate(req, Context()):
-        toks.extend(out.token_ids)
+async def _chunks(engine, req, ctx=None):
+    """Every EngineOutput of one request: (token_ids, finish_reason)."""
+    outs = []
+    async for out in engine.generate(req, ctx or Context()):
+        outs.append((list(out.token_ids), out.finish_reason))
         if out.finish_reason:
-            fin = out.finish_reason
             break
-    return toks, fin
+    return outs
+
+
+async def _collect(engine, req):
+    outs = await _chunks(engine, req)
+    return [t for ids, _ in outs for t in ids], outs[-1][1]
 
 
 def _mixed_requests():
@@ -55,68 +60,43 @@ def _mixed_requests():
     design: the sampler-param cache freezes its build-time reseeds)."""
     rng = np.random.RandomState(11)
     p = [rng.randint(1, 400, n).tolist() for n in (8, 15, 22, 30)]
-    return [
-        _req(p[0]),
-        _req(p[1], repetition_penalty=1.3, frequency_penalty=0.4),
-        _req(p[2], logit_bias={7: -100.0, 19: 4.0}),
-        _req(p[3], temperature=0.8, top_k=16, seed=123),
-    ]
+    return {
+        "greedy": _req(p[0]),
+        "penalties": _req(p[1], repetition_penalty=1.3,
+                          frequency_penalty=0.4),
+        "logit_bias": _req(p[2], logit_bias={7: -100.0, 19: 4.0}),
+        "seeded": _req(p[3], temperature=0.8, top_k=16, seed=123),
+    }
 
 
-@pytest.mark.parametrize("arm", ["single", "windowed", "pipelined"])
-def test_token_identity_optimizations_on_off(run_async, arm):
-    """Every scheduler arm must emit bit-identical tokens with the
-    dynaturbo optimizations on (defaults) and off (legacy)."""
-    arm_kw = {"single": dict(decode_steps=1),
-              "windowed": dict(decode_steps=4, pipeline_decode=False),
-              "pipelined": dict(decode_steps=4, pipeline_decode=True)}[arm]
+@pytest.mark.parametrize("kind",
+                         ["greedy", "penalties", "logit_bias", "seeded"])
+def test_window_matches_single_step_per_kind(run_async, kind):
+    """Each pinned request kind gives the same tokens through the
+    pipelined K-step window as through single steps."""
     cfg = ModelConfig.tiny()
 
-    async def gen_all(engine):
-        outs = await asyncio.gather(
-            *(_collect(engine, r) for r in _mixed_requests()))
-        await engine.stop()
-        return outs
-
-    results = {}
-    for name, toggles in (("legacy", LEGACY), ("new", {})):
-        eng = JaxEngine(cfg, _ecfg(**arm_kw, **toggles), seed=0)
-        results[name] = run_async(gen_all(eng))
-    assert results["legacy"] == results["new"]
-    assert all(len(t) == 10 and f == "length"
-               for t, f in results["new"])
-
-
-def test_token_identity_spec_arm(run_async):
-    """Spec-decode arm: same identity contract (admission moved into the
-    step; the spec step itself is untouched)."""
-    cfg = ModelConfig.tiny()
-    prompt = [5, 6, 7, 5, 6, 7, 5, 6] * 3  # spec-friendly motif
-
-    async def gen(engine):
-        out = await _collect(engine, _req(prompt, mt=12))
-        await engine.stop()
+    async def gen(k):
+        eng = JaxEngine(cfg, _ecfg(decode_steps=k), seed=0)
+        out = await _collect(eng, _mixed_requests()[kind])
+        await eng.stop()
         return out
 
-    results = {}
-    for name, toggles in (("legacy", LEGACY), ("new", {})):
-        eng = JaxEngine(cfg, _ecfg(page_size=8, spec_decode=True,
-                                   spec_tokens=2, decode_steps=2,
-                                   **toggles), seed=0)
-        results[name] = run_async(gen(eng))
-    assert results["legacy"] == results["new"]
-    assert len(results["new"][0]) == 12
+    single, window = run_async(gen(1)), run_async(gen(4))
+    assert single == window
+    assert len(window[0]) == 10 and window[1] == "length"
 
 
 def test_stop_string_identity_through_backend(run_async):
     """e2e stop-string arm: Backend + real engine. A stop string cut from
     the free-running text must truncate identically (text and finish
-    reason) with the optimizations on and off."""
+    reason) whether the tokens arrive one by one (decode_steps 1) or as
+    row-windows (decode_steps 4)."""
     cfg = ModelConfig.tiny()
     tok = ByteTokenizer()
 
-    async def gen(toggles, stop):
-        eng = JaxEngine(cfg, _ecfg(decode_steps=4, **toggles), seed=0)
+    async def gen(k, stop):
+        eng = JaxEngine(cfg, _ecfg(decode_steps=k), seed=0)
         be = Backend(eng, tok)
         req = PreprocessedRequest(
             token_ids=list(range(60, 80)), sampling=SamplingOptions(),
@@ -132,13 +112,187 @@ def test_stop_string_identity_through_backend(run_async):
         await eng.stop()
         return text, fin
 
-    free, fin = run_async(gen({}, None))
+    free, fin = run_async(gen(4, None))
     assert fin == "length" and len(free) > 4
     needle = free[2:5]
-    a = run_async(gen(LEGACY, [needle]))
-    b = run_async(gen({}, [needle]))
+    a = run_async(gen(1, [needle]))
+    b = run_async(gen(4, [needle]))
     assert a == b
     assert b[1] == "stop" and needle not in b[0]
+
+
+# ------------------------------------------- the pipelined arm, by hand
+# The engine is never started: the test thread calls _step() itself, so
+# each iteration's dispatches can be read off before the next one runs
+# (_emit puts straight into the queue while no loop thread is captured).
+
+
+def _submit(eng, req):
+    seq = Sequence(req=req, context=Context(), out=asyncio.Queue(),
+                   tokens=list(req.token_ids),
+                   num_prompt=len(req.token_ids))
+    eng.waiting.append(seq)
+    return seq
+
+
+def _spy_dispatches(eng):
+    """Per _step: which of the two dispatches were called and shipped."""
+    log = []
+    for kind in ("_dispatch_prefill", "_dispatch_decode_window"):
+        def spy(*a, _fn=getattr(eng, kind), _kind=kind, **kw):
+            out = _fn(*a, **kw)
+            log[-1][_kind] = out is not None
+            return out
+        setattr(eng, kind, spy)
+    step = eng._step
+
+    def stepped():
+        log.append({"running": len(eng.running)})
+        step()
+        eng._reap()
+        return log[-1]
+
+    return stepped, log
+
+
+def _step_until(stepped, cond, limit=64):
+    for _ in range(limit):
+        if cond():
+            return
+        stepped()
+    raise AssertionError("condition not reached")
+
+
+def test_an_iteration_that_ships_a_prefill_ships_no_window():
+    """Prefill priority (prefill_token_budget None): with a row decoding,
+    the iteration that ships the second prompt's prefill does not even
+    try a window."""
+    eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
+    stepped, log = _spy_dispatches(eng)
+    first = _submit(eng, _req(range(1, 9), mt=24))
+    _step_until(stepped, lambda: first.generated >= 5)
+    second = _submit(eng, _req(range(20, 40), mt=4))
+    _step_until(stepped, lambda: second.finished and first.finished)
+    shipped = [it for it in log if it.get("_dispatch_prefill")]
+    assert len(shipped) == 2
+    assert shipped[1]["running"] == 1, "no row was decoding beside it"
+    assert all("_dispatch_decode_window" not in it for it in shipped)
+    assert eng.mixed_dispatches == 0
+    assert first.generated == 24 and second.generated == 4
+
+
+def test_a_sweep_that_ships_nothing_still_ships_a_window():
+    """Prefill priority again, but the only prefill candidate was
+    cancelled before its sweep: the iteration fills the device with a
+    decode window instead of idling."""
+    eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
+    stepped, log = _spy_dispatches(eng)
+    first = _submit(eng, _req(range(1, 9), mt=24))
+    _step_until(stepped, lambda: first.generated >= 5)
+    second = _submit(eng, _req(range(20, 40), mt=4))
+    _step_until(stepped, lambda: second in eng.prefilling)
+    second.context.stop_generating()
+    it = stepped()
+    assert it == {"running": 1, "_dispatch_prefill": False,
+                  "_dispatch_decode_window": True}
+    assert second.finished == "cancelled"
+    _step_until(stepped, lambda: first.finished)
+    assert first.generated == 24
+
+
+def test_sampler_upload_reused_until_the_batch_changes():
+    """Two windows over the same rows and page counts share one upload
+    (the cache entry is the same object); a row finishing rebuilds it
+    for the rows that remain."""
+    eng = JaxEngine(ModelConfig.tiny(), EngineConfig(
+        page_size=32, num_pages=16, max_batch=4, prefill_chunk=32,
+        prefill_buckets=(32,), batch_buckets=(4,), page_buckets=(4,),
+        decode_steps=2), seed=0)
+    stepped, log = _spy_dispatches(eng)
+    short = _submit(eng, _req(range(1, 9), mt=5))
+    long_ = _submit(eng, _req(range(30, 36), mt=14))
+    seen = []          # (rows of the window, the cache entry after it)
+    while not (short.finished and long_.finished):
+        if stepped().get("_dispatch_decode_window"):
+            seen.append((list(eng._samp_cache[0][2]), eng._samp_cache))
+    pairs = list(zip(seen, seen[1:]))
+    same = [(a, b) for a, b in pairs if a[0] == b[0]]
+    changed = [(a, b) for a, b in pairs if a[0] != b[0]]
+    assert same and all(a[1] is b[1] for a, b in same)
+    assert changed and all(a[1] is not b[1] for a, b in changed)
+    assert [s is long_ for s in seen[-1][0]] == [True]
+
+
+# --------------------------------- one chunk a row-window, or token by token
+
+
+def test_a_row_window_reaches_the_client_as_one_output(run_async):
+    """A row whose stop ids fit the device table: the prefill's token,
+    then one EngineOutput per K-step window, cut by the device's emitted
+    count at the budget."""
+    async def main():
+        eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
+        outs = await _chunks(eng, _req(range(40, 60), mt=10))
+        await eng.stop()
+        return outs
+
+    outs = run_async(main())
+    assert [len(ids) for ids, _ in outs if ids] == [1, 4, 4, 1]
+    assert outs[-1][1] == "length"
+
+
+def test_a_stop_list_wider_than_the_device_table_stops_on_the_host(
+        run_async):
+    """More stop ids than max_eos_ids: the device table holds only the
+    first two, so the row takes the per-token path (one EngineOutput a
+    token) and the host's check stops it on the third id, mid-window,
+    with nothing of the window's tail emitted."""
+    cfg = ModelConfig.tiny()
+
+    async def gen(stop_ids):
+        eng = JaxEngine(cfg, _ecfg(decode_steps=4, max_eos_ids=2), seed=0)
+        outs = await _chunks(eng, _req(range(40, 60), mt=12,
+                                       stop_ids=stop_ids))
+        await eng.stop()
+        return outs
+
+    free = [t for ids, _ in run_async(gen(())) for t in ids]
+    assert len(free) == 12
+    hit = free[5]                       # lands mid-window for K=4
+    unused = [v for v in range(1, 400) if v not in free][:2]
+    outs = run_async(gen(unused + [hit]))
+    assert [t for ids, _ in outs for t in ids] == free[:free.index(hit) + 1]
+    assert all(len(ids) <= 1 for ids, _ in outs)
+    assert outs[-1][1] == "eos"
+
+
+# ------------------------------------------------------------- admission
+
+
+def test_admission_runs_on_the_step_thread_in_its_own_phase(run_async):
+    """_admit is called from the step thread only (never from _loop's
+    thread) and its time is the ledger's `admit` phase."""
+    eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
+    admit, threads = eng._admit, set()
+
+    def spy():
+        threads.add(threading.current_thread().name)
+        admit()
+
+    eng._admit = spy
+
+    async def main():
+        out = await asyncio.gather(_collect(eng, _req(range(1, 9), mt=6)),
+                                   _collect(eng, _req(range(9, 30), mt=6)))
+        stats = eng.stats()
+        await eng.stop()
+        return out, stats
+
+    out, stats = run_async(main())
+    assert all(len(t) == 6 for t, _ in out)
+    assert threads and all(t.startswith("jax-step") for t in threads)
+    assert stats["step_phase_seconds_total"]["admit"] > 0.0
+    assert "_admit" not in inspect.getsource(JaxEngine._loop)
 
 
 def _run_fence_grid(run_async, name, ecfg):
@@ -198,8 +352,8 @@ class _ChunkEngine:
 
 
 def test_async_detok_ordering_under_concurrency(run_async):
-    """DYN_ASYNC_DETOK (default on): per-request chunk texts must come
-    back in chunk order and concatenate to exactly the inline decode of
+    """Detokenisation runs on the detok executor: per-request chunk
+    texts must come back in chunk order and concatenate to exactly the inline decode of
     the same ids, across many concurrent streams."""
     tok = ByteTokenizer()
     texts = [f"stream-{i}: héllo wörld →🌍 {'x' * i}" for i in range(6)]
@@ -269,53 +423,9 @@ def test_async_detok_cancellation_isolated(run_async):
     assert run_async(main()) == text
 
 
-def _bench_record(disp, dev, extra_bucket=None, **headline):
-    buckets = {"decode_window:4x16x4": {
-        "samples": 10, "dispatch_us": disp, "device_us": dev,
-        "tokens_per_s": 1000.0}}
-    if extra_bucket:
-        buckets[extra_bucket] = {"samples": 2, "dispatch_us": 5.0,
-                                 "device_us": 1.0, "tokens_per_s": 0.0}
-    detail = {"bucket_cost": buckets, "itl_raw_chunk_p99_ms": 10.0,
-              "loop_lag_p99_ms": 2.0, "post_warmup_compiles": 0}
-    detail.update(headline)
-    return {"metric": "m", "value": 1.0, "unit": "ms", "detail": detail}
-
-
-def test_cost_diff_tool(tmp_path, capsys):
-    from tools import cost_diff
-
-    before = _bench_record(100.0, 50.0, itl_raw_chunk_p99_ms=12.0)
-    after = _bench_record(60.0, 50.0, extra_bucket="admit:host",
-                          itl_raw_chunk_p99_ms=9.0)
-    diff = cost_diff.diff_reports(before, after)
-    by_bucket = {r["bucket"]: r for r in diff["buckets"]}
-    assert by_bucket["decode_window:4x16x4"]["dispatch_us_delta"] == -40.0
-    assert by_bucket["decode_window:4x16x4"]["device_us_delta"] == 0.0
-    # one-sided bucket: missing side stays None, no crash
-    assert by_bucket["admit:host"]["dispatch_us_before"] is None
-    assert by_bucket["admit:host"]["dispatch_us_delta"] is None
-    assert diff["headline"]["itl_raw_chunk_p99_ms"]["delta"] == -3.0
-
-    bf, af = tmp_path / "b.json", tmp_path / "a.json"
-    bf.write_text(json.dumps(before))
-    af.write_text(json.dumps(after))
-    assert cost_diff.main([str(bf), str(af)]) == 0
-    out = capsys.readouterr().out
-    assert "decode_window:4x16x4" in out and "-40.0" in out
-    assert cost_diff.main(["--json", str(bf), str(af)]) == 0
-    parsed = json.loads(capsys.readouterr().out)
-    assert parsed["headline"]["itl_raw_chunk_p99_ms"]["after"] == 9.0
-    # reports without a cost table are a hard error, not an empty diff
-    nf = tmp_path / "n.json"
-    nf.write_text(json.dumps({"metric": "m", "detail": {}}))
-    assert cost_diff.main([str(nf), str(nf)]) == 1
-
-
 def test_hotpath_scenario_cpu_smoke():
-    """CI smoke for the evidence pipeline: the CPU hotpath scenario must
-    produce ONE record with a non-empty per-bucket cost table,
-    post_warmup_compiles == 0, and itl_raw_chunk_p99_ms present."""
+    """CI smoke: the CPU hotpath scenario must produce ONE record with
+    post_warmup_compiles == 0 and itl_raw_chunk_p99_ms present."""
     import sys
 
     import bench
@@ -333,9 +443,6 @@ def test_hotpath_scenario_cpu_smoke():
     detail = record["detail"]
     assert record["unit"] == "ms"
     assert isinstance(record["value"], (int, float))
-    assert detail["bucket_cost"], "cost table empty — --prof-sample rot"
-    assert any(k.startswith("decode_window:")
-               for k in detail["bucket_cost"])
     assert detail["post_warmup_compiles"] == 0
     assert "itl_raw_chunk_p99_ms" in detail
     assert "loop_lag_p99_ms" in detail
@@ -349,8 +456,6 @@ def test_sequence_stop_set_cached_once():
     first access) instead of rebuilding `x or []` defaults per token —
     later mutation of the request's lists must not change it (proves
     the cache is actually hit, not rebuilt)."""
-    from dynamo_tpu.engine.jax_engine import Sequence
-
     req = _req([1, 2, 3], mt=10, eos=(7,))
     req.stop.stop_token_ids = [9]
     seq = Sequence(req=req, context=Context(), out=asyncio.Queue(),
@@ -364,8 +469,6 @@ def test_sequence_stop_set_cached_once():
 
 
 def test_sequence_stop_set_respects_ignore_eos():
-    from dynamo_tpu.engine.jax_engine import Sequence
-
     req = _req([1], mt=10, eos=(7,))
     req.stop.ignore_eos = True
     req.stop.stop_token_ids = [9]
@@ -382,8 +485,6 @@ def test_emit_routes_by_thread_id_without_exception_probe():
     puts directly — and no asyncio loop probe is involved at all."""
     import threading
     import types
-
-    from dynamo_tpu.engine.jax_engine import JaxEngine, Sequence
 
     calls = []
     fake_loop = types.SimpleNamespace(

@@ -248,8 +248,7 @@ def test_warmup_covers_host_tier_programs():
     ecfg = EngineConfig(page_size=8, num_pages=16, max_batch=2,
                         prefill_chunk=16, batch_buckets=(1, 2),
                         prefill_buckets=(16,), page_buckets=(4,),
-                        decode_steps=1, pipeline_decode=False,
-                        host_pages=8)
+                        decode_steps=1, host_pages=8)
     eng = JaxEngine(cfg, ecfg, seed=0)
     eng.warmup(decode=False)
     # replay the tier drain's gather/scatter at several distinct batch

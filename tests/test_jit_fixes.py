@@ -26,8 +26,7 @@ from dynamo_tpu.models.config import ModelConfig
 def mk_engine(**eng_kw):
     cfg = ModelConfig.tiny()
     defaults = dict(page_size=8, num_pages=32, max_batch=4,
-                    prefill_chunk=32, decode_steps=1,
-                    pipeline_decode=False)
+                    prefill_chunk=32, decode_steps=1)
     defaults.update(eng_kw)
     return JaxEngine(cfg, EngineConfig(**defaults), seed=0)
 

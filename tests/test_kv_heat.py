@@ -4,8 +4,7 @@ host-tier default, and router-overlap autotune.
 Eviction policy is A/B'd at the PageManager level (`lru` is the
 pre-dynaheat control, `cost` the GreedyDual hot-prefix policy); the
 restore-overlap pipeline is pinned by engine-level token identity against
-the serial drain; cost_diff's cache counter family closes the evidence
-loop for --scenario shared A/Bs.
+the serial drain.
 """
 
 import numpy as np
@@ -315,39 +314,3 @@ def test_router_autotune_moves_weight():
         s2.observe_calibration(predicted=8, realized=0, isl_blocks=8)
     assert s2.load_balance_weight == 0.3
     assert s2.autotune_adjustments == 0
-
-
-def test_cost_diff_cache_family(tmp_path, capsys):
-    """The cache counter family rides cost_diff: two --scenario shared
-    reports (flat dynaheat keys, NO bucket cost table) diff cleanly with
-    before/after/delta per key and a rendered cache section."""
-    import json
-
-    from tools import cost_diff
-
-    def rep(hit, p95, wait, off_, drop):
-        return {"metric": "m", "value": hit, "unit": "rate", "detail": {
-            "prefix_hit_rate": hit, "hit_rate_windowed": hit,
-            "ttft_p95_ms": p95, "restore_wait_ms": wait,
-            "restore_batch_pages_mean": 2.0,
-            "device_hit_blocks": 10, "host_restored_blocks": 5,
-            "fresh_blocks": 20, "evict_offloaded_total": off_,
-            "evict_dropped_total": drop, "host_evictions_total": 1,
-            "post_warmup_compiles": 0}}
-
-    before = rep(0.30, 80.0, 40.0, 3, 9)
-    after = rep(0.45, 60.0, 25.0, 10, 2)
-    diff = cost_diff.diff_reports(before, after)
-    assert round(diff["cache"]["prefix_hit_rate"]["delta"], 4) == 0.15
-    assert diff["cache"]["restore_wait_ms"]["delta"] == -15.0
-    assert diff["cache"]["evict_dropped_total"]["delta"] == -7
-    assert diff["headline"]["ttft_p95_ms"]["delta"] == -20.0
-
-    bf, af = tmp_path / "b.json", tmp_path / "a.json"
-    bf.write_text(json.dumps(before))
-    af.write_text(json.dumps(after))
-    # cache-only reports (no bucket table) are NOT an error
-    assert cost_diff.main([str(bf), str(af)]) == 0
-    out = capsys.readouterr().out
-    assert "cache (dynaheat)" in out
-    assert "prefix_hit_rate" in out

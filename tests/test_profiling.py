@@ -1,17 +1,9 @@
-"""dynaprof: loop-lag monitor, stall watchdog, sampled device/host split,
-per-request cost attribution, /debug/profile round-trip.
+"""dynaprof: loop-lag monitor, stall watchdog, per-request cost
+attribution, /debug/profile round-trip.
 
-The central invariants:
-
-- ``DYN_PROF_SAMPLE=0`` (default) adds ZERO host syncs to the serving hot
-  path: the compile fence stays at 0, the profiler records nothing, and
-  the step timeline carries no profiler events (byte-identical event
-  stream to a build without dynaprof).
-- A sampled run produces a non-empty per-bucket cost table and a
-  device/host split without breaking the zero-compile invariant.
-- Attribution conserves dispatches: every dispatch distributes exactly
-  1.0 of step share across its batch, so the per-request shares sum to
-  the engine's dispatch counter.
+The central invariant: attribution conserves dispatches. Every dispatch
+distributes exactly 1.0 of step share across its batch, so the
+per-request shares sum to the engine's dispatch counter.
 """
 
 import asyncio
@@ -21,6 +13,7 @@ import time
 import pytest
 
 from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
+from dynamo_tpu.engine.profiler import PHASES
 from dynamo_tpu.llm.protocols.common import (PreprocessedRequest,
                                              SamplingOptions,
                                              StopConditions)
@@ -123,7 +116,7 @@ def test_watchdog_bounded_stacks(run_async):
     assert snap["dropped"] == 1
 
 
-# ------------------------------------------------- engine sampled profiling
+# ------------------------------------------------------ engine attribution
 
 
 def _req(tokens, mt=6, **sampling):
@@ -162,83 +155,11 @@ async def _drive(eng, reqs):
     return results, costs
 
 
-def test_sampled_device_host_split(run_async):
-    """DYN_PROF_SAMPLE=1 (every step): the cost table fills per compiled
-    program, the device/host split is measured, and the sampled syncs
-    trigger no post-warmup compile."""
-    eng = _tiny_engine(prof_sample=1)
-
-    async def main():
-        out = await _drive(eng, [_req(list(range(1, 20))),
-                                 _req([7] * 24, mt=5),
-                                 _req(list(range(40, 45)), mt=4)])
-        await eng.stop()
-        return out
-
-    run_async(main())
-    prof = eng.profiler
-    assert prof.profiled_steps > 0
-    assert 0.0 < prof.device_time_fraction() <= 1.0
-    table = prof.cost_table()
-    assert table, "sampled run must produce a per-bucket cost table"
-    assert any(k.startswith("prefill:") for k in table)
-    assert any(k.startswith(("decode_window:", "decode:")) for k in table)
-    for row in table.values():
-        assert row["samples"] >= 1
-        assert row["device_us"] >= 0.0
-    # the sampled sync is a drain, not a new program: fence stays 0
-    assert eng.fence.post_warmup_compiles == 0
-    st = eng.stats()
-    assert st["bucket_cost"] == table
-    assert st["device_time_fraction"] == round(
-        prof.device_time_fraction(), 4)
-    assert st["profiled_steps_total"] == prof.profiled_steps
-    # sampled dispatches landed in the step timeline
-    kinds = [e["kind"] for e in eng.step_timeline.snapshot()]
-    assert "prof_sample" in kinds
-    # loop-lag gauges ride stats() (engine.start acquired the monitor)
-    assert st["loop_lag_p99_seconds"] >= 0.0
-    eng.fence.disarm()
-
-
-def test_sample_zero_adds_no_syncs(run_async):
-    """The default-off contract: with DYN_PROF_SAMPLE=0 the mixed
-    prefill/decode e2e shows post_warmup_compiles == 0, the profiler
-    records NOTHING, and the step timeline carries no profiler events —
-    the same event stream as a build without dynaprof."""
-    eng = _tiny_engine()            # prof_sample=None -> env default 0
-    assert eng.profiler.sample == 0
-
-    async def main():
-        out = await _drive(eng, [_req(list(range(1, 20))),
-                                 _req([9] * 24, mt=6),
-                                 _req(list(range(50, 55)), mt=4,
-                                      temperature=0.9, seed=7)])
-        await eng.stop()
-        return out
-
-    (results, costs) = run_async(main())
-    assert all(len(r) >= 4 for r in results)
-    assert eng.fence.post_warmup_compiles == 0
-    assert eng.profiler.profiled_steps == 0
-    assert eng.profiler.device_seconds_total == 0.0
-    assert eng.profiler.cost_table() == {}
-    kinds = {e["kind"] for e in eng.step_timeline.snapshot()}
-    assert "prof_sample" not in kinds
-    assert kinds <= {"admit", "prefill", "decode", "decode_window",
-                     "spec_verify", "compile"}
-    # attribution is ALWAYS on (host counters only): every finish chunk
-    # carries a cost block even with sampling off
-    assert len(costs) == 3 and all(c is not None for c in costs)
-    assert all(c["device_ms_est"] is None for c in costs)  # nothing sampled
-    eng.fence.disarm()
-
-
 def test_attribution_sums_to_engine_totals(run_async):
     """Conservation: each dispatch distributes exactly 1.0 step share
     over its batch, so per-request shares sum to the engine's dispatch
     counter; per-request token counts sum to the engine totals."""
-    eng = _tiny_engine(prof_sample=2)
+    eng = _tiny_engine()
     reqs = [_req(list(range(1, 20)), mt=6),
             _req([3] * 24, mt=5),
             _req(list(range(60, 70)), mt=4),
@@ -265,8 +186,11 @@ def test_attribution_sums_to_engine_totals(run_async):
         assert c["kv_pages_peak"] >= 1
         assert c["kv_bytes_peak"] > 0
         assert c["dispatches"] >= 1
-    # sampled run: the share-scaled device estimate is populated
-    assert any(c["device_ms_est"] is not None for c in costs)
+    assert eng.fence.post_warmup_compiles == 0
+    kinds = {e["kind"] for e in eng.step_timeline.snapshot()}
+    assert {"admit", "prefill", "decode_window"} <= kinds <= {
+        "admit", "prefill", "decode", "decode_window", "spec_verify",
+        "compile"}
     # the engine also registered every attribution in the process ring
     assert profiling.request_attribution is not None
     eng.fence.disarm()
@@ -281,7 +205,7 @@ def test_engine_gauges_reach_forward_pass_metrics(run_async):
     the aggregator's dyn_engine_* gauges)."""
     from dynamo_tpu.llm.kv_router.protocols import ForwardPassMetrics
 
-    eng = _tiny_engine(prof_sample=1)
+    eng = _tiny_engine()
 
     async def main():
         await _drive(eng, [_req(list(range(1, 12)), mt=4)])
@@ -292,9 +216,7 @@ def test_engine_gauges_reach_forward_pass_metrics(run_async):
     assert m.kv_free_blocks > 0
     assert m.batch_dispatches_total >= 2
     assert m.queue_wait_seconds_total >= 0.0
-    assert m.device_time_fraction > 0.0
-    assert m.bucket_cost
-    # aggregator render path: the labeled bucket-cost family appears
+    # aggregator render path
     from dynamo_tpu.metrics.component import MetricsAggregator
 
     agg = MetricsAggregator.__new__(MetricsAggregator)
@@ -305,10 +227,13 @@ def test_engine_gauges_reach_forward_pass_metrics(run_async):
     agg.scrape_failures_total = agg.consecutive_scrape_failures = 0
     agg._client = None
     text = agg.render_prometheus()
-    assert "dyn_engine_device_time_fraction" in text
-    assert "dyn_engine_bucket_cost_us{" in text
     assert 'quantile="p99"' in text
     assert "dyn_engine_kv_free_blocks" in text
+    # the phase ledger is what /debug/profile's `engines` carries
+    led = profiling.profiles_snapshot()[eng.profiler.name]
+    assert led["step_iterations"] == eng.stats()["step_iterations_total"] > 0
+    assert set(led["phase_seconds"]) == set(PHASES)
+    assert led["phase_seconds"]["dispatch_window"] > 0.0
     eng.fence.disarm()
 
 
