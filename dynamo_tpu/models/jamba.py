@@ -57,11 +57,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from .config import ModelConfig
-from .llama import (DROP_SLOT, KVCacheSpec, Params, _attention, _mlp,
+from .llama import (KVCacheSpec, Params, _attention, _mlp,
                     _pool_window_attention, _pool_window_attention_pallas,
                     _scatter_pages, _scatter_pages_paged, _use_pallas,
-                    carry_active, carry_step_update, embed_tokens, logits_at,
-                    rms_norm)
+                    carry_active, carry_step_update, commit_window,
+                    embed_tokens, logits_at, rms_norm)
 from ..runtime.config import env_flag
 
 State = Tuple[jax.Array, jax.Array]     # (ssm [S,M,N,di] f32, conv [S,M,(dc-1)*di])
@@ -452,7 +452,6 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                       state_slots=None, *, k_steps: int,
                       logprobs_topn: int = 0):
         B = tokens.shape[0]
-        ps = kv_k.shape[3]
         start = positions
         wk = jnp.zeros((n_attn, B, k_steps, KV, hd), kv_k.dtype)
         wv = jnp.zeros_like(wk)
@@ -505,17 +504,8 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
             toks.append(tok)
 
         with jax.named_scope("kv_carry"):
-            wpos = start[:, None] + jnp.arange(k_steps)[None, :]
-            page = page_table[jnp.arange(B)[:, None],
-                              jnp.clip(wpos // ps, 0,
-                                       page_table.shape[1] - 1)]
-            valid = jnp.logical_and(start[:, None] >= 0,
-                                    wpos < pos[:, None])
-            flat = jnp.broadcast_to(
-                jnp.where(valid, page * ps + wpos % ps, DROP_SLOT),
-                (n_attn, B, k_steps))
-            kv_k = jax.vmap(_scatter_pages)(kv_k, wk, flat)
-            kv_v = jax.vmap(_scatter_pages)(kv_v, wv, flat)
+            kv_k = commit_window(kv_k, wk, page_table, start, pos)
+            kv_v = commit_window(kv_v, wv, page_table, start, pos)
             state = _store(state, state_slots, ssm, conv)
         out_toks = jnp.stack(toks, axis=1)
         carry = (tok, pos, done, steps, remaining)

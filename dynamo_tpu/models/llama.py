@@ -60,6 +60,13 @@ class KVCacheSpec:
         # kv-head-major page layout [L, pages, KV, ps, hd]: the Pallas decode
         # kernel then consumes pages with NO in-kernel transpose (batched
         # MXU dots over the leading KV axis) and (ps, hd) is lane-aligned.
+        # The price: a token's row lies UNDER the KV axis, and the TPU
+        # compiler scatters only along a major axis, so a scatter of single
+        # rows (_scatter_pages) relayouts the whole pool before and after
+        # (KV-major, then one tile per token: four copies a pool, two
+        # where [KV, hd] is one tile). Hot paths therefore write WHOLE
+        # pages along (L, pages): _scatter_pages_paged in prefill,
+        # commit_window at the end of the decode window.
         # The reference models this as KvLayout::{KvFirst,BlockFirst}
         # (lib/llm/src/kv/layer.rs:100-106) — layout chosen for the device.
         return (cfg.num_layers, self.num_pages, cfg.num_kv_heads,
@@ -246,6 +253,77 @@ def _scatter_pages(cache_layer: jax.Array, new: jax.Array,
     # advanced indices (pages, offs) separated by the KV slice put the
     # scatter axis first: target shape [B*T, KV, hd]
     return cache_layer.at[pages, :, offs].set(rows, mode="drop")
+
+
+def commit_window(kv: jax.Array, w: jax.Array, page_table: jax.Array,
+                  start: jax.Array, pos: jax.Array) -> jax.Array:
+    """Commit a decode window's K (or V) into the pool by WHOLE PAGES,
+    along the pool's own major axis, in place.
+
+    kv: [L, pages, KV, ps, hd] (donated by the caller); w: [L, B, k_steps,
+    KV, hd], entry i the K/V of position start + i; page_table: [B, P];
+    start: [B] position of the window's first token (-1: padding row);
+    pos: [B] the carry's position after the window. Entry i commits iff
+    start >= 0 and start + i < pos: a row that froze mid-window commits
+    only what it produced, a padding row nothing.
+
+    Why not a row scatter (`.at[page, :, offset]`, _scatter_pages): the
+    TPU compiler scatters only along a major axis, and in this layout a
+    token's row sits UNDER the KV axis. It therefore copied the pool to a
+    KV-major layout, again to one tile per token, scattered, and copied
+    back twice: eight pool-sized copies a window for K and V (four where
+    a [KV, hd] update is one tile, KV 8), 14% of the device's time at
+    qwen3-30b-a3b's pool (ledger, PR 29) to write 1,536 rows of 1 KB.
+    Here the pool is viewed as [L * pages, KV, ps, hd] (a bitcast), the
+    S pages a row's window can touch are gathered ([L, B, S] pages, tens
+    of MB), the k_steps rows are put in with one select a step, and the
+    whole pages are scattered back along axis 0; an untouched page's
+    index is out of range and dropped. No op has a pool-sized output
+    (tests/test_tpu_compile.py holds the compiled program to that).
+
+    Why writing whole pages back is safe: a page a window writes belongs
+    to exactly one running row. PageManager shares only FULL, published
+    pages: a prefix hit is capped at (len - 1) // ps pages
+    (allocate_sequence), a page a row fills while decoding is published
+    only up to the tokens whose K/V are in the pool (commit_chain with
+    extent filled - 1), and a window writes positions >= start, which lie
+    past every full page of its row; fresh pages are handed to one row
+    (refcount 1) and the engine forks no row. So no two written (row,
+    page slot) entries name one page, and the bytes written back beside
+    the new rows are the bytes just read: the pool afterwards is
+    bit-identical to a row scatter's (tests/test_window_commit.py).
+    """
+    L, num_pages, KV, ps, hd = kv.shape
+    B, k_steps = w.shape[1:3]
+    S = 1 + (k_steps + ps - 2) // ps          # pages a window can touch
+    P = page_table.shape[1]
+    first = jnp.maximum(start, 0) // ps                          # [B]
+    cols = first[:, None] + jnp.arange(S, dtype=jnp.int32)       # [B, S]
+    page = jnp.take_along_axis(page_table, jnp.minimum(cols, P - 1), axis=1)
+    # token i's row inside the row's S gathered pages, as s * ps + offset
+    steps = jnp.arange(k_steps, dtype=jnp.int32)
+    valid = jnp.logical_and(start[:, None] >= 0,
+                            start[:, None] + steps < pos[:, None])
+    rel = jnp.where(valid, (start - first * ps)[:, None] + steps,
+                    -1)                                          # [B, K]
+    slot = jnp.arange(S * ps, dtype=jnp.int32).reshape(S, ps)
+    hit = rel[:, :, None, None] == slot                          # [B,K,S,ps]
+    # a page slot past the table's last column, or an id outside the pool
+    # (a row scatter drops those too), is never written
+    touched = (hit.any(axis=(1, 3)) & (cols < P)
+               & (page >= 0) & (page < num_pages))               # [B, S]
+    layer0 = jnp.arange(L, dtype=jnp.int32)[:, None, None] * num_pages
+    src = layer0 + jnp.clip(page, 0, num_pages - 1)              # [L, B, S]
+    dst = jnp.where(touched, layer0 + page, L * num_pages)
+    flat = kv.reshape(L * num_pages, KV, ps, hd)
+    pages = flat[src]                              # [L, B, S, KV, ps, hd]
+    for i in range(k_steps):
+        pages = jnp.where(hit[None, :, i, :, None, :, None],
+                          w[:, :, i, None, :, None, :].astype(kv.dtype),
+                          pages)
+    flat = flat.at[dst.reshape(-1)].set(
+        pages.reshape(-1, KV, ps, hd), mode="drop")
+    return flat.reshape(kv.shape)
 
 
 def _use_pallas() -> bool:
@@ -853,11 +931,16 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     """Fused K-step decode with a READ-ONLY pool and a fully on-device
     sequence carry. The pool is gathered but never written inside the
     window; the K new tokens' K/V accumulate in a small per-layer window
-    buffer that attention reads alongside the pool, and ONE scatter at the
-    end commits the window into the pool. This keeps peak HBM at ~one pool
-    copy — an unrolled chain of full forward() steps makes XLA hold
-    several pool instances (each step's scatter output is a new buffer)
-    and OOMs large pools.
+    buffer that attention reads alongside the pool, and ONE commit at the
+    end writes the window into the pool by whole pages, in place
+    (commit_window: a gather of the few pages the rows' windows touch, a
+    select per step, a scatter along the pool's major axis). No op of the
+    program has an output of the pool's size: an unrolled chain of full
+    forward() steps makes XLA hold several pool instances (each step's
+    scatter output is a new buffer) and OOMs large pools, and a single
+    scatter of token ROWS at the end, which this was until PR 30, made
+    the compiler relayout the pool around it: eight pool-sized copies a
+    window and one pool of temporaries (see commit_window).
 
     The carry (tok, pos, done, steps, remaining) lives on device so the
     engine can dispatch window N+1 *before* reading back window N's tokens
@@ -906,7 +989,6 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                       logprobs_topn: int = 0):
         B = tokens.shape[0]
         L = cfg.num_layers
-        ps = kv_k.shape[3]
         start = positions  # [B] position of the first window token (-1 pad)
         wdt = kv_k.dtype
         wk = jnp.zeros((L, B, k_steps, KV, hd), wdt)
@@ -1003,21 +1085,12 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                 nxt, tok, pos, done, steps, remaining, eos_table)
             toks.append(tok)
 
-        # commit the window into the pool: one scatter per layer; entry i
-        # holds the K/V of position start+i, valid only if the row was
-        # still active at step i (start+i < final pos)
+        # commit the window into the pool by whole pages (commit_window):
+        # entry i holds the K/V of position start+i, valid only if the
+        # row was still active at step i (start+i < final pos)
         with jax.named_scope("kv_carry"):
-            wpos = start[:, None] + jnp.arange(k_steps)[None, :]  # [B, K]
-            page = page_table[jnp.arange(B)[:, None],
-                              jnp.clip(wpos // ps, 0,
-                                       page_table.shape[1] - 1)]
-            valid = jnp.logical_and(start[:, None] >= 0,
-                                    wpos < pos[:, None])
-            flat = jnp.where(valid, page * ps + wpos % ps, DROP_SLOT)
-            kv_k = jax.vmap(_scatter_pages)(kv_k, wk, jnp.broadcast_to(
-                flat, (cfg.num_layers,) + flat.shape))
-            kv_v = jax.vmap(_scatter_pages)(kv_v, wv, jnp.broadcast_to(
-                flat, (cfg.num_layers,) + flat.shape))
+            kv_k = commit_window(kv_k, wk, page_table, start, pos)
+            kv_v = commit_window(kv_v, wv, page_table, start, pos)
         out_toks = jnp.stack(toks, axis=1)
         carry = (tok, pos, done, steps, remaining)
         if logprobs_topn:

@@ -208,14 +208,17 @@ def test_engine_behind_full_llm_chain(run_async):
 
 
 @pytest.mark.parametrize("rng_seed,lens,k,n,concurrent", [
-    (7, (9, 21), 4, 11, False), (3, (7, 18, 33), 3, 9, True)],
-    ids=["sequential", "concurrent"])
+    (7, (9, 21), 4, 11, False), (3, (7, 18, 33), 3, 9, True),
+    (5, (2, 6, 14), 4, 11, True)],
+    ids=["sequential", "concurrent", "page-straddle"])
 def test_multi_step_decode_matches_single_step(run_async, rng_seed, lens, k,
                                                n, concurrent):
     """The fused K-step decode window must produce exactly the same
     tokens as K single steps: a greedy and a seeded row one after the
-    other (K 4), and three seeded rows sharing pipelined windows (K 3:
-    the device carry is exact, not speculative)."""
+    other (K 4), three seeded rows sharing pipelined windows (K 3: the
+    device carry is exact, not speculative), and three rows whose prompts
+    end two tokens short of a page (length ps - 2 mod ps), so that every
+    row's first window commits on both sides of a page boundary."""
     def sampling(i):
         if concurrent:
             return SamplingOptions(temperature=0.7, top_k=12, seed=100 + i)

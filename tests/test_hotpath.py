@@ -59,8 +59,11 @@ def _mixed_requests():
     pinned token-identity surface (unseeded sampling is exempt by
     design: the sampler-param cache freezes its build-time reseeds)."""
     rng = np.random.RandomState(11)
-    p = [rng.randint(1, 400, n).tolist() for n in (8, 15, 22, 30)]
+    p = [rng.randint(1, 400, n).tolist() for n in (8, 15, 22, 30, 2)]
     return {
+        # a prompt of page_size - 2: the first window's commit lies on
+        # both sides of a page boundary
+        "page_straddle": _req(p[4]),
         "greedy": _req(p[0]),
         "penalties": _req(p[1], repetition_penalty=1.3,
                           frequency_penalty=0.4),
@@ -69,8 +72,8 @@ def _mixed_requests():
     }
 
 
-@pytest.mark.parametrize("kind",
-                         ["greedy", "penalties", "logit_bias", "seeded"])
+@pytest.mark.parametrize("kind", ["greedy", "penalties", "logit_bias",
+                                  "seeded", "page_straddle"])
 def test_window_matches_single_step_per_kind(run_async, kind):
     """Each pinned request kind gives the same tokens through the
     pipelined K-step window as through single steps."""

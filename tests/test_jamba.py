@@ -125,16 +125,20 @@ def test_from_hf_config_on_the_catalog_config():
         ModelConfig.from_hf_config(dict(published, sliding_window=4096))
 
 
-@pytest.mark.parametrize("tied", [False, True])
-def test_prefill_and_window_match_reference(tied):
-    """(a) and (f): prefill_step then decode_window through the pools
-    against the reference's full forward, on logits (the window's top-8
-    log-probabilities at each of its steps), with the head tied and not."""
+@pytest.mark.parametrize("tied,n_prompt", [(False, 21), (True, 21),
+                                           (False, PS - 2)])
+def test_prefill_and_window_match_reference(tied, n_prompt):
+    """(a) and (f): prefill_step then two decode_windows through the
+    pools against the reference's full forward, on logits (the window's
+    top-8 log-probabilities at each of its steps), with the head tied
+    and not. The second window reads from the pool what the first one
+    committed: from a prompt of PS - 2 the first window's four rows lie
+    on both sides of a page boundary (commit_window's second page slot)."""
     cfg = tiny(tie_word_embeddings=tied)
     params = jamba.init_params(cfg, jax.random.PRNGKey(0))
     assert ("lm_head" in params) == (not tied)
     pools = Pools(cfg)
-    prompt = np.random.default_rng(0).integers(1, 512, 21)
+    prompt = np.random.default_rng(0).integers(1, 512, n_prompt)
     logits = pools.run_prefill(params, prompt, 0, 32)
     want = ref_logits(params, cfg, prompt)
     assert np.abs(logits - want[-1]).max() < ATOL
@@ -144,25 +148,29 @@ def test_prefill_and_window_match_reference(tied):
     window = jamba.make_decode_window_fn(cfg, True, 64)
     B, K = 2, 4
     first = int(np.argmax(logits))
-    toks, emitted, aux, _carry, *_ = window(
-        params, jnp.asarray([first, 0], jnp.int32),
-        jnp.asarray([len(prompt), -1], jnp.int32), jnp.zeros(B, bool),
-        jnp.zeros(B, jnp.int32), jnp.asarray([100, 1], jnp.int32),
-        pools.kv_k, pools.kv_v, pools.table(B), jnp.zeros(B),
-        jnp.zeros(B, jnp.int32), jnp.ones(B), jnp.zeros(B, jnp.uint32),
-        jnp.full((B, 8), -1, jnp.int32), None, pools.state,
-        jnp.asarray([pools.slot, pools.drop], jnp.int32),
-        k_steps=K, logprobs_topn=8)
-    assert list(np.asarray(emitted)) == [K, 0]
-    seq = list(prompt) + [first] + [int(t) for t in toks[0]]
+    carry = (jnp.asarray([first, 0], jnp.int32),
+             jnp.asarray([len(prompt), -1], jnp.int32), jnp.zeros(B, bool),
+             jnp.zeros(B, jnp.int32), jnp.asarray([100, 1], jnp.int32))
+    kv_k, kv_v, state = pools.kv_k, pools.kv_v, pools.state
+    toks, vals, ids = [], [], []
+    for _ in range(2):
+        t, emitted, aux, carry, kv_k, kv_v, state = window(
+            params, *carry, kv_k, kv_v, pools.table(B), jnp.zeros(B),
+            jnp.zeros(B, jnp.int32), jnp.ones(B), jnp.zeros(B, jnp.uint32),
+            jnp.full((B, 8), -1, jnp.int32), None, state,
+            jnp.asarray([pools.slot, pools.drop], jnp.int32),
+            k_steps=K, logprobs_topn=8)
+        assert list(np.asarray(emitted)) == [K, 0]
+        toks += [int(x) for x in t[0]]
+        vals += list(np.asarray(aux[1][0]))
+        ids += list(np.asarray(aux[2][0]))
+    seq = list(prompt) + [first] + toks
     want = np.asarray(jax.nn.log_softmax(
         ref_logits(params, cfg, seq[:-1]), -1))
-    _lp, top_vals, top_ids = aux
-    for j in range(K):
+    for j in range(2 * K):
         at = len(prompt) + j
-        got = np.asarray(top_vals[0, j])
-        assert np.abs(got - want[at][np.asarray(top_ids[0, j])]).max() < ATOL
-        assert int(toks[0, j]) == int(np.argmax(want[at]))
+        assert np.abs(vals[j] - want[at][ids[j]]).max() < ATOL
+        assert toks[j] == int(np.argmax(want[at]))
 
 
 @pytest.mark.parametrize("cuts", [(13,), (8, 29)])

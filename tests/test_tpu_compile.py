@@ -173,15 +173,25 @@ def _preset_1b():
                                                     model_path=None))
 
 
-def _engine_shapes(cfg, sharding):
+def _engine_shapes(cfg, sharding, num_pages=NUM_PAGES):
     """params + KV pools exactly as JaxEngine.__init__ builds them, as
     shapes: nothing can be put on a described device."""
     params = jax.eval_shape(
         lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
     kv_k, kv_v = jax.eval_shape(
-        lambda: llama.init_kv_cache(cfg, llama.KVCacheSpec(NUM_PAGES, PS)))
+        lambda: llama.init_kv_cache(cfg, llama.KVCacheSpec(num_pages, PS)))
     return (_on(sharding, params), _on(sharding, kv_k),
             _on(sharding, kv_v))
+
+
+def _lower_window(fn, sharding, params, kv_k, kv_v, B, P, logprobs_topn=0):
+    """The fused window lowered as the engine calls it (decode_steps 4)."""
+    s = partial(_sds, sharding)
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    return fn.lower(
+        params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+        s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+        s((B, 8), jnp.int32), None, k_steps=4, logprobs_topn=logprobs_topn)
 
 
 @pytest.mark.parametrize("logprobs_topn", [0, 20], ids=["plain", "logprobs"])
@@ -192,15 +202,8 @@ def test_decode_window_program_compiles(one_chip, tpu_kernel_path,
     cfg = _preset_1b()
     params, kv_k, kv_v = _engine_shapes(cfg, one_chip)
     fn = llama.make_decode_window_fn(cfg, True, 64)
-    s = partial(_sds, one_chip)
-    B = B_DEC
-    i32 = s((B,), jnp.int32)
-    f32 = s((B,), jnp.float32)
-    compiled = fn.lower(
-        params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
-        s((B, P_DEC), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
-        s((B, 8), jnp.int32), None, k_steps=4,
-        logprobs_topn=logprobs_topn).compile()
+    compiled = _lower_window(fn, one_chip, params, kv_k, kv_v, B_DEC, P_DEC,
+                             logprobs_topn).compile()
     assert _has_kernel(compiled)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -249,17 +252,84 @@ def test_decode_window_program_compiles_model4(topo, tpu_kernel_path):
     kvs = NamedSharding(mesh, kv_cache_pspec(cfg))
     kv_k, kv_v = _on(kvs, kv_k), _on(kvs, kv_v)
     fn = llama.make_decode_window_fn(cfg, True, 64, mesh=mesh)
-    s = partial(_sds, rep)
-    B = B_DEC
-    i32 = s((B,), jnp.int32)
-    f32 = s((B,), jnp.float32)
-    compiled = fn.lower(
-        params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
-        s((B, P_DEC), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
-        s((B, 8), jnp.int32), None, k_steps=4, logprobs_topn=0).compile()
+    compiled = _lower_window(fn, rep, params, kv_k, kv_v, B_DEC,
+                             P_DEC).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text or "all-gather" in text
+
+
+# ------------------- the window's commit at the benchmark's pool shapes
+
+
+def _pool_sized_copies(text: str, pool_elems: int):
+    """Names of the optimized program's `copy` ops (plain or async) whose
+    result holds at least a pool's elements."""
+    import re
+
+    out = []
+    shape = r"\w+\[([\d,]*)\]"
+    for m in re.finditer(
+            rf"^\s*(?:ROOT )?%?([\w.\-]+) = (?:\({shape}[^)]*\)|{shape}\S*) "
+            r"(?:copy|copy-start)\(", text, re.M):
+        n = 1
+        for d in filter(None, (m.group(2) or m.group(3)).split(",")):
+            n *= int(d)
+        if n >= pool_elems:
+            out.append(m.group(1))
+    return out
+
+
+def test_pool_sized_copy_is_recognised():
+    """The guard below reads the compiler's text: it must see the copies
+    the parent of PR 30 made (lines from its compiled window)."""
+    text = """
+  %copy.562 = bf16[6,1280,4,64,128]{4,3,1,0,2:T(8,128)(2,1)} copy(%kv_k.1)
+  %copy.564 = bf16[491520,4,128]{2,1,0:T(4,128)(2,1)} copy(%bitcast.42), metadata={}
+  %copy.614 = s32[64,2,1]{1,0,2:T(8,128)S(1)} copy(%bitcast_or_fusion.4)
+  %bitcast.55 = bf16[6,1280,4,64,128]{4,3,2,1,0:T(8,128)(2,1)} bitcast(%fusion.43)
+  %copy-start.3 = (bf16[7680,4,64,128]{3,2,1,0}, bf16[7680,4,64,128]{3,2,1,0}, u32[]) copy-start(%x)
+"""
+    assert _pool_sized_copies(text, 6 * 1280 * 4 * 64 * 128) == [
+        "copy.562", "copy.564", "copy-start.3"]
+
+
+@pytest.mark.parametrize("name,experts,num_pages,B,P", [
+    ("qwen3-30b-a3b", 8, 1280, 64, 32), ("mixtral-8x7b", 2, 768, 32, 64)])
+def test_window_commit_makes_no_pool_sized_copy(one_chip, tpu_kernel_path,
+                                                name, experts, num_pages,
+                                                B, P):
+    """The fused decode window at the pool shapes of the benchmark's
+    cells 2 and 1 ([6, 1280, 4, 64, 128] B 64, [3, 768, 8, 64, 128] B 32;
+    every width as published, the expert count cut so the compile stays
+    short): commit_window writes whole pages along the pool's major
+    axis, so the optimized program holds NO copy of a pool's size, the
+    pools alias their inputs, and the temporaries stay under one pool.
+    A row scatter in kv_carry brings back eight relayout copies of the
+    pool a window (four at KV 8) and one pool of temporaries: 14% of
+    cell 2's device time (ledger, PR 29)."""
+    import dataclasses
+
+    from dynamo_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig.from_local_path(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "configs", name))
+    cfg = dataclasses.replace(
+        cfg, num_experts=experts,
+        num_experts_per_tok=min(cfg.num_experts_per_tok, experts))
+    params, kv_k, kv_v = _engine_shapes(cfg, one_chip, num_pages)
+    assert kv_k.shape == (cfg.num_layers, num_pages, cfg.num_kv_heads, PS,
+                          128)
+    fn = llama.make_decode_window_fn(cfg, True, 64)
+    compiled = _lower_window(fn, one_chip, params, kv_k, kv_v, B,
+                             P).compile()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(compiled.as_text(), kv_k.size) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = kv_k.size * kv_k.dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
 
 
 # -------------------- MLA + routed experts at Moonlight-16B-A3B's widths
