@@ -1770,9 +1770,9 @@ class JaxEngine:
             pages = np.asarray(seq.pages, np.int64)
             table[i, :len(seq.pages)] = seq.pages
             last_idx[i] = chunk - 1
-            # flat slots are always built: model modules without a paged
-            # commit path (MLA's latent cache) ignore page_slots and use
-            # these; llama ignores them when page_slots is present
+            # flat slots are always built: they are the commit path of an
+            # unaligned chunk; every model module ignores them when
+            # page_slots is present
             pos = np.arange(start, start + chunk)
             slots[i, :chunk] = pages[pos // ps] * ps + pos % ps
             if use_paged:
@@ -3017,9 +3017,12 @@ def _make_decode_multi(model, cfg: ModelConfig, max_top_k: int,
     overhead) exceeds step compute; the default K is unmeasured on a
     directly attached chip.
 
-    Generic fallback for model modules without make_decode_window_fn
-    (e.g. MLA): full forward per step with per-step pool writes; stopped
-    rows write DROP_SLOT so nothing lands in their pages."""
+    Generic fallback for a model module without make_decode_window_fn:
+    full forward per step with per-step pool writes; stopped rows write
+    DROP_SLOT so nothing lands in their pages. Every module of the
+    registry supplies its own read-only-pool window since PR 31 (llama,
+    jamba, mla), so the engine builds this for none of them; it stays
+    for benchmark/rehearse.py, which imports it by name (ROADMAP C3)."""
     from ..models.llama import carry_active, carry_step_update, logits_at
 
     @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),

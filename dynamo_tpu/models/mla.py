@@ -7,14 +7,24 @@ TPU-first design points:
 - the KV cache stores ONLY the rank-r latent ``c_kv`` plus the shared
   rope key ``k_rope`` per token — cache bytes/token shrink by ~an order
   of magnitude vs GQA, so the same HBM pool holds proportionally more
-  context (paged pools [L, pages, 1, ps, r] and [L, pages, 1, ps, dr],
-  shape-compatible with the engine's generic page machinery);
-- decode uses the absorbed form: W_UK is folded into the query
+  context (paged pools [L, pages, 1, ps, r] and [L, pages, 1, ps,
+  rope_width], shape-compatible with the engine's generic page
+  machinery);
+- every program uses the absorbed form: W_UK is folded into the query
   (q_lat = q_nope · W_UK) and W_UV into the output, so attention runs
-  entirely in latent space — two big MXU einsums per layer instead of
-  materializing per-head K/V;
-- prefill/decode share one program exactly like models/llama.py (scatter
-  new latents into pages, gather the page table, masked attention).
+  entirely in latent space against ONE latent head — each cached token
+  is read once for all heads, and no per-head K/V is materialized;
+- the pools are read-only inside every program (prefill chunk, single
+  step, fused decode window): the program's own tokens wait in a small
+  buffer, attention merges the pool's part and the buffer's part by
+  online-softmax statistics, and one commit per pool writes whole pages
+  (or token rows) along the pool's major axis, in place;
+- on the chip decode and multi-row prefill chunks read the pool through
+  the Pallas latent kernel (ops/paged_attention.py
+  latent_attention_layered: the heads of one token a block in decode,
+  1,024 (token, head) rows a block in prefill); a one-row chunk, and
+  everything off the chip, through a blockwise XLA arm (_attend_pool
+  has the measurements). Neither forms [B, H, T, S].
 
 Weight layout follows the DeepSeek-V2 architecture (q LoRA optional,
 kv LoRA + decoupled rope head); MoE layers reuse the Mixtral-style
@@ -25,20 +35,43 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.paged_attention import (NEG_INF, latent_attention_decode_layered,
+                                   latent_attention_prefill_layered)
+from ..runtime.config import env_flag
 from .config import ModelConfig
-from .llama import (DROP_SLOT, KVCacheSpec, _mlp, apply_rope, logits_at,
-                    rms_norm, rope_freqs)
+from . import llama
+from .llama import (KVCacheSpec, _mlp, _moe_use_blocked, apply_rope,
+                    carry_active, carry_step_update, commit_window,
+                    logits_at, rms_norm, rope_freqs)
 
 Params = Dict[str, jax.Array]
 
 
 # ---------------------------------------------------------------- KV cache
+
+
+_LANES = 128
+
+
+def rope_width(cfg: ModelConfig) -> int:
+    """Columns a token's rope key takes in its pool: qk_rope_head_dim,
+    and where the programs run on a TPU (llama._use_pallas) rounded up
+    to whole lanes of 128, the rest zeros. The TPU runtime does not
+    store a pool whose minor axis is 64 wide page by page: its compact
+    layout for bf16[L, pages, 1, ps, 64] makes PAGES the minor axis
+    (major_to_minor (0, 2, 3, 4, 1); my chip run, PR 31), and every
+    program that reads or writes a page would relayout the whole pool
+    first. At 128 columns the pool is row-major and a page is one
+    contiguous block, for the price of 128 B a token and layer. Other
+    backends keep the pool as narrow as the key."""
+    dr = cfg.qk_rope_head_dim
+    return -(-dr // _LANES) * _LANES if llama._use_pallas() else dr
 
 
 def cache_shapes(cfg: ModelConfig, spec: KVCacheSpec):
@@ -47,7 +80,7 @@ def cache_shapes(cfg: ModelConfig, spec: KVCacheSpec):
     latent = (cfg.num_layers, spec.num_pages, 1, spec.page_size,
               cfg.kv_lora_rank)
     rope = (cfg.num_layers, spec.num_pages, 1, spec.page_size,
-            cfg.qk_rope_head_dim)
+            rope_width(cfg))
     return latent, rope
 
 
@@ -205,193 +238,388 @@ def _dense_gate(w, topi, E):
                    * w[..., None], axis=-2)
 
 
-def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig,
-                      mesh=None) -> jax.Array:
-    """Routed experts plus the always-on shared experts. Large
-    dispatches on an unsharded expert axis use the sorted blocked
-    dispatch (~top_k/E of the dense FLOPs — with E up to 256 on
-    DeepSeek-V3 the dense-over-experts einsum is ~32x waste); decode-
-    sized dispatches and expert-parallel meshes keep the dense einsum
-    (see llama._moe_mlp for the strategy rationale)."""
-    from .llama import _moe_use_blocked, moe_block, moe_experts_blocked
+def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
+                      live=None, layer=None) -> jax.Array:
+    """Routed experts plus the always-on shared experts, on x [B, T, D].
+
+    ``lp`` holds one layer's router, bias and shared-expert leaves. The
+    routed experts are either that layer's ``[E, ...]`` stacks (the dense
+    einsum over every expert: decode-sized dispatches, where one read of
+    the weights bounds both forms, and expert-parallel meshes) or, with
+    ``layer`` (a traced index into the expert segment), the whole
+    ``[Lm, E, ...]`` parameters read in place by the sorted blocked
+    dispatch, whose work follows the ``live`` (token, expert) pairs
+    (llama.moe_experts_blocked; _moe_use_blocked holds the rule)."""
+    from .llama import moe_block, moe_experts_blocked
 
     B, T, D = x.shape
-    E = lp["w_gate_e"].shape[0]
-    x32 = x.astype(jnp.float32)
-    w, topi = _deepseek_gate(x32, lp["w_router"],
-                             lp.get("router_bias"), cfg)
+    E = lp["w_gate_e"].shape[-3]
     k = cfg.num_experts_per_tok
-    if _moe_use_blocked(mesh, B * T, E, k):
+    x32 = x.astype(jnp.float32)
+    with jax.named_scope("moe.router"):
+        w, topi = _deepseek_gate(x32, lp["w_router"],
+                                 lp.get("router_bias"), cfg)
+    if layer is not None:
         out = moe_experts_blocked(
             x32.reshape(B * T, D), w.reshape(B * T, k),
             topi.reshape(B * T, k), lp["w_gate_e"], lp["w_up_e"],
-            lp["w_down_e"],
-            moe_block(B * T, k, lp["w_gate_e"].shape)).reshape(B, T, D)
+            lp["w_down_e"], moe_block(B * T, k, lp["w_gate_e"].shape),
+            live=None if live is None else live.reshape(B * T),
+            layer=layer).reshape(B, T, D)
     else:
-        gate = _dense_gate(w, topi, E)
-        ge = jnp.einsum("btd,edi->btei", x32,
-                        lp["w_gate_e"].astype(jnp.float32))
-        up = jnp.einsum("btd,edi->btei", x32,
-                        lp["w_up_e"].astype(jnp.float32))
-        act = jax.nn.silu(ge) * up
-        down = jnp.einsum("btei,eid->bted", act,
-                          lp["w_down_e"].astype(jnp.float32))
-        out = jnp.einsum("bted,bte->btd", down, gate)
+        with jax.named_scope("moe.router"):
+            gate = _dense_gate(w, topi, E)
+        with jax.named_scope("moe.experts"):
+            ge = jnp.einsum("btd,edi->btei", x32,
+                            lp["w_gate_e"].astype(jnp.float32))
+            up = jnp.einsum("btd,edi->btei", x32,
+                            lp["w_up_e"].astype(jnp.float32))
+            act = jax.nn.silu(ge) * up
+            down = jnp.einsum("btei,eid->bted", act,
+                              lp["w_down_e"].astype(jnp.float32))
+            out = jnp.einsum("bted,bte->btd", down, gate)
     if cfg.n_shared_experts > 0:
-        out = out + _mlp(x32, lp["w_gate_s"].astype(jnp.float32),
-                         lp["w_up_s"].astype(jnp.float32),
-                         lp["w_down_s"].astype(jnp.float32))
+        with jax.named_scope("moe.shared"):
+            out = out + (jax.nn.silu(x @ lp["w_gate_s"])
+                         * (x @ lp["w_up_s"])) @ lp["w_down_s"]
     return out.astype(x.dtype)
 
 
-def _scatter_rows(cache_layer: jax.Array, new: jax.Array,
-                  flat_slots: jax.Array) -> jax.Array:
-    """cache_layer: [pages, 1, ps, d]; new: [B, T, d]; flat_slots [B, T]
-    (page*ps + off; DROP_SLOT pads)."""
-    _, _, ps, d = cache_layer.shape
-    idx = flat_slots.reshape(-1)
-    pages, offs = idx // ps, idx % ps
-    rows = new.reshape(-1, d).astype(cache_layer.dtype)
-    return cache_layer.at[pages, 0, offs].set(rows, mode="drop")
+# --------------------------------------------------------- latent attention
+#
+# Every program reads the pools and never writes them before its end:
+# the tokens a program adds (a prefill chunk, a decode window) keep their
+# (c_kv, k_rope) in a small buffer of their own, attention is the pool's
+# part (positions before the program's first) merged with the buffer's
+# part by their online-softmax statistics, and ONE commit per pool writes
+# the buffer in, along the pool's major axis, in place. No op has an
+# output of a pool's size (tests/test_tpu_compile.py).
+#
+# A part is (acc, m, l): acc [B, T, H, r] float32 = sum_j exp(s_j - m) c_j
+# (NOT divided by l), m [B, T, H] the running maximum, l [B, T, H] the
+# sum of exp(s_j - m). An empty part is (0, NEG_INF, 0).
+
+_POOL_BLOCK_TOKENS = 512  # cached tokens a step of the XLA arm gathers per row
 
 
-def _mla_attention(q_lat, q_rope, c_pages, r_pages, page_table,
-                   q_positions, scale):
-    """Latent-space paged attention.
+def _scores(q_lat, q_rope, c, kr, scale):
+    """[B, T, H, S] float32: q_lat . c + q_rope . k_r, operands in their
+    own type, float32 accumulation."""
+    return (jnp.einsum("bthr,bsr->bths", q_lat, c,
+                       preferred_element_type=jnp.float32)
+            + jnp.einsum("bthd,bsd->bths", q_rope, kr,
+                         preferred_element_type=jnp.float32)) * scale
 
-    q_lat: [B, T, H, r] (absorbed queries); q_rope: [B, T, H, dr];
-    c_pages: [pages, 1, ps, r]; r_pages: [pages, 1, ps, dr];
-    page_table: [B, P]; q_positions: [B, T]. Returns [B, T, H, r]
-    (latent-space context, to be up-projected by W_UV)."""
+
+def _attend_local(q_lat, q_rope, c_loc, r_loc, mask, scale):
+    """The part over a buffer: q_*: [B, T, H, *]; c_loc: [B, K, r];
+    r_loc: [B, K, dr]; mask: [B, T, K] (True: visible)."""
+    s = jnp.where(mask[:, :, None, :],
+                  _scores(q_lat, q_rope, c_loc, r_loc, scale), NEG_INF)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(mask[:, :, None, :], jnp.exp(s - m[..., None]), 0.0)
+    acc = jnp.einsum("bths,bsr->bthr", p.astype(c_loc.dtype), c_loc,
+                     preferred_element_type=jnp.float32)
+    return acc, m, jnp.sum(p, axis=-1)
+
+
+def _attend_pool_xla(q_lat, q_rope, c_pool, r_pool, l_idx, page_table,
+                     lengths, scale):
+    """The part over the pool's positions < lengths[b], in plain XLA:
+    blocks of _POOL_BLOCK_TOKENS tokens' pages are gathered along the
+    pools' major axis and folded in by online softmax, as many blocks as the
+    longest row needs (a traced bound: a 96-token prompt in a
+    9,216-token bucket runs one block, not eighteen). Nothing is [B, H, T, P * ps]:
+    a block's scores are [B, T, H, 512] float32. The arm of a one-row
+    chunk everywhere, and of every program where the kernels are off
+    (the CPU, DYN_DISABLE_PALLAS, a mesh of more than one device)."""
     B, T, H, r = q_lat.shape
-    _, _, ps, dr = r_pages.shape
+    L, NP, _, ps, _ = c_pool.shape
     P = page_table.shape[1]
-    S = P * ps
+    nb = min(max(_POOL_BLOCK_TOKENS // ps, 1), P)
+    S = nb * ps
+    pt = jnp.pad(page_table, ((0, 0), (0, -P % nb)))
+    cf = c_pool.reshape(L * NP, ps, r)
+    rf = r_pool.reshape(L * NP, ps, r_pool.shape[-1])
+    n_blocks = (jnp.max(lengths) + S - 1) // S
 
-    c = c_pages[page_table].reshape(B, S, r)  # [B, P, 1, ps, r] → [B, S, r]
-    kr = r_pages[page_table].reshape(B, S, dr)
-    scores = (jnp.einsum("bthr,bsr->bhts", q_lat.astype(jnp.float32),
-                         c.astype(jnp.float32))
-              + jnp.einsum("bthd,bsd->bhts", q_rope.astype(jnp.float32),
-                           kr.astype(jnp.float32))) * scale
-    mask = (jnp.arange(S)[None, None, :] <= q_positions[:, :, None])
-    scores = jnp.where(mask[:, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhts,bsr->bthr", probs, c.astype(jnp.float32))
-    return out
+    def block(j, part):
+        acc, m, l = part
+        idx = l_idx * NP + lax.dynamic_slice_in_dim(pt, j * nb, nb, axis=1)
+        c = cf[idx].reshape(B, S, r)
+        kr = rf[idx].reshape(B, S, -1)
+        valid = ((j * S + jnp.arange(S, dtype=jnp.int32))[None, :]
+                 < lengths[:, None])[:, None, None, :]       # [B,1,1,S]
+        s = jnp.where(valid, _scores(q_lat, q_rope, c, kr, scale), NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new[..., None]), 0.0)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bths,bsr->bthr", p.astype(c.dtype), c,
+            preferred_element_type=jnp.float32)
+        return acc, m_new, alpha * l + jnp.sum(p, axis=-1)
+
+    return lax.fori_loop(0, n_blocks, block, (
+        jnp.zeros((B, T, H, r), jnp.float32),
+        jnp.full((B, T, H), NEG_INF, jnp.float32),
+        jnp.zeros((B, T, H), jnp.float32)))
+
+
+def _attend_pool(q_lat, q_rope, c_pool, r_pool, l_idx, page_table, lengths,
+                 scale, kernel: Optional[bool]):
+    """Dispatch of the pool's part, by shape, where ``kernel`` is not
+    None (True: interpret mode, the CPU's test hook; None: the XLA arm
+    for everything).
+
+    One token a row (a decode step): the Pallas kernel, which follows
+    each row's own length. A chunk of ONE row: the XLA arm, whose big
+    batched matmuls run that shape faster than the kernel's page-sized
+    ones. A chunk of several rows: the kernel over blocks of (token,
+    head) rows, which skips the padding rows of the bucket and the
+    blocks past each row's length, where the XLA arm computes every row
+    to the longest. One layer, T 512 over 8k of cached prefix, device ms
+    (tools/latent_attn_timing.py; my chip run, PR 31): PB 1 XLA 2.13 /
+    kernel 3.70; PB 4 with one row live 23.1 / 5.1, all four live 23.1 /
+    15.3."""
+    B, T = q_lat.shape[:2]
+    if kernel is None or (T > 1 and B == 1):
+        return _attend_pool_xla(q_lat, q_rope, c_pool, r_pool, l_idx,
+                                page_table, lengths, scale)
+    if T == 1:
+        acc, m, l = latent_attention_decode_layered(
+            q_lat[:, 0], q_rope[:, 0], c_pool, r_pool, l_idx, page_table,
+            lengths, scale=scale, interpret=kernel)
+        return acc[:, None], m[:, None], l[:, None]
+    return latent_attention_prefill_layered(
+        q_lat, q_rope, c_pool, r_pool, l_idx, page_table, lengths,
+        scale=scale, interpret=kernel)
+
+
+def _merge(a, b):
+    """Two parts over disjoint keys -> the attention output in latent
+    space [B, T, H, r] float32 (normalised once, here)."""
+    (acc_a, m_a, l_a), (acc_b, m_b, l_b) = a, b
+    m = jnp.maximum(m_a, m_b)
+    w_a, w_b = jnp.exp(m_a - m), jnp.exp(m_b - m)
+    l = jnp.maximum(w_a * l_a + w_b * l_b, 1e-9)   # a padding row: zeros
+    return (acc_a * w_a[..., None] + acc_b * w_b[..., None]) / l[..., None]
+
+
+def _kernel_mode(allow_pallas: bool, mesh) -> Optional[bool]:
+    """None: the XLA arm; False: the kernel on the chip; True: the
+    kernel in interpret mode (DYN_PALLAS_INTERPRET, the hook
+    llama._attention honours, never on a TPU backend). Under a mesh of
+    more than one device the XLA arm stays (GSPMD shards its einsums;
+    the kernel has no shard_map wrapper yet)."""
+    if (not allow_pallas or env_flag("DYN_DISABLE_PALLAS")
+            or (mesh is not None and mesh.size > 1)):
+        return None
+    if llama._use_pallas():
+        return False
+    return True if env_flag("DYN_PALLAS_INTERPRET") else None
+
+
+def _at(params: Params, keys, l):
+    """One layer's leaves of the stacks named, by a (traced) index: the
+    slice a scan over the stack would make, without cutting the stacks
+    into a dense and an expert segment first."""
+    return {k: lax.dynamic_index_in_dim(params[k], l, 0, False)
+            for k in keys}
+
+
+def _latent_qkv(cfg: ModelConfig, lp, x, safe_pos, inv_freq, dtype):
+    """x [B, T, D] (normed) -> absorbed queries q_lat [B, T, H, r] =
+    q_nope . W_UK and q_rope [B, T, H, dr], and what the cache keeps of
+    the tokens: c_kv [B, T, r] (normed) and k_rope [B, T, dr], all in
+    the pools' ``dtype``; both rope parts padded with zeros to the rope
+    pool's width (rope_width; no padding off the TPU), which leaves every
+    score what it was."""
+    B, T, _ = x.shape
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    if cfg.q_lora_rank > 0:
+        q_all = rms_norm(x @ lp["w_dq"], lp["q_norm"],
+                         cfg.rms_norm_eps) @ lp["w_uq"]
+    else:
+        q_all = x @ lp["w_q"]
+    q_all = q_all.reshape(B, T, H, dn + dr)
+    q_rope = apply_rope(q_all[..., dn:], safe_pos, inv_freq)
+    q_lat = jnp.einsum("bthd,rhd->bthr", q_all[..., :dn],
+                       lp["w_uk"].reshape(r, H, dn),
+                       preferred_element_type=jnp.float32)
+    ckr = x @ lp["w_dkv"]                                  # [B, T, r + dr]
+    c_kv = rms_norm(ckr[..., :r], lp["kv_norm"], cfg.rms_norm_eps)
+    k_rope = apply_rope(ckr[..., None, r:], safe_pos,
+                        inv_freq)[..., 0, :]               # one shared head
+    pad = [(0, rope_width(cfg) - dr)]
+    return (q_lat.astype(dtype),
+            jnp.pad(q_rope.astype(dtype), [(0, 0)] * 3 + pad),
+            c_kv.astype(dtype),
+            jnp.pad(k_rope.astype(dtype), [(0, 0)] * 2 + pad))
+
+
+def _layers(params: Params, cfg: ModelConfig, h, attend, cache, mesh=None,
+            live=None):
+    """All layers on h [B, T, D]. ``attend(l, lp, x, cache_l) ->
+    (out_lat [B, T, H, r] float32, cache_l)`` is the latent attention of
+    layer ``l`` on the normed input: the caller owns where the tokens'
+    latents go (``cache``: a pytree with a leading layer axis, scanned
+    in and out: the window buffers in the decode window; None in forward,
+    which gets the chunk's own latents back stacked by layer). The leading ``first_k_dense_replace`` layers carry a dense
+    MLP, the rest routed experts; both index the attention stacks by
+    the absolute layer, so no stack and no pool is sliced in two."""
+    B, T, _ = h.shape
+    H, r, dv = cfg.num_heads, cfg.kv_lora_rank, cfg.v_head_dim
+    L = cfg.num_layers
+    attn_keys = _mla_attn_keys(cfg)
+
+    def block(h, l, cache_l, mlp):
+        lp = _at(params, attn_keys, l)
+        with jax.named_scope("attn"):
+            x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
+            out_lat, cache_l = attend(l, lp, x, cache_l)
+            # up-project the latent context per head: out = out_lat . W_UV
+            out = jnp.einsum("bthr,rhd->bthd", out_lat.astype(h.dtype),
+                             lp["w_uv"].reshape(r, H, dv),
+                             preferred_element_type=jnp.float32)
+            h = h + out.reshape(B, T, H * dv).astype(h.dtype) @ lp["w_o"]
+        x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
+        return h + mlp(x, l), cache_l
+
+    def run(h, mlp, l0, l1):
+        def layer(h, xs):
+            l, cache_l = xs
+            return block(h, l, cache_l, mlp)
+
+        return lax.scan(layer, h, (
+            jnp.arange(l0, l1, dtype=jnp.int32),
+            jax.tree.map(lambda a: a[l0:l1], cache)))
+
+    def dense_mlp(suffix):
+        keys = tuple(k + suffix for k in ("w_gate", "w_up", "w_down"))
+
+        def mlp(x, l):
+            lp = _at(params, keys, l)
+            return _mlp(x, *(lp[k] for k in keys))
+
+        return mlp
+
+    if cfg.num_experts == 0:
+        return run(h, dense_mlp(""), 0, L)
+    kd = cfg.first_k_dense_replace
+    moe_keys = list(_moe_layer_params(cfg, params))
+    in_place = _moe_use_blocked(mesh, B * T, cfg.num_experts,
+                                cfg.num_experts_per_tok)
+    experts = ("w_gate_e", "w_up_e", "w_down_e")
+
+    def moe_mlp(x, l):
+        li = l - kd
+        with jax.named_scope("moe"):
+            if not in_place:
+                return _deepseek_moe_mlp(x, _at(params, moe_keys, li), cfg,
+                                         mesh=mesh)
+            # the sorted dispatch reads w[layer, expert] from the whole
+            # stacks: sliced out first, each layer's 128 experts would
+            # be copied before the block loop may index them
+            lp = _at(params, [k for k in moe_keys if k not in experts], li)
+            lp.update({k: params[k] for k in experts})
+            return _deepseek_moe_mlp(x, lp, cfg, mesh=mesh, live=live,
+                                     layer=li)
+
+    parts = []
+    if kd > 0:
+        h, part = run(h, dense_mlp("_d"), 0, kd)
+        parts.append(part)
+    h, part = run(h, moe_mlp, kd, L)
+    parts.append(part)
+    return h, jax.tree.map(lambda *a: jnp.concatenate(a, axis=0), *parts)
+
+
+def _commit_chunk(pool: jax.Array, new: jax.Array, flat_slots: jax.Array,
+                  page_slots: Optional[jax.Array]) -> jax.Array:
+    """Write a chunk's latents (or rope keys) of ALL layers into the pool,
+    once, along its major axis, in place.
+
+    pool: [L, pages, 1, ps, d] (donated by the caller); new: [L, B, T, d].
+    With ``page_slots`` [B, T // ps] (the engine passes them when every
+    chunk starts on a page and T is whole pages) whole pages are written
+    to the pool seen as [L * pages, 1, ps, d]; a tail page carries junk
+    past the chunk's end, which no query reads before the token that
+    belongs there is written (llama._scatter_pages_paged has the
+    argument). Otherwise ``flat_slots`` [B, T] (page * ps + offset) write
+    token rows into [L * pages * ps, d]: with one latent head a token's
+    row lies directly under (page, offset), so that too is a scatter on
+    the major axis. Ids outside the pool (DROP_SLOT, padding) are
+    dropped."""
+    L, NP, _, ps, d = pool.shape
+    B, T = new.shape[1:3]
+    layers = jnp.arange(L, dtype=jnp.int32)[:, None]
+    new = new.astype(pool.dtype)
+    if page_slots is not None:
+        idx = page_slots.reshape(-1)                       # [B * T/ps]
+        dst = jnp.where((idx >= 0) & (idx < NP), layers * NP + idx, L * NP)
+        flat = pool.reshape(L * NP, 1, ps, d).at[dst.reshape(-1)].set(
+            new.reshape(-1, 1, ps, d), mode="drop")
+    else:
+        idx = flat_slots.reshape(-1)                       # [B * T]
+        dst = jnp.where((idx >= 0) & (idx < NP * ps),
+                        layers * (NP * ps) + idx, L * NP * ps)
+        flat = pool.reshape(L * NP * ps, d).at[dst.reshape(-1)].set(
+            new.reshape(-1, d), mode="drop")
+    return flat.reshape(pool.shape)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             positions: jax.Array, kv_lat: jax.Array, kv_rope: jax.Array,
             page_table: jax.Array, flat_slots: jax.Array,
             allow_pallas: bool = True, mesh=None,
+            page_slots: Optional[jax.Array] = None,
             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Same signature/contract as llama.forward; (kv_k, kv_v) ≡
-    (latent pool, rope pool)."""
-    del allow_pallas  # latent attention is XLA-einsum throughout;
-    # mesh is only consulted to pick the MoE dispatch strategy
+    (latent pool, rope pool). A row's positions are consecutive from
+    positions[b, 0] (-1: padding, at the row's end), as the engine
+    builds every chunk: the pool holds what lies before positions[b, 0],
+    the chunk attends to that and, causally, to itself."""
     inv_freq = rope_freqs(cfg, dim=cfg.qk_rope_head_dim)
-    H = cfg.num_heads
-    r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-    scale = 1.0 / math.sqrt(dn + dr)
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
     B, T = tokens.shape
-
+    kernel = _kernel_mode(allow_pallas, mesh)
     h = params["embed"][tokens]
     safe_pos = jnp.maximum(positions, 0)
+    live = positions >= 0
+    before = jnp.maximum(positions[:, 0], 0)           # [B] pool extent
+    own = (live[:, None, :]
+           & (positions[:, None, :] <= positions[:, :, None]))  # [B, T, T]
 
-    def layer_with(mlp_apply):
-        def layer(h, xs):
-            lp, c_layer, r_layer = xs
-            x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
-            # queries
-            if cfg.q_lora_rank > 0:
-                q_all = rms_norm(x @ lp["w_dq"], lp["q_norm"],
-                                 cfg.rms_norm_eps) @ lp["w_uq"]
-            else:
-                q_all = x @ lp["w_q"]
-            q_all = q_all.reshape(B, T, H, dn + dr)
-            q_nope, q_rope = q_all[..., :dn], q_all[..., dn:]
-            q_rope = apply_rope(q_rope, safe_pos, inv_freq)
-            # kv latent + shared rope key
-            ckr = x @ lp["w_dkv"]  # [B, T, r + dr]
-            c_kv = rms_norm(ckr[..., :r], lp["kv_norm"], cfg.rms_norm_eps)
-            k_rope = apply_rope(ckr[..., None, r:], safe_pos,
-                                inv_freq)[..., 0, :]  # one shared rope head
-            c_layer = _scatter_rows(c_layer, c_kv, flat_slots)
-            r_layer = _scatter_rows(r_layer, k_rope, flat_slots)
-            # absorbed attention: q_lat = q_nope · W_UK (per head)
-            w_uk = lp["w_uk"].reshape(r, H, dn)
-            q_lat = jnp.einsum("bthd,rhd->bthr",
-                               q_nope.astype(jnp.float32),
-                               w_uk.astype(jnp.float32))
-            out_lat = _mla_attention(q_lat, q_rope, c_layer, r_layer,
-                                     page_table, positions, scale)
-            # up-project latent context per head: out = out_lat · W_UV
-            w_uv = lp["w_uv"].reshape(r, H, dv)
-            out = jnp.einsum("bthr,rhd->bthd", out_lat,
-                             w_uv.astype(jnp.float32))
-            h2 = h + out.reshape(B, T, H * dv).astype(h.dtype) @ lp["w_o"]
-            x = rms_norm(h2, lp["ln_mlp"], cfg.rms_norm_eps)
-            return h2 + mlp_apply(x, lp), (c_layer, r_layer)
+    def attend(l, lp, x, _):
+        q_lat, q_rope, c_kv, k_rope = _latent_qkv(cfg, lp, x, safe_pos,
+                                                  inv_freq, kv_lat.dtype)
+        with jax.named_scope("attn.latent"):
+            out = _merge(
+                _attend_pool(q_lat, q_rope, kv_lat, kv_rope, l, page_table,
+                             before, scale, kernel),
+                _attend_local(q_lat, q_rope, c_kv, k_rope, own, scale))
+        return out, (c_kv, k_rope)
 
-        return layer
-
-    if cfg.num_experts == 0:
-        layer_params = {k: params[k] for k in _mla_layer_keys(cfg)}
-        dense = layer_with(
-            lambda x, lp: _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"]))
-        h, (new_c, new_r) = lax.scan(dense, h,
-                                     (layer_params, kv_lat, kv_rope))
-    else:
-        # DeepSeek-MoE: dense first-k layers, then MoE layers — two scans
-        # over layer segments (per-segment param stacks; the pools are
-        # sliced/concatenated, an extra copy the small latent cache
-        # affords)
-        kd = cfg.first_k_dense_replace
-        attn = {k: params[k] for k in _mla_attn_keys(cfg)}
-        seg_a = jax.tree.map(lambda a: a[:kd], attn)
-        seg_b = jax.tree.map(lambda a: a[kd:], attn)
-        new_c_parts, new_r_parts = [], []
-        if kd > 0:
-            seg_a.update({k: params[f"{k}_d"]
-                          for k in ("w_gate", "w_up", "w_down")})
-            dense = layer_with(lambda x, lp: _mlp(
-                x, lp["w_gate"], lp["w_up"], lp["w_down"]))
-            h, (c_a, r_a) = lax.scan(dense, h,
-                                     (seg_a, kv_lat[:kd], kv_rope[:kd]))
-            new_c_parts.append(c_a)
-            new_r_parts.append(r_a)
-        seg_b.update(_moe_layer_params(cfg, params))
-        moe = layer_with(
-            lambda x, lp: _deepseek_moe_mlp(x, lp, cfg, mesh=mesh))
-        h, (c_b, r_b) = lax.scan(moe, h,
-                                 (seg_b, kv_lat[kd:], kv_rope[kd:]))
-        new_c_parts.append(c_b)
-        new_r_parts.append(r_b)
-        new_c = jnp.concatenate(new_c_parts, axis=0)
-        new_r = jnp.concatenate(new_r_parts, axis=0)
+    h, (c_new, r_new) = _layers(params, cfg, h, attend, None, mesh=mesh,
+                                live=live)
+    with jax.named_scope("kv_carry"):
+        kv_lat = _commit_chunk(kv_lat, c_new, flat_slots, page_slots)
+        kv_rope = _commit_chunk(kv_rope, r_new, flat_slots, page_slots)
     h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps)
-    return h, new_c, new_r
+    return h, kv_lat, kv_rope
 
 
 def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
-    """Jitted (prefill_step, decode_step); same contract as llama.
-    Latent attention is XLA-einsum based throughout, so the pallas
-    kernel knob is accepted for interface parity and ignored (GSPMD
-    shards the einsums directly); mesh only picks the MoE dispatch
-    strategy (expert-sharded meshes keep the dense einsum)."""
-    del allow_pallas
+    """Jitted (prefill_step, decode_step); same contract as llama."""
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v"))
     def prefill_step(params, tokens, positions, kv_k, kv_v, page_table,
                      flat_slots, last_idx, page_slots=None):
-        # page_slots accepted for engine-contract parity with llama; the
-        # MLA latent cache keeps the row-scatter commit (its pages hold
-        # compressed latents, not per-head K/V blocks)
-        del page_slots
         h, k2, v2 = forward(params, cfg, tokens, positions, kv_k, kv_v,
-                            page_table, flat_slots, mesh=mesh)
+                            page_table, flat_slots,
+                            allow_pallas=allow_pallas, mesh=mesh,
+                            page_slots=page_slots)
         return logits_at(params, cfg, h, last_idx), k2, v2
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v"))
@@ -399,11 +627,102 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
                     flat_slots):
         h, k2, v2 = forward(params, cfg, tokens[:, None], positions[:, None],
                             kv_k, kv_v, page_table, flat_slots[:, None],
-                            mesh=mesh)
+                            allow_pallas=allow_pallas, mesh=mesh)
         return (logits_at(params, cfg, h,
                           jnp.zeros(tokens.shape[0], jnp.int32)), k2, v2)
 
     return prefill_step, decode_step
+
+
+def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
+                          max_top_k: int = 64, mesh=None,
+                          pallas_interpret: bool = False):
+    """The fused K-step window of llama.make_decode_window_fn, same
+    signature and contract, over the latent cache: the latent and rope
+    pools are read-only inside the window (the Pallas latent decode
+    kernel on the chip, the XLA arm elsewhere), the window's own
+    (c_kv, k_rope) live in buffers [L, B, K, 1, *] merged in by
+    online-softmax statistics, and one llama.commit_window per pool
+    writes them in by whole pages, in place."""
+    from ..engine.sampling import (logprob_aux, sample_tokens,
+                                   update_penalty_state)
+
+    inv_freq = rope_freqs(cfg, dim=cfg.qk_rope_head_dim)
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    kernel = _kernel_mode(allow_pallas, mesh)
+    if pallas_interpret and kernel is None and not llama._use_pallas():
+        kernel = True
+
+    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
+             donate_argnames=("kv_k", "kv_v"))
+    def decode_window(params, tokens, positions, done, steps, remaining,
+                      kv_k, kv_v, page_table, temperature, top_k, top_p,
+                      seeds, eos_table, penalties=None, *, k_steps: int,
+                      logprobs_topn: int = 0):
+        B = tokens.shape[0]
+        L = cfg.num_layers
+        start = positions       # [B] the window's first position (-1 pad)
+        before = jnp.maximum(start, 0)
+        wc = jnp.zeros((L, B, k_steps, 1, kv_k.shape[-1]), kv_k.dtype)
+        wr = jnp.zeros((L, B, k_steps, 1, kv_v.shape[-1]), kv_v.dtype)
+        slot = jnp.arange(k_steps, dtype=jnp.int32)
+
+        def one_step(tok, pos, wc, wr, i):
+            safe_pos = jnp.maximum(pos, 0)[:, None]
+            seen = ((slot[None, :] <= i)
+                    & (start[:, None] >= 0))[:, None, :]      # [B, 1, K]
+
+            def attend(l, lp, x, bufs):
+                wc_l, wr_l = bufs
+                q_lat, q_rope, c_kv, k_rope = _latent_qkv(
+                    cfg, lp, x, safe_pos, inv_freq, wc.dtype)
+                wc_l = wc_l.at[:, i, 0].set(c_kv[:, 0])
+                wr_l = wr_l.at[:, i, 0].set(k_rope[:, 0])
+                with jax.named_scope("attn.latent"):
+                    out = _merge(
+                        _attend_pool(q_lat, q_rope, kv_k, kv_v, l,
+                                     page_table, before, scale, kernel),
+                        _attend_local(q_lat, q_rope, wc_l[:, :, 0],
+                                      wr_l[:, :, 0], seen, scale))
+                return out, (wc_l, wr_l)
+
+            h = params["embed"][tok][:, None]                 # [B, 1, D]
+            h, (wc, wr) = _layers(params, cfg, h, attend, (wc, wr),
+                                  mesh=mesh)
+            h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps)
+            return logits_at(params, cfg, h, jnp.zeros(B, jnp.int32)), wc, wr
+
+        tok, pos = tokens, positions
+        toks, lps, tvs, tis = [], [], [], []
+        emitted = jnp.zeros((B,), jnp.int32)
+        for i in range(k_steps):
+            # frozen (done / padding) rows flow through the matmuls;
+            # their outputs are discarded and their latents never commit
+            logits, wc, wr = one_step(tok, pos, wc, wr, i)
+            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
+                                steps, max_top_k=max_top_k,
+                                penalties=penalties)
+            if logprobs_topn:
+                lp, tv, ti = logprob_aux(logits, nxt, logprobs_topn)
+                lps.append(lp); tvs.append(tv); tis.append(ti)
+            penalties = update_penalty_state(penalties, nxt, done)
+            emitted = emitted + carry_active(done, pos).astype(jnp.int32)
+            tok, pos, done, steps, remaining = carry_step_update(
+                nxt, tok, pos, done, steps, remaining, eos_table)
+            toks.append(tok)
+
+        with jax.named_scope("kv_carry"):
+            kv_k = commit_window(kv_k, wc, page_table, start, pos)
+            kv_v = commit_window(kv_v, wr, page_table, start, pos)
+        out_toks = jnp.stack(toks, axis=1)
+        carry = (tok, pos, done, steps, remaining)
+        if logprobs_topn:
+            aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
+                   jnp.stack(tis, axis=1))
+            return out_toks, emitted, aux, carry, kv_k, kv_v
+        return out_toks, emitted, carry, kv_k, kv_v
+
+    return decode_window
 
 
 # -------------------------------------------------- full-attention reference

@@ -293,6 +293,210 @@ def paged_attention_decode_layered(q: jax.Array, k_pools: jax.Array,
     return out
 
 
+# ------------------------------------------------ latent (MLA) attention
+
+LATENT_TOKENS_PER_STEP = 1024  # cached tokens a grid step takes
+LATENT_BLOCK_ROWS = 1024       # query rows (tokens x heads) a block holds
+DECODE_NAME = "latent_attention_decode_layered"
+PREFILL_NAME = "latent_attention_prefill_layered"
+
+
+def _latent_kernel(ps: int, G: int, scale: float,
+                   l_ref, pt_ref, len_ref,
+                   ql_ref, qr_ref, *refs):
+    """One grid step: G pages of one row against one block of query rows
+    (all heads of one token in decode, heads x tokens of a chunk in
+    prefill). refs: G latent pages [ps, r], G rope pages [ps, dw], then
+    acc / m / l and the scratch (m, l, acc)."""
+    del l_ref, pt_ref
+    c_refs, r_refs = refs[:G], refs[G:2 * G]
+    o_ref, m_out, l_out, m_ref, l_ref2, acc_ref = refs[2 * G:]
+    b = pl.program_id(0)
+    s = pl.program_id(2)
+
+    @pl.when(s == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref2[...] = jnp.zeros_like(l_ref2)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    length = len_ref[b]
+
+    # a step wholly past the row's context: no compute, and its index
+    # maps name the blocks the row's last live step fetched (no traffic)
+    @pl.when(s * G * ps < length)
+    def _():
+        # the step's pages as ONE [G * ps, *] block: one matmul a product
+        ccat = jnp.concatenate([c[...] for c in c_refs], axis=0)
+        rcat = jnp.concatenate([r[...] for r in r_refs], axis=0)
+        nt = (((1,), (1,)), ((), ()))                   # a . b^T
+        sc = (jax.lax.dot_general(ql_ref[...], ccat, nt,
+                                  preferred_element_type=jnp.float32)
+              + jax.lax.dot_general(qr_ref[...], rcat, nt,
+                                    preferred_element_type=jnp.float32)
+              ) * scale                                 # [M, G * ps]
+        pos = s * G * ps + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        valid = pos < length
+        sc = jnp.where(valid, sc, NEG_INF)
+        m_prev = m_ref[:, :1]                           # [M, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        l_new = alpha * l_ref2[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(ccat.dtype), ccat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [M, r]
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref2[...] = jnp.broadcast_to(l_new, l_ref2.shape)
+
+    @pl.when(s == pl.num_programs(2) - 1)
+    def _():
+        o_ref[...] = acc_ref[...]
+        m_out[...] = m_ref[...]
+        l_out[...] = l_ref2[...]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "pages_per_step", "block_rows",
+                                             "name"))
+def latent_attention_layered(q_lat: jax.Array, q_rope: jax.Array,
+                             c_pools: jax.Array, r_pools: jax.Array,
+                             layer: jax.Array, page_table: jax.Array,
+                             lengths: jax.Array, *, scale: float,
+                             interpret: bool = False,
+                             pages_per_step: int | None = None,
+                             block_rows: int = LATENT_BLOCK_ROWS,
+                             name: str = DECODE_NAME):
+    """Latent (MLA, absorbed) attention of M query rows a batch row
+    against what ONE layer of the stacked latent and rope pools holds
+    of that row: its positions < lengths[b], the same for all M.
+
+    q_lat: [B, M, r] (q_nope . W_UK); q_rope: [B, M, dw]; M is the heads
+    of one token (a decode step) or tokens x heads of a prefill chunk,
+    whose queries all see the whole cached prefix. c_pools: [L, pages, 1,
+    ps, r]; r_pools: [L, pages, 1, ps, dw]; ``layer`` a traced int32
+    scalar (a scalar-prefetch operand, as paged_attention_decode_layered
+    has it); page_table: [B, P]; lengths: [B] (0: nothing in the pool).
+
+    There is ONE latent "KV head": every page is read once for all the
+    rows of a block, the score is q_lat . c + q_rope . k_r, and the value
+    is the latent itself (a prefix of the key). Returns a PART, for the
+    caller's merge with the attention over the program's own tokens:
+    acc [B, M, r] float32 = sum_j exp(s_j - m) c_j (NOT divided by l),
+    m [B, M] the maximum and l [B, M] the sum of exp(s_j - m); a row
+    with nothing in the pool gives (0, NEG_INF, 0). W_UV is applied
+    outside. Operands go to the MXU in the pools' type with float32
+    accumulation; softmax runs in float32.
+
+    ``pages_per_step`` and ``block_rows`` are the handles of the tests
+    (steps that divide nothing, several blocks at a small size) and of
+    tools/latent_attn_timing.py; the two entries below, which are what
+    models/mla.py calls, take neither and run the measured constants.
+
+    Grid (B, M / block_rows, ceil(P / G)): a step takes G =
+    ``pages_per_step`` pages of the row (default: LATENT_TOKENS_PER_STEP
+    tokens' worth), each pool passed G times with an index map of its
+    own, so a page does not cost a grid step (the GQA kernel at one KV
+    head ran 4,096 steps a layer and step and reached 5% of its
+    roofline: PERF.md, cell 4). Steps past a row's context re-point at
+    the blocks its last live step fetched and are skipped.
+
+    What a step costs is mostly its OPERANDS: about 0.1 us each for the
+    pipeline's bookkeeping, 2 a page, whatever the page's size, where
+    a 64-token page's bytes take 0.1 us at the HBM's peak and its
+    matmuls less. So the kernel's time follows the number of pages,
+    not of tokens: B 64 rows of ~8.5k tokens, one layer, device ms (my
+    chip runs, PR 31): pages of 64: 3.00 / 2.52 / 2.27 / 2.33 at G 4 /
+    8 / 16 / 32 against the XLA arm's 1.63; pages of 128: 1.63 / 1.38 /
+    1.37 at G 4 / 8 / 16 against 1.50, and with 34 of the 64 rows empty
+    1.09 against 1.50 (the XLA arm computes every row to the longest).
+    A cell of long contexts wants pages of 128 (cell 5's engine data)."""
+    B, M, r = q_lat.shape
+    dw = q_rope.shape[-1]
+    ps = c_pools.shape[3]
+    P = page_table.shape[1]
+    G = max(1, min(pages_per_step or LATENT_TOKENS_PER_STEP // ps, P))
+    steps = (P + G - 1) // G
+    mb = min(block_rows, M)
+    assert M % mb == 0, (M, mb)
+
+    def page_index(j):
+        def index(b, q, s, l, pt, ln):
+            n = (ln[b] + ps - 1) // ps                  # live pages
+            last = jnp.maximum(n - 1, 0)
+            p = jnp.minimum(jnp.minimum(s, last // G) * G + j, last)
+            return (l[0], pt[b, jnp.minimum(p, P - 1)], 0, 0, 0)
+        return index
+
+    def rows(b, q, s, l, pt, ln):
+        return (b, q, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B, M // mb, steps),
+        in_specs=[pl.BlockSpec((None, mb, r), rows),
+                  pl.BlockSpec((None, mb, dw), rows)]
+        + [pl.BlockSpec((None, None, None, ps, r), page_index(j))
+           for j in range(G)]
+        + [pl.BlockSpec((None, None, None, ps, dw), page_index(j))
+           for j in range(G)],
+        out_specs=[pl.BlockSpec((None, mb, r), rows),
+                   pl.BlockSpec((None, mb, 128), rows),
+                   pl.BlockSpec((None, mb, 128), rows)],
+        scratch_shapes=[pltpu.VMEM((mb, 128), jnp.float32),
+                        pltpu.VMEM((mb, 128), jnp.float32),
+                        pltpu.VMEM((mb, r), jnp.float32)],
+    )
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_kernel, ps, G, scale),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, M, r), jnp.float32),
+                   jax.ShapeDtypeStruct((B, M, 128), jnp.float32),
+                   jax.ShapeDtypeStruct((B, M, 128), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # a block of 1,024 rows holds its scores, its probabilities
+            # and three [rows, r] float32 buffers: past the default limit
+            vmem_limit_bytes=(64 << 20) if mb > 128 else None),
+        interpret=interpret,
+        # the name a device trace shows the kernel under (what
+        # benchmark/metrics/latent_attn_roofline.py matches)
+        name=name,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      q_lat.astype(c_pools.dtype), q_rope.astype(r_pools.dtype),
+      *([c_pools] * G), *([r_pools] * G))
+    return acc, m[:, :, 0], l[:, :, 0]
+
+
+def latent_attention_decode_layered(q_lat, q_rope, c_pools, r_pools, layer,
+                                    page_table, lengths, *, scale,
+                                    interpret=False):
+    """One decode step: q_lat [B, H, r], q_rope [B, H, dw], the heads of
+    one token a row; 32 heads against the one latent head."""
+    return latent_attention_layered(
+        q_lat, q_rope, c_pools, r_pools, layer, page_table, lengths,
+        scale=scale, interpret=interpret, name=DECODE_NAME)
+
+
+def latent_attention_prefill_layered(q_lat, q_rope, c_pools, r_pools, layer,
+                                     page_table, lengths, *, scale,
+                                     interpret=False):
+    """A prefill chunk against its cached prefix: q_lat [B, T, H, r],
+    q_rope [B, T, H, dw], every query of row b seeing the pool's
+    positions < lengths[b] (the chunk's own tokens are the caller's).
+    Blocks of LATENT_BLOCK_ROWS (token, head) rows keep their scores on
+    the chip and fill the MXU's rows, which the heads of one token do
+    not; a padding row (length 0) costs its grid steps and nothing
+    else."""
+    B, T, H, r = q_lat.shape
+    acc, m, l = latent_attention_layered(
+        q_lat.reshape(B, T * H, r), q_rope.reshape(B, T * H, -1), c_pools,
+        r_pools, layer, page_table, lengths, scale=scale,
+        interpret=interpret, name=PREFILL_NAME)
+    return acc.reshape(B, T, H, r), m.reshape(B, T, H), l.reshape(B, T, H)
+
+
 def paged_attention_prefill_sharded(q: jax.Array, k_pages: jax.Array,
                                     v_pages: jax.Array,
                                     page_table: jax.Array,

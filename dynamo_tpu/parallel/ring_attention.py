@@ -262,7 +262,7 @@ def make_mla_long_prefill_fn(cfg: ModelConfig, mesh: Mesh, *,
     import math
 
     from ..models.llama import apply_rope, rms_norm, rope_freqs
-    from ..models.mla import _mla_layer_keys
+    from ..models.mla import _mla_layer_keys, rope_width
 
     if cfg.num_experts > 0:
         raise ValueError(
@@ -333,7 +333,9 @@ def make_mla_long_prefill_fn(cfg: ModelConfig, mesh: Mesh, *,
         h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps)
         last_idx = jnp.argmax(positions, axis=1)
         h_last = h[jnp.arange(B), last_idx]
-        # KV-head axis = 1, matching the MLA paged pools
+        # KV-head axis = 1 and the rope key padded to whole lanes,
+        # matching the MLA paged pools (mla.cache_shapes)
+        r_all = jnp.pad(r_all, [(0, 0)] * 3 + [(0, rope_width(cfg) - dr)])
         return (project_logits(params, cfg, h_last),
                 c_all[:, :, :, None], r_all[:, :, :, None])
 

@@ -382,6 +382,108 @@ def test_mla_moe_step_compiles_at_moonlight_widths(one_chip, step):
             < 16 * 1024 ** 3)
 
 
+# ------------- latent attention at Kanana-2-30B-A3B's widths (cell 5)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _kanana_cell():
+    """(configuration directory, the cell's engine data): the pool, the
+    page size and the buckets are read from the benchmark's own files,
+    so the compiles below are of the shapes the cell runs."""
+    import json
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "kanana-2-30b-a3b.doc-qa.json")) as f:
+        engine = json.load(f)["engine"]
+    return (os.path.join(ROOT, "benchmark", "configs", "kanana-2-30b-a3b"),
+            engine)
+
+
+def _kanana(one_chip, experts=8):
+    """The configuration as the cell runs it (every width as published),
+    the expert count cut so the compiles stay short; params and the two
+    pools as shapes on the described chip."""
+    import dataclasses
+
+    from dynamo_tpu.models import mla
+    from dynamo_tpu.models.config import ModelConfig
+
+    path, e = _kanana_cell()
+    cfg = dataclasses.replace(ModelConfig.from_local_path(path),
+                              num_experts=experts)
+    params = _on(one_chip, jax.eval_shape(
+        lambda: mla.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (_on(one_chip, x) for x in jax.eval_shape(
+        lambda: mla.init_kv_cache(cfg, llama.KVCacheSpec(
+            e["num_pages"], e["page_size"]))))
+    assert kv_k.shape == (6, e["num_pages"], 1, e["page_size"], 512)
+    # the rope key in whole lanes
+    assert kv_v.shape == (6, e["num_pages"], 1, e["page_size"], 128)
+    return cfg, params, kv_k, kv_v, e
+
+
+@pytest.mark.parametrize("page_size,P", [(128, 72), (64, 144)])
+def test_latent_decode_kernel_compiles(one_chip, page_size, P):
+    """32 heads against one latent head of 512 + a rope key padded to
+    128, 1,024 cached tokens a grid step, at the cell's batch and at
+    both page sizes a 9,216-token bucket can have: the kernel itself,
+    and no relayout of either pool in front of it (a rope pool 64
+    columns wide would be copied whole: the runtime stores it with PAGES
+    as its minor axis)."""
+    s = partial(_sds, one_chip)
+    pages = 64 * P
+    compiled = jax.jit(partial(pa.latent_attention_decode_layered,
+                               scale=192 ** -0.5)).lower(
+        s((64, 32, 512), jnp.bfloat16), s((64, 32, 128), jnp.bfloat16),
+        s((6, pages, 1, page_size, 512), jnp.bfloat16),
+        s((6, pages, 1, page_size, 128), jnp.bfloat16), s((), jnp.int32),
+        s((64, P), jnp.int32), s((64,), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(compiled.as_text(),
+                              6 * pages * page_size * 128) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("program", ["window", "prefill", "prefill-rows"])
+def test_latent_programs_make_no_pool_sized_copy(one_chip, tpu_kernel_path,
+                                                 program):
+    """models/mla.py at the pool shapes of kanana-2-30b-a3b.doc-qa
+    ([6, 1536, 1, 128, 512] latents, [6, 1536, 1, 128, 128] rope keys):
+    the pools are read-only inside the fused window (B 64, P 72) and
+    inside a prefill chunk (PB 8 x T 512), and one commit per pool writes
+    whole pages (``prefill-rows``: token rows, the engine's path for an
+    unaligned chunk) along its major axis. So the optimized program
+    holds no copy of a pool's size, both pools alias their inputs, and
+    the temporaries stay under the latent pool. The segment slice and
+    concatenate that forward() made of the pools until PR 31 were two
+    pool-sized copies in every program."""
+    from dynamo_tpu.models import mla
+
+    cfg, params, kv_k, kv_v, e = _kanana(one_chip)
+    s = partial(_sds, one_chip)
+    P = e["page_buckets"][-1]
+    if program == "window":
+        fn = mla.make_decode_window_fn(cfg, True, 64)
+        compiled = _lower_window(fn, one_chip, params, kv_k, kv_v,
+                                 e["max_batch"], P).compile()
+    else:
+        prefill, _ = mla.make_step_fns(cfg)
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        pslots = s((PB, T // e["page_size"]), jnp.int32) \
+            if program == "prefill" else None
+        compiled = prefill.lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), pslots).compile()
+    assert _has_kernel(compiled)      # decode's, and a multi-row chunk's
+    assert _pool_sized_copies(compiled.as_text(), kv_v.size) == []
+    mem = compiled.memory_analysis()
+    pools = sum(x.size * x.dtype.itemsize for x in (kv_k, kv_v))
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < kv_k.size * kv_k.dtype.itemsize
+
+
 def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
     """The Pallas kernel's serialized module rides inside the
     tpu_custom_call's opaque config, source locations included: without
