@@ -3,21 +3,26 @@
 The serving hot loop. The XLA fallback (models/llama.py _paged_attention)
 gathers every sequence's pages into a dense [B, S, KV, hd] tensor each
 decode step — O(B·S) HBM traffic through an intermediate buffer. This
-kernel instead walks the page table (scalar-prefetched so the index map
-can address pages before the body runs), streams each needed page
-HBM→VMEM exactly once, and runs an online-softmax (flash) accumulation
-on-chip for ALL heads of the sequence at once:
+kernel instead walks the page table (scalar-prefetched, read inside the
+body), copies each needed page HBM→VMEM exactly once, and runs an
+online-softmax (flash) accumulation on-chip for ALL heads of the
+sequence at once:
 
-  grid = (batch, pages); per (b, p): q·Kᵀ for every GQA group (MXU,
-  batched over the leading KV axis — the pool layout [N, KV, ps, hd] is
-  chosen so no in-kernel transpose is needed) → running max/sum rescale →
-  acc += softmax·V, output written on the final page step.
+  grid = (batch,); per row a loop over its chunks of G pages (G by
+  shape: _decode_pages_per_step), the pools left in HBM and a chunk's
+  pages brought in by async copies while the chunk before it computes;
+  per chunk: q·Kᵀ for every GQA group (MXU, batched over the leading KV
+  axis — the pool layout [N, KV, ps, hd] is chosen so no in-kernel
+  transpose is needed) → running max/sum rescale → acc += softmax·V,
+  output written when the row's loop ends.
 
-Pages past a sequence's length are clamped to the row's first page in the
-index map: Pallas skips re-fetching a block whose index is unchanged, so
-trailing invalid pages cost no HBM traffic (and `pl.when` skips their
-compute). Short sequences therefore pay for the pages they own, not for
-the padded page-table width.
+The loop runs over the pages that hold positions [lower, length) and no
+others: a page past a row's end or before its sliding window costs no
+copy, no grid step and no compute, and a padding row costs one empty
+grid step. A call's time therefore follows the pages its rows own, not
+the padded page-table width (one grid step a slot of the table, as this
+kernel had it until PR 32, took 0.19 us a slot for the pipeline's
+bookkeeping alone: 5-20% of the bytes' roofline in the benchmark's cells).
 
 This is the role block_copy.cu + the engines' paged-attention CUDA
 kernels play in the reference (SURVEY §2.3), expressed TPU-natively.
@@ -49,83 +54,202 @@ def effective_window(window, is_sliding, B: int):
         (B,))
 
 
-def _decode_kernel(ps: int, scale: float, return_stats: bool,
+# Cached tokens a chunk of the decode kernel's row loop takes, and the
+# VMEM a chunk's K and V may hold. One layer's call, device ms at 1 / 2 /
+# 4 / 8 / 16 / 32 pages a chunk, then the parent's kernel (a grid step a
+# slot of the page table) and the XLA gather arm
+# (tools/paged_attn_timing.py, my chip run, PR 32; rows and contexts as
+# the cells' traffic draws them; "-": past the chip's VMEM):
+#   cell 1, B 32 x P 64, KV 8, 14 rows live, 93 pages:
+#       0.073 0.056 0.052 0.053 0.057 -     | 0.520 | 3.51
+#   cell 2, B 64 x P 32, KV 4, 64 rows, 443 pages:
+#       0.242 0.161 0.119 0.105 0.102 0.120 | 0.645 | 1.84
+#   cell 3, B 32 x P 64, KV 8, 14 rows, 381 pages:
+#       0.254 0.177 0.152 0.154 0.156 -     | 0.652 | 3.51
+#   cell 4, B 128 x P 32, KV 1, 128 rows, 1,667 pages:
+#       0.818 0.497 0.328 0.236 0.204 0.200 | 1.389 | 0.80
+# A copy costs about as much as a page of one KV head's bytes, so many
+# small pages (KV 1) want many a chunk; past ~2,048 (token, KV head) rows
+# a chunk nothing is gained and the masked positions of short rows cost.
+DECODE_TOKENS_PER_STEP = 1024
+_DECODE_VMEM_BYTES = 6 << 20
+
+
+def _decode_pages_per_step(P: int, KV: int, ps: int, hd: int,
+                           itemsize: int) -> int:
+    """Pages a chunk: DECODE_TOKENS_PER_STEP tokens' worth, at most the
+    table's width, halved while K and V of a chunk (two slots each in the
+    pools' type, one float32 upcast each) pass _DECODE_VMEM_BYTES: 16 a
+    chunk at KV 1, 8 at KV 4, 4 at KV 8 for bf16 pages of 64 x 128."""
+    G = max(1, min(P, DECODE_TOKENS_PER_STEP // ps))
+    while G > 1 and KV * G * ps * hd * (4 * itemsize + 8) > _DECODE_VMEM_BYTES:
+        G //= 2
+    return G
+
+
+def _reset_row(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _attend(q, k, v, pos0, lower, length, scale: float,
+            softcap: float | None, m_ref, l_ref, acc_ref):
+    """One online-softmax update of a row's running (m, l, acc) by T
+    cached positions pos0 .. pos0 + T - 1, of which [lower, length) are
+    visible. q: [KV, group, hd], k / v: [KV, T, hd], all float32."""
+    KV, group, hd = q.shape
+    H = KV * group
+    # batched over the shared leading KV axis (MXU, no transposes)
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * scale    # [KV, group, T]
+    if softcap:  # Gemma-2 score softcap — BEFORE masking
+        s = softcap * jnp.tanh(s / softcap)
+    pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    valid = jnp.logical_and(pos >= lower, pos < length)
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_prev = m_ref[:, :1].reshape(KV, group, 1)
+    l_prev = l_ref[:, :1].reshape(KV, group, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)                    # [KV, group, 1]
+    # exp only where valid: an all-masked block (possible when the
+    # sliding window empties the pool view) would otherwise compute
+    # exp(NEG_INF - NEG_INF) = 1 and corrupt the running sum
+    p_exp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_new = alpha * l_prev + jnp.sum(p_exp, axis=2, keepdims=True)
+    pv = jax.lax.dot_general(
+        p_exp, v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)            # [KV, group, hd]
+    acc_ref[...] = acc_ref[...] * alpha.reshape(H, 1) + pv.reshape(H, hd)
+    m_ref[...] = jnp.broadcast_to(m_new.reshape(H, 1), m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new.reshape(H, 1), l_ref.shape)
+
+
+def _write_row(o_ref, stats_out, m_ref, l_ref, acc_ref):
+    l = jnp.maximum(l_ref[:, :1], 1e-9)  # length-0 (padding) rows → 0
+    o_ref[...] = (acc_ref[...] / l).reshape(o_ref.shape).astype(o_ref.dtype)
+    if stats_out:
+        stats_out[0][...] = m_ref[...]
+        stats_out[1][...] = l_ref[...]
+
+
+def _decode_kernel(ps: int, G: int, P: int, scale: float,
                    softcap: float | None,
-                   # scalar prefetch (leading extras ignored: the layered
-                   # variant prefetches the layer index first)
-                   pt_ref, len_ref, lo_ref,
-                   # blocks (leading dims squeezed by BlockSpec None-dims)
-                   q_ref, k_ref, v_ref, o_ref, *rest):
-    if return_stats:
-        m_out, l_out, m_ref, l_ref, acc_ref = rest
-    else:
-        m_ref, l_ref, acc_ref = rest
+                   # scalar prefetch
+                   layer_ref, pt_ref, len_ref, lo_ref,
+                   # q: one row's block; the pools: whole, in HBM
+                   q_ref, k_hbm, v_hbm, o_ref, *rest):
+    """One grid step = one ROW: a loop over the chunks of G pages that
+    hold its positions [lower, length), each chunk's pages copied
+    HBM->VMEM by G async copies a pool into one [KV, G * ps, hd] buffer
+    (two slots: chunk j + 1, or the next row's first chunk, is in flight
+    while chunk j computes), then ONE online-softmax update over the
+    chunk's G * ps positions. A page past the row's end is not copied
+    (its stale slot is masked), so time follows the pages rows own."""
+    *stats_out, kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref = rest
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    layer = layer_ref[0]
+
+    def span(r):
+        # the row's pages [first, end) and its chunks, counted from first
+        # (lax.div: nothing here is negative, and `//` on traced ints
+        # lowers through a sign helper that costs more to trace than
+        # the rest of the kernel)
+        first = jax.lax.div(lo_ref[r], ps)
+        end = jnp.minimum(jax.lax.div(len_ref[r] + (ps - 1), ps), P)
+        return first, end, jax.lax.div(
+            jnp.maximum(end - first, 0) + (G - 1), G)
+
+    def copies(r, j, slot, do: str):
+        # chunk j of row r: a copy a pool for each page it has (a loop,
+        # not G unrolled copies: the program stays small); "start" and
+        # "wait" build the same descriptors
+        first, end, _ = span(r)
+        p0 = first + j * G
+
+        def page(g, carry):
+            at = pl.ds(pl.multiple_of(g * ps, ps), ps)
+            for i, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                getattr(pltpu.make_async_copy(
+                    pool.at[layer, pt_ref[r, p0 + g]],
+                    buf.at[slot, :, at, :], sems.at[i, slot]), do)()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(end - p0, 0, G), page, 0)
+
+    first, _, n = span(b)
+
+    @pl.when(b == 0)
+    def _():
+        # a page that is not copied leaves its slot as it was: masked
+        # scores may be anything, but 0 * v must not be NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+
+    base = slot_ref[0]  # the slot the row's first chunk is copied to
+
+    # the row before starts this row's first chunk beside its own last;
+    # the first row, and one after a row with nothing to read, start it
+    @pl.when(jnp.logical_or(b == 0, span(jnp.maximum(b - 1, 0))[2] == 0))
+    def _():
+        copies(b, 0, base, "start")
+
+    _reset_row(m_ref, l_ref, acc_ref)
+    q = q_ref[...].astype(jnp.float32)
+
+    def chunk(j, carry):
+        slot = (base + j) & 1
+        more = j + 1 < n
+
+        # in flight while this chunk computes: the row's next chunk, or
+        # after its last the next row's first
+        @pl.when(jnp.logical_or(more, b + 1 < B))
+        def _():
+            copies(jnp.where(more, b, b + 1), jnp.where(more, j + 1, 0),
+                   1 - slot, "start")
+
+        copies(b, j, slot, "wait")
+        _attend(q, kbuf[slot].astype(jnp.float32),
+                vbuf[slot].astype(jnp.float32), (first + j * G) * ps,
+                lo_ref[b], len_ref[b], scale, softcap, m_ref, l_ref, acc_ref)
+        return carry
+
+    jax.lax.fori_loop(0, n, chunk, 0)
+    slot_ref[0] = (base + n) & 1
+    _write_row(o_ref, stats_out, m_ref, l_ref, acc_ref)
+
+
+def _decode_kernel_narrow(ps: int, scale: float, softcap: float | None,
+                          layer_ref, pt_ref, len_ref, lo_ref,
+                          q_ref, k_ref, v_ref, o_ref, *rest):
+    """Head dims that are no multiple of 128 lanes (64: run.py --model
+    1b): the chip's compiler refuses every slice of such a pool in HBM
+    ("Slice shape along dimension 4 must be aligned to tiling (128)"), so
+    no copy of _decode_kernel's can be written. Here the pipeline brings
+    the pages: grid (B, P), one page a step as a block whose index map
+    reads the table, steps outside [lower, length) skipped."""
+    del layer_ref, pt_ref
+    *stats_out, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
     p = pl.program_id(1)
-    KV, group, hd = q_ref.shape
-    H = KV * group
 
     @pl.when(p == 0)
     def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _reset_row(m_ref, l_ref, acc_ref)
 
-    length = len_ref[b]
-    lower = lo_ref[b]  # first visible position (sliding window); else 0
-
-    # pages wholly outside [lower, length): no compute (and the index map
-    # re-points them at an already-fetched page, so no HBM traffic)
-    @pl.when(jnp.logical_and(p * ps < length, (p + 1) * ps > lower))
+    @pl.when(jnp.logical_and(p * ps < len_ref[b], (p + 1) * ps > lo_ref[b]))
     def _():
-        q = q_ref[...].astype(jnp.float32)            # [KV, group, hd]
-        k = k_ref[...].astype(jnp.float32)            # [KV, ps, hd]
-        v = v_ref[...].astype(jnp.float32)
-
-        # batched over the shared leading KV axis (MXU, no transposes)
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [KV, group, ps]
-        if softcap:  # Gemma-2 score softcap — BEFORE masking
-            s = softcap * jnp.tanh(s / softcap)
-        pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        valid = jnp.logical_and(pos >= lower, pos < length)
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[:, :1].reshape(KV, group, 1)
-        l_prev = l_ref[:, :1].reshape(KV, group, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)                # [KV, group, 1]
-        # exp only where valid: an all-masked page (possible when the
-        # sliding window empties the pool view) would otherwise compute
-        # exp(NEG_INF - NEG_INF) = 1 and corrupt the running sum
-        p_exp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_prev + jnp.sum(p_exp, axis=2, keepdims=True)
-        pv = jax.lax.dot_general(
-            p_exp, v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)        # [KV, group, hd]
-        acc_ref[...] = acc_ref[...] * alpha.reshape(H, 1) + pv.reshape(H, hd)
-        m_ref[...] = jnp.broadcast_to(m_new.reshape(H, 1), m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new.reshape(H, 1), l_ref.shape)
+        _attend(q_ref[...].astype(jnp.float32),
+                k_ref[...].astype(jnp.float32),
+                v_ref[...].astype(jnp.float32), p * ps, lo_ref[b],
+                len_ref[b], scale, softcap, m_ref, l_ref, acc_ref)
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _():
-        l = jnp.maximum(l_ref[:, :1], 1e-9)  # length-0 (padding) rows → 0
-        o_ref[...] = (acc_ref[...] / l).reshape(KV, group, hd).astype(
-            o_ref.dtype)
-        if return_stats:
-            m_out[...] = m_ref[...]
-            l_out[...] = l_ref[...]
-
-
-def _decode_kernel_layered(ps: int, scale: float, return_stats: bool,
-                           softcap: float | None,
-                           l_ref, pt_ref, len_ref, lo_ref, *refs):
-    # layered variant: the layer index rides as the first scalar-prefetch
-    # operand (consumed by the BlockSpec index maps); the body is identical
-    del l_ref
-    return _decode_kernel(ps, scale, return_stats, softcap,
-                          pt_ref, len_ref, lo_ref, *refs)
+        _write_row(o_ref, stats_out, m_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit,
@@ -207,7 +331,7 @@ def paged_attention_decode_sharded(q: jax.Array, k_pools: jax.Array,
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "interpret", "return_stats",
-                                    "softcap"))
+                                    "softcap", "pages_per_step"))
 def paged_attention_decode_layered(q: jax.Array, k_pools: jax.Array,
                                    v_pools: jax.Array, layer: jax.Array,
                                    page_table: jax.Array,
@@ -216,19 +340,27 @@ def paged_attention_decode_layered(q: jax.Array, k_pools: jax.Array,
                                    interpret: bool = False,
                                    return_stats: bool = False,
                                    softcap: float | None = None,
-                                   lower: jax.Array | None = None):
+                                   lower: jax.Array | None = None,
+                                   pages_per_step: int | None = None):
     """paged_attention_decode against ONE layer of the stacked pools.
 
     k_pools/v_pools: [L, num_pages, KV, ps, hd]; ``layer`` a traced int32
-    scalar. The layer rides as a scalar-prefetch operand consumed only by
-    the BlockSpec index maps, so the kernel streams pages of that layer
-    straight out of the stacked pool — no [num_pages, ...] layer slice is
-    ever materialized. That matters because XLA materializes `pool[l]`
+    scalar. The layer rides as a scalar-prefetch operand and the pools
+    stay in HBM: the kernel copies pages of that layer straight out of
+    the stacked pool — no [num_pages, ...] layer slice is ever
+    materialized. That matters because XLA materializes `pool[l]`
     (≈200 MB/layer at serving sizes) when it feeds a pallas_call, and a
     K-step fused decode window would pay that copy L·K times per window
     (measured: ~30 ms/step at B=32 — 4x the whole model's weight
     bandwidth); this variant makes the pool read O(live pages) as the
-    kernel intends."""
+    kernel intends.
+
+    ``pages_per_step`` (exactly that many, up to the table's width) is
+    the handle of the tests and of tools/paged_attn_timing.py; the model
+    code passes none and runs _decode_pages_per_step's rule by shape.
+    A head dim that is no multiple of 128 runs _decode_kernel_narrow
+    (the same arithmetic, a page a grid step; the handle means nothing
+    there)."""
     B, H, hd = q.shape
     L, _, KV, ps, _ = k_pools.shape
     P = page_table.shape[1]
@@ -239,46 +371,54 @@ def paged_attention_decode_layered(q: jax.Array, k_pools: jax.Array,
     if lower is None:
         lower = jnp.zeros_like(lengths)
 
-    def page_index(b, p, l, pt, ln, lo):
-        # pages outside [lower, length) re-point at the first NEEDED page
-        # (index unchanged between steps → Pallas skips the fetch)
-        needed = jnp.logical_and(p * ps < ln[b], (p + 1) * ps > lo[b])
-        first = jnp.minimum(lo[b] // ps, P - 1)
-        return (l[0], jnp.where(needed, pt[b, p], pt[b, first]),
-                0, 0, 0)
+    def row(b, *_):
+        return (b, 0, 0, 0)
 
     out_shape = [jax.ShapeDtypeStruct((B, KV, group, hd), q.dtype)]
-    out_specs = [pl.BlockSpec((None, KV, group, hd),
-                              lambda b, p, l, pt, ln, lo: (b, 0, 0, 0))]
+    out_specs = [pl.BlockSpec((None, KV, group, hd), row)]
     if return_stats:
-        out_shape += [jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
-                      jax.ShapeDtypeStruct((B, H, 128), jnp.float32)]
+        out_shape += [jax.ShapeDtypeStruct((B, H, 128), jnp.float32)] * 2
         out_specs += [pl.BlockSpec((None, H, 128),
-                                   lambda b, p, l, pt, ln, lo: (b, 0, 0))] * 2
+                                   lambda b, *_: (b, 0, 0))] * 2
+    running = [pltpu.VMEM((H, 128), jnp.float32),
+               pltpu.VMEM((H, 128), jnp.float32),
+               pltpu.VMEM((H, hd), jnp.float32)]
+    if hd % 128 == 0:
+        G = min(P, pages_per_step) if pages_per_step else \
+            _decode_pages_per_step(P, KV, ps, hd, k_pools.dtype.itemsize)
+        kernel = functools.partial(_decode_kernel, ps, G, P, scale, softcap)
+        grid = (B,)
+        # rows in order: a row's last chunk starts the next row's first
+        semantics = ("arbitrary",)
+        pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+        scratch = [pltpu.VMEM((2, KV, G * ps, hd), k_pools.dtype),
+                   pltpu.VMEM((2, KV, G * ps, hd), v_pools.dtype),
+                   pltpu.SemaphoreType.DMA((2, 2)),
+                   pltpu.SMEM((1,), jnp.int32)] + running
+    else:
+        def page_index(b, p, l, pt, ln, lo):
+            # pages outside [lower, length) re-point at the first NEEDED
+            # page (index unchanged between steps: no fetch)
+            needed = jnp.logical_and(p * ps < ln[b], (p + 1) * ps > lo[b])
+            first = jnp.minimum(lo[b] // ps, P - 1)
+            return (l[0], jnp.where(needed, pt[b, p], pt[b, first]),
+                    0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, P),
-        in_specs=[
-            pl.BlockSpec((None, KV, group, hd),
-                         lambda b, p, l, pt, ln, lo: (b, 0, 0, 0)),
-            pl.BlockSpec((None, None, KV, ps, hd), page_index),
-            pl.BlockSpec((None, None, KV, ps, hd), page_index),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, hd), jnp.float32),
-        ],
-    )
+        kernel = functools.partial(_decode_kernel_narrow, ps, scale, softcap)
+        grid = (B, P)
+        semantics = ("parallel", "arbitrary")
+        pool_spec = pl.BlockSpec((None, None, KV, ps, hd), page_index)
+        scratch = running
+
     res = pl.pallas_call(
-        functools.partial(_decode_kernel_layered, ps, scale, return_stats,
-                          softcap),
-        grid_spec=grid_spec,
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=grid,
+            in_specs=[pl.BlockSpec((None, KV, group, hd), row),
+                      pool_spec, pool_spec],
+            out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         # the name a device trace shows the kernel under: what
         # benchmark/harness/trace.py DECODE_KERNEL_OP matches
@@ -396,10 +536,11 @@ def latent_attention_layered(q_lat: jax.Array, q_rope: jax.Array,
     Grid (B, M / block_rows, ceil(P / G)): a step takes G =
     ``pages_per_step`` pages of the row (default: LATENT_TOKENS_PER_STEP
     tokens' worth), each pool passed G times with an index map of its
-    own, so a page does not cost a grid step (the GQA kernel at one KV
-    head ran 4,096 steps a layer and step and reached 5% of its
-    roofline: PERF.md, cell 4). Steps past a row's context re-point at
-    the blocks its last live step fetched and are skipped.
+    own, so a page does not cost a grid step. Steps past a row's context
+    re-point at the blocks its last live step fetched and are skipped;
+    they still cost their operands' bookkeeping (the GQA kernel above
+    loops over a row's own pages inside one grid step instead, and
+    copies them itself: PERF.md, Findings PR 32).
 
     What a step costs is mostly its OPERANDS: about 0.1 us each for the
     pipeline's bookkeeping, 2 a page, whatever the page's size, where

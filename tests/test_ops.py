@@ -7,7 +7,8 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.models.llama import _paged_attention
-from dynamo_tpu.ops.paged_attention import paged_attention_decode
+from dynamo_tpu.ops.paged_attention import (paged_attention_decode,
+                                            paged_attention_decode_layered)
 
 
 def _random_pages(key, num_pages, ps, KV, hd, dtype=jnp.float32):
@@ -17,29 +18,53 @@ def _random_pages(key, num_pages, ps, KV, hd, dtype=jnp.float32):
             jax.random.normal(k2, shape, dtype))
 
 
-@pytest.mark.parametrize("group,hd,ps", [(4, 64, 8), (1, 32, 16)])
-def test_decode_kernel_matches_gather(group, hd, ps):
-    KV = 2
+# pages a chunk of the decode kernel's row loop: the module's own rule,
+# one page, one that divides the tables below (P 4, 2), one that does
+# not, one larger than the table
+PAGES_PER_STEP = [None, 1, 2, 3, 8]
+
+
+def _decode(q, k_pages, v_pages, table, lengths, pages_per_step, **kw):
+    """The decode kernel in interpret mode: the 4-D wrapper at the
+    module's own rule, the layered entry where a test picks the pages."""
+    if pages_per_step is None:
+        return paged_attention_decode(q, k_pages, v_pages, table, lengths,
+                                      interpret=True, **kw)
+    return paged_attention_decode_layered(
+        q, k_pages[None], v_pages[None], jnp.int32(0), table, lengths,
+        interpret=True, pages_per_step=pages_per_step, **kw)
+
+
+def _tables(rng, lengths, P, ps, num_pages):
+    table = np.zeros((len(lengths), P), np.int32)
+    for b, n in enumerate(lengths):
+        npages = -(-int(n) // ps)
+        table[b, :npages] = rng.choice(
+            np.arange(1, num_pages), npages, replace=False)
+    return table
+
+
+@pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
+@pytest.mark.parametrize("KV,group,hd,ps", [(2, 4, 64, 8), (2, 1, 32, 16),
+                                            (2, 4, 128, 8), (4, 1, 128, 16),
+                                            (1, 20, 128, 128)])
+def test_decode_kernel_matches_gather(KV, group, hd, ps, pages_per_step):
     H = KV * group
-    B, P, num_pages = 5, 4, 32
+    B, P, num_pages = 6, 4, 32
     key = jax.random.PRNGKey(0)
     kq, kp, kt = jax.random.split(key, 3)
     q = jax.random.normal(kq, (B, H, hd), jnp.float32)
     k_pages, v_pages = _random_pages(kp, num_pages, ps, KV, hd)
 
-    # distinct random page tables + varied lengths (incl. exact page fill)
-    rng = np.random.RandomState(3)
-    table = np.zeros((B, P), np.int32)
-    lengths = np.array([1, ps, ps + 3, 2 * ps, P * ps], np.int32)
-    for b in range(B):
-        npages = -(-int(lengths[b]) // ps)
-        table[b, :npages] = rng.choice(
-            np.arange(1, num_pages), npages, replace=False)
+    # distinct random page tables + varied lengths: inside a page, ending
+    # exactly on a page (ps, 3 ps), on a chunk of two pages (2 ps), on
+    # the table's end
+    lengths = np.array([1, ps, ps + 3, 2 * ps, P * ps, 3 * ps], np.int32)
+    table = _tables(np.random.RandomState(3), lengths, P, ps, num_pages)
 
     scale = hd ** -0.5
-    got = paged_attention_decode(q, k_pages, v_pages, jnp.asarray(table),
-                                 jnp.asarray(lengths), scale=scale,
-                                 interpret=True)
+    got = _decode(q, k_pages, v_pages, jnp.asarray(table),
+                  jnp.asarray(lengths), pages_per_step, scale=scale)
 
     # XLA gather path: q positions are length-1 (the just-written token)
     positions = jnp.asarray(lengths - 1)[:, None]
@@ -49,31 +74,43 @@ def test_decode_kernel_matches_gather(group, hd, ps):
                                rtol=2e-5, atol=2e-5)
 
 
-def test_decode_kernel_padding_rows_zero():
-    """length-0 rows (batch padding) must come out as zeros, not NaN."""
-    B, H, KV, hd, ps, P = 3, 4, 2, 32, 8, 2
+@pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
+@pytest.mark.parametrize("hd", [32, 128])
+def test_decode_kernel_padding_rows_zero(hd, pages_per_step):
+    """length-0 rows (batch padding) must come out as zeros, not NaN:
+    first, last, and between live rows (a live row's first chunk is
+    started by the row before it, whatever that row holds)."""
+    B, H, KV, ps, P = 6, 4, 2, 8, 2
     q = jnp.ones((B, H, hd), jnp.float32)
     k_pages, v_pages = _random_pages(jax.random.PRNGKey(1), 8, ps, KV, hd)
-    table = jnp.zeros((B, P), jnp.int32)
-    lengths = jnp.asarray([0, 5, 0], jnp.int32)
-    out = paged_attention_decode(q, k_pages, v_pages, table, lengths,
-                                 interpret=True)
+    table = jnp.asarray([[0, 0], [1, 0], [0, 0], [0, 0], [2, 3], [0, 0]],
+                        jnp.int32)
+    lengths = jnp.asarray([0, 5, 0, 0, 16, 0], jnp.int32)
+    out, m, l = _decode(q, k_pages, v_pages, table, lengths, pages_per_step,
+                        return_stats=True)
     out = np.asarray(out)
     assert np.isfinite(out).all()
-    np.testing.assert_array_equal(out[0], 0.0)
-    np.testing.assert_array_equal(out[2], 0.0)
-    assert np.abs(out[1]).sum() > 0
+    for b in (0, 2, 3, 5):
+        np.testing.assert_array_equal(out[b], 0.0)
+        np.testing.assert_array_equal(np.asarray(l)[b], 0.0)
+        np.testing.assert_array_equal(np.asarray(m)[b], np.float32(-1e30))
+    want = _paged_attention(q[:, None], k_pages, v_pages, table,
+                            (lengths - 1)[:, None], hd ** -0.5)[:, 0]
+    for b in (1, 4):
+        np.testing.assert_allclose(out[b], np.asarray(want)[b],
+                                   rtol=2e-5, atol=2e-5)
 
 
-def test_decode_kernel_bf16():
-    B, H, KV, hd, ps, P = 2, 8, 4, 64, 8, 2
+@pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
+@pytest.mark.parametrize("hd", [64, 128])
+def test_decode_kernel_bf16(hd, pages_per_step):
+    B, H, KV, ps, P = 2, 8, 4, 8, 2
     q = jax.random.normal(jax.random.PRNGKey(2), (B, H, hd), jnp.bfloat16)
     k_pages, v_pages = _random_pages(jax.random.PRNGKey(3), 8, ps, KV, hd,
                                      jnp.bfloat16)
     table = jnp.asarray([[1, 2], [3, 0]], jnp.int32)
     lengths = jnp.asarray([11, 8], jnp.int32)
-    got = paged_attention_decode(q, k_pages, v_pages, table, lengths,
-                                 interpret=True)
+    got = _decode(q, k_pages, v_pages, table, lengths, pages_per_step)
     assert got.dtype == jnp.bfloat16
     positions = (lengths - 1)[:, None]
     want = _paged_attention(q[:, None], k_pages, v_pages, table, positions,
@@ -81,6 +118,44 @@ def test_decode_kernel_bf16():
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                rtol=0.05, atol=0.05)
+
+
+@pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
+def test_decode_kernel_stats_merge_matches_xla(pages_per_step):
+    """(out, m, l) of the kernel at any pages a chunk, merged with the
+    in-flight window buffer as _pool_window_attention_pallas merges them,
+    is the XLA path's attention over pool + buffer: the statistics are
+    those of the WHOLE pool view, whatever chunks it was read in."""
+    from dynamo_tpu.models.llama import _pool_window_attention
+
+    B, H, KV, hd, ps, P, K, i = 4, 8, 4, 128, 8, 4, 4, 2
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    k_pool, v_pool = _random_pages(ks[0], 24, ps, KV, hd)
+    q = jax.random.normal(ks[1], (B, 1, H, hd), jnp.float32)
+    wk = jax.random.normal(ks[2], (B, K, KV, hd), jnp.float32)
+    wv = jax.random.normal(ks[3], (B, K, KV, hd), jnp.float32)
+    # mid-page, a chunk of two pages exactly, the whole table, empty pool
+    start = np.array([13, 16, 32, 0], np.int32)
+    table = jnp.asarray(_tables(np.random.RandomState(11), start, P, ps, 24))
+    scale = hd ** -0.5
+    out_p, m_p, l_p = (np.asarray(x, np.float64) for x in _decode(
+        q[:, 0], k_pool, v_pool, table, jnp.asarray(start), pages_per_step,
+        scale=scale, return_stats=True))
+    qg = np.asarray(q, np.float64).reshape(B, KV, H // KV, hd)
+    sw = np.einsum("bkgh,bwkh->bkgw", qg, np.asarray(wk, np.float64)) * scale
+    sw = np.where(np.arange(K) <= i, sw, -1e30)
+    m_w = sw.max(-1)
+    p_w = np.exp(sw - m_w[..., None])
+    out_w = np.einsum("bkgw,bwkh->bkgh", p_w, np.asarray(wv, np.float64))
+    m_p, l_p = m_p.reshape(B, KV, -1), l_p.reshape(B, KV, -1)
+    m_t = np.maximum(m_p, m_w)
+    a_p, a_w = np.exp(m_p - m_t) * l_p, np.exp(m_w - m_t)
+    got = ((out_p.reshape(B, KV, -1, hd) * a_p[..., None]
+            + out_w * a_w[..., None])
+           / (a_p + a_w * p_w.sum(-1))[..., None]).reshape(B, 1, H, hd)
+    want = _pool_window_attention(q, k_pool, v_pool, table,
+                                  jnp.asarray(start), wk, wv, i, scale)
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 def test_pool_window_merge_matches_xla():
@@ -181,13 +256,19 @@ def test_prefill_kernel_bf16():
                                rtol=2e-2, atol=2e-2)
 
 
-def test_decode_kernel_softcap_and_window_match_gather():
+@pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
+@pytest.mark.parametrize("hd,window", [(32, 6), (128, 6), (128, 20)])
+def test_decode_kernel_softcap_and_window_match_gather(hd, window,
+                                                       pages_per_step):
     """Gemma-2 semantics in the decode kernel: tanh score softcap and a
     per-row lower bound (sliding window) match the XLA path — including
-    the degenerate all-masked-page case the valid-mask guards."""
+    the degenerate all-masked-page case the valid-mask guards. At a
+    window of 6 the view of the longest row begins in its last page (at
+    two pages a chunk: a whole chunk slid past); at 20, inside its
+    second page."""
     from dynamo_tpu.models.llama import _paged_attention
 
-    KV, group, hd, ps = 2, 2, 32, 8
+    KV, group, ps = 2, 2, 8
     H = KV * group
     B, P, num_pages = 4, 4, 32
     key = jax.random.PRNGKey(7)
@@ -195,22 +276,16 @@ def test_decode_kernel_softcap_and_window_match_gather():
     q = jax.random.normal(kq, (B, H, hd), jnp.float32)
     k_pages, v_pages = _random_pages(kp, num_pages, ps, KV, hd)
 
-    rng = np.random.RandomState(7)
-    table = np.zeros((B, P), np.int32)
     lengths = np.array([ps + 3, 2 * ps, P * ps, 5], np.int32)
-    for b in range(B):
-        npages = -(-int(lengths[b]) // ps)
-        table[b, :npages] = rng.choice(
-            np.arange(1, num_pages), npages, replace=False)
+    table = _tables(np.random.RandomState(7), lengths, P, ps, num_pages)
 
     scale = hd ** -0.5
-    window, softcap = 6, 15.0
+    softcap = 15.0
     eff = np.full(B, window, np.int32)
     lower = np.clip(lengths - eff, 0, np.maximum(lengths - 1, 0))
-    got = paged_attention_decode(
-        q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(lengths),
-        scale=scale, interpret=True, softcap=softcap,
-        lower=jnp.asarray(lower))
+    got = _decode(q, k_pages, v_pages, jnp.asarray(table),
+                  jnp.asarray(lengths), pages_per_step, scale=scale,
+                  softcap=softcap, lower=jnp.asarray(lower))
 
     positions = jnp.asarray(lengths - 1)[:, None]
     want = _paged_attention(q[:, None], k_pages, v_pages,
@@ -219,6 +294,26 @@ def test_decode_kernel_softcap_and_window_match_gather():
                             is_sliding=True)[:, 0]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_decode_kernel_empty_window_view():
+    """A window that slid past the whole pool (lower == length, as the
+    fused window's later steps give it): zeros and (m, l) = (NEG_INF, 0),
+    whether the view ends inside a page or on one."""
+    KV, group, hd, ps, P = 2, 2, 128, 8, 4
+    q = jax.random.normal(jax.random.PRNGKey(5), (3, KV * group, hd))
+    k_pages, v_pages = _random_pages(jax.random.PRNGKey(6), 16, ps, KV, hd)
+    lengths = jnp.asarray([13, 16, 7], jnp.int32)
+    table = jnp.asarray(_tables(np.random.RandomState(5), [13, 16, 7], P,
+                                ps, 16))
+    out, m, l = _decode(q, k_pages, v_pages, table, lengths, 2,
+                        return_stats=True,
+                        lower=jnp.asarray([13, 16, 0], jnp.int32))
+    for b in (0, 1):
+        np.testing.assert_array_equal(np.asarray(out)[b], 0.0)
+        np.testing.assert_array_equal(np.asarray(l)[b], 0.0)
+        np.testing.assert_array_equal(np.asarray(m)[b], np.float32(-1e30))
+    assert np.abs(np.asarray(out)[2]).sum() > 0
 
 
 def test_prefill_kernel_softcap_and_window_match_gather():
@@ -286,3 +381,34 @@ def test_prefill_kernel_window_second_chunk_page_skip():
                             window=window, is_sliding=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 4, 6])
+def test_decode_kernel_copies_in_tpu_interpreter(pages_per_step):
+    """The kernel's own copies under the TPU interpreter: buffers start
+    as NaN and a copy's bytes arrive only when it is WAITED for, so a
+    chunk computed before its wait, a wait that names another slot, or a
+    stale page that leaks through the mask shows as a wrong row. Padding
+    rows first, between and last: each hands the next row's first chunk
+    on."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                   uninitialized_memory="nan")
+    rng = np.random.RandomState(0)
+    B, KV, group, hd, ps, P, N = 8, 2, 4, 128, 8, 6, 40
+    q = jnp.asarray(rng.randn(B, KV * group, hd), jnp.float32)
+    k = jnp.asarray(rng.randn(1, N, KV, ps, hd), jnp.float32)
+    v = jnp.asarray(rng.randn(1, N, KV, ps, hd), jnp.float32)
+    lengths = np.array([0, 5, 0, P * ps, 0, 0, 2 * ps, 3 * ps + 1], np.int32)
+    table = jnp.asarray(_tables(rng, lengths, P, ps, N))
+    got = paged_attention_decode_layered(
+        q, k, v, jnp.int32(0), table, jnp.asarray(lengths),
+        interpret=interp, pages_per_step=pages_per_step)
+    want = _paged_attention(q[:, None], k[0], v[0], table,
+                            jnp.asarray(lengths - 1)[:, None],
+                            hd ** -0.5)[:, 0]
+    live = lengths > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(got)[~live], 0.0)

@@ -108,6 +108,28 @@ def test_decode_kernel_compiles(one_chip, width, entry, stats, lower,
     assert _has_kernel(lowered.compile())
 
 
+@pytest.mark.parametrize("B,P,KV,group,ps", [
+    (32, 64, 8, 4, 64),      # mixtral-8x7b.chat-steady / .shared-prefix
+    (64, 32, 4, 8, 64),      # qwen3-30b-a3b.decode-heavy
+    (128, 32, 1, 20, 64),    # jamba2-3b.reason-decode
+    (64, 16, 4, 8, 128),     # pages of 128 (cell 5's page size)
+    (1, 64, 8, 4, 64),       # one row: the K = 1 decode step
+    (8, 1, 8, 4, 64),        # a one-page table
+], ids=["mixtral", "qwen3", "jamba", "ps128", "b1", "p1"])
+def test_decode_kernel_compiles_at_cell_shapes(one_chip, B, P, KV, group,
+                                               ps):
+    """The benchmark's decode shapes at the pages a chunk the rule by
+    shape gives them: a chunk's buffers and its float32 upcasts have to
+    fit the chip's scoped VMEM at every one."""
+    s = partial(_sds, one_chip)
+    k = s((3, 768, KV, ps, 128), jnp.bfloat16)
+    lengths = s((B,), jnp.int32)
+    lowered = pa.paged_attention_decode_layered.lower(
+        s((B, KV * group, 128), jnp.bfloat16), k, k, s((), jnp.int32),
+        s((B, P), jnp.int32), lengths, return_stats=True, lower=lengths)
+    assert _has_kernel(lowered.compile())
+
+
 # ----------------------------------------------------------- prefill kernel
 
 
