@@ -278,6 +278,9 @@ class Sequence:
     tokens: List[int]            # prompt + generated (host truth)
     num_prompt: int
     pages: List[int] = field(default_factory=list)
+    # True from admission on a prefix hit until the row's first chunk is
+    # dispatched: that chunk starts from the last hit page's snapshot
+    state_from_page: bool = False
     # row of the recurrent-state pool, for a model that keeps state
     # beside its pages (claimed at admission, released with the pages)
     state_slot: Optional[int] = None
@@ -449,6 +452,12 @@ class JaxEngine:
             # None for every other module: their programs take no operand
             # for it and their call forms are unchanged.
             self.state = None
+            # True where the module also keeps the state at each page's
+            # end under the page's id (init_state_snapshots): the pool is
+            # the last member of self.state, a prefix hit hands over
+            # pages AND state, and the prefix cache stays on
+            self._state_snapshots = False
+            self.state_restores_total = 0
             self._state_free: List[int] = []
             # slots held and slots there, summed at every decode dispatch
             # (_count_decode_slots): the pool's fill over a window is a
@@ -460,6 +469,10 @@ class JaxEngine:
                 self.state = model.init_state(
                     model_cfg, self.ecfg.max_batch + 1, dtype)
                 self._state_free = list(range(self.ecfg.max_batch))[::-1]
+                if hasattr(model, "init_state_snapshots"):
+                    self._state_snapshots = True
+                    self.state = (*self.state, model.init_state_snapshots(
+                        model_cfg, spec, dtype))
         if mesh is not None:
             from ..parallel.mesh import shard_kv_cache, shard_params
             self.params = shard_params(self.params, model_cfg, mesh)
@@ -538,8 +551,10 @@ class JaxEngine:
                               self.ecfg.page_size,
                               host_pages=self.ecfg.host_pages,
                               evict_policy=self.ecfg.evict_policy,
-                              # a hit hands over pages and no state
-                              prefix_reuse=self.state is None)
+                              # a hit hands over pages, and state only
+                              # where the module snapshots it by the page
+                              prefix_reuse=(self.state is None
+                                            or self._state_snapshots))
         # host-DRAM offload pools (same per-page layout as the HBM pool)
         self.host_k = self.host_v = None
         self.host_k_s = self.host_v_s = None
@@ -706,10 +721,20 @@ class JaxEngine:
             return contextlib.nullcontext()
         return jax.default_device(self.device)
 
-    def _state_args(self, slots) -> tuple:
-        """The two trailing operands of a step program of a model with
-        recurrent state (the pool and the rows' slots); none otherwise."""
-        return () if self.state is None else (self.state, slots)
+    def _state_args(self, slots, src=None) -> tuple:
+        """The trailing operands of a step program of a model with
+        recurrent state: the pools and the rows' slots, and for a prefill
+        of a module that snapshots by the page (``src`` given) the page
+        whose snapshot each row starts from (-1: none). None otherwise."""
+        if self.state is None:
+            return ()
+        if src is None or not self._state_snapshots:
+            return (self.state, slots)
+        return (self.state, slots, src)
+
+    def _no_src(self, n: int) -> np.ndarray:
+        """``src`` of n rows that start from no page's snapshot."""
+        return np.full(n, -1, np.int32)
 
     def _take_state(self, out):
         """A step program's results without the state pool it returned
@@ -778,7 +803,8 @@ class JaxEngine:
                             jnp.zeros((PB, P), jnp.int32),
                             jnp.full((PB, T), DROP_SLOT, jnp.int32),
                             jnp.zeros((PB,), jnp.int32), pslots,
-                            *self._state_args(self._drop_slots(PB))))
+                            *self._state_args(self._drop_slots(PB),
+                                              self._no_src(PB))))
                     # penalties=None EXPLICITLY: the jit cache keys on the
                     # call's (args, kwargs) treedef, so an explicit-None
                     # kwarg and an omitted default are DIFFERENT entries —
@@ -1215,6 +1241,10 @@ class JaxEngine:
                 "state_slots_active": total - len(self._state_free),
                 "state_slots_held_total": self.state_slots_held_total,
                 "state_slots_seen_total": self.state_slots_seen_total,
+                # rows whose first chunk started from a page's snapshot
+                # (0 for ever where the module keeps none)
+                "state_restores_total": self.state_restores_total,
+                # the pool by slot and, where there is one, by page
                 "state_pool_bytes": int(sum(x.nbytes for x in self.state))}
 
     def _windowed_hit_rate(self) -> float:
@@ -1453,6 +1483,13 @@ class JaxEngine:
                 # until no window in flight lists it); wait for frees
                 break
             chain = self._chain(seq)
+            if self._state_snapshots:
+                # a hit must leave a token to prefill: the state comes
+                # back in the row's first chunk, from the last hit page's
+                # snapshot (a resumed row's extent is one token short of
+                # its tokens, so PageManager's own cap is one page long)
+                chain = chain[:max(seq.prefill_extent - 1, 0)
+                              // self.ecfg.page_size]
             with self._pm_lock:
                 alloc = self.pm.allocate_sequence(seq.tokens, chain=chain)
                 if (alloc is None
@@ -1468,11 +1505,13 @@ class JaxEngine:
             self.waiting.pop(0)
             pages, cached_tokens = alloc
             seq.pages = pages
-            if self.state is not None:
-                # whatever the slot holds is dropped by the prefill chunk
-                # that starts at position 0 (computed is 0 here: no hit)
-                seq.state_slot = self._state_free.pop()
             seq.computed = min(cached_tokens, seq.prefill_extent)
+            if self.state is not None:
+                # whatever the slot holds is dropped by the row's first
+                # chunk: zeros where it starts at position 0, the last
+                # hit page's snapshot after a hit (_dispatch_prefill)
+                seq.state_slot = self._state_free.pop()
+                seq.state_from_page = seq.computed > 0
             if alloc.restores:
                 # restore_wait stops when the sequence clears the
                 # _unrestored_pages gate in _dispatch_prefill
@@ -1761,10 +1800,17 @@ class JaxEngine:
         slots = np.full((B, T), DROP_SLOT, np.int32)
         pslots = np.full((B, max(T // ps, 1)), self.ecfg.num_pages, np.int32)
         sslots = self._drop_slots(B)
+        ssrc = self._no_src(B)
         for i, (seq, chunk) in enumerate(zip(batch, chunks)):
             if sslots is not None:
                 sslots[i] = seq.state_slot
             start = seq.computed
+            if seq.state_from_page:
+                # the first chunk after a hit (whole pages, so start is
+                # a page's first token): the state after the page before
+                ssrc[i] = seq.pages[start // ps - 1]
+                seq.state_from_page = False
+                self.state_restores_total += 1
             tokens[i, :chunk] = seq.tokens[start:start + chunk]
             positions[i, :chunk] = np.arange(start, start + chunk)
             pages = np.asarray(seq.pages, np.int64)
@@ -1785,7 +1831,7 @@ class JaxEngine:
             self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots),
             jnp.asarray(last_idx),
             jnp.asarray(pslots) if use_paged else None,
-            *self._state_args(sslots)))
+            *self._state_args(sslots, ssrc)))
         self._account_dispatch(batch)
         self.steps += 1
         self.prefill_slots_total += B * T
@@ -3093,8 +3139,8 @@ def _refuse_recurrent_state(ecfg: EngineConfig, mesh) -> None:
     if mesh is not None and mesh.size > 1:
         raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
             what="a mesh of more than one device",
-            why="no sharding rule places the state pool or the Mamba "
-            "leaves"))
+            why="no sharding rule places the state pools or the leaves "
+            "of the layers that keep state"))
 
 
 def _span_ms(start: Optional[float], end: Optional[float]

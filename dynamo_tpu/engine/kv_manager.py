@@ -46,8 +46,9 @@ EVICT_POLICIES = ("lru", "cost")
 
 RECURRENT_STATE_REFUSAL = (
     "{what} is not supported for a model with recurrent state "
-    "(models/jamba.py): {why}; nothing snapshots or moves the state pool "
-    "yet (ROADMAP B7)")
+    "(models/jamba.py, models/lfm2.py): {why}; a state snapshot lives in "
+    "the device pool under its page's id, or not at all, and nothing "
+    "moves or rolls back a state (ROADMAP B7)")
 
 
 def refuse_recurrent_state(engine, what: str) -> None:
@@ -174,10 +175,10 @@ class PageManager:
         self.page_size = page_size
         self.evict_policy = evict_policy
         # False for a model whose sequences carry recurrent state beside
-        # their pages (the engine sets it from the model module): a
-        # prefix hit would hand over pages and no state, so no page is
-        # ever published or matched and every hit counts as a miss.
-        # Until state snapshots exist; not a knob.
+        # their pages and whose module cannot snapshot it by the page
+        # (the engine sets it from the model module): a prefix hit would
+        # hand over pages and no state, so no page is ever published or
+        # matched and every hit counts as a miss. Not a knob.
         self.prefix_reuse = prefix_reuse
         # every pool structure below is event-loop-affine: all methods
         # are sync (each call is one atomic block under the loop), and

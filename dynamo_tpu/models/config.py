@@ -55,6 +55,9 @@ class ModelConfig:
     n_group: int = 0               # 0 = no group-limited routing
     topk_group: int = 0
     norm_topk_prob: bool = False
+    # what the renormalisation of the chosen sigmoid scores adds to their
+    # sum (DeepSeek-V3's 1e-20; LFM2's 1e-6)
+    moe_renorm_eps: float = 1e-20
     # real DeepSeek checkpoints store rope dims INTERLEAVED (pairs
     # (2i, 2i+1)); the loader permutes those weight columns to our
     # split-half rope convention (scores are permutation-invariant)
@@ -84,6 +87,15 @@ class ModelConfig:
     mamba_dt_rank: int = 0
     attn_layer_period: int = 0
     attn_layer_offset: int = 0
+    # LFM2 (model_type "lfm2_moe", models/lfm2.py): each layer's operator
+    # by name, "conv" (a gated short convolution of conv_l_cache taps,
+    # whose per-sequence state is the last conv_l_cache - 1 gated inputs)
+    # or "full_attention"; the first num_dense_layers layers have a dense
+    # MLP, the others routed experts. A non-empty layer_types switches the
+    # model module.
+    layer_types: tuple = ()
+    conv_l_cache: int = 3
+    num_dense_layers: int = 0
     dtype: str = "bfloat16"
 
     @property
@@ -92,7 +104,7 @@ class ModelConfig:
 
     @property
     def has_recurrent_state(self) -> bool:
-        return self.mamba_d_state > 0
+        return self.mamba_d_state > 0 or "conv" in self.layer_types
 
     @property
     def mamba_d_inner(self) -> int:
@@ -102,6 +114,9 @@ class ModelConfig:
     def attn_layer_ids(self) -> tuple:
         """Layers whose mixer is attention, for a model whose layers are
         of two kinds (every layer attends otherwise)."""
+        if self.layer_types:
+            return tuple(l for l, kind in enumerate(self.layer_types)
+                         if kind == "full_attention")
         if not self.has_recurrent_state:
             return tuple(range(self.num_layers))
         return tuple(l for l in range(self.num_layers)
@@ -228,6 +243,46 @@ class ModelConfig:
             c.attn_layer_period = cfg.get("attn_layer_period", 8)
             c.attn_layer_offset = cfg.get("attn_layer_offset", 4)
             c.rms_norm_eps = cfg.get("rms_norm_eps", 1e-6)
+        if mt == "lfm2_moe":
+            kinds = tuple(cfg["layer_types"][:cfg["num_hidden_layers"]])
+            odd = sorted(set(kinds) - {"conv", "full_attention"})
+            if odd or len(kinds) != cfg["num_hidden_layers"]:
+                raise NotImplementedError(
+                    "lfm2_moe: layer_types must name num_hidden_layers "
+                    "layers, each conv or full_attention (got "
+                    f"{odd or len(kinds)})")
+            if cfg.get("conv_bias"):
+                raise NotImplementedError(
+                    "lfm2_moe with conv_bias true is not supported (the "
+                    "short convolution is computed without a bias)")
+            if not (cfg.get("use_expert_bias", True)
+                    and cfg.get("norm_topk_prob", True)):
+                raise NotImplementedError(
+                    "lfm2_moe without use_expert_bias or norm_topk_prob "
+                    "is not supported (the gate selects by score + bias "
+                    "and renormalises the chosen scores)")
+            rope = cfg.get("rope_parameters") or {}
+            c.model_type = "lfm2_moe"
+            # a cut in depth keeps the published list whole: the first
+            # num_hidden_layers entries are the layers that run
+            c.layer_types = kinds
+            c.conv_l_cache = cfg.get("conv_L_cache", 3)
+            c.num_dense_layers = cfg.get("num_dense_layers", 0)
+            c.rms_norm_eps = cfg.get("norm_eps", 1e-5)
+            c.rope_theta = rope.get("rope_theta",
+                                    cfg.get("rope_theta", 1000000.0))
+            c.qk_norm = True
+            c.num_experts = cfg.get("num_experts", 0)
+            c.num_experts_per_tok = cfg.get("num_experts_per_tok", 4)
+            c.moe_intermediate_size = cfg.get("moe_intermediate_size")
+            # sigmoid scores, selection by score + bias, the unbiased
+            # scores of the chosen renormalised: DeepSeek-V3's gate
+            # without groups (models/mla.py _deepseek_gate)
+            c.moe_router = "deepseek_v3"
+            c.norm_topk_prob = True
+            c.moe_renorm_eps = 1e-6
+            c.routed_scaling_factor = cfg.get("routed_scaling_factor", 1.0)
+            c.tie_word_embeddings = cfg.get("tie_word_embeddings", True)
         if mt in ("gemma", "gemma2"):
             # Gemma rides the Llama GQA stack with four semantic switches
             c.model_type = "gemma"

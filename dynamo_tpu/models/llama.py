@@ -685,50 +685,67 @@ def _moe_use_blocked(mesh, n_tokens: int, n_experts: int,
             and (mesh is None or mesh.size == 1))
 
 
-def _moe_mlp(h: jax.Array, w_router, w_gate, w_up, w_down,
-             top_k: int, mesh=None, live=None, layer=None) -> jax.Array:
-    """Mixtral-style MoE MLP: token-choice top-k routing.
-
-    Two execution strategies, chosen at trace time (shapes are static
-    under jit) by ``_moe_use_blocked``:
-    - ``moe_experts_blocked`` sorted dispatch — work follows the live
-      (token, expert) pairs; ``live`` [B, T] bool marks the rows that
-      are not padding (None = all), and with ``layer`` the w_* are the
-      whole [L, E, ...] parameters, read in place (``forward``).
-    - dense einsum over ALL experts weighted by the routing mask —
+def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
+                blocked: bool, live=None, layer=None,
+                out_dtype=jnp.float32) -> jax.Array:
+    """The routed experts' MLPs on x [B, T, D], given a gate's output
+    (weights, idx: [B, T, k]): the execution half of an MoE MLP, shared
+    by every gate (the softmax top-k of ``_moe_mlp``, the sigmoid gate of
+    mla.py, which lfm2.py uses too). Two forms; the CALLER picks
+    (``_moe_use_blocked`` holds the rule by shape):
+    - ``blocked``: ``moe_experts_blocked`` sorted dispatch: work follows
+      the live (token, expert) pairs; ``live`` [B, T] bool marks the
+      rows that are not padding (None = all), and with ``layer`` the w_*
+      are the whole [L, E, ...] parameters, read in place.
+    - dense einsum over ALL experts weighted by the routing mask:
       decode-sized dispatches (one read of the weights bounds both
       forms) and expert-parallel meshes (GSPMD shards the E axis of the
       einsum; the sorted form's dynamic expert indexing would
       all-gather).
-    """
-    B, T, D = h.shape
+    float32 operands and accumulation in both; the result in
+    ``out_dtype``."""
+    B, T, D = x.shape
+    E = w_gate.shape[-3]
+    k = idx.shape[-1]
+    if blocked:
+        out = moe_experts_blocked(
+            x.reshape(B * T, D).astype(jnp.float32),
+            weights.reshape(B * T, k), idx.reshape(B * T, k),
+            w_gate, w_up, w_down, moe_block(B * T, k, w_gate.shape),
+            live=None if live is None else live.reshape(B * T),
+            layer=layer)
+        return out.reshape(B, T, D).astype(out_dtype)
+    with jax.named_scope("moe.router"):
+        full_gate = jnp.sum(
+            jax.nn.one_hot(idx, E, dtype=jnp.float32) * weights[..., None],
+            axis=2)
+    # dense-over-experts: out = sum_e gate[...,e] * mlp_e(x)
+    with jax.named_scope("moe.experts"):
+        ge = jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
+                        w_gate.astype(jnp.float32))
+        up = jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
+                        w_up.astype(jnp.float32))
+        act = jax.nn.silu(ge) * up
+        down = jnp.einsum("btei,eid->bted", act, w_down.astype(jnp.float32))
+        out = jnp.einsum("bted,bte->btd", down, full_gate)
+        return out.astype(out_dtype)
+
+
+def _moe_mlp(h: jax.Array, w_router, w_gate, w_up, w_down,
+             top_k: int, mesh=None, live=None, layer=None) -> jax.Array:
+    """Mixtral-style MoE MLP: token-choice top-k routing, softmax over
+    the chosen logits, then ``moe_experts`` in the form the shape rule
+    picks (sorted wherever ``layer`` says the parameters are whole)."""
+    B, T, _ = h.shape
     E = w_gate.shape[-3]
     with jax.named_scope("moe.router"):
         logits = (h @ w_router).astype(jnp.float32)  # [B, T, E]
         weights, idx = lax.top_k(logits, top_k)  # [B, T, k]
         weights = jax.nn.softmax(weights, axis=-1)
-    if layer is not None or _moe_use_blocked(mesh, B * T, E, top_k):
-        out = moe_experts_blocked(
-            h.reshape(B * T, D).astype(jnp.float32),
-            weights.reshape(B * T, top_k), idx.reshape(B * T, top_k),
-            w_gate, w_up, w_down, moe_block(B * T, top_k, w_gate.shape),
-            live=None if live is None else live.reshape(B * T),
-            layer=layer)
-        return out.reshape(B, T, D).astype(h.dtype)
-    with jax.named_scope("moe.router"):
-        full_gate = jnp.sum(
-            jax.nn.one_hot(idx, E, dtype=jnp.float32) * weights[..., None],
-            axis=2)
-    # dense-over-experts: out = sum_e gate[...,e] * mlp_e(h)
-    with jax.named_scope("moe.experts"):
-        ge = jnp.einsum("btd,edi->btei", h.astype(jnp.float32),
-                        w_gate.astype(jnp.float32))
-        up = jnp.einsum("btd,edi->btei", h.astype(jnp.float32),
-                        w_up.astype(jnp.float32))
-        act = jax.nn.silu(ge) * up
-        down = jnp.einsum("btei,eid->bted", act, w_down.astype(jnp.float32))
-        out = jnp.einsum("bted,bte->btd", down, full_gate)
-        return out.astype(h.dtype)
+    return moe_experts(
+        h, weights, idx, w_gate, w_up, w_down,
+        layer is not None or _moe_use_blocked(mesh, B * T, E, top_k),
+        live=live, layer=layer, out_dtype=h.dtype)
 
 
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,

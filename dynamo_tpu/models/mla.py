@@ -226,16 +226,9 @@ def _deepseek_gate(x32, w_router, bias, cfg: ModelConfig):
     # v2: transformers' DeepseekV2MoEGate ignores norm_topk_prob (always
     # scales); configs setting it are rejected at ModelConfig load.
     if cfg.moe_router == "deepseek_v3" and cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.moe_renorm_eps)
     w = w * cfg.routed_scaling_factor
     return w, topi
-
-
-def _dense_gate(w, topi, E):
-    """(weights, indices) → dense [B, T, E] mask for the
-    dense-over-experts einsum path."""
-    return jnp.sum(jax.nn.one_hot(topi, E, dtype=jnp.float32)
-                   * w[..., None], axis=-2)
 
 
 def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
@@ -243,41 +236,21 @@ def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
     """Routed experts plus the always-on shared experts, on x [B, T, D].
 
     ``lp`` holds one layer's router, bias and shared-expert leaves. The
-    routed experts are either that layer's ``[E, ...]`` stacks (the dense
-    einsum over every expert: decode-sized dispatches, where one read of
-    the weights bounds both forms, and expert-parallel meshes) or, with
-    ``layer`` (a traced index into the expert segment), the whole
-    ``[Lm, E, ...]`` parameters read in place by the sorted blocked
-    dispatch, whose work follows the ``live`` (token, expert) pairs
-    (llama.moe_experts_blocked; _moe_use_blocked holds the rule)."""
-    from .llama import moe_block, moe_experts_blocked
-
-    B, T, D = x.shape
-    E = lp["w_gate_e"].shape[-3]
-    k = cfg.num_experts_per_tok
+    routed experts run through ``llama.moe_experts``, the execution
+    every gate shares: either that layer's ``[E, ...]`` stacks (the
+    dense einsum over every expert: decode-sized dispatches, where one
+    read of the weights bounds both forms, and expert-parallel meshes)
+    or, with ``layer`` (a traced index into the expert segment), the
+    whole ``[Lm, E, ...]`` parameters read in place by the sorted
+    blocked dispatch, whose work follows the ``live`` (token, expert)
+    pairs (_moe_use_blocked holds the rule; the callers apply it)."""
     x32 = x.astype(jnp.float32)
     with jax.named_scope("moe.router"):
         w, topi = _deepseek_gate(x32, lp["w_router"],
                                  lp.get("router_bias"), cfg)
-    if layer is not None:
-        out = moe_experts_blocked(
-            x32.reshape(B * T, D), w.reshape(B * T, k),
-            topi.reshape(B * T, k), lp["w_gate_e"], lp["w_up_e"],
-            lp["w_down_e"], moe_block(B * T, k, lp["w_gate_e"].shape),
-            live=None if live is None else live.reshape(B * T),
-            layer=layer).reshape(B, T, D)
-    else:
-        with jax.named_scope("moe.router"):
-            gate = _dense_gate(w, topi, E)
-        with jax.named_scope("moe.experts"):
-            ge = jnp.einsum("btd,edi->btei", x32,
-                            lp["w_gate_e"].astype(jnp.float32))
-            up = jnp.einsum("btd,edi->btei", x32,
-                            lp["w_up_e"].astype(jnp.float32))
-            act = jax.nn.silu(ge) * up
-            down = jnp.einsum("btei,eid->bted", act,
-                              lp["w_down_e"].astype(jnp.float32))
-            out = jnp.einsum("bted,bte->btd", down, gate)
+    out = llama.moe_experts(x32, w, topi, lp["w_gate_e"], lp["w_up_e"],
+                            lp["w_down_e"], layer is not None, live=live,
+                            layer=layer)
     if cfg.n_shared_experts > 0:
         with jax.named_scope("moe.shared"):
             out = out + (jax.nn.silu(x @ lp["w_gate_s"])

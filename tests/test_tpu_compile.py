@@ -506,6 +506,93 @@ def test_latent_programs_make_no_pool_sized_copy(one_chip, tpu_kernel_path,
     assert mem.temp_size_in_bytes < kv_k.size * kv_k.dtype.itemsize
 
 
+# ---- gated short convolutions + packed GQA at LFM2-24B-A2B's widths (cell 6)
+
+
+def _lfm2(one_chip, experts=8):
+    """The configuration as the cell runs it (every width as published),
+    the expert count cut so the compiles stay short; params, the two KV
+    pools and the state pools (by slot, by page) as shapes on the
+    described chip, at the cell's engine data."""
+    import dataclasses
+    import json
+
+    from dynamo_tpu.models import lfm2
+    from dynamo_tpu.models.config import ModelConfig
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "lfm2-24b-a2b.agent-loop.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = dataclasses.replace(ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", "lfm2-24b-a2b")), num_experts=experts)
+    spec = llama.KVCacheSpec(e["num_pages"], e["page_size"])
+    params = _on(one_chip, jax.eval_shape(
+        lambda: lfm2.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (_on(one_chip, x) for x in jax.eval_shape(
+        lambda: lfm2.init_kv_cache(cfg, spec)))
+    state = _on(one_chip, jax.eval_shape(lambda: (
+        *lfm2.init_state(cfg, e["max_batch"] + 1),
+        lfm2.init_state_snapshots(cfg, spec))))
+    # two KV heads of 64 side by side in 128 lanes; 48 KB of state a row
+    # and a page (6 conv layers x 2 x 2,048 bf16)
+    assert kv_k.shape == kv_v.shape == (2, e["num_pages"], 4,
+                                        e["page_size"], 128)
+    assert [x.shape for x in state] == [(e["max_batch"] + 1, 24576),
+                                        (e["num_pages"], 24576)]
+    return lfm2, cfg, params, kv_k, kv_v, state, e
+
+
+@pytest.mark.parametrize("program", ["window", "prefill"])
+def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
+                                                       tpu_kernel_path,
+                                                       monkeypatch, program):
+    """models/lfm2.py at the pool shapes of lfm2-24b-a2b.agent-loop
+    ([2, 4096, 4, 64, 128] K and V, [65, 24576] state by slot, [4096,
+    24576] snapshots by page): the fused window (B 64, P 64, the cell's
+    8 steps) and a prefill chunk (the cell's largest, PB 4 x T 512, whole
+    pages) take the snapshot pool
+    among their operands, write every pool along its major axis in
+    place, and hold no copy of a pool's size; all four pools alias their
+    inputs. (The token-row commit of an unaligned chunk and of the K=1
+    decode step, llama._scatter_pages, relayouts a pool here as it does
+    in llama.py: no cell runs it.) The published layout of the heads ([2, 4096, 8, 64, 64]) is
+    relayouted around every program: six pool-sized copies and 2 GiB of
+    temporaries a window (scratch compile, PR 33), and the decode
+    kernel's fast form cannot slice 64 lanes (PR 32): hence the packing,
+    which the window's kernel here confirms (tpu_custom_call, KV' 4)."""
+    lfm2, cfg, params, kv_k, kv_v, state, e = _lfm2(one_chip)
+    monkeypatch.setattr(lfm2, "_use_pallas", lambda: True)
+    s = partial(_sds, one_chip)
+    P = e["page_buckets"][-1]
+    if program == "window":
+        B = e["max_batch"]
+        i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+        compiled = lfm2.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, state, i32,
+            k_steps=e["decode_steps"], logprobs_topn=0).compile()
+        assert _has_kernel(compiled)
+    else:
+        prefill, _ = lfm2.make_step_fns(cfg)
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        compiled = prefill.lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
+            state, s((PB,), jnp.int32), s((PB,), jnp.int32)).compile()
+    smallest = min(kv_k.size, state[1].size)
+    assert _pool_sized_copies(compiled.as_text(), smallest) == []
+    mem = compiled.memory_analysis()
+    pools = sum(x.size * x.dtype.itemsize
+                for x in (kv_k, kv_v, *state))
+    assert mem.alias_size_in_bytes >= pools
+    if program == "window":
+        assert mem.temp_size_in_bytes < kv_k.size * kv_k.dtype.itemsize / 4
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)
+
+
 def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
     """The Pallas kernel's serialized module rides inside the
     tpu_custom_call's opaque config, source locations included: without
