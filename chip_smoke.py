@@ -135,8 +135,8 @@ def phase_device(s: Settings) -> dict:
 
 
 def phase_kernels(s: Settings) -> None:
-    """Decode kernel (plain, layered, with stats) and the opt-in flash
-    prefill kernel against the XLA gather path, at the model's widths."""
+    """Decode kernel (plain, layered, with stats) and the paged prefill
+    kernel against the XLA gather path, at the model's widths."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -195,19 +195,28 @@ def phase_kernels(s: Settings) -> None:
     errs["decode_stats_m"] = err(m, m_ref)
     errs["decode_stats_l_rel"] = float(jnp.max(jnp.abs(l - l_ref) / l_ref))
 
-    qp = jax.random.normal(kp, (Bp, T, H, hd), dt)
+    # the prefill kernel copies whole pages itself, which the chip's
+    # compiler accepts for heads of 128 lanes only (llama._attention
+    # sends a chunk over narrower heads, --model 1b's 64, to the XLA
+    # arm): checked at 128 where the model's heads are narrower
+    hdp = hd if hd % 128 == 0 or s.interpret else 128
+    kq, kk, kv_ = jax.random.split(kp, 3)
+    qp = jax.random.normal(kq, (Bp, T, H, hdp), dt)
+    kl = jax.random.normal(kk, (N, KV, ps, hdp), dt)
+    vl = jax.random.normal(kv_, (N, KV, ps, hdp), dt)
     starts = rng.randint(0, P * ps - T + 1, Bp)
     starts[0] = P * ps - T
     qpos = jnp.asarray(starts[:, None] + np.arange(T)[None, :], jnp.int32)
-    want_p = _paged_attention(qp, kl, vl, table[:Bp], qpos, scale)
+    want_p = _paged_attention(qp, kl, vl, table[:Bp], qpos, hdp ** -0.5)
     errs["prefill_flash"] = err(pa.paged_attention_prefill(
-        qp, kl, vl, table[:Bp], qpos, scale=scale, interpret=s.interpret),
-        want_p)
+        qp, kl, vl, table[:Bp], qpos, scale=hdp ** -0.5,
+        interpret=s.interpret), want_p)
     errs = {k: round(v, 5) for k, v in errs.items()}
     ok = all(np.isfinite(v) and v <= KERNEL_ATOL for v in errs.values())
     emit("kernels", ok=ok, atol=KERNEL_ATOL, max_abs_err=errs,
          shapes={"B": B, "H": H, "KV": KV, "hd": hd, "ps": ps, "P": P,
-                 "L": L, "prefill_T": T, "dtype": "bfloat16"})
+                 "L": L, "prefill_T": T, "prefill_hd": hdp,
+                 "dtype": "bfloat16"})
     check(ok, f"a kernel left the {KERNEL_ATOL} bound: {errs}")
 
 

@@ -366,12 +366,16 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                mesh=None, softcap: Optional[float] = None,
                window: Optional[int] = None,
                is_sliding=False) -> jax.Array:
-    """Dispatch: decode (T==1) on TPU → Pallas flash kernel over pages;
-    otherwise the XLA gather path. With a >1-device ``mesh`` the kernel
-    runs per model-shard via shard_map (heads follow their kv heads —
-    ops/paged_attention.py *_sharded wrappers), so TP no longer forces
-    the XLA gather for prefill or K=1 decode (VERDICT r3 weak #3).
-    ``allow_pallas=False`` still forces the XLA path outright."""
+    """Dispatch: on a TPU backend decode (T == 1) and a chunk of queries
+    (T > 1: prefill, speculative verify) each run their Pallas kernel
+    over the row's own pages (ops/paged_attention.py); everything else
+    runs the XLA gather path: other platforms, ``allow_pallas=False``, a
+    mesh the shard_map wrappers cannot split (below), and a chunk of
+    queries over heads that are no multiple of 128 lanes (run.py --model
+    1b: the chip's compiler refuses the page copies, see
+    _decode_kernel_narrow). With a >1-device ``mesh`` the kernels run per
+    model-shard via shard_map (heads follow their kv heads: the *_sharded
+    wrappers)."""
     # CPU test hook: DYN_PALLAS_INTERPRET drives the kernel-in-engine
     # path in interpret mode — but NEVER on a real TPU backend (a
     # lingering env var must not silently interpret-mode a hardware
@@ -413,10 +417,10 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 q[:, 0], k_pages, v_pages, page_table,
                 lengths, scale=scale, softcap=softcap,
                 lower=lower)[:, None]
-    if (T > 1 and pallas_ok and env_flag("DYN_PREFILL_PALLAS")):
-        # opt-in flash prefill (any non-empty value, like the sibling
-        # DYN_DISABLE_PALLAS flag): pages stream through VMEM instead of
-        # the XLA path's dense [B, P*ps, KV, hd] gather per layer
+    if T > 1 and pallas_ok and (hd % 128 == 0 or interp):
+        # pages stream through VMEM, the live rows' and no others,
+        # instead of the XLA path's dense [B, P*ps, KV, hd] gather and
+        # float32 scores over every slot of the table
         if sharded:
             return paged_attention_prefill_sharded(
                 q, k_pages, v_pages, page_table, q_positions, mesh=mesh,

@@ -1,12 +1,13 @@
-"""Pallas TPU kernel: decode-time paged GQA attention.
+"""Pallas TPU kernels: paged GQA attention, a decode step and a chunk of
+queries (prefill), and latent (MLA) attention over its two pools.
 
-The serving hot loop. The XLA fallback (models/llama.py _paged_attention)
-gathers every sequence's pages into a dense [B, S, KV, hd] tensor each
-decode step — O(B·S) HBM traffic through an intermediate buffer. This
-kernel instead walks the page table (scalar-prefetched, read inside the
-body), copies each needed page HBM→VMEM exactly once, and runs an
-online-softmax (flash) accumulation on-chip for ALL heads of the
-sequence at once:
+The decode kernel is the serving hot loop. The XLA fallback
+(models/llama.py _paged_attention) gathers every sequence's pages into a
+dense [B, S, KV, hd] tensor each decode step — O(B·S) HBM traffic
+through an intermediate buffer. This kernel instead walks the page
+table (scalar-prefetched, read inside the body), copies each needed page
+HBM→VMEM exactly once, and runs an online-softmax (flash) accumulation
+on-chip for ALL heads of the sequence at once:
 
   grid = (batch,); per row a loop over its chunks of G pages (G by
   shape: _decode_pages_per_step), the pools left in HBM and a chunk's
@@ -23,6 +24,14 @@ grid step. A call's time therefore follows the pages its rows own, not
 the padded page-table width (one grid step a slot of the table, as this
 kernel had it until PR 32, took 0.19 us a slot for the pipeline's
 bookkeeping alone: 5-20% of the bytes' roofline in the benchmark's cells).
+
+The prefill kernel (paged_attention_prefill, T > 1) is the same form
+for a block of one row's queries: grid (batch, blocks of the chunk), the
+pages between the first position the block's earliest query can see and
+its last query's own, all KV heads a step. A padding row, a page past a
+block's causal edge and a page wholly before its sliding window cost
+nothing, where the XLA path gathers every slot of the table for every
+row and makes float32 scores over all of it.
 
 This is the role block_copy.cu + the engines' paged-attention CUDA
 kernels play in the reference (SURVEY §2.3), expressed TPU-natively.
@@ -85,6 +94,23 @@ def _decode_pages_per_step(P: int, KV: int, ps: int, hd: int,
     while G > 1 and KV * G * ps * hd * (4 * itemsize + 8) > _DECODE_VMEM_BYTES:
         G //= 2
     return G
+
+
+def _page_copies(do: str, n, source, ps: int, pools, bufs, sems, slot):
+    """Start (or wait for: ``do``) the async copies of a chunk's first
+    ``n`` pages, one a pool a page, into rows [g * ps, (g + 1) * ps) of
+    ``bufs[i][slot]``; ``source(pool, g)`` is page g's slice of the pool
+    in HBM. A loop, not unrolled copies: the program stays small, and
+    "start" and "wait" build the same descriptors."""
+    def page(g, carry):
+        at = pl.ds(pl.multiple_of(g * ps, ps), ps)
+        for i, (pool, buf) in enumerate(zip(pools, bufs)):
+            getattr(pltpu.make_async_copy(
+                source(pool, g), buf.at[slot, :, at, :],
+                sems.at[i, slot]), do)()
+        return carry
+
+    jax.lax.fori_loop(0, n, page, 0)
 
 
 def _reset_row(m_ref, l_ref, acc_ref):
@@ -164,21 +190,12 @@ def _decode_kernel(ps: int, G: int, P: int, scale: float,
             jnp.maximum(end - first, 0) + (G - 1), G)
 
     def copies(r, j, slot, do: str):
-        # chunk j of row r: a copy a pool for each page it has (a loop,
-        # not G unrolled copies: the program stays small); "start" and
-        # "wait" build the same descriptors
+        # chunk j of row r: a copy a pool for each page it has
         first, end, _ = span(r)
         p0 = first + j * G
-
-        def page(g, carry):
-            at = pl.ds(pl.multiple_of(g * ps, ps), ps)
-            for i, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
-                getattr(pltpu.make_async_copy(
-                    pool.at[layer, pt_ref[r, p0 + g]],
-                    buf.at[slot, :, at, :], sems.at[i, slot]), do)()
-            return carry
-
-        jax.lax.fori_loop(0, jnp.clip(end - p0, 0, G), page, 0)
+        _page_copies(do, jnp.clip(end - p0, 0, G),
+                     lambda pool, g: pool.at[layer, pt_ref[r, p0 + g]],
+                     ps, (k_hbm, v_hbm), (kbuf, vbuf), sems, slot)
 
     first, _, n = span(b)
 
@@ -638,6 +655,279 @@ def latent_attention_prefill_layered(q_lat, q_rope, c_pools, r_pools, layer,
     return acc.reshape(B, T, H, r), m.reshape(B, T, H), l.reshape(B, T, H)
 
 
+# ------------------------------------------------------- prefill kernel
+
+# A chunk of queries (T > 1) against the pages its rows own: the decode
+# kernel's form, for a block of queries. Cached tokens a chunk of the row
+# loop takes, query rows (tokens x group) a KV head a block holds, and the
+# VMEM a block's buffers may take. One layer's call, device ms of the whole
+# program (the two transposes around the kernel included), at <tokens a
+# block> x <pages a chunk> 32x8 / 64x8 / 128x8 / 256x8 / 128x4 / 128x16,
+# then the rule below, the kernel this one replaced (a grid step a slot of
+# the table for every KV head, float32 operands; "-": past the chip's VMEM)
+# and the XLA gather arm (tools/paged_attn_timing.py --arms prefill, my
+# chip run, PR 34; the cells' largest prefill batch, live rows as
+# (first position, tokens), the other rows padding):
+#   cell 1, PB 4 x T 512, P 64, KV 8 x 4, rows (0, 410) (0, 512):
+#       0.224 0.224 0.185 0.179 0.202 0.245 | 0.179 | 0.919 | 8.54
+#   cell 2, PB 8 x T 256, P 32, KV 4 x 8, rows (0, 226) (0, 166):
+#       0.130 0.105 0.108 0.102 0.096 0.133 | 0.108 | 0.375 | 4.08
+#   cell 3, PB 4 x T 512, P 64, KV 8 x 4, rows (1536, 167) (1536, 177):
+#       0.311 0.303 0.282 0.268 0.353 0.247 | 0.268 | 2.047 | 8.54
+#   cell 4, PB 8 x T 512, P 32, KV 1 x 20, four rows of 331-495 from 0:
+#       0.351 0.352 0.342 0.349 0.346 0.398 | 0.351 | -     | 3.14
+#   cell 6, PB 4 x T 256, P 64, KV 4 x 8 (packed), three rows of 167-227
+#   after 3,072: 0.721 0.499 0.553 0.555 0.756 0.492 | 0.553 | 2.462 | 3.97
+#   every row live: cell 1 0.340 (XLA 8.54), cell 2 0.264 (4.08).
+# Short contexts want chunks of 4-8 pages (a chunk's masked tail costs),
+# 3k cached tokens 8-16; a block re-reads the row's pages, so few large
+# blocks, up to what VMEM holds beside the lane-wide statistics. Cell 6 is
+# bound by the arithmetic (0.15 ms at the chip's bf16 peak), the others by
+# moving q and the output of every row of the bucket, live or not.
+PREFILL_TOKENS_PER_STEP = 512
+PREFILL_BLOCK_ROWS = 1024
+_PREFILL_VMEM_BYTES = 40 << 20
+_PREFILL_VMEM_LIMIT = 64 << 20
+
+
+def _prefill_vmem_bytes(tq: int, G: int, group: int, KV: int, ps: int,
+                        hd: int, itemsize: int) -> int:
+    """What a block of tq tokens and chunks of G pages hold in VMEM: q and
+    the output (two slots each, the pipeline's), acc and the lane-wide
+    (m, l) in float32, the positions' column, K and V of a chunk (two
+    slots each), and a KV head's scores, mask and probabilities."""
+    R, C = tq * group, G * ps
+    return (KV * R * (hd * (4 * itemsize + 4) + 2 * 128 * 4) + 2 * R * 128 * 4
+            + 4 * KV * C * hd * itemsize + R * C * (12 + itemsize))
+
+
+def _prefill_sizes(T: int, group: int, KV: int, P: int, ps: int, hd: int,
+                   itemsize: int) -> tuple:
+    """(tokens a query block, pages a chunk) by shape: the chunk of
+    PREFILL_TOKENS_PER_STEP tokens, at most the table; the block halved
+    from the whole chunk of queries while a KV head has more than
+    PREFILL_BLOCK_ROWS rows in it or the buffers pass _PREFILL_VMEM_BYTES
+    (rows stay a multiple of 16 sublanes): 256 tokens at group 4, 128 at
+    group 8, 32 at group 20 (640 rows) for T 512."""
+    G = max(1, min(P, PREFILL_TOKENS_PER_STEP // ps))
+    tq = T
+    while (tq % 2 == 0 and (tq // 2 * group) % 16 == 0
+           and (tq * group > PREFILL_BLOCK_ROWS
+                or _prefill_vmem_bytes(tq, G, group, KV, ps, hd, itemsize)
+                > _PREFILL_VMEM_BYTES)):
+        tq //= 2
+    return tq, G
+
+
+def _prefill_kernel(ps: int, G: int, scale: float, softcap: float | None,
+                    # scalar prefetch
+                    pt_ref, first_ref, end_ref, win_ref,
+                    # one block of one row's queries; the pools: whole, HBM
+                    q_ref, qpos_ref, k_hbm, v_hbm, o_ref,
+                    kbuf, vbuf, sems, slot_ref, m_ref, l_ref, acc_ref):
+    """One grid step = one BLOCK of one row's queries, all KV heads: a
+    loop over the chunks of G pages that hold the positions the block can
+    see (pages [first, end) of the row's table, worked out per block
+    outside), each chunk's pages copied HBM->VMEM as _decode_kernel
+    copies them (two slots: the next chunk, or the next step's first, is
+    in flight while one computes), then one online-softmax update a KV
+    head over the chunk's G * ps positions. Operands go to the MXU in the
+    pools' type; scores, statistics and the accumulator are float32. Rows
+    are a KV head's (token, group-head) pairs, flattened outside. kv slot
+    j of table entry p holds position p * ps + j, visible to a query at
+    position t iff t - window < p * ps + j <= t."""
+    KV, R, hd = q_ref.shape
+    C = G * ps
+    b, nq = pl.program_id(0), pl.num_programs(1)
+    step = b * nq + pl.program_id(1)
+    steps = pl.num_programs(0) * nq
+
+    def chunks(s):
+        # lax.div: nothing here is negative (see _decode_kernel)
+        return jax.lax.div(
+            jnp.maximum(end_ref[s] - first_ref[s], 0) + (G - 1), G)
+
+    def copies(s, j, slot, do: str):
+        # chunk j of step s: a copy a pool for each page it has
+        row = jax.lax.div(s, nq)
+        p0 = first_ref[s] + j * G
+        _page_copies(do, jnp.clip(end_ref[s] - p0, 0, G),
+                     lambda pool, g: pool.at[pt_ref[row, p0 + g]],
+                     ps, (k_hbm, v_hbm), (kbuf, vbuf), sems, slot)
+
+    n = chunks(step)
+
+    @pl.when(step == 0)
+    def _():
+        # a page that is not copied leaves its slot as it was: masked
+        # scores may be anything, but 0 * v must not be NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+
+    base = slot_ref[0]  # the slot the step's first chunk is copied to
+
+    # the step before starts this step's first chunk beside its own last;
+    # the first step, and one after a step with nothing to read, start it
+    @pl.when(jnp.logical_or(step == 0, chunks(jnp.maximum(step - 1, 0)) == 0))
+    def _():
+        copies(step, 0, base, "start")
+
+    @pl.when(n == 0)
+    def _():  # padding: nothing to read, zeros out
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n > 0)
+    def _():
+        _reset_row(m_ref, l_ref, acc_ref)
+        q_pos = qpos_ref[...]                              # [R, 1]
+        seen = q_pos - win_ref[b]                          # window's edge
+
+        def chunk(j, carry):
+            slot = (base + j) & 1
+            more = j + 1 < n
+
+            @pl.when(jnp.logical_or(more, step + 1 < steps))
+            def _():
+                copies(jnp.where(more, step, step + 1),
+                       jnp.where(more, j + 1, 0), 1 - slot, "start")
+
+            copies(step, j, slot, "wait")
+            kv_pos = (first_ref[step] + j * G) * ps \
+                + jax.lax.broadcasted_iota(jnp.int32, (R, C), 1)
+
+            def head(kv, carry):
+                s = jax.lax.dot_general(
+                    q_ref[kv], kbuf[slot, kv], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # [R, C]
+                if softcap:  # Gemma-2 score softcap — BEFORE masking
+                    s = softcap * jnp.tanh(s / softcap)
+                valid = jnp.logical_and(kv_pos <= q_pos, kv_pos > seen)
+                s = jnp.where(valid, s, NEG_INF)
+                m_prev = m_ref[kv][:, :1]                  # [R, 1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # exp only where valid: a query that sees nothing of the
+                # chunk would otherwise add exp(0) = 1 a position
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                l_new = alpha * l_ref[kv][:, :1] \
+                    + jnp.sum(p, axis=1, keepdims=True)
+                v = vbuf[slot, kv]
+                acc_ref[kv] = acc_ref[kv] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)    # [R, hd]
+                m_ref[kv] = jnp.broadcast_to(m_new, (R, 128))
+                l_ref[kv] = jnp.broadcast_to(l_new, (R, 128))
+                return carry
+
+            jax.lax.fori_loop(0, KV, head, 0)
+            return carry
+
+        jax.lax.fori_loop(0, n, chunk, 0)
+        # a query that saw nothing (padding inside a live row) -> 0
+        l = jnp.maximum(l_ref[:, :, :1], 1e-9)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    slot_ref[0] = (base + n) & 1
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "softcap",
+                                             "block_tokens",
+                                             "pages_per_step"))
+def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
+                            v_pages: jax.Array, page_table: jax.Array,
+                            q_positions: jax.Array, *,
+                            scale: float | None = None,
+                            interpret: bool = False,
+                            softcap: float | None = None,
+                            eff_win: jax.Array | None = None,
+                            block_tokens: int | None = None,
+                            pages_per_step: int | None = None) -> jax.Array:
+    """A chunk of queries against the paged pool (flash form): what
+    models/llama.py _attention runs for T > 1 on a TPU.
+
+    q: [B, T, H, hd] (the current chunk); k_pages/v_pages:
+    [num_pages, KV, ps, hd], the chunk's K/V already written;
+    page_table: [B, P]; q_positions: [B, T] absolute (-1 padding);
+    ``eff_win`` [B]: the per-row effective sliding window. Returns
+    [B, T, H, hd] in q.dtype: llama._paged_attention's arithmetic
+    (operands in the pool's type, float32 accumulation and statistics,
+    probabilities cast to V's type, softcap before the mask), without its
+    dense [B, P * ps, KV, hd] gather and its float32 scores over every
+    slot of the table. A query at -1 gives zeros.
+
+    Grid (B, T / block): a step a block of one row's queries, which reads
+    the pages between the first position its earliest query can see and
+    its last query's own, and no others: time follows the live rows and
+    what they hold. ``block_tokens`` (a divisor of T) and
+    ``pages_per_step`` are the handles of the tests and of
+    tools/paged_attn_timing.py; the model code passes neither and runs
+    _prefill_sizes' rule by shape."""
+    B, T, H, hd = q.shape
+    _, KV, ps, _ = k_pages.shape
+    P = page_table.shape[1]
+    group = H // KV
+    if scale is None:
+        scale = hd ** -0.5
+    tq, G = _prefill_sizes(T, group, KV, P, ps, hd, k_pages.dtype.itemsize)
+    tq = block_tokens or tq
+    G = min(P, pages_per_step or G)
+    assert T % tq == 0, (T, tq)
+    nq, R = T // tq, tq * group
+    # kernel rows: a KV head's (token, group-head) pairs, token-major,
+    # so every block is 2-D a head with the head dim in lanes
+    q4 = q.astype(k_pages.dtype).reshape(B, T, KV, group, hd).transpose(
+        0, 2, 1, 3, 4).reshape(B, KV, T * group, hd)
+    pos = q_positions.astype(jnp.int32)
+    # a query's position as a column (positions ride sublanes, like the
+    # score rows they mask)
+    qpos = jnp.repeat(pos, group, axis=1)[:, :, None]  # [B, T * group, 1]
+    if eff_win is None:
+        eff_win = jnp.full((B,), jnp.int32(NO_WINDOW))
+    eff_win = eff_win.astype(jnp.int32)
+    # a block's pages: from the first position its earliest live query
+    # can see to its last query's own; a block of padding has none
+    blocks = pos.reshape(B, nq, tq)
+    hi = jnp.max(blocks, axis=2) + 1
+    lo = jnp.min(jnp.where(blocks >= 0, blocks, NO_WINDOW), axis=2) + 1 \
+        - eff_win[:, None]
+    first = jnp.clip(lo, 0, hi) // ps
+    end = jnp.minimum((hi + ps - 1) // ps, P)
+
+    def rows(b, i, *_):
+        return (b, 0, i, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, ps, G, scale, softcap),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(B, nq),
+            in_specs=[pl.BlockSpec((None, KV, R, hd), rows),
+                      pl.BlockSpec((None, R, 1), lambda b, i, *_: (b, i, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, KV, R, hd), rows),
+            scratch_shapes=[pltpu.VMEM((2, KV, G * ps, hd), k_pages.dtype),
+                            pltpu.VMEM((2, KV, G * ps, hd), v_pages.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((KV, R, 128), jnp.float32),
+                            pltpu.VMEM((KV, R, 128), jnp.float32),
+                            pltpu.VMEM((KV, R, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, T * group, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # steps in order: a step's last chunk starts the next's first
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM_LIMIT),
+        interpret=interpret,
+        # the name a device trace shows the kernel under; NOT what
+        # benchmark/harness/trace.py DECODE_KERNEL_OP matches
+        name="paged_attention_prefill",
+    )(page_table.astype(jnp.int32), first.reshape(-1), end.reshape(-1),
+      eff_win, q4, qpos, k_pages, v_pages)
+    return out.reshape(B, KV, T, group, hd).transpose(
+        0, 2, 1, 3, 4).reshape(B, T, H, hd)
+
+
 def paged_attention_prefill_sharded(q: jax.Array, k_pages: jax.Array,
                                     v_pages: jax.Array,
                                     page_table: jax.Array,
@@ -653,9 +943,7 @@ def paged_attention_prefill_sharded(q: jax.Array, k_pages: jax.Array,
     on its local KV heads (q heads follow their kv heads; GQA groups
     never straddle shards while num_kv_heads % tp == 0) and local batch
     rows. No collectives inside: softmax is per-head, so the output
-    stays head-sharded into wo. Closes the r3 gap where prefill dropped
-    to the XLA gather path the moment the pool was mesh-sharded
-    (VERDICT r3 weak #3)."""
+    stays head-sharded into wo."""
     from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -676,157 +964,3 @@ def paged_attention_prefill_sharded(q: jax.Array, k_pages: jax.Array,
         out_specs=P("data", None, "model", None),
         check_vma=False,  # pallas_call outputs carry no vma annotation
     )(q, k_pages, v_pages, page_table, q_positions, eff_win)
-
-
-# ------------------------------------------------------- prefill kernel
-
-
-def _prefill_kernel(ps: int, scale: float, softcap: float | None,
-                    pt_ref, len_ref, lo_ref, win_ref,    # scalar prefetch
-                    q_ref, qpos_ref, k_ref, v_ref, o_ref,
-                    m_ref, l_ref, acc_ref):
-    """Chunked-prefill flash attention over the paged pool.
-
-    Per (b, kv) the query chunk stays VMEM-resident while pages stream
-    in (grid innermost axis); online softmax runs per query row. Rows
-    are the chunk's (token, group-head) pairs flattened to R = T*group
-    BEFORE the call, so every block is 2-D with the head dim in lanes —
-    the chip's compiler refuses a (T, group) tile with group < 8 and the
-    in-kernel reshapes that went with it. The causal structure is
-    positional: kv slot j of table entry p holds logical position
-    p*ps+j, visible to row r iff within (q_position[r] - window,
-    q_position[r]] — window is the per-row effective sliding window
-    (huge when the layer is global).
-    """
-    b = pl.program_id(0)
-    p = pl.program_id(2)
-
-    @pl.when(p == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b]
-    lower = lo_ref[b]  # first position any query of the row can see
-    win = win_ref[b]
-
-    # pages wholly outside [lower, length): no compute, no fetch
-    @pl.when(jnp.logical_and(p * ps < length, (p + 1) * ps > lower))
-    def _():
-        q = q_ref[...].astype(jnp.float32)             # [R, hd]
-        k = k_ref[...].astype(jnp.float32)             # [ps, hd]
-        v = v_ref[...].astype(jnp.float32)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [R, ps]
-        if softcap:  # Gemma-2 score softcap — BEFORE masking
-            s = softcap * jnp.tanh(s / softcap)
-        kv_pos = p * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        q_pos = qpos_ref[...]                          # [R, 1]
-        valid = jnp.logical_and(kv_pos <= q_pos,       # causal + padding
-                                kv_pos > q_pos - win)  # sliding window
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_ref[:, :1]                          # [R, 1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # exp only where valid: an all-masked (row, page) pair (window
-        # already slid past the page) would otherwise add exp(0)=1 rows
-        p_exp = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_prev + jnp.sum(p_exp, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p_exp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)        # [R, hd]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(p == pl.num_programs(2) - 1)
-    def _():
-        l = jnp.maximum(l_ref[:, :1], 1e-9)  # all-masked (padding) rows → 0
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "softcap"))
-def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
-                            v_pages: jax.Array, page_table: jax.Array,
-                            q_positions: jax.Array, *,
-                            scale: float | None = None,
-                            interpret: bool = False,
-                            softcap: float | None = None,
-                            eff_win: jax.Array | None = None) -> jax.Array:
-    """Chunked-prefill paged GQA attention (flash form).
-
-    q: [B, T, H, hd] (the current chunk); k_pages/v_pages:
-    [num_pages, KV, ps, hd] — the chunk's K/V already written;
-    page_table: [B, P]; q_positions: [B, T] absolute (-1 padding).
-    Returns [B, T, H, hd] in q.dtype, numerically matching the XLA
-    gather path (models/llama.py _paged_attention) which materializes
-    a dense [B, P*ps, KV, hd] copy per layer; here pages stream through
-    VMEM once. Opt-in via DYN_PREFILL_PALLAS (see llama._attention).
-    """
-    B, T, H, hd = q.shape
-    _, KV, ps, _ = k_pages.shape
-    P = page_table.shape[1]
-    group = H // KV
-    R = T * group  # kernel rows: (token, group-head) pairs, token-major
-    if scale is None:
-        scale = hd ** -0.5
-    q4 = q.reshape(B, T, KV, group, hd).transpose(0, 2, 1, 3, 4).reshape(
-        B, KV, R, hd)
-    # per-row query position as a column (positions ride sublanes, like
-    # the score rows they mask)
-    qpos = jnp.repeat(q_positions.astype(jnp.int32), group,
-                      axis=1)[:, :, None]              # [B, R, 1]
-    # pages to visit per row: those covering [lower, max position]
-    lengths = jnp.max(q_positions, axis=1) + 1  # [B]; all-pad rows → 0
-    if eff_win is None:
-        eff_win = jnp.full((B,), jnp.int32(NO_WINDOW))
-    # first position visible to ANY query of the row: min valid q_pos
-    # minus the window; pages before it are skipped outright
-    minq = jnp.min(jnp.where(q_positions >= 0, q_positions, NO_WINDOW),
-                   axis=1)
-    lower = jnp.clip(minq + 1 - eff_win, 0, jnp.maximum(lengths - 1, 0))
-
-    def page_index(b, kv, p, pt, ln, lo, win):
-        needed = jnp.logical_and(p * ps < ln[b], (p + 1) * ps > lo[b])
-        first = jnp.minimum(lo[b] // ps, P - 1)
-        return (jnp.where(needed, pt[b, p], pt[b, first]), kv, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B, KV, P),
-        in_specs=[
-            pl.BlockSpec((None, None, R, hd),
-                         lambda b, kv, p, pt, ln, lo, win: (b, kv, 0, 0)),
-            pl.BlockSpec((None, R, 1),
-                         lambda b, kv, p, pt, ln, lo, win: (b, 0, 0)),
-            pl.BlockSpec((None, None, ps, hd), page_index),
-            pl.BlockSpec((None, None, ps, hd), page_index),
-        ],
-        out_specs=pl.BlockSpec((None, None, R, hd),
-                               lambda b, kv, p, pt, ln, lo, win:
-                               (b, kv, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((R, 128), jnp.float32),
-            pltpu.VMEM((R, 128), jnp.float32),
-            pltpu.VMEM((R, hd), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_prefill_kernel, ps, scale, softcap),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, R, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-        name="paged_attention_prefill",
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      lower.astype(jnp.int32), eff_win.astype(jnp.int32),
-      q4, qpos, k_pages, v_pages)
-    return out.reshape(B, KV, T, group, hd).transpose(
-        0, 2, 1, 3, 4).reshape(B, T, H, hd)

@@ -324,9 +324,6 @@ register_env("DYN_DISABLE_PALLAS", None, "models",
 register_env("DYN_PALLAS_INTERPRET", None, "models",
              "CPU test hook: any non-empty value runs Pallas kernels in "
              "interpret mode (never on a real TPU backend).")
-register_env("DYN_PREFILL_PALLAS", None, "models",
-             "Any non-empty value opts prefill into the flash Pallas "
-             "kernel (pages stream through VMEM).")
 
 register_env("DYN_DISABLE_NATIVE", None, "utils",
              "Any non-empty value disables building/loading the native "

@@ -67,7 +67,6 @@ def test_fails_alone_in_a_directory(tmp_path):
 def tiny(monkeypatch, tmp_path):
     monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
     monkeypatch.delenv("DYN_DISABLE_PALLAS", raising=False)
-    monkeypatch.delenv("DYN_PREFILL_PALLAS", raising=False)
     monkeypatch.setenv("DYN_JIT_FENCE", "raise")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     import jax

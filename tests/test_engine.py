@@ -388,18 +388,16 @@ def test_admission_clamped_to_warmed_grid(run_async):
 
 
 def test_prefill_pallas_flag_token_identity(run_async, monkeypatch):
-    """DYN_PREFILL_PALLAS routes chunked prefill through the flash
-    kernel (interpret mode on CPU): served tokens must be identical to
-    the default XLA gather path — the kernel-in-engine integration, not
-    just the kernel math."""
+    """DYN_PALLAS_INTERPRET routes chunked prefill through the paged
+    prefill kernel (interpret mode on the CPU, what a TPU backend runs
+    by default): served tokens must be identical to the XLA gather
+    path: the kernel-in-engine integration, not just the kernel math."""
     prompt = list(range(40, 40 + 21))
 
     def run(flagged):
         if flagged:
-            monkeypatch.setenv("DYN_PREFILL_PALLAS", "1")
             monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
         else:
-            monkeypatch.delenv("DYN_PREFILL_PALLAS", raising=False)
             monkeypatch.delenv("DYN_PALLAS_INTERPRET", raising=False)
         engine = mk_engine(page_size=4, num_pages=32, prefill_chunk=16)
 

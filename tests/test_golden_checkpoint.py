@@ -256,12 +256,11 @@ def test_gemma2_engine_generation_matches_transformers(gemma2_checkpoint,
                                                        kernels):
     """Full serving path on a Gemma-2 checkpoint greedy-matches
     transformers.generate across the sliding-window boundary — on the
-    XLA attention paths AND on the Pallas kernel paths (flash prefill +
+    XLA attention paths AND on the Pallas kernel paths (paged prefill +
     fused-window decode in interpret mode), which implement the score
     softcap and per-layer sliding window natively."""
     if kernels:
         monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
-        monkeypatch.setenv("DYN_PREFILL_PALLAS", "1")
     from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
     from dynamo_tpu.llm.protocols.common import (PreprocessedRequest,
                                                  SamplingOptions,

@@ -256,6 +256,158 @@ def test_prefill_kernel_bf16():
                                rtol=2e-2, atol=2e-2)
 
 
+def _prefill_case(seed, KV, group, hd, ps, P, T, starts, counts,
+                  dtype=jnp.float32, num_pages=None):
+    """A chunk of T queries a row: row b holds ``counts[b]`` live queries
+    at positions ``starts[b]`` .. (0: a padding row, all -1), its table
+    the pages those positions need, the rest 0. Returns (q, k_pages,
+    v_pages, table, positions)."""
+    rng = np.random.RandomState(seed)
+    B = len(starts)
+    lengths = [s + c if c else 0 for s, c in zip(starts, counts)]
+    num_pages = num_pages or 1 + sum(-(-n // ps) for n in lengths) + 3
+    q = jnp.asarray(rng.randn(B, T, KV * group, hd), dtype)
+    k_pages = jnp.asarray(rng.randn(num_pages, KV, ps, hd), dtype)
+    v_pages = jnp.asarray(rng.randn(num_pages, KV, ps, hd), dtype)
+    table = _tables(rng, lengths, P, ps, num_pages)
+    positions = np.full((B, T), -1, np.int32)
+    for b, (s, c) in enumerate(zip(starts, counts)):
+        positions[b, :c] = s + np.arange(c)
+    return q, k_pages, v_pages, jnp.asarray(table), jnp.asarray(positions)
+
+
+def _check_prefill(case, tol=2e-5, **kw):
+    """The kernel against the XLA arm at every live query; zeros at
+    every query of position -1 (the arm gives a uniform average there)."""
+    from dynamo_tpu.ops.paged_attention import paged_attention_prefill
+
+    q, k_pages, v_pages, table, positions = case
+    scale = q.shape[-1] ** -0.5
+    window = kw.pop("window", None)
+    arm = {} if window is None else {"window": window, "is_sliding": True}
+    if window is not None:
+        kw["eff_win"] = jnp.full((q.shape[0],), window, jnp.int32)
+    want = _paged_attention(q, k_pages, v_pages, table, positions, scale,
+                            softcap=kw.get("softcap"), **arm)
+    kw.setdefault("interpret", True)
+    got = paged_attention_prefill(q, k_pages, v_pages, table, positions,
+                                  scale=scale, **kw)
+    live = np.asarray(positions) >= 0
+    np.testing.assert_allclose(np.asarray(got, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
+                               rtol=tol, atol=tol)
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[~live], 0.0)
+    return got
+
+
+# (tokens a query block, pages a chunk): the module's own rule, then one
+# block and chunks of a page, several blocks and a chunk that divides
+# nothing, a chunk wider than the table
+PREFILL_SIZES = [(None, None), (16, 1), (8, 3), (4, 16)]
+
+
+@pytest.mark.parametrize("block_tokens,pages_per_step", PREFILL_SIZES)
+def test_prefill_kernel_rows_of_different_lengths(block_tokens,
+                                                  pages_per_step):
+    """One call, ps 8, T 16: a padding row first, between and last (each
+    hands the next step's first chunk on); a row from position 0 that
+    fills the chunk; a row whose chunk starts after three cached pages (a
+    prefix hit: positions from an offset on a page's edge); a chunk that
+    starts inside a page and straddles the next; a row of one token; a
+    row that ends on the table's last slot."""
+    starts = [0, 0, 24, 0, 13, 5, 0, 48, 0]
+    counts = [0, 16, 9, 0, 16, 1, 0, 16, 0]
+    case = _prefill_case(11, 2, 4, 32, 8, 8, 16, starts, counts)
+    _check_prefill(case, block_tokens=block_tokens,
+                   pages_per_step=pages_per_step)
+
+
+def test_prefill_kernel_all_rows_padding():
+    case = _prefill_case(12, 2, 2, 32, 8, 4, 8, [0, 0], [0, 0])
+    got = _check_prefill(case)
+    assert got.shape == case[0].shape
+
+
+def test_prefill_kernel_one_kv_head_group_20():
+    """Jamba's attention at its prefill length: KV 1, group 20, T 512
+    over pages of 64, by the module's own rule (32 tokens = 640 rows a
+    block): a cold prompt, a chunk after 300 cached tokens, padding."""
+    from dynamo_tpu.ops.paged_attention import _prefill_sizes
+
+    assert _prefill_sizes(512, 20, 1, 32, 64, 128, 2) == (32, 8)
+    case = _prefill_case(13, 1, 20, 128, 64, 16, 512, [0, 300, 0],
+                         [512, 200, 0])
+    _check_prefill(case, tol=5e-5)
+
+
+def test_prefill_kernel_packed_lanes():
+    """LFM2's packed pool: two KV heads of 64 share a 128-lane row and a
+    query sits in its KV head's lanes, zeros in the other's (models/
+    lfm2.py _qkv). The kernel on the packed arrays equals the XLA arm on
+    them, and each head's own lanes equal attention over heads of 64."""
+    rng = np.random.RandomState(14)
+    B, T, H, KV, hd, ps, P, pack = 2, 16, 8, 4, 64, 8, 6, 2
+    starts, counts = [20, 0], [16, 11]
+    _, k64, v64, table, positions = _prefill_case(
+        14, KV, H // KV, hd, ps, P, T, starts, counts)
+    q64 = jnp.asarray(rng.randn(B, T, H, hd), jnp.float32)
+    lanes = jax.nn.one_hot((np.arange(H) // (H // KV)) % pack, pack)
+    q = (q64[..., None, :] * lanes[:, :, None]).reshape(B, T, H, pack * hd)
+    N = k64.shape[0]
+
+    def packed(x):  # [N, KV, ps, hd] -> [N, KV / pack, ps, pack * hd]
+        return x.reshape(N, KV // pack, pack, ps, hd).transpose(
+            0, 1, 3, 2, 4).reshape(N, KV // pack, ps, pack * hd)
+    scale = hd ** -0.5
+    from dynamo_tpu.ops.paged_attention import paged_attention_prefill
+    got = paged_attention_prefill(q, packed(k64), packed(v64), table,
+                                  positions, scale=scale, interpret=True)
+    live = np.asarray(positions) >= 0
+    want = _paged_attention(q, packed(k64), packed(v64), table, positions,
+                            scale)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-5)
+    own = jnp.sum(got.reshape(B, T, H, pack, hd) * lanes[:, :, None], axis=3)
+    want64 = _paged_attention(q64, k64, v64, table, positions, scale)
+    np.testing.assert_allclose(np.asarray(own)[live],
+                               np.asarray(want64)[live],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("block_tokens,pages_per_step", PREFILL_SIZES)
+@pytest.mark.parametrize("window", [5, 20])
+def test_prefill_kernel_softcap_and_window_by_row(window, block_tokens,
+                                                  pages_per_step):
+    """Softcap + sliding window over rows at different offsets: at a
+    window of 5 a block's view starts pages past the row's first (whole
+    chunks slid past: neither copied nor computed) and the chunk's early
+    queries see less than a page; at 20 it starts inside a page."""
+    case = _prefill_case(15, 2, 2, 32, 8, 8, 16, [0, 40, 0, 17],
+                         [16, 16, 0, 12])
+    _check_prefill(case, softcap=12.0, window=window,
+                   block_tokens=block_tokens, pages_per_step=pages_per_step)
+
+
+@pytest.mark.parametrize("block_tokens,pages_per_step",
+                         [(16, 1), (8, 2), (4, 3), (16, 8)])
+def test_prefill_kernel_copies_in_tpu_interpreter(block_tokens,
+                                                  pages_per_step):
+    """The kernel's own copies under the TPU interpreter, as
+    test_decode_kernel_copies_in_tpu_interpreter: buffers start as NaN
+    and a copy's bytes arrive only when it is waited for, so a chunk
+    computed before its wait, a wait that names another slot or a stale
+    page that leaks through the mask shows as a wrong row."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                   uninitialized_memory="nan")
+    starts = [0, 0, 24, 0, 0, 13, 48, 0]
+    counts = [0, 16, 9, 0, 0, 16, 16, 0]
+    case = _prefill_case(16, 2, 4, 128, 8, 8, 16, starts, counts)
+    _check_prefill(case, interpret=interp, block_tokens=block_tokens,
+                   pages_per_step=pages_per_step)
+
+
 @pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
 @pytest.mark.parametrize("hd,window", [(32, 6), (128, 6), (128, 20)])
 def test_decode_kernel_softcap_and_window_match_gather(hd, window,
@@ -317,7 +469,7 @@ def test_decode_kernel_empty_window_view():
 
 
 def test_prefill_kernel_softcap_and_window_match_gather():
-    """Gemma-2 semantics in the flash prefill kernel: softcap + per-row
+    """Gemma-2 semantics in the paged prefill kernel: softcap + per-row
     effective window (with page skipping below the window) match the XLA
     gather path over a chunk longer than the window."""
     from dynamo_tpu.models.llama import _paged_attention

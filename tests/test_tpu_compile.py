@@ -2,8 +2,8 @@
 v5e, at the widths the chip serves. Nothing runs: the TPU compiler is
 installed here and refuses what the attached chip would refuse (a block
 shape the tiling cannot hold, too much VMEM, a program past HBM), which
-interpret-mode tests cannot see — the flash prefill kernel passed every
-interpret test while no width of it compiled.
+interpret-mode tests cannot see — a prefill kernel passed every
+interpret test while no width of it compiled (PR 21).
 
 The topology is described inside a module-scoped fixture (never at
 import, never autouse): only the xdist worker that is handed this file
@@ -135,19 +135,24 @@ def test_decode_kernel_compiles_at_cell_shapes(one_chip, B, P, KV, group,
 
 @pytest.mark.parametrize("softcap,window", [(None, False), (50.0, True)],
                          ids=["plain", "softcap-window"])
-@pytest.mark.parametrize("T", [128, 512])
-@pytest.mark.parametrize("width", sorted(WIDTHS))
-def test_prefill_kernel_compiles(one_chip, width, T, softcap, window):
-    """The DYN_PREFILL_PALLAS kernel: refused at every width until its
-    blocks were made 2-D (rows = token x group-head, head dim in lanes)."""
-    w = WIDTHS[width]
+@pytest.mark.parametrize("B,T,P,KV,group", [
+    (8, 256, 32, 4, 8),      # qwen3-30b-a3b.decode-heavy
+    (4, 512, 64, 8, 4),      # mixtral-8x7b.chat-steady / .shared-prefix
+    (8, 512, 32, 1, 20),     # jamba2-3b.reason-decode
+    (4, 512, 64, 4, 8),      # lfm2-24b-a2b.agent-loop: heads packed 2 a row
+], ids=["qwen3", "mixtral", "jamba", "lfm2-packed"])
+def test_prefill_kernel_compiles(one_chip, B, T, P, KV, group, softcap,
+                                 window):
+    """The paged prefill kernel at the benchmark's prefill shapes (the
+    cells' largest batch and length buckets, heads of 128 lanes), at the
+    block and chunk its rule by shape gives them: a block's buffers have
+    to fit the VMEM limit the call asks for."""
     s = partial(_sds, one_chip)
-    q = s((B_PRE, T, w["H"], w["hd"]), jnp.bfloat16)
-    k = s((NUM_PAGES, w["KV"], PS, w["hd"]), jnp.bfloat16)
+    q = s((B, T, KV * group, 128), jnp.bfloat16)
+    k = s((NUM_PAGES, KV, PS, 128), jnp.bfloat16)
     lowered = pa.paged_attention_prefill.lower(
-        q, k, k, s((B_PRE, P_PRE), jnp.int32), s((B_PRE, T), jnp.int32),
-        softcap=softcap,
-        eff_win=s((B_PRE,), jnp.int32) if window else None)
+        q, k, k, s((B, P), jnp.int32), s((B, T), jnp.int32),
+        softcap=softcap, eff_win=s((B,), jnp.int32) if window else None)
     assert _has_kernel(lowered.compile())
 
 
@@ -172,10 +177,10 @@ def test_sharded_kernels_compile_on_four_chips(topo):
                   ).lower(q, pools, pools, s((), jnp.int32, P()), table,
                           lengths).compile()
     assert _has_kernel(dec)
-    T = 128
-    qp = s((B_PRE, T, w["H"], w["hd"]), jnp.bfloat16,
+    T, hd = 128, WIDTHS["8b"]["hd"]   # the prefill kernel: 128 lanes only
+    qp = s((B_PRE, T, w["H"], hd), jnp.bfloat16,
            P(None, None, "model", None))
-    pool = s((NUM_PAGES, w["KV"], PS, w["hd"]), jnp.bfloat16,
+    pool = s((NUM_PAGES, w["KV"], PS, hd), jnp.bfloat16,
              P(None, "model", None, None))
     pre = jax.jit(partial(pa.paged_attention_prefill_sharded, mesh=mesh)
                   ).lower(qp, pool, pool, s((B_PRE, P_PRE), jnp.int32, P()),
@@ -233,15 +238,17 @@ def test_decode_window_program_compiles(one_chip, tpu_kernel_path,
 
 
 @pytest.mark.parametrize("flash", [False, True], ids=["xla", "flash"])
-def test_prefill_program_compiles(one_chip, tpu_kernel_path, monkeypatch,
-                                  flash):
-    """One warmed bucket of chunked prefill (PB=8, T=512, P=64), on the
-    default XLA gather path and with DYN_PREFILL_PALLAS."""
-    if flash:
-        monkeypatch.setenv("DYN_PREFILL_PALLAS", "1")
-    else:
-        monkeypatch.delenv("DYN_PREFILL_PALLAS", raising=False)
+def test_prefill_program_compiles(one_chip, tpu_kernel_path, flash):
+    """One warmed bucket of chunked prefill (PB=8, T=512, P=64) as a TPU
+    backend builds it: at the 1B preset's heads of 64 on the XLA gather
+    path (the chip's compiler refuses the kernel's page copies there),
+    at heads of 128 with the paged prefill kernel inside."""
+    import dataclasses
+
     cfg = _preset_1b()
+    if flash:
+        cfg = dataclasses.replace(cfg, num_heads=16, num_kv_heads=4,
+                                  head_dim=128)
     params, kv_k, kv_v = _engine_shapes(cfg, one_chip)
     prefill, _ = llama.make_step_fns(cfg)
     s = partial(_sds, one_chip)
@@ -581,6 +588,11 @@ def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
             kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
             s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
             state, s((PB,), jnp.int32), s((PB,), jnp.int32)).compile()
+        # the chunk's attention is the paged prefill kernel on the packed
+        # pool: no float32 scores over the whole table (2.06 GiB of
+        # temporaries on the XLA arm, 0.12 now: scratch compile, PR 34)
+        assert _has_kernel(compiled)
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
     smallest = min(kv_k.size, state[1].size)
     assert _pool_sized_copies(compiled.as_text(), smallest) == []
     mem = compiled.memory_analysis()
