@@ -630,6 +630,9 @@ class JaxEngine:
         # thread id of the loop's thread, captured in start(): _emit's
         # on/off-loop routing is one integer compare (no exception probe)
         self._aio_loop_tid: Optional[int] = None
+        # the serving loop's ledger (runtime/profiling.py), held from
+        # start(); brackets on the null ledger do nothing
+        self._loop_ledger = profiling.NULL_LEDGER
         self._stopped = False
         # dynarevive graceful drain: a draining engine refuses new work
         # (typed NoCapacity) while in-flight sequences run to completion
@@ -1062,8 +1065,9 @@ class JaxEngine:
             self._aio_loop_tid = threading.get_ident()
             # dynaprof: the serving loop gets a lag monitor + stall
             # watchdog for as long as an engine runs on it (refcounted;
-            # stop() releases)
-            profiling.acquire_loop_profiler()
+            # stop() releases), and its ledger for _loop's own bracket
+            # and the frontend's intake sum
+            self._loop_ledger = profiling.acquire_loop_profiler().ledger
             self._loop_task = asyncio.ensure_future(self._loop())
 
     async def stop(self) -> None:
@@ -1131,6 +1135,10 @@ class JaxEngine:
                        tokens=list(request.token_ids),
                        num_prompt=len(request.token_ids),
                        trace_ctx=tracing.get_tracer().current_trace_ctx())
+        if context.t_received is not None:
+            # the frontend's first leg: its handler's entry to the stamp
+            # engine_ttft_seconds_total starts from
+            self._loop_ledger.add("intake", seq.arrival - context.t_received)
         if seq.num_prompt == 0:
             yield EngineOutput(finish_reason="error", text="empty prompt")
             return
@@ -1182,7 +1190,14 @@ class JaxEngine:
             # where the step thread's time went (engine/profiler.py
             # PHASES; sums to the wall time) and the TTFT split
             "step_phase_seconds_total": self.profiler.phase_snapshot(),
+            # the step thread's CPU time by the same phases: wall less
+            # CPU in a phase that does host work is the GIL or the run
+            # queue (thread_runq_wait_seconds_total tells them apart)
+            "step_phase_cpu_seconds_total": self.profiler.cpu_snapshot(),
             "step_iterations_total": self.profiler.step_iterations,
+            # the loop and detokeniser threads' ledger, the frontend's
+            # two legs, per-thread CPU / run-queue time, GC pauses
+            **profiling.host_stats(self.profiler.native_id),
             "prefill_wait_seconds_total": self.prefill_wait_seconds_total,
             "first_token_seconds_total": self.first_token_seconds_total,
             "engine_ttft_seconds_total": self.engine_ttft_seconds_total,
@@ -1293,6 +1308,10 @@ class JaxEngine:
 
     async def _loop(self) -> None:
         loop = asyncio.get_running_loop()
+        # this coroutine's own work between two steps, from the step
+        # future's wake-up to the next hand-off, is the loop ledger's
+        # `engine_loop` (a leave without its enter does nothing)
+        led = self._loop_ledger
         # `await run_in_executor` suspends this coroutine at least once
         # per iteration (the step future is never done at await time), so
         # the event loop drains its ready queue every step without a
@@ -1300,6 +1319,7 @@ class JaxEngine:
         while not self._stopped:
             if not (self.waiting or self.prefilling or self.running
                     or self._inflight or self._pending_prefill):
+                led.leave("engine_loop")
                 self._wake.clear()
                 self.profiler.slept = True   # the gap to the next step is idle
                 await self._wake.wait()
@@ -1313,11 +1333,15 @@ class JaxEngine:
                 # free of the coroutine when no chaos is configured.
                 await guard.chaos_point("engine.stall")
             try:
-                await loop.run_in_executor(self._exec, self._step)
+                step = loop.run_in_executor(self._exec, self._step)
+                led.leave("engine_loop")
+                await step
+                led.enter("engine_loop")
                 self._reap()
             except Exception:  # noqa: BLE001 — engine loop must survive
                 log.exception("engine step failed")
                 await loop.run_in_executor(self._exec, self._abort_all)
+        led.leave("engine_loop")
         # shutdown: drain in-flight windows so no client hangs on a queue
         if self._inflight or self._pending_prefill:
             try:
@@ -2778,8 +2802,11 @@ class JaxEngine:
             # dynaslo: first token-bearing emission is TTFT; later gaps
             # are per-token ITL (an n-token window emission records n
             # per-token gaps of gap/n, so window size never skews the
-            # distribution). Host clock reads only.
-            now = time.monotonic()
+            # distribution). Host clock reads only. The same stamp rides
+            # the output (never the wire) to the frontend, which sums
+            # `now` -> its chunk's resp.write returned as
+            # emit_to_wire_seconds_total.
+            now = out.emit_t = time.monotonic()
             if seq.last_emit_t is None:
                 self.latency.observe("ttft", now - seq.arrival)
                 seq.t_first_token = now

@@ -1,15 +1,20 @@
 """dynaprof engine layer: the step thread's phase ledger.
 
 Every second of the engine's step thread belongs to exactly one named
-phase (``PHASES``), kept as a ledger — entering a phase closes the
-interval of the one around it, so nested brackets (a pipeline flush
-inside a window dispatch) stay disjoint and the phases sum to the
-thread's wall time by construction. Each bracket costs two
-``perf_counter`` reads and one ``jax.profiler.TraceAnnotation
-("dyn.<phase>")``, which is an atomic load while no profiler session is
-open and, while one is, puts the phase on the step thread's line of
-``/host:CPU`` on the device trace's clock.
-``stats()["step_phase_seconds_total"]`` carries the ledger.
+phase (``PHASES``), kept as a ledger (``runtime/profiling.py
+PhaseLedger``, the class the event loop's and the detokeniser workers'
+ledgers are instances of too): entering a phase closes the interval of
+the one around it, so nested brackets (a pipeline flush inside a window
+dispatch) stay disjoint and the phases sum to the thread's wall time by
+construction. Each bracket costs two ``perf_counter`` reads, two
+``thread_time`` reads (the thread's CPU clock: ~0.3 us each, a real
+system call) and one ``jax.profiler.TraceAnnotation("dyn.<phase>")``,
+which is an atomic load while no profiler session is open and, while
+one is, puts the phase on the step thread's line of ``/host:CPU`` on the
+device trace's clock. ``stats()["step_phase_seconds_total"]`` carries
+the ledger and ``step_phase_cpu_seconds_total`` the CPU time beside it:
+in a phase that does host work, wall less CPU is time the thread wanted
+to run and did not (the GIL, or the kernel's run queue).
 
 Nothing here touches the device: device time is read from a profiler
 trace (benchmark/harness/trace.py, host_trace.py), never from a host
@@ -18,8 +23,8 @@ clock around a sync (dynalint DL018 holds profiler code to that).
 
 from __future__ import annotations
 
-import time
-from typing import Dict
+import threading
+from typing import Dict, Optional
 
 from jax.profiler import TraceAnnotation
 
@@ -41,73 +46,31 @@ def _settle_gap(seconds: Dict[str, float], slept: bool) -> None:
     seconds[_GAP] = 0.0
 
 
-class _Phase:
-    """One phase's re-usable bracket (``with profiler.phase(name):``).
-    The ledger lives on the profiler; a bracket only parks, on the
-    profiler's stack, the phase to hand the clock back to and its open
-    trace annotation."""
-
-    __slots__ = ("prof", "name", "label")
-
-    def __init__(self, prof: "EngineProfiler", name: str):
-        self.prof = prof
-        self.name = name
-        self.label = "dyn." + name
-
-    def __enter__(self) -> None:
-        stack = self.prof._open
-        stack.append(self.prof._switch(self.name))
-        stack.append(TraceAnnotation(self.label))
-
-    def __exit__(self, *exc) -> None:
-        stack = self.prof._open
-        stack.pop().__exit__(*exc)
-        self.prof._switch(stack.pop())
-
-
-class EngineProfiler:
-    """Per-engine step-phase ledger. All mutation happens on the
+class EngineProfiler(profiling.PhaseLedger):
+    """Per-engine step-phase ledger: the step thread's instance of the
+    one ledger class, with nesting brackets. All mutation happens on the
     engine's single-worker executor thread (the same serialization the
     scheduler itself relies on); ``summary()`` and ``phase_snapshot()``
     reads are snapshot-style dict builds."""
 
     def __init__(self, name: str):
+        super().__init__(PHASES + (_GAP,), _GAP, cpu=True,
+                         annotation=TraceAnnotation)
         self.name = name
-        # seconds per phase, the phase the clock is running for, and when
-        # it started running
-        self.phase_seconds: Dict[str, float] = dict.fromkeys(
-            PHASES + (_GAP,), 0.0)
         self.step_iterations = 0
         self.slept = False      # _loop waited on _wake since the last step
-        self._phases = {n: _Phase(self, n) for n in PHASES}
-        self._open: list = []   # outer phase, annotation per open bracket
         self._step_ann = None
-        self._cur = _GAP
-        self._t = time.perf_counter()
-        self._ver = 0           # odd while _switch is mid-update
         profiling.register_profile(name, self)
-
-    def phase(self, name: str) -> _Phase:
-        return self._phases[name]
-
-    def _switch(self, name: str) -> str:
-        """Close the running phase's interval and start ``name``'s;
-        returns the phase that was running."""
-        now = time.perf_counter()
-        prev = self._cur
-        self._ver += 1
-        self.phase_seconds[prev] += now - self._t
-        self._cur = name
-        self._t = now
-        self._ver += 1
-        return prev
 
     def step_begin(self) -> None:
         """Entry of one ``_step``: the time since the last one ended was
         ``idle`` if the loop slept on its wake event in between, else
         ``between_steps`` (executor hop, reap)."""
+        if threading.get_ident() != self._tid:
+            self.bind_thread()  # the executor's worker, on its first step
         self._switch("other")
         _settle_gap(self.phase_seconds, self.slept)
+        _settle_gap(self.phase_cpu_seconds, self.slept)
         self.slept = False
         self.step_iterations += 1
         # a TraceAnnotation opens when it is made and closes in __exit__
@@ -117,21 +80,22 @@ class EngineProfiler:
         self._step_ann.__exit__(None, None, None)
         self._switch(_GAP)
 
-    def phase_snapshot(self) -> Dict[str, float]:
-        """{phase: cumulative seconds} up to now, the running interval
-        included, so two snapshots differ by the wall time between them.
-        Read from any thread: retried while the step thread is inside
-        ``_switch``."""
-        for _ in range(16):
-            ver = self._ver
-            seconds = dict(self.phase_seconds)
-            cur, t = self._cur, self._t
-            if ver == self._ver and not ver & 1:
-                break
-        seconds[cur] += time.perf_counter() - t
+    def _settled(self, seconds: Dict[str, float]) -> Dict[str, float]:
         _settle_gap(seconds, self.slept)
         del seconds[_GAP]
         return seconds
+
+    def phase_snapshot(self) -> Dict[str, float]:
+        """{phase: cumulative seconds} up to now, the running interval
+        included, so two snapshots differ by the wall time between them.
+        Read from any thread."""
+        return self._settled(self.snapshot()[0])
+
+    def cpu_snapshot(self) -> Dict[str, float]:
+        """{phase: cumulative CPU seconds of the step thread}, settled at
+        each switch: without the running interval, which only the step
+        thread itself could read."""
+        return self._settled(self.snapshot()[1])
 
     def summary(self) -> dict:
         """What /debug/profile and a blackbox dump carry per engine."""

@@ -38,7 +38,14 @@ def _detok_executor() -> ThreadPoolExecutor:
 
 
 def _decode_many(decode, ids: List[int]) -> str:
-    return "".join(p for p in map(decode.step, ids) if p)
+    # the worker thread's one bracket (``dyn.detok`` on a trace, `detok`
+    # of stats()["loop_phase_seconds_total"])
+    led = profiling.worker_ledger("detok")
+    led.enter("detok")
+    try:
+        return "".join(p for p in map(decode.step, ids) if p)
+    finally:
+        led.leave("detok")
 
 
 class StopSequenceJail:
@@ -151,82 +158,107 @@ class Backend:
             return released + tail + jail.flush()
 
         loop = asyncio.get_running_loop()
+        # the loop thread's ledger (runtime/profiling.py LOOP_PHASES).
+        # What the frontend opened as `intake` ends where the engine's
+        # stream is first pulled: the request's first real wait. Each
+        # output is then `deliver` up to its hand-off to the detokeniser
+        # and `encode_write` from the text's return until the consumer
+        # has written it (llm/http/service.py leaves it when resp.write
+        # returned) or asks for the next output.
+        led = profiling.loop_ledger()
+        led.leave("intake")
 
         agen = _aiter(self.engine.generate(request, context))
-        async for raw in agen:
-            out = raw if isinstance(raw, EngineOutput) else EngineOutput.from_dict(raw)
-            if out.cost is not None:
-                # remote workers attach dynaprof cost attribution to the
-                # finish chunk; registering it here makes the FRONTEND
-                # process's /v1/traces/{rid} and usage extension work even
-                # when the engine ran in another process
-                profiling.record_attribution(context.id, out.cost)
-            # Stop checks are pure host arithmetic and stay inline: they
-            # decide which ids are even eligible for decoding (skipped
-            # eos under skip_special_tokens, nothing past the finish).
-            # Only the tokenizer work ships to the detok executor.
-            emit_ids: List[int] = []
-            decode_ids: List[int] = []
-            for tid in out.token_ids:
-                produced += 1
-                is_eos = tid in eos_ids and produced >= min_tokens
-                is_stop_tok = tid in stop_ids and produced >= min_tokens
-                if not (is_eos and request.output.skip_special_tokens):
-                    decode_ids.append(tid)
-                emit_ids.append(tid)
-                if is_eos:
-                    finished = FINISH_EOS
-                elif is_stop_tok:
-                    finished = FINISH_STOP
-                elif max_tokens is not None and produced >= max_tokens:
-                    finished = FINISH_LENGTH
-                if finished:
-                    break
-            if not decode_ids:
-                text = ""
-            else:
-                # awaited before the next engine chunk is pulled — the
-                # per-request decode order is preserved by construction
-                text = await loop.run_in_executor(
-                    _detok_executor(), _decode_many, decode, decode_ids)
-            released, hit = jail.feed(text) if text else ("", False)
-            if hit:
-                finished = finished or FINISH_STOP
-            out.token_ids = emit_ids
-            out.finish_reason = finished or out.finish_reason
-            out.completion_tokens = produced
-            if out.finish_reason:
-                out.text = _final_text(released, stop_seq_hit=hit)
-                if out.cost is None and finished is not None and not hit:
-                    # the Backend's own stop (token cap / eos / stop
-                    # token) fired BEFORE the engine's finish chunk —
-                    # the chunk that carries the dynaprof cost block
-                    # (replica, prefix split). The engine enforces the
-                    # same budget/eos on device, so its finish is
-                    # already in flight: drain it (bounded) so remote
-                    # cost attribution still lands in this process's
-                    # ring. Skipped for stop-STRING matches (`hit`) —
-                    # the engine doesn't know host-side stop sequences
-                    # and would not finish within the bound.
-                    out.cost = await self._harvest_finish_cost(
-                        agen, context)
+        try:
+            async for raw in agen:
+                led.enter("deliver")
+                out = raw if isinstance(raw, EngineOutput) else EngineOutput.from_dict(raw)
+                if out.emit_t is not None:
+                    # the engine's _emit stamp of the newest tokens on
+                    # their way out: the frontend reads it back when the
+                    # chunk that carries them is written
+                    context.t_emit = out.emit_t
+                if out.cost is not None:
+                    # remote workers attach dynaprof cost attribution to the
+                    # finish chunk; registering it here makes the FRONTEND
+                    # process's /v1/traces/{rid} and usage extension work even
+                    # when the engine ran in another process
+                    profiling.record_attribution(context.id, out.cost)
+                # Stop checks are pure host arithmetic and stay inline: they
+                # decide which ids are even eligible for decoding (skipped
+                # eos under skip_special_tokens, nothing past the finish).
+                # Only the tokenizer work ships to the detok executor.
+                emit_ids: List[int] = []
+                decode_ids: List[int] = []
+                for tid in out.token_ids:
+                    produced += 1
+                    is_eos = tid in eos_ids and produced >= min_tokens
+                    is_stop_tok = tid in stop_ids and produced >= min_tokens
+                    if not (is_eos and request.output.skip_special_tokens):
+                        decode_ids.append(tid)
+                    emit_ids.append(tid)
+                    if is_eos:
+                        finished = FINISH_EOS
+                    elif is_stop_tok:
+                        finished = FINISH_STOP
+                    elif max_tokens is not None and produced >= max_tokens:
+                        finished = FINISH_LENGTH
+                    if finished:
+                        break
+                if not decode_ids:
+                    text = ""
+                else:
+                    # awaited before the next engine chunk is pulled — the
+                    # per-request decode order is preserved by construction
+                    step = loop.run_in_executor(
+                        _detok_executor(), _decode_many, decode, decode_ids)
+                    led.leave("deliver")
+                    text = await step
+                led.enter("encode_write")
+                released, hit = jail.feed(text) if text else ("", False)
+                if hit:
+                    finished = finished or FINISH_STOP
+                out.token_ids = emit_ids
+                out.finish_reason = finished or out.finish_reason
+                out.completion_tokens = produced
+                if out.finish_reason:
+                    out.text = _final_text(released, stop_seq_hit=hit)
+                    if out.cost is None and finished is not None and not hit:
+                        # the Backend's own stop (token cap / eos / stop
+                        # token) fired BEFORE the engine's finish chunk —
+                        # the chunk that carries the dynaprof cost block
+                        # (replica, prefix split). The engine enforces the
+                        # same budget/eos on device, so its finish is
+                        # already in flight: drain it (bounded) so remote
+                        # cost attribution still lands in this process's
+                        # ring. Skipped for stop-STRING matches (`hit`) —
+                        # the engine doesn't know host-side stop sequences
+                        # and would not finish within the bound.
+                        led.leave("encode_write")   # a bounded wait
+                        out.cost = await self._harvest_finish_cost(
+                            agen, context)
+                        led.enter("encode_write")
+                    yield out
+                    context.stop_generating()
+                    return
+                out.text = released
                 yield out
-                context.stop_generating()
-                return
-            out.text = released
-            yield out
-            if context.stopped:
-                # deadline expiry finishes as "timeout" (client-visible),
-                # caller cancellation as "cancelled"
-                context.stop_generating()
-                yield EngineOutput(text=_final_text("", False) or None,
-                                   finish_reason=context.cancel_reason(),
-                                   completion_tokens=produced)
-                return
-        # engine stream exhausted without a finish reason: flush held text and
-        # stamp a terminal reason so downstream never fabricates one
-        yield EngineOutput(token_ids=[], text=_final_text("", False) or "",
-                           finish_reason=FINISH_STOP, completion_tokens=produced)
+                led.leave("encode_write")
+                if context.stopped:
+                    # deadline expiry finishes as "timeout" (client-visible),
+                    # caller cancellation as "cancelled"
+                    context.stop_generating()
+                    yield EngineOutput(text=_final_text("", False) or None,
+                                       finish_reason=context.cancel_reason(),
+                                       completion_tokens=produced)
+                    return
+            # engine stream exhausted without a finish reason: flush held text and
+            # stamp a terminal reason so downstream never fabricates one
+            yield EngineOutput(token_ids=[], text=_final_text("", False) or "",
+                               finish_reason=FINISH_STOP, completion_tokens=produced)
+
+        finally:
+            led.leave("encode_write")
 
 
 async def _aiter(gen):

@@ -13,6 +13,7 @@ from __future__ import annotations
 import logging
 from typing import AsyncIterator, Optional
 
+from ..runtime import profiling
 from ..runtime.component import Client
 from ..runtime.engine import Annotated, Context
 from .backend import Backend
@@ -144,6 +145,10 @@ class RemoteOpenAIEngine:
     async def _run(self, request, context: Context):
         payload = request.model_dump(exclude_none=True) \
             if hasattr(request, "model_dump") else request
+        # the frontend's `intake` bracket ends where the request leaves
+        # this process (what follows is the network's wait, not the loop's
+        # work)
+        profiling.loop_ledger().leave("intake")
         stream = await self.client.generate(
             payload, mode=self.mode, context=context)
         try:

@@ -373,6 +373,23 @@ class HttpService:
 
     async def _serve(self, request: web.Request, model_cls, engines: dict,
                      endpoint: str) -> web.StreamResponse:
+        # the loop ledger's `intake` (runtime/profiling.py LOOP_PHASES)
+        # opens in _serve_request once the client's own bytes are in and
+        # runs without a suspension up to the engine's stream: a local
+        # chain leaves it where it first pulls that stream
+        # (llm/backend.py), a remote one before its request goes out
+        # (llm/engines.py), every early return here
+        t_received = time.monotonic()
+        led = profiling.loop_ledger()
+        try:
+            return await self._serve_request(request, model_cls, engines,
+                                             endpoint, t_received, led)
+        finally:
+            led.leave("intake")
+
+    async def _serve_request(self, request: web.Request, model_cls,
+                             engines: dict, endpoint: str,
+                             t_received: float, led) -> web.StreamResponse:
         # request identity: echo the client's X-Request-Id (or mint one) on
         # EVERY response — SSE streams and error paths included — so logs,
         # traces and client records join on one id
@@ -393,6 +410,8 @@ class HttpService:
             hdrs["traceparent"] = tp
         with span:
             try:
+                await request.read()    # cached: json() below waits no more
+                led.enter("intake")
                 body = await request.json()
                 req = model_cls(**body)
             # body parse/validation awaits only the client's own bytes —
@@ -431,6 +450,7 @@ class HttpService:
             # X-Request-Deadline-Ms header beats the registered default
             deadline = _request_deadline(request, req)
             ctx = Context(rid, deadline=deadline)
+            ctx.t_received = t_received
             try:
                 t0 = time.monotonic()
                 n = getattr(req, "n", 1) or 1
@@ -507,6 +527,7 @@ class HttpService:
             **(hdrs or {}),
         })
         await resp.prepare(http_request)
+        led = profiling.loop_ledger()
         errored = False
         saw_first_token = False
         last_token_t: Optional[float] = None
@@ -548,6 +569,20 @@ class HttpService:
                 itl_n += 1
             last_token_t = now
             await resp.write(b"data: " + json.dumps(data).encode() + b"\n\n")
+            # the Backend opened `encode_write` when the text came back
+            # from the detokeniser; a write that was suspended (the
+            # client stopped reading) lost the clock to the next bracket
+            # and leaves nothing
+            led.leave("encode_write")
+            t_emit = ctx.t_emit
+            if t_emit is not None:
+                # the way back: the engine's _emit of the newest tokens
+                # this chunk carries -> written
+                ctx.t_emit = None
+                wire = time.monotonic() - t_emit
+                led.add("emit_to_wire", wire)
+                if not first_text_written:
+                    led.add("first_emit_to_wire", wire)
             if not first_text_written and _carries_text(data):
                 # the frontend's share of TTFT, on the request's own
                 # trace: request received (the http.request span's start)

@@ -137,6 +137,15 @@ STATS_PROMETHEUS_SKIP = {
            "prefill_slots_total", "prefill_dispatches_total",
            "decode_rows_total", "decode_slots_total",
            "decode_windows_total", "warmup_seconds")},
+    # the host's account beside it (PR 35; runtime/profiling.py
+    # host_stats): process-wide, read as deltas, no gauge
+    **{key: "stats()-only counter of the host threads' time accounting"
+       for key in (
+           "loop_lag_seconds_total", "loop_lag_samples_total",
+           "intake_seconds_total", "intake_total",
+           "emit_to_wire_seconds_total", "emit_to_wire_total",
+           "first_emit_to_wire_seconds_total", "first_emit_to_wire_total",
+           "gc_pause_seconds_total")},
 }
 
 

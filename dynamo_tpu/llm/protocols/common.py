@@ -153,6 +153,11 @@ class EngineOutput:
     # absent on every other chunk and on legacy peers (optional field =
     # wire-compatible)
     cost: Optional[dict] = None
+    # dynaprof: the engine's time.monotonic() at the _emit of these
+    # tokens. In-process only: to_dict / from_dict leave it out, a
+    # remote peer's output has none
+    emit_t: Optional[float] = field(default=None, compare=False,
+                                    repr=False)
 
     @property
     def finished(self) -> bool:

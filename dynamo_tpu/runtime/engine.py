@@ -36,7 +36,7 @@ class Context:
     """
 
     __slots__ = ("id", "_stop", "_kill", "annotations", "deadline",
-                 "_kill_cbs")
+                 "_kill_cbs", "t_received", "t_emit")
 
     def __init__(self, request_id: Optional[str] = None, deadline=None):
         self.id: str = request_id or uuid.uuid4().hex
@@ -49,6 +49,13 @@ class Context:
         # a client disconnect must not wait for an abandoned generator
         # chain to be garbage-collected before the worker stops decoding
         self._kill_cbs: list = []
+        # dynaprof, both time.monotonic(), both in-process only: when the
+        # HTTP handler was entered, and the engine's _emit of the newest
+        # tokens on their way to the client (the frontend sums received
+        # -> Sequence.arrival and emit -> written, runtime/profiling.py
+        # LoopLedger)
+        self.t_received: Optional[float] = None
+        self.t_emit: Optional[float] = None
 
     @property
     def expired(self) -> bool:

@@ -3,9 +3,18 @@
 dyntrace (runtime/tracing.py) answers *how long* each stage of a request
 took in wall-clock; this module answers *where the time went* — the
 measurement gap that kept "scheduler overhead, not FLOPs" an inference.
-Three planes, all stdlib-only (the device-side half lives in
-``engine/profiler.py`` because it needs jax):
+Planes, all stdlib-only (the step thread's ledger lives in
+``engine/profiler.py`` because it needs jax; it is an instance of the
+``PhaseLedger`` here):
 
+- **Phase ledgers** — ``PhaseLedger``: every second of one thread in
+  exactly one named slot, each bracket an event on a profiler trace's
+  clock. One instance per hot-path thread: the step thread's
+  (``engine/profiler.py``), the event loop's (``LoopLedger``, five
+  slots) and one per ``dyn-detok`` worker. Beside them what the kernel
+  and the interpreter already count: per-thread CPU and run-queue time
+  (``/proc/self/task/<tid>/schedstat`` or ``stat``, read at ``stats()``
+  time only) and garbage-collection pauses (``gc.callbacks``).
 - **Event-loop lag monitor** — an asyncio task sleeps a fixed interval
   and records how late it woke (sampled sleep-drift, the classic
   continuous-profiling signal for a starved event loop). Bounded ring;
@@ -31,7 +40,9 @@ Overhead budget and knobs: docs/profiling.md.
 from __future__ import annotations
 
 import asyncio
+import gc
 import logging
+import os
 import sys
 import threading
 import time
@@ -70,6 +81,8 @@ class LoopLagMonitor:
         self.last_beat = time.monotonic()
         self.loop_thread_id: Optional[int] = None
         self.beats = 0
+        # what the ring cannot give: a delta between two stats() reads
+        self.lag_seconds_total = 0.0
         self._task: Optional[asyncio.Task] = None
 
     async def _run(self) -> None:
@@ -79,8 +92,10 @@ class LoopLagMonitor:
             t0 = loop.time()
             self.last_beat = time.monotonic()
             await asyncio.sleep(self.interval)
+            lag = max(loop.time() - t0 - self.interval, 0.0)
+            self.lag_seconds_total += lag
             self.beats += 1
-            self.samples.append(max(loop.time() - t0 - self.interval, 0.0))
+            self.samples.append(lag)
 
     def start(self) -> None:
         if self._task is None or self._task.done():
@@ -212,6 +227,300 @@ class StallWatchdog(threading.Thread):
                 "threshold_ms": round(self.threshold * 1000.0, 3)}
 
 
+# ------------------------------------------------------------ phase ledgers
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has jax
+    loaded, else None: a frontend-only process holds no device to trace
+    and never imports jax for a bracket's sake."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+class _Phase:
+    """One phase's re-usable nesting bracket (``with ledger.phase(name):``).
+    The ledger lives on its owner; a bracket only parks, on the ledger's
+    stack, the phase to hand the clock back to and its open trace
+    annotation."""
+
+    __slots__ = ("prof", "name", "label")
+
+    def __init__(self, prof: "PhaseLedger", name: str):
+        self.prof = prof
+        self.name = name
+        self.label = prof.prefix + name
+
+    def __enter__(self) -> None:
+        prof = self.prof
+        stack = prof._open
+        stack.append(prof._switch(self.name))
+        stack.append(prof.annotation(self.label))
+
+    def __exit__(self, *exc) -> None:
+        stack = self.prof._open
+        stack.pop().__exit__(*exc)
+        self.prof._switch(stack.pop())
+
+
+class PhaseLedger:
+    """One thread's time as a ledger: the clock always runs for exactly
+    one of ``phases``, entering one closes the interval of whichever ran
+    before, so the slots add up to the thread's wall time by
+    construction. All mutation happens on the owning thread; a snapshot
+    may be read from any.
+
+    Two bracket styles over the one ``_switch``, one style a ledger:
+
+    - **nesting** (``with ledger.phase(name):``, the step thread): the
+      exit hands the clock back to the phase around it;
+    - **flat** (``enter(name)`` ... ``leave(name)``, the event loop and
+      the pool workers, where brackets of interleaved tasks cannot
+      nest): ``enter`` takes the clock from whoever holds it, ``leave``
+      hands it to ``base``, and does nothing when the clock was taken
+      over meanwhile (the bracket's task was suspended: its interval,
+      and its trace event, ended where the other bracket began).
+
+    A bracket is one ``perf_counter`` read each way (with ``cpu`` one
+    ``thread_time`` read beside it) and one ``TraceAnnotation(prefix +
+    name)``: an atomic load while no profiler session is open, an event
+    on this thread's line of ``/host:CPU`` on the device trace's clock
+    while one is."""
+
+    # the two clocks, read into the instance when it is made (a test
+    # hands a ledger its clocks here)
+    clock = staticmethod(time.perf_counter)
+    cpu_clock = staticmethod(time.thread_time)
+
+    def __init__(self, phases: Tuple[str, ...], base: str,
+                 prefix: str = "dyn.", cpu: bool = False,
+                 annotation: Any = None):
+        self.prefix = prefix
+        self.base = base
+        # seconds per phase, the phase the clock is running for, and
+        # when it started running
+        self.phase_seconds: Dict[str, float] = dict.fromkeys(phases, 0.0)
+        # CPU seconds of the owning thread per phase, settled at each
+        # switch (thread_time is the CALLING thread's clock, so a
+        # snapshot from elsewhere cannot add the running interval)
+        self.phase_cpu_seconds: Optional[Dict[str, float]] = (
+            dict.fromkeys(phases, 0.0) if cpu else None)
+        self.phase_calls: Dict[str, int] = dict.fromkeys(phases, 0)
+        self.annotation = annotation or _trace_annotation()
+        self._phases = {n: _Phase(self, n) for n in phases}
+        self._labels = {n: prefix + n for n in phases}
+        self._open: list = []   # nesting: outer phase, annotation a bracket
+        self._ann = None        # flat: the open bracket's annotation
+        self._clock = self.clock
+        self._cpu_clock = self.cpu_clock if cpu else None
+        self._cur = base
+        self._t = self._clock()
+        self._c = 0.0
+        self._ver = 0           # odd while _switch is mid-update
+        self._tid: Optional[int] = None
+        self.native_id: Optional[int] = None
+
+    def bind_thread(self) -> None:
+        """Called on the owning thread before its first bracket (again
+        if the owner changes): the kernel's id of it, for schedstat, and
+        the start of its CPU clock."""
+        self._tid = threading.get_ident()
+        self.native_id = threading.get_native_id()
+        if self._cpu_clock is not None:
+            self._c = self._cpu_clock()
+
+    def phase(self, name: str) -> _Phase:
+        return self._phases[name]
+
+    def _switch(self, name: str) -> str:
+        """Close the running phase's interval and start ``name``'s;
+        returns the phase that was running."""
+        now = self._clock()
+        prev = self._cur
+        if self._cpu_clock is not None:
+            cpu = self._cpu_clock()
+            self._ver += 1
+            self.phase_cpu_seconds[prev] += cpu - self._c
+            self._c = cpu
+        else:
+            self._ver += 1
+        self.phase_seconds[prev] += now - self._t
+        self._cur = name
+        self._t = now
+        self._ver += 1
+        return prev
+
+    def enter(self, name: str) -> None:
+        ann = self._ann
+        if ann is not None:     # taken over from a suspended bracket
+            ann.__exit__(None, None, None)
+        self._switch(name)
+        self.phase_calls[name] += 1
+        cls = self.annotation
+        self._ann = cls(self._labels[name]) if cls is not None else None
+
+    def leave(self, name: str) -> None:
+        if self._cur != name:
+            return
+        ann = self._ann
+        if ann is not None:
+            ann.__exit__(None, None, None)
+            self._ann = None
+        self._switch(self.base)
+
+    def snapshot(self) -> Tuple[Dict[str, float], Optional[Dict[str, float]],
+                                Dict[str, int]]:
+        """({phase: wall seconds}, {phase: CPU seconds} or None, {phase:
+        flat-bracket entries}), cumulative. The wall seconds include the
+        running interval, so two snapshots differ by the wall time
+        between them. Read from any thread: retried while the owner is
+        inside ``_switch``."""
+        for _ in range(16):
+            ver = self._ver
+            seconds = dict(self.phase_seconds)
+            cpu = (dict(self.phase_cpu_seconds)
+                   if self.phase_cpu_seconds is not None else None)
+            calls = dict(self.phase_calls)
+            cur, t = self._cur, self._t
+            if ver == self._ver and not ver & 1:
+                break
+        seconds[cur] += self._clock() - t
+        return seconds, cpu, calls
+
+
+class _NullLedger:
+    """What ``loop_ledger()`` hands out on a loop nobody profiles."""
+
+    def enter(self, name: str) -> None:
+        pass
+
+    leave = enter
+
+    def add(self, name: str, seconds: float) -> None:
+        pass
+
+
+NULL_LEDGER = _NullLedger()
+
+# the event loop's slots (docs/profiling.md has the table of what each
+# covers and which file opens it); "other" is asyncio's and aiohttp's own
+# machinery and the selector's wait: how BUSY the loop thread is comes
+# from its CPU clock (thread_cpu_seconds_total), not from this ledger
+LOOP_PHASES = ("intake", "deliver", "encode_write", "engine_loop", "other")
+# intervals the frontend sums on the loop thread: each a [seconds, count]
+LOOP_SUMS = ("intake", "emit_to_wire", "first_emit_to_wire")
+
+
+class LoopLedger(PhaseLedger):
+    """The event-loop thread's ledger (flat brackets ``dyn.loop.<name>``)
+    and, on the same thread, the frontend's two legs of a request:
+    handler entry -> ``Sequence.arrival`` and ``_emit`` -> the chunk's
+    ``resp.write`` returned."""
+
+    def __init__(self):
+        super().__init__(LOOP_PHASES, "other", prefix="dyn.loop.")
+        self.sums: Dict[str, List[float]] = {n: [0.0, 0] for n in LOOP_SUMS}
+        self.bind_thread()
+
+    def add(self, name: str, seconds: float) -> None:
+        s = self.sums[name]
+        s[0] += seconds
+        s[1] += 1
+
+
+# one flat ledger per pool worker thread, by pool: the dyn-detok workers
+# bracket each decode as ``dyn.detok`` (llm/backend.py)
+_workers: Dict[str, List[PhaseLedger]] = {}
+_worker_local = threading.local()
+_workers_lock = threading.Lock()
+
+
+def worker_ledger(pool: str) -> PhaseLedger:
+    """The calling pool thread's own ledger (slots ``<pool>`` and
+    ``other``: waiting for work), made on its first call."""
+    led = getattr(_worker_local, "ledger", None)
+    if led is None:
+        led = PhaseLedger((pool, "other"), "other")
+        led.bind_thread()
+        _worker_local.ledger = led
+        with _workers_lock:
+            _workers.setdefault(pool, []).append(led)
+    return led
+
+
+_CLK_TCK = os.sysconf("SC_CLK_TCK") if hasattr(os, "sysconf") else 100
+
+
+def _thread_times(native_id: Optional[int]
+                  ) -> Optional[Tuple[float, Optional[float]]]:
+    """(seconds on a CPU, seconds runnable but waiting for one) of one
+    thread of this process, as the kernel counts them in
+    ``/proc/self/task/<tid>/schedstat`` (nanoseconds). A kernel without
+    that file (gVisor, which the benchmark's machines run) still keeps
+    the thread's CPU ticks in ``.../stat`` (utime + stime, 10 ms each)
+    and no run-queue time: then the second is None. None where neither
+    file is there (or the thread is gone)."""
+    if native_id is None:
+        return None
+    task = f"/proc/self/task/{native_id}/"
+    try:
+        with open(task + "schedstat") as f:
+            run_ns, wait_ns = f.read().split()[:2]
+        return int(run_ns) * 1e-9, int(wait_ns) * 1e-9
+    except (OSError, ValueError):
+        pass
+    try:
+        with open(task + "stat") as f:
+            # fields after the command's closing parenthesis: state is
+            # the 3rd of the line, utime and stime the 14th and 15th
+            fields = f.read().rsplit(")", 1)[1].split()
+        return (int(fields[11]) + int(fields[12])) / _CLK_TCK, None
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+class GcClock:
+    """Garbage-collection pauses as the interpreter reports them
+    (``gc.callbacks``): seconds and collections by generation, and a
+    ``dyn.gc`` annotation on whichever thread collects. A collection
+    holds the GIL, so no two overlap."""
+
+    def __init__(self):
+        self.pause_seconds = 0.0
+        self.collections = {"0": 0, "1": 0, "2": 0}
+        self._t = 0.0
+        self._ann = None
+        self._cls = None
+
+    def install(self) -> bool:
+        callbacks = getattr(gc, "callbacks", None)
+        if callbacks is None:
+            return False
+        if self not in callbacks:
+            callbacks.append(self)
+        self._cls = _trace_annotation()
+        return True
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t = time.perf_counter()
+            if self._cls is not None:
+                self._ann = self._cls("dyn.gc")
+        else:
+            self.pause_seconds += time.perf_counter() - self._t
+            gen = str(info.get("generation", 2))
+            self.collections[gen] = self.collections.get(gen, 0) + 1
+            ann, self._ann = self._ann, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+
+
+_gc_clock: Optional[GcClock] = None     # None until a loop profiler starts
+
+
 # ------------------------------------------------------------- loop profiler
 
 
@@ -226,6 +535,8 @@ class LoopProfiler:
                                  or 250.0) / 1000.0
         self.watchdog = (StallWatchdog(self.monitor, stall_threshold_s)
                          if stall_threshold_s > 0 else None)
+        # made on the loop's own thread (acquire_loop_profiler runs there)
+        self.ledger = LoopLedger()
         self._started = False
 
     def start(self) -> None:
@@ -257,7 +568,7 @@ _latest: Optional[LoopProfiler] = None  # last started (stats() fallback)
 def acquire_loop_profiler() -> LoopProfiler:
     """Start (or join) the running loop's profiler. Must be called from
     the event loop; pair with :func:`release_loop_profiler`."""
-    global _latest
+    global _latest, _gc_clock
     loop = asyncio.get_running_loop()
     key = id(loop)
     with _lp_lock:
@@ -267,6 +578,11 @@ def acquire_loop_profiler() -> LoopProfiler:
             _loop_profilers[key] = ent
         ent[1] += 1
         prof = ent[0]
+        # an engine may have brought jax in since the ledger was made
+        prof.ledger.annotation = _trace_annotation()
+        clock = _gc_clock or GcClock()
+        if clock.install():
+            _gc_clock = clock
     prof.start()
     _latest = prof
     return prof
@@ -299,6 +615,63 @@ def current_loop_profiler() -> Optional[LoopProfiler]:
         if ent is not None:
             return ent[0]
     return _latest
+
+
+def loop_ledger():
+    """The running loop's ledger, for a caller on that loop's thread
+    (looked up once a request, not once a bracket); a ledger whose
+    brackets do nothing where no profiler was acquired on this loop."""
+    try:
+        ent = _loop_profilers.get(id(asyncio.get_running_loop()))
+    except RuntimeError:
+        return NULL_LEDGER
+    return ent[0].ledger if ent is not None else NULL_LEDGER
+
+
+def host_stats(step_native_id: Optional[int] = None) -> dict:
+    """What engine ``stats()`` carries of the host beside the step
+    thread's own ledger, process-wide like ``loop_lag_*`` (the serving
+    loop, found as ``loop_lag_snapshot`` finds it): cumulative, so a
+    reader takes deltas. A key is ABSENT, not zero, where its source is:
+    no profiled loop, no per-thread file under ``/proc`` (or, for the
+    run-queue time, no ``schedstat``), no ``gc.callbacks``."""
+    out: dict = {}
+    with _workers_lock:
+        detok = list(_workers.get("detok", ()))
+    prof = current_loop_profiler()
+    if prof is not None:
+        seconds, _, calls = prof.ledger.snapshot()
+        # the workers' brackets beside the loop's slots: another
+        # thread's time, so NOT part of the sum that equals wall time
+        snaps = [w.snapshot() for w in detok]
+        seconds["detok"] = sum(s[0]["detok"] for s in snaps)
+        calls["detok"] = sum(s[2]["detok"] for s in snaps)
+        out["loop_phase_seconds_total"] = seconds
+        out["loop_phase_calls_total"] = calls
+        out["loop_lag_seconds_total"] = prof.monitor.lag_seconds_total
+        out["loop_lag_samples_total"] = prof.monitor.beats
+        for name, (total, n) in prof.ledger.sums.items():
+            out[f"{name}_seconds_total"] = total
+            out[f"{name}_total"] = n
+    threads = {"step": [step_native_id],
+               "loop": [prof.ledger.native_id] if prof is not None else [],
+               "detok": [w.native_id for w in detok]}
+    cpu: Dict[str, float] = {}
+    runq: Dict[str, float] = {}
+    for name, ids in threads.items():
+        got = [s for s in map(_thread_times, ids) if s is not None]
+        if got:
+            cpu[name] = sum(s[0] for s in got)
+            if all(s[1] is not None for s in got):
+                runq[name] = sum(s[1] for s in got)
+    if cpu:
+        out["thread_cpu_seconds_total"] = cpu
+    if runq:
+        out["thread_runq_wait_seconds_total"] = runq
+    if _gc_clock is not None:
+        out["gc_pause_seconds_total"] = _gc_clock.pause_seconds
+        out["gc_collections_total"] = dict(_gc_clock.collections)
+    return out
 
 
 def loop_lag_snapshot() -> dict:

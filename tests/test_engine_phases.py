@@ -95,14 +95,13 @@ def test_phases_close_on_the_wall_clock(run_async):
 def test_a_nested_phase_takes_the_clock_and_gives_it_back(monkeypatch):
     """A flush inside a dispatch (readback inside dispatch_window) must
     not be counted twice: the ledger hands the clock to the inner phase
-    and back. The test hands the ledger its clock (it reads
-    ``time.perf_counter`` and nothing else of ``time``), so the account
-    is exact whatever else the machine is doing."""
-    import types
+    and back. The test hands the ledger its clock (``PhaseLedger.clock``,
+    read when the ledger is made), so the account is exact whatever else
+    the machine is doing."""
+    from dynamo_tpu.runtime.profiling import PhaseLedger
 
     now = [100.0]
-    monkeypatch.setattr(engine_profiler, "time",
-                        types.SimpleNamespace(perf_counter=lambda: now[0]))
+    monkeypatch.setattr(PhaseLedger, "clock", staticmethod(lambda: now[0]))
 
     def sleep(seconds):
         now[0] += seconds
