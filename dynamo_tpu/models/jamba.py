@@ -24,10 +24,14 @@ d_inner]`` float32 and ``[S, M, (d_conv - 1) * d_inner]``, S slots, M
 Mamba layers; slot-major, so a row's whole state is one contiguous 8.5 MB
 and the gather and scatter by slot move whole slabs) and passes it
 through every program with the rows' slot indices, as it passes the KV
-pools with page tables. A program
-gathers its rows' state once, carries it, and scatters it back once; a
-row that does not advance (padding, frozen by a stop) writes back what
-it read. A prefill chunk that starts at position 0 starts from zeros,
+pools with page tables. A prefill chunk gathers its few rows' state,
+carries it through the layers and scatters it back, row by row in place;
+a decode step (the fused window's, and decode_step) on a TPU leaves ``s``
+in the pool: a Pallas kernel reads each row's ``[N, d_inner]`` block
+where it lies and writes it back there (ops/selective_scan.py), and only
+the conv tail (a tenth of the bytes) is gathered and scattered once a
+program. A row that does not advance (padding, frozen by a stop) writes
+back what it read. A chunk that starts at position 0 starts from zeros,
 whatever the slot held.
 
 The Mamba layers are stacked on a leading axis and ``lax.scan``-ned in
@@ -37,11 +41,12 @@ only (``[n_attn, pages, KV, ps, hd]``) and go through llama.py's paged
 attention: the page scatter, ``_attention``, and the read-only-pool
 window attention with the Pallas decode kernel on a TPU.
 
-The selective scan is plain XLA here, in two forms: a chunk of T tokens
-from a carried state (``_ssm_chunk``: time blocks run side by side from
+The selective scan has two forms: a chunk of T tokens from a carried
+state, plain XLA (``_ssm_chunk``: time blocks run side by side from
 zero and are stitched by their entry states, so nothing of size ``[T,
-d_inner, N]`` is ever held) and one token from a stored state (the
-fused window). Scopes: ``ssm`` around the mixer with
+d_inner, N]`` is ever held), and one token from a stored state: the
+kernel on the pool where the attention kernels run (``_scan_in_place``),
+``_ssm_step`` on gathered rows elsewhere. Scopes: ``ssm`` around the mixer with
 ``ssm.proj``, ``ssm.conv``, ``ssm.scan`` inside; ``attn``, ``mlp``,
 ``lm_head``, ``sample``, ``kv_carry`` as in llama.py.
 """
@@ -62,6 +67,7 @@ from .llama import (KVCacheSpec, Params, _attention, _mlp,
                     _scatter_pages, _scatter_pages_paged, _use_pallas,
                     carry_active, carry_step_update, commit_window,
                     embed_tokens, logits_at, rms_norm)
+from ..ops.selective_scan import selective_scan_step
 from ..runtime.config import env_flag
 
 State = Tuple[jax.Array, jax.Array]     # (ssm [S,M,N,di] f32, conv [S,M,(dc-1)*di])
@@ -226,11 +232,14 @@ def _ssm_chunk(s0, dt, x, b, c, a_neg):
     return s, y
 
 
-def _mamba(cfg: ModelConfig, mp, u, valid, s, tail):
+def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step):
     """The Mamba-1 mixer on a chunk. u [B, T, D] (normed); valid [B, T]
     (a row's valid tokens lead); s [B, N, di] float32 and tail
     [B, d_conv - 1, di]: the rows' state on entry. Returns (out [B, T, D],
-    s, tail) with the state after each row's last valid token."""
+    s, tail) with the state after each row's last valid token. ``step``
+    is the one-token recurrence (T == 1) with _ssm_step's operands and
+    results, ``s`` being whatever it carries: the rows' states, or the
+    pool they lie in (_stack)."""
     f32 = jnp.float32
     B, T, _ = u.shape
     di, N, R, dc = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
@@ -269,8 +278,7 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail):
         with jax.named_scope("ssm.scan"):
             a_neg = -jnp.exp(mp["A_log"].astype(f32)).T         # [N, di]
             if T == 1:      # one token from a stored state
-                s, y = _ssm_step(s, dt[:, 0], xc[:, 0], b[:, 0], c[:, 0],
-                                 a_neg)
+                s, y = step(s, dt[:, 0], xc[:, 0], b[:, 0], c[:, 0], a_neg)
                 y = y[:, None]
             else:           # a chunk from a carried state, in time blocks
                 s, y = _ssm_chunk(s, dt, xc, b, c, a_neg)
@@ -287,13 +295,18 @@ def _at(params: Params, keys, i):
 
 
 def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
-           cache):
-    """All layers on h [B, T, D]. ssm [B, M, N, di] / conv [B, M,
-    (dc-1)*di] are the ROWS' state (gathered by the caller), updated in
-    place layer by layer. ``attend(a, x, cache) -> (out, cache)`` is the
-    attention mixer of attending layer a on the normed input: the caller
-    owns how K/V are cached (pages for a chunk, the window buffer inside
-    the fused window)."""
+           cache, in_pool=None):
+    """All layers on h [B, T, D]. conv [B, M, (dc-1)*di] is the ROWS' conv
+    tail (gathered by the caller), updated layer by layer. ssm is their
+    scan state: the rows' own, [B, M, N, di], sliced and updated a layer
+    like the tail; or, with ``in_pool`` = (the rows' slots [B], the rows
+    that start from zeros [B], the kernel's ``interpret`` flag) and T ==
+    1, the POOL ``[S, M, N, di]`` itself, which each layer's kernel call
+    reads and writes at ``[slots, m]`` and nowhere else: the loops carry
+    the pool's buffer, never a copy of the rows. ``attend(a, x, cache) ->
+    (out, cache)`` is the attention mixer of attending layer a on the
+    normed input: the caller owns how K/V are cached (pages for a chunk,
+    the window buffer inside the fused window)."""
     eps = cfg.rms_norm_eps
     B = h.shape[0]
     dc1, di = cfg.mamba_d_conv - 1, cfg.mamba_d_inner
@@ -329,12 +342,19 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
             mp = _at(params, MAMBA_KEYS, m)
             x = norm(h, lax.dynamic_index_in_dim(
                 params["ln_mixer"], l0 + i, 0, False))
-            out, s, tail = _mamba(
-                cfg, mp, x, valid,
-                lax.dynamic_index_in_dim(ssm, m, 1, False),
-                lax.dynamic_index_in_dim(conv, m, 1, False).reshape(
-                    B, dc1, di))
-            ssm = lax.dynamic_update_index_in_dim(ssm, s, m, 1)
+            tail = lax.dynamic_index_in_dim(conv, m, 1, False).reshape(
+                B, dc1, di)
+            if in_pool is None:
+                out, s, tail = _mamba(
+                    cfg, mp, x, valid,
+                    lax.dynamic_index_in_dim(ssm, m, 1, False), tail)
+                ssm = lax.dynamic_update_index_in_dim(ssm, s, m, 1)
+            else:
+                slots, fresh, interpret = in_pool
+                out, ssm, tail = _mamba(
+                    cfg, mp, x, valid, ssm, tail,
+                    lambda pool, *row: selective_scan_step(
+                        pool, slots, m, *row, fresh, interpret=interpret))
             conv = lax.dynamic_update_index_in_dim(
                 conv, tail.reshape(B, dc1 * di), m, 1)
             return (mlp(h + out, l0 + i), ssm, conv), None
@@ -352,14 +372,25 @@ def _qkv(cfg: ModelConfig, params: Params, a: int, x):
             (x @ params["wv"][a]).reshape(B, T, KV, hd))
 
 
-def _rows(state: State, slots):
-    """The rows' state out of the pool: ([B, M, N, di], [B, M, ...])."""
-    return state[0][slots], state[1][slots]
+def _scan_in_place(allow_pallas: bool, interpret: bool = False):
+    """Where a decode step's scan state lies. None: the rows' state is
+    gathered and _ssm_step runs on it (XLA: the CPU, ``allow_pallas``
+    off). Else the kernel advances it in the pool and this is its
+    ``interpret`` flag. Chosen as the attention kernels are: on a TPU
+    backend, or under the tests' hook (``pallas_interpret``,
+    DYN_PALLAS_INTERPRET off the TPU)."""
+    interpret = interpret or (env_flag("DYN_PALLAS_INTERPRET")
+                              and not env_flag("DYN_DISABLE_PALLAS")
+                              and not _use_pallas())
+    if allow_pallas and (_use_pallas() or interpret):
+        return interpret
+    return None
 
 
-def _store(state: State, slots, ssm, conv) -> State:
-    return (state[0].at[slots].set(ssm),
-            state[1].at[slots].set(conv.astype(state[1].dtype)))
+def _store_rows(pool, slots, rows):
+    """The rows written back to their slots: a scatter along the pool's
+    major axis, in place in a donated pool (row by row, a slab each)."""
+    return pool.at[slots].set(rows.astype(pool.dtype))
 
 
 def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
@@ -370,10 +401,15 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
     Arguments as llama.forward, plus the state pool and the rows' slots.
     Returns (hidden [B, T, D], kv_k, kv_v, state)."""
     valid = positions >= 0
-    ssm, conv = _rows(state, state_slots)
     fresh = positions[:, 0] == 0
-    ssm = jnp.where(fresh[:, None, None, None], 0.0, ssm)
-    conv = jnp.where(fresh[:, None, None], 0, conv)
+    conv = jnp.where(fresh[:, None, None], 0, state[1][state_slots])
+    interpret = _scan_in_place(allow_pallas) if tokens.shape[1] == 1 else None
+    if interpret is None:
+        in_pool = None
+        ssm = jnp.where(fresh[:, None, None, None], 0.0,
+                        state[0][state_slots])
+    else:
+        in_pool, ssm = (state_slots, fresh, interpret), state[0]
 
     def attend(a, x, cache):
         kv_k, kv_v = cache
@@ -391,8 +427,10 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
 
     h = embed_tokens(params, cfg, tokens)
     h, ssm, conv, (kv_k, kv_v) = _stack(params, cfg, h, valid, ssm, conv,
-                                        attend, (kv_k, kv_v))
-    return h, kv_k, kv_v, _store(state, state_slots, ssm, conv)
+                                        attend, (kv_k, kv_v), in_pool)
+    if in_pool is None:
+        ssm = _store_rows(state[0], state_slots, ssm)
+    return h, kv_k, kv_v, (ssm, _store_rows(state[1], state_slots, conv))
 
 
 # ----------------------------------------------------- jitted entry points
@@ -432,17 +470,19 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                           pallas_interpret: bool = False):
     """The fused K-step window of llama.make_decode_window_fn (read-only
     KV pool + window buffer + on-device carry) with the rows' recurrent
-    state carried beside it: gathered from the pool once, advanced by
-    every step a row is active in, scattered back once."""
+    state carried beside it, advanced by every step a row is active in:
+    the conv tails gathered from the pool once and scattered back once;
+    the scan state likewise on the XLA arm, and left in the pool where the
+    kernel runs (_scan_in_place), every step reading and writing the
+    rows' blocks where they lie."""
     from ..engine.sampling import (logprob_aux, sample_tokens,
                                    update_penalty_state)
 
     KV, hd = cfg.num_kv_heads, cfg.head_dim_
     n_attn = len(cfg.attn_layer_ids)
-    pallas_interpret = pallas_interpret or (
-        env_flag("DYN_PALLAS_INTERPRET")
-        and not env_flag("DYN_DISABLE_PALLAS") and not _use_pallas())
-    use_pallas = allow_pallas and (_use_pallas() or pallas_interpret)
+    # one choice for both kernels, the window's attention and the scan
+    scan_interpret = _scan_in_place(allow_pallas, pallas_interpret)
+    use_pallas = scan_interpret is not None
 
     @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
              donate_argnames=("kv_k", "kv_v", "state"))
@@ -455,7 +495,11 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
         start = positions
         wk = jnp.zeros((n_attn, B, k_steps, KV, hd), kv_k.dtype)
         wv = jnp.zeros_like(wk)
-        ssm, conv = _rows(state, state_slots)
+        conv = state[1][state_slots]
+        if scan_interpret is None:
+            in_pool, ssm = None, state[0][state_slots]
+        else:
+            in_pool, ssm = (state_slots, None, scan_interpret), state[0]
 
         def one_step(tok, pos, active, wk, wv, ssm, conv, i):
             def attend(a, x, cache):
@@ -467,7 +511,7 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                     out = _pool_window_attention_pallas(
                         q, kv_k, kv_v, jnp.int32(a), page_table, start,
                         wk_l, wv_l, i, cfg.attn_scale,
-                        interpret=pallas_interpret)
+                        interpret=scan_interpret)
                 else:
                     out = _pool_window_attention(
                         q, kv_k[a], kv_v[a], page_table, start, wk_l, wv_l,
@@ -477,7 +521,8 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
 
             h = embed_tokens(params, cfg, tok)[:, None]
             h, ssm, conv, (wk, wv) = _stack(
-                params, cfg, h, active[:, None], ssm, conv, attend, (wk, wv))
+                params, cfg, h, active[:, None], ssm, conv, attend, (wk, wv),
+                in_pool)
             return (logits_at(params, cfg, h, jnp.zeros(B, jnp.int32)),
                     wk, wv, ssm, conv)
 
@@ -506,7 +551,9 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
         with jax.named_scope("kv_carry"):
             kv_k = commit_window(kv_k, wk, page_table, start, pos)
             kv_v = commit_window(kv_v, wv, page_table, start, pos)
-            state = _store(state, state_slots, ssm, conv)
+            if in_pool is None:
+                ssm = _store_rows(state[0], state_slots, ssm)
+            state = (ssm, _store_rows(state[1], state_slots, conv))
         out_toks = jnp.stack(toks, axis=1)
         carry = (tok, pos, done, steps, remaining)
         if logprobs_topn:

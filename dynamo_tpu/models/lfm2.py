@@ -470,11 +470,11 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                           max_top_k: int = 64, mesh=None,
                           pallas_interpret: bool = False):
     """The fused K-step window of llama.make_decode_window_fn (read-only
-    KV pool + window buffer + on-device carry) with the rows' state
-    carried beside it, as jamba's: gathered from the pool once, advanced
-    by every step a row is active in, scattered back once. A row that
-    fills a page inside the window leaves its state after that token in
-    the page's snapshot (at most one page a row: k_steps <= page size)."""
+    KV pool + window buffer + on-device carry) with the rows' state (48
+    KB a row) carried beside it as jamba's conv tails are: gathered once,
+    advanced by every step a row is active in, scattered back once. A row
+    that fills a page inside the window leaves its state after it in the
+    page's snapshot (at most one page a row: k_steps <= page size)."""
     from ..engine.sampling import (logprob_aux, sample_tokens,
                                    update_penalty_state)
 

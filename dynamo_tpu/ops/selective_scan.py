@@ -1,0 +1,202 @@
+"""Pallas TPU kernel: one token of a Mamba-1 selective scan on recurrent
+state that STAYS IN ITS POOL.
+
+The state of a sequence is float32 ``[N, d_inner]`` a layer (327,680
+bytes at Jamba2-3B's widths), kept in one pool ``[slots, layers, N,
+d_inner]`` that the engine owns (models/jamba.py init_state). A decode
+step's recurrence reads and writes every live row's state once:
+
+    s = exp(dt * A) * s + (dt * x) outer B        y = sum_n s * C
+
+and nothing else: its time is the state's bytes. The XLA form of it
+(models/jamba.py _ssm_step on gathered rows) moved those bytes three
+more times a window: a gather of the rows out of the pool, a copy of the
+gathered rows into the layer loops' carry, a scatter back (1.1 GB each
+at 128 rows; PERF.md, Findings PR 36). Here the pool is an operand left
+in HBM and aliased to the result; the kernel copies a row's ``[N,
+d_inner]`` block from ``pool[slots[b], layer]`` into VMEM, advances it,
+and copies it back to where it lay. No array of the pool's size or of
+the gathered rows' is read or written by any op around it.
+
+Form (ops/paged_attention.py _decode_kernel's, PR 32): grid = (groups of
+G rows,), run in order; a ring of three VMEM slots of ``[G, N, d_inner]``;
+a group's rows come in by one async copy each while the group before
+computes, are advanced in place in the slot, and leave by one async copy
+each while the next group computes. The copies are loops with a traced
+bound (the last group may be short), not unrolled: the body is small
+and lowers once whatever G is.
+
+Rows that share a slot (the engine's padding rows all carry the drop
+slot) are harmless as long as they do not advance: ``dt = 0`` writes
+back the bits that were read, and a copy that races another copy of the
+same bytes to the same place changes nothing. Live rows' slots are
+distinct (one sequence a slot).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+NAME = "selective_scan_step"    # the kernel's name in a device trace
+# Rows a grid step takes. The kernel's time is its copies' (on a v5e at
+# 128 rows 140 us a call with the arithmetic, 139 without it, 42 for the
+# arithmetic alone; 8 / 16 / 32 rows a step within 1% of each other:
+# tools/ssm_step_timing.py; PERF.md, PR 36), so the smallest whole
+# block of sublanes, and the smallest ring, will do.
+ROWS_PER_STEP = 8
+_SLOTS = 3                      # the ring: in flight, computing, leaving
+
+
+def _step_kernel(G: int,
+                 # scalar prefetch
+                 slots_ref, layer_ref, fresh_ref,
+                 # a group's rows of dt, x [G, di] and B, C [G, N, 1];
+                 # A [N, di]; the pool: whole, in HBM
+                 dt_ref, x_ref, b_ref, c_ref, a_ref, pool_in,
+                 y_ref, pool_out, buf, sems):
+    i = pl.program_id(0)
+    steps = pl.num_programs(0)
+    B = slots_ref.shape[0]
+    layer = layer_ref[0]
+
+    def copies(j, do: str, out: bool):
+        """Start (or wait for: ``do``) the copies of group j's rows, pool
+        -> ring slot j % 3 or back; "start" and "wait" build the same
+        descriptors."""
+        k = jax.lax.rem(j, _SLOTS)
+
+        def row(g, carry):
+            where = slots_ref[j * G + g], layer
+            if out:
+                cp = pltpu.make_async_copy(buf.at[k, g], pool_out.at[where],
+                                           sems.at[1, k])
+            else:
+                cp = pltpu.make_async_copy(pool_in.at[where], buf.at[k, g],
+                                           sems.at[0, k])
+            getattr(cp, do)()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(B - j * G, G), row, 0)
+
+    @pl.when(i == 0)
+    def _():
+        copies(i, "start", False)
+
+    # group i - 2 left from the slot group i + 1 comes into
+    @pl.when(i >= 2)
+    def _():
+        copies(i - 2, "wait", True)
+
+    @pl.when(i + 1 < steps)
+    def _():
+        copies(i + 1, "start", False)
+
+    copies(i, "wait", False)
+    k = jax.lax.rem(i, _SLOTS)
+    a = a_ref[...]                                      # [N, di]
+
+    def row(g, carry):
+        at = pl.ds(g, 1)
+
+        # a chunk that starts a sequence starts from zeros, whatever
+        # the slot held
+        @pl.when(fresh_ref[i * G + g] != 0)
+        def _():
+            buf[k, g] = jnp.zeros(buf.shape[2:], buf.dtype)
+
+        dt = dt_ref[at, :]                              # [1, di]
+        s = (jnp.exp(dt * a) * buf[k, g]
+             + (dt * x_ref[at, :]) * b_ref[g])          # [N, di]
+        buf[k, g] = s
+        y_ref[at, :] = jnp.sum(s * c_ref[g], axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(B - i * G, G), row, 0)
+    copies(i, "start", True)
+
+    @pl.when(i == steps - 1)
+    def _():
+        @pl.when(i >= 1)
+        def _():
+            copies(i - 1, "wait", True)
+
+        copies(i, "wait", True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "rows_per_step"))
+def selective_scan_step(pool: jax.Array, slots: jax.Array, layer: jax.Array,
+                        dt: jax.Array, x: jax.Array, b: jax.Array,
+                        c: jax.Array, a_neg: jax.Array,
+                        fresh: jax.Array | None = None, *,
+                        interpret: bool = False,
+                        rows_per_step: int | None = None):
+    """One token of the recurrence for B rows whose state lies in
+    ``pool[slots[b], layer]``, in place.
+
+    pool: [S, M, N, di] float32; slots: [B] int32; ``layer`` a traced
+    int32 scalar (a scalar-prefetch operand: one lowering for every layer
+    of a ``lax.scan``); dt, x: [B, di]; b, c: [B, N]; a_neg: [N, di] =
+    -exp(A_log).T, all float32; ``fresh`` [B] bool: rows that start from
+    zeros instead of what their slot holds. Returns (pool, y [B, di]):
+    models/jamba.py _ssm_step's arithmetic, float32 throughout. A row
+    with dt = 0 leaves its state bit for bit; several such rows may share
+    a slot (the engine's drop slot). Rows that advance must hold distinct
+    slots.
+
+    jit-ted so that every call site of a program shares ONE lowering of
+    the kernel (a window calls it from three layer loops in each of its
+    unrolled steps; a kernel instance costs 0.2-0.4 s to trace and lower
+    at every start: PERF.md, Findings PR 32 and 34). ``rows_per_step`` is
+    the handle of the tests and of tools/ssm_step_timing.py; the model
+    code passes none."""
+    S, M, N, di = pool.shape
+    B = slots.shape[0]
+    G = min(B, rows_per_step or ROWS_PER_STEP)
+    if G < B:
+        assert G % 8 == 0, (B, G)   # a block of rows is whole sublanes
+    if fresh is None:
+        fresh = jnp.zeros((B,), jnp.int32)
+
+    def rows(i, *_):
+        return (i, 0)
+
+    def cols(i, *_):
+        return (i, 0, 0)
+
+    y, pool = pl.pallas_call(
+        functools.partial(_step_kernel, G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(pl.cdiv(B, G),),
+            in_specs=[pl.BlockSpec((G, di), rows),
+                      pl.BlockSpec((G, di), rows),
+                      # B and C ride sublanes, as the state's N does
+                      pl.BlockSpec((G, N, 1), cols),
+                      pl.BlockSpec((G, N, 1), cols),
+                      pl.BlockSpec((N, di), lambda i, *_: (0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((G, di), rows),
+                       pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[pltpu.VMEM((_SLOTS, G, N, di), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2, _SLOTS))]),
+        out_shape=[jax.ShapeDtypeStruct((B, di), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operand 8 (after the three prefetched scalars: dt, x, b, c, a,
+        # pool) IS result 1: the rows are written where they were read
+        input_output_aliases={8: 1},
+        compiler_params=pltpu.CompilerParams(
+            # groups in order: a group's copies are started by the one
+            # before it and waited for by the ones after
+            dimension_semantics=("arbitrary",),
+            # the ring, and room for a row's temporaries and the blocks
+            vmem_limit_bytes=_SLOTS * G * N * di * 4 + (8 << 20)),
+        interpret=interpret,
+        name=NAME,
+    )(slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      fresh.astype(jnp.int32), dt, x, b[:, :, None], c[:, :, None], a_neg,
+      pool)
+    return pool, y

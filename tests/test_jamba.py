@@ -15,6 +15,7 @@ import asyncio
 import importlib.util
 import json
 import os
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -125,15 +126,20 @@ def test_from_hf_config_on_the_catalog_config():
         ModelConfig.from_hf_config(dict(published, sliding_window=4096))
 
 
-@pytest.mark.parametrize("tied,n_prompt", [(False, 21), (True, 21),
-                                           (False, PS - 2)])
-def test_prefill_and_window_match_reference(tied, n_prompt):
+@pytest.mark.parametrize("tied,n_prompt,interpret", [
+    (False, 21, False), (True, 21, False), (False, PS - 2, False),
+    (False, 21, True)], ids=["untied", "tied", "page-edge",
+                             "pallas_interpret"])
+def test_prefill_and_window_match_reference(tied, n_prompt, interpret):
     """(a) and (f): prefill_step then two decode_windows through the
     pools against the reference's full forward, on logits (the window's
     top-8 log-probabilities at each of its steps), with the head tied
     and not. The second window reads from the pool what the first one
     committed: from a prompt of PS - 2 the first window's four rows lie
-    on both sides of a page boundary (commit_window's second page slot)."""
+    on both sides of a page boundary (commit_window's second page slot).
+    ``pallas_interpret``: the window's kernels under interpretation, the
+    scan state advanced in the pool (ops/selective_scan.py); the second
+    window then starts from what the first one's kernel left there."""
     cfg = tiny(tie_word_embeddings=tied)
     params = jamba.init_params(cfg, jax.random.PRNGKey(0))
     assert ("lm_head" in params) == (not tied)
@@ -145,7 +151,8 @@ def test_prefill_and_window_match_reference(tied, n_prompt):
     # padding rows read and wrote the drop slot, and left it as it was
     assert float(jnp.abs(pools.state[0][pools.drop]).max()) == 0.0
 
-    window = jamba.make_decode_window_fn(cfg, True, 64)
+    window = jamba.make_decode_window_fn(cfg, True, 64,
+                                         pallas_interpret=interpret)
     B, K = 2, 4
     first = int(np.argmax(logits))
     carry = (jnp.asarray([first, 0], jnp.int32),
@@ -164,6 +171,8 @@ def test_prefill_and_window_match_reference(tied, n_prompt):
         toks += [int(x) for x in t[0]]
         vals += list(np.asarray(aux[1][0]))
         ids += list(np.asarray(aux[2][0]))
+    # the padding row went through the drop slot and left it as it was
+    assert float(jnp.abs(state[0][pools.drop]).max()) == 0.0
     seq = list(prompt) + [first] + toks
     want = np.asarray(jax.nn.log_softmax(
         ref_logits(params, cfg, seq[:-1]), -1))
@@ -329,14 +338,21 @@ def test_a_slot_is_reused_while_the_previous_window_is_in_flight(run_async):
     assert stats["state_slots_active"] == 0
 
 
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["xla", "pallas_interpret"])
 def test_a_row_that_stops_mid_window_keeps_the_state_of_its_last_token(
-        run_async):
+        run_async, monkeypatch, interpret):
     """(c) max_tokens 3 = one token from prefill and two of a 4-step
     window: the row freezes after step 2. Its slot then holds the state
     after the last token it CONSUMED (prompt + 2 tokens; the third was
     sampled and never fed back). Shown on logits: one more decode step
     from the slot and the pages, on the third token, against the
-    reference's last row."""
+    reference's last row. ``pallas_interpret``: the engine's window and
+    the decode step here run the scan kernel on the pool (under
+    interpretation), where a frozen row's dt = 0 writes back the bits it
+    read."""
+    if interpret:
+        monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
     eng = _engine()
     (p,) = _prompts(5, 19)
     held = []
@@ -434,6 +450,149 @@ def test_models_without_state_take_no_state_operand():
     assert eng.state is None and eng._state_args(None) == ()
     assert eng.pm.prefix_reuse
     assert not [k for k in eng.stats() if k.startswith("state_")]
+
+
+# ------------------------------------------- the scan kernel on the pool
+
+
+def _scan_case(rng, S, M, N, di, slots, still=()):
+    """A pool of random states and one token's operands for the rows at
+    ``slots``; rows in ``still`` (and every row on the last slot, the
+    engine's drop slot) have dt = 0."""
+    B = len(slots)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    dt = rng.uniform(1e-3, 1e-1, (B, di))
+    dt[[b for b in range(B) if b in still or slots[b] == S - 1]] = 0.0
+    return (f(S, M, N, di), jnp.asarray(slots, jnp.int32),
+            (jnp.asarray(dt, jnp.float32), f(B, di), f(B, N), f(B, N),
+             -jnp.exp(f(N, di))))
+
+
+@pytest.mark.parametrize("slots,rows_per_step,still", [
+    ((3,), None, ()),                                   # one row
+    ((5, 0, 3, 1, 6, 2, 4, 7), None, ()),               # eight, permuted
+    ((4, 1, 6), None, (1,)),                            # not a power of two
+    ((7, 2, 9, 0, 5, 11, 3, 10, 1, 8, 6, 4), 8, (3,)),  # a short last group
+    ((2, 12, 5, 12, 12, 0, 12, 9, 12, 12, 12, 12, 12, 12, 12, 12,
+      12, 12, 12, 12, 7, 12, 12, 12), 8, (0,)),         # rows on the drop slot
+], ids=["one", "eight-permuted", "three", "twelve-in-groups",
+        "drop-slot-shared"])
+def test_scan_kernel_in_the_pool_matches_the_step_on_gathered_rows(
+        slots, rows_per_step, still):
+    """ops/selective_scan.py under interpretation against _ssm_step on
+    the gathered rows: y and the rows' new state agree to float32
+    rounding (the same products, the sum over N in another order); a row
+    with dt = 0 keeps its state BIT FOR BIT, and so does the drop slot
+    that several padding rows share, across grid steps; a call on layer m
+    touches no other layer of any slot and no slot that is not listed;
+    a second layer of the same pool then does the same on its own m; a
+    row marked fresh starts from zeros whatever its slot held."""
+    from dynamo_tpu.ops.selective_scan import selective_scan_step
+
+    S, M, N, di = 13, 3, 16, 256
+    rng = np.random.default_rng(len(slots))
+    pool, at, row = _scan_case(rng, S, M, N, di, slots, still)
+    pool0 = np.asarray(pool)
+    idx = np.asarray(slots)
+    for m in (1, 2):                    # two layers of ONE pool, in turn
+        before = np.asarray(pool)
+        want_s, want_y = jamba._ssm_step(pool[at, m], *row)
+        pool, y = selective_scan_step(pool, at, jnp.int32(m), *row,
+                                      interpret=True,
+                                      rows_per_step=rows_per_step)
+        got = np.asarray(pool)
+        live = [b for b in range(len(slots))
+                if float(jnp.abs(row[0][b]).max()) > 0]
+        assert np.abs(np.asarray(y) - np.asarray(want_y)).max() < 1e-4
+        assert np.abs(got[idx[live], m]
+                      - np.asarray(want_s)[live]).max() < 1e-5
+        assert np.abs(got[idx[live], m] - before[idx[live], m]).max() > 1e-3
+        # rows that do not advance, the drop slot among them: the same bits
+        for b in set(range(len(slots))) - set(live):
+            assert (got[idx[b], m] == before[idx[b], m]).all()
+        # nothing else moved: the other layers, the slots no row holds
+        others = [x for x in range(M) if x != m]
+        assert (got[:, others] == before[:, others]).all()
+        unheld = sorted(set(range(S)) - set(slots))
+        assert (got[unheld] == before[unheld]).all()
+    assert (np.asarray(pool)[:, 0] == pool0[:, 0]).all()
+    # a fresh row reads zeros, whatever its slot held
+    fresh = jnp.arange(len(slots)) == 0
+    want_s, want_y = jamba._ssm_step(
+        jnp.where(fresh[:, None, None], 0.0, pool[at, 0]), *row)
+    pool, y = selective_scan_step(pool, at, jnp.int32(0), *row, fresh,
+                                  interpret=True,
+                                  rows_per_step=rows_per_step)
+    assert np.abs(np.asarray(y) - np.asarray(want_y)).max() < 1e-4
+    assert np.abs(np.asarray(pool[at[0], 0])
+                  - np.asarray(want_s[0])).max() < 1e-5
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step"])
+def test_the_kernel_arm_never_gathers_or_scatters_the_scan_pool(program,
+                                                                monkeypatch):
+    """The traced program, not its timing: with the kernel arm on, no
+    ``gather`` / ``scatter`` / ``dynamic_slice`` / ``dynamic_update_slice``
+    of decode_window (or decode_step) has an operand of the scan pool's
+    shape, no value anywhere has the gathered rows' shape, and every
+    kernel call takes the pool as an operand that IS one of its results
+    (``input_output_aliases``). On the XLA arm the same walk finds the
+    gather and the scatter: the check can see what it guards against."""
+    from tests.test_sampling_topk import _eqns  # every equation, nested too
+
+    cfg = tiny()
+    S, B, K = 5, 2, 4
+    pool_shape = (S, jamba.num_mamba_layers(cfg), cfg.mamba_d_state,
+                  cfg.mamba_d_inner)
+    rows_shape = (B,) + pool_shape[1:]
+    params = jax.eval_shape(
+        lambda: jamba.init_params(cfg, jax.random.PRNGKey(0)))
+    kv_k, kv_v = jax.eval_shape(
+        lambda: jamba.init_kv_cache(cfg, KVCacheSpec(16, PS)))
+    state = jax.eval_shape(lambda: jamba.init_state(cfg, S))
+    s = jax.ShapeDtypeStruct
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+
+    def trace(interpret):
+        if program == "window":
+            fn = jamba.make_decode_window_fn(cfg, True, 64,
+                                             pallas_interpret=interpret)
+            return jax.make_jaxpr(partial(fn, k_steps=K, logprobs_topn=0))(
+                params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+                s((B, 8), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+                s((B, 8), jnp.int32), None, state, i32)
+        _, fn = jamba.make_step_fns(cfg)
+        return jax.make_jaxpr(fn)(params, i32, i32, kv_k, kv_v,
+                                  s((B, 8), jnp.int32), i32, state, i32)
+
+    def walk(jaxpr):
+        moves, kernels, rows = [], [], 0
+        for eqn in _eqns(jaxpr.jaxpr):
+            shapes = [getattr(v.aval, "shape", None) for v in eqn.invars]
+            rows += sum(getattr(v.aval, "shape", None) == rows_shape
+                        for v in eqn.outvars)
+            if eqn.primitive.name in ("gather", "scatter", "dynamic_slice",
+                                      "dynamic_update_slice") \
+                    and shapes[0] == pool_shape:
+                moves.append(eqn.primitive.name)
+            if eqn.primitive.name == "pallas_call" and pool_shape in shapes:
+                kernels.append((shapes.index(pool_shape), eqn))
+        return moves, kernels, rows
+
+    moves, kernels, rows = walk(trace(False))
+    assert "gather" in moves and "scatter" in moves and rows and not kernels
+
+    monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")     # decode_step's hook
+    moves, kernels, rows = walk(trace(True))
+    assert moves == [] and rows == 0
+    # one call a run of Mamba layers a step (the jitted wrapper's trace
+    # is shared; the call sites are not)
+    runs = sum(seg[0] == "mamba" for seg in jamba.segments(cfg))
+    assert len(kernels) == runs * (K if program == "window" else 1)
+    for operand, eqn in kernels:
+        aliases = dict(eqn.params["input_output_aliases"])
+        assert operand in aliases
+        assert eqn.outvars[aliases[operand]].aval.shape == pool_shape
 
 
 # ------------------------------------------------------------- refusals

@@ -605,6 +605,98 @@ def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
             < 15.75 * 1024 ** 3)
 
 
+# ---- the selective-scan step on the state pool at Jamba2-3B's widths (cell 4)
+
+
+def _jamba(one_chip):
+    """The configuration as jamba2-3b.reason-decode runs it (full depth,
+    every width as published); params and the three pools as shapes on
+    the described chip, at the cell's engine data."""
+    import json
+
+    from dynamo_tpu.models import jamba
+    from dynamo_tpu.models.config import ModelConfig
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "jamba2-3b.reason-decode.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", "jamba2-3b"))
+    params = _on(one_chip, jax.eval_shape(
+        lambda: jamba.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (_on(one_chip, x) for x in jax.eval_shape(
+        lambda: jamba.init_kv_cache(cfg, llama.KVCacheSpec(e["num_pages"],
+                                                           64))))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: jamba.init_state(cfg, e["max_batch"] + 1)))
+    assert state[0].shape == (129, 26, 16, 5120)
+    return jamba, cfg, params, kv_k, kv_v, state, e
+
+
+@pytest.mark.parametrize("B", [128, 8, 1, 24])
+def test_scan_kernel_compiles(one_chip, B):
+    """ops/selective_scan.py at cell 4's pool and its three batch buckets
+    (and a batch whose last group of rows is short): the chip's compiler
+    takes the row copies, the ring in VMEM and the blocks of rows."""
+    from dynamo_tpu.ops.selective_scan import selective_scan_step
+
+    s = partial(_sds, one_chip)
+    S, M, N, di = 129, 26, 16, 5120
+    f32 = jnp.float32
+    assert _has_kernel(selective_scan_step.lower(
+        s((S, M, N, di), f32), s((B,), jnp.int32), s((), jnp.int32),
+        s((B, di), f32), s((B, di), f32), s((B, N), f32), s((B, N), f32),
+        s((N, di), f32), s((B,), jnp.bool_)).compile())
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
+def test_jamba_programs_write_no_array_of_the_state_pools_size(
+        one_chip, tpu_kernel_path, monkeypatch, program):
+    """models/jamba.py at the shapes of jamba2-3b.reason-decode (state
+    pool [129, 26, 16, 5120] float32 = 1.10 GB): the fused window (B 128,
+    4 steps) and decode_step advance the scan state IN the pool through
+    the kernel: no value of the optimized program has the gathered rows'
+    shape [128, 26, 16, 5120] (the parent gathered them, copied them into
+    the layer loops' carry and scattered them back: 1.96 GiB of
+    temporaries a window, 0.31 now; scratch compile, PR 36), and no copy
+    of a pool's size exists. A prefill chunk (PB 8 x T 512) gathers its
+    eight rows and stores them row by row in place: the pool aliases its
+    input and is never copied."""
+    jamba, cfg, params, kv_k, kv_v, state, e = _jamba(one_chip)
+    monkeypatch.setattr(jamba, "_use_pallas", lambda: True)
+    s = partial(_sds, one_chip)
+    P, B = e["page_buckets"][-1], e["max_batch"]
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    if program == "window":
+        compiled = jamba.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, state, i32, k_steps=4,
+            logprobs_topn=0).compile()
+    elif program == "decode_step":
+        compiled = jamba.make_step_fns(cfg)[1].lower(
+            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, state,
+            i32).compile()
+    else:
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        compiled = jamba.make_step_fns(cfg)[0].lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), state,
+            s((PB,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(text, state[0].size) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in (kv_k, kv_v, *state))
+    if program != "prefill":
+        assert "f32[%d,26,16,5120]" % B not in text
+        assert mem.temp_size_in_bytes < 2 ** 29
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)
+
+
 def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
     """The Pallas kernel's serialized module rides inside the
     tpu_custom_call's opaque config, source locations included: without
