@@ -51,8 +51,8 @@ from ..runtime import blackbox, guard, profiling, slo, tracing
 from ..runtime.config import env_bool, env_int, env_str
 from ..runtime.engine import Context
 from .jit_fence import CompileFence
-from .kv_manager import (RECURRENT_STATE_REFUSAL, ChainHashCache,
-                         PageManager)
+from .kv_manager import (BLOCK_GENERATION_REFUSAL, RECURRENT_STATE_REFUSAL,
+                         ChainHashCache, PageManager)
 from .profiler import EngineProfiler, memory_snapshot
 from .sampling import (SamplingBatch, logprob_aux, sample_tokens,
                        update_penalty_state, verify_greedy_draft)
@@ -160,7 +160,12 @@ class EngineConfig:
     # window). The serving loop is dispatch-latency-bound — per-step host
     # round-trips dwarf the ~ms device compute — so K amortizes dispatch
     # K-fold. EOS/stop/budget masking runs ON DEVICE (rows freeze), so K
-    # can grow without dead compute past a stop.
+    # can grow without dead compute past a stop. One token a row a step
+    # is a property of the configuration: for a model that generates by
+    # diffusion over blocks (ModelConfig.block_length L > 1) this is the
+    # number of TOKENS a row can emit in one window, a multiple of L: the
+    # window generates decode_steps / L whole blocks, each in up to
+    # denoising_steps + 1 forwards of L positions a row.
     decode_steps: int = 4
     # the prefill policy of an iteration, one field. None is prefill
     # priority: an iteration that ships a prefill batch ships no decode
@@ -270,6 +275,17 @@ class EngineConfig:
         }
 
 
+# the columns of the block window's per-row counts, in the order
+# llama._make_block_window_fn returns them ([B, 5])
+_BLOCK_WINDOW_COUNTS = ("blocks", "forwards", "commit_forwards",
+                        "early_exits", "dropped_tokens")
+
+
+def _whole_blocks(n_tokens: int, block: int) -> int:
+    """The positions of n_tokens tokens that lie in whole blocks."""
+    return n_tokens // block * block
+
+
 @dataclass(eq=False)  # identity semantics: `in`/`==` must never deep-compare
 class Sequence:
     req: PreprocessedRequest
@@ -333,6 +349,9 @@ class Sequence:
     # membership instead of rebuilding `x or []` defaults every token
     _stop_set: Optional[frozenset] = None
     _dev_stop_count: int = -1
+    # cfg.block_length of the model that serves it: above 1 (generation
+    # by diffusion over blocks) only whole blocks have K/V in the cache
+    block: int = 1
 
     @property
     def stop_set(self) -> frozenset:
@@ -366,7 +385,12 @@ class Sequence:
         """Tokens whose KV must exist before decode can run. Fresh request:
         the whole prompt (its last logits seed sampling). Resumed after
         preemption: everything except the final token, which is the next
-        decode input (its KV is written by that decode step)."""
+        decode input (its KV is written by that decode step). A model
+        that generates by blocks: the whole blocks of what there is
+        (prompt, and after a preemption what was generated); the tail
+        opens the first block the window generates, as final positions."""
+        if self.block > 1:
+            return _whole_blocks(len(self.tokens), self.block)
         return self.num_prompt if self.generated == 0 else len(self.tokens) - 1
 
 
@@ -377,11 +401,17 @@ class _PendingWindow:
     back is deferred until after the NEXT window is enqueued."""
 
     batch: List[Sequence]
-    toks: jax.Array                 # [B, K] sampled tokens
+    # [B, K] sampled tokens, one a step; for a window of blocks the
+    # tokens by POSITION (a row's new ones start past the final positions
+    # its first block came with)
+    toks: jax.Array
     emitted: jax.Array              # [B] on-device valid-token counts
     carry: tuple                    # (tok, pos, done, steps, remaining)
     index: Dict[int, int] = field(default_factory=dict)  # id(seq) → row
     aux: Optional[tuple] = None     # (lp [B,K], tv [B,K,N], ti [B,K,N])
+    # the block window's per-row counts ([B, 5]: blocks, forwards, commit
+    # forwards, early exits, dropped tokens); None for a window of tokens
+    info: Optional[jax.Array] = None
     processed: bool = False
 
 
@@ -420,6 +450,13 @@ class JaxEngine:
                            or "single")
         model = get_model_module(model_cfg)
         self.mesh = mesh
+        # tokens a decode forward yields a row: 1, or the block of a
+        # model that generates by diffusion over blocks (a property of
+        # the configuration, like the module; no EngineConfig field)
+        self.block = model_cfg.block_length
+        if self.block > 1:
+            _refuse_block_generation(model_cfg, self.ecfg, mesh)
+        self.diffusion = dict.fromkeys(_BLOCK_WINDOW_COUNTS + ("tokens",), 0)
         # a one-device mesh names the replica's OWN device (dynashard's
         # one-chip replicas): params and pools are built and committed
         # there, and the step thread uploads its inputs there — without
@@ -753,6 +790,23 @@ class JaxEngine:
         return (None if self.state is None
                 else np.full(n, self.ecfg.max_batch, np.int32))
 
+    def _blank_tokens(self, n: int) -> np.ndarray:
+        """The window's token operand of n rows that carry nothing: one
+        id a row, or for a model that generates by blocks a block a row
+        with every position masked (-1)."""
+        if self.block == 1:
+            return np.zeros(n, np.int32)
+        return np.full((n, self.block), -1, np.int32)
+
+    def _split_info(self, out):
+        """A window program's results without the per-row counts the
+        block window returns last ((results, counts); counts None for a
+        window of tokens)."""
+        if self.block == 1:
+            return out, None
+        *out, info = out
+        return out, info
+
     @property
     def role(self) -> str:
         return self.latency.role
@@ -808,6 +862,12 @@ class JaxEngine:
                             jnp.zeros((PB,), jnp.int32), pslots,
                             *self._state_args(self._drop_slots(PB),
                                               self._no_src(PB))))
+                    n += 1
+                    if self.block > 1:
+                        # prefill samples nothing and its program has no
+                        # head (logits is None): the first token comes
+                        # out of the first block the window generates
+                        continue
                     # penalties=None EXPLICITLY: the jit cache keys on the
                     # call's (args, kwargs) treedef, so an explicit-None
                     # kwarg and an omitted default are DIFFERENT entries —
@@ -828,7 +888,6 @@ class JaxEngine:
                         # a fence trip the jitted-window variants above
                         # don't cover (DL026, same finding class)
                         logprob_aux(logits, toks, ecfg.max_top_logprobs)
-                    n += 1
             for B in (grid["decode_batches"] if decode else []):
                 tableB = jnp.zeros((B, P), jnp.int32)
                 if ecfg.decode_steps > 1:
@@ -860,8 +919,10 @@ class JaxEngine:
                             # kwargs from omitted defaults (compile-fence
                             # finding, same class as the penalties=None
                             # note above)
-                            out = self._take_state(self.decode_multi_fn(
-                                self.params, jnp.zeros(B, jnp.int32),
+                            out, _ = self._split_info(self._take_state(
+                                self.decode_multi_fn(
+                                self.params,
+                                jnp.asarray(self._blank_tokens(B)),
                                 jnp.zeros(B, jnp.int32) - 1,
                                 jnp.zeros(B, bool), jnp.zeros(B, jnp.int32),
                                 jnp.ones(B, jnp.int32), self.kv_k,
@@ -872,7 +933,7 @@ class JaxEngine:
                                          jnp.int32),
                                 pv, *self._state_args(self._drop_slots(B)),
                                 k_steps=ecfg.decode_steps,
-                                logprobs_topn=topn))
+                                logprobs_topn=topn)))
                             if topn:
                                 (toks, _emitted, _aux, _carry, self.kv_k,
                                  self.kv_v) = out
@@ -988,14 +1049,14 @@ class JaxEngine:
                 # uncommitted coincide on one device)
                 carry = carries.get(Bp) if self.mesh is not None else None
                 if carry is None:
-                    carry = (jnp.zeros(Bp, jnp.int32),
+                    carry = (jnp.asarray(self._blank_tokens(Bp)),
                              jnp.zeros(Bp, jnp.int32),
                              jnp.zeros(Bp, bool), jnp.zeros(Bp, jnp.int32),
                              jnp.ones(Bp, jnp.int32))
                 for Bn in bset:
                     _merge_carry(*carry, jnp.zeros(Bn, jnp.int32),
                                  jnp.zeros(Bn, bool),
-                                 jnp.zeros(Bn, jnp.int32),
+                                 jnp.asarray(self._blank_tokens(Bn)),
                                  jnp.zeros(Bn, jnp.int32) - 1,
                                  jnp.zeros(Bn, jnp.int32),
                                  jnp.ones(Bn, jnp.int32))
@@ -1134,7 +1195,18 @@ class JaxEngine:
         seq = Sequence(req=request, context=context, out=asyncio.Queue(),
                        tokens=list(request.token_ids),
                        num_prompt=len(request.token_ids),
-                       trace_ctx=tracing.get_tracer().current_trace_ctx())
+                       trace_ctx=tracing.get_tracer().current_trace_ctx(),
+                       block=self.block)
+        if self.block > 1 and (
+                _wants_count_state(request.sampling)
+                or getattr(request.sampling, "logit_bias", None)):
+            yield EngineOutput(
+                finish_reason="error", text=BLOCK_GENERATION_REFUSAL.format(
+                    what="a sampling penalty or logit_bias",
+                    why="the counts of a row's tokens change inside a "
+                    "block, between the forwards that make its positions "
+                    "final, and the window keeps no such state"))
+            return
         if context.t_received is not None:
             # the frontend's first leg: its handler's entry to the stamp
             # engine_ttft_seconds_total starts from
@@ -1184,6 +1256,7 @@ class JaxEngine:
             "kv_active_blocks": self.pm.active,
             "kv_total_blocks": self.ecfg.num_pages - 1,
             **self._state_stats(),
+            **self._diffusion_stats(),
             "num_requests_waiting": len(self.waiting),
             "queue_wait_seconds_total": round(self.queue_wait_seconds_total,
                                               4),
@@ -1261,6 +1334,19 @@ class JaxEngine:
                 "state_restores_total": self.state_restores_total,
                 # the pool by slot and, where there is one, by page
                 "state_pool_bytes": int(sum(x.nbytes for x in self.state))}
+
+    def _diffusion_stats(self) -> dict:
+        """stats() of a model that generates by diffusion over blocks
+        (none for any other): blocks started by a live row, forwards a
+        row went through (denoising + commit) and the commit forwards
+        among them, tokens emitted, tokens generated past a stop id or
+        the budget inside a block and dropped, blocks that finished in
+        fewer denoising forwards than their schedule. Summed from the
+        window program's own counts at read-back (_process_window);
+        ``decode_tokens_total`` keeps counting tokens, not forwards."""
+        if self.block == 1:
+            return {}
+        return {f"diffusion_{k}_total": v for k, v in self.diffusion.items()}
 
     def _windowed_hit_rate(self) -> float:
         """Prefix-hit tokens / prompt tokens over the admission window
@@ -1883,7 +1969,9 @@ class JaxEngine:
         # one on-device sampling pass over the full bucket (avoids a fresh
         # compile per finishing-count); skipped entirely when every
         # finishing row is a preemption-resume (next token already sampled)
-        if any(s.generated == 0 for _, s in finishing):
+        # and for a model that generates by blocks (the logits at the
+        # prompt's last position are of that position's own token)
+        if self.block == 1 and any(s.generated == 0 for _, s in finishing):
             sampled, aux = self._sample_device(batch, logits)
         else:
             sampled, aux = None, None
@@ -1983,7 +2071,7 @@ class JaxEngine:
         with self.profiler.phase("process_prefill"):
             for i, seq in pf.finishing:
                 self._commit_full_pages(seq)
-                if seq.generated == 0:
+                if seq.generated == 0 and self.block == 1:
                     self._append_token(seq, int(toks[i]),
                                        lp=self._lp_entry(seq, aux, i))
                     if seq.finished is None:
@@ -1991,6 +2079,7 @@ class JaxEngine:
                         self.running.append(seq)
                 else:
                     # resumed after preemption: last token already sampled
+                    # (or a model that generates by blocks: nothing is)
                     seq.last_token = seq.tokens[-1]
                     # proto: request.lifecycle prefill->decode
                     self.running.append(seq)
@@ -2256,7 +2345,11 @@ class JaxEngine:
         back. Rows carried over from the in-flight window take their
         (token, position, done, step, budget) state from the on-device
         carry — the host's lagging view never enters the feedback loop —
-        while newly admitted rows are seeded from host state. ``batch``
+        while newly admitted rows are seeded from host state. For a model
+        that generates by blocks the carry's token is a block a row
+        ([B, L], -1 = masked) and its position the block's start: a
+        carried row starts a fresh block, a host-seeded one the block
+        its tail opens. ``batch``
         restricts the window to a subset of running rows (the spec-decode
         fallback arm, which has already swept cancellations)."""
         K = self.ecfg.decode_steps
@@ -2274,7 +2367,9 @@ class JaxEngine:
         if not batch:
             return None
         # grow pages to cover this window AND the in-flight one (device
-        # positions can lead host state by up to K tokens)
+        # positions can lead host state by up to K tokens; a window of
+        # blocks writes whole blocks from the host's last whole block on,
+        # which is no further)
         self._grow_or_preempt(batch, 2 * K)
         # the flush inside _grow_or_preempt may have finished rows
         batch = [s for s in batch
@@ -2339,7 +2434,7 @@ class JaxEngine:
                                           d_seeds, d_eos, d_sslots))
         from_carry = np.zeros(B, bool)
         src = np.zeros(B, np.int32)
-        ntok = np.zeros(B, np.int32)
+        ntok = self._blank_tokens(B)
         npos = np.full(B, -1, np.int32)
         nsteps = np.zeros(B, np.int32)
         nrem = np.ones(B, np.int32)
@@ -2348,8 +2443,15 @@ class JaxEngine:
                 from_carry[i] = True
                 src[i] = prev.index[id(seq)]
             else:
-                ntok[i] = seq.last_token
-                npos[i] = len(seq.tokens) - 1
+                if self.block > 1:
+                    # the row's next block: what lies past its whole
+                    # blocks is final from the start, the rest masked
+                    npos[i] = seq.prefill_extent
+                    tail = seq.tokens[npos[i]:]
+                    ntok[i, :len(tail)] = tail
+                else:
+                    ntok[i] = seq.last_token
+                    npos[i] = len(seq.tokens) - 1
                 nsteps[i] = seq.generated
                 nrem[i] = max(min(seq.max_new() - seq.generated,
                                   self.cap_tokens - len(seq.tokens)), 1)
@@ -2365,10 +2467,10 @@ class JaxEngine:
         pen = self._penalty_args(batch, sb, B)
         topn = (self.ecfg.max_top_logprobs
                 if self._wants_logprobs(batch) else 0)
-        out = self._take_state(self.decode_multi_fn(
+        out, info = self._split_info(self._take_state(self.decode_multi_fn(
             self.params, tok, pos, done, steps, rem, self.kv_k, self.kv_v,
             d_table, d_temp, d_topk, d_topp, d_seeds, d_eos, pen,
-            *self._state_args(d_sslots), k_steps=K, logprobs_topn=topn))
+            *self._state_args(d_sslots), k_steps=K, logprobs_topn=topn)))
         if topn:
             toks, emitted, aux, carry, self.kv_k, self.kv_v = out
         else:
@@ -2379,6 +2481,7 @@ class JaxEngine:
         self.steps += 1
         pend = _PendingWindow(batch=list(batch), toks=toks,
                               emitted=emitted, carry=carry, aux=aux,
+                              info=info,
                               index={id(s): i for i, s in enumerate(batch)})
         self._inflight.append(pend)
         return pend
@@ -2405,6 +2508,12 @@ class JaxEngine:
             # flight.
             counts = np.asarray(pend.emitted)
             done = np.asarray(pend.carry[2])
+            info = None if pend.info is None else np.asarray(pend.info)
+        if info is not None:
+            for k, v in zip(_BLOCK_WINDOW_COUNTS,
+                            info[:len(pend.batch)].sum(axis=0)):
+                self.diffusion[k] += int(v)
+            self.diffusion["tokens"] += int(counts[:len(pend.batch)].sum())
         if pend in self._inflight:
             self._inflight.remove(pend)
         if self._pending is pend:
@@ -2416,17 +2525,24 @@ class JaxEngine:
             for i, seq in enumerate(pend.batch):
                 if seq.finished is not None:
                     continue
+                # a window of blocks returns tokens by position: a row's
+                # new ones start past the final positions its first block
+                # came with (the host's tokens are current here: windows
+                # are read back in order)
+                off = len(seq.tokens) % self.block
                 if not seq.context.stopped \
                         and self._device_stops_complete(seq):
                     # one chunk per row-window, cut by the device's
                     # emitted count and done flag
                     emitted += self._append_row(
-                        seq, toks[i], int(counts[i]), bool(done[i]), aux, i)
+                        seq, toks[i], int(counts[i]), bool(done[i]), aux, i,
+                        off)
                     continue
                 # token by token, for the rows the device cannot speak
                 # for: a stop list wider than max_eos_ids (the host's
                 # check wins, the device lags) and cancelled rows
-                for j in range(K):
+                for j in (range(K) if self.block == 1 else
+                          range(off, off + int(counts[i]))):
                     if seq.finished is not None or seq.context.stopped:
                         break  # tokens past EOS/stop are discarded
                     self._append_token(seq, int(toks[i, j]),
@@ -2441,7 +2557,9 @@ class JaxEngine:
     def _count_decode_slots(self, batch: List[Sequence], B: int,
                             K: int) -> None:
         """Fill counters of one decode dispatch: live rows x K steps
-        against the B x K slots of the bucket that ``_pick`` chose."""
+        against the B x K slots of the bucket that ``_pick`` chose (K
+        tokens a row a window either way: K steps of one token, or K / L
+        blocks of L)."""
         self.decode_rows_total += len(batch) * K
         self.decode_slots_total += B * K
         self.decode_windows_total += 1
@@ -2457,13 +2575,13 @@ class JaxEngine:
         return seq.dev_stop_count <= self.ecfg.max_eos_ids
 
     def _append_row(self, seq: Sequence, row: np.ndarray, n: int,
-                    dev_done: bool, aux, i: int) -> int:
+                    dev_done: bool, aux, i: int, off: int = 0) -> int:
         """Bulk-append one window row using the device's valid-token
         count: ONE EngineOutput (one cross-thread wakeup) for the whole
         window instead of one per token, one page-publish sweep, and the
         finish decision read off the device's done flag. Token identity
         with the per-token path is pinned by test."""
-        n = min(n, row.shape[0])
+        n = min(n, row.shape[0] - off)
         if n <= 0:
             if dev_done and seq.finished is None:
                 # row entered the window already frozen but never got its
@@ -2471,7 +2589,7 @@ class JaxEngine:
                 # window processing) — terminate so it can't re-dispatch
                 self._terminate(seq, FINISH_LENGTH)
             return 0
-        ids = [int(t) for t in row[:n]]
+        ids = [int(t) for t in row[off:off + n]]
         prev_filled = len(seq.tokens)
         seq.tokens.extend(ids)
         seq.last_token = ids[-1]
@@ -2479,7 +2597,8 @@ class JaxEngine:
         self.decode_tokens_total += n
         lps = tops = None
         if aux is not None and seq.req.output.logprobs is not None:
-            entries = [self._lp_entry(seq, aux, i, j) for j in range(n)]
+            entries = [self._lp_entry(seq, aux, i, off + j)
+                       for j in range(n)]
             lps = [e[0] for e in entries]
             tops = [e[1] for e in entries]
         self._emit(seq, EngineOutput(
@@ -2488,11 +2607,7 @@ class JaxEngine:
         # prefix-cache publish when the row crossed a page boundary (same
         # len-1 publishable-extent rule as _append_token; commit_chain
         # dedups blocks already published)
-        filled = len(seq.tokens)
-        ps = self.ecfg.page_size
-        if (filled - 1) // ps > max(prev_filled - 1, 0) // ps:
-            self.pm.commit_chain(seq.pages, seq.tokens, filled - 1,
-                                 chain=self._chain(seq))
+        self._publish(seq, prev_filled)
         if dev_done:
             last = ids[-1]
             hit = last in seq.stop_set
@@ -2649,15 +2764,11 @@ class JaxEngine:
         # is len(tokens) - 1 positions, one token past the page boundary.
         # Committing at filled % ps == 0 (the pre-pipelining rule) would
         # publish a page whose last slot is junk and poison later hits.
-        filled = len(seq.tokens)
-        ps = self.ecfg.page_size
-        if (filled - 1) >= ps and (filled - 1) % ps == 0:
-            # multi-token publish (commit() dedups the already-published
-            # blocks): speculative accepts can append several tokens
-            # between boundary checks, so commit everything the extent
-            # covers, not just the newest block
-            self.pm.commit_chain(seq.pages, seq.tokens, filled - 1,
-                                 chain=self._chain(seq))
+        # (multi-token publish: commit() dedups the already-published
+        # blocks, and speculative accepts can append several tokens
+        # between boundary checks, so _publish commits everything the
+        # extent covers, not just the newest block)
+        self._publish(seq, len(seq.tokens) - 1)
         if eos:
             self._terminate(seq, FINISH_EOS)
         elif (seq.generated >= seq.max_new()
@@ -2666,6 +2777,26 @@ class JaxEngine:
             # the device froze this row at the grid boundary, so stop
             # appending its (repeated) trailing tokens
             self._terminate(seq, FINISH_LENGTH)
+
+    def _kv_extent(self, n_tokens: int) -> int:
+        """Positions of a row of n_tokens tokens whose K/V in the pool is
+        final: all but the newest token's (written when it next serves
+        as a decode input), or for a model that generates by blocks the
+        whole blocks (a block cut by a stop id or the budget is never
+        committed, and the window reads tokens back block by block)."""
+        if self.block > 1:
+            return _whole_blocks(n_tokens, self.block)
+        return n_tokens - 1
+
+    def _publish(self, seq: Sequence, prev_filled: int) -> None:
+        """Publish to the prefix cache the pages that the tokens appended
+        since ``prev_filled`` completed: those the final extent now
+        covers and did not before."""
+        ps = self.ecfg.page_size
+        extent = self._kv_extent(len(seq.tokens))
+        if extent // ps > max(self._kv_extent(prev_filled), 0) // ps:
+            self.pm.commit_chain(seq.pages, seq.tokens, extent,
+                                 chain=self._chain(seq))
 
     def _terminate(self, seq: Sequence, reason: str) -> None:
         """Terminal-state a sequence. The finished flag is set NOW (no
@@ -3170,6 +3301,39 @@ def _refuse_recurrent_state(ecfg: EngineConfig, mesh) -> None:
             "of the layers that keep state"))
 
 
+def _refuse_block_generation(cfg: ModelConfig, ecfg: EngineConfig,
+                             mesh) -> None:
+    """What JaxEngine itself refuses a model that generates by diffusion
+    over blocks (cfg.block_length > 1), at construction."""
+    cfg.check_page_size(ecfg.page_size)
+    if ecfg.decode_steps < cfg.block_length \
+            or ecfg.decode_steps % cfg.block_length:
+        raise ValueError(
+            f"decode_steps ({ecfg.decode_steps}) must be a multiple of "
+            f"block_length ({cfg.block_length}): a window generates whole "
+            f"blocks, decode_steps / block_length of them a row")
+    if ecfg.host_pages > 0:
+        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
+            what="the host KV tier (host_pages > 0)",
+            why="no test shows a page restored from the host against "
+            "the block mask's invariant (a page holds whole blocks)"))
+    if ecfg.spec_decode:
+        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
+            what="spec_decode",
+            why="the verify forward scores one drafted token a position "
+            "under the causal mask, and a block is not drafted token by "
+            "token"))
+    if ecfg.long_prefill_threshold is not None:
+        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
+            what="long_prefill_threshold (ring-attention prefill)",
+            why="the ring's position predicates are causal"))
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
+            what="a mesh of more than one device",
+            why="the block window folds a block's queries into the decode "
+            "kernel's group axis and has no shard_map form"))
+
+
 def _span_ms(start: Optional[float], end: Optional[float]
              ) -> Optional[float]:
     if start is None or end is None:
@@ -3195,7 +3359,9 @@ def _merge_carry(c_tok, c_pos, c_done, c_steps, c_rem, src, from_carry,
     previous batch); fresh rows take the host-provided values. Runs as one
     tiny jitted program so no host sync enters the dispatch path."""
     src = jnp.clip(src, 0, c_tok.shape[0] - 1)
-    tok = jnp.where(from_carry, c_tok[src], n_tok)
+    # a block window's token carry is a block a row ([B, L])
+    tok = jnp.where(from_carry if c_tok.ndim == 1 else from_carry[:, None],
+                    c_tok[src], n_tok)
     pos = jnp.where(from_carry, c_pos[src], n_pos)
     done = jnp.where(from_carry, c_done[src], False)
     steps = jnp.where(from_carry, c_steps[src], n_steps)

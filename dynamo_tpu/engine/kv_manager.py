@@ -51,14 +51,31 @@ RECURRENT_STATE_REFUSAL = (
     "moves or rolls back a state (ROADMAP B7)")
 
 
+BLOCK_GENERATION_REFUSAL = (
+    "{what} is not supported for a model that generates by diffusion "
+    "over blocks (block_length > 1, models/llama.py "
+    "_make_block_window_fn): {why}; its step yields a block a row, and "
+    "only JaxEngine's window arm on one device keeps the books of that "
+    "(ROADMAP B10)")
+
+
 def refuse_recurrent_state(engine, what: str) -> None:
     """Raise where ``engine`` serves a model that keeps recurrent state
     beside its KV pages: the paths that move KV pages between places
-    (host tier, disagg, KV transfer) would leave that state behind."""
+    (host tier, disagg, KV transfer) would leave that state behind. The
+    same paths refuse a model that generates by diffusion over blocks:
+    they hand a sequence over as "pages + the last token", and such a
+    sequence resumes from its whole blocks plus a tail of final
+    tokens."""
     if getattr(engine, "state", None) is not None:
         raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
             what=what, why="it moves KV pages between places, and a "
             "sequence's pages without its state are not the sequence"))
+    if getattr(engine, "block", 1) > 1:
+        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
+            what=what, why="it hands a sequence over as its pages and "
+            "the first token that prefill sampled, and here prefill "
+            "samples none and the pages hold whole blocks only"))
 
 
 def hash_block(parent: int, tokens: Sequence[int]) -> int:

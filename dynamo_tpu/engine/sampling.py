@@ -300,3 +300,72 @@ def logprob_aux(logits: jax.Array, chosen: jax.Array, topn: int):
         logp = jax.nn.log_softmax(logits, axis=-1)
         tv, ti = exact_top_k(logp, topn)
         return _gather_rows(logp, chosen), tv, ti
+
+
+# ------------------------------------------- generation by diffusion (blocks)
+
+
+def sample_with_confidence(logits: jax.Array, temperature: jax.Array,
+                           top_k: jax.Array, top_p: jax.Array,
+                           seeds: jax.Array, step: jax.Array,
+                           max_top_k: int = 64
+                           ) -> Tuple[jax.Array, jax.Array]:
+    """A draw a POSITION for a model that generates by diffusion over
+    blocks (models/llama.py block window): logits [B * L, V] float32 (a
+    row's L positions one after another: made flat, because turning
+    [B, L, V] into rows afterwards is a copy of all of it on a TPU), the
+    per-row sampling parameters [B], ``step`` [B, L] the RNG step of each
+    position (its absolute position, so a draw does not depend on which
+    forward made it). Returns (ids [B, L] int32, p [B, L] float32) with
+    ``p = softmax(logits)[id]``: the confidence the unmasking orders by,
+    of the RAW distribution as ``logprob_aux`` reports it (the family
+    publishes it so; temperature and top-k shape the draw only); by the
+    row's log-sum-exp, so no [B * L, V] array of probabilities is made."""
+    B, L = step.shape
+
+    def rep(a):
+        return jnp.repeat(a, L)
+
+    ids = sample_tokens(logits, rep(temperature), rep(top_k), rep(top_p),
+                        rep(seeds), step.reshape(B * L),
+                        max_top_k=max_top_k, penalties=None)
+    with jax.named_scope("sample"):
+        p = jnp.exp(_gather_rows(logits, ids)
+                    - jax.nn.logsumexp(logits, axis=-1))
+    return ids.reshape(B, L), p.reshape(B, L)
+
+
+def unmask(strategy: str, masked: jax.Array, conf: jax.Array,
+           n: jax.Array, threshold: float, last) -> jax.Array:
+    """Which masked positions of a block one denoising forward makes
+    final. masked [B, L] bool; conf [B, L] the sampled ids'
+    probabilities; n [B] the positions a forward of this block takes
+    (ceil(masked at block start / denoising_steps)); ``last`` (traced
+    bool) is the schedule's last forward, which takes what is left.
+
+    - ``sequential``: the leftmost n masked;
+    - ``low_confidence_static``: the n masked of highest confidence
+      (equal confidences by lower position);
+    - ``low_confidence_dynamic``: every masked position whose confidence
+      passes ``threshold`` if those are at least n, else as static.
+
+    A row with nothing masked picks nothing."""
+    if strategy not in ("sequential", "low_confidence_static",
+                        "low_confidence_dynamic"):
+        raise ValueError(f"unknown remasking strategy {strategy!r}")
+    L = masked.shape[1]
+    if strategy == "sequential":
+        rank = jnp.cumsum(masked.astype(jnp.int32), axis=1) - 1
+    else:
+        c = jnp.where(masked, conf, -1.0)
+        j = jnp.arange(L)
+        before = (c[:, None, :] > c[:, :, None]) | (
+            (c[:, None, :] == c[:, :, None]) & (j[None, None, :]
+                                                < j[None, :, None]))
+        rank = jnp.sum(before & masked[:, None, :], axis=2)
+    pick = masked & (rank < n[:, None])
+    if strategy == "low_confidence_dynamic":
+        high = masked & (conf > threshold)
+        enough = jnp.sum(high, axis=1) >= n
+        pick = jnp.where(enough[:, None], high, pick)
+    return jnp.where(last, masked, pick)

@@ -15,6 +15,10 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+REMASKING_STRATEGIES = ("sequential", "low_confidence_static",
+                        "low_confidence_dynamic")
+
+
 @dataclass
 class ModelConfig:
     model_type: str = "llama"
@@ -96,6 +100,22 @@ class ModelConfig:
     layer_types: tuple = ()
     conv_l_cache: int = 3
     num_dense_layers: int = 0
+    # Generation by diffusion over blocks (model_type "sdar_moe"): the
+    # attention mask is causal across blocks of block_length positions and
+    # bidirectional inside one, the logits at a position are the
+    # distribution of the token AT it (no shift), and text is generated a
+    # block at a time: the block starts as mask_token_id, each denoising
+    # forward makes some of its positions final (remasking_strategy:
+    # "sequential", "low_confidence_static" or "low_confidence_dynamic"
+    # with confidence_threshold), ceil(masked / denoising_steps) a
+    # forward. block_length > 1 switches the decode window's program
+    # (models/llama.py) and the engine's bookkeeping: a step then yields a
+    # block a row, not a token.
+    block_length: int = 1
+    mask_token_id: int = 0
+    denoising_steps: int = 1
+    remasking_strategy: str = "sequential"
+    confidence_threshold: float = 0.9
     dtype: str = "bfloat16"
 
     @property
@@ -105,6 +125,19 @@ class ModelConfig:
     @property
     def has_recurrent_state(self) -> bool:
         return self.mamba_d_state > 0 or "conv" in self.layer_types
+
+    def check_page_size(self, page_size: int) -> None:
+        """Refuse a KV page that a block of the mask straddles: a
+        position's K/V depends on every token up to the end of its block,
+        so a page's content is a function of the prefix up to the page's
+        end (what the prefix cache hashes) only where block_length
+        divides page_size."""
+        if page_size % self.block_length:
+            raise ValueError(
+                f"block_length ({self.block_length}) must divide page_size "
+                f"({page_size}): a page whose last block runs into the "
+                f"next page holds K/V that depends on tokens past the "
+                f"page's end, and a prefix hit on it would not be exact")
 
     @property
     def mamba_d_inner(self) -> int:
@@ -198,26 +231,49 @@ class ModelConfig:
         if mt == "qwen2":
             c.model_type = "llama"  # same decoder shape (GQA + SwiGLU)
             c.attn_bias = True      # qwen2 keeps bias on q/k/v projections
-        if mt in ("qwen3", "qwen3_moe"):
+        if mt in ("qwen3", "qwen3_moe", "sdar_moe"):
             # Qwen3 = Llama GQA + per-head q/k RMSNorm (no qkv bias);
             # the MoE variant routes Mixtral-style (softmax-then-top-k ==
             # top-k-then-softmax after renorm) with its own expert width
             c.model_type = "qwen3"
             c.qk_norm = True
-            if mt == "qwen3_moe":
+            if mt == "sdar_moe":
+                # the Qwen3-MoE layer under a block mask, generated by
+                # diffusion over blocks; the four generation keys are the
+                # family's published defaults where the file leaves them
+                # out
+                c.model_type = "sdar_moe"
+                c.block_length = int(cfg.get("block_length", 4))
+                c.mask_token_id = int(cfg.get("mask_token_id", 151669))
+                c.denoising_steps = int(cfg.get("denoising_steps",
+                                                c.block_length))
+                c.remasking_strategy = cfg.get("remasking_strategy",
+                                               "low_confidence_dynamic")
+                c.confidence_threshold = float(
+                    cfg.get("confidence_threshold", 0.9))
+                if c.remasking_strategy not in REMASKING_STRATEGIES:
+                    raise NotImplementedError(
+                        f"sdar_moe: remasking_strategy "
+                        f"{c.remasking_strategy!r} is not one of "
+                        f"{REMASKING_STRATEGIES}")
+                if c.block_length < 1 or c.denoising_steps < 1:
+                    raise ValueError(
+                        "sdar_moe: block_length and denoising_steps must "
+                        "be at least 1")
+            if mt in ("qwen3_moe", "sdar_moe"):
                 if not cfg.get("norm_topk_prob", False):
                     # our dense-over-experts MoE normalizes the top-k
                     # weights (softmax over the selected logits); the
                     # un-renormalized variant would silently diverge
                     raise NotImplementedError(
-                        "qwen3_moe with norm_topk_prob=false is not "
+                        f"{mt} with norm_topk_prob=false is not "
                         "supported (router weights are renormalized)")
                 if (cfg.get("decoder_sparse_step", 1) != 1
                         or cfg.get("mlp_only_layers")):
                     # every layer is treated as MoE; interleaved dense
                     # layers would need per-layer MLP selection
                     raise NotImplementedError(
-                        "qwen3_moe with dense layers interleaved "
+                        f"{mt} with dense layers interleaved "
                         "(decoder_sparse_step != 1 or mlp_only_layers) "
                         "is not supported")
                 c.num_experts = cfg.get("num_experts", 128)

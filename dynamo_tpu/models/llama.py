@@ -347,12 +347,18 @@ def _softcap_mask(scores: jax.Array, visible: jax.Array,
 
 
 def _visible(kv_pos: jax.Array, q_pos: jax.Array,
-             window: Optional[int], is_sliding) -> jax.Array:
+             window: Optional[int], is_sliding, block: int = 1) -> jax.Array:
     """Causal visibility of kv position j to query position t, with the
     optional Gemma-2 sliding window: on sliding layers only the last
     ``window`` positions (j > t - window) are visible. ``is_sliding`` is
-    a traced bool scalar (layer parity under lax.scan)."""
-    vis = kv_pos <= q_pos
+    a traced bool scalar (layer parity under lax.scan). With ``block``
+    > 1 (cfg.block_length: generation by diffusion over blocks) the mask
+    is causal across blocks and bidirectional inside one: t sees j iff
+    j lies before the end of t's block."""
+    if block > 1:
+        vis = kv_pos < (q_pos // block + 1) * block
+    else:
+        vis = kv_pos <= q_pos
     if window is not None:
         in_win = kv_pos > q_pos - window
         vis = jnp.logical_and(vis, jnp.logical_or(
@@ -365,7 +371,7 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                scale: float, allow_pallas: bool = True,
                mesh=None, softcap: Optional[float] = None,
                window: Optional[int] = None,
-               is_sliding=False) -> jax.Array:
+               is_sliding=False, block: int = 1) -> jax.Array:
     """Dispatch: on a TPU backend decode (T == 1) and a chunk of queries
     (T > 1: prefill, speculative verify) each run their Pallas kernel
     over the row's own pages (ops/paged_attention.py); everything else
@@ -375,7 +381,10 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     1b: the chip's compiler refuses the page copies, see
     _decode_kernel_narrow). With a >1-device ``mesh`` the kernels run per
     model-shard via shard_map (heads follow their kv heads: the *_sharded
-    wrappers)."""
+    wrappers). ``block`` > 1 is the block mask of ``_visible``: the
+    prefill kernel and the XLA arm take it (a decode step of one query
+    does not exist under it; the block window below has its own
+    attention)."""
     # CPU test hook: DYN_PALLAS_INTERPRET drives the kernel-in-engine
     # path in interpret mode — but NEVER on a real TPU backend (a
     # lingering env var must not silently interpret-mode a hardware
@@ -399,7 +408,7 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     eff = None
     if window is not None:
         eff = effective_window(window, is_sliding, B)
-    if T == 1 and pallas_ok:
+    if T == 1 and pallas_ok and block == 1:
         lengths = q_positions[:, 0] + 1  # padding rows: -1 → 0 → zeros out
         lower = None
         if eff is not None:
@@ -422,6 +431,11 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         # instead of the XLA path's dense [B, P*ps, KV, hd] gather and
         # float32 scores over every slot of the table
         if sharded:
+            if block > 1:
+                raise NotImplementedError(
+                    "the sharded prefill kernel has no block mask: a "
+                    "mesh is not supported for a model that generates "
+                    "by diffusion over blocks")
             return paged_attention_prefill_sharded(
                 q, k_pages, v_pages, page_table, q_positions, mesh=mesh,
                 scale=scale, interpret=interp, softcap=softcap,
@@ -429,17 +443,17 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         return paged_attention_prefill(q, k_pages, v_pages, page_table,
                                        q_positions, scale=scale,
                                        interpret=interp, softcap=softcap,
-                                       eff_win=eff)
+                                       eff_win=eff, block=block)
     return _paged_attention(q, k_pages, v_pages, page_table, q_positions,
                             scale, softcap=softcap, window=window,
-                            is_sliding=is_sliding)
+                            is_sliding=is_sliding, block=block)
 
 
 def _paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                      page_table: jax.Array, q_positions: jax.Array,
                      scale: float, softcap: Optional[float] = None,
                      window: Optional[int] = None,
-                     is_sliding=False) -> jax.Array:
+                     is_sliding=False, block: int = 1) -> jax.Array:
     """Gather-based paged GQA attention (XLA path; the Pallas kernel in
     dynamo_tpu/ops/paged_attention.py replaces this on TPU hot paths).
 
@@ -469,7 +483,7 @@ def _paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     # mask [B, T, S]: slot j (logical position) visible iff j <= query pos
     # (and within the sliding window on Gemma-2 sliding layers)
     mask = _visible(jnp.arange(S)[None, None, :], q_positions[:, :, None],
-                    window, is_sliding)
+                    window, is_sliding, block)
     scores = _softcap_mask(scores, mask[:, None, None, :, :], softcap)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgts,bskh->btkgh", probs.astype(v.dtype), v,
@@ -800,6 +814,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             q, k = _qk_headnorm(q, k, lp, cfg)
             q = apply_rope(q, safe_pos, inv_freq)
             k = apply_rope(k, safe_pos, inv_freq)
+            if cfg.block_length > 1:
+                k, v = _block_kv(k, v, k_layer.dtype)
             if page_slots is not None:
                 k_layer = _scatter_pages_paged(k_layer, k, page_slots)
                 v_layer = _scatter_pages_paged(v_layer, v, page_slots)
@@ -810,7 +826,8 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                               scale, allow_pallas=allow_pallas, mesh=mesh,
                               softcap=cfg.attn_logit_softcap,
                               window=cfg.sliding_window,
-                              is_sliding=_sliding_flag(cfg, l_idx))
+                              is_sliding=_sliding_flag(cfg, l_idx),
+                              block=cfg.block_length)
             h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"], lp,
                               "ln_attn_post", cfg)
         x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
@@ -863,11 +880,17 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
                      kv_k: jax.Array, kv_v: jax.Array, page_table: jax.Array,
                      flat_slots: jax.Array, last_idx: jax.Array,
                      page_slots: Optional[jax.Array] = None):
-        """Process prompt chunks [B, T]; returns (logits [B, V], kv_k, kv_v)."""
+        """Process prompt chunks [B, T]; returns (logits [B, V], kv_k, kv_v).
+        For a configuration that generates by blocks the logits are None:
+        its prefill yields no token (the logits at a prompt's last
+        position are of that position's own token), so the program has
+        no head."""
         h, kv_k2, kv_v2 = forward(params, cfg, tokens, positions, kv_k, kv_v,
                                   page_table, flat_slots,
                                   allow_pallas=allow_pallas,
                                   page_slots=page_slots, mesh=mesh)
+        if cfg.block_length > 1:
+            return None, kv_k2, kv_v2
         return logits_at(params, cfg, h, last_idx), kv_k2, kv_v2
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v"))
@@ -931,9 +954,17 @@ def carry_active(done: jax.Array, pos: jax.Array) -> jax.Array:
 def carry_step_update(nxt, tok, pos, done, steps, remaining, eos_table):
     """Shared on-device sequence-carry update for one fused decode step:
     freeze rows that sample a stop token or exhaust their budget. Both
-    fused-window implementations (llama window form and the engine's
-    generic full-forward fallback) MUST use this — the host bookkeeping in
-    _process_window assumes identical stop semantics on every path."""
+    fused-window implementations that yield ONE TOKEN A ROW A STEP (llama
+    window form and the engine's generic full-forward fallback) MUST use
+    this — the host bookkeeping in _process_window assumes identical stop
+    semantics on every path. The block window (_make_block_window_fn),
+    whose step yields a block a row, uses ``block_carry_update`` in its
+    place: the same three stop conditions (a stop id is emitted and
+    freezes the row, the budget counts emitted tokens, a frozen row
+    neither advances nor commits) applied to the block's new positions in
+    order, so what the host assumes (``emitted`` tokens are the row's
+    next tokens, ``done`` says the row froze in this window, the last
+    emitted token decides stop-versus-length) still holds."""
     active = carry_active(done, pos)
     hit_stop = jnp.any(nxt[:, None] == eos_table, axis=1)
     remaining = jnp.where(active, remaining - 1, remaining)
@@ -974,7 +1005,14 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     (lib/runtime/src/pipeline/network/tcp/server.rs); here the analogous
     move is keeping the sampling feedback loop on device.
 
-    Signature matches engine._make_decode_multi's generic fallback."""
+    Signature matches engine._make_decode_multi's generic fallback.
+
+    A configuration that generates by diffusion over blocks
+    (``cfg.block_length`` > 1) gets ``_make_block_window_fn``'s program
+    under the same name and call form: a window of whole blocks."""
+    if cfg.block_length > 1:
+        return _make_block_window_fn(cfg, allow_pallas, max_top_k, mesh,
+                                     pallas_interpret)
     from ..engine.sampling import (logprob_aux, sample_tokens,
                                    update_penalty_state)
 
@@ -1121,6 +1159,333 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
         return out_toks, emitted, carry, kv_k, kv_v
 
     return decode_window
+
+
+
+# ------------------------------- block window (generation by diffusion)
+
+
+def _block_kv(k, v, dtype):
+    """A token's K and V in the type they are kept in, for a
+    configuration that generates by blocks: the one place both of its
+    programs (block-causal prefill, block window) make them, so that
+    tools/diffusion_block_check.py can round them to 8 bits there and
+    show that the cell's agreement check sees the cache's precision."""
+    return k.astype(dtype), v.astype(dtype)
+
+
+def block_carry_update(tok, new, pos, done, steps, remaining, eos_table,
+                       block: int):
+    """The block window's carry update after one block is final: what
+    ``carry_step_update`` does for a step of one token, for a step that
+    yields a block.
+
+    tok [B, L]: the block's final tokens; new [B, L]: positions that were
+    masked when the block began (the others were the prompt's tail). A
+    live row emits its new positions in order while its budget lasts and
+    up to and including the first stop id; what follows in the block is
+    dropped. The row advances (and its block commits) only if every new
+    position was emitted; it freezes if it hit a stop id, spent its
+    budget, or dropped anything. Returns (emit [B, L] bool, pos, done,
+    steps, remaining)."""
+    active = carry_active(done, pos)
+    new = jnp.logical_and(new, active[:, None])
+    count = jnp.cumsum(new.astype(jnp.int32), axis=1)
+    stop = jnp.logical_and(
+        new, jnp.any(tok[:, :, None] == eos_table[:, None, :], axis=2))
+    stops_before = jnp.cumsum(stop.astype(jnp.int32), axis=1) \
+        - stop.astype(jnp.int32)
+    emit = new & (count <= remaining[:, None]) & (stops_before == 0)
+    n_emit = jnp.sum(emit.astype(jnp.int32), axis=1)
+    whole = n_emit == jnp.sum(new.astype(jnp.int32), axis=1)
+    remaining = remaining - n_emit
+    steps = steps + n_emit
+    pos = jnp.where(active & whole, pos + block, pos)
+    done = done | (active & (jnp.any(emit & stop, axis=1)
+                             | (remaining <= 0) | ~whole))
+    return emit, pos, done, steps, remaining
+
+
+def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
+                          max_top_k: int, mesh, pallas_interpret: bool):
+    """The fused decode window of a configuration that generates by
+    diffusion over blocks of L = ``cfg.block_length`` positions: a window
+    of W = k_steps / L BLOCKS a row, under the name and the call form of
+    ``make_decode_window_fn``'s program.
+
+    One block of a row, at positions s .. s + L - 1 (s a multiple of L;
+    everything before s is in the pool or in the window buffer): the
+    input is the block's final tokens (the prompt's tail, in a row's
+    first block) and ``mask_token_id`` elsewhere. While any live row has
+    a masked position (a ``lax.while_loop``: the batch takes as many
+    forwards as its slowest row, at most ``cfg.denoising_steps``): one
+    forward of [B, L] queries (scope ``diffusion.denoise``), then at
+    every masked position a draw and its probability, and
+    ``sampling.unmask`` makes n = ceil(masked at block start /
+    denoising_steps) of them final by ``cfg.remasking_strategy`` (scope
+    ``diffusion.unmask``); a final position never changes again. Then one
+    more forward on the final tokens (``diffusion.commit``), which yields
+    no token: the K/V of a position depends on the tokens of its whole
+    block, so only this forward's K/V may be kept.
+
+    K/V of the window's blocks live in the window buffer [Lyr, B, W * L,
+    KV, hd] only (every forward overwrites its block's L slots) and the
+    pool is read-only; ``commit_window`` writes the W * L positions at
+    the end, of which a row commits the blocks it finished WHOLE
+    (``block_carry_update``). Attention of a block's queries: every
+    pooled position and every buffer slot up to the block's end is
+    visible to EVERY query of the block (the mask is causal across blocks
+    and bidirectional inside one), so no per-query mask exists: on the
+    chip the L queries fold into the decode kernel's group axis (G * L
+    rows a KV head) and the buffer side merges by the kernel's
+    online-softmax statistics, as the one-token window does.
+
+    Carry: ``tokens`` is [B, L], a final token's id or -1 for a masked
+    position (masked-ness is this flag, never ``id == mask_token_id``: a
+    prompt may contain that id); ``positions`` the block's start (-1
+    padding). After a window a continuing row starts a fresh block (all
+    -1). Returns (toks [B, W * L] by position, emitted [B], [aux,] carry,
+    kv_k, kv_v, info [B, 5]): a row's new tokens are
+    ``toks[i, off : off + emitted[i]]`` with ``off`` the final positions
+    its first block came with; ``info`` counts, a live row, blocks,
+    forwards (denoising + commit), commit forwards, blocks that took
+    fewer denoising forwards than their schedule (an early exit of the
+    dynamic strategy) and tokens generated but dropped."""
+    from ..engine.sampling import (logprob_aux, sample_with_confidence,
+                                   unmask)
+
+    L, S = cfg.block_length, cfg.denoising_steps
+    inv_freq = rope_freqs(cfg)
+    scale = cfg.attn_scale
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    pallas_interpret = pallas_interpret or (
+        env_flag("DYN_PALLAS_INTERPRET")
+        and not env_flag("DYN_DISABLE_PALLAS")
+        and not _use_pallas())
+    use_pallas = allow_pallas and (_use_pallas() or pallas_interpret)
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "the block window has no sharded form (engine/jax_engine.py "
+            "refuses a mesh for a configuration with block_length > 1)")
+
+    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
+             donate_argnames=("kv_k", "kv_v"))
+    def decode_window(params, tokens, positions, done, steps, remaining,
+                      kv_k, kv_v, page_table, temperature, top_k, top_p,
+                      seeds, eos_table, penalties=None, *, k_steps: int,
+                      logprobs_topn: int = 0):
+        assert penalties is None, "no penalty state inside a block"
+        assert k_steps % L == 0, (k_steps, L)
+        B = tokens.shape[0]
+        Lyr = cfg.num_layers
+        W = k_steps // L
+        start = positions
+        wdt = kv_k.dtype
+        wk = jnp.zeros((Lyr, B, k_steps, KV, hd), wdt)
+        wv = jnp.zeros((Lyr, B, k_steps, KV, hd), wdt)
+        layer_params = {k: params[k] for k in _layer_keys(cfg)}
+        act = _act(cfg)
+        offs = jnp.arange(L, dtype=jnp.int32)
+
+        def block_forward(x_tok, wk, wv, w: int, want_logits: bool):
+            """x_tok [B, L] at positions start + w * L + (0 .. L-1);
+            the block's K/V overwrite slots [w * L, (w + 1) * L)."""
+            h = embed_tokens(params, cfg, x_tok)            # [B, L, D]
+            q_pos = jnp.maximum(start, 0)[:, None] + (w * L + offs)[None, :]
+
+            def layer(h, xs):
+                lp, l_idx, wk_l, wv_l = xs
+                with jax.named_scope("attn"):
+                    x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                                 cfg.norm_unit_offset)
+                    xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+                    if cfg.attn_bias:
+                        xq, xk, xv = (xq + lp["bq"], xk + lp["bk"],
+                                      xv + lp["bv"])
+                    q, k = _qk_headnorm(xq.reshape(B, L, H, hd),
+                                        xk.reshape(B, L, KV, hd), lp, cfg)
+                    q = apply_rope(q, q_pos, inv_freq)
+                    k = apply_rope(k, q_pos, inv_freq)
+                    k, v = _block_kv(k, xv.reshape(B, L, KV, hd), wdt)
+                    wk_l = lax.dynamic_update_slice_in_dim(wk_l, k, w * L, 1)
+                    wv_l = lax.dynamic_update_slice_in_dim(wv_l, v, w * L, 1)
+                    attn = _block_window_attention(
+                        q, kv_k, kv_v, l_idx, page_table, start, wk_l,
+                        wv_l, (w + 1) * L, scale,
+                        use_pallas=use_pallas, interpret=pallas_interpret)
+                    h = _residual_add(
+                        h, attn.reshape(B, L, H * hd) @ lp["wo"], lp,
+                        "ln_attn_post", cfg)
+                x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps,
+                             cfg.norm_unit_offset)
+                if cfg.num_experts > 0:
+                    with jax.named_scope("moe"):
+                        mlp_out = _moe_mlp(
+                            x, lp["w_router"], lp["w_gate"], lp["w_up"],
+                            lp["w_down"], cfg.num_experts_per_tok, mesh=mesh)
+                else:
+                    mlp_out = _mlp(x, lp["w_gate"], lp["w_up"],
+                                   lp["w_down"], act)
+                h = _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
+                return h, (wk_l, wv_l)
+
+            h, (wk, wv) = lax.scan(
+                layer, h,
+                (layer_params, jnp.arange(Lyr, dtype=jnp.int32), wk, wv))
+            if not want_logits:
+                return None, wk, wv
+            h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps,
+                         cfg.norm_unit_offset)
+            # [B * L, V], a row's positions one after another: every
+            # consumer wants rows, and a reshape of [B, L, V] is a copy
+            return project_logits(params, cfg, h.reshape(B * L, -1)), wk, wv
+
+        tok, pos = tokens, positions
+        out_toks = []
+        emitted = jnp.zeros((B,), jnp.int32)
+        info = jnp.zeros((B, 5), jnp.int32)
+        N = logprobs_topn
+        lps, tvs, tis = [], [], []
+        for w in range(W):
+            active = carry_active(done, pos)
+            masked0 = jnp.logical_and(tok < 0, active[:, None])
+            n0 = jnp.sum(masked0.astype(jnp.int32), axis=1)
+            n_step = (n0 + S - 1) // S
+            aux0 = ((jnp.zeros((B, L), jnp.float32),
+                     jnp.zeros((B, L, N), jnp.float32),
+                     jnp.zeros((B, L, N), jnp.int32)) if N else ())
+            rng_step = pos[:, None] + offs[None, :]
+
+            def denoise(c, w=w, n_step=n_step, rng_step=rng_step):
+                i, tok, masked, wk, wv, fwd, aux = c
+                with jax.named_scope("diffusion.denoise"):
+                    x = jnp.where(tok < 0, cfg.mask_token_id, tok)
+                    logits, wk, wv = block_forward(x, wk, wv, w, True)
+                with jax.named_scope("diffusion.unmask"):
+                    ids, conf = sample_with_confidence(
+                        logits, temperature, top_k, top_p, seeds, rng_step,
+                        max_top_k=max_top_k)
+                    pick = unmask(cfg.remasking_strategy, masked, conf,
+                                  n_step, cfg.confidence_threshold,
+                                  i >= S - 1)
+                    if N:
+                        lp, tv, ti = logprob_aux(logits, ids.reshape(B * L),
+                                                 N)
+                        aux = (jnp.where(pick, lp.reshape(B, L), aux[0]),
+                               jnp.where(pick[..., None],
+                                         tv.reshape(B, L, N), aux[1]),
+                               jnp.where(pick[..., None],
+                                         ti.reshape(B, L, N), aux[2]))
+                    fwd = fwd + jnp.any(masked, axis=1).astype(jnp.int32)
+                    tok = jnp.where(pick, ids, tok)
+                    masked = masked & ~pick
+                return i + 1, tok, masked, wk, wv, fwd, aux
+
+            _, tok, _, wk, wv, fwd, aux = lax.while_loop(
+                lambda c: jnp.any(c[2]), denoise,
+                (jnp.int32(0), tok, masked0, wk, wv,
+                 jnp.zeros((B,), jnp.int32), aux0))
+            with jax.named_scope("diffusion.commit"):
+                # every position final: this forward's K/V are the block's
+                _, wk, wv = block_forward(jnp.maximum(tok, 0), wk, wv, w,
+                                          False)
+            with jax.named_scope("diffusion.unmask"):
+                emit, pos, done, steps, remaining = block_carry_update(
+                    tok, masked0, pos, done, steps, remaining, eos_table, L)
+                n_emit = jnp.sum(emit.astype(jnp.int32), axis=1)
+                emitted = emitted + n_emit
+                live = active.astype(jnp.int32)
+                scheduled = (n0 + jnp.maximum(n_step, 1) - 1) \
+                    // jnp.maximum(n_step, 1)
+                info = info + jnp.stack(
+                    [live, fwd + live, live,
+                     (active & (fwd < scheduled)).astype(jnp.int32),
+                     n0 - n_emit], axis=1)
+            out_toks.append(tok)
+            if N:
+                lps.append(aux[0]); tvs.append(aux[1]); tis.append(aux[2])
+            tok = jnp.full((B, L), -1, jnp.int32)   # the next block: fresh
+
+        with jax.named_scope("kv_carry"):
+            kv_k = commit_window(kv_k, wk, page_table, start, pos)
+            kv_v = commit_window(kv_v, wv, page_table, start, pos)
+        toks = jnp.concatenate(out_toks, axis=1)
+        carry = (tok, pos, done, steps, remaining)
+        if N:
+            aux = (jnp.concatenate(lps, axis=1),
+                   jnp.concatenate(tvs, axis=1),
+                   jnp.concatenate(tis, axis=1))
+            return toks, emitted, aux, carry, kv_k, kv_v, info
+        return toks, emitted, carry, kv_k, kv_v, info
+
+    return decode_window
+
+
+def _block_window_attention(q, k_pools, v_pools, l_idx, page_table, start,
+                            wk_l, wv_l, visible: int, scale,
+                            use_pallas: bool, interpret: bool):
+    """Attention of one block's L queries in the block window: the
+    (frozen) pool for positions < start, and the window buffer's first
+    ``visible`` slots (slot j holds position start + j; the queries'
+    own block ends at ``visible``). Every key on either side is visible
+    to every query of the block, so the only masks are the pool's extent
+    and the buffer's.
+
+    q: [B, L, H, hd]; *_pools: [Lyr, pages, KV, ps, hd]; l_idx: traced
+    scalar; wk_l / wv_l: [B, K, KV, hd]; start: [B] (-1: padding row,
+    sees nothing). On the chip the pool side is the decode kernel with
+    the L queries folded into its group axis (q as [B, KV * L * G, hd],
+    head-major under each KV head), its (m, l) statistics merged with the
+    buffer side's as ``_pool_window_attention_pallas`` merges them; off
+    it, ``_pool_window_attention``'s gather and one softmax."""
+    from ..ops.paged_attention import (NEG_INF,
+                                       paged_attention_decode_layered)
+
+    B, L, H, hd = q.shape
+    KV = wk_l.shape[2]
+    G = H // KV
+    K = wk_l.shape[1]
+    qg = q.reshape(B, L, KV, G, hd).transpose(0, 2, 1, 3, 4)  # [B,KV,L,G,hd]
+    q32 = qg.reshape(B, KV, L * G, hd).astype(jnp.float32)
+    mask_w = (jnp.arange(K)[None, :] < visible) & (start[:, None] >= 0)
+    sw = jnp.einsum("bkrh,bwkh->bkrw", q32,
+                    wk_l.astype(jnp.float32)) * scale      # [B,KV,L*G,K]
+    sw = jnp.where(mask_w[:, None, None, :], sw, NEG_INF)
+    if use_pallas:
+        out_p, m_p, l_p = paged_attention_decode_layered(
+            qg.reshape(B, KV * L * G, hd), k_pools, v_pools, l_idx,
+            page_table, jnp.maximum(start, 0), scale=scale,
+            return_stats=True, interpret=interpret)
+        m_w = jnp.max(sw, axis=-1)
+        p_w = jnp.exp(sw - m_w[..., None])
+        l_w = jnp.sum(p_w, axis=-1)
+        out_w = jnp.einsum("bkrw,bwkh->bkrh", p_w, wv_l.astype(jnp.float32))
+        m_p = m_p.reshape(B, KV, L * G)
+        l_p = l_p.reshape(B, KV, L * G)
+        m_t = jnp.maximum(m_p, m_w)
+        a_p = jnp.exp(m_p - m_t) * l_p
+        a_w = jnp.exp(m_w - m_t)
+        l_t = jnp.maximum(a_p + a_w * l_w, 1e-9)
+        out = (out_p.reshape(B, KV, L * G, hd).astype(jnp.float32)
+               * a_p[..., None] + out_w * a_w[..., None]) / l_t[..., None]
+    else:
+        ps = k_pools.shape[3]
+        S = page_table.shape[1] * ps
+        kp = k_pools[l_idx][page_table].transpose(0, 1, 3, 2, 4).reshape(
+            B, S, KV, hd)
+        vp = v_pools[l_idx][page_table].transpose(0, 1, 3, 2, 4).reshape(
+            B, S, KV, hd)
+        sp = jnp.einsum("bkrh,bskh->bkrs", q32,
+                        kp.astype(jnp.float32)) * scale
+        mask_p = jnp.arange(S)[None, :] < start[:, None]
+        sp = jnp.where(mask_p[:, None, None, :], sp, NEG_INF)
+        p = jax.nn.softmax(jnp.concatenate([sp, sw], axis=-1), axis=-1)
+        out = (jnp.einsum("bkrs,bskh->bkrh", p[..., :S],
+                          vp.astype(jnp.float32))
+               + jnp.einsum("bkrw,bwkh->bkrh", p[..., S:],
+                            wv_l.astype(jnp.float32)))
+    out = out.reshape(B, KV, L, G, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, L, H, hd).astype(q.dtype)
 
 
 def _pool_window_attention_pallas(q, k_pools, v_pools, l_idx, page_table,
@@ -1275,7 +1640,8 @@ def full_attention_layer(cfg: ModelConfig, h: jax.Array, lp: Params,
                         k.astype(jnp.float32)) * scale
     mask = _visible(jnp.arange(T)[None, None, :],
                     jnp.arange(T)[None, :, None],
-                    cfg.sliding_window, is_sliding)  # [1, T, T]
+                    cfg.sliding_window, is_sliding,
+                    cfg.block_length)  # [1, T, T]
     scores = _softcap_mask(scores, mask[:, None, None],
                            cfg.attn_logit_softcap)
     probs = jax.nn.softmax(scores, axis=-1)

@@ -16,6 +16,30 @@ flag per family. Four modules:
   short convolutions and attention, two dense MLPs and then routed
   experts (mla.py's sigmoid gate, llama.py's expert execution).
 
+**Which change the step's shape.** For every configuration but one
+kind, a decode step takes one token a row in and gives one out. A
+configuration with ``block_length`` > 1 (``model_type: sdar_moe``: the
+Qwen3-MoE layer under a block mask, text generated by diffusion over
+blocks) stays in ``llama.py``, whose ``_visible`` / prefill kernel take
+the block mask and whose ``make_decode_window_fn`` then returns the
+BLOCK window (``_make_block_window_fn``, same name ``decode_window``,
+same call form): a window of whole blocks, each up to
+``denoising_steps`` forwards of ``[B, L]`` positions and one commit
+forward; the carry's token operand is a block a row and the program
+returns its own per-row counts last. The engine keys its bookkeeping on
+``cfg.block_length`` (``JaxEngine.block``), never on an option. What
+refuses such a configuration, each by a ``NotImplementedError`` that
+says "<what> is not supported for a model that generates by diffusion
+over blocks (block_length > 1, models/llama.py _make_block_window_fn):
+<why>; its step yields a block a row, and only JaxEngine's window arm
+on one device keeps the books of that (ROADMAP B10)": ``host_pages >
+0``, ``spec_decode``, ``long_prefill_threshold``, a mesh of more than
+one device (``engine/jax_engine.py _refuse_block_generation``), the
+three disagg / KV-transfer classes below (``kv_manager.
+refuse_recurrent_state``), and, by the request, a sampling penalty or
+``logit_bias``. ``page_size`` must be a multiple of ``block_length``
+and ``decode_steps`` a multiple of it (``ValueError``).
+
 **Which keep state.** ``jamba.py`` and ``lfm2.py`` carry per-sequence
 **recurrent state** beside the KV pages. A module declares that by
 having ``init_state(cfg, slots)`` (a tuple of pools indexed by slot);

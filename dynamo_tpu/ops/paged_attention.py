@@ -720,6 +720,7 @@ def _prefill_sizes(T: int, group: int, KV: int, P: int, ps: int, hd: int,
 
 
 def _prefill_kernel(ps: int, G: int, scale: float, softcap: float | None,
+                    block: int,
                     # scalar prefetch
                     pt_ref, first_ref, end_ref, win_ref,
                     # one block of one row's queries; the pools: whole, HBM
@@ -735,7 +736,9 @@ def _prefill_kernel(ps: int, G: int, scale: float, softcap: float | None,
     pools' type; scores, statistics and the accumulator are float32. Rows
     are a KV head's (token, group-head) pairs, flattened outside. kv slot
     j of table entry p holds position p * ps + j, visible to a query at
-    position t iff t - window < p * ps + j <= t."""
+    position t iff t - window < p * ps + j <= t; with ``block`` > 1 (the
+    block mask of generation by diffusion: llama._visible) the upper
+    edge is the end of t's block of ``block`` positions, not t."""
     KV, R, hd = q_ref.shape
     C = G * ps
     b, nq = pl.program_id(0), pl.num_programs(1)
@@ -781,6 +784,12 @@ def _prefill_kernel(ps: int, G: int, scale: float, softcap: float | None,
         _reset_row(m_ref, l_ref, acc_ref)
         q_pos = qpos_ref[...]                              # [R, 1]
         seen = q_pos - win_ref[b]                          # window's edge
+        if block > 1:
+            # the last position of the query's own block (lax.rem: a
+            # padding query at -1 keeps seeing nothing)
+            q_pos = jnp.where(
+                q_pos >= 0,
+                q_pos - jax.lax.rem(q_pos, block) + (block - 1), -1)
 
         def chunk(j, carry):
             slot = (base + j) & 1
@@ -833,7 +842,7 @@ def _prefill_kernel(ps: int, G: int, scale: float, softcap: float | None,
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "softcap",
                                              "block_tokens",
-                                             "pages_per_step"))
+                                             "pages_per_step", "block"))
 def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
                             v_pages: jax.Array, page_table: jax.Array,
                             q_positions: jax.Array, *,
@@ -842,7 +851,8 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
                             softcap: float | None = None,
                             eff_win: jax.Array | None = None,
                             block_tokens: int | None = None,
-                            pages_per_step: int | None = None) -> jax.Array:
+                            pages_per_step: int | None = None,
+                            block: int = 1) -> jax.Array:
     """A chunk of queries against the paged pool (flash form): what
     models/llama.py _attention runs for T > 1 on a TPU.
 
@@ -862,7 +872,11 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
     what they hold. ``block_tokens`` (a divisor of T) and
     ``pages_per_step`` are the handles of the tests and of
     tools/paged_attn_timing.py; the model code passes neither and runs
-    _prefill_sizes' rule by shape."""
+    _prefill_sizes' rule by shape. ``block`` > 1: the mask is causal
+    across blocks of that many positions and bidirectional inside one, so
+    a query sees to the end of its own block (whose K/V the caller has
+    written with the chunk's) and a block of queries reads up to L - 1
+    positions further."""
     B, T, H, hd = q.shape
     _, KV, ps, _ = k_pages.shape
     P = page_table.shape[1]
@@ -889,6 +903,8 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
     # can see to its last query's own; a block of padding has none
     blocks = pos.reshape(B, nq, tq)
     hi = jnp.max(blocks, axis=2) + 1
+    if block > 1:
+        hi = jnp.where(hi > 0, (hi + block - 1) // block * block, hi)
     lo = jnp.min(jnp.where(blocks >= 0, blocks, NO_WINDOW), axis=2) + 1 \
         - eff_win[:, None]
     first = jnp.clip(lo, 0, hi) // ps
@@ -898,7 +914,7 @@ def paged_attention_prefill(q: jax.Array, k_pages: jax.Array,
         return (b, 0, i, 0)
 
     out = pl.pallas_call(
-        functools.partial(_prefill_kernel, ps, G, scale, softcap),
+        functools.partial(_prefill_kernel, ps, G, scale, softcap, block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(B, nq),
             in_specs=[pl.BlockSpec((None, KV, R, hd), rows),

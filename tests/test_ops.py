@@ -288,7 +288,8 @@ def _check_prefill(case, tol=2e-5, **kw):
     if window is not None:
         kw["eff_win"] = jnp.full((q.shape[0],), window, jnp.int32)
     want = _paged_attention(q, k_pages, v_pages, table, positions, scale,
-                            softcap=kw.get("softcap"), **arm)
+                            softcap=kw.get("softcap"),
+                            block=kw.get("block", 1), **arm)
     kw.setdefault("interpret", True)
     got = paged_attention_prefill(q, k_pages, v_pages, table, positions,
                                   scale=scale, **kw)
@@ -406,6 +407,98 @@ def test_prefill_kernel_copies_in_tpu_interpreter(block_tokens,
     case = _prefill_case(16, 2, 4, 128, 8, 8, 16, starts, counts)
     _check_prefill(case, interpret=interp, block_tokens=block_tokens,
                    pages_per_step=pages_per_step)
+
+
+@pytest.mark.parametrize("block", [2, 4, 8])
+@pytest.mark.parametrize("block_tokens,pages_per_step", PREFILL_SIZES)
+def test_prefill_kernel_block_edge_matches_gather(block_tokens,
+                                                  pages_per_step, block):
+    """The block mask of generation by diffusion (causal across blocks of
+    ``block`` positions, bidirectional inside one): a query sees to the
+    end of its own block, so a block of queries reads up to block - 1
+    positions past its last query: into the next page where a chunk ends
+    on a page's last block (row 1: 24 + 8 = 32), and not past the row's
+    last whole block (rows end on a block's end, as the engine's chunks
+    do). Against the XLA arm with the same mask."""
+    case = _prefill_case(21, 2, 2, 32, 8, 8, 16, [0, 24, 0, 8],
+                         [16, 8, 0, 16])
+    _check_prefill(case, block=block, block_tokens=block_tokens,
+                   pages_per_step=pages_per_step)
+
+
+def test_prefill_kernel_block_edge_in_tpu_interpreter():
+    """The block edge under the TPU interpreter (copies arrive when
+    waited for, buffers start as NaN): the page a block of queries now
+    reads beyond its last query's own is copied before it is computed."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                   uninitialized_memory="nan")
+    case = _prefill_case(22, 2, 4, 128, 8, 8, 16, [0, 0, 24, 8],
+                         [0, 16, 8, 16])
+    _check_prefill(case, interpret=interp, block=4, block_tokens=8,
+                   pages_per_step=2)
+
+
+def test_prefill_kernel_block_one_is_the_causal_kernel():
+    """block=1 is the kernel as it was: the same lowered text with and
+    without the argument (the branch is taken at trace time)."""
+    from dynamo_tpu.ops.paged_attention import paged_attention_prefill
+
+    q, k_pages, v_pages, table, positions = _prefill_case(
+        23, 2, 2, 128, 8, 4, 16, [0, 8], [16, 8])
+    low = [paged_attention_prefill.lower(
+        q, k_pages, v_pages, table, positions, scale=0.1, interpret=True,
+        **kw).as_text() for kw in ({}, {"block": 1})]
+    assert low[0] == low[1]
+    other = paged_attention_prefill.lower(
+        q, k_pages, v_pages, table, positions, scale=0.1, interpret=True,
+        block=4).as_text()
+    assert other != low[0]
+
+
+@pytest.mark.parametrize("L,G,hd", [(4, 2, 128), (4, 8, 128), (2, 4, 64),
+                                    (8, 1, 128)])
+def test_block_window_attention_kernel_arm_matches_gather(L, G, hd):
+    """The block window's attention with the L queries of a block folded
+    into the decode kernel's group axis (G x L rows a KV head, interpret
+    mode) and merged with the window buffer by the kernel's statistics,
+    against its XLA arm (one gather, one softmax): rows at different
+    pool extents (none, inside a page, on a page's end), a padding row,
+    a buffer with one and with two visible blocks."""
+    from dynamo_tpu.models.llama import _block_window_attention
+
+    KV, ps, P, num_pages, B, layers = 2, 8, 4, 24, 4, 2
+    rng = np.random.RandomState(31)
+    starts = np.array([0, 8 + L, 3 * ps, -1], np.int32)
+    table = _tables(rng, np.maximum(starts, 0), P, ps, num_pages)
+    k_pools = jnp.asarray(rng.randn(layers, num_pages, KV, ps, hd),
+                          jnp.float32)
+    v_pools = jnp.asarray(rng.randn(layers, num_pages, KV, ps, hd),
+                          jnp.float32)
+    q = jnp.asarray(rng.randn(B, L, KV * G, hd), jnp.float32)
+    wk = jnp.asarray(rng.randn(B, 2 * L, KV, hd), jnp.float32)
+    wv = jnp.asarray(rng.randn(B, 2 * L, KV, hd), jnp.float32)
+    for visible in (L, 2 * L):
+        got, want = (_block_window_attention(
+            q, k_pools, v_pools, jnp.int32(1), jnp.asarray(table),
+            jnp.asarray(starts), wk, wv, visible, hd ** -0.5,
+            use_pallas=arm, interpret=True) for arm in (True, False))
+        live = starts >= 0
+        np.testing.assert_allclose(np.asarray(got)[live],
+                                   np.asarray(want)[live],
+                                   rtol=2e-5, atol=2e-5)
+    # what the buffer's later block holds must not matter to the first
+    first = _block_window_attention(
+        q, k_pools, v_pools, jnp.int32(1), jnp.asarray(table),
+        jnp.asarray(starts), wk.at[:, L:].set(9.0), wv.at[:, L:].set(9.0),
+        L, hd ** -0.5, use_pallas=True, interpret=True)
+    again = _block_window_attention(
+        q, k_pools, v_pools, jnp.int32(1), jnp.asarray(table),
+        jnp.asarray(starts), wk, wv, L, hd ** -0.5, use_pallas=True,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(first)[:3],
+                                  np.asarray(again)[:3])
 
 
 @pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)

@@ -1,0 +1,786 @@
+"""Generation by diffusion over blocks (``model_type: sdar_moe``:
+models/llama.py under a block mask, ``_make_block_window_fn``, the
+engine's bookkeeping of a step that yields a block a row) against the
+plain reference of ``benchmark/configs/sdar-30b-a3b-chat/reference.py``,
+on seeded random float32 weights at tiny widths.
+
+Tolerance: everything here runs in float32 on the CPU, the engine and the
+reference differ in the order of their sums (paged gather against one
+dense softmax, experts by einsum against one at a time), and measured
+differences of log-probabilities are 1e-6 to 3e-6; ATOL = 1e-4 leaves two
+orders of room and is three orders under what a wrong mask, a lost page
+or a stale K/V row reads (0.05 and more, ``test_the_causal_mask_is_seen``).
+"""
+
+import asyncio
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
+from dynamo_tpu.engine.sampling import unmask
+from dynamo_tpu.llm.protocols.common import (OutputOptions,
+                                             PreprocessedRequest,
+                                             SamplingOptions, StopConditions)
+from dynamo_tpu.models import llama
+from dynamo_tpu.models.config import ModelConfig
+from dynamo_tpu.models.llama import DROP_SLOT, KVCacheSpec
+from dynamo_tpu.models.registry import get_model_module
+from dynamo_tpu.runtime.engine import Context
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIG_DIR = os.path.join(ROOT, "benchmark", "configs", "sdar-30b-a3b-chat")
+ATOL = 1e-4
+PS = 8
+MASK = 511
+STRATEGIES = ("sequential", "low_confidence_static",
+              "low_confidence_dynamic")
+
+
+def _reference():
+    spec = importlib.util.spec_from_file_location(
+        "sdar_reference", os.path.join(CONFIG_DIR, "reference.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+REF = _reference()
+
+
+def tiny(**over) -> ModelConfig:
+    hf = dict(model_type="sdar_moe", vocab_size=512, hidden_size=64,
+              intermediate_size=128, moe_intermediate_size=32,
+              num_hidden_layers=2, num_attention_heads=4,
+              num_key_value_heads=2, head_dim=16, rope_theta=10000.0,
+              num_experts=8, num_experts_per_tok=2, norm_topk_prob=True,
+              rms_norm_eps=1e-6, tie_word_embeddings=False,
+              block_length=4, denoising_steps=4, mask_token_id=MASK,
+              remasking_strategy="sequential")
+    hf.update(over)
+    cfg = ModelConfig.from_hf_config(hf)
+    cfg.dtype = "float32"
+    return cfg
+
+
+def make_params(cfg, seed=0, sharp=1.0):
+    """``sharp`` scales the head: at 40 a greedy token's probability
+    passes 0.9 at most positions, which is what lets the dynamic
+    strategy finish a block early."""
+    params = llama.init_params(cfg, jax.random.PRNGKey(seed))
+    params["lm_head"] = params["lm_head"] * sharp
+    return params
+
+
+def ref_logits(params, cfg, tokens):
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(REF.reference_logits(params, cfg, tokens))
+
+
+def _engine(cfg=None, params=None, **over) -> JaxEngine:
+    base = dict(page_size=PS, num_pages=64, max_batch=4, prefill_chunk=16,
+                batch_buckets=(4,), prefill_buckets=(16,),
+                page_buckets=(16,), max_prefill_batch=2, decode_steps=8,
+                warmup_logprobs=False)
+    base.update(over)
+    cfg = cfg or tiny()
+    return JaxEngine(cfg, EngineConfig(**base),
+                     params=params or make_params(cfg), seed=0)
+
+
+def _req(prompt, n, logprobs=None, **stop):
+    stop.setdefault("ignore_eos", True)
+    return PreprocessedRequest(
+        token_ids=[int(t) for t in prompt], sampling=SamplingOptions(),
+        stop=StopConditions(max_tokens=n, **stop),
+        output=OutputOptions(logprobs=logprobs))
+
+
+async def _collect(engine, req):
+    toks, tops, finish = [], [], None
+    async for out in engine.generate(req, Context()):
+        toks.extend(out.token_ids)
+        tops.extend(out.top_logprobs or [])
+        if out.finish_reason is not None:
+            finish = out.finish_reason
+            break
+    return toks, tops, finish
+
+
+async def _gen(engine, prompt, n, logprobs=None, **stop):
+    return await _collect(engine, _req(prompt, n, logprobs, **stop))
+
+
+def _prompts(seed, *lens):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 500, n).tolist() for n in lens]
+
+
+def _max_diff(rows, tops):
+    """Largest gap between the engine's top log-probabilities and the
+    reference's rows (logits [n, V]) at the same positions."""
+    want = np.asarray(jax.nn.log_softmax(jnp.asarray(rows), -1))
+    assert len(tops) == len(rows)
+    return max(abs(want[j][i] - v) for j, top in enumerate(tops)
+               for i, v in top.items())
+
+
+# --------------------------------------------------------- configuration
+
+
+def test_from_hf_config_on_the_catalog_config():
+    """The benchmark's config.json (the catalog row's config, cut to 6
+    layers, plus the generation keys) loads as the Qwen3-MoE shape plus a
+    block mask, and dispatches to models/llama.py."""
+    cfg = ModelConfig.from_local_path(CONFIG_DIR)
+    assert (cfg.model_type, cfg.qk_norm, cfg.num_experts,
+            cfg.num_experts_per_tok, cfg.intermediate_size) == (
+        "sdar_moe", True, 128, 8, 768)
+    assert (cfg.block_length, cfg.denoising_steps, cfg.mask_token_id,
+            cfg.remasking_strategy, cfg.confidence_threshold) == (
+        4, 4, 151669, "sequential", 0.9)
+    assert get_model_module(cfg) is llama
+    assert not cfg.has_recurrent_state
+
+
+def test_generation_keys_default_to_the_familys():
+    cfg = tiny()
+    hf = dict(model_type="sdar_moe", vocab_size=512, hidden_size=64,
+              intermediate_size=128, moe_intermediate_size=32,
+              num_hidden_layers=2, num_attention_heads=4,
+              norm_topk_prob=True)
+    got = ModelConfig.from_hf_config(hf)
+    assert (got.block_length, got.denoising_steps, got.mask_token_id,
+            got.remasking_strategy, got.confidence_threshold) == (
+        4, 4, 151669, "low_confidence_dynamic", 0.9)
+    assert cfg.remasking_strategy == "sequential"
+    with pytest.raises(NotImplementedError, match="remasking_strategy"):
+        ModelConfig.from_hf_config(dict(hf, remasking_strategy="random"))
+    with pytest.raises(NotImplementedError, match="sdar_moe with "
+                       "norm_topk_prob=false"):
+        ModelConfig.from_hf_config(dict(hf, norm_topk_prob=False))
+
+
+@pytest.mark.parametrize("strategy,n,want", [
+    ("sequential", 1, [1, 0, 0, 0]), ("sequential", 2, [1, 1, 0, 0]),
+    ("low_confidence_static", 1, [0, 0, 0, 1]),
+    ("low_confidence_static", 2, [0, 1, 0, 1]),
+    ("low_confidence_dynamic", 1, [0, 1, 0, 1]),    # two pass 0.9 >= n
+    ("low_confidence_dynamic", 3, [1, 1, 0, 1]),    # too few: as static
+])
+def test_unmask_by_hand(strategy, n, want):
+    """One row, positions 0, 1, 3 masked with confidences .1, .95, .97
+    (position 2 is final and its .99 must not count)."""
+    masked = jnp.asarray([[True, True, False, True]])
+    conf = jnp.asarray([[0.1, 0.95, 0.99, 0.97]])
+    got = unmask(strategy, masked, conf, jnp.asarray([n]), 0.9, False)
+    assert np.asarray(got)[0].astype(int).tolist() == want
+    last = unmask(strategy, masked, conf, jnp.asarray([n]), 0.9, True)
+    assert np.asarray(last)[0].tolist() == [True, True, False, True]
+
+
+def test_equal_confidences_go_by_position():
+    masked = jnp.ones((1, 4), bool)
+    conf = jnp.full((1, 4), 0.5)
+    got = unmask("low_confidence_static", masked, conf, jnp.asarray([2]),
+                 0.9, False)
+    assert np.asarray(got)[0].tolist() == [True, True, False, False]
+
+
+# ------------------------------------ (a) programs against the reference
+
+
+class Pools:
+    """One sequence's pages in a small pool, driven as the engine drives
+    the two programs."""
+
+    def __init__(self, cfg, pages=(3, 5, 7, 9, 11, 2)):
+        self.cfg = cfg
+        self.kv_k, self.kv_v = llama.init_kv_cache(cfg, KVCacheSpec(16, PS))
+        self.pages = list(pages)
+        self.prefill, _ = llama.make_step_fns(cfg)
+        self.window = llama.make_decode_window_fn(cfg, True, 64)
+
+    def table(self, rows, width=8):
+        t = np.zeros((rows, width), np.int32)
+        t[0, :len(self.pages)] = self.pages
+        return jnp.asarray(t)
+
+    def run_prefill(self, params, tokens, bucket=32):
+        n = len(tokens)
+        tok = np.zeros((2, bucket), np.int32)
+        pos = np.full((2, bucket), -1, np.int32)
+        slots = np.full((2, bucket), DROP_SLOT, np.int32)
+        at = np.arange(n)
+        tok[0, :n], pos[0, :n] = tokens, at
+        slots[0, :n] = np.asarray(self.pages)[at // PS] * PS + at % PS
+        _, self.kv_k, self.kv_v = self.prefill(
+            params, jnp.asarray(tok), jnp.asarray(pos), self.kv_k,
+            self.kv_v, self.table(2), jnp.asarray(slots),
+            jnp.asarray([max(n - 1, 0), 0]), None)
+
+    def run_window(self, params, tail, start, budget, k_steps=8, topn=0,
+                   stop_ids=()):
+        L = self.cfg.block_length
+        tok = np.full((2, L), -1, np.int32)
+        tok[0, :len(tail)] = tail
+        eos = np.full((2, 8), -1, np.int32)
+        eos[0, :len(stop_ids)] = stop_ids
+        out = self.window(
+            params, jnp.asarray(tok), jnp.asarray([start, -1], jnp.int32),
+            jnp.zeros(2, bool), jnp.zeros(2, jnp.int32),
+            jnp.asarray([budget, 1], jnp.int32), self.kv_k, self.kv_v,
+            self.table(2), jnp.zeros(2), jnp.zeros(2, jnp.int32),
+            jnp.ones(2), jnp.zeros(2, jnp.uint32), jnp.asarray(eos), None,
+            k_steps=k_steps, logprobs_topn=topn)
+        *out, self.kv_k, self.kv_v, info = out
+        return out, np.asarray(info)
+
+
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+@pytest.mark.parametrize("n_prompt", [16, 17, 18, 19, 2])
+def test_prefill_and_every_denoising_forward_match_reference(
+        n_prompt, path, monkeypatch):
+    """(a) block-causal prefill of the prompt's whole blocks, then two
+    blocks through the window (the first opened by the prompt's tail):
+    the FULL log-softmax row of the forward that made each position final
+    against the reference's row for that position, teacher-forced on the
+    window's own tokens; on the XLA arm and with both Pallas kernels in
+    interpret mode (the prefill kernel with the block edge, the decode
+    kernel at group G x L). Logits, not tokens."""
+    if path == "kernels":
+        monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    cfg = tiny()
+    params = make_params(cfg, 1)
+    (prompt,) = _prompts(n_prompt, n_prompt)
+    pools = Pools(cfg)
+    L, V = cfg.block_length, cfg.vocab_size
+    start = n_prompt // L * L
+    if start:
+        pools.run_prefill(params, prompt[:start])
+    (toks, emitted, aux, carry), info = pools.run_window(
+        params, prompt[start:], start, budget=100, topn=V)
+    off = n_prompt - start
+    n = int(emitted[0])
+    assert n == 2 * L - off and int(carry[1][0]) == start + 2 * L
+    new = [int(t) for t in np.asarray(toks)[0, off:off + n]]
+    want = ref_logits(params, cfg, prompt + new[:-1])[n_prompt - 1:]
+    want = np.asarray(jax.nn.log_softmax(jnp.asarray(want), -1))
+    _, tv, ti = (np.asarray(a) for a in aux)
+    for j in range(n):
+        got = np.empty(V, np.float32)
+        got[ti[0, off + j]] = tv[0, off + j]
+        assert np.abs(got - want[j]).max() < ATOL, j
+        assert new[j] == int(np.argmax(want[j]))
+    # a live row's counts: 2 blocks, a denoising forward a new position
+    # and a commit forward a block, nothing early, nothing dropped
+    assert info[0].tolist() == [2, n + 2, 2, 0, 0]
+    assert info[1].tolist() == [0, 0, 0, 0, 0]
+
+
+def test_the_window_commits_whole_blocks_only():
+    """A budget that ends inside the second block: the row emits up to
+    it, freezes, and commits the first block alone; the pool's rows of
+    the second block keep the bytes they had."""
+    cfg = tiny()
+    params = make_params(cfg, 2)
+    (prompt,) = _prompts(3, 16)
+    pools = Pools(cfg)
+    pools.run_prefill(params, prompt)
+    before = np.asarray(pools.kv_k)
+    (toks, emitted, carry), info = pools.run_window(params, [], 16, budget=6)
+    assert int(emitted[0]) == 6 and bool(carry[2][0])
+    assert int(carry[1][0]) == 20                 # one whole block
+    assert info[0].tolist() == [2, 10, 2, 0, 2]   # two tokens dropped
+    after = np.asarray(pools.kv_k)
+    page = pools.pages[2]                         # positions 16 .. 23
+    changed = np.abs(after[:, page] - before[:, page]).sum(axis=(0, 1, 3))
+    assert (changed[:4] > 0).all() and (changed[4:] == 0).all()
+
+
+# --------------------------------- (b) the engine against reference_generate
+
+
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_generate_equals_reference_generate(strategy, run_async):
+    """(b) ``engine.generate`` end to end (prefill of whole blocks, the
+    tail opening the first block, pipelined windows of two blocks, the
+    cut at max_tokens inside a block) against ``reference_generate``'s
+    plain loop, for prompts with tails 0 to 3 and one shorter than a
+    block: the same tokens, the same log-probabilities from the forward
+    that made each position final, and the reference's counts of
+    forwards."""
+    cfg = tiny(remasking_strategy=strategy)
+    params = make_params(cfg, 3)
+    eng = _engine(cfg, params)
+    prompts = _prompts(4, 12, 13, 14, 15, 3)
+
+    async def main():
+        outs = [await _gen(eng, p, 9, logprobs=5) for p in prompts]
+        stats = eng.stats()
+        await eng.stop()
+        return outs, stats
+
+    outs, stats = run_async(main())
+    total = dict.fromkeys(("blocks", "denoise_forwards", "commit_forwards",
+                           "early_exits", "dropped_tokens"), 0)
+    for p, (toks, tops, finish) in zip(prompts, outs):
+        want, rows, counts = REF.reference_generate(params, cfg, p, 9)
+        assert toks == want and finish == "length"
+        assert _max_diff(rows, tops) < ATOL
+        for k in total:
+            total[k] += counts[k]
+    assert stats["diffusion_blocks_total"] == total["blocks"]
+    assert stats["diffusion_forwards_total"] == (
+        total["denoise_forwards"] + total["commit_forwards"])
+    assert stats["diffusion_commit_forwards_total"] == total[
+        "commit_forwards"]
+    assert stats["diffusion_dropped_tokens_total"] == total[
+        "dropped_tokens"]
+    assert stats["diffusion_early_exits_total"] == total["early_exits"]
+    assert stats["diffusion_tokens_total"] == 9 * len(prompts)
+    assert eng.decode_tokens_total == 9 * len(prompts)
+
+
+def test_the_agreement_check_form_is_exact_under_sequential(run_async):
+    """What benchmark/harness/serve.py agree does: the reference is handed
+    ``prompt + toks[:-1]`` and row ``len(prompt) - 1 + j`` is compared
+    with the engine's j-th token, the ninth the first of a third block
+    whose other positions the engine generated and never emitted."""
+    eng = _engine()
+    (p,) = _prompts(9, 24)
+
+    async def main():
+        out = await _gen(eng, p, 9, logprobs=5)
+        await eng.stop()
+        return out
+
+    toks, tops, _ = run_async(main())
+    rows = ref_logits(eng.params, eng.cfg, p + toks[:-1])[len(p) - 1:]
+    assert len(rows) == 9 and _max_diff(rows, tops) < ATOL
+
+
+def test_the_causal_mask_is_seen(run_async):
+    """The control of (a) and (b): the same engine with the CAUSAL mask
+    in its prefill (the program built from the configuration with
+    block_length 1) disagrees with the reference by far more than ATOL,
+    and generates other tokens."""
+    cfg = tiny()
+    params = make_params(cfg, 3)
+    eng = _engine(cfg, params)
+    causal = dataclasses.replace(cfg, block_length=1)
+    eng.prefill_fn, _ = llama.make_step_fns(causal)
+    (p,) = _prompts(4, 12)
+
+    async def main():
+        out = await _gen(eng, p, 9, logprobs=5)
+        await eng.stop()
+        return out
+
+    toks, tops, _ = run_async(main())
+    want, rows, _ = REF.reference_generate(params, cfg, p, 9)
+    rows = ref_logits(params, cfg, p + toks[:-1])[len(p) - 1:]
+    assert _max_diff(rows, tops) > 500 * ATOL
+
+
+# ------------------------------- (c) stops inside a block, what is committed
+
+
+def test_max_tokens_eos_and_a_stop_id_inside_a_block(run_async):
+    """(c) a 16-token prompt and an uncut run of 12 tokens t0..t11. Cut
+    by max_tokens 6, by an EOS id equal to t5, by a stop id equal to t5:
+    the client gets t0..t5 each time (the stop id included, as a row of
+    one token a step gets it), with ``length`` / ``eos``; tokens past it
+    are dropped and counted. Then the same prompt + t0..t5 + more is sent:
+    it may hit only pages whose every block was final, here the two pages
+    of the prompt (16 tokens), never the page the cut block lies in."""
+    cfg = tiny()
+    params = make_params(cfg, 5)
+    (p,) = _prompts(6, 16)
+    eng = _engine(cfg, params)
+
+    async def run():
+        full, _, _ = await _gen(eng, p, 12)
+        by_len = await _gen(eng, p, 6)
+        req = _req(p, 12, ignore_eos=False)
+        req.eos_token_ids = [full[5]]
+        by_eos = await _collect(eng, req)
+        by_stop = await _gen(eng, p, 12, stop_token_ids=[full[5]])
+        dropped = eng.stats()["diffusion_dropped_tokens_total"]
+        hits0 = eng.prefix_hit_tokens_total
+        after, _, _ = await _gen(eng, p + full[:6] + [7, 8, 9], 4)
+        hit = eng.prefix_hit_tokens_total - hits0
+        await eng.stop()
+        return full, by_len, by_eos, by_stop, dropped, hit, after
+
+    full, by_len, by_eos, by_stop, dropped, hit, after = run_async(run())
+    assert full[5] not in full[:5], "the draw must not stop earlier"
+    assert by_len[0] == full[:6] and by_len[2] == "length"
+    assert by_eos[0] == full[:6] and by_eos[2] == "eos"
+    assert by_stop[0] == full[:6] and by_stop[2] == "eos"
+    # each cut run generated its second block whole and dropped t6, t7
+    assert dropped == 3 * 2
+    assert hit == 16
+    want, _, _ = REF.reference_generate(params, cfg,
+                                        p + full[:6] + [7, 8, 9], 4)
+    assert after == want
+
+
+def test_a_stop_list_wider_than_the_device_table(run_async):
+    """The host's token-by-token path (more stop ids than max_eos_ids):
+    the device cannot see the stop and runs on, the host cuts at it."""
+    cfg = tiny()
+    params = make_params(cfg, 5)
+    (p,) = _prompts(6, 16)
+    eng = _engine(cfg, params, max_eos_ids=2)
+
+    async def run():
+        full, _, _ = await _gen(eng, p, 12)
+        cut = await _gen(eng, p, 12,
+                         stop_token_ids=[600, 601, 602, full[5]])
+        await eng.stop()
+        return full, cut
+
+    full, cut = run_async(run())
+    assert cut[0] == full[:6] and cut[2] == "eos"
+
+
+# ----------------------------------------------------- (d) the prefix cache
+
+
+def test_a_prefix_hit_gives_the_logits_of_a_cold_run(run_async):
+    """(d) a second request that shares three pages (24 tokens = six
+    whole blocks) with the first takes them as a hit and answers with the
+    log-probabilities of the reference, as a cold engine does: a page's
+    K/V is a function of the tokens up to the page's end because the
+    block length divides the page."""
+    cfg = tiny()
+    params = make_params(cfg, 7)
+    shared, = _prompts(8, 26)
+    a, b = shared + [11, 12, 13], shared[:25] + [21, 22, 23, 24, 25]
+    eng = _engine(cfg, params)
+
+    async def run():
+        await _gen(eng, a, 8)
+        hits0 = eng.prefix_hit_tokens_total
+        warm = await _gen(eng, b, 9, logprobs=5)
+        hit = eng.prefix_hit_tokens_total - hits0
+        await eng.stop()
+        cold_eng = _engine(cfg, params)
+        cold = await _gen(cold_eng, b, 9, logprobs=5)
+        await cold_eng.stop()
+        return warm, cold, hit
+
+    warm, cold, hit = run_async(run())
+    assert hit == 24
+    assert warm[0] == cold[0]
+    want, rows, _ = REF.reference_generate(params, cfg, b, 9)
+    assert warm[0] == want
+    assert _max_diff(rows, warm[1]) < ATOL
+
+
+def test_pages_a_window_fills_are_published_by_whole_blocks(run_async):
+    """A row that generates across a page's end publishes that page once
+    the block that ends it is final (read back with its window), and a
+    later request hits it: prompt 12 + 9 generated = 21 tokens, two full
+    pages, of which the second was filled by a window."""
+    cfg = tiny()
+    params = make_params(cfg, 7)
+    (p,) = _prompts(12, 12)
+    eng = _engine(cfg, params)
+
+    async def run():
+        toks, _, _ = await _gen(eng, p, 9)
+        hits0 = eng.prefix_hit_tokens_total
+        again = await _gen(eng, p + toks + [5, 6], 5, logprobs=5)
+        hit = eng.prefix_hit_tokens_total - hits0
+        await eng.stop()
+        return toks, again, hit
+
+    toks, again, hit = run_async(run())
+    assert hit == 16
+    want, rows, _ = REF.reference_generate(params, cfg, p + toks + [5, 6], 5)
+    assert again[0] == want and _max_diff(rows, again[1]) < ATOL
+
+
+def test_a_block_that_straddles_a_page_is_refused():
+    with pytest.raises(ValueError, match=r"block_length \(3\) must divide "
+                       r"page_size \(8\).*prefix hit"):
+        _engine(tiny(block_length=3, denoising_steps=3))
+    with pytest.raises(ValueError, match=r"decode_steps \(6\) must be a "
+                       r"multiple of block_length \(4\)"):
+        _engine(decode_steps=6)
+    with pytest.raises(ValueError, match="decode_steps"):
+        _engine(decode_steps=1)
+
+
+# ------------------------------------------------ (e) preemption and resume
+
+
+def test_preempt_and_resume_equals_an_uninterrupted_run(run_async):
+    """(e) a pool too small for four rows preempts some; a preempted row
+    gives up its pages, comes back, prefills the whole blocks of prompt +
+    what it had generated, opens its next block with the tail, and
+    answers as it does alone."""
+    cfg = tiny()
+    params = make_params(cfg, 6)
+    prompts = _prompts(6, 14, 15, 16, 13)
+    eng = _engine(cfg, params, num_pages=14, watermark_pages=1,
+                  prefill_buckets=(16, 32), prefill_chunk=32)
+    preempted = []
+    grow = eng._grow_or_preempt
+
+    def spy(batch, lookahead):
+        before = {id(s): s for s in eng.running}
+        grow(batch, lookahead)
+        preempted.extend(s for s in eng.waiting if id(s) in before)
+
+    eng._grow_or_preempt = spy
+
+    async def main():
+        alone_eng = _engine(cfg, params)
+        alone = [(await _gen(alone_eng, p, 18))[0] for p in prompts]
+        await alone_eng.stop()
+        together = await asyncio.wait_for(asyncio.gather(*(
+            _gen(eng, p, 18) for p in prompts)), 300)
+        await eng.stop()
+        return alone, [t for t, _, _ in together]
+
+    alone, together = run_async(main())
+    assert preempted, "the pool was meant to run out"
+    assert together == alone
+
+
+# ----------------------------------------- (f) the dynamic strategy's exits
+
+
+def test_a_sharp_head_lets_the_dynamic_strategy_finish_early(run_async):
+    """(f) with the head scaled 40x most greedy tokens pass the 0.9
+    threshold, so blocks finish in two or three forwards instead of
+    five; tokens, log-probabilities and every count agree with the
+    reference's loop, and ``early_exits`` is no longer zero."""
+    cfg = tiny(remasking_strategy="low_confidence_dynamic")
+    params = make_params(cfg, 11, sharp=40.0)
+    eng = _engine(cfg, params)
+    prompts = _prompts(12, 16, 17)
+
+    async def main():
+        outs = [await _gen(eng, p, 16, logprobs=5) for p in prompts]
+        stats = eng.stats()
+        await eng.stop()
+        return outs, stats
+
+    outs, stats = run_async(main())
+    fwd = commit = early = blocks = 0
+    for p, (toks, tops, _) in zip(prompts, outs):
+        want, rows, counts = REF.reference_generate(params, cfg, p, 16)
+        assert toks == want
+        # a sharp head makes log-probabilities large: relative room
+        assert _max_diff(rows, tops) < 40 * ATOL
+        fwd += counts["denoise_forwards"]
+        commit += counts["commit_forwards"]
+        early += counts["early_exits"]
+        blocks += counts["blocks"]
+    assert early > 0 and fwd < 4 * blocks
+    assert stats["diffusion_early_exits_total"] == early
+    assert stats["diffusion_forwards_total"] == fwd + commit
+    assert stats["diffusion_blocks_total"] == blocks
+
+
+# --------------------------------- (g) a prompt that contains the mask id
+
+
+def test_a_prompt_may_contain_the_mask_token(run_async):
+    """(g) masked-ness is a flag a position: a prompt whose whole blocks
+    AND whose tail hold ``mask_token_id`` is served as any other."""
+    cfg = tiny()
+    params = make_params(cfg, 13)
+    (p,) = _prompts(14, 14)
+    p[3] = p[9] = p[12] = p[13] = MASK
+    eng = _engine(cfg, params)
+
+    async def main():
+        out = await _gen(eng, p, 9, logprobs=5)
+        await eng.stop()
+        return out
+
+    toks, tops, _ = run_async(main())
+    want, rows, _ = REF.reference_generate(params, cfg, p, 9)
+    assert toks == want and _max_diff(rows, tops) < ATOL
+
+
+# ------------------------------------------------------------ (i) refusals
+
+
+def _refused(what):
+    return pytest.raises(
+        NotImplementedError,
+        match=f"{what}.*generates by diffusion over blocks.*"
+              "_make_block_window_fn.*yields a block a row")
+
+
+class _Blocks:
+    """Stands for an engine that serves a model generating by blocks."""
+    state = None
+    block = 4
+
+
+@pytest.mark.parametrize("what,build", [
+    ("host KV tier", lambda: _engine(host_pages=8)),
+    ("spec_decode", lambda: _engine(spec_decode=True)),
+    ("long_prefill_threshold", lambda: _engine(long_prefill_threshold=64)),
+    ("mesh", lambda: JaxEngine(
+        tiny(), EngineConfig(page_size=PS, num_pages=16, decode_steps=8),
+        mesh=jax.sharding.Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2),
+                               ("data", "model")))),
+    ("disaggregated prefill worker",
+     lambda: __import__("dynamo_tpu.llm.disagg.prefill_worker",
+                        fromlist=["PrefillWorker"]).PrefillWorker(
+                            None, _Blocks())),
+    ("disaggregated decode engine",
+     lambda: __import__("dynamo_tpu.llm.disagg.decode",
+                        fromlist=["DisaggDecodeEngine"]).DisaggDecodeEngine(
+                            _Blocks(), None, None, None, 0)),
+    ("KV transfer server",
+     lambda: __import__("dynamo_tpu.llm.disagg.transfer",
+                        fromlist=["KvTransferServer"]).KvTransferServer(
+                            _Blocks())),
+])
+def test_what_refuses_a_model_that_generates_by_blocks(what, build):
+    """(i) each path that assumes a step of one token a row refuses at
+    construction, and says why."""
+    if what == "mesh" and len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    with _refused(what):
+        build()
+
+
+def test_the_sharded_prefill_kernel_refuses_the_block_mask(monkeypatch):
+    """(i) the engine refuses a mesh at construction; ``forward(...,
+    mesh=)`` called past it must not fall back to the causal mask in
+    silence: the sharded kernel arm has no block edge and says so."""
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2),
+                             ("data", "model"))
+    q = jnp.zeros((1, 8, 4, 16))
+    pool = jnp.zeros((4, 2, PS, 16))
+    with pytest.raises(NotImplementedError,
+                       match="no block mask.*diffusion over blocks"):
+        llama._attention(q, pool, pool, jnp.zeros((1, 2), jnp.int32),
+                         jnp.arange(8)[None], 0.25, mesh=mesh, block=4)
+
+
+def test_prefill_of_a_block_model_has_no_head():
+    """Its prefill yields no token (the logits at a prompt's last
+    position are of that position's own token), so the program returns
+    no logits and its lowered text holds no vocabulary-wide product."""
+    cfg = tiny()
+    params = make_params(cfg)
+    pools = Pools(cfg)
+    i32 = jnp.zeros((2, 32), jnp.int32)
+    args = (params, i32, i32 - 1, pools.kv_k, pools.kv_v, pools.table(2),
+            i32, jnp.zeros(2, jnp.int32), None)
+    assert f"x{cfg.vocab_size}x" not in pools.prefill.lower(*args).as_text()
+    logits, _, _ = pools.prefill(*args)
+    assert logits is None
+    causal, _ = llama.make_step_fns(dataclasses.replace(cfg, block_length=1))
+    assert f"x{cfg.vocab_size}x" in causal.lower(*args).as_text()
+
+
+@pytest.mark.parametrize("sampling", [
+    dict(repetition_penalty=1.2), dict(frequency_penalty=0.5),
+    dict(presence_penalty=0.5), dict(logit_bias={3: 2.0})])
+def test_a_penalty_is_refused_by_the_request(sampling, run_async):
+    """(i) a request whose sampling needs a per-token history inside a
+    block ends at once with an error that says why; the engine goes on
+    serving."""
+    eng = _engine()
+    (p,) = _prompts(15, 12)
+
+    async def main():
+        req = _req(p, 4)
+        req.sampling = SamplingOptions(**sampling)
+        outs = [o async for o in eng.generate(req, Context())]
+        ok = await _gen(eng, p, 4)
+        await eng.stop()
+        return outs, ok
+
+    outs, ok = run_async(main())
+    assert len(outs) == 1 and outs[0].finish_reason == "error"
+    assert "sampling penalty or logit_bias" in outs[0].text
+    assert "generates by diffusion over blocks" in outs[0].text
+    assert len(ok[0]) == 4
+
+
+# ------------------------------------------------------ warm-up and sampling
+
+
+def test_warmup_covers_the_serving_forms(run_async, monkeypatch):
+    """No compile after warm-up: the block window from a host-seeded
+    carry and from the previous window's, the merge of the two, the
+    prefill without a sampling program."""
+    monkeypatch.setenv("DYN_JIT_FENCE", "raise")
+    eng = _engine()
+    prompts = _prompts(16, 12, 14, 19)
+
+    async def main():
+        eng.warmup()
+        outs = await asyncio.gather(*(_gen(eng, p, 20) for p in prompts))
+        stats = eng.stats()
+        await eng.stop()
+        return outs, stats
+
+    outs, stats = run_async(main())
+    assert all(len(t) == 20 for t, _, _ in outs)
+    assert stats["post_warmup_compiles_total"] == 0
+    assert stats["first_tokens_total"] == 3
+
+
+def test_sampled_rows_draw_by_position(run_async):
+    """A seeded temperature row draws the same tokens whether it runs
+    alone or beside others (the RNG step of a draw is its absolute
+    position), and differs from greedy."""
+    eng = _engine()
+    (p, q) = _prompts(17, 13, 16)
+
+    async def one(prompt, n, seed=None):
+        req = _req(prompt, n)
+        if seed is not None:
+            req.sampling = SamplingOptions(temperature=1.5, seed=seed)
+        return (await _collect(eng, req))[0]
+
+    async def main():
+        alone = await one(p, 12, 7)
+        both = await asyncio.gather(one(p, 12, 7), one(q, 12))
+        greedy = await one(p, 12)
+        await eng.stop()
+        return alone, both[0], greedy
+
+    alone, beside, greedy = run_async(main())
+    assert alone == beside and alone != greedy
+
+
+def test_both_windows_are_named_decode_window():
+    """The block window keeps the name of the window of tokens: the
+    benchmark's ``window_ms_mean`` and ``decode_rows_mean`` find the
+    program by it (``trace.WINDOW_MODULE``). (That block_length 1 lowers
+    to the programs it lowered to before is shown by digests of all six
+    cells' programs against the parent's: CHANGES.md, PR 38.)"""
+    hf = dict(model_type="qwen3_moe", vocab_size=512, hidden_size=64,
+              intermediate_size=128, moe_intermediate_size=32,
+              num_hidden_layers=2, num_attention_heads=4,
+              num_key_value_heads=2, head_dim=16, num_experts=8,
+              num_experts_per_tok=2, norm_topk_prob=True)
+    cfg = ModelConfig.from_hf_config(hf)
+    assert cfg.block_length == 1
+    fn = llama.make_decode_window_fn(cfg, True, 64)
+    assert fn.__name__ == "decode_window"
+    blocky = llama.make_decode_window_fn(tiny(), True, 64)
+    assert blocky.__name__ == "decode_window"

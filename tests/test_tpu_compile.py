@@ -605,6 +605,86 @@ def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
             < 15.75 * 1024 ** 3)
 
 
+# ------ the block window and block-causal prefill at SDAR-30B-A3B's widths
+
+
+@pytest.mark.parametrize("program", ["window", "window-logprobs",
+                                     "prefill"])
+def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
+                                                       tpu_kernel_path,
+                                                       program):
+    """The programs of sdar-30b-a3b-chat.decode-heavy at the cell's pool
+    ([6, 1280, 4, 64, 128]) and engine data, every width as published
+    (the expert count cut to 8 so the compile stays short): the block
+    window (B 64, P 32, the cell's decode_steps: whole blocks of 4, each
+    a while loop of denoising forwards and a commit forward, the decode
+    kernel at group 8 x 4 = 32 inside) and a block-causal prefill chunk
+    (PB 8 x T 256, the prefill kernel with the block edge). The pools
+    alias their inputs, and the window holds no copy of a pool's size:
+    its pools are read-only inside the loops and written once, by
+    commit_window, along their major axis. The prefill holds the two
+    copies that llama.forward's scan over the pools has held at these
+    shapes since before this configuration (the same program with
+    block_length 1, cell 2's, is compiled beside it): the block mask adds
+    none and no temporary."""
+    import dataclasses
+    import json
+
+    from dynamo_tpu.models.config import ModelConfig
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "workloads",
+                           "sdar-30b-a3b-chat.decode-heavy.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = ModelConfig.from_local_path(os.path.join(
+        root, "benchmark", "configs", "sdar-30b-a3b-chat"))
+    assert cfg.block_length == 4 and e["decode_steps"] % 4 == 0
+    cfg = dataclasses.replace(cfg, num_experts=8)
+    params, kv_k, kv_v = _engine_shapes(cfg, one_chip, e["num_pages"])
+    assert kv_k.shape == (6, 1280, 4, PS, 128)
+    s = partial(_sds, one_chip)
+    P = e["page_buckets"][-1]
+    if program == "prefill":
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+
+        def lower(c):
+            return llama.make_step_fns(c)[0].lower(
+                params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+                kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+                s((PB,), jnp.int32), s((PB, T // PS), jnp.int32)).compile()
+
+        compiled = lower(cfg)
+        causal = lower(dataclasses.replace(cfg, block_length=1))
+        assert _has_kernel(compiled)
+        assert len(_pool_sized_copies(compiled.as_text(), kv_k.size)) == len(
+            _pool_sized_copies(causal.as_text(), kv_k.size))
+        mem, was = compiled.memory_analysis(), causal.memory_analysis()
+        assert mem.alias_size_in_bytes == was.alias_size_in_bytes
+        assert mem.temp_size_in_bytes < was.temp_size_in_bytes + 2 ** 20
+        return
+    else:
+        B = e["max_batch"]
+        i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+        compiled = llama.make_decode_window_fn(cfg, True, 64).lower(
+            params, s((B, cfg.block_length), jnp.int32), i32,
+            s((B,), jnp.bool_), i32, i32, kv_k, kv_v, s((B, P), jnp.int32),
+            f32, i32, f32, s((B,), jnp.uint32), s((B, 8), jnp.int32), None,
+            k_steps=e["decode_steps"],
+            logprobs_topn=20 if program == "window-logprobs" else 0
+        ).compile()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(compiled.as_text(), kv_k.size) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = kv_k.size * kv_k.dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    # the agreement check's variant holds [B x 4, V] log-probabilities
+    # and their top 20 besides
+    assert mem.temp_size_in_bytes < pool_bytes * (
+        2 if program == "window-logprobs" else 1)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)
+
+
 # ---- the selective-scan step on the state pool at Jamba2-3B's widths (cell 4)
 
 
