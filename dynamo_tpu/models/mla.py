@@ -20,9 +20,10 @@ TPU-first design points:
   online-softmax statistics, and one commit per pool writes whole pages
   (or token rows) along the pool's major axis, in place;
 - on the chip decode and multi-row prefill chunks read the pool through
-  the Pallas latent kernel (ops/paged_attention.py
-  latent_attention_layered: the heads of one token a block in decode,
-  1,024 (token, head) rows a block in prefill); a one-row chunk, and
+  the Pallas latent kernels (ops/paged_attention.py: a decode step a
+  grid step a row, the row's own pages copied by the kernel,
+  latent_attention_decode_layered; a prefill chunk 1,024 (token, head)
+  rows a block, latent_attention_layered); a one-row chunk, and
   everything off the chip, through a blockwise XLA arm (_attend_pool
   has the measurements). Neither forms [B, H, T, S].
 
@@ -344,8 +345,9 @@ def _attend_pool(q_lat, q_rope, c_pool, r_pool, l_idx, page_table, lengths,
     None (True: interpret mode, the CPU's test hook; None: the XLA arm
     for everything).
 
-    One token a row (a decode step): the Pallas kernel, which follows
-    each row's own length. A chunk of ONE row: the XLA arm, whose big
+    One token a row (a decode step): the Pallas decode kernel, whose
+    time follows the pages each row owns (a padding row costs one empty
+    grid step). A chunk of ONE row: the XLA arm, whose big
     batched matmuls run that shape faster than the kernel's page-sized
     ones. A chunk of several rows: the kernel over blocks of (token,
     head) rows, which skips the padding rows of the bucket and the

@@ -452,8 +452,25 @@ def paged_attention_decode_layered(q: jax.Array, k_pools: jax.Array,
 
 # ------------------------------------------------ latent (MLA) attention
 
-LATENT_TOKENS_PER_STEP = 1024  # cached tokens a grid step takes
-LATENT_BLOCK_ROWS = 1024       # query rows (tokens x heads) a block holds
+# Cached tokens a chunk of the decode kernel's row loop takes (and a grid
+# step of the blocked kernel, which is prefill's), and query rows (tokens x
+# heads) a block of the blocked kernel holds. One layer's decode call,
+# device ms at 4 / 8 / 16 (/ 32) pages a chunk, then the blocked kernel at
+# 1,024 tokens a step (this entry's form until PR 39) and the XLA arm
+# (tools/latent_attn_timing.py, my chip run, PR 39; B 64 rows of 8,257-8,833
+# tokens, 32 heads, bf16; the bytes' floor 0.78 ms, 0.37 with 34 rows empty):
+#   pages of 128, 64 rows live:  1.157 1.003 1.033       | 1.378 | 1.527
+#   pages of 128, 30 rows live:  0.573 0.504 0.531       | 1.094 | 1.527
+#   pages of  64, 64 rows live:  1.774 1.304 1.091 1.037 | 2.160 | 1.625
+#   pages of  64, 30 rows live:  0.857 0.639 0.540 0.533 | 1.861 | 1.625
+# A turn of the row loop costs ~0.3 us beside its arithmetic (4 -> 8 pages
+# of 128: 512 turns fewer, 0.15 ms), a copy's issue 0.01-0.02 us (pages of
+# 64 against 128 at the same tokens a chunk); past 1,024 tokens a chunk the
+# last chunk's masked tail costs what the turns save (a row of 8.5k tokens
+# computes 10,240 positions at 2,048 a chunk, 9,216 at 1,024). Pages of 64
+# would want 2,048; the cell with this family has pages of 128.
+LATENT_TOKENS_PER_STEP = 1024
+LATENT_BLOCK_ROWS = 1024
 DECODE_NAME = "latent_attention_decode_layered"
 PREFILL_NAME = "latent_attention_prefill_layered"
 
@@ -523,17 +540,18 @@ def latent_attention_layered(q_lat: jax.Array, q_rope: jax.Array,
                              interpret: bool = False,
                              pages_per_step: int | None = None,
                              block_rows: int = LATENT_BLOCK_ROWS,
-                             name: str = DECODE_NAME):
+                             name: str = PREFILL_NAME):
     """Latent (MLA, absorbed) attention of M query rows a batch row
     against what ONE layer of the stacked latent and rope pools holds
     of that row: its positions < lengths[b], the same for all M.
 
-    q_lat: [B, M, r] (q_nope . W_UK); q_rope: [B, M, dw]; M is the heads
-    of one token (a decode step) or tokens x heads of a prefill chunk,
-    whose queries all see the whole cached prefix. c_pools: [L, pages, 1,
-    ps, r]; r_pools: [L, pages, 1, ps, dw]; ``layer`` a traced int32
-    scalar (a scalar-prefetch operand, as paged_attention_decode_layered
-    has it); page_table: [B, P]; lengths: [B] (0: nothing in the pool).
+    q_lat: [B, M, r] (q_nope . W_UK); q_rope: [B, M, dw]; M is tokens x
+    heads of a prefill chunk, whose queries all see the whole cached
+    prefix (or the heads of one token: the tests, the timing tool).
+    c_pools: [L, pages, 1, ps, r]; r_pools: [L, pages, 1, ps, dw];
+    ``layer`` a traced int32 scalar (a scalar-prefetch operand, as
+    paged_attention_decode_layered has it); page_table: [B, P];
+    lengths: [B] (0: nothing in the pool).
 
     There is ONE latent "KV head": every page is read once for all the
     rows of a block, the score is q_lat . c + q_rope . k_r, and the value
@@ -547,28 +565,22 @@ def latent_attention_layered(q_lat: jax.Array, q_rope: jax.Array,
 
     ``pages_per_step`` and ``block_rows`` are the handles of the tests
     (steps that divide nothing, several blocks at a small size) and of
-    tools/latent_attn_timing.py; the two entries below, which are what
-    models/mla.py calls, take neither and run the measured constants.
+    tools/latent_attn_timing.py; latent_attention_prefill_layered, which
+    is what models/mla.py calls, takes neither and runs the measured
+    constants. A decode step has an entry and a kernel of its own
+    (latent_attention_decode_layered).
 
     Grid (B, M / block_rows, ceil(P / G)): a step takes G =
     ``pages_per_step`` pages of the row (default: LATENT_TOKENS_PER_STEP
     tokens' worth), each pool passed G times with an index map of its
     own, so a page does not cost a grid step. Steps past a row's context
     re-point at the blocks its last live step fetched and are skipped;
-    they still cost their operands' bookkeeping (the GQA kernel above
-    loops over a row's own pages inside one grid step instead, and
-    copies them itself: PERF.md, Findings PR 32).
-
-    What a step costs is mostly its OPERANDS: about 0.1 us each for the
-    pipeline's bookkeeping, 2 a page, whatever the page's size, where
-    a 64-token page's bytes take 0.1 us at the HBM's peak and its
-    matmuls less. So the kernel's time follows the number of pages,
-    not of tokens: B 64 rows of ~8.5k tokens, one layer, device ms (my
-    chip runs, PR 31): pages of 64: 3.00 / 2.52 / 2.27 / 2.33 at G 4 /
-    8 / 16 / 32 against the XLA arm's 1.63; pages of 128: 1.63 / 1.38 /
-    1.37 at G 4 / 8 / 16 against 1.50, and with 34 of the 64 rows empty
-    1.09 against 1.50 (the XLA arm computes every row to the longest).
-    A cell of long contexts wants pages of 128 (cell 5's engine data)."""
+    they still cost their operands' bookkeeping: about 0.1 us an operand
+    a grid step, 2 a page, whatever the page's size (my chip runs,
+    PR 31). A block of 1,024 query rows hides that behind its matmuls;
+    the 32 rows of a decode step did not (1.38 ms a layer at B 64 x 8.5k
+    tokens and pages of 128, 2.27 at pages of 64, for 0.78 ms of bytes),
+    which is why decode left this form (PR 39)."""
     B, M, r = q_lat.shape
     dw = q_rope.shape[-1]
     ps = c_pools.shape[3]
@@ -627,14 +639,163 @@ def latent_attention_layered(q_lat: jax.Array, q_rope: jax.Array,
     return acc, m[:, :, 0], l[:, :, 0]
 
 
-def latent_attention_decode_layered(q_lat, q_rope, c_pools, r_pools, layer,
-                                    page_table, lengths, *, scale,
-                                    interpret=False):
+def _latent_decode_kernel(ps: int, G: int, P: int, scale: float,
+                          # scalar prefetch
+                          layer_ref, pt_ref, len_ref,
+                          # q: one row's blocks; the pools: whole, in HBM
+                          ql_ref, qr_ref, c_hbm, r_hbm, o_ref, m_out, l_out,
+                          cbuf, rbuf, sems, slot_ref, m_ref, l_ref, acc_ref):
+    """One grid step = one ROW, in _decode_kernel's form: a loop over the
+    chunks of G pages that hold its positions < length, each chunk's pages
+    copied HBM->VMEM by G async copies a pool into one [1, G * ps, *]
+    buffer (two slots: chunk j + 1, or the next row's first chunk, is in
+    flight while chunk j computes), then ONE online-softmax update over
+    the chunk's G * ps positions, in _latent_kernel's arithmetic. A page
+    past the row's end is not copied (its stale slot is masked), and a
+    row with nothing in the pool costs one empty grid step."""
+    b = pl.program_id(0)
+    B = pl.num_programs(0)
+    layer = layer_ref[0]
+
+    def span(r):
+        # the row's pages [0, end) and its chunks (lax.div: see
+        # _decode_kernel)
+        end = jnp.minimum(jax.lax.div(len_ref[r] + (ps - 1), ps), P)
+        return end, jax.lax.div(end + (G - 1), G)
+
+    def copies(r, j, slot, do: str):
+        # chunk j of row r: a copy a pool for each page it has
+        p0 = j * G
+        _page_copies(do, jnp.clip(span(r)[0] - p0, 0, G),
+                     lambda pool, g: pool.at[layer, pt_ref[r, p0 + g]],
+                     ps, (c_hbm, r_hbm), (cbuf, rbuf), sems, slot)
+
+    n = span(b)[1]
+
+    @pl.when(b == 0)
+    def _():
+        # a page that is not copied leaves its slot as it was: masked
+        # scores may be anything, but the latent is the VALUE too, and
+        # 0 * c must not be NaN
+        cbuf[...] = jnp.zeros_like(cbuf)
+        slot_ref[0] = 0
+
+    base = slot_ref[0]  # the slot the row's first chunk is copied to
+
+    # the row before starts this row's first chunk beside its own last;
+    # the first row, and one after a row with nothing to read, start it
+    @pl.when(jnp.logical_or(b == 0, span(jnp.maximum(b - 1, 0))[1] == 0))
+    def _():
+        copies(b, 0, base, "start")
+
+    _reset_row(m_ref, l_ref, acc_ref)
+    length = len_ref[b]
+
+    def chunk(j, carry):
+        slot = (base + j) & 1
+        more = j + 1 < n
+
+        # in flight while this chunk computes: the row's next chunk, or
+        # after its last the next row's first
+        @pl.when(jnp.logical_or(more, b + 1 < B))
+        def _():
+            copies(jnp.where(more, b, b + 1), jnp.where(more, j + 1, 0),
+                   1 - slot, "start")
+
+        copies(b, j, slot, "wait")
+        ccat, rcat = cbuf[slot, 0], rbuf[slot, 0]       # [G * ps, *]
+        nt = (((1,), (1,)), ((), ()))                   # a . b^T
+        sc = (jax.lax.dot_general(ql_ref[...], ccat, nt,
+                                  preferred_element_type=jnp.float32)
+              + jax.lax.dot_general(qr_ref[...], rcat, nt,
+                                    preferred_element_type=jnp.float32)
+              ) * scale                                 # [H, G * ps]
+        pos = j * (G * ps) + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        valid = pos < length
+        sc = jnp.where(valid, sc, NEG_INF)
+        m_prev = m_ref[:, :1]                           # [H, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(sc - m_new), 0.0)
+        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(ccat.dtype), ccat, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [H, r]
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n, chunk, 0)
+    slot_ref[0] = (base + n) & 1
+    o_ref[...] = acc_ref[...]
+    m_out[...] = m_ref[...]
+    l_out[...] = l_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "pages_per_step"))
+def latent_attention_decode_layered(q_lat: jax.Array, q_rope: jax.Array,
+                                    c_pools: jax.Array, r_pools: jax.Array,
+                                    layer: jax.Array, page_table: jax.Array,
+                                    lengths: jax.Array, *, scale: float,
+                                    interpret: bool = False,
+                                    pages_per_step: int | None = None):
     """One decode step: q_lat [B, H, r], q_rope [B, H, dw], the heads of
-    one token a row; 32 heads against the one latent head."""
-    return latent_attention_layered(
-        q_lat, q_rope, c_pools, r_pools, layer, page_table, lengths,
-        scale=scale, interpret=interpret, name=DECODE_NAME)
+    one token a row; 32 heads against the one latent head. Pools, table,
+    lengths and the PART returned (acc [B, H, r] float32 not divided by
+    l, m, l; a row with nothing in the pool (0, NEG_INF, 0)) are
+    latent_attention_layered's, and so is the arithmetic.
+
+    The form is paged_attention_decode_layered's (PR 32): grid (B,), the
+    pools passed once and left in HBM, the kernel copying each row's own
+    pages in chunks of G = LATENT_TOKENS_PER_STEP tokens' worth
+    (``pages_per_step``: the handle of the tests and of
+    tools/latent_attn_timing.py; the model code passes none). Blocked
+    operands (latent_attention_layered, this entry's form until PR 39)
+    cost ~0.1 us each a grid step whatever they move, 2 a page: as much
+    as the bytes of a 128-token page."""
+    B, H, r = q_lat.shape
+    dw = q_rope.shape[-1]
+    ps = c_pools.shape[3]
+    P = page_table.shape[1]
+    G = max(1, min(pages_per_step or LATENT_TOKENS_PER_STEP // ps, P))
+
+    def row(b, *_):
+        return (b, 0, 0)
+
+    acc, m, l = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, ps, G, P, scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B,),
+            in_specs=[pl.BlockSpec((None, H, r), row),
+                      pl.BlockSpec((None, H, dw), row),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((None, H, r), row),
+                       pl.BlockSpec((None, H, 128), row),
+                       pl.BlockSpec((None, H, 128), row)],
+            scratch_shapes=[pltpu.VMEM((2, 1, G * ps, r), c_pools.dtype),
+                            pltpu.VMEM((2, 1, G * ps, dw), r_pools.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((H, 128), jnp.float32),
+                            pltpu.VMEM((H, 128), jnp.float32),
+                            pltpu.VMEM((H, r), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, r), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, 128), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            # rows in order: a row's last chunk starts the next row's first
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        # the name a device trace shows the kernel under (what
+        # benchmark/metrics/latent_attn_roofline.py matches)
+        name=DECODE_NAME,
+    )(jnp.asarray(layer, jnp.int32).reshape(1),
+      page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+      q_lat.astype(c_pools.dtype), q_rope.astype(r_pools.dtype),
+      c_pools, r_pools)
+    return acc, m[:, :, 0], l[:, :, 0]
 
 
 def latent_attention_prefill_layered(q_lat, q_rope, c_pools, r_pools, layer,

@@ -273,43 +273,78 @@ def v3_params(cfg, seed=0, bias=0.05):
     return params
 
 
-def _pool_case(lengths, P, ps=8, H=4, r=16, dr=8, L=2, seed=0):
-    """Random pools, a page table of distinct pages a row, queries."""
+def _pool_case(lengths, P, ps=8, H=4, r=16, dr=8, L=2, seed=0, table=None):
+    """Random pools, a page table of distinct pages a row (or ``table``:
+    rows of page ids, padded to P with 0), queries."""
     rng = np.random.RandomState(seed)
     B = len(lengths)
     NP = 1 + B * P
     c_pool = jnp.asarray(rng.randn(L, NP, 1, ps, r), jnp.float32)
     r_pool = jnp.asarray(rng.randn(L, NP, 1, ps, dr), jnp.float32)
-    table = np.zeros((B, P), np.int32)
-    for b, n in enumerate(lengths):
-        live = -(-n // ps)
-        table[b, :live] = 1 + b * P + rng.permutation(P)[:live]
+    if table is None:
+        table = np.zeros((B, P), np.int32)
+        for b, n in enumerate(lengths):
+            live = -(-n // ps)
+            table[b, :live] = 1 + b * P + rng.permutation(P)[:live]
+    else:
+        table = np.asarray([list(row) + [0] * (P - len(row))
+                            for row in table], np.int32)
     q_lat = jnp.asarray(rng.randn(B, 1, H, r), jnp.float32)
     q_rope = jnp.asarray(rng.randn(B, 1, H, dr), jnp.float32)
     return (q_lat, q_rope, c_pool, r_pool, jnp.asarray(table),
             jnp.asarray(lengths, jnp.int32))
 
 
-@pytest.mark.parametrize("lengths,P,G", [
-    ([13, 8, 1, 40], 5, 8),      # ends inside a page, on one, one token
-    ([0, 21, 0, 3], 4, 2),       # rows with nothing in the pool
-    ([5], 1, 8),                 # a one-page table
-    ([64, 37, 9, 0, 50], 8, 3),  # G divides neither P nor the live pages
-], ids=["inside-page", "empty-rows", "one-page", "uneven-steps"])
-def test_latent_kernel_matches_xla_arm(lengths, P, G):
+# pages of 8 tokens; G pages a chunk of the kernel's row loop. ``table``:
+# the rows' page ids as given (unused slots 0, or what the case puts there)
+@pytest.mark.parametrize("lengths,P,G,table", [
+    ([13, 8, 1, 40], 5, 8, None),      # ends inside a page, on one, one token
+    ([0, 21, 0, 3], 4, 2, None),       # rows with nothing in the pool
+    ([5], 1, 8, None),                 # a one-page table
+    ([64, 37, 9, 0, 50], 8, 3, None),  # G divides neither P nor live pages
+    # the row loop's hand-over: a row with nothing to read first, last,
+    # between two live rows, two in a row, every row
+    ([0, 30, 17], 4, 2, None),
+    ([30, 17, 0], 4, 2, None),
+    ([30, 0, 17], 4, 2, None),
+    ([26, 0, 0, 9, 0], 4, 2, None),
+    ([0, 0, 0], 4, 2, None),
+    # a length on a chunk's edge (G 2 x 8 tokens), one short, one past,
+    # and on the second chunk's edge
+    ([16, 15, 17, 32, 31, 33], 5, 2, None),
+    # G divides neither row's pages (7 and 5 pages by 3), then does (6)
+    ([56, 40, 48], 8, 3, None),
+    # one chunk holds the whole table, and more than it
+    ([40, 3, 24], 5, 5, None),
+    ([40, 3, 24], 5, 16, None),
+    # two rows with the SAME page ids (prefix hits), a third sharing its
+    # first pages; ids in descending order
+    ([22, 22, 19], 4, 2, [[3, 7, 5], [3, 7, 5], [3, 7, 9]]),
+    ([40, 17], 5, 2, [[10, 9, 7, 4, 2], [8, 6, 1]]),
+    # a table wider than any row's pages, garbage in the unused slots
+    # (ids of other rows' pages and of pages nobody wrote)
+    ([20, 0, 9], 12, 4, [[5, 2, 8] + [30, 31, 1, 5, 2, 36, 7, 7, 3],
+                          [9, 9, 9, 33, 4, 4, 4, 4, 4, 4, 4, 4],
+                          [6, 1] + [35] * 10]),
+], ids=["inside-page", "empty-rows", "one-page", "uneven-steps",
+        "empty-first", "empty-last", "empty-between", "empty-runs",
+        "all-empty", "chunk-edges", "uneven-chunks", "one-chunk",
+        "chunk-past-table", "shared-pages", "descending-pages",
+        "wide-table-garbage"])
+def test_latent_kernel_matches_xla_arm(lengths, P, G, table):
     """The Pallas latent decode kernel (interpret mode) against the XLA
     arm on the same pools: the un-normalised p . c, the running maximum
     and the sum, per row and head; a row with nothing in the pool is
     (0, NEG_INF, 0) on both."""
     from dynamo_tpu.ops.paged_attention import (
-        DECODE_NAME, NEG_INF, latent_attention_layered)
+        NEG_INF, latent_attention_decode_layered)
 
-    q_lat, q_rope, c_pool, r_pool, table, lens = _pool_case(lengths, P)
+    q_lat, q_rope, c_pool, r_pool, table, lens = _pool_case(
+        lengths, P, table=table)
     for layer in (0, 1):
-        acc, m, l = latent_attention_layered(
+        acc, m, l = latent_attention_decode_layered(
             q_lat[:, 0], q_rope[:, 0], c_pool, r_pool, jnp.int32(layer),
-            table, lens, scale=0.2, interpret=True, pages_per_step=G,
-            name=DECODE_NAME)
+            table, lens, scale=0.2, interpret=True, pages_per_step=G)
         ref_acc, ref_m, ref_l = mla._attend_pool_xla(
             q_lat, q_rope, c_pool, r_pool, jnp.int32(layer), table, lens,
             0.2)
@@ -319,6 +354,40 @@ def test_latent_kernel_matches_xla_arm(lengths, P, G):
         empty = np.asarray(lens) == 0
         assert (np.asarray(m)[empty] == NEG_INF).all()
         assert not np.asarray(acc)[empty].any()
+        assert not np.asarray(l)[empty].any()
+
+
+@pytest.mark.parametrize("G", [1, 2, 3, 8])
+def test_latent_decode_kernel_copies_in_tpu_interpreter(G):
+    """The kernel's own copies under the TPU interpreter, as
+    tests/test_ops.py test_decode_kernel_copies_in_tpu_interpreter:
+    buffers start as NaN and a copy's bytes arrive only when it is WAITED
+    for, so a chunk computed before its wait, a wait that names another
+    slot, a stale page that leaks through the mask or a latent buffer
+    that was never zeroed (the latent is the value: 0 x NaN) shows as a
+    wrong row. Rows with nothing to read first, between (two in a row)
+    and last: each hands the next row's first chunk on."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from dynamo_tpu.ops.paged_attention import (
+        NEG_INF, latent_attention_decode_layered)
+
+    interp = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                   uninitialized_memory="nan")
+    lengths = [0, 5, 0, 48, 0, 0, 16, 25, 0]
+    q_lat, q_rope, c_pool, r_pool, table, lens = _pool_case(
+        lengths, 6, r=128, dr=128)
+    acc, m, l = latent_attention_decode_layered(
+        q_lat[:, 0], q_rope[:, 0], c_pool, r_pool, jnp.int32(1), table, lens,
+        scale=0.05, interpret=interp, pages_per_step=G)
+    ref_acc, ref_m, ref_l = mla._attend_pool_xla(
+        q_lat, q_rope, c_pool, r_pool, jnp.int32(1), table, lens, 0.05)
+    np.testing.assert_allclose(acc, ref_acc[:, 0], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(l, ref_l[:, 0], rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(m, ref_m[:, 0], rtol=1e-6, atol=1e-6)
+    empty = np.asarray(lens) == 0
+    assert (np.asarray(m)[empty] == NEG_INF).all()
+    assert not np.asarray(acc)[empty].any()
 
 
 @pytest.mark.parametrize("T,block_rows", [(6, 8), (16, 64), (4, 1024)])

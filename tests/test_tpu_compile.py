@@ -452,22 +452,25 @@ def _kanana(one_chip, experts=8):
     return cfg, params, kv_k, kv_v, e
 
 
+@pytest.mark.parametrize("B", [64, 8, 1])
 @pytest.mark.parametrize("page_size,P", [(128, 72), (64, 144)])
-def test_latent_decode_kernel_compiles(one_chip, page_size, P):
+def test_latent_decode_kernel_compiles(one_chip, page_size, P, B):
     """32 heads against one latent head of 512 + a rope key padded to
-    128, 1,024 cached tokens a grid step, at the cell's batch and at
-    both page sizes a 9,216-token bucket can have: the kernel itself,
-    and no relayout of either pool in front of it (a rope pool 64
-    columns wide would be copied whole: the runtime stores it with PAGES
-    as its minor axis)."""
+    128, a grid step a row and 1,024 cached tokens a chunk of its loop,
+    at the cell's ``batch_buckets`` and at both page sizes a 9,216-token
+    bucket can have: the kernel itself (its page copies are slices of
+    the pools in HBM, which the chip's compiler takes only at whole
+    lanes), and no relayout of either pool in front of it (a rope pool
+    64 columns wide would be copied whole: the runtime stores it with
+    PAGES as its minor axis)."""
     s = partial(_sds, one_chip)
     pages = 64 * P
     compiled = jax.jit(partial(pa.latent_attention_decode_layered,
                                scale=192 ** -0.5)).lower(
-        s((64, 32, 512), jnp.bfloat16), s((64, 32, 128), jnp.bfloat16),
+        s((B, 32, 512), jnp.bfloat16), s((B, 32, 128), jnp.bfloat16),
         s((6, pages, 1, page_size, 512), jnp.bfloat16),
         s((6, pages, 1, page_size, 128), jnp.bfloat16), s((), jnp.int32),
-        s((64, P), jnp.int32), s((64,), jnp.int32)).compile()
+        s((B, P), jnp.int32), s((B,), jnp.int32)).compile()
     assert _has_kernel(compiled)
     assert _pool_sized_copies(compiled.as_text(),
                               6 * pages * page_size * 128) == []

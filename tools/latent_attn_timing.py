@@ -5,8 +5,15 @@ take.
     chiprun -- python3 tools/latent_attn_timing.py
 
 Decode (B 64 rows, one token each, 32 heads, contexts ~8.6k in a
-bucket of 72 pages of 128; ``--page-size 64`` for 144 of 64): the
-Pallas kernel at ``pages_per_step`` 4 / 8 / 16 against the XLA arm (``mla._attend_pool_xla``). Prefill (one chunk of T
+bucket of 72 pages of 128; ``--page-size 64`` for 144 of 64; every row
+live, and with 34 of the 64 empty): the decode kernel
+(``latent_attention_decode_layered``: a grid step a row, the row's pages
+copied by the kernel) at ``pages_per_step`` 4 / 8 / 16 (and 32 pages of
+64), beside the blocked kernel that was the decode entry until PR 39
+(``latent_attention_layered`` with the heads of one token as its block:
+every page an operand of a grid step) at its 1,024 tokens a step, and
+the XLA arm (``mla._attend_pool_xla``). ``--arms decode`` times these
+alone. Prefill (one chunk of T
 512 a row over 4k and 8k of cached prefix, PB 1 and PB 4 with one and
 all rows live): the kernel over blocks of 512 / 1,024 (token,
 head) rows against the XLA arm in the absorbed form, and against the
@@ -94,7 +101,7 @@ def _materialised(q_nope, q_rope, w_uk, w_uv, c_pool, r_pool, l_idx,
         jnp.zeros((B, T, H), jnp.float32)))
 
 
-def cases(key):
+def cases(key, arms):
     """(label, fn, args, reference label or None, work) per form."""
     ks = jax.random.split(key, 8)
     c_pool = jax.random.normal(ks[0], (L, PAGES, 1, PS, R), BF)
@@ -117,60 +124,68 @@ def cases(key):
                            preferred_element_type=jnp.float32).astype(BF)
         return q_nope, q_rope, q_lat
 
-    # ---- decode: 64 rows of 8,257..8,833 tokens
-    rng = np.random.RandomState(31)
-    B = 64
-    lens = jnp.asarray(rng.randint(8257, 8833, B), jnp.int32)
-    table = table_of(B, rng)
-    _, q_rope, q_lat = queries(B, 1, ks[4])
-    ops, bytes_ = latent_work.latent_attention_decode(
-        np.asarray(lens).tolist(), num_heads=H, kv_lora_rank=R,
-        rope_dim=DR, page_size=PS)
-    work = {"least_ms": roofline.least_seconds(
-        ops, bytes_, jax.devices()[0].device_kind)["seconds"] * 1e3}
-
     # the pools and the weights are ARGUMENTS of every program: closed
     # over, an array is baked into the HLO as a constant, half a GB a
     # program (that ran the 40 GiB host out of memory in PR 31's first try)
     def xla(ql, qr, t, n, c_pool, r_pool):
         return mla._attend_pool_xla(ql, qr, c_pool, r_pool, layer, t, n,
                                     SCALE)
-    xla.__name__ = "decode_xla"
-    out.append(("decode_xla", jax.jit(xla),
-                (q_lat, q_rope, table, lens, c_pool, r_pool), None, work))
-    for G in (4, 8, 16):
-        def kern(ql, qr, t, n, c_pool, r_pool, G=G):
+
+    # ---- decode: 64 rows of 8,257..8,833 tokens; ``_live30``: 34 of them
+    # empty (a bucket of 64 at 30 running rows), scattered over the batch
+    rng = np.random.RandomState(31)
+    B = 64
+    full = rng.randint(8257, 8833, B)
+    table = table_of(B, rng)
+    _, q_rope, q_lat = queries(B, 1, ks[4])
+    empty = rng.permutation(B)[:34]
+    decode = (("", full), ("_live30", np.where(
+        np.isin(np.arange(B), empty), 0, full))) if "decode" in arms else ()
+    for tag, lens in decode:
+        ops, bytes_ = latent_work.latent_attention_decode(
+            lens[lens > 0].tolist(), num_heads=H, kv_lora_rank=R,
+            rope_dim=DR, page_size=PS)
+        work = {"least_ms": roofline.least_seconds(
+            ops, bytes_, jax.devices()[0].device_kind)["seconds"] * 1e3}
+        args = (q_lat, q_rope, table, jnp.asarray(lens, jnp.int32), c_pool,
+                r_pool)
+        ref = "decode%s_xla" % tag
+        out.append((ref, _named(xla, ref), args, None, work))
+
+        def blocked(ql, qr, t, n, c_pool, r_pool):
             a, m, l = pa.latent_attention_layered(
                 ql[:, 0], qr[:, 0], c_pool, r_pool, layer, t, n,
-                scale=SCALE, pages_per_step=G, name=pa.DECODE_NAME)
+                scale=SCALE, name="latent_blocked")
             return a[:, None], m[:, None], l[:, None]
-        kern.__name__ = "decode_kernel_g%d" % G
-        out.append((kern.__name__, jax.jit(kern),
-                    (q_lat, q_rope, table, lens, c_pool, r_pool),
-                    "decode_xla", work))
+        label = "decode%s_blocked" % tag
+        out.append((label, _named(blocked, label), args, ref, work))
+        for G in (4, 8, 16) + ((32,) if PS < 128 else ()):
+            def kern(ql, qr, t, n, c_pool, r_pool, G=G):
+                a, m, l = pa.latent_attention_decode_layered(
+                    ql[:, 0], qr[:, 0], c_pool, r_pool, layer, t, n,
+                    scale=SCALE, pages_per_step=G)
+                return a[:, None], m[:, None], l[:, None]
+            label = "decode%s_kernel_g%d" % (tag, G)
+            out.append((label, _named(kern, label), args, ref, work))
 
     # ---- prefill: chunks of T 512 over a cached prefix
     T = 512
-    for tag, B, live, ctx in (("pb1_4k", 1, 1, 4096), ("pb1_8k", 1, 1, 8192),
-                              ("pb4_1live_8k", 4, 1, 8192),
-                              ("pb4_4live_8k", 4, 4, 8192)):
+    prefill = (("pb1_4k", 1, 1, 4096), ("pb1_8k", 1, 1, 8192),
+               ("pb4_1live_8k", 4, 1, 8192),
+               ("pb4_4live_8k", 4, 4, 8192)) if "prefill" in arms else ()
+    for tag, B, live, ctx in prefill:
         lens = jnp.asarray([ctx] * live + [0] * (B - live), jnp.int32)
         table = table_of(B, rng)
         q_nope, q_rope, q_lat = queries(B, T, ks[5])
         ref = "prefill_%s_xla" % tag
-
-        def xla(ql, qr, t, n, c_pool, r_pool):
-            return mla._attend_pool_xla(ql, qr, c_pool, r_pool, layer, t, n,
-                                        SCALE)
-        xla.__name__ = ref
-        out.append((ref, jax.jit(xla),
+        out.append((ref, _named(xla, ref),
                     (q_lat, q_rope, table, lens, c_pool, r_pool), None, {}))
 
         def mat(qn, qr, t, n, c_pool, r_pool, w_uk, w_uv):
             return _materialised(qn, qr, w_uk, w_uv, c_pool, r_pool, layer,
                                  t, n)
-        mat.__name__ = "prefill_%s_materialised_xla" % tag
-        out.append((mat.__name__, jax.jit(mat),
+        label = "prefill_%s_materialised_xla" % tag
+        out.append((label, _named(mat, label),
                     (q_nope, q_rope, table, lens, c_pool, r_pool, w_uk,
                      w_uv), None, {}))
         for mb in (512, 1024):
@@ -181,11 +196,20 @@ def cases(key):
                     block_rows=mb, name=pa.PREFILL_NAME)
                 return (a.reshape(B, T, H, R), m.reshape(B, T, H),
                         l.reshape(B, T, H))
-            kern.__name__ = "prefill_%s_kernel_mb%d" % (tag, mb)
-            out.append((kern.__name__, jax.jit(kern),
+            label = "prefill_%s_kernel_mb%d" % (tag, mb)
+            out.append((label, _named(kern, label),
                         (q_lat, q_rope, table, lens, c_pool, r_pool), ref,
                         {}))
     return out
+
+
+def _named(fn, label):
+    """``fn`` jitted as a program of its own, named ``label``: the name
+    its executions carry on the trace's ``XLA Modules`` line."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = label
+    return jax.jit(program)
 
 
 def _normalised(part):
@@ -194,12 +218,14 @@ def _normalised(part):
 
 
 def main() -> int:
+    global PS, P, PAGES
     ap = argparse.ArgumentParser()
     ap.add_argument("--reps", type=int, default=6)
     ap.add_argument("--page-size", type=int, default=PS, choices=[64, 128])
+    ap.add_argument("--arms", default="decode,prefill",
+                    help="comma-separated: decode, prefill")
     ap.add_argument("--out", default="chiprun_out/latent_attn_timing.json")
     opts = ap.parse_args()
-    global PS, P, PAGES
     if opts.page_size != PS:
         PS, P, PAGES = opts.page_size, P * PS // opts.page_size, \
             PAGES * PS // opts.page_size
@@ -211,7 +237,8 @@ def main() -> int:
     refs, agree, table = {}, True, []
     opts_tr = jax.profiler.ProfileOptions()
     opts_tr.python_tracer_level = 0     # device lines only: a small file
-    for label, fn, args, ref, work in cases(jax.random.PRNGKey(31)):
+    for label, fn, args, ref, work in cases(jax.random.PRNGKey(31),
+                                             opts.arms.split(",")):
         try:
             got = jax.block_until_ready(fn(*args))
         except Exception as e:     # a form the compiler refuses is a row
@@ -253,7 +280,7 @@ def main() -> int:
         print(json.dumps(row), flush=True)
     result = {"ok": agree, "device": {"platform": dev.platform,
                                       "kind": dev.device_kind},
-              "reps": opts.reps, "table": table}
+              "reps": opts.reps, "page_size": PS, "table": table}
     os.makedirs(os.path.dirname(opts.out), exist_ok=True)
     with open(opts.out, "w") as f:
         json.dump(result, f, indent=1)
