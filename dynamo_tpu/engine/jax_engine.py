@@ -457,6 +457,11 @@ class JaxEngine:
         if self.block > 1:
             _refuse_block_generation(model_cfg, self.ecfg, mesh)
         self.diffusion = dict.fromkeys(_BLOCK_WINDOW_COUNTS + ("tokens",), 0)
+        # what a model module's decode window counts by itself and
+        # returns before the state (its WINDOW_COUNTS names them: routed
+        # and held expert pairs, models/granite.py); summed into stats()
+        self.window_counts = dict.fromkeys(
+            getattr(model, "WINDOW_COUNTS", ()), 0)
         # a one-device mesh names the replica's OWN device (dynashard's
         # one-chip replicas): params and pools are built and committed
         # there, and the step thread uploads its inputs there — without
@@ -799,10 +804,11 @@ class JaxEngine:
         return np.full((n, self.block), -1, np.int32)
 
     def _split_info(self, out):
-        """A window program's results without the per-row counts the
-        block window returns last ((results, counts); counts None for a
-        window of tokens)."""
-        if self.block == 1:
+        """A window program's results without the counts it returns last
+        ((results, counts): the block window's per-row counts, or the
+        vector a module's WINDOW_COUNTS names; None for a window that
+        counts nothing)."""
+        if self.block == 1 and not self.window_counts:
             return out, None
         *out, info = out
         return out, info
@@ -1256,6 +1262,7 @@ class JaxEngine:
             "kv_active_blocks": self.pm.active,
             "kv_total_blocks": self.ecfg.num_pages - 1,
             **self._state_stats(),
+            **self.window_counts,
             **self._diffusion_stats(),
             "num_requests_waiting": len(self.waiting),
             "queue_wait_seconds_total": round(self.queue_wait_seconds_total,
@@ -2509,7 +2516,10 @@ class JaxEngine:
             counts = np.asarray(pend.emitted)
             done = np.asarray(pend.carry[2])
             info = None if pend.info is None else np.asarray(pend.info)
-        if info is not None:
+        if info is not None and self.block == 1:
+            for k, v in zip(self.window_counts, info):
+                self.window_counts[k] += int(v)
+        elif info is not None:
             for k, v in zip(_BLOCK_WINDOW_COUNTS,
                             info[:len(pend.batch)].sum(axis=0)):
                 self.diffusion[k] += int(v)

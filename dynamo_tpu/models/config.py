@@ -100,6 +100,35 @@ class ModelConfig:
     layer_types: tuple = ()
     conv_l_cache: int = 3
     num_dense_layers: int = 0
+    # Granite 4.0-H (model_type "granitemoehybrid", models/granite.py):
+    # layer_types names each layer "mamba" or "attention" (no positional
+    # embedding); a Mamba-2 mixer has mamba_n_heads heads of mamba_d_head
+    # channels, each with a [mamba_d_head, mamba_d_state] matrix of state
+    # and ONE scalar decay, and B and C shared by all heads
+    # (one group: more is refused); mamba_chunk_size is the chunk of the matmul
+    # form a prompt runs. mamba_n_heads > 0 switches the model module.
+    # Every layer's second half is routed experts (top-k of the router's
+    # outputs, softmax over the chosen) plus one shared expert of
+    # shared_intermediate_size. Four multipliers: the embedding's, the
+    # attention scores' (in place of 1/sqrt(head_dim)), the one on what
+    # every block adds to the residual stream, and the divisor of the
+    # logits.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 64
+    mamba_chunk_size: int = 256
+    shared_intermediate_size: int = 0
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # The chip's share of a layer's experts (expert parallelism on one
+    # of its chips): num_experts is how many are HELD here, the router
+    # keeps router_experts outputs (0 = num_experts: every expert is
+    # here) and the held ones are [first_expert, first_expert +
+    # num_experts). A pair routed elsewhere is not computed, and nothing
+    # stands in for it.
+    router_experts: int = 0
+    first_expert: int = 0
     # Generation by diffusion over blocks (model_type "sdar_moe"): the
     # attention mask is causal across blocks of block_length positions and
     # bidirectional inside one, the logits at a position are the
@@ -140,6 +169,12 @@ class ModelConfig:
                 f"page's end, and a prefix hit on it would not be exact")
 
     @property
+    def router_width(self) -> int:
+        """Outputs of the MoE router: the published count of experts,
+        whatever share of them is held here."""
+        return self.router_experts or self.num_experts
+
+    @property
     def mamba_d_inner(self) -> int:
         return self.mamba_expand * self.hidden_size
 
@@ -149,7 +184,7 @@ class ModelConfig:
         of two kinds (every layer attends otherwise)."""
         if self.layer_types:
             return tuple(l for l, kind in enumerate(self.layer_types)
-                         if kind == "full_attention")
+                         if kind in ("full_attention", "attention"))
         if not self.has_recurrent_state:
             return tuple(range(self.num_layers))
         return tuple(l for l in range(self.num_layers)
@@ -163,7 +198,10 @@ class ModelConfig:
     @property
     def attn_scale(self) -> float:
         """Attention logit scale: 1/sqrt(head_dim) unless the config pins
-        a different denominator (Gemma-2's query_pre_attn_scalar)."""
+        a different denominator (Gemma-2's query_pre_attn_scalar) or the
+        scale itself (Granite's attention_multiplier)."""
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
         denom = self.query_pre_attn_scalar or self.head_dim_
         return 1.0 / (denom ** 0.5)
 
@@ -339,6 +377,8 @@ class ModelConfig:
             c.moe_renorm_eps = 1e-6
             c.routed_scaling_factor = cfg.get("routed_scaling_factor", 1.0)
             c.tie_word_embeddings = cfg.get("tie_word_embeddings", True)
+        if mt == "granitemoehybrid":
+            c._read_granite(cfg)
         if mt in ("gemma", "gemma2"):
             # Gemma rides the Llama GQA stack with four semantic switches
             c.model_type = "gemma"
@@ -358,6 +398,71 @@ class ModelConfig:
                 c.final_logit_softcap = cfg.get("final_logit_softcapping")
                 c.query_pre_attn_scalar = cfg.get("query_pre_attn_scalar")
         return c
+
+    def _read_granite(self, cfg: dict) -> None:
+        """The keys of a ``granitemoehybrid`` config.json. ``num_local_
+        experts`` is the experts HELD; a file cut to a chip's share names
+        the published count (``router_num_experts``) and the first expert
+        held (``first_local_expert``) beside it."""
+        def refuse(what: str, why: str):
+            raise NotImplementedError(
+                f"granitemoehybrid with {what} is not supported ({why})")
+
+        L = cfg["num_hidden_layers"]
+        kinds = tuple(cfg["layer_types"][:L])
+        odd = sorted(set(kinds) - {"mamba", "attention"})
+        if odd or len(kinds) != L:
+            refuse(f"layer_types {odd or len(kinds)}",
+                   "it must name num_hidden_layers layers, each mamba or "
+                   "attention")
+        if cfg.get("mamba_n_groups", 1) != 1:
+            refuse("mamba_n_groups > 1",
+                   "B and C are computed once for all heads, and the "
+                   "chunked form's C.B product is one matrix a chunk")
+        if cfg.get("position_embedding_type", "nope") != "nope":
+            refuse(f"position_embedding_type "
+                   f"{cfg['position_embedding_type']!r}",
+                   "its attending layers apply no positional embedding")
+        if cfg.get("mamba_proj_bias") or cfg.get("attention_bias"):
+            refuse("mamba_proj_bias or attention_bias true",
+                   "the mixers' projections are computed without a bias")
+        if not cfg.get("mamba_conv_bias", True):
+            refuse("mamba_conv_bias false",
+                   "the causal convolution adds its bias leaf")
+        heads, d_head = cfg["mamba_n_heads"], cfg["mamba_d_head"]
+        expand = cfg.get("mamba_expand", 2)
+        if heads * d_head != expand * cfg["hidden_size"]:
+            refuse(f"mamba_n_heads x mamba_d_head = {heads * d_head}",
+                   f"mamba_expand x hidden_size is "
+                   f"{expand * cfg['hidden_size']}, the mixer's one inner "
+                   f"width")
+        held = cfg["num_local_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("first_local_expert", 0)
+        if not 0 <= first <= width - held:
+            refuse(f"first_local_expert {first}",
+                   f"the {held} experts held must lie inside the "
+                   f"router's {width}")
+        if cfg["num_experts_per_tok"] > width:
+            refuse(f"num_experts_per_tok {cfg['num_experts_per_tok']}",
+                   f"the router has {width} outputs")
+        self.model_type = "granitemoehybrid"
+        self.layer_types = kinds
+        self.mamba_n_heads, self.mamba_d_head = heads, d_head
+        self.mamba_d_state = cfg["mamba_d_state"]
+        self.mamba_d_conv = cfg.get("mamba_d_conv", 4)
+        self.mamba_expand = expand
+        self.mamba_chunk_size = cfg.get("mamba_chunk_size", 256)
+        self.shared_intermediate_size = cfg.get("shared_intermediate_size",
+                                                0)
+        self.embedding_multiplier = float(cfg.get("embedding_multiplier", 1))
+        self.attention_multiplier = cfg.get("attention_multiplier")
+        self.residual_multiplier = float(cfg.get("residual_multiplier", 1))
+        self.logits_scaling = float(cfg.get("logits_scaling", 1))
+        self.num_experts, self.router_experts = held, width
+        self.first_expert = first
+        self.num_experts_per_tok = cfg["num_experts_per_tok"]
+        self.tie_word_embeddings = cfg.get("tie_word_embeddings", True)
 
     @classmethod
     def from_local_path(cls, path: str) -> "ModelConfig":

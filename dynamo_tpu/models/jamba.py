@@ -55,7 +55,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import List, Tuple
+from typing import Callable, List, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -232,6 +232,26 @@ def _ssm_chunk(s0, dt, x, b, c, a_neg):
     return s, y
 
 
+def _causal_conv(mp, x, valid, tail, dc: int):
+    """silu(causal depthwise conv1d(x; conv_w, b_conv)) on a chunk x
+    [B, T, C] float32 entered with the rows' last dc - 1 inputs ``tail``
+    [B, dc - 1, C]. Returns (the result [B, T, C], the next chunk's
+    tail)."""
+    f32 = jnp.float32
+    T = x.shape[1]
+    with jax.named_scope("ssm.conv"):
+        xp = jnp.concatenate([tail.astype(f32), x], axis=1)
+        cw = mp["conv_w"].astype(f32)                       # [dc, C]
+        xc = jax.nn.silu(mp["b_conv"].astype(f32) + sum(
+            xp[:, k:k + T] * cw[k] for k in range(dc)))
+        # the next chunk's tail: the dc - 1 inputs that end at the
+        # row's last valid token (the old tail where it has none)
+        n_valid = jnp.sum(valid, axis=1)
+        tail = jax.vmap(lambda row, n: lax.dynamic_slice_in_dim(
+            row, n, dc - 1, 0))(xp, n_valid).astype(tail.dtype)
+    return xc, tail
+
+
 def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step):
     """The Mamba-1 mixer on a chunk. u [B, T, D] (normed); valid [B, T]
     (a row's valid tokens lead); s [B, N, di] float32 and tail
@@ -257,16 +277,7 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step):
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm.proj"):
             x, z = jnp.split(dot(u, mp["w_in"]), 2, axis=-1)    # [B, T, di]
-        with jax.named_scope("ssm.conv"):
-            xp = jnp.concatenate([tail.astype(f32), x], axis=1)
-            cw = mp["conv_w"].astype(f32)                       # [dc, di]
-            xc = jax.nn.silu(mp["b_conv"].astype(f32) + sum(
-                xp[:, k:k + T] * cw[k] for k in range(dc)))
-            # the next chunk's tail: the dc - 1 inputs that end at the
-            # row's last valid token (the old tail where it has none)
-            n_valid = jnp.sum(valid, axis=1)
-            tail = jax.vmap(lambda row, n: lax.dynamic_slice_in_dim(
-                row, n, dc - 1, 0))(xp, n_valid).astype(tail.dtype)
+        xc, tail = _causal_conv(mp, x, valid, tail, dc)
         with jax.named_scope("ssm.proj"):
             dt_r, b, c = jnp.split(dot(xc, mp["w_x"]), [R, R + N], axis=-1)
             dt_r = rms_norm(dt_r, mp["dt_norm"].astype(f32), eps)
@@ -294,8 +305,37 @@ def _at(params: Params, keys, i):
             for k in keys}
 
 
+def _dense_ff(params: Params, cfg: ModelConfig, norm, h, l, valid):
+    """Jamba's second half of layer l (traced inside a run): h + the
+    dense SwiGLU MLP of norm(h), and nothing counted."""
+    lp = _at(params, ("ln_mlp", "w_gate", "w_up", "w_down"), l)
+    return h + _mlp(norm(h, lp["ln_mlp"]), lp["w_gate"], lp["w_up"],
+                    lp["w_down"]), None
+
+
+class Blocks(NamedTuple):
+    """What a family of this layout supplies (runs of state-space mixers
+    between attending layers, one pool of scan state ``[S, M, N, C]``
+    float32 and one of conv tails ``[S, M, (d_conv - 1) * conv_width]``);
+    the layer loops, the pools' traffic, the attention and the window
+    are this module's for all of them. models/granite.py is the second."""
+    keys: tuple             # the state-space mixer's leaves, stacked [M, ...]
+    mixer: Callable         # _mamba's call form
+    ff: Callable            # _dense_ff's call form: the layer's second half
+    step: Callable          # selective_scan_step's call form: the kernel
+    conv_width: Callable    # cfg -> channels of a conv tail
+    # names of what ``ff`` counts a layer (int32, one each, summed over
+    # the layers and a window's steps and returned by the window before
+    # the state: the engine adds them to stats()); none: ff returns None
+    counts: tuple = ()
+
+
+MAMBA1 = Blocks(MAMBA_KEYS, _mamba, _dense_ff,
+                selective_scan_step, lambda cfg: cfg.mamba_d_inner)
+
+
 def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
-           cache, in_pool=None):
+           cache, in_pool=None, blocks: Blocks = MAMBA1):
     """All layers on h [B, T, D]. conv [B, M, (dc-1)*di] is the ROWS' conv
     tail (gathered by the caller), updated layer by layer. ssm is their
     scan state: the rows' own, [B, M, N, di], sliced and updated a layer
@@ -306,10 +346,13 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
     the pool's buffer, never a copy of the rows. ``attend(a, x, cache) ->
     (out, cache)`` is the attention mixer of attending layer a on the
     normed input: the caller owns how K/V are cached (pages for a chunk,
-    the window buffer inside the fused window)."""
+    the window buffer inside the fused window). Returns (the final norm
+    of h, ssm, conv, cache, what the layers' second halves counted: None
+    for blocks that count nothing)."""
     eps = cfg.rms_norm_eps
     B = h.shape[0]
-    dc1, di = cfg.mamba_d_conv - 1, cfg.mamba_d_inner
+    dc1, di = cfg.mamba_d_conv - 1, blocks.conv_width(cfg)
+    res = cfg.residual_multiplier
     wdt = params["embed"].dtype
     # the residual stream is float32 and every block reads it through a
     # norm that hands the matmuls the weights' type: 56 additions deep,
@@ -320,10 +363,15 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
     def norm(h, w):
         return rms_norm(h, w.astype(jnp.float32), eps).astype(wdt)
 
-    def mlp(h, l):
-        lp = _at(params, ("ln_mlp", "w_gate", "w_up", "w_down"), l)
-        return h + _mlp(norm(h, lp["ln_mlp"]), lp["w_gate"], lp["w_up"],
-                        lp["w_down"])
+    tally = jnp.zeros(len(blocks.counts), jnp.int32) if blocks.counts \
+        else None
+
+    def mlp(h, l, tally):
+        h, counted = blocks.ff(params, cfg, norm, h, l, valid)
+        return h, tally if counted is None else tally + counted
+
+    def add(h, out):        # a mixer's output onto the residual stream
+        return h + (out if res == 1.0 else res * out)
 
     for seg in segments(cfg):
         if seg[0] == "attn":
@@ -331,37 +379,38 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
             with jax.named_scope("attn"):
                 x = norm(h, params["ln_mixer"][l])
                 out, cache = attend(a, x, cache)
-                h = h + out
-            h = mlp(h, l)
+                h = add(h, out)
+            h, tally = mlp(h, l, tally)
             continue
         _, m0, l0, count = seg
 
         def layer(carry, i, m0=m0, l0=l0):
-            h, ssm, conv = carry
+            h, ssm, conv, tally = carry
             m = m0 + i
-            mp = _at(params, MAMBA_KEYS, m)
+            mp = _at(params, blocks.keys, m)
             x = norm(h, lax.dynamic_index_in_dim(
                 params["ln_mixer"], l0 + i, 0, False))
             tail = lax.dynamic_index_in_dim(conv, m, 1, False).reshape(
                 B, dc1, di)
             if in_pool is None:
-                out, s, tail = _mamba(
+                out, s, tail = blocks.mixer(
                     cfg, mp, x, valid,
                     lax.dynamic_index_in_dim(ssm, m, 1, False), tail)
                 ssm = lax.dynamic_update_index_in_dim(ssm, s, m, 1)
             else:
                 slots, fresh, interpret = in_pool
-                out, ssm, tail = _mamba(
+                out, ssm, tail = blocks.mixer(
                     cfg, mp, x, valid, ssm, tail,
-                    lambda pool, *row: selective_scan_step(
+                    lambda pool, *row: blocks.step(
                         pool, slots, m, *row, fresh, interpret=interpret))
             conv = lax.dynamic_update_index_in_dim(
                 conv, tail.reshape(B, dc1 * di), m, 1)
-            return (mlp(h + out, l0 + i), ssm, conv), None
+            h, tally = mlp(add(h, out), l0 + i, tally)
+            return (h, ssm, conv, tally), None
 
-        (h, ssm, conv), _ = lax.scan(layer, (h, ssm, conv),
-                                     jnp.arange(count, dtype=jnp.int32))
-    return norm(h, params["ln_final"]), ssm, conv, cache
+        (h, ssm, conv, tally), _ = lax.scan(
+            layer, (h, ssm, conv, tally), jnp.arange(count, dtype=jnp.int32))
+    return norm(h, params["ln_final"]), ssm, conv, cache, tally
 
 
 def _qkv(cfg: ModelConfig, params: Params, a: int, x):
@@ -395,7 +444,8 @@ def _store_rows(pool, slots, rows):
 
 def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
             page_table, flat_slots, state: State, state_slots,
-            allow_pallas: bool = True, page_slots=None, mesh=None):
+            allow_pallas: bool = True, page_slots=None, mesh=None,
+            blocks: Blocks = MAMBA1):
     """A chunk [B, T] for every row from its stored state (zeros where
     the chunk starts at position 0): prefill, and K=1 decode at T = 1.
     Arguments as llama.forward, plus the state pool and the rows' slots.
@@ -426,8 +476,9 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
                 (kv_k.at[a].set(k_l), kv_v.at[a].set(v_l)))
 
     h = embed_tokens(params, cfg, tokens)
-    h, ssm, conv, (kv_k, kv_v) = _stack(params, cfg, h, valid, ssm, conv,
-                                        attend, (kv_k, kv_v), in_pool)
+    h, ssm, conv, (kv_k, kv_v), _ = _stack(params, cfg, h, valid, ssm, conv,
+                                           attend, (kv_k, kv_v), in_pool,
+                                           blocks)
     if in_pool is None:
         ssm = _store_rows(state[0], state_slots, ssm)
     return h, kv_k, kv_v, (ssm, _store_rows(state[1], state_slots, conv))
@@ -436,7 +487,8 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
 # ----------------------------------------------------- jitted entry points
 
 
-def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
+def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None,
+                  blocks: Blocks = MAMBA1):
     """(prefill_step, decode_step) as llama.make_step_fns builds them, each
     with two more operands, the state pool (donated like the KV pools)
     and the rows' slots, and one more result, the pool."""
@@ -448,7 +500,7 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
         h, kv_k, kv_v, state = forward(
             params, cfg, tokens, positions, kv_k, kv_v, page_table,
             flat_slots, state, state_slots, allow_pallas=allow_pallas,
-            page_slots=page_slots, mesh=mesh)
+            page_slots=page_slots, mesh=mesh, blocks=blocks)
         return logits_at(params, cfg, h, last_idx), kv_k, kv_v, state
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "state"))
@@ -457,7 +509,7 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
         h, kv_k, kv_v, state = forward(
             params, cfg, tokens[:, None], positions[:, None], kv_k, kv_v,
             page_table, flat_slots[:, None], state, state_slots,
-            allow_pallas=allow_pallas, mesh=mesh)
+            allow_pallas=allow_pallas, mesh=mesh, blocks=blocks)
         return (logits_at(params, cfg, h,
                           jnp.zeros(tokens.shape[0], jnp.int32)),
                 kv_k, kv_v, state)
@@ -467,7 +519,8 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
 
 def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                           max_top_k: int = 64, mesh=None,
-                          pallas_interpret: bool = False):
+                          pallas_interpret: bool = False,
+                          blocks: Blocks = MAMBA1):
     """The fused K-step window of llama.make_decode_window_fn (read-only
     KV pool + window buffer + on-device carry) with the rows' recurrent
     state carried beside it, advanced by every step a row is active in:
@@ -520,22 +573,25 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                         (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
 
             h = embed_tokens(params, cfg, tok)[:, None]
-            h, ssm, conv, (wk, wv) = _stack(
+            h, ssm, conv, (wk, wv), counted = _stack(
                 params, cfg, h, active[:, None], ssm, conv, attend, (wk, wv),
-                in_pool)
+                in_pool, blocks)
             return (logits_at(params, cfg, h, jnp.zeros(B, jnp.int32)),
-                    wk, wv, ssm, conv)
+                    wk, wv, ssm, conv, counted)
 
         tok, pos = tokens, positions
         toks, lps, tvs, tis = [], [], [], []
         emitted = jnp.zeros((B,), jnp.int32)
+        tally = []      # what each step's layers counted (blocks.counts)
         for i in range(k_steps):
             # a frozen or padding row flows through the matmuls; its
             # state does not move (dt masked to 0, conv tail kept) and
             # its K/V never commit
             active = carry_active(done, pos)
-            logits, wk, wv, ssm, conv = one_step(tok, pos, active, wk, wv,
-                                                 ssm, conv, i)
+            logits, wk, wv, ssm, conv, counted = one_step(
+                tok, pos, active, wk, wv, ssm, conv, i)
+            if counted is not None:
+                tally.append(counted)
             nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
                                 steps, max_top_k=max_top_k,
                                 penalties=penalties)
@@ -556,10 +612,14 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
             state = (ssm, _store_rows(state[1], state_slots, conv))
         out_toks = jnp.stack(toks, axis=1)
         carry = (tok, pos, done, steps, remaining)
+        # the window's own counts go before the state, where the block
+        # window's go (JaxEngine._split_info)
+        counted = (sum(tally),) if tally else ()
         if logprobs_topn:
             aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
                    jnp.stack(tis, axis=1))
-            return out_toks, emitted, aux, carry, kv_k, kv_v, state
-        return out_toks, emitted, carry, kv_k, kv_v, state
+            return (out_toks, emitted, aux, carry, kv_k, kv_v, *counted,
+                    state)
+        return out_toks, emitted, carry, kv_k, kv_v, *counted, state
 
     return decode_window

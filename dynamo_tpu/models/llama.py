@@ -150,10 +150,13 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float,
 
 def embed_tokens(params: Params, cfg: ModelConfig,
                  tokens: jax.Array) -> jax.Array:
-    """Token embedding lookup; Gemma scales by sqrt(hidden)."""
+    """Token embedding lookup; Gemma scales by sqrt(hidden), Granite by
+    its embedding_multiplier."""
     h = params["embed"][tokens]
     if cfg.embed_scale:
         h = h * jnp.asarray(math.sqrt(cfg.hidden_size), h.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
     return h
 
 
@@ -167,6 +170,8 @@ def project_logits(params: Params, cfg: ModelConfig,
         if head is None:
             head = params["embed"].T
         logits = (h @ head).astype(jnp.float32)
+        if cfg.logits_scaling != 1.0:       # Granite divides its logits
+            logits = logits / cfg.logits_scaling
         if cfg.final_logit_softcap:
             c = cfg.final_logit_softcap
             logits = c * jnp.tanh(logits / c)
@@ -613,14 +618,16 @@ def moe_block_plan(counts: jax.Array, block: int, n_max: int):
 
 def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
                         w_gate, w_up, w_down, block: int, live=None,
-                        layer=None, act=jax.nn.silu) -> jax.Array:
+                        layer=None, act=jax.nn.silu,
+                        first=None) -> jax.Array:
     """Sparse top-k expert dispatch with static shapes, NO token drops,
     and work in proportion to the LIVE (token, expert) pairs.
 
     x: [N, D] (f32) flattened tokens; weights/idx: [N, k] routing output;
     live: optional [N] bool, False on padding rows (None = all live);
     w_*: a layer's [E, ...] expert stacks, or with ``layer`` (traced
-    index) the whole [L, E, ...] parameters, read in place.
+    index) the whole [L, E, ...] parameters, read in place. ``first``:
+    see ``moe_experts``; a pair whose expert is not held is not live.
 
     Sort the N*k pairs by expert, a dead row's pairs behind every
     expert's run. Expert e's run is cut into ``ceil(count_e / block)``
@@ -648,6 +655,9 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
 
     with jax.named_scope("moe.dispatch"):
         pair_e = idx.reshape(-1)                          # [NK]
+        if first is not None:
+            pair_e = pair_e - first
+            pair_e = jnp.where((pair_e >= 0) & (pair_e < E), pair_e, E)
         if live is not None:
             pair_e = jnp.where(jnp.repeat(live, k), pair_e, E)
         order = jnp.argsort(pair_e, stable=True)          # sorted -> pair
@@ -705,7 +715,7 @@ def _moe_use_blocked(mesh, n_tokens: int, n_experts: int,
 
 def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
                 blocked: bool, live=None, layer=None,
-                out_dtype=jnp.float32) -> jax.Array:
+                out_dtype=jnp.float32, first=None) -> jax.Array:
     """The routed experts' MLPs on x [B, T, D], given a gate's output
     (weights, idx: [B, T, k]): the execution half of an MoE MLP, shared
     by every gate (the softmax top-k of ``_moe_mlp``, the sigmoid gate of
@@ -721,7 +731,16 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
       einsum; the sorted form's dynamic expert indexing would
       all-gather).
     float32 operands and accumulation in both; the result in
-    ``out_dtype``."""
+    ``out_dtype``.
+
+    ``first`` tells the layer WHICH experts it holds (the chip's share
+    of a layer under expert parallelism): None = all of them, idx counts
+    the w_* stacks' own E. An int: the gate routed over more experts than
+    are here, idx counts the gate's outputs, the stacks hold experts
+    ``[first, first + E)``, and only the pairs whose expert lies there
+    are computed (dense: their one-hot over the held range, all zeros
+    for an absent one; sorted: an absent pair is not live). The result
+    is this share's part of the sum; nothing stands in for the rest."""
     B, T, D = x.shape
     E = w_gate.shape[-3]
     k = idx.shape[-1]
@@ -731,11 +750,12 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
             weights.reshape(B * T, k), idx.reshape(B * T, k),
             w_gate, w_up, w_down, moe_block(B * T, k, w_gate.shape),
             live=None if live is None else live.reshape(B * T),
-            layer=layer)
+            layer=layer, first=first)
         return out.reshape(B, T, D).astype(out_dtype)
     with jax.named_scope("moe.router"):
+        held = idx if first is None else idx - first
         full_gate = jnp.sum(
-            jax.nn.one_hot(idx, E, dtype=jnp.float32) * weights[..., None],
+            jax.nn.one_hot(held, E, dtype=jnp.float32) * weights[..., None],
             axis=2)
     # dense-over-experts: out = sum_e gate[...,e] * mlp_e(x)
     with jax.named_scope("moe.experts"):

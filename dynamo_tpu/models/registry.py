@@ -4,14 +4,25 @@ The engine resolves init_params / init_kv_cache / make_step_fns through
 this table, so adding a family (reference: each engine adapter brings its
 own model zoo, lib/llm/src/engines/) is one module with the shared paged
 step-fn contract. The table dispatches by what a configuration HAS
-(latent ranks, a list of layer kinds, a state-space width), not by a
-flag per family. Four modules:
+(latent ranks, Mamba-2 heads, a list of layer kinds, a state-space
+width), not by a flag per family. Five modules:
 
 - ``llama.py``: one homogeneous stack of attention + MLP-or-MoE layers,
   scanned (Llama / Qwen2 / Qwen3 / Qwen3-MoE / Mixtral / Gemma shapes);
 - ``mla.py``: DeepSeek-V2/V3 latent attention (a latent and a rope pool);
 - ``jamba.py``: layers of two kinds in a fixed pattern, Mamba-1 mixers
-  and attention, dense MLPs;
+  and attention, dense MLPs; it owns the layout the next module shares
+  (runs of state-space layers scanned between the attending ones, a
+  pool of scan state and one of conv tails, the window's carry) and
+  takes a family's mixer, second half of a layer and step kernel as
+  ``jamba.Blocks``;
+- ``granite.py`` (``mamba_n_heads`` > 0: ``granitemoehybrid``): on that
+  layout, Mamba-2 mixers (a matrix of state a head, a chunked matmul
+  form for prompts, ``ops/selective_scan.py ssd_step`` for decode),
+  attention without positions scaled by ``attention_multiplier``, and
+  in every layer routed experts beside one shared expert (llama.py's
+  expert execution, told which experts it holds), under the embedding,
+  residual and logits multipliers;
 - ``lfm2.py``: layers of two kinds by a list (``layer_types``), gated
   short convolutions and attention, two dense MLPs and then routed
   experts (mla.py's sigmoid gate, llama.py's expert execution).
@@ -40,8 +51,17 @@ refuse_recurrent_state``), and, by the request, a sampling penalty or
 ``logit_bias``. ``page_size`` must be a multiple of ``block_length``
 and ``decode_steps`` a multiple of it (``ValueError``).
 
-**Which keep state.** ``jamba.py`` and ``lfm2.py`` carry per-sequence
-**recurrent state** beside the KV pages. A module declares that by
+**Which hold a share of their experts.** A configuration whose
+``num_experts`` (held here) is less than its ``router_width`` is one
+chip's share of a layer under expert parallelism: the router keeps its
+published width and top-k, ``llama.moe_experts(first=...)`` computes the
+pairs routed to experts ``[first_expert, first_expert + num_experts)``
+in both execution forms, and the partial sum goes on; nothing stands in
+for the other chips or their exchange (one device: ROADMAP B9).
+``granite.py`` is the one module that passes it.
+
+**Which keep state.** ``jamba.py``, ``granite.py`` and ``lfm2.py`` carry
+per-sequence **recurrent state** beside the KV pages. A module declares that by
 having ``init_state(cfg, slots)`` (a tuple of pools indexed by slot);
 the engine then owns the pools, every step program takes ``(state,
 state_slots)`` as trailing operands and returns the state last.
@@ -55,11 +75,13 @@ whose snapshot its state starts from (-1: none). A program that writes
 a page's last token writes the row's state after that token under the
 page's id, so the snapshot lives and dies with the page. ``jamba.py``
 declares none (a Mamba row is 9.3 MB; a snapshot a page is out of the
-question): for it a prefix hit counts as a miss, as before.
+question), nor does ``granite.py`` (36 MiB a row at nine layers of 128 x
+64 x 128 float32): for them a prefix hit counts as a miss, as before.
 
 What refuses a model with recurrent state, at construction, each by a
 ``NotImplementedError`` that says "<what> is not supported for a model
-with recurrent state (models/jamba.py, models/lfm2.py): <why>; a state
+with recurrent state (models/jamba.py, models/lfm2.py,
+models/granite.py): <why>; a state
 snapshot lives in the device pool under its page's id, or not at all,
 and nothing moves or rolls back a state (ROADMAP B7)":
 
@@ -85,6 +107,10 @@ def get_model_module(cfg: ModelConfig):
         from . import mla
 
         return mla
+    if cfg.mamba_n_heads > 0:
+        from . import granite
+
+        return granite
     if cfg.layer_types:
         from . import lfm2
 

@@ -36,6 +36,7 @@ distinct (one sequence a slot).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -200,3 +201,143 @@ def selective_scan_step(pool: jax.Array, slots: jax.Array, layer: jax.Array,
       fresh.astype(jnp.int32), dt, x, b[:, :, None], c[:, :, None], a_neg,
       pool)
     return pool, y
+
+
+# ------------------------------------------- a matrix of state a head
+#
+# Mamba-2 (models/granite.py): H heads of P channels, each head a
+# [P, N] matrix of state with ONE scalar decay a head and B, C shared by
+# all heads:
+#
+#     S[h] = exp(dt[h] A[h]) S[h] + dt[h] (x[h] outer B)     y[h] = S[h] C
+#
+# The pool keeps a row's layer as [N, H * P] float32 (4 MiB at
+# granite-4.0-h-small's 128 x 64 x 128): the published [H, P, N] with N
+# moved to the front, so that what is 8,192 wide a row (x, dt, y: H * P)
+# lies along the lanes as it comes out of the projections and goes into
+# the next, and what is 128 wide (B, C) is what crosses to the sublanes;
+# y is a sum over sublanes. It is then the recurrence above with the
+# decay a row of [1, H * P] that every state index shares, and the kernel
+# below is _step_kernel's form at one row a grid step: a row is 4 MiB,
+# so the ring of three slots is 12 MiB of VMEM and a copy is long enough
+# to run at the memory's rate alone. The arithmetic runs over the row in
+# lane chunks (nothing of a row's size is held beside the slot).
+
+SSD_NAME = "ssd_step"           # the kernel's name in a device trace
+_SSD_LANES = 512                # lanes of a row advanced at a time
+
+
+def _ssd_kernel(slots_ref, layer_ref, fresh_ref,
+                # a row's decay and dt * x [1, 1, C], B and C [1, N, 1];
+                # the pool: whole, in HBM
+                dec_ref, dtx_ref, b_ref, c_ref, pool_in,
+                y_ref, pool_out, buf, sems):
+    i = pl.program_id(0)
+    steps = pl.num_programs(0)
+    layer = layer_ref[0]
+
+    def copy(j, out: bool):
+        k = jax.lax.rem(j, _SLOTS)
+        where = slots_ref[j], layer
+        if out:
+            return pltpu.make_async_copy(buf.at[k], pool_out.at[where],
+                                         sems.at[1, k])
+        return pltpu.make_async_copy(pool_in.at[where], buf.at[k],
+                                     sems.at[0, k])
+
+    @pl.when(i == 0)
+    def _():
+        copy(i, False).start()
+
+    # row i - 2 left from the slot row i + 1 comes into
+    @pl.when(i >= 2)
+    def _():
+        copy(i - 2, True).wait()
+
+    @pl.when(i + 1 < steps)
+    def _():
+        copy(i + 1, False).start()
+
+    copy(i, False).wait()
+    k = jax.lax.rem(i, _SLOTS)
+    N, C = buf.shape[1:]
+    W = math.gcd(C, _SSD_LANES)
+
+    # a chunk that starts a sequence starts from zeros, whatever the
+    # slot held
+    @pl.when(fresh_ref[i] != 0)
+    def _():
+        buf[k] = jnp.zeros((N, C), buf.dtype)
+
+    b, c = b_ref[0], c_ref[0]                           # [N, 1]
+
+    def lanes(j, carry):
+        at = pl.ds(pl.multiple_of(j * W, W), W)
+        s = dec_ref[0, :, at] * buf[k, :, at] + dtx_ref[0, :, at] * b
+        buf[k, :, at] = s                               # [N, W]
+        y_ref[0, :, at] = jnp.sum(s * c, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, C // W, lanes, 0)
+    copy(i, True).start()
+
+    @pl.when(i == steps - 1)
+    def _():
+        @pl.when(i >= 1)
+        def _():
+            copy(i - 1, True).wait()
+
+        copy(i, True).wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def ssd_step(pool: jax.Array, slots: jax.Array, layer: jax.Array,
+             dec: jax.Array, dtx: jax.Array, b: jax.Array, c: jax.Array,
+             fresh: jax.Array | None = None, *, interpret: bool = False):
+    """One token of the head-matrix recurrence for B rows whose state
+    lies in ``pool[slots[b], layer]``, in place.
+
+    pool: [S, M, N, C] float32, C = heads x head channels; slots: [B]
+    int32; ``layer`` a traced int32 scalar; dec = exp(dt * A) and dtx =
+    dt * x, both [B, C] (a head's decay repeated over its channels); b,
+    c: [B, N], all float32; ``fresh`` [B] bool: rows that start from
+    zeros. Returns (pool, y [B, C]) with y[b, hp] = sum_n s[n, hp] c[n]:
+    models/granite.py _ssd_step's arithmetic. A row with dec = 1 and dtx
+    = 0 (dt = 0) leaves its state bit for bit; several such rows may
+    share a slot. Rows that advance must hold distinct slots. jit-ted
+    for the reason selective_scan_step is."""
+    S, M, N, C = pool.shape
+    B = slots.shape[0]
+    if fresh is None:
+        fresh = jnp.zeros((B,), jnp.int32)
+
+    def row(i, *_):
+        return (i, 0, 0)
+
+    y, pool = pl.pallas_call(
+        _ssd_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B,),
+            in_specs=[pl.BlockSpec((1, 1, C), row),
+                      pl.BlockSpec((1, 1, C), row),
+                      pl.BlockSpec((1, N, 1), row),
+                      pl.BlockSpec((1, N, 1), row),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, 1, C), row),
+                       pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[pltpu.VMEM((_SLOTS, N, C), jnp.float32),
+                            pltpu.SemaphoreType.DMA((2, _SLOTS))]),
+        out_shape=[jax.ShapeDtypeStruct((B, 1, C), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operand 7 (after the three prefetched scalars: dec, dtx, b, c,
+        # pool) IS result 1: the rows are written where they were read
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_SLOTS * N * C * 4 + (8 << 20)),
+        interpret=interpret,
+        name=SSD_NAME,
+    )(slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+      fresh.astype(jnp.int32), dec[:, None, :], dtx[:, None, :],
+      b[:, :, None], c[:, :, None], pool)
+    return pool, y[:, 0]

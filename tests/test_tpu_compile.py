@@ -780,6 +780,97 @@ def test_jamba_programs_write_no_array_of_the_state_pools_size(
             < 15.75 * 1024 ** 3)
 
 
+def _granite(one_chip):
+    """The configuration as granite-4.0-h-small.rag-decode runs it (10 of
+    40 layers, 36 of 72 experts held, every width as published); params
+    and the three pools as shapes on the described chip, at the cell's
+    engine data."""
+    import json
+
+    from dynamo_tpu.models import granite
+    from dynamo_tpu.models.config import ModelConfig
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "granite-4.0-h-small.rag-decode.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", "granite-4.0-h-small"))
+    params = _on(one_chip, jax.eval_shape(
+        lambda: granite.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (_on(one_chip, x) for x in jax.eval_shape(
+        lambda: granite.init_kv_cache(cfg, llama.KVCacheSpec(e["num_pages"],
+                                                             64))))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: granite.init_state(cfg, e["max_batch"] + 1)))
+    assert state[0].shape == (65, 9, 128, 8192)     # 4 MiB a layer a row
+    return granite, cfg, params, kv_k, kv_v, state, e
+
+
+@pytest.mark.parametrize("B", [64, 4, 1])
+def test_ssd_step_kernel_compiles(one_chip, B):
+    """ops/selective_scan.py ssd_step at cell 8's pool and its three
+    batch buckets: the chip's compiler takes the 4 MiB row copies, the
+    12 MiB ring in VMEM and the lane chunks of a row."""
+    from dynamo_tpu.ops.selective_scan import ssd_step
+
+    s = partial(_sds, one_chip)
+    S, M, N, C = 65, 9, 128, 8192
+    f32 = jnp.float32
+    assert _has_kernel(ssd_step.lower(
+        s((S, M, N, C), f32), s((B,), jnp.int32), s((), jnp.int32),
+        s((B, C), f32), s((B, C), f32), s((B, N), f32), s((B, N), f32),
+        s((B,), jnp.bool_)).compile())
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
+def test_granite_programs_write_no_array_of_the_state_pools_size(
+        one_chip, tpu_kernel_path, monkeypatch, program):
+    """models/granite.py at the shapes of granite-4.0-h-small.rag-decode
+    (matrix-state pool [65, 9, 128, 8192] float32 = 2.28 GiB): the fused
+    window (B 64, 4 steps) and decode_step advance the state IN the pool
+    through the kernel: no value of the optimized program has the
+    gathered rows' shape [64, 9, 128, 8192] (2.25 GiB: gathered and
+    scattered back it would be 4.5 GiB a window) and no copy of a pool's
+    size exists. A prefill chunk (PB 4 x T 512) gathers its four rows and
+    stores them row by row in place: the pool aliases its input and is
+    never copied. Every program fits beside the 13.1 GiB resident."""
+    from dynamo_tpu.models import jamba
+
+    granite, cfg, params, kv_k, kv_v, state, e = _granite(one_chip)
+    monkeypatch.setattr(jamba, "_use_pallas", lambda: True)
+    s = partial(_sds, one_chip)
+    P, B = e["page_buckets"][-1], e["max_batch"]
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    if program == "window":
+        compiled = granite.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, state, i32, k_steps=4,
+            logprobs_topn=0).compile()
+    elif program == "decode_step":
+        compiled = granite.make_step_fns(cfg)[1].lower(
+            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, state,
+            i32).compile()
+    else:
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        compiled = granite.make_step_fns(cfg)[0].lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), state,
+            s((PB,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(text, state[0].size) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in (kv_k, kv_v, *state))
+    if program != "prefill":
+        assert "f32[%d,9,128,8192]" % B not in text
+        assert mem.temp_size_in_bytes < 2 ** 29
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)
+
+
 def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
     """The Pallas kernel's serialized module rides inside the
     tpu_custom_call's opaque config, source locations included: without

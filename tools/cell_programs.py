@@ -1,0 +1,204 @@
+#!/usr/bin/env python3
+"""A cell's serving programs for a DESCRIBED v5e, from shapes, whatever
+operands the model module's programs take: benchmark/rehearse.py's
+memory count for the cells it cannot lower (a model that keeps
+recurrent state: ``(state, state_slots)`` trailing operands, and
+``state_src`` where the module snapshots; a window whose token operand
+is a block a row), and a digest of each program as lowered.
+
+    python3 tools/cell_programs.py --workload CELL            # memory
+    python3 tools/cell_programs.py --workload CELL --digest   # no compile
+
+Memory (the default): every program of the cell's warm grid (each
+prefill bucket, each window bucket, ``decode_step`` at the largest
+batch), the benchmark's weight maker and one layer of the
+configuration's plain reference are compiled, nothing runs, and the
+compiler's own ``memory_analysis`` gives arguments, temporaries and
+aliased bytes. GB = 2**30 bytes. The last line is what ``about.json``'s
+``memory`` holds: resident = parameters + K/V pools + state pools, peak
+= resident + the largest temporaries of a serving program.
+
+``--digest``: sha256[:16] of each serving program's lowered StableHLO
+(with the scope names, without source lines), one line a program and
+one for the table: what a PR that must leave a cell's programs as they
+were compares between its parent's checkout and its own (``--code
+<checkout>`` imports ``dynamo_tpu`` and ``benchmark`` from there;
+``--root`` says where BENCHMARK.json and the cell's files are read).
+A compile that passes is not a chip run and gives no time.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import hashlib
+import json
+import os
+import sys
+import time
+from functools import partial
+
+os.environ["TPU_LOG_DIR"] = "disabled"
+os.environ["JAX_PLATFORMS"] = "cpu"
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+LIMIT_GB = 15.75
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--root", default=ROOT,
+                    help="where BENCHMARK.json and benchmark/ are read from")
+    ap.add_argument("--code", default=ROOT,
+                    help="the checkout whose dynamo_tpu/ is lowered")
+    ap.add_argument("--digest", action="store_true",
+                    help="digests of the lowered programs; no compile")
+    a = ap.parse_args()
+    sys.path.insert(0, os.path.abspath(a.code))
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from benchmark.harness import cells, weights
+    from dynamo_tpu.engine.jax_engine import (EngineConfig,
+                                              _make_decode_multi)
+    from dynamo_tpu.models import llama
+    from dynamo_tpu.models.config import ModelConfig
+    from dynamo_tpu.models.registry import get_model_module
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    # a location is the op's name-scope path alone, as in a serving
+    # process (runtime/compile_cache.py): no file, no line
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    from dynamo_tpu.models import jamba, lfm2, mla  # noqa: F401
+
+    # the chip's arms, as on the chip: the model code asks
+    # jax.default_backend(), which sees the CPU here, through a name
+    # each module imported for itself
+    for name, mod in sys.modules.items():
+        if name.startswith("dynamo_tpu.models.") \
+                and hasattr(mod, "_use_pallas"):
+            mod._use_pallas = lambda: True
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    i32 = jnp.int32
+
+    def s(shape, dtype=i32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def on(tree):
+        return jax.tree.map(lambda x: s(x.shape, x.dtype), tree)
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    cell = cells.load_cell(a.workload, a.root)
+    cfg = ModelConfig.from_local_path(cell["model_path"])
+    model = get_model_module(cfg)
+    ecfg = dataclasses.replace(EngineConfig(),
+                               **cells.engine_overrides(cell))
+    grid = ecfg.warmed_grid()
+    spec = llama.KVCacheSpec(ecfg.num_pages, ecfg.page_size)
+    params = on(jax.eval_shape(
+        lambda: model.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (on(x) for x in jax.eval_shape(
+        lambda: model.init_kv_cache(cfg, spec)))
+    state, snapshots = None, False
+    if hasattr(model, "init_state"):    # as JaxEngine.__init__ builds it
+        state = jax.eval_shape(
+            lambda: model.init_state(cfg, ecfg.max_batch + 1))
+        if hasattr(model, "init_state_snapshots"):
+            snapshots = True
+            state = (*state, jax.eval_shape(
+                lambda: model.init_state_snapshots(cfg, spec)))
+        state = on(state)
+
+    def state_args(rows, prefill=False):
+        if state is None:
+            return ()
+        return (state, s((rows,)), *([s((rows,))] if prefill and snapshots
+                                     else []))
+
+    out = {"cell": a.workload, "grid": grid,
+           "params_gb": nbytes(params) / 2 ** 30,
+           "kv_pool_gb": nbytes((kv_k, kv_v)) / 2 ** 30,
+           "state_pool_gb": nbytes(state) / 2 ** 30, "programs": []}
+    digests = []
+
+    def record(name, lowered):
+        if a.digest:
+            text = lowered.as_text(debug_info=True)
+            row = {"program": name,
+                   "sha256_16": hashlib.sha256(text.encode()).hexdigest()[:16]}
+            digests.append(row["sha256_16"])
+        else:
+            t0 = time.monotonic()
+            mem = lowered.compile().memory_analysis()
+            row = {"program": name,
+                   "arguments_gb": round(
+                       mem.argument_size_in_bytes / 2 ** 30, 3),
+                   "temporaries_gb": round(
+                       mem.temp_size_in_bytes / 2 ** 30, 3),
+                   "alias_gb": round(mem.alias_size_in_bytes / 2 ** 30, 3),
+                   "compile_s": round(time.monotonic() - t0, 1)}
+            out["programs"].append(row)
+        print(json.dumps(row), flush=True)
+
+    prefill, decode_step = model.make_step_fns(cfg)
+    if hasattr(model, "make_decode_window_fn"):
+        window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
+    else:
+        window = _make_decode_multi(model, cfg, ecfg.max_top_k)
+    ps, L = ecfg.page_size, cfg.block_length
+    for P in grid["page_buckets"]:
+        for T in grid["prefill_lens"]:
+            for PB in grid["prefill_batches"]:
+                pslots = s((PB, T // ps)) if T % ps == 0 else None
+                record(f"prefill PB={PB} T={T} P={P}", prefill.lower(
+                    params, s((PB, T)), s((PB, T)), kv_k, kv_v,
+                    s((PB, P)), s((PB, T)), s((PB,)), pslots,
+                    *state_args(PB, prefill=True)))
+        for B in grid["decode_batches"]:
+            row_i, row_f = s((B,)), s((B,), jnp.float32)
+            tokens = row_i if L == 1 else s((B, L))
+            for topn in ((0, 20) if a.digest else (0,)):
+                record(f"window B={B} P={P} topn={topn}", window.lower(
+                    params, tokens, row_i, s((B,), jnp.bool_), row_i,
+                    row_i, kv_k, kv_v, s((B, P)), row_f, row_i, row_f,
+                    s((B,), jnp.uint32), s((B, ecfg.max_eos_ids)), None,
+                    *state_args(B), k_steps=ecfg.decode_steps,
+                    logprobs_topn=topn))
+        if L == 1:
+            B = grid["decode_batches"][-1]
+            record(f"decode_step B={B} P={P}", decode_step.lower(
+                params, s((B,)), s((B,)), kv_k, kv_v, s((B, P)), s((B,)),
+                *state_args(B)))
+    if a.digest:
+        table = hashlib.sha256("".join(digests).encode()).hexdigest()[:16]
+        print(json.dumps({"cell": a.workload, "programs": len(digests),
+                          "sha256_16_of_table": table}))
+        return 0
+    serving = len(out["programs"])
+    record("weights.make_params", jax.jit(
+        lambda k: weights.build_tree(model, cfg, k, cell["weight_scales"])
+    ).lower(s((2,), jnp.uint32)))
+    layer = getattr(cells.load_reference(cell), "layer", None)
+    if layer is not None:
+        record("reference layer T=104", jax.jit(partial(layer, cfg)).lower(
+            params, s((104, cfg.hidden_size), jnp.float32), s((), i32)))
+    worst = max(r["temporaries_gb"] for r in out["programs"][:serving])
+    out["resident_gb"] = (out["params_gb"] + out["kv_pool_gb"]
+                          + out["state_pool_gb"])
+    out["peak_gb"] = out["resident_gb"] + worst
+    out["limit_gb"] = LIMIT_GB
+    out["fits"] = out["peak_gb"] < LIMIT_GB
+    print(json.dumps({k: v for k, v in out.items() if k != "programs"}))
+    return 0 if out["fits"] else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
