@@ -45,7 +45,7 @@ from ..llm.protocols.common import (FINISH_CANCELLED, FINISH_EOS,
                                     FINISH_LENGTH, FINISH_TIMEOUT,
                                     EngineOutput, PreprocessedRequest)
 from ..models.config import ModelConfig
-from ..models.llama import DROP_SLOT, KVCacheSpec
+from ..models.llama import DROP_SLOT, KVCacheSpec, moe_kernel_takes
 from ..models.registry import get_model_module
 from ..runtime import blackbox, guard, profiling, slo, tracing
 from ..runtime.config import env_bool, env_int, env_str
@@ -731,6 +731,10 @@ class JaxEngine:
         # tokens / rows against the slots of the bucket that ran
         self.prefill_slots_total = 0
         self.prefill_dispatches_total = 0
+        # of those, the programs whose expert layers run as one kernel
+        # (ops/moe_grouped.py): known from the bucket's rows by the rule
+        # the program was built under
+        self.moe_grouped_programs_total = 0
         self.decode_rows_total = 0
         self.decode_slots_total = 0
         self.decode_windows_total = 0
@@ -1286,6 +1290,7 @@ class JaxEngine:
             "prefill_tokens_total": self.prefill_tokens_total,
             "prefill_slots_total": self.prefill_slots_total,
             "prefill_dispatches_total": self.prefill_dispatches_total,
+            "moe_grouped_programs_total": self.moe_grouped_programs_total,
             "decode_rows_total": self.decode_rows_total,
             "decode_slots_total": self.decode_slots_total,
             "decode_windows_total": self.decode_windows_total,
@@ -1953,6 +1958,8 @@ class JaxEngine:
         self.steps += 1
         self.prefill_slots_total += B * T
         self.prefill_dispatches_total += 1
+        self.moe_grouped_programs_total += moe_kernel_takes(
+            self.cfg, self.params, self.mesh, B * T)
         self._stamp_first_dispatch(batch)
         self.step_timeline.add(
             "prefill", batch=len(batch), tokens=int(sum(chunks)),

@@ -135,6 +135,7 @@ STATS_PROMETHEUS_SKIP = {
            "first_token_seconds_total", "engine_ttft_seconds_total",
            "first_tokens_total", "prefill_tokens_total",
            "prefill_slots_total", "prefill_dispatches_total",
+           "moe_grouped_programs_total",
            "decode_rows_total", "decode_slots_total",
            "decode_windows_total", "warmup_seconds")},
     # the host's account beside it (PR 35; runtime/profiling.py

@@ -291,7 +291,7 @@ def _moe_ff(params: Params, cfg: ModelConfig, norm, h, l, valid):
             routed = moe_experts(x, weights, idx,
                                  *(params[n] for n in EXPERT_KEYS), True,
                                  live=valid, layer=l, out_dtype=x.dtype,
-                                 first=first)
+                                 first=first, width=cfg.router_width)
         else:
             lp = _at(params, EXPERT_KEYS, l)
             routed = moe_experts(x, weights, idx, lp["w_gate"], lp["w_up"],

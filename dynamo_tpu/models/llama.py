@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.moe_grouped import i_tile, moe_grouped_mlp
 from ..ops.paged_attention import (effective_window,
                                    paged_attention_decode,
                                    paged_attention_decode_sharded,
@@ -569,26 +570,32 @@ def _dyn_expert(w, e, layer=None):
     return one(w).astype(jnp.float32)
 
 
-def moe_block(n_tokens: int, top_k: int, w_shape) -> int:
+def moe_block(n_tokens: int, top_k: int, w_shape,
+              width: Optional[int] = None) -> int:
     """Rows of one block of the sorted dispatch, from the shapes at trace
-    time (``w_shape``: the [..., E, D, I] of an expert stack).
+    time (``w_shape``: the [..., E, D, I] of an expert stack; ``width``:
+    the experts the gate scored where that is more than the E held here,
+    see ``moe_experts``).
 
     The power of two at or above the pairs an expert gets from a full
-    bucket (N*k/E), within 32..256: a sparse bucket then pads each live
-    expert's one block with few dead rows. Where reading an expert's
-    weights outweighs a 256-row block's own traffic the padding rows are
-    free and a second block for the same expert is not (it reads the
-    weights again), so the block is the ridge's 256 whatever the bucket.
-    Per block: 3 bf16 matrices, 6*D*I bytes, against ~16 float32 bytes a
-    row for each of D + I columns (x in, the two [block, I] products,
-    y out and into place). On the v5e (PERF.md, PR 28): Mixtral-8x7B
-    (4,096 x 14,336) runs 512 slots in 4.5 ms a layer at 256 against
-    4.1-6.0 at 128; Qwen3-30B-A3B (2,048 x 768) runs 2,048 slots in
-    5.1-7.0 ms at 128 against 6.0 at 256."""
+    bucket (N*k/width), within 32..256: a sparse bucket then pads each
+    live expert's one block with few dead rows, which cost their share
+    of the block's arithmetic and of its rows in and out. Where an
+    expert's matrices are too large to be held whole while the next
+    one's stream in (ops/moe_grouped.py ``i_tile``, at bfloat16:
+    Mixtral's 4,096 x 14,336) every block streams them again, padding rows are free and a
+    second block for the same expert is not: the ridge's 256 whatever
+    the bucket. On the v5e, one layer through the kernel (PERF.md, PR 42;
+    the loop of small programs it replaced: PR 28): Mixtral-8x7B runs
+    512 slots in 4.1 ms at 256; Qwen3-30B-A3B (2,048 x 768) 2,048 slots
+    in 2.6-3.7 at 128; LFM2-24B-A2B (2,048 x 1,536) 1,024 slots in 1.9
+    at 64, and on PR 42's first build 2.2 at 128 against 2.6 at 256;
+    granite-4.0-h-small's 36 of 72 (4,096 x 768) 512 slots in 1.5 at
+    128, on the first build 1.7 against 2.1 at 256."""
     E, D, I = w_shape[-3:]
-    if 6 * D * I >= 16 * 256 * (D + I):
+    if i_tile(256, D, I, 2) < I:
         return 256
-    mean = max(n_tokens * top_k // E, 1)
+    mean = max(n_tokens * top_k // (width or E), 1)
     return min(max(1 << (mean - 1).bit_length(), 32), 256)
 
 
@@ -616,6 +623,114 @@ def moe_block_plan(counts: jax.Array, block: int, n_max: int):
     return b_end[-1], block_e, row0, g_end[block_e]
 
 
+def moe_pair_slots(counts: jax.Array, block: int, pair_e: jax.Array,
+                   place: jax.Array) -> jax.Array:
+    """Where each pair's row lies when every block of ``moe_block_plan``
+    has ``block`` rows of its own, block j's at ``j * block``: a pair of
+    expert e that is the r-th of e's run sits in e's block ``r // block``
+    at row ``r % block``, which is its place in sorted order moved up by
+    the padding of the runs before e's. ``pair_e`` [NK] (E = not live:
+    its slot is -1), ``place`` [NK] the pair's place in sorted order."""
+    E = counts.shape[0]
+    per_e = (counts + block - 1) // block
+    ahead = ((jnp.cumsum(per_e) - per_e) * block
+             - (jnp.cumsum(counts) - counts))             # [E]
+    # chosen by comparison, not by index: NK lookups in a table of E are
+    # NK scalar loads on the TPU (7-10 ns each)
+    mine = pair_e[:, None] == jnp.arange(E, dtype=pair_e.dtype)
+    return jnp.where(pair_e < E,
+                     place + jnp.sum(jnp.where(mine, ahead, 0), axis=1), -1)
+
+
+def _moe_kernel_interpret(w) -> Optional[bool]:
+    """How the sorted dispatch runs its blocks. None: the loop of small
+    XLA programs (off the TPU, and for int8 stacks, which ``_dyn_expert``
+    dequantizes an expert at a time). Else ops/moe_grouped.py runs them
+    as one kernel and this is its ``interpret`` flag; chosen as the
+    attention kernels are (a TPU backend, or DYN_PALLAS_INTERPRET off
+    it: the tests' hook)."""
+    from .quant import QuantInt8
+
+    if isinstance(w, QuantInt8):
+        return None
+    if _use_pallas():
+        return False
+    if env_flag("DYN_PALLAS_INTERPRET") and not env_flag("DYN_DISABLE_PALLAS"):
+        return True
+    return None
+
+
+def moe_kernel_takes(cfg: ModelConfig, params: Params, mesh,
+                     n_tokens: int) -> bool:
+    """Whether the expert layers of a program over ``n_tokens`` token
+    rows run ops/moe_grouped.py: the sorted form by the shape rule, and
+    stacks the kernel reads. (engine stats: moe_grouped_programs_total)"""
+    if cfg.num_experts == 0 or not _moe_use_blocked(
+            mesh, n_tokens, cfg.num_experts, cfg.num_experts_per_tok):
+        return False
+    # the routed experts' stack, under either name the modules give it
+    stack = params["w_gate_e"] if "w_gate_e" in params else params["w_gate"]
+    return _moe_kernel_interpret(stack) is not None
+
+
+# Rows the XLA side of the kernel's dispatch moves at a time. A prefill
+# bucket is mostly padding where one short prompt rides in a batch of
+# eight (cell 2: 12% of 2,048 rows live), so what is gathered in and read
+# back follows the LIVE blocks and tokens in loops whose bounds the
+# device knows after the sort, a few MB a turn (the bucket's worst case
+# in one gather each cost 8 of cell 2's 28 ms prefill: PERF.md, PR 42).
+_MOE_CHUNK_ROWS = 2048
+_MOE_CHUNK_TOKENS = 256
+
+
+def _moe_rows_in(x: jax.Array, tok: jax.Array, row0: jax.Array, n_blocks,
+                 block: int) -> jax.Array:
+    """[n_max * block, D]: block j's rows of ``x`` at ``j * block``, its
+    pairs' tokens and past its run whatever pair follows (no row's
+    result depends on another's, and only live pairs' rows are read
+    back). Only the blocks below ``n_blocks`` are filled, ``per`` at a
+    time; the rest of the buffer is never written and never read."""
+    n_max, D = row0.shape[0], x.shape[1]
+    per = max(_MOE_CHUNK_ROWS // block, 1)
+    last = tok.shape[0] - 1
+
+    def fill(i, xs):
+        rows = (lax.dynamic_slice(row0, (i * per,), (per,))[:, None]
+                + jnp.arange(block, dtype=jnp.int32)).reshape(-1)
+        return lax.dynamic_update_slice(
+            xs, x[tok[jnp.minimum(rows, last)]], (i * per * block, 0))
+
+    return lax.fori_loop(0, (n_blocks + per - 1) // per, fill,
+                         lax.empty((n_max * block, D), x.dtype))
+
+
+def _moe_rows_out(ys: jax.Array, slot: jax.Array,
+                  weights: jax.Array) -> jax.Array:
+    """[N, D] float32: every token's pairs read back from their slots
+    (``slot`` [N, k], -1 = not live: zeros, whatever the slot holds) and
+    summed with their routing weights, a chunk of tokens at a time and
+    only the chunks that hold a live pair."""
+    N, k = slot.shape
+    c = _MOE_CHUNK_TOKENS if N % _MOE_CHUNK_TOKENS == 0 else N
+    # [chunks, k, c]: a token's pairs along the major axis, so that
+    # their sum is k slabs added
+    slot = slot.reshape(N // c, c, k).transpose(0, 2, 1)
+    weights = weights.reshape(N // c, c, k).transpose(0, 2, 1)
+    holds = jnp.any(slot >= 0, axis=(1, 2))
+    live_first = jnp.argsort(~holds, stable=True)
+
+    def add(i, out):
+        at = live_first[i]
+        s = lax.dynamic_index_in_dim(slot, at, 0, False)
+        w = lax.dynamic_index_in_dim(weights, at, 0, False)
+        y = jnp.where((s >= 0)[..., None], ys[jnp.maximum(s, 0)], 0.0)
+        return lax.dynamic_update_slice(
+            out, jnp.sum(y * w[..., None], axis=0), (at * c, 0))
+
+    return lax.fori_loop(0, jnp.sum(holds), add,
+                         jnp.zeros((N, ys.shape[1]), jnp.float32))
+
+
 def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
                         w_gate, w_up, w_down, block: int, live=None,
                         layer=None, act=jax.nn.silu,
@@ -631,27 +746,36 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
 
     Sort the N*k pairs by expert, a dead row's pairs behind every
     expert's run. Expert e's run is cut into ``ceil(count_e / block)``
-    blocks and only those run (``moe_block_plan``; a ``fori_loop`` whose
-    bound is known on the device after the sort — on the v5e 3-17% faster
-    than a scan of the worst case under a ``cond``): each gathers its rows
-    of ``x``, fetches ONE expert's weights by traced index (HBM only
-    streams the experts that hold a pair) and writes its [block, D]
-    result at its place in sorted order. A run's last block overhangs
-    the next run with zero rows, which that run's own blocks, coming
-    later, overwrite. Every pair's result is then read back from its
-    sorted place and summed into its token with its routing weight; a
-    dead row reads zeros. Exact same math as the dense-over-experts
-    einsum: the per-row MLP does not depend on which block a row sits in.
+    blocks and only those run (``moe_block_plan``): each takes its rows
+    of ``x``, ONE expert's weights (HBM only streams the experts that
+    hold a pair) and yields its [block, D] result. Every pair's result is
+    then read back from where it lies and summed into its token with its
+    routing weight; a dead row reads zeros. Exact same math as the
+    dense-over-experts einsum: the per-row MLP does not depend on which
+    block a row sits in.
 
-    Nothing D wide is sized by a worst case: the one buffer is the
-    N*k + block pairs' own results, of which a sparse bucket writes only
-    its live blocks. Reference analog: vLLM's fused_moe dispatch.
+    Two forms of running the blocks (``_moe_kernel_interpret``):
+    - on a TPU ONE kernel whose grid is the plan (ops/moe_grouped.py):
+      the live blocks' rows are gathered in the weights' dtype, block
+      j's at ``j * block`` (``_moe_rows_in``), each block writes its own
+      slot, and the live tokens' pairs are read back from theirs
+      (``_moe_rows_out``);
+    - off it a ``fori_loop`` whose bound is known on the device after
+      the sort, one small XLA program a block, which writes its result
+      at its place in sorted order into the N*k + block pairs' own
+      buffer. A run's last block overhangs the next run with zero rows,
+      which that run's own blocks, coming later, overwrite.
+    Reference analog: vLLM's fused_moe dispatch.
     """
     N, D = x.shape
     k = idx.shape[-1]
     E = w_gate.shape[-3]
     NK = N * k
     n_max = (NK + block - 1) // block + E
+    interpret = _moe_kernel_interpret(w_gate)
+    if interpret is not None:       # whole chunks of blocks: _moe_rows_in
+        per = max(_MOE_CHUNK_ROWS // block, 1)
+        n_max = (n_max + per - 1) // per * per
 
     with jax.named_scope("moe.dispatch"):
         pair_e = idx.reshape(-1)                          # [NK]
@@ -668,6 +792,22 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
         counts = jnp.sum(jax.nn.one_hot(pair_e, E, dtype=jnp.int32), axis=0)
         n_blocks, block_e, row0, row_end = moe_block_plan(
             counts, block, n_max)
+
+    if interpret is not None:
+        with jax.named_scope("moe.dispatch"):
+            xs = _moe_rows_in(x.astype(w_gate.dtype), tok, row0, n_blocks,
+                              block)
+        with jax.named_scope("moe.experts"):
+            stacks = (w_gate, w_up, w_down)
+            if layer is None:
+                stacks, layer = [w[None] for w in stacks], 0
+            ys = moe_grouped_mlp(
+                xs, *stacks, jnp.asarray(layer, jnp.int32), n_blocks,
+                block_e, block=block, act=act, interpret=interpret)
+        with jax.named_scope("moe.dispatch"):
+            return _moe_rows_out(
+                ys, moe_pair_slots(counts, block, pair_e, place).reshape(N, k),
+                weights.astype(jnp.float32))
 
     def run_block(j, ys):
         r0 = row0[j]
@@ -715,7 +855,8 @@ def _moe_use_blocked(mesh, n_tokens: int, n_experts: int,
 
 def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
                 blocked: bool, live=None, layer=None,
-                out_dtype=jnp.float32, first=None) -> jax.Array:
+                out_dtype=jnp.float32, first=None,
+                width: Optional[int] = None) -> jax.Array:
     """The routed experts' MLPs on x [B, T, D], given a gate's output
     (weights, idx: [B, T, k]): the execution half of an MoE MLP, shared
     by every gate (the softmax top-k of ``_moe_mlp``, the sigmoid gate of
@@ -739,8 +880,10 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
     are here, idx counts the gate's outputs, the stacks hold experts
     ``[first, first + E)``, and only the pairs whose expert lies there
     are computed (dense: their one-hot over the held range, all zeros
-    for an absent one; sorted: an absent pair is not live). The result
-    is this share's part of the sum; nothing stands in for the rest."""
+    for an absent one; sorted: an absent pair is not live; ``width``,
+    the experts the gate scored, sizes its blocks by the pairs that stay
+    here). The result is this share's part of the sum; nothing stands in
+    for the rest."""
     B, T, D = x.shape
     E = w_gate.shape[-3]
     k = idx.shape[-1]
@@ -748,7 +891,7 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
         out = moe_experts_blocked(
             x.reshape(B * T, D).astype(jnp.float32),
             weights.reshape(B * T, k), idx.reshape(B * T, k),
-            w_gate, w_up, w_down, moe_block(B * T, k, w_gate.shape),
+            w_gate, w_up, w_down, moe_block(B * T, k, w_gate.shape, width),
             live=None if live is None else live.reshape(B * T),
             layer=layer, first=first)
         return out.reshape(B, T, D).astype(out_dtype)

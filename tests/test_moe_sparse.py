@@ -181,16 +181,23 @@ def test_shape_rule_keeps_dense_on_a_mesh():
     assert not llama._moe_use_blocked(mesh, 4096, 8, 2)
 
 
-@pytest.mark.parametrize("N,k,w_shape,block", [
-    (2048, 8, (128, 2048, 768), 128),    # Qwen3-MoE, PB 8 x T 256
-    (512, 8, (128, 2048, 768), 32),      # few pairs an expert: the floor
-    (8192, 8, (128, 2048, 768), 256),    # many: the ridge is the ceiling
-    (2048, 2, (8, 4096, 14336), 256),    # Mixtral, PB 4 x T 512
-    (512, 2, (3, 8, 4096, 14336), 256),  # Mixtral, PB 1 x T 512, in place:
-                                         # the weights outweigh the rows
+@pytest.mark.parametrize("N,k,w_shape,width,block", [
+    (2048, 8, (128, 2048, 768), None, 128),   # Qwen3-MoE, PB 8 x T 256
+    (512, 8, (128, 2048, 768), None, 32),     # few pairs an expert: the
+                                              # floor
+    (8192, 8, (128, 2048, 768), None, 256),   # many: the ceiling
+    (2048, 2, (8, 4096, 14336), None, 256),   # Mixtral, PB 4 x T 512
+    (512, 2, (3, 8, 4096, 14336), None, 256),  # Mixtral, PB 1 x T 512, in
+                                               # place: its matrices stream
+                                               # in tiles, again a block
+    (1024, 4, (64, 2048, 1536), None, 64),    # LFM2, PB 4 x T 256: held
+    (2048, 4, (64, 2048, 1536), None, 128),   # whole, so by the pairs
+    (512, 10, (36, 4096, 768), 72, 128),      # granite, PB 1 x T 512: half
+    (2048, 10, (36, 4096, 768), 72, 256),     # the pairs stay on this chip
+    (512, 10, (36, 4096, 768), None, 256),
 ])
-def test_block_comes_from_the_shapes(N, k, w_shape, block):
-    assert llama.moe_block(N, k, w_shape) == block
+def test_block_comes_from_the_shapes(N, k, w_shape, width, block):
+    assert llama.moe_block(N, k, w_shape, width) == block
 
 
 def test_moe_mlp_paths_agree():
@@ -284,3 +291,241 @@ def test_prefill_program_slices_no_layers_expert_stack(T, slices_a_layer):
     one = {"1x1x%dx%d" % (D, I), "1x1x%dx%d" % (I, D)}
     assert bool(stack & set(sliced)) == slices_a_layer
     assert bool(one & set(sliced)) != slices_a_layer
+
+
+# ---- the blocks as one grouped-matmul kernel (ops/moe_grouped.py) ----
+#
+# Under the Pallas interpreter, which the hook of the other kernels turns
+# on off the TPU. The interpreter hands out result buffers filled with
+# NaN: a slot no block wrote reads as NaN if anyone reads it.
+
+@pytest.fixture
+def kernel_form(monkeypatch):
+    monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    monkeypatch.delenv("DYN_DISABLE_PALLAS", raising=False)
+
+
+def _calls(monkeypatch):
+    """Count the kernel's calls from moe_experts_blocked."""
+    seen = []
+    real = llama.moe_grouped_mlp
+
+    def counted(*a, **kw):
+        seen.append(kw["block"])
+        return real(*a, **kw)
+
+    monkeypatch.setattr(llama, "moe_grouped_mlp", counted)
+    return seen
+
+
+@pytest.mark.parametrize("N,E,k,D,I,block", [
+    (300, 16, 4, 256, 128, 32),    # thin experts: I < D, many of them
+    (512, 8, 2, 128, 512, 256),    # wide: I > D, few
+    (256, 4, 1, 128, 128, 32),     # k = 1; every expert owns two blocks
+    (130, 8, 8, 128, 256, 64),     # k = E: every expert takes every row
+    (512, 128, 8, 128, 128, 32),   # Qwen3-MoE's routing shape
+])
+def test_kernel_matches_dense(kernel_form, monkeypatch, N, E, k, D, I, block):
+    seen = _calls(monkeypatch)
+    args = _case(N, E, k, D, I, seed=5)
+    ref = _dense_ref(*args)
+    got = llama.moe_experts_blocked(*args, block)
+    assert seen == [block]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("first", [None, 4, 12], ids=lambda f: "first_%s" % f)
+@pytest.mark.parametrize("share", [0.13, 1.0])
+def test_kernel_live_rows_held_experts_in_place(kernel_form, monkeypatch,
+                                                share, first):
+    """Dead rows make no pair, a pair whose expert is not held neither
+    (``first``: the stacks hold experts [first, first + 8) of the 24 the
+    router scores), and the stacks are the whole [L, E, ...] parameters:
+    the layers around the one asked for are NaN, so reading them
+    shows."""
+    seen = _calls(monkeypatch)
+    N, E, k, D, I = 256, 8, 4, 128, 128
+    x, _, _, wg, wu, wd = _case(N, E, k, D, I, seed=6)
+    w, idx = _routing(jax.random.PRNGKey(7), N, E if first is None else 24, k)
+    live = jax.random.uniform(jax.random.PRNGKey(9), (N,)) < share
+    held = idx if first is None else idx - first
+    here = (held >= 0) & (held < E)
+    ref = np.asarray(_dense_ref(x, jnp.where(here, w, 0.0),
+                                jnp.where(here, held, 0), wg, wu, wd))
+    stacks = [jnp.stack([a * jnp.nan, a, a * jnp.nan]) for a in (wg, wu, wd)]
+    got = np.asarray(jax.jit(lambda *a: llama.moe_experts_blocked(
+        *a, 32, live=live, layer=jnp.int32(1), first=first))(
+            x, w, idx, *stacks))
+    assert seen == [32]
+    keep = np.asarray(live)
+    np.testing.assert_allclose(got[keep], ref[keep], rtol=2e-4, atol=2e-4)
+    assert not got[~keep].any()
+
+
+def test_kernel_dispatch_follows_the_live_chunks(kernel_form):
+    """A bucket of four chunks of 256 tokens of which two hold live
+    rows, and more blocks than one turn of the gather takes: the rows in
+    and the pairs read back go by chunks of live work
+    (``_moe_rows_in`` / ``_moe_rows_out``); the dead chunks read zeros."""
+    N, E, k, D, I = 1024, 8, 8, 128, 128
+    x, w, idx, wg, wu, wd = _case(N, E, k, D, I, seed=15)
+    rows = np.arange(N)
+    live = jnp.asarray(((rows >= 256) & (rows < 300)) | (rows >= 800))
+    ref = np.asarray(_dense_ref(x, w, idx, wg, wu, wd))
+    got = np.asarray(jax.jit(lambda *a: llama.moe_experts_blocked(
+        *a, 32, live=live))(x, w, idx, wg, wu, wd))
+    keep = np.asarray(live)
+    assert llama._MOE_CHUNK_ROWS // 32 < keep.sum() * k // 32  # > one turn
+    np.testing.assert_allclose(got[keep], ref[keep], rtol=2e-4, atol=2e-4)
+    assert not got[~keep].any()
+
+
+def test_kernel_expert_without_a_pair_and_with_many_blocks(kernel_form):
+    """Experts 1 and 6 get every pair (five blocks of 64 for 300 rows
+    each), the other six none: they own no block and are never read."""
+    N, E, k, D, I = 300, 8, 2, 128, 128
+    x, w, _, wg, wu, wd = _case(N, E, k, D, I, seed=10)
+    idx = jnp.tile(jnp.asarray([[1, 6]], jnp.int32), (N, 1))
+    ref = _dense_ref(x, w, idx, wg, wu, wd)
+    unused = jnp.asarray([0, 2, 3, 4, 5, 7])
+    wg, wu, wd = (a.at[unused].set(jnp.nan) for a in (wg, wu, wd))
+    got = llama.moe_experts_blocked(x, w, idx, wg, wu, wd, 64)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_kernel_all_rows_dead(kernel_form, monkeypatch):
+    """No block runs, no slot is written: zeros, not what the slots
+    held."""
+    seen = _calls(monkeypatch)
+    args = _case(256, 8, 2, 128, 128, seed=11)
+    got = np.asarray(llama.moe_experts_blocked(
+        *args, 64, live=jnp.zeros((256,), bool)))
+    assert seen == [64]
+    assert got.shape == (256, 128) and not got.any()
+
+
+def test_kernel_bfloat16_stacks(kernel_form):
+    """Stored bfloat16, the operands go to the dots as bfloat16 (one pass
+    of the MXU, what XLA's default precision gives the loop form on the
+    chip) and accumulate in float32: the dense form within bfloat16's
+    rounding of x and of the activation."""
+    N, E, k, D, I = 256, 8, 2, 128, 256
+    x, w, idx, wg, wu, wd = _case(N, E, k, D, I, seed=12)
+    x = x.astype(jnp.bfloat16).astype(jnp.float32)
+    wg, wu, wd = (a.astype(jnp.bfloat16) for a in (wg, wu, wd))
+    ref = np.asarray(_dense_ref(x, w, idx, *(a.astype(jnp.float32)
+                                             for a in (wg, wu, wd))))
+    got = np.asarray(llama.moe_experts_blocked(x, w, idx, wg, wu, wd, 64))
+    assert np.abs(got - ref).max() <= 0.01 * np.abs(ref).max()
+
+
+@pytest.mark.parametrize("tile", [None, 128, 256], ids=lambda t: "tile_%s" % t)
+def test_kernel_tiles_the_intermediate_width(tile):
+    """The kernel alone on a plan of five blocks of which three run,
+    ``I`` whole or in tiles that accumulate the down-projection: the
+    same rows either way, and a block past the plan's end writes
+    nothing (its slot keeps the interpreter's NaN)."""
+    from dynamo_tpu.ops.moe_grouped import moe_grouped_mlp
+
+    E, D, I, block = 4, 128, 512, 32
+    wg, wu, wd = (a[None] for a in _weights(jax.random.PRNGKey(13), E, D, I))
+    xs = jax.random.normal(jax.random.PRNGKey(14), (5 * block, D))
+    block_e = jnp.asarray([0, 2, 2, 3, 3], jnp.int32)
+    ys = np.asarray(moe_grouped_mlp(
+        xs, wg, wu, wd, jnp.int32(0), jnp.int32(3), block_e, block=block,
+        interpret=True, tile=tile))
+    for j in range(3):
+        e, rows = int(block_e[j]), slice(j * block, (j + 1) * block)
+        want = (jax.nn.silu(xs[rows] @ wg[0, e]) * (xs[rows] @ wu[0, e])
+                ) @ wd[0, e]
+        np.testing.assert_allclose(ys[rows], np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+    assert np.isnan(ys[3 * block:]).all()
+
+
+@pytest.mark.parametrize("block,D,I,whole", [
+    (128, 2048, 768, True),      # Qwen3-30B-A3B, Kanana-2, SDAR (cells
+                                 # 2, 5, 7)
+    (256, 2048, 1536, True),     # LFM2-24B-A2B (cell 6)
+    (256, 4096, 768, True),      # granite-4.0-h-small (cell 8)
+    (256, 2048, 1408, True),     # Moonlight-16B-A3B
+    (256, 4096, 14336, False),   # Mixtral-8x7B (cells 1, 3)
+])
+def test_tile_comes_from_the_shapes(block, D, I, whole):
+    """An expert's matrices whole where two copies of them fit VMEM
+    beside the rows (consecutive blocks of one expert then share a
+    fetch), else whole lanes that divide I and fit."""
+    from dynamo_tpu.ops import moe_grouped
+
+    ti = moe_grouped.i_tile(block, D, I, 2)
+    assert (ti == I) is whole
+    assert I % ti == 0 and (ti == I or ti % 128 == 0)
+    assert moe_grouped._vmem_bytes(block, D, ti, 2) <= moe_grouped._VMEM_BYTES
+    if not whole:
+        assert moe_grouped._vmem_bytes(block, D, 2 * ti, 2) \
+            > moe_grouped._VMEM_BYTES or I % (2 * ti)
+
+
+@pytest.mark.parametrize("why", ["off_the_tpu", "pallas_disabled", "int8"])
+def test_loop_form_runs_where_the_kernel_may_not(monkeypatch, why):
+    """Off the TPU without the tests' hook, under the kill switch, and
+    for int8 stacks (an expert is dequantized at a time) the blocks run
+    as the loop of small programs: the kernel is never called."""
+    from dynamo_tpu.models.quant import quantize_int8
+
+    monkeypatch.delenv("DYN_PALLAS_INTERPRET", raising=False)
+    if why != "off_the_tpu":
+        monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    if why == "pallas_disabled":
+        monkeypatch.setenv("DYN_DISABLE_PALLAS", "1")
+    seen = _calls(monkeypatch)
+    x, w, idx, wg, wu, wd = _case(300, 8, 2, seed=8)
+    if why == "int8":
+        qs = [quantize_int8(a) for a in (wg, wu, wd)]
+        wg, wu, wd = (q.dequant(jnp.float32) for q in qs)
+    else:
+        qs = [wg, wu, wd]
+    assert llama._moe_kernel_interpret(qs[0]) is None
+    got = llama.moe_experts_blocked(x, w, idx, *qs, 64)
+    assert not seen
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(_dense_ref(x, w, idx, wg, wu, wd)),
+        rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("T,hook,takes", [
+    (512, True, True), (128, True, False), (512, False, False)])
+def test_engine_counts_the_prefill_programs_that_take_the_kernel(
+        monkeypatch, T, hook, takes):
+    """``moe_kernel_takes`` is the rule ``_dispatch_prefill`` counts
+    ``moe_grouped_programs_total`` by: the sorted form by the bucket's
+    rows, and a form of running it that is the kernel."""
+    monkeypatch.delenv("DYN_PALLAS_INTERPRET", raising=False)
+    if hook:
+        monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    cfg, params, _, _ = _prefill_setup(1)
+    assert llama.moe_kernel_takes(cfg, params, None, T) is takes
+    dense = ModelConfig.tiny()
+    assert not llama.moe_kernel_takes(
+        dense, llama.init_params(dense, jax.random.PRNGKey(0)), None, T)
+
+
+def test_serving_prefill_through_the_kernel_matches_the_loop(monkeypatch):
+    """llama.forward on a padded T = 512 chunk, experts in place, in
+    either form of running the blocks."""
+    cfg, params, (kv_k, kv_v), table = _prefill_setup(2)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (300,), 0, 500)
+    tok, pos, flat = _chunk(table, tokens, 0, 300, 512)
+    monkeypatch.delenv("DYN_PALLAS_INTERPRET", raising=False)
+    loop, _, _ = llama.forward(params, cfg, tok, pos, kv_k, kv_v, table,
+                               flat, allow_pallas=False)
+    monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    seen = _calls(monkeypatch)
+    kernel, _, _ = llama.forward(params, cfg, tok, pos, kv_k, kv_v, table,
+                                 flat, allow_pallas=False)
+    assert len(seen) == 1       # one trace of the scanned layer
+    np.testing.assert_allclose(np.asarray(kernel[0, :300], np.float32),
+                               np.asarray(loop[0, :300], np.float32),
+                               rtol=5e-2, atol=5e-2)

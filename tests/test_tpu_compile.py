@@ -411,6 +411,39 @@ def test_mla_moe_step_compiles_at_moonlight_widths(one_chip, step):
             < 16 * 1024 ** 3)
 
 
+# -------- the sorted expert dispatch as one kernel (ops/moe_grouped.py)
+
+
+@pytest.mark.parametrize("name,E,first,k,D,I,N", [
+    ("mixtral", 8, None, 2, 4096, 14336, 512),      # I in tiles
+    ("qwen3", 128, None, 8, 2048, 768, 2048),
+    ("lfm2", 64, None, 4, 2048, 1536, 1024),
+    ("granite", 36, 18, 10, 4096, 768, 2048),       # a share of 72
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_moe_grouped_kernel_compiles_at_cell_shapes(one_chip,
+                                                    tpu_kernel_path, name,
+                                                    E, first, k, D, I, N):
+    """One layer's sorted dispatch at the cells' widths, stacks in place
+    in bfloat16: the kernel is there, takes the VMEM its tile was sized
+    for, and nothing around it outgrows the rows' own buffers (block
+    j's rows in, its slot out) and a chunk of either on its way."""
+    block = llama.moe_block(N, k, (2, E, D, I))
+    per = llama._MOE_CHUNK_ROWS // block
+    n_max = -(-(-(-N * k // block) + E) // per) * per
+    s = partial(_sds, one_chip)
+    compiled = jax.jit(lambda x, w, idx, wg, wu, wd, live, layer:
+                       llama.moe_experts_blocked(
+                           x, w, idx, wg, wu, wd, block, live=live,
+                           layer=layer, first=first)).lower(
+        s((N, D), jnp.float32), s((N, k), jnp.float32), s((N, k), jnp.int32),
+        s((2, E, D, I), jnp.bfloat16), s((2, E, D, I), jnp.bfloat16),
+        s((2, E, I, D), jnp.bfloat16), s((N,), jnp.bool_),
+        s((), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 1.25 * n_max * block * D * (2 + 4) + (128 << 20)
+
+
 # ------------- latent attention at Kanana-2-30B-A3B's widths (cell 5)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -1,25 +1,43 @@
-"""Device time of one layer's MoE MLP in either form, at the cells' shapes.
+"""Device time of one layer's routed experts in every form, at the cells'
+shapes.
 
     chiprun -- python3 tools/moe_form_timing.py
 
-Times ``models.llama._moe_mlp`` on one layer of Mixtral-8x7B's experts
-(8, top-2, 4,096 x 14,336) at N = 128 / 512 / 2,048 rows and of
-Qwen3-30B-A3B's (128, top-8, 2,048 x 768) at N = 2,048, with ~13%, ~50%
-and 100% of the rows live, in the dense-over-experts form and in the
-sorted dispatch (``moe_experts_blocked``): the rule of
-``_moe_use_blocked`` rests on this table (PERF.md, PR 28). The form is
-forced from here, by patching the rule while the program is traced; the
-sorted form is also timed at block 128 and 256, and reading
-``w[layer, expert]`` in place from a ``[2, E, ...]`` stack. Dead rows
-are what a padded prefill holds: one and the same row (the embedding of
-token 0), marked by ``live`` where ``_moe_mlp`` takes it. A tree whose
-``_moe_mlp`` takes no ``live`` (before PR 28) is timed as it is: its
-sorted form is the static scan of ``N*k/256 + E`` blocks.
+Times ``models.llama.moe_experts`` behind a softmax top-k router on one
+layer of Mixtral-8x7B's experts (8, top-2, 4,096 x 14,336; cells 1, 3)
+at N = 128 / 512 / 2,048 rows, of Qwen3-30B-A3B's (128, top-8, 2,048 x
+768; cells 2, 7) at N = 2,048, of LFM2-24B-A2B's (64, top-4, 2,048 x
+1,536; cell 6) at N = 1,024 / 2,048 and of granite-4.0-h-small's (the 36
+this chip holds of 72, top-10, 4,096 x 768; cell 8) at N = 512 / 2,048,
+with ~13%, ~50% and 100% of the rows live, in three forms:
+
+- ``dense``: every expert on every row (``_moe_use_blocked`` says no);
+- ``loop``: the sorted dispatch as a ``fori_loop`` of one small XLA
+  program a block (``moe_experts_blocked`` off the TPU, and before
+  PR 42 on it);
+- ``kernel``: the sorted dispatch as one grouped-matmul kernel
+  (ops/moe_grouped.py; what a TPU runs since PR 42).
+
+The rule of ``_moe_use_blocked`` / ``moe_block`` rests on this table
+(PERF.md, PR 28, PR 42). The form is forced from here, by patching what
+picks it while the program is traced. The sorted forms read ``w[layer,
+expert]`` in place from a ``[2, E, ...]`` stack, as the cells' prefill
+programs do, at ``moe_block``'s rows a block and, with ``--blocks 128,
+256``, at others. Dead rows are what a padded prefill holds: one and the
+same row, marked by ``live``.
 
 The time is the program's duration on the device's clock (line ``XLA
-Modules`` of a profiler trace), median of ``--reps`` executions. The
-sorted form's result is checked on the device against the dense form's
-on the live rows; dead rows have to be zeros where ``live`` is taken.
+Modules`` of a profiler trace), median of ``--reps`` executions;
+``kernel_ms`` is the kernel's own op in it. ``blocks_run`` and
+``experts_read_gb`` (the weights of the experts that hold a live pair,
+each once) come from the routing, read back from the device;
+``read_gbps_program`` / ``read_gbps_kernel`` are those bytes over either
+time: against the chip's 819 GB/s, how near a form is to one stream of
+its weights. Each sorted form's result is checked against the dense
+form's, both computed on the device, on the live rows: ``max_err`` after
+rounding both to bfloat16, as a bfloat16 model hands the result on (a
+whole step of that type or nothing), ``max_err_f32`` before it; dead
+rows have to be zeros.
 
 Exits 1 where the platform is not a TPU: a CPU time is no device time.
 One JSON line per measurement, the whole table under
@@ -30,7 +48,6 @@ from __future__ import annotations
 
 import argparse
 import contextlib
-import inspect
 import json
 import os
 import statistics
@@ -46,58 +63,91 @@ import numpy as np
 
 from benchmark.harness import trace as bm_trace
 from dynamo_tpu.models import llama
+from dynamo_tpu.ops import moe_grouped
 
-# (name, E, k, D, I, N...)
-SHAPES = [("mixtral", 8, 2, 4096, 14336, (128, 512, 2048)),
-          ("qwen3", 128, 8, 2048, 768, (2048,))]
+# (name, experts held, router's width, first held, k, D, I, N...)
+SHAPES = [("mixtral", 8, 8, None, 2, 4096, 14336, (128, 512, 2048)),
+          ("qwen3", 128, 128, None, 8, 2048, 768, (2048,)),
+          ("lfm2", 64, 64, None, 4, 2048, 1536, (1024, 2048)),
+          ("granite", 36, 72, 18, 10, 4096, 768, (512, 2048))]
 FILLS = (0.13, 0.5, 1.0)
-TAKES_LIVE = "live" in inspect.signature(llama._moe_mlp).parameters
 
 
-def programs(name, E, k, N):
-    """(label, jitted fn(h, live, w_router, wg, wu, wd), in_place)."""
+def route(h, wr, k):
+    logits = (h @ wr).astype(jnp.float32)
+    weights, idx = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(weights, axis=-1), idx
+
+
+def programs(name, E, k, D, I, first, width, N, blocks):
+    """(label, form, block or None, jitted fn(h, live, wr, wg, wu, wd))."""
     out = []
 
-    def build(label, use_sorted, block=None, in_place=False):
+    def build(form, block=None):
         def fn(h, live, wr, wg, wu, wd):
-            kw = {}
-            if TAKES_LIVE:
-                kw["live"] = live[None]
-                if in_place:
-                    kw["layer"] = jnp.int32(1)
+            weights, idx = route(h, wr, k)
             with contextlib.ExitStack() as forced:
-                forced.enter_context(mock.patch.object(
-                    llama, "_moe_use_blocked", lambda *a: use_sorted))
+                if form == "loop":
+                    forced.enter_context(mock.patch.object(
+                        llama, "_moe_kernel_interpret", lambda w: None))
                 if block is not None:
                     forced.enter_context(mock.patch.object(
                         llama, "moe_block", lambda *a: block))
-                return llama._moe_mlp(h[None], wr, wg, wu, wd, k, **kw)[0]
+                if form == "dense":
+                    return llama.moe_experts(
+                        h[None], weights[None], idx[None], wg[1], wu[1],
+                        wd[1], False, first=first)[0]
+                return llama.moe_experts(
+                    h[None], weights[None], idx[None], wg, wu, wd, True,
+                    live=live[None], layer=jnp.int32(1), first=first,
+                    width=width)[0]
 
-        fn.__name__ = "%s_n%d_%s" % (name, N, label)
-        out.append((fn.__name__, jax.jit(fn), in_place))
+        label = "%s_n%d_%s" % (name, N, form)
+        if block is not None:
+            label += "_b%d" % block
+        fn.__name__ = label
+        out.append((label, form, block, jax.jit(fn)))
 
-    build("dense", False)
-    if TAKES_LIVE:
-        for block in (128, 256):
-            build("sorted_b%d" % block, True, block)
-        build("sorted_in_place", True, None, in_place=True)
-    else:
-        build("sorted", True)
+    build("dense")
+    own = llama.moe_block(N, k, (E, D, I), width)
+    for form in ("loop", "kernel"):
+        build(form)
+        # the rule's own block again would be the same program under
+        # another name, and the compile cache hands back the first
+        for block in blocks:
+            if block != own:
+                build(form, block)
     return out
 
 
-def time_model(name, E, k, D, I, Ns, reps):
+def as_served(y):
+    """A float32 result as a bfloat16 model hands it on."""
+    return np.asarray(jnp.asarray(y).astype(jnp.bfloat16), np.float32)
+
+
+def plan_numbers(idx, live, E, first, block, D, I):
+    """blocks run and bytes of the experts that hold a live pair."""
+    e = np.asarray(idx)[np.asarray(live)].reshape(-1) - (first or 0)
+    counts = np.bincount(e[(e >= 0) & (e < E)], minlength=E)
+    return {"block": block,
+            "blocks_run": int(np.sum(-(-counts // block))),
+            "live_row_share": float(counts.sum()) / max(
+                int(np.sum(-(-counts // block))) * block, 1),
+            "experts_read_gb": int(np.sum(counts > 0)) * 3 * D * I * 2 / 1e9}
+
+
+def time_model(name, E, width, first, k, D, I, Ns, reps, blocks):
     """One model's weights on the device, every program of every N
     warmed and checked, then all of them under one trace."""
     key = jax.random.split(jax.random.PRNGKey(28), 5)
     shapes = ((E, D, I), (E, D, I), (E, I, D))
-    layer1 = tuple(
-        (jax.random.normal(kk, shp, jnp.bfloat16)
-         * float(shp[-2]) ** -0.5).astype(jnp.bfloat16)
-        for kk, shp in zip(key[:3], shapes))
     # [2, E, ...] for the in-place read: layer 1 is the layer timed
-    stack = tuple(jnp.stack([w * 0.5, w]) for w in layer1)
-    wr = (jax.random.normal(key[3], (D, E), jnp.bfloat16)
+    stack = tuple(
+        jnp.stack([w * 0.5, w]) for w in (
+            (jax.random.normal(kk, shp, jnp.bfloat16)
+             * float(shp[-2]) ** -0.5).astype(jnp.bfloat16)
+            for kk, shp in zip(key[:3], shapes)))
+    wr = (jax.random.normal(key[3], (D, width), jnp.bfloat16)
           * 2.0 * D ** -0.5).astype(jnp.bfloat16)
     runs, agree = [], True
     for N in Ns:
@@ -107,37 +157,43 @@ def time_model(name, E, k, D, I, Ns, reps):
             live = jnp.arange(N) < max(int(round(fill * N)), 1)
             inputs[fill] = (jnp.where(live[:, None], rows, rows[:1]), live)
         ref = {}
-        for label, fn, in_place in programs(name, E, k, N):
-            ws = stack if in_place else layer1
+        for label, form, block, fn in programs(name, E, k, D, I, first,
+                                                  width, N, blocks):
+            checked = {}
             for fill, (h, live) in inputs.items():
                 y = np.asarray(jax.block_until_ready(
-                    fn(h, live, wr, *ws)), np.float32)
+                    fn(h, live, wr, *stack)), np.float32)
                 n_live = int(live.sum())
-                if label.endswith("_dense"):
+                if form == "dense":
                     ref[fill] = y
                     continue
-                err = float(np.abs(y[:n_live] - ref[fill][:n_live]).max())
+                err32 = float(np.abs(y[:n_live] - ref[fill][:n_live]).max())
+                err = float(np.abs(as_served(y[:n_live])
+                                   - as_served(ref[fill][:n_live])).max())
                 scale = float(np.abs(ref[fill][:n_live]).max())
                 dead = float(np.abs(y[n_live:]).max()) if n_live < N else 0.0
-                ok = err <= 0.02 * scale and (dead == 0.0 or not TAKES_LIVE)
+                ok = bool(np.isfinite(y).all() and err <= 0.02 * scale
+                          and dead == 0.0)
                 agree &= ok
-                if not ok:
-                    print(json.dumps({
-                        "differs_from_dense": label, "fill": fill,
-                        "max_err": err, "ref_max": scale,
-                        "dead_rows_max": dead}))
-            runs.append((label, fn, ws, inputs))
+                checked[fill] = {"max_err": err, "max_err_f32": err32,
+                                 "ref_max": scale, "dead_rows_max": dead,
+                                 "agrees": ok}
+                _, idx = route(h, wr, k)
+                checked[fill].update(plan_numbers(
+                    idx, live, E, first,
+                    block or llama.moe_block(N, k, (E, D, I), width), D, I))
+            runs.append((label, form, fn, inputs, checked))
     with tempfile.TemporaryDirectory() as tmp:
         jax.profiler.start_trace(tmp)
-        for label, fn, ws, inputs in runs:
+        for label, form, fn, inputs, _ in runs:
             for h, live in inputs.values():
                 for _ in range(reps):
-                    jax.block_until_ready(fn(h, live, wr, *ws))
+                    jax.block_until_ready(fn(h, live, wr, *stack))
         jax.profiler.stop_trace()
         planes = bm_trace.load(bm_trace.find_xplane(tmp))
     plane = next(iter(planes.values()))
     table = []
-    for label, fn, ws, inputs in runs:
+    for label, form, fn, inputs, checked in runs:
         mine = sorted((s, d) for n, s, d in plane["modules"]
                       if n.startswith("jit_%s(" % label))
         assert len(mine) == reps * len(inputs), (label, len(mine))
@@ -145,18 +201,28 @@ def time_model(name, E, k, D, I, Ns, reps):
             part = mine[i * reps:(i + 1) * reps]
             durs = [d for _, d in part]
             t0, t1 = part[0][0], part[-1][0] + part[-1][1]
-            ops = {}
+            ops, kernel = {}, 0.0
             for n, s, d in plane["ops"]:
                 kind, shp = bm_trace._op(n)
                 if t0 <= s < t1 and not bm_trace.CONTAINER_OP.match(kind):
-                    key = "%s_%s" % (kind, shp)
-                    ops[key] = ops.get(key, 0.0) + d
+                    op = "%s_%s" % (kind, shp)
+                    ops[op] = ops.get(op, 0.0) + d
+                    if kind == moe_grouped.NAME:
+                        kernel += d
             top = sorted(ops.items(), key=lambda kv: -kv[1])[:6]
+            ms = statistics.median(durs) * 1e3
             row = {"program": label, "live_share": fill, "n": len(durs),
-                   "device_ms_median": statistics.median(durs) * 1e3,
+                   "device_ms_median": ms,
                    "device_ms_min": min(durs) * 1e3,
                    "device_ms_max": max(durs) * 1e3,
-                   "ops_ms": [[k, v / len(durs) * 1e3] for k, v in top]}
+                   **checked.get(fill, {})}
+            if form != "dense":
+                row["read_gbps_program"] = row["experts_read_gb"] / ms * 1e3
+            if form == "kernel":
+                row["kernel_ms"] = kernel / len(durs) * 1e3
+                row["read_gbps_kernel"] = (row["experts_read_gb"]
+                                           / row["kernel_ms"] * 1e3)
+            row["ops_ms"] = [[op, v / len(durs) * 1e3] for op, v in top]
             table.append(row)
             print(json.dumps(row))
     return table, agree
@@ -165,6 +231,9 @@ def time_model(name, E, k, D, I, Ns, reps):
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--reps", type=int, default=8)
+    ap.add_argument("--models", default=",".join(s[0] for s in SHAPES))
+    ap.add_argument("--blocks", default="",
+                    help="rows a block to time besides moe_block's own")
     ap.add_argument("--out", default="chiprun_out/moe_form_timing.json")
     opts = ap.parse_args()
     dev = jax.devices()[0]
@@ -172,14 +241,18 @@ def main() -> int:
         print(json.dumps({"ok": False, "error": "platform is %s, not tpu"
                           % dev.platform}))
         return 1
+    blocks = [int(b) for b in opts.blocks.split(",") if b]
     table, agree = [], True
-    for name, E, k, D, I, Ns in SHAPES:
-        rows, ok = time_model(name, E, k, D, I, Ns, opts.reps)
+    for name, E, width, first, k, D, I, Ns in SHAPES:
+        if name not in opts.models.split(","):
+            continue
+        rows, ok = time_model(name, E, width, first, k, D, I, Ns, opts.reps,
+                              blocks)
         table += rows
         agree &= ok
     result = {"ok": agree, "device": {"platform": dev.platform,
                                       "kind": dev.device_kind},
-              "takes_live": TAKES_LIVE, "reps": opts.reps, "table": table}
+              "reps": opts.reps, "table": table}
     os.makedirs(os.path.dirname(opts.out), exist_ok=True)
     with open(opts.out, "w") as f:
         json.dump(result, f, indent=1)
