@@ -235,9 +235,14 @@ class EngineConfig:
         return min(self._pick(self.batch_buckets, n), self.max_batch)
 
     def prefill_bucket_batch(self, n: int) -> int:
-        """Prefill batches only use the two warmed buckets
-        (bucket_batch(1) and bucket_batch(max_prefill_batch)) so a
-        mid-serving prompt mix never triggers a fresh XLA compile."""
+        """The batch buckets a prefill may run in: bucket_batch(1) and
+        bucket_batch(max_prefill_batch), the image warmed_grid() takes
+        and warmup() compiles, so a mid-serving prompt mix never
+        triggers a fresh XLA compile. It names the warmed SET; which of
+        the two a dispatch runs in is choose_prefill_bucket's, by what
+        the warmed programs cost on this device. For n rows this is the
+        answer of an engine that has measured nothing: one row in the
+        small bucket, more in the wide one."""
         small = self.bucket_batch(1)
         return small if n <= small else self.bucket_batch(
             self.max_prefill_batch)
@@ -284,6 +289,53 @@ _BLOCK_WINDOW_COUNTS = ("blocks", "forwards", "commit_forwards",
 def _whole_blocks(n_tokens: int, block: int) -> int:
     """The positions of n_tokens tokens that lie in whole blocks."""
     return n_tokens // block * block
+
+
+# a per-row cost within this share of the least is a tie: the timing of
+# a warmed program repeats to a few percent (PERF.md, PR 43)
+_COST_TIE = 0.05
+# warmup() times every warmed prefill program once, and a second time
+# while the timing as a whole stays inside this many seconds
+_COST_TIMING_SECONDS = 1.0
+# (PB, T) -> device ms of that warmed prefill program with one row live
+# and with every row live
+PrefillCosts = Dict[Tuple[int, int], Tuple[float, float]]
+
+
+def prefill_cost_ms(costs: PrefillCosts, PB: int, T: int,
+                    rows: int) -> float:
+    """What the warmed program (PB, T) costs with ``rows`` live rows, in
+    device ms: the reading at one live row (the program's fixed part:
+    dense work on padded rows, weight reads) plus a line to the reading
+    at PB (attention and the sorted expert dispatch follow the live
+    rows)."""
+    one, full = costs[(PB, T)]
+    return one if PB == 1 else one + (rows - 1) / (PB - 1) * (full - one)
+
+
+def choose_prefill_bucket(costs: PrefillCosts, T: int, n: int,
+                          unmeasured: int) -> Tuple[int, int]:
+    """(batch bucket, rows shipped) of a prefill dispatch with n rows
+    waiting at length bucket T: of the batch buckets ``costs`` has a
+    reading for at T, the one whose program costs the least a row it
+    ships (each ships min(n, PB)); a near-tie goes to the one that ships
+    more rows. A dense model past the MXU's ridge gains nothing from a
+    batch and ships a row a program; one bound by the read of its
+    experts shares that read and keeps the wide bucket. Without a
+    reading (an engine that was never warmed, or warmed one bucket) the
+    answer is ``unmeasured``, EngineConfig.prefill_bucket_batch's."""
+    offers = []
+    for PB, t in costs:
+        if t == T:
+            rows = min(n, PB)
+            offers.append(
+                (prefill_cost_ms(costs, PB, T, rows) / rows, rows, PB))
+    if not offers:
+        return unmeasured, min(n, unmeasured)
+    least = min(offers)[0]
+    _, rows, PB = max((o for o in offers if o[0] <= least * (1 + _COST_TIE)),
+                      key=lambda o: (o[1], -o[0]))
+    return PB, rows
 
 
 @dataclass(eq=False)  # identity semantics: `in`/`==` must never deep-compare
@@ -735,6 +787,14 @@ class JaxEngine:
         # (ops/moe_grouped.py): known from the bucket's rows by the rule
         # the program was built under
         self.moe_grouped_programs_total = 0
+        # what warmup() read of its prefill programs on this device
+        # (_time_prefill_programs); empty until then, and
+        # _dispatch_prefill keeps the config's rule
+        self._prefill_costs: PrefillCosts = {}
+        # candidate rows a dispatch left for a later program, and the
+        # dispatches whose bucket is not the config's rule's
+        self.prefill_rows_held_back_total = 0
+        self.prefill_bucket_narrowed_total = 0
         self.decode_rows_total = 0
         self.decode_slots_total = 0
         self.decode_windows_total = 0
@@ -1122,6 +1182,7 @@ class JaxEngine:
                     break
                 size *= 2
         jax.block_until_ready(self.kv_k)
+        self._time_prefill_programs(grid)
         # arm the runtime compile fence: from here on, ANY XLA compile is
         # a serving stall — counted always, warn/raise per DYN_JIT_FENCE
         self.fence.arm()
@@ -1129,6 +1190,74 @@ class JaxEngine:
         log.info("warmup compiled %d programs in %.1fs", n,
                  self.warmup_seconds)
         return n
+
+    def _time_prefill_programs(self, grid: dict) -> None:
+        """Read what each warmed prefill program (PB, T) costs on this
+        device, for _dispatch_prefill's choice of a batch bucket: every
+        row live, and for PB > 1 one row live (prefill_cost_ms draws the
+        line between). Real token ids from a fixed key (all-zero tokens
+        would send every token to one expert), positions from 0, nothing
+        committed (dropped slots, pages and state slots), in warmup()'s
+        call form so that nothing compiles. Each program runs once, and
+        a second time for the lesser of two, the short ones first, while
+        the whole stays inside _COST_TIMING_SECONDS. One warmed bucket:
+        nothing to choose, nothing timed."""
+        buckets = grid["prefill_batches"]
+        self._prefill_costs = {}
+        if len(buckets) < 2:
+            return
+        ecfg, ps = self.ecfg, self.ecfg.page_size
+        ids = np.random.default_rng(0)
+        page_buckets = grid["page_buckets"] or [8]
+
+        def run(PB: int, T: int, live: int) -> float:
+            # the page bucket of a prompt's first chunk of T tokens
+            P = next((p for p in page_buckets if p * ps >= T),
+                     page_buckets[-1])
+            n = min(T, P * ps)
+            tokens = np.zeros((PB, T), np.int32)
+            positions = np.full((PB, T), -1, np.int32)
+            last_idx = np.zeros(PB, np.int32)
+            tokens[:live, :n] = ids.integers(
+                0, self.cfg.vocab_size, (live, n))
+            positions[:live, :n] = np.arange(n)
+            last_idx[:live] = n - 1
+            operands = jax.block_until_ready((
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.zeros((PB, P), jnp.int32),
+                jnp.full((PB, T), DROP_SLOT, jnp.int32),
+                jnp.asarray(last_idx),
+                (jnp.full((PB, T // ps), ecfg.num_pages, jnp.int32)
+                 if T % ps == 0 else None)))
+            tokens, positions, table, slots, last_idx, pslots = operands
+            t0 = time.perf_counter()
+            out = jax.block_until_ready(self.prefill_fn(
+                self.params, tokens, positions, self.kv_k, self.kv_v,
+                table, slots, last_idx, pslots,
+                *self._state_args(self._drop_slots(PB), self._no_src(PB))))
+            ms = (time.perf_counter() - t0) * 1e3
+            _logits, self.kv_k, self.kv_v = self._take_state(out)
+            return ms
+
+        forms = [(PB, T, live) for T in grid["prefill_lens"]
+                 for PB in buckets for live in sorted({1, PB})]
+        t0 = time.monotonic()
+        read = {form: run(*form) for form in forms}
+        for form in sorted(forms, key=read.get):    # the short ones first
+            if (time.monotonic() - t0 + read[form] / 1e3
+                    < _COST_TIMING_SECONDS):
+                read[form] = min(read[form], run(*form))
+        self._prefill_costs = {
+            (PB, T): (read[PB, T, 1], read[PB, T, PB])
+            for PB, T, _ in forms}
+        log.info("prefill programs, ms at one row live / all: %s",
+                 self._prefill_cost_table())
+
+    def _prefill_cost_table(self) -> dict:
+        """``_prefill_costs`` as stats() shows it: "<PB>x<T>" -> [ms with
+        one row live, ms with every row live]."""
+        return {f"{PB}x{T}": [round(one, 3), round(full, 3)]
+                for (PB, T), (one, full) in self._prefill_costs.items()}
 
     def start(self) -> None:
         if self._loop_task is None:
@@ -1291,10 +1420,16 @@ class JaxEngine:
             "prefill_slots_total": self.prefill_slots_total,
             "prefill_dispatches_total": self.prefill_dispatches_total,
             "moe_grouped_programs_total": self.moe_grouped_programs_total,
+            # the choice of a prefill's batch bucket (_dispatch_prefill)
+            "prefill_rows_held_back_total":
+                self.prefill_rows_held_back_total,
+            "prefill_bucket_narrowed_total":
+                self.prefill_bucket_narrowed_total,
             "decode_rows_total": self.decode_rows_total,
             "decode_slots_total": self.decode_slots_total,
             "decode_windows_total": self.decode_windows_total,
             "warmup_seconds": self.warmup_seconds,
+            "prefill_program_cost_ms": self._prefill_cost_table(),
             "gpu_cache_usage_perc": self.pm.usage(),
             # dynacache: the headline rate is WINDOWED (last
             # DYN_CACHE_WINDOW admissions) so the aggregator gauge tracks
@@ -1826,10 +1961,16 @@ class JaxEngine:
                           ) -> Optional[_PendingPrefill]:
         """Enqueue one chunked-prefill step over a BATCH of prefilling
         sequences (each contributes its next chunk) WITHOUT reading back.
-        Batching prompts into one dispatch matters as much as the decode
-        window when dispatch latency dominates: N prompts cost one round
-        trip, not N — and under pipelining that round trip overlaps the
-        in-flight decode window."""
+        On the HOST a batch is the cheaper: N prompts cost one round
+        trip, not N, and under pipelining that round trip overlaps the
+        in-flight decode window. On the DEVICE it is so only where the
+        rows share something, as the read of a layer's experts: a dense
+        chunk of a few hundred tokens is compute-bound alone, and N
+        prompts in a program padded to its bucket cost more than N
+        programs of one. So the batch formed here is what MAY ship, and
+        choose_prefill_bucket takes the warmed bucket that costs the
+        least a row by what warmup() read of the programs; the rows it
+        leaves stay in ``prefilling``, in order, for the next sweep."""
         candidates: List[Sequence] = []
         for seq in list(self.prefilling):
             if seq.context.stopped:
@@ -1902,9 +2043,14 @@ class JaxEngine:
                 total += c
             batch = kept
 
+        unmeasured = self.ecfg.prefill_bucket_batch(len(batch))
+        B, rows = choose_prefill_bucket(
+            self._prefill_costs, hb, len(batch), unmeasured)
+        self.prefill_rows_held_back_total += len(batch) - rows
+        self.prefill_bucket_narrowed_total += int(B != unmeasured)
+        batch = batch[:rows]
         chunks = [min(s.prefill_extent - s.computed, self.ecfg.prefill_chunk)
                   for s in batch]
-        B = self.ecfg.prefill_bucket_batch(len(batch))
         T = self.ecfg.bucket_len(max(chunks))
         P = self.ecfg.bucket_pages(max(len(s.pages) for s in batch))
 

@@ -136,6 +136,7 @@ STATS_PROMETHEUS_SKIP = {
            "first_tokens_total", "prefill_tokens_total",
            "prefill_slots_total", "prefill_dispatches_total",
            "moe_grouped_programs_total",
+           "prefill_rows_held_back_total", "prefill_bucket_narrowed_total",
            "decode_rows_total", "decode_slots_total",
            "decode_windows_total", "warmup_seconds")},
     # the host's account beside it (PR 35; runtime/profiling.py

@@ -16,7 +16,8 @@ The analysis types values along two axes:
 - **shape provenance** — ``BUCKETED`` (int literals, ``EngineConfig``
   /``ModelConfig`` attribute reads, and anything laundered through a
   bucket helper: ``bucket_batch``/``prefill_bucket_batch``/``bucket_len``
-  /``bucket_pages``/``_pick``/``_long_bucket``/``_pad_pow2``), ``RAW``
+  /``bucket_pages``/``_pick``/``_long_bucket``/``_pad_pow2``/
+  ``choose_prefill_bucket``), ``RAW``
   (request-varying: ``len(...)`` of request data, ``List``-annotated
   parameters, list comprehensions — their length is data-dependent), or
   ``UNKNOWN``. Only definitely-RAW shapes are reported: a whole-program
@@ -85,7 +86,7 @@ ENGINE_MARKER = "engine/"
 # (that is their whole job). New helpers must be added here AND warmed.
 BUCKET_HELPERS = frozenset({
     "bucket_batch", "prefill_bucket_batch", "bucket_len", "bucket_pages",
-    "_pick", "_pad_pow2", "_long_bucket",
+    "_pick", "_pad_pow2", "_long_bucket", "choose_prefill_bucket",
 })
 # attribute bases whose reads are config-static (never request-varying)
 CONFIG_BASE_RE = re.compile(
