@@ -39,7 +39,6 @@ from typing import AsyncIterator, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from ..llm.protocols.common import (FINISH_CANCELLED, FINISH_EOS,
                                     FINISH_LENGTH, FINISH_TIMEOUT,
@@ -47,6 +46,7 @@ from ..llm.protocols.common import (FINISH_CANCELLED, FINISH_EOS,
 from ..models.config import ModelConfig
 from ..models.llama import DROP_SLOT, KVCacheSpec, moe_kernel_takes
 from ..models.registry import get_model_module
+from ..models.window import WindowResults, unpack as unpack_window
 from ..runtime import blackbox, guard, profiling, slo, tracing
 from ..runtime.config import env_bool, env_int, env_str
 from ..runtime.engine import Context
@@ -55,7 +55,7 @@ from .kv_manager import (BLOCK_GENERATION_REFUSAL, RECURRENT_STATE_REFUSAL,
                          ChainHashCache, PageManager)
 from .profiler import EngineProfiler, memory_snapshot
 from .sampling import (SamplingBatch, logprob_aux, sample_tokens,
-                       update_penalty_state, verify_greedy_draft)
+                       verify_greedy_draft)
 from .spec_decode import propose_ngram_draft
 
 log = logging.getLogger("dynamo_tpu.engine")
@@ -448,22 +448,15 @@ class Sequence:
 
 @dataclass
 class _PendingWindow:
-    """A dispatched-but-unread decode window. ``toks`` and ``carry`` are
-    device arrays (futures under JAX async dispatch); reading ``toks``
-    back is deferred until after the NEXT window is enqueued."""
+    """A dispatched-but-unread decode window. ``res`` holds device
+    arrays (futures under JAX async dispatch); reading its ``toks`` back
+    is deferred until after the NEXT window is enqueued. Its ``counts``
+    are the block window's per-row counts ([B, 5]: _BLOCK_WINDOW_COUNTS)
+    or a module's WINDOW_COUNTS vector."""
 
     batch: List[Sequence]
-    # [B, K] sampled tokens, one a step; for a window of blocks the
-    # tokens by POSITION (a row's new ones start past the final positions
-    # its first block came with)
-    toks: jax.Array
-    emitted: jax.Array              # [B] on-device valid-token counts
-    carry: tuple                    # (tok, pos, done, steps, remaining)
+    res: WindowResults
     index: Dict[int, int] = field(default_factory=dict)  # id(seq) → row
-    aux: Optional[tuple] = None     # (lp [B,K], tv [B,K,N], ti [B,K,N])
-    # the block window's per-row counts ([B, 5]: blocks, forwards, commit
-    # forwards, early exits, dropped tokens); None for a window of tokens
-    info: Optional[jax.Array] = None
     processed: bool = False
 
 
@@ -585,14 +578,10 @@ class JaxEngine:
                     f"batch_buckets {bad} not divisible by mesh data axis "
                     f"({d}): shard_map decode windows need whole rows per "
                     f"data shard")
-        if hasattr(model, "make_decode_window_fn"):
-            # model-provided fused window (read-only pool + window buffer:
-            # one pool copy in HBM; see llama.make_decode_window_fn)
-            self.decode_multi_fn = model.make_decode_window_fn(
-                model_cfg, True, self.ecfg.max_top_k, mesh=mesh)
-        else:
-            self.decode_multi_fn = _make_decode_multi(
-                model, model_cfg, self.ecfg.max_top_k, mesh=mesh)
+        # the module's fused window (read-only pool + window buffer: one
+        # pool copy in HBM; models/window.py, llama.make_decode_window_fn)
+        self.decode_multi_fn = model.make_decode_window_fn(
+            model_cfg, True, self.ecfg.max_top_k, mesh=mesh)
         # self-speculative decode: the [B, K+1] verify forward (only
         # built — and only warmed — when the flag is on, so the default
         # compiled-program set is untouched). Model families without a
@@ -867,15 +856,19 @@ class JaxEngine:
             return np.zeros(n, np.int32)
         return np.full((n, self.block), -1, np.int32)
 
-    def _split_info(self, out):
-        """A window program's results without the counts it returns last
-        ((results, counts): the block window's per-row counts, or the
-        vector a module's WINDOW_COUNTS names; None for a window that
-        counts nothing)."""
-        if self.block == 1 and not self.window_counts:
-            return out, None
-        *out, info = out
-        return out, info
+    def _take_window(self, out, topn: int) -> WindowResults:
+        """A window program's results by name (models/window.py knows
+        their order), without the pools it returned: those are the
+        engine's again. ``counts``: the block window's per-row counts, or
+        the vector a module's WINDOW_COUNTS names; None for a window
+        that counts nothing."""
+        res = unpack_window(
+            out, topn, counts=self.block > 1 or bool(self.window_counts),
+            state=self.state is not None)
+        self.kv_k, self.kv_v = res.kv_k, res.kv_v
+        if res.state is not None:
+            self.state = res.state
+        return res._replace(kv_k=None, kv_v=None, state=None)
 
     @property
     def role(self) -> str:
@@ -989,28 +982,29 @@ class JaxEngine:
                             # kwargs from omitted defaults (compile-fence
                             # finding, same class as the penalties=None
                             # note above)
-                            out, _ = self._split_info(self._take_state(
-                                self.decode_multi_fn(
-                                self.params,
+                            def window(*carry, pv=pv, topn=topn):
+                                return self._take_window(
+                                    self.decode_multi_fn(
+                                        self.params, *carry, self.kv_k,
+                                        self.kv_v, tableB, jnp.zeros(B),
+                                        jnp.zeros(B, jnp.int32),
+                                        jnp.ones(B),
+                                        jnp.zeros(B, jnp.uint32),
+                                        jnp.full((B, ecfg.max_eos_ids), -1,
+                                                 jnp.int32),
+                                        pv, *self._state_args(
+                                            self._drop_slots(B)),
+                                        k_steps=ecfg.decode_steps,
+                                        logprobs_topn=topn), topn)
+
+                            res = window(
                                 jnp.asarray(self._blank_tokens(B)),
                                 jnp.zeros(B, jnp.int32) - 1,
                                 jnp.zeros(B, bool), jnp.zeros(B, jnp.int32),
-                                jnp.ones(B, jnp.int32), self.kv_k,
-                                self.kv_v, tableB, jnp.zeros(B),
-                                jnp.zeros(B, jnp.int32),
-                                jnp.ones(B), jnp.zeros(B, jnp.uint32),
-                                jnp.full((B, ecfg.max_eos_ids), -1,
-                                         jnp.int32),
-                                pv, *self._state_args(self._drop_slots(B)),
-                                k_steps=ecfg.decode_steps,
-                                logprobs_topn=topn)))
+                                jnp.ones(B, jnp.int32))
+                            toks = res.toks
                             if topn:
-                                (toks, _emitted, _aux, _carry, self.kv_k,
-                                 self.kv_v) = out
                                 n += 1
-                            else:
-                                (toks, _emitted, _carry, self.kv_k,
-                                 self.kv_v) = out
                             if pv is None and self.mesh is not None:
                                 # committed-carry variant: under a mesh
                                 # the pipelined window's (tok, pos, done,
@@ -1026,26 +1020,8 @@ class JaxEngine:
                                 # to warm that variant; save it for the
                                 # merge-combo loop below.
                                 if topn == 0:
-                                    carries[B] = _carry
-                                out = self._take_state(
-                                    self.decode_multi_fn(
-                                        self.params, *_carry, self.kv_k,
-                                        self.kv_v, tableB, jnp.zeros(B),
-                                        jnp.zeros(B, jnp.int32),
-                                        jnp.ones(B),
-                                        jnp.zeros(B, jnp.uint32),
-                                        jnp.full((B, ecfg.max_eos_ids), -1,
-                                                 jnp.int32),
-                                        pv, *self._state_args(
-                                            self._drop_slots(B)),
-                                        k_steps=ecfg.decode_steps,
-                                        logprobs_topn=topn))
-                                if topn:
-                                    (toks, _emitted, _aux, _carry,
-                                     self.kv_k, self.kv_v) = out
-                                else:
-                                    (toks, _emitted, _carry, self.kv_k,
-                                     self.kv_v) = out
+                                    carries[B] = res.carry
+                                toks = window(*res.carry).toks
                                 n += 1
                 else:
                     logits, self.kv_k, self.kv_v = self._take_state(
@@ -2617,7 +2593,7 @@ class JaxEngine:
                                   self.cap_tokens - len(seq.tokens)), 1)
         if prev is not None:
             tok, pos, done, steps, rem = _merge_carry(
-                *prev.carry, jnp.asarray(src), jnp.asarray(from_carry),
+                *prev.res.carry, jnp.asarray(src), jnp.asarray(from_carry),
                 jnp.asarray(ntok), jnp.asarray(npos), jnp.asarray(nsteps),
                 jnp.asarray(nrem))
         else:
@@ -2627,21 +2603,15 @@ class JaxEngine:
         pen = self._penalty_args(batch, sb, B)
         topn = (self.ecfg.max_top_logprobs
                 if self._wants_logprobs(batch) else 0)
-        out, info = self._split_info(self._take_state(self.decode_multi_fn(
+        res = self._take_window(self.decode_multi_fn(
             self.params, tok, pos, done, steps, rem, self.kv_k, self.kv_v,
             d_table, d_temp, d_topk, d_topp, d_seeds, d_eos, pen,
-            *self._state_args(d_sslots), k_steps=K, logprobs_topn=topn)))
-        if topn:
-            toks, emitted, aux, carry, self.kv_k, self.kv_v = out
-        else:
-            toks, emitted, carry, self.kv_k, self.kv_v = out
-            aux = None
+            *self._state_args(d_sslots), k_steps=K, logprobs_topn=topn),
+            topn)
         self._account_dispatch(batch)
         self._count_decode_slots(batch, B, K)
         self.steps += 1
-        pend = _PendingWindow(batch=list(batch), toks=toks,
-                              emitted=emitted, carry=carry, aux=aux,
-                              info=info,
+        pend = _PendingWindow(batch=list(batch), res=res,
                               index={id(s): i for i, s in enumerate(batch)})
         self._inflight.append(pend)
         return pend
@@ -2658,17 +2628,18 @@ class JaxEngine:
         with self.profiler.phase("readback_window"):
             # the step thread blocked on the device until this window
             # (and whatever was queued ahead of it) has run
-            toks = np.asarray(pend.toks)
-            aux = (tuple(np.asarray(a) for a in pend.aux)
-                   if pend.aux is not None else None)
+            res = pend.res
+            toks = np.asarray(res.toks)
+            aux = (tuple(np.asarray(a) for a in res.aux)
+                   if res.aux is not None else None)
             # outputs of the same program as toks — ready the moment
             # toks is, so these reads add no extra device sync. carry is
             # never donated (warmup's merge-combo loop reuses one), so
             # reading done here is safe even with the next window in
             # flight.
-            counts = np.asarray(pend.emitted)
-            done = np.asarray(pend.carry[2])
-            info = None if pend.info is None else np.asarray(pend.info)
+            counts = np.asarray(res.emitted)
+            done = np.asarray(res.carry[2])
+            info = None if res.counts is None else np.asarray(res.counts)
         if info is not None and self.block == 1:
             for k, v in zip(self.window_counts, info):
                 self.window_counts[k] += int(v)
@@ -3374,74 +3345,14 @@ class RemoteReservation:
         return self.cached_tokens // self.page_size
 
 
-def _make_decode_multi(model, cfg: ModelConfig, max_top_k: int,
-                       mesh=None):
-    """Fused K-step decode: forward → on-device sample → feed back, K
-    times inside one jitted program, with the sequence carry (tok, pos,
-    done, steps, remaining) staying on device so windows pipeline without
-    a host sync between them. One dispatch + one (overlapped) host
-    readback per K tokens — what matters when dispatch latency (Python
-    overhead) exceeds step compute; the default K is unmeasured on a
-    directly attached chip.
-
-    Generic fallback for a model module without make_decode_window_fn:
-    full forward per step with per-step pool writes; stopped rows write
-    DROP_SLOT so nothing lands in their pages. Every module of the
-    registry supplies its own read-only-pool window since PR 31 (llama,
-    jamba, mla), so the engine builds this for none of them; it stays
-    for benchmark/rehearse.py, which imports it by name (ROADMAP C3)."""
-    from ..models.llama import carry_active, carry_step_update, logits_at
-
-    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
-             donate_argnames=("kv_k", "kv_v"))
-    def decode_multi(params, tokens, positions, done, steps, remaining,
-                     kv_k, kv_v, page_table, temperature, top_k, top_p,
-                     seeds, eos_table, penalties=None, *, k_steps: int,
-                     logprobs_topn: int = 0):
-        B = tokens.shape[0]
-        ps = kv_k.shape[3]
-        P = page_table.shape[1]
-        rows = jnp.arange(B)
-
-        # UNROLLED (k_steps is static): an outer lax.scan would carry the
-        # whole KV pools and XLA double-buffers scan carries — stacked on
-        # the layer scan inside forward() that blows HBM. A straight-line
-        # K-step program lets XLA alias the pool updates in place.
-        tok, pos = tokens, positions
-        toks = []
-        lps, tvs, tis = [], [], []
-        # mirror of the llama window fn's per-row valid-token count: the
-        # host slices toks[i, :emitted[i]] instead of re-deriving stop
-        # semantics token by token
-        emitted = jnp.zeros((B,), jnp.int32)
-        for i in range(k_steps):
-            active = carry_active(done, pos)
-            page = page_table[rows, jnp.clip(pos // ps, 0, P - 1)]
-            slot = jnp.where(active, page * ps + pos % ps, DROP_SLOT)
-            h, kv_k, kv_v = model.forward(
-                params, cfg, tok[:, None], pos[:, None], kv_k, kv_v,
-                page_table, slot[:, None], mesh=mesh)
-            logits = logits_at(params, cfg, h, jnp.zeros(B, jnp.int32))
-            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                                steps, max_top_k=max_top_k,
-                                penalties=penalties)
-            if logprobs_topn:
-                lp, tv, ti = logprob_aux(logits, nxt, logprobs_topn)
-                lps.append(lp); tvs.append(tv); tis.append(ti)
-            penalties = update_penalty_state(penalties, nxt, done)
-            emitted = emitted + active.astype(jnp.int32)
-            tok, pos, done, steps, remaining = carry_step_update(
-                nxt, tok, pos, done, steps, remaining, eos_table)
-            toks.append(tok)
-        out_toks = jnp.stack(toks, axis=1)
-        carry = (tok, pos, done, steps, remaining)
-        if logprobs_topn:
-            aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
-                   jnp.stack(tis, axis=1))
-            return out_toks, emitted, aux, carry, kv_k, kv_v
-        return out_toks, emitted, carry, kv_k, kv_v
-
-    return decode_multi
+def _make_decode_multi(*args, **kwargs):
+    # the generic full-forward window, gone since every module of
+    # models/registry.py supplies make_decode_window_fn (models/window.py);
+    # the name stays for benchmark/rehearse.py:52, which imports it beside
+    # EngineConfig and calls it in an else no module reaches (ROADMAP B1
+    # removes that import; C3)
+    raise NotImplementedError(
+        "every model module supplies make_decode_window_fn")
 
 
 def _refuse_recurrent_state(ecfg: EngineConfig, mesh) -> None:

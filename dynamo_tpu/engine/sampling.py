@@ -94,13 +94,11 @@ def apply_penalties(logits: jax.Array, counts: jax.Array,
 
 
 def update_penalty_state(penalties, sampled: jax.Array, done: jax.Array):
-    """Fold a window step's sampled tokens into the penalty state — ONE
-    implementation shared by both fused decode windows (llama
-    decode_window and the engine's generic fallback), so the live-mask
-    timing vs carry_step_update can never drift between them. ``done``
-    is the PRE-step mask: tokens sampled while a row was live are the
-    ones the host will append. Returns the updated tuple (or None
-    through the penalty-free path)."""
+    """Fold a window step's sampled tokens into the penalty state, for
+    the one loop that samples (models/window.py make_window), before its
+    carry_step_update. ``done`` is the PRE-step mask: tokens sampled
+    while a row was live are the ones the host will append. Returns the
+    updated tuple (or None through the penalty-free path)."""
     if penalties is None:
         return None
     counts, presence, rest = penalties[0], penalties[1], penalties[2:]

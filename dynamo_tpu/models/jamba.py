@@ -45,7 +45,7 @@ The selective scan has two forms: a chunk of T tokens from a carried
 state, plain XLA (``_ssm_chunk``: time blocks run side by side from
 zero and are stitched by their entry states, so nothing of size ``[T,
 d_inner, N]`` is ever held), and one token from a stored state: the
-kernel on the pool where the attention kernels run (``_scan_in_place``),
+kernel on the pool where the attention kernels run (``llama.kernel_mode``),
 ``_ssm_step`` on gathered rows elsewhere. Scopes: ``ssm`` around the mixer with
 ``ssm.proj``, ``ssm.conv``, ``ssm.scan`` inside; ``attn``, ``mlp``,
 ``lm_head``, ``sample``, ``kv_carry`` as in llama.py.
@@ -62,13 +62,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from .config import ModelConfig
-from .llama import (KVCacheSpec, Params, _attention, _mlp,
-                    _pool_window_attention, _pool_window_attention_pallas,
-                    _scatter_pages, _scatter_pages_paged, _use_pallas,
-                    carry_active, carry_step_update, commit_window,
-                    embed_tokens, logits_at, rms_norm)
+from .llama import (KVCacheSpec, Params, _at, _attention, _mlp,
+                    _scatter_pages, _scatter_pages_paged, commit_window,
+                    embed_tokens, kernel_mode, logits_at, rms_norm,
+                    window_attention)
+from .window import Family, make_window
 from ..ops.selective_scan import selective_scan_step
-from ..runtime.config import env_flag
 
 State = Tuple[jax.Array, jax.Array]     # (ssm [S,M,N,di] f32, conv [S,M,(dc-1)*di])
 
@@ -299,12 +298,6 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step):
     return out, s, tail
 
 
-def _at(params: Params, keys, i):
-    """One layer's leaves of the stacks named, by a (traced) index."""
-    return {k: lax.dynamic_index_in_dim(params[k], i, 0, False)
-            for k in keys}
-
-
 def _dense_ff(params: Params, cfg: ModelConfig, norm, h, l, valid):
     """Jamba's second half of layer l (traced inside a run): h + the
     dense SwiGLU MLP of norm(h), and nothing counted."""
@@ -421,21 +414,6 @@ def _qkv(cfg: ModelConfig, params: Params, a: int, x):
             (x @ params["wv"][a]).reshape(B, T, KV, hd))
 
 
-def _scan_in_place(allow_pallas: bool, interpret: bool = False):
-    """Where a decode step's scan state lies. None: the rows' state is
-    gathered and _ssm_step runs on it (XLA: the CPU, ``allow_pallas``
-    off). Else the kernel advances it in the pool and this is its
-    ``interpret`` flag. Chosen as the attention kernels are: on a TPU
-    backend, or under the tests' hook (``pallas_interpret``,
-    DYN_PALLAS_INTERPRET off the TPU)."""
-    interpret = interpret or (env_flag("DYN_PALLAS_INTERPRET")
-                              and not env_flag("DYN_DISABLE_PALLAS")
-                              and not _use_pallas())
-    if allow_pallas and (_use_pallas() or interpret):
-        return interpret
-    return None
-
-
 def _store_rows(pool, slots, rows):
     """The rows written back to their slots: a scatter along the pool's
     major axis, in place in a donated pool (row by row, a slab each)."""
@@ -453,7 +431,10 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
     valid = positions >= 0
     fresh = positions[:, 0] == 0
     conv = jnp.where(fresh[:, None, None], 0, state[1][state_slots])
-    interpret = _scan_in_place(allow_pallas) if tokens.shape[1] == 1 else None
+    # one token from a stored state: the kernel advances it in the pool
+    # where the attention kernels run (llama.kernel_mode); else the rows'
+    # state is gathered and the XLA step or chunk runs on it
+    interpret = kernel_mode(allow_pallas) if tokens.shape[1] == 1 else None
     if interpret is None:
         in_pool = None
         ssm = jnp.where(fresh[:, None, None, None], 0.0,
@@ -522,104 +503,60 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                           pallas_interpret: bool = False,
                           blocks: Blocks = MAMBA1):
     """The fused K-step window of llama.make_decode_window_fn (read-only
-    KV pool + window buffer + on-device carry) with the rows' recurrent
-    state carried beside it, advanced by every step a row is active in:
-    the conv tails gathered from the pool once and scattered back once;
-    the scan state likewise on the XLA arm, and left in the pool where the
-    kernel runs (_scan_in_place), every step reading and writing the
-    rows' blocks where they lie."""
-    from ..engine.sampling import (logprob_aux, sample_tokens,
-                                   update_penalty_state)
-
+    KV pool + window buffer + on-device carry: models/window.py's
+    program) with the rows' recurrent state carried beside it, advanced
+    by every step a row is active in: the conv tails gathered from the
+    pool once and scattered back once; the scan state likewise on the XLA
+    arm, and left in the pool where the kernel runs, every step reading
+    and writing the rows' blocks where they lie."""
     KV, hd = cfg.num_kv_heads, cfg.head_dim_
     n_attn = len(cfg.attn_layer_ids)
     # one choice for both kernels, the window's attention and the scan
-    scan_interpret = _scan_in_place(allow_pallas, pallas_interpret)
+    scan_interpret = kernel_mode(allow_pallas, pallas_interpret)
     use_pallas = scan_interpret is not None
 
-    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
-             donate_argnames=("kv_k", "kv_v", "state"))
-    def decode_window(params, tokens, positions, done, steps, remaining,
-                      kv_k, kv_v, page_table, temperature, top_k, top_p,
-                      seeds, eos_table, penalties=None, state=None,
-                      state_slots=None, *, k_steps: int,
-                      logprobs_topn: int = 0):
-        B = tokens.shape[0]
-        start = positions
-        wk = jnp.zeros((n_attn, B, k_steps, KV, hd), kv_k.dtype)
+    def begin(w):
+        wk = jnp.zeros((n_attn, w.start.shape[0], w.k_steps, KV, hd),
+                       w.kv_k.dtype)
         wv = jnp.zeros_like(wk)
-        conv = state[1][state_slots]
-        if scan_interpret is None:
-            in_pool, ssm = None, state[0][state_slots]
-        else:
-            in_pool, ssm = (state_slots, None, scan_interpret), state[0]
+        conv = w.state[1][w.state_slots]
+        ssm = w.state[0] if use_pallas else w.state[0][w.state_slots]
+        return wk, wv, ssm, conv
 
-        def one_step(tok, pos, active, wk, wv, ssm, conv, i):
-            def attend(a, x, cache):
-                wk, wv = cache
-                q, k, v = _qkv(cfg, params, a, x)
-                wk_l = wk[a].at[:, i].set(k[:, 0].astype(wk.dtype))
-                wv_l = wv[a].at[:, i].set(v[:, 0].astype(wv.dtype))
-                if use_pallas:
-                    out = _pool_window_attention_pallas(
-                        q, kv_k, kv_v, jnp.int32(a), page_table, start,
-                        wk_l, wv_l, i, cfg.attn_scale,
-                        interpret=scan_interpret)
-                else:
-                    out = _pool_window_attention(
-                        q, kv_k[a], kv_v[a], page_table, start, wk_l, wv_l,
-                        i, cfg.attn_scale)
-                return (out.reshape(B, 1, -1) @ params["wo"][a],
-                        (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
+    def step(w, bufs, tok, pos, active, i):
+        # a frozen or padding row flows through the matmuls; its state
+        # does not move (dt masked to 0, conv tail kept) and its K/V
+        # never commit
+        wk, wv, ssm, conv = bufs
+        B = tok.shape[0]
+        in_pool = (w.state_slots, None, scan_interpret) if use_pallas \
+            else None
 
-            h = embed_tokens(params, cfg, tok)[:, None]
-            h, ssm, conv, (wk, wv), counted = _stack(
-                params, cfg, h, active[:, None], ssm, conv, attend, (wk, wv),
-                in_pool, blocks)
-            return (logits_at(params, cfg, h, jnp.zeros(B, jnp.int32)),
-                    wk, wv, ssm, conv, counted)
+        def attend(a, x, cache):
+            wk, wv = cache
+            q, k, v = _qkv(cfg, w.params, a, x)
+            wk_l = wk[a].at[:, i].set(k[:, 0].astype(wk.dtype))
+            wv_l = wv[a].at[:, i].set(v[:, 0].astype(wv.dtype))
+            out = window_attention(q, w.kv_k, w.kv_v, a, w.page_table,
+                                   w.start, wk_l, wv_l, i, cfg.attn_scale,
+                                   scan_interpret)
+            return (out.reshape(B, 1, -1) @ w.params["wo"][a],
+                    (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
 
-        tok, pos = tokens, positions
-        toks, lps, tvs, tis = [], [], [], []
-        emitted = jnp.zeros((B,), jnp.int32)
-        tally = []      # what each step's layers counted (blocks.counts)
-        for i in range(k_steps):
-            # a frozen or padding row flows through the matmuls; its
-            # state does not move (dt masked to 0, conv tail kept) and
-            # its K/V never commit
-            active = carry_active(done, pos)
-            logits, wk, wv, ssm, conv, counted = one_step(
-                tok, pos, active, wk, wv, ssm, conv, i)
-            if counted is not None:
-                tally.append(counted)
-            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                                steps, max_top_k=max_top_k,
-                                penalties=penalties)
-            if logprobs_topn:
-                lp, tv, ti = logprob_aux(logits, nxt, logprobs_topn)
-                lps.append(lp); tvs.append(tv); tis.append(ti)
-            penalties = update_penalty_state(penalties, nxt, done)
-            emitted = emitted + active.astype(jnp.int32)
-            tok, pos, done, steps, remaining = carry_step_update(
-                nxt, tok, pos, done, steps, remaining, eos_table)
-            toks.append(tok)
+        h = embed_tokens(w.params, cfg, tok)[:, None]
+        h, ssm, conv, (wk, wv), counted = _stack(
+            w.params, cfg, h, active[:, None], ssm, conv, attend, (wk, wv),
+            in_pool, blocks)
+        return (logits_at(w.params, cfg, h, jnp.zeros(B, jnp.int32)),
+                (wk, wv, ssm, conv), counted)
 
-        with jax.named_scope("kv_carry"):
-            kv_k = commit_window(kv_k, wk, page_table, start, pos)
-            kv_v = commit_window(kv_v, wv, page_table, start, pos)
-            if in_pool is None:
-                ssm = _store_rows(state[0], state_slots, ssm)
-            state = (ssm, _store_rows(state[1], state_slots, conv))
-        out_toks = jnp.stack(toks, axis=1)
-        carry = (tok, pos, done, steps, remaining)
-        # the window's own counts go before the state, where the block
-        # window's go (JaxEngine._split_info)
-        counted = (sum(tally),) if tally else ()
-        if logprobs_topn:
-            aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
-                   jnp.stack(tis, axis=1))
-            return (out_toks, emitted, aux, carry, kv_k, kv_v, *counted,
-                    state)
-        return out_toks, emitted, carry, kv_k, kv_v, *counted, state
+    def commit(w, bufs, pos):
+        wk, wv, ssm, conv = bufs
+        kv_k = commit_window(w.kv_k, wk, w.page_table, w.start, pos)
+        kv_v = commit_window(w.kv_v, wv, w.page_table, w.start, pos)
+        if not use_pallas:
+            ssm = _store_rows(w.state[0], w.state_slots, ssm)
+        return kv_k, kv_v, (ssm, _store_rows(w.state[1], w.state_slots,
+                                             conv))
 
-    return decode_window
+    return make_window(Family(begin, step, commit), max_top_k)

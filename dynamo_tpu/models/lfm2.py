@@ -64,14 +64,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from .config import ModelConfig
-from .llama import (KVCacheSpec, Params, _attention, _mlp, _moe_use_blocked,
-                    _pool_window_attention, _pool_window_attention_pallas,
-                    _qk_headnorm, _scatter_pages, _scatter_pages_paged,
-                    _use_pallas, apply_rope, carry_active, carry_step_update,
-                    commit_window, embed_tokens, logits_at, moe_experts,
-                    rms_norm, rope_freqs)
+from .llama import (KVCacheSpec, Params, _at, _attention, _mlp,
+                    _moe_use_blocked, _qk_headnorm, _scatter_pages,
+                    _scatter_pages_paged, apply_rope, commit_window,
+                    embed_tokens, kernel_mode, logits_at, moe_experts,
+                    rms_norm, rope_freqs, window_attention)
 from .mla import _deepseek_gate
-from ..runtime.config import env_flag
+from .window import Family, make_window
 
 State = Tuple[jax.Array, jax.Array]     # (by slot [S, W], by page [pages, W])
 
@@ -233,12 +232,6 @@ def _short_conv(cfg: ModelConfig, cp, u, valid, tail, ends):
             out = jnp.dot(y.astype(u.dtype), cp["w_out"],
                           preferred_element_type=f32)
     return out, tail, at_ends
-
-
-def _at(params: Params, keys, i):
-    """One layer's leaves of the stacks named, by a (traced) index."""
-    return {k: lax.dynamic_index_in_dim(params[k], i, 0, False)
-            for k in keys}
 
 
 def _stack(params: Params, cfg: ModelConfig, h, valid, conv, attend, cache,
@@ -470,105 +463,72 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                           max_top_k: int = 64, mesh=None,
                           pallas_interpret: bool = False):
     """The fused K-step window of llama.make_decode_window_fn (read-only
-    KV pool + window buffer + on-device carry) with the rows' state (48
-    KB a row) carried beside it as jamba's conv tails are: gathered once,
-    advanced by every step a row is active in, scattered back once. A row
-    that fills a page inside the window leaves its state after it in the
-    page's snapshot (at most one page a row: k_steps <= page size)."""
-    from ..engine.sampling import (logprob_aux, sample_tokens,
-                                   update_penalty_state)
-
+    KV pool + window buffer + on-device carry: models/window.py's
+    program) with the rows' state (48 KB a row) carried beside it as
+    jamba's conv tails are: gathered once, advanced by every step a row
+    is active in, scattered back once. A row that fills a page inside the
+    window leaves its state after it in the page's snapshot (at most one
+    page a row: k_steps <= page size)."""
     n_attn = len(cfg.attn_layer_ids)
     inv_freq = rope_freqs(cfg)
-    pallas_interpret = pallas_interpret or (
-        env_flag("DYN_PALLAS_INTERPRET")
-        and not env_flag("DYN_DISABLE_PALLAS") and not _use_pallas())
-    use_pallas = allow_pallas and (_use_pallas() or pallas_interpret)
+    mode = kernel_mode(allow_pallas, pallas_interpret)
 
-    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
-             donate_argnames=("kv_k", "kv_v", "state"))
-    def decode_window(params, tokens, positions, done, steps, remaining,
-                      kv_k, kv_v, page_table, temperature, top_k, top_p,
-                      seeds, eos_table, penalties=None, state=None,
-                      state_slots=None, *, k_steps: int,
-                      logprobs_topn: int = 0):
-        B = tokens.shape[0]
-        _, NP, KVp, ps, hdp = kv_k.shape
-        P = page_table.shape[1]
-        assert k_steps <= ps, "a window may fill one page a row at most"
-        start = positions
-        wk = jnp.zeros((n_attn, B, k_steps, KVp, hdp), kv_k.dtype)
+    def begin(w):
+        _, NP, KVp, ps, hdp = w.kv_k.shape
+        B = w.start.shape[0]
+        assert w.k_steps <= ps, "a window may fill one page a row at most"
+        wk = jnp.zeros((n_attn, B, w.k_steps, KVp, hdp), w.kv_k.dtype)
         wv = jnp.zeros_like(wk)
-        by_slot, by_page = state
-        conv = _rows(state, state_slots, cfg)
-        snap, snap_page = jnp.zeros_like(conv), jnp.full((B,), NP, jnp.int32)
+        conv = _rows(w.state, w.state_slots, cfg)
+        # the state after the token that filled a row's page, and the page
+        # (NP: none, dropped)
+        return (wk, wv, conv, jnp.zeros_like(conv),
+                jnp.full((B,), NP, jnp.int32))
 
-        def one_step(tok, pos, active, wk, wv, conv, i):
-            def attend(a, x, cache):
-                wk, wv = cache
-                q, k, v = _qkv(cfg, params, a, x, pos[:, None], inv_freq)
-                wk_l = wk[a].at[:, i].set(k[:, 0].astype(wk.dtype))
-                wv_l = wv[a].at[:, i].set(v[:, 0].astype(wv.dtype))
-                if use_pallas:
-                    out = _pool_window_attention_pallas(
-                        q, kv_k, kv_v, jnp.int32(a), page_table, start,
-                        wk_l, wv_l, i, cfg.attn_scale,
-                        interpret=pallas_interpret)
-                else:
-                    out = _pool_window_attention(
-                        q, kv_k[a], kv_v[a], page_table, start, wk_l, wv_l,
-                        i, cfg.attn_scale)
-                return (_attn_out(cfg, params, a, out),
-                        (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
+    def step(w, bufs, tok, pos, active, i):
+        # a frozen or padding row flows through the matmuls; its state
+        # does not move and its K/V never commit
+        wk, wv, conv, snap, snap_page = bufs
+        B = tok.shape[0]
+        ps, P = w.kv_k.shape[3], w.page_table.shape[1]
 
-            h = embed_tokens(params, cfg, tok)[:, None]
-            h, conv, _, (wk, wv) = _stack(
-                params, cfg, h, active[:, None], conv, attend, (wk, wv))
-            return (logits_at(params, cfg, h, jnp.zeros(B, jnp.int32)),
-                    wk, wv, conv)
+        def attend(a, x, cache):
+            wk, wv = cache
+            q, k, v = _qkv(cfg, w.params, a, x, pos[:, None], inv_freq)
+            wk_l = wk[a].at[:, i].set(k[:, 0].astype(wk.dtype))
+            wv_l = wv[a].at[:, i].set(v[:, 0].astype(wv.dtype))
+            out = window_attention(q, w.kv_k, w.kv_v, a, w.page_table,
+                                   w.start, wk_l, wv_l, i, cfg.attn_scale,
+                                   mode)
+            return (_attn_out(cfg, w.params, a, out),
+                    (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
 
-        tok, pos = tokens, positions
-        toks, lps, tvs, tis = [], [], [], []
-        emitted = jnp.zeros((B,), jnp.int32)
-        for i in range(k_steps):
-            # a frozen or padding row flows through the matmuls; its
-            # state does not move and its K/V never commit
-            active = carry_active(done, pos)
-            logits, wk, wv, conv = one_step(tok, pos, active, wk, wv, conv,
-                                            i)
-            with jax.named_scope("state.snapshot"):
-                fills = active & ((pos + 1) % ps == 0)
-                col = jnp.clip(pos // ps, 0, P - 1)
-                snap = jnp.where(fills[:, None, None, None], conv, snap)
-                snap_page = jnp.where(
-                    fills, jnp.take_along_axis(page_table, col[:, None],
-                                               axis=1)[:, 0], snap_page)
-            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                                steps, max_top_k=max_top_k,
-                                penalties=penalties)
-            if logprobs_topn:
-                lp, tv, ti = logprob_aux(logits, nxt, logprobs_topn)
-                lps.append(lp); tvs.append(tv); tis.append(ti)
-            penalties = update_penalty_state(penalties, nxt, done)
-            emitted = emitted + active.astype(jnp.int32)
-            tok, pos, done, steps, remaining = carry_step_update(
-                nxt, tok, pos, done, steps, remaining, eos_table)
-            toks.append(tok)
-
-        with jax.named_scope("kv_carry"):
-            kv_k = commit_window(kv_k, wk, page_table, start, pos)
-            kv_v = commit_window(kv_v, wv, page_table, start, pos)
-            by_slot = by_slot.at[state_slots].set(conv.reshape(B, -1))
+        h = embed_tokens(w.params, cfg, tok)[:, None]
+        h, conv, _, (wk, wv) = _stack(
+            w.params, cfg, h, active[:, None], conv, attend, (wk, wv))
+        logits = logits_at(w.params, cfg, h, jnp.zeros(B, jnp.int32))
         with jax.named_scope("state.snapshot"):
-            by_page = by_page.at[snap_page].set(snap.reshape(B, -1),
-                                                mode="drop")
-        state = (by_slot, by_page)
-        out_toks = jnp.stack(toks, axis=1)
-        carry = (tok, pos, done, steps, remaining)
-        if logprobs_topn:
-            aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
-                   jnp.stack(tis, axis=1))
-            return out_toks, emitted, aux, carry, kv_k, kv_v, state
-        return out_toks, emitted, carry, kv_k, kv_v, state
+            fills = active & ((pos + 1) % ps == 0)
+            col = jnp.clip(pos // ps, 0, P - 1)
+            snap = jnp.where(fills[:, None, None, None], conv, snap)
+            snap_page = jnp.where(
+                fills, jnp.take_along_axis(w.page_table, col[:, None],
+                                           axis=1)[:, 0], snap_page)
+        return logits, (wk, wv, conv, snap, snap_page), None
 
-    return decode_window
+    def commit(w, bufs, pos):
+        wk, wv, conv = bufs[:3]
+        by_slot, by_page = w.state
+        return (commit_window(w.kv_k, wk, w.page_table, w.start, pos),
+                commit_window(w.kv_v, wv, w.page_table, w.start, pos),
+                (by_slot.at[w.state_slots].set(
+                    conv.reshape(len(w.state_slots), -1)), by_page))
+
+    def settle(w, bufs, state):
+        snap, snap_page = bufs[3:]
+        with jax.named_scope("state.snapshot"):
+            by_page = state[1].at[snap_page].set(
+                snap.reshape(len(snap_page), -1), mode="drop")
+        return state[0], by_page
+
+    return make_window(Family(begin, step, commit, settle), max_top_k)

@@ -39,8 +39,11 @@ from ..ops.paged_attention import (effective_window,
                                    paged_attention_decode_sharded,
                                    paged_attention_prefill,
                                    paged_attention_prefill_sharded)
+from ..engine.sampling import logprob_aux, sample_with_confidence, unmask
 from ..runtime.config import env_flag
 from .config import ModelConfig
+from .window import (Family, WindowResults, block_carry_update, carry_active,
+                     make_window)
 
 Params = Dict[str, jax.Array]
 
@@ -342,6 +345,31 @@ def _use_pallas() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def kernel_mode(allow_pallas: bool = True, pallas_interpret: bool = False,
+                mesh=None) -> Optional[bool]:
+    """How a site that has a Pallas kernel and an XLA arm runs: None the
+    XLA arm, False the kernel on the chip, True the kernel interpreted.
+    The one choice of every family's attention, scan and expert kernels,
+    taken in Python before tracing: the kernel on a TPU backend
+    (``_use_pallas``, read through this module when called, so a test or
+    a tool that wants the chip's arm patches ``llama._use_pallas`` and
+    reaches every family); off it, interpreted under the tests' hooks
+    (a window maker's ``pallas_interpret``, or DYN_PALLAS_INTERPRET,
+    which never interprets on a TPU backend: a lingering variable must
+    not slow a hardware run, nor pass the DYN_DISABLE_PALLAS kill
+    switch); else the XLA arm. ``mesh`` is given by a site whose kernel
+    has no shard_map wrapper (mla.py's): it stays on the XLA arm under
+    more than one device, where GSPMD shards its einsums."""
+    if not allow_pallas or (mesh is not None and mesh.size > 1):
+        return None
+    if _use_pallas():
+        return False
+    if pallas_interpret or (env_flag("DYN_PALLAS_INTERPRET")
+                            and not env_flag("DYN_DISABLE_PALLAS")):
+        return True
+    return None
+
+
 def _softcap_mask(scores: jax.Array, visible: jax.Array,
                   softcap: Optional[float]) -> jax.Array:
     """Gemma-2 attention-score postprocess: tanh softcap (BEFORE masking —
@@ -391,17 +419,11 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     prefill kernel and the XLA arm take it (a decode step of one query
     does not exist under it; the block window below has its own
     attention)."""
-    # CPU test hook: DYN_PALLAS_INTERPRET drives the kernel-in-engine
-    # path in interpret mode — but NEVER on a real TPU backend (a
-    # lingering env var must not silently interpret-mode a hardware
-    # bench), and never past the DYN_DISABLE_PALLAS kill switch
-    interp = (env_flag("DYN_PALLAS_INTERPRET")
-              and not env_flag("DYN_DISABLE_PALLAS")
-              and not _use_pallas())
+    mode = kernel_mode(allow_pallas)
+    pallas_ok, interp = mode is not None, mode is True
     B, T, H, hd = q.shape
     KV = k_pages.shape[1]
     sharded = mesh is not None and mesh.size > 1
-    pallas_ok = allow_pallas and (_use_pallas() or interp)
     if sharded:
         # shard_map needs whole GQA groups and whole batch rows per shard;
         # shapes are static at trace time so this is a compile-time choice
@@ -427,7 +449,7 @@ def _attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                 lengths, mesh=mesh, scale=scale, interpret=interp,
                 return_stats=False, softcap=softcap, lower=lower)
             return out[:, None]
-        if _use_pallas():  # unsharded K=1: hardware kernel only (no
+        if not interp:  # unsharded K=1: hardware kernel only (no
             return paged_attention_decode(  # interpret hook needed here)
                 q[:, 0], k_pages, v_pages, page_table,
                 lengths, scale=scale, softcap=softcap,
@@ -651,13 +673,7 @@ def _moe_kernel_interpret(w) -> Optional[bool]:
     it: the tests' hook)."""
     from .quant import QuantInt8
 
-    if isinstance(w, QuantInt8):
-        return None
-    if _use_pallas():
-        return False
-    if env_flag("DYN_PALLAS_INTERPRET") and not env_flag("DYN_DISABLE_PALLAS"):
-        return True
-    return None
+    return None if isinstance(w, QuantInt8) else kernel_mode()
 
 
 def moe_kernel_takes(cfg: ModelConfig, params: Params, mesh,
@@ -929,6 +945,37 @@ def _moe_mlp(h: jax.Array, w_router, w_gate, w_up, w_down,
         live=live, layer=layer, out_dtype=h.dtype)
 
 
+def _layer_ff(h, lp, cfg: ModelConfig, mesh, experts=None, live=None,
+              l_idx=None):
+    """The second half of a layer: h + the MLP, or the routed experts, of
+    norm(h). ``experts``: the stacked (gate, up, down) weights of every
+    layer, read in place at ``l_idx`` by the sorted dispatch, with
+    ``live`` the rows that make pairs (a prefill); else ``lp`` holds the
+    layer's own."""
+    x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+    if cfg.num_experts == 0:
+        mlp_out = _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], _act(cfg))
+    else:
+        with jax.named_scope("moe"):
+            if experts is not None:
+                mlp_out = _moe_mlp(x, lp["w_router"], *experts,
+                                   cfg.num_experts_per_tok, mesh=mesh,
+                                   live=live, layer=l_idx)
+            else:
+                mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
+                                   lp["w_up"], lp["w_down"],
+                                   cfg.num_experts_per_tok, mesh=mesh)
+    return _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
+
+
+def _at(params: Params, keys, i):
+    """One layer's leaves of the stacks named, by a (traced) index: the
+    slice a scan over the stack would make, where the stacks are not cut
+    into runs first (jamba.py, lfm2.py, mla.py)."""
+    return {k: lax.dynamic_index_in_dim(params[k], i, 0, False)
+            for k in keys}
+
+
 def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             positions: jax.Array, kv_k: jax.Array, kv_v: jax.Array,
             page_table: jax.Array, flat_slots: jax.Array,
@@ -950,7 +997,6 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     B, T = tokens.shape
 
     h = embed_tokens(params, cfg, tokens)  # [B, T, D]
-    act = _act(cfg)
     safe_pos = jnp.maximum(positions, 0)
 
     layer_params = {k: params[k] for k in _layer_keys(cfg)}
@@ -959,6 +1005,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     # sliced out (copied) before the block loop may index it
     moe_in_place = cfg.num_experts > 0 and _moe_use_blocked(
         mesh, B * T, cfg.num_experts, cfg.num_experts_per_tok)
+    experts = live = None
     if moe_in_place:
         experts = [layer_params.pop(k) for k in ("w_gate", "w_up", "w_down")]
         live = positions >= 0   # padding rows make no (token, expert) pair
@@ -993,20 +1040,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                               block=cfg.block_length)
             h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"], lp,
                               "ln_attn_post", cfg)
-        x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-        if cfg.num_experts > 0:
-            with jax.named_scope("moe"):
-                if moe_in_place:
-                    mlp_out = _moe_mlp(x, lp["w_router"], *experts,
-                                       cfg.num_experts_per_tok, mesh=mesh,
-                                       live=live, layer=l_idx)
-                else:
-                    mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
-                                       lp["w_up"], lp["w_down"],
-                                       cfg.num_experts_per_tok, mesh=mesh)
-        else:
-            mlp_out = _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], act)
-        h = _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
+        h = _layer_ff(h, lp, cfg, mesh, experts, live, l_idx)
         return h, (k_layer, v_layer)
 
     h, (new_k, new_v) = lax.scan(
@@ -1109,37 +1143,6 @@ def make_verify_fn(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
 # ------------------------------------------------- fused decode window
 
 
-def carry_active(done: jax.Array, pos: jax.Array) -> jax.Array:
-    """Rows still generating: not stopped, not padding (pos < 0)."""
-    return jnp.logical_and(jnp.logical_not(done), pos >= 0)
-
-
-def carry_step_update(nxt, tok, pos, done, steps, remaining, eos_table):
-    """Shared on-device sequence-carry update for one fused decode step:
-    freeze rows that sample a stop token or exhaust their budget. Both
-    fused-window implementations that yield ONE TOKEN A ROW A STEP (llama
-    window form and the engine's generic full-forward fallback) MUST use
-    this — the host bookkeeping in _process_window assumes identical stop
-    semantics on every path. The block window (_make_block_window_fn),
-    whose step yields a block a row, uses ``block_carry_update`` in its
-    place: the same three stop conditions (a stop id is emitted and
-    freezes the row, the budget counts emitted tokens, a frozen row
-    neither advances nor commits) applied to the block's new positions in
-    order, so what the host assumes (``emitted`` tokens are the row's
-    next tokens, ``done`` says the row froze in this window, the last
-    emitted token decides stop-versus-length) still holds."""
-    active = carry_active(done, pos)
-    hit_stop = jnp.any(nxt[:, None] == eos_table, axis=1)
-    remaining = jnp.where(active, remaining - 1, remaining)
-    tok = jnp.where(active, nxt, tok)
-    pos = jnp.where(active, pos + 1, pos)
-    steps = jnp.where(active, steps + 1, steps)
-    done = jnp.logical_or(
-        done, jnp.logical_and(active, jnp.logical_or(
-            hit_stop, remaining <= 0)))
-    return tok, pos, done, steps, remaining
-
-
 def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                           max_top_k: int = 64, mesh=None,
                           pallas_interpret: bool = False):
@@ -1157,18 +1160,9 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     the compiler relayout the pool around it: eight pool-sized copies a
     window and one pool of temporaries (see commit_window).
 
-    The carry (tok, pos, done, steps, remaining) lives on device so the
-    engine can dispatch window N+1 *before* reading back window N's tokens
-    (async pipelining — the host never sits on the critical path between
-    windows). Stop conditions run on device: a row freezes (no position
-    advance, no KV writes) as soon as it samples an EOS/stop token or
-    exhausts its token budget, so K can grow without dead compute past the
-    stop and without stray writes into released pages. The reference keeps
-    streaming off the sync path with its TCP response plane
-    (lib/runtime/src/pipeline/network/tcp/server.rs); here the analogous
-    move is keeping the sampling feedback loop on device.
-
-    Signature matches engine._make_decode_multi's generic fallback.
+    The program is models/window.py's (``make_window``: the loop, the
+    sampler, the on-device carry and its stop rules, the results); what
+    is here is this family's buffers, one step, and the commit.
 
     A configuration that generates by diffusion over blocks
     (``cfg.block_length`` > 1) gets ``_make_block_window_fn``'s program
@@ -1176,9 +1170,6 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     if cfg.block_length > 1:
         return _make_block_window_fn(cfg, allow_pallas, max_top_k, mesh,
                                      pallas_interpret)
-    from ..engine.sampling import (logprob_aux, sample_tokens,
-                                   update_penalty_state)
-
     inv_freq = rope_freqs(cfg)
     scale = cfg.attn_scale
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
@@ -1194,135 +1185,78 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     # path in interpret mode for CPU parity tests.
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
     sharded = mesh is not None and mesh.size > 1
-    # the same CPU test hook _attention honors: engine-level window tests
-    # drive the kernel path in interpret mode (never on a real TPU)
-    pallas_interpret = pallas_interpret or (
-        env_flag("DYN_PALLAS_INTERPRET")
-        and not env_flag("DYN_DISABLE_PALLAS")
-        and not _use_pallas())
-    use_pallas = (allow_pallas and (_use_pallas() or pallas_interpret)
-                  and cfg.num_kv_heads % max(tp, 1) == 0)
+    mode = kernel_mode(allow_pallas, pallas_interpret)
+    if cfg.num_kv_heads % max(tp, 1):
+        mode = None
+    L = cfg.num_layers
 
-    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
-             donate_argnames=("kv_k", "kv_v"))
-    def decode_window(params, tokens, positions, done, steps, remaining,
-                      kv_k, kv_v, page_table, temperature, top_k, top_p,
-                      seeds, eos_table, penalties=None, *, k_steps: int,
-                      logprobs_topn: int = 0):
-        B = tokens.shape[0]
-        L = cfg.num_layers
-        start = positions  # [B] position of the first window token (-1 pad)
+    def begin(w):
+        B = w.start.shape[0]
+        wdt = w.kv_k.dtype
+        return (jnp.zeros((L, B, w.k_steps, KV, hd), wdt),
+                jnp.zeros((L, B, w.k_steps, KV, hd), wdt))
+
+    def step(w, bufs, tok, pos, active, i):
+        # frozen (done/pad) rows still flow through the matmuls: their
+        # outputs are discarded and their KV never commit (commit's mask),
+        # so correctness needs no per-row control flow
+        params, kv_k, kv_v = w.params, w.kv_k, w.kv_v
+        page_table, start = w.page_table, w.start
+        wk, wv = bufs
+        B = tok.shape[0]
         wdt = kv_k.dtype
-        wk = jnp.zeros((L, B, k_steps, KV, hd), wdt)
-        wv = jnp.zeros((L, B, k_steps, KV, hd), wdt)
         layer_params = {k: params[k] for k in _layer_keys(cfg)}
+        h = embed_tokens(params, cfg, tok)[:, None]  # [B, 1, D]
+        safe_pos = jnp.maximum(pos, 0)[:, None]
 
-        act = _act(cfg)
+        def layer(h, xs):
+            # NOTE: the pools are closure-captured, NOT scanned xs —
+            # scanning them makes XLA materialize a fresh per-layer
+            # slice copy for each unrolled step's pallas operand
+            # (≈6.4 GB/step of copy traffic at serving sizes)
+            lp, l_idx, wk_l, wv_l = xs
+            with jax.named_scope("attn"):
+                x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                             cfg.norm_unit_offset)
+                xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+                if cfg.attn_bias:
+                    xq, xk, xv = (xq + lp["bq"], xk + lp["bk"],
+                                  xv + lp["bv"])
+                q, k = _qk_headnorm(xq.reshape(B, 1, H, hd),
+                                    xk.reshape(B, 1, KV, hd), lp, cfg)
+                q = apply_rope(q, safe_pos, inv_freq)
+                k = apply_rope(k, safe_pos, inv_freq)
+                v = xv.reshape(B, 1, KV, hd)
+                wk_l = wk_l.at[:, i].set(k[:, 0].astype(wdt))
+                wv_l = wv_l.at[:, i].set(v[:, 0].astype(wdt))
+                attn = window_attention(
+                    q, kv_k, kv_v, l_idx, page_table, start, wk_l, wv_l, i,
+                    scale, mode, mesh=mesh if sharded else None,
+                    softcap=cfg.attn_logit_softcap,
+                    window=cfg.sliding_window,
+                    is_sliding=_sliding_flag(cfg, l_idx),
+                    q_pos=safe_pos[:, 0])
+                h = _residual_add(
+                    h, attn.reshape(B, 1, H * hd) @ lp["wo"], lp,
+                    "ln_attn_post", cfg)
+            return _layer_ff(h, lp, cfg, mesh), (wk_l, wv_l)
 
-        def one_step(tok, pos, wk, wv, i):
-            h = embed_tokens(params, cfg, tok)[:, None]  # [B, 1, D]
-            safe_pos = jnp.maximum(pos, 0)[:, None]
+        h, (wk, wv) = lax.scan(
+            layer, h,
+            (layer_params, jnp.arange(L, dtype=jnp.int32), wk, wv))
+        h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps, cfg.norm_unit_offset)
+        logits = logits_at(params, cfg, h, jnp.zeros(B, jnp.int32))
+        return logits, (wk, wv), None
 
-            def layer(h, xs):
-                # NOTE: the pools are closure-captured, NOT scanned xs —
-                # scanning them makes XLA materialize a fresh per-layer
-                # slice copy for each unrolled step's pallas operand
-                # (≈6.4 GB/step of copy traffic at serving sizes)
-                lp, l_idx, wk_l, wv_l = xs
-                with jax.named_scope("attn"):
-                    x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
-                                 cfg.norm_unit_offset)
-                    xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
-                    if cfg.attn_bias:
-                        xq, xk, xv = (xq + lp["bq"], xk + lp["bk"],
-                                      xv + lp["bv"])
-                    q, k = _qk_headnorm(xq.reshape(B, 1, H, hd),
-                                        xk.reshape(B, 1, KV, hd), lp, cfg)
-                    q = apply_rope(q, safe_pos, inv_freq)
-                    k = apply_rope(k, safe_pos, inv_freq)
-                    v = xv.reshape(B, 1, KV, hd)
-                    wk_l = wk_l.at[:, i].set(k[:, 0].astype(wdt))
-                    wv_l = wv_l.at[:, i].set(v[:, 0].astype(wdt))
-                    if use_pallas:
-                        attn = _pool_window_attention_pallas(
-                            q, kv_k, kv_v, l_idx, page_table, start, wk_l,
-                            wv_l, i, scale,
-                            interpret=pallas_interpret,
-                            mesh=mesh if sharded else None,
-                            softcap=cfg.attn_logit_softcap,
-                            window=cfg.sliding_window,
-                            is_sliding=_sliding_flag(cfg, l_idx),
-                            q_pos=safe_pos[:, 0])
-                    else:
-                        attn = _pool_window_attention(
-                            q, kv_k[l_idx], kv_v[l_idx], page_table, start,
-                            wk_l, wv_l, i, scale,
-                            softcap=cfg.attn_logit_softcap,
-                            window=cfg.sliding_window,
-                            is_sliding=_sliding_flag(cfg, l_idx),
-                            q_pos=safe_pos[:, 0])
-                    h = _residual_add(
-                        h, attn.reshape(B, 1, H * hd) @ lp["wo"], lp,
-                        "ln_attn_post", cfg)
-                x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-                if cfg.num_experts > 0:
-                    with jax.named_scope("moe"):
-                        mlp_out = _moe_mlp(
-                            x, lp["w_router"], lp["w_gate"], lp["w_up"],
-                            lp["w_down"], cfg.num_experts_per_tok, mesh=mesh)
-                else:
-                    mlp_out = _mlp(x, lp["w_gate"], lp["w_up"],
-                                   lp["w_down"], act)
-                h = _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
-                return h, (wk_l, wv_l)
+    def commit(w, bufs, pos):
+        # the window into the pool by whole pages (commit_window): entry
+        # i holds the K/V of position start+i, valid only if the row was
+        # still active at step i (start+i < final pos)
+        wk, wv = bufs
+        return (commit_window(w.kv_k, wk, w.page_table, w.start, pos),
+                commit_window(w.kv_v, wv, w.page_table, w.start, pos), None)
 
-            h, (wk, wv) = lax.scan(
-                layer, h,
-                (layer_params, jnp.arange(L, dtype=jnp.int32), wk, wv))
-            h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-            logits = logits_at(params, cfg, h, jnp.zeros(B, jnp.int32))
-            return logits, wk, wv
-
-        tok, pos = tokens, positions
-        toks = []
-        lps, tvs, tis = [], [], []
-        # per-row count of tokens this window actually produced: a row
-        # that freezes (stop token / budget) mid-window stops counting, so
-        # the host can slice toks[i, :emitted[i]] without a per-step scan
-        emitted = jnp.zeros((B,), jnp.int32)
-        for i in range(k_steps):
-            # frozen (done/pad) rows still flow through the matmuls — their
-            # outputs are discarded and their KV never commits (commit mask
-            # below), so correctness needs no per-row control flow
-            logits, wk, wv = one_step(tok, pos, wk, wv, i)
-            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                                steps, max_top_k=max_top_k,
-                                penalties=penalties)
-            if logprobs_topn:
-                lp, tv, ti = logprob_aux(logits, nxt, logprobs_topn)
-                lps.append(lp); tvs.append(tv); tis.append(ti)
-            penalties = update_penalty_state(penalties, nxt, done)
-            emitted = emitted + carry_active(done, pos).astype(jnp.int32)
-            tok, pos, done, steps, remaining = carry_step_update(
-                nxt, tok, pos, done, steps, remaining, eos_table)
-            toks.append(tok)
-
-        # commit the window into the pool by whole pages (commit_window):
-        # entry i holds the K/V of position start+i, valid only if the
-        # row was still active at step i (start+i < final pos)
-        with jax.named_scope("kv_carry"):
-            kv_k = commit_window(kv_k, wk, page_table, start, pos)
-            kv_v = commit_window(kv_v, wv, page_table, start, pos)
-        out_toks = jnp.stack(toks, axis=1)
-        carry = (tok, pos, done, steps, remaining)
-        if logprobs_topn:
-            aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
-                   jnp.stack(tis, axis=1))
-            return out_toks, emitted, aux, carry, kv_k, kv_v
-        return out_toks, emitted, carry, kv_k, kv_v
-
-    return decode_window
-
+    return make_window(Family(begin, step, commit), max_top_k)
 
 
 # ------------------------------- block window (generation by diffusion)
@@ -1335,38 +1269,6 @@ def _block_kv(k, v, dtype):
     tools/diffusion_block_check.py can round them to 8 bits there and
     show that the cell's agreement check sees the cache's precision."""
     return k.astype(dtype), v.astype(dtype)
-
-
-def block_carry_update(tok, new, pos, done, steps, remaining, eos_table,
-                       block: int):
-    """The block window's carry update after one block is final: what
-    ``carry_step_update`` does for a step of one token, for a step that
-    yields a block.
-
-    tok [B, L]: the block's final tokens; new [B, L]: positions that were
-    masked when the block began (the others were the prompt's tail). A
-    live row emits its new positions in order while its budget lasts and
-    up to and including the first stop id; what follows in the block is
-    dropped. The row advances (and its block commits) only if every new
-    position was emitted; it freezes if it hit a stop id, spent its
-    budget, or dropped anything. Returns (emit [B, L] bool, pos, done,
-    steps, remaining)."""
-    active = carry_active(done, pos)
-    new = jnp.logical_and(new, active[:, None])
-    count = jnp.cumsum(new.astype(jnp.int32), axis=1)
-    stop = jnp.logical_and(
-        new, jnp.any(tok[:, :, None] == eos_table[:, None, :], axis=2))
-    stops_before = jnp.cumsum(stop.astype(jnp.int32), axis=1) \
-        - stop.astype(jnp.int32)
-    emit = new & (count <= remaining[:, None]) & (stops_before == 0)
-    n_emit = jnp.sum(emit.astype(jnp.int32), axis=1)
-    whole = n_emit == jnp.sum(new.astype(jnp.int32), axis=1)
-    remaining = remaining - n_emit
-    steps = steps + n_emit
-    pos = jnp.where(active & whole, pos + block, pos)
-    done = done | (active & (jnp.any(emit & stop, axis=1)
-                             | (remaining <= 0) | ~whole))
-    return emit, pos, done, steps, remaining
 
 
 def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
@@ -1414,18 +1316,11 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
     forwards (denoising + commit), commit forwards, blocks that took
     fewer denoising forwards than their schedule (an early exit of the
     dynamic strategy) and tokens generated but dropped."""
-    from ..engine.sampling import (logprob_aux, sample_with_confidence,
-                                   unmask)
-
     L, S = cfg.block_length, cfg.denoising_steps
     inv_freq = rope_freqs(cfg)
     scale = cfg.attn_scale
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
-    pallas_interpret = pallas_interpret or (
-        env_flag("DYN_PALLAS_INTERPRET")
-        and not env_flag("DYN_DISABLE_PALLAS")
-        and not _use_pallas())
-    use_pallas = allow_pallas and (_use_pallas() or pallas_interpret)
+    mode = kernel_mode(allow_pallas, pallas_interpret)
     if mesh is not None and mesh.size > 1:
         raise NotImplementedError(
             "the block window has no sharded form (engine/jax_engine.py "
@@ -1447,7 +1342,6 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
         wk = jnp.zeros((Lyr, B, k_steps, KV, hd), wdt)
         wv = jnp.zeros((Lyr, B, k_steps, KV, hd), wdt)
         layer_params = {k: params[k] for k in _layer_keys(cfg)}
-        act = _act(cfg)
         offs = jnp.arange(L, dtype=jnp.int32)
 
         def block_forward(x_tok, wk, wv, w: int, want_logits: bool):
@@ -1475,22 +1369,11 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
                     attn = _block_window_attention(
                         q, kv_k, kv_v, l_idx, page_table, start, wk_l,
                         wv_l, (w + 1) * L, scale,
-                        use_pallas=use_pallas, interpret=pallas_interpret)
+                        use_pallas=mode is not None, interpret=bool(mode))
                     h = _residual_add(
                         h, attn.reshape(B, L, H * hd) @ lp["wo"], lp,
                         "ln_attn_post", cfg)
-                x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps,
-                             cfg.norm_unit_offset)
-                if cfg.num_experts > 0:
-                    with jax.named_scope("moe"):
-                        mlp_out = _moe_mlp(
-                            x, lp["w_router"], lp["w_gate"], lp["w_up"],
-                            lp["w_down"], cfg.num_experts_per_tok, mesh=mesh)
-                else:
-                    mlp_out = _mlp(x, lp["w_gate"], lp["w_up"],
-                                   lp["w_down"], act)
-                h = _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
-                return h, (wk_l, wv_l)
+                return _layer_ff(h, lp, cfg, mesh), (wk_l, wv_l)
 
             h, (wk, wv) = lax.scan(
                 layer, h,
@@ -1573,13 +1456,11 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
             kv_k = commit_window(kv_k, wk, page_table, start, pos)
             kv_v = commit_window(kv_v, wv, page_table, start, pos)
         toks = jnp.concatenate(out_toks, axis=1)
-        carry = (tok, pos, done, steps, remaining)
-        if N:
-            aux = (jnp.concatenate(lps, axis=1),
-                   jnp.concatenate(tvs, axis=1),
-                   jnp.concatenate(tis, axis=1))
-            return toks, emitted, aux, carry, kv_k, kv_v, info
-        return toks, emitted, carry, kv_k, kv_v, info
+        aux = (jnp.concatenate(lps, axis=1), jnp.concatenate(tvs, axis=1),
+               jnp.concatenate(tis, axis=1)) if N else None
+        return WindowResults(toks, emitted, aux,
+                             (tok, pos, done, steps, remaining), kv_k, kv_v,
+                             info, None).pack()
 
     return decode_window
 
@@ -1649,6 +1530,23 @@ def _block_window_attention(q, k_pools, v_pools, l_idx, page_table, start,
                             wv_l.astype(jnp.float32)))
     out = out.reshape(B, KV, L, G, hd).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, L, H, hd).astype(q.dtype)
+
+
+def window_attention(q, k_pools, v_pools, l_idx, page_table, start, wk_l,
+                     wv_l, i: int, scale, mode: Optional[bool], mesh=None,
+                     **knobs):
+    """One window step's attention of layer ``l_idx`` over the read-only
+    pools and the window buffer, as ``mode`` (kernel_mode) says: the
+    Pallas decode kernel on the pools where they lie, or the XLA gather
+    arm on the layer's slice. ``knobs``: softcap, window, is_sliding,
+    q_pos (Gemma-2)."""
+    if mode is None:
+        return _pool_window_attention(q, k_pools[l_idx], v_pools[l_idx],
+                                      page_table, start, wk_l, wv_l, i,
+                                      scale, **knobs)
+    return _pool_window_attention_pallas(
+        q, k_pools, v_pools, jnp.asarray(l_idx, jnp.int32), page_table,
+        start, wk_l, wv_l, i, scale, interpret=mode, mesh=mesh, **knobs)
 
 
 def _pool_window_attention_pallas(q, k_pools, v_pools, l_idx, page_table,

@@ -44,12 +44,11 @@ from jax import lax
 
 from ..ops.paged_attention import (NEG_INF, latent_attention_decode_layered,
                                    latent_attention_prefill_layered)
-from ..runtime.config import env_flag
 from .config import ModelConfig
 from . import llama
-from .llama import (KVCacheSpec, _mlp, _moe_use_blocked, apply_rope,
-                    carry_active, carry_step_update, commit_window,
-                    logits_at, rms_norm, rope_freqs)
+from .llama import (KVCacheSpec, _at, _mlp, _moe_use_blocked, apply_rope,
+                    commit_window, logits_at, rms_norm, rope_freqs)
+from .window import Family, make_window
 
 Params = Dict[str, jax.Array]
 
@@ -380,28 +379,6 @@ def _merge(a, b):
     return (acc_a * w_a[..., None] + acc_b * w_b[..., None]) / l[..., None]
 
 
-def _kernel_mode(allow_pallas: bool, mesh) -> Optional[bool]:
-    """None: the XLA arm; False: the kernel on the chip; True: the
-    kernel in interpret mode (DYN_PALLAS_INTERPRET, the hook
-    llama._attention honours, never on a TPU backend). Under a mesh of
-    more than one device the XLA arm stays (GSPMD shards its einsums;
-    the kernel has no shard_map wrapper yet)."""
-    if (not allow_pallas or env_flag("DYN_DISABLE_PALLAS")
-            or (mesh is not None and mesh.size > 1)):
-        return None
-    if llama._use_pallas():
-        return False
-    return True if env_flag("DYN_PALLAS_INTERPRET") else None
-
-
-def _at(params: Params, keys, l):
-    """One layer's leaves of the stacks named, by a (traced) index: the
-    slice a scan over the stack would make, without cutting the stacks
-    into a dense and an expert segment first."""
-    return {k: lax.dynamic_index_in_dim(params[k], l, 0, False)
-            for k in keys}
-
-
 def _latent_qkv(cfg: ModelConfig, lp, x, safe_pos, inv_freq, dtype):
     """x [B, T, D] (normed) -> absorbed queries q_lat [B, T, H, r] =
     q_nope . W_UK and q_rope [B, T, H, dr], and what the cache keeps of
@@ -558,7 +535,9 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
     inv_freq = rope_freqs(cfg, dim=cfg.qk_rope_head_dim)
     scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
     B, T = tokens.shape
-    kernel = _kernel_mode(allow_pallas, mesh)
+    # no shard_map wrapper for the latent kernels yet: the XLA arm
+    # under a mesh (llama.kernel_mode)
+    kernel = llama.kernel_mode(allow_pallas, mesh=mesh)
     h = params["embed"][tokens]
     safe_pos = jnp.maximum(positions, 0)
     live = positions >= 0
@@ -613,91 +592,59 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                           max_top_k: int = 64, mesh=None,
                           pallas_interpret: bool = False):
     """The fused K-step window of llama.make_decode_window_fn, same
-    signature and contract, over the latent cache: the latent and rope
-    pools are read-only inside the window (the Pallas latent decode
-    kernel on the chip, the XLA arm elsewhere), the window's own
-    (c_kv, k_rope) live in buffers [L, B, K, 1, *] merged in by
-    online-softmax statistics, and one llama.commit_window per pool
+    signature and contract (models/window.py's program), over the latent
+    cache: the latent and rope pools are read-only inside the window (the
+    Pallas latent decode kernel on the chip, the XLA arm elsewhere), the
+    window's own (c_kv, k_rope) live in buffers [L, B, K, 1, *] merged in
+    by online-softmax statistics, and one llama.commit_window per pool
     writes them in by whole pages, in place."""
-    from ..engine.sampling import (logprob_aux, sample_tokens,
-                                   update_penalty_state)
-
     inv_freq = rope_freqs(cfg, dim=cfg.qk_rope_head_dim)
     scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
-    kernel = _kernel_mode(allow_pallas, mesh)
-    if pallas_interpret and kernel is None and not llama._use_pallas():
-        kernel = True
+    kernel = llama.kernel_mode(allow_pallas, pallas_interpret, mesh)
+    L = cfg.num_layers
 
-    @partial(jax.jit, static_argnames=("k_steps", "logprobs_topn"),
-             donate_argnames=("kv_k", "kv_v"))
-    def decode_window(params, tokens, positions, done, steps, remaining,
-                      kv_k, kv_v, page_table, temperature, top_k, top_p,
-                      seeds, eos_table, penalties=None, *, k_steps: int,
-                      logprobs_topn: int = 0):
-        B = tokens.shape[0]
-        L = cfg.num_layers
-        start = positions       # [B] the window's first position (-1 pad)
-        before = jnp.maximum(start, 0)
-        wc = jnp.zeros((L, B, k_steps, 1, kv_k.shape[-1]), kv_k.dtype)
-        wr = jnp.zeros((L, B, k_steps, 1, kv_v.shape[-1]), kv_v.dtype)
-        slot = jnp.arange(k_steps, dtype=jnp.int32)
+    def begin(w):
+        B, K = w.start.shape[0], w.k_steps
+        before = jnp.maximum(w.start, 0)
+        wc = jnp.zeros((L, B, K, 1, w.kv_k.shape[-1]), w.kv_k.dtype)
+        wr = jnp.zeros((L, B, K, 1, w.kv_v.shape[-1]), w.kv_v.dtype)
+        return wc, wr, before, jnp.arange(K, dtype=jnp.int32)
 
-        def one_step(tok, pos, wc, wr, i):
-            safe_pos = jnp.maximum(pos, 0)[:, None]
-            seen = ((slot[None, :] <= i)
-                    & (start[:, None] >= 0))[:, None, :]      # [B, 1, K]
+    def step(w, bufs, tok, pos, active, i):
+        # frozen (done / padding) rows flow through the matmuls; their
+        # outputs are discarded and their latents never commit
+        wc, wr, before, slot = bufs
+        safe_pos = jnp.maximum(pos, 0)[:, None]
+        seen = ((slot[None, :] <= i)
+                & (w.start[:, None] >= 0))[:, None, :]      # [B, 1, K]
 
-            def attend(l, lp, x, bufs):
-                wc_l, wr_l = bufs
-                q_lat, q_rope, c_kv, k_rope = _latent_qkv(
-                    cfg, lp, x, safe_pos, inv_freq, wc.dtype)
-                wc_l = wc_l.at[:, i, 0].set(c_kv[:, 0])
-                wr_l = wr_l.at[:, i, 0].set(k_rope[:, 0])
-                with jax.named_scope("attn.latent"):
-                    out = _merge(
-                        _attend_pool(q_lat, q_rope, kv_k, kv_v, l,
-                                     page_table, before, scale, kernel),
-                        _attend_local(q_lat, q_rope, wc_l[:, :, 0],
-                                      wr_l[:, :, 0], seen, scale))
-                return out, (wc_l, wr_l)
+        def attend(l, lp, x, bufs):
+            wc_l, wr_l = bufs
+            q_lat, q_rope, c_kv, k_rope = _latent_qkv(
+                cfg, lp, x, safe_pos, inv_freq, wc.dtype)
+            wc_l = wc_l.at[:, i, 0].set(c_kv[:, 0])
+            wr_l = wr_l.at[:, i, 0].set(k_rope[:, 0])
+            with jax.named_scope("attn.latent"):
+                out = _merge(
+                    _attend_pool(q_lat, q_rope, w.kv_k, w.kv_v, l,
+                                 w.page_table, before, scale, kernel),
+                    _attend_local(q_lat, q_rope, wc_l[:, :, 0],
+                                  wr_l[:, :, 0], seen, scale))
+            return out, (wc_l, wr_l)
 
-            h = params["embed"][tok][:, None]                 # [B, 1, D]
-            h, (wc, wr) = _layers(params, cfg, h, attend, (wc, wr),
-                                  mesh=mesh)
-            h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps)
-            return logits_at(params, cfg, h, jnp.zeros(B, jnp.int32)), wc, wr
+        h = w.params["embed"][tok][:, None]                 # [B, 1, D]
+        h, (wc, wr) = _layers(w.params, cfg, h, attend, (wc, wr), mesh=mesh)
+        h = rms_norm(h, w.params["ln_final"], cfg.rms_norm_eps)
+        return (logits_at(w.params, cfg, h,
+                          jnp.zeros(tok.shape[0], jnp.int32)),
+                (wc, wr, before, slot), None)
 
-        tok, pos = tokens, positions
-        toks, lps, tvs, tis = [], [], [], []
-        emitted = jnp.zeros((B,), jnp.int32)
-        for i in range(k_steps):
-            # frozen (done / padding) rows flow through the matmuls;
-            # their outputs are discarded and their latents never commit
-            logits, wc, wr = one_step(tok, pos, wc, wr, i)
-            nxt = sample_tokens(logits, temperature, top_k, top_p, seeds,
-                                steps, max_top_k=max_top_k,
-                                penalties=penalties)
-            if logprobs_topn:
-                lp, tv, ti = logprob_aux(logits, nxt, logprobs_topn)
-                lps.append(lp); tvs.append(tv); tis.append(ti)
-            penalties = update_penalty_state(penalties, nxt, done)
-            emitted = emitted + carry_active(done, pos).astype(jnp.int32)
-            tok, pos, done, steps, remaining = carry_step_update(
-                nxt, tok, pos, done, steps, remaining, eos_table)
-            toks.append(tok)
+    def commit(w, bufs, pos):
+        wc, wr = bufs[:2]
+        return (commit_window(w.kv_k, wc, w.page_table, w.start, pos),
+                commit_window(w.kv_v, wr, w.page_table, w.start, pos), None)
 
-        with jax.named_scope("kv_carry"):
-            kv_k = commit_window(kv_k, wc, page_table, start, pos)
-            kv_v = commit_window(kv_v, wr, page_table, start, pos)
-        out_toks = jnp.stack(toks, axis=1)
-        carry = (tok, pos, done, steps, remaining)
-        if logprobs_topn:
-            aux = (jnp.stack(lps, axis=1), jnp.stack(tvs, axis=1),
-                   jnp.stack(tis, axis=1))
-            return out_toks, emitted, aux, carry, kv_k, kv_v
-        return out_toks, emitted, carry, kv_k, kv_v
-
-    return decode_window
+    return make_window(Family(begin, step, commit), max_top_k)
 
 
 # -------------------------------------------------- full-attention reference

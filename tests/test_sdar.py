@@ -765,22 +765,3 @@ def test_sampled_rows_draw_by_position(run_async):
 
     alone, beside, greedy = run_async(main())
     assert alone == beside and alone != greedy
-
-
-def test_both_windows_are_named_decode_window():
-    """The block window keeps the name of the window of tokens: the
-    benchmark's ``window_ms_mean`` and ``decode_rows_mean`` find the
-    program by it (``trace.WINDOW_MODULE``). (That block_length 1 lowers
-    to the programs it lowered to before is shown by digests of all six
-    cells' programs against the parent's: CHANGES.md, PR 38.)"""
-    hf = dict(model_type="qwen3_moe", vocab_size=512, hidden_size=64,
-              intermediate_size=128, moe_intermediate_size=32,
-              num_hidden_layers=2, num_attention_heads=4,
-              num_key_value_heads=2, head_dim=16, num_experts=8,
-              num_experts_per_tok=2, norm_topk_prob=True)
-    cfg = ModelConfig.from_hf_config(hf)
-    assert cfg.block_length == 1
-    fn = llama.make_decode_window_fn(cfg, True, 64)
-    assert fn.__name__ == "decode_window"
-    blocky = llama.make_decode_window_fn(tiny(), True, 64)
-    assert blocky.__name__ == "decode_window"

@@ -588,7 +588,7 @@ def _lfm2(one_chip, experts=8):
 @pytest.mark.parametrize("program", ["window", "prefill"])
 def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
                                                        tpu_kernel_path,
-                                                       monkeypatch, program):
+                                                       program):
     """models/lfm2.py at the pool shapes of lfm2-24b-a2b.agent-loop
     ([2, 4096, 4, 64, 128] K and V, [65, 24576] state by slot, [4096,
     24576] snapshots by page): the fused window (B 64, P 64, the cell's
@@ -604,7 +604,6 @@ def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
     kernel's fast form cannot slice 64 lanes (PR 32): hence the packing,
     which the window's kernel here confirms (tpu_custom_call, KV' 4)."""
     lfm2, cfg, params, kv_k, kv_v, state, e = _lfm2(one_chip)
-    monkeypatch.setattr(lfm2, "_use_pallas", lambda: True)
     s = partial(_sds, one_chip)
     P = e["page_buckets"][-1]
     if program == "window":
@@ -767,7 +766,7 @@ def test_scan_kernel_compiles(one_chip, B):
 
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_jamba_programs_write_no_array_of_the_state_pools_size(
-        one_chip, tpu_kernel_path, monkeypatch, program):
+        one_chip, tpu_kernel_path, program):
     """models/jamba.py at the shapes of jamba2-3b.reason-decode (state
     pool [129, 26, 16, 5120] float32 = 1.10 GB): the fused window (B 128,
     4 steps) and decode_step advance the scan state IN the pool through
@@ -779,7 +778,6 @@ def test_jamba_programs_write_no_array_of_the_state_pools_size(
     eight rows and stores them row by row in place: the pool aliases its
     input and is never copied."""
     jamba, cfg, params, kv_k, kv_v, state, e = _jamba(one_chip)
-    monkeypatch.setattr(jamba, "_use_pallas", lambda: True)
     s = partial(_sds, one_chip)
     P, B = e["page_buckets"][-1], e["max_batch"]
     i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
@@ -857,7 +855,7 @@ def test_ssd_step_kernel_compiles(one_chip, B):
 
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_granite_programs_write_no_array_of_the_state_pools_size(
-        one_chip, tpu_kernel_path, monkeypatch, program):
+        one_chip, tpu_kernel_path, program):
     """models/granite.py at the shapes of granite-4.0-h-small.rag-decode
     (matrix-state pool [65, 9, 128, 8192] float32 = 2.28 GiB): the fused
     window (B 64, 4 steps) and decode_step advance the state IN the pool
@@ -867,10 +865,7 @@ def test_granite_programs_write_no_array_of_the_state_pools_size(
     size exists. A prefill chunk (PB 4 x T 512) gathers its four rows and
     stores them row by row in place: the pool aliases its input and is
     never copied. Every program fits beside the 13.1 GiB resident."""
-    from dynamo_tpu.models import jamba
-
     granite, cfg, params, kv_k, kv_v, state, e = _granite(one_chip)
-    monkeypatch.setattr(jamba, "_use_pallas", lambda: True)
     s = partial(_sds, one_chip)
     P, B = e["page_buckets"][-1], e["max_batch"]
     i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)

@@ -63,8 +63,7 @@ def main() -> int:
     from jax.sharding import SingleDeviceSharding
 
     from benchmark.harness import cells, weights
-    from dynamo_tpu.engine.jax_engine import (EngineConfig,
-                                              _make_decode_multi)
+    from dynamo_tpu.engine.jax_engine import EngineConfig
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
     from dynamo_tpu.models.registry import get_model_module
@@ -73,15 +72,10 @@ def main() -> int:
     # a location is the op's name-scope path alone, as in a serving
     # process (runtime/compile_cache.py): no file, no line
     jax.config.update("jax_traceback_in_locations_limit", 0)
-    from dynamo_tpu.models import jamba, lfm2, mla  # noqa: F401
-
     # the chip's arms, as on the chip: the model code asks
-    # jax.default_backend(), which sees the CPU here, through a name
-    # each module imported for itself
-    for name, mod in sys.modules.items():
-        if name.startswith("dynamo_tpu.models.") \
-                and hasattr(mod, "_use_pallas"):
-            mod._use_pallas = lambda: True
+    # jax.default_backend(), which sees the CPU here (every family's
+    # choice of kernel reads llama._use_pallas: llama.kernel_mode)
+    llama._use_pallas = lambda: True
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
@@ -149,10 +143,7 @@ def main() -> int:
         print(json.dumps(row), flush=True)
 
     prefill, decode_step = model.make_step_fns(cfg)
-    if hasattr(model, "make_decode_window_fn"):
-        window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
-    else:
-        window = _make_decode_multi(model, cfg, ecfg.max_top_k)
+    window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
     ps, L = ecfg.page_size, cfg.block_length
     for P in grid["page_buckets"]:
         for T in grid["prefill_lens"]:
