@@ -102,11 +102,29 @@ def engine_overrides(cell: dict) -> dict:
             for k, v in cell["engine"].items()}
 
 
+def context_tokens(cell: dict) -> int:
+    """The longest sequence the cell's engine takes: its largest page
+    bucket in tokens. Engine data that leave ``page_buckets`` or
+    ``page_size`` out run the program's defaults, read from
+    ``EngineConfig`` (only then is the program imported)."""
+    e = cell["engine"]
+    if "page_buckets" not in e or "page_size" not in e:
+        from dynamo_tpu.engine.jax_engine import EngineConfig
+
+        e = {"page_buckets": EngineConfig.page_buckets,
+             "page_size": EngineConfig.page_size, **e}
+    return e["page_buckets"][-1] * e["page_size"]
+
+
+def metrics_in(bench: dict, name: str, kind: str) -> list:
+    """The ``end_to_end`` or ``per_layer`` entries of a loaded
+    BENCHMARK.json that this cell reports: a metric without a
+    ``workloads`` key belongs to every cell."""
+    return [m for m in bench[kind] if name in m.get("workloads", [name])]
+
+
 def metrics_for(name: str, kind: str, root: str = ROOT) -> list:
-    """The ``end_to_end`` or ``per_layer`` entries this cell reports: a
-    metric without a ``workloads`` key belongs to every cell."""
-    return [m for m in load_benchmark(root)[kind]
-            if name in m.get("workloads", [name])]
+    return metrics_in(load_benchmark(root), name, kind)
 
 
 def _module(path: str, name: str):

@@ -49,8 +49,7 @@ def main(argv=None) -> int:
     from jax.sharding import SingleDeviceSharding
 
     from benchmark.harness import cells, weights
-    from dynamo_tpu.engine.jax_engine import (EngineConfig,
-                                              _make_decode_multi)
+    from dynamo_tpu.engine.jax_engine import EngineConfig
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
     from dynamo_tpu.models.registry import get_model_module
@@ -97,11 +96,9 @@ def main(argv=None) -> int:
         print(json.dumps(row), flush=True)
 
     prefill, _ = model.make_step_fns(cfg)
-    # the decode program as JaxEngine.__init__ chooses it
-    if hasattr(model, "make_decode_window_fn"):
-        window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
-    else:
-        window = _make_decode_multi(model, cfg, ecfg.max_top_k)
+    # the decode program as JaxEngine.__init__ makes it: every module of
+    # models/registry.py supplies the window (models/window.py make_window)
+    window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
     ps = ecfg.page_size
     for P in grid["page_buckets"]:
         for T in grid["prefill_lens"]:

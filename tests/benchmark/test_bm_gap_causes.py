@@ -25,19 +25,16 @@ STEP = {1: "dyn.step", 2: "dyn.dispatch_window", 3: "dyn.readback_window",
 LOOP = {11: "dyn.loop.deliver", 12: "dyn.loop.encode_write",
         13: "dyn.loop.engine_loop", 14: "dyn.loop.intake"}
 DETOK = {21: "dyn.detok"}
-NEW = ("loop_thread_busy_share", "emit_to_wire_ms_mean", "intake_ms_mean",
-       "intake_ms_mean.shared-prefix", "step_offcpu_share",
-       "gc_pause_share", "step_gap_stream_share",
-       "idle_host_work_share", "idle_readback_share", "idle_no_work_share",
-       "prefill_device_wait_ms_mean", "prefill_readback_lag_ms_mean",
-       "prefill_device_wait_ms_mean.shared-prefix",
-       "prefill_readback_lag_ms_mean.shared-prefix")
-# cell 6 lists a metric under a name of its own (test_bm_lfm2.py holds
-# every entry that names the cell to that cell alone)
-NEW += tuple(n + ".agent-loop" for n in (
-    "loop_thread_busy_share", "emit_to_wire_ms_mean", "step_offcpu_share",
-    "gc_pause_share", "step_gap_stream_share", "idle_host_work_share",
-    "idle_readback_share", "idle_no_work_share"))
+# every engine has these counters and threads: one entry each, without
+# a ``workloads`` list (until PR 45 cells 6 and 7 listed copies)
+EVERY_CELL = ("loop_thread_busy_share", "emit_to_wire_ms_mean",
+              "step_offcpu_share", "gc_pause_share",
+              "step_gap_stream_share", "idle_host_work_share",
+              "idle_readback_share", "idle_no_work_share")
+# the TTFT side: cell 1, and cell 3 as ``.tpot`` (PERF.md section 3)
+TTFT_SIDE = ("intake_ms_mean", "prefill_device_wait_ms_mean",
+             "prefill_readback_lag_ms_mean")
+NEW = EVERY_CELL + TTFT_SIDE + tuple(n + ".tpot" for n in TTFT_SIDE)
 BUSY_S, WINDOW_S = 970 * US, 2500 * US
 
 
@@ -265,7 +262,7 @@ def test_the_counter_readers_by_hand():
     assert read["loop_thread_busy_share"](raw) == pytest.approx(50.0)
     assert read["emit_to_wire_ms_mean"](raw) == pytest.approx(3.0)
     assert read["intake_ms_mean"](raw) == pytest.approx(2.0)
-    assert read["intake_ms_mean.shared-prefix"](raw) == pytest.approx(2.0)
+    assert read["intake_ms_mean.tpot"](raw) == pytest.approx(2.0)
     # work phases: admit + dispatch_window + process_window + other = 9 s
     # of wall, 6.3 s of CPU
     assert read["step_offcpu_share"](raw) == pytest.approx(30.0)
@@ -306,13 +303,22 @@ def test_a_platform_without_schedstat_or_gc_callbacks_reads_none():
     assert cells.load_reader("step_offcpu_share")(raw) is not None
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_a_new_entry_has_a_reader_and_a_list_of_accepted_cells(name):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    accepted = [w["name"] for w in bench["workloads"]][:6]
+def _listed_in_pr_35():
+    """(entry, cell) as PRs 35 and 33 listed them: the eight in cells
+    1-6, the TTFT side in cell 1 and, as a variant, in cell 3."""
+    accepted = [w["name"] for w in cells.load_benchmark()["workloads"]][:6]
+    return ([(n, c) for n in EVERY_CELL for c in accepted]
+            + [(n, accepted[0]) for n in TTFT_SIDE]
+            + [(n + ".tpot", accepted[2]) for n in TTFT_SIDE])
+
+
+@pytest.mark.parametrize("name, cell", _listed_in_pr_35(),
+                         ids=lambda v: v)
+def test_a_new_entry_has_a_reader_and_a_list_of_accepted_cells(name, cell):
+    bench = cells.load_benchmark()
     entry = next(m for m in bench["per_layer"] if m["name"] == name)
-    assert entry["workloads"] and set(entry["workloads"]) <= set(accepted)
+    assert entry in cells.metrics_in(bench, cell, "per_layer")
+    assert ("workloads" in entry) == (name not in EVERY_CELL)
     assert os.path.isfile(cells.reader_path(name))
     assert entry["source"] == ("device_trace" if "gap_causes" in open(
         cells.reader_path(name)).read() else "program_counter")

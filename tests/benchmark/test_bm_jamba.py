@@ -93,15 +93,31 @@ def test_the_tiny_jamba_cell_end_to_end(jroot):
         "post_warmup_compiles"] == 0
 
 
-def test_the_tiny_jamba_cell_reports_what_the_jamba_cell_reports(jroot):
-    per_layer = {m["name"] for m in cells.metrics_for(CELL, "per_layer",
-                                                      jroot)}
+def benchmark_lists_hold(bench: dict) -> None:
+    """What this file asserts of BENCHMARK.json's lists, of a loaded
+    dict: the repo's file here, a copy with a later configuration
+    appended in test_bm_contract.py. Membership, never a position."""
+    mine = {m["name"] for m in cells.metrics_in(bench, LIKE, "per_layer")}
     assert {"ssm_busy_share", "ssm_scan_busy_share", "ssm_scan_roofline",
             "state_pool_fill_share", "paged_attn_roofline.hybrid",
-            "host_step_busy_share.reason-decode",
-            "warmup_s.reason-decode"} <= per_layer
+            "host_step_busy_share", "warmup_s"} <= mine
     assert not {"paged_attn_roofline", "paged_attn_busy_share",
-                "moe_busy_share"} & per_layer
+                "moe_busy_share"} & mine
+    assert {m["name"] for m in cells.metrics_in(bench, LIKE, "end_to_end")
+            } == {"tpot_p50_ms", "output_tok_s", "setup_s"}
+    # the cell's alone: the scan's two readers and its own roofline
+    for m in bench["per_layer"]:
+        if m["name"] in ("ssm_scan_busy_share", "ssm_scan_roofline",
+                         "paged_attn_roofline.hybrid"):
+            assert m["workloads"] == [LIKE], m["name"]
+
+
+def test_the_tiny_jamba_cell_reports_what_the_jamba_cell_reports(jroot):
+    benchmark_lists_hold(cells.load_benchmark(ROOT))
+    per_layer = {m["name"] for m in cells.metrics_for(CELL, "per_layer",
+                                                      jroot)}
+    assert per_layer == {m["name"] for m in cells.metrics_for(
+        LIKE, "per_layer", ROOT)}
     # a variant without a file of its own is read by its quantity's
     assert cells.reader_path("warmup_s.reason-decode", jroot).endswith(
         os.path.join("metrics", "warmup_s.py"))

@@ -121,25 +121,40 @@ def test_a_traced_run_reads_every_counter_metric_then_is_refused(kroot):
     assert any(n["note"] == "client" and n["failed"] == 0 for n in notes)
 
 
+def benchmark_lists_hold(bench: dict) -> None:
+    """What this file asserts of BENCHMARK.json's lists, of a loaded
+    dict: the repo's file here, a copy with a later configuration
+    appended in test_bm_contract.py. Membership, never a position."""
+    mine = {m["name"] for m in cells.metrics_in(bench, LIKE, "per_layer")}
+    assert {"latent_attn_busy_share", "latent_attn_roofline",
+            "moe_shared_busy_share", "moe_busy_share",
+            "prefix_hit_share.tpot", "ttft_mean_ms.tpot",
+            "warmup_s", "window_ms_mean", "prefill_ms_mean",
+            "decode_rows_mean", "device_idle_share"} <= mine
+    # the base entries of the TTFT side move ttft_mean_ms, which this
+    # cell does not report
+    assert not {"paged_attn_roofline", "paged_attn_busy_share",
+                "ssm_busy_share", "prefix_hit_share",
+                "ttft_p95_ms"} & mine
+    assert {m["name"] for m in cells.metrics_in(bench, LIKE, "end_to_end")
+            } == {"tpot_p50_ms", "setup_s"}
+    for m in bench["per_layer"]:
+        if m["name"] in ("latent_attn_busy_share", "latent_attn_roofline",
+                         "moe_shared_busy_share"):
+            assert m["workloads"] == [LIKE], m["name"]
+
+
 def test_the_tiny_cell_reports_what_the_kanana_cell_reports(kroot):
+    benchmark_lists_hold(cells.load_benchmark(ROOT))
     per_layer = {m["name"] for m in cells.metrics_for(CELL, "per_layer",
                                                       kroot)}
     mine = {m["name"] for m in cells.metrics_for(LIKE, "per_layer", ROOT)}
     assert per_layer == mine
-    assert {"latent_attn_busy_share", "latent_attn_roofline",
-            "moe_shared_busy_share", "moe_busy_share.doc-qa",
-            "prefix_hit_share.doc-qa", "ttft_mean_ms.doc-qa",
-            "warmup_s.doc-qa", "window_ms_mean", "prefill_ms_mean",
-            "decode_rows_mean", "device_idle_share"} <= mine
-    assert not {"paged_attn_roofline", "paged_attn_busy_share",
-                "ssm_busy_share", "moe_busy_share"} & mine
-    assert {m["name"] for m in cells.metrics_for(LIKE, "end_to_end", ROOT)
-            } == {"tpot_p50_ms", "setup_s"}
     # a variant without a file of its own is read by its quantity's
     for name in mine:
         assert os.path.isfile(cells.reader_path(name, ROOT)), name
-    assert cells.reader_path("moe_busy_share.doc-qa", ROOT).endswith(
-        os.path.join("metrics", "moe_busy_share.py"))
+    assert cells.reader_path("prefix_hit_share.tpot", ROOT).endswith(
+        os.path.join("metrics", "prefix_hit_share.py"))
 
 
 # ------------------------------------------- the repo's own cell's files
@@ -199,22 +214,19 @@ def test_the_cell_offers_its_traffic_under_the_knee_and_its_pool_holds_it():
 
 
 def test_doc_qa_lengths_stay_inside_their_limits_and_the_cells_context():
-    """What test_bm_traffic.py asserts of every mix, with this cell's
-    page bucket as the context (that file's 4,096 is the context of the
-    cells of PR 23; conftest.py skips its doc-qa case)."""
+    """What test_bm_traffic.py asserts of every mix (since PR 45 with the
+    context of the cells that run it, as here), and what this mix asks
+    of its documents."""
+    from test_bm_traffic import _lengths_hold
+
     from benchmark.harness import traffic
 
     cell = cells.load_cell(LIKE, ROOT)
-    p, e = cell["traffic_params"], cell["engine"]
-    context = e["page_buckets"][-1] * e["page_size"]
+    p = cell["traffic_params"]
+    assert cells.context_tokens(cell) == 72 * 128
+    _lengths_hold(p, [cell])
     sched = traffic.schedule(p, 50)
     assert len(sched) == round(p["rate_rps"] * 50)
-    for r in sched:
-        assert p["prompt_len"]["min"] <= r["prompt_len"] \
-            <= p["prompt_len"]["max"]
-        assert p["output_len"]["min"] <= r["output_len"] \
-            <= p["output_len"]["max"]
-        assert r["prompt_len"] + r["output_len"] < context
     # every document is asked for, the rarest a few times
     asks = [sum(r["prefix"] == d for r in sched) for d in range(16)]
     assert min(asks) >= 3 and max(asks) > 10 * min(asks) / 2
@@ -342,7 +354,7 @@ def _reader(name):
 def test_the_scope_share_readers_by_hand(traced, monkeypatch):
     for name, want in (("latent_attn_busy_share", 35.0),   # 200 + 50 + 100
                        ("moe_shared_busy_share", 10.0),
-                       ("moe_busy_share.doc-qa", 50.0)):   # shared + routed
+                       ("moe_busy_share", 50.0)):   # shared + routed
         read = _reader(name)
         monkeypatch.setitem(read.__globals__, "__file__", traced)
         assert read(RAW) == pytest.approx(want), name

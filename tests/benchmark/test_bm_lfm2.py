@@ -119,38 +119,47 @@ def test_a_traced_run_reads_every_counter_metric_then_is_refused(lroot):
     assert any(n["note"] == "client" and n["failed"] == 0 for n in notes)
 
 
+OWN = {"conv_busy_share", "state_snapshot_busy_share",
+       "state_restore_share", "paged_attn_roofline.agent-loop"}
+
+
+def benchmark_lists_hold(bench: dict) -> None:
+    """What this file asserts of BENCHMARK.json's lists, of a loaded
+    dict: the repo's file here, a copy with a later configuration
+    appended in test_bm_contract.py. Membership, never a position."""
+    mine = {m["name"] for m in cells.metrics_in(bench, LIKE, "per_layer")}
+    assert OWN | {"paged_attn_busy_share", "moe_busy_share",
+                  "state_pool_fill_share", "prefix_hit_share.tpot",
+                  "ttft_mean_ms.tpot", "warmup_s", "window_ms_mean",
+                  "prefill_ms_mean", "decode_rows_mean",
+                  "device_idle_share"} <= mine
+    assert not {"paged_attn_roofline", "paged_attn_roofline.hybrid",
+                "ssm_busy_share", "prefix_hit_share",
+                "latent_attn_roofline"} & mine
+    assert {m["name"] for m in cells.metrics_in(bench, LIKE, "end_to_end")
+            } == {"tpot_p50_ms", "output_tok_s", "setup_s"}
+    for m in bench["per_layer"]:
+        if LIKE in m.get("workloads", []):
+            assert m["moves"] == "tpot_p50_ms", m["name"]
+        if m["name"] in OWN:
+            # this cell's alone
+            assert m["workloads"] == [LIKE], m["name"]
+    assert LIKE in next(m for m in bench["end_to_end"]
+                        if m["name"] == "output_tok_s")["workloads"]
+
+
 def test_the_tiny_cell_reports_what_the_lfm2_cell_reports(lroot):
+    benchmark_lists_hold(cells.load_benchmark(ROOT))
     per_layer = {m["name"] for m in cells.metrics_for(CELL, "per_layer",
                                                       lroot)}
     mine = {m["name"] for m in cells.metrics_for(LIKE, "per_layer", ROOT)}
     assert per_layer == mine
-    assert {"conv_busy_share", "state_snapshot_busy_share",
-            "state_restore_share", "paged_attn_roofline.agent-loop",
-            "paged_attn_busy_share.agent-loop", "moe_busy_share.agent-loop",
-            "state_pool_fill_share.agent-loop",
-            "prefix_hit_share.agent-loop", "ttft_mean_ms.agent-loop",
-            "warmup_s.agent-loop", "window_ms_mean", "prefill_ms_mean",
-            "decode_rows_mean", "device_idle_share"} <= mine
-    assert not {"paged_attn_roofline", "paged_attn_roofline.hybrid",
-                "ssm_busy_share", "moe_busy_share",
-                "latent_attn_roofline"} & mine
-    assert {m["name"] for m in cells.metrics_for(LIKE, "end_to_end", ROOT)
-            } == {"tpot_p50_ms", "output_tok_s", "setup_s"}
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    for m in bench["per_layer"]:
-        if LIKE in m.get("workloads", []):
-            # every new entry is this cell's alone, and has a reader
-            assert m["workloads"] == [LIKE], m["name"]
-            assert os.path.isfile(cells.reader_path(m["name"], ROOT))
-            assert m["moves"] == ("setup_s" if m["name"].startswith(
-                "warmup_s") else "tpot_p50_ms")
+    for name in mine:
+        assert os.path.isfile(cells.reader_path(name, ROOT)), name
     assert cells.reader_path("paged_attn_roofline.agent-loop", ROOT
                              ).endswith("paged_attn_roofline.agent-loop.py")
     assert cells.reader_path("paged_attn_busy_share.agent-loop", ROOT
                              ).endswith("paged_attn_busy_share.py")
-    assert next(m for m in bench["end_to_end"]
-                if m["name"] == "output_tok_s")["workloads"][-1] == LIKE
 
 
 # ------------------------------------------- the repo's own cell's files

@@ -115,43 +115,48 @@ def test_a_traced_run_reads_every_counter_metric_then_is_refused(sroot):
 
 NEW = {"denoise_forwards_per_token", "commit_busy_share",
        "unmask_busy_share", "block_attn_busy_share", "block_attn_roofline"}
-VARIANTS = {"moe_busy_share", "sampler_busy_share", "host_step_busy_share",
-            "step_gap_ms_mean", "decode_slot_fill_share",
-            "prefill_slot_fill_share", "warmup_s", "loop_thread_busy_share",
-            "emit_to_wire_ms_mean", "step_offcpu_share", "gc_pause_share",
-            "step_gap_stream_share", "idle_host_work_share",
-            "idle_readback_share", "idle_no_work_share", "output_tok_s"}
+# the accepted quantities the cell reports beside them: until PR 45 each
+# listed again as ``<quantity>.diffusion``, now the quantity's one entry
+SHARED = {"moe_busy_share", "sampler_busy_share", "host_step_busy_share",
+          "step_gap_ms_mean", "decode_slot_fill_share",
+          "prefill_slot_fill_share", "warmup_s", "loop_thread_busy_share",
+          "emit_to_wire_ms_mean", "step_offcpu_share", "gc_pause_share",
+          "step_gap_stream_share", "idle_host_work_share",
+          "idle_readback_share", "idle_no_work_share"}
 
 
-def test_the_cell_reports_what_the_issue_lists():
-    mine = {m["name"] for m in cells.metrics_for(LIKE, "per_layer", ROOT)}
-    assert NEW | {q + ".diffusion" for q in VARIANTS} <= mine
+def benchmark_lists_hold(bench: dict) -> None:
+    """What this file asserts of BENCHMARK.json's lists, of a loaded
+    dict: the repo's file here, a copy with a later configuration
+    appended in test_bm_contract.py. Membership, never a position."""
+    mine = {m["name"] for m in cells.metrics_in(bench, LIKE, "per_layer")}
+    assert NEW | SHARED | {"output_tok_s.tpot"} <= mine
     # the accepted metrics without a workloads list: every cell's
     assert {"window_ms_mean", "decode_rows_mean", "prefill_ms_mean",
             "device_idle_share", "kv_pool_fill_share",
             "chunk_gap_p99_ms"} <= mine
     assert not {"paged_attn_roofline", "paged_attn_busy_share",
-                "moe_busy_share", "ssm_busy_share"} & mine
-    assert {m["name"] for m in cells.metrics_for(LIKE, "end_to_end", ROOT)
+                "ssm_busy_share"} & mine
+    assert {m["name"] for m in cells.metrics_in(bench, LIKE, "end_to_end")
             } == {"tpot_p50_ms", "setup_s"}
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
     for m in bench["per_layer"]:
-        if LIKE in m.get("workloads", []):
-            # every new entry is this cell's alone, and has a reader
+        if m["name"] in NEW:
+            # this cell's alone
             assert m["workloads"] == [LIKE], m["name"]
-            assert os.path.isfile(cells.reader_path(m["name"], ROOT))
-            assert m["moves"] == ("setup_s" if m["name"].startswith(
-                "warmup_s") else "tpot_p50_ms")
-    for q in VARIANTS:
-        assert cells.reader_path(q + ".diffusion", ROOT).endswith(q + ".py")
+            assert m["moves"] == "tpot_p50_ms"
     # the rate is a per-layer line of the traced run
-    # (`output_tok_s.diffusion`, read by metrics/output_tok_s.py), not
-    # the end-to-end entry: test_bm_lfm2.py holds that entry's
-    # `workloads` to END with the LFM2 cell, and neither that file nor
-    # the list's order is a model_config PR's to change
+    # (`output_tok_s.tpot`, read by metrics/output_tok_s.py), not the
+    # end-to-end entry: a new bounded metric in a cell is a re-rating
     assert LIKE not in next(m for m in bench["end_to_end"]
                             if m["name"] == "output_tok_s")["workloads"]
+
+
+def test_the_cell_reports_what_the_issue_lists():
+    benchmark_lists_hold(cells.load_benchmark(ROOT))
+    for m in cells.metrics_for(LIKE, "per_layer", ROOT):
+        assert os.path.isfile(cells.reader_path(m["name"], ROOT))
+    assert cells.reader_path("output_tok_s.tpot", ROOT).endswith(
+        "output_tok_s.py")
 
 
 # ------------------------------------------- the repo's own cell's files
@@ -427,10 +432,9 @@ def test_the_scope_share_readers_by_hand(traced, monkeypatch, name, want):
 def test_the_shared_scope_readers_see_through_the_new_scopes(traced,
                                                              monkeypatch):
     """``moe`` and ``sample`` nest under the new scopes and inside the
-    block's loop; the accepted readers that the ``.diffusion`` variants
-    resolve to find them there."""
-    for name, want in (("moe_busy_share.diffusion", 60.0),
-                       ("sampler_busy_share.diffusion", 15.0)):
+    block's loop; the accepted readers find them there."""
+    for name, want in (("moe_busy_share", 60.0),
+                       ("sampler_busy_share", 15.0)):
         read = _reader(name)
         monkeypatch.setitem(read.__globals__, "__file__", traced)
         assert read(RAW) == pytest.approx(want), name

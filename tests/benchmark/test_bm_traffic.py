@@ -6,9 +6,9 @@ import os
 
 import pytest
 
-from bm_paths import BENCH
+from bm_paths import BENCH, FIXTURES
 
-from benchmark.harness import traffic
+from benchmark.harness import cells, traffic
 
 MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(BENCH, "traffic"))
                if f.endswith(".json"))
@@ -36,15 +36,51 @@ def test_same_seed_same_requests_other_seed_other_texts(mix):
         [len(m["content"]) for m in ms] for ms in texts(7)]
 
 
-@pytest.mark.parametrize("mix", MIXES)
-def test_lengths_stay_inside_their_limits_and_the_context(mix):
-    p = _params(mix)
+def _lengths_hold(p, running):
+    """Every request of the mix inside its own limits and inside the
+    context of every cell that runs it (``cells.context_tokens``: the
+    cell's largest page bucket in tokens). A mix no cell runs has no
+    context to be held to, and fails."""
+    assert running, "no cell runs this mix"
+    context = min(cells.context_tokens(cell) for cell in running)
     for r in traffic.schedule(p, 50):
         assert p["prompt_len"]["min"] <= r["prompt_len"] \
             <= p["prompt_len"]["max"]
         assert p["output_len"]["min"] <= r["output_len"] \
             <= p["output_len"]["max"]
-        assert r["prompt_len"] + r["output_len"] < 4096
+        assert r["prompt_len"] + r["output_len"] < context
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_lengths_stay_inside_their_limits_and_the_context(mix):
+    running = [cells.load_cell(w["name"])
+               for w in cells.load_benchmark()["workloads"]
+               if w["traffic"] == mix]
+    _lengths_hold(_params(mix), running)
+
+
+# prompts of 6k-12k and outputs of 512-1,536 (ISSUE 45: the mix of a
+# window-attention cell): 13,824 tokens at the most
+LONG = os.path.join(FIXTURES, "long-decode.json")
+HOLDS = {"engine": {"page_size": 64, "page_buckets": [64, 217]}}   # 13,888
+SHORT = {"engine": {"page_buckets": [64]}}      # the program's page of 64
+
+
+@pytest.mark.parametrize("running, said", [
+    ([HOLDS], None), ([HOLDS, SHORT], "< 4096"), ([SHORT], "< 4096"),
+    ([], "no cell runs this mix")],
+    ids=["holds", "one-of-two-does-not", "does-not", "no-cell"])
+def test_a_long_mix_is_held_to_the_cells_that_run_it(running, said):
+    with open(LONG) as f:
+        p = json.load(f)
+    longest = max(r["prompt_len"] + r["output_len"]
+                  for r in traffic.schedule(p, 50))
+    assert 12288 < longest <= 13824
+    if said is None:
+        _lengths_hold(p, running)
+    else:
+        with pytest.raises(AssertionError, match=said):
+            _lengths_hold(p, running)
 
 
 @pytest.mark.parametrize("mix", [m for m in MIXES
