@@ -39,6 +39,7 @@ from typing import AsyncIterator, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..llm.protocols.common import (FINISH_CANCELLED, FINISH_EOS,
                                     FINISH_LENGTH, FINISH_TIMEOUT,
@@ -52,6 +53,7 @@ from ..runtime.config import env_bool, env_int, env_str
 from ..runtime.engine import Context
 from .jit_fence import CompileFence
 from .kv_manager import (BLOCK_GENERATION_REFUSAL, RECURRENT_STATE_REFUSAL,
+                         WINDOW_POOL_REFUSAL, WindowPagePool,
                          ChainHashCache, PageManager)
 from .profiler import EngineProfiler, memory_snapshot
 from .sampling import (SamplingBatch, logprob_aux, sample_tokens,
@@ -209,6 +211,11 @@ class EngineConfig:
     prefill_buckets: Tuple[int, ...] = (16, 64, 512)
     page_buckets: Tuple[int, ...] = (8, 64)
     watermark_pages: int = 4  # keep-free headroom before admitting
+    # pages of the window layers' K/V pool, for a model whose kinds of
+    # layer keep a pool each (ModelConfig.kv_pool_by_kind; num_pages is
+    # then the pool of the layers that see the whole context). 0: every
+    # row of max_batch at the most it can hold (the table's slots)
+    window_pages: int = 0
 
     def __post_init__(self) -> None:
         if self.prefill_chunk % self.page_size != 0:
@@ -404,6 +411,12 @@ class Sequence:
     # cfg.block_length of the model that serves it: above 1 (generation
     # by diffusion over blocks) only whole blocks have K/V in the cache
     block: int = 1
+    # the row's pages of the window layers' pool (a model with a pool a
+    # kind of layer): logical pages wfirst, wfirst + 1, ...; the pages
+    # before wfirst were given back. wreserved: its reservation there.
+    wpages: List[int] = field(default_factory=list)
+    wfirst: int = 0
+    wreserved: int = 0
 
     @property
     def stop_set(self) -> frozenset:
@@ -560,6 +573,31 @@ class JaxEngine:
                     self._state_snapshots = True
                     self.state = (*self.state, model.init_state_snapshots(
                         model_cfg, spec, dtype))
+            # the window layers' pools and their books, for a model whose
+            # kinds of layer keep a pool each: (K, V) [L_win, pages_w,
+            # ...], donated through every program behind the full layers'
+            # pools and returned last, as a state pool is. None otherwise.
+            self.wkv = None
+            self.wpm: Optional[WindowPagePool] = None
+            if model_cfg.kv_pool_by_kind:
+                _refuse_window_pools(self.ecfg, mesh)
+                slots = model.window_table_slots(
+                    model_cfg, self.ecfg.page_size,
+                    max(self.ecfg.prefill_chunk,
+                        2 * self.ecfg.decode_steps + 1))
+                pages_w = (self.ecfg.window_pages
+                           or self.ecfg.max_batch * slots + 1)
+                self.wpm = WindowPagePool(pages_w, self.ecfg.page_size,
+                                          model_cfg.sliding_window, slots)
+                self.wkv = model.init_window_kv_cache(
+                    model_cfg, KVCacheSpec(pages_w, self.ecfg.page_size),
+                    dtype)
+            # the window pool's fill and the rows past the window, summed
+            # at every decode dispatch (_count_decode_slots)
+            self.kv_window_pages_held_total = 0
+            self.kv_window_pages_seen_total = 0
+            self.decode_row_steps_total = 0
+            self.decode_row_steps_past_window_total = 0
         if mesh is not None:
             from ..parallel.mesh import shard_kv_cache, shard_params
             self.params = shard_params(self.params, model_cfg, mesh)
@@ -636,8 +674,11 @@ class JaxEngine:
                               evict_policy=self.ecfg.evict_policy,
                               # a hit hands over pages, and state only
                               # where the module snapshots it by the page
-                              prefix_reuse=(self.state is None
-                                            or self._state_snapshots))
+                              # ... and never where the window layers
+                              # have given the hit's pages back
+                              prefix_reuse=((self.state is None
+                                             or self._state_snapshots)
+                                            and self.wkv is None))
         # host-DRAM offload pools (same per-page layout as the HBM pool)
         self.host_k = self.host_v = None
         self.host_k_s = self.host_v_s = None
@@ -824,6 +865,10 @@ class JaxEngine:
         recurrent state: the pools and the rows' slots, and for a prefill
         of a module that snapshots by the page (``src`` given) the page
         whose snapshot each row starts from (-1: none). None otherwise."""
+        if self.wkv is not None:
+            # the window layers' pools and the rows' tables into them
+            # (_window_tables): the same two places
+            return (self.wkv, slots)
         if self.state is None:
             return ()
         if src is None or not self._state_snapshots:
@@ -837,16 +882,62 @@ class JaxEngine:
     def _take_state(self, out):
         """A step program's results without the state pool it returned
         last (kept as the engine's), for a model with recurrent state."""
+        if self.wkv is not None:
+            *out, self.wkv = out
+            return out
         if self.state is None:
             return out
         *out, self.state = out
         return out
 
-    def _drop_slots(self, n: int) -> np.ndarray:
+    def _drop_slots(self, n: int, T: Optional[int] = None,
+                    paged: bool = False):
         """Slot operand of n rows that all read and write the drop slot
-        (None where the model keeps no state)."""
+        (None where the model keeps no state); for a model with window
+        pools, the tables of n padding rows (``T``, ``paged``:
+        _window_tables)."""
+        if self.wkv is not None:
+            return self._window_tables([], n, T, paged)
         return (None if self.state is None
                 else np.full(n, self.ecfg.max_batch, np.int32))
+
+    def _window_tables(self, rows, B: int, T: Optional[int] = None,
+                       paged: bool = False):
+        """The rows' operand into the window layers' pool (None for a
+        model without one): (table [B, S_w], base [B]) for a decode
+        window, with ``T`` also the pool's write slots of a prefill chunk
+        or a single step, flat [B, T] or with ``paged`` by the page
+        [B, T // ps]. ``rows``: (sequence, first position written, tokens
+        written) a live row; the others are padding that reads page 0 and
+        writes nothing. Slot s of a row's table is its logical page
+        ``wfirst + s``, so positions count from ``base = wfirst * ps``."""
+        if self.wkv is None:
+            return None
+        wpm, ps = self.wpm, self.ecfg.page_size
+        table = np.zeros((B, wpm.table_slots), np.int32)
+        base = np.zeros(B, np.int32)
+        slots = None
+        if T is not None:
+            slots = (np.full((B, max(T // ps, 1)), wpm.num_pages, np.int32)
+                     if paged else np.full((B, T), DROP_SLOT, np.int32))
+        for i, (seq, start, n) in enumerate(rows):
+            assert len(seq.wpages) <= wpm.table_slots, (
+                len(seq.wpages), wpm.table_slots)
+            table[i, :len(seq.wpages)] = seq.wpages
+            base[i] = seq.wfirst * ps
+            if slots is None:
+                continue
+            held = np.fromiter(seq.wpages, np.int64, len(seq.wpages))
+            if paged:
+                first = start // ps - seq.wfirst
+                npg = (n + ps - 1) // ps
+                slots[i, :npg] = held[first:first + npg]
+            else:
+                pos = np.arange(start, start + n)
+                slots[i, :n] = held[pos // ps - seq.wfirst] * ps + pos % ps
+        if slots is None:
+            return table, base
+        return table, base, slots
 
     def _blank_tokens(self, n: int) -> np.ndarray:
         """The window's token operand of n rows that carry nothing: one
@@ -864,9 +955,11 @@ class JaxEngine:
         that counts nothing."""
         res = unpack_window(
             out, topn, counts=self.block > 1 or bool(self.window_counts),
-            state=self.state is not None)
+            state=self.state is not None or self.wkv is not None)
         self.kv_k, self.kv_v = res.kv_k, res.kv_v
-        if res.state is not None:
+        if self.wkv is not None:
+            self.wkv = res.state
+        elif res.state is not None:
             self.state = res.state
         return res._replace(kv_k=None, kv_v=None, state=None)
 
@@ -923,8 +1016,9 @@ class JaxEngine:
                             jnp.zeros((PB, P), jnp.int32),
                             jnp.full((PB, T), DROP_SLOT, jnp.int32),
                             jnp.zeros((PB,), jnp.int32), pslots,
-                            *self._state_args(self._drop_slots(PB),
-                                              self._no_src(PB))))
+                            *self._state_args(
+                                self._drop_slots(PB, T, pslots is not None),
+                                self._no_src(PB))))
                     n += 1
                     if self.block > 1:
                         # prefill samples nothing and its program has no
@@ -1030,7 +1124,7 @@ class JaxEngine:
                             jnp.zeros(B, jnp.int32) - 1, self.kv_k,
                             self.kv_v, tableB,
                             jnp.full((B,), DROP_SLOT, jnp.int32),
-                            *self._state_args(self._drop_slots(B))))
+                            *self._state_args(self._drop_slots(B, 1))))
                     toks = sample_tokens(
                         logits, jnp.zeros(B),
                         jnp.zeros(B, jnp.int32),
@@ -1210,7 +1304,8 @@ class JaxEngine:
             out = jax.block_until_ready(self.prefill_fn(
                 self.params, tokens, positions, self.kv_k, self.kv_v,
                 table, slots, last_idx, pslots,
-                *self._state_args(self._drop_slots(PB), self._no_src(PB))))
+                *self._state_args(self._drop_slots(PB, T, pslots is not None),
+                                  self._no_src(PB))))
             ms = (time.perf_counter() - t0) * 1e3
             _logits, self.kv_k, self.kv_v = self._take_state(out)
             return ms
@@ -1371,6 +1466,7 @@ class JaxEngine:
             "kv_active_blocks": self.pm.active,
             "kv_total_blocks": self.ecfg.num_pages - 1,
             **self._state_stats(),
+            **self._window_pool_stats(),
             **self.window_counts,
             **self._diffusion_stats(),
             "num_requests_waiting": len(self.waiting),
@@ -1457,6 +1553,50 @@ class JaxEngine:
                 "state_restores_total": self.state_restores_total,
                 # the pool by slot and, where there is one, by page
                 "state_pool_bytes": int(sum(x.nbytes for x in self.state))}
+
+    def _window_pool_stats(self) -> dict:
+        """stats() of the window layers' pool (none for a model with one
+        pool): its pages now, the fill summed at every decode dispatch
+        (held / seen), pages handed to rows and pages given back while
+        the row ran, and the decode row-steps past the window. The
+        ``kv_*_blocks`` keys above stay the full layers' pool."""
+        if self.wpm is None:
+            return {}
+        wpm = self.wpm
+        return {
+            "kv_window_total_blocks": wpm.capacity,
+            "kv_window_active_blocks": wpm.held,
+            "kv_window_reserved_blocks": wpm.reserved,
+            "kv_window_table_slots": wpm.table_slots,
+            "kv_window_pages_held_total": self.kv_window_pages_held_total,
+            "kv_window_pages_seen_total": self.kv_window_pages_seen_total,
+            "kv_window_pages_allocated_total": wpm.allocated_total,
+            "kv_window_pages_released_total": wpm.released_total,
+            "decode_row_steps_total": self.decode_row_steps_total,
+            "decode_row_steps_past_window_total":
+                self.decode_row_steps_past_window_total}
+
+    def _reserve_window(self, seq: Sequence) -> bool:
+        """Reserve, at admission, the most window-pool pages the row will
+        hold at once over its life: its tokens at their most (prompt,
+        budget, the lookahead a decode dispatch covers), up to the
+        table's slots. False: the pool is spoken for."""
+        most = min(seq.num_prompt + seq.max_new()
+                   + 2 * self.ecfg.decode_steps, self.cap_tokens)
+        need = self.wpm.peak(max(most, len(seq.tokens) + 1))
+        if not self.wpm.reserve(need):
+            return False
+        seq.wreserved = need
+        return True
+
+    def _give_back(self, rows) -> None:
+        """Give back each row's window-pool pages that no query at its
+        position or later can see. ``rows``: (sequence, the position of
+        its next query). On the step thread, under ``dyn.kv.release`` in
+        the trace (inside the dispatch phase that called it)."""
+        with TraceAnnotation("dyn.kv.release"):
+            for seq, pos in rows:
+                seq.wfirst = self.wpm.give_back(seq.wpages, seq.wfirst, pos)
 
     def _diffusion_stats(self) -> dict:
         """stats() of a model that generates by diffusion over blocks
@@ -1715,6 +1855,8 @@ class JaxEngine:
                 # every state slot is held (a finished row keeps its slot
                 # until no window in flight lists it); wait for frees
                 break
+            if self.wpm is not None and not self._reserve_window(seq):
+                break  # the window layers' pool is spoken for; wait
             chain = self._chain(seq)
             if self._state_snapshots:
                 # a hit must leave a token to prefill: the state comes
@@ -1729,6 +1871,9 @@ class JaxEngine:
                         or self.pm.available < self.ecfg.watermark_pages):
                     if alloc is not None:
                         self.pm.release_sequence(alloc[0])
+                    if self.wpm is not None:
+                        self.wpm.unreserve(seq.wreserved)
+                        seq.wreserved = 0
                     break  # out of pages; wait for frees
                 if alloc.restores:
                     # gate this sequence out of prefill until its
@@ -2043,10 +2188,20 @@ class JaxEngine:
                      and all(s.computed % ps == 0 for s in batch))
         slots = np.full((B, T), DROP_SLOT, np.int32)
         pslots = np.full((B, max(T // ps, 1)), self.ecfg.num_pages, np.int32)
-        sslots = self._drop_slots(B)
+        if self.wkv is not None:
+            # the window layers' pages: give back what the chunk's first
+            # query no longer sees, then cover the chunk
+            self._give_back([(s, s.computed) for s in batch])
+            for seq, chunk in zip(batch, chunks):
+                self.wpm.cover(seq.wpages, seq.wfirst, seq.computed + chunk)
+            sslots = self._window_tables(
+                [(s, s.computed, c) for s, c in zip(batch, chunks)], B, T,
+                use_paged)
+        else:
+            sslots = self._drop_slots(B)
         ssrc = self._no_src(B)
         for i, (seq, chunk) in enumerate(zip(batch, chunks)):
-            if sslots is not None:
+            if self.state is not None:
                 sslots[i] = seq.state_slot
             start = seq.computed
             if seq.state_from_page:
@@ -2259,6 +2414,15 @@ class JaxEngine:
                 self.waiting.insert(0, victim)
                 if victim is seq:
                     break
+        if self.wpm is not None:
+            # the window layers' pages: what no later query sees goes
+            # back, then the same lookahead is covered, inside each row's
+            # reservation (made at admission for its whole life)
+            self._give_back([(s, len(s.tokens) - 1) for s in batch])
+            for seq in batch:
+                self.wpm.cover(seq.wpages, seq.wfirst,
+                               min(len(seq.tokens) + lookahead,
+                                   self.cap_tokens))
         # drain tier ops queued by grow-evictions NOW, before this step's
         # forward dispatch: the evicted page's new owner writes it in the
         # program we're about to enqueue, and a drain on the NEXT step
@@ -2290,9 +2454,11 @@ class JaxEngine:
         positions = np.full(B, -1, np.int32)
         table = np.zeros((B, P), np.int32)
         slots = np.full(B, DROP_SLOT, np.int32)
-        sslots = self._drop_slots(B)
+        sslots = self._drop_slots(B) if self.wkv is None else \
+            self._window_tables([(s, len(s.tokens) - 1, 1) for s in batch],
+                                B, 1)
         for i, seq in enumerate(batch):
-            if sslots is not None:
+            if self.state is not None:
                 sslots[i] = seq.state_slot
             pos = len(seq.tokens) - 1  # position of last_token
             tokens[i] = seq.last_token
@@ -2538,7 +2704,8 @@ class JaxEngine:
         # objects themselves (identity compare), so no stale hit is
         # possible. NOTE: a hit also freezes the build-time random seeds
         # of UNSEEDED sampled rows for the cached span.
-        key = (B, P, list(batch), [len(s.pages) for s in batch])
+        key = (B, P, list(batch), [len(s.pages) for s in batch],
+               [(s.wfirst, len(s.wpages)) for s in batch])
         cached = self._samp_cache
         if cached is not None and cached[0] == key:
             sb, (d_table, d_temp, d_topk, d_topp, d_seeds,
@@ -2546,9 +2713,10 @@ class JaxEngine:
         else:
             table = np.zeros((B, P), np.int32)
             eos = np.full((B, E), -1, np.int32)
-            sslots = self._drop_slots(B)
+            sslots = self._drop_slots(B) if self.wkv is None else \
+                self._window_tables([(s, 0, 0) for s in batch], B)
             for i, seq in enumerate(batch):
-                if sslots is not None:
+                if self.state is not None:
                     # fixed from admission to release, and a release
                     # changes the batch: safe under the key above
                     sslots[i] = seq.state_slot
@@ -2565,7 +2733,8 @@ class JaxEngine:
             d_topk = jnp.asarray(sb.top_k)
             d_topp = jnp.asarray(sb.top_p)
             d_seeds = jnp.asarray(sb.seeds)
-            d_sslots = None if sslots is None else jnp.asarray(sslots)
+            d_sslots = (None if sslots is None else
+                        jax.tree_util.tree_map(jnp.asarray, sslots))
             self._samp_cache = (key, sb, (d_table, d_temp, d_topk, d_topp,
                                           d_seeds, d_eos, d_sslots))
         from_carry = np.zeros(B, bool)
@@ -2701,6 +2870,15 @@ class JaxEngine:
             self.state_slots_held_total += (self.ecfg.max_batch
                                             - len(self._state_free))
             self.state_slots_seen_total += self.ecfg.max_batch
+        if self.wpm is not None:
+            self.kv_window_pages_held_total += self.wpm.held
+            self.kv_window_pages_seen_total += self.wpm.capacity
+            self.decode_row_steps_total += len(batch) * K
+            # row-steps whose query has positions behind its window (the
+            # host's view of the row at dispatch: the device may be a
+            # window ahead, never behind)
+            self.decode_row_steps_past_window_total += K * sum(
+                len(s.tokens) > self.wpm.window for s in batch)
 
     def _device_stops_complete(self, seq: Sequence) -> bool:
         """True when the row's full stop-id set fit the on-device stop
@@ -2966,6 +3144,11 @@ class JaxEngine:
         if seq.pages:
             self.pm.release_sequence(seq.pages)
             seq.pages = []
+        if self.wpm is not None:
+            # both pools, under the same rule as the state slot below
+            self.wpm.release(seq.wpages)
+            self.wpm.unreserve(seq.wreserved)
+            seq.wfirst = seq.wreserved = 0
         if seq.state_slot is not None:
             # reached only once no window in flight lists the row
             # (_release_or_defer; preemption flushes first), so the next
@@ -3373,6 +3556,32 @@ def _refuse_recurrent_state(ecfg: EngineConfig, mesh) -> None:
             what="a mesh of more than one device",
             why="no sharding rule places the state pools or the leaves "
             "of the layers that keep state"))
+
+
+def _refuse_window_pools(ecfg: EngineConfig, mesh) -> None:
+    """What JaxEngine itself refuses a model whose window layers keep a
+    pool of their own, at construction. (A prefix hit cannot happen: the
+    page manager of such an engine publishes and matches nothing.)"""
+    if ecfg.host_pages > 0:
+        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
+            what="the host KV tier (host_pages > 0, with or without "
+            "kv_compress)",
+            why="a page restored from the host is a page of the full "
+            "layers' pool alone"))
+    if ecfg.spec_decode:
+        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
+            what="spec_decode",
+            why="the verify forward writes drafted tokens' K/V through "
+            "one page table"))
+    if ecfg.long_prefill_threshold is not None:
+        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
+            what="long_prefill_threshold (ring-attention prefill)",
+            why="the ring scatters a prompt's K/V into one pool"))
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
+            what="a mesh of more than one device",
+            why="no sharding rule places the window layers' pools, and "
+            "the shard_map wrappers take one table a row"))
 
 
 def _refuse_block_generation(cfg: ModelConfig, ecfg: EngineConfig,

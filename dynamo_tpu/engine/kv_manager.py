@@ -60,6 +60,15 @@ BLOCK_GENERATION_REFUSAL = (
     "(ROADMAP B10)")
 
 
+WINDOW_POOL_REFUSAL = (
+    "{what} is not supported for a model whose window layers keep a K/V "
+    "pool of their own (kv_pool_by_kind: models/llama.py "
+    "_forward_by_kind, WindowPagePool): {why}; the window layers' pages "
+    "behind a row's window are given back while the row runs, and only "
+    "JaxEngine's own steps on one device keep the books of both pools "
+    "(ROADMAP B6)")
+
+
 def refuse_recurrent_state(engine, what: str) -> None:
     """Raise where ``engine`` serves a model that keeps recurrent state
     beside its KV pages: the paths that move KV pages between places
@@ -77,6 +86,91 @@ def refuse_recurrent_state(engine, what: str) -> None:
             what=what, why="it hands a sequence over as its pages and "
             "the first token that prefill sampled, and here prefill "
             "samples none and the pages hold whole blocks only"))
+    if getattr(engine, "wkv", None) is not None:
+        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
+            what=what, why="it moves a sequence as the pages of one pool, "
+            "and the window layers' pages of the same positions are in "
+            "another pool or already given back"))
+
+
+class WindowPagePool:
+    """Host-side books of the window layers' K/V pool: a free list of its
+    own pages, and what each row holds of them.
+
+    A row's pages are ``held`` (page ids of logical pages ``first``,
+    ``first + 1``, ... of the row: logical page p holds positions
+    ``[p * page_size, (p + 1) * page_size)``). ``cover`` appends pages up
+    to a position the next program writes, ``give_back`` takes away the
+    pages that lie wholly at or before ``pos - window`` (no query at
+    ``pos`` or later sees them), so a row never holds more than
+    ``table_slots`` pages and its table into the pool has that many
+    slots, whatever its context.
+
+    A row is admitted with a reservation of the most pages it will hold
+    at once (``reserve``), counted against the pool's size: an admitted
+    row's ``cover`` then always finds a page, no row waits for another's
+    pages, and the pool being full defers admission and nothing else.
+    Nothing is shared or published: a page belongs to one row from
+    ``cover`` to ``give_back`` / ``release``."""
+
+    def __init__(self, num_pages: int, page_size: int, window: int,
+                 table_slots: int):
+        self.num_pages = num_pages
+        self.page_size = page_size
+        self.window = window
+        self.table_slots = table_slots
+        # page 0 is the padding target of device tables, never handed out
+        self.free: deque = deque(range(1, num_pages))  # guarded-by: loop
+        self.reserved = 0  # guarded-by: loop
+        self.allocated_total = 0  # guarded-by: loop
+        self.released_total = 0  # guarded-by: loop
+
+    @property
+    def capacity(self) -> int:
+        return self.num_pages - 1
+
+    @property
+    def held(self) -> int:
+        return self.capacity - len(self.free)
+
+    def peak(self, tokens: int) -> int:
+        """The most pages a row holds at once while it has at most
+        ``tokens`` tokens: all of them, up to the table's slots."""
+        return min(-(-tokens // self.page_size), self.table_slots)
+
+    def reserve(self, pages: int) -> bool:
+        if self.reserved + pages > self.capacity:
+            return False
+        self.reserved += pages
+        return True
+
+    def unreserve(self, pages: int) -> None:
+        self.reserved -= pages
+        assert self.reserved >= 0, "window pool reservation underflow"
+
+    def cover(self, held: List[int], first: int, upto: int) -> None:
+        """Append pages to ``held`` until the row's pages reach position
+        ``upto`` - 1. The caller holds a reservation that covers it."""
+        while (first + len(held)) * self.page_size < upto:
+            held.append(self.free.popleft())
+            self.allocated_total += 1
+
+    def give_back(self, held: List[int], first: int, pos: int) -> int:
+        """Free the pages of ``held`` that lie wholly at or before
+        ``pos - window``: those no query at ``pos`` or later can see.
+        Returns the row's new first logical page."""
+        keep = max((pos - self.window + 1) // self.page_size, first)
+        n = min(keep - first, len(held))
+        if n > 0:
+            self.free.extend(held[:n])
+            del held[:n]
+            self.released_total += n
+        return first + max(n, 0)
+
+    def release(self, held: List[int]) -> None:
+        """A row's end (or its preemption): every page it holds."""
+        self.free.extend(held)
+        held.clear()
 
 
 def hash_block(parent: int, tokens: Sequence[int]) -> int:

@@ -104,6 +104,17 @@ class ForwardPassMetrics:
     loop_lag_p50_seconds: float = 0.0
     loop_lag_p99_seconds: float = 0.0
     batch_dispatches_total: int = 0
+    # the window layers' K/V pool of a model with a pool a kind of layer
+    # (engine/kv_manager.py WindowPagePool; all 0 for any other model):
+    # its fill summed at every decode dispatch (held / seen), pages
+    # handed to rows and pages given back while the row ran, and the
+    # decode row-steps whose query had positions behind its window
+    kv_window_pages_held_total: int = 0
+    kv_window_pages_seen_total: int = 0
+    kv_window_pages_allocated_total: int = 0
+    kv_window_pages_released_total: int = 0
+    decode_row_steps_total: int = 0
+    decode_row_steps_past_window_total: int = 0
 
     def to_dict(self) -> dict:
         return dict(self.__dict__)

@@ -335,6 +335,26 @@ class MetricsAggregator:
             ("dyn_engine_long_prefills_total",
              "sequence-parallel ring prefills served",
              lambda m: m.long_prefills_total),
+            # the window layers' K/V pool (a model with a pool a kind of
+            # layer; 0 for any other)
+            ("dyn_engine_kv_window_pages_held_total",
+             "window-pool pages held, summed at every decode dispatch",
+             lambda m: m.kv_window_pages_held_total),
+            ("dyn_engine_kv_window_pages_seen_total",
+             "window-pool pages there, summed at every decode dispatch",
+             lambda m: m.kv_window_pages_seen_total),
+            ("dyn_engine_kv_window_pages_allocated_total",
+             "window-pool pages handed to rows",
+             lambda m: m.kv_window_pages_allocated_total),
+            ("dyn_engine_kv_window_pages_released_total",
+             "window-pool pages given back while their row ran",
+             lambda m: m.kv_window_pages_released_total),
+            ("dyn_engine_decode_row_steps_total",
+             "decode row-steps dispatched (window-pool models)",
+             lambda m: m.decode_row_steps_total),
+            ("dyn_engine_decode_row_steps_past_window_total",
+             "decode row-steps with positions behind their window",
+             lambda m: m.decode_row_steps_past_window_total),
         ]
         for name, help_, get in per_worker:
             rows = [

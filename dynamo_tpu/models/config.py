@@ -71,7 +71,7 @@ class ModelConfig:
     # Gemma-2 final-logit softcap
     embed_scale: bool = False      # multiply embeddings by sqrt(hidden)
     norm_unit_offset: bool = False  # rms_norm weight is (1 + w)
-    hidden_act: str = "silu"       # "silu" | "gelu_tanh"
+    hidden_act: str = "silu"       # "silu" | "gelu_tanh" | "relu"
     query_pre_attn_scalar: Optional[float] = None  # attn scale override
     final_logit_softcap: Optional[float] = None
     # Gemma-2 only: sandwich norms (post-attention + pre/post-feedforward
@@ -80,6 +80,26 @@ class ModelConfig:
     sandwich_norms: bool = False
     attn_logit_softcap: Optional[float] = None
     sliding_window: Optional[int] = None
+    # What each layer's attention may see and whether it rotates q and k,
+    # a layer at a time: layer_window[l] is the window of layer l (position
+    # j is visible from t iff t - window < j <= t) or None where the layer
+    # sees every earlier position; layer_rope[l] is False where the layer
+    # applies no positional embedding. () = every layer sees everything
+    # and rotates. Gemma-2's even-layer rule is one such layout, filled
+    # from sliding_window where none is given (__post_init__); model_type
+    # "smallthinker" states both (sliding_window_layout, rope_layout).
+    layer_window: tuple = ()
+    layer_rope: tuple = ()
+    # SmallThinker: layers of a kind share a K/V pool of their own
+    # (models/llama.py init_kv_cache, engine/kv_manager.py WindowPagePool):
+    # the window layers' pool keeps only the pages a row can still see.
+    # Set by the configuration's family, not a switch: Gemma-2 keeps one
+    # pool under a mask (its prefix cache, host tier and mesh are built
+    # on one pool).
+    kv_pool_by_kind: bool = False
+    # SmallThinker: the router reads the layer's un-normed input, before
+    # attention (its logits are made at the layer's entry)
+    moe_early_router: bool = False
     # Jamba (model_type "jamba", models/jamba.py): Mamba-1 mixers in
     # every layer but those at attn_layer_offset + k * attn_layer_period,
     # which attend (no positional embedding of any kind). mamba_d_state
@@ -147,9 +167,33 @@ class ModelConfig:
     confidence_threshold: float = 0.9
     dtype: str = "bfloat16"
 
+    def __post_init__(self) -> None:
+        if self.sliding_window is not None and not self.layer_window:
+            # Gemma-2: the window on even-indexed layers (HF
+            # Gemma2DecoderLayer ``is_sliding = not bool(layer_idx % 2)``)
+            self.layer_window = tuple(
+                None if l % 2 else self.sliding_window
+                for l in range(self.num_layers))
+
     @property
     def is_mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def window_layer_ids(self) -> tuple:
+        """Layers whose attention is held to a window."""
+        return tuple(l for l, w in enumerate(self.layer_window)
+                     if w is not None)
+
+    @property
+    def full_layer_ids(self) -> tuple:
+        """Layers whose attention sees every earlier position."""
+        held = set(self.window_layer_ids)
+        return tuple(l for l in range(self.num_layers) if l not in held)
+
+    def rotates(self, l: int) -> bool:
+        """Whether layer l applies the rotary embedding."""
+        return not self.layer_rope or bool(self.layer_rope[l])
 
     @property
     def has_recurrent_state(self) -> bool:
@@ -217,7 +261,9 @@ class ModelConfig:
             model_type="mixtral" if mt == "mixtral" else "llama",
             vocab_size=cfg["vocab_size"],
             hidden_size=cfg["hidden_size"],
-            intermediate_size=cfg["intermediate_size"],
+            # SmallThinker has experts only, and names their width alone
+            intermediate_size=cfg["moe_ffn_hidden_size" if mt ==
+                                  "smallthinker" else "intermediate_size"],
             num_layers=cfg["num_hidden_layers"],
             num_heads=cfg["num_attention_heads"],
             num_kv_heads=cfg.get("num_key_value_heads",
@@ -379,6 +425,8 @@ class ModelConfig:
             c.tie_word_embeddings = cfg.get("tie_word_embeddings", True)
         if mt == "granitemoehybrid":
             c._read_granite(cfg)
+        if mt == "smallthinker":
+            c._read_smallthinker(cfg)
         if mt in ("gemma", "gemma2"):
             # Gemma rides the Llama GQA stack with four semantic switches
             c.model_type = "gemma"
@@ -394,10 +442,66 @@ class ModelConfig:
                 c.model_type = "gemma2"
                 c.sandwich_norms = True
                 c.sliding_window = cfg.get("sliding_window", 4096)
+                c.layer_window = ()
+                c.__post_init__()   # the even-layer rule
                 c.attn_logit_softcap = cfg.get("attn_logit_softcapping")
                 c.final_logit_softcap = cfg.get("final_logit_softcapping")
                 c.query_pre_attn_scalar = cfg.get("query_pre_attn_scalar")
         return c
+
+    def _read_smallthinker(self, cfg: dict) -> None:
+        """The keys of a ``smallthinker`` config.json: primary experts
+        only, a softmax over the chosen, ReGLU, and the two per-layer
+        layouts, which must name the same layers (a window layer rotates,
+        a full layer applies no positional embedding)."""
+        def refuse(what: str, why: str):
+            raise NotImplementedError(
+                f"smallthinker with {what} is not supported ({why})")
+
+        L = cfg["num_hidden_layers"]
+        win = list(cfg["sliding_window_layout"][:L])
+        rope = list(cfg["rope_layout"][:L])
+        if len(win) != L or len(rope) != L:
+            refuse(f"layouts of {len(win)} / {len(rope)} entries",
+                   f"sliding_window_layout and rope_layout must each "
+                   f"name num_hidden_layers = {L} layers")
+        if [bool(w) for w in win] != [bool(r) for r in rope]:
+            refuse("sliding_window_layout != rope_layout",
+                   "a window layer rotates q and k and a full layer "
+                   "applies no positional embedding: the two pools hold "
+                   "K by that rule, and no layer of a third kind exists")
+        if not any(win) or all(win):
+            refuse("layers of one kind only",
+                   "the K/V pools are one a kind of layer; a model whose "
+                   "layers all see the same is model_type llama")
+        for key in ("moe_num_secondary_experts",
+                    "moe_num_active_secondary_experts",
+                    "moe_secondary_ffn_hidden_size"):
+            if cfg.get(key):
+                refuse(f"{key} = {cfg[key]}",
+                       "secondary experts are not computed; the published "
+                       "configuration has primary experts only")
+        if not (cfg.get("moe_primary_router_apply_softmax", True)
+                and cfg.get("norm_topk_prob", True)):
+            refuse("moe_primary_router_apply_softmax or norm_topk_prob "
+                   "false", "the routing weights are a softmax over the "
+                   "chosen experts' logits")
+        act = cfg.get("hidden_act", "relu")
+        if act != "relu":
+            refuse(f"hidden_act {act!r}", "its experts are ReGLU")
+        if cfg.get("rope_scaling"):
+            refuse("rope_scaling", "the window layers rotate by rope_theta "
+                   "alone")
+        window = int(cfg["sliding_window_size"])
+        self.model_type = "smallthinker"
+        self.sliding_window = window
+        self.layer_window = tuple(window if w else None for w in win)
+        self.layer_rope = tuple(bool(r) for r in rope)
+        self.kv_pool_by_kind = True
+        self.moe_early_router = True
+        self.hidden_act = "relu"
+        self.num_experts = cfg["moe_num_primary_experts"]
+        self.num_experts_per_tok = cfg["moe_num_active_primary_experts"]
 
     def _read_granite(self, cfg: dict) -> None:
         """The keys of a ``granitemoehybrid`` config.json. ``num_local_

@@ -60,7 +60,8 @@ class KVCacheSpec:
     num_pages: int
     page_size: int
 
-    def shape(self, cfg: ModelConfig) -> Tuple[int, ...]:
+    def shape(self, cfg: ModelConfig,
+              layers: Optional[int] = None) -> Tuple[int, ...]:
         # kv-head-major page layout [L, pages, KV, ps, hd]: the Pallas decode
         # kernel then consumes pages with NO in-kernel transpose (batched
         # MXU dots over the leading KV axis) and (ps, hd) is lane-aligned.
@@ -73,15 +74,41 @@ class KVCacheSpec:
         # commit_window at the end of the decode window.
         # The reference models this as KvLayout::{KvFirst,BlockFirst}
         # (lib/llm/src/kv/layer.rs:100-106) — layout chosen for the device.
-        return (cfg.num_layers, self.num_pages, cfg.num_kv_heads,
-                self.page_size, cfg.head_dim_)
+        return (cfg.num_layers if layers is None else layers,
+                self.num_pages, cfg.num_kv_heads, self.page_size,
+                cfg.head_dim_)
 
 
 def init_kv_cache(cfg: ModelConfig, spec: KVCacheSpec,
                   dtype=None) -> Tuple[jax.Array, jax.Array]:
-    shape = spec.shape(cfg)
+    """The K and V pools, [L, pages, ...]; for a configuration whose
+    kinds of layer keep a pool each (``cfg.kv_pool_by_kind``) the pool of
+    the layers that see the whole context, [L_full, pages, ...], layer
+    ``cfg.full_layer_ids[a]`` at index a."""
+    shape = spec.shape(cfg, len(cfg.full_layer_ids)
+                       if cfg.kv_pool_by_kind else None)
     dtype = dtype or cfg.jax_dtype
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def init_window_kv_cache(cfg: ModelConfig, spec: KVCacheSpec,
+                         dtype=None) -> Tuple[jax.Array, jax.Array]:
+    """The window layers' K and V pools, [L_win, pages, ...] with pages
+    of their own count and their own ids: layer
+    ``cfg.window_layer_ids[a]`` at index a. A row's table into it has a
+    bounded number of slots (``window_table_slots``) and starts at the
+    first page the row still holds, not at position 0."""
+    shape = spec.shape(cfg, len(cfg.window_layer_ids))
+    dtype = dtype or cfg.jax_dtype
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def window_table_slots(cfg: ModelConfig, page_size: int, ahead: int) -> int:
+    """Slots of a row's table into the window layers' pool: the pages
+    that intersect ``window`` positions behind a query and ``ahead``
+    tokens written in one program (a prefill chunk, two decode windows)
+    past it, whatever the row's context."""
+    return -(-(cfg.sliding_window + ahead) // page_size) + 1
 
 
 # ------------------------------------------------------------------ params
@@ -185,6 +212,8 @@ def project_logits(params: Params, cfg: ModelConfig,
 def _act(cfg: ModelConfig):
     if cfg.hidden_act == "gelu_tanh":
         return lambda x: jax.nn.gelu(x, approximate=True)
+    if cfg.hidden_act == "relu":
+        return jax.nn.relu
     return jax.nn.silu
 
 
@@ -563,13 +592,15 @@ def _qk_headnorm(q, k, lp, cfg: ModelConfig):
             rms_norm(k, lp["k_norm"], cfg.rms_norm_eps))
 
 
-def _sliding_flag(cfg: ModelConfig, l_idx):
-    """Traced per-layer sliding-window flag: Gemma-2 applies the window on
-    even-indexed layers only (HF Gemma2DecoderLayer
-    ``is_sliding = not bool(layer_idx % 2)``)."""
-    if cfg.sliding_window is None:
+def _window_flag(cfg: ModelConfig, l_idx):
+    """Whether layer ``l_idx`` (traced under lax.scan) is held to the
+    window, looked up in the configuration's per-layer layout
+    (``cfg.layer_window``; Gemma-2's even-layer rule is one). One pool
+    under a mask: a configuration whose kinds of layer keep a pool each
+    runs ``_forward_by_kind``, where the kind is static."""
+    if not cfg.window_layer_ids:
         return False
-    return (l_idx % 2) == 0
+    return jnp.asarray([w is not None for w in cfg.layer_window])[l_idx]
 
 
 def _dyn_expert(w, e, layer=None):
@@ -872,7 +903,7 @@ def _moe_use_blocked(mesh, n_tokens: int, n_experts: int,
 def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
                 blocked: bool, live=None, layer=None,
                 out_dtype=jnp.float32, first=None,
-                width: Optional[int] = None) -> jax.Array:
+                width: Optional[int] = None, act=jax.nn.silu) -> jax.Array:
     """The routed experts' MLPs on x [B, T, D], given a gate's output
     (weights, idx: [B, T, k]): the execution half of an MoE MLP, shared
     by every gate (the softmax top-k of ``_moe_mlp``, the sigmoid gate of
@@ -909,7 +940,7 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
             weights.reshape(B * T, k), idx.reshape(B * T, k),
             w_gate, w_up, w_down, moe_block(B * T, k, w_gate.shape, width),
             live=None if live is None else live.reshape(B * T),
-            layer=layer, first=first)
+            layer=layer, act=act, first=first)
         return out.reshape(B, T, D).astype(out_dtype)
     with jax.named_scope("moe.router"):
         held = idx if first is None else idx - first
@@ -922,36 +953,49 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
                         w_gate.astype(jnp.float32))
         up = jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
                         w_up.astype(jnp.float32))
-        act = jax.nn.silu(ge) * up
-        down = jnp.einsum("btei,eid->bted", act, w_down.astype(jnp.float32))
+        down = jnp.einsum("btei,eid->bted", act(ge) * up,
+                          w_down.astype(jnp.float32))
         out = jnp.einsum("bted,bte->btd", down, full_gate)
         return out.astype(out_dtype)
 
 
 def _moe_mlp(h: jax.Array, w_router, w_gate, w_up, w_down,
-             top_k: int, mesh=None, live=None, layer=None) -> jax.Array:
+             top_k: int, mesh=None, live=None, layer=None,
+             act=jax.nn.silu, logits=None) -> jax.Array:
     """Mixtral-style MoE MLP: token-choice top-k routing, softmax over
     the chosen logits, then ``moe_experts`` in the form the shape rule
-    picks (sorted wherever ``layer`` says the parameters are whole)."""
+    picks (sorted wherever ``layer`` says the parameters are whole).
+    ``logits`` [B, T, E] float32: the router's, where they were made
+    before this point (``router_logits``: a router that reads the
+    layer's input); ``w_router`` is then not read."""
     B, T, _ = h.shape
     E = w_gate.shape[-3]
     with jax.named_scope("moe.router"):
-        logits = (h @ w_router).astype(jnp.float32)  # [B, T, E]
+        if logits is None:
+            logits = (h @ w_router).astype(jnp.float32)  # [B, T, E]
         weights, idx = lax.top_k(logits, top_k)  # [B, T, k]
         weights = jax.nn.softmax(weights, axis=-1)
     return moe_experts(
         h, weights, idx, w_gate, w_up, w_down,
         layer is not None or _moe_use_blocked(mesh, B * T, E, top_k),
-        live=live, layer=layer, out_dtype=h.dtype)
+        live=live, layer=layer, out_dtype=h.dtype, act=act)
+
+
+def router_logits(h: jax.Array, w_router) -> jax.Array:
+    """The router's logits [B, T, E] float32 on ``h`` as it is handed in:
+    for a router that reads the layer's un-normed input, before attention
+    (``cfg.moe_early_router``), called at the layer's entry."""
+    with jax.named_scope("moe"), jax.named_scope("moe.router"):
+        return (h @ w_router).astype(jnp.float32)
 
 
 def _layer_ff(h, lp, cfg: ModelConfig, mesh, experts=None, live=None,
-              l_idx=None):
+              l_idx=None, logits=None):
     """The second half of a layer: h + the MLP, or the routed experts, of
     norm(h). ``experts``: the stacked (gate, up, down) weights of every
     layer, read in place at ``l_idx`` by the sorted dispatch, with
     ``live`` the rows that make pairs (a prefill); else ``lp`` holds the
-    layer's own."""
+    layer's own. ``logits``: the router's, made at the layer's entry."""
     x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
     if cfg.num_experts == 0:
         mlp_out = _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], _act(cfg))
@@ -960,11 +1004,13 @@ def _layer_ff(h, lp, cfg: ModelConfig, mesh, experts=None, live=None,
             if experts is not None:
                 mlp_out = _moe_mlp(x, lp["w_router"], *experts,
                                    cfg.num_experts_per_tok, mesh=mesh,
-                                   live=live, layer=l_idx)
+                                   live=live, layer=l_idx, act=_act(cfg),
+                                   logits=logits)
             else:
                 mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
                                    lp["w_up"], lp["w_down"],
-                                   cfg.num_experts_per_tok, mesh=mesh)
+                                   cfg.num_experts_per_tok, mesh=mesh,
+                                   act=_act(cfg), logits=logits)
     return _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
 
 
@@ -1036,7 +1082,7 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
                               scale, allow_pallas=allow_pallas, mesh=mesh,
                               softcap=cfg.attn_logit_softcap,
                               window=cfg.sliding_window,
-                              is_sliding=_sliding_flag(cfg, l_idx),
+                              is_sliding=_window_flag(cfg, l_idx),
                               block=cfg.block_length)
             h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"], lp,
                               "ln_attn_post", cfg)
@@ -1059,7 +1105,201 @@ def logits_at(params: Params, cfg: ModelConfig, hidden: jax.Array,
     return project_logits(params, cfg, h_last)
 
 
+# ------------------------------------------- layers of two kinds, two pools
+
+
+def layer_period(cfg: ModelConfig) -> int:
+    """The shortest period of the per-layer layout (window, rotation)
+    that divides the depth: the layers a scan step unrolls, so that each
+    layer's kind is static."""
+    L = cfg.num_layers
+    kinds = [(cfg.layer_window[l], cfg.rotates(l)) for l in range(L)]
+    return next(p for p in range(1, L + 1)
+                if L % p == 0 and all(kinds[l] == kinds[l % p]
+                                      for l in range(L)))
+
+
+def _relative(positions: jax.Array, base: jax.Array) -> jax.Array:
+    """Positions counted from a row's ``base`` (the first position of
+    slot 0 of its window table); padding (-1) stays -1. The masks of
+    attention and the commit compare positions with each other and with
+    the table's slots, so they take these as they take absolute ones."""
+    return jnp.where(positions >= 0,
+                     positions - base.reshape((-1,) + (1,) * (positions.ndim
+                                                              - 1)), -1)
+
+
+def _qkv(cfg: ModelConfig, lp, x: jax.Array, pos: jax.Array, inv_freq,
+         rotate: bool):
+    """q [B, T, H, hd], k, v [B, T, KV, hd] of one layer on the normed
+    ``x``; the rotary embedding only where the layer's layout has it."""
+    B, T, _ = x.shape
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+    if cfg.attn_bias:
+        xq, xk, xv = xq + lp["bq"], xk + lp["bk"], xv + lp["bv"]
+    q, k = _qk_headnorm(xq.reshape(B, T, H, hd), xk.reshape(B, T, KV, hd),
+                        lp, cfg)
+    if rotate:
+        q, k = apply_rope(q, pos, inv_freq), apply_rope(k, pos, inv_freq)
+    return q, k, xv.reshape(B, T, KV, hd)
+
+
+def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
+                     kv_k, kv_v, page_table, flat_slots, wkv, wtab,
+                     allow_pallas: bool = True, page_slots=None, mesh=None):
+    """``forward`` for a configuration whose layers are of two kinds with
+    a pool each (``cfg.kv_pool_by_kind``): layers that see the whole
+    context write and read ``kv_k`` / ``kv_v`` [L_full, pages, ...] by
+    ``page_table``, layers held to the window ``wkv`` = (K, V)
+    [L_win, pages_w, ...] by the row's window table. ``wtab`` =
+    (table [B, S_w], base [B], slots): slot s of a row's table is the
+    page of positions ``base + s * ps ...``, so the table, the kernel's
+    grid and what it copies follow the window and not the context;
+    ``slots`` are the window pool's write slots in the form of
+    ``flat_slots`` ([B, T]) or, where ``page_slots`` is given, of
+    ``page_slots`` ([B, T // ps]).
+
+    One scan over the periods of the layout, a period's layers unrolled:
+    a layer's window, its rotation and its pool are static, and each
+    attention call is handed its kind's pool, table and window. A kind's
+    pool is seen as [layers * pages, ...], so that a layer's pages are
+    written and read along the major axis and no layer is sliced out
+    (lfm2.py's form). The router reads the layer's input
+    where ``cfg.moe_early_router``. Returns (hidden, kv_k, kv_v, wkv)."""
+    wk, wv = wkv
+    wtable, wbase, wslots = wtab
+    inv_freq = rope_freqs(cfg)
+    B, T = tokens.shape
+    H, hd = cfg.num_heads, cfg.head_dim_
+    ps = kv_k.shape[3]
+    p = layer_period(cfg)
+    n_per = cfg.num_layers // p
+    windows = cfg.layer_window[:p]
+    n_win = sum(w is not None for w in windows)
+    n_full = p - n_win
+    NPf, NPw = kv_k.shape[1], wk.shape[1]
+    h = embed_tokens(params, cfg, tokens)
+    safe_pos = jnp.maximum(positions, 0)
+    rel_pos = _relative(positions, wbase)
+    keys = _layer_keys(cfg)
+    experts = live = None
+    if cfg.num_experts > 0 and _moe_use_blocked(
+            mesh, B * T, cfg.num_experts, cfg.num_experts_per_tok):
+        # the sorted dispatch reads w[layer, expert] in place (forward)
+        keys = [k for k in keys if k not in ("w_gate", "w_up", "w_down")]
+        experts = [params[k] for k in ("w_gate", "w_up", "w_down")]
+        live = positions >= 0
+
+    def write(pool, new, slots, off, pages, table, pos):
+        """``new`` into one layer's pages (from page ``off`` on) of a
+        kind's flat pool: whole pages of an aligned chunk, one token a
+        row by whole pages too (commit_window: a row scatter makes the
+        compiler relayout the pool around it), else by rows."""
+        if page_slots is not None:
+            dst = jnp.where((slots >= 0) & (slots < pages), slots + off,
+                            pool.shape[0])
+            return _scatter_pages_paged(pool, new, dst)
+        if T == 1:
+            at = pos[:, 0]
+            return commit_window(pool[None], new[None], table + off, at,
+                                 at + 1)[0]
+        dst = jnp.where((slots >= 0) & (slots < pages * ps),
+                        slots + off * ps, pool.shape[0] * ps)
+        return _scatter_pages(pool, new, dst)
+
+    def period(carry, per):
+        h, fk, fv, pk, pv = carry
+        a_full = a_win = 0
+        for j, window in enumerate(windows):
+            l_idx = per * p + j
+            lp = _at(params, keys, l_idx)
+            logits = (router_logits(h, lp["w_router"])
+                      if cfg.moe_early_router else None)
+            with jax.named_scope("attn"):
+                x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                             cfg.norm_unit_offset)
+                q, k, v = _qkv(cfg, lp, x, safe_pos, inv_freq, cfg.rotates(j))
+                if window is None:
+                    off = (per * n_full + a_full) * NPf
+                    a_full += 1
+                    slots = page_slots if page_slots is not None \
+                        else flat_slots
+                    fk = write(fk, k, slots, off, NPf, page_table, positions)
+                    fv = write(fv, v, slots, off, NPf, page_table, positions)
+                    with jax.named_scope("attn.full"):
+                        attn = _attention(
+                            q, fk, fv, page_table + off, positions,
+                            cfg.attn_scale, allow_pallas=allow_pallas,
+                            mesh=mesh, softcap=cfg.attn_logit_softcap)
+                else:
+                    off = (per * n_win + a_win) * NPw
+                    a_win += 1
+                    pk = write(pk, k, wslots, off, NPw, wtable, rel_pos)
+                    pv = write(pv, v, wslots, off, NPw, wtable, rel_pos)
+                    with jax.named_scope("attn.window"):
+                        attn = _attention(
+                            q, pk, pv, wtable + off, rel_pos,
+                            cfg.attn_scale, allow_pallas=allow_pallas,
+                            mesh=mesh, softcap=cfg.attn_logit_softcap,
+                            window=window, is_sliding=True)
+                h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"],
+                                  lp, "ln_attn_post", cfg)
+            h = _layer_ff(h, lp, cfg, mesh, experts, live, l_idx, logits)
+        return (h, fk, fv, pk, pv), None
+
+    def flat(pool):
+        return pool.reshape(-1, *pool.shape[2:])
+
+    # the pools ride the scan as its CARRY, each seen as [layers * pages,
+    # ...]: as scanned xs / ys (forward's form) the results are buffers
+    # of their own, a second copy of every pool among the temporaries
+    (h, fk, fv, pk, pv), _ = lax.scan(
+        period, (h, flat(kv_k), flat(kv_v), flat(wk), flat(wv)),
+        jnp.arange(n_per, dtype=jnp.int32))
+    h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps,
+                 cfg.norm_unit_offset)
+    return (h, fk.reshape(kv_k.shape), fv.reshape(kv_v.shape),
+            (pk.reshape(wk.shape), pv.reshape(wv.shape)))
+
+
+def _make_step_fns_by_kind(cfg: ModelConfig, allow_pallas: bool, mesh):
+    """``make_step_fns`` for a configuration with a pool a kind of layer:
+    the same two programs under the same names, with the window layers'
+    pools and tables as trailing operands (``wkv``, ``wtab``:
+    ``_forward_by_kind``) and the pools returned last, as a model with
+    state returns its state."""
+
+    @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "wkv"))
+    def prefill_step(params, tokens, positions, kv_k, kv_v, page_table,
+                     flat_slots, last_idx, page_slots, wkv, wtab):
+        h, kv_k2, kv_v2, wkv2 = _forward_by_kind(
+            params, cfg, tokens, positions, kv_k, kv_v, page_table,
+            flat_slots, wkv, wtab, allow_pallas=allow_pallas,
+            page_slots=page_slots, mesh=mesh)
+        return logits_at(params, cfg, h, last_idx), kv_k2, kv_v2, wkv2
+
+    @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "wkv"))
+    def decode_step(params, tokens, positions, kv_k, kv_v, page_table,
+                    flat_slots, wkv, wtab):
+        h, kv_k2, kv_v2, wkv2 = _forward_by_kind(
+            params, cfg, tokens[:, None], positions[:, None], kv_k, kv_v,
+            page_table, flat_slots[:, None], wkv, wtab,
+            allow_pallas=allow_pallas, mesh=mesh)
+        return (logits_at(params, cfg, h,
+                          jnp.zeros(tokens.shape[0], jnp.int32)),
+                kv_k2, kv_v2, wkv2)
+
+    return prefill_step, decode_step
+
+
 # ----------------------------------------------------- jitted entry points
+
+
+def _one_pool_only(cfg: ModelConfig) -> None:
+    """The programs over one pool rotate q and k in every layer."""
+    assert all(cfg.rotates(l) for l in range(cfg.num_layers)), \
+        "a layer without rotation runs _forward_by_kind (kv_pool_by_kind)"
 
 
 def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
@@ -1071,6 +1311,9 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
     model-shard via shard_map (see _attention); ``allow_pallas=False``
     forces the XLA gather path everywhere.
     """
+    if cfg.kv_pool_by_kind:
+        return _make_step_fns_by_kind(cfg, allow_pallas, mesh)
+    _one_pool_only(cfg)
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v"))
     def prefill_step(params: Params, tokens: jax.Array, positions: jax.Array,
@@ -1170,6 +1413,11 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     if cfg.block_length > 1:
         return _make_block_window_fn(cfg, allow_pallas, max_top_k, mesh,
                                      pallas_interpret)
+    if cfg.kv_pool_by_kind:
+        return make_window(
+            _window_family_by_kind(cfg, allow_pallas, mesh,
+                                   pallas_interpret), max_top_k)
+    _one_pool_only(cfg)
     inv_freq = rope_freqs(cfg)
     scale = cfg.attn_scale
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
@@ -1234,7 +1482,7 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                     scale, mode, mesh=mesh if sharded else None,
                     softcap=cfg.attn_logit_softcap,
                     window=cfg.sliding_window,
-                    is_sliding=_sliding_flag(cfg, l_idx),
+                    is_sliding=_window_flag(cfg, l_idx),
                     q_pos=safe_pos[:, 0])
                 h = _residual_add(
                     h, attn.reshape(B, 1, H * hd) @ lp["wo"], lp,
@@ -1257,6 +1505,108 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
                 commit_window(w.kv_v, wv, w.page_table, w.start, pos), None)
 
     return make_window(Family(begin, step, commit), max_top_k)
+
+
+def _window_family_by_kind(cfg: ModelConfig, allow_pallas: bool, mesh,
+                           pallas_interpret: bool) -> Family:
+    """The decode window's ``begin`` / ``step`` / ``commit`` for a
+    configuration with a pool a kind of layer. The window layers' pools
+    are the program's ``state`` operand (K, V) and ``state_slots`` the
+    rows' (table [B, S_w], base [B]) into them (``_forward_by_kind``);
+    both pools are read-only in the steps, the steps' K/V of every layer
+    go to one buffer [L, B, K, KV, hd], and the commit writes each kind's
+    layers to its own pool by whole pages, the window layers' at the
+    positions counted from the row's base."""
+    inv_freq = rope_freqs(cfg)
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
+    mode = kernel_mode(allow_pallas, pallas_interpret, mesh)
+    L = cfg.num_layers
+    p = layer_period(cfg)
+    windows = cfg.layer_window[:p]
+    n_win = sum(w is not None for w in windows)
+    n_full = p - n_win
+    keys = _layer_keys(cfg)
+    full_ids = jnp.asarray(cfg.full_layer_ids, jnp.int32)
+    win_ids = jnp.asarray(cfg.window_layer_ids, jnp.int32)
+
+    def begin(w):
+        B = w.start.shape[0]
+        wdt = w.kv_k.dtype
+        return (jnp.zeros((L, B, w.k_steps, KV, hd), wdt),
+                jnp.zeros((L, B, w.k_steps, KV, hd), wdt))
+
+    def step(w, bufs, tok, pos, active, i):
+        params = w.params
+        (pk, pv), (wtable, wbase) = w.state, w.state_slots
+        wk, wv = bufs
+        B = tok.shape[0]
+        wdt = w.kv_k.dtype
+        h = embed_tokens(params, cfg, tok)[:, None]
+        safe_pos = jnp.maximum(pos, 0)[:, None]
+        rel_start = _relative(w.start, wbase)
+        rel_pos = safe_pos[:, 0] - wbase
+
+        def period(h, xs):
+            per, wk_p, wv_p = xs
+            a_full = a_win = 0
+            ks, vs = [], []
+            for j, window in enumerate(windows):
+                lp = _at(params, keys, per * p + j)
+                logits = (router_logits(h, lp["w_router"])
+                          if cfg.moe_early_router else None)
+                with jax.named_scope("attn"):
+                    x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
+                                 cfg.norm_unit_offset)
+                    q, k, v = _qkv(cfg, lp, x, safe_pos, inv_freq,
+                                   cfg.rotates(j))
+                    wk_l = wk_p[j].at[:, i].set(k[:, 0].astype(wdt))
+                    wv_l = wv_p[j].at[:, i].set(v[:, 0].astype(wdt))
+                    if window is None:
+                        a, a_full = per * n_full + a_full, a_full + 1
+                        with jax.named_scope("attn.full"):
+                            attn = window_attention(
+                                q, w.kv_k, w.kv_v, a, w.page_table, w.start,
+                                wk_l, wv_l, i, cfg.attn_scale, mode,
+                                softcap=cfg.attn_logit_softcap)
+                    else:
+                        a, a_win = per * n_win + a_win, a_win + 1
+                        with jax.named_scope("attn.window"):
+                            attn = window_attention(
+                                q, pk, pv, a, wtable, rel_start, wk_l, wv_l,
+                                i, cfg.attn_scale, mode,
+                                softcap=cfg.attn_logit_softcap,
+                                window=window, is_sliding=True,
+                                q_pos=rel_pos)
+                    h = _residual_add(
+                        h, attn.reshape(B, 1, H * hd) @ lp["wo"], lp,
+                        "ln_attn_post", cfg)
+                h = _layer_ff(h, lp, cfg, mesh, logits=logits)
+                ks.append(wk_l)
+                vs.append(wv_l)
+            return h, (jnp.stack(ks), jnp.stack(vs))
+
+        h, (wk, wv) = lax.scan(
+            period, h, (jnp.arange(L // p, dtype=jnp.int32),
+                        wk.reshape(L // p, p, *wk.shape[1:]),
+                        wv.reshape(L // p, p, *wv.shape[1:])))
+        h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps,
+                     cfg.norm_unit_offset)
+        logits = logits_at(params, cfg, h, jnp.zeros(B, jnp.int32))
+        return logits, (wk.reshape(bufs[0].shape),
+                        wv.reshape(bufs[1].shape)), None
+
+    def commit(w, bufs, pos):
+        wk, wv = bufs
+        (pk, pv), (wtable, wbase) = w.state, w.state_slots
+        start_w, pos_w = _relative(w.start, wbase), _relative(pos, wbase)
+        return (commit_window(w.kv_k, wk[full_ids], w.page_table, w.start,
+                              pos),
+                commit_window(w.kv_v, wv[full_ids], w.page_table, w.start,
+                              pos),
+                (commit_window(pk, wk[win_ids], wtable, start_w, pos_w),
+                 commit_window(pv, wv[win_ids], wtable, start_w, pos_w)))
+
+    return Family(begin, step, commit)
 
 
 # ------------------------------- block window (generation by diffusion)
@@ -1684,7 +2034,7 @@ def full_attention_layer(cfg: ModelConfig, h: jax.Array, lp: Params,
     default mesh=None (which may pick the blocked MoE dispatch) is
     correct there too.
     ``is_sliding`` is the traced Gemma-2 per-layer window flag (the
-    caller owns the layer-parity bookkeeping — see _sliding_flag)."""
+    caller owns the per-layer bookkeeping — see _window_flag)."""
     B, T = h.shape[:2]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps, cfg.norm_unit_offset)
@@ -1712,7 +2062,8 @@ def full_attention_layer(cfg: ModelConfig, h: jax.Array, lp: Params,
     x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
     if cfg.num_experts > 0:
         mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"], lp["w_up"],
-                           lp["w_down"], cfg.num_experts_per_tok, mesh=mesh)
+                           lp["w_down"], cfg.num_experts_per_tok, mesh=mesh,
+                           act=_act(cfg))
     else:
         mlp_out = _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], _act(cfg))
     return _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
@@ -1733,7 +2084,7 @@ def reference_forward(params: Params, cfg: ModelConfig,
     def layer(h, xs):
         lp, l_idx = xs
         return full_attention_layer(cfg, h, lp, pos, inv_freq, scale,
-                                    is_sliding=_sliding_flag(cfg, l_idx)), \
+                                    is_sliding=_window_flag(cfg, l_idx)), \
             None
 
     h, _ = lax.scan(layer, h,

@@ -34,7 +34,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.config import ModelConfig
-from ..models.llama import (Params, _layer_keys, _sliding_flag,
+from ..models.llama import (Params, _layer_keys, _window_flag,
                             embed_tokens, full_attention_layer,
                             project_logits, rms_norm, rope_freqs)
 
@@ -93,7 +93,7 @@ def make_pp_forward(cfg: ModelConfig, mesh: Mesh,
             lp, li = xs
             return full_attention_layer(
                 cfg, h, lp, pos, inv_freq, scale,
-                is_sliding=_sliding_flag(cfg, layer_off + li)), None
+                is_sliding=_window_flag(cfg, layer_off + li)), None
 
         h, _ = lax.scan(layer, h,
                         (lp_stack, jnp.arange(n_local)))

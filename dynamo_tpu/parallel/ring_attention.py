@@ -185,7 +185,7 @@ def make_long_prefill_fn(cfg: ModelConfig, mesh: Mesh, *,
     transfer plane). ``positions`` are absolute; -1 marks padding.
     """
     from ..models.llama import (_act, _layer_keys, _mlp, _moe_mlp,
-                                _qk_headnorm, _residual_add, _sliding_flag,
+                                _qk_headnorm, _residual_add, _window_flag,
                                 apply_rope, embed_tokens, project_logits,
                                 rms_norm, rope_freqs)
 
@@ -219,7 +219,7 @@ def make_long_prefill_fn(cfg: ModelConfig, mesh: Mesh, *,
                                   seq_axis=seq_axis,
                                   softcap=cfg.attn_logit_softcap,
                                   window=cfg.sliding_window,
-                                  is_sliding=_sliding_flag(cfg, l_idx))
+                                  is_sliding=_window_flag(cfg, l_idx))
             h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"],
                               lp, "ln_attn_post", cfg)
             x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)

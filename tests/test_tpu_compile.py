@@ -935,3 +935,83 @@ def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
         assert checkout not in body and b"paged_attention.py" in body
     finally:
         jax.config.update(knob, was)
+
+
+# ---- two kinds of layer, a pool each, at SmallThinker's widths (cell 9)
+
+
+def _smallthinker(one_chip):
+    """The configuration as smallthinker-21b-a3b.mixed-length runs it (8
+    of 52 layers, every width as published); params, both kinds' pools
+    and the rows' tables into the window layers' pool as shapes on the
+    described chip, at the cell's engine data."""
+    import json
+
+    from dynamo_tpu.models.config import ModelConfig
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "smallthinker-21b-a3b.mixed-length.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", "smallthinker-21b-a3b"))
+    params = _on(one_chip, jax.eval_shape(
+        lambda: llama.init_params(cfg, jax.random.PRNGKey(0))))
+    kv = tuple(_on(one_chip, x) for x in jax.eval_shape(
+        lambda: llama.init_kv_cache(cfg, llama.KVCacheSpec(e["num_pages"],
+                                                           64))))
+    wkv = tuple(_on(one_chip, x) for x in jax.eval_shape(
+        lambda: llama.init_window_kv_cache(
+            cfg, llama.KVCacheSpec(e["window_pages"], 64))))
+    assert kv[0].shape == (2, 5632, 4, 64, 128)
+    assert wkv[0].shape == (6, 3520, 4, 64, 128)
+    slots = llama.window_table_slots(cfg, 64, e["prefill_chunk"])
+    assert slots == 73      # the window, not the context of 217 pages
+    return cfg, params, kv, wkv, slots, e
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
+def test_smallthinker_programs_write_no_array_of_either_pools_size(
+        one_chip, tpu_kernel_path, program):
+    """models/llama.py by kind at the shapes of smallthinker-21b-a3b.
+    mixed-length (full layers' pool [2, 5632, ...] = 0.69 GiB each of K
+    and V, window layers' [6, 3520, ...] = 1.29 GiB each): the fused
+    window (B 48, 4 steps) reads both pools where they lie and commits
+    each kind's K/V by whole pages in place; decode_step writes its one
+    token a row the same way; a prefill chunk (PB 8 x T 512) carries the
+    pools through its scan over the periods and scatters whole pages.
+    No copy of either pool's size exists, all four pools alias their
+    inputs, and the temporaries stay far under a pool (as scanned xs /
+    ys the pools were 6.27 GiB of temporaries: scratch compile, PR 46)."""
+    cfg, params, (kv_k, kv_v), wkv, slots, e = _smallthinker(one_chip)
+    s = partial(_sds, one_chip)
+    P, B = e["page_buckets"][-1], e["max_batch"]
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    if program == "window":
+        compiled = llama.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, wkv,
+            (s((B, slots), jnp.int32), i32), k_steps=4,
+            logprobs_topn=0).compile()
+    elif program == "decode_step":
+        compiled = llama.make_step_fns(cfg)[1].lower(
+            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, wkv,
+            (s((B, slots), jnp.int32), i32, s((B, 1), jnp.int32))).compile()
+    else:
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        compiled = llama.make_step_fns(cfg)[0].lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), wkv,
+            (s((PB, slots), jnp.int32), s((PB,), jnp.int32),
+             s((PB, T // 64), jnp.int32))).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(text, kv_k.size) == []    # the smaller pool
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in (kv_k, kv_v, *wkv))
+    assert mem.temp_size_in_bytes < (2 ** 29 if program != "prefill"
+                                     else 1.25 * 2 ** 30)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)

@@ -4,7 +4,9 @@ operands the model module's programs take: benchmark/rehearse.py's
 memory count for the cells it cannot lower (a model that keeps
 recurrent state: ``(state, state_slots)`` trailing operands, and
 ``state_src`` where the module snapshots; a window whose token operand
-is a block a row), and a digest of each program as lowered.
+is a block a row; a model with a K/V pool a kind of layer: the window
+layers' pools and the rows' tables into them in the same two places),
+and a digest of each program as lowered.
 
     python3 tools/cell_programs.py --workload CELL            # memory
     python3 tools/cell_programs.py --workload CELL --digest   # no compile
@@ -15,7 +17,7 @@ batch), the benchmark's weight maker and one layer of the
 configuration's plain reference are compiled, nothing runs, and the
 compiler's own ``memory_analysis`` gives arguments, temporaries and
 aliased bytes. GB = 2**30 bytes. The last line is what ``about.json``'s
-``memory`` holds: resident = parameters + K/V pools + state pools, peak
+``memory`` holds: resident = parameters + K/V pools (both kinds') + state pools, peak
 = resident + the largest temporaries of a serving program.
 
 ``--digest``: sha256[:16] of each serving program's lowered StableHLO
@@ -110,8 +112,26 @@ def main() -> int:
             state = (*state, jax.eval_shape(
                 lambda: model.init_state_snapshots(cfg, spec)))
         state = on(state)
+    # the window layers' pools and the rows' tables into them, for a
+    # model with a pool a kind of layer (as JaxEngine.__init__ and
+    # _window_tables build them); the parent of PR 46 has no such model
+    wkv, w_slots = None, 0
+    if getattr(cfg, "kv_pool_by_kind", False):
+        w_slots = model.window_table_slots(
+            cfg, ps := ecfg.page_size,
+            max(ecfg.prefill_chunk, 2 * ecfg.decode_steps + 1))
+        wkv = on(jax.eval_shape(lambda: model.init_window_kv_cache(
+            cfg, llama.KVCacheSpec(
+                ecfg.window_pages or ecfg.max_batch * w_slots + 1, ps))))
 
-    def state_args(rows, prefill=False):
+    def state_args(rows, prefill=False, T=None):
+        if wkv is not None:
+            tables = (s((rows, w_slots)), s((rows,)))
+            if T is not None:
+                paged = prefill and T % ecfg.page_size == 0
+                tables += (s((rows, T // ecfg.page_size) if paged
+                             else (rows, T)),)
+            return (wkv, tables)
         if state is None:
             return ()
         return (state, s((rows,)), *([s((rows,))] if prefill and snapshots
@@ -120,7 +140,8 @@ def main() -> int:
     out = {"cell": a.workload, "grid": grid,
            "params_gb": nbytes(params) / 2 ** 30,
            "kv_pool_gb": nbytes((kv_k, kv_v)) / 2 ** 30,
-           "state_pool_gb": nbytes(state) / 2 ** 30, "programs": []}
+           "state_pool_gb": nbytes(state) / 2 ** 30,
+           "window_kv_pool_gb": nbytes(wkv) / 2 ** 30, "programs": []}
     digests = []
 
     def record(name, lowered):
@@ -152,7 +173,7 @@ def main() -> int:
                 record(f"prefill PB={PB} T={T} P={P}", prefill.lower(
                     params, s((PB, T)), s((PB, T)), kv_k, kv_v,
                     s((PB, P)), s((PB, T)), s((PB,)), pslots,
-                    *state_args(PB, prefill=True)))
+                    *state_args(PB, prefill=True, T=T)))
         for B in grid["decode_batches"]:
             row_i, row_f = s((B,)), s((B,), jnp.float32)
             tokens = row_i if L == 1 else s((B, L))
@@ -167,7 +188,7 @@ def main() -> int:
             B = grid["decode_batches"][-1]
             record(f"decode_step B={B} P={P}", decode_step.lower(
                 params, s((B,)), s((B,)), kv_k, kv_v, s((B, P)), s((B,)),
-                *state_args(B)))
+                *state_args(B, T=1)))
     if a.digest:
         table = hashlib.sha256("".join(digests).encode()).hexdigest()[:16]
         print(json.dumps({"cell": a.workload, "programs": len(digests),
@@ -183,7 +204,7 @@ def main() -> int:
             params, s((104, cfg.hidden_size), jnp.float32), s((), i32)))
     worst = max(r["temporaries_gb"] for r in out["programs"][:serving])
     out["resident_gb"] = (out["params_gb"] + out["kv_pool_gb"]
-                          + out["state_pool_gb"])
+                          + out["state_pool_gb"] + out["window_kv_pool_gb"])
     out["peak_gb"] = out["resident_gb"] + worst
     out["limit_gb"] = LIMIT_GB
     out["fits"] = out["peak_gb"] < LIMIT_GB
