@@ -828,6 +828,9 @@ class JaxEngine:
         self.decode_rows_total = 0
         self.decode_slots_total = 0
         self.decode_windows_total = 0
+        # of those, the windows with a sampled row: their draws ran
+        # sample_tokens' sampled arm, every other window's one argmax
+        self.decode_windows_sampled_total = 0
         self.warmup_seconds = 0.0
         # iterations where a decode window dispatched WHILE prompts were
         # still prefilling — the observable for budgeted mixing
@@ -1144,8 +1147,7 @@ class JaxEngine:
                         jnp.full((B, Kv), DROP_SLOT, jnp.int32))
                     verify_greedy_draft(logits,
                                         jnp.zeros((B, Kv - 1), jnp.int32),
-                                        jnp.zeros(B, jnp.int32),
-                                        max_top_k=ecfg.max_top_k)
+                                        jnp.zeros(B, jnp.int32))
                     n += 1
                 n += 1
                 if progress:
@@ -1500,6 +1502,8 @@ class JaxEngine:
             "decode_rows_total": self.decode_rows_total,
             "decode_slots_total": self.decode_slots_total,
             "decode_windows_total": self.decode_windows_total,
+            "decode_windows_sampled_total":
+                self.decode_windows_sampled_total,
             "warmup_seconds": self.warmup_seconds,
             "prefill_program_cost_ms": self._prefill_cost_table(),
             "gpu_cache_usage_perc": self.pm.usage(),
@@ -2613,8 +2617,7 @@ class JaxEngine:
             self.params, jnp.asarray(tokens), jnp.asarray(positions),
             self.kv_k, self.kv_v, jnp.asarray(table), jnp.asarray(slots))
         out_d, acc_d = verify_greedy_draft(
-            logits, jnp.asarray(draft_arr), jnp.asarray(draft_len),
-            max_top_k=self.ecfg.max_top_k)
+            logits, jnp.asarray(draft_arr), jnp.asarray(draft_len))
         self._account_dispatch(batch)
         with self.profiler.phase("readback_window"):
             out = np.asarray(out_d)  # host sync — the spec arm is synchronous
@@ -2866,6 +2869,8 @@ class JaxEngine:
         self.decode_rows_total += len(batch) * K
         self.decode_slots_total += B * K
         self.decode_windows_total += 1
+        self.decode_windows_sampled_total += any(
+            not s.req.sampling.greedy for s in batch)
         if self.state is not None:
             self.state_slots_held_total += (self.ecfg.max_batch
                                             - len(self._state_free))

@@ -119,6 +119,13 @@ def update_penalty_state(penalties, sampled: jax.Array, done: jax.Array):
 _CHUNK = 128  # lanes of one TPU vector register: a chunk is one lane row
 
 
+def _rank_bits(bits: jax.Array) -> jax.Array:
+    """float32 bits <-> the int32 of the same rank (its own inverse):
+    ``lax.top_k``'s total order, -0.0 below 0.0, a NaN past the
+    infinity of its sign."""
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
 def _top_k_two_key(x: jax.Array, ids: jax.Array, k: int
                    ) -> Tuple[jax.Array, jax.Array]:
     """The k best of every (narrow) float32 row with their ``ids``, by
@@ -130,12 +137,9 @@ def _top_k_two_key(x: jax.Array, ids: jax.Array, k: int
     ``lax.top_k`` itself would do on paper; on a v5e its single-row
     lowering does not keep equal values in index order (PERF.md, PR 25).
     """
-    def flip(bits):     # float32 bits <-> int32 of the same rank
-        return bits ^ ((bits >> 31) & 0x7FFFFFFF)
-
-    key = flip(jax.lax.bitcast_convert_type(x, jnp.int32))
+    key = _rank_bits(jax.lax.bitcast_convert_type(x, jnp.int32))
     key, neg_ids = jax.lax.sort((key, -ids), dimension=1, num_keys=2)
-    vals = jax.lax.bitcast_convert_type(flip(key[:, :-k - 1:-1]),
+    vals = jax.lax.bitcast_convert_type(_rank_bits(key[:, :-k - 1:-1]),
                                         jnp.float32)
     return vals, -neg_ids[:, :-k - 1:-1]
 
@@ -183,6 +187,29 @@ def exact_top_k(x: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
     return vals.astype(dtype), ids
 
 
+def greedy_tokens(logits: jax.Array) -> jax.Array:
+    """The greedy token of every row of logits [B, V], int32: the token
+    ``exact_top_k(logits, k)[1][:, 0]`` names, to the letter (the
+    maximum in :func:`_rank_bits`' total order, the lowest vocabulary id
+    among equals), by one argmax over that int32 key: one read of the
+    logits, no sort, no chunks, no pad, no copy. "To the letter" as far
+    as ``exact_top_k`` is itself defined: a row holding a NaN with the
+    sign bit set is not (its first stage is a float ``max``, which hands
+    on a NaN of either sign, where this key ranks that one below
+    ``-inf``), and with N <= 2k chunks ``exact_top_k`` is the plain
+    ``lax.top_k``, whose order among equals is the lowering's (lowest id
+    on the CPU; one row on a v5e not: PERF.md, PR 25) where this is
+    lowest id always. What a temperature-0 row draws wherever it is
+    drawn: :func:`sample_tokens`' all-greedy arm and
+    :func:`verify_greedy_draft` call this, the sampled arm's greedy rows
+    read the same token off ``exact_top_k``. No scope of its own, like
+    ``exact_top_k``: the caller's ``sample`` names it (a scope inside
+    itself would count an op twice in the trace's sums)."""
+    key = _rank_bits(jax.lax.bitcast_convert_type(
+        logits.astype(jnp.float32), jnp.int32))
+    return jnp.argmax(key, axis=-1).astype(jnp.int32)
+
+
 @partial(jax.jit, static_argnames=("max_top_k",))
 def sample_tokens(logits: jax.Array, temperature: jax.Array,
                   top_k: jax.Array, top_p: jax.Array, seeds: jax.Array,
@@ -198,6 +225,11 @@ def sample_tokens(logits: jax.Array, temperature: jax.Array,
     ``(counts [B,V], presence [B,V], rep [B], freq [B], pres [B])``
     consumed by :func:`apply_penalties`; None (the default and the only
     pre-compiled variant) keeps the penalty-free program.
+
+    The program branches at run time on what the batch asks for: a
+    batch of greedy rows alone (padding rows are temperature 0) is
+    :func:`greedy_tokens`, the candidates are selected only where a row
+    is sampled. Same tokens either way.
     """
     with jax.named_scope("sample"):
         if penalties is not None:
@@ -205,43 +237,59 @@ def sample_tokens(logits: jax.Array, temperature: jax.Array,
         step = jnp.broadcast_to(step, temperature.shape)
         B, V = logits.shape
 
+        # above the branch, so that behind a head the divide stays the
+        # root of the head's output fusion (a branch cannot be reached
+        # into); a greedy row divides by 1.0 and keeps its bits
         temp = jnp.where(temperature > 0, temperature, 1.0)[:, None]
         scaled = logits / temp
 
-        # top-k within a static bound: take max_top_k once, mask per-row k.
-        # Greedy rows reuse this pass: argmax == top-1. Device time on a
-        # v5e at [64, 151936] float32 (tools/sampler_op_timing.py; PERF.md,
-        # PR 25): this function 12.46 ms on lax.top_k, which inside this
-        # program compiles to a stable sort of the whole row (alone, as a
-        # TopK custom call, 1.91 ms), 0.55 ms on exact_top_k; a jnp.argmax
-        # alone 0.03 ms. A greedy-only branch could save the 0.5 ms, and
-        # lose them with the first sampled row in the batch
-        k_vals, k_idx = exact_top_k(scaled, max_top_k)  # [B, K]
-        greedy = k_idx[:, 0]
-        ranks = jnp.arange(max_top_k)[None, :]
-        eff_k = jnp.where(top_k[:, None] > 0,
-                          jnp.minimum(top_k[:, None], max_top_k), max_top_k)
-        k_vals = jnp.where(ranks < eff_k, k_vals, -jnp.inf)
+        def sampled_arm():
+            # top-k within a static bound: take max_top_k once, mask
+            # per-row k. Greedy rows of a batch with a sampled row reuse
+            # this pass: argmax == top-1
+            k_vals, k_idx = exact_top_k(scaled, max_top_k)  # [B, K]
+            greedy = k_idx[:, 0]
+            ranks = jnp.arange(max_top_k)[None, :]
+            eff_k = jnp.where(top_k[:, None] > 0,
+                              jnp.minimum(top_k[:, None], max_top_k),
+                              max_top_k)
+            k_vals = jnp.where(ranks < eff_k, k_vals, -jnp.inf)
 
-        # top-p over the (sorted) top-k candidates
-        probs = jax.nn.softmax(k_vals, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep = (cum - probs) < top_p[:, None]  # always keep the first candidate
-        k_vals = jnp.where(keep, k_vals, -jnp.inf)
+            # top-p over the (sorted) top-k candidates
+            probs = jax.nn.softmax(k_vals, axis=-1)
+            cum = jnp.cumsum(probs, axis=-1)
+            # always keep the first candidate
+            keep = (cum - probs) < top_p[:, None]
+            k_vals = jnp.where(keep, k_vals, -jnp.inf)
 
-        def row_sample(i):
-            key = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(0), seeds[i]), step[i])
-            choice = jax.random.categorical(key, k_vals[i])
-            return k_idx[i, choice]
+            def row_sample(i):
+                key = jax.random.fold_in(
+                    jax.random.fold_in(jax.random.PRNGKey(0), seeds[i]),
+                    step[i])
+                choice = jax.random.categorical(key, k_vals[i])
+                return k_idx[i, choice]
 
-        sampled = jax.vmap(row_sample)(jnp.arange(B))
-        return jnp.where(temperature > 0, sampled, greedy).astype(jnp.int32)
+            sampled = jax.vmap(row_sample)(jnp.arange(B))
+            return jnp.where(temperature > 0, sampled,
+                             greedy).astype(jnp.int32)
+
+        # Device time on a v5e, float32 (tools/sampler_op_timing.py;
+        # PERF.md, PR 47), us, behind a bfloat16 head (the matmul and
+        # this function in one program): the program without the branch
+        # on any batch | this one on a batch of greedy rows | on a batch
+        # whose last row alone is sampled: [256, 151936] 3,631 | 1,385 |
+        # 3,636, [64, 151936] 1,320 | 853 | 1,322, [128, 65536] 1,035 |
+        # 476 | 1,036. The greedy arm alone 209 / 55 / 48 (one read of
+        # the logits at 819 GB/s: 190 / 48 / 41), the predicate 0.5.
+        # (On lax.top_k the function took 12.46 ms at [64, 151936]:
+        # there it compiles to a stable sort of the whole row; PR 25.)
+        return jax.lax.cond(jnp.any(temperature > 0), sampled_arm,
+                            lambda: greedy_tokens(scaled))
 
 
-@partial(jax.jit, static_argnames=("max_top_k",))
+@jax.jit
 def verify_greedy_draft(logits: jax.Array, draft: jax.Array,
-                        draft_len: jax.Array, max_top_k: int = 64
+                        draft_len: jax.Array
                         ) -> Tuple[jax.Array, jax.Array]:
     """Vectorized accept-mask + bonus-token draw for self-speculative
     decode (greedy rows only — the engine bypasses speculation for
@@ -258,15 +306,14 @@ def verify_greedy_draft(logits: jax.Array, draft: jax.Array,
     the bonus token greedily drawn at the first divergent (or final)
     position; entries past that are -1.
 
-    The greedy target is computed exactly as :func:`sample_tokens`'
-    greedy arm (:func:`exact_top_k`'s first element over the
-    temperature-1 logits), so speculation on/off is token-identical by
-    construction, tie-breaking included.
+    The greedy target is :func:`greedy_tokens`, what
+    :func:`sample_tokens` draws for a greedy row on either arm, so
+    speculation on/off is token-identical by construction, tie-breaking
+    included.
     """
     B, K1, V = logits.shape
     K = K1 - 1
-    _, k_idx = exact_top_k(logits.reshape(B * K1, V), max_top_k)
-    greedy = k_idx[:, 0].reshape(B, K1).astype(jnp.int32)
+    greedy = greedy_tokens(logits.reshape(B * K1, V)).reshape(B, K1)
     match = jnp.logical_and(draft == greedy[:, :K],
                             jnp.arange(K)[None, :] < draft_len[:, None])
     # longest all-true prefix: cumprod zeroes everything past a miss

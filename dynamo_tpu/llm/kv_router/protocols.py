@@ -149,7 +149,8 @@ STATS_PROMETHEUS_SKIP = {
            "moe_grouped_programs_total",
            "prefill_rows_held_back_total", "prefill_bucket_narrowed_total",
            "decode_rows_total", "decode_slots_total",
-           "decode_windows_total", "warmup_seconds")},
+           "decode_windows_total", "decode_windows_sampled_total",
+           "warmup_seconds")},
     # the host's account beside it (PR 35; runtime/profiling.py
     # host_stats): process-wide, read as deltas, no gauge
     **{key: "stats()-only counter of the host threads' time accounting"

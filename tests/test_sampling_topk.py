@@ -2,7 +2,9 @@
 the vocabulary. It has to be ``jax.lax.top_k`` to the letter (values
 descending, equal values by lower vocabulary index), so everything here
 compares against the plain call, and the three users of the helper
-against copies of their bodies from before it existed.
+against copies of their bodies from before it existed. ``greedy_tokens``,
+the draw of a batch with no sampled row (``sample_tokens`` branches on
+it at run time), has to be that selection's first element to the letter.
 """
 
 import jax
@@ -11,8 +13,9 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import sampling
-from dynamo_tpu.engine.sampling import (exact_top_k, logprob_aux,
-                                        sample_tokens, verify_greedy_draft)
+from dynamo_tpu.engine.sampling import (exact_top_k, greedy_tokens,
+                                        logprob_aux, sample_tokens,
+                                        verify_greedy_draft)
 
 C = 128
 # (V, k): Qwen3, Mixtral at the sampler's bound; a vocabulary that needs
@@ -83,6 +86,70 @@ def test_exact_top_k_is_lax_top_k(V, k, B, kind):
     assert int(jnp.max(got_i)) < V
 
 
+# the vocabularies of the benchmark's configurations, one that needs a
+# padded tail, and one small enough that exact_top_k is the plain call
+GREEDY_V = [32000, 65536, 100352, 128256, 151936, 128815, 1000]
+GREEDY_ROWS = ["distinct", "bf16_grained", "all_equal", "signed_zeros_on_top",
+               "inf_twice", "neg_inf_but_one", "nan"]
+
+
+def _greedy_rows(kind, B, V, seed):
+    rng = np.random.default_rng(seed)
+    x = (rng.standard_normal((B, V)) * 3.0).astype(np.float32)
+    at = lambda n: np.stack([rng.choice(V, size=n, replace=False)
+                             for _ in range(B)])
+    put = lambda ids, v: np.put_along_axis(x, ids, np.float32(v), axis=1)
+    if kind == "bf16_grained":      # the maximum ties all the time
+        x = np.round(x * 2.0) / 2.0
+    elif kind == "all_equal":
+        x[:] = 0.25
+    elif kind == "signed_zeros_on_top":
+        # 0.0 and -0.0 the two largest, -0.0 at the lower id in half the
+        # rows: the total order puts 0.0 first whichever comes first
+        x = -np.abs(x) - 1.0
+        ids = np.sort(at(2), axis=1)
+        ids[::2] = ids[::2, ::-1]
+        put(ids[:, :1], 0.0)
+        put(ids[:, 1:], -0.0)
+    elif kind == "inf_twice":
+        put(at(2), np.inf)
+    elif kind == "neg_inf_but_one":
+        one = at(1)
+        keep = np.take_along_axis(x, one, axis=1)
+        x[:] = -np.inf
+        np.put_along_axis(x, one, keep, axis=1)
+    elif kind == "nan":
+        put(at(1), np.nan)
+    else:
+        assert kind == "distinct"
+    return x
+
+
+@pytest.mark.parametrize("kind", GREEDY_ROWS)
+@pytest.mark.parametrize("V", GREEDY_V)
+def test_greedy_arm_is_the_first_of_exact_top_k(V, kind):
+    """One argmax over the rank key names the token the two stages put
+    first, element for element: what lets an all-greedy batch skip them.
+    Through ``sample_tokens`` too, where a greedy row is divided by 1.0
+    before the branch: the quotient keeps every bit that decides. (The
+    NaN planted is numpy's, sign bit clear: see ``greedy_tokens``.)"""
+    x = _greedy_rows(kind, 6, V, seed=V + len(kind))
+    want = np.asarray(exact_top_k(jnp.asarray(x), 64)[1][:, 0])
+    got = jax.jit(greedy_tokens)(jnp.asarray(x))
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    zeros = jnp.zeros((6,), jnp.int32)
+    drawn = sample_tokens(jnp.asarray(x), jnp.zeros((6,), jnp.float32), zeros,
+                          jnp.ones((6,), jnp.float32),
+                          jnp.arange(6, dtype=jnp.uint32), zeros)
+    np.testing.assert_array_equal(np.asarray(drawn), want)
+    if kind == "signed_zeros_on_top":
+        assert np.all(x[np.arange(6), want] == 0.0)
+        assert not np.any(np.signbit(x[np.arange(6), want]))
+    if kind == "nan":
+        assert np.all(np.isnan(x[np.arange(6), want]))
+
+
 # ---------------------------------------------------------------------
 # token identity: the three users against their bodies on plain
 # lax.top_k, as they stood before exact_top_k (the plain reference)
@@ -148,6 +215,12 @@ ROW_MIXES = {
     "mixed": ([0.0, 0.7, 0.0, 1.0, 1.5, 0.0, 0.8, 1.0],
               [0, 0, 10, 50, 0, 64, 5, 0],
               [1.0, 0.9, 0.5, 1.0, 0.8, 1.0, 0.95, 0.6]),
+    # one sampled row switches the arm: the other seven are the sampled
+    # arm's greedy rows
+    "one_of_eight": ([0.0, 0.0, 0.0, 0.9, 0.0, 0.0, 0.0, 0.0],
+                     [0, 0, 0, 30, 0, 0, 0, 0],
+                     [1.0, 1.0, 1.0, 0.9, 1.0, 1.0, 1.0, 1.0]),
+    "last_alone": ([0.0] * 7 + [1.2], [0] * 8, [1.0] * 7 + [0.8]),
 }
 
 
@@ -245,11 +318,14 @@ def _eqns(jaxpr):
                     yield from _eqns(sub)
 
 
-def _selections(fn, *shapes):
+def _selections_in(jaxpr):
     """[(primitive, width of its operand)] for every top_k and sort."""
-    jaxpr = jax.make_jaxpr(fn)(*shapes).jaxpr
     return [(e.primitive.name, e.invars[0].aval.shape[-1])
             for e in _eqns(jaxpr) if e.primitive.name in ("top_k", "sort")]
+
+
+def _selections(fn, *shapes):
+    return _selections_in(jax.make_jaxpr(fn)(*shapes).jaxpr)
 
 
 def _users(B, V):
@@ -265,27 +341,99 @@ def _users(B, V):
                                  row(i32))),
         "logprob_aux": (20, lambda lg, ch: logprob_aux(lg, ch, 20),
                         (sd((B, V), f32), row(i32))),
+        "greedy_tokens": (64, greedy_tokens, (sd((B, V), f32),)),
     }
 
 
-@pytest.mark.parametrize("user", ["sample_tokens", "verify_greedy_draft",
-                                  "logprob_aux"])
+# a greedy draw selects nothing: one argmax, at any V
+GREEDY_USERS = ("verify_greedy_draft", "greedy_tokens")
+USERS = ["sample_tokens", "verify_greedy_draft", "logprob_aux",
+         "greedy_tokens"]
+
+
+def _arms(V):
+    """(greedy arm, sampled arm) of ``sample_tokens``' branch, each a
+    jaxpr to read alone."""
+    _, fn, shapes = _users(8, V)["sample_tokens"]
+    conds = [e for e in _eqns(jax.make_jaxpr(fn)(*shapes).jaxpr)
+             if e.primitive.name == "cond"]
+    assert len(conds) == 1, conds
+    # lax.cond(pred, true, false) keeps them as (false, true)
+    return conds[0].params["branches"]
+
+
+@pytest.mark.parametrize("user", USERS)
 @pytest.mark.parametrize("V", [151936, 32000, 128815])
 def test_no_selection_is_wider_than_the_candidates(V, user):
     k, fn, shapes = _users(8, V)[user]
     found = _selections(fn, *shapes)
+    if user in GREEDY_USERS:
+        assert found == []
+        return
     widest = max(k * C, -(-V // C))
     assert found and all(w <= widest for _, w in found), found
     # the chunk maxima, then the candidates
     assert found == [("sort", -(-V // C)), ("sort", k * C)]
 
 
-@pytest.mark.parametrize("user", ["sample_tokens", "verify_greedy_draft",
-                                  "logprob_aux"])
+@pytest.mark.parametrize("user", USERS)
 def test_a_tiny_vocabulary_takes_the_plain_call(user):
     V = 512
     _, fn, shapes = _users(8, V)[user]
-    assert _selections(fn, *shapes) == [("top_k", V)]
+    assert _selections(fn, *shapes) == (
+        [] if user in GREEDY_USERS else [("top_k", V)])
+
+
+@pytest.mark.parametrize("V", [151936, 32000, 128815, 512])
+def test_the_greedy_arm_of_sample_tokens_selects_nothing(V):
+    """Every selection of ``sample_tokens`` sits in the arm a sampled
+    row switches on; the arm of an all-greedy batch, read alone, holds
+    one argmax and nothing the width of the vocabulary but its input."""
+    greedy_arm, sampled_arm = _arms(V)
+    assert _selections_in(greedy_arm.jaxpr) == []
+    assert _selections_in(sampled_arm.jaxpr) == (
+        [("top_k", V)] if V == 512 else
+        [("sort", -(-V // C)), ("sort", 64 * C)])
+    names = [e.primitive.name for e in _eqns(greedy_arm.jaxpr)]
+    assert "argmax" in names
+    assert not {"div", "pad", "gather", "cumsum"} & set(names), names
+    # the temperature's divide stands ABOVE the branch, once, where a
+    # head's output fusion takes it in: neither arm divides the logits
+    _, fn, shapes = _users(8, V)["sample_tokens"]
+    wide = lambda jaxpr: [e for e in _eqns(jaxpr) if e.primitive.name == "div"
+                          and e.outvars[0].aval.shape == (8, V)]
+    assert len(wide(jax.make_jaxpr(fn)(*shapes).jaxpr)) == 1
+    assert wide(greedy_arm.jaxpr) == wide(sampled_arm.jaxpr) == []
+
+
+@pytest.mark.parametrize("mix", ["greedy", "one_of_eight", "last_alone"])
+def test_the_branch_inside_a_while_loop_draws_the_same(mix):
+    """The block window's place for the draw: the body of a
+    ``lax.while_loop`` under ``jit``. Same tokens as outside, on either
+    arm."""
+    V, n = 32000, 3
+    temperature, top_k, top_p = ROW_MIXES[mix]
+    args = (jnp.asarray(temperature, jnp.float32),
+            jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32),
+            jnp.arange(B_TOK, dtype=jnp.uint32) * 7919 + 11)
+    logits = jnp.stack([_logits(B_TOK, V, seed=V + i, tied=True)
+                        for i in range(n)])
+
+    @jax.jit
+    def looped(logits):
+        def body(c):
+            i, out = c
+            tok = sample_tokens(logits[i], *args,
+                                jnp.full((B_TOK,), i, jnp.int32))
+            return i + 1, out.at[i].set(tok)
+        return jax.lax.while_loop(
+            lambda c: c[0] < n, body,
+            (jnp.int32(0), jnp.zeros((n, B_TOK), jnp.int32)))[1]
+
+    want = np.stack([np.asarray(_ref_sample_tokens(
+        logits[i], *args, jnp.full((B_TOK,), i, jnp.int32)))
+        for i in range(n)])
+    np.testing.assert_array_equal(np.asarray(looped(logits)), want)
 
 
 def test_the_shape_alone_decides():

@@ -309,6 +309,16 @@ def _pool_sized_copies(text: str, pool_elems: int):
     return out
 
 
+def _computations_with(text: str, shape: str, op: str):
+    """The optimized program's computations (a fusion's body is one)
+    that hold an `op` whose result is of `shape`, each as its text."""
+    import re
+
+    found = re.compile(r" = %s\S* %s\(" % (re.escape(shape), op))
+    return [body for body in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+            if found.search(body)]
+
+
 def test_pool_sized_copy_is_recognised():
     """The guard below reads the compiler's text: it must see the copies
     the parent of PR 30 made (lines from its compiled window)."""
@@ -713,9 +723,30 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
     pool_bytes = kv_k.size * kv_k.dtype.itemsize
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     # the agreement check's variant holds [B x 4, V] log-probabilities
-    # and their top 20 besides
-    assert mem.temp_size_in_bytes < pool_bytes * (
-        2 if program == "window-logprobs" else 1)
+    # and their top 20 besides. The other: 145 MB + THREE arrays of the
+    # logits' size (156 MB each) in the arm of the draw that a sampled
+    # row switches on, where the program without a branch held two: the
+    # head's output (the logits over the temperature) is the operand of
+    # sample_tokens' conditional, and an operand lives as long as its
+    # conditional, so exact_top_k's two relayouts stand beside it and not
+    # in its place (456 -> 612 MB; wherever the divide stands)
+    logits_bytes = B * cfg.block_length * cfg.vocab_size * 4
+    assert mem.temp_size_in_bytes < (
+        2 * pool_bytes if program == "window-logprobs"
+        else pool_bytes + logits_bytes)
+    # what the arm of a sampled row costs beside that is the parent's:
+    # the two relayouts a draw (a denoising forward's, the commit's), and
+    # the temperature's divide in the head's own output fusion, not a
+    # pass over the logits of its own
+    if program == "window":
+        import re
+        text, rows, V = compiled.as_text(), B * cfg.block_length, cfg.vocab_size
+        chunks = "%d,%d,128" % (rows, -(-V // 128))
+        assert len(re.findall(r" = f32\[(?:%d,%d|%s)\]\S* copy\(" % (
+            rows, V, chunks), text)) == 4
+        wide = _computations_with(text, "f32[%d,%d]" % (rows, V), "divide")
+        assert wide and all(" convolution(" in body for body in wide), (
+            [body.splitlines()[0] for body in wide])
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 15.75 * 1024 ** 3)
 

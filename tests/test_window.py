@@ -212,3 +212,86 @@ def test_every_modules_window_is_named_decode_window(cfg):
     cfg = cfg()
     fn = get_model_module(cfg).make_decode_window_fn(cfg, True, 64)
     assert fn.__name__ == "decode_window"
+
+
+# ------------------------------------------------- the draw's two arms
+# sample_tokens branches at run time on whether a row is sampled. One
+# window of make_window's loop and one of the block window, through the
+# engine: an all-greedy batch and a batch with one sampled row emit what
+# they emit with the sampler as it stood before the branch, and the
+# engine counts the windows that had a sampled row.
+
+
+def _llama_engine():
+    import jax
+    from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
+    from dynamo_tpu.models import llama
+
+    cfg = _qwen3_moe()
+    cfg.dtype = "float32"
+    return JaxEngine(
+        cfg, EngineConfig(page_size=4, num_pages=64, max_batch=4,
+                          prefill_chunk=16, batch_buckets=(4,),
+                          prefill_buckets=(16,), page_buckets=(16,),
+                          max_prefill_batch=2, decode_steps=8,
+                          warmup_logprobs=False),
+        params=llama.init_params(cfg, jax.random.PRNGKey(0)), seed=0)
+
+
+def _parents_sample_tokens(logits, temperature, top_k, top_p, seeds, step,
+                           max_top_k=64, penalties=None):
+    from tests.test_sampling_topk import _ref_sample_tokens
+
+    assert penalties is None
+    return _ref_sample_tokens(logits, temperature, top_k, top_p, seeds,
+                              step, max_top_k)
+
+
+@pytest.mark.parametrize("make,tokens", [
+    pytest.param(_llama_engine, 9, id="llama"),
+    pytest.param(lambda: _tiny("test_sdar", "_engine"), 8,
+                 id="llama-blocks"),
+])
+def test_a_window_draws_the_parents_tokens_on_either_arm(
+        make, tokens, run_async, monkeypatch):
+    import asyncio
+
+    import jax
+    from dynamo_tpu.llm.protocols.common import SamplingOptions
+    from tests.test_sdar import _collect, _prompts, _req
+
+    p, q = _prompts(47, 12, 12)
+
+    async def batch(eng, sampled):
+        reqs = [_req(p, tokens), _req(q, tokens)]
+        if sampled:
+            reqs[1].sampling = SamplingOptions(temperature=1.3, top_k=20,
+                                               top_p=0.9, seed=11)
+        outs = await asyncio.gather(*(_collect(eng, r) for r in reqs))
+        s = eng.stats()
+        return ([t for t, _, _ in outs], s["decode_windows_total"],
+                s["decode_windows_sampled_total"])
+
+    async def main(eng):
+        got = [await batch(eng, False), await batch(eng, True)]
+        await eng.stop()
+        return got
+
+    greedy, mixed = run_async(main(make()))
+    assert all(len(t) == tokens for t in greedy[0] + mixed[0])
+    assert greedy[0][0] == mixed[0][0] and greedy[0][1] != mixed[0][1]
+    # a row's whole generation is one window, and the engine dispatches
+    # the next before it has read that: every window of the second batch
+    # held the sampled row, none of the first
+    assert greedy[1] >= 1 and greedy[2] == 0
+    assert mixed[2] == mixed[1] - greedy[1] >= 1
+
+    # the same two batches with the sampler of before the branch
+    from dynamo_tpu.engine import jax_engine, sampling
+    from dynamo_tpu.models import window
+    jax.clear_caches()      # the programs hold the sampler they traced
+    for mod in (sampling, window, jax_engine):
+        monkeypatch.setattr(mod, "sample_tokens", _parents_sample_tokens)
+    want_greedy, want_mixed = run_async(main(make()))
+    assert greedy[0] == want_greedy[0] and mixed[0] == want_mixed[0]
+    jax.clear_caches()
