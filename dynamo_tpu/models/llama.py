@@ -364,6 +364,39 @@ def commit_window(kv: jax.Array, w: jax.Array, page_table: jax.Array,
     return flat.reshape(kv.shape)
 
 
+def _write_layer_pages(pool: jax.Array, new: jax.Array, slots: jax.Array,
+                       off, pages: int, table: jax.Array, pos: jax.Array,
+                       paged: bool) -> jax.Array:
+    """``new`` [B, T, KV, hd] into ONE layer's pages of a flat pool
+    [layers * pages, KV, ps, hd], the layer's first page at ``off``:
+    whole pages of an aligned chunk (``paged``: ``slots`` [B, T // ps]
+    page ids), one token a row by whole pages too (commit_window at
+    ``table`` / ``pos``: a row scatter makes the compiler relayout the
+    pool around it), else by rows (``slots`` [B, T] page * ps + offset).
+    Slots count from the layer's own page 0. One outside the layer's
+    pages, negative or past them, becomes the flat pool's end and is
+    dropped: shifted by ``off`` alone, a padding slot of layer l would
+    land in layer l + 1's page 0."""
+    ps = pool.shape[2]
+    if paged:
+        dst = jnp.where((slots >= 0) & (slots < pages), slots + off,
+                        pool.shape[0])
+        return _scatter_pages_paged(pool, new, dst)
+    if new.shape[1] == 1:
+        at = pos[:, 0]
+        return commit_window(pool[None], new[None], table + off, at,
+                             at + 1)[0]
+    dst = jnp.where((slots >= 0) & (slots < pages * ps),
+                    slots + off * ps, pool.shape[0] * ps)
+    return _scatter_pages(pool, new, dst)
+
+
+def _flat_pool(pool: jax.Array) -> jax.Array:
+    """[L, pages, KV, ps, hd] seen as [L * pages, KV, ps, hd]: a bitcast
+    (the merged axes are major, and kv_cache_pspec shards KV alone)."""
+    return pool.reshape(-1, *pool.shape[2:])
+
+
 def _use_pallas() -> bool:
     """Route decode attention through the Pallas kernel on TPU backends
     (DYN_DISABLE_PALLAS=1 forces the XLA gather path everywhere)."""
@@ -1031,9 +1064,19 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
 
     tokens: [B, T] (T=1 for decode); positions: [B, T] absolute positions
     (-1 for padding rows); page_table: [B, P]; flat_slots: [B, T] cache
-    write slots (page*page_size + offset, -1 to drop padding);
+    write slots (page*page_size + offset of the position's own page in
+    ``page_table``; DROP_SLOT or -1 drops a padding token);
     page_slots: optional [B, T // ps] page-granular write path for
     aligned prefill chunks (see _scatter_pages_paged).
+
+    The pools ride the scan over the layers as its CARRY, each seen as
+    [L * pages, KV, ps, hd]: layer l writes (_write_layer_pages) and
+    reads (the table shifted by l * pages) its own pages of the donated
+    buffer, so a program touches the pages of its chunk and no more. As
+    scanned xs / ys every layer's pool was sliced out, updated and put
+    into a stacked result that was then copied over the donated buffer:
+    six pool-sized ops a program, whatever the chunk held (PERF.md,
+    Findings PR 49).
 
     Returns (hidden [B, T, D], new_kv_k, new_kv_v).
     """
@@ -1056,8 +1099,14 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
         experts = [layer_params.pop(k) for k in ("w_gate", "w_up", "w_down")]
         live = positions >= 0   # padding rows make no (token, expert) pair
 
-    def layer(h, xs):
-        lp, l_idx, k_layer, v_layer = xs
+    pages = kv_k.shape[1]
+    paged = page_slots is not None
+    slots = page_slots if paged else flat_slots
+
+    def layer(carry, xs):
+        h, fk, fv = carry
+        lp, l_idx = xs
+        off = l_idx * pages
         with jax.named_scope("attn"):
             x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
                          cfg.norm_unit_offset)
@@ -1071,14 +1120,12 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             q = apply_rope(q, safe_pos, inv_freq)
             k = apply_rope(k, safe_pos, inv_freq)
             if cfg.block_length > 1:
-                k, v = _block_kv(k, v, k_layer.dtype)
-            if page_slots is not None:
-                k_layer = _scatter_pages_paged(k_layer, k, page_slots)
-                v_layer = _scatter_pages_paged(v_layer, v, page_slots)
-            else:
-                k_layer = _scatter_pages(k_layer, k, flat_slots)
-                v_layer = _scatter_pages(v_layer, v, flat_slots)
-            attn = _attention(q, k_layer, v_layer, page_table, positions,
+                k, v = _block_kv(k, v, fk.dtype)
+            fk = _write_layer_pages(fk, k, slots, off, pages, page_table,
+                                    positions, paged)
+            fv = _write_layer_pages(fv, v, slots, off, pages, page_table,
+                                    positions, paged)
+            attn = _attention(q, fk, fv, page_table + off, positions,
                               scale, allow_pallas=allow_pallas, mesh=mesh,
                               softcap=cfg.attn_logit_softcap,
                               window=cfg.sliding_window,
@@ -1087,12 +1134,13 @@ def forward(params: Params, cfg: ModelConfig, tokens: jax.Array,
             h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"], lp,
                               "ln_attn_post", cfg)
         h = _layer_ff(h, lp, cfg, mesh, experts, live, l_idx)
-        return h, (k_layer, v_layer)
+        return (h, fk, fv), None
 
-    h, (new_k, new_v) = lax.scan(
-        layer, h, (layer_params, jnp.arange(cfg.num_layers), kv_k, kv_v))
+    (h, fk, fv), _ = lax.scan(
+        layer, (h, _flat_pool(kv_k), _flat_pool(kv_v)),
+        (layer_params, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-    return h, new_k, new_v
+    return h, fk.reshape(kv_k.shape), fv.reshape(kv_v.shape)
 
 
 def logits_at(params: Params, cfg: ModelConfig, hidden: jax.Array,
@@ -1172,7 +1220,6 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
     inv_freq = rope_freqs(cfg)
     B, T = tokens.shape
     H, hd = cfg.num_heads, cfg.head_dim_
-    ps = kv_k.shape[3]
     p = layer_period(cfg)
     n_per = cfg.num_layers // p
     windows = cfg.layer_window[:p]
@@ -1191,22 +1238,8 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
         experts = [params[k] for k in ("w_gate", "w_up", "w_down")]
         live = positions >= 0
 
-    def write(pool, new, slots, off, pages, table, pos):
-        """``new`` into one layer's pages (from page ``off`` on) of a
-        kind's flat pool: whole pages of an aligned chunk, one token a
-        row by whole pages too (commit_window: a row scatter makes the
-        compiler relayout the pool around it), else by rows."""
-        if page_slots is not None:
-            dst = jnp.where((slots >= 0) & (slots < pages), slots + off,
-                            pool.shape[0])
-            return _scatter_pages_paged(pool, new, dst)
-        if T == 1:
-            at = pos[:, 0]
-            return commit_window(pool[None], new[None], table + off, at,
-                                 at + 1)[0]
-        dst = jnp.where((slots >= 0) & (slots < pages * ps),
-                        slots + off * ps, pool.shape[0] * ps)
-        return _scatter_pages(pool, new, dst)
+    paged = page_slots is not None
+    slots = page_slots if paged else flat_slots
 
     def period(carry, per):
         h, fk, fv, pk, pv = carry
@@ -1223,10 +1256,10 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
                 if window is None:
                     off = (per * n_full + a_full) * NPf
                     a_full += 1
-                    slots = page_slots if page_slots is not None \
-                        else flat_slots
-                    fk = write(fk, k, slots, off, NPf, page_table, positions)
-                    fv = write(fv, v, slots, off, NPf, page_table, positions)
+                    fk = _write_layer_pages(fk, k, slots, off, NPf,
+                                            page_table, positions, paged)
+                    fv = _write_layer_pages(fv, v, slots, off, NPf,
+                                            page_table, positions, paged)
                     with jax.named_scope("attn.full"):
                         attn = _attention(
                             q, fk, fv, page_table + off, positions,
@@ -1235,8 +1268,10 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
                 else:
                     off = (per * n_win + a_win) * NPw
                     a_win += 1
-                    pk = write(pk, k, wslots, off, NPw, wtable, rel_pos)
-                    pv = write(pv, v, wslots, off, NPw, wtable, rel_pos)
+                    pk = _write_layer_pages(pk, k, wslots, off, NPw, wtable,
+                                            rel_pos, paged)
+                    pv = _write_layer_pages(pv, v, wslots, off, NPw, wtable,
+                                            rel_pos, paged)
                     with jax.named_scope("attn.window"):
                         attn = _attention(
                             q, pk, pv, wtable + off, rel_pos,
@@ -1248,14 +1283,13 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
             h = _layer_ff(h, lp, cfg, mesh, experts, live, l_idx, logits)
         return (h, fk, fv, pk, pv), None
 
-    def flat(pool):
-        return pool.reshape(-1, *pool.shape[2:])
-
     # the pools ride the scan as its CARRY, each seen as [layers * pages,
-    # ...]: as scanned xs / ys (forward's form) the results are buffers
-    # of their own, a second copy of every pool among the temporaries
+    # ...] (forward's form): as scanned xs / ys the results would be
+    # buffers of their own, a second copy of every pool among the
+    # temporaries
     (h, fk, fv, pk, pv), _ = lax.scan(
-        period, (h, flat(kv_k), flat(kv_v), flat(wk), flat(wv)),
+        period, (h, _flat_pool(kv_k), _flat_pool(kv_v), _flat_pool(wk),
+                 _flat_pool(wv)),
         jnp.arange(n_per, dtype=jnp.int32))
     h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps,
                  cfg.norm_unit_offset)
@@ -1306,7 +1340,10 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
     """Build the jitted (prefill_step, decode_step) pair for one config.
 
     Closures instead of static args because ModelConfig holds dicts
-    (rope_scaling). KV buffers are donated so XLA updates pages in place.
+    (rope_scaling). The KV buffers are donated and ride ``forward``'s scan
+    as its carry, so the pools a program returns ARE its operands, with
+    the chunk's pages scattered in: no op has a pool-sized output of its
+    own (tests/test_tpu_compile.py).
     With a >1-device ``mesh`` the Pallas attention kernels run per
     model-shard via shard_map (see _attention); ``allow_pallas=False``
     forces the XLA gather path everywhere.

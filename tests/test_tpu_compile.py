@@ -319,6 +319,20 @@ def _computations_with(text: str, shape: str, op: str):
             if found.search(body)]
 
 
+def _cell_config(name: str, experts: int):
+    """A benchmark configuration at every published width, the expert
+    count cut so that a whole program's compile stays short."""
+    import dataclasses
+
+    from dynamo_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", name))
+    return dataclasses.replace(
+        cfg, num_experts=experts,
+        num_experts_per_tok=min(cfg.num_experts_per_tok, experts))
+
+
 def test_pool_sized_copy_is_recognised():
     """The guard below reads the compiler's text: it must see the copies
     the parent of PR 30 made (lines from its compiled window)."""
@@ -347,16 +361,7 @@ def test_window_commit_makes_no_pool_sized_copy(one_chip, tpu_kernel_path,
     A row scatter in kv_carry brings back eight relayout copies of the
     pool a window (four at KV 8) and one pool of temporaries: 14% of
     cell 2's device time (ledger, PR 29)."""
-    import dataclasses
-
-    from dynamo_tpu.models.config import ModelConfig
-
-    cfg = ModelConfig.from_local_path(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "benchmark", "configs", name))
-    cfg = dataclasses.replace(
-        cfg, num_experts=experts,
-        num_experts_per_tok=min(cfg.num_experts_per_tok, experts))
+    cfg = _cell_config(name, experts)
     params, kv_k, kv_v = _engine_shapes(cfg, one_chip, num_pages)
     assert kv_k.shape == (cfg.num_layers, num_pages, cfg.num_kv_heads, PS,
                           128)
@@ -369,6 +374,51 @@ def test_window_commit_makes_no_pool_sized_copy(one_chip, tpu_kernel_path,
     pool_bytes = kv_k.size * kv_k.dtype.itemsize
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("name,experts,cell,pool,parent_temp", [
+    ("mixtral-8x7b", 2, "mixtral-8x7b.chat-steady", (3, 768, 8, PS, 128),
+     1_746_892_288),
+    ("qwen3-30b-a3b", 8, "qwen3-30b-a3b.decode-heavy", (6, 1280, 4, PS, 128),
+     1_494_419_968)])
+def test_prefill_carries_its_pools_and_copies_none(one_chip, tpu_kernel_path,
+                                                   name, experts, cell, pool,
+                                                   parent_temp):
+    """The largest prefill program of the benchmark's cells 1 / 3 (PB 4 x
+    T 512 on [3, 768, 8, 64, 128]) and of cell 2 (PB 8 x T 256 on [6,
+    1280, 4, 64, 128]), from the cells' engine data, every width as
+    published, the expert count cut so the compile stays short: the
+    pools ride llama.forward's scan as its carry, seen as [L * pages,
+    ...], and a layer scatters its chunk's pages into the donated
+    buffer. So no copy of a pool's size exists, both pools alias their
+    inputs, and the temporaries hold no pool. As scanned xs / ys (until
+    PR 49) the program held two plain copies of a pool, a per-layer
+    slice and update of each pool's size, and three pools among its
+    temporaries: 1,746,892,288 bytes where this form reads 370,185,216
+    (cells 1 / 3, two experts) and 1,494,419,968 where it reads 609,792
+    (cell 2, eight experts) (scratch compiles, PR 49); with every
+    expert 1.34 -> 0.22 GB and 2.17 -> 0.55 GB, and 0 where the parent
+    took 1.67 GB more for each GB of pool."""
+    import json
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           cell + ".json")) as f:
+        e = json.load(f)["engine"]
+    cfg = _cell_config(name, experts)
+    params, kv_k, kv_v = _engine_shapes(cfg, one_chip, e["num_pages"])
+    assert kv_k.shape == pool
+    s = partial(_sds, one_chip)
+    PB, T = e["max_prefill_batch"], e["prefill_buckets"][-1]
+    compiled = llama.make_step_fns(cfg)[0].lower(
+        params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k, kv_v,
+        s((PB, e["page_buckets"][-1]), jnp.int32), s((PB, T), jnp.int32),
+        s((PB,), jnp.int32), s((PB, T // PS), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(compiled.as_text(), kv_k.size) == []
+    mem = compiled.memory_analysis()
+    pool_bytes = kv_k.size * kv_k.dtype.itemsize
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < parent_temp - pool_bytes
 
 
 # -------------------- MLA + routed experts at Moonlight-16B-A3B's widths
@@ -667,11 +717,11 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
     (PB 8 x T 256, the prefill kernel with the block edge). The pools
     alias their inputs, and the window holds no copy of a pool's size:
     its pools are read-only inside the loops and written once, by
-    commit_window, along their major axis. The prefill holds the two
-    copies that llama.forward's scan over the pools has held at these
-    shapes since before this configuration (the same program with
-    block_length 1, cell 2's, is compiled beside it): the block mask adds
-    none and no temporary."""
+    commit_window, along their major axis. Neither does the prefill,
+    block-causal or causal (the same program with block_length 1, cell
+    2's, is compiled beside it): the pools ride llama.forward's scan as
+    its carry, a chunk's pages are scattered into the donated buffers,
+    and the block mask adds no temporary."""
     import dataclasses
     import json
 
@@ -701,8 +751,8 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
         compiled = lower(cfg)
         causal = lower(dataclasses.replace(cfg, block_length=1))
         assert _has_kernel(compiled)
-        assert len(_pool_sized_copies(compiled.as_text(), kv_k.size)) == len(
-            _pool_sized_copies(causal.as_text(), kv_k.size))
+        assert _pool_sized_copies(compiled.as_text(), kv_k.size) == []
+        assert _pool_sized_copies(causal.as_text(), kv_k.size) == []
         mem, was = compiled.memory_analysis(), causal.memory_analysis()
         assert mem.alias_size_in_bytes == was.alias_size_in_bytes
         assert mem.temp_size_in_bytes < was.temp_size_in_bytes + 2 ** 20
@@ -1012,7 +1062,8 @@ def test_smallthinker_programs_write_no_array_of_either_pools_size(
     pools through its scan over the periods and scatters whole pages.
     No copy of either pool's size exists, all four pools alias their
     inputs, and the temporaries stay far under a pool (as scanned xs /
-    ys the pools were 6.27 GiB of temporaries: scratch compile, PR 46)."""
+    ys, llama.forward's form until PR 49, the pools were 6.27 GiB of
+    temporaries: scratch compile, PR 46)."""
     cfg, params, (kv_k, kv_v), wkv, slots, e = _smallthinker(one_chip)
     s = partial(_sds, one_chip)
     P, B = e["page_buckets"][-1], e["max_batch"]
