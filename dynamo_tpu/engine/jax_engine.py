@@ -813,6 +813,11 @@ class JaxEngine:
         # tokens / rows against the slots of the bucket that ran
         self.prefill_slots_total = 0
         self.prefill_dispatches_total = 0
+        # a row's chunks, and those that start past position 0: from
+        # what the row's earlier chunks left (pages, and for a model
+        # with recurrent state the state in its slot)
+        self.prefill_row_chunks_total = 0
+        self.prefill_row_chunks_carried_total = 0
         # of those, the programs whose expert layers run as one kernel
         # (ops/moe_grouped.py): known from the bucket's rows by the rule
         # the program was built under
@@ -1493,6 +1498,9 @@ class JaxEngine:
             "prefill_tokens_total": self.prefill_tokens_total,
             "prefill_slots_total": self.prefill_slots_total,
             "prefill_dispatches_total": self.prefill_dispatches_total,
+            "prefill_row_chunks_total": self.prefill_row_chunks_total,
+            "prefill_row_chunks_carried_total":
+                self.prefill_row_chunks_carried_total,
             "moe_grouped_programs_total": self.moe_grouped_programs_total,
             # the choice of a prefill's batch bucket (_dispatch_prefill)
             "prefill_rows_held_back_total":
@@ -2208,6 +2216,8 @@ class JaxEngine:
             if self.state is not None:
                 sslots[i] = seq.state_slot
             start = seq.computed
+            self.prefill_row_chunks_total += 1
+            self.prefill_row_chunks_carried_total += start > 0
             if seq.state_from_page:
                 # the first chunk after a hit (whole pages, so start is
                 # a page's first token): the state after the page before

@@ -46,8 +46,8 @@ EVICT_POLICIES = ("lru", "cost")
 
 RECURRENT_STATE_REFUSAL = (
     "{what} is not supported for a model with recurrent state "
-    "(models/jamba.py, models/lfm2.py, models/granite.py): {why}; a state "
-    "snapshot lives in "
+    "(models/jamba.py, models/lfm2.py, models/granite.py, "
+    "models/kimi_linear.py): {why}; a state snapshot lives in "
     "the device pool under its page's id, or not at all, and nothing "
     "moves or rolls back a state (ROADMAP B7)")
 

@@ -115,6 +115,11 @@ class ForwardPassMetrics:
     kv_window_pages_released_total: int = 0
     decode_row_steps_total: int = 0
     decode_row_steps_past_window_total: int = 0
+    # a row's prefill chunks, and those that start past position 0 (from
+    # what the row's earlier chunks left: pages, and the recurrent state
+    # in its slot where the model keeps one)
+    prefill_row_chunks_total: int = 0
+    prefill_row_chunks_carried_total: int = 0
 
     def to_dict(self) -> dict:
         return dict(self.__dict__)

@@ -355,6 +355,12 @@ class MetricsAggregator:
             ("dyn_engine_decode_row_steps_past_window_total",
              "decode row-steps with positions behind their window",
              lambda m: m.decode_row_steps_past_window_total),
+            ("dyn_engine_prefill_row_chunks_total",
+             "prefill chunks dispatched, a row each",
+             lambda m: m.prefill_row_chunks_total),
+            ("dyn_engine_prefill_row_chunks_carried_total",
+             "of those, chunks that start past position 0",
+             lambda m: m.prefill_row_chunks_carried_total),
         ]
         for name, help_, get in per_worker:
             rows = [

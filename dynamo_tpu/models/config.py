@@ -149,6 +149,22 @@ class ModelConfig:
     # stands in for it.
     router_experts: int = 0
     first_expert: int = 0
+    # Kimi Linear (model_type "kimi_linear", models/kimi_linear.py):
+    # layer_types names each layer "kda" or "attention". A KDA mixer
+    # (Kimi Delta Attention: a gated delta rule) has kda_n_heads heads,
+    # each with a [kda_head_dim, kda_head_dim] float32 matrix of state
+    # that decays A KEY CHANNEL and is corrected by a rank-1 delta a
+    # token; q, k and v each pass a causal depthwise convolution of
+    # mamba_d_conv taps (the conv tails' pool has jamba.py's ranks).
+    # kda_chunk_size is the chunk of the matmul form a prompt runs (the
+    # program's choice, in no published file). An attending layer is
+    # MLA (the latent ranks above) and, with mla_nope, applies no
+    # rotation to the qk_rope_head_dim shared columns. kda_n_heads > 0
+    # switches the model module.
+    kda_n_heads: int = 0
+    kda_head_dim: int = 128
+    kda_chunk_size: int = 16
+    mla_nope: bool = False
     # Generation by diffusion over blocks (model_type "sdar_moe"): the
     # attention mask is causal across blocks of block_length positions and
     # bidirectional inside one, the logits at a position are the
@@ -197,7 +213,8 @@ class ModelConfig:
 
     @property
     def has_recurrent_state(self) -> bool:
-        return self.mamba_d_state > 0 or "conv" in self.layer_types
+        return (self.mamba_d_state > 0 or "conv" in self.layer_types
+                or self.kda_n_heads > 0)
 
     def check_page_size(self, page_size: int) -> None:
         """Refuse a KV page that a block of the mask straddles: a
@@ -427,6 +444,8 @@ class ModelConfig:
             c._read_granite(cfg)
         if mt == "smallthinker":
             c._read_smallthinker(cfg)
+        if mt == "kimi_linear":
+            c._read_kimi_linear(cfg)
         if mt in ("gemma", "gemma2"):
             # Gemma rides the Llama GQA stack with four semantic switches
             c.model_type = "gemma"
@@ -502,6 +521,88 @@ class ModelConfig:
         self.hidden_act = "relu"
         self.num_experts = cfg["moe_num_primary_experts"]
         self.num_experts_per_tok = cfg["moe_num_active_primary_experts"]
+
+    def _read_kimi_linear(self, cfg: dict) -> None:
+        """The keys of a ``kimi_linear`` config.json. The two lists of
+        ``linear_attn_config`` count layers from 1 and are kept whole in
+        a file cut in depth: the entries up to ``num_hidden_layers`` are
+        the layers that run. ``num_experts`` is the experts HELD; a file
+        cut to a chip's share names the published count
+        (``router_num_experts``) and the first expert held
+        (``first_local_expert``) beside it, as granite's does."""
+        def refuse(what: str, why: str):
+            raise NotImplementedError(
+                f"kimi_linear with {what} is not supported ({why})")
+
+        L = cfg["num_hidden_layers"]
+        lin = cfg["linear_attn_config"]
+        kda = [l for l in lin["kda_layers"] if l <= L]
+        full = [l for l in lin["full_attn_layers"] if l <= L]
+        if sorted(kda + full) != list(range(1, L + 1)):
+            refuse(f"kda_layers {kda} and full_attn_layers {full}",
+                   f"up to num_hidden_layers they must partition the "
+                   f"layers 1..{L}: every layer is of exactly one kind")
+        if not kda or not full:
+            refuse("layers of one kind only",
+                   "the state pool holds the KDA layers and the latent "
+                   "pools the attending ones; a model of one kind is "
+                   "another module's")
+        if not cfg.get("mla_use_nope", False):
+            refuse("mla_use_nope false",
+                   "its attending layers apply no rotation to the shared "
+                   "key columns, and no cell would run the rotated form")
+        if cfg.get("q_lora_rank"):
+            refuse(f"q_lora_rank {cfg['q_lora_rank']}",
+                   "its attending layers project the queries at full "
+                   "rank")
+        if (cfg.get("num_expert_group") or 1) != 1 \
+                or (cfg.get("topk_group") or 1) != 1:
+            refuse(f"num_expert_group {cfg.get('num_expert_group')} / "
+                   f"topk_group {cfg.get('topk_group')}",
+                   "the gate chooses among all the router's outputs: "
+                   "groups other than 1 are not computed")
+        if cfg.get("moe_router_activation_func", "sigmoid") != "sigmoid":
+            refuse(f"moe_router_activation_func "
+                   f"{cfg['moe_router_activation_func']!r}",
+                   "the gate scores by a sigmoid")
+        if cfg.get("moe_layer_freq", 1) != 1:
+            refuse(f"moe_layer_freq {cfg['moe_layer_freq']}",
+                   "every layer after the first_k_dense_replace dense "
+                   "ones has routed experts")
+        if cfg.get("rope_scaling"):
+            refuse("rope_scaling", "no layer rotates")
+        held = cfg["num_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("first_local_expert", 0)
+        if not 0 <= first <= width - held:
+            refuse(f"first_local_expert {first}",
+                   f"the {held} experts held must lie inside the "
+                   f"router's {width}")
+        if cfg["num_experts_per_token"] > width:
+            refuse(f"num_experts_per_token {cfg['num_experts_per_token']}",
+                   f"the router has {width} outputs")
+        attending = set(full)
+        self.model_type = "kimi_linear"
+        self.layer_types = tuple("attention" if l in attending else "kda"
+                                 for l in range(1, L + 1))
+        self.kda_n_heads = lin["num_heads"]
+        self.kda_head_dim = lin["head_dim"]
+        self.mamba_d_conv = lin.get("short_conv_kernel_size", 4)
+        self.mla_nope = True
+        self.q_lora_rank = 0
+        self.kv_lora_rank = cfg["kv_lora_rank"]
+        self.qk_nope_head_dim = cfg["qk_nope_head_dim"]
+        self.qk_rope_head_dim = cfg["qk_rope_head_dim"]
+        self.v_head_dim = cfg["v_head_dim"]
+        self.num_experts, self.router_experts = held, width
+        self.first_expert = first
+        self.num_experts_per_tok = cfg["num_experts_per_token"]
+        self.moe_router = "deepseek_v3"
+        self.norm_topk_prob = bool(cfg.get("moe_renormalize", True))
+        self.routed_scaling_factor = cfg.get("routed_scaling_factor", 1.0)
+        self.n_shared_experts = cfg.get("num_shared_experts", 0)
+        self.first_k_dense_replace = cfg.get("first_k_dense_replace", 0)
+        self.moe_intermediate_size = cfg["moe_intermediate_size"]
 
     def _read_granite(self, cfg: dict) -> None:
         """The keys of a ``granitemoehybrid`` config.json. ``num_local_

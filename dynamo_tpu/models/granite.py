@@ -265,7 +265,7 @@ def held_first(cfg: ModelConfig):
     return None if cfg.router_width == cfg.num_experts else cfg.first_expert
 
 
-def _moe_ff(params: Params, cfg: ModelConfig, norm, h, l, valid):
+def _moe_ff(params: Params, cfg: ModelConfig, norm, h, l, valid, l0=None):
     """(h + r * (routed experts held here + the shared expert) of
     norm(h), WINDOW_COUNTS of this layer), layer l (traced inside a
     run): jamba._dense_ff's call form."""

@@ -81,10 +81,14 @@ def segments(cfg: ModelConfig) -> List[tuple]:
     layer, count) and ("attn", attention index, layer), in layer order."""
     out: List[tuple] = []
     m = first = 0           # next Mamba index, first layer of the run
+    # a run is cut where the layers' second halves change kind (dense
+    # MLPs in the first first_k_dense_replace layers, experts after)
+    cut = cfg.first_k_dense_replace
     for a, l in enumerate((*cfg.attn_layer_ids, cfg.num_layers)):
-        if l > first:
-            out.append(("mamba", m, first, l - first))
-            m += l - first
+        for lo, hi in ((first, min(l, cut)), (max(first, cut), l)):
+            if hi > lo:
+                out.append(("mamba", m, lo, hi - lo))
+                m += hi - lo
         if l < cfg.num_layers:
             out.append(("attn", a, l))
         first = l + 1
@@ -231,17 +235,18 @@ def _ssm_chunk(s0, dt, x, b, c, a_neg):
     return s, y
 
 
-def _causal_conv(mp, x, valid, tail, dc: int):
+def _causal_conv(mp, x, valid, tail, dc: int, scope: str = "ssm.conv"):
     """silu(causal depthwise conv1d(x; conv_w, b_conv)) on a chunk x
     [B, T, C] float32 entered with the rows' last dc - 1 inputs ``tail``
-    [B, dc - 1, C]. Returns (the result [B, T, C], the next chunk's
-    tail)."""
+    [B, dc - 1, C] (no bias where the family has no ``b_conv`` leaf).
+    Returns (the result [B, T, C], the next chunk's tail)."""
     f32 = jnp.float32
     T = x.shape[1]
-    with jax.named_scope("ssm.conv"):
+    with jax.named_scope(scope):
         xp = jnp.concatenate([tail.astype(f32), x], axis=1)
         cw = mp["conv_w"].astype(f32)                       # [dc, C]
-        xc = jax.nn.silu(mp["b_conv"].astype(f32) + sum(
+        bias = mp["b_conv"].astype(f32) if "b_conv" in mp else 0.0
+        xc = jax.nn.silu(bias + sum(
             xp[:, k:k + T] * cw[k] for k in range(dc)))
         # the next chunk's tail: the dc - 1 inputs that end at the
         # row's last valid token (the old tail where it has none)
@@ -298,20 +303,96 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step):
     return out, s, tail
 
 
-def _dense_ff(params: Params, cfg: ModelConfig, norm, h, l, valid):
-    """Jamba's second half of layer l (traced inside a run): h + the
-    dense SwiGLU MLP of norm(h), and nothing counted."""
+def _dense_ff(params: Params, cfg: ModelConfig, norm, h, l, valid,
+              l0=None):
+    """Jamba's second half of layer l (traced inside the run that starts
+    at layer l0, a Python int): h + the dense SwiGLU MLP of norm(h), and
+    nothing counted."""
     lp = _at(params, ("ln_mlp", "w_gate", "w_up", "w_down"), l)
     return h + _mlp(norm(h, lp["ln_mlp"]), lp["w_gate"], lp["w_up"],
                     lp["w_down"]), None
+
+
+class Attending(NamedTuple):
+    """The attending half of a family on this layout: the attending
+    layers' attention over their pools (the module's ``init_kv_cache``
+    shapes them) in a chunk and in the fused window. ``GQA`` below is
+    Jamba's and Granite's (K and V pages a KV head, no positions,
+    llama.py's paged attention and kernels); models/kimi_linear.py
+    supplies the latent one from models/mla.py's functions. ``a`` counts
+    the attending layers; every ``attend(a, x, cache) -> (out [B, T, D],
+    cache)`` is _stack's."""
+    # (cfg, params, positions, kv_k, kv_v, page_table, flat_slots,
+    #  page_slots, allow_pallas, mesh) -> (attend, the cache _stack
+    #  carries, finish(cache) -> (kv_k, kv_v)): a prefill chunk or a K=1
+    #  decode step over the pools
+    chunk: Callable
+    # (cfg, interpret, mesh) -> (begin(w) -> buffers, attend_of(w, i,
+    #  pos) -> attend over the buffers, commit(w, buffers, pos) -> (kv_k,
+    #  kv_v)): the window's read-only pools and its own tokens' buffers
+    window: Callable
+
+
+def _gqa_chunk(cfg: ModelConfig, params: Params, positions, kv_k, kv_v,
+               page_table, flat_slots, page_slots, allow_pallas, mesh):
+    def attend(a, x, cache):
+        kv_k, kv_v = cache
+        q, k, v = _qkv(cfg, params, a, x)
+        if page_slots is not None:
+            k_l = _scatter_pages_paged(kv_k[a], k, page_slots)
+            v_l = _scatter_pages_paged(kv_v[a], v, page_slots)
+        else:
+            k_l = _scatter_pages(kv_k[a], k, flat_slots)
+            v_l = _scatter_pages(kv_v[a], v, flat_slots)
+        out = _attention(q, k_l, v_l, page_table, positions, cfg.attn_scale,
+                         allow_pallas=allow_pallas, mesh=mesh)
+        return (out.reshape(*x.shape[:2], -1) @ params["wo"][a],
+                (kv_k.at[a].set(k_l), kv_v.at[a].set(v_l)))
+
+    return attend, (kv_k, kv_v), lambda cache: cache
+
+
+def _gqa_window(cfg: ModelConfig, interpret, mesh):
+    KV, hd = cfg.num_kv_heads, cfg.head_dim_
+    n_attn = len(cfg.attn_layer_ids)
+
+    def begin(w):
+        wk = jnp.zeros((n_attn, w.start.shape[0], w.k_steps, KV, hd),
+                       w.kv_k.dtype)
+        return wk, jnp.zeros_like(wk)
+
+    def attend_of(w, i, pos):
+        def attend(a, x, cache):
+            wk, wv = cache
+            q, k, v = _qkv(cfg, w.params, a, x)
+            wk_l = wk[a].at[:, i].set(k[:, 0].astype(wk.dtype))
+            wv_l = wv[a].at[:, i].set(v[:, 0].astype(wv.dtype))
+            out = window_attention(q, w.kv_k, w.kv_v, a, w.page_table,
+                                   w.start, wk_l, wv_l, i, cfg.attn_scale,
+                                   interpret)
+            return (out.reshape(x.shape[0], 1, -1) @ w.params["wo"][a],
+                    (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
+
+        return attend
+
+    def commit(w, bufs, pos):
+        wk, wv = bufs
+        return (commit_window(w.kv_k, wk, w.page_table, w.start, pos),
+                commit_window(w.kv_v, wv, w.page_table, w.start, pos))
+
+    return begin, attend_of, commit
+
+
+GQA = Attending(_gqa_chunk, _gqa_window)
 
 
 class Blocks(NamedTuple):
     """What a family of this layout supplies (runs of state-space mixers
     between attending layers, one pool of scan state ``[S, M, N, C]``
     float32 and one of conv tails ``[S, M, (d_conv - 1) * conv_width]``);
-    the layer loops, the pools' traffic, the attention and the window
-    are this module's for all of them. models/granite.py is the second."""
+    the layer loops, the pools' traffic and the window are this module's
+    for all of them. models/granite.py is the second, and
+    models/kimi_linear.py the third, whose attending half is latent."""
     keys: tuple             # the state-space mixer's leaves, stacked [M, ...]
     mixer: Callable         # _mamba's call form
     ff: Callable            # _dense_ff's call form: the layer's second half
@@ -321,6 +402,7 @@ class Blocks(NamedTuple):
     # the layers and a window's steps and returned by the window before
     # the state: the engine adds them to stats()); none: ff returns None
     counts: tuple = ()
+    attending: Attending = GQA
 
 
 MAMBA1 = Blocks(MAMBA_KEYS, _mamba, _dense_ff,
@@ -359,8 +441,8 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
     tally = jnp.zeros(len(blocks.counts), jnp.int32) if blocks.counts \
         else None
 
-    def mlp(h, l, tally):
-        h, counted = blocks.ff(params, cfg, norm, h, l, valid)
+    def mlp(h, l, tally, l0):
+        h, counted = blocks.ff(params, cfg, norm, h, l, valid, l0)
         return h, tally if counted is None else tally + counted
 
     def add(h, out):        # a mixer's output onto the residual stream
@@ -373,7 +455,7 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
                 x = norm(h, params["ln_mixer"][l])
                 out, cache = attend(a, x, cache)
                 h = add(h, out)
-            h, tally = mlp(h, l, tally)
+            h, tally = mlp(h, l, tally, l)
             continue
         _, m0, l0, count = seg
 
@@ -398,7 +480,7 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
                         pool, slots, m, *row, fresh, interpret=interpret))
             conv = lax.dynamic_update_index_in_dim(
                 conv, tail.reshape(B, dc1 * di), m, 1)
-            h, tally = mlp(add(h, out), l0 + i, tally)
+            h, tally = mlp(add(h, out), l0 + i, tally, l0)
             return (h, ssm, conv, tally), None
 
         (h, ssm, conv, tally), _ = lax.scan(
@@ -442,24 +524,13 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
     else:
         in_pool, ssm = (state_slots, fresh, interpret), state[0]
 
-    def attend(a, x, cache):
-        kv_k, kv_v = cache
-        q, k, v = _qkv(cfg, params, a, x)
-        if page_slots is not None:
-            k_l = _scatter_pages_paged(kv_k[a], k, page_slots)
-            v_l = _scatter_pages_paged(kv_v[a], v, page_slots)
-        else:
-            k_l = _scatter_pages(kv_k[a], k, flat_slots)
-            v_l = _scatter_pages(kv_v[a], v, flat_slots)
-        out = _attention(q, k_l, v_l, page_table, positions, cfg.attn_scale,
-                         allow_pallas=allow_pallas, mesh=mesh)
-        return (out.reshape(*x.shape[:2], -1) @ params["wo"][a],
-                (kv_k.at[a].set(k_l), kv_v.at[a].set(v_l)))
-
+    attend, cache, finish = blocks.attending.chunk(
+        cfg, params, positions, kv_k, kv_v, page_table, flat_slots,
+        page_slots, allow_pallas, mesh)
     h = embed_tokens(params, cfg, tokens)
-    h, ssm, conv, (kv_k, kv_v), _ = _stack(params, cfg, h, valid, ssm, conv,
-                                           attend, (kv_k, kv_v), in_pool,
-                                           blocks)
+    h, ssm, conv, cache, _ = _stack(params, cfg, h, valid, ssm, conv,
+                                    attend, cache, in_pool, blocks)
+    kv_k, kv_v = finish(cache)
     if in_pool is None:
         ssm = _store_rows(state[0], state_slots, ssm)
     return h, kv_k, kv_v, (ssm, _store_rows(state[1], state_slots, conv))
@@ -509,51 +580,36 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     pool once and scattered back once; the scan state likewise on the XLA
     arm, and left in the pool where the kernel runs, every step reading
     and writing the rows' blocks where they lie."""
-    KV, hd = cfg.num_kv_heads, cfg.head_dim_
-    n_attn = len(cfg.attn_layer_ids)
     # one choice for both kernels, the window's attention and the scan
     scan_interpret = kernel_mode(allow_pallas, pallas_interpret)
     use_pallas = scan_interpret is not None
+    att_begin, attend_of, att_commit = blocks.attending.window(
+        cfg, scan_interpret, mesh)
 
     def begin(w):
-        wk = jnp.zeros((n_attn, w.start.shape[0], w.k_steps, KV, hd),
-                       w.kv_k.dtype)
-        wv = jnp.zeros_like(wk)
+        att = att_begin(w)
         conv = w.state[1][w.state_slots]
         ssm = w.state[0] if use_pallas else w.state[0][w.state_slots]
-        return wk, wv, ssm, conv
+        return att, ssm, conv
 
     def step(w, bufs, tok, pos, active, i):
         # a frozen or padding row flows through the matmuls; its state
         # does not move (dt masked to 0, conv tail kept) and its K/V
         # never commit
-        wk, wv, ssm, conv = bufs
+        att, ssm, conv = bufs
         B = tok.shape[0]
         in_pool = (w.state_slots, None, scan_interpret) if use_pallas \
             else None
-
-        def attend(a, x, cache):
-            wk, wv = cache
-            q, k, v = _qkv(cfg, w.params, a, x)
-            wk_l = wk[a].at[:, i].set(k[:, 0].astype(wk.dtype))
-            wv_l = wv[a].at[:, i].set(v[:, 0].astype(wv.dtype))
-            out = window_attention(q, w.kv_k, w.kv_v, a, w.page_table,
-                                   w.start, wk_l, wv_l, i, cfg.attn_scale,
-                                   scan_interpret)
-            return (out.reshape(B, 1, -1) @ w.params["wo"][a],
-                    (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
-
         h = embed_tokens(w.params, cfg, tok)[:, None]
-        h, ssm, conv, (wk, wv), counted = _stack(
-            w.params, cfg, h, active[:, None], ssm, conv, attend, (wk, wv),
-            in_pool, blocks)
+        h, ssm, conv, att, counted = _stack(
+            w.params, cfg, h, active[:, None], ssm, conv,
+            attend_of(w, i, pos), att, in_pool, blocks)
         return (logits_at(w.params, cfg, h, jnp.zeros(B, jnp.int32)),
-                (wk, wv, ssm, conv), counted)
+                (att, ssm, conv), counted)
 
     def commit(w, bufs, pos):
-        wk, wv, ssm, conv = bufs
-        kv_k = commit_window(w.kv_k, wk, w.page_table, w.start, pos)
-        kv_v = commit_window(w.kv_v, wv, w.page_table, w.start, pos)
+        att, ssm, conv = bufs
+        kv_k, kv_v = att_commit(w, att, pos)
         if not use_pallas:
             ssm = _store_rows(w.state[0], w.state_slots, ssm)
         return kv_k, kv_v, (ssm, _store_rows(w.state[1], w.state_slots,

@@ -232,7 +232,8 @@ def _deepseek_gate(x32, w_router, bias, cfg: ModelConfig):
 
 
 def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
-                      live=None, layer=None) -> jax.Array:
+                      live=None, layer=None, first=None,
+                      gate=None) -> jax.Array:
     """Routed experts plus the always-on shared experts, on x [B, T, D].
 
     ``lp`` holds one layer's router, bias and shared-expert leaves. The
@@ -243,14 +244,22 @@ def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
     or, with ``layer`` (a traced index into the expert segment), the
     whole ``[Lm, E, ...]`` parameters read in place by the sorted
     blocked dispatch, whose work follows the ``live`` (token, expert)
-    pairs (_moe_use_blocked holds the rule; the callers apply it)."""
+    pairs (_moe_use_blocked holds the rule; the callers apply it).
+    ``first``: which experts the stacks hold of those the gate scored
+    (``llama.moe_experts``: a chip's share of the layer; None = all).
+    ``gate``: the gate's (weights, indices) where the caller has made
+    them already (models/kimi_linear.py counts the pairs held)."""
     x32 = x.astype(jnp.float32)
-    with jax.named_scope("moe.router"):
-        w, topi = _deepseek_gate(x32, lp["w_router"],
-                                 lp.get("router_bias"), cfg)
+    if gate is None:
+        with jax.named_scope("moe.router"):
+            gate = _deepseek_gate(x32, lp["w_router"],
+                                  lp.get("router_bias"), cfg)
+    w, topi = gate
     out = llama.moe_experts(x32, w, topi, lp["w_gate_e"], lp["w_up_e"],
                             lp["w_down_e"], layer is not None, live=live,
-                            layer=layer)
+                            layer=layer, first=first,
+                            width=None if first is None
+                            else cfg.router_width)
     if cfg.n_shared_experts > 0:
         with jax.named_scope("moe.shared"):
             out = out + (jax.nn.silu(x @ lp["w_gate_s"])
@@ -385,7 +394,9 @@ def _latent_qkv(cfg: ModelConfig, lp, x, safe_pos, inv_freq, dtype):
     the tokens: c_kv [B, T, r] (normed) and k_rope [B, T, dr], all in
     the pools' ``dtype``; both rope parts padded with zeros to the rope
     pool's width (rope_width; no padding off the TPU), which leaves every
-    score what it was."""
+    score what it was. With ``cfg.mla_nope`` neither side is rotated
+    (``safe_pos`` and ``inv_freq`` are not read): the dr columns are a
+    second, un-compressed key part shared by the heads."""
     B, T, _ = x.shape
     H, r = cfg.num_heads, cfg.kv_lora_rank
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -395,19 +406,32 @@ def _latent_qkv(cfg: ModelConfig, lp, x, safe_pos, inv_freq, dtype):
     else:
         q_all = x @ lp["w_q"]
     q_all = q_all.reshape(B, T, H, dn + dr)
-    q_rope = apply_rope(q_all[..., dn:], safe_pos, inv_freq)
+    # with mla_nope the dr columns of both sides stay as they are made
+    q_rope = q_all[..., dn:] if cfg.mla_nope else apply_rope(
+        q_all[..., dn:], safe_pos, inv_freq)
     q_lat = jnp.einsum("bthd,rhd->bthr", q_all[..., :dn],
                        lp["w_uk"].reshape(r, H, dn),
                        preferred_element_type=jnp.float32)
     ckr = x @ lp["w_dkv"]                                  # [B, T, r + dr]
     c_kv = rms_norm(ckr[..., :r], lp["kv_norm"], cfg.rms_norm_eps)
-    k_rope = apply_rope(ckr[..., None, r:], safe_pos,
-                        inv_freq)[..., 0, :]               # one shared head
+    k_rope = ckr[..., r:] if cfg.mla_nope else apply_rope(
+        ckr[..., None, r:], safe_pos, inv_freq)[..., 0, :]  # one shared head
     pad = [(0, rope_width(cfg) - dr)]
     return (q_lat.astype(dtype),
             jnp.pad(q_rope.astype(dtype), [(0, 0)] * 3 + pad),
             c_kv.astype(dtype),
             jnp.pad(k_rope.astype(dtype), [(0, 0)] * 2 + pad))
+
+
+def _latent_out(cfg: ModelConfig, lp, out_lat, dtype):
+    """The latent context [B, T, H, r] up-projected a head (out_lat .
+    W_UV) and through W_O: what an attending layer adds, [B, T, D]."""
+    B, T = out_lat.shape[:2]
+    H, r, dv = cfg.num_heads, cfg.kv_lora_rank, cfg.v_head_dim
+    out = jnp.einsum("bthr,rhd->bthd", out_lat.astype(dtype),
+                     lp["w_uv"].reshape(r, H, dv),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, T, H * dv).astype(dtype) @ lp["w_o"]
 
 
 def _layers(params: Params, cfg: ModelConfig, h, attend, cache, mesh=None,
@@ -421,7 +445,6 @@ def _layers(params: Params, cfg: ModelConfig, h, attend, cache, mesh=None,
     MLP, the rest routed experts; both index the attention stacks by
     the absolute layer, so no stack and no pool is sliced in two."""
     B, T, _ = h.shape
-    H, r, dv = cfg.num_heads, cfg.kv_lora_rank, cfg.v_head_dim
     L = cfg.num_layers
     attn_keys = _mla_attn_keys(cfg)
 
@@ -430,11 +453,7 @@ def _layers(params: Params, cfg: ModelConfig, h, attend, cache, mesh=None,
         with jax.named_scope("attn"):
             x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps)
             out_lat, cache_l = attend(l, lp, x, cache_l)
-            # up-project the latent context per head: out = out_lat . W_UV
-            out = jnp.einsum("bthr,rhd->bthd", out_lat.astype(h.dtype),
-                             lp["w_uv"].reshape(r, H, dv),
-                             preferred_element_type=jnp.float32)
-            h = h + out.reshape(B, T, H * dv).astype(h.dtype) @ lp["w_o"]
+            h = h + _latent_out(cfg, lp, out_lat, h.dtype)
         x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps)
         return h + mlp(x, l), cache_l
 

@@ -4,8 +4,8 @@ The engine resolves init_params / init_kv_cache / make_step_fns through
 this table, so adding a family (reference: each engine adapter brings its
 own model zoo, lib/llm/src/engines/) is one module with the shared paged
 step-fn contract. The table dispatches by what a configuration HAS
-(latent ranks, Mamba-2 heads, a list of layer kinds, a state-space
-width), not by a flag per family. Five modules:
+(KDA heads, latent ranks, Mamba-2 heads, a list of layer kinds, a
+state-space width), not by a flag per family. Six modules:
 
 - ``llama.py``: one homogeneous stack of attention + MLP-or-MoE layers,
   scanned (Llama / Qwen2 / Qwen3 / Qwen3-MoE / Mixtral / Gemma shapes);
@@ -23,6 +23,15 @@ width), not by a flag per family. Five modules:
   in every layer routed experts beside one shared expert (llama.py's
   expert execution, told which experts it holds), under the embedding,
   residual and logits multipliers;
+- ``kimi_linear.py`` (``kda_n_heads`` > 0: ``kimi_linear``, asked
+  before ``is_mla``, which is true of it too): on jamba.py's layout, Kimi
+  Delta Attention mixers (a gated delta rule with a decay a key channel
+  on a matrix of state a head, a chunked matmul form for prompts,
+  ``ops/kda.py kda_step`` for decode) and, as its ``jamba.Attending``,
+  latent attention without positions built from mla.py's functions over
+  pools of the attending layers only; a dense first layer, then mla.py's
+  sigmoid gate and expert execution told which experts it holds, beside
+  a shared expert;
 - ``lfm2.py``: layers of two kinds by a list (``layer_types``), gated
   short convolutions and attention, two dense MLPs and then routed
   experts (mla.py's sigmoid gate, llama.py's expert execution).
@@ -70,10 +79,12 @@ published width and top-k, ``llama.moe_experts(first=...)`` computes the
 pairs routed to experts ``[first_expert, first_expert + num_experts)``
 in both execution forms, and the partial sum goes on; nothing stands in
 for the other chips or their exchange (one device: ROADMAP B9).
-``granite.py`` is the one module that passes it.
+``granite.py`` and ``kimi_linear.py`` (through
+``mla._deepseek_moe_mlp(first=...)``) pass it.
 
-**Which keep state.** ``jamba.py``, ``granite.py`` and ``lfm2.py`` carry
-per-sequence **recurrent state** beside the KV pages. A module declares that by
+**Which keep state.** ``jamba.py``, ``granite.py``, ``kimi_linear.py``
+and ``lfm2.py`` carry per-sequence **recurrent state** beside the KV
+pages. A module declares that by
 having ``init_state(cfg, slots)`` (a tuple of pools indexed by slot);
 the engine then owns the pools, every step program takes ``(state,
 state_slots)`` as trailing operands and returns the state last.
@@ -88,12 +99,14 @@ a page's last token writes the row's state after that token under the
 page's id, so the snapshot lives and dies with the page. ``jamba.py``
 declares none (a Mamba row is 9.3 MB; a snapshot a page is out of the
 question), nor does ``granite.py`` (36 MiB a row at nine layers of 128 x
-64 x 128 float32): for them a prefix hit counts as a miss, as before.
+64 x 128 float32) nor ``kimi_linear.py`` (12.4 MiB a row at six layers of
+32 x 128 x 128 float32): for them a prefix hit counts as a miss, as
+before.
 
 What refuses a model with recurrent state, at construction, each by a
 ``NotImplementedError`` that says "<what> is not supported for a model
 with recurrent state (models/jamba.py, models/lfm2.py,
-models/granite.py): <why>; a state
+models/granite.py, models/kimi_linear.py): <why>; a state
 snapshot lives in the device pool under its page's id, or not at all,
 and nothing moves or rolls back a state (ROADMAP B7)":
 
@@ -115,6 +128,10 @@ from .config import ModelConfig
 
 
 def get_model_module(cfg: ModelConfig):
+    if cfg.kda_n_heads > 0:     # before is_mla: its attending layers are
+        from . import kimi_linear   # latent, and its other layers keep state
+
+        return kimi_linear
     if cfg.is_mla:
         from . import mla
 

@@ -335,6 +335,11 @@ def test_every_new_key_is_cumulative_and_the_loops_slots_add_up(run_async):
                 _stream(http, base, 10 + i, max_tokens=40)
                 for i in range(3)))
             await asyncio.sleep(0.01)
+            # the three are taken in before the first read, however busy
+            # the machine is (under six workers 10 ms was not always
+            # enough: the delta below read 6 intakes for 4, PR 50)
+            while eng.stats()["intake_total"] < 4:
+                await asyncio.sleep(0.005)
             # the ledger is read somewhere inside a stats() call: the
             # wall time between two reads lies between these two
             before0 = time.perf_counter()

@@ -13,6 +13,7 @@ described device cannot be read back without one.
 """
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -976,6 +977,105 @@ def test_granite_programs_write_no_array_of_the_state_pools_size(
     if program != "prefill":
         assert "f32[%d,9,128,8192]" % B not in text
         assert mem.temp_size_in_bytes < 2 ** 29
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)
+
+
+def _kimi(one_chip):
+    """The configuration as kimi-linear-48b-a3b.doc-reason runs it (8 of
+    27 layers, 64 of 256 experts held, every width as published); params
+    and the four pools as shapes on the described chip, at the cell's
+    engine data."""
+    import json
+
+    from dynamo_tpu.models import kimi_linear
+    from dynamo_tpu.models.config import ModelConfig
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "kimi-linear-48b-a3b.doc-reason.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", "kimi-linear-48b-a3b"))
+    params = _on(one_chip, jax.eval_shape(
+        lambda: kimi_linear.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (_on(one_chip, x) for x in jax.eval_shape(
+        lambda: kimi_linear.init_kv_cache(
+            cfg, llama.KVCacheSpec(e["num_pages"], e["page_size"]))))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: kimi_linear.init_state(cfg, e["max_batch"] + 1)))
+    assert state[0].shape == (129, 6, 128, 4096)    # 2 MiB a layer a row
+    return kimi_linear, cfg, params, kv_k, kv_v, state, e
+
+
+@pytest.mark.parametrize("B", [128, 8, 1])
+def test_kda_step_kernel_compiles(one_chip, B):
+    """ops/kda.py kda_step at the new cell's pool and its three batch
+    buckets: the chip's compiler takes the 2 MiB row copies, the 6 MiB
+    ring in VMEM, the [128, 32] blocks of q, k and the decay and the
+    unrolled loop over a row's 32 heads."""
+    from dynamo_tpu.ops.kda import kda_step
+
+    s = partial(_sds, one_chip)
+    S, M, N, H, dv = 129, 6, 128, 32, 128
+    f32 = jnp.float32
+    assert _has_kernel(kda_step.lower(
+        s((S, M, N, H * dv), f32), s((B,), jnp.int32), s((), jnp.int32),
+        s((B, H, N), f32), s((B, H, N), f32), s((B, H, dv), f32),
+        s((B, H, N), f32), s((B, H), f32), s((B,), jnp.bool_)).compile())
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
+def test_kimi_linear_programs_write_no_array_of_a_pools_size(
+        one_chip, tpu_kernel_path, program):
+    """models/kimi_linear.py at the shapes of
+    kimi-linear-48b-a3b.doc-reason (matrix-state pool [129, 6, 128, 4096]
+    float32 = 1.51 GiB; latent pools of the 2 attending layers, 0.875 +
+    0.22 GiB): the fused window (B 128, 4 steps) and decode_step advance
+    the state IN the pool through the kernel: no value of the optimized
+    program has the gathered rows' shape [128, 6, 128, 4096] (1.5 GiB:
+    gathered and scattered back it would be 3 GiB a window) and no copy
+    of a pool's size exists, the state's or the latents'. A prefill
+    chunk (PB 8 x T 512) gathers its eight rows and stores them row by
+    row in place, and commits its latents once a pool: the pools alias
+    their inputs and are never copied. Every program fits beside the
+    10.7 GiB resident."""
+    kimi, cfg, params, kv_k, kv_v, state, e = _kimi(one_chip)
+    s = partial(_sds, one_chip)
+    P, B = e["page_buckets"][-1], e["max_batch"]
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    if program == "window":
+        compiled = kimi.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, state, i32, k_steps=4,
+            logprobs_topn=0).compile()
+    elif program == "decode_step":
+        compiled = kimi.make_step_fns(cfg)[1].lower(
+            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, state,
+            i32).compile()
+    else:
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        compiled = kimi.make_step_fns(cfg)[0].lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
+            state, s((PB,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    # the one large copy a B 128 program makes is of WEIGHTS: the
+    # dense-over-experts form's relayout of the 7 x 64 w_down_e matrices
+    # (1.97 GiB of temporaries once a program; PERF.md, Open questions)
+    big = _pool_sized_copies(text, state[0].size)
+    assert all("%%%s = bf16[7,64,1024,2304]" % name in text for name in big)
+    for pool in (state[0], kv_k, kv_v):
+        dims = ",".join(map(str, pool.shape))
+        assert not re.search(
+            r" = \w+\[%s\]\S* (?:copy|copy-start)\(" % dims, text), dims
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in (kv_k, kv_v, *state))
+    if program != "prefill":
+        assert "f32[%d,6,128,4096]" % B not in text
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 15.75 * 1024 ** 3)
 
