@@ -813,6 +813,9 @@ class JaxEngine:
         # tokens / rows against the slots of the bucket that ran
         self.prefill_slots_total = 0
         self.prefill_dispatches_total = 0
+        # of those, the dispatches behind which the same iteration
+        # enqueued a decode window (_step_window, rule 2)
+        self.prefill_window_topups_total = 0
         # a row's chunks, and those that start past position 0: from
         # what the row's earlier chunks left (pages, and for a model
         # with recurrent state the state in its slot)
@@ -1498,6 +1501,8 @@ class JaxEngine:
             "prefill_tokens_total": self.prefill_tokens_total,
             "prefill_slots_total": self.prefill_slots_total,
             "prefill_dispatches_total": self.prefill_dispatches_total,
+            "prefill_window_topups_total":
+                self.prefill_window_topups_total,
             "prefill_row_chunks_total": self.prefill_row_chunks_total,
             "prefill_row_chunks_carried_total":
                 self.prefill_row_chunks_carried_total,
@@ -1754,18 +1759,52 @@ class JaxEngine:
         the host round-trip overlaps device compute (the on-device carry
         makes this exact, not speculative). In order: dispatch, admit,
         read back what the last iteration dispatched, free deferred
-        pages."""
+        pages.
+
+        Prefill priority (``prefill_token_budget`` None), by what the
+        iteration can observe:
+
+        1. with something to prefill it enqueues the prefill first;
+        2. after that dispatch and admission, if NOTHING is left to
+           prefill, it enqueues the next window behind the prefill, its
+           rows taken from the in-flight window's carry as in any steady
+           iteration (``prefill_window_topups_total``); if something is
+           left (another chunk, a prompt admitted meanwhile, rows the
+           bucket choice held back) it enqueues no window and the next
+           iteration ships the next prefill;
+        3. the order of programs on the device is the one an engine
+           without (2) gives the same arrivals: the window only reaches
+           the queue one host iteration sooner. So the iteration after
+           such a pair first reads the prefill back (it lies AHEAD of the
+           window, and its rows enter ``running``) and admits, and then
+           ships a prefill if one is now due, else the next window; it
+           reads the topped-up window back only after that."""
         prev = self._pending
         prev_pf = self._pending_prefill
         budget = self.ecfg.prefill_token_budget
+        if budget is None and prev is not None and prev_pf is not None:
+            # the last iteration shipped prev behind prev_pf (rule 3)
+            self._process_prefill(prev_pf)
+            self._admit()
         if budget is None and self.prefilling:
-            # prefill priority: an iteration that ships a prefill ships
-            # no window. When the sweep ships NOTHING (every candidate
-            # restore-gated, cancelled, or cache-covered) the device
-            # would idle a whole iteration: a decode window fills it.
             self._pending_prefill = self._dispatch_prefill(None)
-            self._pending = (self._dispatch_decode_window()
-                             if self._pending_prefill is None else None)
+            # admission's host work overlaps the device; admitted
+            # sequences enter prefilling for the next sweep
+            self._admit()
+            if self._pending_prefill is None:
+                # the sweep shipped NOTHING (every candidate
+                # restore-gated, cancelled, or cache-covered): the device
+                # would idle a whole iteration, a decode window fills it
+                self._pending = self._dispatch_decode_window()
+            elif not self.prefilling:
+                # rule 2. The rows of the prefill before this one, long
+                # done on the device, decode in this window too
+                if prev_pf is not None:
+                    self._process_prefill(prev_pf)
+                self._pending = self._dispatch_decode_window()
+                self.prefill_window_topups_total += self._pending is not None
+            else:
+                self._pending = None
         else:
             # budgeted mixing (or nothing to prefill): decode windows
             # keep their cadence even while prompts are prefilling
@@ -1774,9 +1813,7 @@ class JaxEngine:
             if (self._pending is not None
                     and self._pending_prefill is not None):
                 self.mixed_dispatches += 1
-        # AFTER the dispatches: admission's host work overlaps the device;
-        # admitted sequences enter prefilling for the next sweep
-        self._admit()
+            self._admit()
         if prev is not None:
             self._process_window(prev)
         if prev_pf is not None:

@@ -139,22 +139,29 @@ def _submit(eng, req):
 
 
 def _spy_dispatches(eng):
-    """Per _step: which of the two dispatches were called and shipped."""
-    log = []
-    for kind in ("_dispatch_prefill", "_dispatch_decode_window"):
-        def spy(*a, _fn=getattr(eng, kind), _kind=kind, **kw):
+    """Per _step: which of the two dispatches were called and shipped.
+    ``stepped.order`` has a string an iteration of what it shipped, in
+    the order it reached the device's queue: P a prefill, W a window."""
+    log, order = [], []
+    for kind, mark in (("_dispatch_prefill", "P"),
+                       ("_dispatch_decode_window", "W")):
+        def spy(*a, _fn=getattr(eng, kind), _kind=kind, _mark=mark, **kw):
             out = _fn(*a, **kw)
             log[-1][_kind] = out is not None
+            if out is not None:
+                order[-1] += _mark
             return out
         setattr(eng, kind, spy)
     step = eng._step
 
     def stepped():
         log.append({"running": len(eng.running)})
+        order.append("")
         step()
         eng._reap()
         return log[-1]
 
+    stepped.order = order
     return stepped, log
 
 
@@ -166,22 +173,138 @@ def _step_until(stepped, cond, limit=64):
     raise AssertionError("condition not reached")
 
 
-def test_an_iteration_that_ships_a_prefill_ships_no_window():
-    """Prefill priority (prefill_token_budget None): with a row decoding,
-    the iteration that ships the second prompt's prefill does not even
-    try a window."""
+@pytest.mark.parametrize("case", ["prompt_waiting", "nothing_waiting"])
+def test_a_prefill_iteration_ships_a_window_only_behind_the_last_prefill(
+        case):
+    """Prefill priority (prefill_token_budget None), with a row decoding.
+    While a prefill is still due after the one just shipped, the
+    iteration ships no window; when nothing is left to prefill it ships
+    the prefill THEN a window behind it, and the next iteration reads the
+    prefill back before it ships anything else."""
+    waiting = case == "prompt_waiting"
+    # a prefill dispatch of one row: of two prompts admitted together
+    # the second is still due after the first ships
+    eng = JaxEngine(ModelConfig.tiny(), _ecfg(
+        decode_steps=4, max_prefill_batch=1 if waiting else 8), seed=0)
+    stepped, log = _spy_dispatches(eng)
+    first = _submit(eng, _req(range(1, 9), mt=40))
+    _step_until(stepped, lambda: first.generated >= 5)
+    assert eng.prefill_window_topups_total == 0, "nothing decoded beside it"
+    second = _submit(eng, _req(range(20, 40), mt=4))
+    third = _submit(eng, _req(range(50, 70), mt=4)) if waiting else None
+    _step_until(stepped, lambda: second in eng.prefilling)
+    if waiting:
+        assert third in eng.prefilling
+        assert stepped() == {"running": 1, "_dispatch_prefill": True}
+        assert stepped.order[-1] == "P" and eng.prefilling == [third]
+        assert eng.prefill_window_topups_total == 0
+    it = stepped()
+    assert it == {"running": 1, "_dispatch_prefill": True,
+                  "_dispatch_decode_window": True}
+    assert stepped.order[-1] == "PW" and not eng.prefilling
+    assert eng.prefill_window_topups_total == 1
+    assert eng.stats()["prefill_window_topups_total"] == 1
+    last = third if waiting else second
+    assert last not in eng.running, "the prefill is not read back yet"
+    # the prefill BEFORE the pair's was read back first: its row decodes
+    # in the window behind the pair, as it would one iteration later
+    assert eng._pending.batch == ([first, second] if waiting else [first])
+    # the iteration after the pair: one window, and the prefill's row is
+    # in it, so the prefill was read back before that window shipped
+    stepped()
+    assert stepped.order[-1] == "W"
+    assert [s for s in eng._pending.batch if s is not first] == (
+        [second, third] if waiting else [second])
+    _step_until(stepped, lambda: all(
+        s.finished for s in (first, second, third) if s is not None))
+    assert eng.mixed_dispatches == 0
+    assert first.generated == 40 and second.generated == 4
+    assert eng.prefill_dispatches_total == (3 if waiting else 2)
+    assert eng.prefill_window_topups_total == 1
+
+
+def test_the_order_of_programs_is_the_one_without_the_top_up():
+    """Rule 3 of _step_window: the window behind a prefill reaches the
+    queue an iteration sooner and nothing else moves. A prompt that lands
+    in the iteration after the pair is served before any second window:
+    P W P' W, never P W W P'."""
     eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
     stepped, log = _spy_dispatches(eng)
-    first = _submit(eng, _req(range(1, 9), mt=24))
+    first = _submit(eng, _req(range(1, 9), mt=48))
     _step_until(stepped, lambda: first.generated >= 5)
-    second = _submit(eng, _req(range(20, 40), mt=4))
-    _step_until(stepped, lambda: second.finished and first.finished)
-    shipped = [it for it in log if it.get("_dispatch_prefill")]
-    assert len(shipped) == 2
-    assert shipped[1]["running"] == 1, "no row was decoding beside it"
-    assert all("_dispatch_decode_window" not in it for it in shipped)
-    assert eng.mixed_dispatches == 0
-    assert first.generated == 24 and second.generated == 4
+    second = _submit(eng, _req(range(20, 40), mt=8))
+    _step_until(stepped, lambda: second in eng.prefilling)
+    start = len(stepped.order)
+    stepped()                                   # P W
+    third = _submit(eng, _req(range(50, 70), mt=8))
+    stepped()                                   # P' W
+    assert stepped.order[start:] == ["PW", "PW"]
+    assert second in eng.running and third not in eng.running
+    assert eng._pending.batch == [first, second]
+    stepped()                                   # third's row joins
+    assert stepped.order[-1] == "W"
+    assert eng._pending.batch == [first, second, third]
+    assert eng.prefill_window_topups_total == 2
+    _step_until(stepped, lambda: first.finished and second.finished
+                and third.finished)
+    assert (first.generated, second.generated, third.generated) == (48, 8, 8)
+
+
+def test_no_window_between_the_chunks_of_one_prompt():
+    """The same arrival with a prompt of two chunks: P1 P2 W."""
+    eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
+    stepped, log = _spy_dispatches(eng)
+    first = _submit(eng, _req(range(1, 9), mt=40))
+    _step_until(stepped, lambda: first.generated >= 5)
+    second = _submit(eng, _req(range(20, 60), mt=4))     # 40 > chunk 32
+    _step_until(stepped, lambda: second in eng.prefilling)
+    start = len(stepped.order)
+    _step_until(stepped, lambda: second in eng.running)
+    assert stepped.order[start:start + 2] == ["P", "PW"]
+    assert eng.prefill_window_topups_total == 1
+    _step_until(stepped, lambda: first.finished and second.finished)
+    assert first.generated == 40 and second.generated == 4
+
+
+@pytest.mark.parametrize("kind", ["greedy", "seeded", "penalties"])
+def test_window_behind_a_prefill_matches_single_step(kind, monkeypatch):
+    """A prompt arrives while a row of the pinned kind is mid-decode: the
+    window shipped behind its prefill takes the decoding row from the
+    in-flight window's carry (_merge_carry), or, for a row with sampling
+    penalties, lands that window first. Both rows get the tokens that
+    single steps give them."""
+    from dynamo_tpu.engine import jax_engine
+    merge = jax_engine._merge_carry
+    orders = []         # every engine's order, the running one last
+    merged = []         # the iterations, counted from 1, that merged
+
+    def counted(*a):
+        merged.append(len(orders[-1]))
+        return merge(*a)
+
+    monkeypatch.setattr(jax_engine, "_merge_carry", counted)
+
+    def gen(k):
+        eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=k), seed=0)
+        stepped, _ = _spy_dispatches(eng)
+        orders.append(stepped.order)
+        req = _mixed_requests()[kind]
+        req.stop.max_tokens = 24
+        first = _submit(eng, req)
+        _step_until(stepped, lambda: first.generated >= 5)
+        second = _submit(eng, _req(range(20, 40), mt=6))
+        _step_until(stepped, lambda: first.finished and second.finished)
+        return [s.tokens[s.num_prompt:] for s in (first, second)], eng
+
+    single, _ = gen(1)
+    assert not merged
+    window, eng = gen(4)
+    assert window == single
+    assert [len(t) for t in window] == [24, 6]
+    assert eng.prefill_window_topups_total == 1
+    # order[0] is the first prompt's own prefill, with nothing to decode
+    pair = orders[-1].index("PW") + 1
+    assert (pair in merged) == (kind != "penalties")
 
 
 def test_a_sweep_that_ships_nothing_still_ships_a_window():

@@ -347,6 +347,48 @@ def test_generate_equals_reference_generate(strategy, run_async):
     assert eng.decode_tokens_total == 9 * len(prompts)
 
 
+def test_a_prompt_arriving_mid_decode_equals_reference_generate():
+    """The scheduler's window behind a prefill (JaxEngine._step_window,
+    rule 2) for a model that generates by blocks: a second prompt arrives
+    while the first row is between two windows, the window shipped behind
+    its prefill gives the first row a fresh block from the in-flight
+    window's carry, and both rows answer as the plain loop does. The
+    engine is stepped by hand, so the arrival falls where it is meant."""
+    from dynamo_tpu.engine.jax_engine import Sequence
+    cfg = tiny()
+    params = make_params(cfg, 3)
+    eng = _engine(cfg, params)
+    first_p, second_p = _prompts(9, 14, 13)
+
+    def submit(prompt, n):
+        req = _req(prompt, n)
+        seq = Sequence(req=req, context=Context(), out=asyncio.Queue(),
+                       tokens=list(req.token_ids),
+                       num_prompt=len(req.token_ids), block=eng.block)
+        eng.waiting.append(seq)
+        return seq
+
+    def step_until(cond):
+        for _ in range(64):
+            if cond():
+                return
+            eng._step()
+            eng._reap()
+        raise AssertionError("condition not reached")
+
+    first = submit(first_p, 40)
+    step_until(lambda: first.generated >= 8)
+    assert eng.prefill_window_topups_total == 0
+    second = submit(second_p, 9)
+    step_until(lambda: eng.prefill_window_topups_total == 1)
+    assert eng._pending.batch == [first], "the window behind the prefill"
+    step_until(lambda: first.finished and second.finished)
+    for seq, prompt, n in ((first, first_p, 40), (second, second_p, 9)):
+        want, _, _ = REF.reference_generate(params, cfg, prompt, n)
+        assert seq.tokens[seq.num_prompt:] == want
+    assert eng.prefill_window_topups_total == 1
+
+
 def test_the_agreement_check_form_is_exact_under_sequential(run_async):
     """What benchmark/harness/serve.py agree does: the reference is handed
     ``prompt + toks[:-1]`` and row ``len(prompt) - 1 + j`` is compared
