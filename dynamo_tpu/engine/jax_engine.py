@@ -488,10 +488,15 @@ class _PendingPrefill:
 class JaxEngine:
     """AsyncEngine over the JAX model (token-level core engine)."""
 
+    @profiling.setup_span("engine_init")
     def __init__(self, model_cfg: ModelConfig, engine_cfg: Optional[EngineConfig]
                  = None, params=None, seed: int = 0, dtype=None, mesh=None,
                  quant: Optional[str] = None,
                  worker_label: Optional[str] = None):
+        # before this process's first jit where enable_compile_cache()
+        # was not called (a test, a library user): the pools below are
+        # eager programs already
+        profiling.install_jit_listeners()
         self.cfg = model_cfg
         self.ecfg = engine_cfg or EngineConfig()
         # dynashard replica identity: a STABLE per-replica label (e.g.
@@ -993,18 +998,32 @@ class JaxEngine:
         latency. Returns the number of programs compiled.
         ``decode=False`` skips the decode-window grid — for prefill-only
         workers (disagg), whose engine never runs a decode step."""
-        with self._on_device():
-            return self._warmup(progress, decode)
+        with self._on_device(), \
+                profiling.setup_ledger().span("warmup") as span:
+            n = self._warmup(progress, decode, span)
+        # the span's own seconds: stats() shows both, and they agree
+        self.warmup_seconds = span.seconds
+        return n
 
-    def _warmup(self, progress: bool, decode: bool) -> int:
+    @contextlib.contextmanager
+    def _warm(self, kind: str):
+        """One program of warmup()'s grid: a ``warmup.<kind>`` span of the
+        set-up ledger and, under the call form the fence noted, a row of
+        ``warmup_programs`` (the span's seconds and jit stages)."""
+        ledger = profiling.setup_ledger()
+        with ledger.span("warmup." + kind) as span:
+            yield
+        ledger.add_warm_program(self.fence.last_dispatch_form(), span)
+
+    def _warmup(self, progress: bool, decode: bool, span) -> int:
         ecfg = self.ecfg
+        bracket = profiling.setup_ledger().span
         # the EXACT reachable shape images (not the declared bucket
         # tuples): _pick doubles past its last bucket, so exotic configs
         # reach shapes the tuples alone would miss — compiling them
         # mid-serving (the compile fence below counts such misses)
         grid = ecfg.warmed_grid()
         page_buckets = grid["page_buckets"] or [8]
-        t0 = time.monotonic()
         n = 0
         # under a mesh: the committed (NamedSharding) decode-window carry
         # per batch bucket, captured below to warm the pipelined call
@@ -1014,22 +1033,25 @@ class JaxEngine:
         for P in page_buckets:
             for T in grid["prefill_lens"]:
                 for PB in prefill_bs:
-                    # warm exactly the serving variant: page-granular
-                    # commit for ps-aligned buckets, row scatter otherwise
-                    pslots = (jnp.full((PB, T // ecfg.page_size),
-                                       ecfg.num_pages, jnp.int32)
-                              if T % ecfg.page_size == 0 else None)
-                    logits, self.kv_k, self.kv_v = self._take_state(
-                        self.prefill_fn(
-                            self.params, jnp.zeros((PB, T), jnp.int32),
-                            jnp.zeros((PB, T), jnp.int32) - 1,
-                            self.kv_k, self.kv_v,
-                            jnp.zeros((PB, P), jnp.int32),
-                            jnp.full((PB, T), DROP_SLOT, jnp.int32),
-                            jnp.zeros((PB,), jnp.int32), pslots,
-                            *self._state_args(
-                                self._drop_slots(PB, T, pslots is not None),
-                                self._no_src(PB))))
+                    with self._warm("prefill"):
+                        # warm exactly the serving variant: page-granular
+                        # commit for ps-aligned buckets, row scatter
+                        # otherwise
+                        pslots = (jnp.full((PB, T // ecfg.page_size),
+                                           ecfg.num_pages, jnp.int32)
+                                  if T % ecfg.page_size == 0 else None)
+                        logits, self.kv_k, self.kv_v = self._take_state(
+                            self.prefill_fn(
+                                self.params, jnp.zeros((PB, T), jnp.int32),
+                                jnp.zeros((PB, T), jnp.int32) - 1,
+                                self.kv_k, self.kv_v,
+                                jnp.zeros((PB, P), jnp.int32),
+                                jnp.full((PB, T), DROP_SLOT, jnp.int32),
+                                jnp.zeros((PB,), jnp.int32), pslots,
+                                *self._state_args(
+                                    self._drop_slots(PB, T,
+                                                     pslots is not None),
+                                    self._no_src(PB))))
                     n += 1
                     if self.block > 1:
                         # prefill samples nothing and its program has no
@@ -1042,20 +1064,24 @@ class JaxEngine:
                     # _sample_device always passes penalties=, and warming
                     # the omitted form left every serving bucket one
                     # compile short (found by the compile fence)
-                    toks = sample_tokens(
-                        logits, jnp.zeros(PB),
-                        jnp.zeros(PB, jnp.int32), jnp.ones(PB),
-                        jnp.zeros(PB, jnp.uint32),
-                        jnp.zeros(PB, jnp.int32),
-                        max_top_k=ecfg.max_top_k, penalties=None)
-                    if ecfg.warmup_logprobs and ecfg.max_top_logprobs > 0:
-                        # _sample_device runs logprob_aux EAGERLY after
-                        # every prefill/decode dispatch that asked for
-                        # logprobs, so its op-by-op executables compile
-                        # per logits bucket on the first such request —
-                        # a fence trip the jitted-window variants above
-                        # don't cover (DL026, same finding class)
-                        logprob_aux(logits, toks, ecfg.max_top_logprobs)
+                    with bracket("warmup.sample"):
+                        toks = sample_tokens(
+                            logits, jnp.zeros(PB),
+                            jnp.zeros(PB, jnp.int32), jnp.ones(PB),
+                            jnp.zeros(PB, jnp.uint32),
+                            jnp.zeros(PB, jnp.int32),
+                            max_top_k=ecfg.max_top_k, penalties=None)
+                        if (ecfg.warmup_logprobs
+                                and ecfg.max_top_logprobs > 0):
+                            # _sample_device runs logprob_aux EAGERLY
+                            # after every prefill/decode dispatch that
+                            # asked for logprobs, so its op-by-op
+                            # executables compile per logits bucket on
+                            # the first such request — a fence trip the
+                            # jitted-window variants above don't cover
+                            # (DL026, same finding class)
+                            logprob_aux(logits, toks,
+                                        ecfg.max_top_logprobs)
             for B in (grid["decode_batches"] if decode else []):
                 tableB = jnp.zeros((B, P), jnp.int32)
                 if ecfg.decode_steps > 1:
@@ -1102,14 +1128,14 @@ class JaxEngine:
                                         k_steps=ecfg.decode_steps,
                                         logprobs_topn=topn), topn)
 
-                            res = window(
-                                jnp.asarray(self._blank_tokens(B)),
-                                jnp.zeros(B, jnp.int32) - 1,
-                                jnp.zeros(B, bool), jnp.zeros(B, jnp.int32),
-                                jnp.ones(B, jnp.int32))
-                            toks = res.toks
-                            if topn:
-                                n += 1
+                            with self._warm("window"):
+                                res = window(
+                                    jnp.asarray(self._blank_tokens(B)),
+                                    jnp.zeros(B, jnp.int32) - 1,
+                                    jnp.zeros(B, bool),
+                                    jnp.zeros(B, jnp.int32),
+                                    jnp.ones(B, jnp.int32))
+                            n += 1
                             if pv is None and self.mesh is not None:
                                 # committed-carry variant: under a mesh
                                 # the pipelined window's (tok, pos, done,
@@ -1126,40 +1152,46 @@ class JaxEngine:
                                 # merge-combo loop below.
                                 if topn == 0:
                                     carries[B] = res.carry
-                                toks = window(*res.carry).toks
+                                with self._warm("window"):
+                                    window(*res.carry)
                                 n += 1
                 else:
-                    logits, self.kv_k, self.kv_v = self._take_state(
-                        self.decode_fn(
-                            self.params, jnp.zeros(B, jnp.int32),
-                            jnp.zeros(B, jnp.int32) - 1, self.kv_k,
-                            self.kv_v, tableB,
-                            jnp.full((B,), DROP_SLOT, jnp.int32),
-                            *self._state_args(self._drop_slots(B, 1))))
-                    toks = sample_tokens(
-                        logits, jnp.zeros(B),
-                        jnp.zeros(B, jnp.int32),
-                        jnp.ones(B), jnp.zeros(B, jnp.uint32),
-                        jnp.zeros(B, jnp.int32),
-                        max_top_k=ecfg.max_top_k, penalties=None)
-                    if ecfg.warmup_logprobs and ecfg.max_top_logprobs > 0:
-                        logprob_aux(logits, toks, ecfg.max_top_logprobs)
+                    with self._warm("window"):
+                        logits, self.kv_k, self.kv_v = self._take_state(
+                            self.decode_fn(
+                                self.params, jnp.zeros(B, jnp.int32),
+                                jnp.zeros(B, jnp.int32) - 1, self.kv_k,
+                                self.kv_v, tableB,
+                                jnp.full((B,), DROP_SLOT, jnp.int32),
+                                *self._state_args(self._drop_slots(B, 1))))
+                    n += 1
+                    with bracket("warmup.sample"):
+                        toks = sample_tokens(
+                            logits, jnp.zeros(B),
+                            jnp.zeros(B, jnp.int32),
+                            jnp.ones(B), jnp.zeros(B, jnp.uint32),
+                            jnp.zeros(B, jnp.int32),
+                            max_top_k=ecfg.max_top_k, penalties=None)
+                        if (ecfg.warmup_logprobs
+                                and ecfg.max_top_logprobs > 0):
+                            logprob_aux(logits, toks,
+                                        ecfg.max_top_logprobs)
                 if self.verify_fn is not None:
                     # speculative verify grid: one [B, K+1] program per
                     # (B, P) bucket + the accept-mask program per B
                     Kv = ecfg.spec_tokens + 1
-                    logits, self.kv_k, self.kv_v = self.verify_fn(
-                        self.params, jnp.zeros((B, Kv), jnp.int32),
-                        jnp.zeros((B, Kv), jnp.int32) - 1, self.kv_k,
-                        self.kv_v, tableB,
-                        jnp.full((B, Kv), DROP_SLOT, jnp.int32))
-                    verify_greedy_draft(logits,
-                                        jnp.zeros((B, Kv - 1), jnp.int32),
-                                        jnp.zeros(B, jnp.int32))
+                    with self._warm("window"):
+                        logits, self.kv_k, self.kv_v = self.verify_fn(
+                            self.params, jnp.zeros((B, Kv), jnp.int32),
+                            jnp.zeros((B, Kv), jnp.int32) - 1, self.kv_k,
+                            self.kv_v, tableB,
+                            jnp.full((B, Kv), DROP_SLOT, jnp.int32))
+                        verify_greedy_draft(
+                            logits, jnp.zeros((B, Kv - 1), jnp.int32),
+                            jnp.zeros(B, jnp.int32))
                     n += 1
-                n += 1
                 if progress:
-                    print(f"warmup: {n} programs, {time.monotonic()-t0:.0f}s",
+                    print(f"warmup: {n} programs, {span.seconds:.0f}s",
                           flush=True)
         # long-context ring-prefill buckets: every padded length a served
         # long prompt can hit, so the first long request never compiles
@@ -1168,20 +1200,22 @@ class JaxEngine:
             from ..parallel.ring_attention import scatter_prefill_kv
             t = self._long_bucket(self.ecfg.long_prefill_threshold + 1)
             while True:
-                logits, k_all, v_all = self.long_prefill_fn(
-                    self.params, jnp.zeros((1, t), jnp.int32),
-                    jnp.zeros((1, t), jnp.int32) - 1)
-                self.kv_k, self.kv_v = scatter_prefill_kv(
-                    self.kv_k, self.kv_v, k_all, v_all,
-                    jnp.full((1, t), DROP_SLOT, jnp.int32))
-                toks = sample_tokens(
-                    logits, jnp.zeros(1), jnp.zeros(1, jnp.int32),
-                    jnp.ones(1), jnp.zeros(1, jnp.uint32),
-                    jnp.zeros(1, jnp.int32),
-                    max_top_k=ecfg.max_top_k, penalties=None)
-                if ecfg.warmup_logprobs and ecfg.max_top_logprobs > 0:
-                    logprob_aux(logits, toks, ecfg.max_top_logprobs)
+                with self._warm("prefill"):
+                    logits, k_all, v_all = self.long_prefill_fn(
+                        self.params, jnp.zeros((1, t), jnp.int32),
+                        jnp.zeros((1, t), jnp.int32) - 1)
+                    self.kv_k, self.kv_v = scatter_prefill_kv(
+                        self.kv_k, self.kv_v, k_all, v_all,
+                        jnp.full((1, t), DROP_SLOT, jnp.int32))
                 n += 1
+                with bracket("warmup.sample"):
+                    toks = sample_tokens(
+                        logits, jnp.zeros(1), jnp.zeros(1, jnp.int32),
+                        jnp.ones(1), jnp.zeros(1, jnp.uint32),
+                        jnp.zeros(1, jnp.int32),
+                        max_top_k=ecfg.max_top_k, penalties=None)
+                    if ecfg.warmup_logprobs and ecfg.max_top_logprobs > 0:
+                        logprob_aux(logits, toks, ecfg.max_top_logprobs)
                 if t >= self.cap_tokens:
                     break
                 t *= 2
@@ -1204,12 +1238,18 @@ class JaxEngine:
                              jnp.zeros(Bp, bool), jnp.zeros(Bp, jnp.int32),
                              jnp.ones(Bp, jnp.int32))
                 for Bn in bset:
-                    _merge_carry(*carry, jnp.zeros(Bn, jnp.int32),
-                                 jnp.zeros(Bn, bool),
-                                 jnp.asarray(self._blank_tokens(Bn)),
-                                 jnp.zeros(Bn, jnp.int32) - 1,
-                                 jnp.zeros(Bn, jnp.int32),
-                                 jnp.ones(Bn, jnp.int32))
+                    with self._warm("window"):
+                        rows = (jnp.zeros(Bn, jnp.int32),
+                                jnp.zeros(Bn, bool),
+                                jnp.asarray(self._blank_tokens(Bn)),
+                                jnp.zeros(Bn, jnp.int32) - 1,
+                                jnp.zeros(Bn, jnp.int32),
+                                jnp.ones(Bn, jnp.int32))
+                        # no wrapper stamps this program's dispatches:
+                        # its row is named from here
+                        self.fence.note_dispatch("_merge_carry",
+                                                 (*carry, *rows))
+                        _merge_carry(*carry, *rows)
                     n += 1
         # host-tier copy programs: offload gathers / restore scatters run
         # MID-SERVING on pow2-padded page batches (engine._drain_kv_tier)
@@ -1219,58 +1259,69 @@ class JaxEngine:
         if self.host_k is not None:
             size = 1
             while True:
-                idx = jnp.zeros(size, jnp.int32)
-                # the serving drain builds its index operands as
-                # jnp.asarray(<python list>, jnp.int32) — a DIFFERENT
-                # lowering (convert_element_type) from zeros/full above,
-                # one tiny program per distinct padded length. Warm that
-                # call form too, or the first drain of each pow2 size
-                # compiles mid-serving (compile-fence finding on the
-                # cache A/B arms).
-                jax.block_until_ready(jnp.asarray([0] * size, jnp.int32))
+                with bracket("warmup.kv_tier"):
+                    idx = jnp.zeros(size, jnp.int32)
+                    # the serving drain builds its index operands as
+                    # jnp.asarray(<python list>, jnp.int32) — a DIFFERENT
+                    # lowering (convert_element_type) from zeros/full
+                    # above, one tiny program per distinct padded length.
+                    # Warm that call form too, or the first drain of each
+                    # pow2 size compiles mid-serving (compile-fence
+                    # finding on the cache A/B arms).
+                    jax.block_until_ready(
+                        jnp.asarray([0] * size, jnp.int32))
                 # both pools: their page shapes differ per model family
                 # (MLA latent vs rope), so each is its own program set
                 for pool_attr in ("kv_k", "kv_v"):
-                    g = _gather_pages(getattr(self, pool_attr), idx)
-                    if self.ecfg.host_tier_int8:
-                        from .kv_compress import (dequantize_pages,
-                                                  quantize_pages)
-
-                        q, s = quantize_pages(g)
-                        if self.mesh is not None:
-                            # serving restores dequantize UNCOMMITTED
-                            # host arrays; under a mesh the committed
-                            # quantize outputs here are a different jit
-                            # cache entry — round-trip through the host
-                            # so warmup matches the serving call form
-                            q = jnp.asarray(np.asarray(q))  # dynalint: disable=implicit-host-transfer
-                            s = jnp.asarray(np.asarray(s))  # dynalint: disable=implicit-host-transfer
-                        rows = dequantize_pages(q, s)
-                    else:
-                        rows = g
-                        if self.mesh is not None:
-                            # same committed-vs-uncommitted note: serving
-                            # restores inject np views of the host pool.
-                            # Warmup-time sync, not a hot-path leak.
-                            rows = jnp.asarray(np.asarray(rows))  # dynalint: disable=implicit-host-transfer
-                    setattr(self, pool_attr, _inject_pages(
-                        getattr(self, pool_attr),
-                        jnp.full((size,), ecfg.num_pages, jnp.int32),
-                        rows))
+                    with self._warm("kv_tier"):
+                        self._warm_tier_copy(pool_attr, idx)
                     n += 1
                 if size >= self.ecfg.num_pages:
                     break
                 size *= 2
+        # the grid's first executions were dispatched without a wait:
+        # what of them the device has not finished is waited for here, in
+        # the warmup span's own seconds, under no child
         jax.block_until_ready(self.kv_k)
         self._time_prefill_programs(grid)
         # arm the runtime compile fence: from here on, ANY XLA compile is
         # a serving stall — counted always, warn/raise per DYN_JIT_FENCE
         self.fence.arm()
-        self.warmup_seconds = time.monotonic() - t0
-        log.info("warmup compiled %d programs in %.1fs", n,
-                 self.warmup_seconds)
         return n
 
+    def _warm_tier_copy(self, pool_attr: str, idx) -> None:
+        """One pool's host-tier programs at one padded size: the offload
+        gather and the restore scatter (with the int8 round trip where
+        the tier compresses)."""
+        pool = getattr(self, pool_attr)
+        # no wrapper stamps these dispatches: the row is named from here
+        self.fence.note_dispatch("_gather_pages", (pool, idx))
+        g = _gather_pages(pool, idx)
+        if self.ecfg.host_tier_int8:
+            from .kv_compress import dequantize_pages, quantize_pages
+
+            q, s = quantize_pages(g)
+            if self.mesh is not None:
+                # serving restores dequantize UNCOMMITTED host arrays;
+                # under a mesh the committed quantize outputs here are a
+                # different jit cache entry — round-trip through the host
+                # so warmup matches the serving call form
+                q = jnp.asarray(np.asarray(q))  # dynalint: disable=implicit-host-transfer
+                s = jnp.asarray(np.asarray(s))  # dynalint: disable=implicit-host-transfer
+            rows = dequantize_pages(q, s)
+        else:
+            rows = g
+            if self.mesh is not None:
+                # same committed-vs-uncommitted note: serving restores
+                # inject np views of the host pool. Warmup-time sync,
+                # not a hot-path leak.
+                rows = jnp.asarray(np.asarray(rows))  # dynalint: disable=implicit-host-transfer
+        setattr(self, pool_attr, _inject_pages(
+            getattr(self, pool_attr),
+            jnp.full((idx.shape[0],), self.ecfg.num_pages, jnp.int32),
+            rows))
+
+    @profiling.setup_span("warmup.cost_timing")
     def _time_prefill_programs(self, grid: dict) -> None:
         """Read what each warmed prefill program (PB, T) costs on this
         device, for _dispatch_prefill's choice of a batch bucket: every

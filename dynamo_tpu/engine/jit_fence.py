@@ -5,9 +5,15 @@ ever happens mid-serving — a mid-flight compile stalls every in-flight
 request for the compile latency (seconds on TPU). The static side of
 that invariant is dynajit (tools/dynalint, DL015-DL017); this module is
 the runtime side: a fence armed at the end of ``warmup()`` that counts
-every XLA compilation afterwards via JAX's monitoring hook
-(``/jax/core/compile/backend_compile_duration`` fires once per real
-backend compile and never on cache hits).
+every program built afterwards via JAX's monitoring hook. In JAX 0.9
+``/jax/core/compile/backend_compile_duration`` wraps
+``compile_or_get_cached`` whole: it fires once per program that reaches
+the backend, on a real compile AND on a persistent-cache hit (then it
+holds the retrieval, milliseconds to a second). The fence counts both:
+either way a program was traced, lowered and loaded mid-serving, and
+every in-flight request waited for it. The trigger, the timeline event
+and the warn/raise messages say which it was (``cache_hit``: the cache's
+own ``cache_hits`` / ``cache_retrieval_time_sec`` events tell).
 
 ``DYN_JIT_FENCE`` picks the reaction:
 
@@ -31,9 +37,11 @@ note lazily into a call-form key (jit name + per-operand dtype[shape]
 and static kwarg values): the runtime twin of dynaform's DL026
 warmup-form-drift key.
 
-The JAX monitoring API has no unregister, so ONE process-wide listener
-is installed lazily and dispatches to live fences (weakly referenced —
-a dropped engine stops counting). Compiles are process-global: with two
+ONE ``jax.monitoring`` duration listener serves the process: the set-up
+ledger's (``runtime/profiling.py install_jit_listeners``, installed
+before the first jit), to which this module subscribes; it dispatches
+to live fences (weakly referenced — a dropped engine stops counting).
+Compiles are process-global: with two
 engines in one process (disagg smoke tests) a compile triggered by
 either increments both armed fences, which is the honest reading — the
 process stalled.
@@ -42,43 +50,28 @@ process stalled.
 from __future__ import annotations
 
 import logging
-import threading
 import weakref
 from typing import Optional
 
+from ..runtime import profiling
 from ..runtime.config import env_str
 
 log = logging.getLogger("dynamo_tpu.engine.fence")
 
-# the per-compile duration event (fires on real backend compiles only;
-# cache hits and device_put do not record it)
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# the per-program duration event (a real backend compile or a
+# persistent-cache hit; device_put does not record it)
+COMPILE_EVENT = profiling.BACKEND_COMPILE_EVENT
 
 _fences: "weakref.WeakSet[CompileFence]" = weakref.WeakSet()
-_install_lock = threading.Lock()
-_installed = False
 
 
 class PostWarmupCompileError(RuntimeError):
-    """An XLA compile happened after warmup with DYN_JIT_FENCE=raise."""
+    """A program was built after warmup with DYN_JIT_FENCE=raise."""
 
 
-def _dispatch(event: str, duration_secs: float, **_kw) -> None:
-    if event != COMPILE_EVENT:
-        return
+def _dispatch(duration_secs: float, cache_hit: bool) -> None:
     for fence in list(_fences):
-        fence.on_compile(duration_secs)
-
-
-def _install_listener() -> None:
-    global _installed
-    with _install_lock:
-        if _installed:
-            return
-        import jax.monitoring
-
-        jax.monitoring.register_event_duration_secs_listener(_dispatch)
-        _installed = True
+        fence.on_compile(duration_secs, cache_hit)
 
 
 class CompileFence:
@@ -143,7 +136,7 @@ class CompileFence:
     def arm(self) -> None:
         """Called at the end of warmup(): from here on, every backend
         compile counts against the zero-compile serving invariant."""
-        _install_listener()
+        profiling.subscribe_backend_compiles(_dispatch)
         _fences.add(self)
         self.armed = True
         # end of warmup = steady state begins: snapshot the pre-incident
@@ -156,13 +149,15 @@ class CompileFence:
     def disarm(self) -> None:
         self.armed = False
 
-    def on_compile(self, duration_secs: float) -> None:
+    def on_compile(self, duration_secs: float,
+                   cache_hit: bool = False) -> None:
         if not self.armed:
             return
         self.post_warmup_compiles += 1
         if self.timeline is not None:
             self.timeline.add("compile",
                               duration_ms=round(duration_secs * 1e3, 3),
+                              cache_hit=cache_hit,
                               post_warmup_total=self.post_warmup_compiles)
         # a post-warmup compile is an incident by definition (the
         # zero-compile invariant broke); already on the cold compile path
@@ -170,13 +165,16 @@ class CompileFence:
         blackbox.notify_trigger("post_warmup_compile", {
             "fence": self.name,
             "duration_ms": round(duration_secs * 1e3, 3),
+            "cache_hit": cache_hit,
             "post_warmup_total": self.post_warmup_compiles,
             "last_dispatch_form": self.last_dispatch_form(),
         })
         mode = self.mode
+        what = ("program read from the compile cache" if cache_hit
+                else "XLA compile")
         if mode == "raise":
             raise PostWarmupCompileError(
-                f"XLA compile after warmup on {self.name} "
+                f"{what} after warmup on {self.name} "
                 f"({duration_secs * 1e3:.1f} ms, "
                 f"{self.post_warmup_compiles} total): an unbucketed "
                 f"shape or request-varying static arg reached a jitted "
@@ -185,8 +183,8 @@ class CompileFence:
                 f"(docs/static_analysis.md)")
         if mode == "warn":
             log.warning(
-                "XLA compile after warmup on %s (%.1f ms, %d total): "
+                "%s after warmup on %s (%.1f ms, %d total): "
                 "an unbucketed shape or request-varying static arg "
                 "reached a jitted call — last dispatched form: %s",
-                self.name, duration_secs * 1e3,
+                what, self.name, duration_secs * 1e3,
                 self.post_warmup_compiles, self.last_dispatch_form())

@@ -166,6 +166,12 @@ STATS_PROMETHEUS_SKIP = {
            "emit_to_wire_seconds_total", "emit_to_wire_total",
            "first_emit_to_wire_seconds_total", "first_emit_to_wire_total",
            "gc_pause_seconds_total")},
+    # the set-up ledger's (PR 52; runtime/profiling.py SetupLedger): a
+    # start's own, on the process's /metrics as
+    # dyn_engine_compile_cache_{hits,misses}_total; no aggregator gauge
+    **{key: "stats()-only counter of the process's set-up ledger"
+       for key in ("compile_cache_hits_total",
+                   "compile_cache_misses_total")},
 }
 
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime.profiling import setup_span
 from .config import ModelConfig
 
 
@@ -34,6 +35,7 @@ def _index(path: str) -> Dict[str, str]:
     raise FileNotFoundError(f"no safetensors checkpoint under {path}")
 
 
+@setup_span("load_params")
 def load_params(path: str, cfg: Optional[ModelConfig] = None,
                 dtype=None, quant: Optional[str] = None
                 ) -> Dict[str, jax.Array]:

@@ -38,6 +38,7 @@ import sys
 import time
 
 from .runtime.config import env_int, env_str
+from .runtime.profiling import setup_ledger, setup_span
 from typing import Optional, Tuple
 
 log = logging.getLogger("dynamo_tpu.run")
@@ -216,6 +217,7 @@ def mesh_axes_for(args) -> dict:
     return axes
 
 
+@setup_span("engine_setup")
 def _jax_engine_setup(args):
     """The out=jax configuration assembly, shared by the single-engine
     build and the dynashard replica set: returns
@@ -333,6 +335,23 @@ async def run_http(args, built=None) -> None:
     already-built ``build_engine(args)`` result (chip_smoke.py builds the
     engine itself so it can time the warm-up and inspect it afterwards);
     None builds it here."""
+    if built is None and not args.output.startswith("dyn"):
+        built = await asyncio.to_thread(build_engine, args)
+    with setup_ledger().span("http_start"):
+        svc, watcher, drt = await _start_http(args, built)
+    log.info("OpenAI frontend on %s:%d: %s", args.http_host, args.http_port,
+             setup_ledger().ready_line())
+    await _wait_for_signal()
+    await svc.stop()
+    if watcher:
+        await watcher.stop()
+    if drt:
+        await drt.shutdown()
+
+
+async def _start_http(args, built):
+    """run_http from its imports to the socket listening (the set-up
+    ledger's ``http_start`` span): (service, watcher, runtime)."""
     from .llm.engines import LocalChatChain, LocalCompletionChain
     from .llm.http.discovery import ModelWatcher
     from .llm.http.service import HttpService, ModelManager
@@ -341,15 +360,14 @@ async def run_http(args, built=None) -> None:
     svc = HttpService(manager)
     watcher = None
     drt = None
-    if args.output.startswith("dyn"):
+    if built is None:
         # standalone frontend: discover models from the control plane
         # (reference components/http/src/main.rs + model watcher)
         drt = await _attach(args)
         watcher = ModelWatcher(drt, manager)
         await watcher.start()
     else:
-        engine, mdc, full = built or await asyncio.to_thread(build_engine,
-                                                            args)
+        engine, mdc, full = built
         if full:
             manager.add_chat_model(mdc.name, engine)
         else:
@@ -378,13 +396,7 @@ async def run_http(args, built=None) -> None:
             # DYN_DRAIN_TIMEOUT_MS
             svc.on_drain(lambda: engine.drain(revive.drain_timeout_s()))
     await svc.start(args.http_host, args.http_port)
-    log.info("OpenAI frontend on %s:%d", args.http_host, args.http_port)
-    await _wait_for_signal()
-    await svc.stop()
-    if watcher:
-        await watcher.stop()
-    if drt:
-        await drt.shutdown()
+    return svc, watcher, drt
 
 
 async def run_text(args) -> None:
@@ -508,7 +520,7 @@ async def run_worker(args, path: str) -> None:
         drt, mdc, engine, namespace=addr.namespace,
         component=addr.component, endpoint=addr.endpoint,
         stats_handler=getattr(engine, "stats", None))
-    log.info("worker serving %s", path)
+    log.info("worker serving %s: %s", path, setup_ledger().ready_line())
     sig = await _wait_for_signal()
     if sig == signal.SIGTERM:
         # rolling restart: discovery record out first (no new
@@ -545,7 +557,8 @@ async def _run_sharded_worker(args, path: str) -> None:
         params=params, seed=args.seed, quant=quant,
         warmup=not args.no_warmup)
     await replica_set.start()
-    log.info("sharded worker serving %s: %s", path, replica_set.describe())
+    log.info("sharded worker serving %s: %s; %s", path,
+             replica_set.describe(), setup_ledger().ready_line())
     sig = await _wait_for_signal()
     if sig == signal.SIGTERM:
         # lifecycle drain bounded internally by DYN_DRAIN_TIMEOUT_MS

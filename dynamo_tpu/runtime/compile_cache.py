@@ -17,17 +17,22 @@ import os
 import re
 
 from .config import env_str
+from .profiling import install_jit_listeners, setup_span
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 
+@setup_span("jax_import")
 def enable_compile_cache() -> str:
     """Switch the persistent compile cache on; returns the directory in
     use. Call before the first jit of any process that serves or
-    benches (run.py, the SDK's TPU workers, bench.py, chip_smoke.py)."""
+    benches (run.py, the SDK's TPU workers, bench.py, chip_smoke.py):
+    it is the program's first ``import jax`` there (the set-up ledger's
+    ``jax_import`` span), and it installs the ledger's jit listeners."""
     import jax
 
+    install_jit_listeners()
     path = env_str("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = os.path.join(_CHECKOUT, ".jax_cache")

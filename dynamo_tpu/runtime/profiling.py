@@ -15,6 +15,11 @@ Planes, all stdlib-only (the step thread's ledger lives in
   and the interpreter already count: per-thread CPU and run-queue time
   (``/proc/self/task/<tid>/schedstat`` or ``stat``, read at ``stats()``
   time only) and garbage-collection pauses (``gc.callbacks``).
+- **Set-up ledger** — ``SetupLedger``: process start to readiness as
+  named spans (``dyn.setup.<name>``; they nest, across threads), and the
+  jit pipeline's stages (trace, lower, cache read, backend compile) from
+  ``jax.monitoring``'s events, by span and by program. The process's one
+  duration listener lives here; ``engine/jit_fence.py`` subscribes to it.
 - **Event-loop lag monitor** — an asyncio task sleeps a fixed interval
   and records how late it woke (sampled sleep-drift, the classic
   continuous-profiling signal for a starved event loop). Bounded ring;
@@ -40,6 +45,7 @@ Overhead budget and knobs: docs/profiling.md.
 from __future__ import annotations
 
 import asyncio
+import functools
 import gc
 import logging
 import os
@@ -521,6 +527,382 @@ class GcClock:
 _gc_clock: Optional[GcClock] = None     # None until a loop profiler starts
 
 
+# ------------------------------------------------------------ set-up ledger
+
+# the jit pipeline's timed stages, by the jax.monitoring event of each
+# (jax/_src/dispatch.py log_elapsed_time: a scalar of the same name when
+# the stage begins, a duration with fun_name when it ends)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+JIT_STAGE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    BACKEND_COMPILE_EVENT: "backend_compile",
+}
+# the persistent cache's own events (jax/_src/compiler.py): on a hit the
+# retrieval's duration, without fun_name, just before the
+# backend_compile_duration that holds it
+CACHE_READ_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+JIT_STAGES = ("trace", "lower", "cache_read", "backend_compile")
+# jit events with no set-up span open (the benchmark's agreement check, a
+# program built after readiness) are charged to this name
+OUTSIDE = "outside"
+
+
+class _SetupSpan:
+    """One bracket of the set-up ledger (``with ledger.span(name) as s:``):
+    ``start`` / ``end`` on the ledger's clock, ``depth`` spans open around
+    it, ``jit`` the stage seconds of what was traced and compiled while it
+    was the innermost, ``jit_below`` those of the spans inside it."""
+
+    __slots__ = ("ledger", "name", "start", "end", "depth", "jit",
+                 "jit_below", "_ann")
+
+    def __init__(self, ledger: "SetupLedger", name: str):
+        self.ledger = ledger
+        self.name = name
+        self.start = self.end = 0.0
+        self.depth = 0
+        self.jit: Dict[str, float] = {}
+        self.jit_below: Dict[str, float] = {}
+        self._ann = None
+
+    @property
+    def seconds(self) -> float:
+        """Of a closed span its length, of an open one the time so far."""
+        return (self.end or self.ledger.clock()) - self.start
+
+    def __enter__(self) -> "_SetupSpan":
+        cls = _trace_annotation()
+        if cls is not None:
+            self._ann = cls("dyn.setup." + self.name)
+        self.ledger._enter(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ledger._exit(self)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+def _add(into: Dict[str, float], stages: Dict[str, float]) -> None:
+    for stage, seconds in stages.items():
+        into[stage] = into.get(stage, 0.0) + seconds
+
+
+class SetupLedger:
+    """Process start to readiness as named spans, and what the jit
+    pipeline did meanwhile, by stage, by span and by program.
+
+    One instance a process (``setup_ledger()``), always on. Spans nest
+    and may open on any thread (set-up crosses ``asyncio.to_thread``): one
+    stack for the process under one lock, ``time.monotonic()`` both ways
+    (the clock of a load generator's ``open`` stamp, so a reader can
+    subtract), one ``TraceAnnotation("dyn.setup.<name>")`` a span. The
+    stage seconds come from JAX's own monitoring events
+    (``install_jit_listeners``): each top-level trace, lowering, cache read
+    and backend compile is added to the innermost open span (else
+    ``OUTSIDE``) and to its program's row. Nothing here is reachable from
+    a step or a request: spans open in set-up code only, the listeners
+    fire only while something is traced or compiled, and ``stats()`` after
+    readiness compares one integer and hands out the tables it built."""
+
+    MAX_SPANS = 256         # of the ordered list; the sums take every span
+    MAX_PROGRAMS = 32       # rows of jit_program_seconds, the costliest
+    MAX_WARM_PROGRAMS = 512
+
+    def __init__(self, clock: Callable[[], float] = time.monotonic):
+        self.clock = clock
+        self.origin = clock()
+        self._lock = threading.Lock()
+        self._open: List[_SetupSpan] = []
+        self.span_seconds: Dict[str, float] = {}
+        self.span_calls: Dict[str, int] = {}
+        self.spans: List[list] = []     # [name, start, end, depth], closed
+        self.span_jit: Dict[str, Dict[str, float]] = {}
+        # of each depth-0 span name, the stages of everything inside it
+        self.top_jit: Dict[str, Dict[str, float]] = {}
+        self.stage_calls: Dict[str, int] = dict.fromkeys(JIT_STAGES, 0)
+        self.programs: Dict[str, dict] = {}
+        self.cache_hits = 0
+        self.cache_misses = 0
+        self.warm_programs: List[dict] = []
+        self._version = 0
+        self._built: Tuple[int, dict] = (-1, {})
+
+    def span(self, name: str) -> _SetupSpan:
+        return _SetupSpan(self, name)
+
+    def _enter(self, span: _SetupSpan) -> None:
+        with self._lock:
+            span.depth = len(self._open)
+            self._open.append(span)
+            span.start = self.clock()
+
+    def _exit(self, span: _SetupSpan) -> None:
+        with self._lock:
+            span.end = self.clock()
+            # not a pop: a span opened on another thread may outlive the
+            # one it was opened inside
+            self._open.remove(span)
+            name = span.name
+            self.span_seconds[name] = (self.span_seconds.get(name, 0.0)
+                                       + span.end - span.start)
+            self.span_calls[name] = self.span_calls.get(name, 0) + 1
+            if len(self.spans) < self.MAX_SPANS:
+                self.spans.append([name, span.start, span.end, span.depth])
+            _add(self.span_jit.setdefault(name, {}), span.jit)
+            _add(span.jit_below, span.jit)
+            if span.depth and self._open:
+                around = self._open[min(span.depth, len(self._open)) - 1]
+                _add(around.jit_below, span.jit_below)
+            else:
+                _add(self.top_jit.setdefault(name, {}), span.jit_below)
+            self._version += 1
+
+    def add_stage(self, stage: str, program: str, seconds: float,
+                  calls: int = 1) -> None:
+        """One top-level stage of the jit pipeline, as the listener saw it
+        end."""
+        with self._lock:
+            jit = (self._open[-1].jit if self._open
+                   else self.span_jit.setdefault(OUTSIDE, {}))
+            jit[stage] = jit.get(stage, 0.0) + seconds
+            row = self.programs.get(program)
+            if row is None:
+                row = self.programs[program] = dict.fromkeys(JIT_STAGES, 0.0)
+                row["calls"] = 0
+            row[stage] += seconds
+            self.stage_calls[stage] += calls
+            if stage in ("cache_read", "backend_compile"):
+                row["calls"] += calls   # programs built, however
+            self._version += 1
+
+    def count_cache(self, hit: bool) -> None:
+        with self._lock:
+            if hit:
+                self.cache_hits += 1
+            else:
+                self.cache_misses += 1
+            self._version += 1
+
+    def add_warm_program(self, form: str, span: _SetupSpan) -> None:
+        """A row of the warm grid: the call form a ``warmup.*`` span ran
+        (``CompileFence.last_dispatch_form()``), the span's seconds and
+        the stages charged to it; seconds less the four stages is the
+        eager arguments and the dispatch of the first execution."""
+        row = {"program": form.split("(", 1)[0], "form": form,
+               "seconds": span.end - span.start,
+               **{s: span.jit.get(s, 0.0) for s in JIT_STAGES}}
+        with self._lock:
+            if len(self.warm_programs) < self.MAX_WARM_PROGRAMS:
+                self.warm_programs.append(row)
+                self._version += 1
+
+    def _program_table(self) -> Dict[str, dict]:
+        """``programs`` as stats() shows it: the eager one-op programs
+        (``jnp.zeros`` is a ``broadcast_in_dim`` of its own, traced,
+        lowered and read from the cache like any other) as one row
+        ``eager``, then the costliest names, the rest as ``other``."""
+        table: Dict[str, dict] = {}
+        for name, row in self.programs.items():
+            into = table.setdefault("eager" if _is_eager(name) else name,
+                                    dict.fromkeys(row, 0))
+            for k, v in row.items():
+                into[k] += v
+
+        def cost(name: str) -> Tuple[bool, float]:
+            # the eager row first, whatever it cost: it is never "other"
+            return name != "eager", -sum(table[name][s] for s in JIT_STAGES)
+
+        names = sorted(table, key=cost)
+        out = {n: table[n] for n in names[:self.MAX_PROGRAMS]}
+        for name in names[self.MAX_PROGRAMS:]:
+            into = out.setdefault("other", dict.fromkeys(table[name], 0))
+            for k, v in table[name].items():
+                into[k] += v
+        return out
+
+    def stats(self) -> dict:
+        """The ledger's keys of engine ``stats()``. Built when something
+        changed since the last call, which after readiness nothing does:
+        then the same tables again, by reference (a reader must not write
+        to them)."""
+        version, built = self._built
+        if version == self._version:
+            return built
+        with self._lock:
+            version = self._version
+            totals = dict.fromkeys(JIT_STAGES, 0.0)
+            for stages in self.span_jit.values():
+                _add(totals, stages)
+            built = {
+                "setup_span_seconds_total": dict(self.span_seconds),
+                "setup_span_calls_total": dict(self.span_calls),
+                "setup_spans": sorted(
+                    (list(s) for s in self.spans), key=lambda s: s[1]),
+                "setup_span_jit_seconds": {
+                    n: dict(st) for n, st in self.span_jit.items() if st},
+                "jit_stage_seconds_total": totals,
+                "jit_stage_calls_total": dict(self.stage_calls),
+                "compile_cache_hits_total": self.cache_hits,
+                "compile_cache_misses_total": self.cache_misses,
+                "jit_program_seconds": self._program_table(),
+                "warmup_programs": [dict(r) for r in self.warm_programs],
+            }
+            self._built = (version, built)
+        return built
+
+    def ready_line(self) -> str:
+        """``ready in 36.9 s: jax_import 3.1, ..., warmup 10.9 (trace 2.0
+        lower 3.1 cache 1.2 compile 0.0), http_start 0.8, outside 19.5``:
+        the depth-0 spans in order with the stages inside each, and what
+        of the time since the ledger was made no span covers."""
+        now = self.clock()
+        with self._lock:
+            top = sorted((s for s in self.spans if s[3] == 0),
+                         key=lambda s: s[1])
+            top_jit = {n: dict(st) for n, st in self.top_jit.items()}
+        seconds: Dict[str, float] = {}
+        for name, start, end, _ in top:
+            seconds[name] = seconds.get(name, 0.0) + end - start
+        parts = []
+        for name, s in seconds.items():
+            part = f"{name} {s:.1f}"
+            jit = top_jit.get(name, {})
+            if any(jit.values()):
+                part += (" (trace {trace:.1f} lower {lower:.1f} cache "
+                         "{cache_read:.1f} compile {backend_compile:.1f})"
+                         .format(**{st: jit.get(st, 0.0)
+                                    for st in JIT_STAGES}))
+            parts.append(part)
+        covered = _union_seconds([(s[1], s[2]) for s in top])
+        parts.append(f"{OUTSIDE} {now - self.origin - covered:.1f}")
+        return f"ready in {now - self.origin:.1f} s: " + ", ".join(parts)
+
+
+def _union_seconds(intervals: List[Tuple[float, float]]) -> float:
+    total, edge = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > edge:
+            total += end - max(start, edge)
+            edge = end
+    return total
+
+
+def _is_eager(name: str) -> bool:
+    """Whether a program's name is one of JAX's own: a primitive that an
+    eager call dispatched (``convert_element_type``, ``broadcast_in_dim``)
+    or a jitted ``jax.numpy`` function (``subtract``, ``_where``). The
+    names the program's jitted entry points are not."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return False
+    return (hasattr(jax.lax, name + "_p")
+            or hasattr(jax.numpy, name.lstrip("_")))
+
+
+_setup = SetupLedger()
+# what runs after the ledger on every backend_compile_duration event,
+# nested or not: fn(seconds, cache_hit) (engine/jit_fence.py)
+_compile_subscribers: List[Callable[[float, bool], None]] = []
+_jit_local = threading.local()      # depth of open stages, a pending read
+_listeners_lock = threading.Lock()
+_listeners_installed = False
+
+
+def setup_ledger() -> SetupLedger:
+    """The process's set-up ledger, made when this module is first
+    imported: its ``origin`` is as near the process's start as the program
+    can see."""
+    return _setup
+
+
+def setup_span(name: str):
+    """Decorator: every call of the function is one span of the process's
+    set-up ledger."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with _setup.span(name):
+                return fn(*args, **kwargs)
+
+        return run
+
+    return deco
+
+
+def _on_jit_begin(event: str, _value, **_kw) -> None:
+    if event in JIT_STAGE_EVENTS:
+        _jit_local.depth = getattr(_jit_local, "depth", 0) + 1
+
+
+def _on_jit_duration(event: str, seconds: float, fun_name: str = "",
+                     **_kw) -> None:
+    stage = JIT_STAGE_EVENTS.get(event)
+    if stage is None:
+        if event == CACHE_READ_EVENT:
+            _jit_local.cache_read = seconds
+        return
+    # a stage that ran inside another on this thread (a jitted jnp
+    # function traced inside a program's trace, an eager constant inside
+    # a lowering) is part of the outer one's duration: not added again
+    depth = _jit_local.depth = max(getattr(_jit_local, "depth", 1) - 1, 0)
+    program = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+    compiled = stage == "backend_compile"
+    # pxla wraps compile_or_get_cached whole: on a persistent-cache hit
+    # the backend's duration is the retrieval just reported plus a
+    # remainder
+    read = _jit_local.__dict__.pop("cache_read", None) if compiled else None
+    if not depth:
+        if read is not None:
+            _setup.add_stage("cache_read", program, read)
+            _setup.add_stage(stage, program, max(seconds - read, 0.0), 0)
+        else:
+            _setup.add_stage(stage, program, seconds)
+    if compiled:
+        for fn in list(_compile_subscribers):
+            fn(seconds, read is not None)
+
+
+def _on_jit_event(event: str, **_kw) -> None:
+    if event == CACHE_HIT_EVENT:
+        _setup.count_cache(True)
+    elif event == CACHE_MISS_EVENT:
+        _setup.count_cache(False)
+
+
+def install_jit_listeners() -> None:
+    """Register the process's ``jax.monitoring`` listeners, once: ONE
+    duration listener, which feeds the set-up ledger and then the compile
+    fences (``subscribe_backend_compiles``), a scalar listener for the
+    stages' beginnings and a plain one for the cache's hits and misses.
+    Called by
+    ``enable_compile_cache()`` and ``JaxEngine.__init__``: before the
+    first jit of any process that serves."""
+    global _listeners_installed
+    with _listeners_lock:
+        if _listeners_installed:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_scalar_listener(_on_jit_begin)
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jit_duration)
+        jax.monitoring.register_event_listener(_on_jit_event)
+        _listeners_installed = True
+
+
+def subscribe_backend_compiles(fn: Callable[[float, bool], None]) -> None:
+    """``fn(seconds, cache_hit)`` on every backend_compile_duration event
+    from now on, on the compiling thread, after the ledger took it; what
+    ``fn`` raises reaches the jit call that compiled."""
+    install_jit_listeners()
+    if fn not in _compile_subscribers:
+        _compile_subscribers.append(fn)
+
+
 # ------------------------------------------------------------- loop profiler
 
 
@@ -671,6 +1053,8 @@ def host_stats(step_native_id: Optional[int] = None) -> dict:
     if _gc_clock is not None:
         out["gc_pause_seconds_total"] = _gc_clock.pause_seconds
         out["gc_collections_total"] = dict(_gc_clock.collections)
+    # process start to readiness, and the jit pipeline's stages
+    out.update(_setup.stats())
     return out
 
 
@@ -694,15 +1078,36 @@ def stall_stacks_folded(limit: Optional[int] = None,
     return prof.watchdog.folded(limit=limit, since=since)
 
 
+def _setup_prom_lines() -> List[str]:
+    st = _setup.stats()
+    if not st["setup_span_seconds_total"]:
+        return []       # a process that set nothing up (a bare frontend)
+    lines = ["# HELP dyn_engine_setup_span_seconds seconds of process "
+             "set-up inside each named span (a span holds its children's)",
+             "# TYPE dyn_engine_setup_span_seconds gauge"]
+    lines += [f'dyn_engine_setup_span_seconds{{span="{name}"}} {s:.6f}'
+              for name, s in st["setup_span_seconds_total"].items()]
+    lines += ["# HELP dyn_engine_jit_stage_seconds_total seconds the jit "
+              "pipeline spent in each stage, process-wide",
+              "# TYPE dyn_engine_jit_stage_seconds_total counter"]
+    lines += [f'dyn_engine_jit_stage_seconds_total{{stage="{stage}"}} {s:.6f}'
+              for stage, s in st["jit_stage_seconds_total"].items()]
+    for name in ("hits", "misses"):
+        lines += [f"# TYPE dyn_engine_compile_cache_{name}_total counter",
+                  f"dyn_engine_compile_cache_{name}_total "
+                  f"{st[f'compile_cache_{name}_total']}"]
+    return lines
+
+
 def render_prom_lines() -> List[str]:
     """Loop-lag/stall gauges for the local process's /metrics exposition
     (the aggregator re-exports per-worker figures from ForwardPassMetrics
-    instead)."""
+    instead), and the set-up ledger's spans and jit stages."""
     prof = current_loop_profiler()
     if prof is None:
-        return []
+        return _setup_prom_lines()
     snap = prof.monitor.snapshot()
-    lines = [
+    lines = _setup_prom_lines() + [
         "# HELP dyn_runtime_loop_lag_seconds event-loop sleep-drift "
         "(sampled callback overrun seen by every task on this loop)",
         "# TYPE dyn_runtime_loop_lag_seconds gauge",
