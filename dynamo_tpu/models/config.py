@@ -156,8 +156,11 @@ class ModelConfig:
     # that decays A KEY CHANNEL and is corrected by a rank-1 delta a
     # token; q, k and v each pass a causal depthwise convolution of
     # mamba_d_conv taps (the conv tails' pool has jamba.py's ranks).
-    # kda_chunk_size is the chunk of the matmul form a prompt runs (the
-    # program's choice, in no published file). An attending layer is
+    # kda_chunk_size is the chunk of the XLA arm of the matmul form a
+    # prompt runs (off the chip, and the chunk kernel's reference; the
+    # program's choice, in no published file: where the kernels run a
+    # prompt runs ops/kda.py kda_chunk, whose chunk is a constant of that
+    # file). An attending layer is
     # MLA (the latent ranks above) and, with mla_nope, applies no
     # rotation to the qk_rope_head_dim shared columns. kda_n_heads > 0
     # switches the model module.

@@ -55,7 +55,7 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Callable, List, NamedTuple, Tuple
+from typing import Callable, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -392,7 +392,8 @@ class Blocks(NamedTuple):
     float32 and one of conv tails ``[S, M, (d_conv - 1) * conv_width]``);
     the layer loops, the pools' traffic and the window are this module's
     for all of them. models/granite.py is the second, and
-    models/kimi_linear.py the third, whose attending half is latent."""
+    models/kimi_linear.py the third, whose attending half is latent and
+    whose prefill chunk has a kernel of its own (``chunk``)."""
     keys: tuple             # the state-space mixer's leaves, stacked [M, ...]
     mixer: Callable         # _mamba's call form
     ff: Callable            # _dense_ff's call form: the layer's second half
@@ -403,6 +404,11 @@ class Blocks(NamedTuple):
     # the state: the engine adds them to stats()); none: ff returns None
     counts: tuple = ()
     attending: Attending = GQA
+    # the scan of a chunk of T > 1 tokens as a kernel on the gathered
+    # rows (ops/kda.py kda_chunk's call form; ``forward`` binds it to
+    # ``mixer`` as ``chunk=`` where the kernels run); None: the mixer's
+    # XLA form
+    chunk: Optional[Callable] = None
 
 
 MAMBA1 = Blocks(MAMBA_KEYS, _mamba, _dense_ff,
@@ -515,14 +521,21 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
     conv = jnp.where(fresh[:, None, None], 0, state[1][state_slots])
     # one token from a stored state: the kernel advances it in the pool
     # where the attention kernels run (llama.kernel_mode); else the rows'
-    # state is gathered and the XLA step or chunk runs on it
-    interpret = kernel_mode(allow_pallas) if tokens.shape[1] == 1 else None
-    if interpret is None:
-        in_pool = None
+    # state is gathered and a chunk runs on it: the family's chunk kernel
+    # where it has one and the kernels run, else the XLA step or chunk
+    one = tokens.shape[1] == 1
+    interpret = kernel_mode(allow_pallas) \
+        if one or blocks.chunk is not None else None
+    in_pool = None
+    if one and interpret is not None:
+        in_pool, ssm = (state_slots, fresh, interpret), state[0]
+    else:
         ssm = jnp.where(fresh[:, None, None, None], 0.0,
                         state[0][state_slots])
-    else:
-        in_pool, ssm = (state_slots, fresh, interpret), state[0]
+        if interpret is not None:   # the mixer with the chunk kernel bound
+            blocks = blocks._replace(mixer=partial(
+                blocks.mixer,
+                chunk=partial(blocks.chunk, interpret=interpret)))
 
     attend, cache, finish = blocks.attending.chunk(
         cfg, params, positions, kv_k, kv_v, page_table, flat_slots,

@@ -47,26 +47,31 @@ d_v]`` and ``[S, M, (d_conv - 1) * 3 H d_k]``. At the published widths a
 row is 2 MiB a layer: the module declares no snapshots, so a prefix hit
 counts as a miss, as for Jamba and Granite.
 
-**Two forms of the scan.** A chunk of T tokens runs in matrix products
-(``_kda_chunk``): for a chunk of Q tokens entered with S_in, ``G_t =
-sum_{s<=t} g_s`` (a channel), ``A_ts = beta_t sum_i k_t[i] k_s[i]
-exp(G_t[i] - G_s[i])`` for s < t, ``(I + A) [W | U] = [beta k exp(G) |
-beta v]`` (unit lower triangular: forward substitution), ``V~ = U - W
-S_in``,
+**Three forms of the scan.** A chunk of T tokens runs in matrix products:
+for a chunk of Q tokens entered with S_in, ``G_t = sum_{s<=t} g_s`` (a
+channel), ``A_ts = beta_t sum_i k_t[i] k_s[i] exp(G_t[i] - G_s[i])`` for
+s < t, ``(I + A) [W | U] = [beta k exp(G) | beta v]`` (unit lower
+triangular: forward substitution), ``V~ = U - W S_in``,
 
     o_t   = (q_t exp(G_t))^T S_in
             + sum_{s<=t} [sum_i q_t[i] k_s[i] exp(G_t[i] - G_s[i])] V~_s
     S_out = Diag(exp(G_Q)) S_in + sum_s (k_s exp(G_Q - G_s)) V~_s^T
 
 with every exponent a difference ``<= 0``: nothing overflows whatever is
-drawn (at Q = 1 it is the recurrence). The tables and the solve do not
-depend on S_in and are made for all of a program's chunks at once; only
-the three products with the state run chunk after chunk. One token from
-a stored state: the kernel on the pool where the attention kernels run,
-``_kda_step`` on gathered rows elsewhere. Scopes: ``kda`` around the
-mixer with ``kda.proj``, ``kda.conv``, ``kda.gate``, ``kda.scan``,
-``kda.norm`` inside; ``attn`` with ``attn.latent``; ``mlp``; ``moe``
-with ``moe.router``, ``moe.dispatch``, ``moe.experts``, ``moe.shared``;
+drawn (at Q = 1 it is the recurrence). Where the kernels run
+(``llama.kernel_mode``, asked by jamba.forward because ``BLOCKS`` carries
+a chunk kernel) that is ONE Pallas kernel on the gathered rows,
+ops/kda.py ``kda_chunk``: a head's state and its tables stay in VMEM
+over the whole chunk of T tokens, Q = 64. Elsewhere, and as the kernel's
+reference, it is ``_kda_chunk`` in plain XLA at Q = ``kda_chunk_size``:
+the tables and the solve do not depend on S_in and are made for all of a
+program's chunks at once; only the three products with the state run
+chunk after chunk. One token from a stored state: the step kernel on the
+pool where the kernels run, ``_kda_step`` on gathered rows elsewhere.
+Scopes: ``kda`` around the mixer with ``kda.proj``, ``kda.conv``,
+``kda.gate``, ``kda.scan`` (either chunk form, and the step), ``kda.norm``
+inside; ``attn`` with ``attn.latent``; ``mlp``; ``moe`` with
+``moe.router``, ``moe.dispatch``, ``moe.experts``, ``moe.shared``;
 ``lm_head``, ``sample``, ``kv_carry`` as in jamba.py.
 """
 
@@ -83,7 +88,7 @@ from .config import ModelConfig
 from .granite import WINDOW_COUNTS, held_first
 from .jamba import _at, _causal_conv, num_mamba_layers
 from .llama import KVCacheSpec, Params, _mlp, _moe_use_blocked, rms_norm
-from ..ops.kda import kda_step
+from ..ops.kda import kda_chunk, kda_step
 
 KDA_KEYS = ("w_qkv", "conv_w", "w_f1", "w_f2", "b_dt", "A_log", "w_beta",
             "w_g1", "w_g2", "b_g", "kda_norm", "w_out")
@@ -265,14 +270,17 @@ def _l2norm(x):
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def _kda(cfg: ModelConfig, mp, u, valid, s, tail, step=_kda_step):
+def _kda(cfg: ModelConfig, mp, u, valid, s, tail, step=_kda_step,
+         chunk=None):
     """The KDA mixer on a chunk: jamba._mamba's call form. u [B, T, D]
     (normed); valid [B, T] (a row's valid tokens lead); s [B, d_k, H *
     d_v] float32 and tail [B, d_conv - 1, 3 H d_k]: the rows' state on
     entry. Returns (out [B, T, D], s, tail) with the state after each
     row's last valid token. ``step`` is the one-token recurrence (T ==
     1) with _kda_step's operands and results, ``s`` being whatever it
-    carries: the rows' states, or the pool they lie in."""
+    carries: the rows' states, or the pool they lie in. ``chunk`` is the
+    chunk kernel (T > 1) with _kda_chunk's operands and results but the
+    chunk size, which is the kernel's own; None: _kda_chunk."""
     f32 = jnp.float32
     B, T, _ = u.shape
     H, dk = cfg.kda_n_heads, cfg.kda_head_dim
@@ -306,6 +314,8 @@ def _kda(cfg: ModelConfig, mp, u, valid, s, tail, step=_kda_step):
                 s, o = step(s, q[:, 0], k[:, 0], v[:, 0], g[:, 0],
                             beta[:, 0])
                 o = o[:, None]
+            elif chunk is not None:     # a chunk, in the kernel
+                s, o = chunk(s, q, k, v, g, beta)
             else:           # a chunk from a carried state, by matmuls
                 s, o = _kda_chunk(s, q, k, v, g, beta, cfg.kda_chunk_size)
         with jax.named_scope("kda.norm"):
@@ -449,7 +459,7 @@ def _latent_window(cfg: ModelConfig, interpret, mesh):
 
 LATENT = jamba.Attending(_latent_chunk, _latent_window)
 BLOCKS = jamba.Blocks(KDA_KEYS, _kda, _ff, kda_step, conv_width,
-                      WINDOW_COUNTS, LATENT)
+                      WINDOW_COUNTS, LATENT, kda_chunk)
 
 
 # ----------------------------------------------------- jitted entry points

@@ -1,8 +1,9 @@
 """Kimi Linear (KDA + latent attention without positions + a dense first
 layer, then sigmoid-routed experts of which a share is held, beside a
-shared expert; models/kimi_linear.py): the step programs, the two forms
-of the delta rule, the step kernel, the chip's share of a layer's
-experts and the engine's state pool against the plain reference
+shared expert; models/kimi_linear.py): the step programs, the forms
+of the delta rule, the step kernel and the chunk kernel, the chip's
+share of a layer's experts and the engine's state pool against the plain
+reference
 (benchmark/configs/kimi-linear-48b-a3b/reference.py), on the CPU at a
 small size with every kind of layer: float32, two whole periods of 8
 layers (attention at layers 4 and 8 of 1..8, layer 1 dense), hidden 64,
@@ -41,7 +42,8 @@ from dynamo_tpu.models import jamba, kimi_linear, llama, mla
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import DROP_SLOT, KVCacheSpec
 from dynamo_tpu.models.registry import get_model_module
-from dynamo_tpu.ops.kda import kda_step
+from dynamo_tpu.ops import kda
+from dynamo_tpu.ops.kda import kda_chunk, kda_step
 from dynamo_tpu.runtime.engine import Context
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -362,6 +364,86 @@ def test_the_chunked_form_is_the_token_recurrence(T, Q, strength):
                                v[1:, :T - 3], g[1:, :T - 3],
                                beta[1:, :T - 3])
     assert np.abs(np.asarray(got_s[1:] - short)).max() < 1e-5
+
+
+@pytest.mark.parametrize("B,T,H,carried,strength", [
+    (2, 256, 1, True, 1.0),
+    (1, 64, 1, False, 25.0),
+    (1, 128, 4, True, 1.0),
+], ids=["two-rows-carried-two-tiles", "strong-from-zeros-one-chunk",
+        "four-heads"])
+def test_chunk_kernel_is_the_token_recurrence(B, T, H, carried, strength):
+    """ops/kda.py kda_chunk under interpretation (heads of 128 channels,
+    as the kernel's blocks lie on the chip) against the recurrence token
+    by token and against _kda_chunk on the same operands: from a carried
+    state and from zeros; one row and two, the second's trailing 37
+    tokens not counting (its state is that of its last counted token);
+    T of one kernel chunk (64: padded to the kernel's tile of 128 with
+    tokens that do not count), of two (one tile) and of four (two tiles:
+    the state stays in VMEM between them); one head and four (a grid
+    step a head: each reads and writes its own lane block of the state
+    and of the tokens' arrays); under the strong decay (g near -20 a
+    token: every exponent the kernel takes is a sum of log decays <= 0,
+    so all is finite) at the same tolerance."""
+    assert T % kda.CHUNK == 0 and (T > kda.TILE) == (T == 256)
+    rng = np.random.default_rng(T + H)
+    dk = 128
+    q, k, v, g, beta = _scan_operands(rng, B, T, H, dk, strength)
+    if strength > 1:
+        assert float(g.mean()) < -15
+    valid = jnp.arange(T)[None, :] < jnp.asarray([T, T - 37][:B])[:, None]
+    g = jnp.where(valid[..., None, None], g, 0.0)
+    beta = jnp.where(valid[..., None], beta, 0.0)
+    s0 = jnp.asarray(rng.normal(size=(B, dk, H * dk)), jnp.float32) \
+        * float(carried)
+    got_s, got_o = kda_chunk(s0, q, k, v, g, beta, interpret=True)
+    assert got_o.shape == v.shape and got_s.shape == s0.shape
+    assert bool(jnp.isfinite(got_o).all() & jnp.isfinite(got_s).all())
+    counted = np.asarray(valid)[..., None, None]
+    for want_s, want_o in (
+            _token_by_token(s0, q, k, v, g, beta),
+            kimi_linear._kda_chunk(s0, q, k, v, g, beta, 16)):
+        assert np.abs(np.asarray(got_o - want_o) * counted).max() < 1e-5
+        assert np.abs(np.asarray(got_s - want_s)).max() < 1e-5
+    if B > 1:
+        short, _ = _token_by_token(s0[1:], q[1:, :T - 37], k[1:, :T - 37],
+                                   v[1:, :T - 37], g[1:, :T - 37],
+                                   beta[1:, :T - 37])
+        assert np.abs(np.asarray(got_s[1:] - short)).max() < 1e-5
+
+
+def test_only_this_family_supplies_a_chunk_kernel():
+    """jamba.Blocks.chunk: None for Jamba's and Granite's blocks (their
+    prefill programs are what they were), ops/kda.py's kda_chunk here."""
+    from dynamo_tpu.models import granite
+
+    assert jamba.MAMBA1.chunk is None and granite.BLOCKS.chunk is None
+    assert kimi_linear.BLOCKS.chunk is kda_chunk
+
+
+def test_the_prefills_kernel_arm_is_its_xla_arm(monkeypatch):
+    """prefill_step where the kernels run (interpreted here:
+    jamba.forward asks llama.kernel_mode for T > 1 because the blocks
+    carry a chunk kernel, and hands it to the mixer) against the XLA arm:
+    the same logits, the same state and conv tails in the pool, over two
+    chunks of one prompt (the second enters with a carried state)."""
+    cfg = tiny(num_hidden_layers=4)     # a dense layer, two KDA, one attending
+    params = make_params(cfg, 5)
+    prompt = np.random.default_rng(5).integers(1, 512, 16)
+    out = []
+    for interpret in (False, True):
+        if interpret:
+            monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+        pools = Pools(cfg)
+        if interpret:   # programs of its own: the mode is read at trace
+            pools.prefill = kimi_linear.make_step_fns(cfg)[0]
+        logits = [pools.run_prefill(params, prompt[at:at + 8], at, 8)
+                  for at in (0, 8)]
+        out.append((*logits, *(np.asarray(x[pools.slot])
+                               for x in pools.state)))
+    for x, y in zip(*out):
+        assert np.abs(x - y).max() < ATOL
+    assert np.abs(out[0][2]).max() > 1e-2
 
 
 @pytest.mark.parametrize("slots,still", [

@@ -1024,6 +1024,29 @@ def test_kda_step_kernel_compiles(one_chip, B):
         s((B, H, N), f32), s((B, H), f32), s((B,), jnp.bool_)).compile())
 
 
+@pytest.mark.parametrize("B", [8, 1])
+def test_kda_chunk_kernel_compiles(one_chip, B):
+    """ops/kda.py kda_chunk at the cell's prefill programs (PB 8 and PB 1
+    x T 512, 32 heads of 128 x 128): the chip's compiler takes the
+    float32 products at Precision.HIGHEST (Mosaic's fp32 contraction),
+    the [128, 128] tables of a grid step, the lane rotations that place
+    the off-diagonal pieces and the transposes; the kernel is in the
+    program under its name. On the chip tools/kda_chunk_timing.py runs
+    it against _kda_chunk on the same operands: state and outputs agree
+    to 2e-5 of the largest value (it reads 3e-6: two HIGHEST forms; a
+    single-pass bfloat16 product anywhere in the scan reads 1e-2)."""
+    from dynamo_tpu.ops import kda
+
+    s = partial(_sds, one_chip)
+    T, H, d = 512, 32, 128
+    f32 = jnp.float32
+    compiled = kda.kda_chunk.lower(
+        s((B, d, H * d), f32), s((B, T, H, d), f32), s((B, T, H, d), f32),
+        s((B, T, H, d), f32), s((B, T, H, d), f32),
+        s((B, T, H), f32)).compile()
+    assert _has_kernel(compiled) and kda.CHUNK_NAME in compiled.as_text()
+
+
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_kimi_linear_programs_write_no_array_of_a_pools_size(
         one_chip, tpu_kernel_path, program):
@@ -1062,6 +1085,9 @@ def test_kimi_linear_programs_write_no_array_of_a_pools_size(
             state, s((PB,), jnp.int32)).compile()
     text = compiled.as_text()
     assert _has_kernel(compiled)
+    # a chunk's scan is the chunk kernel, a token's the step kernel
+    assert ("kda_chunk" in text) == (program == "prefill")
+    assert ("kda_step" in text) == (program != "prefill")
     # the one large copy a B 128 program makes is of WEIGHTS: the
     # dense-over-experts form's relayout of the 7 x 64 w_down_e matrices
     # (1.97 GiB of temporaries once a program; PERF.md, Open questions)
