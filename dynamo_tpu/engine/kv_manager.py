@@ -47,9 +47,9 @@ EVICT_POLICIES = ("lru", "cost")
 RECURRENT_STATE_REFUSAL = (
     "{what} is not supported for a model with recurrent state "
     "(models/jamba.py, models/lfm2.py, models/granite.py, "
-    "models/kimi_linear.py): {why}; a state snapshot lives in "
-    "the device pool under its page's id, or not at all, and nothing "
-    "moves or rolls back a state (ROADMAP B7)")
+    "models/kimi_linear.py, models/solar_open2.py): {why}; a state "
+    "snapshot lives in the device pool under its page's id, or not at "
+    "all, and nothing moves or rolls back a state (ROADMAP B7)")
 
 
 BLOCK_GENERATION_REFUSAL = (

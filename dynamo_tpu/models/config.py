@@ -168,6 +168,15 @@ class ModelConfig:
     kda_head_dim: int = 128
     kda_chunk_size: int = 16
     mla_nope: bool = False
+    # Solar Open 2 (model_type "solar_open2", models/solar_open2.py): the
+    # same KDA mixer beside GQA layers without positions (K/V pages a KV
+    # head, kv_lora_rank 0) whose output passes an elementwise sigmoid
+    # gate of the layer's normed input (the leaf ``wg``, always there).
+    # kda_beta_scale multiplies the delta rule's sigmoid beta: 2
+    # where the configuration allows negative eigenvalues (beta in (0, 2):
+    # the transition Diag(exp(g)) (I - beta k k^T) may have an eigenvalue
+    # in (-1, 0)), 1 otherwise.
+    kda_beta_scale: float = 1.0
     # Generation by diffusion over blocks (model_type "sdar_moe"): the
     # attention mask is causal across blocks of block_length positions and
     # bidirectional inside one, the logits at a position are the
@@ -449,6 +458,8 @@ class ModelConfig:
             c._read_smallthinker(cfg)
         if mt == "kimi_linear":
             c._read_kimi_linear(cfg)
+        if mt == "solar_open2":
+            c._read_solar_open2(cfg)
         if mt in ("gemma", "gemma2"):
             # Gemma rides the Llama GQA stack with four semantic switches
             c.model_type = "gemma"
@@ -605,6 +616,82 @@ class ModelConfig:
         self.routed_scaling_factor = cfg.get("routed_scaling_factor", 1.0)
         self.n_shared_experts = cfg.get("num_shared_experts", 0)
         self.first_k_dense_replace = cfg.get("first_k_dense_replace", 0)
+        self.moe_intermediate_size = cfg["moe_intermediate_size"]
+
+    def _read_solar_open2(self, cfg: dict) -> None:
+        """The keys of a ``solar_open2`` config.json. ``gqa_layers``
+        counts layers from 0 and is kept whole in a file cut in depth:
+        the entries under ``num_hidden_layers`` are the layers that
+        attend, every other layer is KDA. ``n_routed_experts`` is the
+        experts HELD; a file cut to a chip's share names the published
+        count (``router_num_experts``) and the first expert held
+        (``first_local_expert``) beside it, as granite's and kimi's do."""
+        def refuse(what: str, why: str):
+            raise NotImplementedError(
+                f"solar_open2 with {what} is not supported ({why})")
+
+        L = cfg["num_hidden_layers"]
+        lin = cfg["linear_attn_config"]
+        attending = {l for l in cfg["gqa_layers"] if 0 <= l < L}
+        if not attending or len(attending) == L:
+            refuse(f"gqa_layers {sorted(attending)} of {L} layers",
+                   "the state pool holds the KDA layers and the K/V pools "
+                   "the attending ones; a model of one kind is another "
+                   "module's")
+        if cfg.get("use_rope", False):
+            refuse("use_rope true",
+                   "its attending layers apply no positional embedding, "
+                   "and no cell would run the rotated form")
+        if not cfg.get("use_gqa_gate", False):
+            refuse("use_gqa_gate false",
+                   "its attending layers gate attention's output (the "
+                   "leaf wg), and no cell would run the ungated form")
+        if cfg.get("kda_use_full_proj", False):
+            refuse("kda_use_full_proj true",
+                   "the decay and the output gate are projected through a "
+                   "bottleneck of the head size (w_f1 / w_f2, w_g1 / w_g2)")
+        if lin.get("num_kv_heads") not in (None, lin["num_heads"]):
+            refuse(f"linear_attn_config.num_kv_heads {lin['num_kv_heads']}",
+                   "q, k and v of a KDA layer all have num_heads heads")
+        if cfg.get("first_k_dense_replace", 0):
+            refuse(f"first_k_dense_replace {cfg['first_k_dense_replace']}",
+                   "every layer's second half is routed experts beside the "
+                   "shared expert; the module builds no dense MLP")
+        if (cfg.get("n_group") or 1) != 1 or (cfg.get("topk_group") or 1) != 1:
+            refuse(f"n_group {cfg.get('n_group')} / topk_group "
+                   f"{cfg.get('topk_group')}",
+                   "the gate chooses among all the router's outputs")
+        if cfg.get("rope_scaling"):
+            refuse("rope_scaling", "no layer rotates")
+        held = cfg["n_routed_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("first_local_expert", 0)
+        if not 0 <= first <= width - held:
+            refuse(f"first_local_expert {first}",
+                   f"the {held} experts held must lie inside the "
+                   f"router's {width}")
+        if cfg["num_experts_per_tok"] > width:
+            refuse(f"num_experts_per_tok {cfg['num_experts_per_tok']}",
+                   f"the router has {width} outputs")
+        self.model_type = "solar_open2"
+        self.layer_types = tuple("attention" if l in attending else "kda"
+                                 for l in range(L))
+        self.kda_n_heads = lin["num_heads"]
+        self.kda_head_dim = lin["head_dim"]
+        self.mamba_d_conv = lin.get("short_conv_kernel_size", 4)
+        self.kda_beta_scale = 2.0 if cfg.get("kda_allow_neg_eigval") else 1.0
+        self.num_experts, self.router_experts = held, width
+        self.first_expert = first
+        self.num_experts_per_tok = cfg["num_experts_per_tok"]
+        # sigmoid scores, selection by score + bias, the unbiased scores
+        # of the chosen renormalised and scaled: DeepSeek-V3's gate
+        # without groups (models/mla.py _deepseek_gate)
+        self.moe_router = "deepseek_v3"
+        self.norm_topk_prob = bool(cfg.get("norm_topk_prob", True))
+        self.routed_scaling_factor = float(
+            cfg.get("routed_scaling_factor", 1.0))
+        self.n_shared_experts = cfg.get("n_shared_experts", 0)
+        self.first_k_dense_replace = 0
         self.moe_intermediate_size = cfg["moe_intermediate_size"]
 
     def _read_granite(self, cfg: dict) -> None:

@@ -317,8 +317,9 @@ class Attending(NamedTuple):
     """The attending half of a family on this layout: the attending
     layers' attention over their pools (the module's ``init_kv_cache``
     shapes them) in a chunk and in the fused window. ``GQA`` below is
-    Jamba's and Granite's (K and V pages a KV head, no positions,
-    llama.py's paged attention and kernels); models/kimi_linear.py
+    Jamba's, Granite's and Solar Open 2's (K and V pages a KV head, no
+    positions, llama.py's paged attention and kernels; an output gate
+    where the params hold ``wg``: ``_gated``); models/kimi_linear.py
     supplies the latent one from models/mla.py's functions. ``a`` counts
     the attending layers; every ``attend(a, x, cache) -> (out [B, T, D],
     cache)`` is _stack's."""
@@ -346,7 +347,7 @@ def _gqa_chunk(cfg: ModelConfig, params: Params, positions, kv_k, kv_v,
             v_l = _scatter_pages(kv_v[a], v, flat_slots)
         out = _attention(q, k_l, v_l, page_table, positions, cfg.attn_scale,
                          allow_pallas=allow_pallas, mesh=mesh)
-        return (out.reshape(*x.shape[:2], -1) @ params["wo"][a],
+        return (_gated(params, a, x, out) @ params["wo"][a],
                 (kv_k.at[a].set(k_l), kv_v.at[a].set(v_l)))
 
     return attend, (kv_k, kv_v), lambda cache: cache
@@ -370,7 +371,7 @@ def _gqa_window(cfg: ModelConfig, interpret, mesh):
             out = window_attention(q, w.kv_k, w.kv_v, a, w.page_table,
                                    w.start, wk_l, wv_l, i, cfg.attn_scale,
                                    interpret)
-            return (out.reshape(x.shape[0], 1, -1) @ w.params["wo"][a],
+            return (_gated(w.params, a, x, out) @ w.params["wo"][a],
                     (wk.at[a].set(wk_l), wv.at[a].set(wv_l)))
 
         return attend
@@ -391,9 +392,11 @@ class Blocks(NamedTuple):
     between attending layers, one pool of scan state ``[S, M, N, C]``
     float32 and one of conv tails ``[S, M, (d_conv - 1) * conv_width]``);
     the layer loops, the pools' traffic and the window are this module's
-    for all of them. models/granite.py is the second, and
+    for all of them. models/granite.py is the second,
     models/kimi_linear.py the third, whose attending half is latent and
-    whose prefill chunk has a kernel of its own (``chunk``)."""
+    whose prefill chunk has a kernel of its own (``chunk``), and
+    models/solar_open2.py the fourth: kimi_linear.py's mixer and second
+    half beside ``GQA``."""
     keys: tuple             # the state-space mixer's leaves, stacked [M, ...]
     mixer: Callable         # _mamba's call form
     ff: Callable            # _dense_ff's call form: the layer's second half
@@ -500,6 +503,22 @@ def _qkv(cfg: ModelConfig, params: Params, a: int, x):
     return ((x @ params["wq"][a]).reshape(B, T, H, hd),
             (x @ params["wk"][a]).reshape(B, T, KV, hd),
             (x @ params["wv"][a]).reshape(B, T, KV, hd))
+
+
+def _gated(params: Params, a: int, x, out):
+    """Attention's output [B, T, H, hd] as the output projection takes it,
+    [B, T, H * hd]: as it is for a family without the leaf ``wg`` (Jamba,
+    Granite: their programs hold nothing of this), and for one that has
+    it (models/solar_open2.py) times ``sigmoid(x @ wg[a])``, an
+    elementwise gate of the layer's normed input at full width, made and
+    applied in float32 under the scope ``attn.gate``."""
+    out = out.reshape(*x.shape[:2], -1)
+    if "wg" not in params:
+        return out
+    with jax.named_scope("attn.gate"):
+        gate = jax.nn.sigmoid(jnp.dot(x, params["wg"][a],
+                                      preferred_element_type=jnp.float32))
+        return (out.astype(jnp.float32) * gate).astype(x.dtype)
 
 
 def _store_rows(pool, slots, rows):

@@ -16,7 +16,7 @@ A KDA mixer has H heads of d_k = d_v channels, a head a matrix of state
     [q, k, v] = silu(causal depthwise conv1d(W_qkv u))     3 x H d_k
     q = l2norm(q) / sqrt(d_k),   k = l2norm(k)             a head
     g    = -exp(A_log[head]) * softplus(W_f2 (W_f1 u) + b_dt)   [H, d_k]
-    beta = sigmoid(W_beta u)                                    [H]
+    beta = kda_beta_scale * sigmoid(W_beta u)                   [H]
     S'   = Diag(exp(g_t)) S_{t-1}            the decay, A KEY CHANNEL
     S_t  = S' + beta_t k_t (v_t - S'^T k_t)^T        the delta rule
     o_t  = S_t^T q_t
@@ -126,24 +126,9 @@ def init_state(cfg: ModelConfig, slots: int, dtype=None) -> jamba.State:
                       dtype or cfg.jax_dtype))
 
 
-def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
-    """Random-init params; each kind of leaf stacked on its own axis 0
-    (pre-norms over all L layers, KDA leaves over the M KDA layers,
-    latent-attention leaves over the attending ones, the dense MLP over
-    the first ``first_k_dense_replace`` layers, router, experts and
-    shared expert over the layers after them). The expert stacks hold
-    the experts HELD (``cfg.num_experts``); the router and its selection
-    bias are ``cfg.router_width`` wide."""
-    dtype = dtype or cfg.jax_dtype
-    D, I, L, V = (cfg.hidden_size, cfg.intermediate_size, cfg.num_layers,
-                  cfg.vocab_size)
-    M, A = num_mamba_layers(cfg), len(cfg.attn_layer_ids)
-    H, dk, dc = cfg.kda_n_heads, cfg.kda_head_dim, cfg.mamba_d_conv
-    Ha, r, dr = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
-    kd, E, W = cfg.first_k_dense_replace, cfg.num_experts, cfg.router_width
-    Im = cfg.moe_intermediate_size
-    Is = Im * cfg.n_shared_experts
+def _drawer(key: jax.Array, dtype):
+    """(w, ks): ``w(*shape)`` draws a normal leaf of std 1 / sqrt(fan_in)
+    from the next of ``ks``, the keys split from ``key``."""
     ks = iter(jax.random.split(key, 32))
 
     def w(*shape):
@@ -151,11 +136,15 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         return (jax.random.normal(next(ks), shape, jnp.float32)
                 * scale).astype(dtype)
 
-    p: Params = {
-        "embed": w(V, D), "lm_head": w(D, V),
-        "ln_mixer": jnp.ones((L, D), dtype),
-        "ln_mlp": jnp.ones((L, D), dtype),
-        "ln_final": jnp.ones((D,), dtype),
+    return w, ks
+
+
+def kda_leaves(cfg: ModelConfig, w, ks, dtype) -> Params:
+    """The KDA mixers' leaves (``KDA_KEYS``), stacked over the M KDA
+    layers: what every family with this mixer draws."""
+    D, M = cfg.hidden_size, num_mamba_layers(cfg)
+    H, dk, dc = cfg.kda_n_heads, cfg.kda_head_dim, cfg.mamba_d_conv
+    return {
         "w_qkv": w(M, D, 3 * H * dk),
         "conv_w": w(M, dc, 3 * H * dk),
         "w_f1": w(M, D, dk), "w_f2": w(M, dk, H * dk),
@@ -168,6 +157,48 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         "b_g": jnp.zeros((M, H * dk), dtype),
         "kda_norm": jnp.ones((M, dk), dtype),
         "w_out": w(M, H * dk, D),
+    }
+
+
+def moe_leaves(cfg: ModelConfig, w, dtype) -> Params:
+    """The leaves of ``_ff``'s expert layers (``MOE_KEYS`` and
+    ``EXPERT_KEYS``), stacked over the layers after the
+    ``first_k_dense_replace`` dense ones. The expert stacks hold the
+    experts HELD (``cfg.num_experts``); the router and its selection
+    bias are ``cfg.router_width`` wide."""
+    D, Le = cfg.hidden_size, cfg.num_layers - cfg.first_k_dense_replace
+    E, W, Im = cfg.num_experts, cfg.router_width, cfg.moe_intermediate_size
+    Is = Im * cfg.n_shared_experts
+    return {
+        "w_router": w(Le, D, W),
+        "router_bias": jnp.zeros((Le, W), dtype),
+        "w_gate_e": w(Le, E, D, Im), "w_up_e": w(Le, E, D, Im),
+        "w_down_e": w(Le, E, Im, D),
+        "w_gate_s": w(Le, D, Is), "w_up_s": w(Le, D, Is),
+        "w_down_s": w(Le, Is, D),
+    }
+
+
+def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
+    """Random-init params; each kind of leaf stacked on its own axis 0
+    (pre-norms over all L layers, KDA leaves over the M KDA layers,
+    latent-attention leaves over the attending ones, the dense MLP over
+    the first ``first_k_dense_replace`` layers, router, experts and
+    shared expert over the layers after them)."""
+    dtype = dtype or cfg.jax_dtype
+    D, I, L, V = (cfg.hidden_size, cfg.intermediate_size, cfg.num_layers,
+                  cfg.vocab_size)
+    A = len(cfg.attn_layer_ids)
+    Ha, r, dr = cfg.num_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    kd = cfg.first_k_dense_replace
+    w, ks = _drawer(key, dtype)
+    return {
+        "embed": w(V, D), "lm_head": w(D, V),
+        "ln_mixer": jnp.ones((L, D), dtype),
+        "ln_mlp": jnp.ones((L, D), dtype),
+        "ln_final": jnp.ones((D,), dtype),
+        **kda_leaves(cfg, w, ks, dtype),
         "w_q": w(A, D, Ha * (dn + dr)),
         "w_dkv": w(A, D, r + dr),
         "kv_norm": jnp.ones((A, r), dtype),
@@ -175,14 +206,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         "w_o": w(A, Ha * dv, D),
         "w_gate_d": w(kd, D, I), "w_up_d": w(kd, D, I),
         "w_down_d": w(kd, I, D),
-        "w_router": w(L - kd, D, W),
-        "router_bias": jnp.zeros((L - kd, W), dtype),
-        "w_gate_e": w(L - kd, E, D, Im), "w_up_e": w(L - kd, E, D, Im),
-        "w_down_e": w(L - kd, E, Im, D),
-        "w_gate_s": w(L - kd, D, Is), "w_up_s": w(L - kd, D, Is),
-        "w_down_s": w(L - kd, Is, D),
+        **moe_leaves(cfg, w, dtype),
     }
-    return p
 
 
 # ----------------------------------------------------------- the mixer
@@ -306,6 +331,8 @@ def _kda(cfg: ModelConfig, mp, u, valid, s, tail, step=_kda_step,
             g = -jnp.exp(mp["A_log"].astype(f32))[:, None] \
                 * jax.nn.softplus(g)
             beta = jax.nn.sigmoid(dot(u, mp["w_beta"]))     # [B, T, H]
+            if cfg.kda_beta_scale != 1.0:   # (0, 2): negative eigenvalues
+                beta = cfg.kda_beta_scale * beta
             # a token that does not count moves no state
             g = jnp.where(valid[:, :, None, None], g, 0.0)
             beta = jnp.where(valid[:, :, None], beta, 0.0)

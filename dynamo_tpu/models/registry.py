@@ -4,8 +4,9 @@ The engine resolves init_params / init_kv_cache / make_step_fns through
 this table, so adding a family (reference: each engine adapter brings its
 own model zoo, lib/llm/src/engines/) is one module with the shared paged
 step-fn contract. The table dispatches by what a configuration HAS
-(KDA heads, latent ranks, Mamba-2 heads, a list of layer kinds, a
-state-space width), not by a flag per family. Six modules:
+(KDA heads with or without latent ranks, latent ranks, Mamba-2 heads, a
+list of layer kinds, a state-space width), not by a flag per family.
+Seven modules:
 
 - ``llama.py``: one homogeneous stack of attention + MLP-or-MoE layers,
   scanned (Llama / Qwen2 / Qwen3 / Qwen3-MoE / Mixtral / Gemma shapes);
@@ -23,8 +24,16 @@ state-space width), not by a flag per family. Six modules:
   in every layer routed experts beside one shared expert (llama.py's
   expert execution, told which experts it holds), under the embedding,
   residual and logits multipliers;
-- ``kimi_linear.py`` (``kda_n_heads`` > 0: ``kimi_linear``, asked
-  before ``is_mla``, which is true of it too): on jamba.py's layout, Kimi
+- ``solar_open2.py`` (``kda_n_heads`` > 0 and no ``kv_lora_rank``:
+  ``solar_open2``): on jamba.py's layout, ``kimi_linear.py``'s KDA mixer
+  (64 heads, ``kda_beta_scale`` 2: beta in (0, 2)), chunk and step
+  kernels and held-experts second half (no dense layer) with
+  ``jamba.GQA`` as its ``jamba.Attending``: K/V pages of the attending
+  layers only, no positions, attention's output gated by ``sigmoid(x @
+  wg)`` because its params hold ``wg``; nothing of a layer is written in
+  the module;
+- ``kimi_linear.py`` (``kda_n_heads`` > 0 with ``kv_lora_rank``:
+  ``kimi_linear``, asked before ``is_mla``, which is true of it too): on jamba.py's layout, Kimi
   Delta Attention mixers (a gated delta rule with a decay a key channel
   on a matrix of state a head, a chunked matmul form for prompts,
   ``ops/kda.py kda_step`` for decode) and, as its ``jamba.Attending``,
@@ -80,10 +89,11 @@ pairs routed to experts ``[first_expert, first_expert + num_experts)``
 in both execution forms, and the partial sum goes on; nothing stands in
 for the other chips or their exchange (one device: ROADMAP B9).
 ``granite.py`` and ``kimi_linear.py`` (through
-``mla._deepseek_moe_mlp(first=...)``) pass it.
+``mla._deepseek_moe_mlp(first=...)``; ``solar_open2.py`` runs the same
+``kimi_linear._ff``) pass it.
 
-**Which keep state.** ``jamba.py``, ``granite.py``, ``kimi_linear.py``
-and ``lfm2.py`` carry per-sequence **recurrent state** beside the KV
+**Which keep state.** ``jamba.py``, ``granite.py``, ``kimi_linear.py``,
+``solar_open2.py`` and ``lfm2.py`` carry per-sequence **recurrent state** beside the KV
 pages. A module declares that by
 having ``init_state(cfg, slots)`` (a tuple of pools indexed by slot);
 the engine then owns the pools, every step program takes ``(state,
@@ -100,15 +110,16 @@ page's id, so the snapshot lives and dies with the page. ``jamba.py``
 declares none (a Mamba row is 9.3 MB; a snapshot a page is out of the
 question), nor does ``granite.py`` (36 MiB a row at nine layers of 128 x
 64 x 128 float32) nor ``kimi_linear.py`` (12.4 MiB a row at six layers of
-32 x 128 x 128 float32): for them a prefix hit counts as a miss, as
-before.
+32 x 128 x 128 float32) nor ``solar_open2.py`` (12.4 MiB a row at three
+layers of 64 x 128 x 128 float32, beside 4 KiB of K/V a token): for them
+a prefix hit counts as a miss, as before.
 
 What refuses a model with recurrent state, at construction, each by a
 ``NotImplementedError`` that says "<what> is not supported for a model
 with recurrent state (models/jamba.py, models/lfm2.py,
-models/granite.py, models/kimi_linear.py): <why>; a state
-snapshot lives in the device pool under its page's id, or not at all,
-and nothing moves or rolls back a state (ROADMAP B7)":
+models/granite.py, models/kimi_linear.py, models/solar_open2.py): <why>;
+a state snapshot lives in the device pool under its page's id, or not at
+all, and nothing moves or rolls back a state (ROADMAP B7)":
 
 - ``EngineConfig.host_pages > 0`` (the host KV tier): "a page restored
   from the host comes without the state that goes with it";
@@ -128,10 +139,11 @@ from .config import ModelConfig
 
 
 def get_model_module(cfg: ModelConfig):
-    if cfg.kda_n_heads > 0:     # before is_mla: its attending layers are
-        from . import kimi_linear   # latent, and its other layers keep state
+    if cfg.kda_n_heads > 0:     # before is_mla: the other layers keep state
+        # the attending layers are latent, or K/V pages
+        from . import kimi_linear, solar_open2
 
-        return kimi_linear
+        return kimi_linear if cfg.is_mla else solar_open2
     if cfg.is_mla:
         from . import mla
 

@@ -1106,6 +1106,120 @@ def test_kimi_linear_programs_write_no_array_of_a_pools_size(
             < 15.75 * 1024 ** 3)
 
 
+def _solar(one_chip):
+    """The configuration as solar-open2-250b.long-reason runs it (4 of 48
+    layers, 40 of 320 experts held, an eighth of the vocabulary, every
+    width as published); params and the four pools as shapes on the
+    described chip, at the cell's engine data."""
+    import json
+
+    from dynamo_tpu.models import solar_open2
+    from dynamo_tpu.models.config import ModelConfig
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "solar-open2-250b.long-reason.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", "solar-open2-250b"))
+    params = _on(one_chip, jax.eval_shape(
+        lambda: solar_open2.init_params(cfg, jax.random.PRNGKey(0))))
+    kv_k, kv_v = (_on(one_chip, x) for x in jax.eval_shape(
+        lambda: solar_open2.init_kv_cache(
+            cfg, llama.KVCacheSpec(e["num_pages"], e["page_size"]))))
+    state = _on(one_chip, jax.eval_shape(
+        lambda: solar_open2.init_state(cfg, e["max_batch"] + 1)))
+    assert state[0].shape == (129, 3, 128, 8192)    # 4 MiB a layer a row
+    assert kv_k.shape == (1, e["num_pages"], 8, 128, 128)
+    return solar_open2, cfg, params, kv_k, kv_v, state, e
+
+
+@pytest.mark.parametrize("B", [128, 1])
+def test_kda_step_kernel_compiles_at_64_heads(one_chip, B):
+    """ops/kda.py kda_step at Solar Open 2's pool: the chip's compiler
+    takes the 4 MiB row copies, the 12 MiB ring in VMEM (28 MiB asked
+    for), the [128, 64] blocks of q, k and the decay and the unrolled
+    loop over a row's 64 heads."""
+    from dynamo_tpu.ops.kda import kda_step
+
+    s = partial(_sds, one_chip)
+    S, M, N, H, dv = 129, 3, 128, 64, 128
+    f32 = jnp.float32
+    assert _has_kernel(kda_step.lower(
+        s((S, M, N, H * dv), f32), s((B,), jnp.int32), s((), jnp.int32),
+        s((B, H, N), f32), s((B, H, N), f32), s((B, H, dv), f32),
+        s((B, H, N), f32), s((B, H), f32), s((B,), jnp.bool_)).compile())
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
+def test_solar_open2_programs_write_no_array_of_a_pools_size(
+        one_chip, tpu_kernel_path, program):
+    """models/solar_open2.py at the shapes of solar-open2-250b.long-reason
+    (matrix-state pool [129, 3, 128, 8192] float32 = 1.51 GiB; K and V
+    pools of the one attending layer, 1.5 GiB each): the fused window (B
+    128, 4 steps) and decode_step advance the state IN the pool through
+    the kernel and read K/V through the GQA decode kernel: no value of
+    the optimized program has the gathered rows' shape [128, 3, 128,
+    8192] and no copy of a pool's size exists, the state's or (in the
+    window, the program a cell serves decode with) K/V's. A
+    prefill chunk (PB 8 x T 512) runs the chunk kernel and the GQA
+    prefill kernel in one program, gathers its eight rows and stores
+    them row by row in place: the pools alias their inputs and are never
+    copied. The gate is in every program under its scope. Every program
+    fits beside the 10.7 GiB resident."""
+    solar, cfg, params, kv_k, kv_v, state, e = _solar(one_chip)
+    s = partial(_sds, one_chip)
+    P, B = e["page_buckets"][-1], e["max_batch"]
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    if program == "window":
+        lowered = solar.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, state, i32,
+            k_steps=e["decode_steps"], logprobs_topn=0)
+    elif program == "decode_step":
+        lowered = solar.make_step_fns(cfg)[1].lower(
+            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, state,
+            i32)
+    else:
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        lowered = solar.make_step_fns(cfg)[0].lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
+            state, s((PB,), jnp.int32))
+    assert "attn.gate" in lowered.as_text(debug_info=True)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    # a chunk's scan is the chunk kernel, a token's the step kernel; the
+    # attention beside it is the GQA kernel of the same kind
+    assert ("kda_chunk" in text) == (program == "prefill")
+    assert ("kda_step" in text) == (program != "prefill")
+    assert ("paged_attention_prefill" if program == "prefill"
+            else "paged_attention_decode") in text
+    # a large copy a B 128 program may make is of WEIGHTS: the
+    # dense-over-experts form's relayout of the 4 x 40 w_down_e matrices
+    # decode_step (K = 1 without the window: no cell's served path, the
+    # program the window is tested against) writes its token's K/V by
+    # jamba.GQA's flat scatter, which relays the K/V pools out, as for
+    # Jamba and Granite: only the state pool is held to the rule there
+    served = program != "decode_step"
+    big = _pool_sized_copies(text, state[0].size)
+    assert all(("%%%s = bf16[4,40,1280,4096]" % name in text) or not served
+               for name in big)
+    for pool in (state[0], kv_k, kv_v) if served else (state[0],):
+        dims = ",".join(map(str, pool.shape))
+        assert not re.search(
+            r" = \w+\[%s\]\S* (?:copy|copy-start)\(" % dims, text), dims
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in (kv_k, kv_v, *state))
+    if program != "prefill":
+        assert "f32[%d,3,128,8192]" % B not in text
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)
+
+
 def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
     """The Pallas kernel's serialized module rides inside the
     tpu_custom_call's opaque config, source locations included: without

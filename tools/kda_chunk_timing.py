@@ -55,18 +55,20 @@ F32 = jnp.float32
 TOL = 2e-5      # of the largest value: two HIGHEST forms; bf16 reads 1e-2
 
 
-def _case(B: int, T: int, H: int, d: int, seed: int):
+def _case(B: int, T: int, H: int, d: int, seed: int, beta_scale: float):
     """_kda_chunk's operands as the mixer makes them: unit keys, queries
     of norm d ** -0.5, log decays of a head's A x softplus, beta in (0,
-    1); odd rows enter with a carried state, the last row's trailing 37
-    tokens do not count."""
+    ``beta_scale``) (2 for a configuration that allows negative
+    eigenvalues: the entries of the unit-lower system double); odd rows
+    enter with a carried state, the last row's trailing 37 tokens do not
+    count."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 7)
     f = lambda i, *shape: jax.random.normal(ks[i], shape, F32)
     q = kimi_linear._l2norm(f(0, B, T, H, d)) * d ** -0.5
     k = kimi_linear._l2norm(f(1, B, T, H, d))
     a = jax.random.uniform(ks[2], (H, 1), F32, 1.0, 16.0)
     g = -a * jax.nn.softplus(f(3, B, T, H, d) - 4.0)
-    beta = jax.nn.sigmoid(f(4, B, T, H))
+    beta = beta_scale * jax.nn.sigmoid(f(4, B, T, H))
     valid = jnp.arange(T)[None, :] < jnp.full((B,), T).at[-1].add(-37)[:, None]
     g = jnp.where(valid[..., None, None], g, 0.0)
     beta = jnp.where(valid[..., None], beta, 0.0)
@@ -97,26 +99,31 @@ def main() -> int:
     ap.add_argument("--xla", default="16,64",
                     help="comma-separated chunk sizes of the XLA arm")
     ap.add_argument("--out", default="chiprun_out/kda_chunk_timing.json")
+    ap.add_argument("--cell", default=CELL,
+                    help="the cell whose KDA heads and prefill chunk are "
+                    "timed (solar-open2-250b.long-reason: 64 heads)")
     opts = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
         print(json.dumps({"ok": False, "error": "platform is %s, not tpu"
                           % dev.platform}))
         return 1
-    with open(os.path.join(ROOT, "benchmark/workloads", CELL + ".json")) as f:
+    with open(os.path.join(ROOT, "benchmark/workloads",
+                           opts.cell + ".json")) as f:
         T = json.load(f)["engine"]["prefill_chunk"]
     cfg = ModelConfig.from_local_path(
-        os.path.join(ROOT, "benchmark/configs", CELL.rsplit(".", 1)[0]))
+        os.path.join(ROOT, "benchmark/configs", opts.cell.rsplit(".", 1)[0]))
     H, d = cfg.kda_n_heads, cfg.kda_head_dim
     opts_tr = jax.profiler.ProfileOptions()
     opts_tr.python_tracer_level = 0     # device lines only: a small file
     agree, table = True, []
     for B in (int(b) for b in opts.rows.split(",")):
-        args, valid = _case(B, T, H, d, 53 + B)
+        args, valid = _case(B, T, H, d, 53 + B, cfg.kda_beta_scale)
         ops, bytes_ = kda_work.kda_prefill(B * T, heads=H, head_dim=d,
                                            layers=1)
         least = roofline.least_seconds(ops, bytes_, dev.device_kind)
         shape = {"B": B, "T": T, "heads": H, "head_dim": d,
+                 "beta_scale": cfg.kda_beta_scale,
                  "least_ms": least["seconds"] * 1e3, "bound": least["bound"]}
         forms = [("xla_q%s" % Q, lambda *a, Q=int(Q):
                   kimi_linear._kda_chunk(*a, Q))
