@@ -38,10 +38,11 @@ the published ``[H, P, N]`` with N in front, so that what is H * P wide
 a token (x, dt, y) lies along the lanes as the projections make and take
 it and only B and C, N wide, cross to the sublanes
 (ops/selective_scan.py ``ssd_step``); both pools then have jamba.py's
-ranks, ``[S, M, N, H * P]`` and ``[S, M, (d_conv - 1) * (d_inner +
-2N)]``. At granite-4.0-h-small's widths a row is 4 MiB a layer, 36 MiB at
-nine layers: the module declares no snapshots, so a prefix hit counts as
-a miss, as for Jamba.
+ranks and axes, ``[S, M, N, H * P]`` slot-major and the conv tails ``[M,
+S, (d_conv - 1) * (d_inner + 2N)]`` layer-major, oldest input first. At
+granite-4.0-h-small's widths a row is 4 MiB a layer, 36 MiB at nine
+layers: the module declares no snapshots, so a prefix hit counts as a
+miss, as for Jamba.
 
 **Two forms of the scan.** A chunk of T tokens runs the chunked form the
 family publishes, in matrix products (``_ssd_chunk``): for a chunk of Q
@@ -94,13 +95,14 @@ def conv_width(cfg: ModelConfig) -> int:
 
 
 def init_state(cfg: ModelConfig, slots: int, dtype=None) -> jamba.State:
-    """The recurrent-state pool for ``slots`` sequences: [S, M, N, H * P]
-    float32 and the conv tails (what declares to the engine that this
-    module's sequences carry state beside pages)."""
+    """The recurrent-state pools for ``slots`` sequences: [S, M, N, H *
+    P] float32, slot-major, and the conv tails [M, S, (d_conv - 1) *
+    conv_width], layer-major as jamba.init_state's (what declares to the
+    engine that this module's sequences carry state beside pages)."""
     M = num_mamba_layers(cfg)
     return (jnp.zeros((slots, M, cfg.mamba_d_state, cfg.mamba_d_inner),
                       jnp.float32),
-            jnp.zeros((slots, M, (cfg.mamba_d_conv - 1) * conv_width(cfg)),
+            jnp.zeros((M, slots, (cfg.mamba_d_conv - 1) * conv_width(cfg)),
                       dtype or cfg.jax_dtype))
 
 
@@ -210,14 +212,17 @@ def _ssd_chunk(s0, dt, x, b, c, a_neg, chunk: int):
     return s, jnp.moveaxis(y, 0, 1).reshape(B, T, H, P)
 
 
-def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step):
+def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step,
+            tail_step=None):
     """The Mamba-2 mixer on a chunk: jamba._mamba's call form. u [B, T,
     D] (normed); valid [B, T] (a row's valid tokens lead); s [B, N, H *
-    P] float32 and tail [B, d_conv - 1, d_inner + 2N]: the rows' state on
-    entry. Returns (out [B, T, D], s, tail) with the state after each
+    P] float32 and tail [B, (d_conv - 1) * (d_inner + 2N)]: the rows' state
+    on entry. Returns (out [B, T, D], s, tail) with the state after each
     row's last valid token. ``step`` is the one-token recurrence (T ==
     1) with _ssd_step's operands and results, ``s`` being whatever it
-    carries: the rows' states, or the pool they lie in."""
+    carries: the rows' states, or the pool they lie in; ``tail_step``
+    likewise the one-token advance of the conv tails
+    (jamba._causal_conv)."""
     f32 = jnp.float32
     B, T, _ = u.shape
     H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
@@ -234,7 +239,8 @@ def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step):
                                    [di, 2 * di + 2 * N], axis=-1)
             dt = jax.nn.softplus(dt + mp["b_dt"].astype(f32))
             dt = jnp.where(valid[:, :, None], dt, 0.0)          # [B, T, H]
-        xbc, tail = _causal_conv(mp, xbc, valid, tail, cfg.mamba_d_conv)
+        xbc, tail = _causal_conv(mp, xbc, valid, tail, cfg.mamba_d_conv,
+                                 tail_step=tail_step)
         x, b, c = jnp.split(xbc, [di, di + N], axis=-1)
         with jax.named_scope("ssm.scan"):
             a_neg = -jnp.exp(mp["A_log"].astype(f32))           # [H]
@@ -305,7 +311,7 @@ def _moe_ff(params: Params, cfg: ModelConfig, norm, h, l, valid, l0=None):
     return h + cfg.residual_multiplier * out, counted
 
 
-BLOCKS = jamba.Blocks(MAMBA2_KEYS, _mamba2, _moe_ff, ssd_step, conv_width,
+BLOCKS = jamba.Blocks(MAMBA2_KEYS, _mamba2, _moe_ff, ssd_step,
                       WINDOW_COUNTS)
 
 

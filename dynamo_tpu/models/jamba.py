@@ -19,20 +19,26 @@ positional embedding of any kind**) and is a Mamba-1 mixer elsewhere:
 What a sequence carries between programs is ``s`` (float32, kept as
 ``[N, d_inner]``: d_inner on the TPU's 128 lanes, the published
 ``[d_inner, N]`` would pad 16 to 128) and the last ``d_conv - 1`` conv
-inputs. The engine owns one pool of both (``init_state``: ``[S, M, N,
-d_inner]`` float32 and ``[S, M, (d_conv - 1) * d_inner]``, S slots, M
-Mamba layers; slot-major, so a row's whole state is one contiguous 8.5 MB
-and the gather and scatter by slot move whole slabs) and passes it
-through every program with the rows' slot indices, as it passes the KV
-pools with page tables. A prefill chunk gathers its few rows' state,
-carries it through the layers and scatters it back, row by row in place;
-a decode step (the fused window's, and decode_step) on a TPU leaves ``s``
-in the pool: a Pallas kernel reads each row's ``[N, d_inner]`` block
-where it lies and writes it back there (ops/selective_scan.py), and only
-the conv tail (a tenth of the bytes) is gathered and scattered once a
-program. A row that does not advance (padding, frozen by a stop) writes
-back what it read. A chunk that starts at position 0 starts from zeros,
-whatever the slot held.
+inputs. The engine owns one pool of each (``init_state``), S slots, M
+Mamba layers: the scan states ``[S, M, N, d_inner]`` float32, slot-major,
+so a row's whole state is one contiguous 8.5 MB and the gather and
+scatter by slot move whole slabs; and the conv tails ``[M, S, (d_conv -
+1) * d_inner]`` in the model's dtype, LAYER-major, the oldest input
+first along the last axis: a program gathers its rows' tails along axis
+1 into ``[M, B, (d_conv - 1) * d_inner]``, where a layer's tails are one
+contiguous block with the rows on the sublanes and the channels on the
+lanes, which a layer-step reads and writes where it lies (no axis of 3,
+6, 9 or 26 layers is ever tiled). The engine passes both through every
+program with the rows' slot indices, as it passes the KV pools with page
+tables. A prefill chunk gathers its few rows' state, carries it through
+the layers and scatters it back, row by row in place; a decode step (the
+fused window's, and decode_step) on a TPU leaves ``s`` in the pool: a
+Pallas kernel reads each row's ``[N, d_inner]`` block where it lies and
+writes it back there (ops/selective_scan.py), and only the conv tails (a
+tenth of the bytes) are gathered and scattered once a program. A row
+that does not advance (padding, frozen by a stop) writes back what it
+read. A chunk that starts at position 0 starts from zeros, whatever the
+slot held.
 
 The Mamba layers are stacked on a leading axis and ``lax.scan``-ned in
 their runs between the attending layers, so a program holds one Mamba
@@ -67,9 +73,10 @@ from .llama import (KVCacheSpec, Params, _at, _attention, _mlp,
                     embed_tokens, kernel_mode, logits_at, rms_norm,
                     window_attention)
 from .window import Family, make_window
+from ..ops.conv_step import conv_tail_step
 from ..ops.selective_scan import selective_scan_step
 
-State = Tuple[jax.Array, jax.Array]     # (ssm [S,M,N,di] f32, conv [S,M,(dc-1)*di])
+State = Tuple[jax.Array, jax.Array]     # (ssm [S,M,N,di] f32, conv [M,S,(dc-1)*di])
 
 MAMBA_KEYS = ("w_in", "conv_w", "b_conv", "w_x", "dt_norm", "ssm_b_norm",
               "ssm_c_norm", "w_dt", "b_dt", "A_log", "d_skip", "w_out")
@@ -112,11 +119,14 @@ def init_kv_cache(cfg: ModelConfig, spec: KVCacheSpec,
 
 
 def init_state(cfg: ModelConfig, slots: int, dtype=None) -> State:
-    """The recurrent-state pool for ``slots`` sequences: what declares to
-    the engine that this module's sequences carry state beside pages."""
+    """The recurrent-state pools for ``slots`` sequences (what declares
+    to the engine that this module's sequences carry state beside pages):
+    the scan states ``[S, M, N, d_inner]`` float32, slot-major, and the
+    conv tails ``[M, S, (d_conv - 1) * d_inner]``, layer-major (a row's
+    last d_conv - 1 inputs of a layer, oldest first, d_inner each)."""
     M, N, di = num_mamba_layers(cfg), cfg.mamba_d_state, cfg.mamba_d_inner
     return (jnp.zeros((slots, M, N, di), jnp.float32),
-            jnp.zeros((slots, M, (cfg.mamba_d_conv - 1) * di),
+            jnp.zeros((M, slots, (cfg.mamba_d_conv - 1) * di),
                       dtype or cfg.jax_dtype))
 
 
@@ -235,17 +245,31 @@ def _ssm_chunk(s0, dt, x, b, c, a_neg):
     return s, y
 
 
-def _causal_conv(mp, x, valid, tail, dc: int, scope: str = "ssm.conv"):
+def _causal_conv(mp, x, valid, tail, dc: int, scope: str = "ssm.conv",
+                 tail_step=None):
     """silu(causal depthwise conv1d(x; conv_w, b_conv)) on a chunk x
     [B, T, C] float32 entered with the rows' last dc - 1 inputs ``tail``
-    [B, dc - 1, C] (no bias where the family has no ``b_conv`` leaf).
-    Returns (the result [B, T, C], the next chunk's tail)."""
+    [B, (dc - 1) * C], oldest first, as the pool keeps them (no bias
+    where the family has no ``b_conv`` leaf). Returns (the result [B, T,
+    C], the next chunk's tail). Tail and chunk are laid end to end, every
+    token's dc taps summed in float32, and each row's next tail cut where
+    its valid tokens end. ``tail_step`` (T == 1, where the kernels run)
+    is the same sum and the same next tail by ops/conv_step.py, bound to
+    a layer by _stack: ``(tails, x [B, C], valid [B], w, bias) ->
+    (tails, the sum before its SiLU)``; ``tail`` is then whatever it
+    carries, every layer's tails [M, B, (dc - 1) * C], of which it
+    advances its layer's where they lie."""
     f32 = jnp.float32
-    T = x.shape[1]
+    B, T, C = x.shape
     with jax.named_scope(scope):
-        xp = jnp.concatenate([tail.astype(f32), x], axis=1)
         cw = mp["conv_w"].astype(f32)                       # [dc, C]
         bias = mp["b_conv"].astype(f32) if "b_conv" in mp else 0.0
+        if tail_step is not None:
+            tail, pre = tail_step(tail, x[:, 0], valid[:, 0], cw,
+                                  jnp.broadcast_to(bias, (C,)))
+            return jax.nn.silu(pre)[:, None], tail
+        xp = jnp.concatenate(
+            [tail.reshape(B, dc - 1, C).astype(f32), x], axis=1)
         xc = jax.nn.silu(bias + sum(
             xp[:, k:k + T] * cw[k] for k in range(dc)))
         # the next chunk's tail: the dc - 1 inputs that end at the
@@ -253,17 +277,19 @@ def _causal_conv(mp, x, valid, tail, dc: int, scope: str = "ssm.conv"):
         n_valid = jnp.sum(valid, axis=1)
         tail = jax.vmap(lambda row, n: lax.dynamic_slice_in_dim(
             row, n, dc - 1, 0))(xp, n_valid).astype(tail.dtype)
-    return xc, tail
+    return xc, tail.reshape(B, (dc - 1) * C)
 
 
-def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step):
+def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step,
+           tail_step=None):
     """The Mamba-1 mixer on a chunk. u [B, T, D] (normed); valid [B, T]
     (a row's valid tokens lead); s [B, N, di] float32 and tail
-    [B, d_conv - 1, di]: the rows' state on entry. Returns (out [B, T, D],
+    [B, (d_conv - 1) * di]: the rows' state on entry. Returns (out [B, T, D],
     s, tail) with the state after each row's last valid token. ``step``
     is the one-token recurrence (T == 1) with _ssm_step's operands and
     results, ``s`` being whatever it carries: the rows' states, or the
-    pool they lie in (_stack)."""
+    pool they lie in (_stack); ``tail_step`` likewise the one-token
+    advance of the conv tails (_causal_conv)."""
     f32 = jnp.float32
     B, T, _ = u.shape
     di, N, R, dc = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
@@ -281,7 +307,8 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step):
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm.proj"):
             x, z = jnp.split(dot(u, mp["w_in"]), 2, axis=-1)    # [B, T, di]
-        xc, tail = _causal_conv(mp, x, valid, tail, dc)
+        xc, tail = _causal_conv(mp, x, valid, tail, dc,
+                                tail_step=tail_step)
         with jax.named_scope("ssm.proj"):
             dt_r, b, c = jnp.split(dot(xc, mp["w_x"]), [R, R + N], axis=-1)
             dt_r = rms_norm(dt_r, mp["dt_norm"].astype(f32), eps)
@@ -390,7 +417,8 @@ GQA = Attending(_gqa_chunk, _gqa_window)
 class Blocks(NamedTuple):
     """What a family of this layout supplies (runs of state-space mixers
     between attending layers, one pool of scan state ``[S, M, N, C]``
-    float32 and one of conv tails ``[S, M, (d_conv - 1) * conv_width]``);
+    float32 and one of conv tails ``[M, S, (d_conv - 1) * channels]``, by
+    the module's ``init_state``);
     the layer loops, the pools' traffic and the window are this module's
     for all of them. models/granite.py is the second,
     models/kimi_linear.py the third, whose attending half is latent and
@@ -401,7 +429,6 @@ class Blocks(NamedTuple):
     mixer: Callable         # _mamba's call form
     ff: Callable            # _dense_ff's call form: the layer's second half
     step: Callable          # selective_scan_step's call form: the kernel
-    conv_width: Callable    # cfg -> channels of a conv tail
     # names of what ``ff`` counts a layer (int32, one each, summed over
     # the layers and a window's steps and returned by the window before
     # the state: the engine adds them to stats()); none: ff returns None
@@ -414,28 +441,28 @@ class Blocks(NamedTuple):
     chunk: Optional[Callable] = None
 
 
-MAMBA1 = Blocks(MAMBA_KEYS, _mamba, _dense_ff,
-                selective_scan_step, lambda cfg: cfg.mamba_d_inner)
+MAMBA1 = Blocks(MAMBA_KEYS, _mamba, _dense_ff, selective_scan_step)
 
 
 def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
            cache, in_pool=None, blocks: Blocks = MAMBA1):
-    """All layers on h [B, T, D]. conv [B, M, (dc-1)*di] is the ROWS' conv
-    tail (gathered by the caller), updated layer by layer. ssm is their
-    scan state: the rows' own, [B, M, N, di], sliced and updated a layer
-    like the tail; or, with ``in_pool`` = (the rows' slots [B], the rows
+    """All layers on h [B, T, D]. conv [M, B, (dc-1)*di] is the ROWS' conv
+    tails (gathered by the caller), layer-major: a layer's are one
+    contiguous block, sliced out and written back a layer. ssm is their
+    scan state: the rows' own, [B, M, N, di], sliced and updated
+    likewise; or, with ``in_pool`` = (the rows' slots [B], the rows
     that start from zeros [B], the kernel's ``interpret`` flag) and T ==
     1, the POOL ``[S, M, N, di]`` itself, which each layer's kernel call
     reads and writes at ``[slots, m]`` and nowhere else: the loops carry
-    the pool's buffer, never a copy of the rows. ``attend(a, x, cache) ->
+    the pool's buffer, never a copy of the rows; the tails are then
+    advanced in the carried array too, a layer's block where it lies
+    (ops/conv_step.py). ``attend(a, x, cache) ->
     (out, cache)`` is the attention mixer of attending layer a on the
     normed input: the caller owns how K/V are cached (pages for a chunk,
     the window buffer inside the fused window). Returns (the final norm
     of h, ssm, conv, cache, what the layers' second halves counted: None
     for blocks that count nothing)."""
     eps = cfg.rms_norm_eps
-    B = h.shape[0]
-    dc1, di = cfg.mamba_d_conv - 1, blocks.conv_width(cfg)
     res = cfg.residual_multiplier
     wdt = params["embed"].dtype
     # the residual stream is float32 and every block reads it through a
@@ -474,21 +501,21 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
             mp = _at(params, blocks.keys, m)
             x = norm(h, lax.dynamic_index_in_dim(
                 params["ln_mixer"], l0 + i, 0, False))
-            tail = lax.dynamic_index_in_dim(conv, m, 1, False).reshape(
-                B, dc1, di)
             if in_pool is None:
                 out, s, tail = blocks.mixer(
                     cfg, mp, x, valid,
-                    lax.dynamic_index_in_dim(ssm, m, 1, False), tail)
+                    lax.dynamic_index_in_dim(ssm, m, 1, False),
+                    lax.dynamic_index_in_dim(conv, m, 0, False))
                 ssm = lax.dynamic_update_index_in_dim(ssm, s, m, 1)
+                conv = lax.dynamic_update_index_in_dim(conv, tail, m, 0)
             else:
                 slots, fresh, interpret = in_pool
-                out, ssm, tail = blocks.mixer(
-                    cfg, mp, x, valid, ssm, tail,
+                out, ssm, conv = blocks.mixer(
+                    cfg, mp, x, valid, ssm, conv,
                     lambda pool, *row: blocks.step(
-                        pool, slots, m, *row, fresh, interpret=interpret))
-            conv = lax.dynamic_update_index_in_dim(
-                conv, tail.reshape(B, dc1 * di), m, 1)
+                        pool, slots, m, *row, fresh, interpret=interpret),
+                    tail_step=lambda tails, *row: conv_tail_step(
+                        tails, m, *row, interpret=interpret))
             h, tally = mlp(add(h, out), l0 + i, tally, l0)
             return (h, ssm, conv, tally), None
 
@@ -527,6 +554,17 @@ def _store_rows(pool, slots, rows):
     return pool.at[slots].set(rows.astype(pool.dtype))
 
 
+def _store_tails(pool, slots, tails):
+    """The rows' conv tails [M, B, W] written back to their slots of the
+    layer-major pool [M, S, W]: _store_rows along axis 1. For this and
+    for the gather the chip's compiler relays the pool slot-major and
+    back (a row of bf16 shares its tiles' words with its neighbour):
+    with the moves 4 x the bytes, once a program; a (layer, slot) pair
+    an index, which needs no relayout, moves the rows one at a time and
+    is 4 x slower still (tools/conv_step_timing.py; PERF.md, PR 55)."""
+    return pool.at[:, slots].set(tails.astype(pool.dtype))
+
+
 def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
             page_table, flat_slots, state: State, state_slots,
             allow_pallas: bool = True, page_slots=None, mesh=None,
@@ -537,7 +575,7 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
     Returns (hidden [B, T, D], kv_k, kv_v, state)."""
     valid = positions >= 0
     fresh = positions[:, 0] == 0
-    conv = jnp.where(fresh[:, None, None], 0, state[1][state_slots])
+    conv = jnp.where(fresh[None, :, None], 0, state[1][:, state_slots])
     # one token from a stored state: the kernel advances it in the pool
     # where the attention kernels run (llama.kernel_mode); else the rows'
     # state is gathered and a chunk runs on it: the family's chunk kernel
@@ -565,7 +603,7 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
     kv_k, kv_v = finish(cache)
     if in_pool is None:
         ssm = _store_rows(state[0], state_slots, ssm)
-    return h, kv_k, kv_v, (ssm, _store_rows(state[1], state_slots, conv))
+    return h, kv_k, kv_v, (ssm, _store_tails(state[1], state_slots, conv))
 
 
 # ----------------------------------------------------- jitted entry points
@@ -610,8 +648,9 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
     program) with the rows' recurrent state carried beside it, advanced
     by every step a row is active in: the conv tails gathered from the
     pool once and scattered back once; the scan state likewise on the XLA
-    arm, and left in the pool where the kernel runs, every step reading
-    and writing the rows' blocks where they lie."""
+    arm, and left in the pool where the kernels run, every step reading
+    and writing the rows' blocks where they lie (and a layer's tails
+    where they lie in the gathered array)."""
     # one choice for both kernels, the window's attention and the scan
     scan_interpret = kernel_mode(allow_pallas, pallas_interpret)
     use_pallas = scan_interpret is not None
@@ -620,7 +659,7 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
 
     def begin(w):
         att = att_begin(w)
-        conv = w.state[1][w.state_slots]
+        conv = w.state[1][:, w.state_slots]
         ssm = w.state[0] if use_pallas else w.state[0][w.state_slots]
         return att, ssm, conv
 
@@ -644,7 +683,7 @@ def make_decode_window_fn(cfg: ModelConfig, allow_pallas: bool = True,
         kv_k, kv_v = att_commit(w, att, pos)
         if not use_pallas:
             ssm = _store_rows(w.state[0], w.state_slots, ssm)
-        return kv_k, kv_v, (ssm, _store_rows(w.state[1], w.state_slots,
-                                             conv))
+        return kv_k, kv_v, (ssm, _store_tails(w.state[1], w.state_slots,
+                                              conv))
 
     return make_window(Family(begin, step, commit), max_top_k)

@@ -42,8 +42,9 @@ What the absent experts would add is left out.
 the last ``d_conv - 1`` inputs of the three convolutions. The pool keeps
 S as ``[N, H * d_v]``, N = d_k: what is H * d_v wide a token (v, beta, o)
 lies along the lanes and a head's q, k and decay are columns on the
-sublanes (ops/kda.py); both pools have jamba.py's ranks, ``[S, M, N, H *
-d_v]`` and ``[S, M, (d_conv - 1) * 3 H d_k]``. At the published widths a
+sublanes (ops/kda.py); both pools have jamba.py's ranks and axes, ``[S,
+M, N, H * d_v]`` slot-major and the conv tails ``[M, S, (d_conv - 1) * 3
+H d_k]`` layer-major, oldest input first. At the published widths a
 row is 2 MiB a layer: the module declares no snapshots, so a prefix hit
 counts as a miss, as for Jamba and Granite.
 
@@ -117,12 +118,13 @@ def init_kv_cache(cfg: ModelConfig, spec: KVCacheSpec, dtype=None):
 
 
 def init_state(cfg: ModelConfig, slots: int, dtype=None) -> jamba.State:
-    """The recurrent-state pool for ``slots`` sequences: [S, M, d_k, H *
-    d_v] float32 and the conv tails (what declares to the engine that
-    this module's sequences carry state beside pages)."""
+    """The recurrent-state pools for ``slots`` sequences: [S, M, d_k, H
+    * d_v] float32, slot-major, and the conv tails [M, S, (d_conv - 1) *
+    conv_width], layer-major as jamba.init_state's (what declares to the
+    engine that this module's sequences carry state beside pages)."""
     M, H, dk = num_mamba_layers(cfg), cfg.kda_n_heads, cfg.kda_head_dim
     return (jnp.zeros((slots, M, dk, H * dk), jnp.float32),
-            jnp.zeros((slots, M, (cfg.mamba_d_conv - 1) * conv_width(cfg)),
+            jnp.zeros((M, slots, (cfg.mamba_d_conv - 1) * conv_width(cfg)),
                       dtype or cfg.jax_dtype))
 
 
@@ -296,16 +298,18 @@ def _l2norm(x):
 
 
 def _kda(cfg: ModelConfig, mp, u, valid, s, tail, step=_kda_step,
-         chunk=None):
+         chunk=None, tail_step=None):
     """The KDA mixer on a chunk: jamba._mamba's call form. u [B, T, D]
     (normed); valid [B, T] (a row's valid tokens lead); s [B, d_k, H *
-    d_v] float32 and tail [B, d_conv - 1, 3 H d_k]: the rows' state on
+    d_v] float32 and tail [B, (d_conv - 1) * 3 H d_k]: the rows' state on
     entry. Returns (out [B, T, D], s, tail) with the state after each
     row's last valid token. ``step`` is the one-token recurrence (T ==
     1) with _kda_step's operands and results, ``s`` being whatever it
     carries: the rows' states, or the pool they lie in. ``chunk`` is the
     chunk kernel (T > 1) with _kda_chunk's operands and results but the
-    chunk size, which is the kernel's own; None: _kda_chunk."""
+    chunk size, which is the kernel's own; None: _kda_chunk.
+    ``tail_step`` is the one-token advance of the conv tails
+    (jamba._causal_conv)."""
     f32 = jnp.float32
     B, T, _ = u.shape
     H, dk = cfg.kda_n_heads, cfg.kda_head_dim
@@ -322,7 +326,7 @@ def _kda(cfg: ModelConfig, mp, u, valid, s, tail, step=_kda_step,
         with jax.named_scope("kda.proj"):
             qkv = dot(u, mp["w_qkv"])                       # [B, T, 3 H dk]
         qkv, tail = _causal_conv(mp, qkv, valid, tail, cfg.mamba_d_conv,
-                                 "kda.conv")
+                                 "kda.conv", tail_step)
         with jax.named_scope("kda.gate"):
             q, k, v = (heads(x) for x in jnp.split(qkv, 3, axis=-1))
             q, k = _l2norm(q) * dk ** -0.5, _l2norm(k)
@@ -485,8 +489,8 @@ def _latent_window(cfg: ModelConfig, interpret, mesh):
 
 
 LATENT = jamba.Attending(_latent_chunk, _latent_window)
-BLOCKS = jamba.Blocks(KDA_KEYS, _kda, _ff, kda_step, conv_width,
-                      WINDOW_COUNTS, LATENT, kda_chunk)
+BLOCKS = jamba.Blocks(KDA_KEYS, _kda, _ff, kda_step, WINDOW_COUNTS, LATENT,
+                      kda_chunk)
 
 
 # ----------------------------------------------------- jitted entry points

@@ -95,7 +95,10 @@ for the other chips or their exchange (one device: ROADMAP B9).
 **Which keep state.** ``jamba.py``, ``granite.py``, ``kimi_linear.py``,
 ``solar_open2.py`` and ``lfm2.py`` carry per-sequence **recurrent state** beside the KV
 pages. A module declares that by
-having ``init_state(cfg, slots)`` (a tuple of pools indexed by slot);
+having ``init_state(cfg, slots)`` (a tuple of pools indexed by slot,
+along whichever axis the module's own programs say: the engine never
+looks inside; jamba.py's layout keeps the scan states slot-major ``[S,
+M, ...]`` and the conv tails layer-major ``[M, S, ...]``);
 the engine then owns the pools, every step program takes ``(state,
 state_slots)`` as trailing operands and returns the state last.
 

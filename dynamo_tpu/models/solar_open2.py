@@ -42,8 +42,8 @@ from .llama import Params
 from ..ops.kda import kda_chunk, kda_step
 
 BLOCKS = jamba.Blocks(kimi_linear.KDA_KEYS, kimi_linear._kda,
-                      kimi_linear._ff, kda_step, kimi_linear.conv_width,
-                      WINDOW_COUNTS, jamba.GQA, kda_chunk)
+                      kimi_linear._ff, kda_step, WINDOW_COUNTS, jamba.GQA,
+                      kda_chunk)
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:

@@ -106,7 +106,7 @@ class Pools:
         self.kv_k, self.kv_v = granite.init_kv_cache(cfg, KVCacheSpec(16, PS))
         ssm, conv = granite.init_state(cfg, slots)
         # what a previous owner left in the slot must not matter
-        self.state = (ssm.at[slot].set(7.0), conv.at[slot].set(3.0))
+        self.state = (ssm.at[slot].set(7.0), conv.at[:, slot].set(3.0))
         self.pages, self.slot, self.drop = list(pages), slot, slots - 1
         self.prefill, self.decode = granite.make_step_fns(cfg)
 
@@ -177,7 +177,7 @@ def test_from_hf_config_on_the_catalog_config():
                                      ("mamba", 5, 6, 4)]
     ssm, conv = jax.eval_shape(lambda: granite.init_state(run, 65))
     assert ssm.shape == (65, 9, 128, 8192) and ssm.dtype == jnp.float32
-    assert conv.shape == (65, 9, 3 * 8448)
+    assert conv.shape == (9, 65, 3 * 8448)         # layer-major
     assert not hasattr(granite, "init_state_snapshots")
 
     for key, value, match in [

@@ -76,7 +76,7 @@ class Pools:
         self.kv_k, self.kv_v = jamba.init_kv_cache(cfg, KVCacheSpec(16, PS))
         ssm, conv = jamba.init_state(cfg, slots)
         # what a previous owner left in the slot must not matter
-        self.state = (ssm.at[slot].set(7.0), conv.at[slot].set(3.0))
+        self.state = (ssm.at[slot].set(7.0), conv.at[:, slot].set(3.0))
         self.pages, self.slot, self.drop = list(pages), slot, slots - 1
         self.prefill, self.decode = jamba.make_step_fns(cfg)
 
@@ -593,6 +593,131 @@ def test_the_kernel_arm_never_gathers_or_scatters_the_scan_pool(program,
         aliases = dict(eqn.params["input_output_aliases"])
         assert operand in aliases
         assert eqn.outvars[aliases[operand]].aval.shape == pool_shape
+
+
+# ------------------------------------------- the conv tails of a decode step
+
+
+# family -> (layers M, rows B, channels C of a tail, a b_conv leaf): the
+# tail shapes of the four families at small widths
+_TAILS = {
+    "mamba1": (3, 8, 128, True),                # d_inner
+    "mamba2": (2, 5, 128 + 2 * 16, True),       # d_inner + 2N, five rows
+    "kda-32-heads": (2, 16, 3 * 32 * 8, False),     # q, k, v of 32 heads
+    "kda-64-heads": (2, 32, 3 * 64 * 8, False),     # of 64, two grid steps
+}
+
+
+@pytest.mark.parametrize("family", list(_TAILS))
+def test_the_tail_step_is_the_chunk_form_at_one_token_bit_for_bit(
+        family, monkeypatch):
+    """ops/conv_step.py (under interpretation, through _causal_conv's
+    ``tail_step``) against _causal_conv's chunk form on a chunk of ONE
+    token, bf16 tails and weights: the same result and the same next
+    tail BIT FOR BIT, for rows that advance, rows that are frozen
+    (``valid`` false keeps the tail) and a fresh row (a tail of zeros);
+    on two layers of one carried array in turn, a call on layer m
+    touching no other layer. 64 heads: rows in two grid steps."""
+    from dynamo_tpu.ops import conv_step
+
+    M, B, C, has_bias = _TAILS[family]
+    dc = 4
+    if family == "kda-64-heads":
+        monkeypatch.setattr(conv_step, "_BLOCK_BYTES", 16 * 3 * C * 2)
+        assert conv_step._rows_per_step(B, 3 * C * 2) == 16
+    rng = np.random.default_rng(C)
+    bf = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    tails = bf(M, B, (dc - 1) * C).at[:, 0].set(0)      # row 0 is fresh
+    valid = jnp.asarray(np.arange(B) % 3 != 1)[:, None]     # 1, 4, ..: frozen
+    chunk = jax.jit(lambda mp, x, tail: jamba._causal_conv(
+        mp, x, valid, tail, dc))
+    step = jax.jit(lambda mp, x, tails, m: jamba._causal_conv(
+        mp, x, valid, tails, dc,
+        tail_step=lambda t, *row: conv_step.conv_tail_step(
+            t, m, *row, interpret=True)))
+    bits = lambda a: np.asarray(a.astype(jnp.float32))
+    for m in (1, 0):
+        mp = {"conv_w": bf(dc, C)}
+        if has_bias:
+            mp["b_conv"] = bf(C)
+        x = jnp.asarray(rng.normal(size=(B, 1, C)), jnp.float32)
+        before = bits(tails)
+        want_xc, want_tail = chunk(mp, x, tails[m])
+        got_xc, tails = step(mp, x, tails, jnp.int32(m))
+        assert got_xc.dtype == jnp.float32 and tails.dtype == jnp.bfloat16
+        assert (np.asarray(got_xc) == np.asarray(want_xc)).all()
+        got = bits(tails)
+        assert (got[m] == bits(want_tail)).all()
+        frozen = ~np.asarray(valid)[:, 0]
+        assert (got[m][frozen] == before[m][frozen]).all()
+        # a row that advances dropped its oldest input and took x in
+        assert (got[m][~frozen][:, :2 * C] == before[m][~frozen][:, C:]).all()
+        assert (got[m][~frozen][:, 2 * C:]
+                == bits(x[:, 0].astype(jnp.bfloat16))[~frozen]).all()
+        others = [k for k in range(M) if k != m]
+        assert (got[others] == before[others]).all()
+
+
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["xla", "pallas_interpret"])
+def test_a_windows_tails_are_those_of_single_steps_and_of_one_chunk(
+        interpret, monkeypatch):
+    """A window of K steps leaves in the pool the conv tails (and the
+    scan state) that K single ``forward(T = 1)`` calls on its tokens
+    leave, and that ONE ``forward(T = K)`` chunk of them leaves: the
+    tails' layout and the step form change where the bytes lie and how
+    they move, not what a slot holds. ``pallas_interpret``: the window
+    and the single steps advance tails and state through the kernels
+    (ops/conv_step.py on the carried tails, ops/selective_scan.py on the
+    pool); the chunk is the chunk form either way."""
+    if interpret:
+        monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")     # decode_step
+    cfg = tiny()
+    params = jamba.init_params(cfg, jax.random.PRNGKey(4))
+    pools = Pools(cfg)
+    prompt = np.random.default_rng(4).integers(1, 512, 19)
+    first = int(np.argmax(pools.run_prefill(params, prompt, 0, 32)))
+    start = jax.tree.map(jnp.copy, (pools.kv_k, pools.kv_v, pools.state))
+    slots = jnp.asarray([pools.slot, pools.drop], jnp.int32)
+    B, K, n = 2, 4, len(prompt)
+
+    window = jamba.make_decode_window_fn(cfg, True, 64,
+                                         pallas_interpret=interpret)
+    toks, emitted, *_, in_window = window(
+        params, jnp.asarray([first, 0], jnp.int32),
+        jnp.asarray([n, -1], jnp.int32), jnp.zeros(B, bool),
+        jnp.zeros(B, jnp.int32), jnp.asarray([100, 1], jnp.int32),
+        *jax.tree.map(jnp.copy, start[:2]), pools.table(B), jnp.zeros(B),
+        jnp.zeros(B, jnp.int32), jnp.ones(B), jnp.zeros(B, jnp.uint32),
+        jnp.full((B, 8), -1, jnp.int32), None, jax.tree.map(jnp.copy,
+                                                            start[2]),
+        slots, k_steps=K, logprobs_topn=0)
+    assert list(np.asarray(emitted)) == [K, 0]
+    fed = [first, *(int(t) for t in toks[0][:K - 1])]   # the last is not fed
+
+    kv_k, kv_v, state = jax.tree.map(jnp.copy, start)
+    for j, tok in enumerate(fed):
+        at = n + j
+        flat = pools.pages[at // PS] * PS + at % PS
+        _, kv_k, kv_v, state = pools.decode(
+            params, jnp.asarray([tok, 0], jnp.int32),
+            jnp.asarray([at, -1], jnp.int32), kv_k, kv_v, pools.table(B),
+            jnp.asarray([flat, DROP_SLOT], jnp.int32), state, slots)
+    pools.kv_k, pools.kv_v, pools.state = jax.tree.map(jnp.copy, start)
+    pools.run_prefill(params, fed, n, 8)
+
+    def of_slot(state):
+        ssm, conv = state
+        assert conv.shape[:2] == (jamba.num_mamba_layers(cfg), 5)
+        return np.asarray(ssm[pools.slot]), np.asarray(conv[:, pools.slot])
+
+    for other in (state, pools.state):
+        for got, want in zip(of_slot(in_window), of_slot(other)):
+            assert np.abs(got - want).max() < ATOL
+    # the tails moved (K tokens in, every layer's), and the padding row
+    # left the drop slot's as they were
+    assert np.abs(of_slot(in_window)[1] - of_slot(start[2])[1]).max() > 1e-2
+    assert float(jnp.abs(in_window[1][:, pools.drop]).max()) == 0.0
 
 
 # ------------------------------------------------------------- refusals

@@ -132,7 +132,7 @@ class Pools:
             cfg, KVCacheSpec(16, PS))
         ssm, conv = kimi_linear.init_state(cfg, slots)
         # what a previous owner left in the slot must not matter
-        self.state = (ssm.at[slot].set(7.0), conv.at[slot].set(3.0))
+        self.state = (ssm.at[slot].set(7.0), conv.at[:, slot].set(3.0))
         self.pages, self.slot, self.drop = list(pages), slot, slots - 1
         self.prefill, self.decode = _step_fns(cfg)
 
@@ -201,7 +201,7 @@ def test_from_hf_config_on_the_cell_config():
         ("mamba", 3, 4, 3), ("attn", 1, 7)]
     state = jax.eval_shape(lambda: kimi_linear.init_state(cfg, 129))
     assert state[0].shape == (129, 6, 128, 4096)        # 2 MiB a layer a row
-    assert state[1].shape == (129, 6, 3 * 12288)
+    assert state[1].shape == (6, 129, 3 * 12288)         # layer-major
     kv = jax.eval_shape(lambda: kimi_linear.init_kv_cache(
         cfg, KVCacheSpec(8, 128)))
     assert kv[0].shape == (2, 8, 1, 128, 512)           # attending layers only

@@ -130,7 +130,7 @@ class Pools:
             cfg, KVCacheSpec(16, PS))
         ssm, conv = solar_open2.init_state(cfg, slots)
         # what a previous owner left in the slot must not matter
-        self.state = (ssm.at[slot].set(7.0), conv.at[slot].set(3.0))
+        self.state = (ssm.at[slot].set(7.0), conv.at[:, slot].set(3.0))
         self.pages, self.slot, self.drop = list(pages), slot, slots - 1
         self.prefill, self.decode = _step_fns(cfg)
 
@@ -203,7 +203,7 @@ def test_from_hf_config_on_the_cell_config():
     assert not set(kimi_linear.DENSE_KEYS) & set(shapes)    # no dense leaf
     state = jax.eval_shape(lambda: solar_open2.init_state(cfg, 129))
     assert state[0].shape == (129, 3, 128, 8192)        # 4 MiB a layer a row
-    assert state[1].shape == (129, 3, 3 * 24576)
+    assert state[1].shape == (3, 129, 3 * 24576)         # layer-major
     kv = jax.eval_shape(lambda: solar_open2.init_kv_cache(
         cfg, KVCacheSpec(8, 128)))
     assert kv[0].shape == (1, 8, 8, 128, 128)           # attending layers only
@@ -312,7 +312,7 @@ def test_the_check_can_see_beta_the_gate_and_the_states_precision():
     u = jnp.asarray(np.random.default_rng(3).normal(size=(8, 1, 64)),
                     jnp.float32)
     kimi_linear._kda(cfg, mp, u, jnp.ones((8, 1), bool),
-                     jnp.zeros((8, 16, 64)), jnp.zeros((8, 3, 192)), spy)
+                     jnp.zeros((8, 16, 64)), jnp.zeros((8, 3 * 192)), spy)
     assert 1.0 < float(seen[0].max()) < 2.0 and float(seen[0].min()) > 0.0
     # the same weights through a program that ignores the key
     clamped = dataclasses.replace(cfg, kda_beta_scale=1.0)

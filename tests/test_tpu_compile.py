@@ -310,6 +310,19 @@ def _pool_sized_copies(text: str, pool_elems: int):
     return out
 
 
+def _tails_stay_layer_major(text: str, tails, B: int, program: str):
+    """The conv tails of a jamba.Blocks family in an optimized program: a
+    decode program (B its rows) carries them layer-major, [M, B, W], and
+    a token's step advances a layer's block through ops/conv_step.py
+    where it lies; a prefill chunk has no such step. (The compiler's own
+    gather and scatter of the rows, once a program, still pass through
+    rows-major values: PERF.md section 7.)"""
+    M, _, W = tails.shape
+    assert ("conv_tail_step" in text) == (program != "prefill")
+    if program != "prefill":
+        assert "bf16[%d,%d,%d]" % (M, B, W) in text
+
+
 def _computations_with(text: str, shape: str, op: str):
     """The optimized program's computations (a fusion's body is one)
     that hold an `op` whose result is of `shape`, each as its text."""
@@ -846,6 +859,26 @@ def test_scan_kernel_compiles(one_chip, B):
         s((N, di), f32), s((B,), jnp.bool_)).compile())
 
 
+@pytest.mark.parametrize("M,B,C", [
+    (26, 128, 5120), (9, 64, 8448), (6, 128, 12288), (3, 128, 24576),
+    (3, 8, 24576), (3, 1, 24576)],
+    ids=["jamba2-3b", "granite-4.0-h-small", "kimi-linear-48b-a3b",
+         "solar-open2-250b", "eight-rows", "one-row"])
+def test_conv_tail_kernel_compiles(one_chip, M, B, C):
+    """ops/conv_step.py at the carried tails of the four cells that run
+    it (cells 4, 8, 10, 11: [M, B, 3 * C] bf16 at their window batch) and
+    at a prefill batch's rows (a chunk of one token): the chip's compiler
+    takes the blocks of whole rows (16 to 64 rows, 2.4 MB at most), the
+    taps at lane offsets of C and the VMEM asked for."""
+    from dynamo_tpu.ops.conv_step import conv_tail_step
+
+    s = partial(_sds, one_chip)
+    f32 = jnp.float32
+    assert _has_kernel(conv_tail_step.lower(
+        s((M, B, 3 * C), jnp.bfloat16), s((), jnp.int32), s((B, C), f32),
+        s((B,), jnp.bool_), s((4, C), f32), s((C,), f32)).compile())
+
+
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_jamba_programs_write_no_array_of_the_state_pools_size(
         one_chip, tpu_kernel_path, program):
@@ -881,6 +914,7 @@ def test_jamba_programs_write_no_array_of_the_state_pools_size(
             s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), state,
             s((PB,), jnp.int32)).compile()
     text = compiled.as_text()
+    _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, state[0].size) == []
     mem = compiled.memory_analysis()
@@ -969,6 +1003,7 @@ def test_granite_programs_write_no_array_of_the_state_pools_size(
             s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), state,
             s((PB,), jnp.int32)).compile()
     text = compiled.as_text()
+    _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, state[0].size) == []
     mem = compiled.memory_analysis()
@@ -1084,6 +1119,7 @@ def test_kimi_linear_programs_write_no_array_of_a_pools_size(
             s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
             state, s((PB,), jnp.int32)).compile()
     text = compiled.as_text()
+    _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     # a chunk's scan is the chunk kernel, a token's the step kernel
     assert ("kda_chunk" in text) == (program == "prefill")
@@ -1190,6 +1226,7 @@ def test_solar_open2_programs_write_no_array_of_a_pools_size(
     assert "attn.gate" in lowered.as_text(debug_info=True)
     compiled = lowered.compile()
     text = compiled.as_text()
+    _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     # a chunk's scan is the chunk kernel, a token's the step kernel; the
     # attention beside it is the GQA kernel of the same kind
