@@ -100,6 +100,19 @@ class ModelConfig:
     # SmallThinker: the router reads the layer's un-normed input, before
     # attention (its logits are made at the layer's entry)
     moe_early_router: bool = False
+    # Cohere2-MoE (model_type "cohere2_moe", models/cohere2_moe.py on
+    # llama.py's by-kind path): a PARALLEL block (one norm a layer,
+    # attention and the second half both read it and are added to the
+    # residual stream once; no ln_mlp), a LayerNorm without bias (the
+    # mean subtracted; its epsilon is kept in rms_norm_eps), the rotation
+    # over interleaved pairs (rope_interleave: in llama.py's family the
+    # pairs (2i, 2i + 1) are rotated as they lie; mla.py's loader
+    # permutes its columns instead), and shared experts whose summed
+    # output is multiplied by shared_expert_scale (1 / num_shared_experts
+    # where they are AVERAGED).
+    parallel_block: bool = False
+    layer_norm: bool = False
+    shared_expert_scale: float = 1.0
     # Jamba (model_type "jamba", models/jamba.py): Mamba-1 mixers in
     # every layer but those at attn_layer_offset + k * attn_layer_period,
     # which attend (no positional embedding of any kind). mamba_d_state
@@ -286,6 +299,10 @@ class ModelConfig:
     @classmethod
     def from_hf_config(cls, cfg: dict) -> "ModelConfig":
         mt = cfg.get("model_type", "llama")
+        if mt == "cohere2_moe":
+            # nothing of this family may be read through the Llama
+            # defaults below (sliding_window would take Gemma-2's rule)
+            return cls._read_cohere2_moe(cfg)
         c = cls(
             model_type="mixtral" if mt == "mixtral" else "llama",
             vocab_size=cfg["vocab_size"],
@@ -481,6 +498,125 @@ class ModelConfig:
                 c.final_logit_softcap = cfg.get("final_logit_softcapping")
                 c.query_pre_attn_scalar = cfg.get("query_pre_attn_scalar")
         return c
+
+    @classmethod
+    def _read_cohere2_moe(cls, cfg: dict) -> "ModelConfig":
+        """The keys of a ``cohere2_moe`` config.json (Command A+): every
+        layer a parallel block under one bias-free LayerNorm; window
+        layers (``layer_types`` ``sliding_attention``) rotate q and k
+        over interleaved pairs, full layers apply no positional
+        embedding; sigmoid-routed experts of width ``intermediate_size``
+        beside ``num_shared_experts`` shared ones that are averaged.
+        ``layer_types`` is kept whole in a file cut in depth: the first
+        ``num_hidden_layers`` entries are the layers that run.
+        ``num_experts`` is the experts HELD; a file cut to a chip's share
+        names the published count (``router_num_experts``) and the first
+        expert held (``first_local_expert``) beside it, as granite's,
+        kimi's and solar's do."""
+        def refuse(what: str, why: str):
+            raise NotImplementedError(
+                f"cohere2_moe with {what} is not supported ({why})")
+
+        L = cfg["num_hidden_layers"]
+        kinds = list(cfg["layer_types"][:L])
+        odd = sorted(set(kinds) - {"sliding_attention", "full_attention"})
+        if odd or len(kinds) != L:
+            refuse(f"layer_types {odd or len(kinds)}",
+                   f"it must name num_hidden_layers = {L} layers, each "
+                   f"sliding_attention or full_attention")
+        if len(set(kinds)) != 2:
+            refuse("layers of one kind only",
+                   "the K/V pools are one a kind of layer; a model whose "
+                   "layers all see the same is another module's")
+        if not cfg.get("use_parallel_block", False):
+            refuse("use_parallel_block false",
+                   "attention and the experts read ONE LayerNorm and are "
+                   "added once; the family's sequential form has a second "
+                   "norm that no leaf of this module holds")
+        if cfg.get("use_qk_norm", False):
+            refuse("use_qk_norm true",
+                   "q and k are rotated as projected; the family's q/k "
+                   "norm is a LayerNorm a head that is not computed")
+        if cfg.get("first_k_dense_replace", 0):
+            refuse(f"first_k_dense_replace {cfg['first_k_dense_replace']}",
+                   "every layer's second half is routed experts beside the "
+                   "shared ones; the module builds no dense MLP "
+                   "(prefix_dense_intermediate_size is read by no layer)")
+        if cfg.get("rotary_pct", 1) != 1:
+            refuse(f"rotary_pct {cfg['rotary_pct']}",
+                   "the window layers rotate all head_dim columns")
+        if cfg.get("position_embedding_type", "rope_gptj") != "rope_gptj":
+            refuse(f"position_embedding_type "
+                   f"{cfg['position_embedding_type']!r}",
+                   "the window layers rotate interleaved pairs (rope_gptj)")
+        if (cfg.get("rope_parameters") or {}).get("rope_type",
+                                                  "default") != "default" \
+                or cfg.get("rope_scaling"):
+            refuse("a rope_type other than default",
+                   "the window layers rotate by rope_theta alone")
+        shared = cfg.get("num_shared_experts", 0)
+        strategy = cfg.get("shared_expert_combination_strategy", "average")
+        if shared and strategy != "average":
+            refuse(f"shared_expert_combination_strategy {strategy!r}",
+                   "the shared experts' outputs are averaged and the mean "
+                   "is added to the routed sum")
+        if cfg.get("expert_selection_fn", "sigmoid") != "sigmoid":
+            refuse(f"expert_selection_fn {cfg['expert_selection_fn']!r}",
+                   "the gate scores by a sigmoid")
+        if not cfg.get("norm_topk_prob", True):
+            refuse("norm_topk_prob false",
+                   "the chosen sigmoid scores are renormalised")
+        if not cfg.get("use_gated_activation", True) \
+                or cfg.get("hidden_act", "silu") != "silu":
+            refuse(f"hidden_act {cfg.get('hidden_act')!r} / "
+                   f"use_gated_activation "
+                   f"{cfg.get('use_gated_activation')}",
+                   "its experts are SwiGLU")
+        if cfg.get("attention_bias", False):
+            refuse("attention_bias true",
+                   "the projections are computed without a bias")
+        held = cfg["num_experts"]
+        width = cfg.get("router_num_experts", held)
+        first = cfg.get("first_local_expert", 0)
+        if not 0 <= first <= width - held:
+            refuse(f"first_local_expert {first}",
+                   f"the {held} experts held must lie inside the "
+                   f"router's {width}")
+        if cfg["num_experts_per_tok"] > width:
+            refuse(f"num_experts_per_tok {cfg['num_experts_per_tok']}",
+                   f"the router has {width} outputs")
+        window = int(cfg["sliding_window"])
+        sliding = [k == "sliding_attention" for k in kinds]
+        rope = cfg.get("rope_parameters") or {}
+        return cls(
+            model_type="cohere2_moe",
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            # the width of ONE expert (the file has no other key for it)
+            intermediate_size=cfg["intermediate_size"],
+            num_layers=L,
+            num_heads=cfg["num_attention_heads"],
+            num_kv_heads=cfg.get("num_key_value_heads",
+                                 cfg["num_attention_heads"]),
+            head_dim=cfg.get("head_dim"),
+            rope_theta=cfg.get("rope_theta", rope.get("rope_theta", 50000.0)),
+            rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+            tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+            num_experts=held, router_experts=width, first_expert=first,
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            # sigmoid scores, the chosen renormalised; no selection bias,
+            # no groups, no scaling factor (the file has no key for any)
+            moe_router="deepseek_v3", norm_topk_prob=True,
+            n_shared_experts=shared,
+            shared_expert_scale=1.0 / shared if shared else 1.0,
+            sliding_window=window,
+            layer_window=tuple(window if s else None for s in sliding),
+            layer_rope=tuple(sliding),
+            kv_pool_by_kind=True, rope_interleave=True,
+            parallel_block=True, layer_norm=True,
+            # logits = logit_scale * (h @ E^T): project_logits divides
+            logits_scaling=1.0 / float(cfg.get("logit_scale", 1.0)),
+        )
 
     def _read_smallthinker(self, cfg: dict) -> None:
         """The keys of a ``smallthinker`` config.json: primary experts

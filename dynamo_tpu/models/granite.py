@@ -74,7 +74,8 @@ from . import jamba
 from .config import ModelConfig
 from .jamba import (_at, _causal_conv, init_kv_cache,  # noqa: F401
                     num_mamba_layers, segments)
-from .llama import Params, _moe_use_blocked, moe_experts, rms_norm
+from .llama import (Params, _moe_use_blocked, held_first,  # noqa: F401
+                    moe_experts, pairs_counted, rms_norm)
 from ..ops.selective_scan import ssd_step
 
 MAMBA2_KEYS = ("w_in", "conv_w", "b_conv", "b_dt", "A_log", "d_skip",
@@ -265,12 +266,6 @@ def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step,
 # ------------------------------------------------- the layer's second half
 
 
-def held_first(cfg: ModelConfig):
-    """llama.moe_experts' ``first``: None where every expert the router
-    scores is here, else the index of the first one held."""
-    return None if cfg.router_width == cfg.num_experts else cfg.first_expert
-
-
 def _moe_ff(params: Params, cfg: ModelConfig, norm, h, l, valid, l0=None):
     """(h + r * (routed experts held here + the shared expert) of
     norm(h), WINDOW_COUNTS of this layer), layer l (traced inside a
@@ -287,10 +282,7 @@ def _moe_ff(params: Params, cfg: ModelConfig, norm, h, l, valid, l0=None):
                 params["w_router"], l, 0, False)).astype(jnp.float32)
             weights, idx = lax.top_k(logits, k)
             weights = jax.nn.softmax(weights, axis=-1)
-            here = (idx >= cfg.first_expert) & (idx < cfg.first_expert + E)
-            counted = jnp.stack([
-                k * jnp.sum(valid), jnp.sum(here & valid[..., None])
-            ]).astype(jnp.int32)
+            counted = pairs_counted(cfg, idx, valid)
         # the sorted form reads w[layer, expert] from the whole stacks,
         # the dense form one layer's (llama._moe_use_blocked: the rule)
         if _moe_use_blocked(None, B * T, E, k):

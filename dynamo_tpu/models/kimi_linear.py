@@ -88,7 +88,8 @@ from . import jamba, llama, mla
 from .config import ModelConfig
 from .granite import WINDOW_COUNTS, held_first
 from .jamba import _at, _causal_conv, num_mamba_layers
-from .llama import KVCacheSpec, Params, _mlp, _moe_use_blocked, rms_norm
+from .llama import (KVCacheSpec, Params, _mlp, _moe_use_blocked,
+                    pairs_counted, rms_norm)
 from ..ops.kda import kda_chunk, kda_step
 
 KDA_KEYS = ("w_qkv", "conv_w", "w_f1", "w_f2", "b_dt", "A_log", "w_beta",
@@ -383,11 +384,7 @@ def _ff(params: Params, cfg: ModelConfig, norm, h, l, valid, l0):
         with jax.named_scope("moe.router"):
             gate = mla._deepseek_gate(x.astype(jnp.float32), lp["w_router"],
                                       lp["router_bias"], cfg)
-            idx = gate[1]
-            here = (idx >= cfg.first_expert) & (idx < cfg.first_expert + E)
-            counted = jnp.stack([
-                k * jnp.sum(valid), jnp.sum(here & valid[..., None])
-            ]).astype(jnp.int32)
+            counted = pairs_counted(cfg, gate[1], valid)
         # the sorted form reads w[layer, expert] from the whole stacks,
         # the dense form one layer's (llama._moe_use_blocked: the rule)
         blocked = _moe_use_blocked(None, B * T, E, k)

@@ -179,6 +179,24 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float,
     return normed.astype(x.dtype) * w
 
 
+def layer_norm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
+    """LayerNorm without a bias: the mean subtracted, the variance about
+    it, the weight applied in float32 before the cast (HF
+    CohereLayerNorm)."""
+    x32 = x.astype(jnp.float32)
+    c = x32 - jnp.mean(x32, axis=-1, keepdims=True)
+    var = jnp.mean(c * c, axis=-1, keepdims=True)
+    return (c * lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(cfg: ModelConfig, x: jax.Array, w: jax.Array) -> jax.Array:
+    """The configuration's norm of the residual stream: ``layer_norm``
+    where it says so (``cfg.layer_norm``), else ``rms_norm``."""
+    if cfg.layer_norm:
+        return layer_norm(x, w, cfg.rms_norm_eps)
+    return rms_norm(x, w, cfg.rms_norm_eps, cfg.norm_unit_offset)
+
+
 def embed_tokens(params: Params, cfg: ModelConfig,
                  tokens: jax.Array) -> jax.Array:
     """Token embedding lookup; Gemma scales by sqrt(hidden), Granite by
@@ -237,11 +255,18 @@ def rope_freqs(cfg: ModelConfig, dim: Optional[int] = None) -> jax.Array:
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
-               inv_freq: jax.Array) -> jax.Array:
-    """x: [..., T, heads, head_dim]; positions: [..., T]."""
+               inv_freq: jax.Array, interleave: bool = False) -> jax.Array:
+    """x: [..., T, heads, head_dim]; positions: [..., T]. Pair i is the
+    columns (i, i + head_dim / 2) (HF ``rotate_half``), or with
+    ``interleave`` the columns (2i, 2i + 1) as they lie (GPT-J's)."""
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [...,T,hd/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., T, 1, hd/2]
     sin = jnp.sin(angles)[..., None, :]
+    if interleave:
+        xp = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+        x1, x2 = xp[..., 0], xp[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -594,8 +619,12 @@ def _layer_keys(cfg: ModelConfig) -> list:
     single source for every forward variant (paged, fused window, full)."""
     keys = ["wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down",
             "ln_attn", "ln_mlp"]
+    if cfg.parallel_block:      # ONE norm a layer
+        keys.remove("ln_mlp")
     if cfg.num_experts > 0:
         keys.append("w_router")
+    if cfg.n_shared_experts > 0:
+        keys += list(SHARED_KEYS)
     if cfg.attn_bias:
         keys += ["bq", "bk", "bv"]
     if cfg.sandwich_norms:
@@ -603,6 +632,9 @@ def _layer_keys(cfg: ModelConfig) -> list:
     if cfg.qk_norm:
         keys += ["q_norm", "k_norm"]
     return keys
+
+
+SHARED_KEYS = ("w_gate_s", "w_up_s", "w_down_s")
 
 
 def _residual_add(h: jax.Array, out: jax.Array, lp, post_key: str,
@@ -992,6 +1024,109 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
         return out.astype(out_dtype)
 
 
+def held_first(cfg: ModelConfig):
+    """``moe_experts``' ``first``: None where every expert the router
+    scores is here, else the index of the first one held."""
+    return None if cfg.router_width == cfg.num_experts else cfg.first_expert
+
+
+def pairs_counted(cfg: ModelConfig, idx: jax.Array,
+                  valid: jax.Array) -> jax.Array:
+    """What a decode window counts of one layer's gate (granite.py
+    ``WINDOW_COUNTS``): the (token, expert) pairs the router chose for
+    the ``valid`` [B, T] rows, and those whose expert is held here."""
+    here = ((idx >= cfg.first_expert)
+            & (idx < cfg.first_expert + cfg.num_experts))
+    return jnp.stack([
+        cfg.num_experts_per_tok * jnp.sum(valid),
+        jnp.sum(here & valid[..., None])]).astype(jnp.int32)
+
+
+def deepseek_gate(x32, w_router, bias, cfg: ModelConfig):
+    """DeepSeek router → (weights [B, T, k], expert indices [B, T, k]).
+
+    v2 (HF DeepseekV2MoEGate): softmax scores; optional group limiting by
+    the MAX score per group; top-k; weights scaled (NOT renormalized).
+    v3 (HF DeepseekV3TopkRouter): sigmoid scores; selection by scores +
+    e_score_correction_bias with groups ranked by their top-2 SUM; the
+    applied weights are the ORIGINAL sigmoid scores of the selected
+    experts, optionally renormalized, then scaled."""
+    E = w_router.shape[-1]
+    k = cfg.num_experts_per_tok
+    logits = x32 @ w_router.astype(jnp.float32)
+    if cfg.moe_router == "deepseek_v3":
+        scores = jax.nn.sigmoid(logits)
+        # no selection bias where the family has none (cohere2_moe)
+        choice = (scores if bias is None
+                  else scores + bias.astype(jnp.float32))
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        choice = scores
+    if cfg.n_group > 0 and cfg.topk_group > 0:
+        G = cfg.n_group
+        cg = choice.reshape(*choice.shape[:-1], G, E // G)
+        if cfg.moe_router == "deepseek_v3":
+            g_scores = jnp.sum(lax.top_k(cg, 2)[0], axis=-1)
+        else:
+            g_scores = jnp.max(cg, axis=-1)
+        _, g_idx = lax.top_k(g_scores, cfg.topk_group)
+        g_mask = jnp.sum(jax.nn.one_hot(g_idx, G, dtype=jnp.float32),
+                         axis=-2)
+        choice = jnp.where(g_mask[..., :, None] > 0, cg,
+                           0.0).reshape(choice.shape)
+    _, topi = lax.top_k(choice, k)
+    w = jnp.take_along_axis(scores, topi, axis=-1)
+    # v3 (HF DeepseekV3TopkRouter): optional renorm, then ALWAYS scaled.
+    # v2: transformers' DeepseekV2MoEGate ignores norm_topk_prob (always
+    # scales); configs setting it are rejected at ModelConfig load.
+    if cfg.moe_router == "deepseek_v3" and cfg.norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.moe_renorm_eps)
+    w = w * cfg.routed_scaling_factor
+    return w, topi
+
+
+def deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
+                     live=None, layer=None, first=None,
+                     gate=None) -> jax.Array:
+    """Routed experts plus the always-on shared experts, on x [B, T, D].
+
+    ``lp`` holds one layer's router, bias and shared-expert leaves. The
+    routed experts run through ``moe_experts``, the execution
+    every gate shares: either that layer's ``[E, ...]`` stacks (the
+    dense einsum over every expert: decode-sized dispatches, where one
+    read of the weights bounds both forms, and expert-parallel meshes)
+    or, with ``layer`` (a traced index into the expert segment), the
+    whole ``[Lm, E, ...]`` parameters read in place by the sorted
+    blocked dispatch, whose work follows the ``live`` (token, expert)
+    pairs (_moe_use_blocked holds the rule; the callers apply it).
+    ``first``: which experts the stacks hold of those the gate scored
+    (``moe_experts``: a chip's share of the layer; None = all).
+    ``gate``: the gate's (weights, indices) where the caller has made
+    them already (models/kimi_linear.py counts the pairs held)."""
+    x32 = x.astype(jnp.float32)
+    if gate is None:
+        with jax.named_scope("moe.router"):
+            gate = deepseek_gate(x32, lp["w_router"],
+                                 lp.get("router_bias"), cfg)
+    w, topi = gate
+    out = moe_experts(x32, w, topi, lp["w_gate_e"], lp["w_up_e"],
+                      lp["w_down_e"], layer is not None, live=live,
+                      layer=layer, first=first,
+                      width=None if first is None else cfg.router_width)
+    if cfg.n_shared_experts > 0:
+        with jax.named_scope("moe.shared"):
+            shared = (jax.nn.silu(x @ lp["w_gate_s"])
+                      * (x @ lp["w_up_s"])) @ lp["w_down_s"]
+            if cfg.shared_expert_scale != 1.0:
+                # shared experts that are AVERAGED: the stacks hold them
+                # side by side, so their sum is one MLP and the mean a
+                # multiple of it
+                shared = shared * jnp.asarray(cfg.shared_expert_scale,
+                                              shared.dtype)
+            out = out + shared
+    return out.astype(x.dtype)
+
+
 def _moe_mlp(h: jax.Array, w_router, w_gate, w_up, w_down,
              top_k: int, mesh=None, live=None, layer=None,
              act=jax.nn.silu, logits=None) -> jax.Array:
@@ -1022,28 +1157,45 @@ def router_logits(h: jax.Array, w_router) -> jax.Array:
         return (h @ w_router).astype(jnp.float32)
 
 
+def _ff_out(x, lp, cfg: ModelConfig, mesh, experts=None, live=None,
+            l_idx=None, logits=None, valid=None):
+    """What the second half of a layer adds, on the normed ``x``: the
+    MLP, the Mixtral-style routed experts, or (``cfg.moe_router`` of the
+    DeepSeek kind) the sigmoid gate's experts HELD here beside the shared
+    ones (``deepseek_moe_mlp``). ``experts``: the stacked (gate, up,
+    down) weights of every layer, read in place at ``l_idx`` by the
+    sorted dispatch, with ``live`` the rows that make pairs (a prefill);
+    else ``lp`` holds the layer's own. ``logits``: the router's, made at
+    the layer's entry. Returns (out, counted): ``counted`` is
+    ``pairs_counted`` of the ``valid`` rows where the gate is the
+    DeepSeek kind and ``valid`` is given (a decode window), else None."""
+    if cfg.num_experts == 0:
+        return _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                    _act(cfg)), None
+    if experts is None:     # the layer's own stacks: the dense form
+        experts = (lp["w_gate"], lp["w_up"], lp["w_down"])
+        live = l_idx = None
+    with jax.named_scope("moe"):
+        if cfg.moe_router == "mixtral":
+            return _moe_mlp(x, lp["w_router"], *experts,
+                            cfg.num_experts_per_tok, mesh=mesh, live=live,
+                            layer=l_idx, act=_act(cfg), logits=logits), None
+        with jax.named_scope("moe.router"):
+            gate = deepseek_gate(x.astype(jnp.float32), lp["w_router"],
+                                 lp.get("router_bias"), cfg)
+            counted = (None if valid is None
+                       else pairs_counted(cfg, gate[1], valid))
+        mp = {**lp, **dict(zip(("w_gate_e", "w_up_e", "w_down_e"), experts))}
+        return deepseek_moe_mlp(x, mp, cfg, mesh, live=live, layer=l_idx,
+                                first=held_first(cfg), gate=gate), counted
+
+
 def _layer_ff(h, lp, cfg: ModelConfig, mesh, experts=None, live=None,
               l_idx=None, logits=None):
-    """The second half of a layer: h + the MLP, or the routed experts, of
-    norm(h). ``experts``: the stacked (gate, up, down) weights of every
-    layer, read in place at ``l_idx`` by the sorted dispatch, with
-    ``live`` the rows that make pairs (a prefill); else ``lp`` holds the
-    layer's own. ``logits``: the router's, made at the layer's entry."""
+    """The second half of a sequential layer: h + ``_ff_out`` of
+    norm(h)."""
     x = rms_norm(h, lp["ln_mlp"], cfg.rms_norm_eps, cfg.norm_unit_offset)
-    if cfg.num_experts == 0:
-        mlp_out = _mlp(x, lp["w_gate"], lp["w_up"], lp["w_down"], _act(cfg))
-    else:
-        with jax.named_scope("moe"):
-            if experts is not None:
-                mlp_out = _moe_mlp(x, lp["w_router"], *experts,
-                                   cfg.num_experts_per_tok, mesh=mesh,
-                                   live=live, layer=l_idx, act=_act(cfg),
-                                   logits=logits)
-            else:
-                mlp_out = _moe_mlp(x, lp["w_router"], lp["w_gate"],
-                                   lp["w_up"], lp["w_down"],
-                                   cfg.num_experts_per_tok, mesh=mesh,
-                                   act=_act(cfg), logits=logits)
+    mlp_out, _ = _ff_out(x, lp, cfg, mesh, experts, live, l_idx, logits)
     return _residual_add(h, mlp_out, lp, "ln_mlp_post", cfg)
 
 
@@ -1183,14 +1335,42 @@ def _qkv(cfg: ModelConfig, lp, x: jax.Array, pos: jax.Array, inv_freq,
     ``x``; the rotary embedding only where the layer's layout has it."""
     B, T, _ = x.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
-    xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
-    if cfg.attn_bias:
-        xq, xk, xv = xq + lp["bq"], xk + lp["bk"], xv + lp["bv"]
+    with jax.named_scope("attn.proj"):
+        xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
+        if cfg.attn_bias:
+            xq, xk, xv = xq + lp["bq"], xk + lp["bk"], xv + lp["bv"]
     q, k = _qk_headnorm(xq.reshape(B, T, H, hd), xk.reshape(B, T, KV, hd),
                         lp, cfg)
     if rotate:
-        q, k = apply_rope(q, pos, inv_freq), apply_rope(k, pos, inv_freq)
+        q = apply_rope(q, pos, inv_freq, cfg.rope_interleave)
+        k = apply_rope(k, pos, inv_freq, cfg.rope_interleave)
     return q, k, xv.reshape(B, T, KV, hd)
+
+
+def _attn_out(cfg: ModelConfig, lp, h, attn):
+    """Attention's heads ``attn`` [B, T, H * hd] through ``wo``, under
+    the scope ``attn``: added to the stream ``h`` in a sequential layer;
+    in a PARALLEL block (``cfg.parallel_block``) the product alone,
+    which ``_second_half`` adds."""
+    with jax.named_scope("attn.proj"):
+        out = attn @ lp["wo"]
+    return out if cfg.parallel_block else _residual_add(
+        h, out, lp, "ln_attn_post", cfg)
+
+
+def _second_half(cfg: ModelConfig, lp, h, x, a, mesh, experts=None,
+                 live=None, l_idx=None, logits=None, valid=None):
+    """(the layer's output, what ``_ff_out`` counted) from ``a`` =
+    ``_attn_out``'s. A sequential layer hands the stream with attention
+    added to ``_layer_ff`` (a norm of its own); a parallel block runs the
+    second half on the layer's ONE normed input ``x`` and adds both
+    branches to the stream once."""
+    if not cfg.parallel_block:
+        return _layer_ff(a, lp, cfg, mesh, experts, live, l_idx,
+                         logits), None
+    ff, counted = _ff_out(x, lp, cfg, mesh, experts, live, l_idx, logits,
+                          valid)
+    return h + a + ff, counted
 
 
 def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
@@ -1214,7 +1394,13 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
     pool is seen as [layers * pages, ...], so that a layer's pages are
     written and read along the major axis and no layer is sliced out
     (lfm2.py's form). The router reads the layer's input
-    where ``cfg.moe_early_router``. Returns (hidden, kv_k, kv_v, wkv)."""
+    where ``cfg.moe_early_router``. The layer's form follows what the
+    configuration has, in Python at trace time: the norm (``_norm``), the
+    rotation's pairs (``_qkv``), a sequential layer or a parallel block
+    (``_attn_out`` / ``_second_half``) and the second half's kind
+    (``_ff_out``); a configuration that has none of the newer forms
+    lowers to the program it lowered to before they existed.
+    Returns (hidden, kv_k, kv_v, wkv)."""
     wk, wv = wkv
     wtable, wbase, wslots = wtab
     inv_freq = rope_freqs(cfg)
@@ -1250,8 +1436,7 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
             logits = (router_logits(h, lp["w_router"])
                       if cfg.moe_early_router else None)
             with jax.named_scope("attn"):
-                x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
-                             cfg.norm_unit_offset)
+                x = _norm(cfg, h, lp["ln_attn"])
                 q, k, v = _qkv(cfg, lp, x, safe_pos, inv_freq, cfg.rotates(j))
                 if window is None:
                     off = (per * n_full + a_full) * NPf
@@ -1278,9 +1463,9 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
                             cfg.attn_scale, allow_pallas=allow_pallas,
                             mesh=mesh, softcap=cfg.attn_logit_softcap,
                             window=window, is_sliding=True)
-                h = _residual_add(h, attn.reshape(B, T, H * hd) @ lp["wo"],
-                                  lp, "ln_attn_post", cfg)
-            h = _layer_ff(h, lp, cfg, mesh, experts, live, l_idx, logits)
+                a = _attn_out(cfg, lp, h, attn.reshape(B, T, H * hd))
+            h, _ = _second_half(cfg, lp, h, x, a, mesh, experts, live, l_idx,
+                                logits)
         return (h, fk, fv, pk, pv), None
 
     # the pools ride the scan as its CARRY, each seen as [layers * pages,
@@ -1291,8 +1476,7 @@ def _forward_by_kind(params: Params, cfg: ModelConfig, tokens, positions,
         period, (h, _flat_pool(kv_k), _flat_pool(kv_v), _flat_pool(wk),
                  _flat_pool(wv)),
         jnp.arange(n_per, dtype=jnp.int32))
-    h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps,
-                 cfg.norm_unit_offset)
+    h = _norm(cfg, h, params["ln_final"])
     return (h, fk.reshape(kv_k.shape), fv.reshape(kv_v.shape),
             (pk.reshape(wk.shape), pv.reshape(wv.shape)))
 
@@ -1553,7 +1737,10 @@ def _window_family_by_kind(cfg: ModelConfig, allow_pallas: bool, mesh,
     both pools are read-only in the steps, the steps' K/V of every layer
     go to one buffer [L, B, K, KV, hd], and the commit writes each kind's
     layers to its own pool by whole pages, the window layers' at the
-    positions counted from the row's base."""
+    positions counted from the row's base. Where the second half is the
+    DeepSeek kind's (``_ff_out``) a step also returns what its layers
+    counted of the live rows' pairs (``pairs_counted``: the module that
+    runs this family declares ``WINDOW_COUNTS``, models/cohere2_moe.py)."""
     inv_freq = rope_freqs(cfg)
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim_
     mode = kernel_mode(allow_pallas, pallas_interpret, mesh)
@@ -1586,14 +1773,13 @@ def _window_family_by_kind(cfg: ModelConfig, allow_pallas: bool, mesh,
         def period(h, xs):
             per, wk_p, wv_p = xs
             a_full = a_win = 0
-            ks, vs = [], []
+            ks, vs, tally = [], [], []
             for j, window in enumerate(windows):
                 lp = _at(params, keys, per * p + j)
                 logits = (router_logits(h, lp["w_router"])
                           if cfg.moe_early_router else None)
                 with jax.named_scope("attn"):
-                    x = rms_norm(h, lp["ln_attn"], cfg.rms_norm_eps,
-                                 cfg.norm_unit_offset)
+                    x = _norm(cfg, h, lp["ln_attn"])
                     q, k, v = _qkv(cfg, lp, x, safe_pos, inv_freq,
                                    cfg.rotates(j))
                     wk_l = wk_p[j].at[:, i].set(k[:, 0].astype(wdt))
@@ -1614,23 +1800,26 @@ def _window_family_by_kind(cfg: ModelConfig, allow_pallas: bool, mesh,
                                 softcap=cfg.attn_logit_softcap,
                                 window=window, is_sliding=True,
                                 q_pos=rel_pos)
-                    h = _residual_add(
-                        h, attn.reshape(B, 1, H * hd) @ lp["wo"], lp,
-                        "ln_attn_post", cfg)
-                h = _layer_ff(h, lp, cfg, mesh, logits=logits)
+                    a = _attn_out(cfg, lp, h, attn.reshape(B, 1, H * hd))
+                h, counted = _second_half(cfg, lp, h, x, a, mesh,
+                                          logits=logits,
+                                          valid=active[:, None])
                 ks.append(wk_l)
                 vs.append(wv_l)
-            return h, (jnp.stack(ks), jnp.stack(vs))
+                if counted is not None:
+                    tally.append(counted)
+            return h, (jnp.stack(ks), jnp.stack(vs),
+                       sum(tally) if tally else None)
 
-        h, (wk, wv) = lax.scan(
+        h, (wk, wv, counted) = lax.scan(
             period, h, (jnp.arange(L // p, dtype=jnp.int32),
                         wk.reshape(L // p, p, *wk.shape[1:]),
                         wv.reshape(L // p, p, *wv.shape[1:])))
-        h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps,
-                     cfg.norm_unit_offset)
+        h = _norm(cfg, h, params["ln_final"])
         logits = logits_at(params, cfg, h, jnp.zeros(B, jnp.int32))
         return logits, (wk.reshape(bufs[0].shape),
-                        wv.reshape(bufs[1].shape)), None
+                        wv.reshape(bufs[1].shape)), (
+            None if counted is None else jnp.sum(counted, axis=0))
 
     def commit(w, bufs, pos):
         wk, wv = bufs

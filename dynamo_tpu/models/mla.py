@@ -190,81 +190,12 @@ def _moe_layer_params(cfg: ModelConfig, params: Params) -> dict:
     return lp
 
 
-def _deepseek_gate(x32, w_router, bias, cfg: ModelConfig):
-    """DeepSeek router → (weights [B, T, k], expert indices [B, T, k]).
-
-    v2 (HF DeepseekV2MoEGate): softmax scores; optional group limiting by
-    the MAX score per group; top-k; weights scaled (NOT renormalized).
-    v3 (HF DeepseekV3TopkRouter): sigmoid scores; selection by scores +
-    e_score_correction_bias with groups ranked by their top-2 SUM; the
-    applied weights are the ORIGINAL sigmoid scores of the selected
-    experts, optionally renormalized, then scaled."""
-    E = w_router.shape[-1]
-    k = cfg.num_experts_per_tok
-    logits = x32 @ w_router.astype(jnp.float32)
-    if cfg.moe_router == "deepseek_v3":
-        scores = jax.nn.sigmoid(logits)
-        choice = scores + bias.astype(jnp.float32)
-    else:
-        scores = jax.nn.softmax(logits, axis=-1)
-        choice = scores
-    if cfg.n_group > 0 and cfg.topk_group > 0:
-        G = cfg.n_group
-        cg = choice.reshape(*choice.shape[:-1], G, E // G)
-        if cfg.moe_router == "deepseek_v3":
-            g_scores = jnp.sum(lax.top_k(cg, 2)[0], axis=-1)
-        else:
-            g_scores = jnp.max(cg, axis=-1)
-        _, g_idx = lax.top_k(g_scores, cfg.topk_group)
-        g_mask = jnp.sum(jax.nn.one_hot(g_idx, G, dtype=jnp.float32),
-                         axis=-2)
-        choice = jnp.where(g_mask[..., :, None] > 0, cg,
-                           0.0).reshape(choice.shape)
-    _, topi = lax.top_k(choice, k)
-    w = jnp.take_along_axis(scores, topi, axis=-1)
-    # v3 (HF DeepseekV3TopkRouter): optional renorm, then ALWAYS scaled.
-    # v2: transformers' DeepseekV2MoEGate ignores norm_topk_prob (always
-    # scales); configs setting it are rejected at ModelConfig load.
-    if cfg.moe_router == "deepseek_v3" and cfg.norm_topk_prob:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + cfg.moe_renorm_eps)
-    w = w * cfg.routed_scaling_factor
-    return w, topi
-
-
-def _deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
-                      live=None, layer=None, first=None,
-                      gate=None) -> jax.Array:
-    """Routed experts plus the always-on shared experts, on x [B, T, D].
-
-    ``lp`` holds one layer's router, bias and shared-expert leaves. The
-    routed experts run through ``llama.moe_experts``, the execution
-    every gate shares: either that layer's ``[E, ...]`` stacks (the
-    dense einsum over every expert: decode-sized dispatches, where one
-    read of the weights bounds both forms, and expert-parallel meshes)
-    or, with ``layer`` (a traced index into the expert segment), the
-    whole ``[Lm, E, ...]`` parameters read in place by the sorted
-    blocked dispatch, whose work follows the ``live`` (token, expert)
-    pairs (_moe_use_blocked holds the rule; the callers apply it).
-    ``first``: which experts the stacks hold of those the gate scored
-    (``llama.moe_experts``: a chip's share of the layer; None = all).
-    ``gate``: the gate's (weights, indices) where the caller has made
-    them already (models/kimi_linear.py counts the pairs held)."""
-    x32 = x.astype(jnp.float32)
-    if gate is None:
-        with jax.named_scope("moe.router"):
-            gate = _deepseek_gate(x32, lp["w_router"],
-                                  lp.get("router_bias"), cfg)
-    w, topi = gate
-    out = llama.moe_experts(x32, w, topi, lp["w_gate_e"], lp["w_up_e"],
-                            lp["w_down_e"], layer is not None, live=live,
-                            layer=layer, first=first,
-                            width=None if first is None
-                            else cfg.router_width)
-    if cfg.n_shared_experts > 0:
-        with jax.named_scope("moe.shared"):
-            out = out + (jax.nn.silu(x @ lp["w_gate_s"])
-                         * (x @ lp["w_up_s"])) @ lp["w_down_s"]
-    return out.astype(x.dtype)
+# the one sigmoid / softmax DeepSeek gate and the held-experts second half
+# live in llama.py (its by-kind path runs them too, and this module
+# imports that one); the names here are what lfm2.py, kimi_linear.py and
+# the tests call
+_deepseek_gate = llama.deepseek_gate
+_deepseek_moe_mlp = llama.deepseek_moe_mlp
 
 
 # --------------------------------------------------------- latent attention

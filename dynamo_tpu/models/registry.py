@@ -5,11 +5,26 @@ this table, so adding a family (reference: each engine adapter brings its
 own model zoo, lib/llm/src/engines/) is one module with the shared paged
 step-fn contract. The table dispatches by what a configuration HAS
 (KDA heads with or without latent ranks, latent ranks, Mamba-2 heads, a
-list of layer kinds, a state-space width), not by a flag per family.
-Seven modules:
+list of layer kinds, a state-space width, a parallel block), not by a
+flag per family. Eight modules:
 
 - ``llama.py``: one homogeneous stack of attention + MLP-or-MoE layers,
   scanned (Llama / Qwen2 / Qwen3 / Qwen3-MoE / Mixtral / Gemma shapes);
+  and, for a configuration whose layers differ in what they may see
+  (``kv_pool_by_kind``: SmallThinker), the by-kind path: a K/V pool a
+  kind of layer, a period's layers unrolled, whose form follows what the
+  configuration has (a sequential or a PARALLEL block, RMSNorm or
+  LayerNorm, the half-split or the interleaved rotation, the Mixtral
+  gate over all experts or the DeepSeek kind's second half over the
+  experts held). It also holds what several modules run: the expert
+  execution (``moe_experts``), the one DeepSeek gate (``deepseek_gate``)
+  and the held-experts second half (``deepseek_moe_mlp``), which
+  ``mla.py`` keeps under their old names;
+- ``cohere2_moe.py`` (``parallel_block``: ``cohere2_moe``, Command A+):
+  the params' tree (one LayerNorm a layer, no ``ln_mlp``; the router at
+  its published width; the experts held; the shared experts side by
+  side), ``WINDOW_COUNTS`` and ``llama.py``'s by-kind programs under the
+  names the engine calls; nothing of a layer is written in the module;
 - ``mla.py``: DeepSeek-V2/V3 latent attention (a latent and a rope pool);
 - ``jamba.py``: layers of two kinds in a fixed pattern, Mamba-1 mixers
   and attention, dense MLPs; it owns the layout the next module shares
@@ -43,7 +58,18 @@ Seven modules:
   a shared expert;
 - ``lfm2.py``: layers of two kinds by a list (``layer_types``), gated
   short convolutions and attention, two dense MLPs and then routed
-  experts (mla.py's sigmoid gate, llama.py's expert execution).
+  experts (llama.py's sigmoid gate and expert execution).
+
+| the configuration has | module | K/V | state |
+|---|---|---|---|
+| ``kda_n_heads`` and ``kv_lora_rank`` | ``kimi_linear.py`` | latent pools of the attending layers | KDA state + conv tails |
+| ``kda_n_heads`` alone | ``solar_open2.py`` | K/V pages of the attending layers | KDA state + conv tails |
+| ``kv_lora_rank`` | ``mla.py`` | a latent and a rope pool | none |
+| ``mamba_n_heads`` | ``granite.py`` | K/V pages of the attending layers | Mamba-2 state + conv tails |
+| ``layer_types`` (``conv`` / ``full_attention``) | ``lfm2.py`` | K/V pages of the attending layers | conv tails, snapshotted by the page |
+| ``mamba_d_state`` | ``jamba.py`` | K/V pages of the attending layers | Mamba-1 state + conv tails |
+| ``parallel_block`` | ``cohere2_moe.py`` | a pool a kind of layer (``llama.py`` by kind) | none |
+| none of these | ``llama.py`` | one pool, or with ``kv_pool_by_kind`` a pool a kind | none |
 
 **What a module writes.** Four functions the engine calls by name:
 ``init_params(cfg, key)``, ``init_kv_cache(cfg, spec)``,
@@ -88,9 +114,11 @@ published width and top-k, ``llama.moe_experts(first=...)`` computes the
 pairs routed to experts ``[first_expert, first_expert + num_experts)``
 in both execution forms, and the partial sum goes on; nothing stands in
 for the other chips or their exchange (one device: ROADMAP B9).
-``granite.py`` and ``kimi_linear.py`` (through
-``mla._deepseek_moe_mlp(first=...)``; ``solar_open2.py`` runs the same
-``kimi_linear._ff``) pass it.
+``granite.py``, ``kimi_linear.py`` (through
+``llama.deepseek_moe_mlp(first=...)``; ``solar_open2.py`` runs the same
+``kimi_linear._ff``) and ``llama.py``'s by-kind path (``_ff_out``, for
+``cohere2_moe.py``) pass it; all four count the pairs routed and held a
+decode window (``llama.pairs_counted``, ``WINDOW_COUNTS``).
 
 **Which keep state.** ``jamba.py``, ``granite.py``, ``kimi_linear.py``,
 ``solar_open2.py`` and ``lfm2.py`` carry per-sequence **recurrent state** beside the KV
@@ -163,6 +191,10 @@ def get_model_module(cfg: ModelConfig):
         from . import jamba
 
         return jamba
+    if cfg.parallel_block:
+        from . import cohere2_moe
+
+        return cohere2_moe
     from . import llama
 
     return llama

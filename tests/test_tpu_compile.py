@@ -1374,3 +1374,110 @@ def test_smallthinker_programs_write_no_array_of_either_pools_size(
                                      else 1.25 * 2 ** 30)
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 15.75 * 1024 ** 3)
+
+
+# ---- a parallel block, 128 / 8 heads, two pools at 530 pages (cell 12)
+
+
+def _command_a(one_chip):
+    """The configuration as command-a-plus-05-2026.rag-long runs it (4 of
+    32 layers, 16 of 128 experts, every width as published); params,
+    both kinds' pools and the rows' tables into the window layers' pool
+    as shapes on the described chip, at the cell's engine data."""
+    import json
+
+    from dynamo_tpu.models import cohere2_moe
+    from dynamo_tpu.models.config import ModelConfig
+
+    with open(os.path.join(ROOT, "benchmark", "workloads",
+                           "command-a-plus-05-2026.rag-long.json")) as f:
+        e = json.load(f)["engine"]
+    cfg = ModelConfig.from_local_path(os.path.join(
+        ROOT, "benchmark", "configs", "command-a-plus-05-2026"))
+    params = _on(one_chip, jax.eval_shape(
+        lambda: cohere2_moe.init_params(cfg, jax.random.PRNGKey(0))))
+    kv = tuple(_on(one_chip, x) for x in jax.eval_shape(
+        lambda: cohere2_moe.init_kv_cache(
+            cfg, llama.KVCacheSpec(e["num_pages"], 64))))
+    wkv = tuple(_on(one_chip, x) for x in jax.eval_shape(
+        lambda: cohere2_moe.init_window_kv_cache(
+            cfg, llama.KVCacheSpec(e["window_pages"], 64))))
+    assert kv[0].shape == (1, e["num_pages"], 8, 64, 128)
+    assert wkv[0].shape == (3, e["window_pages"], 8, 64, 128)
+    slots = llama.window_table_slots(cfg, 64, e["prefill_chunk"])
+    assert slots == 73      # the window, not the context of 530 pages
+    assert e["page_buckets"][-1] * 64 >= 33920
+    return cohere2_moe, cfg, params, kv, wkv, slots, e
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
+def test_command_a_programs_write_no_array_of_either_pools_size(
+        one_chip, tpu_kernel_path, program):
+    """models/llama.py by kind in its parallel form at the shapes of
+    command-a-plus-05-2026.rag-long (the full layer's pool [1, 7232, ...]
+    and the window layers' [3, 2337, ...], 0.88 GiB and 0.86 GiB each of
+    K and V; a table of 531 pages a row = 33,984 tokens): the fused
+    window (B 32) reads both pools where they lie and commits each
+    kind's K/V by whole pages in place and returns its pair counts;
+    decode_step writes its one token a row the same way; a prefill chunk
+    (PB 8 x T 512, 128 query heads) carries the pools through its scan
+    and scatters whole pages. No copy of either pool's size exists, all
+    four pools alias their inputs, and arguments + temporaries fit the
+    chip."""
+    model, cfg, params, (kv_k, kv_v), wkv, slots, e = _command_a(one_chip)
+    s = partial(_sds, one_chip)
+    P, B = e["page_buckets"][-1], e["max_batch"]
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    if program == "window":
+        compiled = model.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, wkv,
+            (s((B, slots), jnp.int32), i32), k_steps=e["decode_steps"],
+            logprobs_topn=0).compile()
+    elif program == "decode_step":
+        compiled = model.make_step_fns(cfg)[1].lower(
+            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, wkv,
+            (s((B, slots), jnp.int32), i32, s((B, 1), jnp.int32))).compile()
+    else:
+        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+        compiled = model.make_step_fns(cfg)[0].lower(
+            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+            s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), wkv,
+            (s((PB, slots), jnp.int32), s((PB,), jnp.int32),
+             s((PB, T // 64), jnp.int32))).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    assert _pool_sized_copies(text, wkv[0].size) == []  # the smaller pool
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in (kv_k, kv_v, *wkv))
+    assert mem.temp_size_in_bytes < (0.75 * 2 ** 30 if program != "prefill"
+                                     else 1.5 * 2 ** 30)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 1024 ** 3)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+def test_attention_kernels_lower_at_sixteen_query_heads_a_kv_head(one_chip,
+                                                                 kernel):
+    """128 query heads over 8 KV heads of 128 (a q block of [8, 16, 128]
+    a row): the decode kernel at the cell's 32 rows over a 531-page
+    table with a window's lower bound, the prefill kernel at PB 8 x T
+    512 over the same table, at the sizes their rules by shape give."""
+    s = partial(_sds, one_chip)
+    B, P, KV, G = 32, 531, 8, 16
+    if kernel == "decode":
+        k = s((3, 2337, KV, 64, 128), jnp.bfloat16)
+        lengths = s((B,), jnp.int32)
+        lowered = pa.paged_attention_decode_layered.lower(
+            s((B, KV * G, 128), jnp.bfloat16), k, k, s((), jnp.int32),
+            s((B, P), jnp.int32), lengths, return_stats=True, lower=lengths)
+    else:
+        k = s((7232, KV, 64, 128), jnp.bfloat16)
+        lowered = pa.paged_attention_prefill.lower(
+            s((8, 512, KV * G, 128), jnp.bfloat16), k, k,
+            s((8, P), jnp.int32), s((8, 512), jnp.int32),
+            eff_win=s((8,), jnp.int32))
+    assert _has_kernel(lowered.compile())

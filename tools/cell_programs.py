@@ -21,8 +21,8 @@ aliased bytes. GB = 2**30 bytes. The last line is what ``about.json``'s
 = resident + the largest temporaries of a serving program.
 
 ``--digest``: sha256[:16] of each serving program's lowered StableHLO
-(with the scope names, without source lines), one line a program and
-one for the table: what a PR that must leave a cell's programs as they
+(with the scope names, without source lines; ``--ops-only``: without
+the scope names too), one line a program and one for the table: what a PR that must leave a cell's programs as they
 were compares between its parent's checkout and its own (``--code
 <checkout>`` imports ``dynamo_tpu`` and ``benchmark`` from there;
 ``--root`` says where BENCHMARK.json and the cell's files are read).
@@ -56,6 +56,10 @@ def main() -> int:
                     help="the checkout whose dynamo_tpu/ is lowered")
     ap.add_argument("--digest", action="store_true",
                     help="digests of the lowered programs; no compile")
+    ap.add_argument("--ops-only", action="store_true",
+                    help="with --digest: of the operations alone, without "
+                    "the scope names (what a PR that adds a scope to a "
+                    "shared path compares)")
     a = ap.parse_args()
     sys.path.insert(0, os.path.abspath(a.code))
 
@@ -146,7 +150,7 @@ def main() -> int:
 
     def record(name, lowered):
         if a.digest:
-            text = lowered.as_text(debug_info=True)
+            text = lowered.as_text(debug_info=not a.ops_only)
             row = {"program": name,
                    "sha256_16": hashlib.sha256(text.encode()).hexdigest()[:16]}
             digests.append(row["sha256_16"])
