@@ -258,15 +258,31 @@ def apply_rope(x: jax.Array, positions: jax.Array,
                inv_freq: jax.Array, interleave: bool = False) -> jax.Array:
     """x: [..., T, heads, head_dim]; positions: [..., T]. Pair i is the
     columns (i, i + head_dim / 2) (HF ``rotate_half``), or with
-    ``interleave`` the columns (2i, 2i + 1) as they lie (GPT-J's)."""
-    angles = positions[..., None].astype(jnp.float32) * inv_freq  # [...,T,hd/2]
+    ``interleave`` the columns (2i, 2i + 1) as they lie (GPT-J's).
+
+    The interleaved arm works on whole heads: out = x cos2 + partner(x)
+    sin2, where cos2 / sin2 hold each pair's angle twice, sin2 carries the
+    sign (- on the even columns) and partner swaps the columns of a pair:
+    the float32 products of (x1 cos - x2 sin, x2 cos + x1 sin). As arrays
+    [..., hd / 2, 2] the pairs fill 2 of a TPU's 128 lanes; the swap is a
+    product with a constant permutation, which moves each value once and
+    adds zeros to it."""
+    pos = positions[..., None].astype(jnp.float32)
+    if interleave:
+        hd = x.shape[-1]
+        angles = (pos * jnp.repeat(inv_freq, 2))[..., None, :]  # [...,T,1,hd]
+        sign = jnp.where(jnp.arange(hd) % 2 == 0, -1.0, 1.0)
+        swap = jnp.eye(hd, dtype=x.dtype)[jnp.arange(hd) ^ 1]
+        partner = jnp.einsum(
+            "...d,de->...e", x, swap, preferred_element_type=jnp.float32,
+            precision=(lax.Precision.HIGHEST if x.dtype == jnp.float32
+                       else None))
+        out = (x.astype(jnp.float32) * jnp.cos(angles)
+               + partner * (jnp.sin(angles) * sign))
+        return out.astype(x.dtype)
+    angles = pos * inv_freq  # [..., T, hd/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., T, 1, hd/2]
     sin = jnp.sin(angles)[..., None, :]
-    if interleave:
-        xp = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
-        x1, x2 = xp[..., 0], xp[..., 1]
-        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -1339,6 +1355,11 @@ def _qkv(cfg: ModelConfig, lp, x: jax.Array, pos: jax.Array, inv_freq,
         xq, xk, xv = x @ lp["wq"], x @ lp["wk"], x @ lp["wv"]
         if cfg.attn_bias:
             xq, xk, xv = xq + lp["bq"], xk + lp["bk"], xv + lp["bv"]
+        # the layout the heads' consumers want is paid on the activation:
+        # without the fence the compiler carries it (and the interleaved
+        # rotation's de-interleave) back through the product onto the
+        # layer's weights, and relays them once a program at its entry
+        xq, xk, xv = lax.optimization_barrier((xq, xk, xv))
     q, k = _qk_headnorm(xq.reshape(B, T, H, hd), xk.reshape(B, T, KV, hd),
                         lp, cfg)
     if rotate:

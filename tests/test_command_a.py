@@ -289,6 +289,32 @@ def test_the_interleaved_rotation_is_the_half_split_one_on_moved_columns():
     np.testing.assert_allclose(got[0, 0], x[0, 0], atol=1e-6)  # position 0
 
 
+@pytest.mark.parametrize("heads", [128, 8])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_the_pairs_rotate_on_whole_heads_as_the_reference_rotates_them(
+        dtype, heads):
+    """``apply_rope``'s interleaved arm is written on whole heads of 128
+    lanes (x cos2 + partner(x) sin2, the swap a product with a constant
+    permutation): at the cell's head counts and positions up to the end
+    of its longest context it is the reference's rotation of the pairs
+    (2i, 2i + 1), the same float32 products, and far from the
+    half-split control."""
+    cfg = ModelConfig.from_local_path(CONFIG_DIR)
+    pos = jnp.asarray([0, 1, 63, 4095, 4096, 20000, 33918, 33919])
+    x = jax.random.normal(jax.random.PRNGKey(heads), (len(pos), heads, 128)
+                          ).astype(dtype)
+    got = llama.apply_rope(x[None], pos[None], llama.rope_freqs(cfg),
+                           True)[0]
+    assert got.dtype == dtype
+    want = REF._rope(x, pos, cfg.rope_theta)
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               want.astype(dtype).astype(jnp.float32),
+                               atol=1e-6)
+    half = REF._rope(x, pos, cfg.rope_theta, half_split=True)
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32) - half))) > 0.1
+
+
 def test_layer_norm_subtracts_the_mean_and_has_no_bias():
     x = jax.random.normal(jax.random.PRNGKey(2), (3, 64)) + 2.0
     w = jax.random.normal(jax.random.PRNGKey(3), (64,))

@@ -323,13 +323,19 @@ def _tails_stay_layer_major(text: str, tails, B: int, program: str):
         assert "bf16[%d,%d,%d]" % (M, B, W) in text
 
 
+def _computations(text: str):
+    """The optimized program's computations (the entry, a loop's body, a
+    fusion's body, ...) by name, each as its text."""
+    return {m.group(1): body for body in
+            re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+            if (m := re.match(r"(?:ENTRY )?%([\w.\-]+) \(", body))}
+
+
 def _computations_with(text: str, shape: str, op: str):
     """The optimized program's computations (a fusion's body is one)
     that hold an `op` whose result is of `shape`, each as its text."""
-    import re
-
     found = re.compile(r" = %s\S* %s\(" % (re.escape(shape), op))
-    return [body for body in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text)
+    return [body for body in _computations(text).values()
             if found.search(body)]
 
 
@@ -1298,6 +1304,37 @@ def test_kernel_cache_key_does_not_hold_the_checkout_path(one_chip):
 # ---- two kinds of layer, a pool each, at SmallThinker's widths (cell 9)
 
 
+def _compile_by_kind(one_chip, model, cfg, params, kv, wkv, slots, e, program,
+                     PB=None):
+    """One of the three serving programs of a configuration with a pool a
+    kind of layer (``model`` = the module whose step functions the cell's
+    engine takes), compiled at the cell's engine data ``e``: the fused
+    window and decode_step at ``max_batch`` rows, a prefill chunk at
+    ``PB`` rows (the cell's largest prefill batch unless given)."""
+    s = partial(_sds, one_chip)
+    kv_k, kv_v = kv
+    P, B = e["page_buckets"][-1], e["max_batch"]
+    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+    if program == "window":
+        return model.make_decode_window_fn(cfg, True, 64).lower(
+            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+            s((B, 8), jnp.int32), None, wkv,
+            (s((B, slots), jnp.int32), i32),
+            k_steps=e.get("decode_steps", 4), logprobs_topn=0).compile()
+    if program == "decode_step":
+        return model.make_step_fns(cfg)[1].lower(
+            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, wkv,
+            (s((B, slots), jnp.int32), i32, s((B, 1), jnp.int32))).compile()
+    PB, T = PB or e["max_prefill_batch"], e["prefill_chunk"]
+    return model.make_step_fns(cfg)[0].lower(
+        params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+        kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+        s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), wkv,
+        (s((PB, slots), jnp.int32), s((PB,), jnp.int32),
+         s((PB, T // 64), jnp.int32))).compile()
+
+
 def _smallthinker(one_chip):
     """The configuration as smallthinker-21b-a3b.mixed-length runs it (8
     of 52 layers, every width as published); params, both kinds' pools
@@ -1342,28 +1379,8 @@ def test_smallthinker_programs_write_no_array_of_either_pools_size(
     ys, llama.forward's form until PR 49, the pools were 6.27 GiB of
     temporaries: scratch compile, PR 46)."""
     cfg, params, (kv_k, kv_v), wkv, slots, e = _smallthinker(one_chip)
-    s = partial(_sds, one_chip)
-    P, B = e["page_buckets"][-1], e["max_batch"]
-    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
-    if program == "window":
-        compiled = llama.make_decode_window_fn(cfg, True, 64).lower(
-            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
-            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
-            s((B, 8), jnp.int32), None, wkv,
-            (s((B, slots), jnp.int32), i32), k_steps=4,
-            logprobs_topn=0).compile()
-    elif program == "decode_step":
-        compiled = llama.make_step_fns(cfg)[1].lower(
-            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, wkv,
-            (s((B, slots), jnp.int32), i32, s((B, 1), jnp.int32))).compile()
-    else:
-        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
-        compiled = llama.make_step_fns(cfg)[0].lower(
-            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
-            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
-            s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), wkv,
-            (s((PB, slots), jnp.int32), s((PB,), jnp.int32),
-             s((PB, T // 64), jnp.int32))).compile()
+    compiled = _compile_by_kind(one_chip, llama, cfg, params, (kv_k, kv_v),
+                                wkv, slots, e, program)
     text = compiled.as_text()
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, kv_k.size) == []    # the smaller pool
@@ -1425,28 +1442,8 @@ def test_command_a_programs_write_no_array_of_either_pools_size(
     four pools alias their inputs, and arguments + temporaries fit the
     chip."""
     model, cfg, params, (kv_k, kv_v), wkv, slots, e = _command_a(one_chip)
-    s = partial(_sds, one_chip)
-    P, B = e["page_buckets"][-1], e["max_batch"]
-    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
-    if program == "window":
-        compiled = model.make_decode_window_fn(cfg, True, 64).lower(
-            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
-            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
-            s((B, 8), jnp.int32), None, wkv,
-            (s((B, slots), jnp.int32), i32), k_steps=e["decode_steps"],
-            logprobs_topn=0).compile()
-    elif program == "decode_step":
-        compiled = model.make_step_fns(cfg)[1].lower(
-            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, wkv,
-            (s((B, slots), jnp.int32), i32, s((B, 1), jnp.int32))).compile()
-    else:
-        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
-        compiled = model.make_step_fns(cfg)[0].lower(
-            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
-            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
-            s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), wkv,
-            (s((PB, slots), jnp.int32), s((PB,), jnp.int32),
-             s((PB, T // 64), jnp.int32))).compile()
+    compiled = _compile_by_kind(one_chip, model, cfg, params, (kv_k, kv_v),
+                                wkv, slots, e, program)
     text = compiled.as_text()
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, wkv[0].size) == []  # the smaller pool
@@ -1481,3 +1478,158 @@ def test_attention_kernels_lower_at_sixteen_query_heads_a_kv_head(one_chip,
             s((8, P), jnp.int32), s((8, 512), jnp.int32),
             eff_win=s((8,), jnp.int32))
     assert _has_kernel(lowered.compile())
+
+
+# ------- the q / k / v products leave the weights where they lie (PR 57)
+
+
+_RELAYS = {"parameter", "constant", "slice", "dynamic-slice", "bitcast",
+           "copy", "transpose", "reshape"}
+
+
+def _weight_sized_relayouts(text: str, least: int, pools=()):
+    """(name, shape, op) of the optimized program's instructions that RUN
+    (in the entry computation, a loop's body or a branch: not a fusion's
+    own producers) and only MOVE at least ``least`` elements: a `copy`
+    that changes the layout (one that changes the memory space alone is
+    the compiler's prefetch), a `reshape`, a `transpose`, or a fusion
+    whose body only slices, bitcasts, copies or transposes. Results of a
+    pool's element count (``pools``) are the page commits' and not
+    these."""
+    comps = _computations(text)
+    runs, todo = set(), [next(n for n, body in comps.items()
+                              if body.startswith("ENTRY "))]
+    while todo:
+        name = todo.pop()
+        if name in runs:
+            continue
+        runs.add(name)
+        for line in comps[name].splitlines():
+            if re.search(r" (?:while|call|conditional)\(", line):
+                todo += re.findall(
+                    r"(?:body|condition|to_apply|true_computation|"
+                    r"false_computation)=%([\w.\-]+)", line)
+                for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                        line):
+                    todo += re.findall(r"%([\w.\-]+)", group)
+    shape = r"\w+\[[\d,]*\](?:\{[^}]*\})?"
+    inst = re.compile(rf"\s*(?:ROOT )?%([\w.\-]+) = \(?({shape})"
+                      rf"(?:, ({shape}))?[^=]*? ([\w\-]+)\((?:%([\w.\-]+))?")
+    lines = {name: [(m, line) for line in comps[name].splitlines()
+                    if (m := inst.match(line))] for name in comps}
+    shapes = {m.group(1): m.group(2) for found in lines.values()
+              for m, _ in found}
+
+    def laid(s):        # a shape without its memory space
+        return re.sub(r"S\(\d+\)", "", s)
+
+    out = []
+    for name in runs:
+        for m, line in lines[name]:
+            res, first, second, op, operand = m.groups()
+            if op == "copy-start":      # (the operand's, the result's, ..)
+                src, first = first, second
+            else:
+                src = shapes.get(operand, "")
+            n = 1
+            for d in filter(None, re.search(r"\[([\d,]*)\]",
+                                            first).group(1).split(",")):
+                n *= int(d)
+            if n < least or n in pools:
+                continue
+            if op in ("copy", "copy-start"):
+                moved = laid(src) != laid(first)
+            elif op == "fusion":
+                body = comps[re.search(r"calls=%([\w.\-]+)", line).group(1)]
+                moved = set(re.findall(r" = \S+ ([\w\-]+)\(", body)) <= _RELAYS
+            else:
+                moved = op in ("reshape", "transpose")
+            if moved:
+                out.append((res, first, op))
+    return out
+
+
+def test_a_weight_sized_relayout_is_recognised():
+    text = """HloModule m, is_scheduled=true
+
+%fused_computation.1 (p: bf16[4,64,32]) -> bf16[64,32] {
+  %p = bf16[4,64,32]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %slice.1 = bf16[1,64,32]{1,2,0:T(8,128)(2,1)} slice(%p), slice={[1:2], [0:64], [0:32]}
+  ROOT %bitcast.1 = bf16[64,32]{0,1:T(8,128)(2,1)} bitcast(%slice.1)
+}
+
+%fused_computation.2 (p.1: bf16[4,64,32], x: bf16[8,64]) -> bf16[8,32] {
+  %p.1 = bf16[4,64,32]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %x = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(1)
+  %fusion.9 = bf16[64,32]{1,0:T(8,128)(2,1)} fusion(%p.1), kind=kLoop, calls=%fused_computation.1
+  ROOT %convolution.1 = bf16[8,32]{1,0:T(8,128)(2,1)} convolution(%x, %fusion.9), dim_labels=bf_io->bf
+}
+
+%body.1 (t: (bf16[4,64,32], bf16[8,64])) -> (bf16[4,64,32], bf16[8,64]) {
+  %t = (bf16[4,64,32]{2,1,0:T(8,128)(2,1)}, bf16[8,64]{1,0:T(8,128)(2,1)}) parameter(0)
+  %w = bf16[4,64,32]{2,1,0:T(8,128)(2,1)} get-tuple-element(%t), index=0
+  %reshape.7 = bf16[16,2,64]{2,1,0:T(2,128)(2,1)} reshape(%copy.3)
+  ROOT %tuple.1 = (bf16[4,64,32]{2,1,0:T(8,128)(2,1)}, bf16[8,64]{1,0:T(8,128)(2,1)}) tuple(%w, %x.1)
+}
+
+%cond.1 (t.1: (bf16[4,64,32], bf16[8,64])) -> pred[] {
+  %t.1 = (bf16[4,64,32]{2,1,0:T(8,128)(2,1)}, bf16[8,64]{1,0:T(8,128)(2,1)}) parameter(0)
+  ROOT %constant.1 = pred[] constant(false)
+}
+
+ENTRY %main.1 (w.1: bf16[4,64,32], x.1: bf16[8,64]) -> bf16[8,32] {
+  %w.1 = bf16[4,64,32]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %x.1 = bf16[8,64]{1,0:T(8,128)(2,1)} parameter(1)
+  %fusion.2 = bf16[64,32]{0,1:T(8,128)(2,1)} fusion(%w.1), kind=kLoop, calls=%fused_computation.1
+  %copy.3 = bf16[64,32]{1,0:T(8,128)(2,1)} copy(%fusion.2)
+  %copy-start.4 = (bf16[64,32]{1,0:T(8,128)(2,1)}, bf16[64,32]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) copy-start(%copy.3)
+  %copy-done.4 = bf16[64,32]{1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.4)
+  %copy.5 = bf16[64,32]{1,0:T(8,128)(2,1)S(1)} copy(%copy.3)
+  %fusion.6 = bf16[8,32]{1,0:T(8,128)(2,1)} fusion(%w.1, %x.1), kind=kOutput, calls=%fused_computation.2
+  %while.1 = (bf16[4,64,32]{2,1,0:T(8,128)(2,1)}, bf16[8,64]{1,0:T(8,128)(2,1)}) while(%tuple.0), condition=%cond.1, body=%body.1
+  ROOT %copy.8 = bf16[8,32]{0,1:T(8,128)(2,1)} copy(%fusion.6)
+}
+"""
+    found = _weight_sized_relayouts(text, 64 * 32)
+    # the slice seen transposed, its transposition, the reshape in the
+    # loop; not the prefetches (copy-start.4, copy.5: the memory space
+    # alone), not the slice inside the product's own fusion (fusion.9),
+    # not the product (fusion.6), not the small copy (copy.8)
+    assert sorted(n for n, _, _ in found) == ["copy.3", "fusion.2",
+                                              "reshape.7"]
+    assert _weight_sized_relayouts(text, 64 * 32, pools=(64 * 32,)) == []
+
+
+@pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
+@pytest.mark.parametrize("cell", ["command_a", "smallthinker"])
+def test_by_kind_programs_relay_no_weight(one_chip, tpu_kernel_path, cell,
+                                          program):
+    """models/llama.py by kind, cells 12 and 9: the q / k / v products
+    hand their results over behind a fence (``llama._qkv``), so the
+    layout the heads' consumers want is paid on q. Without it the
+    compiler asks the product for q head-major, turns it into a
+    convolution over the head axis, relays the layer's WEIGHT for it
+    (cell 12: `slice_bitcast_fusion` + `copy bf16[16384,4096]` and, where
+    a layer rotates interleaved pairs, `reshape bf16[128,64,2,4096]`;
+    cell 9: `copy bf16[8,2560,3584]` and two of `bf16[8,2560,512]`) and,
+    the stacks being program parameters, does so once an execution at
+    the program's entry: 4.0 ms of cell 12's 70.5 ms window, 4.8 ms of
+    its 30.2 ms prefill chunk (ledger, PR 56). A prefill chunk is
+    compiled at ONE row, the smallest program of the warm grid: its
+    activations are far under a layer's wq, so whatever moves that many
+    elements and is no pool is a weight."""
+    if cell == "command_a":
+        model, cfg, params, kv, wkv, slots, e = _command_a(one_chip)
+    else:
+        model = llama
+        cfg, params, kv, wkv, slots, e = _smallthinker(one_chip)
+    compiled = _compile_by_kind(one_chip, model, cfg, params, kv, wkv, slots,
+                                e, program, PB=1)
+    assert _has_kernel(compiled)
+    assert _weight_sized_relayouts(
+        compiled.as_text(), params["wq"].size // cfg.num_layers,
+        pools=(kv[0].size, wkv[0].size)) == []
+    if cell == "command_a" and program == "window":
+        # 617.6 MiB with the three relaid wq among them, 19.8 behind the
+        # fence (scratch compile, PR 57)
+        assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
