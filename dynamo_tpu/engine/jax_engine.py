@@ -46,15 +46,13 @@ from ..llm.protocols.common import (FINISH_CANCELLED, FINISH_EOS,
                                     EngineOutput, PreprocessedRequest)
 from ..models.config import ModelConfig
 from ..models.llama import DROP_SLOT, KVCacheSpec, moe_kernel_takes
-from ..models.registry import get_model_module
+from ..models.registry import family_of
 from ..models.window import WindowResults, unpack as unpack_window
 from ..runtime import blackbox, guard, profiling, slo, tracing
 from ..runtime.config import env_bool, env_int, env_str
 from ..runtime.engine import Context
 from .jit_fence import CompileFence
-from .kv_manager import (BLOCK_GENERATION_REFUSAL, RECURRENT_STATE_REFUSAL,
-                         WINDOW_POOL_REFUSAL, WindowPagePool,
-                         ChainHashCache, PageManager)
+from .kv_manager import ChainHashCache, PageManager, WindowPagePool
 from .profiler import EngineProfiler, memory_snapshot
 from .sampling import (SamplingBatch, logprob_aux, sample_tokens,
                        verify_greedy_draft)
@@ -511,20 +509,33 @@ class JaxEngine:
         self.mesh_shape = (",".join(f"{k}={v}" for k, v in
                                     self.mesh_axes.items())
                            or "single")
-        model = get_model_module(model_cfg)
+        # the family's record (models/registry.py FAMILIES): its module
+        # and what that declares; asked once, here
+        self.family = fam = family_of(model_cfg)
+        model = fam.module
         self.mesh = mesh
         # tokens a decode forward yields a row: 1, or the block of a
         # model that generates by diffusion over blocks (a property of
         # the configuration, like the module; no EngineConfig field)
-        self.block = model_cfg.block_length
+        self.block = model_cfg.block_length if fam.by_blocks else 1
         if self.block > 1:
-            _refuse_block_generation(model_cfg, self.ecfg, mesh)
+            _check_block_sizes(model_cfg, self.ecfg)
+        for feature, on in (
+                ("host_pages", self.ecfg.host_pages > 0),
+                ("spec_decode", self.ecfg.spec_decode),
+                ("long_prefill_threshold",
+                 self.ecfg.long_prefill_threshold is not None),
+                ("mesh", mesh is not None and mesh.size > 1)):
+            if on:
+                fam.refuse(feature)
+        # the one refusal made by the request (generate): None where
+        # the family serves penalties
+        self._penalty_refusal = fam.refusal("sampling_penalty")
         self.diffusion = dict.fromkeys(_BLOCK_WINDOW_COUNTS + ("tokens",), 0)
-        # what a model module's decode window counts by itself and
-        # returns before the state (its WINDOW_COUNTS names them: routed
-        # and held expert pairs, models/granite.py); summed into stats()
-        self.window_counts = dict.fromkeys(
-            getattr(model, "WINDOW_COUNTS", ()), 0)
+        # what the family's decode window counts by itself and returns
+        # before the state (routed and held expert pairs,
+        # models/granite.py); summed into stats()
+        self.window_counts = dict.fromkeys(fam.window_counts, 0)
         # a one-device mesh names the replica's OWN device (dynashard's
         # one-chip replicas): params and pools are built and committed
         # there, and the step thread uploads its inputs there — without
@@ -550,14 +561,14 @@ class JaxEngine:
             spec = KVCacheSpec(self.ecfg.num_pages, self.ecfg.page_size)
             self.kv_k, self.kv_v = model.init_kv_cache(model_cfg, spec,
                                                        dtype)
-            # recurrent state beside the paged KV, for a model module that
+            # recurrent state beside the paged KV, for a family that
             # declares it (init_state): one pool the step thread donates
             # through every program as it does the KV pools, max_batch
             # slots + one drop slot that padding rows read and write.
             # None for every other module: their programs take no operand
             # for it and their call forms are unchanged.
             self.state = None
-            # True where the module also keeps the state at each page's
+            # True where the family also keeps the state at each page's
             # end under the page's id (init_state_snapshots): the pool is
             # the last member of self.state, a prefix hit hands over
             # pages AND state, and the prefix cache stays on
@@ -569,14 +580,13 @@ class JaxEngine:
             # ratio of two deltas, and idle time around it counts nothing
             self.state_slots_held_total = 0
             self.state_slots_seen_total = 0
-            if hasattr(model, "init_state"):
-                _refuse_recurrent_state(self.ecfg, mesh)
-                self.state = model.init_state(
+            if fam.init_state is not None:
+                self.state = fam.init_state(
                     model_cfg, self.ecfg.max_batch + 1, dtype)
                 self._state_free = list(range(self.ecfg.max_batch))[::-1]
-                if hasattr(model, "init_state_snapshots"):
+                if fam.init_state_snapshots is not None:
                     self._state_snapshots = True
-                    self.state = (*self.state, model.init_state_snapshots(
+                    self.state = (*self.state, fam.init_state_snapshots(
                         model_cfg, spec, dtype))
             # the window layers' pools and their books, for a model whose
             # kinds of layer keep a pool each: (K, V) [L_win, pages_w,
@@ -584,8 +594,7 @@ class JaxEngine:
             # pools and returned last, as a state pool is. None otherwise.
             self.wkv = None
             self.wpm: Optional[WindowPagePool] = None
-            if model_cfg.kv_pool_by_kind:
-                _refuse_window_pools(self.ecfg, mesh)
+            if fam.pool_by_kind:
                 slots = model.window_table_slots(
                     model_cfg, self.ecfg.page_size,
                     max(self.ecfg.prefill_chunk,
@@ -631,8 +640,8 @@ class JaxEngine:
         # verify fn (MLA's latent cache) silently keep the standard path.
         self.verify_fn = None
         if self.ecfg.spec_decode:
-            if hasattr(model, "make_verify_fn"):
-                self.verify_fn = model.make_verify_fn(model_cfg, mesh=mesh)
+            if fam.make_verify_fn is not None:
+                self.verify_fn = fam.make_verify_fn(model_cfg, mesh=mesh)
             else:
                 log.warning("spec_decode enabled but %s has no "
                             "make_verify_fn; speculation disabled",
@@ -881,11 +890,11 @@ class JaxEngine:
         recurrent state: the pools and the rows' slots, and for a prefill
         of a module that snapshots by the page (``src`` given) the page
         whose snapshot each row starts from (-1: none). None otherwise."""
-        if self.wkv is not None:
+        if self.family.pool_by_kind:
             # the window layers' pools and the rows' tables into them
             # (_window_tables): the same two places
             return (self.wkv, slots)
-        if self.state is None:
+        if self.family.init_state is None:
             return ()
         if src is None or not self._state_snapshots:
             return (self.state, slots)
@@ -896,14 +905,13 @@ class JaxEngine:
         return np.full(n, -1, np.int32)
 
     def _take_state(self, out):
-        """A step program's results without the state pool it returned
-        last (kept as the engine's), for a model with recurrent state."""
-        if self.wkv is not None:
+        """A step program's results without the pool it returned last
+        (kept as the engine's): the window layers' pools or the state,
+        by what the family's record declares."""
+        if self.family.pool_by_kind:
             *out, self.wkv = out
-            return out
-        if self.state is None:
-            return out
-        *out, self.state = out
+        elif self.family.init_state is not None:
+            *out, self.state = out
         return out
 
     def _drop_slots(self, n: int, T: Optional[int] = None,
@@ -912,9 +920,9 @@ class JaxEngine:
         (None where the model keeps no state); for a model with window
         pools, the tables of n padding rows (``T``, ``paged``:
         _window_tables)."""
-        if self.wkv is not None:
+        if self.family.pool_by_kind:
             return self._window_tables([], n, T, paged)
-        return (None if self.state is None
+        return (None if self.family.init_state is None
                 else np.full(n, self.ecfg.max_batch, np.int32))
 
     def _window_tables(self, rows, B: int, T: Optional[int] = None,
@@ -969,13 +977,14 @@ class JaxEngine:
         engine's again. ``counts``: the block window's per-row counts, or
         the vector a module's WINDOW_COUNTS names; None for a window
         that counts nothing."""
+        fam = self.family
         res = unpack_window(
-            out, topn, counts=self.block > 1 or bool(self.window_counts),
-            state=self.state is not None or self.wkv is not None)
+            out, topn, counts=fam.by_blocks or bool(fam.window_counts),
+            state=fam.pool_by_kind or fam.init_state is not None)
         self.kv_k, self.kv_v = res.kv_k, res.kv_v
-        if self.wkv is not None:
+        if fam.pool_by_kind:
             self.wkv = res.state
-        elif res.state is not None:
+        elif fam.init_state is not None:
             self.state = res.state
         return res._replace(kv_k=None, kv_v=None, state=None)
 
@@ -1468,15 +1477,11 @@ class JaxEngine:
                        num_prompt=len(request.token_ids),
                        trace_ctx=tracing.get_tracer().current_trace_ctx(),
                        block=self.block)
-        if self.block > 1 and (
+        if self._penalty_refusal and (
                 _wants_count_state(request.sampling)
                 or getattr(request.sampling, "logit_bias", None)):
-            yield EngineOutput(
-                finish_reason="error", text=BLOCK_GENERATION_REFUSAL.format(
-                    what="a sampling penalty or logit_bias",
-                    why="the counts of a row's tokens change inside a "
-                    "block, between the forwards that make its positions "
-                    "final, and the window keeps no such state"))
+            yield EngineOutput(finish_reason="error",
+                               text=self._penalty_refusal)
             return
         if context.t_received is not None:
             # the frontend's first leg: its handler's entry to the stamp
@@ -3631,66 +3636,9 @@ class RemoteReservation:
         return self.cached_tokens // self.page_size
 
 
-def _make_decode_multi(*args, **kwargs):
-    # the generic full-forward window, gone since every module of
-    # models/registry.py supplies make_decode_window_fn (models/window.py);
-    # the name stays for benchmark/rehearse.py:52, which imports it beside
-    # EngineConfig and calls it in an else no module reaches (ROADMAP B1
-    # removes that import; C3)
-    raise NotImplementedError(
-        "every model module supplies make_decode_window_fn")
-
-
-def _refuse_recurrent_state(ecfg: EngineConfig, mesh) -> None:
-    """What JaxEngine itself refuses a model with recurrent state, at
-    construction."""
-    if ecfg.host_pages > 0:
-        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
-            what="the host KV tier (host_pages > 0)",
-            why="a page restored from the host comes without the state "
-            "that goes with it"))
-    if ecfg.spec_decode:
-        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
-            what="spec_decode",
-            why="a rejected draft token has already advanced the state, "
-            "which cannot be rolled back"))
-    if mesh is not None and mesh.size > 1:
-        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
-            what="a mesh of more than one device",
-            why="no sharding rule places the state pools or the leaves "
-            "of the layers that keep state"))
-
-
-def _refuse_window_pools(ecfg: EngineConfig, mesh) -> None:
-    """What JaxEngine itself refuses a model whose window layers keep a
-    pool of their own, at construction. (A prefix hit cannot happen: the
-    page manager of such an engine publishes and matches nothing.)"""
-    if ecfg.host_pages > 0:
-        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
-            what="the host KV tier (host_pages > 0, with or without "
-            "kv_compress)",
-            why="a page restored from the host is a page of the full "
-            "layers' pool alone"))
-    if ecfg.spec_decode:
-        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
-            what="spec_decode",
-            why="the verify forward writes drafted tokens' K/V through "
-            "one page table"))
-    if ecfg.long_prefill_threshold is not None:
-        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
-            what="long_prefill_threshold (ring-attention prefill)",
-            why="the ring scatters a prompt's K/V into one pool"))
-    if mesh is not None and mesh.size > 1:
-        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
-            what="a mesh of more than one device",
-            why="no sharding rule places the window layers' pools, and "
-            "the shard_map wrappers take one table a row"))
-
-
-def _refuse_block_generation(cfg: ModelConfig, ecfg: EngineConfig,
-                             mesh) -> None:
-    """What JaxEngine itself refuses a model that generates by diffusion
-    over blocks (cfg.block_length > 1), at construction."""
+def _check_block_sizes(cfg: ModelConfig, ecfg: EngineConfig) -> None:
+    """The sizes a model that generates by diffusion over blocks
+    (cfg.block_length > 1) needs of the engine's configuration."""
     cfg.check_page_size(ecfg.page_size)
     if ecfg.decode_steps < cfg.block_length \
             or ecfg.decode_steps % cfg.block_length:
@@ -3698,26 +3646,6 @@ def _refuse_block_generation(cfg: ModelConfig, ecfg: EngineConfig,
             f"decode_steps ({ecfg.decode_steps}) must be a multiple of "
             f"block_length ({cfg.block_length}): a window generates whole "
             f"blocks, decode_steps / block_length of them a row")
-    if ecfg.host_pages > 0:
-        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
-            what="the host KV tier (host_pages > 0)",
-            why="no test shows a page restored from the host against "
-            "the block mask's invariant (a page holds whole blocks)"))
-    if ecfg.spec_decode:
-        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
-            what="spec_decode",
-            why="the verify forward scores one drafted token a position "
-            "under the causal mask, and a block is not drafted token by "
-            "token"))
-    if ecfg.long_prefill_threshold is not None:
-        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
-            what="long_prefill_threshold (ring-attention prefill)",
-            why="the ring's position predicates are causal"))
-    if mesh is not None and mesh.size > 1:
-        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
-            what="a mesh of more than one device",
-            why="the block window folds a block's queries into the decode "
-            "kernel's group axis and has no shard_map form"))
 
 
 def _span_ms(start: Optional[float], end: Optional[float]

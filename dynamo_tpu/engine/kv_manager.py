@@ -44,55 +44,6 @@ HASH_SEED = 1337  # match the reference's block hasher (kv_router/indexer.rs)
 EVICT_POLICIES = ("lru", "cost")
 
 
-RECURRENT_STATE_REFUSAL = (
-    "{what} is not supported for a model with recurrent state "
-    "(models/jamba.py, models/lfm2.py, models/granite.py, "
-    "models/kimi_linear.py, models/solar_open2.py): {why}; a state "
-    "snapshot lives in the device pool under its page's id, or not at "
-    "all, and nothing moves or rolls back a state (ROADMAP B7)")
-
-
-BLOCK_GENERATION_REFUSAL = (
-    "{what} is not supported for a model that generates by diffusion "
-    "over blocks (block_length > 1, models/llama.py "
-    "_make_block_window_fn): {why}; its step yields a block a row, and "
-    "only JaxEngine's window arm on one device keeps the books of that "
-    "(ROADMAP B10)")
-
-
-WINDOW_POOL_REFUSAL = (
-    "{what} is not supported for a model whose window layers keep a K/V "
-    "pool of their own (kv_pool_by_kind: models/llama.py "
-    "_forward_by_kind, WindowPagePool): {why}; the window layers' pages "
-    "behind a row's window are given back while the row runs, and only "
-    "JaxEngine's own steps on one device keep the books of both pools "
-    "(ROADMAP B6)")
-
-
-def refuse_recurrent_state(engine, what: str) -> None:
-    """Raise where ``engine`` serves a model that keeps recurrent state
-    beside its KV pages: the paths that move KV pages between places
-    (host tier, disagg, KV transfer) would leave that state behind. The
-    same paths refuse a model that generates by diffusion over blocks:
-    they hand a sequence over as "pages + the last token", and such a
-    sequence resumes from its whole blocks plus a tail of final
-    tokens."""
-    if getattr(engine, "state", None) is not None:
-        raise NotImplementedError(RECURRENT_STATE_REFUSAL.format(
-            what=what, why="it moves KV pages between places, and a "
-            "sequence's pages without its state are not the sequence"))
-    if getattr(engine, "block", 1) > 1:
-        raise NotImplementedError(BLOCK_GENERATION_REFUSAL.format(
-            what=what, why="it hands a sequence over as its pages and "
-            "the first token that prefill sampled, and here prefill "
-            "samples none and the pages hold whole blocks only"))
-    if getattr(engine, "wkv", None) is not None:
-        raise NotImplementedError(WINDOW_POOL_REFUSAL.format(
-            what=what, why="it moves a sequence as the pages of one pool, "
-            "and the window layers' pages of the same positions are in "
-            "another pool or already given back"))
-
-
 class WindowPagePool:
     """Host-side books of the window layers' K/V pool: a free list of its
     own pages, and what each row holds of them.

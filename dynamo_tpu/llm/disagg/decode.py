@@ -14,7 +14,6 @@ import asyncio
 import logging
 from typing import AsyncIterator, Optional
 
-from ...engine.kv_manager import refuse_recurrent_state
 from ...runtime import guard, tracing
 from ...runtime.config import env_float, env_int
 from ...runtime.engine import Context
@@ -48,7 +47,9 @@ class DisaggDecodeEngine:
                  router: DisaggRouter, engine_id: int,
                  prefill_timeout: Optional[float] = None,
                  max_dispatches: Optional[int] = None):
-        refuse_recurrent_state(engine, "a disaggregated decode engine")
+        # what a JaxEngine's family is refused (models/registry.py REFUSALS)
+        if (family := getattr(engine, "family", None)) is not None:
+            family.refuse("disagg_decode")
         self.engine = engine
         if hasattr(engine, "set_role"):
             # dynaslo: the wrapped engine serves the decode side of the

@@ -23,7 +23,6 @@ from typing import Dict, List, Optional, Set
 
 import numpy as np
 
-from ...engine.kv_manager import refuse_recurrent_state
 from ...runtime import guard, tracing
 from ...runtime.engine import Context
 from ..protocols.common import (PreprocessedRequest, SamplingOptions,
@@ -45,7 +44,9 @@ class PrefillWorker:
         from ...runtime.config import env_bool, env_int
 
         self.drt = drt
-        refuse_recurrent_state(engine, "a disaggregated prefill worker")
+        # what a JaxEngine's family is refused (models/registry.py REFUSALS)
+        if (family := getattr(engine, "family", None)) is not None:
+            family.refuse("disagg_prefill")
         self.engine = engine
         if hasattr(engine, "set_role"):
             # dynaslo: this engine serves prefill-only — its latency

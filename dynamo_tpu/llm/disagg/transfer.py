@@ -40,7 +40,6 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
-from ...engine.kv_manager import refuse_recurrent_state
 from ...runtime import codec, guard, tracing, wire
 from ...runtime.codec import TwoPartMessage
 from ...runtime.config import env_float
@@ -167,7 +166,9 @@ class KvTransferServer:
     (per-chunk late-write guard)."""
 
     def __init__(self, engine):
-        refuse_recurrent_state(engine, "the KV transfer server")
+        # what a JaxEngine's family is refused (models/registry.py REFUSALS)
+        if (family := getattr(engine, "family", None)) is not None:
+            family.refuse("kv_transfer")
         self.engine = engine
         self._server: Optional[asyncio.AbstractServer] = None
         self._waiters: Dict[str, asyncio.Future] = {}

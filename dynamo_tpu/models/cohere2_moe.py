@@ -30,12 +30,118 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .config import ModelConfig
+from .config import ModelConfig, held_experts, refuser
 from .granite import WINDOW_COUNTS  # noqa: F401  (the engine reads it)
 from .kimi_linear import _drawer
 from .llama import (Params, init_kv_cache, init_window_kv_cache,  # noqa: F401
                     make_decode_window_fn, make_step_fns,
                     window_table_slots)
+
+
+def read_config(cfg: dict) -> ModelConfig:
+    """The keys of a ``cohere2_moe`` config.json (Command A+): every
+    layer a parallel block under one bias-free LayerNorm; window layers
+    (``layer_types`` ``sliding_attention``) rotate q and k over
+    interleaved pairs, full layers apply no positional embedding;
+    sigmoid-routed experts of width ``intermediate_size`` beside
+    ``num_shared_experts`` shared ones that are averaged. ``layer_types``
+    is kept whole in a file cut in depth: the first ``num_hidden_layers``
+    entries are the layers that run (``num_experts``:
+    config.held_experts). Nothing of this family is read through
+    config.hf_base: ``sliding_window`` there would take Gemma-2's rule."""
+    refuse = refuser("cohere2_moe")
+    L = cfg["num_hidden_layers"]
+    kinds = list(cfg["layer_types"][:L])
+    odd = sorted(set(kinds) - {"sliding_attention", "full_attention"})
+    if odd or len(kinds) != L:
+        refuse(f"layer_types {odd or len(kinds)}",
+               f"it must name num_hidden_layers = {L} layers, each "
+               f"sliding_attention or full_attention")
+    if len(set(kinds)) != 2:
+        refuse("layers of one kind only",
+               "the K/V pools are one a kind of layer; a model whose "
+               "layers all see the same is another module's")
+    if not cfg.get("use_parallel_block", False):
+        refuse("use_parallel_block false",
+               "attention and the experts read ONE LayerNorm and are "
+               "added once; the family's sequential form has a second "
+               "norm that no leaf of this module holds")
+    if cfg.get("use_qk_norm", False):
+        refuse("use_qk_norm true",
+               "q and k are rotated as projected; the family's q/k "
+               "norm is a LayerNorm a head that is not computed")
+    if cfg.get("first_k_dense_replace", 0):
+        refuse(f"first_k_dense_replace {cfg['first_k_dense_replace']}",
+               "every layer's second half is routed experts beside the "
+               "shared ones; the module builds no dense MLP "
+               "(prefix_dense_intermediate_size is read by no layer)")
+    if cfg.get("rotary_pct", 1) != 1:
+        refuse(f"rotary_pct {cfg['rotary_pct']}",
+               "the window layers rotate all head_dim columns")
+    if cfg.get("position_embedding_type", "rope_gptj") != "rope_gptj":
+        refuse(f"position_embedding_type "
+               f"{cfg['position_embedding_type']!r}",
+               "the window layers rotate interleaved pairs (rope_gptj)")
+    if (cfg.get("rope_parameters") or {}).get("rope_type",
+                                              "default") != "default" \
+            or cfg.get("rope_scaling"):
+        refuse("a rope_type other than default",
+               "the window layers rotate by rope_theta alone")
+    shared = cfg.get("num_shared_experts", 0)
+    strategy = cfg.get("shared_expert_combination_strategy", "average")
+    if shared and strategy != "average":
+        refuse(f"shared_expert_combination_strategy {strategy!r}",
+               "the shared experts' outputs are averaged and the mean "
+               "is added to the routed sum")
+    if cfg.get("expert_selection_fn", "sigmoid") != "sigmoid":
+        refuse(f"expert_selection_fn {cfg['expert_selection_fn']!r}",
+               "the gate scores by a sigmoid")
+    if not cfg.get("norm_topk_prob", True):
+        refuse("norm_topk_prob false",
+               "the chosen sigmoid scores are renormalised")
+    if not cfg.get("use_gated_activation", True) \
+            or cfg.get("hidden_act", "silu") != "silu":
+        refuse(f"hidden_act {cfg.get('hidden_act')!r} / "
+               f"use_gated_activation "
+               f"{cfg.get('use_gated_activation')}",
+               "its experts are SwiGLU")
+    if cfg.get("attention_bias", False):
+        refuse("attention_bias true",
+               "the projections are computed without a bias")
+    held, width, first = held_experts(
+        cfg, "num_experts", "num_experts_per_tok", refuse)
+    window = int(cfg["sliding_window"])
+    sliding = [k == "sliding_attention" for k in kinds]
+    rope = cfg.get("rope_parameters") or {}
+    return ModelConfig(
+        model_type="cohere2_moe",
+        vocab_size=cfg["vocab_size"],
+        hidden_size=cfg["hidden_size"],
+        # the width of ONE expert (the file has no other key for it)
+        intermediate_size=cfg["intermediate_size"],
+        num_layers=L,
+        num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg.get("num_key_value_heads",
+                             cfg["num_attention_heads"]),
+        head_dim=cfg.get("head_dim"),
+        rope_theta=cfg.get("rope_theta", rope.get("rope_theta", 50000.0)),
+        rms_norm_eps=cfg.get("layer_norm_eps", 1e-5),
+        tie_word_embeddings=cfg.get("tie_word_embeddings", True),
+        num_experts=held, router_experts=width, first_expert=first,
+        num_experts_per_tok=cfg["num_experts_per_tok"],
+        # sigmoid scores, the chosen renormalised; no selection bias,
+        # no groups, no scaling factor (the file has no key for any)
+        moe_router="deepseek_v3", norm_topk_prob=True,
+        n_shared_experts=shared,
+        shared_expert_scale=1.0 / shared if shared else 1.0,
+        sliding_window=window,
+        layer_window=tuple(window if s else None for s in sliding),
+        layer_rope=tuple(sliding),
+        kv_pool_by_kind=True, rope_interleave=True,
+        parallel_block=True, layer_norm=True,
+        # logits = logit_scale * (h @ E^T): project_logits divides
+        logits_scaling=1.0 / float(cfg.get("logit_scale", 1.0)),
+    )
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:

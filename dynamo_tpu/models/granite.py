@@ -71,7 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import jamba
-from .config import ModelConfig
+from .config import ModelConfig, held_experts, hf_base, refuser
 from .jamba import (_at, _causal_conv, init_kv_cache,  # noqa: F401
                     num_mamba_layers, segments)
 from .llama import (Params, _moe_use_blocked, held_first,  # noqa: F401
@@ -85,6 +85,58 @@ SHARED_KEYS = ("w_gate_s", "w_up_s", "w_down_s")
 # what a decode window counts, a live row-step a layer: the (token,
 # expert) pairs the router chose, and those whose expert is held here
 WINDOW_COUNTS = ("moe_pairs_routed_total", "moe_pairs_held_total")
+
+
+def read_config(cfg: dict) -> ModelConfig:
+    """The keys of a ``granitemoehybrid`` config.json
+    (``num_local_experts``: config.held_experts)."""
+    refuse = refuser("granitemoehybrid")
+    c = hf_base(cfg)
+    L = cfg["num_hidden_layers"]
+    kinds = tuple(cfg["layer_types"][:L])
+    odd = sorted(set(kinds) - {"mamba", "attention"})
+    if odd or len(kinds) != L:
+        refuse(f"layer_types {odd or len(kinds)}",
+               "it must name num_hidden_layers layers, each mamba or "
+               "attention")
+    if cfg.get("mamba_n_groups", 1) != 1:
+        refuse("mamba_n_groups > 1",
+               "B and C are computed once for all heads, and the "
+               "chunked form's C.B product is one matrix a chunk")
+    if cfg.get("position_embedding_type", "nope") != "nope":
+        refuse(f"position_embedding_type "
+               f"{cfg['position_embedding_type']!r}",
+               "its attending layers apply no positional embedding")
+    if cfg.get("mamba_proj_bias") or cfg.get("attention_bias"):
+        refuse("mamba_proj_bias or attention_bias true",
+               "the mixers' projections are computed without a bias")
+    if not cfg.get("mamba_conv_bias", True):
+        refuse("mamba_conv_bias false",
+               "the causal convolution adds its bias leaf")
+    heads, d_head = cfg["mamba_n_heads"], cfg["mamba_d_head"]
+    expand = cfg.get("mamba_expand", 2)
+    if heads * d_head != expand * cfg["hidden_size"]:
+        refuse(f"mamba_n_heads x mamba_d_head = {heads * d_head}",
+               f"mamba_expand x hidden_size is "
+               f"{expand * cfg['hidden_size']}, the mixer's one inner "
+               f"width")
+    c.num_experts, c.router_experts, c.first_expert = held_experts(
+        cfg, "num_local_experts", "num_experts_per_tok", refuse)
+    c.model_type = "granitemoehybrid"
+    c.layer_types = kinds
+    c.mamba_n_heads, c.mamba_d_head = heads, d_head
+    c.mamba_d_state = cfg["mamba_d_state"]
+    c.mamba_d_conv = cfg.get("mamba_d_conv", 4)
+    c.mamba_expand = expand
+    c.mamba_chunk_size = cfg.get("mamba_chunk_size", 256)
+    c.shared_intermediate_size = cfg.get("shared_intermediate_size", 0)
+    c.embedding_multiplier = float(cfg.get("embedding_multiplier", 1))
+    c.attention_multiplier = cfg.get("attention_multiplier")
+    c.residual_multiplier = float(cfg.get("residual_multiplier", 1))
+    c.logits_scaling = float(cfg.get("logits_scaling", 1))
+    c.num_experts_per_tok = cfg["num_experts_per_tok"]
+    c.tie_word_embeddings = cfg.get("tie_word_embeddings", True)
+    return c
 
 
 def conv_width(cfg: ModelConfig) -> int:

@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .config import ModelConfig
+from .config import ModelConfig, hf_base
 from .llama import (KVCacheSpec, Params, _at, _attention, _mlp,
                     _scatter_pages, _scatter_pages_paged, commit_window,
                     embed_tokens, kernel_mode, logits_at, rms_norm,
@@ -81,6 +81,30 @@ State = Tuple[jax.Array, jax.Array]     # (ssm [S,M,N,di] f32, conv [M,S,(dc-1)*
 MAMBA_KEYS = ("w_in", "conv_w", "b_conv", "w_x", "dt_norm", "ssm_b_norm",
               "ssm_c_norm", "w_dt", "b_dt", "A_log", "d_skip", "w_out")
 SCAN_BLOCK = 32     # tokens per time block of the prefill scan
+
+
+def read_config(cfg: dict) -> ModelConfig:
+    """The keys of a ``jamba`` config.json."""
+    c = hf_base(cfg)
+    if (cfg.get("num_experts") or 1) > 1:
+        # every layer's MLP is dense here; the expert_layer_* keys would
+        # select routed layers
+        raise NotImplementedError(
+            "jamba with num_experts > 1 is not supported (every layer's "
+            "MLP is computed dense)")
+    if cfg.get("sliding_window"):
+        raise NotImplementedError(
+            "jamba with sliding_window set is not supported (its "
+            "attention layers attend to the whole context)")
+    c.model_type = "jamba"
+    c.mamba_d_state = cfg.get("mamba_d_state", 16)
+    c.mamba_d_conv = cfg.get("mamba_d_conv", 4)
+    c.mamba_expand = cfg.get("mamba_expand", 2)
+    c.mamba_dt_rank = cfg.get("mamba_dt_rank") or -(-cfg["hidden_size"] // 16)
+    c.attn_layer_period = cfg.get("attn_layer_period", 8)
+    c.attn_layer_offset = cfg.get("attn_layer_offset", 4)
+    c.rms_norm_eps = cfg.get("rms_norm_eps", 1e-6)
+    return c
 
 
 def segments(cfg: ModelConfig) -> List[tuple]:

@@ -85,7 +85,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import jamba, llama, mla
-from .config import ModelConfig
+from .config import ModelConfig, held_experts, hf_base, refuser
 from .granite import WINDOW_COUNTS, held_first
 from .jamba import _at, _causal_conv, num_mamba_layers
 from .llama import (KVCacheSpec, Params, _mlp, _moe_use_blocked,
@@ -99,6 +99,75 @@ DENSE_KEYS = ("w_gate_d", "w_up_d", "w_down_d")
 EXPERT_KEYS = ("w_gate_e", "w_up_e", "w_down_e")
 MOE_KEYS = ("w_router", "router_bias", "w_gate_s", "w_up_s", "w_down_s")
 _HIGHEST = lax.Precision.HIGHEST
+
+
+def read_config(cfg: dict) -> ModelConfig:
+    """The keys of a ``kimi_linear`` config.json. The two lists of
+    ``linear_attn_config`` count layers from 1 and are kept whole in a
+    file cut in depth: the entries up to ``num_hidden_layers`` are the
+    layers that run (``num_experts``: config.held_experts)."""
+    refuse = refuser("kimi_linear")
+    c = hf_base(cfg)
+    L = cfg["num_hidden_layers"]
+    lin = cfg["linear_attn_config"]
+    kda = [l for l in lin["kda_layers"] if l <= L]
+    full = [l for l in lin["full_attn_layers"] if l <= L]
+    if sorted(kda + full) != list(range(1, L + 1)):
+        refuse(f"kda_layers {kda} and full_attn_layers {full}",
+               f"up to num_hidden_layers they must partition the "
+               f"layers 1..{L}: every layer is of exactly one kind")
+    if not kda or not full:
+        refuse("layers of one kind only",
+               "the state pool holds the KDA layers and the latent "
+               "pools the attending ones; a model of one kind is "
+               "another module's")
+    if not cfg.get("mla_use_nope", False):
+        refuse("mla_use_nope false",
+               "its attending layers apply no rotation to the shared "
+               "key columns, and no cell would run the rotated form")
+    if cfg.get("q_lora_rank"):
+        refuse(f"q_lora_rank {cfg['q_lora_rank']}",
+               "its attending layers project the queries at full "
+               "rank")
+    if (cfg.get("num_expert_group") or 1) != 1 \
+            or (cfg.get("topk_group") or 1) != 1:
+        refuse(f"num_expert_group {cfg.get('num_expert_group')} / "
+               f"topk_group {cfg.get('topk_group')}",
+               "the gate chooses among all the router's outputs: "
+               "groups other than 1 are not computed")
+    if cfg.get("moe_router_activation_func", "sigmoid") != "sigmoid":
+        refuse(f"moe_router_activation_func "
+               f"{cfg['moe_router_activation_func']!r}",
+               "the gate scores by a sigmoid")
+    if cfg.get("moe_layer_freq", 1) != 1:
+        refuse(f"moe_layer_freq {cfg['moe_layer_freq']}",
+               "every layer after the first_k_dense_replace dense "
+               "ones has routed experts")
+    if cfg.get("rope_scaling"):
+        refuse("rope_scaling", "no layer rotates")
+    c.num_experts, c.router_experts, c.first_expert = held_experts(
+        cfg, "num_experts", "num_experts_per_token", refuse)
+    attending = set(full)
+    c.model_type = "kimi_linear"
+    c.layer_types = tuple("attention" if l in attending else "kda"
+                          for l in range(1, L + 1))
+    c.kda_n_heads = lin["num_heads"]
+    c.kda_head_dim = lin["head_dim"]
+    c.mamba_d_conv = lin.get("short_conv_kernel_size", 4)
+    c.mla_nope = True
+    c.q_lora_rank = 0
+    c.kv_lora_rank = cfg["kv_lora_rank"]
+    c.qk_nope_head_dim = cfg["qk_nope_head_dim"]
+    c.qk_rope_head_dim = cfg["qk_rope_head_dim"]
+    c.v_head_dim = cfg["v_head_dim"]
+    c.num_experts_per_tok = cfg["num_experts_per_token"]
+    c.moe_router = "deepseek_v3"
+    c.norm_topk_prob = bool(cfg.get("moe_renormalize", True))
+    c.routed_scaling_factor = cfg.get("routed_scaling_factor", 1.0)
+    c.n_shared_experts = cfg.get("num_shared_experts", 0)
+    c.first_k_dense_replace = cfg.get("first_k_dense_replace", 0)
+    c.moe_intermediate_size = cfg["moe_intermediate_size"]
+    return c
 
 
 def conv_width(cfg: ModelConfig) -> int:

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .config import ModelConfig
+from .config import ModelConfig, hf_base
 from .llama import (KVCacheSpec, Params, _at, _attention, _mlp,
                     _moe_use_blocked, _qk_headnorm, _scatter_pages,
                     _scatter_pages_paged, apply_rope, commit_window,
@@ -78,6 +78,49 @@ CONV_KEYS = ("w_in", "conv_w", "w_out")
 DENSE_KEYS = ("w_gate_d", "w_up_d", "w_down_d")
 EXPERT_KEYS = ("w_gate_e", "w_up_e", "w_down_e")
 _LANES = 128
+
+
+def read_config(cfg: dict) -> ModelConfig:
+    """The keys of an ``lfm2_moe`` config.json. A cut in depth keeps the
+    published ``layer_types`` whole: the first ``num_hidden_layers``
+    entries are the layers that run."""
+    c = hf_base(cfg)
+    kinds = tuple(cfg["layer_types"][:cfg["num_hidden_layers"]])
+    odd = sorted(set(kinds) - {"conv", "full_attention"})
+    if odd or len(kinds) != cfg["num_hidden_layers"]:
+        raise NotImplementedError(
+            "lfm2_moe: layer_types must name num_hidden_layers layers, "
+            f"each conv or full_attention (got {odd or len(kinds)})")
+    if cfg.get("conv_bias"):
+        raise NotImplementedError(
+            "lfm2_moe with conv_bias true is not supported (the short "
+            "convolution is computed without a bias)")
+    if not (cfg.get("use_expert_bias", True)
+            and cfg.get("norm_topk_prob", True)):
+        raise NotImplementedError(
+            "lfm2_moe without use_expert_bias or norm_topk_prob is not "
+            "supported (the gate selects by score + bias and renormalises "
+            "the chosen scores)")
+    rope = cfg.get("rope_parameters") or {}
+    c.model_type = "lfm2_moe"
+    c.layer_types = kinds
+    c.conv_l_cache = cfg.get("conv_L_cache", 3)
+    c.num_dense_layers = cfg.get("num_dense_layers", 0)
+    c.rms_norm_eps = cfg.get("norm_eps", 1e-5)
+    c.rope_theta = rope.get("rope_theta", cfg.get("rope_theta", 1000000.0))
+    c.qk_norm = True
+    c.num_experts = cfg.get("num_experts", 0)
+    c.num_experts_per_tok = cfg.get("num_experts_per_tok", 4)
+    c.moe_intermediate_size = cfg.get("moe_intermediate_size")
+    # sigmoid scores, selection by score + bias, the unbiased scores of
+    # the chosen renormalised: DeepSeek-V3's gate without groups
+    # (models/mla.py _deepseek_gate)
+    c.moe_router = "deepseek_v3"
+    c.norm_topk_prob = True
+    c.moe_renorm_eps = 1e-6
+    c.routed_scaling_factor = cfg.get("routed_scaling_factor", 1.0)
+    c.tie_word_embeddings = cfg.get("tie_word_embeddings", True)
+    return c
 
 
 def segments(cfg: ModelConfig) -> List[tuple]:

@@ -44,13 +44,52 @@ from jax import lax
 
 from ..ops.paged_attention import (NEG_INF, latent_attention_decode_layered,
                                    latent_attention_prefill_layered)
-from .config import ModelConfig
+from .config import ModelConfig, hf_base
 from . import llama
 from .llama import (KVCacheSpec, _at, _mlp, _moe_use_blocked, apply_rope,
                     commit_window, logits_at, rms_norm, rope_freqs)
 from .window import Family, make_window
 
 Params = Dict[str, jax.Array]
+
+
+def read_config(cfg: dict) -> ModelConfig:
+    """The keys of a ``deepseek_v2`` / ``deepseek_v3`` config.json."""
+    mt = cfg["model_type"]
+    c = hf_base(cfg)
+    c.model_type = mt
+    c.q_lora_rank = cfg.get("q_lora_rank") or 0
+    c.kv_lora_rank = cfg.get("kv_lora_rank", 512)
+    c.qk_nope_head_dim = cfg.get("qk_nope_head_dim", 128)
+    c.qk_rope_head_dim = cfg.get("qk_rope_head_dim", 64)
+    c.v_head_dim = cfg.get("v_head_dim", 128)
+    c.num_experts = cfg.get("n_routed_experts") or 0
+    c.num_experts_per_tok = cfg.get("num_experts_per_tok", 2)
+    c.rope_interleave = cfg.get("rope_interleave", True)
+    if c.num_experts > 0:
+        c.moe_router = mt
+        c.n_shared_experts = cfg.get("n_shared_experts") or 0
+        c.first_k_dense_replace = cfg.get("first_k_dense_replace", 0)
+        c.moe_intermediate_size = cfg.get("moe_intermediate_size")
+        c.routed_scaling_factor = cfg.get("routed_scaling_factor", 1.0)
+        c.norm_topk_prob = cfg.get("norm_topk_prob", False)
+        if mt == "deepseek_v2" and c.norm_topk_prob:
+            # The installed transformers DeepseekV2MoEGate ignores this
+            # flag (always scales, never renormalizes) while DeepSeek's
+            # remote-code gate renormalizes instead of scaling: two
+            # conflicting oracles, and no published V2 checkpoint sets
+            # it. Reject loudly rather than silently diverging from
+            # either.
+            raise NotImplementedError(
+                "deepseek_v2 with norm_topk_prob=true is not supported "
+                "(conflicting reference semantics)")
+        if mt == "deepseek_v3" or cfg.get("topk_method",
+                                          "greedy") != "greedy":
+            # v2 "greedy" routes without group limiting; v3 is always
+            # group-limited (noaux_tc)
+            c.n_group = cfg.get("n_group") or 0
+            c.topk_group = cfg.get("topk_group") or 0
+    return c
 
 
 # ---------------------------------------------------------------- KV cache

@@ -1,75 +1,11 @@
-"""Model-family registry: ModelConfig → model module.
-
-The engine resolves init_params / init_kv_cache / make_step_fns through
-this table, so adding a family (reference: each engine adapter brings its
-own model zoo, lib/llm/src/engines/) is one module with the shared paged
-step-fn contract. The table dispatches by what a configuration HAS
-(KDA heads with or without latent ranks, latent ranks, Mamba-2 heads, a
-list of layer kinds, a state-space width, a parallel block), not by a
-flag per family. Eight modules:
-
-- ``llama.py``: one homogeneous stack of attention + MLP-or-MoE layers,
-  scanned (Llama / Qwen2 / Qwen3 / Qwen3-MoE / Mixtral / Gemma shapes);
-  and, for a configuration whose layers differ in what they may see
-  (``kv_pool_by_kind``: SmallThinker), the by-kind path: a K/V pool a
-  kind of layer, a period's layers unrolled, whose form follows what the
-  configuration has (a sequential or a PARALLEL block, RMSNorm or
-  LayerNorm, the half-split or the interleaved rotation, the Mixtral
-  gate over all experts or the DeepSeek kind's second half over the
-  experts held). It also holds what several modules run: the expert
-  execution (``moe_experts``), the one DeepSeek gate (``deepseek_gate``)
-  and the held-experts second half (``deepseek_moe_mlp``), which
-  ``mla.py`` keeps under their old names;
-- ``cohere2_moe.py`` (``parallel_block``: ``cohere2_moe``, Command A+):
-  the params' tree (one LayerNorm a layer, no ``ln_mlp``; the router at
-  its published width; the experts held; the shared experts side by
-  side), ``WINDOW_COUNTS`` and ``llama.py``'s by-kind programs under the
-  names the engine calls; nothing of a layer is written in the module;
-- ``mla.py``: DeepSeek-V2/V3 latent attention (a latent and a rope pool);
-- ``jamba.py``: layers of two kinds in a fixed pattern, Mamba-1 mixers
-  and attention, dense MLPs; it owns the layout the next module shares
-  (runs of state-space layers scanned between the attending ones, a
-  pool of scan state and one of conv tails, the window's carry) and
-  takes a family's mixer, second half of a layer and step kernel as
-  ``jamba.Blocks``;
-- ``granite.py`` (``mamba_n_heads`` > 0: ``granitemoehybrid``): on that
-  layout, Mamba-2 mixers (a matrix of state a head, a chunked matmul
-  form for prompts, ``ops/selective_scan.py ssd_step`` for decode),
-  attention without positions scaled by ``attention_multiplier``, and
-  in every layer routed experts beside one shared expert (llama.py's
-  expert execution, told which experts it holds), under the embedding,
-  residual and logits multipliers;
-- ``solar_open2.py`` (``kda_n_heads`` > 0 and no ``kv_lora_rank``:
-  ``solar_open2``): on jamba.py's layout, ``kimi_linear.py``'s KDA mixer
-  (64 heads, ``kda_beta_scale`` 2: beta in (0, 2)), chunk and step
-  kernels and held-experts second half (no dense layer) with
-  ``jamba.GQA`` as its ``jamba.Attending``: K/V pages of the attending
-  layers only, no positions, attention's output gated by ``sigmoid(x @
-  wg)`` because its params hold ``wg``; nothing of a layer is written in
-  the module;
-- ``kimi_linear.py`` (``kda_n_heads`` > 0 with ``kv_lora_rank``:
-  ``kimi_linear``, asked before ``is_mla``, which is true of it too): on jamba.py's layout, Kimi
-  Delta Attention mixers (a gated delta rule with a decay a key channel
-  on a matrix of state a head, a chunked matmul form for prompts,
-  ``ops/kda.py kda_step`` for decode) and, as its ``jamba.Attending``,
-  latent attention without positions built from mla.py's functions over
-  pools of the attending layers only; a dense first layer, then mla.py's
-  sigmoid gate and expert execution told which experts it holds, beside
-  a shared expert;
-- ``lfm2.py``: layers of two kinds by a list (``layer_types``), gated
-  short convolutions and attention, two dense MLPs and then routed
-  experts (llama.py's sigmoid gate and expert execution).
-
-| the configuration has | module | K/V | state |
-|---|---|---|---|
-| ``kda_n_heads`` and ``kv_lora_rank`` | ``kimi_linear.py`` | latent pools of the attending layers | KDA state + conv tails |
-| ``kda_n_heads`` alone | ``solar_open2.py`` | K/V pages of the attending layers | KDA state + conv tails |
-| ``kv_lora_rank`` | ``mla.py`` | a latent and a rope pool | none |
-| ``mamba_n_heads`` | ``granite.py`` | K/V pages of the attending layers | Mamba-2 state + conv tails |
-| ``layer_types`` (``conv`` / ``full_attention``) | ``lfm2.py`` | K/V pages of the attending layers | conv tails, snapshotted by the page |
-| ``mamba_d_state`` | ``jamba.py`` | K/V pages of the attending layers | Mamba-1 state + conv tails |
-| ``parallel_block`` | ``cohere2_moe.py`` | a pool a kind of layer (``llama.py`` by kind) | none |
-| none of these | ``llama.py`` | one pool, or with ``kv_pool_by_kind`` a pool a kind | none |
+"""Model families, declared once. ``FAMILIES`` names each family's
+``model_type`` strings with their readers, what a ``ModelConfig`` of it
+HAS, its module and what that module declares; ``REFUSALS`` says which
+serving feature is refused to which capability, and why. The engine,
+llm/disagg, ``ModelConfig.from_hf_config`` and the tools read these two
+tables and spell no family out. A new family writes a module (each
+module's docstring says what its layers are) with its ``read_config(dict)
+-> ModelConfig`` in it, and one record.
 
 **What a module writes.** Four functions the engine calls by name:
 ``init_params(cfg, key)``, ``init_kv_cache(cfg, spec)``,
@@ -78,123 +14,232 @@ flag per family. Eight modules:
 allow_pallas=True, max_top_k=64, mesh=None, pallas_interpret=False)``,
 the fused window, of which a module writes its buffers, ONE step and
 the commit: ``models/window.py`` says what those are and owns the rest.
-Four more are optional and found by ``hasattr`` (ROADMAP C18):
-``init_state`` and ``init_state_snapshots`` (below), ``make_verify_fn``
-(the speculative verify forward) and ``WINDOW_COUNTS`` (the names of
-what the window's steps count).
+What else it has, its record declares:
 
-**Which change the step's shape.** For every configuration but one
-kind, a decode step takes one token a row in and gives one out. A
-configuration with ``block_length`` > 1 (``model_type: sdar_moe``: the
-Qwen3-MoE layer under a block mask, text generated by diffusion over
-blocks) stays in ``llama.py``, whose ``_visible`` / prefill kernel take
-the block mask and whose ``make_decode_window_fn`` then returns the
-BLOCK window (``_make_block_window_fn``, same name ``decode_window``,
-same call form): a window of whole blocks, each up to
-``denoising_steps`` forwards of ``[B, L]`` positions and one commit
-forward; the carry's token operand is a block a row and the program
-returns its own per-row counts last. The engine keys its bookkeeping on
-``cfg.block_length`` (``JaxEngine.block``), never on an option. What
-refuses such a configuration, each by a ``NotImplementedError`` that
-says "<what> is not supported for a model that generates by diffusion
-over blocks (block_length > 1, models/llama.py _make_block_window_fn):
-<why>; its step yields a block a row, and only JaxEngine's window arm
-on one device keeps the books of that (ROADMAP B10)": ``host_pages >
-0``, ``spec_decode``, ``long_prefill_threshold``, a mesh of more than
-one device (``engine/jax_engine.py _refuse_block_generation``), the
-three disagg / KV-transfer classes below (``kv_manager.
-refuse_recurrent_state``), and, by the request, a sampling penalty or
-``logit_bias``. ``page_size`` must be a multiple of ``block_length``
-and ``decode_steps`` a multiple of it (``ValueError``).
-
-**Which hold a share of their experts.** A configuration whose
-``num_experts`` (held here) is less than its ``router_width`` is one
-chip's share of a layer under expert parallelism: the router keeps its
-published width and top-k, ``llama.moe_experts(first=...)`` computes the
-pairs routed to experts ``[first_expert, first_expert + num_experts)``
-in both execution forms, and the partial sum goes on; nothing stands in
-for the other chips or their exchange (one device: ROADMAP B9).
-``granite.py``, ``kimi_linear.py`` (through
-``llama.deepseek_moe_mlp(first=...)``; ``solar_open2.py`` runs the same
-``kimi_linear._ff``) and ``llama.py``'s by-kind path (``_ff_out``, for
-``cohere2_moe.py``) pass it; all four count the pairs routed and held a
-decode window (``llama.pairs_counted``, ``WINDOW_COUNTS``).
-
-**Which keep state.** ``jamba.py``, ``granite.py``, ``kimi_linear.py``,
-``solar_open2.py`` and ``lfm2.py`` carry per-sequence **recurrent state** beside the KV
-pages. A module declares that by
-having ``init_state(cfg, slots)`` (a tuple of pools indexed by slot,
-along whichever axis the module's own programs say: the engine never
-looks inside; jamba.py's layout keeps the scan states slot-major ``[S,
-M, ...]`` and the conv tails layer-major ``[M, S, ...]``);
-the engine then owns the pools, every step program takes ``(state,
-state_slots)`` as trailing operands and returns the state last.
-
-**Which of those snapshot, and so take prefix hits.** ``lfm2.py`` also
-has ``init_state_snapshots(cfg, spec)``: a pool indexed by PAGE id (48 KB
-a page at LFM2-24B's widths cut to 8 layers). The engine appends it to
-``state``, leaves ``PageManager.prefix_reuse`` True, and gives
-``prefill_step`` one more operand, ``state_src``: for each row the page
-whose snapshot its state starts from (-1: none). A program that writes
-a page's last token writes the row's state after that token under the
-page's id, so the snapshot lives and dies with the page. ``jamba.py``
-declares none (a Mamba row is 9.3 MB; a snapshot a page is out of the
-question), nor does ``granite.py`` (36 MiB a row at nine layers of 128 x
-64 x 128 float32) nor ``kimi_linear.py`` (12.4 MiB a row at six layers of
-32 x 128 x 128 float32) nor ``solar_open2.py`` (12.4 MiB a row at three
-layers of 64 x 128 x 128 float32, beside 4 KiB of K/V a token): for them
-a prefix hit counts as a miss, as before.
-
-What refuses a model with recurrent state, at construction, each by a
-``NotImplementedError`` that says "<what> is not supported for a model
-with recurrent state (models/jamba.py, models/lfm2.py,
-models/granite.py, models/kimi_linear.py, models/solar_open2.py): <why>;
-a state snapshot lives in the device pool under its page's id, or not at
-all, and nothing moves or rolls back a state (ROADMAP B7)":
-
-- ``EngineConfig.host_pages > 0`` (the host KV tier): "a page restored
-  from the host comes without the state that goes with it";
-- ``EngineConfig.spec_decode``: "a rejected draft token has already
-  advanced the state, which cannot be rolled back";
-- a mesh of more than one device: "no sharding rule places the state
-  pools or the leaves of the layers that keep state";
-- ``llm/disagg`` ``PrefillWorker``, ``DisaggDecodeEngine`` and
-  ``KvTransferServer`` (disagg and KV transfer): "it moves KV pages
-  between places, and a sequence's pages without its state are not the
-  sequence".
+- ``init_state(cfg, slots)``: per-sequence recurrent state beside the KV
+  pages, a tuple of pools indexed by slot along whichever axis the
+  module's programs say (the engine owns them and never looks inside).
+  Every step program takes ``(state, state_slots)`` as trailing operands
+  and returns the state last; a prefix hit counts as a miss.
+- ``init_state_snapshots(cfg, spec)``: that state kept at each page's
+  end in a pool indexed by PAGE id, appended to ``state``.
+  ``prefill_step`` takes one more operand, ``state_src`` (for each row
+  the page whose snapshot its state starts from, -1: none), a program
+  that writes a page's last token writes the row's state under the
+  page's id, and the prefix cache stays on: a hit hands over pages AND
+  state.
+- ``make_verify_fn``: the speculative verify forward (none: the flag
+  leaves the standard path).
+- ``window_counts``: what the window's steps count and return before
+  the state (``models/window.py``).
+- ``pool_by_kind``: the window layers keep K/V pools of their own
+  (``init_window_kv_cache``, ``window_table_slots``; engine/kv_manager.py
+  ``WindowPagePool``): the programs take ``(window pools, the rows'
+  tables)`` where a family with state takes ``(state, state_slots)``,
+  and nothing is published to the prefix cache.
+- ``by_blocks``: a decode forward yields ``cfg.block_length`` tokens a
+  row (diffusion over blocks). ``make_decode_window_fn`` returns the
+  BLOCK window (same name ``decode_window``, same call form), whose
+  token operand is a block a row and which returns its own per-row
+  counts last; ``prefill_step`` samples nothing; ``page_size`` and
+  ``decode_steps`` must be multiples of the block (``ValueError``).
 """
 
 from __future__ import annotations
 
+from types import ModuleType
+from typing import Callable, Dict, NamedTuple, Optional
+
+from . import (cohere2_moe, config, granite, jamba, kimi_linear, lfm2,
+               llama, mla, solar_open2)
 from .config import ModelConfig
 
 
-def get_model_module(cfg: ModelConfig):
-    if cfg.kda_n_heads > 0:     # before is_mla: the other layers keep state
-        # the attending layers are latent, or K/V pages
-        from . import kimi_linear, solar_open2
+class ModelFamily(NamedTuple):
+    """One family (the module's docstring)."""
+    name: str
+    # model_type of a config.json it claims -> its reader (dict ->
+    # ModelConfig)
+    readers: Dict[str, Callable]
+    # whether a ModelConfig is of this family, asked in FAMILIES' order
+    has: Callable[[ModelConfig], bool]
+    module: ModuleType
+    init_state: Optional[Callable] = None
+    init_state_snapshots: Optional[Callable] = None
+    make_verify_fn: Optional[Callable] = None
+    window_counts: tuple = ()
+    pool_by_kind: bool = False
+    by_blocks: bool = False
 
-        return kimi_linear if cfg.is_mla else solar_open2
-    if cfg.is_mla:
-        from . import mla
+    def refusal(self, feature: str) -> Optional[str]:
+        """Why REFUSALS refuses ``feature`` to this family, or None where
+        it is served."""
+        what, whys = REFUSALS[feature]
+        for capability, (has, sentence) in CAPABILITIES.items():
+            if capability in whys and has(self):
+                return sentence.format(what=what, why=whys[capability])
+        return None
 
-        return mla
-    if cfg.mamba_n_heads > 0:
-        from . import granite
+    def refuse(self, feature: str) -> None:
+        """Raise where REFUSALS refuses ``feature`` to this family: what
+        the engine's constructor and the classes of llm/disagg call."""
+        said = self.refusal(feature)
+        if said is not None:
+            raise NotImplementedError(said)
 
-        return granite
-    if cfg.layer_types:
-        from . import lfm2
 
-        return lfm2
-    if cfg.mamba_d_state > 0:
-        from . import jamba
+def _llama(name: str, readers: dict, has: Callable, **declares):
+    return ModelFamily(name, readers, has, llama,
+                       make_verify_fn=llama.make_verify_fn, **declares)
 
-        return jamba
-    if cfg.parallel_block:
-        from . import cohere2_moe
 
-        return cohere2_moe
-    from . import llama
+FAMILIES = (
+    # KDA before latent ranks: kimi_linear has both, and keeps state
+    ModelFamily("kimi_linear", {"kimi_linear": kimi_linear.read_config},
+                lambda c: c.kda_n_heads > 0 and c.is_mla, kimi_linear,
+                init_state=kimi_linear.init_state,
+                window_counts=kimi_linear.WINDOW_COUNTS),
+    ModelFamily("solar_open2", {"solar_open2": solar_open2.read_config},
+                lambda c: c.kda_n_heads > 0, solar_open2,
+                init_state=solar_open2.init_state,
+                window_counts=solar_open2.WINDOW_COUNTS),
+    ModelFamily("mla", {"deepseek_v2": mla.read_config,
+                        "deepseek_v3": mla.read_config},
+                lambda c: c.is_mla, mla),
+    ModelFamily("granite", {"granitemoehybrid": granite.read_config},
+                lambda c: c.mamba_n_heads > 0, granite,
+                init_state=granite.init_state,
+                window_counts=granite.WINDOW_COUNTS),
+    ModelFamily("lfm2", {"lfm2_moe": lfm2.read_config},
+                lambda c: bool(c.layer_types), lfm2,
+                init_state=lfm2.init_state,
+                init_state_snapshots=lfm2.init_state_snapshots),
+    ModelFamily("jamba", {"jamba": jamba.read_config},
+                lambda c: c.mamba_d_state > 0, jamba,
+                init_state=jamba.init_state),
+    ModelFamily("cohere2_moe", {"cohere2_moe": cohere2_moe.read_config},
+                lambda c: c.parallel_block, cohere2_moe,
+                window_counts=cohere2_moe.WINDOW_COUNTS, pool_by_kind=True),
+    # models/llama.py's three forms: a pool a kind of layer, generation
+    # by blocks, and everything else
+    _llama("llama_by_kind", {"smallthinker": config.read_smallthinker},
+           lambda c: c.kv_pool_by_kind, pool_by_kind=True),
+    _llama("llama_by_blocks", {"sdar_moe": config.read_sdar_moe},
+           lambda c: c.block_length > 1, by_blocks=True),
+    _llama("llama", {"llama": config.read_llama,
+                     "mistral": config.read_llama,
+                     "mixtral": config.read_mixtral,
+                     "qwen2": config.read_qwen2,
+                     "qwen3": config.read_qwen3,
+                     "qwen3_moe": config.read_qwen3_moe,
+                     "gemma": config.read_gemma,
+                     "gemma2": config.read_gemma2},
+           lambda c: True),
+)
 
-    return llama
+
+def family_of(cfg: ModelConfig) -> ModelFamily:
+    """The first family of the table that ``cfg`` is of."""
+    return next(f for f in FAMILIES if f.has(cfg))
+
+
+def get_model_module(cfg: ModelConfig) -> ModuleType:
+    return family_of(cfg).module
+
+
+def reader_of(model_type: str) -> Callable:
+    """The reader of the family that claims ``model_type``."""
+    for f in FAMILIES:
+        if model_type in f.readers:
+            return f.readers[model_type]
+    claimed = sorted(t for f in FAMILIES for t in f.readers)
+    raise NotImplementedError(
+        f"model_type {model_type!r} is claimed by no family of "
+        f"models/registry.py FAMILIES (claimed: {', '.join(claimed)}); "
+        f"it is not read as llama")
+
+
+# ------------------------------------------------------------- refusals
+#
+# capability -> (whether a family has it, the sentence that refuses it
+# {what} because {why}). Each (feature, capability) of REFUSALS that holds
+# a reason is an item of ROADMAP queue B (B10, B7, B6).
+CAPABILITIES = {
+    "by_blocks": (
+        lambda f: f.by_blocks,
+        "{what} is not supported for a model that generates by diffusion "
+        "over blocks (block_length > 1, models/llama.py "
+        "_make_block_window_fn): {why}; its step yields a block a row, "
+        "and only JaxEngine's window arm on one device keeps the books of "
+        "that (ROADMAP B10)"),
+    "state": (
+        lambda f: f.init_state is not None,
+        "{what} is not supported for a model with recurrent state ("
+        + ", ".join(f"models/{f.module.__name__.rpartition('.')[2]}.py"
+                    for f in reversed(FAMILIES) if f.init_state is not None)
+        + "): {why}; a state snapshot lives in the device pool under its "
+        "page's id, or not at all, and nothing moves or rolls back a "
+        "state (ROADMAP B7)"),
+    "pool_by_kind": (
+        lambda f: f.pool_by_kind,
+        "{what} is not supported for a model whose window layers keep a "
+        "K/V pool of their own (kv_pool_by_kind: models/llama.py "
+        "_forward_by_kind, WindowPagePool): {why}; the window layers' "
+        "pages behind a row's window are given back while the row runs, "
+        "and only JaxEngine's own steps on one device keep the books of "
+        "both pools (ROADMAP B6)"),
+}
+
+# what the three classes that move KV pages between places are refused
+_MOVES_PAGES = dict(
+    state="it moves KV pages between places, and a sequence's pages "
+    "without its state are not the sequence",
+    by_blocks="it hands a sequence over as its pages and the first token "
+    "that prefill sampled, and here prefill samples none and the pages "
+    "hold whole blocks only",
+    pool_by_kind="it moves a sequence as the pages of one pool, and the "
+    "window layers' pages of the same positions are in another pool or "
+    "already given back")
+
+# feature -> (what it is called, why it is refused a capability); a
+# capability a feature does not name is served
+REFUSALS = {
+    "host_pages": (
+        "the host KV tier (host_pages > 0, with or without kv_compress)",
+        dict(state="a page restored from the host comes without the state "
+             "that goes with it",
+             by_blocks="no test shows a page restored from the host against "
+             "the block mask's invariant (a page holds whole blocks)",
+             pool_by_kind="a page restored from the host is a page of the "
+             "full layers' pool alone")),
+    "spec_decode": (
+        "spec_decode",
+        dict(state="a rejected draft token has already advanced the state, "
+             "which cannot be rolled back",
+             by_blocks="the verify forward scores one drafted token a "
+             "position under the causal mask, and a block is not drafted "
+             "token by token",
+             pool_by_kind="the verify forward writes drafted tokens' K/V "
+             "through one page table")),
+    "long_prefill_threshold": (
+        "long_prefill_threshold (ring-attention prefill)",
+        dict(by_blocks="the ring's position predicates are causal",
+             pool_by_kind="the ring scatters a prompt's K/V into one pool")),
+    "mesh": (
+        "a mesh of more than one device",
+        dict(state="no sharding rule places the state pools or the leaves "
+             "of the layers that keep state",
+             by_blocks="the block window folds a block's queries into the "
+             "decode kernel's group axis and has no shard_map form",
+             pool_by_kind="no sharding rule places the window layers' "
+             "pools, and the shard_map wrappers take one table a row")),
+    "disagg_prefill": ("a disaggregated prefill worker", _MOVES_PAGES),
+    "disagg_decode": ("a disaggregated decode engine", _MOVES_PAGES),
+    "kv_transfer": ("the KV transfer server", _MOVES_PAGES),
+    # the one refusal made by the request, not at construction
+    "sampling_penalty": (
+        "a sampling penalty or logit_bias",
+        dict(by_blocks="the counts of a row's tokens change inside a "
+             "block, between the forwards that make its positions final, "
+             "and the window keeps no such state")),
+}

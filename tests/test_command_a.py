@@ -226,12 +226,15 @@ def test_from_hf_config_on_the_catalog_config():
 
 
 def test_the_family_is_read_as_neither_llama_nor_gemma2():
-    """An unknown ``model_type`` falls through ``from_hf_config``'s Llama
-    defaults and ``sliding_window`` without a layout takes Gemma-2's
-    even-layer rule: this family's file read so would be a sequential
-    RMSNorm model with a window on layers 0 and 2."""
+    """A ``model_type`` that no family claims is refused (before PR 58 it
+    fell through ``from_hf_config``'s Llama defaults), and
+    ``sliding_window`` without a layout takes Gemma-2's even-layer rule:
+    this family's file read so would be a sequential RMSNorm model with
+    a window on layers 0 and 2."""
     hf = tiny_hf()
-    as_llama = ModelConfig.from_hf_config(dict(hf, model_type="mystery"))
+    with pytest.raises(NotImplementedError, match="mystery.*cohere2_moe"):
+        ModelConfig.from_hf_config(dict(hf, model_type="mystery"))
+    as_llama = ModelConfig.from_hf_config(dict(hf, model_type="llama"))
     assert as_llama.model_type == "llama" and not as_llama.parallel_block
     cfg = ModelConfig.from_hf_config(hf)
     assert cfg.layer_window == (WINDOW, WINDOW, WINDOW, None)

@@ -29,7 +29,7 @@ from dynamo_tpu.llm.protocols.common import (OutputOptions,
 from dynamo_tpu.models import jamba
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import DROP_SLOT, KVCacheSpec
-from dynamo_tpu.models.registry import get_model_module
+from dynamo_tpu.models.registry import family_of, get_model_module
 from dynamo_tpu.runtime.engine import Context
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -726,6 +726,7 @@ def test_a_windows_tails_are_those_of_single_steps_and_of_one_chunk(
 class _Stateful:
     """Stands for an engine that serves a model with recurrent state."""
     state = object()
+    family = family_of(tiny())
 
 
 def _refused(what):

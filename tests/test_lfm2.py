@@ -32,7 +32,7 @@ from dynamo_tpu.llm.protocols.common import (OutputOptions,
 from dynamo_tpu.models import lfm2, mla
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import DROP_SLOT, KVCacheSpec
-from dynamo_tpu.models.registry import get_model_module
+from dynamo_tpu.models.registry import family_of, get_model_module
 from dynamo_tpu.runtime.engine import Context
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -695,6 +695,7 @@ def _refused(what):
 class _Stateful:
     """Stands for an engine that serves a model with recurrent state."""
     state = object()
+    family = family_of(tiny())
 
 
 @pytest.mark.parametrize("what,build", [

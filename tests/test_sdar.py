@@ -30,7 +30,7 @@ from dynamo_tpu.llm.protocols.common import (OutputOptions,
 from dynamo_tpu.models import llama
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.models.llama import DROP_SLOT, KVCacheSpec
-from dynamo_tpu.models.registry import get_model_module
+from dynamo_tpu.models.registry import family_of, get_model_module
 from dynamo_tpu.runtime.engine import Context
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -670,6 +670,7 @@ class _Blocks:
     """Stands for an engine that serves a model generating by blocks."""
     state = None
     block = 4
+    family = family_of(tiny())
 
 
 @pytest.mark.parametrize("what,build", [

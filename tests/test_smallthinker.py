@@ -28,13 +28,12 @@ import numpy as np
 import pytest
 
 from benchmark.harness import weights
-from dynamo_tpu.engine import kv_manager
 from dynamo_tpu.engine.jax_engine import EngineConfig, JaxEngine
 from dynamo_tpu.engine.kv_manager import WindowPagePool
 from dynamo_tpu.llm.protocols.common import (OutputOptions,
                                              PreprocessedRequest,
                                              SamplingOptions, StopConditions)
-from dynamo_tpu.models import llama
+from dynamo_tpu.models import llama, registry
 from dynamo_tpu.models.config import ModelConfig
 from dynamo_tpu.runtime.engine import Context
 from tools.smallthinker_long_context_check import (early_give_back,
@@ -537,7 +536,7 @@ def test_disagg_and_kv_transfer_refuse_this_engine(what):
              "KV transfer server": lambda: KvTransferServer(eng)}[what]
     with _refused(what):
         build()
-    assert "ROADMAP B6" in kv_manager.WINDOW_POOL_REFUSAL
+    assert "ROADMAP B6" in registry.CAPABILITIES["pool_by_kind"][1]
 
 
 # ------------------------------------------------------ the page books

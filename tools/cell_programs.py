@@ -72,7 +72,7 @@ def main() -> int:
     from dynamo_tpu.engine.jax_engine import EngineConfig
     from dynamo_tpu.models import llama
     from dynamo_tpu.models.config import ModelConfig
-    from dynamo_tpu.models.registry import get_model_module
+    from dynamo_tpu.models.registry import family_of
 
     jax.config.update("jax_enable_compilation_cache", False)
     # a location is the op's name-scope path alone, as in a serving
@@ -98,7 +98,8 @@ def main() -> int:
 
     cell = cells.load_cell(a.workload, a.root)
     cfg = ModelConfig.from_local_path(cell["model_path"])
-    model = get_model_module(cfg)
+    fam = family_of(cfg)    # the record JaxEngine.__init__ reads
+    model = fam.module
     ecfg = dataclasses.replace(EngineConfig(),
                                **cells.engine_overrides(cell))
     grid = ecfg.warmed_grid()
@@ -108,19 +109,19 @@ def main() -> int:
     kv_k, kv_v = (on(x) for x in jax.eval_shape(
         lambda: model.init_kv_cache(cfg, spec)))
     state, snapshots = None, False
-    if hasattr(model, "init_state"):    # as JaxEngine.__init__ builds it
+    if fam.init_state is not None:
         state = jax.eval_shape(
-            lambda: model.init_state(cfg, ecfg.max_batch + 1))
-        if hasattr(model, "init_state_snapshots"):
+            lambda: fam.init_state(cfg, ecfg.max_batch + 1))
+        if fam.init_state_snapshots is not None:
             snapshots = True
             state = (*state, jax.eval_shape(
-                lambda: model.init_state_snapshots(cfg, spec)))
+                lambda: fam.init_state_snapshots(cfg, spec)))
         state = on(state)
     # the window layers' pools and the rows' tables into them, for a
     # model with a pool a kind of layer (as JaxEngine.__init__ and
-    # _window_tables build them); the parent of PR 46 has no such model
+    # _window_tables build them)
     wkv, w_slots = None, 0
-    if getattr(cfg, "kv_pool_by_kind", False):
+    if fam.pool_by_kind:
         w_slots = model.window_table_slots(
             cfg, ps := ecfg.page_size,
             max(ecfg.prefill_chunk, 2 * ecfg.decode_steps + 1))
@@ -169,7 +170,7 @@ def main() -> int:
 
     prefill, decode_step = model.make_step_fns(cfg)
     window = model.make_decode_window_fn(cfg, True, ecfg.max_top_k)
-    ps, L = ecfg.page_size, cfg.block_length
+    ps, L = ecfg.page_size, cfg.block_length if fam.by_blocks else 1
     for P in grid["page_buckets"]:
         for T in grid["prefill_lens"]:
             for PB in grid["prefill_batches"]:
