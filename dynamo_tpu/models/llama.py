@@ -958,7 +958,11 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
 # rows through one expert up to which computing EVERY expert for every
 # row is free: the experts' weights are read from HBM either way, and N
 # rows against one read of a bf16 [D, I] matrix are N FLOP a byte, under
-# the v5e's ridge (197 TFLOP/s / 819 GB/s = 240 FLOP a byte)
+# the v5e's ridge (197 TFLOP/s / 819 GB/s = 240 FLOP a byte). One read
+# at every row count up to here: the dense arm's down product contracts
+# (e, i) at once, with the gate inside, on w_down [E, I, D] as stored
+# (moe_experts' docstring: a product that keeps e has the whole stack
+# relaid once an execution from 128 rows up)
 _MOE_DENSE_ROWS = 256
 
 
@@ -998,7 +1002,17 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
       decode-sized dispatches (one read of the weights bounds both
       forms) and expert-parallel meshes (GSPMD shards the E axis of the
       einsum; the sorted form's dynamic expert indexing would
-      all-gather).
+      all-gather). The gate's weight multiplies the activation
+      ``act(x w_gate) * (x w_up)`` [B, T, E, I] and the down product is
+      ONE contraction over (e, i) with ``w_down`` [E, I, D]: the stack
+      is read in the layout it is stored in at every row count, and no
+      [B, T, E, D] intermediate exists. (A down product that keeps e,
+      summed under the gate afterwards, wants I minor on ``w_down`` from
+      128 rows up; a caller slices a layer's stack inside its loop over
+      layers, so the compiler relays the WHOLE stack at the program's
+      entry, once an execution: 6.5 ms of cell 10's 85 ms window, 5.0 of
+      cell 11's 142. The flat ``[N, E*I] @ [E*I, D]`` relays ``w_gate``
+      and ``w_up`` instead. PERF.md, PR 59.)
     float32 operands and accumulation in both; the result in
     ``out_dtype``.
 
@@ -1028,15 +1042,16 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
         full_gate = jnp.sum(
             jax.nn.one_hot(held, E, dtype=jnp.float32) * weights[..., None],
             axis=2)
-    # dense-over-experts: out = sum_e gate[...,e] * mlp_e(x)
+    # dense-over-experts: out = sum_e gate[...,e] * mlp_e(x), the gate
+    # inside the down product: ONE contraction over (e, i)
     with jax.named_scope("moe.experts"):
         ge = jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
                         w_gate.astype(jnp.float32))
         up = jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
                         w_up.astype(jnp.float32))
-        down = jnp.einsum("btei,eid->bted", act(ge) * up,
-                          w_down.astype(jnp.float32))
-        out = jnp.einsum("bted,bte->btd", down, full_gate)
+        out = jnp.einsum("btei,eid->btd",
+                         (act(ge) * up) * full_gate[..., None],
+                         w_down.astype(jnp.float32))
         return out.astype(out_dtype)
 
 

@@ -223,6 +223,100 @@ def test_moe_mlp_paths_agree():
         rtol=5e-2, atol=5e-2)  # bf16 inputs; different summation orders
 
 
+# ---- the dense arm: one contraction over (e, i), the gate inside (PR 59)
+
+
+_ABSENT = 7     # experts the gate scores beyond the E held, with ``first``
+
+
+def _dense_arm_case(shape, first, E=5, k=3, D=16, I=24, seed=59):
+    """x [B, T, D], a gate over ``E`` experts (``first`` None) or over
+    E + _ABSENT of which the stacks hold ``[first, first + E)``, stacks
+    in bfloat16 as a cell stores them. With ``first`` the first token's
+    experts are all absent and the second's all held."""
+    rng = np.random.default_rng(seed)
+    B, T = shape
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    wg, wu, wd = (f(E, D, I) * D ** -0.5, f(E, D, I) * D ** -0.5,
+                  f(E, I, D) * I ** -0.5)
+    wg, wu, wd = (w.astype(jnp.bfloat16) for w in (wg, wu, wd))
+    width = E if first is None else E + _ABSENT
+    w, idx = _routing(jax.random.PRNGKey(seed), B * T, width, k)
+    if first is not None:
+        idx = idx.at[0].set(jnp.asarray([0, width - 1, first + E]))
+        idx = idx.at[1].set(first + jnp.arange(k))
+    return (f(B, T, D), w.reshape(B, T, k), idx.reshape(B, T, k), wg, wu, wd)
+
+
+def _expert_loop(x, w, idx, wg, wu, wd, first, act):
+    """A pair at a time, float32 at the highest precision: the part of
+    the sum the held experts make."""
+    hi = jax.lax.Precision.HIGHEST
+    B, T, D = x.shape
+    E = wg.shape[0]
+    wg, wu, wd = (np.asarray(v, np.float32) for v in (wg, wu, wd))
+    want = np.zeros((B, T, D), np.float32)
+    for b in range(B):
+        for t in range(T):
+            for j in range(idx.shape[-1]):
+                e = int(idx[b, t, j]) - (first or 0)
+                if 0 <= e < E:
+                    h = (act(jnp.matmul(x[b, t], wg[e], precision=hi))
+                         * jnp.matmul(x[b, t], wu[e], precision=hi))
+                    want[b, t] += float(w[b, t, j]) * np.asarray(
+                        jnp.matmul(h, wd[e], precision=hi))
+    return want
+
+
+@pytest.mark.parametrize("shape", [(6, 1), (1, 7), (3, 4)],
+                         ids=["N_1_D", "1_T_D", "B_4_D"])
+@pytest.mark.parametrize("out_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("act", [jax.nn.silu, jax.nn.relu],
+                         ids=["silu", "relu"])
+@pytest.mark.parametrize("first", [None, 4], ids=lambda f: "first_%s" % f)
+def test_dense_arm_matches_a_loop_over_the_held_pairs(first, act, out_dtype,
+                                                      shape):
+    """llama.moe_experts' dense arm (the gate folded into the down
+    product: one contraction over (e, i) on the [E, I, D] stack as
+    stored) against a loop over the pairs by hand, in the three input
+    forms the programs hand it (a decode step's [N, 1, D], a prefill
+    chunk's [1, T, D], a block window's [B, 4, D]). A pair whose expert
+    is not held adds EXACTLY zero: a token with none of its experts
+    here reads 0.0 in every element, whatever the result's type."""
+    x, w, idx, wg, wu, wd = _dense_arm_case(shape, first)
+    got = llama.moe_experts(x, w, idx, wg, wu, wd, False, first=first,
+                            act=act, out_dtype=out_dtype)
+    assert got.dtype == out_dtype and got.shape == x.shape
+    want = _expert_loop(x, w, idx, wg, wu, wd, first, act)
+    # float32 reads 4e-7 here; bfloat16 is the result's own rounding
+    # (7e-3 on values up to 2.8)
+    tol = 1e-5 if out_dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                               rtol=tol, atol=tol)
+    if first is not None:
+        assert (np.asarray(got, np.float32).reshape(-1, x.shape[-1])[0]
+                == 0).all()
+        assert np.abs(want.reshape(-1, x.shape[-1])[1]).max() > 0.05
+
+
+@pytest.mark.parametrize("shape", [(6, 1), (1, 7), (3, 4)],
+                         ids=["N_1_D", "1_T_D", "B_4_D"])
+@pytest.mark.parametrize("first", [None, 4], ids=lambda f: "first_%s" % f)
+def test_dense_arm_matches_the_sorted_arm(first, shape):
+    """The two arms of llama.moe_experts on one routing, inside the
+    tolerance this file holds the sorted form to against the dense
+    reference (2e-4; float32 on the CPU reads 7e-7: the arms differ in
+    where the gate's weight multiplies and in the order of the sum)."""
+    x, w, idx, wg, wu, wd = _dense_arm_case(shape, first)
+    dense = llama.moe_experts(x, w, idx, wg, wu, wd, False, first=first)
+    width = None if first is None else wg.shape[0] + _ABSENT
+    srt = llama.moe_experts(x, w, idx, wg, wu, wd, True, first=first,
+                            width=width)
+    np.testing.assert_allclose(np.asarray(srt), np.asarray(dense),
+                               rtol=2e-4, atol=2e-4)
+
+
 def _prefill_setup(seed, num_pages=64, ps=8):
     cfg = ModelConfig.tiny(num_experts=8, num_experts_per_tok=2,
                            model_type="mixtral")

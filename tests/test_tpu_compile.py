@@ -665,6 +665,18 @@ def _lfm2(one_chip, experts=8):
     return lfm2, cfg, params, kv_k, kv_v, state, e
 
 
+def _lfm2_prefill(one_chip, shapes, PB, T):
+    """models/lfm2.py's prefill chunk of PB rows x T tokens (whole pages)
+    at ``_lfm2``'s shapes, compiled."""
+    lfm2, cfg, params, kv_k, kv_v, state, e = shapes
+    s = partial(_sds, one_chip)
+    return lfm2.make_step_fns(cfg)[0].lower(
+        params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k, kv_v,
+        s((PB, e["page_buckets"][-1]), jnp.int32), s((PB, T), jnp.int32),
+        s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
+        state, s((PB,), jnp.int32), s((PB,), jnp.int32)).compile()
+
+
 @pytest.mark.parametrize("program", ["window", "prefill"])
 def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
                                                        tpu_kernel_path,
@@ -683,11 +695,10 @@ def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
     temporaries a window (scratch compile, PR 33), and the decode
     kernel's fast form cannot slice 64 lanes (PR 32): hence the packing,
     which the window's kernel here confirms (tpu_custom_call, KV' 4)."""
-    lfm2, cfg, params, kv_k, kv_v, state, e = _lfm2(one_chip)
-    s = partial(_sds, one_chip)
-    P = e["page_buckets"][-1]
+    shapes = lfm2, cfg, params, kv_k, kv_v, state, e = _lfm2(one_chip)
     if program == "window":
-        B = e["max_batch"]
+        s = partial(_sds, one_chip)
+        B, P = e["max_batch"], e["page_buckets"][-1]
         i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
         compiled = lfm2.make_decode_window_fn(cfg, True, 64).lower(
             params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
@@ -696,13 +707,8 @@ def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
             k_steps=e["decode_steps"], logprobs_topn=0).compile()
         assert _has_kernel(compiled)
     else:
-        prefill, _ = lfm2.make_step_fns(cfg)
-        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
-        compiled = prefill.lower(
-            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
-            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
-            s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
-            state, s((PB,), jnp.int32), s((PB,), jnp.int32)).compile()
+        compiled = _lfm2_prefill(one_chip, shapes, e["max_prefill_batch"],
+                                 e["prefill_chunk"])
         # the chunk's attention is the paged prefill kernel on the packed
         # pool: no float32 scores over the whole table (2.06 GiB of
         # temporaries on the XLA arm, 0.12 now: scratch compile, PR 34)
@@ -1022,6 +1028,48 @@ def test_granite_programs_write_no_array_of_the_state_pools_size(
             < 15.75 * 1024 ** 3)
 
 
+@pytest.fixture(scope="module")
+def state_program(one_chip):
+    """program(cell, name) -> (the cell's shapes, the lowered program,
+    the compiled one) of a family that carries (state, state_slots)
+    behind its K/V operands (``cell``: ``_kimi`` or ``_solar``): the
+    fused window at the cell's largest batch and its ``decode_steps``,
+    ``decode_step`` at the same batch, a prefill chunk at
+    ``max_prefill_batch``. Compiled once a run of this file, under the
+    caller's ``tpu_kernel_path``: the pool rules and the weight rules
+    read the same program."""
+    made = {}
+
+    def program(cell, name):
+        if (cell, name) in made:
+            return made[cell, name]
+        shapes = model, cfg, params, kv_k, kv_v, state, e = cell(one_chip)
+        s = partial(_sds, one_chip)
+        P, B = e["page_buckets"][-1], e["max_batch"]
+        i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
+        if name == "window":
+            lowered = model.make_decode_window_fn(cfg, True, 64).lower(
+                params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
+                s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
+                s((B, 8), jnp.int32), None, state, i32,
+                k_steps=e.get("decode_steps", 4), logprobs_topn=0)
+        elif name == "decode_step":
+            lowered = model.make_step_fns(cfg)[1].lower(
+                params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32,
+                state, i32)
+        else:
+            PB, T = e["max_prefill_batch"], e["prefill_chunk"]
+            lowered = model.make_step_fns(cfg)[0].lower(
+                params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
+                kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
+                s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
+                state, s((PB,), jnp.int32))
+        made[cell, name] = shapes, lowered, lowered.compile()
+        return made[cell, name]
+
+    return program
+
+
 def _kimi(one_chip):
     """The configuration as kimi-linear-48b-a3b.doc-reason runs it (8 of
     27 layers, 64 of 256 experts held, every width as published); params
@@ -1090,7 +1138,7 @@ def test_kda_chunk_kernel_compiles(one_chip, B):
 
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_kimi_linear_programs_write_no_array_of_a_pools_size(
-        one_chip, tpu_kernel_path, program):
+        tpu_kernel_path, state_program, program):
     """models/kimi_linear.py at the shapes of
     kimi-linear-48b-a3b.doc-reason (matrix-state pool [129, 6, 128, 4096]
     float32 = 1.51 GiB; latent pools of the 2 attending layers, 0.875 +
@@ -1103,38 +1151,18 @@ def test_kimi_linear_programs_write_no_array_of_a_pools_size(
     row in place, and commits its latents once a pool: the pools alias
     their inputs and are never copied. Every program fits beside the
     10.7 GiB resident."""
-    kimi, cfg, params, kv_k, kv_v, state, e = _kimi(one_chip)
-    s = partial(_sds, one_chip)
-    P, B = e["page_buckets"][-1], e["max_batch"]
-    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
-    if program == "window":
-        compiled = kimi.make_decode_window_fn(cfg, True, 64).lower(
-            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
-            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
-            s((B, 8), jnp.int32), None, state, i32, k_steps=4,
-            logprobs_topn=0).compile()
-    elif program == "decode_step":
-        compiled = kimi.make_step_fns(cfg)[1].lower(
-            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, state,
-            i32).compile()
-    else:
-        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
-        compiled = kimi.make_step_fns(cfg)[0].lower(
-            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
-            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
-            s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
-            state, s((PB,), jnp.int32)).compile()
+    (_, cfg, params, kv_k, kv_v, state, e), _, compiled = state_program(
+        _kimi, program)
+    B = e["max_batch"]
     text = compiled.as_text()
     _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     # a chunk's scan is the chunk kernel, a token's the step kernel
     assert ("kda_chunk" in text) == (program == "prefill")
     assert ("kda_step" in text) == (program != "prefill")
-    # the one large copy a B 128 program makes is of WEIGHTS: the
-    # dense-over-experts form's relayout of the 7 x 64 w_down_e matrices
-    # (1.97 GiB of temporaries once a program; PERF.md, Open questions)
-    big = _pool_sized_copies(text, state[0].size)
-    assert all("%%%s = bf16[7,64,1024,2304]" % name in text for name in big)
+    # no copy of the state pool's size or more, of a pool or of weights
+    # (the experts' stacks: test_dense_experts_relay_no_expert_stack)
+    assert _pool_sized_copies(text, state[0].size) == []
     for pool in (state[0], kv_k, kv_v):
         dims = ",".join(map(str, pool.shape))
         assert not re.search(
@@ -1194,7 +1222,7 @@ def test_kda_step_kernel_compiles_at_64_heads(one_chip, B):
 
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_solar_open2_programs_write_no_array_of_a_pools_size(
-        one_chip, tpu_kernel_path, program):
+        tpu_kernel_path, state_program, program):
     """models/solar_open2.py at the shapes of solar-open2-250b.long-reason
     (matrix-state pool [129, 3, 128, 8192] float32 = 1.51 GiB; K and V
     pools of the one attending layer, 1.5 GiB each): the fused window (B
@@ -1208,29 +1236,10 @@ def test_solar_open2_programs_write_no_array_of_a_pools_size(
     them row by row in place: the pools alias their inputs and are never
     copied. The gate is in every program under its scope. Every program
     fits beside the 10.7 GiB resident."""
-    solar, cfg, params, kv_k, kv_v, state, e = _solar(one_chip)
-    s = partial(_sds, one_chip)
-    P, B = e["page_buckets"][-1], e["max_batch"]
-    i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
-    if program == "window":
-        lowered = solar.make_decode_window_fn(cfg, True, 64).lower(
-            params, i32, i32, s((B,), jnp.bool_), i32, i32, kv_k, kv_v,
-            s((B, P), jnp.int32), f32, i32, f32, s((B,), jnp.uint32),
-            s((B, 8), jnp.int32), None, state, i32,
-            k_steps=e["decode_steps"], logprobs_topn=0)
-    elif program == "decode_step":
-        lowered = solar.make_step_fns(cfg)[1].lower(
-            params, i32, i32, kv_k, kv_v, s((B, P), jnp.int32), i32, state,
-            i32)
-    else:
-        PB, T = e["max_prefill_batch"], e["prefill_chunk"]
-        lowered = solar.make_step_fns(cfg)[0].lower(
-            params, s((PB, T), jnp.int32), s((PB, T), jnp.int32), kv_k,
-            kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
-            s((PB,), jnp.int32), s((PB, T // e["page_size"]), jnp.int32),
-            state, s((PB,), jnp.int32))
+    (_, cfg, params, kv_k, kv_v, state, e), lowered, compiled = (
+        state_program(_solar, program))
+    B = e["max_batch"]
     assert "attn.gate" in lowered.as_text(debug_info=True)
-    compiled = lowered.compile()
     text = compiled.as_text()
     _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
@@ -1240,16 +1249,13 @@ def test_solar_open2_programs_write_no_array_of_a_pools_size(
     assert ("kda_step" in text) == (program != "prefill")
     assert ("paged_attention_prefill" if program == "prefill"
             else "paged_attention_decode") in text
-    # a large copy a B 128 program may make is of WEIGHTS: the
-    # dense-over-experts form's relayout of the 4 x 40 w_down_e matrices
+    # no copy of the state pool's size or more, of a pool or of weights.
     # decode_step (K = 1 without the window: no cell's served path, the
     # program the window is tested against) writes its token's K/V by
     # jamba.GQA's flat scatter, which relays the K/V pools out, as for
     # Jamba and Granite: only the state pool is held to the rule there
     served = program != "decode_step"
-    big = _pool_sized_copies(text, state[0].size)
-    assert all(("%%%s = bf16[4,40,1280,4096]" % name in text) or not served
-               for name in big)
+    assert not served or _pool_sized_copies(text, state[0].size) == []
     for pool in (state[0], kv_k, kv_v) if served else (state[0],):
         dims = ",".join(map(str, pool.shape))
         assert not re.search(
@@ -1633,3 +1639,53 @@ def test_by_kind_programs_relay_no_weight(one_chip, tpu_kernel_path, cell,
         # 617.6 MiB with the three relaid wq among them, 19.8 behind the
         # fence (scratch compile, PR 57)
         assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2 ** 20
+
+
+# ----- the dense-over-experts down product reads w_down where it lies (PR 59)
+
+
+@pytest.mark.parametrize("cell,program", [
+    ("kimi", "window"), ("kimi", "decode_step"), ("solar", "window"),
+    ("solar", "decode_step"), ("lfm2", "prefill")])
+def test_dense_experts_relay_no_expert_stack(one_chip, tpu_kernel_path,
+                                             state_program, cell, program):
+    """llama.moe_experts' dense arm at the row counts where the compiler
+    used to relay the experts' stack: the B 128 window and decode_step of
+    cells 10 and 11 (128 x 1 rows) and cell 6's PB 1 x T 256 prefill
+    chunk (1 x 256 rows, all 64 experts). The down product contracts (e,
+    i) at once on [E, I, D] as stored, so no instruction that runs only
+    MOVES a layer's w_down_e in elements or more and is no pool. With the
+    product that kept e (`btei,eid->bted`) the compiler wanted I minor on
+    w_down from 128 rows up and, the stack being sliced a layer inside
+    the loop over layers, relaid ALL layers at the program's entry, once
+    an execution: `copy bf16[7,64,1024,2304]{2,3,1,0}` in both of cell
+    10's programs (6.5 ms of an 85 ms window, 1.97 GiB of temporaries)
+    and `copy bf16[4,40,1280,4096]{2,3,1,0}` in cell 11's window (5.0 ms
+    of 142): those three cases fail on the parent of PR 59. Cell 11's
+    decode_step and cell 6's chunk hold there too. Cell 6's is a limit
+    of this way of compiling: lowered from shapes, here or on the chip
+    itself, the parent's program shows no copy, while the program the
+    engine compiles from its arrays cost 22.4 ms on the chip for the
+    14.2 it costs now (warmup()'s timed table, PERF.md, PR 59; PR 33
+    saw `copy bf16[6,64,1536,2048]` in its trace). The case keeps the
+    shape under the rule. A layer's w_gate_e / w_up_e have the same
+    element count, so the rule holds them as well (the flat `[N, E*I] @
+    [E*I, D]` form relays those two instead)."""
+    if cell == "lfm2":
+        shapes = _, cfg, params, kv_k, kv_v, state, e = _lfm2(one_chip,
+                                                              experts=64)
+        assert 256 in e["prefill_buckets"]
+        compiled = _lfm2_prefill(one_chip, shapes, 1, 256)
+    else:
+        (_, cfg, params, kv_k, kv_v, state, e), _, compiled = state_program(
+            {"kimi": _kimi, "solar": _solar}[cell], program)
+    down = params["w_down_e"]
+    assert down.shape[1:] == (cfg.num_experts, cfg.moe_intermediate_size,
+                              cfg.hidden_size)
+    assert _weight_sized_relayouts(
+        compiled.as_text(), down.size // down.shape[0],
+        pools=tuple(x.size for x in (kv_k, kv_v, *state))) == []
+    if (cell, program) == ("kimi", "window"):
+        # 2,386.9 MiB with the relaid stack among them, 334.0 without
+        # (scratch compile, PR 59)
+        assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20

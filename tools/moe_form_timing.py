@@ -7,11 +7,21 @@ Times ``models.llama.moe_experts`` behind a softmax top-k router on one
 layer of Mixtral-8x7B's experts (8, top-2, 4,096 x 14,336; cells 1, 3)
 at N = 128 / 512 / 2,048 rows, of Qwen3-30B-A3B's (128, top-8, 2,048 x
 768; cells 2, 7) at N = 2,048, of LFM2-24B-A2B's (64, top-4, 2,048 x
-1,536; cell 6) at N = 1,024 / 2,048 and of granite-4.0-h-small's (the 36
-this chip holds of 72, top-10, 4,096 x 768; cell 8) at N = 512 / 2,048,
-with ~13%, ~50% and 100% of the rows live, in three forms:
+1,536; cell 6) at N = 256 (the PB 1 x T 256 suffix chunk) / 1,024 /
+2,048, of granite-4.0-h-small's (the 36 this chip holds of 72, top-10,
+4,096 x 768; cell 8) at N = 512 / 2,048, and of Kimi-Linear-48B-A3B's
+(the 64 held of 256, top-8, 2,304 x 1,024; cell 10) and Solar-Open2-250B's
+(the 40 held of 320, top-8, 4,096 x 1,280; cell 11) at N = 128, their
+B 128 decode window's rows, with ~13%, ~50% and 100% of the rows live,
+in three forms:
 
-- ``dense``: every expert on every row (``_moe_use_blocked`` says no);
+- ``dense``: every expert on every row (``_moe_use_blocked`` says no),
+  the gate inside the down product, one contraction over (e, i) on
+  ``w_down`` [E, I, D] as stored. The arm ALONE, on one layer's slice
+  outside any loop: the whole-stack relayout a down product that keeps
+  e costs a window (PR 59) belongs to the slice inside the window's loop
+  over layers, and no line here shows it for either form; a window is
+  judged by ``tools/program_ops.py`` on its trace;
 - ``loop``: the sorted dispatch as a ``fori_loop`` of one small XLA
   program a block (``moe_experts_blocked`` off the TPU, and before
   PR 42 on it);
@@ -68,8 +78,10 @@ from dynamo_tpu.ops import moe_grouped
 # (name, experts held, router's width, first held, k, D, I, N...)
 SHAPES = [("mixtral", 8, 8, None, 2, 4096, 14336, (128, 512, 2048)),
           ("qwen3", 128, 128, None, 8, 2048, 768, (2048,)),
-          ("lfm2", 64, 64, None, 4, 2048, 1536, (1024, 2048)),
-          ("granite", 36, 72, 18, 10, 4096, 768, (512, 2048))]
+          ("lfm2", 64, 64, None, 4, 2048, 1536, (256, 1024, 2048)),
+          ("granite", 36, 72, 18, 10, 4096, 768, (512, 2048)),
+          ("kimi", 64, 256, 0, 8, 2304, 1024, (128,)),
+          ("solar", 40, 320, 0, 8, 4096, 1280, (128,))]
 FILLS = (0.13, 0.5, 1.0)
 
 
