@@ -72,7 +72,7 @@ class ModelConfig:
     # Gemma-2 final-logit softcap
     embed_scale: bool = False      # multiply embeddings by sqrt(hidden)
     norm_unit_offset: bool = False  # rms_norm weight is (1 + w)
-    hidden_act: str = "silu"       # "silu" | "gelu_tanh" | "relu"
+    hidden_act: str = "silu"   # "silu" | "gelu_tanh" | "relu" | "relu2"
     query_pre_attn_scalar: Optional[float] = None  # attn scale override
     final_logit_softcap: Optional[float] = None
     # Gemma-2 only: sandwich norms (post-attention + pre/post-feedforward
@@ -135,9 +135,9 @@ class ModelConfig:
     # "mamba" or "attention" (no positional
     # embedding); a Mamba-2 mixer has mamba_n_heads heads of mamba_d_head
     # channels, each with a [mamba_d_head, mamba_d_state] matrix of state
-    # and ONE scalar decay, and B and C shared by all heads
-    # (one group: more is refused); mamba_chunk_size is the chunk of the matmul
-    # form a prompt runs.
+    # and ONE scalar decay, and B and C shared by the heads of a group
+    # (mamba_n_groups of them; granite-4.0-h-small has one);
+    # mamba_chunk_size is the chunk of the matmul form a prompt runs.
     # Every layer's second half is routed experts (top-k of the router's
     # outputs, softmax over the chosen) plus one shared expert of
     # shared_intermediate_size. Four multipliers: the embedding's, the
@@ -146,6 +146,7 @@ class ModelConfig:
     # logits.
     mamba_n_heads: int = 0
     mamba_d_head: int = 64
+    mamba_n_groups: int = 1
     mamba_chunk_size: int = 256
     shared_intermediate_size: int = 0
     embedding_multiplier: float = 1.0
@@ -160,6 +161,14 @@ class ModelConfig:
     # stands in for it.
     router_experts: int = 0
     first_expert: int = 0
+    # Nemotron-H (models/nemotron_h.py): layer_types names each layer
+    # "mamba" (granite.py's Mamba-2 mixer), "attention" or "moe", and a
+    # layer is that ONE sub-block under its own norm. An expert is not
+    # gated (hidden_act "relu2": relu(x W_up)^2 W_down, two matrices) and
+    # the routed ones work at moe_latent_size: the layer projects its
+    # input down once a token and the experts' weighted sum up again;
+    # the router and the shared expert read the full width.
+    moe_latent_size: int = 0
     # Kimi Linear (models/kimi_linear.py): layer_types names each layer
     # "kda" or "attention". A KDA mixer
     # (Kimi Delta Attention: a gated delta rule) has kda_n_heads heads,

@@ -13,15 +13,21 @@ Shared(x))``, ``x = rms_norm(h)``, ``r = residual_multiplier``; exit
 ``logits = (rms_norm(h) @ head) / logits_scaling``. An attending layer
 is GQA, causal, scores scaled by ``attention_multiplier`` (not
 1/sqrt(head_dim)). A Mamba-2 mixer has H heads of P channels (H * P =
-d_inner), a state of N a channel, ONE group:
+d_inner), a state of N a channel, and G groups (``mamba_n_groups``;
+granite-4.0-h-small has ONE, models/nemotron_h.py runs this mixer with
+eight): head h reads the B and C of group g = h // (H / G), and the gated
+norm is over each group's d_inner / G channels:
 
-    [z, xBC, dt] = split(W_in u)                   d_inner / d_inner + 2N / H
+    [z, xBC, dt] = split(W_in u)                   d_inner / d_inner + 2GN / H
     xBC  = silu(causal depthwise conv1d(xBC; conv_w, b_conv))
-    [x, B, C] = split(xBC)                         d_inner / N / N
+    [x, B, C] = split(xBC)                         d_inner / GN / GN
     dt   = softplus(dt + b_dt)   a head,           A = -exp(A_log) a head
-    S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] (x_t[h] outer B_t)
-    y_t[h] = S_t[h] C_t + d_skip[h] x_t[h]
-    out  = W_out(rms_norm(y * silu(z)) * ssm_norm)     the gate, THEN the norm
+    S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] (x_t[h] outer B_t[g])
+    y_t[h] = S_t[h] C_t[g] + d_skip[h] x_t[h]
+    out  = W_out(group_rms_norm(y * silu(z)) * ssm_norm)   the gate, THEN the norm
+
+The number of groups is taken in Python at trace time: with one, every
+function below lowers to what it lowered to before there were groups.
 
 Routed: the router keeps its published width (``cfg.router_width``
 outputs) and its top-k, the softmax is over the chosen logits; the
@@ -33,13 +39,13 @@ shared expert is what goes on to the next layer.
 
 **State.** A sequence carries, a Mamba-2 layer, the matrix state of its
 heads (float32) and the last ``d_conv - 1`` inputs of the convolution
-(``d_inner + 2N`` channels). The pool keeps the matrix as ``[N, H * P]``:
+(``d_inner + 2GN`` channels). The pool keeps the matrix as ``[N, H * P]``:
 the published ``[H, P, N]`` with N in front, so that what is H * P wide
 a token (x, dt, y) lies along the lanes as the projections make and take
 it and only B and C, N wide, cross to the sublanes
 (ops/selective_scan.py ``ssd_step``); both pools then have jamba.py's
 ranks and axes, ``[S, M, N, H * P]`` slot-major and the conv tails ``[M,
-S, (d_conv - 1) * (d_inner + 2N)]`` layer-major, oldest input first. At
+S, (d_conv - 1) * (d_inner + 2GN)]`` layer-major, oldest input first. At
 granite-4.0-h-small's widths a row is 4 MiB a layer, 36 MiB at nine
 layers: the module declares no snapshots, so a prefix hit counts as a
 miss, as for Jamba.
@@ -52,7 +58,8 @@ tokens entered with S_in, ``a_t = dt_t A``, ``L_t = sum_{s<=t} a_s``,
             + exp(L_t) S_in C_t
     S_out = exp(L_Q) S_in + sum_s exp(L_Q - L_s) dt_s (x_s outer B_s)
 
-with ``C_t . B_s`` ONE [Q, Q] matrix for all heads, and every exponent a
+with ``C_t . B_s`` ONE [Q, Q] matrix a GROUP, which the group's heads
+share (one for all heads where there is one group), and every exponent a
 difference ``<= 0``: nothing overflows whatever is drawn. One token from
 a stored state: the kernel on the pool where the attention kernels run,
 ``_ssd_step`` on gathered rows elsewhere. Scopes: ``ssm`` around the
@@ -99,10 +106,6 @@ def read_config(cfg: dict) -> ModelConfig:
         refuse(f"layer_types {odd or len(kinds)}",
                "it must name num_hidden_layers layers, each mamba or "
                "attention")
-    if cfg.get("mamba_n_groups", 1) != 1:
-        refuse("mamba_n_groups > 1",
-               "B and C are computed once for all heads, and the "
-               "chunked form's C.B product is one matrix a chunk")
     if cfg.get("position_embedding_type", "nope") != "nope":
         refuse(f"position_embedding_type "
                f"{cfg['position_embedding_type']!r}",
@@ -120,12 +123,17 @@ def read_config(cfg: dict) -> ModelConfig:
                f"mamba_expand x hidden_size is "
                f"{expand * cfg['hidden_size']}, the mixer's one inner "
                f"width")
+    groups = cfg.get("mamba_n_groups", 1)
+    if groups < 1 or heads % groups:
+        refuse(f"mamba_n_groups {groups}",
+               f"a group is a whole number of the {heads} heads")
     c.num_experts, c.router_experts, c.first_expert = held_experts(
         cfg, "num_local_experts", "num_experts_per_tok", refuse)
     c.model_type = "granitemoehybrid"
     c.layer_types = kinds
     c.mamba_n_heads, c.mamba_d_head = heads, d_head
     c.mamba_d_state = cfg["mamba_d_state"]
+    c.mamba_n_groups = groups
     c.mamba_d_conv = cfg.get("mamba_d_conv", 4)
     c.mamba_expand = expand
     c.mamba_chunk_size = cfg.get("mamba_chunk_size", 256)
@@ -140,8 +148,8 @@ def read_config(cfg: dict) -> ModelConfig:
 
 
 def conv_width(cfg: ModelConfig) -> int:
-    """Channels the convolution runs over: x, B and C (one group)."""
-    return cfg.mamba_d_inner + 2 * cfg.mamba_d_state
+    """Channels the convolution runs over: x, and B and C a group."""
+    return cfg.mamba_d_inner + 2 * cfg.mamba_n_groups * cfg.mamba_d_state
 
 
 # ------------------------------------------------------- params and pools
@@ -151,8 +159,9 @@ def init_state(cfg: ModelConfig, slots: int, dtype=None) -> jamba.State:
     """The recurrent-state pools for ``slots`` sequences: [S, M, N, H *
     P] float32, slot-major, and the conv tails [M, S, (d_conv - 1) *
     conv_width], layer-major as jamba.init_state's (what declares to the
-    engine that this module's sequences carry state beside pages)."""
-    M = num_mamba_layers(cfg)
+    engine that this module's sequences carry state beside pages). M
+    counts the layers ``layer_types`` names "mamba"."""
+    M = cfg.layer_types.count("mamba")
     return (jnp.zeros((slots, M, cfg.mamba_d_state, cfg.mamba_d_inner),
                       jnp.float32),
             jnp.zeros((M, slots, (cfg.mamba_d_conv - 1) * conv_width(cfg)),
@@ -191,7 +200,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
         "ln_final": jnp.ones((D,), dtype),
         "wq": w(A, D, H * hd), "wk": w(A, D, KV * hd),
         "wv": w(A, D, KV * hd), "wo": w(A, H * hd, D),
-        "w_in": w(M, D, 2 * di + 2 * N + Hm),
+        "w_in": w(M, D, di + conv_width(cfg) + Hm),
         "conv_w": w(M, dc, conv_width(cfg)),
         "b_conv": jnp.zeros((M, conv_width(cfg)), dtype),
         "b_dt": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
@@ -217,8 +226,16 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None) -> Params:
 def _ssd_step(s, dec, dtx, b, c):
     """One token of the recurrence for every row. s [B, N, C] float32, C
     = H * P; dec = exp(dt A) and dtx = dt * x [B, C] (a head's dt and
-    decay repeated over its channels); b, c [B, N]. A row whose dt is 0
-    keeps its state (dec 1, nothing added)."""
+    decay repeated over its channels); b, c [B, N], or by group [B, G,
+    N]: group g's are those of channels [g C / G, (g + 1) C / G). A row
+    whose dt is 0 keeps its state (dec 1, nothing added)."""
+    if b.ndim == 3:
+        B, G, N = b.shape
+        b, c = (jnp.swapaxes(v, 1, 2)[..., None] for v in (b, c))
+        s = s.reshape(B, N, G, -1)                  # [B, N, G, C / G]
+        s = (dec.reshape(B, 1, G, -1) * s + dtx.reshape(B, 1, G, -1) * b)
+        return (s.reshape(B, N, -1),
+                jnp.sum(s * c, axis=1).reshape(B, -1))
     s = dec[:, None, :] * s + dtx[:, None, :] * b[:, :, None]
     return s, jnp.sum(s * c[:, :, None], axis=1)
 
@@ -227,13 +244,15 @@ def _ssd_chunk(s0, dt, x, b, c, a_neg, chunk: int):
     """T tokens of the recurrence from the carried state s0 [B, N, H *
     P], in T / Q chunks of the matmul form (the module's docstring). dt
     [B, T, H] (0 at a token that does not count); x [B, T, H, P]; b, c
-    [B, T, N]; a_neg [H] = -exp(A_log), all float32. Returns (s after
+    [B, T, N], or by group [B, T, G, N] (head h reads group h // (H /
+    G)); a_neg [H] = -exp(A_log), all float32. Returns (s after
     the last token, y [B, T, H, P]). Per chunk and row: one [Q, Q]
-    product C . B, one [Q, Q, H] table of decays (exponents are
-    differences L_t - L_s with s <= t, never positive), and three
+    product C . B (a group), one [Q, Q, H] table of decays (exponents
+    are differences L_t - L_s with s <= t, never positive), and three
     products with the heads as a batch."""
     B, T, H = dt.shape
     P, N = x.shape[-1], b.shape[-1]
+    G = b.shape[2] if b.ndim == 4 else None
     Q = math.gcd(T, chunk)
     nb = T // Q
 
@@ -242,10 +261,29 @@ def _ssd_chunk(s0, dt, x, b, c, a_neg, chunk: int):
 
     later = (jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :])[:, :, None]
 
+    def by_group(s, cum, dtx, b_c, c_c):
+        """``one``'s three products with (group, head of the group) as
+        the batch: b_c, c_c [B, Q, G, N]."""
+        J = H // G
+        s = s.reshape(B, N, G, J, P)
+        decay = jnp.exp(jnp.where(later, cum[:, :, None] - cum[:, None],
+                                  -jnp.inf)).reshape(B, Q, Q, G, J)
+        w = jnp.einsum("btgn,bsgn->btsg", c_c, b_c)[..., None] * decay
+        dtx = dtx.reshape(B, Q, G, J, P)
+        y = jnp.einsum("btsgj,bsgjp->btgjp", w, dtx)
+        y = y + (jnp.exp(cum).reshape(B, Q, G, J, 1)
+                 * jnp.einsum("btgn,bngjp->btgjp", c_c, s))
+        to_end = jnp.exp(cum[:, -1:] - cum).reshape(B, Q, G, J, 1)
+        s = (jnp.exp(cum[:, -1]).reshape(B, 1, G, J, 1) * s
+             + jnp.einsum("bsgn,bsgjp->bngjp", b_c, to_end * dtx))
+        return s.reshape(B, N, H * P), y.reshape(B, Q, H, P)
+
     def one(s, xs):
         dt_c, x_c, b_c, c_c = xs
         cum = jnp.cumsum(dt_c * a_neg, axis=1)              # [B, Q, H]
         dtx = dt_c[..., None] * x_c                         # [B, Q, H, P]
+        if G is not None:
+            return by_group(s, cum, dtx, b_c, c_c)
         s = s.reshape(B, N, H, P)
         # inside the chunk: (C_t . B_s) exp(L_t - L_s) for s <= t
         w = (jnp.einsum("btn,bsn->bts", c_c, b_c)[..., None]
@@ -269,7 +307,7 @@ def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step,
             tail_step=None):
     """The Mamba-2 mixer on a chunk: jamba._mamba's call form. u [B, T,
     D] (normed); valid [B, T] (a row's valid tokens lead); s [B, N, H *
-    P] float32 and tail [B, (d_conv - 1) * (d_inner + 2N)]: the rows' state
+    P] float32 and tail [B, (d_conv - 1) * conv_width]: the rows' state
     on entry. Returns (out [B, T, D], s, tail) with the state after each
     row's last valid token. ``step`` is the one-token recurrence (T ==
     1) with _ssd_step's operands and results, ``s`` being whatever it
@@ -279,6 +317,7 @@ def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step,
     f32 = jnp.float32
     B, T, _ = u.shape
     H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+    G = cfg.mamba_n_groups
     di = H * P
 
     def dot(a, w):
@@ -289,12 +328,14 @@ def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step,
     with jax.named_scope("ssm"):
         with jax.named_scope("ssm.proj"):
             z, xbc, dt = jnp.split(dot(u, mp["w_in"]),
-                                   [di, 2 * di + 2 * N], axis=-1)
+                                   [di, 2 * di + 2 * G * N], axis=-1)
             dt = jax.nn.softplus(dt + mp["b_dt"].astype(f32))
             dt = jnp.where(valid[:, :, None], dt, 0.0)          # [B, T, H]
         xbc, tail = _causal_conv(mp, xbc, valid, tail, cfg.mamba_d_conv,
                                  tail_step=tail_step)
-        x, b, c = jnp.split(xbc, [di, di + N], axis=-1)
+        x, b, c = jnp.split(xbc, [di, di + G * N], axis=-1)
+        if G > 1:       # B and C a group: [B, T, G, N]
+            b, c = b.reshape(B, T, G, N), c.reshape(B, T, G, N)
         with jax.named_scope("ssm.scan"):
             a_neg = -jnp.exp(mp["A_log"].astype(f32))           # [H]
             if T == 1:      # one token from a stored state
@@ -308,8 +349,14 @@ def _mamba2(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssd_step,
                 y = y.reshape(B, T, di)
             y = y + jnp.repeat(mp["d_skip"].astype(f32), P) * x
         with jax.named_scope("ssm.norm"):
-            g = rms_norm(y * jax.nn.silu(z), mp["ssm_norm"].astype(f32),
-                         cfg.rms_norm_eps)
+            g = y * jax.nn.silu(z)
+            w = mp["ssm_norm"].astype(f32)
+            if G > 1:   # the RMS over each group's di / G channels
+                g = rms_norm(g.reshape(B, T, G, di // G),
+                             w.reshape(G, di // G),
+                             cfg.rms_norm_eps).reshape(B, T, di)
+            else:
+                g = rms_norm(g, w, cfg.rms_norm_eps)
         with jax.named_scope("ssm.proj"):
             out = dot(g, mp["w_out"])
     return out, s, tail

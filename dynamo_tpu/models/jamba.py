@@ -446,9 +446,12 @@ class Blocks(NamedTuple):
     the layer loops, the pools' traffic and the window are this module's
     for all of them. models/granite.py is the second,
     models/kimi_linear.py the third, whose attending half is latent and
-    whose prefill chunk has a kernel of its own (``chunk``), and
+    whose prefill chunk has a kernel of its own (``chunk``),
     models/solar_open2.py the fourth: kimi_linear.py's mixer and second
-    half beside ``GQA``."""
+    half beside ``GQA``; and models/nemotron_h.py the fifth, whose layer
+    is ONE sub-block (a mixer, or a second half, alone): its own
+    ``segments`` names, a run, where the second halves lie, and a run
+    may have none."""
     keys: tuple             # the state-space mixer's leaves, stacked [M, ...]
     mixer: Callable         # _mamba's call form
     ff: Callable            # _dense_ff's call form: the layer's second half
@@ -463,6 +466,13 @@ class Blocks(NamedTuple):
     # ``mixer`` as ``chunk=`` where the kernels run); None: the mixer's
     # XLA form
     chunk: Optional[Callable] = None
+    # the layer pattern as runs (``segments``' form). A family whose
+    # layers do not all have both halves appends to a run where its
+    # second halves start in THEIR stacks (``ln_mlp`` and what ``ff``
+    # reads; None: the run's layers have no second half), its ``l`` then
+    # counting the mixers' ``ln_mixer``; and names a second half that no
+    # mixer precedes ("ff", its index)
+    segments: Callable = segments
 
 
 MAMBA1 = Blocks(MAMBA_KEYS, _mamba, _dense_ff, selective_scan_step)
@@ -508,18 +518,24 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
     def add(h, out):        # a mixer's output onto the residual stream
         return h + (out if res == 1.0 else res * out)
 
-    for seg in segments(cfg):
+    for seg in blocks.segments(cfg):
+        if seg[0] == "ff":
+            h, tally = mlp(h, seg[1], tally, seg[1])
+            continue
         if seg[0] == "attn":
-            _, a, l = seg
+            _, a, l, *f = seg
+            f = f[0] if f else l
             with jax.named_scope("attn"):
                 x = norm(h, params["ln_mixer"][l])
                 out, cache = attend(a, x, cache)
                 h = add(h, out)
-            h, tally = mlp(h, l, tally, l)
+            if f is not None:
+                h, tally = mlp(h, f, tally, f)
             continue
-        _, m0, l0, count = seg
+        _, m0, l0, count, *f0 = seg
+        f0 = f0[0] if f0 else l0
 
-        def layer(carry, i, m0=m0, l0=l0):
+        def layer(carry, i, m0=m0, l0=l0, f0=f0):
             h, ssm, conv, tally = carry
             m = m0 + i
             mp = _at(params, blocks.keys, m)
@@ -540,7 +556,9 @@ def _stack(params: Params, cfg: ModelConfig, h, valid, ssm, conv, attend,
                         pool, slots, m, *row, fresh, interpret=interpret),
                     tail_step=lambda tails, *row: conv_tail_step(
                         tails, m, *row, interpret=interpret))
-            h, tally = mlp(add(h, out), l0 + i, tally, l0)
+            h = add(h, out)
+            if f0 is not None:
+                h, tally = mlp(h, f0 + i, tally, f0)
             return (h, ssm, conv, tally), None
 
         (h, ssm, conv, tally), _ = lax.scan(

@@ -227,11 +227,19 @@ def project_logits(params: Params, cfg: ModelConfig,
         return logits
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """relu(x)^2: the activation of an expert that is not gated
+    (models/nemotron_h.py)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def _act(cfg: ModelConfig):
     if cfg.hidden_act == "gelu_tanh":
         return lambda x: jax.nn.gelu(x, approximate=True)
     if cfg.hidden_act == "relu":
         return jax.nn.relu
+    if cfg.hidden_act == "relu2":
+        return relu2
     return jax.nn.silu
 
 
@@ -797,7 +805,8 @@ def moe_kernel_takes(cfg: ModelConfig, params: Params, mesh,
             mesh, n_tokens, cfg.num_experts, cfg.num_experts_per_tok):
         return False
     # the routed experts' stack, under either name the modules give it
-    stack = params["w_gate_e"] if "w_gate_e" in params else params["w_gate"]
+    # (the up matrix: an expert that is not gated has no other going in)
+    stack = params["w_up_e"] if "w_up_e" in params else params["w_up"]
     return _moe_kernel_interpret(stack) is not None
 
 
@@ -869,7 +878,8 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
     x: [N, D] (f32) flattened tokens; weights/idx: [N, k] routing output;
     live: optional [N] bool, False on padding rows (None = all live);
     w_*: a layer's [E, ...] expert stacks, or with ``layer`` (traced
-    index) the whole [L, E, ...] parameters, read in place. ``first``:
+    index) the whole [L, E, ...] parameters, read in place; ``w_gate``
+    None: an expert that is not gated (``moe_experts``). ``first``:
     see ``moe_experts``; a pair whose expert is not held is not live.
 
     Sort the N*k pairs by expert, a dead row's pairs behind every
@@ -897,10 +907,10 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
     """
     N, D = x.shape
     k = idx.shape[-1]
-    E = w_gate.shape[-3]
+    E = w_up.shape[-3]
     NK = N * k
     n_max = (NK + block - 1) // block + E
-    interpret = _moe_kernel_interpret(w_gate)
+    interpret = _moe_kernel_interpret(w_up)
     if interpret is not None:       # whole chunks of blocks: _moe_rows_in
         per = max(_MOE_CHUNK_ROWS // block, 1)
         n_max = (n_max + per - 1) // per * per
@@ -923,12 +933,13 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
 
     if interpret is not None:
         with jax.named_scope("moe.dispatch"):
-            xs = _moe_rows_in(x.astype(w_gate.dtype), tok, row0, n_blocks,
+            xs = _moe_rows_in(x.astype(w_up.dtype), tok, row0, n_blocks,
                               block)
         with jax.named_scope("moe.experts"):
             stacks = (w_gate, w_up, w_down)
             if layer is None:
-                stacks, layer = [w[None] for w in stacks], 0
+                stacks, layer = [w if w is None else w[None]
+                                 for w in stacks], 0
             ys = moe_grouped_mlp(
                 xs, *stacks, jnp.asarray(layer, jnp.int32), n_blocks,
                 block_e, block=block, act=act, interpret=interpret)
@@ -942,10 +953,10 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
         rows = r0 + jnp.arange(block, dtype=jnp.int32)
         t = lax.dynamic_slice(tok, (r0,), (block,))
         xb = jnp.where((rows < row_end[j])[:, None], x[t], 0.0)
-        wg, wu, wd = (_dyn_expert(w, block_e[j], layer)
+        wg, wu, wd = (w if w is None else _dyn_expert(w, block_e[j], layer)
                       for w in (w_gate, w_up, w_down))
-        yb = (act(xb @ wg) * (xb @ wu)) @ wd
-        return lax.dynamic_update_slice(ys, yb, (r0, 0))
+        hid = act(xb @ wu) if wg is None else act(xb @ wg) * (xb @ wu)
+        return lax.dynamic_update_slice(ys, hid @ wd, (r0, 0))
 
     with jax.named_scope("moe.experts"):
         ys = jnp.zeros((NK + block, D), jnp.float32)
@@ -1016,6 +1027,12 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
     float32 operands and accumulation in both; the result in
     ``out_dtype``.
 
+    ``w_gate`` None: an expert that is NOT gated, ``act(x w_up) w_down``,
+    two matrices, each read once in every form (models/nemotron_h.py:
+    ``act`` = ``relu2``; handing ``w_up`` in as the gate too would give
+    relu(a) a, the same number, and read and multiply every expert
+    twice).
+
     ``first`` tells the layer WHICH experts it holds (the chip's share
     of a layer under expert parallelism): None = all of them, idx counts
     the w_* stacks' own E. An int: the gate routed over more experts than
@@ -1027,13 +1044,13 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
     here). The result is this share's part of the sum; nothing stands in
     for the rest."""
     B, T, D = x.shape
-    E = w_gate.shape[-3]
+    E = w_up.shape[-3]
     k = idx.shape[-1]
     if blocked:
         out = moe_experts_blocked(
             x.reshape(B * T, D).astype(jnp.float32),
             weights.reshape(B * T, k), idx.reshape(B * T, k),
-            w_gate, w_up, w_down, moe_block(B * T, k, w_gate.shape, width),
+            w_gate, w_up, w_down, moe_block(B * T, k, w_up.shape, width),
             live=None if live is None else live.reshape(B * T),
             layer=layer, act=act, first=first)
         return out.reshape(B, T, D).astype(out_dtype)
@@ -1045,12 +1062,16 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
     # dense-over-experts: out = sum_e gate[...,e] * mlp_e(x), the gate
     # inside the down product: ONE contraction over (e, i)
     with jax.named_scope("moe.experts"):
-        ge = jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
-                        w_gate.astype(jnp.float32))
-        up = jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
-                        w_up.astype(jnp.float32))
-        out = jnp.einsum("btei,eid->btd",
-                         (act(ge) * up) * full_gate[..., None],
+        def into(w):
+            return jnp.einsum("btd,edi->btei", x.astype(jnp.float32),
+                              w.astype(jnp.float32))
+
+        if w_gate is None:
+            hid = act(into(w_up))
+        else:
+            ge, up = into(w_gate), into(w_up)
+            hid = act(ge) * up
+        out = jnp.einsum("btei,eid->btd", hid * full_gate[..., None],
                          w_down.astype(jnp.float32))
         return out.astype(out_dtype)
 
@@ -1073,8 +1094,11 @@ def pairs_counted(cfg: ModelConfig, idx: jax.Array,
         jnp.sum(here & valid[..., None])]).astype(jnp.int32)
 
 
-def deepseek_gate(x32, w_router, bias, cfg: ModelConfig):
+def deepseek_gate(x32, w_router, bias, cfg: ModelConfig, precision=None):
     """DeepSeek router → (weights [B, T, k], expert indices [B, T, k]).
+    ``precision``: of the logits' product (None: the backend's default,
+    which on a TPU is ONE bf16 pass over x32; models/nemotron_h.py asks
+    for ``HIGHEST``).
 
     v2 (HF DeepseekV2MoEGate): softmax scores; optional group limiting by
     the MAX score per group; top-k; weights scaled (NOT renormalized).
@@ -1084,7 +1108,8 @@ def deepseek_gate(x32, w_router, bias, cfg: ModelConfig):
     experts, optionally renormalized, then scaled."""
     E = w_router.shape[-1]
     k = cfg.num_experts_per_tok
-    logits = x32 @ w_router.astype(jnp.float32)
+    logits = jnp.matmul(x32, w_router.astype(jnp.float32),
+                        precision=precision)
     if cfg.moe_router == "deepseek_v3":
         scores = jax.nn.sigmoid(logits)
         # no selection bias where the family has none (cohere2_moe)

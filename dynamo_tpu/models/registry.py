@@ -5,7 +5,11 @@ serving feature is refused to which capability, and why. The engine,
 llm/disagg, ``ModelConfig.from_hf_config`` and the tools read these two
 tables and spell no family out. A new family writes a module (each
 module's docstring says what its layers are) with its ``read_config(dict)
--> ModelConfig`` in it, and one record.
+-> ModelConfig`` in it, and one record. The order of the records is the
+order ``family_of`` asks in: a family whose configuration also has what
+a later one asks for stands first (kimi_linear before mla; nemotron_h,
+whose layer is ONE sub-block and whose Mamba-2 mixer is granite.py's run
+by groups, before granite).
 
 **What a module writes.** Four functions the engine calls by name:
 ``init_params(cfg, key)``, ``init_kv_cache(cfg, spec)``,
@@ -51,7 +55,7 @@ from types import ModuleType
 from typing import Callable, Dict, NamedTuple, Optional
 
 from . import (cohere2_moe, config, granite, jamba, kimi_linear, lfm2,
-               llama, mla, solar_open2)
+               llama, mla, nemotron_h, solar_open2)
 from .config import ModelConfig
 
 
@@ -106,6 +110,12 @@ FAMILIES = (
     ModelFamily("mla", {"deepseek_v2": mla.read_config,
                         "deepseek_v3": mla.read_config},
                 lambda c: c.is_mla, mla),
+    # experts in a latent before Mamba-2 heads: nemotron_h has both, and
+    # its layer is one sub-block
+    ModelFamily("nemotron_h", {"nemotron_h": nemotron_h.read_config},
+                lambda c: c.mamba_n_heads > 0 and c.moe_latent_size > 0,
+                nemotron_h, init_state=nemotron_h.init_state,
+                window_counts=nemotron_h.WINDOW_COUNTS),
     ModelFamily("granite", {"granitemoehybrid": granite.read_config},
                 lambda c: c.mamba_n_heads > 0, granite,
                 init_state=granite.init_state,

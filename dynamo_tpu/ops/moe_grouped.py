@@ -8,6 +8,11 @@ its rows:
 
     y = (act(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e]
 
+or, for an expert that is not gated (``w_gate`` None: two matrices, each
+read once),
+
+    y = act(x @ w_up[e]) @ w_down[e]
+
 Off the TPU a ``fori_loop`` runs one small XLA program a block. On the
 v5e that loop cannot overlap anything: a block's three weight matrices
 are sliced out of the stack, multiplied and dropped before the next
@@ -84,18 +89,23 @@ def i_tile(block: int, D: int, I: int, w_bytes: int) -> int:
 def _mlp_kernel(act, n_i: int,
                 # scalar prefetch
                 n_blocks_ref, block_e_ref, layer_ref,
-                # a block's rows [block, D]; its expert's [D, ti],
-                # [D, ti], [ti, D]; its slot of the result [block, D]
-                x_ref, wg_ref, wu_ref, wd_ref, y_ref):
+                # a block's rows [block, D]; its expert's [D, ti] (the
+                # gate's, where it has one), [D, ti], [ti, D]; its slot
+                # of the result [block, D]
+                x_ref, *refs):
     del block_e_ref, layer_ref            # the index maps read them
+    *wg_ref, wu_ref, wd_ref, y_ref = refs
+    gated = bool(wg_ref)
     j, it = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j < n_blocks_ref[0])
     def _():
         x = x_ref[...]
-        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        if gated:
+            g = jnp.dot(x, wg_ref[0][...],
+                        preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        h = (act(g) * u).astype(wd_ref.dtype)
+        h = (act(g) * u if gated else act(u)).astype(wd_ref.dtype)
         y = jnp.dot(h, wd_ref[...], preferred_element_type=jnp.float32)
         if n_i == 1:
             y_ref[...] = y
@@ -120,16 +130,17 @@ def moe_grouped_mlp(xs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     """Every live block's expert MLP, float32 ``[n_max * block, D]``.
 
     xs: ``[n_max * block, D]`` in the weights' dtype, block j's rows at
-    ``j * block``; w_gate, w_up ``[L, E, D, I]`` and w_down ``[L, E, I,
-    D]``, left where they are; layer: int32 scalar; n_blocks: int32
-    scalar, block_e: int32 ``[n_max]`` (``moe_block_plan``). Rows of a
-    block at or past ``n_blocks`` are not written. ``tile`` overrides
-    ``i_tile`` (the tests')."""
+    ``j * block``; w_gate (None: an expert that is not gated), w_up ``[L,
+    E, D, I]`` and w_down ``[L, E, I, D]``, left where they are; layer:
+    int32 scalar; n_blocks: int32 scalar, block_e: int32 ``[n_max]``
+    (``moe_block_plan``). Rows of a block at or past ``n_blocks`` are not
+    written. ``tile`` overrides ``i_tile`` (the tests')."""
     n_max = block_e.shape[0]
-    L, E, D, I = w_gate.shape
+    gated = w_gate is not None
+    L, E, D, I = w_up.shape
     assert xs.shape == (n_max * block, D), (xs.shape, n_max, block, D)
-    assert xs.dtype == w_gate.dtype, (xs.dtype, w_gate.dtype)
-    ti = tile or i_tile(block, D, I, w_gate.dtype.itemsize)
+    assert xs.dtype == w_up.dtype, (xs.dtype, w_up.dtype)
+    ti = tile or i_tile(block, D, I, w_up.dtype.itemsize)
     assert I % ti == 0, (I, ti)
     n_i = I // ti
 
@@ -158,8 +169,7 @@ def moe_grouped_mlp(xs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
             num_scalar_prefetch=3,
             grid=(n_max, n_i),
             in_specs=[pl.BlockSpec((block, D), rows),
-                      pl.BlockSpec((None, None, D, ti), up),
-                      pl.BlockSpec((None, None, D, ti), up),
+                      *[pl.BlockSpec((None, None, D, ti), up)] * (1 + gated),
                       pl.BlockSpec((None, None, ti, D), down)],
             out_specs=pl.BlockSpec((block, D), rows)),
         out_shape=jax.ShapeDtypeStruct((n_max * block, D), jnp.float32),
@@ -170,4 +180,5 @@ def moe_grouped_mlp(xs: jax.Array, w_gate: jax.Array, w_up: jax.Array,
         interpret=interpret,
         name=NAME,
     )(n_blocks.reshape(1).astype(jnp.int32), block_e.astype(jnp.int32),
-      layer.reshape(1).astype(jnp.int32), xs, w_gate, w_up, w_down)
+      layer.reshape(1).astype(jnp.int32), xs,
+      *([w_gate] if gated else []), w_up, w_down)

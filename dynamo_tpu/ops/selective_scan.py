@@ -222,14 +222,22 @@ def selective_scan_step(pool: jax.Array, slots: jax.Array, layer: jax.Array,
 # so the ring of three slots is 12 MiB of VMEM and a copy is long enough
 # to run at the memory's rate alone. The arithmetic runs over the row in
 # lane chunks (nothing of a row's size is held beside the slot).
+#
+# With G groups (models/nemotron_h.py: 8) head h reads the B and C of
+# group h // (H / G): B and C come as [N, G] a row, N on the sublanes as
+# for one group and the groups along the lanes (a [G, N, 1] block would
+# pad every group's column to 128 lanes: 1 MiB a row-step beside the 8
+# MiB of state), and the lane chunks of group g's channels [g C / G,
+# (g + 1) C / G) read column g. The groups are a Python loop at trace
+# time; one group is the kernel as it was.
 
 SSD_NAME = "ssd_step"           # the kernel's name in a device trace
 _SSD_LANES = 512                # lanes of a row advanced at a time
 
 
 def _ssd_kernel(slots_ref, layer_ref, fresh_ref,
-                # a row's decay and dt * x [1, 1, C], B and C [1, N, 1];
-                # the pool: whole, in HBM
+                # a row's decay and dt * x [1, 1, C], B and C [1, N, 1]
+                # ([1, N, G] by group); the pool: whole, in HBM
                 dec_ref, dtx_ref, b_ref, c_ref, pool_in,
                 y_ref, pool_out, buf, sems):
     i = pl.program_id(0)
@@ -261,7 +269,9 @@ def _ssd_kernel(slots_ref, layer_ref, fresh_ref,
     copy(i, False).wait()
     k = jax.lax.rem(i, _SLOTS)
     N, C = buf.shape[1:]
-    W = math.gcd(C, _SSD_LANES)
+    G = b_ref.shape[2]
+    Cg = C // G                         # a group's channels
+    W = math.gcd(Cg, _SSD_LANES)
 
     # a chunk that starts a sequence starts from zeros, whatever the
     # slot held
@@ -269,16 +279,24 @@ def _ssd_kernel(slots_ref, layer_ref, fresh_ref,
     def _():
         buf[k] = jnp.zeros((N, C), buf.dtype)
 
-    b, c = b_ref[0], c_ref[0]                           # [N, 1]
+    def advance(b, c, first):
+        """The lane chunks of the channels [first, first + Cg) with b, c
+        [N, 1]."""
+        def lanes(j, carry):
+            at = pl.ds(pl.multiple_of(first + j * W if first else j * W, W),
+                       W)
+            s = dec_ref[0, :, at] * buf[k, :, at] + dtx_ref[0, :, at] * b
+            buf[k, :, at] = s                           # [N, W]
+            y_ref[0, :, at] = jnp.sum(s * c, axis=0, keepdims=True)
+            return carry
 
-    def lanes(j, carry):
-        at = pl.ds(pl.multiple_of(j * W, W), W)
-        s = dec_ref[0, :, at] * buf[k, :, at] + dtx_ref[0, :, at] * b
-        buf[k, :, at] = s                               # [N, W]
-        y_ref[0, :, at] = jnp.sum(s * c, axis=0, keepdims=True)
-        return carry
+        jax.lax.fori_loop(0, Cg // W, lanes, 0)
 
-    jax.lax.fori_loop(0, C // W, lanes, 0)
+    if G == 1:
+        advance(b_ref[0], c_ref[0], 0)
+    else:
+        for g in range(G):
+            advance(b_ref[0, :, g:g + 1], c_ref[0, :, g:g + 1], g * Cg)
     copy(i, True).start()
 
     @pl.when(i == steps - 1)
@@ -300,8 +318,10 @@ def ssd_step(pool: jax.Array, slots: jax.Array, layer: jax.Array,
     pool: [S, M, N, C] float32, C = heads x head channels; slots: [B]
     int32; ``layer`` a traced int32 scalar; dec = exp(dt * A) and dtx =
     dt * x, both [B, C] (a head's decay repeated over its channels); b,
-    c: [B, N], all float32; ``fresh`` [B] bool: rows that start from
-    zeros. Returns (pool, y [B, C]) with y[b, hp] = sum_n s[n, hp] c[n]:
+    c: [B, N], or by group [B, G, N] (group g's are those of channels
+    [g C / G, (g + 1) C / G)), all float32; ``fresh`` [B] bool: rows that
+    start from zeros. Returns (pool, y [B, C]) with y[b, hp] = sum_n s[n,
+    hp] c[n]:
     models/granite.py _ssd_step's arithmetic. A row with dec = 1 and dtx
     = 0 (dt = 0) leaves its state bit for bit; several such rows may
     share a slot. Rows that advance must hold distinct slots. jit-ted
@@ -314,14 +334,19 @@ def ssd_step(pool: jax.Array, slots: jax.Array, layer: jax.Array,
     def row(i, *_):
         return (i, 0, 0)
 
+    # B and C: a row's [N, 1], N on the sublanes; [N, G] by group
+    bc = pl.BlockSpec((1, N, b.shape[1] if b.ndim == 3 else 1), row)
+
+    def column(v):
+        return v[:, :, None] if v.ndim == 2 else jnp.swapaxes(v, 1, 2)
+
     y, pool = pl.pallas_call(
         _ssd_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(B,),
             in_specs=[pl.BlockSpec((1, 1, C), row),
                       pl.BlockSpec((1, 1, C), row),
-                      pl.BlockSpec((1, N, 1), row),
-                      pl.BlockSpec((1, N, 1), row),
+                      bc, bc,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec((1, 1, C), row),
                        pl.BlockSpec(memory_space=pl.ANY)],
@@ -339,5 +364,5 @@ def ssd_step(pool: jax.Array, slots: jax.Array, layer: jax.Array,
         name=SSD_NAME,
     )(slots.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
       fresh.astype(jnp.int32), dec[:, None, :], dtx[:, None, :],
-      b[:, :, None], c[:, :, None], pool)
+      column(b), column(c), pool)
     return pool, y[:, 0]

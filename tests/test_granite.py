@@ -181,7 +181,7 @@ def test_from_hf_config_on_the_catalog_config():
     assert not hasattr(granite, "init_state_snapshots")
 
     for key, value, match in [
-            ("mamba_n_groups", 2, "mamba_n_groups"),
+            ("mamba_n_groups", 3, "mamba_n_groups"),   # no divisor of 128
             ("position_embedding_type", "rope", "position_embedding_type"),
             ("mamba_proj_bias", True, "mamba_proj_bias"),
             ("mamba_d_head", 32, "mamba_n_heads x mamba_d_head"),
@@ -189,6 +189,82 @@ def test_from_hf_config_on_the_catalog_config():
             ("layer_types", ["mamba", "conv"] * 20, "layer_types")]:
         with pytest.raises(NotImplementedError, match=match):
             ModelConfig.from_hf_config(dict(published, **{key: value}))
+
+
+@pytest.mark.parametrize("program", ["chunk", "step"])
+def test_one_group_lowers_without_a_group_axis(program, monkeypatch):
+    """Since PR 60 the mixer computes B and C by group
+    (models/nemotron_h.py runs it with 8), the count taken in Python at
+    trace time: at ``mamba_n_groups`` 1, this family's published value,
+    the mixer traces what it traced before there were groups. The
+    chunked form's C . B product is ONE [B, Q, Q] matrix with the row as
+    its only batch axis, the step kernel takes B and C as [B, N, 1] ([B, N,
+    G] by group), and
+    the gated norm's one reduction is over all of d_inner; with two
+    groups (read, no longer refused) each of the three has its group
+    axis. (``tools/cell_programs.py --digest`` shows the same of cell
+    8's lowered programs, parent | change: CHANGES.md, PR 60.)"""
+    from tests.test_sampling_topk import _eqns
+
+    B, T, Q = 2, (16 if program == "chunk" else 1), 8
+    monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+
+    def traced(groups):
+        cfg = tiny(mamba_n_groups=groups)
+        assert cfg.mamba_n_groups == groups
+        assert granite.conv_width(cfg) == 128 + 2 * groups * 16
+        params = jax.eval_shape(
+            lambda: granite.init_params(cfg, jax.random.PRNGKey(0)))
+        mp = {k: jax.ShapeDtypeStruct(params[k].shape[1:], params[k].dtype)
+              for k in granite.MAMBA2_KEYS}
+        s = jax.ShapeDtypeStruct
+        pool = s((5, 1, 16, 128), jnp.float32)
+        tails = s((1, B, 3 * granite.conv_width(cfg)), jnp.float32)
+
+        def step_mixer(mp, u, valid, pool, tails):
+            return granite._mamba2(
+                cfg, mp, u, valid, pool, tails,
+                lambda pool, *row: ssd_step(
+                    pool, jnp.arange(B), jnp.int32(0), *row, None,
+                    interpret=True),
+                tail_step=lambda t, *row: jamba.conv_tail_step(
+                    t, jnp.int32(0), *row, interpret=True))
+
+        def chunk_mixer(mp, u, valid, s0, tail):
+            return granite._mamba2(cfg, mp, u, valid, s0, tail)
+
+        if program == "step":
+            args = (mp, s((B, 1, 64), jnp.float32), s((B, 1), jnp.bool_),
+                    pool, tails)
+            return list(_eqns(jax.make_jaxpr(step_mixer)(*args).jaxpr))
+        args = (mp, s((B, T, 64), jnp.float32), s((B, T), jnp.bool_),
+                s((B, 16, 128), jnp.float32), tails.update(
+                    shape=(B, 3 * granite.conv_width(cfg))))
+        return list(_eqns(jax.make_jaxpr(chunk_mixer)(*args).jaxpr))
+
+    def shapes_of(eqns, name):
+        return [tuple(v.aval.shape for v in e.invars)
+                + tuple(v.aval.shape for v in e.outvars)
+                for e in eqns if e.primitive.name == name]
+
+    one, two = traced(1), traced(2)
+    if program == "chunk":
+        cb = lambda eqns: [s for s in shapes_of(eqns, "dot_general")
+                           if s[-1][-2:] == (Q, Q)]
+        assert [s[-1] for s in cb(one)] == [(B, Q, Q)]
+        assert [s[-1] for s in cb(two)] == [(B, 2, Q, Q)]
+    else:
+        kernel = lambda eqns: [s for s in shapes_of(eqns, "pallas_call")
+                               if (5, 1, 16, 128) in s]
+        assert (B, 16, 1) in kernel(one)[0]
+        assert (B, 16, 2) in kernel(two)[0]         # [N, G] a row
+        assert (B, 16, 1) not in kernel(two)[0]
+    # the gated norm's mean of squares: over d_inner, or a group's half
+    widths = lambda eqns: {s[0][-1] for s in shapes_of(eqns, "reduce_sum")
+                           if len(s[0]) >= 3 and s[0][-1] in (64, 128)
+                           and s[0][:2] == (B, T)}
+    assert 128 in widths(one) and 64 not in widths(one)
+    assert 64 in widths(two)
 
 
 @pytest.mark.parametrize("tied,n_prompt,interpret", [

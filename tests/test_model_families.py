@@ -37,6 +37,8 @@ CELLS = {
     "kimi-linear-48b-a3b": ("kimi_linear", "kimi_linear"),
     "lfm2-24b-a2b": ("lfm2", "lfm2"),
     "mixtral-8x7b": ("llama", "llama"),
+    # since PR 60 (a family of its own, asked before granite's)
+    "nemotron-3-super-120b-a12b": ("nemotron_h", "nemotron_h"),
     "qwen3-30b-a3b": ("llama", "llama"),
     "sdar-30b-a3b-chat": ("llama_by_blocks", "llama"),
     "smallthinker-21b-a3b": ("llama_by_kind", "llama"),
@@ -64,6 +66,8 @@ TINY = {
     "kimi_linear": dict(kda_n_heads=2, kv_lora_rank=16),
     "solar_open2": dict(kda_n_heads=2),
     "mla": dict(kv_lora_rank=16),
+    "nemotron_h": dict(mamba_n_heads=2, mamba_d_state=4,
+                       moe_latent_size=8),
     "granite": dict(mamba_n_heads=2, mamba_d_state=4),
     "lfm2": dict(layer_types=("conv", "full_attention")),
     "jamba": dict(mamba_d_state=4, mamba_dt_rank=4, attn_layer_period=2,

@@ -981,6 +981,24 @@ def test_ssd_step_kernel_compiles(one_chip, B):
         s((B,), jnp.bool_)).compile())
 
 
+@pytest.mark.parametrize("B", [128, 8, 1])
+def test_ssd_step_kernel_by_groups_compiles(one_chip, B):
+    """ssd_step with B and C by group at the pool and the three batch
+    buckets of nemotron-3-super-120b-a12b.agent-reason (8 groups of 16
+    heads: 1,024 channels a group, two lane chunks): the chip's compiler
+    takes B and C as [N, G] a row and the static lane slice that picks a
+    group's column."""
+    from dynamo_tpu.ops.selective_scan import ssd_step
+
+    s = partial(_sds, one_chip)
+    S, M, N, C, G = 129, 5, 128, 8192, 8
+    f32 = jnp.float32
+    assert _has_kernel(ssd_step.lower(
+        s((S, M, N, C), f32), s((B,), jnp.int32), s((), jnp.int32),
+        s((B, C), f32), s((B, C), f32), s((B, G, N), f32),
+        s((B, G, N), f32), s((B,), jnp.bool_)).compile())
+
+
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_granite_programs_write_no_array_of_the_state_pools_size(
         one_chip, tpu_kernel_path, program):
