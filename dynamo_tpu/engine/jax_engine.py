@@ -471,7 +471,7 @@ class _PendingWindow:
     processed: bool = False
 
 
-@dataclass
+@dataclass(eq=False)  # identity: two mid-prompt markers are not one
 class _PendingPrefill:
     """A dispatched-but-unread prefill batch: ``sampled`` is the on-device
     first-token draw for rows that completed their prompt this chunk
@@ -739,7 +739,9 @@ class JaxEngine:
         # old window still writes it)
         self._inflight: List[_PendingWindow] = []
         self._pending: Optional[_PendingWindow] = None
-        self._pending_prefill: Optional[_PendingPrefill] = None
+        # the prefills not read back yet, in the order they were enqueued:
+        # at most two (_step_window)
+        self._pending_prefills: List[_PendingPrefill] = []
         self._deferred_free: List[Sequence] = []
         # (key, SamplingBatch, device arrays) of the last decode-window
         # dispatch, reused while its key holds (_dispatch_decode_window)
@@ -830,6 +832,9 @@ class JaxEngine:
         # of those, the dispatches behind which the same iteration
         # enqueued a decode window (_step_window, rule 2)
         self.prefill_window_topups_total = 0
+        # and those enqueued in the same iteration as the prefill before
+        # them (_step_window, rule 1)
+        self.prefill_runahead_total = 0
         # a row's chunks, and those that start past position 0: from
         # what the row's earlier chunks left (pages, and for a model
         # with recurrent state the state in its slot)
@@ -1437,7 +1442,7 @@ class JaxEngine:
 
         def busy() -> bool:
             return bool(self.waiting or self.prefilling or self.running
-                        or self._inflight or self._pending_prefill)
+                        or self._inflight or self._pending_prefills)
 
         while busy() and loop.time() < deadline:
             await asyncio.sleep(0.02)
@@ -1559,6 +1564,7 @@ class JaxEngine:
             "prefill_dispatches_total": self.prefill_dispatches_total,
             "prefill_window_topups_total":
                 self.prefill_window_topups_total,
+            "prefill_runahead_total": self.prefill_runahead_total,
             "prefill_row_chunks_total": self.prefill_row_chunks_total,
             "prefill_row_chunks_carried_total":
                 self.prefill_row_chunks_carried_total,
@@ -1740,7 +1746,7 @@ class JaxEngine:
         # yield of its own here
         while not self._stopped:
             if not (self.waiting or self.prefilling or self.running
-                    or self._inflight or self._pending_prefill):
+                    or self._inflight or self._pending_prefills):
                 led.leave("engine_loop")
                 self._wake.clear()
                 self.profiler.slept = True   # the gap to the next step is idle
@@ -1765,7 +1771,7 @@ class JaxEngine:
                 await loop.run_in_executor(self._exec, self._abort_all)
         led.leave("engine_loop")
         # shutdown: drain in-flight windows so no client hangs on a queue
-        if self._inflight or self._pending_prefill:
+        if self._inflight or self._pending_prefills:
             try:
                 await loop.run_in_executor(self._exec, self._flush_pipeline)
             except Exception:  # noqa: BLE001
@@ -1814,49 +1820,73 @@ class JaxEngine:
         the next prefill chunk BEFORE reading back the previous ones, so
         the host round-trip overlaps device compute (the on-device carry
         makes this exact, not speculative). In order: dispatch, admit,
-        read back what the last iteration dispatched, free deferred
+        read back what earlier iterations dispatched, free deferred
         pages.
 
         Prefill priority (``prefill_token_budget`` None), by what the
         iteration can observe:
 
-        1. with something to prefill it enqueues the prefill first;
-        2. after that dispatch and admission, if NOTHING is left to
-           prefill, it enqueues the next window behind the prefill, its
-           rows taken from the in-flight window's carry as in any steady
-           iteration (``prefill_window_topups_total``); if something is
-           left (another chunk, a prompt admitted meanwhile, rows the
-           bucket choice held back) it enqueues no window and the next
+        1. with something to prefill it enqueues the prefill first, and
+           after that dispatch and admission, if something is STILL left
+           (another chunk, a prompt admitted meanwhile, rows the bucket
+           choice held back) and no earlier prefill is un-read, the next
+           prefill at once, behind it (``prefill_runahead_total``): a
+           dispatch is formed from host state that the dispatch before it
+           left current, and needs none of its results. No window lies
+           between two prefills. At most two prefills are un-read at any
+           time (the one an iteration ships and one ahead): the iteration
+           after such a pair reads the older back before it ships, and
+           the newer at its end, so the run-ahead engages at the first
+           iteration of a run of prefills and every later one ships one.
+           The bound is the host's bookkeeping: a mid-prompt chunk has
+           nothing to read and counts as read once visited, so more than
+           two programs may lie unfinished on the device;
+        2. after the last of these dispatches and its admission, if
+           NOTHING is left to prefill, it enqueues the next window behind
+           the prefill (``prefill_window_topups_total``). Every earlier
+           prefill is read back before that window is built, so its rows
+           decode in it; behind a single prefill the window's rows come
+           from the in-flight window's carry as in any steady iteration,
+           behind two the in-flight window (it ended before the first of
+           them began) is read back first and the rows come from the
+           host. If something is left it enqueues no window and the next
            iteration ships the next prefill;
         3. the order of programs on the device is the one an engine
-           without (2) gives the same arrivals: the window only reaches
-           the queue one host iteration sooner. So the iteration after
-           such a pair first reads the prefill back (it lies AHEAD of the
+           without the run-ahead of (1) and the window of (2) gives the
+           same admissions: a program only reaches the queue one host
+           iteration sooner. So the iteration after a window behind a
+           prefill first reads the prefill back (it lies AHEAD of the
            window, and its rows enter ``running``) and admits, and then
            ships a prefill if one is now due, else the next window; it
            reads the topped-up window back only after that."""
         prev = self._pending
-        prev_pf = self._pending_prefill
+        unread = self._pending_prefills
+        earlier = list(unread)
         budget = self.ecfg.prefill_token_budget
-        if budget is None and prev is not None and prev_pf is not None:
-            # the last iteration shipped prev behind prev_pf (rule 3)
-            self._process_prefill(prev_pf)
+        if budget is None and prev is not None and earlier:
+            # the last iteration shipped prev behind its last prefill
+            # (rule 3)
+            self._read_prefills(earlier)
             self._admit()
         if budget is None and self.prefilling:
-            self._pending_prefill = self._dispatch_prefill(None)
-            # admission's host work overlaps the device; admitted
-            # sequences enter prefilling for the next sweep
-            self._admit()
-            if self._pending_prefill is None:
+            # the last iteration's run-ahead left two: the older, ended
+            # on the device by now, is read before a third ships
+            self._read_prefills(unread[:-1])
+            shipped = self._ship_prefill()
+            if shipped and self.prefilling and len(unread) < 2:
+                self.prefill_runahead_total += self._ship_prefill()
+            if not shipped:
                 # the sweep shipped NOTHING (every candidate
                 # restore-gated, cancelled, or cache-covered): the device
                 # would idle a whole iteration, a decode window fills it
                 self._pending = self._dispatch_decode_window()
             elif not self.prefilling:
-                # rule 2. The rows of the prefill before this one, long
-                # done on the device, decode in this window too
-                if prev_pf is not None:
-                    self._process_prefill(prev_pf)
+                # rule 2. The rows of the prefills before the last one,
+                # long done on the device, decode in this window too; the
+                # window in flight ended before the first of two began
+                if prev is not None and len(unread) > 1:
+                    self._process_window(prev)
+                self._read_prefills(unread[:-1])
                 self._pending = self._dispatch_decode_window()
                 self.prefill_window_topups_total += self._pending is not None
             else:
@@ -1865,21 +1895,36 @@ class JaxEngine:
             # budgeted mixing (or nothing to prefill): decode windows
             # keep their cadence even while prompts are prefilling
             self._pending = self._dispatch_decode_window()
-            self._pending_prefill = self._dispatch_prefill(budget)
-            if (self._pending is not None
-                    and self._pending_prefill is not None):
-                self.mixed_dispatches += 1
+            pf = self._dispatch_prefill(budget)
+            if pf is not None:
+                unread.append(pf)
+                self.mixed_dispatches += self._pending is not None
             self._admit()
         if prev is not None:
             self._process_window(prev)
-        if prev_pf is not None:
-            self._process_prefill(prev_pf)
+        self._read_prefills(earlier)
         self._drain_deferred()
         # idle drain: with no live work left, read back the remaining
         # windows now so final tokens/finishes emit and pages free
         if (not (self.running or self.prefilling or self.waiting)
-                and (self._inflight or self._pending_prefill)):
+                and (self._inflight or unread)):
             self._flush_pipeline()
+
+    def _ship_prefill(self) -> bool:
+        """One prefill of the priority arm, un-read, and the admission
+        behind it: admission's host work overlaps the device, and what it
+        admits enters ``prefilling`` for the next sweep. False when the
+        sweep shipped nothing."""
+        pf = self._dispatch_prefill(None)
+        if pf is not None:
+            self._pending_prefills.append(pf)
+        self._admit()
+        return pf is not None
+
+    def _read_prefills(self, pfs: List[_PendingPrefill]) -> None:
+        """Read back ``pfs``, oldest first (read ones are skipped)."""
+        for pf in pfs:
+            self._process_prefill(pf)
 
     def _flush_pipeline(self) -> None:
         """Synchronize: read back every in-flight window/prefill so host
@@ -1889,17 +1934,15 @@ class JaxEngine:
         for w in list(self._inflight):
             self._process_window(w)
         self._pending = None
-        if self._pending_prefill is not None:
-            self._process_prefill(self._pending_prefill)
-            self._pending_prefill = None
+        self._read_prefills(list(self._pending_prefills))
         self._drain_deferred()
 
     def _abort_all(self) -> None:
         """Error path: drop pipeline state, release everything, fail all
         in-flight requests (the loop itself must survive). Covers the
-        sequences parked OUTSIDE prefilling/running: deferred frees and a
-        pending prefill's finishing rows — dropping either would hang its
-        client on a queue that never sees a finish_reason."""
+        sequences parked OUTSIDE prefilling/running: deferred frees and
+        every un-read prefill's finishing rows — dropping either would
+        hang its client on a queue that never sees a finish_reason."""
         try:
             jax.block_until_ready(self.kv_k)
         except Exception:  # noqa: BLE001
@@ -1913,11 +1956,11 @@ class JaxEngine:
             pass
         self._offload_inflight.clear()
         parked = list(self._deferred_free)
-        if self._pending_prefill is not None:
-            parked += [s for _, s in self._pending_prefill.finishing]
+        for pf in self._pending_prefills:
+            parked += [s for _, s in pf.finishing]
         self._inflight.clear()
         self._pending = None
-        self._pending_prefill = None
+        self._pending_prefills.clear()
         self._deferred_free.clear()
         for seq in parked + self.prefilling + self.running:
             self._release(seq)
@@ -2458,6 +2501,8 @@ class JaxEngine:
         if pf.processed:
             return
         pf.processed = True
+        if pf in self._pending_prefills:
+            self._pending_prefills.remove(pf)
         if not pf.finishing:
             return      # a mid-prompt chunk: nothing to read back
         with self.profiler.phase("readback_prefill"):

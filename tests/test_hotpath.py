@@ -141,15 +141,23 @@ def _submit(eng, req):
 def _spy_dispatches(eng):
     """Per _step: which of the two dispatches were called and shipped.
     ``stepped.order`` has a string an iteration of what it shipped, in
-    the order it reached the device's queue: P a prefill, W a window."""
-    log, order = [], []
+    the order it reached the device's queue: P a prefill, W a window;
+    ``stepped.queue(start)`` joins them: the order on the device's queue
+    from iteration ``start`` on, whatever iteration shipped what. Every
+    prefill dispatch checks that at most one prefill before it is still
+    un-read: never three."""
+    log, order, prefills = [], [], []
     for kind, mark in (("_dispatch_prefill", "P"),
                        ("_dispatch_decode_window", "W")):
         def spy(*a, _fn=getattr(eng, kind), _kind=kind, _mark=mark, **kw):
+            if _mark == "P":
+                assert stepped.unread() <= 1, "a third prefill un-read"
             out = _fn(*a, **kw)
-            log[-1][_kind] = out is not None
+            log[-1][_kind] = log[-1].get(_kind, False) or out is not None
             if out is not None:
                 order[-1] += _mark
+                if _mark == "P":
+                    prefills.append(out)
             return out
         setattr(eng, kind, spy)
     step = eng._step
@@ -159,9 +167,12 @@ def _spy_dispatches(eng):
         order.append("")
         step()
         eng._reap()
+        assert stepped.unread() == len(eng._pending_prefills) <= 2
         return log[-1]
 
     stepped.order = order
+    stepped.queue = lambda start=0: "".join(order[start:])
+    stepped.unread = lambda: sum(not pf.processed for pf in prefills)
     return stepped, log
 
 
@@ -173,46 +184,64 @@ def _step_until(stepped, cond, limit=64):
     raise AssertionError("condition not reached")
 
 
+def _decoding_row(mt=40, **kw):
+    """An engine with one row mid-decode (a window in flight), its spy,
+    and the row."""
+    eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4, **kw), seed=0)
+    stepped, _ = _spy_dispatches(eng)
+    first = _submit(eng, _req(range(1, 9), mt=mt))
+    _step_until(stepped, lambda: first.generated >= 5)
+    assert eng.prefill_window_topups_total == 0, "nothing decoded beside it"
+    assert eng.prefill_runahead_total == 0
+    return eng, stepped, first
+
+
+def _in_one_of(eng, seq):
+    """Where the scheduler holds a live sequence: exactly one place."""
+    return [name for name in ("waiting", "prefilling", "running")
+            if seq in getattr(eng, name)]
+
+
 @pytest.mark.parametrize("case", ["prompt_waiting", "nothing_waiting"])
 def test_a_prefill_iteration_ships_a_window_only_behind_the_last_prefill(
         case):
     """Prefill priority (prefill_token_budget None), with a row decoding.
     While a prefill is still due after the one just shipped, the
-    iteration ships no window; when nothing is left to prefill it ships
-    the prefill THEN a window behind it, and the next iteration reads the
-    prefill back before it ships anything else."""
+    iteration ships that prefill too and no window between them; when
+    nothing is left to prefill it ships a window behind the last prefill,
+    and the next iteration reads that prefill back before it ships
+    anything else. On the device's queue: P P W W with a second prompt
+    waiting, P W W without."""
     waiting = case == "prompt_waiting"
     # a prefill dispatch of one row: of two prompts admitted together
     # the second is still due after the first ships
-    eng = JaxEngine(ModelConfig.tiny(), _ecfg(
-        decode_steps=4, max_prefill_batch=1 if waiting else 8), seed=0)
-    stepped, log = _spy_dispatches(eng)
-    first = _submit(eng, _req(range(1, 9), mt=40))
-    _step_until(stepped, lambda: first.generated >= 5)
-    assert eng.prefill_window_topups_total == 0, "nothing decoded beside it"
+    eng, stepped, first = _decoding_row(
+        max_prefill_batch=1 if waiting else 8)
     second = _submit(eng, _req(range(20, 40), mt=4))
     third = _submit(eng, _req(range(50, 70), mt=4)) if waiting else None
     _step_until(stepped, lambda: second in eng.prefilling)
     if waiting:
         assert third in eng.prefilling
-        assert stepped() == {"running": 1, "_dispatch_prefill": True}
-        assert stepped.order[-1] == "P" and eng.prefilling == [third]
-        assert eng.prefill_window_topups_total == 0
+    start = len(stepped.order)
     it = stepped()
     assert it == {"running": 1, "_dispatch_prefill": True,
                   "_dispatch_decode_window": True}
-    assert stepped.order[-1] == "PW" and not eng.prefilling
+    assert stepped.order[-1] == ("PPW" if waiting else "PW")
+    assert not eng.prefilling
     assert eng.prefill_window_topups_total == 1
     assert eng.stats()["prefill_window_topups_total"] == 1
+    assert eng.stats()["prefill_runahead_total"] == int(waiting)
     last = third if waiting else second
-    assert last not in eng.running, "the prefill is not read back yet"
-    # the prefill BEFORE the pair's was read back first: its row decodes
-    # in the window behind the pair, as it would one iteration later
+    assert last not in eng.running, "the last prefill is not read back yet"
+    assert stepped.unread() == 1
+    # the prefill BEFORE the last was read back first: its row decodes
+    # in the window behind the run, as it would an iteration later
     assert eng._pending.batch == ([first, second] if waiting else [first])
-    # the iteration after the pair: one window, and the prefill's row is
-    # in it, so the prefill was read back before that window shipped
+    # the iteration after: one window, and the last prefill's row is in
+    # it, so the prefill was read back before that window shipped
     stepped()
-    assert stepped.order[-1] == "W"
+    assert stepped.order[start:] == ["PPW" if waiting else "PW", "W"]
+    assert stepped.queue(start) == ("PPWW" if waiting else "PWW")
     assert [s for s in eng._pending.batch if s is not first] == (
         [second, third] if waiting else [second])
     _step_until(stepped, lambda: all(
@@ -221,6 +250,9 @@ def test_a_prefill_iteration_ships_a_window_only_behind_the_last_prefill(
     assert first.generated == 40 and second.generated == 4
     assert eng.prefill_dispatches_total == (3 if waiting else 2)
     assert eng.prefill_window_topups_total == 1
+    # no prefill ever followed a prefill where nothing waited
+    assert eng.prefill_runahead_total == int(waiting)
+    assert eng.prefill_runahead_total < eng.prefill_dispatches_total
 
 
 def test_the_order_of_programs_is_the_one_without_the_top_up():
@@ -228,10 +260,7 @@ def test_the_order_of_programs_is_the_one_without_the_top_up():
     queue an iteration sooner and nothing else moves. A prompt that lands
     in the iteration after the pair is served before any second window:
     P W P' W, never P W W P'."""
-    eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
-    stepped, log = _spy_dispatches(eng)
-    first = _submit(eng, _req(range(1, 9), mt=48))
-    _step_until(stepped, lambda: first.generated >= 5)
+    eng, stepped, first = _decoding_row(mt=48)
     second = _submit(eng, _req(range(20, 40), mt=8))
     _step_until(stepped, lambda: second in eng.prefilling)
     start = len(stepped.order)
@@ -239,39 +268,171 @@ def test_the_order_of_programs_is_the_one_without_the_top_up():
     third = _submit(eng, _req(range(50, 70), mt=8))
     stepped()                                   # P' W
     assert stepped.order[start:] == ["PW", "PW"]
+    assert stepped.queue(start) == "PWPW"
     assert second in eng.running and third not in eng.running
     assert eng._pending.batch == [first, second]
     stepped()                                   # third's row joins
     assert stepped.order[-1] == "W"
     assert eng._pending.batch == [first, second, third]
     assert eng.prefill_window_topups_total == 2
+    assert eng.prefill_runahead_total == 0, "no prefill followed a prefill"
     _step_until(stepped, lambda: first.finished and second.finished
                 and third.finished)
     assert (first.generated, second.generated, third.generated) == (48, 8, 8)
 
 
-def test_no_window_between_the_chunks_of_one_prompt():
-    """The same arrival with a prompt of two chunks: P1 P2 W."""
-    eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=4), seed=0)
-    stepped, log = _spy_dispatches(eng)
-    first = _submit(eng, _req(range(1, 9), mt=40))
-    _step_until(stepped, lambda: first.generated >= 5)
-    second = _submit(eng, _req(range(20, 60), mt=4))     # 40 > chunk 32
+@pytest.mark.parametrize("chunks,want", [
+    (2, ["PPW"]), (3, ["PP", "PW"]), (5, ["PP", "P", "P", "PW"])])
+def test_no_window_between_the_chunks_of_one_prompt(chunks, want):
+    """The same arrival with a prompt of several chunks beside a decoding
+    row: P1 .. Pn W on the queue. The first iteration of the run ships
+    two and leaves both un-read; every later one reads the older back,
+    ships one and reads the other at its end, and the window comes behind
+    the last (never three prefills un-read: _spy_dispatches)."""
+    eng, stepped, first = _decoding_row(num_pages=128, page_buckets=(64,))
+    n = 32 * (chunks - 1) + 8
+    second = _submit(eng, _req(range(20, 20 + n), mt=4))
     _step_until(stepped, lambda: second in eng.prefilling)
     start = len(stepped.order)
+    for i, shipped in enumerate(want[:-1]):
+        stepped()
+        assert stepped.order[-1] == shipped
+        assert stepped.unread() == (2 if i == 0 else 1)
     _step_until(stepped, lambda: second in eng.running)
-    assert stepped.order[start:start + 2] == ["P", "PW"]
+    assert stepped.order[start:start + len(want)] == want
+    assert stepped.queue(start).startswith("P" * chunks + "W")
     assert eng.prefill_window_topups_total == 1
+    # the one dispatch that shared an iteration with the one before it
+    assert eng.prefill_runahead_total == 1
     _step_until(stepped, lambda: first.finished and second.finished)
     assert first.generated == 40 and second.generated == 4
+    assert eng.prefill_dispatches_total == 1 + chunks
 
 
+def test_a_row_the_bucket_choice_held_back_rides_the_same_iteration():
+    """Two prompts admitted together under a table that ships one row a
+    program (choose_prefill_bucket holds the second back): P P W in ONE
+    iteration, and the first prompt's row decodes in that W."""
+    eng, stepped, first = _decoding_row(batch_buckets=(1, 4))
+    eng._prefill_costs = {(1, 32): (1.0, 1.0), (4, 32): (4.4, 4.5)}
+    second = _submit(eng, _req(range(20, 40), mt=4))
+    third = _submit(eng, _req(range(50, 70), mt=4))
+    _step_until(stepped, lambda: third in eng.prefilling)
+    assert second in eng.prefilling
+    stepped()
+    assert stepped.order[-1] == "PPW"
+    assert eng.prefill_rows_held_back_total == 1
+    assert eng.prefill_runahead_total == 1
+    assert eng._pending.batch == [first, second]
+    assert second.generated == 1 and third.generated == 0
+    _step_until(stepped, lambda: first.finished and second.finished
+                and third.finished)
+    assert (first.generated, second.generated, third.generated) == (40, 4, 4)
+
+
+def test_a_prompt_admitted_behind_a_finishing_prefill_rides_the_same_iteration():
+    """A prefill whose row finishes its prompt (and draws its first
+    token) with the next prompt still WAITING: the admission behind the
+    first dispatch brings it in and its prefill follows at once
+    (sample_tokens then a prefill: no host iteration between them)."""
+    eng, stepped, first = _decoding_row()
+    second = _submit(eng, _req(range(20, 40), mt=4))
+    _step_until(stepped, lambda: second in eng.prefilling)
+    third = _submit(eng, _req(range(50, 70), mt=4))
+    assert third in eng.waiting
+    stepped()
+    assert stepped.order[-1] == "PPW"
+    assert eng.prefill_runahead_total == 1
+    assert eng._pending.batch == [first, second]
+    assert third not in eng.running and stepped.unread() == 1
+    stepped()
+    assert stepped.order[-1] == "W"
+    assert eng._pending.batch == [first, second, third]
+    _step_until(stepped, lambda: first.finished and second.finished
+                and third.finished)
+    assert (first.generated, second.generated, third.generated) == (40, 4, 4)
+
+
+@pytest.mark.parametrize("how", ["flush", "abort", "drain", "preempt"])
+def test_two_prefills_out_are_both_settled(how, run_async):
+    """Prompts that ship one row a program beside a decoding row. With
+    four, the first iteration ships two prefills and leaves both un-read,
+    each with a row that finished its prompt: _flush_pipeline reads both
+    back, _abort_all fails both rows with everyone else, drain() waits
+    for both. With two, the same iteration ships the window behind them,
+    and a pool that runs out under that window (the flush inside
+    _grow_or_preempt, the second prefill and the window in flight still
+    out) loses nobody: every live row is in exactly one of waiting /
+    prefilling / running."""
+    eng, stepped, first = _decoding_row(max_prefill_batch=1, max_batch=8,
+                                        batch_buckets=(8,))
+    n = 2 if how == "preempt" else 4
+    rest = [_submit(eng, _req(range(20 * i, 20 * i + 20), mt=4))
+            for i in range(1, n + 1)]
+    everyone = [first] + rest
+    _step_until(stepped, lambda: rest[-1] in eng.prefilling)
+    if how == "preempt":
+        grow, fails = eng.pm.grow, [2]
+
+        def short(pages, target):
+            # the first row the window grows finds the pool empty, and
+            # again after the flush: the newest arrival is preempted
+            if fails[0] and stepped.order[-1] == "PP":
+                fails[0] -= 1
+                return False
+            return grow(pages, target)
+
+        eng.pm.grow = short
+        stepped()
+        assert stepped.order[-1] == "PPW" and fails == [0]
+        assert not eng._pending_prefills, "the flush read the second too"
+        # both prompts were prefilled; the newest went back to waiting
+        assert [_in_one_of(eng, s) for s in everyone] == [
+            ["running"], ["running"], ["waiting"]]
+        assert eng._pending.batch == [first, rest[0]]
+    else:
+        stepped()
+        assert stepped.order[-1] == "PP" and eng.prefilling == rest[2:]
+        assert [[s for _, s in pf.finishing]
+                for pf in eng._pending_prefills] == [rest[:1], rest[1:2]]
+        assert all(not _in_one_of(eng, s) for s in rest[:2]), "parked"
+    if how == "flush":
+        eng._flush_pipeline()
+        assert not eng._pending_prefills and not eng._inflight
+        assert eng.running == [first] + rest[:2]
+    elif how == "abort":
+        eng._abort_all()
+        assert not eng._pending_prefills and not eng._inflight
+        assert [s.finished for s in everyone] == ["error"] * 5
+        assert all(s.finish_emitted for s in everyone)
+        assert not (eng.running or eng.prefilling or eng.waiting)
+        assert eng.pm.active == 0
+        return
+    elif how == "drain":
+        async def main():
+            eng.start()
+            drained = await eng.drain(timeout_s=60.0)
+            await eng.stop()
+            return drained
+        assert run_async(main()) is True
+    if how != "drain":
+        _step_until(stepped, lambda: all(s.finished for s in everyone))
+    assert [s.generated for s in everyone] == [40] + [4] * n
+    assert all(s.finish_emitted for s in everyone)
+    assert not eng._pending_prefills and eng.pm.active == 0
+
+
+@pytest.mark.parametrize("chunks", [1, 2, 3])
 @pytest.mark.parametrize("kind", ["greedy", "seeded", "penalties"])
-def test_window_behind_a_prefill_matches_single_step(kind, monkeypatch):
-    """A prompt arrives while a row of the pinned kind is mid-decode: the
-    window shipped behind its prefill takes the decoding row from the
-    in-flight window's carry (_merge_carry), or, for a row with sampling
-    penalties, lands that window first. Both rows get the tokens that
+def test_window_behind_a_prefill_matches_single_step(kind, chunks,
+                                                     monkeypatch):
+    """A prompt of one, two or three chunks arrives while a row of the
+    pinned kind is mid-decode. Behind a single prefill the window takes
+    the decoding row from the in-flight window's carry (_merge_carry),
+    or, for a row with sampling penalties, lands that window first;
+    behind a run of prefills (the second enqueued in the iteration that
+    shipped the first, a third in the next) the in-flight window is read
+    back before the window is built. Both rows get the tokens that
     single steps give them."""
     from dynamo_tpu.engine import jax_engine
     merge = jax_engine._merge_carry
@@ -285,14 +446,15 @@ def test_window_behind_a_prefill_matches_single_step(kind, monkeypatch):
     monkeypatch.setattr(jax_engine, "_merge_carry", counted)
 
     def gen(k):
-        eng = JaxEngine(ModelConfig.tiny(), _ecfg(decode_steps=k), seed=0)
+        eng = JaxEngine(ModelConfig.tiny(), _ecfg(
+            decode_steps=k, num_pages=128, page_buckets=(32,)), seed=0)
         stepped, _ = _spy_dispatches(eng)
         orders.append(stepped.order)
         req = _mixed_requests()[kind]
         req.stop.max_tokens = 24
         first = _submit(eng, req)
         _step_until(stepped, lambda: first.generated >= 5)
-        second = _submit(eng, _req(range(20, 40), mt=6))
+        second = _submit(eng, _req(range(20, 32 * chunks + 8), mt=6))
         _step_until(stepped, lambda: first.finished and second.finished)
         return [s.tokens[s.num_prompt:] for s in (first, second)], eng
 
@@ -302,9 +464,11 @@ def test_window_behind_a_prefill_matches_single_step(kind, monkeypatch):
     assert window == single
     assert [len(t) for t in window] == [24, 6]
     assert eng.prefill_window_topups_total == 1
+    assert eng.prefill_runahead_total == min(chunks, 2) - 1
     # order[0] is the first prompt's own prefill, with nothing to decode
-    pair = orders[-1].index("PW") + 1
-    assert (pair in merged) == (kind != "penalties")
+    pair = orders[-1].index({1: "PW", 2: "PPW", 3: "PW"}[chunks]) + 1
+    assert chunks < 3 or orders[-1][pair - 2] == "PP"
+    assert (pair in merged) == (kind != "penalties" and chunks == 1)
 
 
 def test_a_sweep_that_ships_nothing_still_ships_a_window():
