@@ -165,7 +165,8 @@ class EngineConfig:
     # diffusion over blocks (ModelConfig.block_length L > 1) this is the
     # number of TOKENS a row can emit in one window, a multiple of L: the
     # window generates decode_steps / L whole blocks, each in up to
-    # denoising_steps + 1 forwards of L positions a row.
+    # denoising_steps forwards a row (the first of them 2L positions
+    # wide: the block before rides it, llama._make_block_window_fn).
     decode_steps: int = 4
     # the prefill policy of an iteration, one field. None is prefill
     # priority: an iteration that ships a prefill batch ships no decode
@@ -287,7 +288,7 @@ class EngineConfig:
 
 # the columns of the block window's per-row counts, in the order
 # llama._make_block_window_fn returns them ([B, 5])
-_BLOCK_WINDOW_COUNTS = ("blocks", "forwards", "commit_forwards",
+_BLOCK_WINDOW_COUNTS = ("blocks", "forwards", "folded_commits",
                         "early_exits", "dropped_tokens")
 
 
@@ -531,7 +532,11 @@ class JaxEngine:
         # the one refusal made by the request (generate): None where
         # the family serves penalties
         self._penalty_refusal = fam.refusal("sampling_penalty")
-        self.diffusion = dict.fromkeys(_BLOCK_WINDOW_COUNTS + ("tokens",), 0)
+        # commit_forwards: forwards that only commit a block. The window
+        # has had none since a block's K/V ride the next block's first
+        # forward; the name stays in stats() for who reads it, at 0
+        self.diffusion = dict.fromkeys(
+            _BLOCK_WINDOW_COUNTS + ("commit_forwards", "tokens"), 0)
         # what the family's decode window counts by itself and returns
         # before the state (routed and held expert pairs,
         # models/granite.py); summed into stats()
@@ -970,11 +975,12 @@ class JaxEngine:
 
     def _blank_tokens(self, n: int) -> np.ndarray:
         """The window's token operand of n rows that carry nothing: one
-        id a row, or for a model that generates by blocks a block a row
-        with every position masked (-1)."""
+        id a row, or for a model that generates by blocks two blocks a
+        row, no pending block (-1) beside an open one with every
+        position masked (-1)."""
         if self.block == 1:
             return np.zeros(n, np.int32)
-        return np.full((n, self.block), -1, np.int32)
+        return np.full((n, 2 * self.block), -1, np.int32)
 
     def _take_window(self, out, topn: int) -> WindowResults:
         """A window program's results by name (models/window.py knows
@@ -1680,8 +1686,12 @@ class JaxEngine:
     def _diffusion_stats(self) -> dict:
         """stats() of a model that generates by diffusion over blocks
         (none for any other): blocks started by a live row, forwards a
-        row went through (denoising + commit) and the commit forwards
-        among them, tokens emitted, tokens generated past a stop id or
+        row went through (the denoising forwards: a block of 4 costs 4,
+        and the first of them carries the block before it as well, whose
+        K/V it makes final: ``folded_commits``, over ``blocks`` the share
+        of blocks committed so; ``commit_forwards``, forwards that only
+        commit, stays 0: the window has none), tokens emitted, tokens
+        generated past a stop id or
         the budget inside a block and dropped, blocks that finished in
         fewer denoising forwards than their schedule. Summed from the
         window program's own counts at read-back (_process_window);
@@ -2799,10 +2809,11 @@ class JaxEngine:
         (token, position, done, step, budget) state from the on-device
         carry — the host's lagging view never enters the feedback loop —
         while newly admitted rows are seeded from host state. For a model
-        that generates by blocks the carry's token is a block a row
-        ([B, L], -1 = masked) and its position the block's start: a
-        carried row starts a fresh block, a host-seeded one the block
-        its tail opens. ``batch``
+        that generates by blocks the carry's token is two blocks a row
+        ([B, 2L]: the pending block, whose K/V the window's first
+        forward makes final, beside the open one, -1 = masked) and its
+        position the open block's start: a carried row starts a fresh
+        block, a host-seeded one the block its tail opens. ``batch``
         restricts the window to a subset of running rows (the spec-decode
         fallback arm, which has already swept cancellations)."""
         K = self.ecfg.decode_steps
@@ -2901,10 +2912,15 @@ class JaxEngine:
             else:
                 if self.block > 1:
                     # the row's next block: what lies past its whole
-                    # blocks is final from the start, the rest masked
-                    npos[i] = seq.prefill_extent
-                    tail = seq.tokens[npos[i]:]
-                    ntok[i, :len(tail)] = tail
+                    # blocks is final from the start, the rest masked.
+                    # Before it the last whole block, if a window made
+                    # it: its K/V are not in the pool yet (_kv_extent)
+                    L = self.block
+                    npos[i] = whole = seq.prefill_extent
+                    if self._kv_extent(seq, len(seq.tokens)) < whole:
+                        ntok[i, :L] = seq.tokens[whole - L:whole]
+                    tail = seq.tokens[whole:]
+                    ntok[i, L:L + len(tail)] = tail
                 else:
                     ntok[i] = seq.last_token
                     npos[i] = len(seq.tokens) - 1
@@ -3243,14 +3259,20 @@ class JaxEngine:
             # appending its (repeated) trailing tokens
             self._terminate(seq, FINISH_LENGTH)
 
-    def _kv_extent(self, n_tokens: int) -> int:
+    def _kv_extent(self, seq: Sequence, n_tokens: int) -> int:
         """Positions of a row of n_tokens tokens whose K/V in the pool is
         final: all but the newest token's (written when it next serves
-        as a decode input), or for a model that generates by blocks the
-        whole blocks (a block cut by a stop id or the budget is never
-        committed, and the window reads tokens back block by block)."""
+        as a decode input), or for a model that generates by blocks what
+        prefill wrote (``seq.computed``: whole blocks) and of the whole
+        blocks a window made all but the last: a block's K/V are made
+        final by the first forward of the block AFTER it
+        (llama._make_block_window_fn), in the next window for a
+        window's last block, so the pool stands one block behind the
+        tokens read back. A block cut by a stop id or the budget is
+        never committed, nor is the last block of a row that finishes."""
         if self.block > 1:
-            return _whole_blocks(n_tokens, self.block)
+            return max(_whole_blocks(n_tokens, self.block) - self.block,
+                       seq.computed)
         return n_tokens - 1
 
     def _publish(self, seq: Sequence, prev_filled: int) -> None:
@@ -3258,8 +3280,8 @@ class JaxEngine:
         since ``prev_filled`` completed: those the final extent now
         covers and did not before."""
         ps = self.ecfg.page_size
-        extent = self._kv_extent(len(seq.tokens))
-        if extent // ps > max(self._kv_extent(prev_filled), 0) // ps:
+        extent = self._kv_extent(seq, len(seq.tokens))
+        if extent // ps > max(self._kv_extent(seq, prev_filled), 0) // ps:
             self.pm.commit_chain(seq.pages, seq.tokens, extent,
                                  chain=self._chain(seq))
 
@@ -3718,7 +3740,7 @@ def _merge_carry(c_tok, c_pos, c_done, c_steps, c_rem, src, from_carry,
     previous batch); fresh rows take the host-provided values. Runs as one
     tiny jitted program so no host sync enters the dispatch path."""
     src = jnp.clip(src, 0, c_tok.shape[0] - 1)
-    # a block window's token carry is a block a row ([B, L])
+    # a block window's token carry is two blocks a row ([B, 2L])
     tok = jnp.where(from_carry if c_tok.ndim == 1 else from_carry[:, None],
                     c_tok[src], n_tok)
     pos = jnp.where(from_carry, c_pos[src], n_pos)

@@ -343,7 +343,8 @@ def _scatter_pages(cache_layer: jax.Array, new: jax.Array,
 
 
 def commit_window(kv: jax.Array, w: jax.Array, page_table: jax.Array,
-                  start: jax.Array, pos: jax.Array) -> jax.Array:
+                  start: jax.Array, pos: jax.Array,
+                  lo: Optional[jax.Array] = None) -> jax.Array:
     """Commit a decode window's K (or V) into the pool by WHOLE PAGES,
     along the pool's own major axis, in place.
 
@@ -352,7 +353,11 @@ def commit_window(kv: jax.Array, w: jax.Array, page_table: jax.Array,
     start: [B] position of the window's first token (-1: padding row);
     pos: [B] the carry's position after the window. Entry i commits iff
     start >= 0 and start + i < pos: a row that froze mid-window commits
-    only what it produced, a padding row nothing.
+    only what it produced, a padding row nothing. ``lo`` [B] (the block
+    window: its buffer begins a block before what a row may write): the
+    first position a row commits where that is not ``start``, which may
+    then lie before 0; entry i commits iff lo >= 0 and lo <= start + i <
+    pos.
 
     Why not a row scatter (`.at[page, :, offset]`, _scatter_pages): the
     TPU compiler scatters only along a major axis, and in this layout a
@@ -389,8 +394,10 @@ def commit_window(kv: jax.Array, w: jax.Array, page_table: jax.Array,
     page = jnp.take_along_axis(page_table, jnp.minimum(cols, P - 1), axis=1)
     # token i's row inside the row's S gathered pages, as s * ps + offset
     steps = jnp.arange(k_steps, dtype=jnp.int32)
-    valid = jnp.logical_and(start[:, None] >= 0,
+    valid = jnp.logical_and((start if lo is None else lo)[:, None] >= 0,
                             start[:, None] + steps < pos[:, None])
+    if lo is not None:
+        valid = valid & (start[:, None] + steps >= lo[:, None])
     rel = jnp.where(valid, (start - first * ps)[:, None] + steps,
                     -1)                                          # [B, K]
     slot = jnp.arange(S * ps, dtype=jnp.int32).reshape(S, ps)
@@ -1915,44 +1922,64 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
     of W = k_steps / L BLOCKS a row, under the name and the call form of
     ``make_decode_window_fn``'s program.
 
-    One block of a row, at positions s .. s + L - 1 (s a multiple of L;
-    everything before s is in the pool or in the window buffer): the
-    input is the block's final tokens (the prompt's tail, in a row's
+    One block of a row, at positions s .. s + L - 1 (s a multiple of L):
+    the input is the block's final tokens (the prompt's tail, in a row's
     first block) and ``mask_token_id`` elsewhere. While any live row has
-    a masked position (a ``lax.while_loop``: the batch takes as many
-    forwards as its slowest row, at most ``cfg.denoising_steps``): one
-    forward of [B, L] queries (scope ``diffusion.denoise``), then at
-    every masked position a draw and its probability, and
-    ``sampling.unmask`` makes n = ceil(masked at block start /
-    denoising_steps) of them final by ``cfg.remasking_strategy`` (scope
-    ``diffusion.unmask``); a final position never changes again. Then one
-    more forward on the final tokens (``diffusion.commit``), which yields
-    no token: the K/V of a position depends on the tokens of its whole
-    block, so only this forward's K/V may be kept.
+    a masked position (the batch takes as many forwards as its slowest
+    row, at most ``cfg.denoising_steps``): one forward (scope
+    ``diffusion.denoise``), then at every masked position a draw and its
+    probability, and ``sampling.unmask`` makes n = ceil(masked at block
+    start / denoising_steps) of them final by ``cfg.remasking_strategy``
+    (scope ``diffusion.unmask``); a final position never changes again.
 
-    K/V of the window's blocks live in the window buffer [Lyr, B, W * L,
-    KV, hd] only (every forward overwrites its block's L slots) and the
-    pool is read-only; ``commit_window`` writes the W * L positions at
-    the end, of which a row commits the blocks it finished WHOLE
-    (``block_carry_update``). Attention of a block's queries: every
+    The K/V of a position depends on the tokens of its whole block, so
+    only a forward on the block's FINAL tokens makes K/V that may be
+    kept. That forward is no forward of its own: the final tokens of
+    block b (the row's PENDING block) ride as the first L queries of the
+    first denoising forward of block b + 1, a [B, 2L] forward under the
+    mask that is causal across blocks and bidirectional inside one. Every
+    layer computes b's final K/V there and b + 1's queries attend to them
+    in that same layer: the K/V and the logits of a commit forward
+    followed by a denoising forward, in one read of the weights. Only the
+    second L rows go through the head. Turns 2 .. S of a block are [B, L]
+    forwards in a ``lax.while_loop``, and the window's W blocks one
+    ``lax.fori_loop`` around both, so the program holds each forward
+    once whatever W is. So a block of L = 4 under a
+    strategy that makes one position final a forward costs FOUR forwards,
+    the first of them 2L rows wide (its routed experts take the sorted
+    form where B * 2L passes ``_MOE_DENSE_ROWS``, by the rule of every
+    other program). The pending block crosses the window's boundary in
+    the carry: a window's last block is committed by the next window's
+    first forward, and a row that finishes leaves its last block
+    uncommitted (nothing reads it).
+
+    K/V of the pending block and of the window's blocks live in the
+    window buffer [Lyr, B, (W + 1) * L, KV, hd] only (slot j holds
+    position start - L + j; every forward overwrites its blocks' slots)
+    and the pool is read-only; ``commit_window`` writes at the end the
+    blocks whose K/V a two-block forward of this window made final: the
+    pending one and all but the last of the window's own. A block the
+    pool already holds (prefilled, a prefix hit's page) is never pending,
+    so never run or written again. Attention of a block's queries: every
     pooled position and every buffer slot up to the block's end is
-    visible to EVERY query of the block (the mask is causal across blocks
-    and bidirectional inside one), so no per-query mask exists: on the
-    chip the L queries fold into the decode kernel's group axis (G * L
-    rows a KV head) and the buffer side merges by the kernel's
-    online-softmax statistics, as the one-token window does.
+    visible to EVERY query of the block, so no per-query mask exists
+    (``_block_window_attention``).
 
-    Carry: ``tokens`` is [B, L], a final token's id or -1 for a masked
-    position (masked-ness is this flag, never ``id == mask_token_id``: a
-    prompt may contain that id); ``positions`` the block's start (-1
-    padding). After a window a continuing row starts a fresh block (all
-    -1). Returns (toks [B, W * L] by position, emitted [B], [aux,] carry,
-    kv_k, kv_v, info [B, 5]): a row's new tokens are
-    ``toks[i, off : off + emitted[i]]`` with ``off`` the final positions
-    its first block came with; ``info`` counts, a live row, blocks,
-    forwards (denoising + commit), commit forwards, blocks that took
-    fewer denoising forwards than their schedule (an early exit of the
-    dynamic strategy) and tokens generated but dropped."""
+    Carry: ``tokens`` is [B, 2L]: the pending block (final ids; all -1
+    where the row has none: fresh from prefill, whose whole blocks the
+    block-causal prefill wrote) beside the open block, a final token's
+    id or -1 for a masked position (masked-ness is this flag, never ``id
+    == mask_token_id``: a prompt may contain that id); ``positions`` the
+    open block's start (-1 padding). After a window a continuing row's
+    last block is pending and it starts a fresh block (all -1). Returns
+    (toks [B, W * L] by position, emitted [B], [aux,] carry, kv_k, kv_v,
+    info [B, 5]): a row's new tokens are ``toks[i, off : off +
+    emitted[i]]`` with ``off`` the final positions its first block came
+    with; ``info`` counts, a live row, blocks, forwards (a two-block
+    forward is ONE), blocks whose K/V a two-block forward made final,
+    blocks that took fewer denoising forwards than their schedule (an
+    early exit of the dynamic strategy) and tokens generated but
+    dropped."""
     L, S = cfg.block_length, cfg.denoising_steps
     inv_freq = rope_freqs(cfg)
     scale = cfg.attn_scale
@@ -1971,21 +1998,45 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
                       logprobs_topn: int = 0):
         assert penalties is None, "no penalty state inside a block"
         assert k_steps % L == 0, (k_steps, L)
+        assert tokens.shape[1] == 2 * L, (tokens.shape, L)
         B = tokens.shape[0]
         Lyr = cfg.num_layers
         W = k_steps // L
         start = positions
         wdt = kv_k.dtype
-        wk = jnp.zeros((Lyr, B, k_steps, KV, hd), wdt)
-        wv = jnp.zeros((Lyr, B, k_steps, KV, hd), wdt)
+        wk = jnp.zeros((Lyr, B, k_steps + L, KV, hd), wdt)
+        wv = jnp.zeros((Lyr, B, k_steps + L, KV, hd), wdt)
         layer_params = {k: params[k] for k in _layer_keys(cfg)}
         offs = jnp.arange(L, dtype=jnp.int32)
+        # what the pool holds of a row, and the slots of the buffer it
+        # does not use: its pending block lies in slots [0, L), or in
+        # the pool with everything before it
+        held = tokens[:, 0] >= 0
+        pooled = jnp.where(held, start - L, start)
+        skip = jnp.where(held, 0, L)
 
-        def block_forward(x_tok, wk, wv, w: int, want_logits: bool):
-            """x_tok [B, L] at positions start + w * L + (0 .. L-1);
-            the block's K/V overwrite slots [w * L, (w + 1) * L)."""
-            h = embed_tokens(params, cfg, x_tok)            # [B, L, D]
-            q_pos = jnp.maximum(start, 0)[:, None] + (w * L + offs)[None, :]
+        def block_forward(x_tok, live, wk, wv, w):
+            """x_tok [B, n * L]: the open block of (traced) index w (n =
+            1), at positions start + w * L + (0 .. L-1), or the pending
+            block before it as well (n = 2); their K/V overwrite the
+            buffer's slots from (w + 2 - n) * L on. ``live`` [B, n * L]:
+            the rows that make (token, expert) pairs where the experts
+            take the sorted form. Returns the OPEN block's logits
+            [B * L, V]."""
+            n = x_tok.shape[1] // L
+            T, at = n * L, (w + 2 - n) * L
+            h = embed_tokens(params, cfg, x_tok)            # [B, T, D]
+            q_pos = jnp.maximum(
+                start[:, None] + (at - L + jnp.arange(T, dtype=jnp.int32)),
+                0)
+            xs = dict(layer_params)
+            # as in forward: the sorted dispatch reads w[layer, expert]
+            # where the parameters lie; as scanned xs the layer's whole
+            # expert stack would be sliced out before a block may index it
+            experts = None
+            if cfg.num_experts > 0 and _moe_use_blocked(
+                    mesh, B * T, cfg.num_experts, cfg.num_experts_per_tok):
+                experts = [xs.pop(k) for k in ("w_gate", "w_up", "w_down")]
 
             def layer(h, xs):
                 lp, l_idx, wk_l, wv_l = xs
@@ -1996,40 +2047,45 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
                     if cfg.attn_bias:
                         xq, xk, xv = (xq + lp["bq"], xk + lp["bk"],
                                       xv + lp["bv"])
-                    q, k = _qk_headnorm(xq.reshape(B, L, H, hd),
-                                        xk.reshape(B, L, KV, hd), lp, cfg)
+                    q, k = _qk_headnorm(xq.reshape(B, T, H, hd),
+                                        xk.reshape(B, T, KV, hd), lp, cfg)
                     q = apply_rope(q, q_pos, inv_freq)
                     k = apply_rope(k, q_pos, inv_freq)
-                    k, v = _block_kv(k, xv.reshape(B, L, KV, hd), wdt)
-                    wk_l = lax.dynamic_update_slice_in_dim(wk_l, k, w * L, 1)
-                    wv_l = lax.dynamic_update_slice_in_dim(wv_l, v, w * L, 1)
+                    k, v = _block_kv(k, xv.reshape(B, T, KV, hd), wdt)
+                    wk_l = lax.dynamic_update_slice_in_dim(wk_l, k, at, 1)
+                    wv_l = lax.dynamic_update_slice_in_dim(wv_l, v, at, 1)
                     attn = _block_window_attention(
-                        q, kv_k, kv_v, l_idx, page_table, start, wk_l,
-                        wv_l, (w + 1) * L, scale,
+                        q, kv_k, kv_v, l_idx, page_table, pooled, wk_l,
+                        wv_l, skip, at + T, L, scale,
                         use_pallas=mode is not None, interpret=bool(mode))
                     h = _residual_add(
-                        h, attn.reshape(B, L, H * hd) @ lp["wo"], lp,
+                        h, attn.reshape(B, T, H * hd) @ lp["wo"], lp,
                         "ln_attn_post", cfg)
-                return _layer_ff(h, lp, cfg, mesh), (wk_l, wv_l)
+                return (_layer_ff(h, lp, cfg, mesh, experts, live, l_idx),
+                        (wk_l, wv_l))
 
             h, (wk, wv) = lax.scan(
                 layer, h,
-                (layer_params, jnp.arange(Lyr, dtype=jnp.int32), wk, wv))
-            if not want_logits:
-                return None, wk, wv
-            h = rms_norm(h, params["ln_final"], cfg.rms_norm_eps,
+                (xs, jnp.arange(Lyr, dtype=jnp.int32), wk, wv))
+            h = rms_norm(h[:, T - L:], params["ln_final"], cfg.rms_norm_eps,
                          cfg.norm_unit_offset)
             # [B * L, V], a row's positions one after another: every
             # consumer wants rows, and a reshape of [B, L, V] is a copy
             return project_logits(params, cfg, h.reshape(B * L, -1)), wk, wv
 
-        tok, pos = tokens, positions
-        out_toks = []
-        emitted = jnp.zeros((B,), jnp.int32)
-        info = jnp.zeros((B, 5), jnp.int32)
+        def open_block(tok):
+            return jnp.where(tok < 0, cfg.mask_token_id, tok)
+
         N = logprobs_topn
-        lps, tvs, tis = [], [], []
-        for w in range(W):
+
+        def block(w, c):
+            """Block w of every row. The window's blocks are ONE loop,
+            so that the program holds each forward and each draw once
+            whatever W is: unrolled, every block's were traced, lowered
+            and read back from the compile cache again (``setup_s`` +16%
+            at W = 2: PERF.md, PR 62)."""
+            (pend, tok, pos, done, steps, remaining, wk, wv, final,
+             emitted, info, toks, aux_w) = c
             active = carry_active(done, pos)
             masked0 = jnp.logical_and(tok < 0, active[:, None])
             n0 = jnp.sum(masked0.astype(jnp.int32), axis=1)
@@ -2038,12 +2094,10 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
                      jnp.zeros((B, L, N), jnp.float32),
                      jnp.zeros((B, L, N), jnp.int32)) if N else ())
             rng_step = pos[:, None] + offs[None, :]
+            folded = active & (pend[:, 0] >= 0)
 
-            def denoise(c, w=w, n_step=n_step, rng_step=rng_step):
-                i, tok, masked, wk, wv, fwd, aux = c
-                with jax.named_scope("diffusion.denoise"):
-                    x = jnp.where(tok < 0, cfg.mask_token_id, tok)
-                    logits, wk, wv = block_forward(x, wk, wv, w, True)
+            def unmask_turn(c, logits):
+                i, tok, masked, fwd, aux = c
                 with jax.named_scope("diffusion.unmask"):
                     ids, conf = sample_with_confidence(
                         logits, temperature, top_k, top_p, seeds, rng_step,
@@ -2062,18 +2116,31 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
                     fwd = fwd + jnp.any(masked, axis=1).astype(jnp.int32)
                     tok = jnp.where(pick, ids, tok)
                     masked = masked & ~pick
-                return i + 1, tok, masked, wk, wv, fwd, aux
+                return i + 1, tok, masked, fwd, aux
 
-            _, tok, _, wk, wv, fwd, aux = lax.while_loop(
-                lambda c: jnp.any(c[2]), denoise,
-                (jnp.int32(0), tok, masked0, wk, wv,
-                 jnp.zeros((B,), jnp.int32), aux0))
-            with jax.named_scope("diffusion.commit"):
-                # every position final: this forward's K/V are the block's
-                _, wk, wv = block_forward(jnp.maximum(tok, 0), wk, wv, w,
-                                          False)
+            def denoise(c, live=jnp.repeat(active[:, None], L, axis=1)):
+                *c, wk, wv = c
+                with jax.named_scope("diffusion.denoise"):
+                    logits, wk, wv = block_forward(open_block(c[1]), live,
+                                                   wk, wv, w)
+                return (*unmask_turn(c, logits), wk, wv)
+
+            with jax.named_scope("diffusion.denoise"):
+                # the block's first forward, whatever the batch holds: the
+                # pending block rides before the open one, and this
+                # forward's K/V of it are the ones kept
+                logits, wk, wv = block_forward(
+                    jnp.concatenate([jnp.maximum(pend, 0), open_block(tok)],
+                                    axis=1),
+                    jnp.repeat(jnp.stack([folded, active], axis=1), L,
+                               axis=1), wk, wv, w)
+            final = jnp.where(folded, pos, final)
+            turn = unmask_turn((jnp.int32(0), tok, masked0,
+                                jnp.zeros((B,), jnp.int32), aux0), logits)
+            _, tok, _, fwd, aux, wk, wv = lax.while_loop(
+                lambda c: jnp.any(c[2]), denoise, (*turn, wk, wv))
             with jax.named_scope("diffusion.unmask"):
-                emit, pos, done, steps, remaining = block_carry_update(
+                emit, new_pos, done, steps, remaining = block_carry_update(
                     tok, masked0, pos, done, steps, remaining, eos_table, L)
                 n_emit = jnp.sum(emit.astype(jnp.int32), axis=1)
                 emitted = emitted + n_emit
@@ -2081,73 +2148,97 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
                 scheduled = (n0 + jnp.maximum(n_step, 1) - 1) \
                     // jnp.maximum(n_step, 1)
                 info = info + jnp.stack(
-                    [live, fwd + live, live,
+                    [live, fwd, folded.astype(jnp.int32),
                      (active & (fwd < scheduled)).astype(jnp.int32),
                      n0 - n_emit], axis=1)
-            out_toks.append(tok)
-            if N:
-                lps.append(aux[0]); tvs.append(aux[1]); tis.append(aux[2])
-            tok = jnp.full((B, L), -1, jnp.int32)   # the next block: fresh
+            toks = lax.dynamic_update_slice_in_dim(toks, tok, w * L, 1)
+            aux_w = tuple(lax.dynamic_update_slice_in_dim(a, x, w * L, 1)
+                          for a, x in zip(aux_w, aux))
+            # a row that went on: the block it finished is pending, the
+            # next one fresh
+            return (jnp.where((new_pos > pos)[:, None], tok, -1),
+                    jnp.full((B, L), -1, jnp.int32), new_pos, done, steps,
+                    remaining, wk, wv, final, emitted, info, toks, aux_w)
+
+        aux_w = ((jnp.zeros((B, k_steps), jnp.float32),
+                  jnp.zeros((B, k_steps, N), jnp.float32),
+                  jnp.zeros((B, k_steps, N), jnp.int32)) if N else ())
+        (pend, tok, pos, done, steps, remaining, wk, wv, final, emitted,
+         info, toks, aux_w) = lax.fori_loop(0, W, block, (
+             tokens[:, :L], tokens[:, L:], positions, done, steps,
+             remaining, wk, wv,
+             pooled,        # a row's positions before it have final K/V
+             jnp.zeros((B,), jnp.int32), jnp.zeros((B, 5), jnp.int32),
+             jnp.zeros((B, k_steps), jnp.int32), aux_w))
 
         with jax.named_scope("kv_carry"):
-            kv_k = commit_window(kv_k, wk, page_table, start, pos)
-            kv_v = commit_window(kv_v, wv, page_table, start, pos)
-        toks = jnp.concatenate(out_toks, axis=1)
-        aux = (jnp.concatenate(lps, axis=1), jnp.concatenate(tvs, axis=1),
-               jnp.concatenate(tis, axis=1)) if N else None
-        return WindowResults(toks, emitted, aux,
-                             (tok, pos, done, steps, remaining), kv_k, kv_v,
+            kv_k = commit_window(kv_k, wk, page_table, start - L, final,
+                                 lo=pooled)
+            kv_v = commit_window(kv_v, wv, page_table, start - L, final,
+                                 lo=pooled)
+        return WindowResults(toks, emitted, aux_w or None,
+                             (jnp.concatenate([pend, tok], axis=1), pos,
+                              done, steps, remaining), kv_k, kv_v,
                              info, None).pack()
 
     return decode_window
 
 
-def _block_window_attention(q, k_pools, v_pools, l_idx, page_table, start,
-                            wk_l, wv_l, visible: int, scale,
+def _block_window_attention(q, k_pools, v_pools, l_idx, page_table, pooled,
+                            wk_l, wv_l, skip, end, block: int, scale,
                             use_pallas: bool, interpret: bool):
-    """Attention of one block's L queries in the block window: the
-    (frozen) pool for positions < start, and the window buffer's first
-    ``visible`` slots (slot j holds position start + j; the queries'
-    own block ends at ``visible``). Every key on either side is visible
-    to every query of the block, so the only masks are the pool's extent
-    and the buffer's.
+    """Attention of one or two blocks' queries in the block window: the
+    (frozen) pool for positions < ``pooled``, and the window buffer's
+    slots from ``skip`` up to the end of the queries' own block: the
+    last block of queries ends at slot ``end`` (a scalar, traced or
+    not), the one before it ``block`` slots earlier, so the second of
+    two sees one block further than the first. Every key on either side
+    is visible to every query of a block, so the only masks are the
+    pool's extent and the buffer's.
 
-    q: [B, L, H, hd]; *_pools: [Lyr, pages, KV, ps, hd]; l_idx: traced
-    scalar; wk_l / wv_l: [B, K, KV, hd]; start: [B] (-1: padding row,
-    sees nothing). On the chip the pool side is the decode kernel with
-    the L queries folded into its group axis (q as [B, KV * L * G, hd],
-    head-major under each KV head), its (m, l) statistics merged with the
-    buffer side's as ``_pool_window_attention_pallas`` merges them; off
-    it, ``_pool_window_attention``'s gather and one softmax."""
+    q: [B, n * block, H, hd]; *_pools: [Lyr, pages, KV, ps, hd]; l_idx:
+    traced scalar; wk_l / wv_l: [B, K, KV, hd]; pooled: [B] (-1: padding
+    row, sees nothing); skip: [B]. On the chip the pool side is ONE call
+    of the decode kernel with the n * block queries folded into its
+    group axis (q as [B, KV * n * block * G, hd], head-major under each
+    KV head: the row's pages are read once for both blocks), its (m, l)
+    statistics merged with the buffer side's as
+    ``_pool_window_attention_pallas`` merges them; off it,
+    ``_pool_window_attention``'s gather and one softmax."""
     from ..ops.paged_attention import (NEG_INF,
                                        paged_attention_decode_layered)
 
-    B, L, H, hd = q.shape
+    B, T, H, hd = q.shape
     KV = wk_l.shape[2]
     G = H // KV
     K = wk_l.shape[1]
-    qg = q.reshape(B, L, KV, G, hd).transpose(0, 2, 1, 3, 4)  # [B,KV,L,G,hd]
-    q32 = qg.reshape(B, KV, L * G, hd).astype(jnp.float32)
-    mask_w = (jnp.arange(K)[None, :] < visible) & (start[:, None] >= 0)
+    qg = q.reshape(B, T, KV, G, hd).transpose(0, 2, 1, 3, 4)  # [B,KV,T,G,hd]
+    q32 = qg.reshape(B, KV, T * G, hd).astype(jnp.float32)
+    slot = jnp.arange(K)
+    # the end of each query row's block, rows being (query, head in group)
+    ends = end - (T - 1 - jnp.arange(T * G) // G) // block * block
+    mask_w = ((slot[None, None, :] < ends[None, :, None])
+              & (slot[None, None, :] >= skip[:, None, None])
+              & (pooled[:, None, None] >= 0))                   # [B,T*G,K]
     sw = jnp.einsum("bkrh,bwkh->bkrw", q32,
-                    wk_l.astype(jnp.float32)) * scale      # [B,KV,L*G,K]
-    sw = jnp.where(mask_w[:, None, None, :], sw, NEG_INF)
+                    wk_l.astype(jnp.float32)) * scale      # [B,KV,T*G,K]
+    sw = jnp.where(mask_w[:, None], sw, NEG_INF)
     if use_pallas:
         out_p, m_p, l_p = paged_attention_decode_layered(
-            qg.reshape(B, KV * L * G, hd), k_pools, v_pools, l_idx,
-            page_table, jnp.maximum(start, 0), scale=scale,
+            qg.reshape(B, KV * T * G, hd), k_pools, v_pools, l_idx,
+            page_table, jnp.maximum(pooled, 0), scale=scale,
             return_stats=True, interpret=interpret)
         m_w = jnp.max(sw, axis=-1)
         p_w = jnp.exp(sw - m_w[..., None])
         l_w = jnp.sum(p_w, axis=-1)
         out_w = jnp.einsum("bkrw,bwkh->bkrh", p_w, wv_l.astype(jnp.float32))
-        m_p = m_p.reshape(B, KV, L * G)
-        l_p = l_p.reshape(B, KV, L * G)
+        m_p = m_p.reshape(B, KV, T * G)
+        l_p = l_p.reshape(B, KV, T * G)
         m_t = jnp.maximum(m_p, m_w)
         a_p = jnp.exp(m_p - m_t) * l_p
         a_w = jnp.exp(m_w - m_t)
         l_t = jnp.maximum(a_p + a_w * l_w, 1e-9)
-        out = (out_p.reshape(B, KV, L * G, hd).astype(jnp.float32)
+        out = (out_p.reshape(B, KV, T * G, hd).astype(jnp.float32)
                * a_p[..., None] + out_w * a_w[..., None]) / l_t[..., None]
     else:
         ps = k_pools.shape[3]
@@ -2158,15 +2249,15 @@ def _block_window_attention(q, k_pools, v_pools, l_idx, page_table, start,
             B, S, KV, hd)
         sp = jnp.einsum("bkrh,bskh->bkrs", q32,
                         kp.astype(jnp.float32)) * scale
-        mask_p = jnp.arange(S)[None, :] < start[:, None]
+        mask_p = jnp.arange(S)[None, :] < pooled[:, None]
         sp = jnp.where(mask_p[:, None, None, :], sp, NEG_INF)
         p = jax.nn.softmax(jnp.concatenate([sp, sw], axis=-1), axis=-1)
         out = (jnp.einsum("bkrs,bskh->bkrh", p[..., :S],
                           vp.astype(jnp.float32))
                + jnp.einsum("bkrw,bwkh->bkrh", p[..., S:],
                             wv_l.astype(jnp.float32)))
-    out = out.reshape(B, KV, L, G, hd).transpose(0, 2, 1, 3, 4)
-    return out.reshape(B, L, H, hd).astype(q.dtype)
+    out = out.reshape(B, KV, T, G, hd).transpose(0, 2, 1, 3, 4)
+    return out.reshape(B, T, H, hd).astype(q.dtype)
 
 
 def window_attention(q, k_pools, v_pools, l_idx, page_table, start, wk_l,

@@ -84,7 +84,8 @@ def block_carry_update(tok, new, pos, done, steps, remaining, eos_table,
     masked when the block began (the others were the prompt's tail). A
     live row emits its new positions in order while its budget lasts and
     up to and including the first stop id; what follows in the block is
-    dropped. The row advances (and its block commits) only if every new
+    dropped. The row advances (and its block becomes the pending one,
+    which the next block's first forward commits) only if every new
     position was emitted; it freezes if it hit a stop id, spent its
     budget, or dropped anything. Returns (emit [B, L] bool, pos, done,
     steps, remaining)."""

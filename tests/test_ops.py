@@ -457,6 +457,30 @@ def test_prefill_kernel_block_one_is_the_causal_kernel():
     assert other != low[0]
 
 
+def _block_window_case(L, G, hd, blocks):
+    """Pools, a table, ``blocks`` * L queries a row and a buffer of three
+    blocks for four rows: no pool, a pool that ends inside a page, one
+    that ends on a page's end, a padding row."""
+    KV, ps, P, num_pages, B, layers = 2, 8, 4, 24, 4, 2
+    rng = np.random.RandomState(31)
+    pooled = np.array([0, 8 + L, 3 * ps, -1], np.int32)
+    table = _tables(rng, np.maximum(pooled, 0), P, ps, num_pages)
+    pools = [jnp.asarray(rng.randn(layers, num_pages, KV, ps, hd),
+                         jnp.float32) for _ in range(2)]
+    q = jnp.asarray(rng.randn(B, blocks * L, KV * G, hd), jnp.float32)
+    wk, wv = (jnp.asarray(rng.randn(B, 3 * L, KV, hd), jnp.float32)
+              for _ in range(2))
+
+    def attend(wk, wv, skip, end, arm, q=q):
+        from dynamo_tpu.models.llama import _block_window_attention
+        return np.asarray(_block_window_attention(
+            q, *pools, jnp.int32(1), jnp.asarray(table),
+            jnp.asarray(pooled), wk, wv, jnp.asarray(skip, jnp.int32),
+            end, L, hd ** -0.5, use_pallas=arm, interpret=True))
+
+    return q, wk, wv, attend
+
+
 @pytest.mark.parametrize("L,G,hd", [(4, 2, 128), (4, 8, 128), (2, 4, 64),
                                     (8, 1, 128)])
 def test_block_window_attention_kernel_arm_matches_gather(L, G, hd):
@@ -465,40 +489,45 @@ def test_block_window_attention_kernel_arm_matches_gather(L, G, hd):
     mode) and merged with the window buffer by the kernel's statistics,
     against its XLA arm (one gather, one softmax): rows at different
     pool extents (none, inside a page, on a page's end), a padding row,
-    a buffer with one and with two visible blocks."""
-    from dynamo_tpu.models.llama import _block_window_attention
+    a buffer with two and with three visible blocks (the end a number
+    or a traced scalar), rows that use the buffer's first block beside
+    rows that do not."""
+    q, wk, wv, attend = _block_window_case(L, G, hd, 1)
+    skip = [0, L, 0, L]
+    for end in (2 * L, jnp.int32(3 * L)):
+        got, want = (attend(wk, wv, skip, end, arm)
+                     for arm in (True, False))
+        np.testing.assert_allclose(got[:3], want[:3], rtol=2e-5, atol=2e-5)
+    # what the buffer's later block holds must not matter to the second,
+    # nor the first block's to a row that skips it
+    first = attend(wk.at[:, 2 * L:].set(9.0), wv.at[:, 2 * L:].set(9.0),
+                   skip, 2 * L, True)
+    again = attend(wk, wv, skip, 2 * L, True)
+    np.testing.assert_array_equal(first[:3], again[:3])
+    other = attend(wk.at[:, :L].set(9.0), wv.at[:, :L].set(9.0), skip,
+                   2 * L, True)
+    np.testing.assert_array_equal(other[1], again[1])
+    assert np.abs(other[0] - again[0]).max() > 1e-3
 
-    KV, ps, P, num_pages, B, layers = 2, 8, 4, 24, 4, 2
-    rng = np.random.RandomState(31)
-    starts = np.array([0, 8 + L, 3 * ps, -1], np.int32)
-    table = _tables(rng, np.maximum(starts, 0), P, ps, num_pages)
-    k_pools = jnp.asarray(rng.randn(layers, num_pages, KV, ps, hd),
-                          jnp.float32)
-    v_pools = jnp.asarray(rng.randn(layers, num_pages, KV, ps, hd),
-                          jnp.float32)
-    q = jnp.asarray(rng.randn(B, L, KV * G, hd), jnp.float32)
-    wk = jnp.asarray(rng.randn(B, 2 * L, KV, hd), jnp.float32)
-    wv = jnp.asarray(rng.randn(B, 2 * L, KV, hd), jnp.float32)
-    for visible in (L, 2 * L):
-        got, want = (_block_window_attention(
-            q, k_pools, v_pools, jnp.int32(1), jnp.asarray(table),
-            jnp.asarray(starts), wk, wv, visible, hd ** -0.5,
-            use_pallas=arm, interpret=True) for arm in (True, False))
-        live = starts >= 0
-        np.testing.assert_allclose(np.asarray(got)[live],
-                                   np.asarray(want)[live],
-                                   rtol=2e-5, atol=2e-5)
-    # what the buffer's later block holds must not matter to the first
-    first = _block_window_attention(
-        q, k_pools, v_pools, jnp.int32(1), jnp.asarray(table),
-        jnp.asarray(starts), wk.at[:, L:].set(9.0), wv.at[:, L:].set(9.0),
-        L, hd ** -0.5, use_pallas=True, interpret=True)
-    again = _block_window_attention(
-        q, k_pools, v_pools, jnp.int32(1), jnp.asarray(table),
-        jnp.asarray(starts), wk, wv, L, hd ** -0.5, use_pallas=True,
-        interpret=True)
-    np.testing.assert_array_equal(np.asarray(first)[:3],
-                                  np.asarray(again)[:3])
+
+@pytest.mark.parametrize("arm", [True, False], ids=["kernel", "xla"])
+@pytest.mark.parametrize("L,G,hd", [(4, 8, 128), (4, 2, 128), (2, 4, 64)])
+def test_two_blocks_of_queries_are_two_calls_of_one(L, G, hd, arm):
+    """2L queries in ONE call (G x 2L rows a KV head in the kernel's
+    group axis: the row's pages are read once), the first block's seeing
+    the buffer up to its own end and the second's one block further:
+    each half equals the call on that block alone, on either arm and for
+    every kind of row."""
+    q, wk, wv, attend = _block_window_case(L, G, hd, 2)
+    skip = [0, L, 0, L]
+    for at in (L, 2 * L):
+        both = attend(wk, wv, skip, at + L, arm)
+        for c in range(2):
+            alone = attend(wk, wv, skip, at + c * L, arm,
+                           q=q[:, c * L:(c + 1) * L])
+            np.testing.assert_allclose(
+                both[:3, c * L:(c + 1) * L], alone[:3], rtol=2e-5,
+                atol=2e-5)
 
 
 @pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)

@@ -225,10 +225,13 @@ class Pools:
             jnp.asarray([max(n - 1, 0), 0]), None)
 
     def run_window(self, params, tail, start, budget, k_steps=8, topn=0,
-                   stop_ids=()):
+                   stop_ids=(), pending=()):
+        """``pending``: the final tokens of the block before ``start``,
+        where the pool does not hold its K/V yet."""
         L = self.cfg.block_length
-        tok = np.full((2, L), -1, np.int32)
-        tok[0, :len(tail)] = tail
+        tok = np.full((2, 2 * L), -1, np.int32)
+        tok[0, :len(pending)] = pending
+        tok[0, L:L + len(tail)] = tail
         eos = np.full((2, 8), -1, np.int32)
         eos[0, :len(stop_ids)] = stop_ids
         out = self.window(
@@ -278,9 +281,12 @@ def test_prefill_and_every_denoising_forward_match_reference(
         assert np.abs(got - want[j]).max() < ATOL, j
         assert new[j] == int(np.argmax(want[j]))
     # a live row's counts: 2 blocks, a denoising forward a new position
-    # and a commit forward a block, nothing early, nothing dropped
-    assert info[0].tolist() == [2, n + 2, 2, 0, 0]
+    # and no forward that only commits (the first block's K/V were made
+    # final by the second's first forward, the second's wait in the
+    # carry), nothing early, nothing dropped
+    assert info[0].tolist() == [2, n, 1, 0, 0]
     assert info[1].tolist() == [0, 0, 0, 0, 0]
+    assert np.asarray(carry[0])[0].tolist() == new[-L:] + [-1] * L
 
 
 def test_the_window_commits_whole_blocks_only():
@@ -296,11 +302,107 @@ def test_the_window_commits_whole_blocks_only():
     (toks, emitted, carry), info = pools.run_window(params, [], 16, budget=6)
     assert int(emitted[0]) == 6 and bool(carry[2][0])
     assert int(carry[1][0]) == 20                 # one whole block
-    assert info[0].tolist() == [2, 10, 2, 0, 2]   # two tokens dropped
+    assert info[0].tolist() == [2, 8, 1, 0, 2]   # two tokens dropped
     after = np.asarray(pools.kv_k)
     page = pools.pages[2]                         # positions 16 .. 23
     changed = np.abs(after[:, page] - before[:, page]).sum(axis=(0, 1, 3))
     assert (changed[:4] > 0).all() and (changed[4:] == 0).all()
+
+
+def _one_block_forward(params, cfg, tokens, at, kv_k, kv_v, table):
+    """One forward of ONE block of one row through ``llama.forward`` (the
+    prefill's: block-causal over the pool, K/V written by rows): the
+    hidden states [L, D] behind ``ln_final`` and the pools after it."""
+    L = cfg.block_length
+    pos = at + np.arange(L)
+    slots = np.asarray(table)[0, pos // PS] * PS + pos % PS
+    h, kv_k, kv_v = llama.forward(
+        params, cfg, jnp.asarray([tokens], jnp.int32), jnp.asarray([pos]),
+        kv_k, kv_v, table[:1], jnp.asarray([slots], jnp.int32),
+        allow_pallas=False)
+    return h[0], kv_k, kv_v
+
+
+def _kv_rows(kv, pages, lo, hi):
+    """A pool's rows [layers, hi - lo, KV, hd] of positions lo .. hi - 1
+    of the sequence that holds ``pages``."""
+    pos = np.arange(lo, hi)
+    return np.asarray(kv)[:, np.asarray(pages)[pos // PS], :, pos % PS]
+
+
+@pytest.mark.parametrize("path", ["xla", "kernels"])
+@pytest.mark.parametrize("tail", [0, 2])
+def test_a_two_block_forward_is_the_commit_and_the_first_denoising_forward(
+        tail, path, monkeypatch):
+    """The [B, 2L] forward that opens a block, against two forwards of
+    one block each (``llama.forward`` on L tokens: the block before on
+    its final tokens, then the open block on its tail and the mask id):
+    the first half's K/V are the commit forward's, the second half's
+    logits the first denoising forward's. ``denoising_steps`` 1 makes
+    every masked position final in that first forward, so the window's
+    top-V log-probabilities are its whole rows. Three rows at once: one
+    with a pending block (prompt 0 .. 11 prefilled, 12 .. 15 pending),
+    one with the same tokens and NO pending block (0 .. 15 prefilled:
+    its pool rows must keep their bits and its logits be the same), and
+    a padding row."""
+    if path == "kernels":
+        monkeypatch.setenv("DYN_PALLAS_INTERPRET", "1")
+    cfg = tiny(denoising_steps=1)
+    params = make_params(cfg, 21)
+    L, V = cfg.block_length, cfg.vocab_size
+    (prompt,) = _prompts(22, 16 + tail)
+    rows = [Pools(cfg, pages=(3, 5, 7)), Pools(cfg, pages=(9, 11, 2))]
+    rows[0].run_prefill(params, prompt[:12])
+    rows[1].kv_k, rows[1].kv_v = rows[0].kv_k, rows[0].kv_v
+    rows[1].run_prefill(params, prompt[:16])
+    kv_k, kv_v = rows[1].kv_k, rows[1].kv_v
+    table = np.zeros((3, 8), np.int32)
+    table[0, :3], table[1, :3] = rows[0].pages, rows[1].pages
+    tok = np.full((3, 2 * L), -1, np.int32)
+    tok[0, :L] = prompt[12:16]
+    tok[:2, L:L + tail] = prompt[16:]
+    before = np.asarray(kv_k), np.asarray(kv_v)
+    # the two forwards of one block, on row 0's pages in a copy
+    _, ck, cv = _one_block_forward(params, cfg, prompt[12:16], 12,
+                                   jnp.array(kv_k), jnp.array(kv_v),
+                                   jnp.asarray(table))
+    h, _, _ = _one_block_forward(
+        params, cfg, prompt[16:] + [MASK] * (L - tail), 16, ck, cv,
+        jnp.asarray(table))
+    want = np.asarray(jax.nn.log_softmax(
+        llama.project_logits(params, cfg, h), -1))
+    ck, cv = np.asarray(ck), np.asarray(cv)
+    toks, emitted, aux, carry, kv_k, kv_v, info = rows[0].window(
+        params, jnp.asarray(tok), jnp.asarray([16, 16, -1], jnp.int32),
+        jnp.zeros(3, bool), jnp.zeros(3, jnp.int32),
+        jnp.asarray([100, 100, 1], jnp.int32), kv_k, kv_v,
+        jnp.asarray(table), jnp.zeros(3), jnp.zeros(3, jnp.int32),
+        jnp.ones(3), jnp.zeros(3, jnp.uint32),
+        jnp.full((3, 8), -1, jnp.int32), None, k_steps=L, logprobs_topn=V)
+    _, tv, ti = (np.asarray(a) for a in aux)
+    for i in range(2):
+        for j in range(tail, L):
+            got = np.empty(V, np.float32)
+            got[ti[i, j]] = tv[i, j]
+            assert np.abs(got - want[j]).max() < ATOL, (i, j)
+    assert np.asarray(info).tolist() == [[1, 1, 1, 0, 0], [1, 1, 0, 0, 0],
+                                         [0] * 5]
+    assert np.asarray(emitted).tolist() == [L - tail, L - tail, 0]
+    after = np.asarray(kv_k), np.asarray(kv_v)
+    for was, now, commit in zip(before, after, (ck, cv)):
+        # row 0: its pending block's rows are the commit forward's
+        assert np.abs(_kv_rows(now, rows[0].pages, 12, 16)
+                      - _kv_rows(commit, rows[0].pages, 12, 16)).max() < 1e-5
+        assert np.abs(_kv_rows(now, rows[0].pages, 12, 16)
+                      - _kv_rows(now, rows[1].pages, 12, 16)).max() < 1e-5
+        # and nothing else of the pool moved: not row 1's pages (no
+        # pending block: its blocks are never run or written again), not
+        # the open block's (its K/V wait in the carry for the next window)
+        now = now.copy()
+        page = rows[0].pages[1]
+        now[:, page, :, 4:] = was[:, page, :, 4:]
+        assert (now == was).all()
+    assert np.asarray(carry[0])[:, :L].tolist()[2] == [-1] * L
 
 
 # --------------------------------- (b) the engine against reference_generate
@@ -336,10 +438,13 @@ def test_generate_equals_reference_generate(strategy, run_async):
         for k in total:
             total[k] += counts[k]
     assert stats["diffusion_blocks_total"] == total["blocks"]
-    assert stats["diffusion_forwards_total"] == (
-        total["denoise_forwards"] + total["commit_forwards"])
-    assert stats["diffusion_commit_forwards_total"] == total[
-        "commit_forwards"]
+    # the reference commits every block by a forward of its own; here
+    # a block's K/V are made final by the next block's first forward,
+    # and a row's last block (cut, or pending when it finishes) by none
+    assert stats["diffusion_forwards_total"] == total["denoise_forwards"]
+    assert stats["diffusion_commit_forwards_total"] == 0
+    assert stats["diffusion_folded_commits_total"] == (
+        total["commit_forwards"] - len(prompts))
     assert stats["diffusion_dropped_tokens_total"] == total[
         "dropped_tokens"]
     assert stats["diffusion_early_exits_total"] == total["early_exits"]
@@ -347,15 +452,18 @@ def test_generate_equals_reference_generate(strategy, run_async):
     assert eng.decode_tokens_total == 9 * len(prompts)
 
 
-def test_a_prompt_arriving_mid_decode_equals_reference_generate():
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_a_prompt_arriving_mid_decode_equals_reference_generate(strategy):
     """The scheduler's window behind a prefill (JaxEngine._step_window,
     rule 2) for a model that generates by blocks: a second prompt arrives
     while the first row is between two windows, the window shipped behind
     its prefill gives the first row a fresh block from the in-flight
-    window's carry, and both rows answer as the plain loop does. The
-    engine is stepped by hand, so the arrival falls where it is meant."""
+    window's carry (with the block before it pending: the merge takes
+    both from the carry, and the new row's from the host with no pending
+    block), and both rows answer as the plain loop does. The engine is
+    stepped by hand, so the arrival falls where it is meant."""
     from dynamo_tpu.engine.jax_engine import Sequence
-    cfg = tiny()
+    cfg = tiny(remasking_strategy=strategy)
     params = make_params(cfg, 3)
     eng = _engine(cfg, params)
     first_p, second_p = _prompts(9, 14, 13)
@@ -526,18 +634,27 @@ def test_a_prefix_hit_gives_the_logits_of_a_cold_run(run_async):
     assert _max_diff(rows, warm[1]) < ATOL
 
 
-def test_pages_a_window_fills_are_published_by_whole_blocks(run_async):
+@pytest.mark.parametrize("n,pages", [(9, 2), (8, 2), (4, 1)])
+def test_pages_a_window_fills_are_published_by_whole_blocks(n, pages,
+                                                            run_async):
     """A row that generates across a page's end publishes that page once
-    the block that ends it is final (read back with its window), and a
-    later request hits it: prompt 12 + 9 generated = 21 tokens, two full
-    pages, of which the second was filled by a window."""
+    the K/V of the block that ends it are final IN THE POOL, and a later
+    request hits it: prompt 12 + 9 generated = 21 tokens, two full pages,
+    of which the second was filled by a window. That is one block later
+    than the tokens are read back: the K/V of block 12 .. 15 are made
+    final by the first forward of block 16 .. 19, not by a forward of
+    their own. With 8 tokens that forward ran (the page is published,
+    the row's last block 16 .. 19 is not committed and nobody reads it);
+    with 4 the row finishes on the page's last token, no later block
+    ever opens, the block stays pending and the page it completes is
+    never handed out: 8 tokens hit where the parent of PR 62 hit 16."""
     cfg = tiny()
     params = make_params(cfg, 7)
     (p,) = _prompts(12, 12)
     eng = _engine(cfg, params)
 
     async def run():
-        toks, _, _ = await _gen(eng, p, 9)
+        toks, _, _ = await _gen(eng, p, n)
         hits0 = eng.prefix_hit_tokens_total
         again = await _gen(eng, p + toks + [5, 6], 5, logprobs=5)
         hit = eng.prefix_hit_tokens_total - hits0
@@ -545,9 +662,71 @@ def test_pages_a_window_fills_are_published_by_whole_blocks(run_async):
         return toks, again, hit
 
     toks, again, hit = run_async(run())
-    assert hit == 16
+    assert hit == pages * PS
     want, rows, _ = REF.reference_generate(params, cfg, p + toks + [5, 6], 5)
     assert again[0] == want and _max_diff(rows, again[1]) < ATOL
+
+
+def test_a_published_page_holds_final_blocks_and_a_hit_page_keeps_its_bits(
+        run_async):
+    """Every page ``_publish`` hands to the prefix cache is read from
+    the pool as it is handed over (windows later in the queue included)
+    and set against the K of a block-causal prefill of the same tokens,
+    which is what final K/V are: a block whose K came from a denoising
+    forward (some positions still masked) differs by far more than the
+    float32 noise. Then a second request hits the published pages and
+    runs its windows on them: a window writes whole pages back, and never
+    one it shares, so the hit pages keep their bits."""
+    cfg = tiny()
+    params = make_params(cfg, 7)
+    a, = _prompts(30, 13)
+    eng = _engine(cfg, params)
+    handed = []
+    commit_chain = eng.pm.commit_chain
+
+    def spy(pages, tokens, extent, **kw):
+        if extent > 16:     # past what the prefill published
+            handed.append((list(pages), list(tokens[:extent]),
+                           np.asarray(eng.kv_k)))
+        return commit_chain(pages, tokens, extent, **kw)
+
+    eng.pm.commit_chain = spy
+
+    async def run():
+        toks, _, _ = await _gen(eng, a, 28)
+        b = a + toks[:20] + [1, 2]
+        hits0 = eng.prefix_hit_tokens_total
+        eng.pm.commit_chain = commit_chain
+        shared = None
+
+        async def second():
+            nonlocal shared
+            async for out in eng.generate(_req(b, 12), Context()):
+                if shared is None:
+                    seq = next(s for s in eng.running + eng.prefilling
+                               if s.num_prompt == len(b))
+                    shared = (seq.pages[:seq.prefix_hit // PS],
+                              np.asarray(eng.kv_k), np.asarray(eng.kv_v))
+                if out.finish_reason is not None:
+                    break
+
+        await second()
+        hit = eng.prefix_hit_tokens_total - hits0
+        pools = np.asarray(eng.kv_k), np.asarray(eng.kv_v)
+        await eng.stop()
+        return hit, shared, pools
+
+    hit, shared, pools = run_async(run())
+    assert handed and max(len(t) for _, t, _ in handed) >= 32
+    for pages, tokens, kv_k in handed:
+        fresh = Pools(cfg, pages=pages)
+        fresh.run_prefill(params, tokens, bucket=64)
+        got = _kv_rows(kv_k, pages, 0, len(tokens))
+        want = _kv_rows(fresh.kv_k, pages, 0, len(tokens))
+        assert np.abs(got - want).max() < 1e-5, len(tokens)
+    assert hit == 32 and len(shared[0]) == 4
+    for was, now in zip(shared[1:], pools):
+        assert (was[:, shared[0]] == now[:, shared[0]]).all()
 
 
 def test_a_block_that_straddles_a_page_is_refused():
@@ -564,12 +743,15 @@ def test_a_block_that_straddles_a_page_is_refused():
 # ------------------------------------------------ (e) preemption and resume
 
 
-def test_preempt_and_resume_equals_an_uninterrupted_run(run_async):
+@pytest.mark.parametrize("strategy", STRATEGIES)
+def test_preempt_and_resume_equals_an_uninterrupted_run(strategy, run_async):
     """(e) a pool too small for four rows preempts some; a preempted row
     gives up its pages, comes back, prefills the whole blocks of prompt +
-    what it had generated, opens its next block with the tail, and
-    answers as it does alone."""
-    cfg = tiny()
+    what it had generated (its last block among them: none is pending
+    any more), opens its next block with the tail, and answers as it
+    does alone. The rows that stay are seeded from the host after the
+    flush, each with its pending block."""
+    cfg = tiny(remasking_strategy=strategy)
     params = make_params(cfg, 6)
     prompts = _prompts(6, 14, 15, 16, 13)
     eng = _engine(cfg, params, num_pages=14, watermark_pages=1,
@@ -630,7 +812,8 @@ def test_a_sharp_head_lets_the_dynamic_strategy_finish_early(run_async):
         blocks += counts["blocks"]
     assert early > 0 and fwd < 4 * blocks
     assert stats["diffusion_early_exits_total"] == early
-    assert stats["diffusion_forwards_total"] == fwd + commit
+    assert stats["diffusion_forwards_total"] == fwd
+    assert stats["diffusion_folded_commits_total"] == commit - len(prompts)
     assert stats["diffusion_blocks_total"] == blocks
 
 

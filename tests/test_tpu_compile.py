@@ -735,13 +735,17 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
                                                        tpu_kernel_path,
                                                        program):
     """The programs of sdar-30b-a3b-chat.decode-heavy at the cell's pool
-    ([6, 1280, 4, 64, 128]) and engine data, every width as published
-    (the expert count cut to 8 so the compile stays short): the block
-    window (B 64, P 32, the cell's decode_steps: whole blocks of 4, each
-    a while loop of denoising forwards and a commit forward, the decode
-    kernel at group 8 x 4 = 32 inside) and a block-causal prefill chunk
-    (PB 8 x T 256, the prefill kernel with the block edge). The pools
-    alias their inputs, and the window holds no copy of a pool's size:
+    ([6, 1280, 4, 64, 128]) and engine data, every width as published:
+    the block window (B 64, P 32, the cell's decode_steps: whole blocks
+    of 4, each a [B, 2L] forward that carries the block before it, with
+    the decode kernel at group 8 x 8 = 64 and the 512 rows' experts
+    through ops/moe_grouped.py, then a while loop of [B, L] denoising
+    forwards, the kernel at group 32 and the experts dense; a token
+    operand of two blocks a row) and a block-causal prefill chunk (PB 8
+    x T 256, the prefill kernel with the block edge; the expert count
+    cut to 8 so that compile stays short: it takes the dense form). The
+    pools alias their inputs, and the window holds no copy of a pool's
+    size:
     its pools are read-only inside the loops and written once, by
     commit_window, along their major axis. Neither does the prefill,
     block-causal or causal (the same program with block_length 1, cell
@@ -760,7 +764,8 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
     cfg = ModelConfig.from_local_path(os.path.join(
         root, "benchmark", "configs", "sdar-30b-a3b-chat"))
     assert cfg.block_length == 4 and e["decode_steps"] % 4 == 0
-    cfg = dataclasses.replace(cfg, num_experts=8)
+    if program == "prefill":
+        cfg = dataclasses.replace(cfg, num_experts=8)
     params, kv_k, kv_v = _engine_shapes(cfg, one_chip, e["num_pages"])
     assert kv_k.shape == (6, 1280, 4, PS, 128)
     s = partial(_sds, one_chip)
@@ -787,19 +792,33 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
         B = e["max_batch"]
         i32, f32 = s((B,), jnp.int32), s((B,), jnp.float32)
         compiled = llama.make_decode_window_fn(cfg, True, 64).lower(
-            params, s((B, cfg.block_length), jnp.int32), i32,
+            params, s((B, 2 * cfg.block_length), jnp.int32), i32,
             s((B,), jnp.bool_), i32, i32, kv_k, kv_v, s((B, P), jnp.int32),
             f32, i32, f32, s((B,), jnp.uint32), s((B, 8), jnp.int32), None,
             k_steps=e["decode_steps"],
             logprobs_topn=20 if program == "window-logprobs" else 0
         ).compile()
     assert _has_kernel(compiled)
-    assert _pool_sized_copies(compiled.as_text(), kv_k.size) == []
+    text = compiled.as_text()
+    assert _pool_sized_copies(text, kv_k.size) == []
+    # a block's two forwards and the sorted dispatch of the first, once
+    # each: the window's blocks are ONE loop
+    assert text.count("tpu_custom_call") == 3
+    # the two-block forward's sorted dispatch reads w[layer, expert]
+    # where the stacks lie: nothing that runs only MOVES a layer's
+    # experts (sliced out of the scan's xs for the kernel, each of the
+    # three would be copied a layer a forward: 1.2 GB)
+    down = params["w_down"]
+    assert _weight_sized_relayouts(text, down.size // down.shape[0],
+                                   pools=(kv_k.size,)) == []
     mem = compiled.memory_analysis()
     pool_bytes = kv_k.size * kv_k.dtype.itemsize
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     # the agreement check's variant holds [B x 4, V] log-probabilities
-    # and their top 20 besides. The other: 145 MB + THREE arrays of the
+    # and their top 20 besides. The other (595.5 MiB, 585.0 on the parent
+    # of PR 62: the two-block forward's rows in and out of the sorted
+    # dispatch, [4,096 + padding, 2,048], and a buffer of one block
+    # more; scratch compile, PR 62): 145 MB + THREE arrays of the
     # logits' size (156 MB each) in the arm of the draw that a sampled
     # row switches on, where the program without a branch held two: the
     # head's output (the logits over the temperature) is the operand of
@@ -810,13 +829,17 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
     assert mem.temp_size_in_bytes < (
         2 * pool_bytes if program == "window-logprobs"
         else pool_bytes + logits_bytes)
-    # what the arm of a sampled row costs beside that is the parent's:
-    # the two relayouts a draw (a denoising forward's, the commit's), and
-    # the temperature's divide in the head's own output fusion, not a
-    # pass over the logits of its own
+    # what the arm of a sampled row costs beside that: the two relayouts
+    # a draw, at the two places a block draws (after its first forward,
+    # which is peeled out of the denoising loop, and in that loop's body:
+    # 2 x 2 = 4, the parent's count, which unrolled two blocks of one
+    # place each; none of them runs for a greedy batch and no two are
+    # alive at once, as the bound above says), and the temperature's
+    # divide in the head's own output fusion, not a pass over the logits
+    # of its own
     if program == "window":
         import re
-        text, rows, V = compiled.as_text(), B * cfg.block_length, cfg.vocab_size
+        rows, V = B * cfg.block_length, cfg.vocab_size
         chunks = "%d,%d,128" % (rows, -(-V // 128))
         assert len(re.findall(r" = f32\[(?:%d,%d|%s)\]\S* copy\(" % (
             rows, V, chunks), text)) == 4

@@ -4,7 +4,7 @@ operands the model module's programs take: benchmark/rehearse.py's
 memory count for the cells it cannot lower (a model that keeps
 recurrent state: ``(state, state_slots)`` trailing operands, and
 ``state_src`` where the module snapshots; a window whose token operand
-is a block a row; a model with a K/V pool a kind of layer: the window
+is two blocks a row; a model with a K/V pool a kind of layer: the window
 layers' pools and the rows' tables into them in the same two places),
 and a digest of each program as lowered.
 
@@ -181,7 +181,9 @@ def main() -> int:
                     *state_args(PB, prefill=True, T=T)))
         for B in grid["decode_batches"]:
             row_i, row_f = s((B,)), s((B,), jnp.float32)
-            tokens = row_i if L == 1 else s((B, L))
+            # a block window's token operand: the pending block beside the
+            # open one
+            tokens = row_i if L == 1 else s((B, 2 * L))
             for topn in ((0, 20) if a.digest else (0,)):
                 record(f"window B={B} P={P} topn={topn}", window.lower(
                     params, tokens, row_i, s((B,), jnp.bool_), row_i,

@@ -4,7 +4,8 @@ by diffusion over blocks, without a chip:
     python3 tools/diffusion_block_memory.py [--workload CELL] [--steps 8,16]
 
 rehearse.py lowers the decode window with a ``[B]`` token operand; such
-a model's window takes ``[B, L]`` (a block a row), so rehearse.py cannot
+a model's window takes ``[B, 2L]`` (two blocks a row: the pending one
+beside the open one, llama._make_block_window_fn), so rehearse.py cannot
 size its cell (PERF.md section 7) and this does, the same way: every
 program of the cell's warm grid (each prefill bucket, each window bucket
 with top-20 logprobs on the smallest), the benchmark's weight maker and
@@ -118,7 +119,7 @@ def main() -> None:
                 for topn in (0, 20) if first else (0,):
                     record(f"window B={B} P={P} steps={K} topn={topn}",
                            window.lower(
-                               params, s((B, L), i32), row_i,
+                               params, s((B, 2 * L), i32), row_i,
                                s((B,), jnp.bool_), row_i, row_i, kv_k, kv_v,
                                s((B, P), i32), row_f, row_i, row_f,
                                s((B,), jnp.uint32),
