@@ -617,6 +617,11 @@ class JaxEngine:
             self.kv_window_pages_seen_total = 0
             self.decode_row_steps_total = 0
             self.decode_row_steps_past_window_total = 0
+            # positions a prefill program ran the self half and the
+            # cross half of the stack on, for a family whose record
+            # declares cross_on_last (_cross_rows_stats)
+            self.self_rows_total = 0
+            self.cross_rows_total = 0
         if mesh is not None:
             from ..parallel.mesh import shard_kv_cache, shard_params
             self.params = shard_params(self.params, model_cfg, mesh)
@@ -896,15 +901,23 @@ class JaxEngine:
         return jax.default_device(self.device)
 
     def _state_args(self, slots, src=None) -> tuple:
-        """The trailing operands of a step program of a model with
-        recurrent state: the pools and the rows' slots, and for a prefill
-        of a module that snapshots by the page (``src`` given) the page
-        whose snapshot each row starts from (-1: none). None otherwise."""
-        if self.family.pool_by_kind:
-            # the window layers' pools and the rows' tables into them
-            # (_window_tables): the same two places
+        """The trailing operands of a step program: the pools a family
+        keeps beside the K/V pools and the rows' places in them
+        (``_row_slots``), in the two positional places ``state`` and
+        ``state_slots``. A family with recurrent state passes (state
+        pools, slots) and, for a prefill of a module that snapshots by
+        the page (``src`` given), the page whose snapshot each row starts
+        from (-1: none); a family whose window layers keep pools of their
+        own (window pools, the rows' tables); a family that declares BOTH
+        the two as pairs in that one order, window first: ((window pools,
+        state pools), (tables, slots)). Nothing otherwise."""
+        fam = self.family
+        if fam.pool_by_kind:
+            # _window_tables: the same two places
+            if fam.init_state is not None:
+                return ((self.wkv, self.state), slots)
             return (self.wkv, slots)
-        if self.family.init_state is None:
+        if fam.init_state is None:
             return ()
         if src is None or not self._state_snapshots:
             return (self.state, slots)
@@ -914,26 +927,50 @@ class JaxEngine:
         """``src`` of n rows that start from no page's snapshot."""
         return np.full(n, -1, np.int32)
 
+    def _keep_pools(self, pools) -> None:
+        """What a program returned last, kept as the engine's again: the
+        window layers' pools, the state, or (window pools, state), by
+        what the family's record declares (``_state_args``' order)."""
+        fam = self.family
+        if fam.pool_by_kind and fam.init_state is not None:
+            self.wkv, self.state = pools
+        elif fam.pool_by_kind:
+            self.wkv = pools
+        elif fam.init_state is not None:
+            self.state = pools
+
     def _take_state(self, out):
-        """A step program's results without the pool it returned last
-        (kept as the engine's): the window layers' pools or the state,
-        by what the family's record declares."""
-        if self.family.pool_by_kind:
-            *out, self.wkv = out
-        elif self.family.init_state is not None:
-            *out, self.state = out
+        """A step program's results without the pools it returned last
+        (``_keep_pools``)."""
+        if self.family.pool_by_kind or self.family.init_state is not None:
+            *out, pools = out
+            self._keep_pools(pools)
         return out
+
+    def _row_slots(self, batch, B: int, wrows=(), T: Optional[int] = None,
+                   paged: bool = False):
+        """The rows' places in what the family keeps beside the K/V
+        pools, for a program of ``B`` rows of which ``batch`` lead: the
+        state slot of each (the drop slot for padding), the tables into
+        the window layers' pool (``_window_tables`` of ``wrows``, ``T``,
+        ``paged``; ``wrows`` may be a generator: only a family with such a
+        pool reads it), both as (tables, slots) for a family that
+        declares both, None for one that declares neither."""
+        slots = None
+        if self.family.init_state is not None:
+            slots = np.full(B, self.ecfg.max_batch, np.int32)
+            slots[:len(batch)] = [s.state_slot for s in batch]
+        if not self.family.pool_by_kind:
+            return slots
+        tables = self._window_tables(wrows, B, T, paged)
+        return tables if slots is None else (tables, slots)
 
     def _drop_slots(self, n: int, T: Optional[int] = None,
                     paged: bool = False):
-        """Slot operand of n rows that all read and write the drop slot
-        (None where the model keeps no state); for a model with window
-        pools, the tables of n padding rows (``T``, ``paged``:
-        _window_tables)."""
-        if self.family.pool_by_kind:
-            return self._window_tables([], n, T, paged)
-        return (None if self.family.init_state is None
-                else np.full(n, self.ecfg.max_batch, np.int32))
+        """``_row_slots`` of n padding rows: every row reads and writes
+        the drop slot and page 0 of the window layers' pool, and writes
+        nothing there."""
+        return self._row_slots((), n, (), T, paged)
 
     def _window_tables(self, rows, B: int, T: Optional[int] = None,
                        paged: bool = False):
@@ -993,10 +1030,7 @@ class JaxEngine:
             out, topn, counts=fam.by_blocks or bool(fam.window_counts),
             state=fam.pool_by_kind or fam.init_state is not None)
         self.kv_k, self.kv_v = res.kv_k, res.kv_v
-        if fam.pool_by_kind:
-            self.wkv = res.state
-        elif fam.init_state is not None:
-            self.state = res.state
+        self._keep_pools(res.state)
         return res._replace(kv_k=None, kv_v=None, state=None)
 
     @property
@@ -1544,6 +1578,7 @@ class JaxEngine:
             "kv_total_blocks": self.ecfg.num_pages - 1,
             **self._state_stats(),
             **self._window_pool_stats(),
+            **self._cross_rows_stats(),
             **self.window_counts,
             **self._diffusion_stats(),
             "num_requests_waiting": len(self.waiting),
@@ -1638,6 +1673,17 @@ class JaxEngine:
                 "state_restores_total": self.state_restores_total,
                 # the pool by slot and, where there is one, by page
                 "state_pool_bytes": int(sum(x.nbytes for x in self.state))}
+
+    def _cross_rows_stats(self) -> dict:
+        """stats() of a family whose programs run the layers that keep
+        nothing a position on each row's last position alone (none for
+        any other): the positions, padding included, that the prefill
+        programs ran the self half on (rows x chunk length a dispatch)
+        and the cross half on (rows x 1)."""
+        if not self.family.cross_on_last:
+            return {}
+        return {"self_rows_total": self.self_rows_total,
+                "cross_rows_total": self.cross_rows_total}
 
     def _window_pool_stats(self) -> dict:
         """stats() of the window layers' pool (none for a model with one
@@ -2352,15 +2398,11 @@ class JaxEngine:
             self._give_back([(s, s.computed) for s in batch])
             for seq, chunk in zip(batch, chunks):
                 self.wpm.cover(seq.wpages, seq.wfirst, seq.computed + chunk)
-            sslots = self._window_tables(
-                [(s, s.computed, c) for s, c in zip(batch, chunks)], B, T,
-                use_paged)
-        else:
-            sslots = self._drop_slots(B)
+        sslots = self._row_slots(
+            batch, B, ((s, s.computed, c) for s, c in zip(batch, chunks)),
+            T, use_paged)
         ssrc = self._no_src(B)
         for i, (seq, chunk) in enumerate(zip(batch, chunks)):
-            if self.state is not None:
-                sslots[i] = seq.state_slot
             start = seq.computed
             self.prefill_row_chunks_total += 1
             self.prefill_row_chunks_carried_total += start > 0
@@ -2395,6 +2437,9 @@ class JaxEngine:
         self.steps += 1
         self.prefill_slots_total += B * T
         self.prefill_dispatches_total += 1
+        if self.family.cross_on_last:
+            self.self_rows_total += B * T
+            self.cross_rows_total += B
         self.moe_grouped_programs_total += moe_kernel_takes(
             self.cfg, self.params, self.mesh, B * T)
         self._stamp_first_dispatch(batch)
@@ -2616,12 +2661,9 @@ class JaxEngine:
         positions = np.full(B, -1, np.int32)
         table = np.zeros((B, P), np.int32)
         slots = np.full(B, DROP_SLOT, np.int32)
-        sslots = self._drop_slots(B) if self.wkv is None else \
-            self._window_tables([(s, len(s.tokens) - 1, 1) for s in batch],
-                                B, 1)
+        sslots = self._row_slots(
+            batch, B, ((s, len(s.tokens) - 1, 1) for s in batch), 1)
         for i, seq in enumerate(batch):
-            if self.state is not None:
-                sslots[i] = seq.state_slot
             pos = len(seq.tokens) - 1  # position of last_token
             tokens[i] = seq.last_token
             positions[i] = pos
@@ -2875,13 +2917,10 @@ class JaxEngine:
         else:
             table = np.zeros((B, P), np.int32)
             eos = np.full((B, E), -1, np.int32)
-            sslots = self._drop_slots(B) if self.wkv is None else \
-                self._window_tables([(s, 0, 0) for s in batch], B)
+            # a row's state slot is fixed from admission to release, and
+            # a release changes the batch: safe under the key above
+            sslots = self._row_slots(batch, B, ((s, 0, 0) for s in batch))
             for i, seq in enumerate(batch):
-                if self.state is not None:
-                    # fixed from admission to release, and a release
-                    # changes the batch: safe under the key above
-                    sslots[i] = seq.state_slot
                 table[i, :len(seq.pages)] = seq.pages
                 ids: List[int] = []
                 if not seq.req.stop.ignore_eos:

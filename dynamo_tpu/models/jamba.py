@@ -1,7 +1,7 @@
 """Jamba: Mamba-1 mixers with attention every ``attn_layer_period`` layers
 (AI21 Jamba family, ``model_type: jamba``), on the engine's paged step-fn
-contract plus one thing no other module has: **per-sequence recurrent
-state that is not a KV page**.
+contract plus the thing this module brought and every module on its
+layout has since: **per-sequence recurrent state that is not a KV page**.
 
 Layer l: ``h += Mixer_l(rms_norm(h))`` then ``h += MLP(rms_norm(h))``
 (SwiGLU, dense, every layer). ``Mixer_l`` attends where ``(l -
@@ -142,13 +142,17 @@ def init_kv_cache(cfg: ModelConfig, spec: KVCacheSpec,
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
-def init_state(cfg: ModelConfig, slots: int, dtype=None) -> State:
+def init_state(cfg: ModelConfig, slots: int, dtype=None,
+               layers: Optional[int] = None) -> State:
     """The recurrent-state pools for ``slots`` sequences (what declares
     to the engine that this module's sequences carry state beside pages):
     the scan states ``[S, M, N, d_inner]`` float32, slot-major, and the
     conv tails ``[M, S, (d_conv - 1) * d_inner]``, layer-major (a row's
-    last d_conv - 1 inputs of a layer, oldest first, d_inner each)."""
-    M, N, di = num_mamba_layers(cfg), cfg.mamba_d_state, cfg.mamba_d_inner
+    last d_conv - 1 inputs of a layer, oldest first, d_inner each).
+    ``layers``: M where the Mamba layers are not Jamba's
+    (models/phi4flash.py)."""
+    M = layers or num_mamba_layers(cfg)
+    N, di = cfg.mamba_d_state, cfg.mamba_d_inner
     return (jnp.zeros((slots, M, N, di), jnp.float32),
             jnp.zeros((M, slots, (cfg.mamba_d_conv - 1) * di),
                       dtype or cfg.jax_dtype))
@@ -305,7 +309,7 @@ def _causal_conv(mp, x, valid, tail, dc: int, scope: str = "ssm.conv",
 
 
 def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step,
-           tail_step=None):
+           tail_step=None, hand_down: bool = False):
     """The Mamba-1 mixer on a chunk. u [B, T, D] (normed); valid [B, T]
     (a row's valid tokens lead); s [B, N, di] float32 and tail
     [B, (d_conv - 1) * di]: the rows' state on entry. Returns (out [B, T, D],
@@ -313,7 +317,12 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step,
     is the one-token recurrence (T == 1) with _ssm_step's operands and
     results, ``s`` being whatever it carries: the rows' states, or the
     pool they lie in (_stack); ``tail_step`` likewise the one-token
-    advance of the conv tails (_causal_conv)."""
+    advance of the conv tails (_causal_conv). dt_r, B and C go through
+    Jamba's three inner RMSNorms where ``mp`` holds their leaves
+    (``dt_norm``), and as they are where it does not (the published
+    Mamba-1 mixer: models/phi4flash.py). ``hand_down``: the scan's
+    output ``y`` [B, T, di] float32, before the gate and the output
+    projection, is returned as a fourth result."""
     f32 = jnp.float32
     B, T, _ = u.shape
     di, N, R, dc = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank,
@@ -335,9 +344,10 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step,
                                 tail_step=tail_step)
         with jax.named_scope("ssm.proj"):
             dt_r, b, c = jnp.split(dot(xc, mp["w_x"]), [R, R + N], axis=-1)
-            dt_r = rms_norm(dt_r, mp["dt_norm"].astype(f32), eps)
-            b = rms_norm(b, mp["ssm_b_norm"].astype(f32), eps)
-            c = rms_norm(c, mp["ssm_c_norm"].astype(f32), eps)
+            if "dt_norm" in mp:
+                dt_r = rms_norm(dt_r, mp["dt_norm"].astype(f32), eps)
+                b = rms_norm(b, mp["ssm_b_norm"].astype(f32), eps)
+                c = rms_norm(c, mp["ssm_c_norm"].astype(f32), eps)
             dt = jax.nn.softplus(dot(dt_r, mp["w_dt"])
                                  + mp["b_dt"].astype(f32))
             dt = jnp.where(valid[:, :, None], dt, 0.0)          # [B, T, di]
@@ -351,7 +361,7 @@ def _mamba(cfg: ModelConfig, mp, u, valid, s, tail, step=_ssm_step,
             y = y + mp["d_skip"].astype(f32) * xc
         with jax.named_scope("ssm.proj"):
             out = dot(y * jax.nn.silu(z), mp["w_out"])
-    return out, s, tail
+    return (out, s, tail, y) if hand_down else (out, s, tail)
 
 
 def _dense_ff(params: Params, cfg: ModelConfig, norm, h, l, valid,

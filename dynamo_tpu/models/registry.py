@@ -9,7 +9,8 @@ module's docstring says what its layers are) with its ``read_config(dict)
 order ``family_of`` asks in: a family whose configuration also has what
 a later one asks for stands first (kimi_linear before mla; nemotron_h,
 whose layer is ONE sub-block and whose Mamba-2 mixer is granite.py's run
-by groups, before granite).
+by groups, before granite; phi4flash, whose Mamba-1 layers stand beside
+window layers with a pool of their own, before jamba).
 
 **What a module writes.** Four functions the engine calls by name:
 ``init_params(cfg, key)``, ``init_kv_cache(cfg, spec)``,
@@ -39,8 +40,18 @@ What else it has, its record declares:
 - ``pool_by_kind``: the window layers keep K/V pools of their own
   (``init_window_kv_cache``, ``window_table_slots``; engine/kv_manager.py
   ``WindowPagePool``): the programs take ``(window pools, the rows'
-  tables)`` where a family with state takes ``(state, state_slots)``,
-  and nothing is published to the prefix cache.
+  tables)`` in the two places where a family with state takes ``(state,
+  state_slots)``, and nothing is published to the prefix cache. A
+  family that declares ``init_state`` as well (models/phi4flash.py)
+  takes both in those two places, as pairs in one order, window first:
+  ``((window pools, state), (tables, state_slots))``, and returns
+  ``(window pools, state)`` last; a sequence of it owns a state slot
+  and pages of both pools, and is refused what either capability is.
+- ``cross_on_last``: the module's programs run the layers that keep
+  nothing a position (models/phi4flash.py: the cross half) on each
+  row's last position alone; the engine counts, a prefill dispatch, the
+  positions each half ran on (``self_rows_total``, ``cross_rows_total``
+  in ``stats()``).
 - ``by_blocks``: a decode forward yields ``cfg.block_length`` tokens a
   row (diffusion over blocks). ``make_decode_window_fn`` returns the
   BLOCK window (same name ``decode_window``, same call form), whose
@@ -55,7 +66,7 @@ from types import ModuleType
 from typing import Callable, Dict, NamedTuple, Optional
 
 from . import (cohere2_moe, config, granite, jamba, kimi_linear, lfm2,
-               llama, mla, nemotron_h, solar_open2)
+               llama, mla, nemotron_h, phi4flash, solar_open2)
 from .config import ModelConfig
 
 
@@ -73,6 +84,7 @@ class ModelFamily(NamedTuple):
     make_verify_fn: Optional[Callable] = None
     window_counts: tuple = ()
     pool_by_kind: bool = False
+    cross_on_last: bool = False
     by_blocks: bool = False
 
     def refusal(self, feature: str) -> Optional[str]:
@@ -124,6 +136,11 @@ FAMILIES = (
                 lambda c: bool(c.layer_types), lfm2,
                 init_state=lfm2.init_state,
                 init_state_snapshots=lfm2.init_state_snapshots),
+    # Mamba-1 beside pools by kind before Mamba-1: phi4flash has both
+    ModelFamily("phi4flash", {"phi4flash": phi4flash.read_config},
+                lambda c: c.mamba_d_state > 0 and c.kv_pool_by_kind,
+                phi4flash, init_state=phi4flash.init_state,
+                pool_by_kind=True, cross_on_last=True),
     ModelFamily("jamba", {"jamba": jamba.read_config},
                 lambda c: c.mamba_d_state > 0, jamba,
                 init_state=jamba.init_state),

@@ -688,7 +688,7 @@ def test_jamba_still_takes_no_hit_and_declares_no_snapshots(run_async):
 def _refused(what):
     return pytest.raises(
         NotImplementedError,
-        match=f"{what}.*recurrent state.*jamba.py, models/lfm2.py.*"
+        match=f"{what}.*recurrent state.*jamba.py, .*models/lfm2.py.*"
               "under its page's id, or not at all")
 
 

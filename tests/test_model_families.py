@@ -39,6 +39,8 @@ CELLS = {
     "mixtral-8x7b": ("llama", "llama"),
     # since PR 60 (a family of its own, asked before granite's)
     "nemotron-3-super-120b-a12b": ("nemotron_h", "nemotron_h"),
+    # since PR 63 (a family of its own, asked before jamba's)
+    "phi-4-mini-flash-reasoning": ("phi4flash", "phi4flash"),
     "qwen3-30b-a3b": ("llama", "llama"),
     "sdar-30b-a3b-chat": ("llama_by_blocks", "llama"),
     "smallthinker-21b-a3b": ("llama_by_kind", "llama"),
@@ -70,6 +72,11 @@ TINY = {
                        moe_latent_size=8),
     "granite": dict(mamba_n_heads=2, mamba_d_state=4),
     "lfm2": dict(layer_types=("conv", "full_attention")),
+    # 8 layers by the family's rule: 2 x (Mamba, window), (Mamba, full),
+    # (memory unit, cross)
+    "phi4flash": dict(mamba_d_state=4, mamba_dt_rank=4, num_layers=8,
+                      kv_pool_by_kind=True, sliding_window=8,
+                      layer_window=(None, 8, None, 8) + (None,) * 4),
     "jamba": dict(mamba_d_state=4, mamba_dt_rank=4, attn_layer_period=2,
                   attn_layer_offset=1),
     "cohere2_moe": dict(parallel_block=True, kv_pool_by_kind=True),

@@ -5,7 +5,8 @@ memory count for the cells it cannot lower (a model that keeps
 recurrent state: ``(state, state_slots)`` trailing operands, and
 ``state_src`` where the module snapshots; a window whose token operand
 is two blocks a row; a model with a K/V pool a kind of layer: the window
-layers' pools and the rows' tables into them in the same two places),
+layers' pools and the rows' tables into them in the same two places; a
+model with both: pairs in those two places, JaxEngine._state_args),
 and a digest of each program as lowered.
 
     python3 tools/cell_programs.py --workload CELL            # memory
@@ -136,6 +137,8 @@ def main() -> int:
                 paged = prefill and T % ecfg.page_size == 0
                 tables += (s((rows, T // ecfg.page_size) if paged
                              else (rows, T)),)
+            if state is not None:   # both, as pairs, window first
+                return ((wkv, state), (tables, s((rows,))))
             return (wkv, tables)
         if state is None:
             return ()
