@@ -839,7 +839,10 @@ class JaxEngine:
         # tokens / rows against the slots of the bucket that ran
         self.prefill_slots_total = 0
         self.prefill_dispatches_total = 0
-        # of those, the dispatches behind which the same iteration
+        # of those, the programs in which no row's logits were wanted
+        # (_dispatch_prefill's last_idx all negative): no head computed
+        self.prefill_logits_skipped_total = 0
+        # and the dispatches behind which the same iteration
         # enqueued a decode window (_step_window, rule 2)
         self.prefill_window_topups_total = 0
         # and those enqueued in the same iteration as the prefill before
@@ -1380,13 +1383,17 @@ class JaxEngine:
         """Read what each warmed prefill program (PB, T) costs on this
         device, for _dispatch_prefill's choice of a batch bucket: every
         row live, and for PB > 1 one row live (prefill_cost_ms draws the
-        line between). Real token ids from a fixed key (all-zero tokens
-        would send every token to one expert), positions from 0, nothing
-        committed (dropped slots, pages and state slots), in warmup()'s
-        call form so that nothing compiles. Each program runs once, and
-        a second time for the lesser of two, the short ones first, while
-        the whole stays inside _COST_TIMING_SECONDS. One warmed bucket:
-        nothing to choose, nothing timed."""
+        line between). The table holds the arm WITH the head (every
+        ``last_idx`` >= 0, as a prompt's last chunk): choose_prefill_bucket
+        compares buckets like for like, and a chunk that skips the head
+        is cheaper by the same amount in every bucket. Real token ids
+        from a fixed key (all-zero tokens would send every token to one
+        expert), positions from 0, nothing committed (dropped slots,
+        pages and state slots), in warmup()'s call form so that nothing
+        compiles. Each program runs once, and a second time for the
+        lesser of two, the short ones first, while the whole stays
+        inside _COST_TIMING_SECONDS. One warmed bucket: nothing to
+        choose, nothing timed."""
         buckets = grid["prefill_batches"]
         self._prefill_costs = {}
         if len(buckets) < 2:
@@ -1603,6 +1610,8 @@ class JaxEngine:
             "prefill_tokens_total": self.prefill_tokens_total,
             "prefill_slots_total": self.prefill_slots_total,
             "prefill_dispatches_total": self.prefill_dispatches_total,
+            "prefill_logits_skipped_total":
+                self.prefill_logits_skipped_total,
             "prefill_window_topups_total":
                 self.prefill_window_topups_total,
             "prefill_runahead_total": self.prefill_runahead_total,
@@ -2295,7 +2304,17 @@ class JaxEngine:
         programs of one. So the batch formed here is what MAY ship, and
         choose_prefill_bucket takes the warmed bucket that costs the
         least a row by what warmup() read of the programs; the rows it
-        leaves stay in ``prefilling``, in order, for the next sweep."""
+        leaves stay in ``prefilling``, in order, for the next sweep.
+
+        ``last_idx`` tells the program which rows' logits will be read:
+        the chunk's last position for a row that ends its prompt here
+        and draws its first token from them (_draws_first_token), -1 for
+        every other row, padding included: a chunk in the middle of a
+        prompt, a preemption-resume, a model that generates by blocks.
+        A program with no entry >= 0 computes no head (and, for a
+        family that declares cross_on_last, none of the stack's suffix:
+        llama.prefill_logits) and returns zeros, which nobody reads;
+        ``prefill_logits_skipped_total`` counts those dispatches."""
         candidates: List[Sequence] = []
         for seq in list(self.prefilling):
             if seq.context.stopped:
@@ -2382,7 +2401,7 @@ class JaxEngine:
         tokens = np.zeros((B, T), np.int32)
         positions = np.full((B, T), -1, np.int32)
         table = np.zeros((B, P), np.int32)
-        last_idx = np.zeros(B, np.int32)
+        last_idx = np.full(B, -1, np.int32)
         ps = self.ecfg.page_size
         # page-granular KV commit when the bucket is page-aligned AND every
         # chunk start is (prefix hits are whole pages and chunk sizes are
@@ -2416,7 +2435,9 @@ class JaxEngine:
             positions[i, :chunk] = np.arange(start, start + chunk)
             pages = np.asarray(seq.pages, np.int64)
             table[i, :len(seq.pages)] = seq.pages
-            last_idx[i] = chunk - 1
+            if (start + chunk >= seq.prefill_extent
+                    and self._draws_first_token(seq)):
+                last_idx[i] = chunk - 1
             # flat slots are always built: they are the commit path of an
             # unaligned chunk; every model module ignores them when
             # page_slots is present
@@ -2437,9 +2458,11 @@ class JaxEngine:
         self.steps += 1
         self.prefill_slots_total += B * T
         self.prefill_dispatches_total += 1
+        wants_logits = bool((last_idx >= 0).any())
+        self.prefill_logits_skipped_total += not wants_logits
         if self.family.cross_on_last:
             self.self_rows_total += B * T
-            self.cross_rows_total += B
+            self.cross_rows_total += B * wants_logits
         self.moe_grouped_programs_total += moe_kernel_takes(
             self.cfg, self.params, self.mesh, B * T)
         self._stamp_first_dispatch(batch)
@@ -2466,13 +2489,22 @@ class JaxEngine:
         # compile per finishing-count); skipped entirely when every
         # finishing row is a preemption-resume (next token already sampled)
         # and for a model that generates by blocks (the logits at the
-        # prompt's last position are of that position's own token)
-        if self.block == 1 and any(s.generated == 0 for _, s in finishing):
+        # prompt's last position are of that position's own token): the
+        # program was told so and computed none
+        if wants_logits:
             sampled, aux = self._sample_device(batch, logits)
         else:
             sampled, aux = None, None
         return _PendingPrefill(finishing=finishing, sampled=sampled,
                                aux=aux)
+
+    def _draws_first_token(self, seq: Sequence) -> bool:
+        """Whether the prefill program that ends ``seq``'s prompt is
+        sampled from (_process_prefill appends the draw): not after a
+        preemption (the next token is sampled already), and never for a
+        model that generates by blocks (the logits at the prompt's last
+        position are of that position's own token)."""
+        return self.block == 1 and seq.generated == 0
 
     def _long_prefill(self, seq: Sequence) -> None:
         """Whole-prompt sequence-parallel prefill via ring attention: run
@@ -2569,7 +2601,7 @@ class JaxEngine:
         with self.profiler.phase("process_prefill"):
             for i, seq in pf.finishing:
                 self._commit_full_pages(seq)
-                if seq.generated == 0 and self.block == 1:
+                if self._draws_first_token(seq):
                     self._append_token(seq, int(toks[i]),
                                        lp=self._lp_entry(seq, aux, i))
                     if seq.finished is None:

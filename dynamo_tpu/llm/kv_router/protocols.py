@@ -151,6 +151,7 @@ STATS_PROMETHEUS_SKIP = {
            "first_token_seconds_total", "engine_ttft_seconds_total",
            "first_tokens_total", "prefill_tokens_total",
            "prefill_slots_total", "prefill_dispatches_total",
+           "prefill_logits_skipped_total",
            "prefill_window_topups_total", "prefill_runahead_total",
            "moe_grouped_programs_total",
            "prefill_rows_held_back_total", "prefill_bucket_narrowed_total",

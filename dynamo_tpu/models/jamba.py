@@ -70,8 +70,8 @@ from jax import lax
 from .config import ModelConfig, hf_base
 from .llama import (KVCacheSpec, Params, _at, _attention, _mlp,
                     _scatter_pages, _scatter_pages_paged, commit_window,
-                    embed_tokens, kernel_mode, logits_at, rms_norm,
-                    window_attention)
+                    embed_tokens, kernel_mode, logits_at, prefill_logits,
+                    rms_norm, window_attention)
 from .window import Family, make_window
 from ..ops.conv_step import conv_tail_step
 from ..ops.selective_scan import selective_scan_step
@@ -675,7 +675,7 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None,
             params, cfg, tokens, positions, kv_k, kv_v, page_table,
             flat_slots, state, state_slots, allow_pallas=allow_pallas,
             page_slots=page_slots, mesh=mesh, blocks=blocks)
-        return logits_at(params, cfg, h, last_idx), kv_k, kv_v, state
+        return prefill_logits(params, cfg, h, last_idx), kv_k, kv_v, state
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "state"))
     def decode_step(params, tokens, positions, kv_k, kv_v, page_table,

@@ -68,7 +68,7 @@ from .llama import (KVCacheSpec, Params, _at, _attention, _mlp,
                     _moe_use_blocked, _qk_headnorm, _scatter_pages,
                     _scatter_pages_paged, apply_rope, commit_window,
                     embed_tokens, kernel_mode, logits_at, moe_experts,
-                    rms_norm, rope_freqs, window_attention)
+                    prefill_logits, rms_norm, rope_freqs, window_attention)
 from .mla import _deepseek_gate
 from .window import Family, make_window
 
@@ -486,7 +486,7 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
             params, cfg, tokens, positions, kv_k, kv_v, page_table,
             flat_slots, state, state_slots, state_src,
             allow_pallas=allow_pallas, page_slots=page_slots, mesh=mesh)
-        return logits_at(params, cfg, h, last_idx), kv_k, kv_v, state
+        return prefill_logits(params, cfg, h, last_idx), kv_k, kv_v, state
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "state"))
     def decode_step(params, tokens, positions, kv_k, kv_v, page_table,

@@ -1368,6 +1368,37 @@ def logits_at(params: Params, cfg: ModelConfig, hidden: jax.Array,
     return project_logits(params, cfg, h_last)
 
 
+def wanted(last_idx: jax.Array) -> jax.Array:
+    """Whether any row of a prefill program wants its logits: a negative
+    ``last_idx`` says the row's are read by nobody (a chunk that ends no
+    prompt, a padding row: ``JaxEngine._dispatch_prefill`` writes them)."""
+    return jnp.any(last_idx >= 0)
+
+
+def no_logits(cfg: ModelConfig, B: int) -> jax.Array:
+    """What a prefill program returns for logits nobody wanted."""
+    with jax.named_scope("lm_head"):
+        return jnp.zeros((B, cfg.vocab_size), jnp.float32)
+
+
+def prefill_logits(params: Params, cfg: ModelConfig, hidden: jax.Array,
+                   last_idx: jax.Array) -> jax.Array:
+    """``logits_at`` as a PREFILL program ends: the head under one
+    conditional on ``wanted(last_idx)``, so a program that ends no
+    prompt does not read ``lm_head``; it returns zeros [B, V] float32.
+    Where a row wants logits the arithmetic is ``logits_at``'s, and a
+    row with a negative entry gets position 0's, which nobody reads.
+    Both arms are in the one executable of the bucket. The decode steps,
+    the windows and the verify forwards call ``logits_at``: every row of
+    theirs is sampled from."""
+    B = hidden.shape[0]
+    with jax.named_scope("lm_head"):
+        h_last = hidden[jnp.arange(B), jnp.maximum(last_idx, 0)]  # [B, D]
+    return lax.cond(wanted(last_idx),
+                    lambda: project_logits(params, cfg, h_last),
+                    lambda: no_logits(cfg, B))
+
+
 # ------------------------------------------- layers of two kinds, two pools
 
 
@@ -1563,7 +1594,7 @@ def _make_step_fns_by_kind(cfg: ModelConfig, allow_pallas: bool, mesh):
             params, cfg, tokens, positions, kv_k, kv_v, page_table,
             flat_slots, wkv, wtab, allow_pallas=allow_pallas,
             page_slots=page_slots, mesh=mesh)
-        return logits_at(params, cfg, h, last_idx), kv_k2, kv_v2, wkv2
+        return prefill_logits(params, cfg, h, last_idx), kv_k2, kv_v2, wkv2
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "wkv"))
     def decode_step(params, tokens, positions, kv_k, kv_v, page_table,
@@ -1620,7 +1651,7 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
                                   page_slots=page_slots, mesh=mesh)
         if cfg.block_length > 1:
             return None, kv_k2, kv_v2
-        return logits_at(params, cfg, h, last_idx), kv_k2, kv_v2
+        return prefill_logits(params, cfg, h, last_idx), kv_k2, kv_v2
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v"))
     def decode_step(params: Params, tokens: jax.Array, positions: jax.Array,

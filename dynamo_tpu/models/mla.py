@@ -47,7 +47,8 @@ from ..ops.paged_attention import (NEG_INF, latent_attention_decode_layered,
 from .config import ModelConfig, hf_base
 from . import llama
 from .llama import (KVCacheSpec, _at, _mlp, _moe_use_blocked, apply_rope,
-                    commit_window, logits_at, rms_norm, rope_freqs)
+                    commit_window, logits_at, prefill_logits, rms_norm,
+                    rope_freqs)
 from .window import Family, make_window
 
 Params = Dict[str, jax.Array]
@@ -563,7 +564,7 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
                             page_table, flat_slots,
                             allow_pallas=allow_pallas, mesh=mesh,
                             page_slots=page_slots)
-        return logits_at(params, cfg, h, last_idx), k2, v2
+        return prefill_logits(params, cfg, h, last_idx), k2, v2
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v"))
     def decode_step(params, tokens, positions, kv_k, kv_v, page_table,

@@ -59,7 +59,9 @@ from is read by nobody: a program runs the self half over its ``[B,
 T]`` chunk, gathers ``h`` and ``m`` at each row's ``last_idx`` and runs
 the cross half on ``[B, 1]`` (a decode step is the same at T 1). Exact,
 not an approximation (``forward(cross_all=True)`` is the other form,
-which tests/test_phi4flash.py sets beside this one).
+which tests/test_phi4flash.py sets beside this one). A prefill program
+in which no row ends its prompt runs none of it (``forward``'s
+``head``): nobody samples from a chunk in the middle of a prompt.
 
 The stack is three scanned runs: ``n_win`` x (Mamba-1, window attention),
 one (Mamba-1 that hands down m, full attention) unrolled, ``n_cross`` x
@@ -91,7 +93,7 @@ from .jamba import State, _mamba, _store_rows, _store_tails
 from .llama import (KVCacheSpec, Params, _at, _attention, _flat_pool, _mlp,
                     _relative, _write_layer_pages, commit_window,
                     embed_tokens, kernel_mode, layer_norm, logits_at,
-                    rms_norm, window_attention,
+                    no_logits, rms_norm, wanted, window_attention,
                     window_table_slots)  # noqa: F401
 from .window import Family, make_window
 from ..ops.conv_step import conv_tail_step
@@ -427,13 +429,20 @@ def _cross_half(cfg: ModelConfig, params: Params, h, m, attend_cross):
 def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
             page_table, flat_slots, last_idx, pools, places,
             allow_pallas: bool = True, page_slots=None, mesh=None,
-            cross_all: bool = False):
+            cross_all: bool = False, head=None):
     """A chunk [B, T] for every row from what it keeps: prefill, and K=1
     decode at T = 1. ``pools`` = (window K/V pools, state pools),
     ``places`` = ((table, base, write slots), state slots): the engine's
     ``_state_args``. The self half runs over the chunk; the cross half on
     each row's ``last_idx`` alone (every position with ``cross_all``).
-    Returns (hidden [B, 1 | T, D], kv_k, kv_v, pools)."""
+    Returns (hidden [B, 1 | T, D], kv_k, kv_v, pools).
+
+    With ``head`` (hidden [B, 1, D] -> logits: a prefill program's) the
+    first result is the logits, and the gather, the cross half and the
+    head run under ONE conditional on ``wanted(last_idx)``
+    (llama.prefill_logits' rule: a negative entry is a row nobody samples
+    from): a chunk that ends no prompt runs the self half, writes every
+    pool and the state, and returns zeros."""
     (wk, wv), state = pools
     (wtable, wbase, wslots), state_slots = places
     B, T = tokens.shape
@@ -479,13 +488,23 @@ def forward(params: Params, cfg: ModelConfig, tokens, positions, kv_k, kv_v,
     h, m, ssm, conv, (pk, pv), (fk, fv) = _self_half(
         cfg, params, h, valid, ssm, conv, in_pool, attend_window,
         (_flat_pool(wk), _flat_pool(wv)), attend_full)
-    pos_c = positions
-    if not cross_all:
-        rows = jnp.arange(B)
-        h, m = h[rows, last_idx][:, None], m[rows, last_idx][:, None]
-        pos_c = positions[rows, last_idx][:, None]
-    h = _cross_half(cfg, params, h, m,
-                    lambda q: attention(q, fk, fv, page_table, pos_c))
+
+    def suffix(idx):
+        hc, mc, pos_c = h, m, positions
+        if not cross_all:
+            rows = jnp.arange(B)
+            hc, mc = h[rows, idx][:, None], m[rows, idx][:, None]
+            pos_c = positions[rows, idx][:, None]
+        return _cross_half(cfg, params, hc, mc,
+                           lambda q: attention(q, fk, fv, page_table, pos_c))
+
+    if head is None:
+        h = suffix(last_idx)
+    else:
+        assert not cross_all
+        h = lax.cond(wanted(last_idx),
+                     lambda: head(suffix(jnp.maximum(last_idx, 0))),
+                     lambda: no_logits(cfg, B))
     if in_pool is None:
         ssm = _store_rows(state[0], state_slots, ssm)
     state = (ssm, _store_tails(state[1], state_slots, conv))
@@ -507,12 +526,12 @@ def make_step_fns(cfg: ModelConfig, allow_pallas: bool = True, mesh=None):
     def prefill_step(params, tokens, positions, kv_k, kv_v, page_table,
                      flat_slots, last_idx, page_slots=None, state=None,
                      state_slots=None):
-        h, kv_k, kv_v, state = forward(
+        return forward(
             params, cfg, tokens, positions, kv_k, kv_v, page_table,
             flat_slots, last_idx, state, state_slots,
-            allow_pallas=allow_pallas, page_slots=page_slots, mesh=mesh)
-        return (logits_at(params, cfg, h, jnp.zeros_like(last_idx)), kv_k,
-                kv_v, state)
+            allow_pallas=allow_pallas, page_slots=page_slots, mesh=mesh,
+            head=lambda h: logits_at(params, cfg, h,
+                                     jnp.zeros_like(last_idx)))
 
     @partial(jax.jit, donate_argnames=("kv_k", "kv_v", "state"))
     def decode_step(params, tokens, positions, kv_k, kv_v, page_table,

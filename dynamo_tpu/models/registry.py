@@ -49,9 +49,11 @@ What else it has, its record declares:
   and pages of both pools, and is refused what either capability is.
 - ``cross_on_last``: the module's programs run the layers that keep
   nothing a position (models/phi4flash.py: the cross half) on each
-  row's last position alone; the engine counts, a prefill dispatch, the
-  positions each half ran on (``self_rows_total``, ``cross_rows_total``
-  in ``stats()``).
+  row's last position alone, and a prefill program only where a row's
+  logits are wanted (a ``last_idx`` >= 0: ``llama.prefill_logits``; a
+  chunk that ends no prompt runs none of them); the engine counts, a
+  prefill dispatch, the positions each half ran on (``self_rows_total``,
+  and ``cross_rows_total`` where it asked for logits, in ``stats()``).
 - ``by_blocks``: a decode forward yields ``cfg.block_length`` tokens a
   row (diffusion over blocks). ``make_decode_window_fn`` returns the
   BLOCK window (same name ``decode_window``, same call form), whose

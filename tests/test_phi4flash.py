@@ -447,7 +447,8 @@ def test_the_books_show_the_architecture(sound):
     """ONE layer of K/V a token of context in the full pool, the window
     pool bounded by rows x (window + chunk) x the window layers, a state
     slot a row + the drop slot, and one stats() with both sets of keys;
-    the prefill programs ran the cross half on 1 position in 8."""
+    of the prompt's five prefill programs the one that ended it ran the
+    cross half, on 1 position in 8, and the four before on none."""
     eng, *_, stats = sound
     H, KV, hd = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim_
     per_token = (eng.kv_k.nbytes + eng.kv_v.nbytes) / (64 * PS)
@@ -464,7 +465,9 @@ def test_the_books_show_the_architecture(sound):
                 "decode_row_steps_past_window_total", "self_rows_total",
                 "cross_rows_total"):
         assert key in stats, key
-    assert stats["self_rows_total"] == CHUNK * stats["cross_rows_total"] > 0
+    assert stats["self_rows_total"] == 5 * CHUNK * stats["cross_rows_total"] > 0
+    assert stats["prefill_logits_skipped_total"] == 4
+    assert stats["prefill_dispatches_total"] == 5
     assert stats["state_slots_total"] == 4
 
 

@@ -259,6 +259,7 @@ def test_prefill_program_compiles(one_chip, tpu_kernel_path, flash):
         s((PB, P_PRE), jnp.int32), s((PB, T), jnp.int32),
         s((PB,), jnp.int32), s((PB, T // PS), jnp.int32)).compile()
     assert _has_kernel(compiled) == flash
+    _head_behind_one_conditional(compiled.as_text())
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 1024 ** 3)
@@ -435,6 +436,7 @@ def test_prefill_carries_its_pools_and_copies_none(one_chip, tpu_kernel_path,
         s((PB,), jnp.int32), s((PB, T // PS), jnp.int32)).compile()
     assert _has_kernel(compiled)
     assert _pool_sized_copies(compiled.as_text(), kv_k.size) == []
+    _head_behind_one_conditional(compiled.as_text())
     mem = compiled.memory_analysis()
     pool_bytes = kv_k.size * kv_k.dtype.itemsize
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
@@ -622,6 +624,7 @@ def test_latent_programs_make_no_pool_sized_copy(one_chip, tpu_kernel_path,
             kv_v, s((PB, P), jnp.int32), s((PB, T), jnp.int32),
             s((PB,), jnp.int32), pslots).compile()
     assert _has_kernel(compiled)      # decode's, and a multi-row chunk's
+    _head_behind_one_conditional(compiled.as_text(), program)
     assert _pool_sized_copies(compiled.as_text(), kv_v.size) == []
     mem = compiled.memory_analysis()
     pools = sum(x.size * x.dtype.itemsize for x in (kv_k, kv_v))
@@ -716,6 +719,7 @@ def test_lfm2_programs_alias_their_pools_and_copy_none(one_chip,
         assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
     smallest = min(kv_k.size, state[1].size)
     assert _pool_sized_copies(compiled.as_text(), smallest) == []
+    _head_behind_one_conditional(compiled.as_text(), program)
     mem = compiled.memory_analysis()
     pools = sum(x.size * x.dtype.itemsize
                 for x in (kv_k, kv_v, *state))
@@ -949,6 +953,7 @@ def test_jamba_programs_write_no_array_of_the_state_pools_size(
             s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), state,
             s((PB,), jnp.int32)).compile()
     text = compiled.as_text()
+    _head_behind_one_conditional(text, program)
     _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, state[0].size) == []
@@ -1056,6 +1061,7 @@ def test_granite_programs_write_no_array_of_the_state_pools_size(
             s((PB,), jnp.int32), s((PB, T // 64), jnp.int32), state,
             s((PB,), jnp.int32)).compile()
     text = compiled.as_text()
+    _head_behind_one_conditional(text, program)
     _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, state[0].size) == []
@@ -1196,6 +1202,7 @@ def test_kimi_linear_programs_write_no_array_of_a_pools_size(
         _kimi, program)
     B = e["max_batch"]
     text = compiled.as_text()
+    _head_behind_one_conditional(text, program)
     _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     # a chunk's scan is the chunk kernel, a token's the step kernel
@@ -1282,6 +1289,7 @@ def test_solar_open2_programs_write_no_array_of_a_pools_size(
     B = e["max_batch"]
     assert "attn.gate" in lowered.as_text(debug_info=True)
     text = compiled.as_text()
+    _head_behind_one_conditional(text, program)
     _tails_stay_layer_major(text, state[1], B, program)
     assert _has_kernel(compiled)
     # a chunk's scan is the chunk kernel, a token's the step kernel; the
@@ -1411,9 +1419,30 @@ def _smallthinker(one_chip):
     return cfg, params, kv, wkv, slots, e
 
 
+@pytest.fixture(scope="module")
+def by_kind_program(one_chip):
+    """program(cell, name, PB=None) -> (model, cfg, params, kv, wkv,
+    slots, e, the compiled program) of cell 9 (``smallthinker``) or 12
+    (``command_a``) through ``_compile_by_kind``, compiled once a run of
+    this file under the caller's ``tpu_kernel_path``: the pool rules and
+    the weight rule read the same window and the same ``decode_step``."""
+    made = {}
+
+    def program(cell, name, PB=None):
+        key = cell, name, PB if name == "prefill" else None
+        if key not in made:
+            shapes = (_command_a(one_chip) if cell == "command_a"
+                      else (llama, *_smallthinker(one_chip)))
+            made[key] = *shapes, _compile_by_kind(one_chip, *shapes, name,
+                                                  PB=PB)
+        return made[key]
+
+    return program
+
+
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_smallthinker_programs_write_no_array_of_either_pools_size(
-        one_chip, tpu_kernel_path, program):
+        by_kind_program, tpu_kernel_path, program):
     """models/llama.py by kind at the shapes of smallthinker-21b-a3b.
     mixed-length (full layers' pool [2, 5632, ...] = 0.69 GiB each of K
     and V, window layers' [6, 3520, ...] = 1.29 GiB each): the fused
@@ -1425,10 +1454,10 @@ def test_smallthinker_programs_write_no_array_of_either_pools_size(
     inputs, and the temporaries stay far under a pool (as scanned xs /
     ys, llama.forward's form until PR 49, the pools were 6.27 GiB of
     temporaries: scratch compile, PR 46)."""
-    cfg, params, (kv_k, kv_v), wkv, slots, e = _smallthinker(one_chip)
-    compiled = _compile_by_kind(one_chip, llama, cfg, params, (kv_k, kv_v),
-                                wkv, slots, e, program)
+    _, cfg, params, (kv_k, kv_v), wkv, slots, e, compiled = by_kind_program(
+        "smallthinker", program)
     text = compiled.as_text()
+    _head_behind_one_conditional(text, program)
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, kv_k.size) == []    # the smaller pool
     mem = compiled.memory_analysis()
@@ -1476,7 +1505,7 @@ def _command_a(one_chip):
 
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 def test_command_a_programs_write_no_array_of_either_pools_size(
-        one_chip, tpu_kernel_path, program):
+        by_kind_program, tpu_kernel_path, program):
     """models/llama.py by kind in its parallel form at the shapes of
     command-a-plus-05-2026.rag-long (the full layer's pool [1, 7232, ...]
     and the window layers' [3, 2337, ...], 0.88 GiB and 0.86 GiB each of
@@ -1488,10 +1517,10 @@ def test_command_a_programs_write_no_array_of_either_pools_size(
     and scatters whole pages. No copy of either pool's size exists, all
     four pools alias their inputs, and arguments + temporaries fit the
     chip."""
-    model, cfg, params, (kv_k, kv_v), wkv, slots, e = _command_a(one_chip)
-    compiled = _compile_by_kind(one_chip, model, cfg, params, (kv_k, kv_v),
-                                wkv, slots, e, program)
+    _, cfg, params, (kv_k, kv_v), wkv, slots, e, compiled = by_kind_program(
+        "command_a", program)
     text = compiled.as_text()
+    _head_behind_one_conditional(text, program)
     assert _has_kernel(compiled)
     assert _pool_sized_copies(text, wkv[0].size) == []  # the smaller pool
     mem = compiled.memory_analysis()
@@ -1649,8 +1678,8 @@ ENTRY %main.1 (w.1: bf16[4,64,32], x.1: bf16[8,64]) -> bf16[8,32] {
 
 @pytest.mark.parametrize("program", ["window", "decode_step", "prefill"])
 @pytest.mark.parametrize("cell", ["command_a", "smallthinker"])
-def test_by_kind_programs_relay_no_weight(one_chip, tpu_kernel_path, cell,
-                                          program):
+def test_by_kind_programs_relay_no_weight(by_kind_program, tpu_kernel_path,
+                                          cell, program):
     """models/llama.py by kind, cells 12 and 9: the q / k / v products
     hand their results over behind a fence (``llama._qkv``), so the
     layout the heads' consumers want is paid on q. Without it the
@@ -1665,14 +1694,10 @@ def test_by_kind_programs_relay_no_weight(one_chip, tpu_kernel_path, cell,
     compiled at ONE row, the smallest program of the warm grid: its
     activations are far under a layer's wq, so whatever moves that many
     elements and is no pool is a weight."""
-    if cell == "command_a":
-        model, cfg, params, kv, wkv, slots, e = _command_a(one_chip)
-    else:
-        model = llama
-        cfg, params, kv, wkv, slots, e = _smallthinker(one_chip)
-    compiled = _compile_by_kind(one_chip, model, cfg, params, kv, wkv, slots,
-                                e, program, PB=1)
+    _, cfg, params, kv, wkv, _, _, compiled = by_kind_program(cell, program,
+                                                              PB=1)
     assert _has_kernel(compiled)
+    _head_behind_one_conditional(compiled.as_text(), program)
     assert _weight_sized_relayouts(
         compiled.as_text(), params["wq"].size // cfg.num_layers,
         pools=(kv[0].size, wkv[0].size)) == []
@@ -1730,3 +1755,139 @@ def test_dense_experts_relay_no_expert_stack(one_chip, tpu_kernel_path,
         # 2,386.9 MiB with the relaid stack among them, 334.0 without
         # (scratch compile, PR 59)
         assert compiled.memory_analysis().temp_size_in_bytes < 512 * 2 ** 20
+
+
+# ----- a prefill program holds its head behind ONE conditional (PR 64)
+
+
+def _head_reads(text: str, leaf: str = "lm_head"):
+    """(the conditionals among the optimized program's instructions, the
+    instructions of its entry computation that read ``params[leaf]`` and
+    are neither a conditional nor the tuple one takes as a branch's
+    operand): what llama.prefill_logits asks of a prefill program is
+    (one, none)."""
+    comps = _computations(text)
+    conds = [line for body in comps.values() for line in body.splitlines()
+             if re.search(r" conditional\(", line)]
+    entry = next(b for b in comps.values() if b.startswith("ENTRY "))
+    head = re.search(r"%([\w.\-]+) = [^\n]* parameter\(\d+\)[^\n]*"
+                     r"op_name=\"params\[\\'" + leaf + r"\\'\]\"", entry)
+    if head is None:        # the embedding's transpose is the head
+        return conds, []
+    handed = {m for line in conds for m in re.findall(r"%([\w.\-]+)", line)}
+    reads = re.compile(r"[(,] ?%%%s[,)]" % re.escape(head.group(1)))
+    return conds, [
+        m.group(1) for line in entry.splitlines()
+        if reads.search(line)
+        and (m := re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line))
+        and not (re.search(r" conditional\(", line)
+                 or (re.search(r" tuple\(", line) and m.group(1) in handed))]
+
+
+def _head_behind_one_conditional(text: str, program: str = "prefill"):
+    """A prefill program's only conditional is llama.prefill_logits', and
+    nothing outside it reads the head (the other programs: not asked)."""
+    if program.startswith("prefill"):
+        conds, outside = _head_reads(text)
+        assert len(conds) == 1 and outside == []
+
+
+def test_a_read_of_the_head_outside_the_conditional_is_recognised():
+    text = """HloModule m, is_scheduled=true
+
+%zeros (e: ()) -> (f32[2,32]) {
+  %e = () parameter(0)
+  %c = f32[]{:T(128)} constant(0)
+  %b = f32[2,32]{1,0:T(2,128)} broadcast(%c), dimensions={}
+  ROOT %t = (f32[2,32]{1,0:T(2,128)}) tuple(%b)
+}
+
+%project (a: (bf16[64,32], bf16[2,64])) -> (f32[2,32]) {
+  %a = (bf16[64,32]{1,0:T(8,128)(2,1)}, bf16[2,64]{1,0:T(8,128)(2,1)}) parameter(0)
+  %w = bf16[64,32]{1,0:T(8,128)(2,1)} get-tuple-element(%a), index=0
+  %x = bf16[2,64]{1,0:T(8,128)(2,1)} get-tuple-element(%a), index=1
+  %d = f32[2,32]{1,0:T(2,128)} convolution(%x, %w), dim_labels=bf_io->bf
+  ROOT %t.1 = (f32[2,32]{1,0:T(2,128)}) tuple(%d)
+}
+
+ENTRY %main.1 (head.1: bf16[64,32], x.1: bf16[2,64], p.1: pred[]) -> f32[2,32] {
+  %head.1 = bf16[64,32]{1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="params[\\'lm_head\\']"}
+  %x.1 = bf16[2,64]{1,0:T(8,128)(2,1)} parameter(1), metadata={op_name="x"}
+  %p.1 = pred[]{:T(128)} parameter(2), metadata={op_name="p"}
+  %tuple.2 = (bf16[64,32]{1,0:T(8,128)(2,1)}, bf16[2,64]{1,0:T(8,128)(2,1)}) tuple(%head.1, %x.1)
+  %tuple.3 = () tuple()
+  %cond.1 = (f32[2,32]{1,0:T(2,128)}) conditional(%p.1, %tuple.2, %tuple.3), true_computation=%project, false_computation=%zeros
+  ROOT %g = f32[2,32]{1,0:T(2,128)} get-tuple-element(%cond.1), index=0
+}
+"""
+    conds, outside = _head_reads(text)
+    assert len(conds) == 1 and outside == []
+    # the head copied on its way in, and read by an op of the entry
+    copied = text.replace(
+        "  %tuple.2 = ", "  %copy.9 = bf16[64,32]{0,1:T(8,128)(2,1)} "
+        "copy(%head.1)\n  %tuple.2 = ")
+    assert _head_reads(copied)[1] == ["copy.9"]
+    plain = text.replace("tuple(%head.1, %x.1)", "tuple(%x.1, %x.1)").replace(
+        "  %tuple.3 = ", "  %dot.4 = f32[2,32]{1,0:T(2,128)} "
+        "convolution(%x.1, %head.1), dim_labels=bf_io->bf\n  %tuple.3 = ")
+    assert _head_reads(plain)[1] == ["dot.4"]
+    # a tuple that no conditional takes is a read
+    loose = text.replace("conditional(%p.1, %tuple.2, %tuple.3)",
+                         "conditional(%p.1, %tuple.3, %tuple.3)")
+    assert _head_reads(loose)[1] == ["tuple.2"]
+
+
+@pytest.fixture(scope="module")
+def phi4flash_cell(one_chip):
+    from tools.cell_programs import Cell
+
+    return Cell("phi-4-mini-flash-reasoning.long-think", one_chip)
+
+
+def test_phi4flash_prefill_keeps_head_and_cross_half_in_one_conditional(
+        phi4flash_cell, tpu_kernel_path, PB=1):
+    """Cell 14's PB 1 x T 512 prefill bucket (every width and the engine
+    data of the cell; PB 8 reads the same by the scratch compile of PR
+    64): ONE conditional holds the gather at the last position, the
+    seven cross layers and the head, so nothing of the entry computation
+    reads ``lm_head`` or a cross layer's memory-unit weights; the
+    conditional takes the full layer's K/V pool (1.41 GiB a side) and
+    the weights as operands and copies none: no copy of a
+    pool's size exists, every pool aliases its input, and the only
+    weight-sized ops that just move data are the parent's own four on
+    one layer's ``wq`` (2560 x 2560; scratch compile, PR 64). The other
+    arm is a broadcast of zeros."""
+    c = phi4flash_cell
+    (name, lowered), = [(n, low) for n, low in c.programs(kinds=("prefill",))
+                        if n.startswith(f"prefill PB={PB} ")]
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    _head_behind_one_conditional(text)
+    assert "params[\\'w_gmu_in\\']" in text
+    assert _head_reads(text, "w_gmu_in")[1] == []
+    zeros = [b for b in _computations(text).values()
+             if re.match(r"%region[\w.\-]* \(arg_empty_tuple", b)]
+    assert len(zeros) == 1 and " broadcast(" in zeros[0] \
+        and "fusion(" not in zeros[0]
+    pools = [x.size for x in c.pools()]
+    # (the conv tails' pool is smaller than a PB 8 chunk's activations)
+    assert _pool_sized_copies(text, min(c.kv_k.size, c.wkv[0].size,
+                                        c.state[0].size)) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(
+        x.size * x.dtype.itemsize for x in c.pools())
+    D = c.cfg.hidden_size   # a chunk's activations are far under a wq
+    moved = _weight_sized_relayouts(text, D * D, pools=tuple(pools))
+    assert sorted(shape.split("{")[0] for _, shape, _ in moved) == [
+        f"bf16[1,{D},{D}]"] * 2 + [f"bf16[{D},{D}]"] * 2
+
+
+def test_phi4flash_decode_step_holds_no_conditional(phi4flash_cell,
+                                                    tpu_kernel_path):
+    """``forward`` without a head is the decode step's, as it was: the
+    cross half follows the self half with no branch between (the lowered
+    program's digest is the parent's: tools/cell_programs.py --digest)."""
+    (_, lowered), = phi4flash_cell.programs(kinds=("decode_step",))
+    text = lowered.as_text()
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
