@@ -712,9 +712,11 @@ class JaxEngine:
             # [.., 1, ps, dr]) — rebuilding from GQA config fields here
             # would allocate wrong-shaped host pools for MLA and crash
             # the first offload landing
-            hk = (model_cfg.num_layers, self.ecfg.host_pages,
+            # (their leading axis too: models/longcat_flash.py keeps
+            # two pool entries a layer)
+            hk = (self.kv_k.shape[0], self.ecfg.host_pages,
                   *self.kv_k.shape[2:])
-            hv = (model_cfg.num_layers, self.ecfg.host_pages,
+            hv = (self.kv_v.shape[0], self.ecfg.host_pages,
                   *self.kv_v.shape[2:])
             if self.ecfg.host_tier_int8:
                 # compressed tier: int8 rows + f32 per-row scales — the

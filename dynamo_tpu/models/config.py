@@ -196,6 +196,19 @@ class ModelConfig:
     # the transition Diag(exp(g)) (I - beta k k^T) may have an eigenvalue
     # in (-1, 0)), 1 otherwise.
     kda_beta_scale: float = 1.0
+    # LongCat-Flash (models/longcat_flash.py): a layer is TWO latent
+    # attentions and two dense MLPs with ONE routed MoE that reads the
+    # first sub-block's post-attention norm and is added after the
+    # second sub-block's MLP (the pools have 2 x num_layers entries).
+    # mla_q_scale multiplies the queries the query LoRA makes,
+    # mla_kv_scale the normed latent (what the cache keeps): 1.0 = the
+    # DeepSeek form as it is. The last zero_experts outputs of the router
+    # are identity experts (no weights: a pair's "output" is the token
+    # itself times its gate weight); router_experts counts them, the
+    # real outputs are the identity_from before them.
+    mla_q_scale: float = 1.0
+    mla_kv_scale: float = 1.0
+    zero_experts: int = 0
     # Generation by diffusion over blocks (model_type "sdar_moe"): the
     # attention mask is causal across blocks of block_length positions and
     # bidirectional inside one, the logits at a position are the
@@ -268,6 +281,13 @@ class ModelConfig:
         """Outputs of the MoE router: the published count of experts,
         whatever share of them is held here."""
         return self.router_experts or self.num_experts
+
+    @property
+    def identity_from(self) -> int:
+        """The router output from which on an index names an identity
+        (zero-compute) expert; 0 where the router has none."""
+        return (self.router_width - self.zero_experts
+                if self.zero_experts else 0)
 
     @property
     def mamba_d_inner(self) -> int:

@@ -1006,7 +1006,8 @@ def _moe_use_blocked(mesh, n_tokens: int, n_experts: int,
 def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
                 blocked: bool, live=None, layer=None,
                 out_dtype=jnp.float32, first=None,
-                width: Optional[int] = None, act=jax.nn.silu) -> jax.Array:
+                width: Optional[int] = None, act=jax.nn.silu,
+                identity_from: int = 0) -> jax.Array:
     """The routed experts' MLPs on x [B, T, D], given a gate's output
     (weights, idx: [B, T, k]): the execution half of an MoE MLP, shared
     by every gate (the softmax top-k of ``_moe_mlp``, the sigmoid gate of
@@ -1049,10 +1050,26 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
     for an absent one; sorted: an absent pair is not live; ``width``,
     the experts the gate scored, sizes its blocks by the pairs that stay
     here). The result is this share's part of the sum; nothing stands in
-    for the rest."""
+    for the rest.
+
+    ``identity_from`` > 0: the gate's outputs from that index on are
+    identity (zero-compute) experts, which have no weights: such a pair
+    adds the token itself times its gate weight (``_identity_part``,
+    float32, in both forms and for every row handed in, whoever holds
+    the real experts: it goes with the token). The real outputs are
+    ``[0, identity_from)`` and the stacks hold ``[first, first + E)`` of
+    THOSE (``first`` None is read as 0), so an identity pair is never a
+    one-hot of the dense form and never a live pair of the sorted one.
+    0 = the gate has none, and nothing here is traced differently."""
     B, T, D = x.shape
     E = w_up.shape[-3]
     k = idx.shape[-1]
+    if identity_from:
+        first = first or 0
+        out = moe_experts(x, weights, idx, w_gate, w_up, w_down, blocked,
+                          live, layer, jnp.float32, first, width, act)
+        return (out + _identity_part(x, weights, idx, identity_from)
+                ).astype(out_dtype)
     if blocked:
         out = moe_experts_blocked(
             x.reshape(B * T, D).astype(jnp.float32),
@@ -1083,6 +1100,16 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
         return out.astype(out_dtype)
 
 
+def _identity_part(x: jax.Array, weights: jax.Array, idx: jax.Array,
+                   identity_from: int) -> jax.Array:
+    """What a token's identity pairs add, [B, T, D] float32:
+    ``x * sum_k w_k [idx_k >= identity_from]`` (``moe_experts``)."""
+    with jax.named_scope("moe.zero"):
+        w0 = jnp.sum(jnp.where(idx >= identity_from,
+                               weights.astype(jnp.float32), 0.0), axis=-1)
+        return x.astype(jnp.float32) * w0[..., None]
+
+
 def held_first(cfg: ModelConfig):
     """``moe_experts``' ``first``: None where every expert the router
     scores is here, else the index of the first one held."""
@@ -1096,9 +1123,14 @@ def pairs_counted(cfg: ModelConfig, idx: jax.Array,
     the ``valid`` [B, T] rows, and those whose expert is held here."""
     here = ((idx >= cfg.first_expert)
             & (idx < cfg.first_expert + cfg.num_experts))
-    return jnp.stack([
-        cfg.num_experts_per_tok * jnp.sum(valid),
-        jnp.sum(here & valid[..., None])]).astype(jnp.int32)
+    counted = [cfg.num_experts_per_tok * jnp.sum(valid),
+               jnp.sum(here & valid[..., None])]
+    if cfg.zero_experts:
+        # a third count where the router has identity outputs
+        # (longcat_flash.py ``WINDOW_COUNTS``): the pairs that chose one
+        counted.append(jnp.sum((idx >= cfg.identity_from)
+                               & valid[..., None]))
+    return jnp.stack(counted).astype(jnp.int32)
 
 
 def deepseek_gate(x32, w_router, bias, cfg: ModelConfig, precision=None):
@@ -1109,6 +1141,8 @@ def deepseek_gate(x32, w_router, bias, cfg: ModelConfig, precision=None):
 
     v2 (HF DeepseekV2MoEGate): softmax scores; optional group limiting by
     the MAX score per group; top-k; weights scaled (NOT renormalized).
+    longcat_flash: v2's softmax scores, selection by scores + bias as
+    v3's, no groups; weights scaled (NOT renormalized).
     v3 (HF DeepseekV3TopkRouter): sigmoid scores; selection by scores +
     e_score_correction_bias with groups ranked by their top-2 SUM; the
     applied weights are the ORIGINAL sigmoid scores of the selected
@@ -1124,7 +1158,10 @@ def deepseek_gate(x32, w_router, bias, cfg: ModelConfig, precision=None):
                   else scores + bias.astype(jnp.float32))
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-        choice = scores
+        # longcat_flash: the softmax scores WITH a selection bias (v2 has
+        # none), no groups, the chosen scores scaled and not renormalised
+        choice = (scores + bias.astype(jnp.float32)
+                  if cfg.moe_router == "longcat_flash" else scores)
     if cfg.n_group > 0 and cfg.topk_group > 0:
         G = cfg.n_group
         cg = choice.reshape(*choice.shape[:-1], G, E // G)
@@ -1175,7 +1212,8 @@ def deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
     out = moe_experts(x32, w, topi, lp["w_gate_e"], lp["w_up_e"],
                       lp["w_down_e"], layer is not None, live=live,
                       layer=layer, first=first,
-                      width=None if first is None else cfg.router_width)
+                      width=None if first is None else cfg.router_width,
+                      identity_from=cfg.identity_from)
     if cfg.n_shared_experts > 0:
         with jax.named_scope("moe.shared"):
             shared = (jax.nn.silu(x @ lp["w_gate_s"])

@@ -28,8 +28,16 @@ TPU-first design points:
   has the measurements). Neither forms [B, H, T, S].
 
 Weight layout follows the DeepSeek-V2 architecture (q LoRA optional,
-kv LoRA + decoupled rope head); MoE layers reuse the Mixtral-style
-dense-over-experts MLP from models/llama.py.
+kv LoRA + decoupled rope head); an expert layer runs the DeepSeek gate
+and ``llama.moe_experts`` (two forms, the dense einsum over every expert
+and the sorted dispatch, and a chip's held share of the experts), the
+execution every gate of models/llama.py shares.
+
+The latent functions here (``_latent_qkv``, ``_latent_out``,
+``_attend_pool``, ``_attend_local``, ``_merge``, ``_commit_chunk``) are
+read by models/kimi_linear.py (its attending layers) and by
+models/longcat_flash.py (two latent attentions a layer over pools of
+2 x num_layers entries, the query LoRA with its two scales).
 """
 
 from __future__ import annotations
@@ -362,7 +370,9 @@ def _merge(a, b):
 def _latent_qkv(cfg: ModelConfig, lp, x, safe_pos, inv_freq, dtype):
     """x [B, T, D] (normed) -> absorbed queries q_lat [B, T, H, r] =
     q_nope . W_UK and q_rope [B, T, H, dr], and what the cache keeps of
-    the tokens: c_kv [B, T, r] (normed) and k_rope [B, T, dr], all in
+    the tokens: c_kv [B, T, r] (normed, times ``cfg.mla_kv_scale``; the
+    query LoRA's output times ``cfg.mla_q_scale``: both 1.0 but in
+    models/longcat_flash.py) and k_rope [B, T, dr], all in
     the pools' ``dtype``; both rope parts padded with zeros to the rope
     pool's width (rope_width; no padding off the TPU), which leaves every
     score what it was. With ``cfg.mla_nope`` neither side is rotated
@@ -374,6 +384,8 @@ def _latent_qkv(cfg: ModelConfig, lp, x, safe_pos, inv_freq, dtype):
     if cfg.q_lora_rank > 0:
         q_all = rms_norm(x @ lp["w_dq"], lp["q_norm"],
                          cfg.rms_norm_eps) @ lp["w_uq"]
+        if cfg.mla_q_scale != 1.0:      # longcat_flash: sqrt(D / q rank)
+            q_all = q_all * jnp.asarray(cfg.mla_q_scale, q_all.dtype)
     else:
         q_all = x @ lp["w_q"]
     q_all = q_all.reshape(B, T, H, dn + dr)
@@ -385,6 +397,8 @@ def _latent_qkv(cfg: ModelConfig, lp, x, safe_pos, inv_freq, dtype):
                        preferred_element_type=jnp.float32)
     ckr = x @ lp["w_dkv"]                                  # [B, T, r + dr]
     c_kv = rms_norm(ckr[..., :r], lp["kv_norm"], cfg.rms_norm_eps)
+    if cfg.mla_kv_scale != 1.0:         # longcat_flash: sqrt(D / kv rank)
+        c_kv = c_kv * jnp.asarray(cfg.mla_kv_scale, c_kv.dtype)
     k_rope = ckr[..., r:] if cfg.mla_nope else apply_rope(
         ckr[..., None, r:], safe_pos, inv_freq)[..., 0, :]  # one shared head
     pad = [(0, rope_width(cfg) - dr)]

@@ -7,7 +7,8 @@ tables and spell no family out. A new family writes a module (each
 module's docstring says what its layers are) with its ``read_config(dict)
 -> ModelConfig`` in it, and one record. The order of the records is the
 order ``family_of`` asks in: a family whose configuration also has what
-a later one asks for stands first (kimi_linear before mla; nemotron_h,
+a later one asks for stands first (kimi_linear and longcat_flash before
+mla; nemotron_h,
 whose layer is ONE sub-block and whose Mamba-2 mixer is granite.py's run
 by groups, before granite; phi4flash, whose Mamba-1 layers stand beside
 window layers with a pool of their own, before jamba).
@@ -68,7 +69,7 @@ from types import ModuleType
 from typing import Callable, Dict, NamedTuple, Optional
 
 from . import (cohere2_moe, config, granite, jamba, kimi_linear, lfm2,
-               llama, mla, nemotron_h, phi4flash, solar_open2)
+               llama, longcat_flash, mla, nemotron_h, phi4flash, solar_open2)
 from .config import ModelConfig
 
 
@@ -121,6 +122,11 @@ FAMILIES = (
                 lambda c: c.kda_n_heads > 0, solar_open2,
                 init_state=solar_open2.init_state,
                 window_counts=solar_open2.WINDOW_COUNTS),
+    # two latent attentions a layer and identity experts before latent
+    # ranks: longcat_flash has those too, and pools of 2 x num_layers
+    ModelFamily("longcat_flash", {"longcat_flash": longcat_flash.read_config},
+                lambda c: c.is_mla and c.moe_router == "longcat_flash",
+                longcat_flash, window_counts=longcat_flash.WINDOW_COUNTS),
     ModelFamily("mla", {"deepseek_v2": mla.read_config,
                         "deepseek_v3": mla.read_config},
                 lambda c: c.is_mla, mla),

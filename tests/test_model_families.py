@@ -36,6 +36,8 @@ CELLS = {
     "kanana-2-30b-a3b": ("mla", "mla"),
     "kimi-linear-48b-a3b": ("kimi_linear", "kimi_linear"),
     "lfm2-24b-a2b": ("lfm2", "lfm2"),
+    # since PR 65 (a family of its own, asked before mla's)
+    "longcat-flash-omni": ("longcat_flash", "longcat_flash"),
     "mixtral-8x7b": ("llama", "llama"),
     # since PR 60 (a family of its own, asked before granite's)
     "nemotron-3-super-120b-a12b": ("nemotron_h", "nemotron_h"),
@@ -67,6 +69,9 @@ TYPES = {
 TINY = {
     "kimi_linear": dict(kda_n_heads=2, kv_lora_rank=16),
     "solar_open2": dict(kda_n_heads=2),
+    "longcat_flash": dict(kv_lora_rank=16, q_lora_rank=24,
+                          moe_router="longcat_flash", num_experts=4,
+                          router_experts=12, zero_experts=4),
     "mla": dict(kv_lora_rank=16),
     "nemotron_h": dict(mamba_n_heads=2, mamba_d_state=4,
                        moe_latent_size=8),
