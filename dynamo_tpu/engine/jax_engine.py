@@ -859,6 +859,12 @@ class JaxEngine:
         # (ops/moe_grouped.py): known from the bucket's rows by the rule
         # the program was built under
         self.moe_grouped_programs_total = 0
+        # the same question of a decode window's forwards, a live row
+        # each: those whose program ran its experts as the kernel, of
+        # decode_rows_total (a token a step) or diffusion_forwards_total
+        # (a block window, whose two kinds of forward have rows of their
+        # own: _window_forwards_grouped)
+        self.moe_grouped_window_forwards_total = 0
         # what warmup() read of its prefill programs on this device
         # (_time_prefill_programs); empty until then, and
         # _dispatch_prefill keeps the config's rule
@@ -1621,6 +1627,8 @@ class JaxEngine:
             "prefill_row_chunks_carried_total":
                 self.prefill_row_chunks_carried_total,
             "moe_grouped_programs_total": self.moe_grouped_programs_total,
+            "moe_grouped_window_forwards_total":
+                self.moe_grouped_window_forwards_total,
             # the choice of a prefill's batch bucket (_dispatch_prefill)
             "prefill_rows_held_back_total":
                 self.prefill_rows_held_back_total,
@@ -3053,9 +3061,14 @@ class JaxEngine:
             for k, v in zip(self.window_counts, info):
                 self.window_counts[k] += int(v)
         elif info is not None:
-            for k, v in zip(_BLOCK_WINDOW_COUNTS,
-                            info[:len(pend.batch)].sum(axis=0)):
+            summed = dict(zip(_BLOCK_WINDOW_COUNTS,
+                              info[:len(pend.batch)].sum(axis=0)))
+            for k, v in summed.items():
                 self.diffusion[k] += int(v)
+            self.moe_grouped_window_forwards_total += (
+                self._window_forwards_grouped(
+                    info.shape[0], int(summed["blocks"]),
+                    int(summed["forwards"])))
             self.diffusion["tokens"] += int(counts[:len(pend.batch)].sum())
         if pend in self._inflight:
             self._inflight.remove(pend)
@@ -3105,6 +3118,10 @@ class JaxEngine:
         blocks of L)."""
         self.decode_rows_total += len(batch) * K
         self.decode_slots_total += B * K
+        if self.block == 1:
+            self.moe_grouped_window_forwards_total += (
+                len(batch) * K * moe_kernel_takes(
+                    self.cfg, self.params, self.mesh, B))
         self.decode_windows_total += 1
         self.decode_windows_sampled_total += any(
             not s.req.sampling.greedy for s in batch)
@@ -3121,6 +3138,20 @@ class JaxEngine:
             # window ahead, never behind)
             self.decode_row_steps_past_window_total += K * sum(
                 len(s.tokens) > self.wpm.window for s in batch)
+
+    def _window_forwards_grouped(self, B: int, blocks: int,
+                                 forwards: int) -> int:
+        """Of the ``forwards`` the live rows of a block window of ``B``
+        rows went through, those whose program ran the routed experts as
+        the kernel: a block's first forward is two blocks wide (one a
+        block a row: ``blocks``), the others one, and each kind asks the
+        shape rule at its own rows (``moe_kernel_takes``)."""
+        def takes(blocks_wide: int) -> bool:
+            return moe_kernel_takes(self.cfg, self.params, self.mesh,
+                                    B * blocks_wide * self.block)
+
+        first = min(blocks, forwards)
+        return first * takes(2) + (forwards - first) * takes(1)
 
     def _device_stops_complete(self, seq: Sequence) -> bool:
         """True when the row's full stop-id set fit the on-device stop

@@ -154,6 +154,7 @@ STATS_PROMETHEUS_SKIP = {
            "prefill_logits_skipped_total",
            "prefill_window_topups_total", "prefill_runahead_total",
            "moe_grouped_programs_total",
+           "moe_grouped_window_forwards_total",
            "prefill_rows_held_back_total", "prefill_bucket_narrowed_total",
            "decode_rows_total", "decode_slots_total",
            "decode_windows_total", "decode_windows_sampled_total",

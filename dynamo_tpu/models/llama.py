@@ -973,33 +973,40 @@ def moe_experts_blocked(x: jax.Array, weights: jax.Array, idx: jax.Array,
                        * weights.astype(jnp.float32)[..., None], axis=1)
 
 
-# rows through one expert up to which computing EVERY expert for every
-# row is free: the experts' weights are read from HBM either way, and N
-# rows against one read of a bf16 [D, I] matrix are N FLOP a byte, under
-# the v5e's ridge (197 TFLOP/s / 819 GB/s = 240 FLOP a byte). One read
-# at every row count up to here: the dense arm's down product contracts
-# (e, i) at once, with the gate inside, on w_down [E, I, D] as stored
-# (moe_experts' docstring: a product that keeps e has the whole stack
-# relaid once an execution from 128 rows up)
-_MOE_DENSE_ROWS = 256
+# The v5e's ridge, FLOP a byte: 197 TFLOP/s over 819 GB/s. The experts'
+# weights are read from HBM in either form, and N rows against one read
+# of a bf16 [D, I] matrix are N FLOP a byte: under this many rows
+# computing EVERY expert for every row hides under that read and is
+# free; from here up the dense form is bound by its arithmetic, E / k
+# times what the router chose (Qwen3-30B-A3B's 128 top-8 on 256 rows:
+# 309 GFLOP a layer = 1.93 ms at 81% of the MXU's peak, against 1.48 ms
+# to read every expert once and less where some hold no pair; PERF.md,
+# PR 66). One read at every row count under it: the dense arm's down
+# product contracts (e, i) at once, with the gate inside, on w_down
+# [E, I, D] as stored (moe_experts' docstring: a product that keeps e
+# has the whole stack relaid once an execution from 128 rows up)
+_MOE_RIDGE_ROWS = 240
 
 
 def _moe_use_blocked(mesh, n_tokens: int, n_experts: int,
                      top_k: int) -> bool:
-    """The sorted dispatch for dispatches past the chip's ridge, and only
-    on UNSHARDED execution.
+    """The dense form while its arithmetic hides under one read of the
+    weights, the sorted dispatch from the chip's ridge up, and only on
+    UNSHARDED execution.
 
-    Up to ``_MOE_DENSE_ROWS`` rows the dense-over-experts einsum is bound
-    by the one read of the experts' weights that the sorted form pays as
-    well (every decode window, N = 1..128, and a PB 1 x T 128 prefill);
-    past it the dense form computes E/k times the row-MLPs that were
-    routed, and the sorted form only the blocks that hold a live pair.
+    Under ``_MOE_RIDGE_ROWS`` rows the dense-over-experts einsum is
+    bound by the one read of the experts' weights that the sorted form
+    pays as well (every decode window of a token a step, N = 1..128, and
+    a PB 1 x T 128 prefill); from there up (a PB 1 x T 256 chunk, the
+    block window's [64, 4] forward) the dense form computes E/k times
+    the row-MLPs that were routed, and the sorted form only the blocks
+    that hold a live pair, reading only the experts that own one.
 
     Under any >1-device mesh the tokens/experts are GSPMD-sharded and
     the sort/gather would turn into cross-device gathers — there the
     dense einsum (whose E axis shards cleanly over the "expert" mesh
     axis) stays the right program."""
-    return (top_k < n_experts and n_tokens > _MOE_DENSE_ROWS
+    return (top_k < n_experts and n_tokens >= _MOE_RIDGE_ROWS
             and (mesh is None or mesh.size == 1))
 
 
@@ -1018,10 +1025,10 @@ def moe_experts(x: jax.Array, weights, idx, w_gate, w_up, w_down,
       rows that are not padding (None = all), and with ``layer`` the w_*
       are the whole [L, E, ...] parameters, read in place.
     - dense einsum over ALL experts weighted by the routing mask:
-      decode-sized dispatches (one read of the weights bounds both
-      forms) and expert-parallel meshes (GSPMD shards the E axis of the
-      einsum; the sorted form's dynamic expert indexing would
-      all-gather). The gate's weight multiplies the activation
+      dispatches of fewer rows than the chip's ridge (one read of the
+      weights bounds both forms) and expert-parallel meshes (GSPMD
+      shards the E axis of the einsum; the sorted form's dynamic expert
+      indexing would all-gather). The gate's weight multiplies the activation
       ``act(x w_gate) * (x w_up)`` [B, T, E, I] and the down product is
       ONE contraction over (e, i) with ``w_down`` [E, I, D]: the stack
       is read in the layout it is stored in at every row count, and no
@@ -1193,8 +1200,9 @@ def deepseek_moe_mlp(x: jax.Array, lp, cfg: ModelConfig, mesh=None,
     ``lp`` holds one layer's router, bias and shared-expert leaves. The
     routed experts run through ``moe_experts``, the execution
     every gate shares: either that layer's ``[E, ...]`` stacks (the
-    dense einsum over every expert: decode-sized dispatches, where one
-    read of the weights bounds both forms, and expert-parallel meshes)
+    dense einsum over every expert: dispatches under the chip's ridge,
+    where one read of the weights bounds both forms, and expert-parallel
+    meshes)
     or, with ``layer`` (a traced index into the expert segment), the
     whole ``[Lm, E, ...]`` parameters read in place by the sorted
     blocked dispatch, whose work follows the ``live`` (token, expert)
@@ -2015,12 +2023,13 @@ def _make_block_window_fn(cfg: ModelConfig, allow_pallas: bool,
     ``lax.fori_loop`` around both, so the program holds each forward
     once whatever W is. So a block of L = 4 under a
     strategy that makes one position final a forward costs FOUR forwards,
-    the first of them 2L rows wide (its routed experts take the sorted
-    form where B * 2L passes ``_MOE_DENSE_ROWS``, by the rule of every
-    other program). The pending block crosses the window's boundary in
-    the carry: a window's last block is committed by the next window's
-    first forward, and a row that finishes leaves its last block
-    uncommitted (nothing reads it).
+    the first of them 2L rows wide (the routed experts of either kind
+    of forward take the sorted form where its rows, B * 2L or B * L,
+    reach ``_MOE_RIDGE_ROWS``, by the rule of every other program: at
+    B 64 both do, at B 8 neither). The pending block crosses the
+    window's boundary in the carry: a window's last block is committed
+    by the next window's first forward, and a row that finishes leaves
+    its last block uncommitted (nothing reads it).
 
     K/V of the pending block and of the window's blocks live in the
     window buffer [Lyr, B, (W + 1) * L, KV, hd] only (slot j holds

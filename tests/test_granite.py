@@ -584,13 +584,17 @@ def _share(cfg_hf, params, first, held):
     return cfg, cut
 
 
-@pytest.mark.parametrize("tokens", [24, 288], ids=["dense", "sorted"])
-def test_the_share_adds_up(tokens):
+@pytest.mark.parametrize("tokens,is_sorted",
+                         [(24, False), (256, True)],
+                         ids=["dense", "sorted"])
+def test_the_share_adds_up(tokens, is_sorted):
     """The guide's test of the cut (section 4): at the small size, the
     routed parts that share 0 (experts 0-5) and share 1 (experts 6-11)
     compute, plus the shared expert counted once, equal what the UNCUT
     reference gives for the whole layer; in both execution forms (24
-    tokens run dense-over-experts, 288 the sorted dispatch), with
+    tokens run dense-over-experts, 256 the sorted dispatch: a PB 1 x T
+    256 chunk's rows, the first bucket past the chip's ridge, 288 before
+    PR 66 moved the rule's edge there), with
     padding rows that count for nothing; and each share's program equals
     the reference given the same share."""
     uncut = tiny(num_local_experts=12, router_num_experts=12)
@@ -614,7 +618,7 @@ def test_the_share_adds_up(tokens):
             out = jnp.stack([REF._experts(cfg, p, row, l) for row in h]) - h
         return jnp.where(valid[..., None], out, 0.0)
 
-    assert llama._moe_use_blocked(None, tokens, 6, 3) == (tokens > 256)
+    assert llama._moe_use_blocked(None, tokens, 6, 3) is is_sorted
     whole = reference(uncut, params)
     parts = []
     for first in (0, 6):

@@ -539,16 +539,18 @@ def _share(params, first, held, k):
     return cfg, cut
 
 
-@pytest.mark.parametrize("tokens,k", [(24, 4), (288, 2)],
+@pytest.mark.parametrize("tokens,k,is_sorted",
+                         [(24, 4, False), (256, 2, True)],
                          ids=["dense", "sorted"])
-def test_the_four_shares_add_up(tokens, k):
+def test_the_four_shares_add_up(tokens, k, is_sorted):
     """The guide's test of the cut (section 4): at 16 experts in four
     shares of four (first 0, 4, 8, 12) the four partial sums, with the
     shared expert counted once, equal what the UNCUT reference gives for
     the whole layer; in both execution forms (24 tokens at top-4 run
-    dense-over-experts, 288 at top-2 the sorted dispatch, which a share
-    of 4 takes only where fewer than 4 are chosen), with padding rows that
-    count for nothing; each share's program equals the reference given
+    dense-over-experts, 256 at top-2 the sorted dispatch, which a share
+    of 4 takes only where fewer than 4 are chosen: the first bucket past
+    the chip's ridge, 288 before PR 66 moved the rule's edge there),
+    with padding rows that count for nothing; each share's program equals the reference given
     the same share, and counts the pairs that lay in its range."""
     uncut = tiny(num_experts_per_token=k)
     params = make_params(uncut, 3)
@@ -572,7 +574,7 @@ def test_the_four_shares_add_up(tokens, k):
                              for row in h]) - h
         return jnp.where(valid[..., None], out, 0.0)
 
-    assert llama._moe_use_blocked(None, tokens, 4, k) == (tokens > 256)
+    assert llama._moe_use_blocked(None, tokens, 4, k) is is_sorted
     whole = reference(uncut, params)
     parts, held = [], 0
     for first in (0, 4, 8, 12):

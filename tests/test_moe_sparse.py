@@ -166,7 +166,11 @@ def test_blocked_with_quantized_experts(in_place):
 @pytest.mark.parametrize("E,k", [(8, 2), (128, 8)])
 @pytest.mark.parametrize("N,use_sorted", [
     (1, False), (4, False), (8, False), (32, False), (64, False),
-    (128, False),               # every decode window, PB 1 x T 128
+    (128, False),               # every decode window of a token a
+                                # step, PB 1 x T 128: under the ridge
+    (256, True),                # the ridge (240 FLOP a byte) passed: a
+                                # PB 1 x T 256 chunk, the block window's
+                                # [64, 4] forward
     (512, True), (2048, True),  # PB 1 x T 512, PB 4 x T 512, PB 8 x T 256
 ])
 def test_shape_rule(N, E, k, use_sorted):
@@ -185,6 +189,8 @@ def test_shape_rule_keeps_dense_on_a_mesh():
     (2048, 8, (128, 2048, 768), None, 128),   # Qwen3-MoE, PB 8 x T 256
     (512, 8, (128, 2048, 768), None, 32),     # few pairs an expert: the
                                               # floor
+    (256, 8, (128, 2048, 768), None, 32),     # SDAR's [64, 4] forward
+    (256, 4, (64, 2048, 1536), None, 32),     # LFM2, PB 1 x T 256
     (8192, 8, (128, 2048, 768), None, 256),   # many: the ceiling
     (2048, 2, (8, 4096, 14336), None, 256),   # Mixtral, PB 4 x T 512
     (512, 2, (3, 8, 4096, 14336), None, 256),  # Mixtral, PB 1 x T 512, in

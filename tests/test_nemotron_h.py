@@ -622,14 +622,17 @@ def _share(params, first, held, **over):
     return cfg, cut
 
 
-@pytest.mark.parametrize("tokens", [24, 288], ids=["dense", "sorted"])
-def test_the_shares_add_up(tokens):
+@pytest.mark.parametrize("tokens,is_sorted",
+                         [(24, False), (256, True)],
+                         ids=["dense", "sorted"])
+def test_the_shares_add_up(tokens, is_sorted):
     """The guide's test of the cut (section 4): at the small size, the
     routed parts that the four shares (experts 0-2, 3-5, 6-8, 9-11)
     compute, EACH through W_lat_out, plus the shared expert counted
     once, equal what the UNCUT reference gives for the whole layer; in
-    both execution forms (24 tokens run dense-over-experts, 288 the
-    sorted dispatch), with padding rows that count for nothing; and each
+    both execution forms (24 tokens run dense-over-experts, 256 the
+    sorted dispatch: the first bucket past the chip's ridge, 288 before
+    PR 66 moved the rule's edge there), with padding rows that count for nothing; and each
     share's program equals the reference given the same share."""
     uncut = tiny(n_routed_experts=12, router_num_experts=12)
     params = make_params(uncut, 3)
@@ -655,7 +658,7 @@ def test_the_shares_add_up(tokens):
         return jnp.where(valid[..., None], out, 0.0)
 
     assert llama._moe_use_blocked(None, tokens, 3, 3) is False
-    assert llama._moe_use_blocked(None, tokens, 6, 3) == (tokens > 256)
+    assert llama._moe_use_blocked(None, tokens, 6, 3) is is_sorted
     whole = reference(uncut, params)
     parts = []
     for first in (0, 3, 6, 9):
@@ -672,7 +675,7 @@ def test_the_shares_add_up(tokens):
     assert np.abs(np.asarray(total - whole)).max() < ATOL
     assert np.abs(np.asarray(parts[0] - whole)).max() > 100 * ATOL
     assert np.abs(np.asarray(program(uncut, params) - whole)).max() < ATOL
-    # two shares of six run the sorted form at 288 tokens
+    # two shares of six run the sorted form at the sorted case's tokens
     halves = [program(*_share(params, first, 6)) for first in (0, 6)]
     assert np.abs(np.asarray(sum(halves) - shared - whole)).max() < ATOL
 

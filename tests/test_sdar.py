@@ -452,6 +452,56 @@ def test_generate_equals_reference_generate(strategy, run_async):
     assert eng.decode_tokens_total == 9 * len(prompts)
 
 
+def test_a_window_of_64_rows_runs_both_kinds_of_forward_sorted(
+        run_async, monkeypatch):
+    """A B 64 window: the two-block forward has 512 token rows and the
+    one-block forward 64 * L = 256, which reach the chip's ridge too
+    (``llama._MOE_RIDGE_ROWS``), so both run their experts through the
+    sorted dispatch, here as the grouped kernel under the Pallas
+    interpreter, with four live rows of 64. Tokens and the
+    log-probabilities of the forward that made each position final
+    (sequential: position 0 of a block by the two-block forward, 1 .. 3
+    by one-block forwards) against ``reference_generate``, and against
+    the same engine with the dense form forced on every forward. The
+    engine counts every row's forward as one through the kernel at B 64
+    and none where the form is forced (B 4, 32 / 16 rows:
+    ``test_warmup_covers_the_serving_forms``)."""
+    monkeypatch.setattr(llama, "_moe_kernel_interpret", lambda w: True)
+    cfg = tiny()
+    params = make_params(cfg, 5)
+    prompts = _prompts(6, 12, 13, 15, 3)
+    assert llama._moe_use_blocked(None, 64 * cfg.block_length, 8, 2)
+
+    def run():
+        eng = _engine(cfg, params, max_batch=64, batch_buckets=(64,))
+
+        async def main():
+            outs = await asyncio.gather(
+                *(_gen(eng, p, 9, logprobs=5) for p in prompts))
+            stats = eng.stats()
+            await eng.stop()
+            return outs, stats
+
+        return run_async(main())
+
+    outs, stats = run()
+    for p, (toks, tops, finish) in zip(prompts, outs):
+        want, rows, _ = REF.reference_generate(params, cfg, p, 9)
+        assert toks == want and finish == "length"
+        assert _max_diff(rows, tops) < ATOL
+    assert stats["diffusion_forwards_total"] > stats[
+        "diffusion_blocks_total"] > 0
+    assert stats["moe_grouped_window_forwards_total"] == stats[
+        "diffusion_forwards_total"]
+    monkeypatch.setattr(llama, "_moe_use_blocked", lambda *a: False)
+    dense, forced = run()
+    assert forced["moe_grouped_window_forwards_total"] == 0
+    for (toks, tops, _), (dtoks, dtops, _) in zip(outs, dense):
+        assert toks == dtoks
+        assert max(abs(top[i] - dtop[i]) for top, dtop in zip(tops, dtops)
+                   for i in top) < ATOL
+
+
 @pytest.mark.parametrize("strategy", STRATEGIES)
 def test_a_prompt_arriving_mid_decode_equals_reference_generate(strategy):
     """The scheduler's window behind a prefill (JaxEngine._step_window,
@@ -948,13 +998,20 @@ def test_a_penalty_is_refused_by_the_request(sampling, run_async):
 # ------------------------------------------------------ warm-up and sampling
 
 
-def test_warmup_covers_the_serving_forms(run_async, monkeypatch):
+@pytest.mark.parametrize("buckets,n", [((4,), 3), ((4, 64), 6)],
+                         ids=["dense", "sorted_at_64"])
+def test_warmup_covers_the_serving_forms(run_async, monkeypatch, buckets,
+                                         n):
     """No compile after warm-up: the block window from a host-seeded
     carry and from the previous window's, the merge of the two, the
-    prefill without a sampling program."""
+    prefill without a sampling program. The window is compiled ONCE a
+    batch bucket, with the form of the experts it serves: at B 64 both
+    kinds of forward take the sorted dispatch (the grouped kernel under
+    the interpreter here), at B 4 neither."""
     monkeypatch.setenv("DYN_JIT_FENCE", "raise")
-    eng = _engine()
-    prompts = _prompts(16, 12, 14, 19)
+    monkeypatch.setattr(llama, "_moe_kernel_interpret", lambda w: True)
+    eng = _engine(max_batch=buckets[-1], batch_buckets=buckets)
+    prompts = _prompts(16, 12, 14, 19, 13, 12, 15)[:n]
 
     async def main():
         eng.warmup()
@@ -966,7 +1023,9 @@ def test_warmup_covers_the_serving_forms(run_async, monkeypatch):
     outs, stats = run_async(main())
     assert all(len(t) == 20 for t, _, _ in outs)
     assert stats["post_warmup_compiles_total"] == 0
-    assert stats["first_tokens_total"] == 3
+    assert stats["first_tokens_total"] == n
+    assert eng.decode_multi_fn.__wrapped__._cache_size() == len(buckets)
+    assert (stats["moe_grouped_window_forwards_total"] > 0) == (n > 4)
 
 
 def test_sampled_rows_draw_by_position(run_async):

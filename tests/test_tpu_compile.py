@@ -744,8 +744,9 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
     of 4, each a [B, 2L] forward that carries the block before it, with
     the decode kernel at group 8 x 8 = 64 and the 512 rows' experts
     through ops/moe_grouped.py, then a while loop of [B, L] denoising
-    forwards, the kernel at group 32 and the experts dense; a token
-    operand of two blocks a row) and a block-causal prefill chunk (PB 8
+    forwards, the kernel at group 32 and the 256 rows' experts through
+    ops/moe_grouped.py too, since the shape rule stands at the chip's
+    ridge (PR 66; dense before); a token operand of two blocks a row) and a block-causal prefill chunk (PB 8
     x T 256, the prefill kernel with the block edge; the expert count
     cut to 8 so that compile stays short: it takes the dense form). The
     pools alias their inputs, and the window holds no copy of a pool's
@@ -805,11 +806,11 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
     assert _has_kernel(compiled)
     text = compiled.as_text()
     assert _pool_sized_copies(text, kv_k.size) == []
-    # a block's two forwards and the sorted dispatch of the first, once
-    # each: the window's blocks are ONE loop
-    assert text.count("tpu_custom_call") == 3
-    # the two-block forward's sorted dispatch reads w[layer, expert]
-    # where the stacks lie: nothing that runs only MOVES a layer's
+    # a block's two forwards and the sorted dispatch of each, once each:
+    # the window's blocks are ONE loop
+    assert text.count("tpu_custom_call") == 4
+    # either forward's sorted dispatch reads w[layer, expert] where the
+    # stacks lie: nothing that runs only MOVES a layer's
     # experts (sliced out of the scan's xs for the kernel, each of the
     # three would be copied a layer a forward: 1.2 GB)
     down = params["w_down"]
@@ -819,10 +820,12 @@ def test_sdar_programs_alias_their_pools_and_copy_none(one_chip,
     pool_bytes = kv_k.size * kv_k.dtype.itemsize
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     # the agreement check's variant holds [B x 4, V] log-probabilities
-    # and their top 20 besides. The other (595.5 MiB, 585.0 on the parent
-    # of PR 62: the two-block forward's rows in and out of the sorted
-    # dispatch, [4,096 + padding, 2,048], and a buffer of one block
-    # more; scratch compile, PR 62): 145 MB + THREE arrays of the
+    # and their top 20 besides. The other (595.8 MiB; 595.5 before PR 66
+    # sent the one-block forward's 256 rows through the sorted dispatch
+    # as well, whose buffers lie where the two-block forward's did; 585.0
+    # on the parent of PR 62: the two-block forward's rows in and out of
+    # the sorted dispatch, [4,096 + padding, 2,048], and a buffer of one
+    # block more; scratch compiles, PR 62, PR 66): 145 MB + THREE arrays of the
     # logits' size (156 MB each) in the arm of the draw that a sampled
     # row switches on, where the program without a branch held two: the
     # head's output (the logits over the temperature) is the operand of
@@ -1733,8 +1736,11 @@ def test_dense_experts_relay_no_expert_stack(one_chip, tpu_kernel_path,
     itself, the parent's program shows no copy, while the program the
     engine compiles from its arrays cost 22.4 ms on the chip for the
     14.2 it costs now (warmup()'s timed table, PERF.md, PR 59; PR 33
-    saw `copy bf16[6,64,1536,2048]` in its trace). The case keeps the
-    shape under the rule. A layer's w_gate_e / w_up_e have the same
+    saw `copy bf16[6,64,1536,2048]` in its trace). Since PR 66 the
+    chunk's 256 rows stand past the shape rule's edge (the chip's ridge)
+    and take the sorted dispatch, which reads w[layer, expert] in place:
+    the case keeps the shape under the rule, whichever form that picks.
+    A layer's w_gate_e / w_up_e have the same
     element count, so the rule holds them as well (the flat `[N, E*I] @
     [E*I, D]` form relays those two instead)."""
     if cell == "lfm2":

@@ -25,7 +25,9 @@ forms of the warm grid.
 The benchmark's result line holds only what its metric readers take; a
 counter a PR adds to the program (``moe_grouped_programs_total`` beside
 ``prefill_dispatches_total``: PERF.md, PR 42; ``prefill_rows_held_back_
-total`` and ``prefill_bucket_narrowed_total``: PR 43) is read this way
+total`` and ``prefill_bucket_narrowed_total``: PR 43; ``moe_grouped_
+window_forwards_total`` beside ``diffusion_forwards_total`` or
+``decode_rows_total``: PR 66) is read this way
 without an edit under benchmark/.
 
     chiprun -- python3 tools/bench_with_counters.py \

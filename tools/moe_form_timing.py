@@ -6,16 +6,19 @@ shapes.
 Times ``models.llama.moe_experts`` behind a softmax top-k router on one
 layer of Mixtral-8x7B's experts (8, top-2, 4,096 x 14,336; cells 1, 3)
 at N = 128 / 512 / 2,048 rows, of Qwen3-30B-A3B's (128, top-8, 2,048 x
-768; cells 2, 7) at N = 2,048, of LFM2-24B-A2B's (64, top-4, 2,048 x
-1,536; cell 6) at N = 256 (the PB 1 x T 256 suffix chunk) / 1,024 /
-2,048, of granite-4.0-h-small's (the 36 this chip holds of 72, top-10,
-4,096 x 768; cell 8) at N = 512 / 2,048, and of Kimi-Linear-48B-A3B's
+768; cells 2, 7) at N = 256 (the one-block ``[64, 4]`` forward of cell
+7's window) / 2,048, of Kanana-2-30B-A3B's (128, top-6, 2,048 x 768;
+cell 5) at N = 256 (its PB 1 x T 256 suffix chunk), of LFM2-24B-A2B's
+(64, top-4, 2,048 x 1,536; cell 6) at N = 256 (the PB 1 x T 256 suffix
+chunk) / 1,024 / 2,048, of granite-4.0-h-small's (the 36 this chip
+holds of 72, top-10, 4,096 x 768; cell 8) at N = 512 / 2,048, and of Kimi-Linear-48B-A3B's
 (the 64 held of 256, top-8, 2,304 x 1,024; cell 10) and Solar-Open2-250B's
 (the 40 held of 320, top-8, 4,096 x 1,280; cell 11) at N = 128, their
 B 128 decode window's rows, with ~13%, ~50% and 100% of the rows live,
 in three forms:
 
-- ``dense``: every expert on every row (``_moe_use_blocked`` says no),
+- ``dense``: every expert on every row (what ``_moe_use_blocked``
+  picks under the chip's ridge, ``llama._MOE_RIDGE_ROWS`` = 240 rows),
   the gate inside the down product, one contraction over (e, i) on
   ``w_down`` [E, I, D] as stored. The arm ALONE, on one layer's slice
   outside any loop: the whole-stack relayout a down product that keeps
@@ -29,8 +32,16 @@ in three forms:
   (ops/moe_grouped.py; what a TPU runs since PR 42).
 
 The rule of ``_moe_use_blocked`` / ``moe_block`` rests on this table
-(PERF.md, PR 28, PR 42). The form is forced from here, by patching what
-picks it while the program is traced. The sorted forms read ``w[layer,
+(PERF.md, PR 28, PR 42, PR 66). Where the rule stands: the dense form
+while its arithmetic hides under one read of the weights, the sorted
+one from 240 rows up. At N = 128 the dense form IS one read of the
+weights; at N = 256 it is bound by the MXU (Qwen3's and Kanana's 1.92 ms
+a layer = 81% of the peak, LFM2's 1.71 = 92%) whatever the rows hold,
+and the kernel costs 1.82-1.85 ms with every row live and every expert
+touched (1.74 for LFM2: the one shape where it is 0.03 behind) and
+11-12 us less for each expert that holds no pair (1.51-1.68 at 13%
+live): PERF.md section 6, PR 66. The form is forced from here, by
+patching what picks it while the program is traced. The sorted forms read ``w[layer,
 expert]`` in place from a ``[2, E, ...]`` stack, as the cells' prefill
 programs do, at ``moe_block``'s rows a block and, with ``--blocks 128,
 256``, at others. Dead rows are what a padded prefill holds: one and the
@@ -77,7 +88,8 @@ from dynamo_tpu.ops import moe_grouped
 
 # (name, experts held, router's width, first held, k, D, I, N...)
 SHAPES = [("mixtral", 8, 8, None, 2, 4096, 14336, (128, 512, 2048)),
-          ("qwen3", 128, 128, None, 8, 2048, 768, (2048,)),
+          ("qwen3", 128, 128, None, 8, 2048, 768, (256, 2048)),
+          ("kanana", 128, 128, None, 6, 2048, 768, (256,)),
           ("lfm2", 64, 64, None, 4, 2048, 1536, (256, 1024, 2048)),
           ("granite", 36, 72, 18, 10, 4096, 768, (512, 2048)),
           ("kimi", 64, 256, 0, 8, 2304, 1024, (128,)),
